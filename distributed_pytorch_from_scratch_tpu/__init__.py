@@ -8,7 +8,6 @@ for the reference analysis and build plan.
 
 __version__ = "0.1.0"
 
-from .runtime import compat as _compat  # noqa: F401  (must precede jax use)
 from .config import (
     BOS_TOKEN, EOS_TOKEN, UNK_TOKEN, IGNORE_INDEX,
     EvalConfig, MeshConfig, ModelConfig, OptimizerConfig, TrainConfig,

@@ -4,11 +4,10 @@ Two layers:
 
 * **Layer 1 — source lints** (`rules.py`, `lints_source.py`,
   `lints_traced.py`, `report.py`): pure-AST rules for this codebase's
-  known failure classes — compat-shim bypass, use-after-donate, host
-  calls inside traced code, PRNG key reuse, lock discipline, dead
-  imports/unreachable code. Stdlib-only: importing these modules never
-  imports jax, so `scripts/graftcheck.py` can sweep the repo on a box
-  where jax is broken (the situation runtime/compat.py exists for).
+  known failure classes — use-after-donate, host calls inside traced
+  code, PRNG key reuse, lock discipline, dead imports/unreachable code.
+  Stdlib-only: importing these modules never imports jax, so
+  `scripts/graftcheck.py` sweeps the repo without paying the jax import.
 
 * **Layer 2 — trace contracts** (`programs.py`, `contracts.py`): lower
   the canonical programs (train step across the ZeRO × wire matrix,

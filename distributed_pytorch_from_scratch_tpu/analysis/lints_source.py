@@ -1,10 +1,7 @@
-"""Source-discipline lints: compat-API bypass, dead imports, unreachable
-statements, and host-thread lock discipline (graftcheck layer 1).
+"""Source-discipline lints: dead imports, unreachable statements, and
+host-thread lock discipline (graftcheck layer 1).
 
-Stdlib-only — see `rules.py`. The compat-bypass rule reads the shimmed
-surface out of `runtime/compat.py`'s own source (an AST literal-eval of its
-`SHIMMED_SURFACE` assignment), so the shim module stays the single owner of
-that list without this module ever importing jax.
+Stdlib-only — see `rules.py`.
 """
 
 from __future__ import annotations
@@ -14,8 +11,6 @@ import os
 from typing import List, Optional, Set
 
 from .rules import SourceFile, Violation, rule
-
-PACKAGE = "distributed_pytorch_from_scratch_tpu"
 
 
 def dotted(node: ast.AST) -> Optional[str]:
@@ -28,92 +23,6 @@ def dotted(node: ast.AST) -> Optional[str]:
         parts.append(node.id)
         return ".".join(reversed(parts))
     return None
-
-
-# ------------------------------------------------------------ compat-bypass --
-
-_FALLBACK_SURFACE = ("jax.shard_map", "jax.typeof", "jax.lax.axis_size",
-                     "jax.lax.pvary")
-_surface_cache: Optional[tuple] = None
-
-
-def shimmed_surface() -> tuple:
-    """The dotted names `runtime/compat.py` shims, read from its
-    `SHIMMED_SURFACE` literal by AST (no import, no jax). Falls back to the
-    names known at this rule's writing if the assignment ever goes missing
-    — the lint degrading is better than the lint crashing."""
-    global _surface_cache
-    if _surface_cache is not None:
-        return _surface_cache
-    compat = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "runtime", "compat.py")
-    surface = _FALLBACK_SURFACE
-    try:
-        tree = ast.parse(open(compat, encoding="utf-8").read())
-        for node in ast.walk(tree):
-            if (isinstance(node, ast.Assign)
-                    and any(isinstance(t, ast.Name)
-                            and t.id == "SHIMMED_SURFACE"
-                            for t in node.targets)):
-                surface = tuple(ast.literal_eval(node.value))
-    except (OSError, SyntaxError, ValueError):
-        pass
-    _surface_cache = surface
-    return surface
-
-
-@rule("compat-bypass",
-      "raw jax API use that bypasses the runtime/compat.py shim layer",
-      "the 0.4.x image breakage PR 2's compat shims fixed: direct "
-      "jax.experimental.shard_map imports and shimmed-surface calls from "
-      "modules that never load the shim break on old-jax images")
-def check_compat_bypass(src: SourceFile) -> List[Violation]:
-    if src.path.replace(os.sep, "/").endswith("runtime/compat.py"):
-        return []
-    out: List[Violation] = []
-    imports_package = False
-    imports_jax = False
-    for node in src.nodes:
-        if isinstance(node, ast.Import):
-            for a in node.names:
-                if a.name.split(".")[0] == PACKAGE:
-                    imports_package = True
-                if a.name.split(".")[0] == "jax":
-                    imports_jax = True
-                if a.name.startswith("jax.experimental.shard_map"):
-                    out.append(Violation(
-                        "compat-bypass", src.path, node.lineno,
-                        "import of jax.experimental.shard_map bypasses "
-                        "runtime/compat.py — use jax.shard_map (the shim "
-                        "guarantees it exists and defaults check_rep off "
-                        "on legacy jax)"))
-        elif isinstance(node, ast.ImportFrom):
-            mod = node.module or ""
-            if mod.split(".")[0] == PACKAGE or node.level:
-                imports_package = True
-            if mod.startswith("jax.experimental.shard_map") or (
-                    mod == "jax.experimental"
-                    and any(a.name == "shard_map" for a in node.names)):
-                out.append(Violation(
-                    "compat-bypass", src.path, node.lineno,
-                    "import of jax.experimental.shard_map bypasses "
-                    "runtime/compat.py — use jax.shard_map"))
-    # shimmed-surface attribute uses are only safe when the compat shim is
-    # guaranteed loaded first: package modules get it from the package
-    # __init__; anything else must import the package (or the shim) itself
-    if src.in_package or imports_package or not imports_jax:
-        return out
-    surface = set(shimmed_surface())
-    for node in src.nodes:
-        name = dotted(node) if isinstance(node, ast.Attribute) else None
-        if name in surface:
-            out.append(Violation(
-                "compat-bypass", src.path, node.lineno,
-                f"{name} is shimmed by runtime/compat.py but this module "
-                f"never loads the shim (import the package, or "
-                f"runtime.compat, before first jax use) — on a 0.4.x "
-                f"image this call does not exist"))
-    return out
 
 
 # ------------------------------------------------------------ unused-import --

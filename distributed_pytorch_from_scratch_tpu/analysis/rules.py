@@ -2,10 +2,9 @@
 
 Layer 1 of the static checker (ISSUE 11). Everything in this module — and
 in the lint modules it drives (`lints_source.py`, `lints_traced.py`) — is
-STDLIB-ONLY: no jax, no package imports. The rules must be runnable on an
-image where jax is broken or absent (the exact situation `runtime/compat.py`
-exists for), and from a standalone `scripts/graftcheck.py` invocation that
-never pays the jax import. Layer 2 (the trace contracts in `contracts.py`)
+STDLIB-ONLY: no jax, no package imports. The rules must be runnable from a
+standalone `scripts/graftcheck.py` invocation that never pays the jax
+import. Layer 2 (the trace contracts in `contracts.py`)
 is the only part that imports jax, and only lazily.
 
 Every rule is the static form of a bug this repo actually shipped or

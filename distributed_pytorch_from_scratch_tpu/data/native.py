@@ -92,6 +92,15 @@ def native_available() -> bool:
     return get_lib() is not None
 
 
+def native_status() -> str:
+    """Which collate path a loader gets, for the entry points to print:
+    the C++ library (built from csrc/dataloader.cpp on first use), or the
+    numpy path and why."""
+    if native_available():
+        return "native C++ (csrc/dataloader.cpp)"
+    return f"numpy (native library unavailable: {_lib_err})"
+
+
 class NativeBPE:
     """Byte-level BPE encoder backed by the C++ library, loaded from a HF
     `tokenizer.json`. Construction verifies parity against the HF encoder on

@@ -33,6 +33,7 @@ from .config import BOS_TOKEN, EOS_TOKEN, IGNORE_INDEX, MeshConfig
 from .data.dataset import get_dataloader
 from .models.transformer import Transformer
 from .obs import SpanTracer
+from .runtime.compile_cache import enable_compile_cache
 from .runtime.mesh import batch_feeder, init_multihost, make_mesh
 from .training.checkpoint import list_checkpoints, load_checkpoint
 from .training.metrics import MetricsWriter
@@ -465,6 +466,7 @@ def evaluate(args: argparse.Namespace) -> dict:
 
 
 def main(argv=None):
+    enable_compile_cache()
     evaluate(get_eval_args(argv))
 
 

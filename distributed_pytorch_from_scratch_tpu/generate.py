@@ -19,6 +19,7 @@ import jax
 from .cli import add_model_shape_args, build_model_config
 from .config import BOS_TOKEN, EOS_TOKEN, MeshConfig
 from .models.transformer import Transformer
+from .runtime.compile_cache import enable_compile_cache
 from .runtime.mesh import make_mesh
 from .training.checkpoint import latest_step, load_checkpoint
 
@@ -170,6 +171,7 @@ def generate(args: argparse.Namespace) -> list:
 
 
 def main(argv=None):
+    enable_compile_cache()
     return generate(get_generate_args(argv))
 
 

@@ -21,14 +21,27 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional
 
-# Per-chip peak bf16 FLOP/s and HBM bandwidth (bytes/s). The FLOPS side
-# must agree with training.metrics.PEAK_FLOPS; bandwidth is the roofline's
-# other axis. Unknown chips assume v5e, clearly labelled in the report.
+# THE peaks table: per-chip peak bf16 FLOP/s and HBM bandwidth (bytes/s).
+# Source: Google Cloud TPU documentation, the per-version "System
+# architecture" pages (v4: 275 TFLOP/s, 1228 GB/s; v5e: 197 TFLOP/s,
+# 819 GB/s; v5p: 459 TFLOP/s, 2765 GB/s; v6e: 918 TFLOP/s, 1640 GB/s).
+# MFU (training.metrics.chip_peak_flops) and every roofline here divide by
+# these and nothing else; a chip that is not listed is an error, not a v5e.
 CHIP_SPECS = {
+    "v4": (275e12, 1228e9),
     "v5e": (197e12, 819e9),
     "v5p": (459e12, 2765e9),
-    "v4": (275e12, 1228e9),
     "v6e": (918e12, 1640e9),
+}
+
+# jax `device_kind` -> CHIP_SPECS key, spelled as the TPU backend reports
+# them (both spellings per chip, as jax's own tpu_info lists them; "TPU v5
+# lite" is what the v5e machine answered, PR 21).
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e", "TPU v5e": "v5e",
+    "TPU v5": "v5p", "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e", "TPU v6e": "v6e",
 }
 
 # Per-chip ICI terms: (one-way per-link ring bandwidth bytes/s, per-hop
@@ -50,14 +63,28 @@ ALLREDUCE_PROBE_BYTES = 4 * 2**20  # metrics.allreduce_p50_us's payload
 
 def chip_key_for(device_kind: str) -> str:
     """CHIP_SPECS key for a jax `device_kind` string ('TPU v6 lite' ->
-    'v6e'; unknown kinds assume v5e — reports label the assumption).
-    The one copy of the lite->e normalization: bench.py's chip_key and
-    train.py's duty-profiler chip detection both route through here."""
-    kind = device_kind.lower().replace(" ", "").replace("lite", "e")
-    for key in sorted(CHIP_SPECS, key=len, reverse=True):
-        if key in kind:
-            return key
-    return "v5e"
+    'v6e'). An unlisted kind — the CPU included — raises: a number divided
+    by another chip's peak is not a measurement."""
+    try:
+        return DEVICE_KINDS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s / HBM bandwidth known for device_kind "
+            f"{device_kind!r}: MFU and roofline numbers cannot be produced "
+            f"on it (known kinds: {sorted(DEVICE_KINDS)}; add the chip, "
+            f"with its source, to obs/attribution.CHIP_SPECS)") from None
+
+
+def chip_specs(chip: str, table: Optional[dict] = None):
+    """(peak bf16 FLOP/s, HBM bytes/s) for a CHIP_SPECS key — or the chip's
+    row of another per-chip `table` (ICI_SPECS); unknown keys raise with
+    the known ones listed, never priced as some other chip."""
+    table = CHIP_SPECS if table is None else table
+    try:
+        return table[chip]
+    except KeyError:
+        raise ValueError(f"unknown chip {chip!r}; expected one of "
+                         f"{sorted(table)}") from None
 
 
 def calibrate_ici(chip: str, n: int,
@@ -71,7 +98,7 @@ def calibrate_ici(chip: str, n: int,
     re-pricing the probe collective with the fitted terms reproduces the
     measurement. This is the 'learned ICI term': one measured collective
     pins the line the whole comm attribution is priced on."""
-    bw, lat = ICI_SPECS.get(chip, ICI_SPECS["v5e"])
+    bw, lat = chip_specs(chip, ICI_SPECS)
     if measured_allreduce_us and n > 1:
         wire = measured_allreduce_us * 1e-6 - 2 * (n - 1) * lat
         if wire > 0:
@@ -274,7 +301,7 @@ def cp_ring_attribution(cfg, batch: int, chunk: int, context: int,
     cp = max(1, cp)
     bw, lat = calibrate_ici(chip, cp,
                             measured_allreduce_us if cp > 1 else None)
-    peak_flops, _ = CHIP_SPECS.get(chip, CHIP_SPECS["v5e"])
+    peak_flops, _ = chip_specs(chip)
     A = 2 if "bf16" in str(cfg.compute_dtype) or "bfloat16" in str(
         cfg.compute_dtype) else 4
     L, h, hd = cfg.num_layers, cfg.num_heads, cfg.head_dim
@@ -384,7 +411,7 @@ def comm_attribution(cfg, batch: int, t: int, tp: int = 1, sp: bool = False,
     bw, lat = calibrate_ici(chip, tp,
                             measured_allreduce_us if tp > 1 else None)
     if phase_ms is None:
-        peak_flops, hbm_bw = CHIP_SPECS.get(chip, CHIP_SPECS["v5e"])
+        peak_flops, hbm_bw = chip_specs(chip)
         world = max(1, tp * dp)
         phases = analytic_phases(cfg, batch, t, remat, family=family)
         phase_ms = {p.name: p.ms(peak_flops * world, hbm_bw * world)
@@ -559,7 +586,7 @@ def attribution(cfg, batch: int, t: int, remat: str = "dots", spd: int = 8,
     measured keys (all optional, ms): fwd_ms, fwdbwd_ms, step_ms,
     h2d_ms, and any 'step_ms_spdN'.
     """
-    peak_flops, hbm_bw = CHIP_SPECS.get(chip, CHIP_SPECS["v5e"])
+    peak_flops, hbm_bw = chip_specs(chip)
     peak_flops *= world
     hbm_bw *= world
     phases = analytic_phases(cfg, batch, t, remat, t_real, block_q, block_k,
@@ -1001,7 +1028,7 @@ def kv_transfer_attribution(pages: int, page_bytes_each: int,
                          f"{pages}/{page_bytes_each}")
     if link not in ("ici", "dcn"):
         raise ValueError(f"link must be 'ici' or 'dcn', got {link!r}")
-    ici_bw, lat = ICI_SPECS.get(chip, ICI_SPECS["v5e"])
+    ici_bw, lat = chip_specs(chip, ICI_SPECS)
     bw = ici_bw if link == "ici" else DCN_BANDWIDTH
     nbytes = pages * page_bytes_each
     ms = (nbytes / bw + lat) * 1e3

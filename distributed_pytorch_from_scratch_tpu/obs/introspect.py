@@ -60,9 +60,17 @@ def _shape_bytes(shape: str) -> int:
     return sum(_member_bytes(shape))
 
 
+# who talks to whom: `replica_groups={{0,1},{2,3}}` / `[2,2]<=[4]` on the
+# reducing/gathering ops, `source_target_pairs={{0,1},{1,0}}` on permutes
+_GROUPS_RE = re.compile(
+    r"(?:replica_groups|source_target_pairs)=(\S+?)(?:,\s|\s|$)")
+
+
 def parse_collectives(hlo_text: str) -> Dict[str, dict]:
-    """{op_kind: {"count": N, "bytes": output bytes summed}} from optimized
-    HLO. Output-shape bytes are the standard per-hop accounting unit (a
+    """{op_kind: {"count": N, "bytes": output bytes summed, "groups":
+    distinct device groupings}} from optimized HLO. Two mesh axes show up
+    as two groupings (dp2 x tp2: tp ops over {{0,1},{2,3}}, dp ops over
+    {{0,2},{1,3}}). Output-shape bytes are the standard per-hop accounting unit (a
     ring all-reduce moves ~2x this on the wire; the relative picture across
     collectives is what matters). Async `-start` forms carry a
     (operand..., result, context...) tuple shape — only the LARGEST member
@@ -71,8 +79,13 @@ def parse_collectives(hlo_text: str) -> Dict[str, dict]:
     out: Dict[str, dict] = {}
     for m in _COLL_RE.finditer(hlo_text):
         op = m.group("op")
-        rec = out.setdefault(op, {"count": 0, "bytes": 0})
+        rec = out.setdefault(op, {"count": 0, "bytes": 0, "groups": []})
         rec["count"] += 1
+        eol = hlo_text.find("\n", m.end())
+        g = _GROUPS_RE.search(hlo_text, m.end(),
+                              eol if eol >= 0 else len(hlo_text))
+        if g and g.group(1) not in rec["groups"]:
+            rec["groups"].append(g.group(1))
         members = _member_bytes(m.group("shape"))
         rec["bytes"] += (max(members, default=0) if m.group("start")
                          else sum(members))

@@ -52,22 +52,38 @@ def causal_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
+IMPLS = ("auto", "xla", "flash", "flash_interpret")
+
+
+def resolve_attention_impl(impl: str) -> str:
+    """The attention implementation `impl` runs as on this backend — what
+    the entry points report, so a caller always knows which one it got.
+
+    'auto' is the Pallas flash kernel on TPU and the XLA path elsewhere.
+    'flash' is compiled by Mosaic: asked for by name off-TPU it is an
+    error, never a quiet interpreter run. 'flash_interpret' is the explicit
+    interpreter opt-in (the CPU tests)."""
+    if impl not in IMPLS:
+        raise ValueError(f"unknown attention impl {impl!r}; expected one of "
+                         f"{IMPLS}")
+    if impl == "auto":
+        return "flash" if jax.default_backend() == "tpu" else "xla"
+    if impl == "flash" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"attention impl 'flash' is compiled by Mosaic and needs a TPU "
+            f"backend (got {jax.default_backend()!r}); use 'xla' (or 'auto') "
+            f"off-TPU, or 'flash_interpret' to run the kernel under the "
+            f"Pallas interpreter on purpose")
+    return impl
+
+
 def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      impl: str = "auto", t_real: int = None) -> jax.Array:
-    if impl == "auto":
-        # Pallas flash on real TPU (1.5x faster fwd+bwd at reference scale,
-        # takes the 45M b32xt1000 train step from 25.9% to 30.0% MFU on v5e);
-        # on CPU the kernel only runs interpreted (slow), so use XLA there.
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+    impl = resolve_attention_impl(impl)
     if impl == "xla":
         return causal_attention_xla(q, k, v, t_real=t_real)
-    if impl == "flash":
-        try:
-            from .pallas.flash_attention import flash_attention
-        except ImportError as e:
-            raise NotImplementedError(
-                "the Pallas flash-attention kernel is not available in this "
-                "build; use impl='xla'") from e
-        # block sizes come from the autotuner table (get_block_config)
-        return flash_attention(q, k, v, t_real=t_real)
-    raise ValueError(f"unknown attention impl {impl!r}")
+    from .pallas.flash_attention import flash_attention
+
+    # block sizes come from the tuned-block table (get_block_config)
+    return flash_attention(q, k, v, t_real=t_real,
+                           interpret=impl == "flash_interpret")

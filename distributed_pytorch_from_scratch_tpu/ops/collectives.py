@@ -8,9 +8,9 @@ the right thing:
 
   reference op                      JAX primitive          transpose
   ------------------------------    -------------------    -------------------
-  Copy    (fwd id, bwd all-reduce,  lax.pvary              lax.psum
+  Copy    (fwd id, bwd all-reduce,  lax.pcast(to=varying)  lax.psum
            comm_ops.py:47-60)
-  Reduce  (fwd all-reduce, bwd id,  lax.psum               lax.pvary
+  Reduce  (fwd all-reduce, bwd id,  lax.psum               lax.pcast(to=varying)
            comm_ops.py:31-44)
   Split   (fwd slice, bwd gather,   slice at axis_index    zero-pad + psum
            comm_ops.py:7-28)                                (== all-gather)
@@ -34,12 +34,31 @@ All ops MUST be called from inside `shard_map` code partitioned over `axis`.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 from jax import lax
 
 
 def _axis_size(axis: str) -> int:
     return lax.axis_size(axis)
+
+
+def vma_tracked(axis: str) -> bool:
+    """Is this shard_map body typed with varying-manual-axes (the default),
+    or was it built with check_vma=False? `axis_index` varies over its axis
+    by definition, so its type says which: under check_vma=False every
+    value's vma is empty."""
+    return bool(jax.typeof(lax.axis_index(axis)).vma)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _copy_untyped(x, axes):
+    return x
+
+
+_copy_untyped.defvjp(lambda x, axes: (x, None),
+                     lambda axes, _, ct: (lax.psum(ct, axes),))
 
 
 def copy_to(x: jax.Array, axis: str = "tp") -> jax.Array:
@@ -52,16 +71,21 @@ def copy_to(x: jax.Array, axis: str = "tp") -> jax.Array:
     No-op when `x` is already varying over `axis`: an already-varying input
     got its tag from an upstream collective (e.g. the sequence-parallel
     all-gather) whose own transpose performs the gradient sum — a second
-    pvary would be ill-typed, and the psum belongs to that producer.
+    varying cast would be ill-typed, and the psum belongs to that producer.
+
+    Inside a shard_map built with check_vma=False (training/zero.py's
+    per-shard-grad builders) there are no tags and a varying cast does not
+    transpose; the same forward/backward pair is then spelled as a custom
+    VJP.
     """
-    vma = getattr(jax.typeof(x), "vma", frozenset()) or frozenset()
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
+    if not vma_tracked(axes[0]):
+        return _copy_untyped(x, axes)
+    vma = jax.typeof(x).vma
     need = tuple(a for a in axes if a not in vma)
     if not need:
         return x
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, need, to="varying")
-    return lax.pvary(x, need)
+    return lax.pcast(x, need, to="varying")
 
 
 def reduce_from(x: jax.Array, axis: str = "tp") -> jax.Array:

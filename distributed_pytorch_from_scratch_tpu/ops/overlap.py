@@ -104,6 +104,18 @@ def _ring_hop_q(z: jax.Array, axis: str, dtype):
     return dequantize_rows(q, sc, dtype)
 
 
+def _like_primal(ct: jax.Array, primal: jax.Array) -> jax.Array:
+    """Type a custom-VJP cotangent like its primal. A weight replicated
+    over the batch axes meets activations that vary over them, so its
+    per-shard cotangent varies over those axes too; shard_map's typing
+    wants it back as replicated as the weight, which is the psum autodiff
+    itself inserts for a plain `x @ w` (the transpose of the implicit
+    varying cast on w). No-op where the types already agree, and under
+    check_vma=False, where nothing carries a type."""
+    extra = tuple(sorted(jax.typeof(ct).vma - jax.typeof(primal).vma))
+    return lax.psum(ct, extra) if extra else ct
+
+
 def _check_2d(name: str, x: jax.Array) -> None:
     if x.ndim < 2:
         raise ValueError(f"{name} needs a (..., seq, feature) operand, got "
@@ -136,10 +148,8 @@ def ring_all_gather(x: jax.Array, axis: str, dim: int = 0) -> jax.Array:
     matmuls are still in flight.
 
     The TRANSPOSE is the conjugate ring reduce-scatter: ppermute transposes
-    to the reverse ppermute (value-correct under this container's legacy
-    shard_map — see training/zero.build_bucketed_grad_fn's note), so
-    differentiating through this gather hands each rank the dp-SUMMED
-    cotangent of its own chunk. That emergent reduce-scatter IS ZeRO-2/3's
+    to the reverse ppermute, so differentiating through this gather hands
+    each rank the dp-SUMMED cotangent of its own chunk. That emergent reduce-scatter IS ZeRO-2/3's
     gradient wire: half the all-reduce bytes, derived by autodiff instead
     of hand-written.
     """
@@ -282,7 +292,7 @@ def _ag_matmul_bwd(axis, quantized, res, dys):
         if s < n - 1:
             chunk = (dequantize_rows(q, sc, x.dtype) if quantized else nxt)
     return dx_acc.astype(x.dtype), tuple(
-        dw.astype(w.dtype) for dw, w in zip(dws, ws))
+        _like_primal(dw.astype(w.dtype), w) for dw, w in zip(dws, ws))
 
 
 ag_matmul.defvjp(_ag_matmul_fwd, _ag_matmul_bwd)
@@ -377,7 +387,7 @@ def _matmul_rs_bwd(axis, quantized, res, dy):
                                 axes=(bdims, bdims))
         if s < n - 1:
             chunk = (dequantize_rows(q, sc, dy.dtype) if quantized else nxt)
-    return dx, dw.astype(w.dtype)
+    return dx, _like_primal(dw.astype(w.dtype), w)
 
 
 matmul_rs.defvjp(_matmul_rs_fwd, _matmul_rs_bwd)

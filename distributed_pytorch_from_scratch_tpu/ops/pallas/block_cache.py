@@ -3,7 +3,8 @@
 Both kernel families keep a small in-memory table of tuned block shapes
 — flash (`flash_attention.py`: (t_bucket, head_dim, dtype, backend) ->
 BlockConfig) and paged (`paged_attention.py`: (page_size, head_dim,
-kv_dtype, backend) -> PagedBlockConfig) — persisted as JSON so one
+kv_dtype, backend) -> PagedBlockConfig) — persisted as JSON (a tracked
+file beside this module, or wherever the table's env var points) so one
 on-chip sweep serves every later run. The env-var/merge/atomic-publish
 mechanics are identical and MUST NOT drift independently (a key-format
 drift between writer and reader silently un-tunes every dispatch), so
@@ -42,10 +43,13 @@ DEFAULT_PROVENANCE = {"source": "sweep", "capture": None, "ts": None}
 
 
 def default_cache_path(env_var: str, filename: str) -> str:
+    """Where a tuned-block table lives: `env_var` when set, else a file
+    beside this module that git tracks. Block shapes decide which kernel
+    is compiled, so nothing outside the checkout (no `$HOME` cache) may
+    supply them unasked; a sweep that should outlive its run is committed."""
     return os.environ.get(
         env_var,
-        os.path.join(os.path.expanduser("~"), ".cache", "dpfs_tpu",
-                     filename))
+        os.path.join(os.path.dirname(os.path.abspath(__file__)), filename))
 
 
 def _parse_raw(raw, path: str):

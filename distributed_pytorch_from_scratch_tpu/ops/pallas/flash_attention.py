@@ -15,8 +15,9 @@ giving the same result.
 
 Forward + backward are both Pallas kernels wired through `jax.custom_vjp`
 (the backward recomputes p = exp(s - logsumexp) blockwise from the saved
-row-logsumexp, the standard flash-attention-2 scheme). Runs compiled on
-TPU and in interpreter mode on CPU (used by the cluster-free tests).
+row-logsumexp, the standard flash-attention-2 scheme). Compiled by Mosaic
+on TPU; the Pallas interpreter runs only when a caller passes
+`interpret=True` (the cluster-free tests do), never as a fallback.
 
 **Grouped-query attention is native to the kernels** (VERDICT r2 #3): when
 k/v arrive with fewer heads than q (hkv < hq), the BlockSpec index maps
@@ -65,10 +66,6 @@ DEFAULT_BWD_BLOCK_K = 1024
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
@@ -161,7 +158,7 @@ def _q_row(bkv, g, hq: int, hkv: int):
 
 
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
-              hq: int, hkv: int):
+              hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
     num_qb = t_pad // block_q
     num_kb = t_pad // block_k
@@ -199,7 +196,7 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
         cost_estimate=pl.CostEstimate(
             flops=flops, bytes_accessed=q.size * 3 * q.dtype.itemsize,
             transcendentals=t_real * t_real * bh // 2),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v)
     return o, lse
 
@@ -383,7 +380,7 @@ def _bwd_fused_gqa_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
-              hq: int, hkv: int):
+              hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
     bhkv = k.shape[0]
     group = hq // hkv
@@ -395,13 +392,13 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
                     keepdims=True)                           # (bh, t_pad, 1)
 
-    # Fused path gate: under the CPU interpreter inside shard_map (vma tags
+    # Fused path gate: under the interpreter inside shard_map (vma tags
     # present), the discharged kernel jaxpr fails shard_map's vma check on
     # plain elementwise ops (the split kernels pass only because their ops
     # sit inside pl.when/cond, which unifies vma). Compiled TPU execution
     # never discharges, so real hardware always takes the fused path; the
     # CPU grad tests outside shard_map still cover its math.
-    interp_vma = _interpret() and getattr(jax.typeof(q), "vma", None)
+    interp_vma = interpret and getattr(jax.typeof(q), "vma", None)
     if num_qb == 1 and num_kb == 1 and not interp_vma:
         if group == 1:
             spec_td = pl.BlockSpec((None, t_pad, d), lambda b: (b, 0, 0))
@@ -418,7 +415,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
                            _out_struct((bh, t_pad, d), v.dtype, q)],
                 compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",)),
-                interpret=_interpret(),
+                interpret=interpret,
             )(q, k, v, do, lse, delta)
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
@@ -438,7 +435,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
                             pltpu.VMEM((t_pad, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
-            interpret=_interpret(),
+            interpret=interpret,
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -459,7 +456,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid dim 2 runs (group x num_qb) sequential steps per kv block;
@@ -495,7 +492,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
+        interpret=interpret,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -508,9 +505,10 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
 # (see DEFAULT_BLOCK_Q's sweep note). Rather than bake one answer in, the
 # kernel consults a small cached table: built-in entries ship the swept
 # defaults, `autotune_block_config` measures and caches the best combo for
-# a new shape, and the cache persists as JSON (FLASH_BLOCKS_CACHE or
-# ~/.cache/dpfs_tpu/flash_blocks.json) so a sweep done once on hardware
-# (scripts/tune_flash_blocks.py --write_cache) serves every later run.
+# a new shape, and the cache persists as JSON (FLASH_BLOCKS_CACHE, else the
+# git-tracked ops/pallas/flash_blocks.json) so a sweep done once on
+# hardware (scripts/tune_flash_blocks.py --write_cache) serves every later
+# run.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -613,7 +611,8 @@ def autotune_block_config(t: int, head_dim: int, dtype=jnp.bfloat16,
                           sweep: Tuple[int, ...] = (128, 256, 512),
                           iters: int = 5, warmup: int = 2,
                           include_current: bool = True,
-                          write_cache: bool = False) -> BlockConfig:
+                          write_cache: bool = False,
+                          interpret: bool = False) -> BlockConfig:
     """Sweep block_q x block_k over `sweep` for this (t, head_dim, dtype),
     time fwd and fwd+bwd on the CURRENT backend, record the best combo in
     the table (and optionally the JSON cache). Returns the winner.
@@ -622,8 +621,12 @@ def autotune_block_config(t: int, head_dim: int, dtype=jnp.bfloat16,
     winning fwd blocks fixed (they run as separate kernels with separate
     VMEM working sets, so the product factorises). Combos that clamp to an
     identical effective shape (blocks > padded t) dedupe before timing.
+    `interpret` runs the sweep's machinery under the Pallas interpreter
+    (tests); its winners say nothing about a chip.
     """
     import time
+
+    _require_tpu("autotune_block_config", interpret)
 
     key = jax.random.key(0)
     shape = (1, batch_heads, t, head_dim)
@@ -665,14 +668,15 @@ def autotune_block_config(t: int, head_dim: int, dtype=jnp.bfloat16,
 
     fwd_bq, fwd_bk = sweep_over(candidates, lambda pair: jax.jit(
         lambda q, k, v: flash_attention(q, k, v, block_q=pair[0],
-                                        block_k=pair[1])))
+                                        block_k=pair[1],
+                                        interpret=interpret)))
 
     def grad_fn(pair):
         def loss(q, k, v):
             return jnp.sum(flash_attention(
                 q, k, v, block_q=fwd_bq, block_k=fwd_bk,
-                bwd_block_q=pair[0], bwd_block_k=pair[1]
-            ).astype(jnp.float32) ** 2)
+                bwd_block_q=pair[0], bwd_block_k=pair[1],
+                interpret=interpret).astype(jnp.float32) ** 2)
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
     bwd_bq, bwd_bk = sweep_over(candidates, grad_fn)
@@ -687,12 +691,22 @@ def autotune_block_config(t: int, head_dim: int, dtype=jnp.bfloat16,
 # ---------------------------------------------------------------- public
 
 
+def _require_tpu(what: str, interpret: bool) -> None:
+    if not interpret and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"{what} is compiled by Mosaic and needs a TPU backend (got "
+            f"{jax.default_backend()!r}); off-TPU use the XLA attention, or "
+            f"ask for the Pallas interpreter explicitly with interpret=True "
+            f"(the tests do)")
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     block_q: int = None,
                     block_k: int = None,
                     bwd_block_q: int = None,
                     bwd_block_k: int = None,
-                    t_real: int = None) -> jax.Array:
+                    t_real: int = None,
+                    interpret: bool = False) -> jax.Array:
     """Causal flash attention. q: (b, heads, t, head_dim); k, v may carry
     FEWER heads (b, kv_heads, t, head_dim) with heads % kv_heads == 0 —
     grouped-query attention routed inside the kernels (no K/V repeat in HBM).
@@ -710,7 +724,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     the kernels do only ~t_real work (block-granular: fully-dead tiles are
     skipped by the grid guards, exactly like the internal padding). Rows
     >= t_real read as zeros and emit exact zero gradients.
+
+    `interpret=True` runs the kernels under the Pallas interpreter (CPU
+    tests); without it a non-TPU backend is an error, not a fallback.
     """
+    _require_tpu("flash_attention", interpret)
     b, h, t, d = q.shape
     hkv = k.shape[1]
     if h % hkv or v.shape[1] != hkv:
@@ -752,23 +770,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return x
 
     o = _flash_with_t(prep(q, h), prep(k, hkv), prep(v, hkv), t_real,
-                      bq, bk, bbq, bbk, h, hkv)
+                      bq, bk, bbq, bbk, h, hkv, interpret)
     return o[:, :t, :].reshape(b, h, t, d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_with_t(q, k, v, t_real: int, block_q: int, block_k: int,
-                  bwd_block_q: int, bwd_block_k: int, hq: int = 1,
-                  hkv: int = 1):
+                  bwd_block_q: int, bwd_block_k: int, hq: int, hkv: int,
+                  interpret: bool):
     o, _ = _fwd_call(q, k, v, t_real=t_real, block_q=block_q,
-                     block_k=block_k, hq=hq, hkv=hkv)
+                     block_k=block_k, hq=hq, hkv=hkv, interpret=interpret)
     return o
 
 
 def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
-                      bwd_block_q, bwd_block_k, hq, hkv):
+                      bwd_block_q, bwd_block_k, hq, hkv, interpret):
     o, lse = _fwd_call(q, k, v, t_real=t_real,
-                       block_q=block_q, block_k=block_k, hq=hq, hkv=hkv)
+                       block_q=block_q, block_k=block_k, hq=hq, hkv=hkv,
+                       interpret=interpret)
     # Name the kernel outputs so remat policies can pin them: under
     # `Transformer(remat="dots")` the checkpoint_dots policy saves only
     # dot_general outputs, and without these tags the backward pass would
@@ -779,11 +798,11 @@ def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
 
 
 def _flash_with_t_bwd(t_real, block_q, block_k, bwd_block_q, bwd_block_k,
-                      hq, hkv, res, do):
+                      hq, hkv, interpret, res, do):
     q, k, v, o, lse = res
     return _bwd_call(q, k, v, o, lse, do, t_real=t_real,
                      block_q=bwd_block_q, block_k=bwd_block_k,
-                     hq=hq, hkv=hkv)
+                     hq=hq, hkv=hkv, interpret=interpret)
 
 
 _flash_with_t.defvjp(_flash_with_t_fwd, _flash_with_t_bwd)
@@ -820,7 +839,7 @@ def _pos_fwd_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref, lse_ref,
     s = jax.lax.dot_general(
         q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale           # (bq, bk)
-    live = qp_ref[0][:, None] >= kp_ref[0][None, :]
+    live = qp_ref[0] >= kp_ref[0]                    # (bq, 1) vs (1, bk)
     s = jnp.where(live, s, MASK)
 
     m_prev = m_ref[:]
@@ -858,7 +877,7 @@ def _pos_dq_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, do_ref, lse_ref,
 
     s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    live = qp_ref[0][:, None] >= kp_ref[0][None, :]
+    live = qp_ref[0] >= kp_ref[0]                    # (bq, 1) vs (1, bk)
     # dead rows carry lse = MASK; exp(MASK - MASK) = 1 would fabricate p, so
     # hard-zero masked entries (their cotangents are exact zeros anyway)
     p = jnp.where(live, jnp.exp(s - lse_ref[0]), 0.0)
@@ -887,7 +906,7 @@ def _pos_dkv_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, do_ref, lse_ref,
 
     st = jax.lax.dot_general(k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) * scale
-    live_t = kp_ref[0][:, None] <= qp_ref[0][None, :]        # (bk, bq)
+    live_t = kp_ref[0] <= qp_ref[0]           # (bk, 1) vs (1, bq) -> (bk, bq)
     pt = jnp.where(live_t, jnp.exp(st - jnp.transpose(lse_ref[0])), 0.0)
     dv_acc[:] += jax.lax.dot_general(
         pt.astype(do_ref.dtype), do_ref[0], (((1,), (0,)), ((), ())),
@@ -917,7 +936,8 @@ def _pos_pad(x, t_pad, fill=0):
 
 def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     q_pos: jax.Array, kv_pos: jax.Array,
-                    block_q: int = 512, block_k: int = 512):
+                    block_q: int = 512, block_k: int = 512,
+                    interpret: bool = False):
     """Position-masked attention over ONE (Q-chunk, KV-chunk) pair.
 
     q: (b, h, tq, d); k, v: (b, hkv, tk, d) (hkv may divide h — grouped
@@ -926,7 +946,9 @@ def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     with kv_pos <= q_pos. Returns (o, lse): o (b, h, tq, d) in q's dtype,
     normalized within the block; lse (b, h, tq) f32, MASK for rows with no
     visible kv here. Differentiable in q/k/v through both outputs.
+    `interpret` as in `flash_attention`.
     """
+    _require_tpu("block_attention", interpret)
     b, h, tq, d = q.shape
     hkv, tk = k.shape[1], k.shape[2]
     if h % hkv or v.shape[1] != hkv:
@@ -946,9 +968,17 @@ def block_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kf, vf = prep(k, hkv, tk_pad), prep(v, hkv, tk_pad)
     qp = _pos_pad(q_pos.astype(jnp.int32), tq_pad, _QPOS_PAD)
     kp = _pos_pad(kv_pos.astype(jnp.int32), tk_pad, _KPOS_PAD)
-    o, lse = _block_attn_vjp(qf, kf, vf, qp, kp, bq, bk, h, hkv)
+    o, lse = _block_attn_vjp(qf, kf, vf, qp, kp, bq, bk, h, hkv, interpret)
     return (o[:, :tq].reshape(b, h, tq, d),
             lse[:, :tq, 0].reshape(b, h, tq))
+
+
+# Position operands of the block kernels. Mosaic wants a block's last two
+# dims divisible by (8, 128) or equal to the array's, which a (1, block)
+# slice of a (b, t) array is not. So positions ride in twice-shaped: down
+# the score tile's ROWS as a (b, t, 1) array in (1, block, 1) blocks, along
+# its COLUMNS as (b, 1, t) in (1, 1, block) blocks — which also hands the
+# kernels the column and the row they broadcast, with no in-kernel relayout.
 
 
 def _block_calls(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
@@ -963,12 +993,15 @@ def _block_calls(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
                 posrow=posrow)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _block_attn_vjp(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
-    return _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _block_attn_vjp(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
+                    interpret):
+    return _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
+                           interpret)
 
 
-def _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
+def _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
+                    interpret):
     c = _block_calls(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv)
     kvr, posr = c["kv"], c["posrow"]
     o, lse = pl.pallas_call(
@@ -981,8 +1014,8 @@ def _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
                          lambda b, i, j: (kvr(b), j, 0)),
             pl.BlockSpec((1, block_k, c["d"]),
                          lambda b, i, j: (kvr(b), j, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i, j: (posr(b), i)),
-            pl.BlockSpec((1, block_k), lambda b, i, j: (posr(b), j)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (posr(b), i, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (posr(b), 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, c["d"]), lambda b, i, j: (b, i, 0)),
@@ -999,17 +1032,19 @@ def _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(qf, kf, vf, qp, kp)
+        interpret=interpret,
+    )(qf, kf, vf, qp[:, :, None], kp[:, None, :])
     return o, lse
 
 
-def _block_attn_vjp_fwd(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv):
-    o, lse = _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv)
+def _block_attn_vjp_fwd(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
+                        interpret):
+    o, lse = _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
+                             interpret)
     return (o, lse), (qf, kf, vf, qp, kp, o, lse)
 
 
-def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, res, cts):
+def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, interpret, res, cts):
     import numpy as np
 
     qf, kf, vf, qp, kp, o, lse = res
@@ -1033,8 +1068,8 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, res, cts):
         grid=(c["bh"], c["num_qb"], c["num_kb"]),
         in_specs=[
             q_spec, kv_spec, kv_spec,
-            pl.BlockSpec((1, block_q), lambda b, i, j: (posr(b), i)),
-            pl.BlockSpec((1, block_k), lambda b, i, j: (posr(b), j)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (posr(b), i, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, i, j: (posr(b), 0, j)),
             q_spec, q1_spec, q1_spec, q1_spec,
         ],
         out_specs=q_spec,
@@ -1042,8 +1077,8 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, res, cts):
         scratch_shapes=[pltpu.VMEM((block_q, c["d"]), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(qf, kf, vf, qp, kp, do, lse, delta, dlse)
+        interpret=interpret,
+    )(qf, kf, vf, qp[:, :, None], kp[:, None, :], do, lse, delta, dlse)
 
     num_qb = c["num_qb"]
     qrow = lambda b, gq: _q_row(b, gq // num_qb, hq, hkv)
@@ -1059,9 +1094,9 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, res, cts):
         grid=(c["bhkv"], c["num_kb"], group * num_qb),
         in_specs=[
             qg_spec, kvo_spec, kvo_spec,
-            pl.BlockSpec((1, block_q),
-                         lambda b, j, gq: (b // hkv, qblk(gq))),
-            pl.BlockSpec((1, block_k), lambda b, j, gq: (b // hkv, j)),
+            pl.BlockSpec((1, 1, block_q),
+                         lambda b, j, gq: (b // hkv, 0, qblk(gq))),
+            pl.BlockSpec((1, block_k, 1), lambda b, j, gq: (b // hkv, j, 0)),
             qg_spec, qg1_spec, qg1_spec, qg1_spec,
         ],
         out_specs=[kvo_spec, kvo_spec],
@@ -1073,8 +1108,8 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, res, cts):
                         pltpu.VMEM((block_k, c["d"]), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(),
-    )(qf, kf, vf, qp, kp, do, lse, delta, dlse)
+        interpret=interpret,
+    )(qf, kf, vf, qp[:, None, :], kp[:, :, None], do, lse, delta, dlse)
 
     zero_pos = lambda p: np.zeros(p.shape, jax.dtypes.float0)
     return dq, dk, dv, zero_pos(qp), zero_pos(kp)

@@ -57,9 +57,9 @@ VMEM pipeline, fewer skip dead context at finer grain.
 Runs compiled on TPU and — ONLY when explicitly asked (`interpret=True`)
 — under the Pallas interpreter on CPU, which is how the identity tests
 pin it token-for-token against the gather oracle without a chip. A
-non-TPU backend withOUT interpret falls back to the gather path with a
-one-time warning (`resolve_paged_attn_impl`): silently interpreting a
-production flag would serve tokens at interpreter speed.
+non-TPU backend withOUT interpret is an error (`check_paged_attn_impl`):
+neither the interpreter nor the gather path stands in for the kernel
+unasked.
 """
 
 from __future__ import annotations
@@ -67,7 +67,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import sys
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -92,6 +91,17 @@ def _interpret_backend() -> bool:
 # ------------------------------------------------------------------ kernel
 
 
+def _head_row(block: jax.Array, head) -> jax.Array:
+    """Row `head` of a (kv_heads, page_size) scale block as (1, page_size),
+    by a masked sublane sum: exact in f32, and neither a dynamic sublane
+    slice nor a transpose (libtpu 0.0.34's Mosaic hangs compiling
+    `ref[0, pl.ds(head, 1), :]` followed by a (1, ps) -> (ps, 1) relayout;
+    measured on the v5e, PR 21)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, block.shape, 0)
+    return jnp.sum(jnp.where(rows == head, block, 0.0), axis=0,
+                   keepdims=True)
+
+
 def _paged_kernel(tbl_ref, start_ref, vmax_ref, base_ref, q_ref, *refs,
                   scale: float, ps: int, n_pages: int, cw: int,
                   num_blocks: int, quantized: bool, out_dtype,
@@ -109,6 +119,7 @@ def _paged_kernel(tbl_ref, start_ref, vmax_ref, base_ref, q_ref, *refs,
     lse_ref = refs[per * n_pages + 1] if want_lse else None
     acc_ref, m_ref, l_ref = refs[per * n_pages + (2 if want_lse else 1):]
     b = pl.program_id(0)
+    hi = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -122,51 +133,48 @@ def _paged_kernel(tbl_ref, start_ref, vmax_ref, base_ref, q_ref, *refs,
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0]                                       # (R, hd)
+        q = q_ref[0, 0].astype(jnp.float32)                   # (R, hd)
         R = q.shape[0]
-        ks, vs = [], []
-        for n in range(n_pages):
-            if quantized:
-                kc = kv_refs[per * n][0, 0]                   # (ps, hd) s8
-                ksc = kv_refs[per * n + 1][0, 0]              # (ps,) f32
-                vc = kv_refs[per * n + 2][0, 0]
-                vsc = kv_refs[per * n + 3][0, 0]
-                # fused dequant: codes * per-head-vector scale, in VMEM,
-                # at the moment of use — no dense dequantized view in HBM
-                ks.append(kc.astype(jnp.float32) * ksc[:, None])
-                vs.append(vc.astype(jnp.float32) * vsc[:, None])
-            else:
-                ks.append(kv_refs[per * n][0, 0].astype(jnp.float32))
-                vs.append(kv_refs[per * n + 1][0, 0].astype(jnp.float32))
-        k = jnp.concatenate(ks, axis=0) if n_pages > 1 else ks[0]
-        v = jnp.concatenate(vs, axis=0) if n_pages > 1 else vs[0]
-        T = n_pages * ps
-        s = jax.lax.dot_general(
-            q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # (R, T)
-        # q row r = gi*cw + qi sits at absolute position start + qi; the
-        # block's keys sit at base + j*T + t. Causality: key <= query.
-        kpos = base_ref[0] + j * T + jax.lax.broadcasted_iota(
-            jnp.int32, (R, T), 1)
+        # q row r = gi*cw + qi sits at absolute position start + qi
         qpos = start_ref[b] + jax.lax.broadcasted_iota(
-            jnp.int32, (R, T), 0) % cw
-        live = kpos <= qpos
-        s = jnp.where(live, s, MASK)
-        m_prev = m_ref[:]
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # clamp: rows with nothing visible in ANY block so far keep
-        # m = MASK, and exp(MASK - MASK) = 1 would resurrect masked
-        # entries (the flash kernels' guard); hard-zero to be safe
-        m_safe = jnp.maximum(m_new, MASK / 2)
-        alpha = jnp.exp(m_prev - m_safe)
-        p = jnp.where(live, jnp.exp(s - m_safe), 0.0)
-        l_ref[:] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[:] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv
+            jnp.int32, (R, ps), 0) % cw
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
+        # The block's pages take the online-softmax update one at a time:
+        # every operand keeps its page-block layout (no lane concatenate),
+        # and a quantized page's scales — one per key — multiply along the
+        # score tile's lane axis instead of being relaid into a column.
+        for n in range(n_pages):
+            k = kv_refs[per * n][0, 0].astype(jnp.float32)    # (ps, hd)
+            v = kv_refs[per * n + per // 2][0, 0].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # (R, ps)
+            if quantized:
+                # fused dequant: q.(code*scale) == (q.code)*scale, applied
+                # in VMEM at the moment of use — no dequantized view in HBM
+                s = s * _head_row(kv_refs[per * n + 1][0], hi)
+            # the page's keys sit at base + (j*n_pages + n)*ps + t
+            kpos = base_ref[0] + (j * n_pages + n) * ps + col
+            live = kpos <= qpos                      # causality: key <= query
+            s = jnp.where(live, s, MASK)
+            m_prev = m_ref[:]
+            l_prev = l_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # clamp: rows with nothing visible in ANY page so far keep
+            # m = MASK, and exp(MASK - MASK) = 1 would resurrect masked
+            # entries (the flash kernels' guard); hard-zero to be safe
+            m_safe = jnp.maximum(m_new, MASK / 2)
+            alpha = jnp.exp(m_prev - m_safe)
+            p = jnp.where(live, jnp.exp(s - m_safe), 0.0)
+            l_ref[:] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            m_ref[:] = m_new
+            if quantized:
+                # p.(code*scale) == (p*scale).code, after the row sum
+                p = p * _head_row(kv_refs[per * n + 3][0], hi)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc_ref[:] = acc_ref[:] * alpha + pv
 
     @pl.when(j == num_blocks - 1)
     def _finalize():
@@ -217,7 +225,7 @@ def paged_attention(q: jax.Array, k_pool, v_pool, page_tbl: jax.Array,
 
     `interpret=True` runs the Pallas interpreter (CPU-testable); on a
     non-TPU backend withOUT it this call would fail to compile — callers
-    go through `resolve_paged_attn_impl` first.
+    go through `check_paged_attn_impl` first.
     """
     b, h, cw, hd = q.shape
     quantized = isinstance(k_pool, tuple)
@@ -261,12 +269,16 @@ def paged_attention(q: jax.Array, k_pool, v_pool, page_tbl: jax.Array,
         page_ix = (lambda bi, hi, j, tbl, st, vm, ba, n=n:
                    (tbl[bi, j * N + n], hi, 0, 0))
         if quantized:
+            # a page's scales ride in for ALL kv heads, (1, kvh, ps): the
+            # block's last two dims must equal the array's (Mosaic rejects
+            # a (1, 1, ps) slice of the head axis); the kernel picks its
+            # head's row (_head_row)
             sc_ix = (lambda bi, hi, j, tbl, st, vm, ba, n=n:
-                     (tbl[bi, j * N + n], hi, 0))
+                     (tbl[bi, j * N + n], 0, 0))
             kv_specs += [pl.BlockSpec((1, 1, ps, hd), page_ix),
-                         pl.BlockSpec((1, 1, ps), sc_ix),
+                         pl.BlockSpec((1, kvh, ps), sc_ix),
                          pl.BlockSpec((1, 1, ps, hd), page_ix),
-                         pl.BlockSpec((1, 1, ps), sc_ix)]
+                         pl.BlockSpec((1, kvh, ps), sc_ix)]
             ops += [k_pool[0], k_pool[1], v_pool[0], v_pool[1]]
         else:
             kv_specs += [pl.BlockSpec((1, 1, ps, hd), page_ix),
@@ -317,30 +329,24 @@ def paged_attention(q: jax.Array, k_pool, v_pool, page_tbl: jax.Array,
     return out.reshape(b, kvh, g, cw, hd).reshape(b, h, cw, hd)
 
 
-# ------------------------------------------------- impl resolution / gate
-
-_warned_fallback = False
+# ---------------------------------------------------------------- impl gate
 
 
-def resolve_paged_attn_impl(impl: str, interpret: bool = False) -> str:
-    """The impl the serving programs should actually build. 'pallas' on a
-    non-TPU backend without the explicit interpreter opt-in falls back to
-    'gather' with a ONE-TIME warning — compiled Mosaic needs a chip, and
-    silently serving tokens through the interpreter would be a perf lie,
-    not a fallback. The gather path stays the oracle either way."""
-    global _warned_fallback
+def check_paged_attn_impl(impl: str, interpret: bool = False) -> str:
+    """Validate the impl the serving programs will build; returns it.
+    'pallas' is compiled by Mosaic: on a non-TPU backend without the
+    explicit interpreter opt-in it is an error. It never degrades to
+    'gather' — a run that asked for the kernel and exits 0 ran the kernel."""
     if impl not in IMPLS:
         raise ValueError(f"paged_attn impl must be one of {IMPLS}, got "
                          f"{impl!r}")
-    if impl == "pallas" and _interpret_backend() and not interpret:
-        if not _warned_fallback:
-            _warned_fallback = True
-            print("Warning: --paged_attn pallas needs a TPU backend "
-                  f"(got {jax.default_backend()!r}); falling back to the "
-                  "gather impl (pass interpret=True — tests do — to run "
-                  "the kernel under the Pallas interpreter instead)",
-                  file=sys.stderr)
-        return "gather"
+    if impl == "pallas" and not interpret and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"paged_attn 'pallas' is compiled by Mosaic and needs a TPU "
+            f"backend (got {jax.default_backend()!r}); use 'gather' "
+            f"off-TPU. Library callers can run the kernel under the Pallas "
+            f"interpreter on purpose with paged_attn_interpret=True (the "
+            f"tests do); there is no CLI switch for it")
     return impl
 
 

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from .attention import causal_attention
+from .attention import causal_attention, resolve_attention_impl
 from .collectives import all_to_all, ring_permute
 
 
@@ -99,20 +99,25 @@ def _block_attn_xla(q, k, v, q_pos, kv_pos, scale):
 
 
 def _block_attn(q, k, v, q_pos, kv_pos, scale, impl: str):
-    """Dispatch one block to the Pallas positional kernel (TPU: MXU dots in
-    the input dtype, no O(tq*tk) f32 score tensor in HBM — VERDICT r2 weak
-    #4) or the dense XLA fallback. Both return (o f32-normalized, lse)."""
-    if impl == "flash":
-        from .pallas.flash_attention import _interpret, block_attention
+    """Dispatch one block to the Pallas positional kernel (MXU dots in the
+    input dtype, no O(tq*tk) f32 score tensor in HBM) or the dense XLA
+    path, per the RESOLVED `impl`. Both return (o f32-normalized, lse)."""
+    if impl == "xla":
+        return _block_attn_xla(q, k, v, q_pos, kv_pos, scale)
+    from .pallas.flash_attention import block_attention
 
-        # The interpreted (CPU) kernel discharges to a jaxpr that fails
+    interpret = impl == "flash_interpret"
+    if interpret and getattr(jax.typeof(q), "vma", None):
+        # The interpreted kernel discharges to a jaxpr that fails
         # shard_map's varying-manual-axes check (same gate as the fused
-        # flash backward); compiled TPU execution never discharges. The CPU
-        # tests cover the kernel's math outside shard_map.
-        if not (_interpret() and getattr(jax.typeof(q), "vma", None)):
-            o, lse = block_attention(q, k, v, q_pos, kv_pos)
-            return o.astype(jnp.float32), lse
-    return _block_attn_xla(q, k, v, q_pos, kv_pos, scale)
+        # flash backward); compiled TPU execution never discharges.
+        raise ValueError(
+            "ring attention with impl='flash_interpret' cannot run inside "
+            "a vma-checked shard_map: build the shard_map with "
+            "check_vma=False (tests/test_ring_attention.py does), or use "
+            "impl='xla'")
+    o, lse = block_attention(q, k, v, q_pos, kv_pos, interpret=interpret)
+    return o.astype(jnp.float32), lse
 
 
 def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -157,14 +162,13 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     All cp/tp/ep members of a pp stage agree on `live`, so the gated conds
     stay uniform within every collective group.
     """
-    if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+    impl = resolve_attention_impl(impl)
     n = lax.axis_size(axis)
     scale = 1.0 / math.sqrt(q.shape[-1])
     t_local = q.shape[2]
     halves = 2 if t_local % 2 == 0 else 1
     th = t_local // halves
-    qc = q if impl == "flash" else q.astype(jnp.float32)
+    qc = q.astype(jnp.float32) if impl == "xla" else q
 
     # derive the accumulators from q so they inherit its varying-axes tags
     # (fresh jnp.zeros would be mesh-invariant and trip shard_map's vma check

@@ -59,12 +59,8 @@ def make_mesh(cfg: MeshConfig, devices: Optional[Sequence[jax.Device]] = None) -
         )
     grid = np.asarray(devices[:n]).reshape(cfg.dp, cfg.pp, cfg.cp, cfg.ep,
                                            cfg.tp)
-    # axis_types landed after jax 0.4.x; Auto is that default anyway, so on
-    # older releases plain Mesh(devices, names) is the same mesh
-    if hasattr(jax.sharding, "AxisType"):
-        return Mesh(grid, AXIS_NAMES,
-                    axis_types=(jax.sharding.AxisType.Auto,) * len(AXIS_NAMES))
-    return Mesh(grid, AXIS_NAMES)
+    return Mesh(grid, AXIS_NAMES,
+                axis_types=(jax.sharding.AxisType.Auto,) * len(AXIS_NAMES))
 
 
 def single_device_mesh() -> Mesh:

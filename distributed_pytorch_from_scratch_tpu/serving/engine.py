@@ -682,11 +682,10 @@ class PagedEngine:
         _setup_decode_weights(self, model, mesh, params, decode_weight_dtype)
         # paged-attention impl (ISSUE 14): 'gather' materializes the dense
         # page view (the oracle); 'pallas' walks the page table in place.
-        # Resolved ONCE here — a non-TPU backend without the interpreter
-        # opt-in falls back to gather with a one-time warning, so every
-        # compiled program below agrees on one impl.
-        from ..ops.pallas.paged_attention import resolve_paged_attn_impl
-        self.paged_attn_impl = resolve_paged_attn_impl(
+        # Checked ONCE here: 'pallas' off-TPU without the interpreter
+        # opt-in raises, so what stats()/the record report is what ran.
+        from ..ops.pallas.paged_attention import check_paged_attn_impl
+        self.paged_attn_impl = check_paged_attn_impl(
             paged_attn_impl, interpret=paged_attn_interpret)
         self._paged_attn_interpret = bool(paged_attn_interpret)
         # int8 pages: codes + per-head-vector scales through the SAME
@@ -745,6 +744,17 @@ class PagedEngine:
         return rope_tables(self._table_len, self.model.cfg.head_dim,
                            self.model.cfg.rope_theta)
 
+    @property
+    def _check_vma(self) -> bool:
+        """shard_map's varying-axes check for the paged programs: on,
+        except for the Pallas INTERPRETER under cp > 1 (CPU tests only).
+        Discharged to a jaxpr, the kernel's page walk dynamic_slices with
+        the cp-varying position base against unvarying operands, which the
+        vma typing rejects ("...as a temporary workaround pass
+        check_vma=False", jax says). Mosaic-compiled kernels never
+        discharge, so on TPU the check stays on."""
+        return not (self._paged_attn_interpret and self.cp > 1)
+
     def _build_step(self):
         model, ps, dtype = self.model, self.page_size, self._dtype
         debug = self._debug_host_sampler
@@ -769,7 +779,8 @@ class PagedEngine:
             in_specs=(self._pspec, pspec, pspec, P(None), P(None),
                       P(None), P(None, None)),
             out_specs=(pspec, pspec,
-                       P(None, "tp") if debug else P(None)))
+                       P(None, "tp") if debug else P(None)),
+            check_vma=self._check_vma)
         return jax.jit(fn, donate_argnums=(1, 2))
 
     def _build_chunk(self, cw: int):
@@ -794,7 +805,8 @@ class PagedEngine:
             in_specs=(self._pspec, pspec, pspec, P(None, None),
                       P(None), P(None), P(None, None), P(None, None),
                       P(None, None), P(None)),
-            out_specs=(pspec, pspec, P(None)))
+            out_specs=(pspec, pspec, P(None)),
+            check_vma=self._check_vma)
         return jax.jit(fn, donate_argnums=(1, 2))
 
     # -- request intake ---------------------------------------------------

@@ -24,6 +24,7 @@ token values, and random ids keep the benchmark checkpoint-free
 
 from __future__ import annotations
 
+import hashlib
 import json
 import time
 from typing import List, Optional
@@ -198,6 +199,10 @@ def run_loadgen(engine: ContinuousBatchingEngine, requests: List[Request],
         "invalid": invalid,
         "wall_s": round(wall, 4),
         "generated_tokens": stats["generated_tokens"],
+        # what was said, not only how fast: two runs of one request set
+        # agree on this iff every request got the same token ids
+        "tokens_digest": hashlib.sha256(json.dumps(
+            sorted((r.rid, r.tokens) for r in done)).encode()).hexdigest(),
         "tokens_per_sec": round(stats["generated_tokens"] / wall, 2),
         "decode_steps": stats["decode_steps"],
         "slot_occupancy_mean": stats["slot_occupancy_mean"],

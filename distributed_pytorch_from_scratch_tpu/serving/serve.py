@@ -32,6 +32,8 @@ from ..cli import add_model_shape_args, build_model_config
 from ..obs.runindex import run_stamp
 from ..config import (BOS_TOKEN, EOS_TOKEN, MODEL_PRESETS, MeshConfig,
                       ModelConfig, model_preset)
+from ..ops.attention import resolve_attention_impl
+from ..runtime.compile_cache import compile_cache_stats, enable_compile_cache
 from ..runtime.mesh import make_mesh
 
 _DRY_CFG = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=4, num_layers=2,
@@ -114,8 +116,8 @@ def get_serve_args(argv=None) -> argparse.Namespace:
                         "(ops/pallas/paged_attention.py — no per-step "
                         "HBM copy of the context, int8 dequant fused "
                         "into the block loop). Token-identical greedy "
-                        "output by contract; non-TPU backends fall back "
-                        "to gather with a one-time warning")
+                        "output by contract; refused on a non-TPU "
+                        "backend (the kernel is compiled by Mosaic)")
     g.add_argument("--num_pages", type=int, default=0,
                    help="--paged: page-pool HBM budget in pages (0 = "
                         "slots x ceil(buf_len/page_size), i.e. no "
@@ -449,6 +451,13 @@ def serve(args: argparse.Namespace) -> dict:
     from .engine import ContinuousBatchingEngine
     from .loadgen import replay_requests, run_loadgen, synthetic_requests
 
+    import jax
+
+    # refuse a kernel this backend cannot compile before any weights load:
+    # it never degrades to gather
+    from ..ops.pallas.paged_attention import check_paged_attn_impl
+    check_paged_attn_impl(args.paged_attn)
+
     if args.trace_requests or args.flight_records \
             or args.metrics_port is not None or args.profile_every:
         require_writable_dir(
@@ -709,8 +718,14 @@ def serve(args: argparse.Namespace) -> dict:
                       else "") + ")"),
         "value": summary["tokens_per_sec"],
         "unit": "tokens/sec (serving)",
+        "platform": jax.devices()[0].platform,
+        "device_kind": jax.devices()[0].device_kind,
+        "device_count": jax.device_count(),
+        "attn_impl": resolve_attention_impl(model.attn_impl),  # prefill
+        "compile_cache": compile_cache_stats(),
         **{k: summary[k] for k in (
             "requests", "completed", "rejected", "invalid", "wall_s",
+            "generated_tokens", "tokens_digest",
             "slot_occupancy_mean", "ttft_ms_p50", "ttft_ms_p95",
             "tpot_ms_p50", "tpot_ms_p95", "queue_wait_ms_p50",
             "queue_wait_ms_p95", "prefill_pad_waste_eliminated")},
@@ -767,6 +782,7 @@ def serve(args: argparse.Namespace) -> dict:
 
 
 def main(argv=None) -> dict:
+    enable_compile_cache()
     return serve(get_serve_args(argv))
 
 

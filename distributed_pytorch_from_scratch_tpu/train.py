@@ -19,6 +19,7 @@ loop `train.py:55-146`), TPU-native:
 from __future__ import annotations
 
 import argparse
+import json
 import math
 import os
 import signal
@@ -37,13 +38,16 @@ from .data.prefetch import Prefetcher, stack_window, window_stream
 from .models.transformer import Transformer
 from .obs import TrainObserver, analyze_compiled, format_analysis
 from .obs.runindex import run_stamp
+from .ops.attention import resolve_attention_impl
+from .runtime.compile_cache import compile_cache_stats, enable_compile_cache
 from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
                            process_info)
 from .training.checkpoint import (latest_step, load_checkpoint,
                                   save_checkpoint)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
                                chip_peak_flops, device_memory_gib,
-                               model_flops_per_step, publish_hbm)
+                               hbm_watermarks, model_flops_per_step,
+                               param_bytes_by_device, publish_hbm)
 from .training.optim import init_adam_state, schedule_lr
 from .training.train_step import (build_grad_accum_step, build_train_step,
                                   build_train_step_multi, resolve_zero_stage)
@@ -498,6 +502,10 @@ def train(args: argparse.Namespace) -> dict:
                                     seed=args.random_seed,
                                     data_mode=args.data_mode)
         vocab_size = dataloader.dataset.vocab_size
+        if args.data_mode == "docs":
+            from .data.native import native_status
+            print(f"data: {len(dataloader.dataset)} documents, collate = "
+                  f"{native_status()}")
         cfg = ModelConfig(attn_dim=pick(args.attn_dim, preset.attn_dim),
                           ffn_dim=pick(args.ffn_dim, preset.ffn_dim),
                           num_heads=pick(args.num_heads, preset.num_heads),
@@ -640,12 +648,16 @@ def train(args: argparse.Namespace) -> dict:
         n_params = sum(int(x.size) for x in jax.tree.leaves(params))
         moe_note = (f", {cfg.num_experts} experts (top-{cfg.moe_top_k})"
                     if cfg.num_experts else "")
+        dev0 = jax.devices()[0]
+        attn_impl = resolve_attention_impl(model.attn_impl)
         print(f"model[{args.family}]: {n_params/1e6:.2f}M params{moe_note}, "
               f"vocab={vocab_size}, "
               f"mesh=dp{args.dp_size} x pp{args.pp_size} x cp{args.cp_size} x "
               f"ep{args.ep_size} x tp{args.tp_size}, "
-              f"compute={cfg.compute_dtype}"
-              + (f", zero={zero_stage}" if zero_stage else ""))
+              f"compute={cfg.compute_dtype}, attn={attn_impl}"
+              + (f", zero={zero_stage}" if zero_stage else "")
+              + f" on {jax.device_count()} x {dev0.platform} "
+                f"[{dev0.device_kind}]")
         opt_state = init_adam_state(params)
         start_step = 0
         if args.resume:
@@ -869,15 +881,19 @@ def train(args: argparse.Namespace) -> dict:
         flops_step = model_flops_per_step(
             cfg, args.batch_size, maxlen,
             params=params if args.family == "gpt2" else None)
-        peak_flops = chip_peak_flops() * mesh_cfg.world_size
+        # None on the CPU backend: there is no peak to divide by, so MFU
+        # is "not measured" there; an unrecognised accelerator raises
+        peak_chip = chip_peak_flops()
+        peak_flops = peak_chip * mesh_cfg.world_size if peak_chip else None
 
         # The steady-shape program is AOT-compiled explicitly (under a traced
         # "compile" span) and introspected once — cost_analysis FLOPs, bytes,
         # per-collective comm, peak HBM — then called directly each dispatch.
-        # Odd shapes (the max_steps tail window) and backends that reject AOT
-        # calls fall back to the jit wrapper, whose recompile lands inside the
-        # "step" span.
-        aot = {"shape": None, "fn": None}
+        # A compile failure (a Mosaic rejection lands here first) raises.
+        # Odd shapes (the max_steps tail window) go through the jit wrapper,
+        # whose recompile lands inside the "step" span.
+        aot = {"shape": None, "fn": None, "compile_s": None,
+               "collectives": None}
 
         def run_step(p, o, ids, tgt, pos, steps_in, step_no):
             # pin only the STEADY shape: a shrunk tail / partial epoch-end
@@ -887,25 +903,22 @@ def train(args: argparse.Namespace) -> dict:
             steady = accum > 1 or steps_in == spd
             if aot["shape"] is None and steady:
                 aot["shape"] = ids.shape
+                t_compile = time.time()
                 with observer.span("compile", step=step_no):
-                    try:
-                        aot["fn"] = step_fn.lower(p, o, ids, tgt, pos).compile()
-                    except Exception as e:
-                        print(f"note: AOT compile unavailable "
-                              f"({type(e).__name__}: {e}); introspection "
-                              f"skipped, using the jit path")
-                if aot["fn"] is not None:
-                    analysis = analyze_compiled(aot["fn"])
-                    # SPMD HLO is per-device: the hand-rolled global estimate
-                    # spreads over world_size devices (and x steps_in for the
-                    # scanned/accumulated multi-batch programs)
-                    expected = flops_step * steps_in / mesh_cfg.world_size
-                    observer.report_compiled(analysis, flops_step,
-                                             steps_in_program=steps_in,
-                                             expected_flops=expected,
-                                             step=step_no)
-                    if is_main:
-                        print(format_analysis(analysis, model_flops=expected))
+                    aot["fn"] = step_fn.lower(p, o, ids, tgt, pos).compile()
+                aot["compile_s"] = time.time() - t_compile
+                analysis = analyze_compiled(aot["fn"])
+                aot["collectives"] = analysis["collectives"]
+                # SPMD HLO is per-device: the hand-rolled global estimate
+                # spreads over world_size devices (and x steps_in for the
+                # scanned/accumulated multi-batch programs)
+                expected = flops_step * steps_in / mesh_cfg.world_size
+                observer.report_compiled(analysis, flops_step,
+                                         steps_in_program=steps_in,
+                                         expected_flops=expected,
+                                         step=step_no)
+                if is_main:
+                    print(format_analysis(analysis, model_flops=expected))
             fn = aot["fn"] if (aot["fn"] is not None
                                and ids.shape == aot["shape"]) else step_fn
             with observer.span("step", step=step_no):
@@ -944,6 +957,9 @@ def train(args: argparse.Namespace) -> dict:
         # accumulate the loss on-device; a float() sync every step would
         # serialize host dispatch with device execution
         accum_loss, n = jnp.zeros((), jnp.float32), start_step
+        # (summed loss, steps) of the first and the newest dispatch: device
+        # values, read once for the summary record
+        first_loss = last_loss = None
         # the sentinel piggybacks on the logging-interval sync: last dispatch's
         # on-device grad norm + the per-interval mean loss, no extra D2H
         last_gnorm = None
@@ -1144,6 +1160,9 @@ def train(args: argparse.Namespace) -> dict:
                     if args.profile_steps:
                         profiler.maybe_stop(n, sync=loss)
                     accum_loss = accum_loss + loss
+                    last_loss = (loss, n - prev_n)
+                    if first_loss is None:
+                        first_loss = last_loss
                     if n // args.log_interval > prev_n // args.log_interval:
                         lr, _ = schedule_lr(ocfg, jnp.asarray(n - 1))
                         # the one blocking D2H of the interval: cumulative loss
@@ -1157,7 +1176,11 @@ def train(args: argparse.Namespace) -> dict:
                         dt = time.time() - t_start
                         tps = tokens_since / max(dt, 1e-9)
                         useful = useful_since / max(tokens_since, 1)
-                        mfu = (flops_step * steps_since) / max(dt, 1e-9) / peak_flops
+                        mfu = ((flops_step * steps_since) / max(dt, 1e-9)
+                               / peak_flops if peak_flops else None)
+                        mfu_s = (f"MFU {mfu*100:.1f}%" if mfu is not None
+                                 else "MFU not measured (no chip peak for "
+                                      "the cpu backend)")
                         # None = the backend reports no memory_stats (CPU):
                         # say so loudly; a 0.00 GiB watermark here misread
                         # as "no HBM used" on every chip-less box (ISSUE 15)
@@ -1167,12 +1190,13 @@ def train(args: argparse.Namespace) -> dict:
                         print(f"step {n}/{args.max_steps} -> avg loss {avg:.4f}, "
                               f"lr {float(lr):.8f}, {tps/1e3:.1f}k tok/s "
                               f"({useful*100:.0f}% useful), "
-                              f"MFU {mfu*100:.1f}%, mem {mem_s}")
+                              f"{mfu_s}, mem {mem_s}")
                         writer.scalar("train/ce_loss", avg, n)
                         writer.scalar("train/lr", float(lr), n)
                         writer.scalar("train/tokens_per_sec", tps, n)
                         writer.scalar("train/useful_token_frac", useful, n)
-                        writer.scalar("train/mfu", mfu, n)
+                        if mfu is not None:  # never export a fake 0
+                            writer.scalar("train/mfu", mfu, n)
                         if mem is not None:  # never export a fake 0
                             writer.scalar("device_memory_gib", mem, n)
                         # live HBM watermarks (ISSUE 15): per-device
@@ -1194,7 +1218,8 @@ def train(args: argparse.Namespace) -> dict:
                             # endpoint view; the goodput buckets ride too
                             # (a dict copy per log interval, not per step)
                             telemetry.gauge("train/tokens_per_sec", tps)
-                            telemetry.gauge("train/mfu", mfu)
+                            if mfu is not None:
+                                telemetry.gauge("train/mfu", mfu)
                             telemetry.gauge("train/loss_avg", avg)
                             telemetry.gauge(
                                 "train/step_time_ms",
@@ -1280,6 +1305,22 @@ def train(args: argparse.Namespace) -> dict:
         # ISSUE 17: provenance stamp — the run-forensics join key every
         # summary record carries uniformly (bench/serve/train)
         out = {"steps": n, "avg_loss": final_avg,
+               "first_loss": (float(first_loss[0]) / first_loss[1]
+                              if first_loss else None),
+               "last_loss": (float(last_loss[0]) / last_loss[1]
+                             if last_loss else None),
+               "platform": dev0.platform, "device_kind": dev0.device_kind,
+               "device_count": jax.device_count(),
+               "mesh": {"dp": args.dp_size, "pp": args.pp_size,
+                        "cp": args.cp_size, "ep": args.ep_size,
+                        "tp": args.tp_size},
+               "attn_impl": attn_impl,
+               "peak_flops_per_chip": peak_chip,
+               "compile_s": aot["compile_s"],
+               "collectives": aot["collectives"],
+               "param_bytes_by_device": param_bytes_by_device(params),
+               "hbm": hbm_watermarks(),
+               "compile_cache": compile_cache_stats(),
                **run_stamp(vars(args))}
         if advisor is not None:  # zero-cost off: no field when off
             out["control"] = advisor.summary()
@@ -1302,7 +1343,9 @@ def train(args: argparse.Namespace) -> dict:
 
 
 def main(argv=None):
-    train(get_train_args(argv))
+    enable_compile_cache()
+    # the summary record is the last stdout line, as serve prints its own
+    print(json.dumps(train(get_train_args(argv))))
 
 
 if __name__ == "__main__":

@@ -6,8 +6,7 @@ memory — models/transformer.py) has so far been picked by hand per preset.
 the chip's HBM budget, so `--remat auto` (train.py / bench.py) runs the
 fastest policy that fits and steps down only when the numbers say so. The
 estimate is deliberately conservative (a `margin` headroom for XLA temps
-and fusion scratch); bench.py's OOM fallback ladder remains the safety net
-behind it.
+and fusion scratch).
 """
 
 from __future__ import annotations
@@ -116,33 +115,23 @@ def estimate_step_gib(cfg, batch: int, seqlen: int, remat: str,
     return (fixed + acts + logits + opt_scratch) / 1024 ** 3
 
 
-_warned_assumed_budget = []
-
-
-def hbm_budget_gib(default: float = 16.0) -> float:
-    """Per-device HBM, from the live backend when one is attached. A
-    backend with no `memory_stats()` (the CPU test mesh) falls back to
-    `default` (the v5e figure) — LOUDLY, once per process: a silently
-    assumed budget is the same silent-zero rot mode as the fake 0-GiB
-    watermark (ISSUE 15), and `--remat auto` decisions made on it must
-    be attributable to the assumption."""
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        stats = getattr(dev, "memory_stats", lambda: None)() or {}
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if limit:
-            return limit / 1024 ** 3
-    except Exception:  # noqa: BLE001 — sizing must never kill the caller
-        pass
-    if not _warned_assumed_budget:
-        _warned_assumed_budget.append(True)
-        import sys
-        print(f"note: this backend reports no memory_stats — HBM budget "
-              f"UNAVAILABLE, assuming {default:g} GiB (v5e); remat/memory "
-              f"decisions sized against the assumption, not the chip",
-              file=sys.stderr)
-    return default
+def hbm_budget_gib() -> float:
+    """Per-device HBM of the attached backend, from `memory_stats()`. A
+    backend that reports none (the CPU test mesh) raises: a remat policy
+    sized against an assumed 16 GiB is a decision about a chip that is not
+    there — callers off-chip pass `select_remat(budget_gib=...)` or name
+    the policy."""
+    import jax
+    dev = jax.local_devices()[0]
+    stats = dev.memory_stats() or {}
+    limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+    if not limit:
+        raise ValueError(
+            f"the {dev.platform} backend reports no memory_stats, so there "
+            f"is no HBM budget to size 'remat auto' against: name the "
+            f"policy (--remat true|dots|false), or pass budget_gib to "
+            f"select_remat")
+    return limit / 1024 ** 3
 
 
 def select_remat(cfg, batch: int, seqlen: int, tp: int = 1, world: int = 1,

@@ -214,10 +214,9 @@ def build_train_step_multi(model: Transformer, mesh, ocfg: OptimizerConfig,
 
     Identical training to N calls of `build_train_step`'s program (the scan
     body IS `_step_body`, same Adam/OneCycle state threading) but with ONE
-    host dispatch, so the host->device round-trip is amortised N-fold. On a
-    directly-attached chip that saves ~100us/step; through a remote/tunneled
-    runtime it is the difference between dispatch-bound and compute-bound
-    training. The reference has no analogue — its hot loop is necessarily
+    host dispatch, so the per-dispatch host cost is amortised N-fold (what
+    that cost is on the chip has not been measured on this code: PERF.md).
+    The reference has no analogue — its hot loop is necessarily
     one `optimizer.step()` per Python iteration
     (`/root/reference/train.py:94-109`).
     """

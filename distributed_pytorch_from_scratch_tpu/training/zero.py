@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any
 
 import jax
@@ -203,6 +204,31 @@ def _check_bucketed_scope(model, what: str) -> None:
 
 # ------------------------------------------------- bucketed grad reduction --
 
+# Why the two grad builders below say check_vma=False. They take jax.grad
+# INSIDE the shard body, on purpose: the per-shard cotangents must come out
+# UN-reduced so the reduction can be issued by hand — in buckets, as
+# reduce-scatters, over int8 rings. Under shard_map's varying-axes typing
+# that is not expressible: a gradient with respect to a replicated
+# parameter is typed replicated, so autodiff inserts the whole-tree psum
+# this module exists to replace, and the hand-rolled ppermute rings
+# (ops/overlap.py) produce values whose replication the checker cannot
+# infer at the out_specs. With the check off jax transposes `psum` to
+# `psum` (not to a varying cast): every cotangent that crosses a psum on
+# its way back fans out by that psum's axis size — see _psum_fanout.
+
+
+def _psum_fanout(mesh: Mesh, batch_axes) -> int:
+    """The factor every per-shard cotangent carries under check_vma=False,
+    where psum transposes to psum. In scope (dense, pp=1, SP whenever
+    tp > 1) each leaf's cotangent crosses exactly two psums: the loss's
+    batch-axis psum and the vocab-parallel CE's tp psum; every other
+    collective on the path (all_gather / psum_scatter / ppermute)
+    transposes value-correctly. Static: axis sizes are mesh facts. The
+    parity tests (tests/test_zero.py, tests/test_overlap.py) compare every
+    grad leaf with the whole-tree reducer and catch any drift here."""
+    return math.prod(mesh.shape[a] for a in (*batch_axes, "tp"))
+
+
 def _spec_axes(spec: P) -> set:
     """Mesh axes a PartitionSpec shards over (entries may be axis names or
     tuples of them)."""
@@ -258,16 +284,11 @@ def build_bucketed_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     transpose's reduction is pinned in tests/test_overlap.py (stage 1)
     and tests/test_zero.py (stage 2).
 
-    Legacy-jax note (this container's 0.4.x shard_map, check_rep=False):
-    the transpose of lax.psum is psum there, so per-shard cotangents
-    inflate by the axis-size product of every psum they cross. Under SP
-    (or tp=1) that product is UNIFORM across leaves — the batch-axis loss
-    psum plus the vocab-parallel CE's tp psum; every other SP collective
-    (all_gather / psum_scatter / ppermute) transposes value-correctly —
-    and the inflation is measured at trace time with a two-line probe and
-    divided out, instead of version-sniffing jax. Parity with the
-    whole-tree reducer is pinned in tests/test_overlap.py, which fails
-    loudly if a jax upgrade changes the transpose semantics.
+    The shard_map runs with check_vma=False, where psum transposes to
+    psum: per-shard cotangents come back multiplied by `_psum_fanout`,
+    which is divided out (the module comment above it says why the check
+    cannot stay on here). Parity with the whole-tree reducer on every leaf
+    is pinned in tests/test_overlap.py.
 
     Scope: dense models on pp=1 meshes, with sequence_parallel on
     whenever tp > 1. MoE routes through ep-sharded expert params, pp
@@ -296,21 +317,13 @@ def build_bucketed_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
         scatter_dims = [-1] * len(leaf_specs)
         grad_specs = specs
 
+    psum_fanout = _psum_fanout(mesh, batch_axes)
+
     def shard_fn(params, input_ids, target_ids, position_ids):
         loss, grads = jax.value_and_grad(
             lambda p: model.loss_shard(p, input_ids, target_ids,
                                        position_ids, mode=loss_mode))(params)
-        # Measure (don't version-sniff) the per-shard cotangent inflation:
-        # each probe differentiates a bare psum over the crossed axes, so
-        # it returns the axis-size product under the legacy
-        # psum-transposes-to-psum semantics and 1.0 wherever the transpose
-        # is value-preserving. Every leaf crosses the batch-axis loss psum
-        # and the CE's tp psum exactly once (the SP/tp=1 scope guarantees
-        # no others), so the correction is one uniform scalar —
-        # constant-folded by XLA.
-        k = (jax.grad(lambda z: jax.lax.psum(z, batch_axes))(1.0)
-             * jax.grad(lambda z: jax.lax.psum(z, ("tp",)))(1.0))
-        grads = jax.tree.map(lambda g: g / k, grads)
+        grads = jax.tree.map(lambda g: g / psum_fanout, grads)
         flat, treedef = jax.tree.flatten(grads)
         assert len(flat) == len(leaf_specs)
         groups: "dict[tuple, list[int]]" = {}
@@ -343,7 +356,9 @@ def build_bucketed_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     batch_spec = P(("dp", "ep"), "cp")
     fn = jax.shard_map(shard_fn, mesh=mesh,
                        in_specs=(specs, batch_spec, batch_spec, batch_spec),
-                       out_specs=(P(), grad_specs))
+                       out_specs=(P(), grad_specs),
+                       # per-shard grads, reduced by hand: see "Why" above
+                       check_vma=False)
     if not model._zigzag:
         return fn
 
@@ -383,9 +398,9 @@ def build_zero3_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     autodiff would SAVE each layer's gathered weights as backward
     residuals and the full replica would rematerialise in HBM. Scope
     otherwise matches the bucketed reducer: dense, pp=1, SP whenever
-    tp > 1. The legacy psum-transpose inflation is probed and divided out
-    exactly as in `build_bucketed_grad_fn` (ppermute rings transpose
-    value-correctly, so the gathers add no inflation of their own).
+    tp > 1. The psum fan-out is divided out exactly as in
+    `build_bucketed_grad_fn` (ppermute rings transpose value-correctly, so
+    the gathers add none of their own).
     """
     _check_bucketed_scope(model, "ZeRO-3 (gather-on-demand params)")
     if model.remat is False:
@@ -403,6 +418,7 @@ def build_zero3_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     sp = model.sequence_parallel
     leaf_specs = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     leaf_dims = jax.tree.leaves(dims)
+    psum_fanout = _psum_fanout(mesh, batch_axes)
 
     def shard_fn(params, input_ids, target_ids, position_ids):
         def loss_of(p):
@@ -419,12 +435,10 @@ def build_zero3_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
                                      position_ids, mode=loss_mode)
 
         loss, grads = jax.value_and_grad(loss_of)(params)
-        # the same trace-time inflation probe as build_bucketed_grad_fn:
-        # only the loss psum and the CE tp psum inflate; the gather rings
-        # (ppermute + slice updates) transpose value-correctly
-        k = (jax.grad(lambda z: jax.lax.psum(z, batch_axes))(1.0)
-             * jax.grad(lambda z: jax.lax.psum(z, ("tp",)))(1.0))
-        grads = jax.tree.map(lambda g: g / k, grads)
+        # only the loss psum and the CE tp psum fan the cotangents out (see
+        # _psum_fanout); the gather rings (ppermute + slice updates)
+        # transpose value-correctly
+        grads = jax.tree.map(lambda g: g / psum_fanout, grads)
         flat, treedef = jax.tree.flatten(grads)
         assert len(flat) == len(leaf_specs)
         groups: "dict[tuple, list[int]]" = {}
@@ -447,7 +461,9 @@ def build_zero3_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     batch_spec = P(("dp", "ep"), "cp")
     fn = jax.shard_map(shard_fn, mesh=mesh,
                        in_specs=(pspecs, batch_spec, batch_spec, batch_spec),
-                       out_specs=(P(), pspecs))
+                       out_specs=(P(), pspecs),
+                       # per-shard grads, reduced by hand: see "Why" above
+                       check_vma=False)
     if not model._zigzag:
         return fn
 

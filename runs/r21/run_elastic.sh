@@ -48,7 +48,7 @@ if [ ! -s "$TOKENS" ]; then
   step corpus 1200 python scripts/make_image_corpus.py /tmp/corpus_texts.json \
       --root /opt/venv/lib/python3.12/site-packages
   step tokenize 1200 python -m distributed_pytorch_from_scratch_tpu.data.tokenizer encode \
-      -i /tmp/corpus_texts.json -o "$TOKENS" -t runs/r4/tokenizer.json
+      -i /tmp/corpus_texts.json -o "$TOKENS" -t tokenizer/tokenizer.json
 fi
 python scripts/run_step.py --manifest "$M" --name train_tp4 --timeout 1200 --grace 90 \
   --tee "$R/train_tp4.log" -- \

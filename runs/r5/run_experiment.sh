@@ -12,7 +12,7 @@
 #   4. block sweep, packed-mode run
 # Every python step runs under scripts/run_step.py: real rc + stderr tail
 # land in $R/session_manifest.jsonl ("failed rc=0" is impossible now).
-# Idempotent: artifacts gate each step; safe to relaunch on every tunnel-up.
+# Idempotent: artifacts gate each step; safe to relaunch.
 # Preflight-validated by tests/test_staged_session.py (every staged command
 # line is parsed by the real argparsers on CPU in CI).
 set -u
@@ -34,7 +34,7 @@ fi
 
 # ---- 2. the real experiment (VERDICT r4 #1) ----------------------------
 if [ ! -s "$R/tokenizer.json" ]; then
-  cp runs/r4/tokenizer.json "$R/tokenizer.json"
+  cp tokenizer/tokenizer.json "$R/tokenizer.json"
 fi
 TOKENS=/tmp/corpus_tokens.json
 if [ ! -s "$TOKENS" ]; then

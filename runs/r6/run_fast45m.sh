@@ -19,7 +19,7 @@ step probe 120 python -c "import jax; d=jax.devices(); assert d[0].platform != '
 
 # 1. one-time flash block sweep -> the autotuner cache every later
 #    flash_attention call on this backend reads (get_block_config)
-if [ ! -s "$HOME/.cache/dpfs_tpu/flash_blocks.json" ]; then
+if [ ! -s distributed_pytorch_from_scratch_tpu/ops/pallas/flash_blocks.json ]; then
   step block_sweep 1800 python scripts/tune_flash_blocks.py --quick --write_cache
 fi
 

@@ -152,8 +152,34 @@ def _load_params(args, model, mesh):
     return jax.device_put(params, model.shardings(mesh))
 
 
+class _DeviceSlices:
+    """Hands every engine of the fleet its own run of devices, in order.
+    Without it each replica's `make_mesh` took `jax.devices()[:tp]`, and on
+    a four-chip host four "replicas" shared chip 0. When the devices run
+    out (two replicas on one chip) the walk starts over at device 0 and
+    says so: sharing is then the only option, and it is reported."""
+
+    def __init__(self, devices):
+        self.devices = list(devices)
+        self.next = 0
+
+    def take(self, n: int):
+        if n > len(self.devices):
+            raise SystemExit(f"an engine at tp{n} needs {n} devices; only "
+                             f"{len(self.devices)} visible")
+        if self.next + n > len(self.devices):
+            print(f"serve_fleet: {len(self.devices)} device(s) cannot give "
+                  f"every engine its own tp{n} slice; engines from here on "
+                  f"share devices, starting again at device 0",
+                  file=sys.stderr)
+            self.next = 0
+        out = self.devices[self.next:self.next + n]
+        self.next += n
+        return out
+
+
 def _build_engine(args, cfg, tp, process_index, writer, rt, telemetry,
-                  buf_len, prefill_only=False, params=None):
+                  buf_len, devices, prefill_only=False, params=None):
     from distributed_pytorch_from_scratch_tpu.config import MeshConfig
     from distributed_pytorch_from_scratch_tpu.models.transformer import (
         Transformer)
@@ -163,7 +189,7 @@ def _build_engine(args, cfg, tp, process_index, writer, rt, telemetry,
     from distributed_pytorch_from_scratch_tpu.serving.scheduler import (
         parse_slo_classes)
 
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp))
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=devices)
     model = Transformer(cfg, tp_size=tp)
     if params is None:
         params = _load_params(args, model, mesh)
@@ -178,7 +204,7 @@ def _build_engine(args, cfg, tp, process_index, writer, rt, telemetry,
         prefill_only=prefill_only)
 
 
-def _reshard_restart(args, cfg, router, buf_len, obs_for):
+def _reshard_restart(args, cfg, router, buf_len, obs_for, slices):
     """Restart --restart_replica at --restart_tp: plan the layout change,
     reshard the LIVE replica's params per leaf (device→device — the
     checkpoint never re-reads), attach the new engine under the old name.
@@ -213,14 +239,16 @@ def _reshard_restart(args, cfg, router, buf_len, obs_for):
         make_layout((("tp", old_tp),), old.model.specs()),
         make_layout((("tp", new_tp),), model.specs()))
     t0 = time.perf_counter()
-    mesh = make_mesh(MeshConfig(dp=1, tp=new_tp))
+    devices = slices.take(new_tp)
+    mesh = make_mesh(MeshConfig(dp=1, tp=new_tp), devices=devices)
     params = reshard_params(old._params_in, mesh, model.specs())
     jax.block_until_ready(params)
     info = dict(plan.summary(),
-                wall_ms=round((time.perf_counter() - t0) * 1e3, 3))
+                wall_ms=round((time.perf_counter() - t0) * 1e3, 3),
+                devices=[d.id for d in devices])
     w, rt, tel = obs_for(args.replicas + 1)
     eng = _build_engine(args, cfg, new_tp, args.replicas + 1, w, rt, tel,
-                        buf_len, params=params)
+                        buf_len, devices, params=params)
     router.replace_replica(name, eng, reshard=info)
     print(f"replica {name} restarted at tp{new_tp}: "
           f"{info['src']} -> {info['dst']}, {info['bytes_moved']} bytes, "
@@ -230,6 +258,9 @@ def _reshard_restart(args, cfg, router, buf_len, obs_for):
 
 def main(argv=None) -> dict:
     args = parse_args(argv)
+    from distributed_pytorch_from_scratch_tpu.runtime.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()
     if args.dry_run:
         args.replicas = min(args.replicas, 2)
         args.num_requests, args.arrival = 8, "burst"
@@ -286,6 +317,16 @@ def main(argv=None) -> dict:
         exporters.append(tel)
         return w, rt, tel
 
+    import jax
+    slices = _DeviceSlices(jax.devices())
+    engine_devices = {}
+
+    def build(name, tp, process_index, w, rt, tel, **kw):
+        devices = slices.take(tp)
+        engine_devices[name] = [d.id for d in devices]
+        return _build_engine(args, cfg, tp, process_index, w, rt, tel,
+                             buf_len, devices, **kw)
+
     try:
         if args.disagg:
             from distributed_pytorch_from_scratch_tpu.obs.attribution import (
@@ -295,10 +336,8 @@ def main(argv=None) -> dict:
             wp, rtp, telp = obs_for(1)
             wd, rtd, teld = obs_for(2)
             ptp = args.prefill_tp or args.tp_size
-            pre = _build_engine(args, cfg, ptp, 1, wp, rtp, telp, buf_len,
-                                prefill_only=True)
-            dec = _build_engine(args, cfg, args.tp_size, 2, wd, rtd, teld,
-                                buf_len)
+            pre = build("prefill", ptp, 1, wp, rtp, telp, prefill_only=True)
+            dec = build("decode", args.tp_size, 2, wd, rtd, teld)
             summary = run_disaggregated(pre, dec, requests)
             done = summary.pop("completed")
             pb = page_bytes(cfg, args.page_size,
@@ -320,9 +359,8 @@ def main(argv=None) -> dict:
             replicas = []
             for i in range(args.replicas):
                 w, rt, tel = obs_for(i + 1)
-                replicas.append((f"r{i}",
-                                 _build_engine(args, cfg, args.tp_size,
-                                               i + 1, w, rt, tel, buf_len)))
+                replicas.append((f"r{i}", build(f"r{i}", args.tp_size,
+                                                i + 1, w, rt, tel)))
             router = FleetRouter(replicas,
                                  prefix_weight=args.prefix_weight,
                                  load_weight=args.load_weight,
@@ -336,7 +374,7 @@ def main(argv=None) -> dict:
                 half = max(1, len(requests) // 2)
                 wave_a = run_fleet_loadgen(router, requests[:half])
                 restart = _reshard_restart(args, cfg, router, buf_len,
-                                           obs_for)
+                                           obs_for, slices)
                 summary = run_fleet_loadgen(router, requests[half:])
                 summary["completed"] = (summary.get("completed", 0)
                                         + wave_a.get("completed", 0))
@@ -355,6 +393,7 @@ def main(argv=None) -> dict:
         for w in writers:
             w.close()
 
+    summary["engine_devices"] = engine_devices
     rec = {"metric": metric, "value":
            summary.get("fleet_tokens_per_sec",
                        summary.get("transferred_pages", 0)),

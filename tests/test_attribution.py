@@ -182,9 +182,49 @@ def test_estimate_rejects_unknown_policy():
         estimate_step_gib(model_preset("45m"), 32, 1000, "sometimes")
 
 
-def test_hbm_budget_falls_back_on_cpu():
-    # the CPU test mesh reports no bytes_limit -> the v5e default
-    assert hbm_budget_gib(default=16.0) > 0
+def test_hbm_budget_raises_without_memory_stats():
+    # the CPU test mesh reports no bytes_limit: no assumed 16 GiB, an error
+    with pytest.raises(ValueError, match="no memory_stats"):
+        hbm_budget_gib()
+    with pytest.raises(ValueError, match="no memory_stats"):
+        select_remat(model_preset("45m"), 32, 1000, verbose=False)
+    # off-chip callers name the budget
+    assert select_remat(model_preset("45m"), 32, 1000, budget_gib=16.0,
+                        verbose=False) in ("false", "dots", "true")
+
+
+def test_one_peaks_table_and_unknown_chips_raise():
+    """MFU and every roofline divide by obs/attribution.CHIP_SPECS and by
+    nothing else; a device_kind it does not list raises, and the CPU has
+    no peak at all (MFU 'not measured', not a share of a v5e)."""
+    import jax
+
+    from distributed_pytorch_from_scratch_tpu.obs.attribution import (
+        CHIP_SPECS, DEVICE_KINDS, chip_key_for, chip_specs)
+    from distributed_pytorch_from_scratch_tpu.training.metrics import (
+        chip_peak_flops)
+
+    assert chip_key_for("TPU v5 lite") == "v5e"      # what the v5e answers
+    assert chip_key_for("TPU v5") == "v5p"           # NOT the v5e
+    assert set(DEVICE_KINDS.values()) <= set(CHIP_SPECS)
+    assert chip_specs("v5e") == (197e12, 819e9)
+    for kind in ("cpu", "TPU v9 mega", ""):
+        with pytest.raises(ValueError, match="no peak"):
+            chip_key_for(kind)
+    with pytest.raises(ValueError, match="unknown chip"):
+        chip_specs("v9")
+    with pytest.raises(ValueError, match="unknown chip"):
+        attribution(model_preset("45m"), 32, 1000, chip="v9")
+    assert chip_peak_flops() is None                 # the CPU test mesh
+
+    class FakeDevice:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    assert chip_peak_flops(FakeDevice()) == 197e12
+    FakeDevice.device_kind = "TPU v9 mega"
+    with pytest.raises(ValueError, match="no peak"):
+        chip_peak_flops(FakeDevice())
+    assert jax.devices()[0].platform == "cpu"
 
 
 def test_moe_estimate_exceeds_dense():

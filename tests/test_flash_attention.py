@@ -3,8 +3,12 @@
 The reference has no fused attention at all (naive O(T^2) masked softmax,
 `/root/reference/models/model.py:73-77`); the oracle here is our XLA
 mirror of that math, so equivalence to it is equivalence to the reference.
-Runs in Pallas interpreter mode on CPU (same kernel code compiles on TPU).
+Every kernel call here asks for the Pallas interpreter by name (the same
+kernel code is compiled by Mosaic on TPU; scripts/tpu_checks.py checks that
+there). Without the opt-in, a non-TPU backend is an error — pinned below.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +20,29 @@ from distributed_pytorch_from_scratch_tpu import (MeshConfig, ModelConfig,
                                                   Transformer, make_mesh)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
     causal_attention_xla)
-from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
-    flash_attention)
+from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+    flash_attention as fa_mod)
+
+flash_attention = functools.partial(fa_mod.flash_attention, interpret=True)
+
+
+def test_kernels_refuse_non_tpu_backend_without_interpreter_opt_in():
+    """No silent interpreter, no silent XLA: asked for by name off-TPU, the
+    Mosaic kernels raise — at the kernel and at the dispatcher."""
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        causal_attention, resolve_attention_impl)
+    q = jnp.zeros((1, 2, 128, 16))
+    pos = jnp.zeros((1, 128), jnp.int32)
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        fa_mod.flash_attention(q, q, q)
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        fa_mod.block_attention(q, q, q, pos, pos)
+    with pytest.raises(ValueError, match="needs a TPU backend"):
+        causal_attention(q, q, q, impl="flash")
+    assert resolve_attention_impl("auto") == "xla"   # and says so
+    assert resolve_attention_impl("flash_interpret") == "flash_interpret"
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        resolve_attention_impl("cuda")
 
 
 @pytest.mark.parametrize("shape", [(2, 4, 128, 64), (1, 2, 300, 64),
@@ -117,7 +142,7 @@ def test_transformer_attn_impl_flash_matches_xla():
                       vocab_size=128, maxlen=160, compute_dtype="float32")
     mesh = make_mesh(MeshConfig(dp=2, tp=4))
     m_xla = Transformer(cfg, tp_size=4, attn_impl="xla")
-    m_fla = Transformer(cfg, tp_size=4, attn_impl="flash")
+    m_fla = Transformer(cfg, tp_size=4, attn_impl="flash_interpret")
     params = m_xla.init(jax.random.key(0))
     params = jax.device_put(params, m_xla.shardings(mesh))
 
@@ -174,8 +199,8 @@ def test_gqa_rejects_nondivisible_heads():
 def test_block_attention_matches_xla_block():
     """Pallas positional kernel vs the dense XLA block math, including an
     all-dead query row (position earlier than every kv) and GQA heads."""
-    from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
-        block_attention)
+    block_attention = functools.partial(fa_mod.block_attention,
+                                        interpret=True)
     from distributed_pytorch_from_scratch_tpu.ops.ring_attention import (
         _BIG_NEG, _block_attn_xla)
 
@@ -348,7 +373,7 @@ def test_autotune_caches_winner(block_table, tmp_path, monkeypatch):
     monkeypatch.setenv("FLASH_BLOCKS_CACHE", str(tmp_path / "fb.json"))
     best = fa.autotune_block_config(128, 16, jnp.float32, batch_heads=2,
                                     sweep=(128,), iters=1, warmup=0,
-                                    write_cache=True)
+                                    write_cache=True, interpret=True)
     assert best == fa.BlockConfig(128, 128, 128, 128)
     assert fa.get_block_config(128, 16, jnp.float32) == best
     fa._BLOCK_TABLE.clear()
@@ -359,7 +384,7 @@ def test_autotune_caches_winner(block_table, tmp_path, monkeypatch):
 # ---- model-level sequence bucketing (attn_t_real) ----
 
 
-@pytest.mark.parametrize("attn_impl", ["xla", "flash"])
+@pytest.mark.parametrize("attn_impl", ["xla", "flash_interpret"])
 def test_model_seq_bucket_matches_unbucketed(attn_impl):
     """A bucket-padded batch (t=200 real in a t=256 buffer, IGNORE_INDEX
     pad targets) through a model with attn_t_real must reproduce the plain

@@ -419,3 +419,46 @@ def test_three_hop_waterfall_real_path():
         assert "kv_import" in [s["name"] for s in m["spans"]]
     _assert_drained(pre)
     _assert_drained(dec)
+
+
+# --------------------------- scripts/serve_fleet.py: one slice per engine --
+
+def _serve_fleet():
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "serve_fleet.py")
+    spec = importlib.util.spec_from_file_location("_fleet_cli", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_fleet_engines_get_disjoint_device_slices(capsys):
+    """Every replica — and the disaggregated prefill and decode engines —
+    builds its mesh on its OWN run of devices. They all used to take
+    `jax.devices()[:tp]`: on a four-chip host four "replicas" shared the
+    first chips and the rest idled."""
+    sf = _serve_fleet()
+    args = sf.parse_args(["--random_init", "--model", "tiny", "--slots", "2",
+                          "--page_size", "8", "--prefill_chunk", "8"])
+    slices = sf._DeviceSlices(jax.devices())
+    engines = [sf._build_engine(args, CFG, tp, i + 1, None, None, None, BUF,
+                                slices.take(tp))
+               for i, tp in enumerate((2, 2, 1))]
+    held = [{d.id for d in e.mesh.devices.flat} for e in engines]
+    assert [len(h) for h in held] == [2, 2, 1]
+    assert not (held[0] & held[1] or held[0] & held[2] or held[1] & held[2])
+    # the weights really live there, not on device 0
+    for e, h in zip(engines, held):
+        leaf = jax.tree.leaves(e._params_in)[0]
+        assert {s.device.id for s in leaf.addressable_shards} == h
+    # the walk is in order and, out of devices, starts over and says so
+    few = sf._DeviceSlices(jax.devices()[:2])
+    assert [d.id for d in few.take(1)] == [0]
+    assert [d.id for d in few.take(1)] == [1]
+    assert capsys.readouterr().err == ""
+    assert [d.id for d in few.take(2)] == [0, 1]
+    assert "share devices" in capsys.readouterr().err
+    with pytest.raises(SystemExit, match="needs 4 devices"):
+        few.take(4)

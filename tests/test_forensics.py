@@ -4,7 +4,7 @@ diff engine, and trajectory changepoint triage.
 What is pinned here, against the two committed fixture run dirs under
 tests/forensics_fixtures/ (run_a: pages_per_block=4, run_b:
 pages_per_block=8 with a degraded copy phase) and the repo's REAL
-BENCH_r02 outage record:
+BENCH_r02 outage fixture:
 
 * RunCard fields for both fixture runs (fingerprint, headline metrics,
   ledger/capture tallies, HBM watermark, graftcheck contracts);
@@ -29,6 +29,8 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIX = os.path.join(REPO, "tests", "forensics_fixtures")
+# a stand-in repo root holding synthetic driver-style records (its README)
+FIX_REPO = os.path.join(FIX, "repo")
 RUN_A = os.path.join(FIX, "run_a")
 RUN_B = os.path.join(FIX, "run_b")
 
@@ -97,11 +99,11 @@ def test_run_card_run_b_ledger():
 
 
 def test_run_card_legacy_note_not_silent_none():
-    """A pre-stamp record (the real BENCH_r01) indexes with the loud
+    """A pre-stamp record (the fixture BENCH_r01) indexes with the loud
     legacy note, and the diff engine refuses to call two fingerprint-less
     configs equal."""
     card = runindex.card_from_bench_path(
-        os.path.join(REPO, "BENCH_r01.json"))
+        os.path.join(FIX_REPO, "BENCH_r01.json"))
     assert card["legacy"] is True
     assert runindex.LEGACY_NOTE in card["notes"]
     assert card["config_fingerprint"] is None
@@ -122,9 +124,9 @@ def test_outage_classifier_is_shared_with_gate():
 
 
 def test_bench_r02_outage_never_baseline():
-    """BENCH_r02 (rc=1, traceback tail, parsed=null) is the real pinned
+    """BENCH_r02 (rc=1, traceback tail, parsed=null) is the pinned
     outage fixture: classified as outage, never selected as baseline."""
-    r02 = os.path.join(REPO, "BENCH_r02.json")
+    r02 = os.path.join(FIX_REPO, "BENCH_r02.json")
     cls = runindex.classify_path(r02)
     assert cls["outage"] is not None
     assert "rc=1" in cls["outage"]
@@ -139,7 +141,7 @@ def test_bench_r02_outage_never_baseline():
     fresh_chip = {"metric": "tokens/sec/chip (x)",
                   "unit": "tokens/sec/chip", "value": 1.0}
     rec, path = gate.pick_baseline(
-        fresh_chip, [os.path.join(REPO, "BENCH_r01.json"), r02])
+        fresh_chip, [os.path.join(FIX_REPO, "BENCH_r01.json"), r02])
     assert path.endswith("BENCH_r01.json")
     assert rec["unit"] == "tokens/sec/chip"
 
@@ -149,7 +151,7 @@ def test_outage_reason_taxonomy():
     assert runindex.outage_reason(None, rc=3) == \
         "no parseable record (rc=3)"
     assert "backend_unavailable" in runindex.outage_reason(
-        {"error": "backend_unavailable", "detail": "tunnel"})
+        {"error": "backend_unavailable", "detail": "no backend"})
     assert runindex.outage_reason({"metric": "x", "value": 1}, rc=1) \
         == "rc=1"
     assert runindex.outage_reason({"value": 1}) == \
@@ -345,7 +347,7 @@ def test_obs_diff_card_and_bare_name_resolution(capsys):
     card = json.loads(capsys.readouterr().out.strip())
     assert card["tag"] == "run_card" and card["run"] == "run_a"
     # bare round names resolve against the repo (r02 -> BENCH_r02.json)
-    assert od.main(["r02", "r01"]) == 0
+    assert od.main(["--repo", FIX_REPO, "r02", "r01"]) == 0
     doc = json.loads(capsys.readouterr().out.strip())
     assert doc["run_a"] == "BENCH_r02" and doc["run_b"] == "BENCH_r01"
     assert doc["outage_a"] is not None  # r02's outage is carried along
@@ -384,16 +386,20 @@ def test_obs_diff_triage_picks_comparable_baseline(tmp_path, capsys):
     assert doc["note"] == "no comparable baseline"
 
 
-def test_obs_diff_index_counts_real_repo(capsys):
-    """--index over the real repo: every committed BENCH round + every
-    runs/ dir gets a card, r02-r05 classified as outages, and no outage
-    is baseline-eligible."""
+def test_obs_diff_index_counts_fixture_repo(capsys):
+    """--index over the fixture repo root: every BENCH record there gets
+    a card, both outage shapes (no record; a backend_unavailable record)
+    classify as outages, and no outage is baseline-eligible. The real
+    repo's runs/ dirs index cleanly too."""
     od = _load_script("obs_diff")
     assert od.main(["--index"]) == 0
+    real = json.loads(capsys.readouterr().out.strip())["cards"]
+    assert all(runindex.validate_card(c) == [] for c in real)
+    assert od.main(["--repo", FIX_REPO, "--index"]) == 0
     cards = json.loads(capsys.readouterr().out.strip())["cards"]
     by_run = {c["run"]: c for c in cards}
     assert by_run["BENCH_r01"]["baseline_eligible"] is True
-    for r in ("BENCH_r02", "BENCH_r03", "BENCH_r04", "BENCH_r05"):
+    for r in ("BENCH_r02", "BENCH_r03"):
         assert by_run[r]["outage"] is True, r
     assert all(not (c["outage"] and c["baseline_eligible"])
                for c in cards)

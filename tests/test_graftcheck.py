@@ -7,7 +7,7 @@ Three layers of pinning:
   clean (tests/graftcheck_fixtures/); plus the pragma escape hatch.
 * **clean-repo gate** — the layer-1 sweep over this repo returns zero
   violations. Every future PR inherits the contract: new dead imports,
-  compat bypasses, donation misuse etc. fail HERE, not on a chip.
+  donation misuse, host calls in traced code etc. fail HERE, not on a chip.
 * **trace contracts** — the acceptance pins: the compiled train step's
   collective inventory matches `obs/attribution.expected_collectives`
   for zero ∈ {1,2,3} at dp2 x tp2 + SP; the int8-wire step provably

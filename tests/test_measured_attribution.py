@@ -339,21 +339,12 @@ def test_publish_hbm_exports_unavailable_loudly(tmp_path):
 
 
 def test_train_scalar_never_fakes_zero_memory(tmp_path):
-    """memory.py's budget fallback warns loudly too (one-time note)."""
+    """memory.py's budget has no fallback either: a backend without
+    memory_stats raises instead of sizing remat against an assumed
+    16 GiB."""
     from distributed_pytorch_from_scratch_tpu.training import memory
-    memory._warned_assumed_budget.clear()
-    import io
-    import sys
-    err = io.StringIO()
-    old = sys.stderr
-    sys.stderr = err
-    try:
-        v = memory.hbm_budget_gib()
-        memory.hbm_budget_gib()     # second call stays quiet
-    finally:
-        sys.stderr = old
-    assert v == 16.0
-    assert err.getvalue().count("UNAVAILABLE") == 1
+    with pytest.raises(ValueError, match="no memory_stats"):
+        memory.hbm_budget_gib()
 
 
 # -------------------------------- schema v4 + collector + obs_top

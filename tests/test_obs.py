@@ -182,7 +182,7 @@ def test_watchdog_quiet_while_beating():
 # ------------------------------------------------------------ introspect
 
 CANNED_HLO = """
-  %ar = f32[8,128]{1,0} all-reduce(f32[8,128]{1,0} %p), replica_groups={}
+  %ar = f32[8,128]{1,0} all-reduce(f32[8,128]{1,0} %p), replica_groups={{0,1},{2,3}}, to_apply=%add
   %ag.1 = bf16[4,256]{1,0} all-gather(bf16[4,64]{1,0} %q), dimensions={1}
   %aas = (f32[16]{0}, f32[16]{0}) all-to-all-start(f32[16]{0} %r)
   %done = f32[8,128]{1,0} all-reduce-done(f32[8,128]{1,0} %ar)
@@ -191,8 +191,13 @@ CANNED_HLO = """
 
 def test_parse_collectives_counts_and_bytes():
     colls = parse_collectives(CANNED_HLO)
-    assert colls["all-reduce"] == {"count": 1, "bytes": 8 * 128 * 4}
-    assert colls["all-gather"] == {"count": 1, "bytes": 4 * 256 * 2}
+    assert colls["all-reduce"]["count"] == 1
+    assert colls["all-reduce"]["bytes"] == 8 * 128 * 4
+    # who talks to whom: two mesh axes show up as two groupings
+    assert colls["all-reduce"]["groups"] == ["{{0,1},{2,3}}"]
+    assert colls["all-gather"]["groups"] == []
+    assert colls["all-gather"]["count"] == 1
+    assert colls["all-gather"]["bytes"] == 4 * 256 * 2
     assert colls["all-to-all"]["count"] == 1
     # async -start tuple = (operand, result): only the result counts, so
     # sync and async lowerings of the same op report the same bytes
@@ -320,13 +325,14 @@ def test_nan_loss_halts_training_with_state_dump(token_corpus, tmp_path,
 
     def nan_builder(*a, **kw):
         fn = real_builder(*a, **kw)
-        calls = [0]
 
+        # jitted like the real step (train AOT-compiles it); the blow-up
+        # keys on the optimizer's own step counter
+        @jax.jit
         def wrapped(p, o, ids, tgt, pos):
             p, o, (loss, g) = fn(p, o, ids, tgt, pos)
-            calls[0] += 1
-            if calls[0] >= 6:  # blow up mid-run, after healthy intervals
-                loss = loss * jnp.float32("nan")
+            # blow up mid-run, after healthy intervals
+            loss = jnp.where(o.step >= 6, jnp.float32("nan"), loss)
             return p, o, (loss, g)
 
         return wrapped
@@ -360,6 +366,7 @@ def test_sentinel_can_be_disabled(token_corpus, tmp_path, monkeypatch):
     def nan_builder(*a, **kw):
         fn = real_builder(*a, **kw)
 
+        @jax.jit   # train AOT-compiles its step
         def wrapped(p, o, ids, tgt, pos):
             p, o, (loss, g) = fn(p, o, ids, tgt, pos)
             return p, o, (loss * jnp.float32("nan"), g)

@@ -448,20 +448,25 @@ def _gate():
     return GATE
 
 
-def test_gate_passes_on_committed_trajectory_vs_itself(capsys):
-    rc = _gate().main(["--fresh", os.path.join(REPO, "BENCH_r01.json")])
+# a synthetic driver-style record (tests/forensics_fixtures/repo/README.md)
+BASE_REC = os.path.join(REPO, "tests", "forensics_fixtures", "repo",
+                        "BENCH_r01.json")
+
+
+def test_gate_passes_on_a_record_vs_itself(capsys):
+    rc = _gate().main(["--fresh", BASE_REC, "--baseline", BASE_REC])
     assert rc == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["status"] == "ok" and out["checks"]
 
 
 def test_gate_fails_on_degraded_record(tmp_path, capsys):
-    base = json.load(open(os.path.join(REPO, "BENCH_r01.json")))["parsed"]
+    base = json.load(open(BASE_REC))["parsed"]
     degraded = dict(base, value=base["value"] * 0.7,
                     vs_baseline=base["vs_baseline"] * 0.7)
     p = tmp_path / "degraded.json"
     p.write_text(json.dumps(degraded))
-    rc = _gate().main(["--fresh", str(p)])
+    rc = _gate().main(["--fresh", str(p), "--baseline", BASE_REC])
     assert rc == 1
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["status"] == "regression"
@@ -469,14 +474,18 @@ def test_gate_fails_on_degraded_record(tmp_path, capsys):
     # within-tolerance wobble still passes
     ok = dict(base, value=base["value"] * 0.95)
     p.write_text(json.dumps(ok))
+    assert _gate().main(["--fresh", str(p), "--baseline", BASE_REC]) == 0
+    # no baseline named: nothing to compare with, said so, passing
     assert _gate().main(["--fresh", str(p)]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["status"] == "no_baseline"
 
 
 def test_gate_skips_on_backend_unavailable(tmp_path, capsys):
     p = tmp_path / "outage.json"
     p.write_text(json.dumps({"metric": "bench",
                              "error": "backend_unavailable",
-                             "detail": "tunnel down"}))
+                             "detail": "no backend"}))
     rc = _gate().main(["--fresh", str(p)])
     assert rc == 0
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])

@@ -116,23 +116,24 @@ def test_quantized_allreduce_matches_psum():
     mesh = make_mesh(MeshConfig(dp=4, tp=2))
     v = jax.random.normal(jax.random.key(5), (8, 3001))
 
-    def q(z):
-        return quantized_allreduce(z[0], ("dp", "tp"))
+    def q(z):  # every rank's copy comes back, stacked: (ranks, n)
+        return quantized_allreduce(z[0], ("dp", "tp"))[None]
 
     def p(z):
         return jax.lax.psum(z[0], ("dp", "tp"))
 
     spec = (P(("dp", "tp")),)
-    rq = jax.jit(jax.shard_map(q, mesh=mesh, in_specs=spec,
-                               out_specs=P()))(v)
+    ring = jax.jit(jax.shard_map(q, mesh=mesh, in_specs=spec,
+                                 out_specs=P(("dp", "tp"))))
+    rq = ring(v)
     rp = jax.jit(jax.shard_map(p, mesh=mesh, in_specs=spec,
                                out_specs=P()))(v)
-    assert rel_err(rq, rp) < 2.0 ** -4
-    # replica-identity: out_specs P() already asserts it (a diverging
-    # value would fail shard_map's replication gather) — and zeros:
-    r0 = jax.jit(jax.shard_map(q, mesh=mesh, in_specs=spec,
-                               out_specs=P()))(jnp.zeros((8, 777)))
-    assert float(jnp.max(jnp.abs(r0))) == 0.0
+    assert rel_err(rq[0], rp) < 2.0 ** -4
+    # replica-identity, checked on the values (the ring's result is built
+    # from ppermutes, so shard_map cannot infer it): all 8 copies equal
+    np.testing.assert_array_equal(np.asarray(rq),
+                                  np.broadcast_to(np.asarray(rq[0]), rq.shape))
+    assert float(jnp.max(jnp.abs(ring(jnp.zeros((8, 777)))))) == 0.0
 
 
 def test_bucketed_reduce_int8_wire_tolerance():
@@ -209,15 +210,15 @@ def test_ring_q_kernels_match_oracles_within_bound(tp):
         return jnp.sum((gather_from(x, "tp", tiled_axis=-2) @ w) ** 2)
 
     specs = (P(None, "tp", None), P())
-    run = lambda fn: jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=specs,
-                                           out_specs=P()))
-    assert rel_err(run(ring_loss)(x, w), run(mono_loss)(x, w)) < 2.0 ** -6
-    gq = jax.jit(jax.jacrev(jax.shard_map(
-        ring_loss, mesh=mesh, in_specs=specs, out_specs=P()),
-        argnums=(0, 1)))(x, w)
-    gm = jax.jit(jax.jacrev(jax.shard_map(
-        mono_loss, mesh=mesh, in_specs=specs, out_specs=P()),
-        argnums=(0, 1)))(x, w)
+    # check_vma=False: replicated w makes both losses replicated, but from
+    # ppermuted / all-gathered chunks typed varying (see the unquantized
+    # oracle in tests/test_overlap.py)
+    smap = lambda fn: jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                                    out_specs=P(), check_vma=False)
+    assert rel_err(jax.jit(smap(ring_loss))(x, w),
+                   jax.jit(smap(mono_loss))(x, w)) < 2.0 ** -6
+    gq = jax.jit(jax.jacrev(smap(ring_loss), argnums=(0, 1)))(x, w)
+    gm = jax.jit(jax.jacrev(smap(mono_loss), argnums=(0, 1)))(x, w)
     for a, bb in zip(gq, gm):
         assert rel_err(a, bb) < 2.0 ** -4
 
@@ -261,6 +262,12 @@ def test_model_ring_q_matches_off_within_bound(family, tp):
     l1, g1 = jax.value_and_grad(ring.make_loss(mesh))(params, ids, tgt, pos)
     assert abs(float(l1) - float(l0)) / abs(float(l0)) < 1e-4
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
+        if float(jnp.max(jnp.abs(b))) < 1e-8:
+            # an analytically zero gradient (gpt2's key bias shifts every
+            # score of a row alike and softmax cancels it): both sides are
+            # float noise ~1e-10, which has no relative error to bound
+            assert float(jnp.max(jnp.abs(a))) < 1e-8
+            continue
         assert rel_err(a, b) < 2.0 ** -4
 
 

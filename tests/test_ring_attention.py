@@ -239,23 +239,37 @@ def test_doc_loss_zigzag_matches_single_device():
 
 # ---- ring + flash kernel composition (VERDICT r3 #2) ----
 #
-# In product code, `_block_attn` falls back to dense XLA whenever the
-# interpreted Pallas kernel would run inside a vma-checked shard_map (the
-# discharged kernel jaxpr fails the varying-manual-axes check), so the
-# composed ring+flash path — the Pallas positional block kernel driven by
-# the online-softmax combine with real ppermutes — never executed in any
-# CPU test. `check_vma=False` removes the tags entirely: the gate at
-# ops/ring_attention.py::_block_attn sees no vma, takes the kernel path,
-# and the FULL composition runs interpreted inside a cp>1 mesh. These
-# tests pin its forward and backward against the dense oracle.
+# The INTERPRETED Pallas kernel cannot run inside a vma-checked shard_map
+# (the discharged kernel jaxpr fails the varying-manual-axes check; Mosaic
+# on TPU never discharges), and `_block_attn` refuses that combination
+# rather than quietly computing the block in XLA. `check_vma=False` removes
+# the tags, so the FULL composition — the Pallas positional block kernel
+# driven by the online-softmax combine with real ppermutes — runs
+# interpreted inside a cp>1 mesh. These tests pin its forward and backward
+# against the dense oracle.
 
 
 def flash_ring(mesh, layout_pos=None):
-    fn = functools.partial(ring_attention, axis="cp", impl="flash")
+    fn = functools.partial(ring_attention, axis="cp", impl="flash_interpret")
     return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, "tp", "cp", None),) * 3 + (P(None, "cp"),),
         out_specs=P(None, "tp", "cp", None), check_vma=False))
+
+
+def test_interpreted_ring_blocks_refuse_a_vma_checked_shard_map():
+    """impl='flash_interpret' under the default (checked) shard_map is an
+    error that names the way out; it used to compute the blocks in XLA
+    without a word."""
+    mesh = make_mesh(MeshConfig(dp=1, cp=2, tp=1))
+    q, k, v, pos = make_qkv(jax.random.key(11), h=2, t=128, d=64)
+    fn = jax.shard_map(
+        functools.partial(ring_attention, axis="cp", impl="flash_interpret"),
+        mesh=mesh,
+        in_specs=(P(None, "tp", "cp", None),) * 3 + (P(None, "cp"),),
+        out_specs=P(None, "tp", "cp", None))
+    with pytest.raises(ValueError, match="check_vma=False"):
+        jax.jit(fn)(q, k, v, pos)
 
 
 @pytest.mark.parametrize("cp,tp", [(2, 1), (2, 2)])
