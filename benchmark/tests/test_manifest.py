@@ -1,0 +1,135 @@
+"""BENCHMARK.json is well formed and every name in it finds its file."""
+
+import json
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+BENCH = os.path.join(ROOT, "benchmark")
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def manifest():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def load(*parts):
+    with open(os.path.join(BENCH, *parts)) as f:
+        return json.load(f)
+
+
+def one_line(text, limit=200):
+    return 1 <= len(text) <= limit and "\n" not in text and "\t" not in text
+
+
+def test_keys_and_sizes(manifest):
+    assert set(manifest) == {"command", "paths", "run_seconds", "configs",
+                             "workloads", "end_to_end", "per_layer"}
+    assert manifest["paths"] == ["benchmark"]
+    assert manifest["command"][:2] == ["python3", "benchmark/run.py"]
+    assert isinstance(manifest["run_seconds"], int)
+    assert 1 <= manifest["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    assert 1 <= len(manifest["configs"]) <= 24
+    assert 1 <= len(manifest["workloads"]) <= 24
+    assert 1 <= len(manifest["end_to_end"]) <= 16
+    assert 1 <= len(manifest["per_layer"]) <= 128
+
+
+def test_names_and_units(manifest):
+    metrics = manifest["end_to_end"] + manifest["per_layer"]
+    for group in (manifest["configs"], manifest["workloads"], metrics):
+        names = [x["name"] for x in group]
+        assert len(names) == len(set(names))
+        assert all(NAME.match(n) for n in names), names
+    for m in metrics:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher"), m
+        assert m["source"] in SOURCES, m
+    for m in manifest["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for m in manifest["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert one_line(m["layer"])
+    assert "setup_s" in [m["name"] for m in manifest["end_to_end"]]
+
+
+def test_configs_have_their_files(manifest):
+    files = set()
+    for c in manifest["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert one_line(c["source"]) and one_line(c["why"])
+        assert c["file"].startswith("benchmark/") and c["file"] not in files
+        files.add(c["file"])
+        body = load(*c["file"].split("/")[1:])
+        assert body["source"] == c["source"]
+        assert sorted(body["reduced"]) == sorted(c["reduced"])
+        assert len(c["reduced"]) <= 16
+        for key in c["reduced"]:
+            assert NAME.match(key)
+            # never a width
+            assert not re.search(r"(_dim|_rank|n_embd|n_inner|hidden|head)",
+                                 key), key
+        assert os.path.isfile(os.path.join(
+            BENCH, "families", body["family"] + ".py"))
+
+
+def test_workloads_have_their_files(manifest):
+    configs = {c["name"] for c in manifest["configs"]}
+    used, pairs = set(), set()
+    for w in manifest["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert w["config"] in configs and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and one_line(w["why"])
+        assert (w["config"], w["traffic"]) not in pairs
+        pairs.add((w["config"], w["traffic"]))
+        used.add(w["config"])
+        body = load("workloads", w["name"] + ".json")
+        assert body["config"] == w["config"] and body["chips"] == w["chips"]
+        assert os.path.isfile(os.path.join(
+            BENCH, "runners", body["runner"] + ".py"))
+        assert os.path.isfile(os.path.join(
+            BENCH, "data", body["data"]["kind"] + ".py"))
+        assert "rehearse" in body
+    assert used == configs
+    four = sum(w["chips"] == 4 for w in manifest["workloads"])
+    assert four <= max(1, len(manifest["workloads"]) // 4)
+
+
+def test_layer_metrics_move_what_their_cells_report(manifest):
+    cells = [w["name"] for w in manifest["workloads"]]
+
+    def cells_of(metric):
+        assert set(metric.get("workloads", cells)) <= set(cells)
+        return set(metric.get("workloads", cells))
+
+    reported = {m["name"]: cells_of(m) for m in manifest["end_to_end"]}
+    assert reported["setup_s"] == set(cells)
+    for m in manifest["per_layer"]:
+        assert m["moves"] in reported, m
+        assert cells_of(m) <= reported[m["moves"]], m
+        assert os.path.isfile(os.path.join(
+            BENCH, "layer_metrics", m["name"] + ".py")), m["name"]
+    for cell in cells:
+        assert sum(cell in c for c in reported.values()) >= 2
+        assert any(cell in cells_of(m) for m in manifest["per_layer"])
+    # one spelling per layer
+    layers = {m["layer"] for m in manifest["per_layer"]}
+    assert len({l.lower() for l in layers}) == len(layers)
+
+
+def test_every_reader_is_in_the_manifest(manifest):
+    names = {m["name"] for m in manifest["per_layer"]}
+    on_disk = {f[:-3] for f in os.listdir(os.path.join(BENCH, "layer_metrics"))
+               if f.endswith(".py")}
+    assert on_disk == names
