@@ -21,6 +21,8 @@ from typing import Callable, Iterable, Iterator, Optional
 
 import numpy as np
 
+from ..obs.trace import span_of
+
 BATCH_KEYS = ("input_ids", "target_ids", "position_ids")
 
 
@@ -58,10 +60,13 @@ class Prefetcher:
     epoch does not leak a blocked thread. Tracks `wait_time` (seconds the
     CONSUMER spent blocked) so the host-overlap win is measurable.
 
-    `tracer`: optional obs.SpanTracer — each window's collate+stack work
-    records a "prefetch_window" span on the producer thread, so the
-    timeline shows the input pipeline's own track next to the train loop
-    (queue-blocked time is excluded: the span covers source+transform only).
+    `tracer`: optional obs.SpanTracer (anything with its `span`) — each
+    window's collate+stack work records a "prefetch_window" span on the
+    producer thread, so the timeline shows the input pipeline's own track
+    next to the train loop (queue-blocked time is excluded: the span covers
+    source+transform only), and each pull a "data_wait" span on the
+    consumer's thread: the time `wait_time` sums. `pull(step=n)` is
+    `next()` with the span's arguments. Without a tracer there are no spans.
     """
 
     _DONE = object()
@@ -70,6 +75,7 @@ class Prefetcher:
                  transform: Optional[Callable] = None, tracer=None):
         self._q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
         self._stop = threading.Event()
+        self._tracer = tracer
         self.wait_time = 0.0
         self.pulls = 0
 
@@ -77,16 +83,14 @@ class Prefetcher:
             try:
                 it = iter(src)
                 while True:
-                    t0 = tracer.now() if tracer is not None else None
                     try:
-                        item = next(it)
+                        with span_of(tracer, "prefetch_window",
+                                     cat="data_prep"):
+                            item = next(it)
+                            if transform is not None:
+                                item = transform(item)
                     except StopIteration:
                         break
-                    if transform is not None:
-                        item = transform(item)
-                    if tracer is not None:
-                        tracer.complete("prefetch_window", t0,
-                                        cat="data_prep")
                     self._put_until_stopped(item)
                     if self._stop.is_set():
                         return
@@ -112,9 +116,16 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        t0 = time.perf_counter()
-        item = self._q.get()
-        self.wait_time += time.perf_counter() - t0
+        return self.pull()
+
+    def pull(self, **span_args):
+        """The next item; `span_args` (the loop's `step=`) go on the
+        "data_wait" span."""
+        with span_of(self._tracer, "data_wait", cat="data_wait",
+                     **span_args):
+            t0 = time.perf_counter()
+            item = self._q.get()
+            self.wait_time += time.perf_counter() - t0
         self.pulls += 1
         if item is self._DONE:
             self.close()

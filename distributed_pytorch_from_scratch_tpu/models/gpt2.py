@@ -401,27 +401,30 @@ class GPT2Transformer:
             x, auxs = lax.scan(body, x, params["layers"])
             aux = (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
                    if self.is_moe else None)
-        x = self.final_norm.apply(params["norm"], x)
-        # tied head: local logits against this shard's embedding rows
-        w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
-        if sp and self.tp_overlap in ("ring", "ring_q"):
-            # ring collective matmul for the tied head too: the gather's
-            # hops hide under the per-chunk logits dots, and the VJP's
-            # reverse ring reduce-scatters the head's input cotangent
-            logits = ag_matmul(x.astype(dtype), (w.T,), "tp",
-                               self.tp_overlap == "ring_q")[0]
-        else:
-            if sp:
-                # the tied head consumes full-sequence activations; the
-                # gather's transpose reduce-scatters the input cotangent
-                x = gather_from(x, "tp", tiled_axis=-2)
-            logits = x.astype(dtype) @ w.T                # (b, t, vp/tp)
+        # `head_loss`: the one boundary inside the loss that a device trace is
+        # split at (final norm, head, CE; benchmark/lib/program_trace.py)
+        with jax.named_scope("head_loss"):
+            x = self.final_norm.apply(params["norm"], x)
+            # tied head: local logits against this shard's embedding rows
+            w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
+            if sp and self.tp_overlap in ("ring", "ring_q"):
+                # ring collective matmul for the tied head too: the gather's
+                # hops hide under the per-chunk logits dots, and the VJP's
+                # reverse ring reduce-scatters the head's input cotangent
+                logits = ag_matmul(x.astype(dtype), (w.T,), "tp",
+                                   self.tp_overlap == "ring_q")[0]
+            else:
+                if sp:
+                    # the tied head consumes full-sequence activations; the
+                    # gather's transpose reduce-scatters the input cotangent
+                    x = gather_from(x, "tp", tiled_axis=-2)
+                logits = x.astype(dtype) @ w.T            # (b, t, vp/tp)
 
-        if self.vocab_padded != self.cfg.vocab_size:
-            local_v = self.vocab_padded // self.tp_size
-            col = lax.axis_index("tp") * local_v + jnp.arange(local_v)
-            logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
-                               logits, jnp.asarray(NEG_INF, logits.dtype))
+            if self.vocab_padded != self.cfg.vocab_size:
+                local_v = self.vocab_padded // self.tp_size
+                col = lax.axis_index("tp") * local_v + jnp.arange(local_v)
+                logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
+                                   logits, jnp.asarray(NEG_INF, logits.dtype))
         return logits, aux
 
     # ---- everything else is the shared machinery (see module docstring) ----

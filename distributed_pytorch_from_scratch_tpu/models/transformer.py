@@ -767,18 +767,21 @@ class Transformer:
             # auxs: None for dense; for MoE a dict of (L,...) stacked sums
             aux = (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
                    if self.is_moe else None)
-        x = self.final_norm.apply(params["norm"], x)
-        logits = self.lm_head.apply(
-            params["lm_head"], x, dtype,
-            input_layout="seq_sharded" if sp else "replicated")
+        # `head_loss`: the one boundary inside the loss that a device trace is
+        # split at (final norm, head, CE; benchmark/lib/program_trace.py)
+        with jax.named_scope("head_loss"):
+            x = self.final_norm.apply(params["norm"], x)
+            logits = self.lm_head.apply(
+                params["lm_head"], x, dtype,
+                input_layout="seq_sharded" if sp else "replicated")
 
-        # Mask padded vocab entries so they carry no probability mass.
-        if self.vocab_padded != self.cfg.vocab_size:
-            local_v = self.vocab_padded // self.tp_size
-            start = lax.axis_index("tp") * local_v
-            col = start + jnp.arange(local_v)
-            logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
-                               logits, jnp.asarray(NEG_INF, logits.dtype))
+            # Mask padded vocab entries so they carry no probability mass.
+            if self.vocab_padded != self.cfg.vocab_size:
+                local_v = self.vocab_padded // self.tp_size
+                start = lax.axis_index("tp") * local_v
+                col = start + jnp.arange(local_v)
+                logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
+                                   logits, jnp.asarray(NEG_INF, logits.dtype))
         return logits, aux
 
     def _pipeline_layers(self, stage_fn, x: jax.Array, layers: Params,
@@ -1083,9 +1086,11 @@ class Transformer:
             chunk = input_ids.shape[0] // self.pp_size
             target_ids = lax.dynamic_slice_in_dim(
                 target_ids, lax.axis_index("pp") * chunk, chunk, axis=0)
-        token_loss, valid = self._token_ce(logits, target_ids, mode)
-        loss_sum = jnp.sum(jnp.where(valid, token_loss, 0.0))
-        count = jnp.sum(valid.astype(jnp.float32))
+        # the CE belongs to the head's scope (see _forward_with_aux)
+        with jax.named_scope("head_loss"):
+            token_loss, valid = self._token_ce(logits, target_ids, mode)
+            loss_sum = jnp.sum(jnp.where(valid, token_loss, 0.0))
+            count = jnp.sum(valid.astype(jnp.float32))
         if self.pp_size > 1:
             if not pp_scatter:
                 # Fallback (batch not pp-divisible): every stage computed

@@ -25,6 +25,26 @@ from .trace import SpanTracer
 from .watchdog import HangWatchdog
 
 
+class LoopSpans:
+    """What `train()` hands, as their `tracer`, to the modules that time
+    their own work (`data/prefetch.Prefetcher`, `runtime/mesh.batch_feeder`,
+    `training/checkpoint.AsyncCheckpointer`): `span(name, cat, **args)` as
+    `SpanTracer` has it. On the thread that made this object (the loop's) a
+    span goes through `TrainObserver.span`: timeline, goodput bucket `cat`,
+    flight ring, watchdog. On any other thread (the prefetch worker, the
+    checkpoint writer) it goes to the timeline alone: that wall time runs
+    beside the loop's and is not the loop's to account."""
+
+    def __init__(self, observer: "TrainObserver"):
+        self._observer = observer
+        self._loop_thread = threading.get_ident()
+
+    def span(self, name: str, cat: Optional[str] = None, **args):
+        if threading.get_ident() != self._loop_thread:
+            return self._observer.tracer.span(name, cat=cat, **args)
+        return self._observer.span(cat or name, name, **args)
+
+
 class TrainObserver:
     def __init__(self, log_dir: str, writer=None, trace: bool = True,
                  watchdog_secs: float = 0.0, sentinel: bool = True,
@@ -66,6 +86,7 @@ class TrainObserver:
             flight=self.flight) if watchdog_secs > 0 else None)
         self._closed = False
         self._local = threading.local()
+        self.loop_spans = LoopSpans(self)
 
     @contextmanager
     def span(self, bucket: str, name: Optional[str] = None, **args):

@@ -11,6 +11,18 @@ This is the HOST timeline — what the training loop's wall clock was spent on
 DEVICE timeline for a short window. The host view is cheap enough to leave on
 for a whole run; the device view is not.
 
+The two meet in `span()`: every with-block is also a
+`jax.profiler.TraceAnnotation("prog.<name>", **args)`, so under ANY capture
+(`--profile_steps`, the duty-cycle and anomaly profilers, the benchmark's) the
+span lies on the `/host:CPU` plane, on its own thread's line, on the clock the
+device ops are stamped with — whether or not the JSONL timeline is written.
+With no capture active the annotation costs one check of an atomic.
+
+The stream is flushed at every `instant()` (the sentinel's and the watchdog's
+markers: what a post-mortem needs is on disk before the process may die),
+every `FLUSH_EVERY` events, and at `close()` — not per event: a span on the
+hot path costs one buffered write under the lock.
+
 Threads map to separate `tid` tracks (the prefetch thread and the async
 checkpoint writer show up alongside the main loop); multi-host processes map
 to `pid`, so traces from several hosts can be concatenated into one viewer.
@@ -22,26 +34,44 @@ import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation
+
+# what `span()` puts in front of a span's name on the profiler's host plane
+ANNOTATION_PREFIX = "prog."
+# events between two flushes of trace.jsonl (an `instant()` flushes at once)
+FLUSH_EVERY = 64
+
+
+def span_of(tracer, name: str, cat: Optional[str] = None, **args):
+    """`tracer.span(...)`, or nothing at all where a module that times its
+    own work was handed no tracer."""
+    if tracer is None:
+        return nullcontext()
+    return tracer.span(name, cat=cat, **args)
 
 
 class SpanTracer:
-    """Thread-safe span recorder. `enabled=False` turns every method into a
-    cheap no-op so call sites need no guards."""
+    """Thread-safe span recorder. `enabled=False` (or `log_dir=None`) writes
+    no timeline: every method but `span()` is then a cheap no-op, and
+    `span()` is only its profiler annotation, so call sites need no guards."""
 
-    def __init__(self, log_dir: str, enabled: bool = True, pid: int = 0,
-                 process_name: Optional[str] = None,
+    def __init__(self, log_dir: Optional[str], enabled: bool = True,
+                 pid: int = 0, process_name: Optional[str] = None,
                  clock: Callable[[], float] = time.perf_counter):
-        self.enabled = enabled
+        self.enabled = enabled and log_dir is not None
         self.pid = pid
         self._clock = clock
         self._t0 = clock()
         self._lock = threading.Lock()
         self._jsonl = None
+        self._unflushed = 0
         self._closed = False
         self.log_dir = log_dir
-        self._jsonl_path = os.path.join(log_dir, "trace.jsonl")
+        self._jsonl_path = (os.path.join(log_dir, "trace.jsonl")
+                            if log_dir is not None else None)
         self._process_name = process_name
         # File creation is LAZY (first emitted event): an invocation that
         # dies in argument/data validation emits nothing and therefore
@@ -67,48 +97,40 @@ class SpanTracer:
                  "tid": 0, "args": {"name": self._process_name}}) + "\n")
 
     def now(self) -> float:
-        """Clock sample for `complete()` (perf_counter seconds)."""
+        """Clock sample for `complete_span()` (perf_counter seconds)."""
         return self._clock()
 
     def _ts_us(self, t: float) -> float:
         return (t - self._t0) * 1e6
 
-    def _emit(self, ev: dict) -> None:
+    def _emit(self, ev: dict, flush: bool = False) -> None:
+        line = json.dumps(ev) + "\n"
         with self._lock:
             if self._closed:
                 return
             if self._jsonl is None:
                 self._open_locked()
-            self._jsonl.write(json.dumps(ev) + "\n")
-            self._jsonl.flush()
+            self._jsonl.write(line)
+            self._unflushed += 1
+            if flush or self._unflushed >= FLUSH_EVERY:
+                self._jsonl.flush()
+                self._unflushed = 0
 
     @contextmanager
     def span(self, name: str, cat: Optional[str] = None, **args):
-        """Record a complete event covering the with-block."""
-        if not self.enabled:
-            yield
-            return
-        t0 = self._clock()
-        try:
-            yield
-        finally:
-            self.complete(name, t0, cat=cat, **args)
-
-    def complete(self, name: str, start: float, cat: Optional[str] = None,
-                 **args) -> None:
-        """Record a complete event from an explicit `now()` start sample —
-        for call sites where a with-block does not fit (producer loops)."""
-        if not self.enabled:
-            return
-        end = self._clock()
-        ev = {"name": name, "ph": "X", "ts": self._ts_us(start),
-              "dur": (end - start) * 1e6, "pid": self.pid,
-              "tid": threading.get_ident()}
-        if cat:
-            ev["cat"] = cat
-        if args:
-            ev["args"] = args
-        self._emit(ev)
+        """The with-block as a profiler annotation `prog.<name>` carrying
+        `args` (what caused the span: `step=` in the loop, the save's step
+        on the writer thread), and, when the timeline is on, as a complete
+        event `<name>` of trace.jsonl."""
+        with TraceAnnotation(ANNOTATION_PREFIX + name, **args):
+            if not self.enabled:
+                yield
+                return
+            t0 = self._clock()
+            try:
+                yield
+            finally:
+                self.complete_span(name, t0, self._clock(), cat=cat, **args)
 
     def complete_span(self, name: str, start: float, end: float,
                       cat: Optional[str] = None, tid: Optional[int] = None,
@@ -151,7 +173,7 @@ class SpanTracer:
               "tid": threading.get_ident()}
         if args:
             ev["args"] = args
-        self._emit(ev)
+        self._emit(ev, flush=True)
 
     def counter(self, name: str, value: float) -> None:
         if not self.enabled:

@@ -197,6 +197,7 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             flops=flops, bytes_accessed=q.size * 3 * q.dtype.itemsize,
             transcendentals=t_real * t_real * bh // 2),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse
 
@@ -416,6 +417,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
                 compiler_params=pltpu.CompilerParams(
                     dimension_semantics=("parallel",)),
                 interpret=interpret,
+                name="flash_bwd",
             )(q, k, v, do, lse, delta)
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
@@ -436,6 +438,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
+            name="flash_bwd",
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -457,6 +460,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid dim 2 runs (group x num_qb) sequential steps per kv block;
@@ -493,6 +497,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1033,6 +1038,7 @@ def _block_fwd_call(qf, kf, vf, qp, kp, block_q, block_k, hq, hkv,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd_block",
     )(qf, kf, vf, qp[:, :, None], kp[:, None, :])
     return o, lse
 
@@ -1078,6 +1084,7 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, interpret, res, cts):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq_block",
     )(qf, kf, vf, qp[:, :, None], kp[:, None, :], do, lse, delta, dlse)
 
     num_qb = c["num_qb"]
@@ -1109,6 +1116,7 @@ def _block_attn_vjp_bwd(block_q, block_k, hq, hkv, interpret, res, cts):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv_block",
     )(qf, kf, vf, qp[:, None, :], kp[:, :, None], do, lse, delta, dlse)
 
     zero_pos = lambda p: np.zeros(p.shape, jax.dtypes.float0)

@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import MeshConfig
+from ..obs.trace import span_of
 
 DP_AXIS = "dp"
 PP_AXIS = "pp"
@@ -84,9 +85,10 @@ def named(mesh: Mesh, *spec) -> NamedSharding:
     return NamedSharding(mesh, P(*spec))
 
 
-def batch_feeder(mesh: Mesh):
+def batch_feeder(mesh: Mesh, tracer=None):
     """Host-batch -> device-array function for (b, t)-shaped (or leading-
-    stacked) token batches, multi-host-aware.
+    stacked) token batches, multi-host-aware: `feed(x)` -> array,
+    `feed(x, y, ...)` -> tuple of arrays.
 
     Single process: `jnp.asarray` (jit reshards per the step's in-specs).
     Multi-process: a host-local full batch cannot be passed to a jit whose
@@ -94,16 +96,29 @@ def batch_feeder(mesh: Mesh):
     assembled via `jax.make_array_from_callback` — every process holds the
     identical (same-seed) host batch and contributes the shards it owns.
     The leading dims beyond (b, t) (steps_per_dispatch / grad-accum
-    stacking) stay unsharded, matching the jnp.asarray path."""
+    stacking) stay unsharded, matching the jnp.asarray path.
+
+    `tracer`: optional obs.SpanTracer (anything with its `span`) — each
+    call is one "h2d" span on the caller's thread; keyword arguments of the
+    call (the loop's `step=`) go on the span. `feed.bytes_fed` counts the
+    host bytes handed over, with or without a tracer."""
     import jax.numpy as jnp
+
     if jax.process_count() == 1:
-        return jnp.asarray
+        put = jnp.asarray
+    else:
+        def put(x):
+            spec = P(*([None] * (x.ndim - 2)), (DP_AXIS, EP_AXIS), CP_AXIS)
+            return jax.make_array_from_callback(
+                x.shape, NamedSharding(mesh, spec), lambda idx: x[idx])
 
-    def feed(x):
-        spec = P(*([None] * (x.ndim - 2)), (DP_AXIS, EP_AXIS), CP_AXIS)
-        return jax.make_array_from_callback(
-            x.shape, NamedSharding(mesh, spec), lambda idx: x[idx])
+    def feed(*xs, **span_args):
+        with span_of(tracer, "h2d", cat="h2d", **span_args):
+            out = tuple(put(x) for x in xs)
+        feed.bytes_fed += sum(x.nbytes for x in xs)
+        return out[0] if len(out) == 1 else out
 
+    feed.bytes_fed = 0
     return feed
 
 

@@ -42,8 +42,8 @@ from .ops.attention import resolve_attention_impl
 from .runtime.compile_cache import compile_cache_stats, enable_compile_cache
 from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
                            process_info)
-from .training.checkpoint import (latest_step, load_checkpoint,
-                                  save_checkpoint)
+from .training.checkpoint import (AsyncCheckpointer, latest_step,
+                                  load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
@@ -52,14 +52,6 @@ from .training.optim import init_adam_state, schedule_lr
 from .training.train_step import (build_grad_accum_step, build_train_step,
                                   build_train_step_multi, resolve_zero_stage)
 from .training.zero import zero1_moment_shardings
-
-
-def _map_moments(opt_state, fn):
-    """Apply `fn` (a params-tree transform, e.g. model.to_canonical) to the
-    Adam moments — they shard/reshape exactly like their params. Identity
-    transforms return the state unchanged."""
-    return opt_state.__class__(step=opt_state.step, mu=fn(opt_state.mu),
-                               nu=fn(opt_state.nu))
 
 
 def get_train_args(argv=None) -> argparse.Namespace:
@@ -663,7 +655,7 @@ def train(args: argparse.Namespace) -> dict:
         if args.resume:
             if nproc > 1:
                 # Only process 0's host is assumed to hold the checkpoint files
-                # (it is the only writer — see schedule_save). It loads and
+                # (it is the only writer — see gather_to_host). It loads and
                 # broadcasts host trees; every process supplies its freshly
                 # initialised tree as the shape/dtype template.
                 last = latest_step(args.save_dir) if is_main else None
@@ -701,7 +693,7 @@ def train(args: argparse.Namespace) -> dict:
                             f"{args.tp_size} --dp {args.dp_size} --zero "
                             f"{zero_stage} --model <preset>")
                     tmpl_p = model.to_canonical(params)
-                    tmpl_o = _map_moments(opt_state, model.to_canonical)
+                    tmpl_o = map_moments(opt_state, model.to_canonical)
                     if is_main:
                         with observer.span("checkpoint", "restore", step=last):
                             ck_p, ck_o, start_step = load_checkpoint(
@@ -715,7 +707,7 @@ def train(args: argparse.Namespace) -> dict:
                     start_step = int(multihost_utils.broadcast_one_to_all(
                         np.int64(start_step)))
                     params = model.from_canonical(ck_p)
-                    opt_state = _map_moments(ck_o, model.from_canonical)
+                    opt_state = map_moments(ck_o, model.from_canonical)
                     print(f"resumed from iter {start_step} in {args.save_dir} "
                           f"(broadcast from process 0)")
             else:
@@ -738,8 +730,8 @@ def train(args: argparse.Namespace) -> dict:
                         if opt_state is None:
                             opt_state = init_adam_state(params)
                         else:
-                            opt_state = _map_moments(opt_state,
-                                                     model.from_canonical)
+                            opt_state = map_moments(opt_state,
+                                                    model.from_canonical)
                         print(f"resumed from iter {start_step} in "
                               f"{args.save_dir}")
                     else:
@@ -836,7 +828,8 @@ def train(args: argparse.Namespace) -> dict:
 
         # single-process: jnp.asarray; multi-host: global-array assembly from
         # per-process shards (every process iterates the identical dataloader)
-        feed = batch_feeder(mesh)
+        # (the "h2d" span is the feeder's own: runtime/mesh.py)
+        feed = batch_feeder(mesh, tracer=observer.loop_spans)
         # profile a window shortly after start so compile+layout churn is over
         profiler = ProfilerTrace(logs_dir, start_step=start_step + 3,
                                  num_steps=args.profile_steps)
@@ -972,7 +965,7 @@ def train(args: argparse.Namespace) -> dict:
         _last_poll = [None]
 
         def shutdown_agreed(step=None) -> bool:
-            """Cross-host-consistent shutdown decision. schedule_save runs a
+            """Cross-host-consistent shutdown decision. A multi-host save runs a
             collective in multi-host mode, so acting on a process-local signal
             would send one process into an all-gather the others never enter
             (deadlock). Every process contributes its local flag and the
@@ -992,77 +985,59 @@ def train(args: argparse.Namespace) -> dict:
                 _last_poll[0] = step
             return bool(np.max(multihost_utils.process_allgather(
                 np.int32(shutdown.requested))))
-        last_saved = start_step
-        pending_save = None  # at most one async checkpoint write in flight
         replicate_fn = []  # lazily-built jitted all-gather for multi-host saves
 
-        def join_save():
-            nonlocal pending_save
-            if pending_save is not None:
-                with observer.span("checkpoint", "join_save",
-                                   step=pending_save.step):
-                    paths = pending_save.join()
-                print(f"saved checkpoint iter {pending_save.step}: {paths[0]}" +
-                      (f" (+{len(paths)-1} shards)" if len(paths) > 1 else ""))
-                pending_save = None
+        def gather_to_host(save_params, save_opt):
+            """AsyncCheckpointer's multi-host hook. Cross-host shards are
+            not addressable from this process, so `jax.device_get` inside
+            the writer would fail. All-gather to every host (XLA collective
+            — all processes must participate), then only process 0 touches
+            the filesystem. Params and the two Adam moments gather
+            SEQUENTIALLY and land in host RAM one at a time, so peak extra
+            device memory is one param-tree — still O(full model) per
+            device transiently, which under --zero1 means saves need that
+            much headroom (per-host shard files would remove even that;
+            not needed at this framework's scales)."""
+            if not replicate_fn:
+                replicate_fn.append(jax.jit(
+                    lambda t: t, out_shardings=jax.tree.map(
+                        lambda _: jax.sharding.NamedSharding(
+                            mesh, jax.sharding.PartitionSpec()),
+                        save_params)))
 
-        def schedule_save(step):
-            with observer.span("checkpoint", "schedule_save", step=step):
-                _schedule_save(step)
+            def gather_host(tree):
+                rep = replicate_fn[0](tree)
+                if is_main:
+                    return jax.device_get(rep)
+                jax.block_until_ready(rep)  # serialize; buffers free on drop
+                return None
 
-        def _schedule_save(step):
-            nonlocal pending_save, last_saved
-            avg = float(accum_loss) / (step - start_step)
-            join_save()  # bound in-flight async writes to one
-            save_params = model.to_canonical(params)
-            save_opt = _map_moments(opt_state, model.to_canonical)
-            if nproc > 1:
-                # Cross-host shards are not addressable from this process, so
-                # `jax.device_get` inside the writer would fail. All-gather to
-                # every host (XLA collective — all processes must participate),
-                # then only process 0 touches the filesystem. Params and the two
-                # Adam moments gather SEQUENTIALLY and land in host RAM one at a
-                # time, so peak extra device memory is one param-tree — still
-                # O(full model) per device transiently, which under --zero1
-                # means saves need that much headroom (per-host shard files
-                # would remove even that; not needed at this framework's
-                # scales).
-                if not replicate_fn:
-                    replicate_fn.append(jax.jit(
-                        lambda t: t, out_shardings=jax.tree.map(
-                            lambda _: jax.sharding.NamedSharding(
-                                mesh, jax.sharding.PartitionSpec()),
-                            save_params)))
+            host_p = gather_host(save_params)
+            host_mu = gather_host(save_opt.mu)
+            host_nu = gather_host(save_opt.nu)
+            if not is_main:
+                return None
+            return host_p, save_opt.__class__(
+                step=np.asarray(int(jax.device_get(save_opt.step)), np.int32),
+                mu=host_mu, nu=host_nu)
 
-                def gather_host(tree):
-                    rep = replicate_fn[0](tree)
-                    if is_main:
-                        return jax.device_get(rep)
-                    jax.block_until_ready(rep)  # serialize; buffers free on drop
-                    return None
+        def print_saved(step, paths):
+            print(f"saved checkpoint iter {step}: {paths[0]}" +
+                  (f" (+{len(paths)-1} shards)" if len(paths) > 1 else ""))
 
-                host_p = gather_host(save_params)
-                host_mu = gather_host(save_opt.mu)
-                host_nu = gather_host(save_opt.nu)
-                if not is_main:
-                    last_saved = step
-                    return
-                save_params = host_p
-                save_opt = save_opt.__class__(
-                    step=np.asarray(int(jax.device_get(save_opt.step)), np.int32),
-                    mu=host_mu, nu=host_nu)
-            pending_save = save_checkpoint(
-                args.save_dir, step, avg, save_params,
-                model.canonical_specs(), args.tp_size, save_opt,
-                reserve_last_n=args.reserve_last_n_ckpts,
-                async_write=True, tracer=observer.tracer,
-                zero_stage=zero_stage, mesh_axes=mesh)
-            last_saved = step
+        # the periodic save: loss sync, at most one async write in flight,
+        # snapshot, writer thread — and their spans (training/checkpoint.py)
+        checkpointer = AsyncCheckpointer(
+            args.save_dir, model, args.tp_size, start_step=start_step,
+            reserve_last_n=args.reserve_last_n_ckpts, zero_stage=zero_stage,
+            mesh_axes=mesh, tracer=observer.loop_spans,
+            gather=gather_to_host if nproc > 1 else None,
+            on_saved=print_saved)
 
         def shutdown_save(step):
             """Shared by both shutdown exits (per-batch poll and post-loop)."""
-            if step > last_saved:
-                schedule_save(step)
+            if step > checkpointer.last_saved:
+                checkpointer.save(step, accum_loss, params, opt_state)
             print(f"shutdown requested: checkpointed at step {step}; "
                   f"restart with --resume to continue")
 
@@ -1086,13 +1061,12 @@ def train(args: argparse.Namespace) -> dict:
                                   else 0),
                     depth=2,
                     transform=stack_window if multi else (lambda bufs: bufs[0]),
-                    tracer=observer.tracer)
-                windows = iter(prefetcher)
+                    tracer=observer.loop_spans)
                 while True:
                     wait_before = prefetcher.wait_time
                     try:
-                        with observer.span("data_wait"):
-                            window = next(windows)
+                        # (the "data_wait" span is the prefetcher's own)
+                        window = prefetcher.pull(step=n)
                     except StopIteration:
                         break
                     # Shutdown poll once per WINDOW: buffered/prefetched batches
@@ -1128,10 +1102,9 @@ def train(args: argparse.Namespace) -> dict:
                     # keeps the real shape for the token accounting below
                     w_feed = (_bucket_window(window, t_bucket) if t_bucket
                               else window)
-                    with observer.span("h2d"):
-                        ids = feed(w_feed["input_ids"])
-                        tgt = feed(w_feed["target_ids"])
-                        pos = feed(w_feed["position_ids"])
+                    ids, tgt, pos = feed(w_feed["input_ids"],
+                                         w_feed["target_ids"],
+                                         w_feed["position_ids"], step=n)
                     params, opt_state, out = run_step(params, opt_state, ids,
                                                       tgt, pos, steps_in, n)
                     if multi:
@@ -1237,7 +1210,7 @@ def train(args: argparse.Namespace) -> dict:
                         # raises TrainingHealthError through the finally below
                         observer.check_health(n, interval_loss, gnorm)
                     if n // args.save_interval > prev_n // args.save_interval:
-                        schedule_save(n)
+                        checkpointer.save(n, accum_loss, params, opt_state)
                     if n >= args.max_steps:
                         done = True
                         break
@@ -1251,7 +1224,7 @@ def train(args: argparse.Namespace) -> dict:
             # code polled after every step and caught this window). The
             # n > last_saved guard keeps a signal the poll already handled from
             # printing the shutdown message twice.
-            if n > last_saved and shutdown_agreed():
+            if n > checkpointer.last_saved and shutdown_agreed():
                 shutdown_save(n)
         finally:
             # On ANY exit (including a raising step): stop the prefetch thread
@@ -1264,7 +1237,7 @@ def train(args: argparse.Namespace) -> dict:
             if prefetcher is not None:
                 prefetcher.close()
             shutdown.restore()
-            join_save()
+            checkpointer.join()
             # duty profiler before the observer/writer: an open capture
             # window finalises + parses into its profile_attribution
             # event while the jsonl stream is still writable

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..obs.trace import span_of
 from .optim import AdamState
 
 CKPT_RE = re.compile(r"tprank-(\d+)_iter-(\d+)_loss-(.+?)\.npz$")
@@ -47,8 +48,11 @@ class AsyncSaveHandle:
     are on disk and returns their paths (re-raising any writer exception).
     """
 
-    def __init__(self, step: int):
+    def __init__(self, step: int, stats: Dict[str, int]):
         self.step = step
+        # what the writer moved, filled in as it goes: `bytes_moved` over
+        # D2H, `files` and `bytes_written` on disk
+        self.stats = stats
         self._paths: List[str] = []
         self._error: Optional[BaseException] = None
         self._thread: Optional[threading.Thread] = None
@@ -136,9 +140,12 @@ def save_checkpoint(save_dir: str, step: int, avg_loss: float, params: Any,
     (full params + both Adam moments over D2H — ~1.5 GB at the 124M-param
     BASELINE config) from the hot loop.
 
-    `tracer`: optional obs.SpanTracer — the D2H+slice+write work records a
-    "checkpoint_write" span on whichever thread performs it (the async
-    writer shows up as its own track in the timeline).
+    `tracer`: optional obs.SpanTracer — the device->host transfer records
+    a "ckpt.d2h" span and the slicing, npz writes and pruning a
+    "ckpt.write" span, both carrying the save's `step`, on whichever thread
+    performs them (the async writer shows up as its own track in the
+    timeline); the async path's on-device copy is a "ckpt.snapshot" span on
+    the caller's thread.
 
     `mesh_axes`: the saving mesh (a live Mesh, or (axis, size) pairs) for
     the ``__layout__`` stamp — mesh shape + per-leaf PartitionSpec + zero
@@ -153,22 +160,29 @@ def save_checkpoint(save_dir: str, step: int, avg_loss: float, params: Any,
                          else (("tp", tp_size),), specs,
                          zero_stage=zero_stage)
 
+    stats: Dict[str, int] = {}
+
     def write(params, opt_state) -> List[str]:
-        t0 = tracer.now() if tracer is not None else None
-        paths = _write(params, opt_state)
-        if tracer is not None:
-            tracer.complete("checkpoint_write", t0, cat="checkpoint",
-                            step=step, files=len(paths))
+        with span_of(tracer, "ckpt.d2h", cat="checkpoint", step=step):
+            params_np = _get_leafwise(params)
+            moments_np = (None if opt_state is None else
+                          (_get_leafwise(opt_state.mu),
+                           _get_leafwise(opt_state.nu)))
+            stats["bytes_moved"] = sum(
+                x.nbytes for x in jax.tree.leaves((params_np, moments_np)))
+        with span_of(tracer, "ckpt.write", cat="checkpoint", step=step):
+            paths = _write(params_np, moments_np)
+            stats["files"] = len(paths)
+            stats["bytes_written"] = sum(os.path.getsize(p) for p in paths)
         return paths
 
-    def _write(params, opt_state) -> List[str]:
-        params_np = _get_leafwise(params)
+    def _write(params_np, moments_np) -> List[str]:
         flat_p = _flatten(params_np, "param")
         flat_s = _flatten(specs, "param")
         flat_opt: Dict[str, Any] = {}
-        if opt_state is not None:
-            flat_opt.update(_flatten(_get_leafwise(opt_state.mu), "mu"))
-            flat_opt.update(_flatten(_get_leafwise(opt_state.nu), "nu"))
+        if moments_np is not None:
+            flat_opt.update(_flatten(moments_np[0], "mu"))
+            flat_opt.update(_flatten(moments_np[1], "nu"))
             # moments shard exactly like their params
             flat_s.update({k.replace("param", "mu", 1): v for k, v in
                            _flatten(specs, "param").items()})
@@ -206,11 +220,95 @@ def save_checkpoint(save_dir: str, step: int, avg_loss: float, params: Any,
     if not async_write:
         return write(params, opt_state)
 
-    snap_p = _SNAPSHOT(params)
-    snap_o = _SNAPSHOT(opt_state) if opt_state is not None else None
-    handle = AsyncSaveHandle(step)
+    with span_of(tracer, "ckpt.snapshot", cat="checkpoint", step=step):
+        snap_p = _SNAPSHOT(params)
+        snap_o = _SNAPSHOT(opt_state) if opt_state is not None else None
+    handle = AsyncSaveHandle(step, stats)
     handle._run(lambda: write(snap_p, snap_o))
     return handle
+
+
+def map_moments(opt_state: AdamState, fn) -> AdamState:
+    """Apply `fn` (a params-tree transform, e.g. model.to_canonical) to the
+    Adam moments — they shard/reshape exactly like their params. Identity
+    transforms return the state unchanged."""
+    return opt_state.__class__(step=opt_state.step, mu=fn(opt_state.mu),
+                               nu=fn(opt_state.nu))
+
+
+class AsyncCheckpointer:
+    """The train loop's periodic save, at most one write in flight:
+    `save(step, accum_loss, params, opt_state)` every save interval,
+    `join()` before exit. `train()` and the benchmark's `train_ckpt` runner
+    both save through here, so what one times is what the other does.
+
+    A save, on the caller's thread: sync the running loss sum to the host
+    for the file name's average (span "ckpt.loss_sync": this drains the
+    device), join the previous write ("ckpt.join_prev"), bring params and
+    moments to the checkpoint's canonical layout, run `gather` if given,
+    and start `save_checkpoint(async_write=True)`, whose on-device copy is
+    "ckpt.snapshot". The writer thread then records "ckpt.d2h" and
+    "ckpt.write". All five carry the save's `step`, cat "checkpoint".
+
+    `gather(params, opt_state) -> (params, opt_state) | None`: the
+    multi-host hook. Cross-host shards are not addressable from one
+    process, so `train()` all-gathers to host arrays there (a collective:
+    every process calls `save`) and returns None on every process but the
+    one that writes. `on_saved(step, paths)` is called at each join.
+    `bytes_moved`, `bytes_written`, `files`, `saves` count what the joined
+    writes did."""
+
+    def __init__(self, save_dir: str, model, tp_size: int, *,
+                 start_step: int = 0, reserve_last_n: int = -1,
+                 zero_stage: int = 0, mesh_axes=None, tracer=None,
+                 gather=None, on_saved=None):
+        self.save_dir = save_dir
+        self._model = model
+        self._start_step = start_step
+        self._gather = gather
+        self._on_saved = on_saved
+        self._tracer = tracer
+        self._save_args = dict(
+            tp_size=tp_size, reserve_last_n=reserve_last_n,
+            zero_stage=zero_stage, mesh_axes=mesh_axes, tracer=tracer)
+        self._pending: Optional[AsyncSaveHandle] = None
+        self.last_saved = start_step
+        self.saves = self.files = self.bytes_moved = self.bytes_written = 0
+
+    def _span(self, name: str, step: int):
+        return span_of(self._tracer, name, cat="checkpoint", step=step)
+
+    def save(self, step: int, accum_loss, params, opt_state) -> None:
+        with self._span("ckpt.loss_sync", step):
+            avg = float(accum_loss) / (step - self._start_step)
+        self.join()  # bound in-flight async writes to one
+        params = self._model.to_canonical(params)
+        opt_state = map_moments(opt_state, self._model.to_canonical)
+        self.last_saved = step
+        if self._gather is not None:
+            with self._span("ckpt.gather", step):
+                gathered = self._gather(params, opt_state)
+            if gathered is None:
+                return
+            params, opt_state = gathered
+        self._pending = save_checkpoint(
+            self.save_dir, step, avg, params, self._model.canonical_specs(),
+            opt_state=opt_state, async_write=True, **self._save_args)
+
+    def join(self) -> Optional[List[str]]:
+        """Wait for the write in flight, if any; its paths."""
+        pending, self._pending = self._pending, None
+        if pending is None:
+            return None
+        with self._span("ckpt.join_prev", pending.step):
+            paths = pending.join()
+        self.saves += 1
+        self.files += pending.stats["files"]
+        self.bytes_moved += pending.stats["bytes_moved"]
+        self.bytes_written += pending.stats["bytes_written"]
+        if self._on_saved is not None:
+            self._on_saved(pending.step, paths)
+        return paths
 
 
 def prune_checkpoints(save_dir: str, reserve_last_n: int, tp_size: int) -> None:

@@ -93,12 +93,22 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
 
     def step(params, opt_state: AdamState, input_ids, target_ids,
              position_ids):
-        loss, grads = grad_fn(params, input_ids, target_ids, position_ids)
+        # The scopes are what a device trace's `op_name` can say that JAX
+        # cannot know (jvp / transpose / rematted_computation it adds
+        # itself): benchmark/lib/program_trace.py splits the step by them.
+        with jax.named_scope("loss_and_grad"):
+            loss, grads = grad_fn(params, input_ids, target_ids,
+                                  position_ids)
         # grad norm: optim.global_norm — the SAME reduction the clipper
         # uses, so the logged/sentinel-watched norm equals the one
         # acted on (and XLA can CSE the two when both are present)
-        out = (loss, global_norm(grads)) if with_grad_norm else loss
-        params, opt_state = adam_update(ocfg, params, grads, opt_state)
+        if with_grad_norm:
+            with jax.named_scope("grad_norm"):
+                out = (loss, global_norm(grads))
+        else:
+            out = loss
+        with jax.named_scope("optimizer"):
+            params, opt_state = adam_update(ocfg, params, grads, opt_state)
         return params, opt_state, out
 
     return step
@@ -274,7 +284,8 @@ def build_grad_accum_step(model: Transformer, mesh, ocfg: OptimizerConfig,
 
         def body(acc, batch):
             loss_sum, g_sum = acc
-            loss, g = grad_fn(params, *batch)
+            with jax.named_scope("loss_and_grad"):
+                loss, g = grad_fn(params, *batch)
             return (loss_sum + loss, jax.tree.map(jnp.add, g_sum, g)), None
 
         (loss_sum, g_sum), _ = jax.lax.scan(
@@ -283,9 +294,13 @@ def build_grad_accum_step(model: Transformer, mesh, ocfg: OptimizerConfig,
         a = input_ids.shape[0]
         grads = jax.tree.map(lambda x: x / a, g_sum)
         # the norm of the MEAN gradient — the quantity Adam actually sees
-        out = ((loss_sum / a, global_norm(grads)) if with_grad_norm
-               else loss_sum / a)
-        params, opt_state = adam_update(ocfg, params, grads, opt_state)
+        if with_grad_norm:
+            with jax.named_scope("grad_norm"):
+                out = (loss_sum / a, global_norm(grads))
+        else:
+            out = loss_sum / a
+        with jax.named_scope("optimizer"):
+            params, opt_state = adam_update(ocfg, params, grads, opt_state)
         return params, opt_state, out
 
     out_spec = (P(), P()) if with_grad_norm else P()

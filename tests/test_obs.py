@@ -39,8 +39,8 @@ def test_tracer_emits_valid_chrome_trace(tmp_path):
     done = threading.Event()
 
     def producer():
-        t0 = tr.now()
-        tr.complete("prefetch_window", t0, cat="data_prep")
+        with tr.span("prefetch_window", cat="data_prep"):
+            pass
         done.set()
 
     threading.Thread(target=producer).start()
@@ -286,8 +286,26 @@ def test_train_run_emits_trace_goodput_and_cost_analysis(token_corpus,
     cats = {e.get("cat") for e in evs}
     assert {"compile", "data_wait", "h2d", "step", "checkpoint",
             "data_prep"} <= cats
-    # the async checkpoint writer traced on its own thread
-    assert any(e["name"] == "checkpoint_write" for e in evs)
+    # the spans live in the modules that do the work, once each per cause,
+    # and every one of them says which step caused it
+    loop_tid = next(e["tid"] for e in evs if e["name"] == "step")
+    for name in ("data_wait", "h2d", "ckpt.loss_sync", "ckpt.snapshot",
+                 "ckpt.join_prev"):
+        mine = [e for e in evs if e["name"] == name]
+        assert mine and all(e["tid"] == loop_tid for e in mine), name
+        assert all("step" in e["args"] for e in mine), name
+    assert len([e for e in evs if e["name"] == "h2d"]) == 30
+    # the async checkpoint writer traced on its own thread, tied to its save
+    snapshots = [e["args"]["step"] for e in evs
+                 if e["name"] == "ckpt.snapshot"]
+    assert snapshots == [10, 20, 30]
+    for name in ("ckpt.d2h", "ckpt.write"):
+        mine = [e for e in evs if e["name"] == name]
+        assert [e["args"]["step"] for e in mine] == snapshots, name
+        assert all(e["tid"] != loop_tid and e["cat"] == "checkpoint"
+                   for e in mine)
+    assert not any(e["name"] in ("checkpoint_write", "schedule_save",
+                                 "join_save") for e in evs)
 
     # -- metrics.jsonl: goodput summary + cost analysis + grad-norm scalars
     recs = [json.loads(l)
