@@ -457,7 +457,8 @@ def build_model(args, cfg, tp: int, remat: str = None, attn_impl: str = "auto",
               sequence_parallel=args.sequence_parallel,
               tp_overlap=args.tp_overlap)
     if remat is not None:
-        kw["remat"] = REMAT_CHOICES[remat]
+        # a key of REMAT_CHOICES, or the ladder rung `--remat auto` chose
+        kw["remat"] = REMAT_CHOICES.get(remat, remat)
     if args.family == "gpt2":
         from distributed_pytorch_from_scratch_tpu.models.gpt2 import (
             GPT2Transformer)
@@ -1776,7 +1777,8 @@ def main(argv=None):
         args.remat = select_remat(cfg, default_batch(args),
                                   args.seqlen or cfg.maxlen,
                                   tp=tp, world=args.dp * tp,
-                                  zero_stage=args.zero, dp=args.dp)
+                                  zero_stage=args.zero, dp=args.dp,
+                                  family=args.family)
     if (args.decode or args.breakdown or args.serving or args.fleet
             or args.reshard):
         if args.introspect and (args.decode or args.serving or args.fleet):

@@ -46,6 +46,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..config import ModelConfig, resolve_dtype
 from ..ops.attention import causal_attention
@@ -58,8 +59,9 @@ from ..parallel.norm import LayerNorm
 from ..runtime.prng import fold
 from ..ops.overlap import ag_matmul
 from ..parallel.linear import apply_column_ring_fused
-from .transformer import (NEG_INF, Transformer, remat_wrap, validate_cp,
-                          validate_pp, validate_t_real, validate_tp_overlap)
+from .transformer import (NEG_INF, Transformer, remat_wrap, resolve_remat,
+                          validate_cp, validate_pp, validate_remat, validate_t_real,
+                          validate_tp_overlap)
 
 Params = Dict[str, Any]
 
@@ -73,7 +75,9 @@ class GPT2Transformer:
     cfg: ModelConfig
     tp_size: int = 1
     attn_impl: str = "auto"
-    remat: "bool | str" = True
+    # same contract as Transformer.remat / remat_budget_gib
+    remat: "bool | str" = "auto"
+    remat_budget_gib: "float | None" = None
     # context parallelism over 'cp', Megatron SP over 'tp', and the GPipe
     # pipeline over 'pp' — all borrowed from the llama family's machinery
     # (the microbatch schedule is Transformer._pipeline_layers, family-
@@ -107,9 +111,7 @@ class GPT2Transformer:
 
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
-        if self.remat not in (True, False, "dots"):
-            raise ValueError(
-                f"remat must be True, False or 'dots', got {self.remat!r}")
+        validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(
                 f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -295,6 +297,11 @@ class GPT2Transformer:
                 q = m["wq"].apply(lp["wq"], y, dtype, input_layout=in_layout)
                 k = m["wk"].apply(lp["wk"], y, dtype, input_layout=in_layout)
                 v = m["wv"].apply(lp["wv"], y, dtype, input_layout=in_layout)
+            # REMAT_LADDER's names (models/transformer.py), as the linears
+            # return them: (b, t, heads*h), the lane-dense shape
+            q = checkpoint_name(q, "q_proj")
+            k = checkpoint_name(k, "k_proj")
+            v = checkpoint_name(v, "v_proj")
             split = lambda z: z.reshape(
                 b, t, self.num_local_heads, h).transpose(0, 2, 1, 3)
             return split(q), split(k), split(v)
@@ -303,8 +310,12 @@ class GPT2Transformer:
             x, o = args
             o = o.transpose(0, 2, 1, 3).reshape(b, t,
                                                 self.num_local_heads * h)
-            x = x + m["wo"].apply(lp["wo"], o, dtype,
-                                  output_layout=out_layout)
+            a = m["wo"].apply(lp["wo"], o, dtype, output_layout=out_layout)
+            if self.tp_size > 1:
+                # named PAST the row-linear's reduce and only where there
+                # is one (REMAT_LADDER)
+                a = checkpoint_name(a, "attn_proj")
+            x = x + a
 
             y = maybe_gather(m["ln2"].apply(lp["ln2"], x))
             if self.is_moe:
@@ -319,11 +330,11 @@ class GPT2Transformer:
                         ff, lax.axis_index("tp") * tl, tl, axis=1)
                 return x + ff, aux
             # gelu_new (tanh approximation), like GPT-2
+            fc = checkpoint_name(
+                m["fc"].apply(lp["fc"], y, dtype, input_layout=in_layout),
+                "ffn_fc")
             x = x + m["proj"].apply(lp["proj"],
-                                    jax.nn.gelu(m["fc"].apply(
-                                        lp["fc"], y, dtype,
-                                        input_layout=in_layout),
-                                        approximate=True), dtype,
+                                    jax.nn.gelu(fc, approximate=True), dtype,
                                     output_layout=out_layout)
             return x, None
 
@@ -380,7 +391,9 @@ class GPT2Transformer:
                 pos_emb, lax.axis_index("tp") * tl, tl, axis=1)
         x = (x + pos_emb).astype(dtype)
 
-        layer_fn = remat_wrap(self._layer_body, self.remat, static_argnums=(3,))
+        layer_fn = remat_wrap(
+            self._layer_body, resolve_remat(self, params, input_ids.shape),
+            static_argnums=(3,))
 
         if self.pp_size > 1:
             def stage_fn(z, layers, pos_m, live=None):

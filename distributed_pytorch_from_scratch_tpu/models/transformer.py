@@ -38,6 +38,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import IGNORE_INDEX, ModelConfig, resolve_dtype
@@ -159,25 +160,89 @@ def validate_tp_overlap(tp_overlap: str, sequence_parallel: bool,
             "collective matmul deliberately never materialises")
 
 
+# The residuals a layer's backward may keep instead of recomputing, in the
+# order they are bought: milliseconds of recompute saved per byte kept, as a
+# v5e measured them on GPT-2 medium (tp 1) and large (dp2 x tp2); PERF.md
+# section 5 has the numbers. Each name is a `checkpoint_name` tag set where
+# the tensor is made: the flash kernel's outputs in
+# ops/pallas/flash_attention.py, the rest in the layer bodies here and in
+# models/gpt2.py. A family tags what it has (`ffn_fc` is the GPT-2 MLP's,
+# `ffn_gate`/`ffn_up` the SwiGLU's), and a name nothing tags saves nothing:
+# `attn_proj`, the attention projection PAST its all-reduce, is tagged only
+# where there is a reduce (tp > 1). Keeping it takes the one collective out
+# of the recomputed forward, which is worth more per byte than anything
+# else; with tp = 1 it would buy one d x d matmul for a stack as large as
+# the layer input's, and the chip measured that as a loss. Rung k keeps the
+# names of rungs 1..k; rung 0 keeps the layer input only (full remat); the
+# top rung keeps every matmul output a layer's backward reads, which is
+# what 'dots' has always meant here. Weights gathered by ZeRO-3 are never
+# on the ladder.
+REMAT_LADDER = (
+    ("true", ()),
+    ("attn_proj", ("attn_proj",)),
+    ("ffn", ("ffn_fc", "ffn_gate", "ffn_up")),
+    ("flash", ("flash_out", "flash_lse")),
+    ("dots", ("q_proj", "k_proj", "v_proj")),
+)
+REMAT_RUNGS = tuple(name for name, _ in REMAT_LADDER)
+
+
+def remat_rung(remat) -> int:
+    """The ladder rung of a `remat` value that names one: True is rung 0,
+    a rung's name itself."""
+    if remat is True:
+        return 0
+    if isinstance(remat, str) and remat in REMAT_RUNGS:
+        return REMAT_RUNGS.index(remat)
+    raise ValueError(
+        f"remat must be True, False, 'auto' or one of {REMAT_RUNGS}, "
+        f"got {remat!r}")
+
+
+def validate_remat(remat) -> None:
+    if remat is not False and remat != "auto":
+        remat_rung(remat)
+
+
 def remat_wrap(layer_fn, remat, static_argnums=()):
     """Apply a per-layer remat policy; shared by every model family.
 
-    'dots' = checkpoint_dots saves matmul outputs; additionally pin the
-    flash kernel's o/lse residuals (tagged via checkpoint_name in
-    ops/pallas/flash_attention.py) so the backward pass never re-runs the
-    forward attention kernel. On the XLA attention path the tags don't
-    exist and the policy degrades gracefully.
+    `remat` is False (keep everything autodiff saves) or a rung of
+    REMAT_LADDER: the layer is a `jax.checkpoint` whose policy saves the
+    rung's names and recomputes the rest. Rung 0 passes no policy, so it is
+    the program `remat=True` has always been. 'auto' is resolved by the
+    caller (`resolve_remat`) before it gets here: the rung depends on the
+    shapes the layer is traced with.
     """
-    if remat == "dots":
-        policy = jax.checkpoint_policies.save_from_both_policies(
-            jax.checkpoint_policies.checkpoint_dots,
-            jax.checkpoint_policies.save_only_these_names(
-                "flash_out", "flash_lse"))
-        return jax.checkpoint(layer_fn, static_argnums=static_argnums,
-                              policy=policy)
-    if remat:
+    if remat is False:
+        return layer_fn
+    rung = remat_rung(remat)
+    if rung == 0:
         return jax.checkpoint(layer_fn, static_argnums=static_argnums)
-    return layer_fn
+    names = [n for _, ns in REMAT_LADDER[:rung + 1] for n in ns]
+    # prevent_cse=False: every layer_fn runs inside a lax.scan, whose
+    # forward and backward are separate loops, so there is nothing to CSE
+    # the recomputation with. The barrier that guards against it is a
+    # `reduce_precision` pass over each kept tensor (1.9 ms a step for
+    # `flash_out` alone on GPT-2 medium) and pins the kernel's padded
+    # layout on the stack.
+    return jax.checkpoint(
+        layer_fn, static_argnums=static_argnums, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
+def resolve_remat(model, params: Params, ids_shape):
+    """`model.remat`, with 'auto' replaced by the rung `select_remat_traced`
+    picks for the shapes this trace holds: `params` and `ids_shape` are the
+    per-shard ones (this is called inside shard_map). Nothing is compiled
+    to find out; the answer is cached per (model, shapes)."""
+    if model.remat != "auto":
+        return model.remat
+    from ..training.memory import select_remat_traced
+    count = lambda tree: sum(int(x.size) for x in jax.tree.leaves(tree))
+    b, t = ids_shape
+    return select_remat_traced(model, count(params), count(params["layers"]),
+                               int(b), int(t))
 
 
 @dataclass(frozen=True)
@@ -263,14 +328,23 @@ class Transformer:
     # HBM residuals for recompute FLOPs is the standard TPU playbook
     # (SURVEY §0 / scaling-book); the reference has no analogue (PyTorch
     # keeps all residuals and simply needs a bigger GPU).
-    #   True   — full per-layer remat (lowest memory, ~33% recompute FLOPs)
-    #   "dots" — jax.checkpoint_policies.checkpoint_dots: matmul outputs are
-    #            saved, only elementwise ops recompute (best speed that still
-    #            bounds residuals; needs flash attention or short t, since
-    #            the XLA attention path's softmax residual is O(t^2))
+    #   True   — rung 0: full per-layer remat (lowest memory, the whole
+    #            layer forward runs again in the backward)
+    #   a rung of REMAT_LADDER by name — keep that rung's named residuals
+    #            and recompute the rest; "dots", the top rung, keeps every
+    #            matmul output a layer's backward reads (needs flash
+    #            attention or short t: the XLA attention path's softmax
+    #            residual is O(t^2) and is recomputed, never kept)
     #   False  — no remat (reference behaviour; OOMs the 45M b32xt1000 run
     #            on a 16G chip)
-    remat: "bool | str" = True
+    # 'auto' (the default) keeps what the chip has room to keep: a rung of
+    # REMAT_LADDER ('true' = True, 'attn_proj', 'ffn', 'flash', 'dots'),
+    # picked while the model is traced from the per-shard shapes and the
+    # device's memory_stats (training/memory.select_remat_traced). A backend
+    # with no memory_stats (the CPU) gets rung 0 unless `remat_budget_gib`
+    # names the HBM to size against.
+    remat: "bool | str" = "auto"
+    remat_budget_gib: "float | None" = None
     # Pad-aware sequence bucketing: when the caller pads its (b, t) batch up
     # to a bucket boundary (e.g. t=1000 real tokens in a t=1024 buffer so
     # every matmul tiles cleanly on the 8x128 vector lanes AND the flash
@@ -293,9 +367,7 @@ class Transformer:
 
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
-        if self.remat not in (True, False, "dots"):
-            raise ValueError(
-                f"remat must be True, False or 'dots', got {self.remat!r}")
+        validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
         if cfg.attn_dim % tp != 0 or cfg.ffn_dim % tp != 0:
@@ -564,6 +636,12 @@ class Transformer:
                                   input_layout=in_layout)
                 v = m["wv"].apply(layer_params["wv"], y, dtype,
                                   input_layout=in_layout)
+            # REMAT_LADDER's names, as the linears return them: (b, t,
+            # heads*h), the lane-dense shape; rope and the head split are
+            # recomputed from them
+            q = checkpoint_name(q, "q_proj")
+            k = checkpoint_name(k, "k_proj")
+            v = checkpoint_name(v, "v_proj")
             # (b, t, heads*h) -> (b, heads, t, h); under grouped-query
             # attention wk/wv produce fewer heads and k/v STAY at the
             # kv-head count — every attention impl handles the grouping
@@ -580,8 +658,14 @@ class Transformer:
             x, o = args
             o = o.transpose(0, 2, 1, 3).reshape(b, t,
                                                 self.num_local_heads * h)
-            x = x + m["wo"].apply(layer_params["wo"], o, dtype,
-                                  output_layout=out_layout)
+            a = m["wo"].apply(layer_params["wo"], o, dtype,
+                              output_layout=out_layout)
+            if self.tp_size > 1:
+                # named PAST the row-linear's reduce, so keeping it drops
+                # the recomputed forward's collective with the matmul;
+                # not named where there is no reduce (REMAT_LADDER)
+                a = checkpoint_name(a, "attn_proj")
+            x = x + a
 
             # FFN sublayer: x + down(silu(gate(x)) * up(x))
             # (model.py:94-95,120) — or, with cfg.num_experts > 0,
@@ -610,6 +694,8 @@ class Transformer:
                                          input_layout=in_layout)
                 u = m["up_proj"].apply(layer_params["up_proj"], y, dtype,
                                        input_layout=in_layout)
+            g = checkpoint_name(g, "ffn_gate")
+            u = checkpoint_name(u, "ffn_up")
             x = x + m["down_proj"].apply(layer_params["down_proj"],
                                          jax.nn.silu(g) * u, dtype,
                                          output_layout=out_layout)
@@ -743,7 +829,9 @@ class Transformer:
         cos = jnp.take(cos_t, position_ids, axis=0, mode="clip")  # (b, t, head_dim)
         sin = jnp.take(sin_t, position_ids, axis=0, mode="clip")
 
-        layer_fn = remat_wrap(self._layer_body, self.remat, static_argnums=(5,))
+        layer_fn = remat_wrap(
+            self._layer_body, resolve_remat(self, params, input_ids.shape),
+            static_argnums=(5,))
 
         if self.pp_size > 1:
             def stage_fn(z, layers, cos_m, sin_m, pos_m, live=None):

@@ -182,7 +182,7 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
                     family: str = "llama") -> List[PhaseCost]:
     """Per-phase FLOPs + HBM bytes for ONE fwd+bwd+adam train step (global,
     all devices), itemised so shares can be compared against measured
-    fwd/bwd/adam times. remat in {'false','dots','true'} (CLI strings)."""
+    fwd/bwd/adam times. remat is 'false' or a REMAT_LADDER rung's name."""
     d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers
     h, hd, kd = cfg.num_heads, cfg.head_dim, cfg.kv_dim
     v = cfg.padded_vocab_size(1)
@@ -229,7 +229,16 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
     #   'false' — nothing replays
     layer_fwd_flops = sum(p.flops for p in fwd[1:6])
     layer_fwd_bytes = sum(p.bytes for p in fwd[1:6])
+    # the rungs of models/transformer.REMAT_LADDER in between keep the
+    # attention projection, then the FFN's input matmuls, then the flash
+    # outputs; the top rung keeps q/k/v too, which leaves the elementwise
+    # replay
+    ffn_in = fwd[4].flops * (ffn_mats - 1) / ffn_mats
+    kept = (fwd[3].flops, ffn_in, fwd[2].flops)
     recompute = {"true": layer_fwd_flops,
+                 "attn_proj": layer_fwd_flops - sum(kept[:1]),
+                 "ffn": layer_fwd_flops - sum(kept[:2]),
+                 "flash": layer_fwd_flops - sum(kept),
                  "dots": fwd[5].flops,
                  "false": 0.0}[str(remat)]
     recompute_bytes = (layer_fwd_bytes * recompute / layer_fwd_flops

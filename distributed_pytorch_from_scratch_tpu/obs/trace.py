@@ -45,6 +45,17 @@ ANNOTATION_PREFIX = "prog."
 FLUSH_EVERY = 64
 
 
+# The process's tracer, for code that is handed none: a model choosing its
+# remat rung while it is traced records the choice here. The newest
+# SpanTracer that writes a timeline; None before one exists and after it
+# closes.
+_current: "Optional[SpanTracer]" = None
+
+
+def current_tracer() -> "Optional[SpanTracer]":
+    return _current
+
+
 def span_of(tracer, name: str, cat: Optional[str] = None, **args):
     """`tracer.span(...)`, or nothing at all where a module that times its
     own work was handed no tracer."""
@@ -73,6 +84,9 @@ class SpanTracer:
         self._jsonl_path = (os.path.join(log_dir, "trace.jsonl")
                             if log_dir is not None else None)
         self._process_name = process_name
+        if self.enabled:
+            global _current
+            _current = self
         # File creation is LAZY (first emitted event): an invocation that
         # dies in argument/data validation emits nothing and therefore
         # must not touch — let alone rotate away — the previous run's
@@ -189,6 +203,9 @@ class SpanTracer:
         was written OR rotated in that case). Idempotent."""
         if not self.enabled:
             return None
+        global _current
+        if _current is self:
+            _current = None
         with self._lock:
             if self._closed:
                 return (os.path.join(self.log_dir, "trace.json")

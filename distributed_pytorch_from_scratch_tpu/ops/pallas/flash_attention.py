@@ -793,10 +793,9 @@ def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
     o, lse = _fwd_call(q, k, v, t_real=t_real,
                        block_q=block_q, block_k=block_k, hq=hq, hkv=hkv,
                        interpret=interpret)
-    # Name the kernel outputs so remat policies can pin them: under
-    # `Transformer(remat="dots")` the checkpoint_dots policy saves only
-    # dot_general outputs, and without these tags the backward pass would
-    # re-run the forward flash kernel just to rebuild o/lse.
+    # Name the kernel outputs so a remat policy can keep them: from the
+    # 'flash' rung of models/transformer.REMAT_LADDER the backward finds
+    # o/lse saved; below it, it re-runs the forward kernel to rebuild them.
     o = checkpoint_name(o, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")
     return o, (q, k, v, o, lse)

@@ -217,13 +217,15 @@ def get_train_args(argv=None) -> argparse.Namespace:
                    help="per-expert slot headroom; overflow tokens fall "
                         "through the residual (default 2.0)")
     g.add_argument("--remat", choices=sorted(REMAT_CHOICES) + ["auto"],
-                   default="true",
-                   help="per-layer rematerialisation: 'true' = lowest "
-                        "memory, 'dots' = fastest that still bounds "
-                        "residuals (see models/transformer.py); 'auto' = "
-                        "the fastest policy whose activation-memory "
-                        "estimate fits the chip "
-                        "(training/memory.select_remat)")
+                   default="auto",
+                   help="per-layer rematerialisation: 'auto' (default) = "
+                        "keep as many of the layer's named residuals "
+                        "(models/transformer.REMAT_LADDER) as the step's "
+                        "memory estimate says the chip has room for "
+                        "(training/memory.select_remat; rung 0 on a "
+                        "backend with no memory_stats); 'true' = rung 0, "
+                        "recompute everything; 'dots' = the top rung, "
+                        "every matmul output kept; 'false' = no remat")
     g.add_argument("--seq_bucket", type=int, default=0,
                    help="pad-aware sequence bucketing: pad each batch's "
                         "sequence dim up to a multiple of N (cleanly "
@@ -516,11 +518,11 @@ def train(args: argparse.Namespace) -> dict:
         remat_key = args.remat
         if remat_key == "auto":
             from .training.memory import select_remat
-            remat_key = select_remat(cfg, args.batch_size, maxlen,
-                                     tp=args.tp_size,
-                                     world=mesh_cfg.world_size,
-                                     zero_stage=zero_stage,
-                                     dp=args.dp_size)
+            remat_key = select_remat(
+                cfg, args.batch_size, maxlen, tp=args.tp_size,
+                world=mesh_cfg.world_size, zero_stage=zero_stage,
+                dp=args.dp_size, family=args.family,
+                sequence_parallel=args.sequence_parallel)
         t_bucket = 0
         if args.seq_bucket:
             if args.seq_bucket < 1 or args.seq_bucket % 128:
@@ -612,7 +614,7 @@ def train(args: argparse.Namespace) -> dict:
                                     pp_remat_steps=args.pp_remat_steps,
                                     pp_schedule=args.pp_schedule,
                                     pp_virtual=args.pp_virtual,
-                                    remat=REMAT_CHOICES[remat_key],
+                                    remat=REMAT_CHOICES.get(remat_key, remat_key),
                                     attn_t_real=attn_t_real)
         else:
             model = Transformer(cfg, tp_size=args.tp_size,
@@ -625,7 +627,7 @@ def train(args: argparse.Namespace) -> dict:
                             pp_remat_steps=args.pp_remat_steps,
                             pp_schedule=args.pp_schedule,
                             pp_virtual=args.pp_virtual,
-                            remat=REMAT_CHOICES[remat_key],
+                            remat=REMAT_CHOICES.get(remat_key, remat_key),
                             attn_t_real=attn_t_real)
         ocfg = OptimizerConfig(lr=args.lr, warmup_steps=args.warmup_steps,
                                max_steps=args.max_steps,

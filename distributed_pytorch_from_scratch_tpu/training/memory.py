@@ -1,31 +1,32 @@
-"""Activation-memory estimates + the per-config remat policy selector.
+"""Step-memory estimate + the selector of the remat rung.
 
-The remat ladder ('false' fastest, 'dots' bounded residuals, 'true' lowest
-memory — models/transformer.py) has so far been picked by hand per preset.
-`select_remat` picks it from an itemised activation-memory estimate against
-the chip's HBM budget, so `--remat auto` (train.py / bench.py) runs the
-fastest policy that fits and steps down only when the numbers say so. The
-estimate is deliberately conservative (a `margin` headroom for XLA temps
-and fusion scratch).
+`models/transformer.REMAT_LADDER` orders the per-layer residuals a backward
+may keep instead of recomputing. `select_remat` picks the highest rung whose
+estimated peak fits the device: it is what a model built with
+`remat="auto"` (the default) calls at trace time with the per-shard shapes
+it is traced with (`select_remat_traced`), and what `train.py --remat auto`
+calls with the ZeRO stage only it knows.
+
+`estimate_step_gib` is held to what the chip counts (`memory_stats()`:
+`peak_bytes_in_use + peak_bytes_reserved`), not to the compiler's plan: on
+a v5e the plan (`memory_analysis().temp_size_in_bytes`) charges every
+stack that lives from the forward loop to the backward loop twice, the
+runtime reserves it once (PERF.md section 5 has both columns, per rung, for
+the benchmark's two cells; tests/test_attribution.py pins them).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import functools
+import sys
+from typing import Dict, Optional
 
-# itemised per-layer residual footprint, in units of (b * t * dtype_bytes):
-#   'false' — everything autodiff saves on the flash path: layer input,
-#             2 norm outputs, q/k/v (k/v at the kv width), rope'd q/k,
-#             flash o + attn-proj input, wo output, gate/up/silu*up, down
-#             output  ->  ~9d + 4kd + 3f per token
-#   'dots'  — matmul outputs + the pinned flash o/lse only: q/k/v, o,
-#             wo out, gate/up, down out  ->  ~4d + 2kd + 2f
-#   'true'  — the layer-boundary carry only  ->  d
-_LAYER_UNITS = {
-    "false": lambda d, kd, f: 9 * d + 4 * kd + 3 * f,
-    "dots": lambda d, kd, f: 4 * d + 2 * kd + 2 * f,
-    "true": lambda d, kd, f: d,
-}
+GIB = 1024 ** 3
+
+# Of (limit - reserve), the share the chosen rung's estimate may take. The
+# estimate has read within 1% of the chip at every rung measured in the
+# benchmark's two cells (PERF.md section 5).
+MARGIN = 0.93
 
 
 def zero_state_bytes_per_param(zero_stage: int, dp: int,
@@ -65,54 +66,141 @@ def zero_state_bytes_per_param(zero_stage: int, dp: int,
     return 16.0 / dp + extra
 
 
-def estimate_step_gib(cfg, batch: int, seqlen: int, remat: str,
-                      tp: int = 1, world: int = 1,
-                      dtype_bytes: int = 2, zero_stage: int = 0,
-                      dp: int = 1) -> float:
-    """Peak-HBM estimate (GiB, per device) for one fwd+bwd+adam train step.
-
-    Fixed state: params + grads (f32) + 2 Adam moments (f32) — 16 bytes
-    per parameter un-sharded, shrunk by the ZeRO ladder per
-    `zero_state_bytes_per_param` (stage 1 moments/dp, stage 2 +grads/dp,
-    stage 3 everything/dp + the gathered working set) — replicated over tp
-    for the norm/embed parts but sharded for the big matrices:
-    approximated as P * state_bytes / max(tp, 1) + 10% for the replicated
-    remainder. (Pre-ZeRO-ladder versions of this estimate ignored
-    optimizer sharding entirely, overestimating every --zero1 run by
-    8 x P x (1 - 1/dp) bytes; `--remat auto` now sees the real budget.)
-    Activations shard over tp (the t or head dim); the batch shards over
-    dp/ep, folded into `world / tp`.
-    """
-    remat = str(remat).lower()
-    if remat not in _LAYER_UNITS:
-        raise ValueError(f"remat must be one of {sorted(_LAYER_UNITS)}, "
-                         f"got {remat!r}")
+def family_num_params(cfg, family: str = "llama") -> int:
+    """Parameters of `cfg` as `family` builds it. The llama family is
+    `cfg.num_params()` (SwiGLU, untied head); GPT-2 has a two-matrix MLP,
+    a position table, LayerNorm biases and a tied head."""
+    if family == "llama":
+        return cfg.num_params()
+    if family != "gpt2":
+        raise ValueError(f"unknown model family {family!r}")
     d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers
-    kd = cfg.kv_dim
+    layer = 4 * d * d + 4 * d + 2 * d * f + f + d + 4 * d
+    if cfg.num_experts:
+        layer = (4 * d * d + 4 * d + 4 * d
+                 + cfg.num_experts * 3 * d * f + d * cfg.num_experts)
+    return cfg.vocab_size * d + cfg.maxlen * d + L * layer + 2 * d
+
+
+def _rung_index(remat) -> Optional[int]:
+    """Ladder index of a remat value or CLI key; None for no remat."""
+    from ..models.transformer import remat_rung
+    if remat is False or str(remat).lower() == "false":
+        return None
+    return remat_rung(remat if remat is True else str(remat).lower())
+
+
+def step_bytes(remat, *, param_count: float, layer_param_count: float,
+               b: int, t: int, d: int, kd: int, f: int, heads: int,
+               head_dim: int, layers: int, vocab: int, tp: int = 1,
+               dtype_bytes: int = 2, ffn_inputs: int = 2,
+               sequence_parallel: bool = False,
+               state_bytes_per_param: float = 16.0,
+               grad_bytes_per_param: float = 4.0) -> Dict[str, float]:
+    """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
+
+    Everything is PER DEVICE: `param_count` / `layer_param_count` are this
+    device's parameters (all, and those of the stacked layers), `b` the
+    sequences of its data shard, `d`/`kd`/`f` the model's full widths
+    (sharded here by `tp`), `layers` the layers of its pipeline stage.
+
+    resident  params and Adam moments (and ZeRO's share of them): what
+              `peak_bytes_in_use` reads, and what a snapshot doubles
+    grads     the f32 gradient tree, live from the backward to Adam
+    cast      the stacked layer weights in the compute dtype (the compiler
+              hoists the casts out of the layer loops)
+    stacks    the layer input every rung keeps, plus the rung's residuals,
+              L of each, at their logical sizes (the chip's count: the
+              stack of `flash_out` is not kept in the kernel's layout, which
+              pads a head of 64 to the 128 lanes)
+    head      logits in f32 and once more in the compute dtype
+    layer     one layer's recompute + backward working set
+    The peak is resident + cast + stacks + max(head, grads + layer): the
+    head's backward is over before the layers' gradients exist.
+    """
+    from ..models.transformer import REMAT_LADDER
+    rung = _rung_index(remat)
+    tok = b * t
+    act = 1.0 / tp if sequence_parallel else 1.0
+    wide = tok * d * dtype_bytes * act       # a (b, t, d) tensor
+    q_w = tok * (d / tp) * dtype_bytes       # a column-linear's output
+    kv_w = tok * (kd / tp) * dtype_bytes
+    f_w = tok * (f / tp) * dtype_bytes
+    h_local = heads / tp
+    names = {
+        "flash_out": tok * h_local * head_dim * dtype_bytes,
+        "flash_lse": tok * h_local * 4,
+        "q_proj": q_w, "k_proj": kv_w, "v_proj": kv_w,
+        "attn_proj": wide if tp > 1 else 0.0,   # named only past a reduce
+        "ffn_fc": f_w if ffn_inputs == 1 else 0.0,
+        "ffn_gate": f_w if ffn_inputs == 2 else 0.0,
+        "ffn_up": f_w if ffn_inputs == 2 else 0.0,
+    }
+    if rung is None:
+        # no remat: everything autodiff saves on the flash path — layer
+        # input, 2 norm outputs, q/k/v and their head-split copies, flash
+        # o/lse + the projection's input, both row-linear outputs, the
+        # FFN's inputs and activation
+        per_layer = (5 * wide + 2 * q_w + 4 * kv_w + names["flash_out"]
+                     + names["flash_lse"] + (ffn_inputs + 1) * f_w)
+    else:
+        per_layer = wide + sum(names[n] for _, ns in REMAT_LADDER[:rung + 1]
+                               for n in ns)
+    out = {
+        "resident": param_count * (state_bytes_per_param
+                                   - grad_bytes_per_param),
+        "grads": param_count * grad_bytes_per_param,
+        "cast": (layer_param_count * dtype_bytes if dtype_bytes < 4
+                 else 0.0),
+        "stacks": layers * per_layer,
+        "head": tok * (vocab / tp) * (4 + dtype_bytes),
+        "layer": tok * dtype_bytes * (6 * d * act + 3.4 * f / tp),
+    }
+    out["total"] = (out["resident"] + out["cast"] + out["stacks"]
+                    + max(out["head"], out["grads"] + out["layer"]))
+    return out
+
+
+def _cfg_step_bytes(cfg, batch: int, seqlen: int, remat, tp: int, world: int,
+                    dtype_bytes: int, zero_stage: int, dp: int, family: str,
+                    sequence_parallel: bool) -> Dict[str, float]:
+    """`step_bytes` of `cfg` built as `family`, at the GLOBAL `batch` over
+    `world` devices. Parameters shard over tp (the big matrices and the
+    vocabulary do; the norms and biases that do not are a thousandth of the
+    count), the batch over world / tp. ZeRO shrinks the state per
+    `zero_state_bytes_per_param`."""
+    tp = max(tp, 1)
+    f = cfg.ffn_dim
     if cfg.num_experts:
         # each token's residuals touch top_k expert FFNs plus the dispatch
         # buffers (~capacity_factor x the dense width)
         f = int(f * max(cfg.moe_top_k, 1) * cfg.moe_capacity_factor / 2)
-    P = cfg.num_params()
-    dp_like = max(world // max(tp, 1), 1)
-    b_local = max(batch // dp_like, 1)
-    tok = b_local * seqlen
+    P = family_num_params(cfg, family)
+    nonlayer = cfg.vocab_size * cfg.attn_dim * (2 if family == "llama"
+                                                else 1)
+    return step_bytes(
+        remat, param_count=P / tp, layer_param_count=(P - nonlayer) / tp,
+        b=max(batch // max(world // tp, 1), 1), t=seqlen, d=cfg.attn_dim,
+        kd=cfg.kv_dim, f=f, heads=cfg.num_heads, head_dim=cfg.head_dim,
+        layers=cfg.num_layers, vocab=cfg.padded_vocab_size(tp), tp=tp,
+        dtype_bytes=dtype_bytes, ffn_inputs=2 if family == "llama" else 1,
+        sequence_parallel=sequence_parallel,
+        state_bytes_per_param=zero_state_bytes_per_param(zero_stage, dp,
+                                                         cfg),
+        grad_bytes_per_param=4.0 / max(dp, 1) if zero_stage >= 2 else 4.0)
 
-    state = zero_state_bytes_per_param(zero_stage, dp, cfg)
-    fixed = P * state / max(tp, 1) * 1.10
-    acts = L * tok * _LAYER_UNITS[remat](d, kd, f) * dtype_bytes / max(tp, 1)
-    # flash lse rows (f32) are saved on every policy that keeps o/lse
-    if remat != "true":
-        acts += L * b_local * cfg.num_heads * seqlen * 4 / max(tp, 1)
-    # the head: logits in f32 for the CE (vocab-parallel: sharded over tp)
-    # appear twice at the bwd peak (value + cotangent)
-    logits = 2 * tok * cfg.padded_vocab_size(tp) * 4 / max(tp, 1)
-    # transient optimizer update working set ~ one f32 param tree at the
-    # optimizer's RESIDENT layout (fully dp-local under ZeRO-3)
-    opt_scratch = P * 4 / max(tp, 1)
-    if zero_stage >= 3:
-        opt_scratch /= max(dp, 1)
-    return (fixed + acts + logits + opt_scratch) / 1024 ** 3
+
+def estimate_step_gib(cfg, batch: int, seqlen: int, remat,
+                      tp: int = 1, world: int = 1,
+                      dtype_bytes: int = 2, zero_stage: int = 0,
+                      dp: int = 1, family: str = "llama",
+                      sequence_parallel: bool = False) -> float:
+    """Peak-HBM estimate (GiB, per device) for one fwd+bwd+Adam train step
+    of `cfg` built as `family`, at the GLOBAL `batch` over `world` devices.
+    `remat` is a rung of the ladder ('true' ... 'dots') or 'false'."""
+    return _cfg_step_bytes(cfg, batch, seqlen, remat, tp, world, dtype_bytes,
+                           zero_stage, dp, family,
+                           sequence_parallel)["total"] / GIB
 
 
 def hbm_budget_gib() -> float:
@@ -131,44 +219,97 @@ def hbm_budget_gib() -> float:
             f"is no HBM budget to size 'remat auto' against: name the "
             f"policy (--remat true|dots|false), or pass budget_gib to "
             f"select_remat")
-    return limit / 1024 ** 3
+    return limit / GIB
+
+
+def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
+          allow_false: bool, verbose: bool, note: str = "") -> str:
+    """The one selector: the highest rung (or 'false' above the ladder)
+    whose `parts(key)["total"]` (a `step_bytes`) fits MARGIN x (budget -
+    reserve).
+
+    `budget_gib` None reads the device; a backend with no `memory_stats`
+    (the CPU) then gets rung 0, the program `remat=True` has always been.
+    `reserve_gib` None leaves room for one more copy of the resident state:
+    `AsyncCheckpointer`'s snapshot, which a model cannot know its caller
+    makes. Says what it chose on stderr and on the program's tracer."""
+    from ..models.transformer import REMAT_RUNGS
+    from ..obs.trace import current_tracer
+    floor = REMAT_RUNGS[0]
+    if budget_gib is None:
+        try:
+            budget_gib = hbm_budget_gib()
+        except ValueError:
+            return floor        # nothing was sized: nothing to say
+    reserve = (parts(floor)["resident"] / GIB if reserve_gib is None
+               else reserve_gib)
+    usable = (budget_gib - reserve) * MARGIN
+    sizes, picked = {}, floor
+    for key in (("false",) if allow_false else ()) + REMAT_RUNGS[::-1]:
+        sizes[key] = parts(key)["total"] / GIB
+        if sizes[key] <= usable or key == floor:
+            picked = key
+            break
+    fields = dict(rung=picked, estimate_gib=sizes[picked],
+                  budget_gib=budget_gib, reserve_gib=reserve,
+                  usable_gib=usable,
+                  **{f"estimate_gib.{k}": v for k, v in sizes.items()})
+    tracer = current_tracer()
+    if tracer is not None:
+        tracer.instant("remat_auto", **fields)
+    if verbose:
+        est = ", ".join(f"{k}={v:.2f}GiB" for k, v in sizes.items())
+        print(f"remat auto: picked '{picked}' (estimates {est}; budget "
+              f"{budget_gib:.2f} GiB - reserve {reserve:.2f} GiB, x margin "
+              f"{MARGIN}{note})", file=sys.stderr)
+    return picked
 
 
 def select_remat(cfg, batch: int, seqlen: int, tp: int = 1, world: int = 1,
-                 budget_gib: Optional[float] = None,
-                 margin: float = 0.75, verbose: bool = True,
-                 zero_stage: int = 0, dp: int = 1) -> str:
-    """The fastest remat policy whose estimated peak fits margin * budget.
+                 budget_gib: Optional[float] = None, verbose: bool = True,
+                 zero_stage: int = 0, dp: int = 1, family: str = "llama",
+                 reserve_gib: Optional[float] = None,
+                 sequence_parallel: bool = False) -> str:
+    """The fastest remat setting whose estimated peak fits the device:
+    'false', or a rung of `models/transformer.REMAT_LADDER` ('dots' ...
+    'true'); a value `Transformer(remat=...)` takes once 'true'/'false'
+    go through `config.REMAT_CHOICES`.
 
-    Returns a REMAT_CHOICES key ('false' | 'dots' | 'true'). margin=0.75
-    leaves a quarter of HBM for XLA temps, fusion scratch, and the
-    donation-transition double-buffering the estimate cannot see.
-
-    `zero_stage`/`dp` size the train state per the ZeRO ladder (see
-    `estimate_step_gib`) so `--remat auto` picks against the budget the
-    stage actually leaves. Stage 3 never picks 'false': without remat,
-    autodiff saves every layer's GATHERED weights as backward residuals —
-    the full replica the stage exists to eliminate (the train CLI refuses
-    the explicit combination with the same rationale).
+    `zero_stage`/`dp` size the train state per the ZeRO ladder. Stage 3
+    never picks 'false': without remat, autodiff saves every layer's
+    GATHERED weights as backward residuals — the full replica the stage
+    exists to eliminate (the train CLI refuses the explicit combination
+    with the same rationale). See `_pick` for budget and reserve.
     """
-    budget = budget_gib if budget_gib is not None else hbm_budget_gib()
-    usable = budget * margin
-    picked = "true"
-    sizes = {}
-    policies = ("false", "dots", "true")
-    if zero_stage >= 3:
-        policies = ("dots", "true")
-    for policy in policies:
-        sizes[policy] = estimate_step_gib(cfg, batch, seqlen, policy,
-                                          tp=tp, world=world,
-                                          zero_stage=zero_stage, dp=dp)
-        if sizes[policy] <= usable:
-            picked = policy
-            break
-    if verbose:
-        import sys
-        est = ", ".join(f"{p}={v:.2f}GiB" for p, v in sizes.items())
-        zn = f", zero{zero_stage} dp{dp}" if zero_stage else ""
-        print(f"remat auto: picked '{picked}' (estimates {est}; budget "
-              f"{budget:.1f} GiB x margin {margin}{zn})", file=sys.stderr)
-    return picked
+    dtype_bytes = 2 if cfg.compute_dtype == "bfloat16" else 4
+    zn = f"; zero{zero_stage} dp{dp}" if zero_stage else ""
+    return _pick(lambda key: _cfg_step_bytes(
+        cfg, batch, seqlen, key, tp, world, dtype_bytes, zero_stage, dp,
+        family, sequence_parallel), budget_gib, reserve_gib,
+        allow_false=zero_stage < 3, verbose=verbose, note=zn)
+
+
+@functools.lru_cache(maxsize=None)
+def select_remat_traced(model, param_count: int, layer_param_count: int,
+                        b: int, t: int) -> str:
+    """`select_remat` for a model that is being traced: `remat="auto"`
+    resolves here, once per (model, per-shard shapes), from what the trace
+    holds — this device's parameter count and its (b, t) token block — and
+    the device's `memory_stats()`. No second compile, no flag. The model
+    cannot see a ZeRO stage (stage 0's state is the largest) and never
+    picks 'false': a rung is always a rematerialising model, which is what
+    ZeRO-3's gather inside the layer body needs."""
+    cfg = model.cfg
+    parts = functools.partial(
+        step_bytes, param_count=param_count,
+        layer_param_count=layer_param_count, b=b,
+        t=t, d=cfg.attn_dim, kd=cfg.kv_dim,
+        f=cfg.ffn_dim, heads=cfg.num_heads, head_dim=cfg.head_dim,
+        layers=cfg.num_layers // model.pp_size,
+        vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
+        dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
+        ffn_inputs=2 if model.uses_rope else 1,   # SwiGLU | GPT-2's MLP
+        sequence_parallel=model.sequence_parallel)
+    return _pick(parts, model.remat_budget_gib, None, allow_false=False,
+                 verbose=True,
+                 note=f"; traced b{b} x t{t}, tp{model.tp_size}")

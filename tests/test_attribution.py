@@ -151,34 +151,107 @@ def test_attribution_bucketed_beats_padded(cfg45m):
 
 
 def test_estimate_monotone_in_remat_policy():
-    cfg = model_preset("45m")
-    est = {p: estimate_step_gib(cfg, 32, 1000, p)
-           for p in ("false", "dots", "true")}
-    assert est["false"] > est["dots"] > est["true"] > 0
+    """Every rung of the ladder keeps what the rung below keeps and more
+    (under tp; without it nothing tags `attn_proj`, so its rung is rung
+    0's size); no remat keeps most."""
+    from distributed_pytorch_from_scratch_tpu.models.transformer import (
+        REMAT_RUNGS)
+    for family in ("llama", "gpt2"):
+        cfg = model_preset("45m")
+        est = [estimate_step_gib(cfg, 32, 1000, p, family=family, tp=2,
+                                 world=2)
+               for p in REMAT_RUNGS + ("false",)]
+        assert est == sorted(est) and len(set(est)) == len(est) and est[0] > 0
+        flat = [estimate_step_gib(cfg, 32, 1000, p, family=family)
+                for p in REMAT_RUNGS]
+        assert flat == sorted(flat) and flat[0] == flat[1] < flat[2]
+
+
+# The benchmark's two cells per rung, GiB on the fullest chip: what the chip
+# counted while the cell's step ran (`memory_stats()`: peak_bytes_in_use +
+# peak_bytes_reserved; my chip runs, PR 26: `.scratch/rungs.py` builds the
+# step as the `train` runner does, one process a rung), and what the
+# compiler planned (`memory_analysis()`: arguments + temporaries; a v5e
+# described in the sandbox gives the chip's own plan to the byte, PR 26).
+# The plan charges some stacks that live from the forward loop to the
+# backward loop twice; the runtime reserves each once, and it is the runtime
+# the estimate is held to (PERF.md section 5). Rung 0 of cell 1 is also the
+# ledger's `device.peak_hbm_gib` (8.60, PR 25); the ledger's 8.09 for cell 2
+# is not the step's peak but the float32 reference's temporaries (3.73 GiB
+# reserved against the step's 3.06), which every rung above 0 exceeds.
+MEDIUM = dict(attn_dim=1024, ffn_dim=4096, num_heads=16, num_layers=24,
+              vocab_size=50257, maxlen=1024, compute_dtype="bfloat16")
+LARGE = dict(attn_dim=1280, ffn_dim=5120, num_heads=20, num_layers=36,
+             vocab_size=50257, maxlen=1024, compute_dtype="bfloat16")
+CELL1 = dict(batch=12, seqlen=1024, family="gpt2")
+CELL2 = dict(batch=16, seqlen=1024, tp=2, world=4, dp=2, family="gpt2")
+#        rung: (chip GiB or None where not run, planned GiB)
+CELL1_GIB = {"true": (8.598, 9.139), "attn_proj": (8.598, 9.138),
+             "ffn": (10.848, 13.637), "flash": (None, 14.799),
+             "dots": (13.113, 16.544)}
+CELL2_GIB = {"true": (7.419, 8.386), "attn_proj": (8.090, 9.695),
+             "ffn": (9.457, 11.025), "flash": (9.809, 11.365),
+             "dots": (10.864, 12.425)}
+
+
+@pytest.mark.parametrize("rung", sorted(CELL1_GIB))
+@pytest.mark.parametrize("cell", ["medium-b12-tp1", "large-b8-tp2"])
+def test_estimate_is_what_the_chip_counts(cell, rung):
+    """Within 1% of the chip's count where a rung was run on the chip
+    (the PR's criterion is 8%), and never over the compiler's plan."""
+    from distributed_pytorch_from_scratch_tpu.config import ModelConfig
+    shape, kw, table = ((MEDIUM, CELL1, CELL1_GIB) if cell.startswith("medium")
+                        else (LARGE, CELL2, CELL2_GIB))
+    chip, planned = table[rung]
+    est = estimate_step_gib(ModelConfig(**shape), remat=rung, **kw)
+    if chip is not None:
+        assert abs(est - chip) / chip < 0.01, (est, chip)
+    assert est < planned * 1.01, (est, planned)
 
 
 def test_select_remat_matches_validated_configs():
     """The selector must reproduce the empirically validated picks: 45m
     b32xt1000 and gpt2-124m b8xt1024 fit a 16G chip without remat
     (bench.py's defaults, proven in round 4)."""
-    assert select_remat(model_preset("45m"), 32, 1000,
+    bf16 = dict(compute_dtype="bfloat16")     # what those runs computed in
+    assert select_remat(model_preset("45m", **bf16), 32, 1000,
                         budget_gib=16.0, verbose=False) == "false"
-    assert select_remat(model_preset("gpt2-124m"), 8, 1024,
+    assert select_remat(model_preset("gpt2-124m", **bf16), 8, 1024,
                         budget_gib=16.0, verbose=False) == "false"
+
+
+def test_select_remat_picks_the_cells_rungs(capsys):
+    """What the benchmark's cells get on a v5e (bytes_limit 15.748 GiB) with
+    the default reserve of one more copy of the resident state (the
+    program's own snapshot): the rung, and the line that says so."""
+    from distributed_pytorch_from_scratch_tpu.config import ModelConfig
+    limit = 15.748
+    assert select_remat(ModelConfig(**MEDIUM), budget_gib=limit, **CELL1) \
+        == "ffn"
+    err = capsys.readouterr().err
+    assert "remat auto: picked 'ffn'" in err and "reserve 3.97 GiB" in err
+    assert "dots=13.06GiB" in err and "ffn=10.79GiB" in err
+    assert select_remat(ModelConfig(**LARGE), budget_gib=limit,
+                        verbose=False, **CELL2) == "flash"
+    # a caller that knows it takes no snapshot passes the true reserve
+    assert select_remat(ModelConfig(**MEDIUM), budget_gib=limit,
+                        reserve_gib=0.0, verbose=False, **CELL1) == "dots"
 
 
 def test_select_remat_steps_down_when_tight():
     """A small budget must force the ladder down — and a hopeless one
     still returns 'true' (the ladder's floor, never an exception)."""
     cfg = model_preset("45m")
+    from distributed_pytorch_from_scratch_tpu.models.transformer import (
+        REMAT_RUNGS)
     assert select_remat(cfg, 32, 1000, budget_gib=10.0,
-                        verbose=False) in ("dots", "true")
+                        verbose=False) in REMAT_RUNGS
     assert select_remat(cfg, 32, 1000, budget_gib=0.1,
                         verbose=False) == "true"
 
 
 def test_estimate_rejects_unknown_policy():
-    with pytest.raises(ValueError, match="remat must be one of"):
+    with pytest.raises(ValueError, match="remat must be"):
         estimate_step_gib(model_preset("45m"), 32, 1000, "sometimes")
 
 
@@ -186,11 +259,12 @@ def test_hbm_budget_raises_without_memory_stats():
     # the CPU test mesh reports no bytes_limit: no assumed 16 GiB, an error
     with pytest.raises(ValueError, match="no memory_stats"):
         hbm_budget_gib()
-    with pytest.raises(ValueError, match="no memory_stats"):
-        select_remat(model_preset("45m"), 32, 1000, verbose=False)
+    # the selector sizes nothing there and stays on rung 0, the program
+    # `remat=True` has always been
+    assert select_remat(model_preset("45m"), 32, 1000, verbose=False) == "true"
     # off-chip callers name the budget
     assert select_remat(model_preset("45m"), 32, 1000, budget_gib=16.0,
-                        verbose=False) in ("false", "dots", "true")
+                        verbose=False) != "true"
 
 
 def test_one_peaks_table_and_unknown_chips_raise():
@@ -317,7 +391,7 @@ def test_zero1_estimate_fix_shrinks_pre_existing_overestimate():
     base = estimate_step_gib(cfg, 32, 1000, "dots")
     z1 = estimate_step_gib(cfg, 32, 1000, "dots", zero_stage=1, dp=8)
     saved = (base - z1) * 1024 ** 3
-    expect = cfg.num_params() * 8.0 * (1 - 1 / 8) * 1.10  # x the tp fudge
+    expect = cfg.num_params() * 8.0 * (1 - 1 / 8)
     assert abs(saved - expect) / expect < 1e-6
 
 

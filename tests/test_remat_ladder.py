@@ -1,0 +1,177 @@
+"""The ladder of named layer residuals (models/transformer.REMAT_LADDER).
+
+Every rung computes what rung 0 computes: the policy only decides which
+tensors the backward finds saved and which it rebuilds. So loss and every
+gradient leaf must match rung 0's, in both families, with and without
+tensor parallelism, and with the flash kernel's tagged outputs in play. The
+text of the compiled program says what a rung buys under tp: the recomputed
+forward's all-reduce is gone from the rung that keeps the attention
+projection's output past its reduce. And `remat="auto"` with a budget that
+fits nothing is the program `remat=True` has always been.
+"""
+
+import dataclasses
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_from_scratch_tpu.config import MeshConfig, ModelConfig
+from distributed_pytorch_from_scratch_tpu.models.gpt2 import GPT2Transformer
+from distributed_pytorch_from_scratch_tpu.models.transformer import (
+    REMAT_LADDER, REMAT_RUNGS, Transformer, remat_rung)
+from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+
+CFG = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=4, num_layers=2,
+                  vocab_size=96, maxlen=32)
+FAMILIES = {"gpt2": GPT2Transformer, "llama": Transformer}
+UPPER_RUNGS = REMAT_RUNGS[1:]
+
+
+def batch(t=16, b=4):
+    ids = jax.random.randint(jax.random.key(3), (b, t + 1), 0,
+                             CFG.vocab_size)
+    pos = jnp.tile(jnp.arange(t, dtype=jnp.int32), (b, 1))
+    return ids[:, :-1], ids[:, 1:], pos
+
+
+@functools.lru_cache(maxsize=None)
+def loss_and_grads(family, tp, remat, attn_impl="xla", budget=None):
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+    model = FAMILIES[family](CFG, tp_size=tp, remat=remat,
+                             attn_impl=attn_impl, remat_budget_gib=budget)
+    params = jax.device_put(model.init(jax.random.key(0)),
+                            model.shardings(mesh))
+    loss, grads = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+        params, *batch())
+    return float(loss), jax.tree.map(np.asarray, grads)
+
+
+def test_ladder_names_are_ordered_and_unique():
+    names = [n for _, ns in REMAT_LADDER for n in ns]
+    assert len(names) == len(set(names))
+    assert REMAT_RUNGS[0] == "true" and REMAT_RUNGS[-1] == "dots"
+    assert remat_rung(True) == 0 and remat_rung("dots") == len(REMAT_RUNGS) - 1
+    for bad in (1, "sometimes", None):
+        with pytest.raises(ValueError, match="remat must be"):
+            Transformer(CFG, remat=bad)
+    assert Transformer(CFG).remat == "auto" == GPT2Transformer(CFG).remat
+
+
+@pytest.mark.parametrize("rung", UPPER_RUNGS)
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_every_rung_gives_rung_zero_gradients(family, tp, rung):
+    want_loss, want = loss_and_grads(family, tp, True)
+    loss, grads = loss_and_grads(family, tp, rung)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("rung", UPPER_RUNGS)
+def test_every_rung_with_the_flash_kernels_tagged_outputs(rung):
+    """`flash_out` / `flash_lse` exist only on the kernel path: under the
+    interpreter the rungs that keep them must still give rung 0's numbers."""
+    want_loss, want = loss_and_grads("gpt2", 1, True, "flash_interpret")
+    loss, grads = loss_and_grads("gpt2", 1, rung, "flash_interpret")
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+def grad_program(family, remat, mesh_cfg, budget=None):
+    mesh = make_mesh(mesh_cfg, devices=jax.devices()[:mesh_cfg.world_size])
+    model = FAMILIES[family](CFG, tp_size=mesh_cfg.tp, remat=remat,
+                             remat_budget_gib=budget)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    return jax.jit(jax.value_and_grad(model.make_loss(mesh))).lower(
+        params, *batch())
+
+
+def rematted_all_reduces(hlo_text: str) -> int:
+    return sum(1 for line in hlo_text.splitlines()
+               if re.search(r"= .*\ball-reduce(-start)?\(", line)
+               and "rematted_computation" in line)
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_recomputed_all_reduce_goes_with_the_projection_output(family):
+    """dp2 x tp2 on four virtual devices. The layers are one scan, so the
+    compiled gradient holds one layer body: at rung 0 (and on every rung
+    that recomputes the attention projection) its recomputed forward has
+    exactly one tensor-parallel all-reduce, the attention projection's (the
+    MLP projection's is dead code: a layer's output is not a residual). On
+    the rung that keeps `attn_proj`, named past the reduce, there is none."""
+    mesh_cfg = MeshConfig(dp=2, tp=2)
+    counts = {r: rematted_all_reduces(
+        grad_program(family, r, mesh_cfg).compile().as_text())
+        for r in REMAT_RUNGS}
+    keeps = next(i for i, (_, names) in enumerate(REMAT_LADDER)
+                 if "attn_proj" in names)
+    assert counts == {r: (1 if i < keeps else 0)
+                      for i, r in enumerate(REMAT_RUNGS)}, counts
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_auto_with_a_budget_that_fits_nothing_is_todays_program(family):
+    """The control of the mechanism: `remat="auto"` (the default) sized
+    against a budget nothing fits lowers to the text of `remat=True`; on
+    this backend, which reports no memory_stats, so does auto with no
+    budget at all; and a budget everything fits gives the top rung's."""
+    mesh_cfg = MeshConfig(dp=1, tp=2)
+    floor = grad_program(family, True, mesh_cfg).as_text()
+    assert grad_program(family, "auto", mesh_cfg, 1e-9).as_text() == floor
+    assert grad_program(family, "auto", mesh_cfg).as_text() == floor
+    top = grad_program(family, "dots", mesh_cfg).as_text()
+    assert top != floor
+    assert grad_program(family, "auto", mesh_cfg, 1e3).as_text() == top
+
+
+def test_auto_records_its_choice_on_the_tracer(tmp_path, capsys):
+    """One line on stderr and one instant event on the program's tracer:
+    rung, its estimate, every estimate tried, budget and reserve."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    from distributed_pytorch_from_scratch_tpu.training import memory
+    memory.select_remat_traced.cache_clear()
+    tracer = SpanTracer(str(tmp_path))
+    try:
+        grad_program("gpt2", "auto", MeshConfig(dp=1, tp=1), 1e3)
+        grad_program("gpt2", "auto", MeshConfig(dp=1, tp=1), 1e3)
+    finally:
+        tracer.close()
+    events = [json.loads(line) for line in open(tmp_path / "trace.jsonl")]
+    chosen = [e for e in events if e["name"] == "remat_auto"]
+    assert len(chosen) == 1                      # once per (model, shapes)
+    args = chosen[0]["args"]
+    assert args["rung"] == "dots" and args["budget_gib"] == 1e3
+    assert args["estimate_gib"] == args["estimate_gib.dots"] > 0
+    assert args["reserve_gib"] > 0 and args["usable_gib"] < 1e3
+    err = capsys.readouterr().err
+    assert err.count("remat auto: picked 'dots'") == 1
+    assert dataclasses.replace(GPT2Transformer(CFG), remat="flash").remat \
+        == "flash"
+
+
+def test_attn_proj_is_named_only_past_a_reduce():
+    """With tp = 1 nothing tags `attn_proj`, so its rung is rung 0's
+    program with the policy's wrapper around it: the same residuals, and
+    the estimate charges it nothing."""
+    from distributed_pytorch_from_scratch_tpu.training.memory import (
+        estimate_step_gib)
+    kw = dict(batch=4, seqlen=16)
+    assert estimate_step_gib(CFG, remat="attn_proj", **kw) \
+        == estimate_step_gib(CFG, remat="true", **kw)
+    assert estimate_step_gib(CFG, remat="attn_proj", tp=2, world=2, **kw) \
+        > estimate_step_gib(CFG, remat="true", tp=2, world=2, **kw)
+    saved = lambda tp, r: str(jax.make_jaxpr(jax.grad(
+        FAMILIES["gpt2"](CFG, tp_size=tp, remat=r).make_loss(
+            make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp]))))(
+        jax.eval_shape(FAMILIES["gpt2"](CFG, tp_size=tp).init,
+                       jax.random.key(0)), *batch())).count("name=attn_proj")
+    assert saved(1, "attn_proj") == 0 and saved(2, "attn_proj") > 0

@@ -1,0 +1,103 @@
+"""The benchmark's two train steps, compiled for a described v5e at the rung
+`remat="auto"` picks there: no chip, the chip's compiler (on-chip-measurement
+guide, section 2). One file, topology in a fixture: only the worker that
+runs this file loads the TPU library.
+
+What is asserted is what the compiler would refuse on the chip (arguments +
+planned temporaries over `bytes_limit`), and that the estimate the rung was
+picked by leaves the snapshot's reserve free. The plan is an upper bound of
+what the runtime reserves: it charges some of the stacks that live from the
+forward loop to the backward loop twice (PERF.md section 5).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from distributed_pytorch_from_scratch_tpu.config import (
+    MeshConfig, ModelConfig, OptimizerConfig)
+from distributed_pytorch_from_scratch_tpu.models.gpt2 import GPT2Transformer
+from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+from distributed_pytorch_from_scratch_tpu.training import memory
+from distributed_pytorch_from_scratch_tpu.training.optim import AdamState
+from distributed_pytorch_from_scratch_tpu.training.train_step import (
+    build_train_step)
+
+V5E_LIMIT_GIB = 15.748          # memory_stats()["bytes_limit"] of a v5e
+CELLS = {
+    # cell: (widths, mesh, global batch, the rung auto picks, its chip GiB)
+    "gpt2-medium.train-b12-t1024": (
+        dict(attn_dim=1024, ffn_dim=4096, num_heads=16, num_layers=24),
+        dict(dp=1, tp=1), 12, "ffn"),
+    "gpt2-large.train-dp2-tp2": (
+        dict(attn_dim=1280, ffn_dim=5120, num_heads=20, num_layers=36),
+        dict(dp=2, tp=2), 16, "flash"),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever stops the description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture
+def described_tpu(monkeypatch):
+    """The flash guards ask `jax.default_backend()`; the target here is the
+    described chip. Steered in the test, not by an option of the program.
+    The persistent compile cache cannot read back what no chip compiled."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    memory.select_remat_traced.cache_clear()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
+                                                      described_tpu, capsys):
+    widths, mesh_sizes, batch, want_rung = CELLS[cell]
+    chips = mesh_sizes["dp"] * mesh_sizes["tp"]
+    mesh = make_mesh(MeshConfig(**mesh_sizes), devices=topo.devices[:chips])
+    cfg = ModelConfig(vocab_size=50257, maxlen=1024,
+                      compute_dtype="bfloat16", **widths)
+    model = GPT2Transformer(cfg, tp_size=mesh_sizes["tp"],
+                            remat_budget_gib=V5E_LIMIT_GIB)
+    assert model.remat == "auto"
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(model.init, jax.random.key(0)), model.shardings(mesh))
+    scalar = NamedSharding(mesh, P())
+    opt = AdamState(step=jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar),
+                    mu=params, nu=params)
+    ids = jax.ShapeDtypeStruct((batch, 1024), jnp.int32,
+                               sharding=NamedSharding(mesh, P(("dp", "ep"),
+                                                              "cp")))
+    step = build_train_step(model, mesh, OptimizerConfig(),
+                            with_grad_norm=True)
+    compiled = step.lower(params, opt, ids, ids, ids).compile()
+
+    assert f"remat auto: picked '{want_rung}'" in capsys.readouterr().err
+    assert "tpu_custom_call" in compiled.as_text()       # the flash kernel
+    plan = compiled.memory_analysis()
+    args = plan.argument_size_in_bytes / memory.GIB
+    planned = args + plan.temp_size_in_bytes / memory.GIB
+    assert planned < V5E_LIMIT_GIB, planned
+    estimate = memory.estimate_step_gib(
+        cfg, batch, 1024, want_rung, tp=mesh_sizes["tp"], world=chips,
+        dp=mesh_sizes["dp"], family="gpt2")
+    # the estimate is of what the chip counts, which the plan bounds ...
+    assert estimate < planned * 1.01, (estimate, planned)
+    # ... and with one more copy of the state (the snapshot) it still fits
+    assert estimate + args < V5E_LIMIT_GIB, (estimate, args)
