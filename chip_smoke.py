@@ -19,8 +19,10 @@ what comes out by the repo's own means:
   train-gpt2-124m  `train --family gpt2 --model gpt2-124m --bf16
                    --batch_size 8` at vocab 50,257, t=1024
   train-4chip      with >= 4 devices: `train --model 45m --dp_size 2
-                   --tp_size 2`: all four devices hold shards, step-1 loss
-                   matches the one-chip run. Otherwise says it did not run.
+                   --tp_size 2`, no layout flag, so the default path runs
+                   (sequence parallelism over the ring collective matmuls):
+                   all four devices hold shards, step-1 loss matches the
+                   one-chip run. Otherwise says it did not run.
 
 One process uses the chip at a time: this parent never touches a JAX
 backend, each phase is a child process and they run one after another. The
@@ -308,11 +310,23 @@ def main(argv=None) -> int:
                   for g in c["groups"]}
         require(len(groups) >= 2, f"train-4chip: collectives over "
                 f"{sorted(groups)} — wanted both tp and dp groups")
+        # no layout flag was passed: at tp 2 the program itself picks
+        # sequence parallelism over the ring collective matmuls, so these
+        # four steps are the bring-up of the ring programs
+        require(rec4["sequence_parallel"] is True
+                and rec4["tp_overlap"] == "ring"
+                and "collective-permute" in rec4["collectives"],
+                f"train-4chip: the default tp layout ran as sequence_parallel="
+                f"{rec4['sequence_parallel']}, tp_overlap="
+                f"{rec4['tp_overlap']!r}, collectives "
+                f"{sorted(rec4['collectives'])} — wanted the ring matmuls")
         comm = ", ".join(f"{op} x{c['count']}"
                          for op, c in sorted(rec4["collectives"].items()))
         print(f"[train-4chip] params per device {held}; step-1 loss "
               f"{rec4['first_loss']:.4f} (one chip {rec45['first_loss']:.4f}"
-              f"); comm: {comm}; replica groups {sorted(groups)}",
+              f"); default tp layout: sequence parallel, tp_overlap="
+              f"{rec4['tp_overlap']}; comm: {comm}; replica groups "
+              f"{sorted(groups)}",
               flush=True)
     else:
         print(f"[train-4chip] DID NOT RUN: {device['count']} device(s) "

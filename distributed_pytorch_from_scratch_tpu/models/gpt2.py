@@ -57,7 +57,7 @@ from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import MoEFFN
 from ..parallel.norm import LayerNorm
 from ..runtime.prng import fold
-from ..ops.overlap import ag_matmul
+from ..ops.overlap import ag_matmul, ring_order
 from ..parallel.linear import apply_column_ring_fused
 from .transformer import (NEG_INF, Transformer, remat_wrap, resolve_remat,
                           validate_cp, validate_pp, validate_remat, validate_t_real,
@@ -85,11 +85,11 @@ class GPT2Transformer:
     cp_size: int = 1
     cp_impl: str = "ring"
     cp_layout: str = "contiguous"
-    sequence_parallel: bool = False
-    # 'ring' = ring-decomposed collective matmuls for the SP tp collectives
-    # — same contract as Transformer.tp_overlap (requires
-    # sequence_parallel; the tied head rings too)
-    tp_overlap: str = "off"
+    # same contract as Transformer.sequence_parallel / .tp_overlap: 'auto'
+    # (the default) is resolved per trace by `resolve_tp_layout`; under
+    # 'ring' the tied head rings too
+    sequence_parallel: "bool | str" = "auto"
+    tp_overlap: str = "auto"
     pp_size: int = 1
     pp_microbatches: int = 0
     pp_remat_steps: bool = False
@@ -175,7 +175,7 @@ class GPT2Transformer:
     @functools.cached_property
     def _mods(self) -> Dict[str, Any]:
         d, f = self.d, self.cfg.ffn_dim
-        ov = self.tp_overlap
+        ov = self._linear_overlap
         mods = {
             "ln1": LayerNorm(d),
             # wq/wk/wv stay overlap='off': the fused ring in _layer_body
@@ -284,6 +284,10 @@ class GPT2Transformer:
         in_layout = ("seq_sharded" if ring_ov
                      else "gathered" if sp else "replicated")
         out_layout = "seq_sharded" if sp else "replicated"
+        # between fc and proj nothing cares where a token sits, so under
+        # the rings the MLP's hidden activation stays in the ring's own
+        # chunk order (ops/overlap.py, "RING ORDER")
+        ffn_order = dict(seq_order="ring") if ring_ov else {}
         b = x.shape[0]
         t = pos.shape[1]  # full (cp-local) sequence length, not x.shape[1]
 
@@ -331,11 +335,12 @@ class GPT2Transformer:
                 return x + ff, aux
             # gelu_new (tanh approximation), like GPT-2
             fc = checkpoint_name(
-                m["fc"].apply(lp["fc"], y, dtype, input_layout=in_layout),
+                m["fc"].apply(lp["fc"], y, dtype, input_layout=in_layout,
+                              **ffn_order),
                 "ffn_fc")
             x = x + m["proj"].apply(lp["proj"],
                                     jax.nn.gelu(fc, approximate=True), dtype,
-                                    output_layout=out_layout)
+                                    output_layout=out_layout, **ffn_order)
             return x, None
 
         # ring overlap: dense segments run even on bubble steps (their tp
@@ -372,6 +377,7 @@ class GPT2Transformer:
         """forward_shard + MoE aux-stat sums (None for dense) — the same
         contract as `Transformer._forward_with_aux`, which the borrowed
         `loss_shard` consumes."""
+        self = self._resolved(input_ids.shape[1])
         dtype = resolve_dtype(self.cfg.compute_dtype)
         sp = self.sequence_parallel
         if sp and input_ids.shape[1] % self.tp_size != 0:
@@ -424,8 +430,9 @@ class GPT2Transformer:
                 # ring collective matmul for the tied head too: the gather's
                 # hops hide under the per-chunk logits dots, and the VJP's
                 # reverse ring reduce-scatters the head's input cotangent
-                logits = ag_matmul(x.astype(dtype), (w.T,), "tp",
-                                   self.tp_overlap == "ring_q")[0]
+                logits = ring_order(ag_matmul(
+                    x.astype(dtype), (w.T,), "tp",
+                    self.tp_overlap == "ring_q")[0], "tp")
             else:
                 if sp:
                     # the tied head consumes full-sequence activations; the
@@ -446,6 +453,9 @@ class GPT2Transformer:
     def num_local_kv_heads(self) -> int:
         return self.num_local_heads  # MHA: the decoder's caches are full-size
 
+    tp_layout = Transformer.tp_layout
+    _resolved = Transformer._resolved
+    _linear_overlap = Transformer._linear_overlap
     _t_real = Transformer._t_real
     _pipeline_layers = Transformer._pipeline_layers
     _pipeline_interleaved = Transformer._pipeline_interleaved

@@ -225,9 +225,12 @@ class FleetCollector:
 
         def fetch():
             try:
+                # the socket's own timeout only ends the abandoned worker;
+                # twice the deadline, so that it can never fire first and
+                # turn a hung endpoint into a fast failure
                 with urllib.request.urlopen(
                         url.rstrip("/") + "/metrics.json",
-                        timeout=self.scrape_timeout) as r:
+                        timeout=2 * self.scrape_timeout) as r:
                     box.append(json.loads(r.read()))
             except Exception:
                 box.append(None)

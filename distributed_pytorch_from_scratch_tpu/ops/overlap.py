@@ -31,7 +31,21 @@ Ring convention (see `ops.collectives.ring_permute`): shift=+1 sends rank
 i -> i+1, so after s forward hops rank r holds the chunk ORIGINATED by
 rank (r - s) mod n; the reduce ring forwards accumulators the same
 direction, with rank r at step s contributing to the chunk destined for
-rank (r + n-1-s) mod n.
+rank (r + n-1-s) mod n = (r - (s+1)) mod n.
+
+RING ORDER. The full-sequence side of both ops (ag_matmul's outputs,
+matmul_rs's input) holds its n sequence chunks in the order the ring
+visits them, not in rank order: chunk j is the one that originated at rank
+(r - j) mod n, this rank's own first. Every slice and every placement is
+then at a static offset. The first version kept rank order and wrote each
+partial dot into its output with a `dynamic_update_slice` at an offset that
+depends on the rank: on a v5e those were stand-alone copies, 0.29 ms each
+for a (8, 512, 2560) chunk and 42 ms of a 312 ms step of GPT-2 large at
+dp2 x tp2, more than the rings hid (PERF.md section 6, PR 28). A consumer
+that does not care where a token sits (gelu, the gated product, the next
+matmul_rs) takes ring order as it is; one that does (attention, the loss)
+goes through `ring_order`, the permutation between the two, which is its
+own inverse and its own transpose and fuses into what reads it.
 
 * `bucketed_psum(tree, axes, bucket_mb, reduce_dtype)` — DP/ZeRO-1
   gradient reduction in size-bounded buckets instead of one end-of-step
@@ -122,14 +136,40 @@ def _check_2d(name: str, x: jax.Array) -> None:
                          f"shape {x.shape}")
 
 
-def _slot_slice(a: jax.Array, slot: jax.Array, tl: int) -> jax.Array:
-    """a[..., slot*tl : (slot+1)*tl, :] with a traced slot index."""
-    return lax.dynamic_slice_in_dim(a, slot * tl, tl, axis=-2)
+def _chunks(a: jax.Array, n: int) -> "list[jax.Array]":
+    """The n equal sequence chunks of `a` (dim -2), at static offsets."""
+    tl = a.shape[-2] // n
+    return [lax.slice_in_dim(a, j * tl, (j + 1) * tl, axis=-2)
+            for j in range(n)]
 
 
-def _slot_update(a: jax.Array, upd: jax.Array, slot: jax.Array,
-                 tl: int) -> jax.Array:
-    return lax.dynamic_update_slice_in_dim(a, upd, slot * tl, axis=-2)
+# -------------------------------------------------------------- ring_order --
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def ring_order(x: jax.Array, axis: str = "tp") -> jax.Array:
+    """Permute the n sequence chunks of `x` (dim -2) between rank order and
+    ring order: chunk j of the result is chunk (r - j) mod n of `x` on rank
+    r. The map is an involution, so the same call goes either way, and a
+    permutation's transpose is its inverse, so the VJP is the same call on
+    the cotangent (a plain transpose of the dynamic slices would be the
+    `dynamic_update_slice` copies this layout exists to avoid). `x` is a
+    per-rank tensor (it varies over `axis`)."""
+    n = _axis_size(axis)
+    if n == 1:
+        return x
+    if x.shape[-2] % n != 0:
+        raise ValueError(
+            f"ring_order: sequence length {x.shape[-2]} not divisible by "
+            f"axis {axis!r} size {n}")
+    idx = lax.axis_index(axis)
+    tl = x.shape[-2] // n
+    return jnp.concatenate(
+        [lax.dynamic_slice_in_dim(x, jnp.mod(idx - j, n) * tl, tl, axis=-2)
+         for j in range(n)], axis=-2)
+
+
+ring_order.defvjp(lambda x, axis: (ring_order(x, axis), None),
+                  lambda axis, _, dy: (ring_order(dy, axis),))
 
 
 # ---------------------------------------------------------- ring_all_gather --
@@ -176,8 +216,9 @@ def ring_all_gather(x: jax.Array, axis: str, dim: int = 0) -> jax.Array:
 def _ag_matmul_impl(x: jax.Array, ws: Tuple[jax.Array, ...],
                     axis: str, quantized: bool) -> Tuple[jax.Array, ...]:
     """Ring all-gather-matmul forward: x (..., t/n, d) seq-sharded over
-    `axis`, each w (d, o_local) -> each y (..., t, o_local), equal to
-    `all_gather(x, axis, tiled over -2) @ w` up to summation order.
+    `axis`, each w (d, o_local) -> each y (..., t, o_local) in RING ORDER,
+    equal to `ring_order(all_gather(x, axis, tiled over -2) @ w)` up to
+    summation order.
 
     quantized=True: the chunk is quantized ONCE here at its origin and the
     int8 codes + per-row scales circulate instead of the full-precision
@@ -185,10 +226,7 @@ def _ag_matmul_impl(x: jax.Array, ws: Tuple[jax.Array, ...],
     dequantizes before its dots — the output equals the monolithic path
     applied to dq(q(x)), one rounding per element total."""
     n = _axis_size(axis)
-    idx = lax.axis_index(axis)
-    tl = x.shape[-2]
-    outs = [jnp.zeros((*x.shape[:-2], tl * n, w.shape[-1]), x.dtype)
-            for w in ws]
+    parts = [[] for _ in ws]
     if quantized:
         q, sc = quantize_rows(x)
         chunk = dequantize_rows(q, sc, x.dtype)
@@ -203,12 +241,12 @@ def _ag_matmul_impl(x: jax.Array, ws: Tuple[jax.Array, ...],
                 sc = ring_permute(sc, axis, shift=1)
             else:
                 nxt = ring_permute(chunk, axis, shift=1)
-        slot = jnp.mod(idx - s, n)  # origin rank of the chunk in hand
+        # the chunk in hand originated at rank (r - s): ring position s
         for j, w in enumerate(ws):
-            outs[j] = _slot_update(outs[j], chunk @ w, slot, tl)
+            parts[j].append(chunk @ w)
         if s < n - 1:
             chunk = (dequantize_rows(q, sc, x.dtype) if quantized else nxt)
-    return tuple(outs)
+    return tuple(jnp.concatenate(p, axis=-2) for p in parts)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
@@ -220,7 +258,8 @@ def ag_matmul(x: jax.Array, ws: Tuple[jax.Array, ...],
     `x` is this rank's (..., t/n, d) sequence chunk; `ws` a tuple of local
     (d, o_j) weights sharing ONE ring (same bytes on the wire as a single
     all-gather, however many weights consume it). Returns a tuple of
-    (..., t, o_j) full-sequence outputs. The custom VJP reduces the fan-out
+    (..., t, o_j) full-sequence outputs in RING ORDER (module docstring;
+    `ring_order` gives rank order). The custom VJP reduces the fan-out
     cotangents on one reverse ring (dx) while re-gathering x chunks for the
     weight grads on a second — both overlapped the same way as the forward.
 
@@ -248,9 +287,8 @@ def _ag_matmul_fwd(x, ws, axis, quantized):
 def _ag_matmul_bwd(axis, quantized, res, dys):
     x, ws = res
     n = _axis_size(axis)
-    idx = lax.axis_index(axis)
-    tl = x.shape[-2]
     bdims = tuple(range(x.ndim - 1))  # batch+seq dims to contract for dw
+    dy_chunks = [_chunks(dy, n) for dy in dys]   # ring order, like the ys
 
     dx_acc = None
     dws = [jnp.zeros_like(w) for w in ws]
@@ -268,18 +306,16 @@ def _ag_matmul_bwd(axis, quantized, res, dys):
                 sc = ring_permute(sc, axis, shift=1)
             else:
                 nxt = ring_permute(chunk, axis, shift=1)
-        # dw ring: the chunk in hand originated at rank `slot`; it pairs
-        # with the cotangent rows of that same slot
-        slot = jnp.mod(idx - s, n)
+        # dw ring: the chunk in hand originated at rank (r - s), ring
+        # position s; it pairs with the cotangent rows of that position.
         # dx ring (the conjugate reduce-scatter): this step contributes the
-        # partial destined for rank `dest`, whose accumulator arrives next
-        dest = jnp.mod(idx + (n - 1 - s), n)
+        # partial destined for rank (r - (s+1)), ring position s+1, whose
+        # accumulator arrives next
         part = None
-        for j, (w, dy) in enumerate(zip(ws, dys)):
-            dy_slot = _slot_slice(dy, slot, tl)
+        for j, w in enumerate(ws):
             dws[j] = dws[j] + jnp.tensordot(
-                chunk, dy_slot, axes=(bdims, bdims))
-            p = _slot_slice(dy, dest, tl) @ w.T
+                chunk, dy_chunks[j][s], axes=(bdims, bdims))
+            p = dy_chunks[j][(s + 1) % n] @ w.T
             part = p if part is None else part + p
         if s == 0:
             dx_acc = part
@@ -302,20 +338,21 @@ ag_matmul.defvjp(_ag_matmul_fwd, _ag_matmul_bwd)
 
 def _matmul_rs_impl(x: jax.Array, w: jax.Array, axis: str,
                     quantized: bool) -> jax.Array:
-    """Ring matmul-reduce-scatter forward: x (..., t, f_local), w
-    (f_local, o) -> (..., t/n, o), equal to
-    `psum_scatter(x @ w, axis, scatter over -2)` up to summation order.
+    """Ring matmul-reduce-scatter forward: x (..., t, f_local) in RING
+    ORDER, w (f_local, o) -> (..., t/n, o), equal to
+    `psum_scatter(ring_order(x) @ w, axis, scatter over -2)` up to
+    summation order.
 
     quantized=True: the circulating accumulator requantizes before each
     hop (int8 codes + per-row scales on the wire); the local partial dot
     and the add stay at the original dtype — n-1 roundings end-to-end."""
     n = _axis_size(axis)
-    idx = lax.axis_index(axis)
-    tl = x.shape[-2] // n
+    x_chunks = _chunks(x, n)
     acc = None
     for s in range(n):
-        dest = jnp.mod(idx + (n - 1 - s), n)
-        part = _slot_slice(x, dest, tl) @ w
+        # the partial for rank (r - (s+1)), ring position s+1; the last
+        # step's is this rank's own
+        part = x_chunks[(s + 1) % n] @ w
         # the hop and the next step's dot are independent: wire hides
         if s == 0:
             acc = part
@@ -331,9 +368,10 @@ def matmul_rs(x: jax.Array, w: jax.Array, axis: str = "tp",
               quantized: bool = False) -> jax.Array:
     """Fused matmul-reduce-scatter over a ring (the ag_matmul conjugate).
 
-    `x` holds this rank's partial-product input over the FULL sequence,
-    `w` the local (f, o) weight; the result is this rank's summed (t/n)
-    sequence chunk. Refuses a sequence length the ring cannot chunk evenly
+    `x` holds this rank's partial-product input over the FULL sequence in
+    RING ORDER (module docstring: what `ag_matmul` returns; `ring_order`
+    converts rank order), `w` the local (f, o) weight; the result is this
+    rank's summed (t/n) sequence chunk. Refuses a sequence length the ring cannot chunk evenly
     — pick a t divisible by the axis size (same constraint as
     `sequence_parallel` itself).
 
@@ -361,11 +399,10 @@ def _matmul_rs_fwd(x, w, axis, quantized):
 def _matmul_rs_bwd(axis, quantized, res, dy):
     x, w = res
     n = _axis_size(axis)
-    idx = lax.axis_index(axis)
-    tl = x.shape[-2] // n
     bdims = tuple(range(x.ndim - 1))
+    x_chunks = _chunks(x, n)
 
-    dx = jnp.zeros_like(x)
+    dx_parts = []
     dw = jnp.zeros_like(w)
     # ring-gather the cotangent chunks; quantized mode codes dy ONCE at
     # origin (a gather ring, like the forward ag chunks)
@@ -381,13 +418,13 @@ def _matmul_rs_bwd(axis, quantized, res, dy):
                 sc = ring_permute(sc, axis, shift=1)
             else:
                 nxt = ring_permute(chunk, axis, shift=1)
-        slot = jnp.mod(idx - s, n)
-        dx = _slot_update(dx, (chunk @ w.T).astype(x.dtype), slot, tl)
-        dw = dw + jnp.tensordot(_slot_slice(x, slot, tl), chunk,
-                                axes=(bdims, bdims))
+        # the cotangent in hand is rank (r - s)'s: ring position s of x
+        dx_parts.append((chunk @ w.T).astype(x.dtype))
+        dw = dw + jnp.tensordot(x_chunks[s], chunk, axes=(bdims, bdims))
         if s < n - 1:
             chunk = (dequantize_rows(q, sc, dy.dtype) if quantized else nxt)
-    return dx, _like_primal(dw.astype(w.dtype), w)
+    return (jnp.concatenate(dx_parts, axis=-2),
+            _like_primal(dw.astype(w.dtype), w))
 
 
 matmul_rs.defvjp(_matmul_rs_fwd, _matmul_rs_bwd)

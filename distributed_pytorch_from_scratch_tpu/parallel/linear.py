@@ -36,7 +36,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.collectives import (copy_to, gather_from, reduce_from,
                                reduce_scatter, split_to)
-from ..ops.overlap import ag_matmul, matmul_rs
+from ..ops.overlap import ag_matmul, matmul_rs, ring_order
 
 Params = Dict[str, Any]
 
@@ -47,6 +47,20 @@ def _check_overlap(overlap: str) -> None:
     if overlap not in OVERLAP_MODES:
         raise ValueError(f"overlap must be one of {OVERLAP_MODES}, "
                          f"got {overlap!r}")
+
+
+def _check_seq_order(seq_order: str, on_ring: bool) -> None:
+    """`seq_order` names how the FULL-sequence side of a ring collective
+    matmul holds its chunks: 'rank' (token order, the default) or 'ring'
+    (ops/overlap.py, "RING ORDER": what the rings produce and consume at no
+    cost). 'ring' means something on the ring path only."""
+    if seq_order not in ("rank", "ring"):
+        raise ValueError(f"seq_order must be 'rank' or 'ring', got "
+                         f"{seq_order!r}")
+    if seq_order == "ring" and not on_ring:
+        raise ValueError("seq_order='ring' is the ring collective matmuls' "
+                         "layout: it needs overlap != 'off' and the "
+                         "'seq_sharded' layout")
 
 
 def _torch_linear_init(key: jax.Array, idim: int, odim: int) -> jax.Array:
@@ -96,15 +110,21 @@ class ColumnParallelLinear:
 
     def apply(self, params: Params, x: jax.Array,
               compute_dtype: jnp.dtype = jnp.float32,
-              input_layout: str = "replicated") -> jax.Array:
+              input_layout: str = "replicated",
+              seq_order: str = "rank") -> jax.Array:
         w = params["weight"].astype(compute_dtype)      # local (idim, odim/n)
-        if input_layout == "seq_sharded" and self.overlap != "off":
+        on_ring = input_layout == "seq_sharded" and self.overlap != "off"
+        _check_seq_order(seq_order, on_ring)
+        if on_ring:
             # ring collective matmul: the gather's ppermute hops hide under
             # the per-chunk partial dots; the custom VJP rings the backward
             # too (matmul_rs for dx, a re-gather ring for dw). 'ring_q'
-            # quantizes every hop's payload (ops/overlap.py).
+            # quantizes every hop's payload (ops/overlap.py). The output
+            # comes in ring order; seq_order='ring' hands it on as it is.
             y = ag_matmul(x.astype(compute_dtype), (w,), self.axis,
                           self.overlap == "ring_q")[0]
+            if seq_order == "rank":
+                y = ring_order(y, self.axis)
             return self._epilogue(params, y, compute_dtype)
         if input_layout == "replicated":
             x = copy_to(x, self.axis)                   # bwd: all-reduce input grads
@@ -173,15 +193,21 @@ class RowParallelLinear:
 
     def apply(self, params: Params, x: jax.Array,
               compute_dtype: jnp.dtype = jnp.float32,
-              output_layout: str = "replicated") -> jax.Array:
+              output_layout: str = "replicated",
+              seq_order: str = "rank") -> jax.Array:
         if self.split_input:
             x = split_to(x, self.axis)                  # (.., idim) -> (.., idim/n)
         w = params["weight"].astype(compute_dtype)      # local (idim/n, odim)
-        if output_layout == "seq_sharded" and self.overlap != "off":
+        on_ring = output_layout == "seq_sharded" and self.overlap != "off"
+        _check_seq_order(seq_order, on_ring)
+        if on_ring:
             # ring collective matmul: per-chunk partial dots interleave with
-            # the reduce ring's hops instead of one blocking psum_scatter
-            y = matmul_rs(x.astype(compute_dtype), w, self.axis,
-                          self.overlap == "ring_q")
+            # the reduce ring's hops instead of one blocking psum_scatter.
+            # It reads x in ring order: seq_order='ring' says x already is.
+            x = x.astype(compute_dtype)
+            if seq_order == "rank":
+                x = ring_order(x, self.axis)
+            y = matmul_rs(x, w, self.axis, self.overlap == "ring_q")
         elif output_layout == "replicated":
             y = reduce_from(x.astype(compute_dtype) @ w, self.axis)
         elif output_layout == "seq_sharded":
@@ -199,7 +225,8 @@ class RowParallelLinear:
 
 
 def apply_column_ring_fused(params_list, x: jax.Array, compute_dtype,
-                            axis: str = "tp", quantized: bool = False):
+                            axis: str = "tp", quantized: bool = False,
+                            seq_order: str = "rank"):
     """Several column-parallel projections of ONE seq-sharded input on ONE
     shared ring (wq/wk/wv, gate/up): the fused ag_matmul moves exactly the
     bytes of the single shared all-gather the monolithic path uses, and the
@@ -211,11 +238,16 @@ def apply_column_ring_fused(params_list, x: jax.Array, compute_dtype,
     guarantees). Returns one local (.., t, odim/n) output per entry.
     `quantized` (tp_overlap='ring_q') puts int8 payloads on the shared
     ring — still one quantization per chunk, however many weights ride it.
+    `seq_order='ring'` leaves the outputs in the ring's own chunk order
+    (`_check_seq_order`), for consumers that do not care where a token sits.
     """
+    _check_seq_order(seq_order, True)
     ws = tuple(p["weight"].astype(compute_dtype) for p in params_list)
     ys = ag_matmul(x.astype(compute_dtype), ws, axis, quantized)
     out = []
     for p, y in zip(params_list, ys):
+        if seq_order == "rank":
+            y = ring_order(y, axis)
         if "bias" in p:
             y = y + p["bias"].astype(compute_dtype)
         out.append(y)

@@ -68,20 +68,28 @@ def get_train_args(argv=None) -> argparse.Namespace:
                    help="zigzag: each cp shard gets an equally early+late "
                         "pair of sequence sub-chunks, balancing causal ring "
                         "work ~2x (ring impl only; needs maxlen %% (2*cp)==0)")
-    g.add_argument("--sequence_parallel", action="store_true",
-                   help="Megatron-style SP: shard inter-block activations "
+    g.add_argument("--sequence_parallel", nargs="?", const="on",
+                   choices=["auto", "on", "off"], default="auto",
+                   help="override of a default the program picks. "
+                        "Megatron-style SP shards inter-block activations "
                         "over the tp axis (reduce-scatter/all-gather instead "
-                        "of all-reduce)")
-    g.add_argument("--tp_overlap", choices=["off", "ring", "ring_q"],
-                   default="off",
-                   help="'ring' decomposes the SP tp collectives into ring "
+                        "of all-reduce). 'auto' (default): on when tp > 1, "
+                        "the model is dense and the (cp-local) sequence "
+                        "length divides by tp, off otherwise "
+                        "(models/transformer.resolve_tp_layout); the bare "
+                        "flag means 'on'")
+    g.add_argument("--tp_overlap", choices=["auto", "off", "ring", "ring_q"],
+                   default="auto",
+                   help="override of a default the program picks. 'ring' "
+                        "decomposes the SP tp collectives into ring "
                         "collective matmuls (ops/overlap.py): each ppermute "
                         "hop hides under the partial dot of the chunk in "
                         "hand, fwd and bwd; 'ring_q' puts int8 codes + "
                         "per-row scales on every hop (half the bf16 chunk "
-                        "bytes; pinned bounds in tests/test_quant.py); "
-                        "requires --sequence_parallel. 'off' stays "
-                        "bit-identical to the monolithic path")
+                        "bytes; pinned bounds in tests/test_quant.py); both "
+                        "need sequence parallelism. 'off' is the monolithic "
+                        "path. 'auto' (default): 'ring' wherever sequence "
+                        "parallelism is on by default and --pp_size is 1")
     g.add_argument("--zero", type=int, choices=[0, 1, 2, 3], default=None,
                    help="ZeRO stage over the dp axis (training/zero.py): "
                         "1 shards the Adam moments (2/dp optimizer memory); "
@@ -94,7 +102,8 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "(peak param HBM full/dp + one layer — the unlock "
                         "for models whose replica exceeds HBM x tp). "
                         "Stages 2/3: dense models, --pp_size 1, and "
-                        "--sequence_parallel whenever tp > 1; stage 3 "
+                        "sequence parallelism whenever tp > 1 (the "
+                        "default there); stage 3 "
                         "needs remat (dots/true/auto) and an f32 "
                         "--dp_reduce_dtype")
     g.add_argument("--zero1", action="store_true",
@@ -515,14 +524,6 @@ def train(args: argparse.Namespace) -> dict:
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
         zero_stage = resolve_zero_stage(args.zero, args.zero1)
-        remat_key = args.remat
-        if remat_key == "auto":
-            from .training.memory import select_remat
-            remat_key = select_remat(
-                cfg, args.batch_size, maxlen, tp=args.tp_size,
-                world=mesh_cfg.world_size, zero_stage=zero_stage,
-                dp=args.dp_size, family=args.family,
-                sequence_parallel=args.sequence_parallel)
         t_bucket = 0
         if args.seq_bucket:
             if args.seq_bucket < 1 or args.seq_bucket % 128:
@@ -547,6 +548,25 @@ def train(args: argparse.Namespace) -> dict:
                       f"tiles; CE masks the pad targets; tok/s and MFU "
                       f"count real tokens)")
         attn_t_real = maxlen if t_bucket else None
+        # --sequence_parallel / --tp_overlap override a default the program
+        # picks: the model resolves it per trace, and the same resolver
+        # answers here for the memory estimate, ZeRO's scope and the
+        # analytic report, so all of them see the layout the step runs
+        from .models.transformer import resolve_tp_layout
+        sp_arg = {"auto": "auto", "on": True,
+                  "off": False}[args.sequence_parallel]
+        sp, tp_overlap = resolve_tp_layout(
+            sp_arg, args.tp_overlap, tp_size=args.tp_size,
+            t_local=(t_bucket or maxlen) // args.cp_size,
+            dense=not cfg.num_experts, pp_size=args.pp_size)
+        remat_key = args.remat
+        if remat_key == "auto":
+            from .training.memory import select_remat
+            remat_key = select_remat(
+                cfg, args.batch_size, maxlen, tp=args.tp_size,
+                world=mesh_cfg.world_size, zero_stage=zero_stage,
+                dp=args.dp_size, family=args.family,
+                sequence_parallel=sp)
         if zero_stage == 3 and args.dp_reduce_dtype != "f32":
             # before the generic needs-a-bucket check: adding a bucket
             # would not make a compressed wire apply to stage 3
@@ -586,10 +606,11 @@ def train(args: argparse.Namespace) -> dict:
                     f"params are pp-replicated and their reduction axes "
                     f"depend on the pipeline head layout — use --zero 1 "
                     f"under pp")
-            if args.tp_size > 1 and not args.sequence_parallel:
+            if args.tp_size > 1 and not sp:
                 raise SystemExit(
                     f"--zero {zero_stage} with --tp_size {args.tp_size} "
-                    f"needs --sequence_parallel: the non-SP path "
+                    f"needs sequence parallelism (the default where the "
+                    f"sequence length divides by tp): the non-SP path "
                     f"all-reduces inside every row-parallel layer, so "
                     f"per-shard cotangent bookkeeping is depth-dependent "
                     f"(turn SP on, or drop to --zero 1)")
@@ -607,7 +628,7 @@ def train(args: argparse.Namespace) -> dict:
             model = GPT2Transformer(cfg, tp_size=args.tp_size,
                                     cp_size=args.cp_size, cp_impl=args.cp_impl,
                                     cp_layout=args.cp_layout,
-                                    sequence_parallel=args.sequence_parallel,
+                                    sequence_parallel=sp_arg,
                                     tp_overlap=args.tp_overlap,
                                     ep_size=args.ep_size, pp_size=args.pp_size,
                                     pp_microbatches=args.pp_microbatches,
@@ -620,7 +641,7 @@ def train(args: argparse.Namespace) -> dict:
             model = Transformer(cfg, tp_size=args.tp_size,
                             cp_size=args.cp_size, cp_impl=args.cp_impl,
                             cp_layout=args.cp_layout,
-                            sequence_parallel=args.sequence_parallel,
+                            sequence_parallel=sp_arg,
                             tp_overlap=args.tp_overlap,
                             ep_size=args.ep_size, pp_size=args.pp_size,
                             pp_microbatches=args.pp_microbatches,
@@ -649,6 +670,8 @@ def train(args: argparse.Namespace) -> dict:
               f"mesh=dp{args.dp_size} x pp{args.pp_size} x cp{args.cp_size} x "
               f"ep{args.ep_size} x tp{args.tp_size}, "
               f"compute={cfg.compute_dtype}, attn={attn_impl}"
+              + (f", sp={'on' if sp else 'off'}, tp_overlap={tp_overlap}"
+                 if args.tp_size > 1 else "")
               + (f", zero={zero_stage}" if zero_stage else "")
               + f" on {jax.device_count()} x {dev0.platform} "
                 f"[{dev0.device_kind}]")
@@ -846,7 +869,7 @@ def train(args: argparse.Namespace) -> dict:
             analytic = analytic_phase_report(_attr(
                 cfg, args.batch_size, maxlen, remat=remat_key,
                 family=args.family, tp=args.tp_size,
-                sp=args.sequence_parallel, tp_overlap=args.tp_overlap,
+                sp=sp, tp_overlap=tp_overlap,
                 dp=args.dp_size, dp_bucket_mb=args.dp_reduce_bucket_mb,
                 dp_reduce_dtype=args.dp_reduce_dtype, chip=chip,
                 world=mesh_cfg.world_size, zero_stage=zero_stage))
@@ -1290,6 +1313,7 @@ def train(args: argparse.Namespace) -> dict:
                         "cp": args.cp_size, "ep": args.ep_size,
                         "tp": args.tp_size},
                "attn_impl": attn_impl,
+               "sequence_parallel": sp, "tp_overlap": tp_overlap,
                "peak_flops_per_chip": peak_chip,
                "compile_s": aot["compile_s"],
                "collectives": aot["collectives"],

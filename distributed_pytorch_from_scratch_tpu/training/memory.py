@@ -309,7 +309,7 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
         vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
         dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
         ffn_inputs=2 if model.uses_rope else 1,   # SwiGLU | GPT-2's MLP
-        sequence_parallel=model.sequence_parallel)
+        sequence_parallel=model.tp_layout(t)[0])
     return _pick(parts, model.remat_budget_gib, None, allow_false=False,
                  verbose=True,
                  note=f"; traced b{b} x t{t}, tp{model.tp_size}")

@@ -194,12 +194,23 @@ def _check_bucketed_scope(model, what: str) -> None:
             f"{what} requires pp_size == 1: non-layer params are "
             f"pp-replicated and their reduction axes depend on the "
             f"pipeline head layout — use the default reducer")
-    if model.tp_size > 1 and not model.sequence_parallel:
+    if model.sequence_parallel is False:
+        _require_sp(model, 0, what)     # said by name: no sequence decides
+
+
+def _require_sp(model, t_local: int, what: str) -> bool:
+    """The model's sequence parallelism as a batch of cp-local sequence
+    length `t_local` is traced, refused where the hand-reduced grad paths
+    cannot do without it: an explicit False at build time, an 'auto' that
+    resolves off (a sequence the tp ranks cannot split) at trace time."""
+    sp = model.tp_layout(t_local)[0]
+    if model.tp_size > 1 and not sp:
         raise ValueError(
             f"{what} with tp > 1 requires sequence_parallel: the non-SP "
             f"path all-reduces inside every row-parallel layer, so "
             f"per-shard cotangent bookkeeping is depth-dependent — use "
             f"the default reducer (or turn SP on)")
+    return sp
 
 
 # ------------------------------------------------- bucketed grad reduction --
@@ -304,7 +315,6 @@ def build_bucketed_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
                          f"{zero_stage}; stage 3 is build_zero3_grad_fn")
     specs = model.specs()
     batch_axes = ("dp", "ep", "cp")
-    sp = model.sequence_parallel
     leaf_specs = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     dp = mesh.shape[DP_AXIS]
     if zero_stage >= 2:
@@ -320,6 +330,8 @@ def build_bucketed_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     psum_fanout = _psum_fanout(mesh, batch_axes)
 
     def shard_fn(params, input_ids, target_ids, position_ids):
+        sp = _require_sp(model, input_ids.shape[1],
+                         "bucketed DP grad reduction")
         loss, grads = jax.value_and_grad(
             lambda p: model.loss_shard(p, input_ids, target_ids,
                                        position_ids, mode=loss_mode))(params)
@@ -415,12 +427,13 @@ def build_zero3_grad_fn(model, mesh: Mesh, loss_mode: str = "vocab_parallel",
     pspecs = zero3_specs(model, mesh, dp_axis)
     dims = zero3_dims(model, dp)
     batch_axes = ("dp", "ep", "cp")
-    sp = model.sequence_parallel
     leaf_specs = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     leaf_dims = jax.tree.leaves(dims)
     psum_fanout = _psum_fanout(mesh, batch_axes)
 
     def shard_fn(params, input_ids, target_ids, position_ids):
+        sp = _require_sp(model, input_ids.shape[1],
+                         "ZeRO-3 (gather-on-demand params)")
         def loss_of(p):
             full = {}
             for key, sub in p.items():

@@ -192,16 +192,28 @@ CELL1_GIB = {"true": (8.598, 9.139), "attn_proj": (8.598, 9.138),
 CELL2_GIB = {"true": (7.419, 8.386), "attn_proj": (8.090, 9.695),
              "ffn": (9.457, 11.025), "flash": (9.809, 11.365),
              "dots": (10.864, 12.425)}
+# Cell 2 in the layout the model picks for itself at tp 2 since PR 28
+# (sequence parallelism over the ring matmuls: every (b, t, d) stack is
+# halved): the chip ran the rung `auto` picks there, 'dots' (my chip runs,
+# PR 28: 10,907,819,520 bytes); the plans are of the ring programs compiled
+# for a described v5e:2x2.
+CELL2_SP = dict(CELL2, sequence_parallel=True)
+CELL2_SP_GIB = {"true": (None, 7.651), "attn_proj": (None, 8.158),
+                "ffn": (None, 10.368), "flash": (None, 10.715),
+                "dots": (10.159, 11.694)}
 
 
 @pytest.mark.parametrize("rung", sorted(CELL1_GIB))
-@pytest.mark.parametrize("cell", ["medium-b12-tp1", "large-b8-tp2"])
+@pytest.mark.parametrize("cell", ["medium-b12-tp1", "large-b8-tp2",
+                                  "large-b8-tp2-sp"])
 def test_estimate_is_what_the_chip_counts(cell, rung):
     """Within 1% of the chip's count where a rung was run on the chip
     (the PR's criterion is 8%), and never over the compiler's plan."""
     from distributed_pytorch_from_scratch_tpu.config import ModelConfig
-    shape, kw, table = ((MEDIUM, CELL1, CELL1_GIB) if cell.startswith("medium")
-                        else (LARGE, CELL2, CELL2_GIB))
+    shape, kw, table = {"medium-b12-tp1": (MEDIUM, CELL1, CELL1_GIB),
+                        "large-b8-tp2": (LARGE, CELL2, CELL2_GIB),
+                        "large-b8-tp2-sp": (LARGE, CELL2_SP, CELL2_SP_GIB),
+                        }[cell]
     chip, planned = table[rung]
     est = estimate_step_gib(ModelConfig(**shape), remat=rung, **kw)
     if chip is not None:
@@ -233,6 +245,10 @@ def test_select_remat_picks_the_cells_rungs(capsys):
     assert "dots=13.06GiB" in err and "ffn=10.79GiB" in err
     assert select_remat(ModelConfig(**LARGE), budget_gib=limit,
                         verbose=False, **CELL2) == "flash"
+    # ... and in the layout the model resolves to at tp 2 (PR 28), whose
+    # halved stacks leave room for the top rung
+    assert select_remat(ModelConfig(**LARGE), budget_gib=limit,
+                        verbose=False, **CELL2_SP) == "dots"
     # a caller that knows it takes no snapshot passes the true reserve
     assert select_remat(ModelConfig(**MEDIUM), budget_gib=limit,
                         reserve_gib=0.0, verbose=False, **CELL1) == "dots"

@@ -27,11 +27,14 @@ from jax.sharding import PartitionSpec as P
 from distributed_pytorch_from_scratch_tpu.config import (
     IGNORE_INDEX, MeshConfig, ModelConfig)
 from distributed_pytorch_from_scratch_tpu.models.gpt2 import GPT2Transformer
-from distributed_pytorch_from_scratch_tpu.models.transformer import Transformer
+from distributed_pytorch_from_scratch_tpu.models.transformer import (
+    Transformer, resolve_tp_layout)
+from distributed_pytorch_from_scratch_tpu.models.vanilla import (
+    VanillaGPT2, VanillaTransformer)
 from distributed_pytorch_from_scratch_tpu.ops.collectives import (
     gather_from, reduce_scatter, split_to)
 from distributed_pytorch_from_scratch_tpu.ops.overlap import (
-    ag_matmul, bucket_partition, matmul_rs)
+    ag_matmul, bucket_partition, matmul_rs, ring_order)
 from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.zero import (
     build_bucketed_grad_fn)
@@ -62,7 +65,9 @@ def assert_trees_close(a, b, rtol=1e-4, atol=1e-5):
 @pytest.mark.parametrize("nw", [1, 3])
 def test_ag_matmul_matches_gather_dot_oracle(tp, nw):
     """ag_matmul == all_gather(x, seq) @ w, values and jacrev grads, for a
-    single weight and for the fused multi-weight ring (wq/wk/wv shape)."""
+    single weight and for the fused multi-weight ring (wq/wk/wv shape). The
+    ring's outputs come in ring order (ops/overlap.py); `ring_order` puts
+    them in rank order, where the position-dependent coefficients apply."""
     mesh = make_mesh(MeshConfig(dp=1, tp=tp))
     b, t, d = 2, 8, 6
     key = jax.random.key(0)
@@ -74,7 +79,8 @@ def test_ag_matmul_matches_gather_dot_oracle(tp, nw):
 
     def ring_loss(x, ws):
         ys = ag_matmul(x, ws, "tp")
-        return sum(jnp.sum(y * c) for y, c in zip(ys, coefs))
+        return sum(jnp.sum(ring_order(y, "tp") * c)
+                   for y, c in zip(ys, coefs))
 
     def mono_loss(x, ws):
         xf = gather_from(x, "tp", tiled_axis=-2)
@@ -98,7 +104,8 @@ def test_ag_matmul_matches_gather_dot_oracle(tp, nw):
 @pytest.mark.parametrize("tp", [2, 4])
 def test_matmul_rs_matches_dot_scatter_oracle(tp):
     """matmul_rs == psum_scatter(x @ w, seq), values and jacrev grads (the
-    row-parallel seq_sharded pattern: split input, partial dot, reduce)."""
+    row-parallel seq_sharded pattern: split input, partial dot, reduce);
+    the ring reads its input in ring order."""
     mesh = make_mesh(MeshConfig(dp=1, tp=tp))
     b, t, f, o = 2, 8, 8, 10
     key = jax.random.key(1)
@@ -107,7 +114,7 @@ def test_matmul_rs_matches_dot_scatter_oracle(tp):
     tgt = jax.random.normal(jax.random.fold_in(key, 2), (b, t, o))
 
     def ring_loss(x, w, tgt):
-        y = matmul_rs(split_to(x, "tp"), w, "tp")
+        y = matmul_rs(ring_order(split_to(x, "tp"), "tp"), w, "tp")
         return jax.lax.psum(jnp.sum((y - tgt) ** 2), "tp")
 
     def mono_loss(x, w, tgt):
@@ -213,16 +220,100 @@ def test_model_ring_overlap_matches_inside_ring_cp_pipeline():
     assert_trees_close(g1, g0, rtol=2e-4, atol=2e-5)
 
 
+# -------------------------------- the layout the model picks for itself ----
+
+MOE_CFG = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=8, num_layers=2,
+                      vocab_size=96, maxlen=64, num_experts=4)
+
+
+@pytest.mark.parametrize("kw,t,want", [
+    # nothing said: on, over the rings, wherever tp splits a dense model's
+    # sequence; both off on one chip, for MoE and for a sequence that does
+    # not divide; the monolithic path under pp
+    (dict(tp_size=2), 32, (True, "ring")),
+    (dict(tp_size=1), 32, (False, "off")),
+    (dict(tp_size=4), 30, (False, "off")),
+    (dict(tp_size=2, cfg=MOE_CFG), 32, (False, "off")),
+    (dict(tp_size=2, pp_size=2), 32, (True, "off")),
+    # an explicit value does what it always did
+    (dict(tp_size=2, sequence_parallel=True), 32, (True, "off")),
+    (dict(tp_size=4, sequence_parallel=True), 30, (True, "off")),
+    (dict(tp_size=2, sequence_parallel=False), 32, (False, "off")),
+    (dict(tp_size=2, tp_overlap="off"), 32, (True, "off")),
+    (dict(tp_size=2, tp_overlap="ring_q"), 32, (True, "ring_q")),
+    (dict(tp_size=2, cfg=MOE_CFG, sequence_parallel=True), 32,
+     (True, "off")),
+])
+@pytest.mark.parametrize("cls", [Transformer, GPT2Transformer])
+def test_tp_layout_resolution(cls, kw, t, want):
+    kw = dict(kw)
+    model = cls(kw.pop("cfg", CFG), **kw)
+    assert model.tp_layout(t) == want
+    resolved = model._resolved(t)
+    assert (resolved.sequence_parallel, resolved.tp_overlap) == want
+    assert resolved._resolved(t) is resolved
+    assert resolve_tp_layout(
+        model.sequence_parallel, model.tp_overlap, tp_size=model.tp_size,
+        t_local=t, dense=not model.is_moe, pp_size=model.pp_size) == want
+
+
+@pytest.mark.parametrize("family", ["llama", "gpt2"])
+def test_default_model_at_tp2_rides_the_rings_and_matches_vanilla(family):
+    """A model built with nothing but its tp size picks sequence parallelism
+    and the ring collective matmuls at tp 2 (its program is, text for text,
+    the one that asks for both by name) and computes what
+    the unsharded reference computes; at tp 1 the default lowers to the text
+    of an explicit off."""
+    cls, oracle = ((GPT2Transformer, VanillaGPT2(CFG)) if family == "gpt2"
+                   else (Transformer, VanillaTransformer(CFG)))
+    mesh = make_mesh(MeshConfig(dp=2, tp=2), devices=jax.devices()[:4])
+    model = cls(CFG, tp_size=2)
+    params = model.init(jax.random.key(0))
+    ids, tgt, pos = make_batch(jax.random.key(2))
+    grad = jax.jit(jax.value_and_grad(model.make_loss(mesh)))
+    text = grad.lower(params, ids, tgt, pos).as_text()
+    assert "collective_permute" in text
+    explicit = cls(CFG, tp_size=2, sequence_parallel=True, tp_overlap="ring")
+    assert text == jax.jit(jax.value_and_grad(explicit.make_loss(mesh))).lower(
+        params, ids, tgt, pos).as_text()
+    l_sh, g_sh = grad(params, ids, tgt, pos)
+    l_ref, g_ref = jax.value_and_grad(oracle.loss)(params, ids, tgt, pos)
+    np.testing.assert_allclose(float(l_sh), float(l_ref), rtol=1e-5)
+    assert_trees_close(g_sh, g_ref)
+
+    one = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    lower = lambda m: jax.jit(jax.value_and_grad(m.make_loss(one))).lower(
+        params, ids, tgt, pos).as_text()
+    assert lower(cls(CFG)) == lower(
+        cls(CFG, sequence_parallel=False, tp_overlap="off"))
+
+
+def test_indivisible_sequence_is_off_by_default_and_raises_when_asked():
+    mesh = make_mesh(MeshConfig(dp=1, tp=4))
+    params = Transformer(CFG, tp_size=4).init(jax.random.key(0))
+    ids, tgt, pos = make_batch(jax.random.key(2), t=30)     # 30 % 4 != 0
+    off = Transformer(CFG, tp_size=4, sequence_parallel=False)
+    np.testing.assert_allclose(
+        float(Transformer(CFG, tp_size=4).make_loss(mesh)(
+            params, ids, tgt, pos)),
+        float(off.make_loss(mesh)(params, ids, tgt, pos)), rtol=1e-6)
+    for kw in (dict(sequence_parallel=True), dict(tp_overlap="ring")):
+        with pytest.raises(ValueError, match="sequence_parallel"):
+            Transformer(CFG, tp_size=4, **kw).make_loss(mesh)(
+                params, ids, tgt, pos)
+
+
 def test_tp_overlap_validation():
     with pytest.raises(ValueError, match="requires sequence_parallel"):
-        Transformer(CFG, tp_size=2, tp_overlap="ring")
+        Transformer(CFG, tp_size=2, sequence_parallel=False,
+                    tp_overlap="ring")
     with pytest.raises(ValueError, match="'off', 'ring' or 'ring_q'"):
         Transformer(CFG, tp_size=2, sequence_parallel=True,
                     tp_overlap="mesh")
-    moe_cfg = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=8, num_layers=2,
-                          vocab_size=96, maxlen=64, num_experts=4)
+    with pytest.raises(ValueError, match="True, False or 'auto'"):
+        Transformer(CFG, tp_size=2, sequence_parallel="on")
     with pytest.raises(ValueError, match="MoE"):
-        Transformer(moe_cfg, tp_size=2, sequence_parallel=True,
+        Transformer(MOE_CFG, tp_size=2, sequence_parallel=True,
                     tp_overlap="ring")
 
 
@@ -288,7 +379,8 @@ def test_bucketed_reduce_bf16_wire_tolerance():
 def test_bucketed_reduce_scope_refusals():
     mesh = make_mesh(MeshConfig(dp=2, tp=2))
     with pytest.raises(ValueError, match="sequence_parallel"):
-        build_bucketed_grad_fn(Transformer(CFG, tp_size=2), mesh)
+        build_bucketed_grad_fn(
+            Transformer(CFG, tp_size=2, sequence_parallel=False), mesh)
     mesh_pp = make_mesh(MeshConfig(pp=2, tp=2))
     with pytest.raises(ValueError, match="pp_size"):
         build_bucketed_grad_fn(

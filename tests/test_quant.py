@@ -37,7 +37,7 @@ from distributed_pytorch_from_scratch_tpu.models.transformer import Transformer
 from distributed_pytorch_from_scratch_tpu.ops.collectives import (
     gather_from, reduce_scatter, split_to)
 from distributed_pytorch_from_scratch_tpu.ops.overlap import (
-    ag_matmul, matmul_rs, quantized_allreduce)
+    ag_matmul, matmul_rs, quantized_allreduce, ring_order)
 from distributed_pytorch_from_scratch_tpu.ops.quant import (
     dequantize_decode_params, dequantize_groups, dequantize_rows,
     quantize_decode_params, quantize_groups, quantize_rows)
@@ -226,7 +226,8 @@ def test_ring_q_kernels_match_oracles_within_bound(tp):
     wr = jax.random.normal(jax.random.fold_in(key, 3), (d, 10))
 
     def rs_q(x, w):
-        return matmul_rs(split_to(x, "tp"), w, "tp", True)
+        # the ring reads its input in ring order (ops/overlap.py)
+        return matmul_rs(ring_order(split_to(x, "tp"), "tp"), w, "tp", True)
 
     def rs_m(x, w):
         return reduce_scatter(split_to(x, "tp") @ w, "tp", scatter_axis=-2)
@@ -275,7 +276,8 @@ def test_ring_q_refusals():
     """ring_q inherits ring's scope: SP required, no MoE; unknown modes
     still refused; CLI parsers refuse the unsupported combos loudly."""
     with pytest.raises(ValueError, match="sequence_parallel"):
-        Transformer(CFG, tp_size=2, tp_overlap="ring_q")
+        Transformer(CFG, tp_size=2, sequence_parallel=False,
+                    tp_overlap="ring_q")
     moe_cfg = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=8,
                           num_layers=2, vocab_size=96, maxlen=64,
                           num_experts=4)

@@ -83,37 +83,50 @@ def test_every_rung_with_the_flash_kernels_tagged_outputs(rung):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
 
 
-def grad_program(family, remat, mesh_cfg, budget=None):
+def grad_program(family, remat, mesh_cfg, budget=None, **layout):
     mesh = make_mesh(mesh_cfg, devices=jax.devices()[:mesh_cfg.world_size])
     model = FAMILIES[family](CFG, tp_size=mesh_cfg.tp, remat=remat,
-                             remat_budget_gib=budget)
+                             remat_budget_gib=budget, **layout)
     params = jax.eval_shape(model.init, jax.random.key(0))
     return jax.jit(jax.value_and_grad(model.make_loss(mesh))).lower(
         params, *batch())
 
 
-def rematted_all_reduces(hlo_text: str) -> int:
+def rematted(hlo_text: str, collective: str) -> int:
     return sum(1 for line in hlo_text.splitlines()
-               if re.search(r"= .*\ball-reduce(-start)?\(", line)
+               if re.search(rf"= .*\b{collective}(-start)?\(", line)
                and "rematted_computation" in line)
 
 
+@pytest.mark.parametrize("layout", ["replicated", "default"])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
-def test_recomputed_all_reduce_goes_with_the_projection_output(family):
+def test_recomputed_all_reduce_goes_with_the_projection_output(family, layout):
     """dp2 x tp2 on four virtual devices. The layers are one scan, so the
-    compiled gradient holds one layer body: at rung 0 (and on every rung
-    that recomputes the attention projection) its recomputed forward has
-    exactly one tensor-parallel all-reduce, the attention projection's (the
-    MLP projection's is dead code: a layer's output is not a residual). On
-    the rung that keeps `attn_proj`, named past the reduce, there is none."""
+    compiled gradient holds one layer body. In the replicated layout its
+    recomputed forward has, at rung 0 (and on every rung that recomputes
+    the attention projection), exactly one tensor-parallel all-reduce, the
+    attention projection's (the MLP projection's is dead code: a layer's
+    output is not a residual); on the rung that keeps `attn_proj`, named
+    past the reduce, there is none. In the layout a model picks for itself
+    at tp 2 (sequence parallelism over the ring matmuls) the recomputed
+    forward's collectives are ring hops: none is an all-reduce, keeping
+    `attn_proj` (named past the reduce-scatter ring) takes hops away, and
+    on the top rung nothing is left to recompute over the wire."""
     mesh_cfg = MeshConfig(dp=2, tp=2)
-    counts = {r: rematted_all_reduces(
-        grad_program(family, r, mesh_cfg).compile().as_text())
-        for r in REMAT_RUNGS}
+    kw = dict(sequence_parallel=False) if layout == "replicated" else {}
+    texts = {r: grad_program(family, r, mesh_cfg, **kw).compile().as_text()
+             for r in REMAT_RUNGS}
+    reduces = {r: rematted(t, "all-reduce") for r, t in texts.items()}
     keeps = next(i for i, (_, names) in enumerate(REMAT_LADDER)
                  if "attn_proj" in names)
-    assert counts == {r: (1 if i < keeps else 0)
-                      for i, r in enumerate(REMAT_RUNGS)}, counts
+    if layout == "replicated":
+        assert reduces == {r: (1 if i < keeps else 0)
+                           for i, r in enumerate(REMAT_RUNGS)}, reduces
+        return
+    hops = [rematted(texts[r], "collective-permute") for r in REMAT_RUNGS]
+    assert not any(reduces.values()), reduces
+    assert hops[keeps] < hops[keeps - 1] and hops[-1] == 0, hops
+    assert hops == sorted(hops, reverse=True), hops
 
 
 @pytest.mark.parametrize("family", sorted(FAMILIES))

@@ -32,7 +32,9 @@ CELLS = {
         dict(dp=1, tp=1), 12, "ffn"),
     "gpt2-large.train-dp2-tp2": (
         dict(attn_dim=1280, ffn_dim=5120, num_heads=20, num_layers=36),
-        dict(dp=2, tp=2), 16, "flash"),
+        # tp 2: the model picks sequence parallelism over the ring matmuls
+        # (PR 28), whose halved layer-boundary stacks leave room for 'dots'
+        dict(dp=2, tp=2), 16, "dots"),
 }
 
 
@@ -89,14 +91,20 @@ def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
     compiled = step.lower(params, opt, ids, ids, ids).compile()
 
     assert f"remat auto: picked '{want_rung}'" in capsys.readouterr().err
-    assert "tpu_custom_call" in compiled.as_text()       # the flash kernel
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text                     # the flash kernel
+    # the ring collective matmuls' hops, asynchronous, at tp 2 and only there
+    sp, overlap = model.tp_layout(1024)
+    assert (sp, overlap) == ((True, "ring") if mesh_sizes["tp"] > 1
+                             else (False, "off"))
+    assert ("collective-permute-start" in text) == sp
     plan = compiled.memory_analysis()
     args = plan.argument_size_in_bytes / memory.GIB
     planned = args + plan.temp_size_in_bytes / memory.GIB
     assert planned < V5E_LIMIT_GIB, planned
     estimate = memory.estimate_step_gib(
         cfg, batch, 1024, want_rung, tp=mesh_sizes["tp"], world=chips,
-        dp=mesh_sizes["dp"], family="gpt2")
+        dp=mesh_sizes["dp"], family="gpt2", sequence_parallel=sp)
     # the estimate is of what the chip counts, which the plan bounds ...
     assert estimate < planned * 1.01, (estimate, planned)
     # ... and with one more copy of the state (the snapshot) it still fits

@@ -160,7 +160,15 @@ def test_zero1_checkpoint_roundtrip(tmp_path):
 
 # ---------------------------------------------------------------- stage 2 --
 
-def test_zero2_grads_match_whole_tree_reducer():
+# the tp layouts ZeRO's hand-reduced grads are pinned on: sequence
+# parallelism asked for by name (the monolithic gather/reduce-scatter), and
+# what a model picks for itself at tp > 1 (the same over the ring matmuls)
+TP_LAYOUTS = [pytest.param(dict(sequence_parallel=True), id="sp"),
+              pytest.param({}, id="default")]
+
+
+@pytest.mark.parametrize("layout", TP_LAYOUTS)
+def test_zero2_grads_match_whole_tree_reducer(layout):
     """ISSUE 9 acceptance: the bucketed reduce-scatter grad path at dp4 is
     value-parity with the whole-tree transpose-derived reducer (f32, exact
     bound — same tolerances as the stage-1 bucketed parity pin), AND the
@@ -168,7 +176,8 @@ def test_zero2_grads_match_whole_tree_reducer():
     every rank still materialised the full tree)."""
     dp, tp = 4, 2
     mesh = make_mesh(MeshConfig(dp=dp, tp=tp))
-    model = Transformer(CFG, tp_size=tp, sequence_parallel=True)
+    model = Transformer(CFG, tp_size=tp, **layout)
+    assert model.tp_layout(32)[0]
     params = model.init(jax.random.key(0))
     ids, tgt, pos = make_batch(jax.random.key(2), t=32)
     l0, g0 = jax.jit(jax.value_and_grad(
@@ -287,8 +296,9 @@ def test_zero3_loss_trajectory_matches_zero1():
     assert big.addressable_shards[0].data.size * 2 * 2 == big.size
 
 
+@pytest.mark.parametrize("layout", TP_LAYOUTS)
 @pytest.mark.parametrize("family", ["llama", "gpt2"])
-def test_zero3_grads_match_whole_tree_reducer(family):
+def test_zero3_grads_match_whole_tree_reducer(family, layout):
     """The gather-transpose grad path (no explicit dp reduction at all)
     equals the whole-tree reducer on every leaf — the stage-3 sibling of
     the stage-2 parity pin, dp2 x tp2 + SP, BOTH families (the per-layer
@@ -297,7 +307,7 @@ def test_zero3_grads_match_whole_tree_reducer(family):
         GPT2Transformer)
     cls = GPT2Transformer if family == "gpt2" else Transformer
     mesh = make_mesh(MeshConfig(dp=2, tp=2))
-    model = cls(CFG, tp_size=2, sequence_parallel=True, remat="dots")
+    model = cls(CFG, tp_size=2, remat="dots", **layout)
     params = model.init(jax.random.key(0))
     ids, tgt, pos = make_batch(jax.random.key(2), t=32)
     l0, g0 = jax.jit(jax.value_and_grad(
@@ -355,7 +365,8 @@ def test_zero_scope_refusals():
             mesh_pp)
     mesh = make_mesh(MeshConfig(dp=2, tp=2))
     with pytest.raises(ValueError, match="sequence_parallel"):
-        build_zero3_grad_fn(Transformer(CFG, tp_size=2), mesh)
+        build_zero3_grad_fn(
+            Transformer(CFG, tp_size=2, sequence_parallel=False), mesh)
     # stage 3 without remat would re-materialise the full replica as
     # backward residuals — refused, not silently absorbed
     with pytest.raises(ValueError, match="remat"):
