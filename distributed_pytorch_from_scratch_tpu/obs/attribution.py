@@ -138,28 +138,28 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
     """MXU work the fwd flash kernel performs at this (t, blocks) vs the
     causal ideal — the quantified 't=1000 -> 1024 padding waste' suspect.
 
-    Counts live (q-block, k-block) tiles with the kernel's own
-    `block_live` predicate; work = live tiles x bq x bk score elements.
-    `waste_ratio` = work / ideal (1.0 = perfect causal skip; the shipped
-    1024x1024 default at t=1000 computes the FULL padded square = ~2.1x).
-    `t_real` < t prices the pad-aware bucketed path (attn_t_real).
+    Reads the kernel's own static plan (`causal_plan_stats`, the function
+    the kernels walk and `_fwd_call`'s cost_estimate prices): a tile is a
+    SUB-TILE of a grid block here, `live_tiles` those the plan computes
+    (masked or not), work = their score elements. `waste_ratio` = work /
+    ideal (1.0 = perfect causal skip; the shipped 1024x1024 grid block at
+    t=1024 computes 10 of its 16 256-wide sub-tile columns: 1.25, where the
+    whole square was 2.0). `t_real` < t prices the pad-aware bucketed path
+    (attn_t_real).
     """
+    from ..ops.pallas.flash_attention import causal_plan_stats
     tiling = resolve_flash_tiling(t, block_q, block_k, head_dim, dtype)
     t_pad, bq, bk = tiling["t_pad"], tiling["block_q"], tiling["block_k"]
     tr = t if t_real is None else t_real
-    num_qb, num_kb = t_pad // bq, t_pad // bk
-    live = 0
-    for qi in range(num_qb):
-        for ki in range(num_kb):
-            if (ki * bk <= qi * bq + bq - 1 and ki * bk < tr
-                    and qi * bq < tr):
-                live += 1
-    work = live * bq * bk
+    plan = causal_plan_stats(t_pad, bq, bk, tr, head_dim)
+    live = plan["computed_unmasked"] + plan["computed_masked"]
     ideal = tr * (tr + 1) / 2
     return {"t_pad": t_pad, "block_q": bq, "block_k": bk,
-            "live_tiles": live, "total_tiles": num_qb * num_kb,
-            "work_elems": work, "ideal_elems": ideal,
-            "waste_ratio": work / ideal}
+            "sub_q": plan["sub_q"], "sub_k": plan["sub_k"],
+            "live_tiles": live, "total_tiles": live + plan["skipped"],
+            "masked_tiles": plan["computed_masked"],
+            "work_elems": plan["work_elems"], "ideal_elems": ideal,
+            "waste_ratio": plan["work_elems"] / ideal}
 
 
 @dataclasses.dataclass
@@ -204,7 +204,8 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
         PhaseCost("attention", attn_elems * 4 * hd,
                   L * (N * (2 * d + 2 * kd) * A + N * h * 4),
                   f"{stats['live_tiles']}/{stats['total_tiles']} live "
-                  f"{stats['block_q']}x{stats['block_k']} tiles, "
+                  f"{stats['sub_q']}x{stats['sub_k']} sub-tiles of "
+                  f"{stats['block_q']}x{stats['block_k']} blocks, "
                   f"{stats['waste_ratio']:.2f}x causal-ideal work"),
         PhaseCost("wo_proj", L * 2 * N * d * d,
                   L * (2 * N * d * A + d * d * A)),

@@ -34,6 +34,11 @@ public `t_real` argument makes the kernels pad-aware for sequence
 bucketing: a t=1024 buffer holding 1000 real tokens does ~1000 tokens of
 work (dead tiles are skipped by the same grid guards as the internal
 padding), with exact zeros and exact zero gradients on the pad rows.
+
+PR 30: inside a grid tile the kernels walk a static causal sub-tile plan
+(`causal_subtile_plan`), so the one-tile-a-head shape the table picks at
+t = 1024 no longer computes the dead half of its square, and `t_real`
+skips inside a tile too.
 """
 
 from __future__ import annotations
@@ -41,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,68 +83,315 @@ def _out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+# ------------------------------------------------------ causal sub-tile plan
+#
+# The block table's winner at t = 1024, head_dim 64 is ONE grid tile a head
+# (DEFAULT_BLOCK_Q's sweep note: grid-step overhead makes smaller grid
+# blocks slower), so the grid guards can skip nothing there. What the
+# kernels skip instead is decided inside the tile, at trace time: the tile's
+# (block_q x block_k) score square is cut into sub-tiles, and a plan that
+# depends on static values alone says which are never computed (wholly
+# above the diagonal, or wholly at or past t_real), which are computed with
+# no iota / compare / select (wholly live), and which build the mask (the
+# diagonal or the t_real edge crosses them). In the backward, adjacent
+# sub-tiles of one kind run as one rectangle.
+
+# Sub-tile shape (sub_q, sub_k). Swept on v5e (TPU v5 lite, jax 0.9.0, PR 30)
+# at the two benchmark cells' shapes, t=1024 hd64 bf16, one tile a head;
+# device time of one call from a profiler capture (`python scripts/
+# tune_flash_blocks.py --subtile --bh 192,80 --edges 1024,512,256,128,128x256`;
+# the host clock around a call this short reads 0.5 ms of dispatch on top).
+# ms a call, forward / backward (both set to the row's shape):
+#
+#   sub_q x sub_k    b*h=192 (gpt2-medium b12)   b*h=80 (gpt2-large dp2 x tp2)
+#   before this PR     0.657 / 1.364               0.273 / 0.568
+#   1024 (one masked)  0.615 / 1.150               0.255 / 0.479
+#   512                0.501 / 0.896               0.207 / 0.374
+#   256                0.487 / 0.833               0.202 / 0.347
+#   128                0.523 / 1.105               0.217 / 0.461
+#   128 x 256          0.475 / 0.833               0.198 / 0.347
+#   256 x 128          0.578 / 1.095
+#   256 x 512, 512 x 256   0.498 / 0.896, 0.558 / 0.954
+#
+# The area computed falls 1.0 -> 0.75 -> 0.625 -> 0.5625 from 1024 to 128, the
+# time does not follow below 256: at head_dim 64 every dot uses half the
+# 128-wide MXU (K = 64 or N = 64) and the kernels are bound by it, not by the
+# vector unit (knocking out the exp or the mask moves the forward by under
+# 0.02 ms, knocking out p @ v by 0.125), so what a finer plan saves in area
+# it loses in shorter dots. Two more readings shaped the kernels: the
+# forward is fastest on UNMERGED sub-tiles (a 128 x 256 float32 tile is 32
+# vregs; one merged 256 x 768 rectangle a sub-row read 0.527 against 0.487),
+# the backward on MERGED rectangles (0.833 against 0.887); and DMA alone
+# (no compute) is 0.300 / 0.668 ms, of which the (t, 1) float32 lse and
+# delta blocks, padded to 128 lanes in HBM, are 0.31 of the backward's.
+FWD_SUBTILE = (128, 256)
+BWD_SUBTILE = (256, 256)
+
+
+def _subtile_shape(block_q: int, block_k: int, head_dim: int,
+                   backward: bool) -> Tuple[int, int]:
+    """(sub_q, sub_k) inside a (block_q x block_k) grid tile. A block no
+    larger than the sub-tile is one sub-tile: the plan of a 128-token tile
+    is a single masked sub-tile, the kernels' text before sub-tiles; it
+    skips something from 512-token blocks up. `head_dim` is an input so
+    that a sweep at another width has a place to land; only 64 has been
+    swept."""
+    del head_dim
+    sq, sk = BWD_SUBTILE if backward else FWD_SUBTILE
+    return min(block_q, sq), min(block_k, sk)
+
+
+def _runs(cells, major: int, minor: int, merge: bool):
+    """A grid of sub-tile flags (None skipped, False unmasked, True masked;
+    `cells[a][b]`, edges `major` x `minor`) as
+    ((a0, a_len, ((b0, b_len, masked), ...)), ...). With `merge`, runs of
+    one flag along b become one rectangle, and neighbours along a are fused
+    where they hold the same unmasked runs."""
+    out = []
+    for a, row in enumerate(cells):
+        runs = []
+        for b, flag in enumerate(row):
+            if flag is None:
+                continue
+            if merge and runs and runs[-1][2] == flag \
+                    and runs[-1][0] + runs[-1][1] == b * minor:
+                runs[-1] = (runs[-1][0], runs[-1][1] + minor, flag)
+            else:
+                runs.append((b * minor, minor, flag))
+        if not runs:
+            continue
+        runs = tuple(runs)
+        prev = out[-1] if out else None
+        if (merge and prev and prev[2] == runs
+                and prev[0] + prev[1] == a * major
+                and not any(m for _, _, m in runs)):
+            out[-1] = (prev[0], prev[1] + major, runs)
+        else:
+            out.append((a * major, major, runs))
+    return tuple(out)
+
+
+class SubtilePlan(NamedTuple):
+    """What one (block_q x block_k) grid tile computes. Coordinates are
+    tile-relative; entry (r, c) is live iff c <= r + diag and r < cut.
+
+    Two views of the same computed sub-tiles, for the two loop orders:
+    `bands`: ((r0, rows, ((c0, cols, masked), ...)), ...) — query sub-rows,
+    each with its key rectangles; `columns`: ((c0, cols, ((r0, rows,
+    masked), ...)), ...) — key sub-columns, each with its query rectangles.
+    The three counts are in sub-tiles of sub_q x sub_k."""
+
+    sub_q: int
+    sub_k: int
+    diag: int
+    cut: int
+    bands: tuple
+    columns: tuple
+    computed_unmasked: int
+    computed_masked: int
+    skipped: int
+
+    @property
+    def work_elems(self) -> int:
+        """Score entries the tile computes (live or masked)."""
+        return (self.computed_unmasked + self.computed_masked) \
+            * self.sub_q * self.sub_k
+
+
+@functools.lru_cache(maxsize=None)
+def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
+                        k_block: int, t_real: int, head_dim: int,
+                        backward: bool = False) -> SubtilePlan:
+    """The static plan of grid tile (q_block, k_block): the ONE source for
+    what the kernels walk, what `_fwd_call`'s cost_estimate counts and what
+    `obs/attribution.flash_tile_stats` reports.
+
+    Causality with a real length reduces to two tile-relative numbers:
+    `diag` = first row - first column (the diagonal's place in the tile)
+    and `cut` = t_real - first row (rows at or past it are dead; a live
+    row r < t_real only sees columns c <= r, so no column test is needed).
+    Both are clamped to where they stop mattering, so every tile wholly
+    under the diagonal and inside t_real has the same plan."""
+    sq, sk = _subtile_shape(block_q, block_k, head_dim, backward)
+    diag = max(-block_q, min(q_block * block_q - k_block * block_k,
+                             block_k - 1))
+    cut = max(0, min(t_real - q_block * block_q, block_q))
+    cells = []
+    for r0 in range(0, block_q, sq):
+        last_live = min(r0 + sq, cut) - 1   # < r0: every row is dead
+        cells.append([
+            None if last_live < r0 or c0 > last_live + diag
+            else r0 + sq > cut or c0 + sk - 1 > r0 + diag
+            for c0 in range(0, block_k, sk)])
+    flat = [f for row in cells for f in row]
+    # the backward walks merged rectangles, the forward single sub-tiles
+    # (FWD_SUBTILE's sweep note)
+    return SubtilePlan(
+        sq, sk, diag, cut, _runs(cells, sq, sk, backward),
+        _runs([list(col) for col in zip(*cells)], sk, sq, backward),
+        flat.count(False), flat.count(True), flat.count(None))
+
+
+def causal_plan_stats(t_pad: int, block_q: int, block_k: int, t_real: int,
+                      head_dim: int, backward: bool = False
+                      ) -> Dict[str, int]:
+    """`causal_subtile_plan` summed over the grid of one head."""
+    out = {"computed_unmasked": 0, "computed_masked": 0, "skipped": 0,
+           "work_elems": 0}
+    for qb in range(t_pad // block_q):
+        for kb in range(t_pad // block_k):
+            plan = causal_subtile_plan(block_q, block_k, qb, kb, t_real,
+                                       head_dim, backward)
+            out["computed_unmasked"] += plan.computed_unmasked
+            out["computed_masked"] += plan.computed_masked
+            out["skipped"] += plan.skipped
+            out["work_elems"] += plan.work_elems
+    out["sub_q"], out["sub_k"] = plan.sub_q, plan.sub_k
+    return out
+
+
+def _tile_plans(block_q: int, block_k: int, num_qb: int, num_kb: int,
+                t_real: int, head_dim: int, backward: bool = False):
+    """The distinct plans of the grid's live tiles. A plan is a function of
+    its clamped (diag, cut), which is how a kernel picks it from the
+    program ids (`_plan_is`)."""
+    plans = {causal_subtile_plan(block_q, block_k, qb, kb, t_real, head_dim,
+                                 backward)
+             for qb in range(num_qb) for kb in range(num_kb)}
+    return sorted((p for p in plans if p.bands),
+                  key=lambda p: (p.diag, p.cut))
+
+
+def _plan_is(plan: SubtilePlan, qi, ki, block_q: int, block_k: int,
+             t_real: int):
+    """Does grid tile (qi, ki) — program ids — run `plan`?"""
+    diag = jnp.maximum(-block_q, jnp.minimum(qi * block_q - ki * block_k,
+                                             block_k - 1))
+    cut = jnp.maximum(0, jnp.minimum(t_real - qi * block_q, block_q))
+    return (diag == plan.diag) & (cut == plan.cut)
+
+
+def _rect_live(plan: SubtilePlan, r0: int, rows: int, c0: int, cols: int,
+               transposed: bool = False):
+    """Mask of a masked rectangle, (rows, cols) or transposed, from the
+    conditions that cross it alone."""
+    shape = (cols, rows) if transposed else (rows, cols)
+    rdim = 1 if transposed else 0
+    row = r0 + jax.lax.broadcasted_iota(jnp.int32, shape, rdim)
+    live = None
+    if c0 + cols - 1 > r0 + plan.diag:          # the diagonal crosses it
+        col = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rdim)
+        live = col <= row + plan.diag
+    if r0 + rows > plan.cut:                    # the t_real edge crosses it
+        inside = row < plan.cut
+        live = inside if live is None else live & inside
+    return live
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+
+
+def _dot(a, b, dims):
+    # Dots run in the INPUT dtype with f32 accumulation: for bf16 inputs the
+    # result is identical to upcasting first (bf16->f32 is exact, the MXU
+    # accumulates f32 either way) but runs in one MXU pass instead of the
+    # multi-pass f32 decomposition.
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------- forward
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale: float, t_real: int,
-                block_q: int, block_k: int, num_kb: int):
+def _softmax_step(state, s, v, masked: bool):
+    """One online-softmax update of a query sub-row by the scores `s` of
+    one rectangle. `state` is (m, l, acc) or None for a sub-row's first
+    rectangle, which has nothing to rescale."""
+    m_new = jnp.max(s, axis=-1, keepdims=True)
+    if state is not None:
+        m_new = jnp.maximum(state[0], m_new)
+    # clamp: an all-dead row (>= t_real, or no live entry yet) keeps
+    # m_new = MASK, and exp(MASK - MASK) = 1 would resurrect masked entries
+    # (the guard _pos_fwd_kernel carries); live rows have m_new > MASK/2 and
+    # are unaffected. A wholly live rectangle has no such row.
+    m_safe = jnp.maximum(m_new, MASK / 2) if masked else m_new
+    p = jnp.exp(s - m_safe)                                  # (rows, cols)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    acc = _dot(p.astype(v.dtype), v, _NN)                    # (rows, d)
+    if state is not None:
+        alpha = jnp.exp(state[0] - m_safe)                   # (rows, 1)
+        l, acc = alpha * state[1] + l, state[2] * alpha + acc
+    return m_new, l, acc
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
+                scale: float, t_real: int, block_q: int, block_k: int,
+                num_kb: int, plans):
+    """A tile wholly above the diagonal or wholly padding has no plan: it is
+    skipped. Every other tile runs the one plan that is its own; a query
+    sub-row keeps ONE online softmax across its rectangles.
+
+    With one key block (the table's winner at t = 1024) a sub-row is
+    complete when its rectangles are done: it finalises from values and no
+    scratch exists. With several, (m, l, acc) live in scratch across the
+    grid's key blocks — the round trip costs (at t = 1024, one tile, it
+    doubled the kernel's time), so it is paid only where it is needed."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, MASK)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    def finalize(rs, m, l, acc):
+        l_safe = jnp.where(l == 0.0, 1.0, l)  # dead (padded) q rows only
+        o_ref[0, rs, :] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[0, rs, :] = m + jnp.log(l_safe)              # (rows, 1)
 
-    # Entire block above the causal diagonal, or entirely padding: skip.
-    block_live = (ki * block_k <= qi * block_q + block_q - 1) & (
-        ki * block_k < t_real) & (qi * block_q < t_real)
+    def dead(rs, rows):
+        # dead rows (>= t_real) emit o = 0 / lse = MASK: the invariant the
+        # backward's dead-row guards rely on, and the public t_real contract
+        o_ref[0, rs, :] = jnp.zeros((rows, o_ref.shape[-1]), o_ref.dtype)
+        lse_ref[0, rs, :] = jnp.full((rows, 1), MASK, jnp.float32)
 
-    @pl.when(block_live)
-    def _compute():
-        # Dot in the INPUT dtype with f32 accumulation: for bf16 inputs the
-        # result is identical to upcasting first (bf16->f32 is exact, the MXU
-        # accumulates f32 either way) but runs in one MXU pass instead of the
-        # multi-pass f32 decomposition.
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale       # (bq, bk)
+    if scratch:
+        acc_ref, m_ref, l_ref = scratch
 
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        # row >= t_real: dead (padding) query rows emit o = 0 / lse = MASK —
-        # the invariant the backward kernels' dead-row guards rely on, and
-        # the public t_real contract (pad rows are exact zeros).
-        s = jnp.where((col > row) | (col >= t_real) | (row >= t_real),
-                      MASK, s)
+        @pl.when(ki == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, MASK)
+            l_ref[:] = jnp.zeros_like(l_ref)
 
-        m_prev = m_ref[:]                                    # (bq, 1)
-        l_prev = l_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        # clamp: all-dead rows (>= t_real) keep m_new = MASK, and
-        # exp(MASK - MASK) = 1 would resurrect masked entries (the same
-        # guard _pos_fwd_kernel carries); live rows have m_new > MASK/2
-        # and are unaffected
-        m_safe = jnp.maximum(m_new, MASK / 2)
-        alpha = jnp.exp(m_prev - m_safe)                     # (bq, 1)
-        p = jnp.exp(s - m_safe)                              # (bq, bk)
-        l_ref[:] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        m_ref[:] = m_new
-        pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)               # (bq, d)
-        acc_ref[:] = acc_ref[:] * alpha + pv
+    for plan in plans:
+        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
+        def _compute(plan=plan):
+            done = 0
+            for r0, rows, rects in plan.bands:
+                rs = slice(r0, r0 + rows)
+                q = q_ref[0, rs, :]
+                state = (m_ref[rs], l_ref[rs], acc_ref[rs]) if scratch \
+                    else None
+                for c0, cols, masked in rects:
+                    cs = slice(c0, c0 + cols)
+                    s = _dot(q, k_ref[0, cs, :], _NT) * scale
+                    if masked:
+                        s = jnp.where(_rect_live(plan, r0, rows, c0, cols),
+                                      s, MASK)
+                    state = _softmax_step(state, s, v_ref[0, cs, :], masked)
+                if scratch:
+                    m_ref[rs], l_ref[rs], acc_ref[rs] = state
+                else:
+                    finalize(rs, *state)
+                done = r0 + rows
+            if not scratch and done < block_q:     # sub-rows past t_real
+                dead(slice(done, block_q), block_q - done)
 
-    @pl.when(ki == num_kb - 1)
-    def _finalize():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)  # padded q rows only
-        o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[:] + jnp.log(l_safe)          # (bq, 1)
+    if scratch:
+        @pl.when(ki == num_kb - 1)
+        def _finalize():
+            finalize(slice(None), m_ref[:], l_ref[:], acc_ref[:])
+    else:
+        @pl.when(qi * block_q >= t_real)       # a query block of padding
+        def _dead_tile():
+            dead(slice(None), block_q)
 
 
 def _kv_row(bh, hq: int, hkv: int):
@@ -166,10 +418,13 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, t_real=t_real,
-        block_q=block_q, block_k=block_k, num_kb=num_kb)
+        block_q=block_q, block_k=block_k, num_kb=num_kb,
+        plans=_tile_plans(block_q, block_k, num_qb, num_kb, t_real, d))
 
     kv = lambda b: _kv_row(b, hq, hkv)
-    flops = 4 * t_real * t_real * d * bh // 2  # causal: half the square
+    # what the plan computes, masked entries of a crossed sub-tile included
+    entries = bh * causal_plan_stats(t_pad, block_q, block_k, t_real,
+                                     d)["work_elems"]
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_qb, num_kb),
@@ -190,12 +445,12 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-        ],
+        ] if num_kb > 1 else [],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=flops, bytes_accessed=q.size * 3 * q.dtype.itemsize,
-            transcendentals=t_real * t_real * bh // 2),
+            flops=4 * d * entries, bytes_accessed=q.size * 3 * q.dtype.itemsize,
+            transcendentals=entries),
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -203,11 +458,32 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
 
 
 # ---------------------------------------------------------------- backward
+#
+# Every backward kernel forms p, dp and ds a rectangle of its tile's plan.
+# Input-dtype dots + f32 accumulation throughout (see `_dot`); p and ds are
+# cast back to the input dtype before their dots — the standard
+# flash-attention-2 bf16 backward. For f32 inputs every cast is a no-op,
+# keeping the tight-tolerance CPU tests exact. Masked entries are
+# hard-zeroed: dead rows (>= t_real) carry lse = MASK, and exp(s - MASK)
+# would fabricate p there — harmless only while their cotangents are exactly
+# zero, which the public t_real path must not rely on (e.g. MoE aux losses
+# touch every row).
+
+
+def _rect_p_ds(plan, rect, q, k, v, do, lse, delta, scale):
+    """p and ds of one rectangle, (rows, cols), cast to the input dtype."""
+    r0, rows, c0, cols, masked = rect
+    p = jnp.exp(_dot(q, k, _NT) * scale - lse)
+    if masked:
+        p = jnp.where(_rect_live(plan, r0, rows, c0, cols), p, 0.0)
+    dp = _dot(do, v, _NT)
+    ds = (p * (dp - delta) * scale).astype(q.dtype)
+    return p.astype(do.dtype), ds
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc, *, scale: float, t_real: int,
-               block_q: int, block_k: int, num_kb: int):
+               block_q: int, block_k: int, num_kb: int, plans):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -215,35 +491,19 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    block_live = (ki * block_k <= qi * block_q + block_q - 1) & (
-        ki * block_k < t_real) & (qi * block_q < t_real)
-
-    @pl.when(block_live)
-    def _compute():
-        # Input-dtype dots + f32 accumulation throughout (see _fwd_kernel);
-        # ds is cast back to the input dtype before its dot — the standard
-        # flash-attention-2 bf16 backward. For f32 inputs every cast is a
-        # no-op, keeping the tight-tolerance CPU tests exact.
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        row = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        col = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        live = (col <= row) & (col < t_real) & (row < t_real)
-        s = jnp.where(live, s, MASK)
-        # hard-zero masked entries: dead rows (>= t_real) carry lse = MASK,
-        # and exp(MASK - MASK) = 1 would fabricate p there — harmless only
-        # while their cotangents are exactly zero, which the public t_real
-        # path must not rely on (e.g. MoE aux losses touch every row)
-        p = jnp.where(live, jnp.exp(s - lse_ref[0]), 0.0)    # (bq, bk)
-        dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0],
-            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0]) * scale).astype(q_ref.dtype)
-        dq_acc[:] += jax.lax.dot_general(
-            ds, k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for plan in plans:
+        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
+        def _compute(plan=plan):
+            for r0, rows, rects in plan.bands:
+                rs = slice(r0, r0 + rows)
+                q, do = q_ref[0, rs, :], do_ref[0, rs, :]
+                lse, delta = lse_ref[0, rs, :], delta_ref[0, rs, :]
+                for c0, cols, masked in rects:
+                    k = k_ref[0, c0:c0 + cols, :]
+                    _, ds = _rect_p_ds(
+                        plan, (r0, rows, c0, cols, masked), q, k,
+                        v_ref[0, c0:c0 + cols, :], do, lse, delta, scale)
+                    dq_acc[rs] += _dot(ds, k, _NN)
 
     @pl.when(ki == num_kb - 1)
     def _finalize():
@@ -252,10 +512,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float, t_real: int,
-                block_q: int, block_k: int, num_qb: int, group: int = 1):
+                block_q: int, block_k: int, num_qb: int, plans,
+                group: int = 1):
     """dk/dv accumulate over the sequential grid dim 2 = (g, qi) — under
     grouped-query attention every one of a kv head's `group` query heads
-    contributes; the index maps route each (g, qi) step to its query row."""
+    contributes; the index maps route each (g, qi) step to its query row.
+    Scores are formed transposed, (cols, rows), so that both accumulating
+    dots contract the minor dimension."""
     ki = pl.program_id(1)
     gq = pl.program_id(2)
     qi = gq % num_qb
@@ -265,35 +528,26 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    block_live = (qi * block_q + block_q - 1 >= ki * block_k) & (
-        qi * block_q < t_real) & (ki * block_k < t_real)
-
-    @pl.when(block_live)
-    def _compute():
-        # Input-dtype dots + f32 accumulation; pt/dst cast back to the input
-        # dtype before their dots (see _dq_kernel).
-        st = jax.lax.dot_general(k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32) * scale
-        col = ki * block_k + jax.lax.broadcasted_iota(    # key index
-            jnp.int32, (block_k, block_q), 0)
-        row = qi * block_q + jax.lax.broadcasted_iota(    # query index
-            jnp.int32, (block_k, block_q), 1)
-        live_t = (col <= row) & (col < t_real) & (row < t_real)
-        st = jnp.where(live_t, st, MASK)
-        # hard-zero like _dq_kernel: dead rows' lse = MASK fabricates p = 1
-        pt = jnp.where(live_t, jnp.exp(st - jnp.transpose(lse_ref[0])),
-                       0.0)                                  # (bk, bq)
-        dv_acc[:] += jax.lax.dot_general(
-            pt.astype(do_ref.dtype), do_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dpt = jax.lax.dot_general(
-            v_ref[0], do_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, bq)
-        dst = (pt * (dpt - jnp.transpose(delta_ref[0])) * scale
-               ).astype(q_ref.dtype)
-        dk_acc[:] += jax.lax.dot_general(
-            dst, q_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    for plan in plans:
+        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
+        def _compute(plan=plan):
+            for c0, cols, rects in plan.columns:
+                cs = slice(c0, c0 + cols)
+                k, v = k_ref[0, cs, :], v_ref[0, cs, :]
+                for r0, rows, masked in rects:
+                    rs = slice(r0, r0 + rows)
+                    q, do = q_ref[0, rs, :], do_ref[0, rs, :]
+                    pt = jnp.exp(_dot(k, q, _NT) * scale
+                                 - jnp.transpose(lse_ref[0, rs, :]))
+                    if masked:                               # (cols, rows)
+                        pt = jnp.where(
+                            _rect_live(plan, r0, rows, c0, cols,
+                                       transposed=True), pt, 0.0)
+                    dv_acc[cs] += _dot(pt.astype(do.dtype), do, _NN)
+                    dpt = _dot(v, do, _NT)
+                    dst = (pt * (dpt - jnp.transpose(delta_ref[0, rs, :]))
+                           * scale).astype(q.dtype)
+                    dk_acc[cs] += _dot(dst, q, _NN)
 
     @pl.when(gq == group * num_qb - 1)
     def _finalize():
@@ -302,82 +556,68 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale: float, t_real: int):
+                      dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc,
+                      scale: float, plan: SubtilePlan):
     """Single-block backward: when the whole (padded) sequence fits one
-    block, compute dq/dk/dv in ONE kernel — s and p are built once and dp
-    is shared, 5 MXU dots instead of the split kernels' 7, one launch
-    instead of two. Grid is (bh,) only.
+    block, compute dq/dk/dv in ONE kernel — s and p are built once a
+    rectangle and dp is shared, 5 MXU dots instead of the split kernels' 7,
+    one launch instead of two. Grid (b*hkv, group): each step handles one
+    query head of the kv head's group (one step when hq == hkv).
+
+    The walk is key sub-column by key sub-column (`plan.columns`): dk and
+    dv of a sub-column are complete when its rectangles are done, dq
+    accumulates in float32 scratch. dk and dv are formed TRANSPOSED,
+    dv^T = do^T @ p and dk^T = q^T @ ds with p and ds as the MXU's
+    stationary operand: `p^T @ do` has Mosaic transpose every (rows, cols)
+    rectangle through the XLU, which cost 0.13 ms of a 1.00 ms call at
+    b*h = 192 (FWD_SUBTILE's sweep note); this way only (rows, d) and
+    (d, cols) arrays are transposed. With group > 1, dk/dv accumulate in
+    `kv_acc` across the sequential group dim.
 
     Refs here are (t, d)/(t, 1): the leading batch*heads dim is a squeezed
-    (None) block dim, so reads/writes are whole-block `[...]` with no ref
-    indexing — `ref[0]` discharges to a vma-mismatched dynamic_slice under
-    the shard_map interpreter."""
-    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-    t_pad = q.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    row = jax.lax.broadcasted_iota(jnp.int32, (t_pad, t_pad), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (t_pad, t_pad), 1)
-    live = (col <= row) & (col < t_real) & (row < t_real)
-    s = jnp.where(live, s, MASK)
-    # hard-zero dead rows (lse = MASK there; see _dq_kernel)
-    p = jnp.where(live, jnp.exp(s - lse_ref[...]), 0.0)      # (t, t) f32
-    # dv[kt, d] = sum_qt p[qt, kt] * do[qt, d]
-    dv_ref[...] = jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
-    dq_ref[...] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    # dk[kt, d] = sum_qt ds[qt, kt] * q[qt, d]
-    dk_ref[...] = jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+    (None) block dim — `ref[0]` discharges to a vma-mismatched
+    dynamic_slice under the shard_map interpreter."""
+    if kv_acc:
+        dk_acc, dv_acc = kv_acc
+        g, group = pl.program_id(1), pl.num_programs(1)
 
+        @pl.when(g == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
 
-def _bwd_fused_gqa_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                          scale: float, t_real: int, group: int):
-    """Grouped-query fused backward: grid (b*hkv, group). Each step handles
-    one query head of the kv head's group — dq writes through directly,
-    dk/dv accumulate in VMEM scratch across the sequential group dim."""
-    g = pl.program_id(1)
+    t_pad, d = dq_ref.shape
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+    done = 0
+    for c0, cols, rects in plan.columns:
+        cs = slice(c0, c0 + cols)
+        k, v = k_ref[cs, :], v_ref[cs, :]
+        dkt = dvt = jnp.zeros((d, cols), jnp.float32)
+        for r0, rows, masked in rects:
+            rs = slice(r0, r0 + rows)
+            q, do = q_ref[rs, :], do_ref[rs, :]
+            p, ds = _rect_p_ds(plan, (r0, rows, c0, cols, masked), q, k, v,
+                               do, lse_ref[rs, :], delta_ref[rs, :], scale)
+            dvt += _dot(jnp.transpose(do), p, _NN)           # (d, cols)
+            dkt += _dot(jnp.transpose(q), ds, _NN)
+            dq_acc[rs] += _dot(ds, k, _NN)
+        if kv_acc:
+            dk_acc[cs] += jnp.transpose(dkt)
+            dv_acc[cs] += jnp.transpose(dvt)
+        else:
+            dk_ref[cs, :] = jnp.transpose(dkt).astype(dk_ref.dtype)
+            dv_ref[cs, :] = jnp.transpose(dvt).astype(dv_ref.dtype)
+        done = c0 + cols
+    dq_ref[...] = dq_acc[:].astype(dq_ref.dtype)
 
-    @pl.when(g == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    q, k, v, do = q_ref[...], k_ref[...], v_ref[...], do_ref[...]
-    t_pad = q.shape[0]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    row = jax.lax.broadcasted_iota(jnp.int32, (t_pad, t_pad), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (t_pad, t_pad), 1)
-    live = (col <= row) & (col < t_real) & (row < t_real)
-    s = jnp.where(live, s, MASK)
-    # hard-zero dead rows (lse = MASK there; see _dq_kernel)
-    p = jnp.where(live, jnp.exp(s - lse_ref[...]), 0.0)      # (t, t) f32
-    dv_acc[:] += jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta_ref[...]) * scale).astype(q.dtype)
-    dq_ref[...] = jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_acc[:] += jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(g == group - 1)
-    def _finalize():
-        dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+    if kv_acc:
+        @pl.when(g == group - 1)
+        def _finalize():
+            dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+    elif done < t_pad:                  # key sub-columns wholly past t_real
+        dk_ref[done:, :] = jnp.zeros((t_pad - done, d), dk_ref.dtype)
+        dv_ref[done:, :] = jnp.zeros((t_pad - done, d), dv_ref.dtype)
 
 
 def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
@@ -401,50 +641,36 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     # CPU grad tests outside shard_map still cover its math.
     interp_vma = interpret and getattr(jax.typeof(q), "vma", None)
     if num_qb == 1 and num_kb == 1 and not interp_vma:
-        if group == 1:
-            spec_td = pl.BlockSpec((None, t_pad, d), lambda b: (b, 0, 0))
-            spec_t1 = pl.BlockSpec((None, t_pad, 1), lambda b: (b, 0, 0))
-            return pl.pallas_call(
-                functools.partial(_bwd_fused_kernel, scale=scale,
-                                  t_real=t_real),
-                grid=(bh,),
-                in_specs=[spec_td, spec_td, spec_td, spec_td, spec_t1,
-                          spec_t1],
-                out_specs=[spec_td, spec_td, spec_td],
-                out_shape=[_out_struct((bh, t_pad, d), q.dtype, q),
-                           _out_struct((bh, t_pad, d), k.dtype, q),
-                           _out_struct((bh, t_pad, d), v.dtype, q)],
-                compiler_params=pltpu.CompilerParams(
-                    dimension_semantics=("parallel",)),
-                interpret=interpret,
-                name="flash_bwd",
-            )(q, k, v, do, lse, delta)
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
         q_t1 = pl.BlockSpec((None, t_pad, 1),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
         kv_td = pl.BlockSpec((None, t_pad, d), lambda b, g: (b, 0, 0))
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(_bwd_fused_gqa_kernel, scale=scale,
-                              t_real=t_real, group=group),
+        acc = pltpu.VMEM((t_pad, d), jnp.float32)
+        return pl.pallas_call(
+            functools.partial(
+                _bwd_fused_kernel, scale=scale,
+                plan=causal_subtile_plan(t_pad, t_pad, 0, 0, t_real, d,
+                                         backward=True)),
             grid=(bhkv, group),
             in_specs=[q_td, kv_td, kv_td, q_td, q_t1, q_t1],
             out_specs=[q_td, kv_td, kv_td],
             out_shape=[_out_struct((bh, t_pad, d), q.dtype, q),
                        _out_struct((bhkv, t_pad, d), k.dtype, q),
                        _out_struct((bhkv, t_pad, d), v.dtype, q)],
-            scratch_shapes=[pltpu.VMEM((t_pad, d), jnp.float32),
-                            pltpu.VMEM((t_pad, d), jnp.float32)],
+            scratch_shapes=[acc] + ([acc, acc] if group > 1 else []),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
             name="flash_bwd",
         )(q, k, v, do, lse, delta)
-        return dq, dk, dv
 
+    plans = _tile_plans(block_q, block_k, num_qb, num_kb, t_real, d,
+                        backward=True)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, t_real=t_real,
-                          block_q=block_q, block_k=block_k, num_kb=num_kb),
+                          block_q=block_q, block_k=block_k, num_kb=num_kb,
+                          plans=plans),
         grid=(bh, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -470,7 +696,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, t_real=t_real,
                           block_q=block_q, block_k=block_k, num_qb=num_qb,
-                          group=group),
+                          plans=plans, group=group),
         grid=(bhkv, num_kb, group * num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, d),

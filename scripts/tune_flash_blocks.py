@@ -15,6 +15,14 @@ the floor. Prints a table and the best combo per shape. Run on hardware:
 
     python scripts/tune_flash_blocks.py [--quick]
 
+`--subtile` sweeps the causal SUB-TILE edge inside the one grid tile a head
+(ops/pallas/flash_attention.py's FWD_SUBTILE / BWD_SUBTILE): the forward and the
+backward Mosaic call timed apart, at t=1024 hd64 bf16 and the b*h of
+`--bh` (192 = gpt2-medium b12 on one chip, 80 = gpt2-large dp2 x tp2 a
+chip). The readings behind the constants are in the note beside them:
+
+    python scripts/tune_flash_blocks.py --subtile --bh 192,80
+
 `--paged` sweeps the PAGED-attention kernel instead (ISSUE 14):
 pages_per_block per (page_size, kv_dtype) serving decode shape
 (ops/pallas/paged_attention.py's autotuner table; --write_cache persists
@@ -48,6 +56,37 @@ def time_fn(fn, *args, iters=20, warmup=3):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e3  # ms
+
+
+def device_ms(fn, *args, match, iters=10):
+    """Mean device duration (ms) of the ops whose HLO text holds `match`,
+    from a profiler capture of `iters` calls. The host clock around a call
+    of under a millisecond also reads the dispatch; the capture reads the
+    kernel, as the benchmark's `kernels.flash_ms` does."""
+    import glob
+    import tempfile
+
+    from jax.profiler import ProfileData
+
+    from distributed_pytorch_from_scratch_tpu.training.metrics import (
+        ProfilerTrace)
+
+    jax.block_until_ready(fn(*args))
+    with tempfile.TemporaryDirectory() as tmp:
+        capture = ProfilerTrace(tmp, start_step=0, num_steps=iters)
+        capture.maybe_start(0)
+        for _ in range(iters):
+            out = fn(*args)
+        capture.maybe_stop(iters, sync=out)
+        data = ProfileData.from_file(glob.glob(os.path.join(
+            capture.log_dir, "plugins", "profile", "*", "*.xplane.pb"))[0])
+    durs = [ev.duration_ns for plane in data.planes
+            if plane.name == "/device:TPU:0"
+            for line in plane.lines if line.name == "XLA Ops"
+            for ev in line.events if match in ev.name]
+    if not durs:
+        raise RuntimeError(f"no op named like {match!r} in the capture")
+    return sum(durs) / len(durs) / 1e6
 
 
 def sweep_shape(name, b, h, hkv, t, d, blocks, iters):
@@ -118,8 +157,50 @@ def sweep_shape(name, b, h, hkv, t, d, blocks, iters):
     return best_fwd, bwd_results[0] if bwd_results else None
 
 
+def sweep_subtiles(bhs, edges, t=1024, d=64, iters=50):
+    """Forward and backward call times (ms) per sub-tile edge and b*h. The
+    edge is set on the module (it is no argument of the kernels: the code
+    picks it), the plan cache cleared and the calls jitted afresh. `edges`
+    are (sub_q, sub_k) pairs; (t, t) is one masked sub-tile: the kernels
+    before sub-tiles."""
+    import distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
+        as fa
+    rows = []
+    for bh in bhs:
+        key = jax.random.PRNGKey(bh)
+        q, k, v, do = (jax.random.normal(kk, (bh, t, d), jnp.bfloat16)
+                       for kk in jax.random.split(key, 4))
+        kw = dict(t_real=t, block_q=t, block_k=t, hq=1, hkv=1,
+                  interpret=False)
+        for edge in edges:
+            fa.FWD_SUBTILE = fa.BWD_SUBTILE = edge
+            fa.causal_subtile_plan.cache_clear()
+            fwd = jax.jit(lambda q, k, v: fa._fwd_call(q, k, v, **kw))
+            bwd = jax.jit(lambda q, k, v, o, lse, do: fa._bwd_call(
+                q, k, v, o, lse, do, **kw))
+            o, lse = fwd(q, k, v)
+            ms_f = device_ms(fwd, q, k, v, match="flash_fwd")
+            ms_b = device_ms(bwd, q, k, v, o, lse, do, match="flash_bwd")
+            host_f = time_fn(fwd, q, k, v, iters=iters)
+            host_b = time_fn(bwd, q, k, v, o, lse, do, iters=iters)
+            rows.append((bh, edge, ms_f, ms_b))
+            print(f"  bh{bh:4d} t{t} hd{d} sub {edge[0]:4d}x{edge[1]:<4d}  "
+                  f"fwd {ms_f:7.3f} ms"
+                  f"   bwd {ms_b:7.3f} ms   (host clock {host_f:.3f} / "
+                  f"{host_b:.3f})", flush=True)
+    return rows
+
+
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--subtile", action="store_true",
+                    help="sweep the causal sub-tile edge inside one grid "
+                         "tile (forward and backward calls timed apart)")
+    ap.add_argument("--bh", default="192,80",
+                    help="--subtile: comma-separated batch*heads a chip")
+    ap.add_argument("--edges", default="128,256,512,1024",
+                    help="--subtile: comma-separated sub-tile shapes, "
+                         "an edge (256) or sub_q x sub_k (128x256)")
     ap.add_argument("--quick", action="store_true",
                     help="fewer block combos / iters")
     ap.add_argument("--iters", type=int, default=20)
@@ -177,6 +258,11 @@ def main():
 
     if args.paged:
         return sweep_paged(args)
+    if args.subtile:
+        return sweep_subtiles([int(x) for x in args.bh.split(",")],
+                              [tuple(int(e) for e in (x.split("x") * 2)[:2])
+                               for x in args.edges.split(",")],
+                              iters=max(args.iters, 50))
 
     sizes = [256, 512, 1024] if args.quick else [128, 256, 512, 1024, 2048]
     blocks = list(itertools.product(sizes, sizes))

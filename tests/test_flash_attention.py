@@ -458,3 +458,203 @@ def test_gqa_kernel_shape_sweep(group, t, dtype):
     atol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(out.astype(jnp.float32),
                                ref.astype(jnp.float32), atol=atol)
+
+
+# ---- the causal sub-tile plan inside a grid tile ----
+#
+# At the default blocks a sequence of 512 tokens or more is ONE grid tile a
+# head whose plan has several sub-tiles (forward 128 x 256, backward 256 x
+# 256): the cases below run at such shapes, so the kernels skip, mask and
+# leave unmasked what the plan says.
+
+
+def _qkv(key, b, h, hkv, t, d, dtype=jnp.float32, n=3):
+    ks = jax.random.split(jax.random.key(key), 4)
+    shapes = [(b, h, t, d), (b, hkv, t, d), (b, hkv, t, d), (b, h, t, d)]
+    return [jax.random.normal(kk, s, dtype) for kk, s in zip(ks, shapes)][:n]
+
+
+def _sliced_oracle(q, k, v, tr):
+    """XLA attention on the first `tr` tokens, kv heads repeated for GQA,
+    zeros on the rows past them."""
+    group = q.shape[1] // k.shape[1]
+    o = causal_attention_xla(q[:, :, :tr],
+                             jnp.repeat(k[:, :, :tr], group, axis=1),
+                             jnp.repeat(v[:, :, :tr], group, axis=1))
+    return jnp.pad(o, ((0, 0), (0, 0), (0, q.shape[2] - tr), (0, 0)))
+
+
+@pytest.mark.parametrize("block_q,block_k,sub,backward", [
+    (1024, 1024, (128, 256), False), (1024, 1024, (256, 256), True),
+    (512, 512, (128, 128), True), (512, 1024, (256, 128), False),
+    (1024, 512, (128, 512), True), (256, 256, (256, 256), False),
+    (1024, 1024, (1024, 1024), True)])
+def test_subtile_plan_covers_live_and_unmasks_no_dead(
+        monkeypatch, block_q, block_k, sub, backward):
+    """The plan alone, over every grid tile of a 2048-token sequence and a
+    sweep of t_real: the computed sub-tiles cover every live (row, col), a
+    sub-tile marked unmasked holds no dead one, a skipped one no live one,
+    and the two views (bands, columns) and the counts describe one set."""
+    monkeypatch.setattr(fa_mod, "BWD_SUBTILE" if backward else "FWD_SUBTILE",
+                        sub)
+    fa_mod.causal_subtile_plan.cache_clear()
+    t_pad = 2048
+    for t_real in (1, 127, 128, 129, 700, 1000, 1024, 1025, 1536, 2048):
+        row = np.arange(t_pad)[:, None]
+        col = np.arange(t_pad)[None, :]
+        live = (col <= row) & (row < t_real)
+        covered = np.zeros_like(live)
+        for qb in range(t_pad // block_q):
+            for kb in range(t_pad // block_k):
+                plan = fa_mod.causal_subtile_plan(
+                    block_q, block_k, qb, kb, t_real, 64, backward)
+                by_bands = np.zeros((block_q, block_k), np.int8)
+                for r0, rows, rects in plan.bands:
+                    for c0, cols, masked in rects:
+                        assert not by_bands[r0:r0 + rows, c0:c0 + cols].any()
+                        by_bands[r0:r0 + rows, c0:c0 + cols] = 1 + masked
+                by_cols = np.zeros_like(by_bands)
+                for c0, cols, rects in plan.columns:
+                    for r0, rows, masked in rects:
+                        by_cols[r0:r0 + rows, c0:c0 + cols] = 1 + masked
+                assert (by_bands == by_cols).all()
+                tile = live[qb * block_q:(qb + 1) * block_q,
+                            kb * block_k:(kb + 1) * block_k]
+                assert tile[by_bands == 1].all()        # unmasked: all live
+                assert not tile[by_bands == 0].any()    # skipped: all dead
+                cell = plan.sub_q * plan.sub_k
+                assert (by_bands == 1).sum() == plan.computed_unmasked * cell
+                assert (by_bands == 2).sum() == plan.computed_masked * cell
+                assert (by_bands == 0).sum() == plan.skipped * cell
+                assert plan.work_elems == (by_bands > 0).sum()
+                # the mask of a masked rectangle is the live set itself
+                r = np.arange(block_q)[:, None]
+                c = np.arange(block_k)[None, :]
+                assert (((c <= r + plan.diag) & (r < plan.cut)) == tile).all()
+                covered[qb * block_q:(qb + 1) * block_q,
+                        kb * block_k:(kb + 1) * block_k] = by_bands > 0
+        assert covered[live].all()
+    fa_mod.causal_subtile_plan.cache_clear()
+
+
+def test_subtile_plan_at_the_benchmark_shape():
+    """t = 1024, head_dim 64, one tile a head: the forward walks 20 of 32
+    128 x 256 sub-tiles (8 masked), the backward 10 of 16 256 x 256 (4
+    masked) as four key sub-columns of a masked square over one unmasked
+    rectangle; a 128-token tile is one masked sub-tile."""
+    fwd = fa_mod.causal_subtile_plan(1024, 1024, 0, 0, 1024, 64)
+    assert (fwd.computed_unmasked, fwd.computed_masked, fwd.skipped) == (
+        12, 8, 12)
+    assert all(cols == 256 for _, _, rects in fwd.bands
+               for _, cols, _ in rects)
+    bwd = fa_mod.causal_subtile_plan(1024, 1024, 0, 0, 1024, 64, True)
+    assert (bwd.computed_unmasked, bwd.computed_masked, bwd.skipped) == (
+        6, 4, 6)
+    assert bwd.columns[0] == (0, 256, ((0, 256, True), (256, 768, False)))
+    assert bwd.columns[-1] == (768, 256, ((768, 256, True),))
+    small = fa_mod.causal_subtile_plan(128, 128, 0, 0, 128, 64)
+    assert small.bands == ((0, 128, ((0, 128, True),)),)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("t", [512, 1024])
+def test_subtile_forward_matches_oracle(t, dtype, tol):
+    q, k, v = _qkv(t, 1, 2, 2, t, 32, dtype)
+    ref = causal_attention_xla(q, k, v).astype(jnp.float32)
+    out = flash_attention(q, k, v).astype(jnp.float32)
+    assert jnp.abs(ref - out).max() < tol
+
+
+@pytest.mark.parametrize("t", [512, 1024])
+def test_subtile_gradients_match_oracle(t):
+    """One tile a head, several sub-tiles: the fused backward's walk of key
+    sub-columns, dk and dv formed transposed."""
+    q, k, v, g = _qkv(t + 1, 1, 2, 2, t, 32, n=4)
+    gr = jax.grad(lambda *a: jnp.vdot(causal_attention_xla(*a), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+
+
+@pytest.mark.parametrize("t_real", [
+    300,    # cuts through a sub-tile of both plans
+    384,    # on a forward sub-row edge, inside a backward sub-tile
+    256,    # on a sub-tile edge of both
+    511])   # one pad row
+def test_subtile_t_real_exact_zeros_with_tail_cotangent(t_real):
+    """t_real against the sub-tiles of one 512-token tile: live rows match
+    the sliced oracle, dead rows are exact zeros, and a nonzero cotangent on
+    them yields exact zero gradients — whether the edge cuts a sub-tile
+    (masked) or falls between two (the dead ones are never computed)."""
+    q, k, v, g = _qkv(t_real, 1, 2, 2, 512, 32, n=4)
+    out = flash_attention(q, k, v, t_real=t_real)
+    ref = _sliced_oracle(q, k, v, t_real)
+    assert jnp.abs(out - ref).max() < 1e-5
+    assert jnp.abs(out[:, :, t_real:]).max() == 0.0
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, t_real=t_real), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert jnp.abs(b[:, :, t_real:]).max() == 0.0
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_subtile_gqa_groups(group):
+    """Grouped query heads through the sub-tile walk: the fused backward
+    accumulates dk/dv of a kv head over its group's grid steps."""
+    q, k, v, g = _qkv(group, 1, 4, 4 // group, 512, 32, n=4)
+    tr = 500
+    out = flash_attention(q, k, v, t_real=tr)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, tr)).max() < 1e-5
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, tr), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, t_real=tr), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+
+
+@pytest.mark.parametrize("blocks,t_real", [
+    ((512, 512), 1024), ((512, 512), 900), ((512, 1024), 700),
+    ((1024, 512), 1024)])
+def test_subtile_multiblock_diagonal_tiles(blocks, t_real):
+    """A grid of several tiles: those the diagonal or the t_real edge
+    crosses walk their plan (forward with the online softmax through
+    scratch, backward in the split dq / dkv kernels), those under it run
+    unmasked, and the kernel picks each tile's plan from the program ids."""
+    q, k, v, g = _qkv(t_real, 1, 2, 1, 1024, 32, n=4)
+    kw = dict(block_q=blocks[0], block_k=blocks[1], bwd_block_q=blocks[0],
+              bwd_block_k=blocks[1], t_real=t_real)
+    out = flash_attention(q, k, v, **kw)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, t_real)).max() < 1e-5
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert not jnp.any(b[:, :, t_real:])
+
+
+def test_subtile_under_shard_map():
+    """One tile of several sub-tiles per shard, heads over tp: forward, and
+    the backward through the split kernels (the fused one is gated off under
+    the interpreter inside shard_map)."""
+    mesh = make_mesh(MeshConfig(dp=1, tp=4))
+    q, k, v = _qkv(7, 1, 4, 4, 512, 32)
+    sm = jax.shard_map(lambda q, k, v: flash_attention(q, k, v, t_real=400),
+                       mesh=mesh, in_specs=(P(None, "tp"),) * 3,
+                       out_specs=P(None, "tp"))
+    out = jax.jit(sm)(q, k, v)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, 400)).max() < 1e-5
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+    g_fl = jax.jit(jax.grad(loss(sm), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss(lambda *a: _sliced_oracle(*a, 400)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ref, g_fl):
+        assert jnp.abs(a - b).max() < 1e-4
