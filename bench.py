@@ -41,6 +41,7 @@ import jax.numpy as jnp
 
 from distributed_pytorch_from_scratch_tpu import (MeshConfig, Transformer,
                                                   make_mesh)
+from distributed_pytorch_from_scratch_tpu import models
 from distributed_pytorch_from_scratch_tpu.config import (IGNORE_INDEX,
                                                          REMAT_CHOICES,
                                                          OptimizerConfig,
@@ -59,7 +60,7 @@ def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--model", default="45m",
                    choices=["45m", "gpt2-124m", "gpt2-355m", "tiny", "45m-moe8"])
-    p.add_argument("--family", default="llama", choices=["llama", "gpt2"],
+    p.add_argument("--family", default="llama", choices=list(models.FAMILIES),
                    help="model family; 'gpt2' benches GPT2Transformer "
                         "(LayerNorm/GELU/learned positions/tied head) at "
                         "the chosen preset shape")
@@ -450,8 +451,8 @@ def parse_args(argv=None):
 
 def build_model(args, cfg, tp: int, remat: str = None, attn_impl: str = "auto",
                 attn_t_real: int = None, cp: int = 1):
-    """The one family dispatch shared by the training/decode/breakdown
-    paths (three copies had already diverged once)."""
+    """The one keyword list shared by the training/decode/breakdown paths
+    (three copies had already diverged once)."""
     kw = dict(tp_size=tp, cp_size=cp, attn_impl=attn_impl,
               attn_t_real=attn_t_real,
               sequence_parallel=args.sequence_parallel,
@@ -459,11 +460,7 @@ def build_model(args, cfg, tp: int, remat: str = None, attn_impl: str = "auto",
     if remat is not None:
         # a key of REMAT_CHOICES, or the ladder rung `--remat auto` chose
         kw["remat"] = REMAT_CHOICES.get(remat, remat)
-    if args.family == "gpt2":
-        from distributed_pytorch_from_scratch_tpu.models.gpt2 import (
-            GPT2Transformer)
-        return GPT2Transformer(cfg, **kw)
-    return Transformer(cfg, **kw)
+    return models.build_model(args.family, cfg, **kw)
 
 
 def dp_reduce_kwargs(args):
@@ -1471,12 +1468,8 @@ def run_breakdown(args, mesh, cfg, tp: int) -> None:
     params, moment_sh = zero_state_put(args, model, mesh,
                                        model.init(jax.random.key(0)))
     pbpd = param_bytes_per_device(params)
-    # ADVICE r5: the param-derived FLOPs count must happen BEFORE the
-    # donating step programs consume the `params` buffers below — the
-    # helper only reads `.size` metadata today, but a donated tree is one
-    # refactor away from 'Array has been deleted'
-    flops = model_flops_per_step(
-        cfg, B, T, params=params if args.family == "gpt2" else None)
+    flops = model_flops_per_step(cfg, B, T,
+                                 num_params=model.num_params(cfg))
     ocfg = OptimizerConfig()
     host_ids = np.asarray(np.random.default_rng(0).integers(
         0, cfg.vocab_size, (B, T_pad), dtype=np.int32))
@@ -1859,7 +1852,7 @@ def main(argv=None):
     tokens_per_sec_per_chip = B * T / step_s / world
 
     flops_per_step = model_flops_per_step(
-        cfg, B, T, params=params if args.family == "gpt2" else None)
+        cfg, B, T, num_params=models.family_class(args.family).num_params(cfg))
     mfu = mfu_of(flops_per_step, step_s, world)
 
     if args.introspect:

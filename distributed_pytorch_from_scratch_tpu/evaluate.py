@@ -31,7 +31,7 @@ import numpy as np
 from .cli import add_model_shape_args, build_model_config
 from .config import BOS_TOKEN, EOS_TOKEN, IGNORE_INDEX, MeshConfig
 from .data.dataset import get_dataloader
-from .models.transformer import Transformer
+from .models import FAMILIES, DecoderStack, build_model
 from .obs import SpanTracer
 from .runtime.compile_cache import enable_compile_cache
 from .runtime.mesh import batch_feeder, init_multihost, make_mesh
@@ -82,7 +82,7 @@ def get_eval_args(argv=None) -> argparse.Namespace:
     g.add_argument("--tokenizer_path", "-t", required=True)
 
     g = p.add_argument_group("model")
-    g.add_argument("--family", choices=["llama", "gpt2"], default="llama",
+    g.add_argument("--family", choices=list(FAMILIES), default="llama",
                    help="must match the trained model family; both decode "
                         "via the KV-cache decoder (gpt2's buffer is capped "
                         "at its learned position table)")
@@ -178,7 +178,7 @@ def calc_val_loss(loss_fn, params, dataloader, batch_rows: int,
     return total / max(docs, 1)
 
 
-def make_greedy_decoder(model: Transformer, mesh, buf_len: int):
+def make_greedy_decoder(model: DecoderStack, mesh, buf_len: int):
     """One fixed-shape jitted step: (params, buffer(1,buf_len), cur_len) ->
     argmax token id at position cur_len-1.
 
@@ -204,7 +204,7 @@ def make_greedy_decoder(model: Transformer, mesh, buf_len: int):
     return jax.jit(step)
 
 
-def greedy_decode(model: Transformer, mesh, params, tokenizer, prompts,
+def greedy_decode(model: DecoderStack, mesh, params, tokenizer, prompts,
                   bos_id: int, eos_id: int,
                   max_decode_len: int = 128,
                   use_kv_cache: bool = True,
@@ -354,19 +354,11 @@ def evaluate(args: argparse.Namespace) -> dict:
     # attention — both decode on the cp=1 path.
     dec_cp = (args.cp_size if (args.cp_layout == "contiguous"
                                and not args.no_kv_cache) else 1)
-    if args.family == "gpt2":
-        from .models.gpt2 import GPT2Transformer
-        model_val = GPT2Transformer(cfg, tp_size=args.tp_size,
-                                    cp_size=args.cp_size,
-                                    cp_impl=args.cp_impl,
-                                    cp_layout=args.cp_layout)
-        model = GPT2Transformer(cfg, tp_size=args.tp_size, cp_size=dec_cp)
-    else:
-        model_val = Transformer(cfg, tp_size=args.tp_size,
-                                cp_size=args.cp_size,
-                                cp_impl=args.cp_impl,
-                                cp_layout=args.cp_layout)
-        model = Transformer(cfg, tp_size=args.tp_size, cp_size=dec_cp)
+    model_val = build_model(args.family, cfg, tp_size=args.tp_size,
+                            cp_size=args.cp_size, cp_impl=args.cp_impl,
+                            cp_layout=args.cp_layout)
+    model = build_model(args.family, cfg, tp_size=args.tp_size,
+                        cp_size=dec_cp)
     template = model.init(jax.random.key(args.random_seed))
     loss_fn = model_val.make_doc_loss(mesh)
     feed = batch_feeder(mesh)

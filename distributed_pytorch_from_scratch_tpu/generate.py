@@ -18,7 +18,7 @@ import jax
 
 from .cli import add_model_shape_args, build_model_config
 from .config import BOS_TOKEN, EOS_TOKEN, MeshConfig
-from .models.transformer import Transformer
+from .models import FAMILIES, build_model
 from .runtime.compile_cache import enable_compile_cache
 from .runtime.mesh import make_mesh
 from .training.checkpoint import latest_step, load_checkpoint
@@ -49,7 +49,7 @@ def get_generate_args(argv=None) -> argparse.Namespace:
                         "only changes the attention schedule) or "
                         "--cp_size 1; 'ulysses' here errors out with that "
                         "pointer instead of silently switching")
-    p.add_argument("--family", choices=["llama", "gpt2"], default="llama")
+    p.add_argument("--family", choices=list(FAMILIES), default="llama")
     add_model_shape_args(p.add_argument_group("model shape"))
     p.add_argument("--temperature", type=float, default=0.0,
                    help="0 = greedy; > 0 samples softmax(logits/T)")
@@ -100,13 +100,8 @@ def generate(args: argparse.Namespace) -> list:
 
     cfg = build_model_config(args, vocab_size)
     mesh = make_mesh(MeshConfig(tp=args.tp_size, cp=args.cp_size))
-    if args.family == "gpt2":
-        from .models.gpt2 import GPT2Transformer
-        model = GPT2Transformer(cfg, tp_size=args.tp_size,
-                                cp_size=args.cp_size)
-    else:
-        model = Transformer(cfg, tp_size=args.tp_size,
-                            cp_size=args.cp_size)
+    model = build_model(args.family, cfg, tp_size=args.tp_size,
+                        cp_size=args.cp_size)
 
     step = args.iter if args.iter is not None else latest_step(args.ckpt_dir)
     if step is None:

@@ -1,1 +1,20 @@
+"""The model families, and the one place a family's name becomes a class."""
 
+from .gpt2 import GPT2Transformer
+from .stack import DecoderStack
+from .transformer import Transformer
+
+FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer}
+
+
+def family_class(family: str) -> "type[DecoderStack]":
+    if family not in FAMILIES:
+        raise ValueError(f"unknown model family {family!r}; expected one of "
+                         f"{sorted(FAMILIES)}")
+    return FAMILIES[family]
+
+
+def build_model(family: str, cfg, **kw) -> DecoderStack:
+    """`cfg` built as `family` (a key of FAMILIES); `kw` are the stack's
+    fields (`DecoderStack`), the same for every family."""
+    return family_class(family)(cfg, **kw)

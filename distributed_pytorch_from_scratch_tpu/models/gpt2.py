@@ -1,12 +1,17 @@
 """GPT-2 model family: LayerNorm + GELU MLP + learned positions + TIED
-vocab-parallel embeddings, on the same parallel primitives as the LLaMA
-family.
+vocab-parallel embeddings, on the same decoder stack as the LLaMA family.
 
 The reference implements exactly one family (RoPE/RMSNorm/SwiGLU,
-`/root/reference/models/model.py`); this module is a framework extension
-demonstrating that the parallel layer/comm stack generalises: a second
-architecture drops in with ~150 lines and inherits the whole loss / train /
-checkpoint / mesh machinery unchanged.
+`/root/reference/models/model.py`); this one is a framework extension.
+`GPT2Transformer` is a subclass of `models/stack.DecoderStack` and holds
+only what differs from its sibling (`models/transformer.Transformer`): its
+modules and parameter tree, the learned position table, the two-matrix
+MLP, the tied head, and its facts. Fields, validation, the tp layout, the
+remat ladder, the layer skeleton, the pipeline schedules, the losses and
+the jitted entry points are the stack's, so context parallelism (ring /
+Ulysses over 'cp'), Megatron sequence parallelism over 'tp', the rings,
+the pipeline over 'pp', MoE and ZeRO-3 compose with this family exactly as
+with the llama one.
 
 Design notes:
 
@@ -18,23 +23,12 @@ Design notes:
   and the embedding weight receives BOTH gradient contributions (lookup and
   head) through plain autodiff.
 
-* **Shared infrastructure by duck-typing.** `loss_shard`, `make_loss`,
-  `make_forward` and `shardings` are borrowed directly from `Transformer`
-  — they only touch `forward_shard`, `specs`, and a handful of static
-  attributes, all of which this class provides. The train step builders,
-  checkpointing, ZeRO-1 and the CLIs therefore work for this family with
-  zero changes.
-
 * **Megatron TP pattern identical to the LLaMA family**: wq/wk/wv + fc are
   column-parallel (`gather_output=False`), wo + proj row-parallel
   (`split_input=False`) — one all-reduce per sublayer per direction.
 
-* Context parallelism (ring / Ulysses over 'cp'), Megatron sequence
-  parallelism over 'tp' and the GPipe pipeline over 'pp' compose with this
-  family exactly like the llama one — same collectives and the same
-  (family-agnostic) microbatch schedule, no RoPE (positions are learned
-  and enter at the embedding, so the cp shards just index their position
-  slice).
+* **No RoPE**: positions are learned and enter at the embedding, so the cp
+  shards just index their position slice and a layer gets nothing.
 """
 
 from __future__ import annotations
@@ -47,109 +41,50 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
-from ..config import ModelConfig, resolve_dtype
-from ..ops.attention import causal_attention
-from ..ops.collectives import gather_from
-from ..ops.ring_attention import ring_attention, ulysses_attention
+from ..config import ModelConfig
+from ..ops.overlap import ag_matmul, ring_order
 from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import MoEFFN
 from ..parallel.norm import LayerNorm
 from ..runtime.prng import fold
-from ..ops.overlap import ag_matmul, ring_order
-from ..parallel.linear import apply_column_ring_fused
-from .transformer import (NEG_INF, Transformer, remat_wrap, resolve_remat,
-                          validate_cp, validate_pp, validate_remat, validate_t_real,
-                          validate_tp_overlap)
-
-Params = Dict[str, Any]
+from .stack import DecoderStack, Params, TPSublayers
 
 INIT_STD = 0.02  # GPT-2's embedding/projection init scale
 
 
 @dataclass(frozen=True)
-class GPT2Transformer:
-    """Static GPT-2 definition; params live in an explicit pytree."""
+class GPT2Transformer(DecoderStack):
+    """The GPT-2 family: learned positions, LayerNorm, a GELU MLP of two
+    matrices, the head tied to the embedding, multi-head attention."""
 
-    cfg: ModelConfig
-    tp_size: int = 1
-    attn_impl: str = "auto"
-    # same contract as Transformer.remat / remat_budget_gib
-    remat: "bool | str" = "auto"
-    remat_budget_gib: "float | None" = None
-    # context parallelism over 'cp', Megatron SP over 'tp', and the GPipe
-    # pipeline over 'pp' — all borrowed from the llama family's machinery
-    # (the microbatch schedule is Transformer._pipeline_layers, family-
-    # agnostic via stage_fn)
-    cp_size: int = 1
-    cp_impl: str = "ring"
-    cp_layout: str = "contiguous"
-    # same contract as Transformer.sequence_parallel / .tp_overlap: 'auto'
-    # (the default) is resolved per trace by `resolve_tp_layout`; under
-    # 'ring' the tied head rings too
-    sequence_parallel: "bool | str" = "auto"
-    tp_overlap: str = "auto"
-    pp_size: int = 1
-    pp_microbatches: int = 0
-    pp_remat_steps: bool = False
-    pp_schedule: str = "gpipe"   # or 'interleaved' (virtual stages);
-    pp_virtual: int = 2          # see Transformer.pp_schedule
-    # Expert parallelism (with cfg.num_experts > 0): the gelu MLP swaps for
-    # the same routed-expert sublayer the llama family uses
-    # (parallel/moe.py — SwiGLU experts; documented design choice, see
-    # _mods). VERDICT r3 #5.
-    ep_size: int = 1
-    # Pad-aware sequence bucketing — same contract as
-    # Transformer.attn_t_real (real token count inside a bucket-padded
-    # batch; attention skips the pad tiles, CE masks the pad targets).
-    attn_t_real: "int | None" = None
-    # ZeRO-3 per-layer param gather — same contract as
-    # Transformer.zero3_axis (set only by training/zero.build_zero3_grad_fn
-    # on its private model copy; every other path leaves it None).
-    zero3_axis: "str | None" = None
+    # what the stack, the decoder (models/decode.py), training/memory.py and
+    # obs/attribution.py ask a family
+    uses_rope = False         # learned position embeddings instead of RoPE
+    attn_norm_key = "ln1"
+    ffn_norm_key = "ln2"
+    ffn_inputs = 1            # fc alone reads the MLP's input
+    tied_head = True
+
+    @staticmethod
+    def num_params(cfg: ModelConfig) -> int:
+        """Two-matrix MLP (or the shared SwiGLU experts and their router),
+        a position table, LayerNorm biases, no head of its own."""
+        d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers
+        layer = 4 * d * d + 4 * d + 2 * d * f + f + d + 4 * d
+        if cfg.num_experts:
+            layer = (4 * d * d + 4 * d + 4 * d
+                     + cfg.num_experts * 3 * d * f + d * cfg.num_experts)
+        return cfg.vocab_size * d + cfg.maxlen * d + L * layer + 2 * d
 
     def __post_init__(self):
-        cfg, tp = self.cfg, self.tp_size
-        validate_remat(self.remat)
-        if cfg.num_heads % tp != 0:
-            raise ValueError(
-                f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
-        if cfg.attn_dim % tp != 0 or cfg.ffn_dim % tp != 0:
-            raise ValueError(
-                f"attn_dim {cfg.attn_dim} and ffn_dim {cfg.ffn_dim} must be "
-                f"divisible by tp_size {tp}")
-        if cfg.kv_heads != cfg.num_heads:
+        if self.cfg.kv_heads != self.cfg.num_heads:
             raise ValueError("grouped-query attention (num_kv_heads) is a "
                              "llama-family feature; the gpt2 family is MHA "
                              "(real GPT-2 has none — documented choice)")
-        if not cfg.num_experts and self.ep_size > 1:
-            raise ValueError("ep_size > 1 requires cfg.num_experts > 0 "
-                             "(a dense model has nothing to shard over 'ep'; "
-                             "use dp for a pure data axis)")
-        validate_cp(cfg, tp, self.cp_size, self.cp_impl, self.cp_layout)
-        validate_tp_overlap(self.tp_overlap, self.sequence_parallel,
-                            cfg.num_experts)
-        validate_pp(cfg.num_layers, self.pp_size, self.pp_microbatches,
-                    self.pp_schedule, self.pp_virtual)
-        validate_t_real(self.attn_t_real, self.cp_size, cfg.num_experts)
-
-    # ---- static properties ----
-
-    # family hooks for the generic KV decoder (models/decode.py): learned
-    # position embeddings instead of RoPE, LayerNorm module keys, MHA
-    uses_rope = False
-    attn_norm_key = "ln1"
-    ffn_norm_key = "ln2"
-
-    @property
-    def is_moe(self) -> bool:
-        # loss_shard, _pipeline_layers and the decoder consult this
-        return self.cfg.num_experts > 0
-
-    @property
-    def d(self) -> int:
-        return self.cfg.attn_dim
+        super().__post_init__()
 
     @property
     def max_decode_positions(self) -> int:
@@ -158,13 +93,7 @@ class GPT2Transformer:
         their buffers; see evaluate.greedy_decode)."""
         return self.cfg.maxlen
 
-    @property
-    def vocab_padded(self) -> int:
-        return self.cfg.padded_vocab_size(self.tp_size)
-
-    @property
-    def num_local_heads(self) -> int:
-        return self.cfg.num_heads // self.tp_size
+    # ---- sub-module definitions ----
 
     @functools.cached_property
     def embedding(self) -> VocabParallelEmbedding:
@@ -178,8 +107,8 @@ class GPT2Transformer:
         ov = self._linear_overlap
         mods = {
             "ln1": LayerNorm(d),
-            # wq/wk/wv stay overlap='off': the fused ring in _layer_body
-            # covers them on ONE shared ring (shared-gather byte parity)
+            # wq/wk/wv (and fc) stay overlap='off': under ring overlap the
+            # stack's fused ring covers them (TPSublayers.columns)
             "wq": ColumnParallelLinear(d, d, gather_output=False),
             "wk": ColumnParallelLinear(d, d, gather_output=False),
             "wv": ColumnParallelLinear(d, d, gather_output=False),
@@ -199,8 +128,7 @@ class GPT2Transformer:
                 ep_size=self.ep_size, tp_size=self.tp_size)
         else:
             mods.update({
-                "fc": ColumnParallelLinear(d, f, gather_output=False,
-                                           overlap=ov),
+                "fc": ColumnParallelLinear(d, f, gather_output=False),
                 "proj": RowParallelLinear(f, d, split_input=False,
                                           overlap=ov),
             })
@@ -213,16 +141,7 @@ class GPT2Transformer:
     # ---- init / specs ----
 
     def init(self, key: jax.Array) -> Params:
-        L = self.cfg.num_layers
-        layer_keys = jax.random.split(fold(key, "layers"), L)
-
-        def one_layer(k: jax.Array) -> Params:
-            return {name: mod.init(fold(k, name))
-                    for name, mod in self._mods.items()}
-
-        layers = jax.vmap(one_layer)(layer_keys)
-        if self._interleaved:
-            layers = self._layers_to_schedule(layers)
+        layers = self._init_layers(key)
         return {
             "embedding": self.embedding.init(fold(key, "embedding")),
             "pos_embedding": {"weight": INIT_STD * jax.random.normal(
@@ -232,247 +151,48 @@ class GPT2Transformer:
         }
 
     def specs(self) -> Params:
-        from jax.sharding import PartitionSpec as P
-
-        lead = "pp" if self.pp_size > 1 else None
-
-        def stack(spec_dict: Params) -> Params:
-            # stacked num_layers axis: sharded over 'pp' when pipelining
-            # ((V, pp, Lv) dim-1 for the interleaved schedule)
-            if self._interleaved:
-                return jax.tree.map(lambda s: P(None, "pp", None, *s),
-                                    spec_dict,
-                                    is_leaf=lambda x: isinstance(x, P))
-            return jax.tree.map(lambda s: P(lead, *s), spec_dict,
-                                is_leaf=lambda x: isinstance(x, P))
-
         return {
             "embedding": self.embedding.specs(),
             "pos_embedding": {"weight": P(None, None)},
-            "layers": {name: stack(mod.specs())
-                       for name, mod in self._mods.items()},
+            "layers": self._layer_specs(),
             "norm": self.final_norm.specs(),
         }
 
-    # ---- per-shard forward (inside shard_map) ----
+    # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
-    def _layer_body(self, x: jax.Array, lp: Params, pos: jax.Array,
-                    dtype, live=None) -> jax.Array:
-        """One GPT-2 block. `live` is the pp x ring-CP bubble gate — same
-        contract as `Transformer._layer_body` (the shared
-        `_live_gated_ring` wraps the dense segments in lax.cond while the
-        ring's ppermutes run unconditionally)."""
-        if self.zero3_axis:
-            # ZeRO-3 per-layer gather — same contract as
-            # Transformer._layer_body (inside remat; transpose
-            # reduce-scatters the weight grads to this rank's shard)
-            from ..training.zero import zero3_layer_gather
-            lp = zero3_layer_gather(self, lp, self.zero3_axis)
-        m = self._mods
-        h = self.cfg.head_dim
-        # sequence parallelism: x is (b, t/tp, d) between sublayers; the
-        # norm output is gathered ONCE per sublayer and shared by the
-        # projections, row-linear outputs reduce-scatter back (the same
-        # Megatron SP pattern as Transformer._layer_body)
-        sp = self.sequence_parallel
-        # ring overlap: the sublayer gather never materialises — the fused
-        # ring collective matmul consumes the seq-sharded activation (same
-        # contract as Transformer._layer_body)
-        ring_ov = sp and self.tp_overlap in ("ring", "ring_q")
-        maybe_gather = ((lambda z: gather_from(z, "tp", tiled_axis=-2))
-                        if sp and not ring_ov else (lambda z: z))
-        in_layout = ("seq_sharded" if ring_ov
-                     else "gathered" if sp else "replicated")
-        out_layout = "seq_sharded" if sp else "replicated"
-        # between fc and proj nothing cares where a token sits, so under
-        # the rings the MLP's hidden activation stays in the ring's own
-        # chunk order (ops/overlap.py, "RING ORDER")
-        ffn_order = dict(seq_order="ring") if ring_ov else {}
-        b = x.shape[0]
-        t = pos.shape[1]  # full (cp-local) sequence length, not x.shape[1]
-
-        def qkv(x):
-            y = maybe_gather(m["ln1"].apply(lp["ln1"], x))
-            if ring_ov:
-                q, k, v = apply_column_ring_fused(
-                    (lp["wq"], lp["wk"], lp["wv"]), y, dtype,
-                    quantized=self.tp_overlap == "ring_q")
-            else:
-                q = m["wq"].apply(lp["wq"], y, dtype, input_layout=in_layout)
-                k = m["wk"].apply(lp["wk"], y, dtype, input_layout=in_layout)
-                v = m["wv"].apply(lp["wv"], y, dtype, input_layout=in_layout)
-            # REMAT_LADDER's names (models/transformer.py), as the linears
-            # return them: (b, t, heads*h), the lane-dense shape
-            q = checkpoint_name(q, "q_proj")
-            k = checkpoint_name(k, "k_proj")
-            v = checkpoint_name(v, "v_proj")
-            split = lambda z: z.reshape(
-                b, t, self.num_local_heads, h).transpose(0, 2, 1, 3)
-            return split(q), split(k), split(v)
-
-        def attn_out(args):
-            x, o = args
-            o = o.transpose(0, 2, 1, 3).reshape(b, t,
-                                                self.num_local_heads * h)
-            a = m["wo"].apply(lp["wo"], o, dtype, output_layout=out_layout)
-            if self.tp_size > 1:
-                # named PAST the row-linear's reduce and only where there
-                # is one (REMAT_LADDER)
-                a = checkpoint_name(a, "attn_proj")
-            x = x + a
-
-            y = maybe_gather(m["ln2"].apply(lp["ln2"], x))
-            if self.is_moe:
-                ff, aux = m["moe"].apply(lp["moe"], y, dtype)
-                if sp:
-                    # Same SP composition as the llama body: the router saw
-                    # the tp-gathered tokens, ff is full-value on every
-                    # rank — keep this rank's sequence slice so the
-                    # residual stays seq-sharded.
-                    tl = ff.shape[1] // self.tp_size
-                    ff = lax.dynamic_slice_in_dim(
-                        ff, lax.axis_index("tp") * tl, tl, axis=1)
-                return x + ff, aux
-            # gelu_new (tanh approximation), like GPT-2
-            fc = checkpoint_name(
-                m["fc"].apply(lp["fc"], y, dtype, input_layout=in_layout,
-                              **ffn_order),
-                "ffn_fc")
-            x = x + m["proj"].apply(lp["proj"],
-                                    jax.nn.gelu(fc, approximate=True), dtype,
-                                    output_layout=out_layout, **ffn_order)
-            return x, None
-
-        # ring overlap: dense segments run even on bubble steps (their tp
-        # ppermutes cannot hide in a stage-divergent cond — see
-        # Transformer._layer_body)
-        if live is None or ring_ov:
-            q, k, v = qkv(x)
-            if self.cp_size > 1:
-                if self.cp_impl == "ring":
-                    o = ring_attention(q, k, v, pos, axis="cp",
-                                       impl=self.attn_impl, live=live)
-                else:
-                    o = ulysses_attention(q, k, v, axis="cp",
-                                          impl=self.attn_impl)
-            else:
-                o = causal_attention(q, k, v, impl=self.attn_impl,
-                                     t_real=self._t_real(t))
-            return attn_out((x, o))
-        return self._live_gated_ring(x, qkv, attn_out, pos, live)
-
-    def forward_shard(self, params: Params, input_ids: jax.Array,
-                      position_ids: jax.Array,
-                      head_layout: str = "replicated") -> jax.Array:
-        """(b_local, t) ids -> (b_local, t, vocab_padded / tp) LOCAL logits —
-        the same per-shard contract as `Transformer.forward_shard`
-        (`head_layout` follows the same pipeline semantics)."""
-        logits, _ = self._forward_with_aux(params, input_ids, position_ids,
-                                           head_layout=head_layout)
-        return logits
-
-    def _forward_with_aux(self, params: Params, input_ids: jax.Array,
-                          position_ids: jax.Array,
-                          head_layout: str = "replicated"):
-        """forward_shard + MoE aux-stat sums (None for dense) — the same
-        contract as `Transformer._forward_with_aux`, which the borrowed
-        `loss_shard` consumes."""
-        self = self._resolved(input_ids.shape[1])
-        dtype = resolve_dtype(self.cfg.compute_dtype)
-        sp = self.sequence_parallel
-        if sp and input_ids.shape[1] % self.tp_size != 0:
-            raise ValueError(
-                f"sequence_parallel needs the (cp-local) sequence length "
-                f"{input_ids.shape[1]} divisible by tp_size {self.tp_size}")
-        x = self.embedding.apply(params["embedding"], input_ids,
-                                 output_layout="seq_sharded" if sp
-                                 else "replicated")
+    def _positions(self, params: Params, x: jax.Array,
+                   position_ids: jax.Array, dtype):
+        """The learned table's rows are added to the embedding; a layer
+        gets nothing."""
         pos_emb = jnp.take(params["pos_embedding"]["weight"], position_ids,
                            axis=0, mode="clip")
-        if sp:
+        if self.sequence_parallel:
             # embedding output is seq-sharded; slice the position rows the
             # same way before the add
             tl = pos_emb.shape[1] // self.tp_size
             pos_emb = lax.dynamic_slice_in_dim(
                 pos_emb, lax.axis_index("tp") * tl, tl, axis=1)
-        x = (x + pos_emb).astype(dtype)
+        return (x + pos_emb).astype(dtype), ()
 
-        layer_fn = remat_wrap(
-            self._layer_body, resolve_remat(self, params, input_ids.shape),
-            static_argnums=(3,))
+    def _mlp(self, lp: Params, y: jax.Array, tp: TPSublayers,
+             dtype) -> jax.Array:
+        """proj(gelu_new(fc(y))): the tanh approximation, like GPT-2"""
+        fc, = tp.columns(lp, ("fc",), y, dtype, **tp.ffn_order)
+        fc = checkpoint_name(fc, "ffn_fc")
+        return tp.row(lp, "proj", jax.nn.gelu(fc, approximate=True), dtype,
+                      **tp.ffn_order)
 
-        if self.pp_size > 1:
-            def stage_fn(z, layers, pos_m, live=None):
-                def body(carry, lp):
-                    return layer_fn(carry, lp, pos_m, dtype, live)
-                z, auxs = lax.scan(body, z, layers)
-                aux = (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
-                       if self.is_moe else None)
-                return z, aux
-
-            x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
-                                           (position_ids,),
-                                           head_layout=head_layout)
-        else:
-            def body(carry, lp):
-                return layer_fn(carry, lp, position_ids, dtype)
-
-            x, auxs = lax.scan(body, x, params["layers"])
-            aux = (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
-                   if self.is_moe else None)
-        # `head_loss`: the one boundary inside the loss that a device trace is
-        # split at (final norm, head, CE; benchmark/lib/program_trace.py)
-        with jax.named_scope("head_loss"):
-            x = self.final_norm.apply(params["norm"], x)
-            # tied head: local logits against this shard's embedding rows
-            w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
-            if sp and self.tp_overlap in ("ring", "ring_q"):
-                # ring collective matmul for the tied head too: the gather's
-                # hops hide under the per-chunk logits dots, and the VJP's
-                # reverse ring reduce-scatters the head's input cotangent
-                logits = ring_order(ag_matmul(
-                    x.astype(dtype), (w.T,), "tp",
-                    self.tp_overlap == "ring_q")[0], "tp")
-            else:
-                if sp:
-                    # the tied head consumes full-sequence activations; the
-                    # gather's transpose reduce-scatters the input cotangent
-                    x = gather_from(x, "tp", tiled_axis=-2)
-                logits = x.astype(dtype) @ w.T            # (b, t, vp/tp)
-
-            if self.vocab_padded != self.cfg.vocab_size:
-                local_v = self.vocab_padded // self.tp_size
-                col = lax.axis_index("tp") * local_v + jnp.arange(local_v)
-                logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
-                                   logits, jnp.asarray(NEG_INF, logits.dtype))
-        return logits, aux
-
-    # ---- everything else is the shared machinery (see module docstring) ----
-
-    @property
-    def num_local_kv_heads(self) -> int:
-        return self.num_local_heads  # MHA: the decoder's caches are full-size
-
-    tp_layout = Transformer.tp_layout
-    _resolved = Transformer._resolved
-    _linear_overlap = Transformer._linear_overlap
-    _t_real = Transformer._t_real
-    _pipeline_layers = Transformer._pipeline_layers
-    _pipeline_interleaved = Transformer._pipeline_interleaved
-    _pp_vary_axes = Transformer._pp_vary_axes
-    _live_gated_ring = Transformer._live_gated_ring
-    _interleaved = Transformer._interleaved
-    _layers_to_schedule = Transformer._layers_to_schedule
-    _layers_to_canonical = Transformer._layers_to_canonical
-    to_canonical = Transformer.to_canonical
-    from_canonical = Transformer.from_canonical
-    canonical_specs = Transformer.canonical_specs
-
-    _zigzag = Transformer._zigzag
-    _token_ce = Transformer._token_ce
-    loss_shard = Transformer.loss_shard
-    doc_loss_shard = Transformer.doc_loss_shard
-    make_forward = Transformer.make_forward
-    make_loss = Transformer.make_loss
-    make_doc_loss = Transformer.make_doc_loss
-    shardings = Transformer.shardings
+    def _head_logits(self, params: Params, x: jax.Array, dtype) -> jax.Array:
+        """Tied head: local logits against this shard's embedding rows."""
+        tp = self._tp_sublayers
+        w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
+        if tp.ring_ov:
+            # ring collective matmul for the tied head too: the gather's
+            # hops hide under the per-chunk logits dots, and the VJP's
+            # reverse ring reduce-scatters the head's input cotangent
+            return ring_order(ag_matmul(x.astype(dtype), (w.T,), "tp",
+                                        tp.ring_quant)[0], "tp")
+        # under sequence parallelism the tied head consumes full-sequence
+        # activations; the gather's transpose reduce-scatters the input
+        # cotangent
+        return tp.gather(x).astype(dtype) @ w.T           # (b, t, vp/tp)

@@ -189,8 +189,10 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
     N = batch * t            # tokens incl. any bucket padding
     A = 2                    # activation bytes (bf16); f32 would be 4
     P = cfg.num_params()
-    # llama: SwiGLU = gate/up/down, 3 matmuls; gpt2: fc/proj gelu MLP, 2
-    ffn_mats = 2 if family == "gpt2" else 3
+    # the matrices that read the MLP's input plus the one that writes its
+    # output: SwiGLU = gate/up/down, 3 matmuls; a fc/proj gelu MLP, 2
+    from ..models import family_class
+    ffn_mats = family_class(family).ffn_inputs + 1
 
     stats = flash_tile_stats(t, block_q, block_k, t_real, hd,
                              cfg.compute_dtype)

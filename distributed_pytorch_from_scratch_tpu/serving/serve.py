@@ -32,6 +32,7 @@ from ..cli import add_model_shape_args, build_model_config
 from ..obs.runindex import run_stamp
 from ..config import (BOS_TOKEN, EOS_TOKEN, MODEL_PRESETS, MeshConfig,
                       ModelConfig, model_preset)
+from ..models import FAMILIES, build_model
 from ..ops.attention import resolve_attention_impl
 from ..runtime.compile_cache import compile_cache_stats, enable_compile_cache
 from ..runtime.mesh import make_mesh
@@ -61,7 +62,7 @@ def get_serve_args(argv=None) -> argparse.Namespace:
                         "tokenizer's convention)")
     g.add_argument("--vocab_size", type=int, default=1024,
                    help="vocab for --random_init without a tokenizer")
-    g.add_argument("--family", choices=["llama", "gpt2"], default="llama")
+    g.add_argument("--family", choices=list(FAMILIES), default="llama")
     g.add_argument("--tp_size", type=int, default=1)
     add_model_shape_args(g)
 
@@ -419,12 +420,7 @@ def _build_drafter(args, vocab_size: int, mesh, family: str):
         dcfg, vocab_size=vocab_size,
         compute_dtype="bfloat16" if getattr(args, "bf16", True) and
         not args.dry_run else "float32")
-    if family == "gpt2":
-        from ..models.gpt2 import GPT2Transformer
-        dmodel = GPT2Transformer(dcfg, tp_size=args.tp_size)
-    else:
-        from ..models.transformer import Transformer
-        dmodel = Transformer(dcfg, tp_size=args.tp_size)
+    dmodel = build_model(family, dcfg, tp_size=args.tp_size)
     if args.drafter_ckpt_dir:
         from ..training.checkpoint import latest_step, load_checkpoint
         step = (args.drafter_iter if args.drafter_iter is not None
@@ -494,12 +490,8 @@ def serve(args: argparse.Namespace) -> dict:
         cfg = build_model_config(args, vocab_size)
 
     mesh = make_mesh(MeshConfig(tp=args.tp_size, cp=args.cp))
-    if args.family == "gpt2":
-        from ..models.gpt2 import GPT2Transformer
-        model = GPT2Transformer(cfg, tp_size=args.tp_size, cp_size=args.cp)
-    else:
-        from ..models.transformer import Transformer
-        model = Transformer(cfg, tp_size=args.tp_size, cp_size=args.cp)
+    model = build_model(args.family, cfg, tp_size=args.tp_size,
+                        cp_size=args.cp)
     params = _load_params(args, model, mesh)
 
     if args.arrival == "replay" and args.replay:

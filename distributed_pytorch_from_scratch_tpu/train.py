@@ -35,7 +35,7 @@ from .config import (IGNORE_INDEX, MODEL_PRESETS, REMAT_CHOICES, MeshConfig,
                      ModelConfig, OptimizerConfig, model_preset)
 from .data.dataset import get_dataloader
 from .data.prefetch import Prefetcher, stack_window, window_stream
-from .models.transformer import Transformer
+from .models import FAMILIES, build_model
 from .obs import TrainObserver, analyze_compiled, format_analysis
 from .obs.runindex import run_stamp
 from .ops.attention import resolve_attention_impl
@@ -197,7 +197,7 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "memory); exclusive with --steps_per_dispatch > 1")
 
     g = p.add_argument_group("model")
-    g.add_argument("--family", choices=["llama", "gpt2"], default="llama",
+    g.add_argument("--family", choices=list(FAMILIES), default="llama",
                    help="model family: 'llama' = the reference architecture "
                         "(RoPE/RMSNorm/SwiGLU), 'gpt2' = LayerNorm/GELU/"
                         "learned positions/tied embeddings (models/gpt2.py; "
@@ -623,33 +623,19 @@ def train(args: argparse.Namespace) -> dict:
         if zero_stage == 2 and not args.dp_reduce_bucket_mb:
             print("zero 2: grads reduce-scatter in 25 MiB buckets "
                   "(--dp_reduce_bucket_mb to tune)")
-        if args.family == "gpt2":
-            from .models.gpt2 import GPT2Transformer
-            model = GPT2Transformer(cfg, tp_size=args.tp_size,
-                                    cp_size=args.cp_size, cp_impl=args.cp_impl,
-                                    cp_layout=args.cp_layout,
-                                    sequence_parallel=sp_arg,
-                                    tp_overlap=args.tp_overlap,
-                                    ep_size=args.ep_size, pp_size=args.pp_size,
-                                    pp_microbatches=args.pp_microbatches,
-                                    pp_remat_steps=args.pp_remat_steps,
-                                    pp_schedule=args.pp_schedule,
-                                    pp_virtual=args.pp_virtual,
-                                    remat=REMAT_CHOICES.get(remat_key, remat_key),
-                                    attn_t_real=attn_t_real)
-        else:
-            model = Transformer(cfg, tp_size=args.tp_size,
-                            cp_size=args.cp_size, cp_impl=args.cp_impl,
-                            cp_layout=args.cp_layout,
-                            sequence_parallel=sp_arg,
-                            tp_overlap=args.tp_overlap,
-                            ep_size=args.ep_size, pp_size=args.pp_size,
-                            pp_microbatches=args.pp_microbatches,
-                            pp_remat_steps=args.pp_remat_steps,
-                            pp_schedule=args.pp_schedule,
-                            pp_virtual=args.pp_virtual,
-                            remat=REMAT_CHOICES.get(remat_key, remat_key),
-                            attn_t_real=attn_t_real)
+        model = build_model(
+            args.family, cfg, tp_size=args.tp_size,
+            cp_size=args.cp_size, cp_impl=args.cp_impl,
+            cp_layout=args.cp_layout,
+            sequence_parallel=sp_arg,
+            tp_overlap=args.tp_overlap,
+            ep_size=args.ep_size, pp_size=args.pp_size,
+            pp_microbatches=args.pp_microbatches,
+            pp_remat_steps=args.pp_remat_steps,
+            pp_schedule=args.pp_schedule,
+            pp_virtual=args.pp_virtual,
+            remat=REMAT_CHOICES.get(remat_key, remat_key),
+            attn_t_real=attn_t_real)
         ocfg = OptimizerConfig(lr=args.lr, warmup_steps=args.warmup_steps,
                                max_steps=args.max_steps,
                                clip_grad_norm=args.clip_grad_norm,
@@ -897,8 +883,7 @@ def train(args: argparse.Namespace) -> dict:
 
                 duty.on_attribution = _on_attribution
         flops_step = model_flops_per_step(
-            cfg, args.batch_size, maxlen,
-            params=params if args.family == "gpt2" else None)
+            cfg, args.batch_size, maxlen, num_params=model.num_params(cfg))
         # None on the CPU backend: there is no peak to divide by, so MFU
         # is "not measured" there; an unrecognised accelerator raises
         peak_chip = chip_peak_flops()

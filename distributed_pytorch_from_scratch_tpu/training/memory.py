@@ -66,22 +66,6 @@ def zero_state_bytes_per_param(zero_stage: int, dp: int,
     return 16.0 / dp + extra
 
 
-def family_num_params(cfg, family: str = "llama") -> int:
-    """Parameters of `cfg` as `family` builds it. The llama family is
-    `cfg.num_params()` (SwiGLU, untied head); GPT-2 has a two-matrix MLP,
-    a position table, LayerNorm biases and a tied head."""
-    if family == "llama":
-        return cfg.num_params()
-    if family != "gpt2":
-        raise ValueError(f"unknown model family {family!r}")
-    d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers
-    layer = 4 * d * d + 4 * d + 2 * d * f + f + d + 4 * d
-    if cfg.num_experts:
-        layer = (4 * d * d + 4 * d + 4 * d
-                 + cfg.num_experts * 3 * d * f + d * cfg.num_experts)
-    return cfg.vocab_size * d + cfg.maxlen * d + L * layer + 2 * d
-
-
 def _rung_index(remat) -> Optional[int]:
     """Ladder index of a remat value or CLI key; None for no remat."""
     from ..models.transformer import remat_rung
@@ -175,15 +159,16 @@ def _cfg_step_bytes(cfg, batch: int, seqlen: int, remat, tp: int, world: int,
         # each token's residuals touch top_k expert FFNs plus the dispatch
         # buffers (~capacity_factor x the dense width)
         f = int(f * max(cfg.moe_top_k, 1) * cfg.moe_capacity_factor / 2)
-    P = family_num_params(cfg, family)
-    nonlayer = cfg.vocab_size * cfg.attn_dim * (2 if family == "llama"
-                                                else 1)
+    from ..models import family_class
+    fam = family_class(family)
+    P = fam.num_params(cfg)
+    nonlayer = cfg.vocab_size * cfg.attn_dim * (1 if fam.tied_head else 2)
     return step_bytes(
         remat, param_count=P / tp, layer_param_count=(P - nonlayer) / tp,
         b=max(batch // max(world // tp, 1), 1), t=seqlen, d=cfg.attn_dim,
         kd=cfg.kv_dim, f=f, heads=cfg.num_heads, head_dim=cfg.head_dim,
         layers=cfg.num_layers, vocab=cfg.padded_vocab_size(tp), tp=tp,
-        dtype_bytes=dtype_bytes, ffn_inputs=2 if family == "llama" else 1,
+        dtype_bytes=dtype_bytes, ffn_inputs=fam.ffn_inputs,
         sequence_parallel=sequence_parallel,
         state_bytes_per_param=zero_state_bytes_per_param(zero_stage, dp,
                                                          cfg),
@@ -308,7 +293,7 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
         layers=cfg.num_layers // model.pp_size,
         vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
         dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
-        ffn_inputs=2 if model.uses_rope else 1,   # SwiGLU | GPT-2's MLP
+        ffn_inputs=model.ffn_inputs,
         sequence_parallel=model.tp_layout(t)[0])
     return _pick(parts, model.remat_budget_gib, None, allow_false=False,
                  verbose=True,

@@ -136,22 +136,17 @@ def chip_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
     return chip_specs(chip_key_for(device.device_kind))[0]
 
 
-def model_flops_per_step(cfg, batch: int, seqlen: int, params=None) -> float:
+def model_flops_per_step(cfg, batch: int, seqlen: int,
+                         num_params: int) -> float:
     """Model FLOPs for one fwd+bwd train step (no remat recompute counted):
     6N_active per token + the 12*L*h*T^2*hd attention term. For MoE models
     only the top_k experts a token is routed through count (the standard
     active-parameter MFU convention); dropped-token underflow is ignored.
 
-    `params`: pass the actual param pytree for families whose shape differs
-    from the llama formula baked into `cfg.num_params()` (the gpt2 family's
-    2-matmul MLP + tied head would otherwise overcount N by ~1/3 of the
-    FFN)."""
-    if params is not None:
-        import jax
-
-        n = sum(int(x.size) for x in jax.tree.leaves(params))
-    else:
-        n = cfg.num_params()
+    `num_params` is the family's count of `cfg`, `model.num_params(cfg)`
+    (the gpt2 family's 2-matmul MLP + tied head are a third of the FFN and
+    a vocabulary short of the llama formula in `cfg.num_params()`)."""
+    n = num_params
     if getattr(cfg, "num_experts", 0):
         inactive = ((cfg.num_experts - cfg.moe_top_k)
                     * 3 * cfg.attn_dim * cfg.ffn_dim)
