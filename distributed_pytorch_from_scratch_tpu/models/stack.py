@@ -44,6 +44,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import IGNORE_INDEX, ModelConfig, resolve_dtype
 from ..ops.attention import causal_attention
 from ..ops.collectives import copy_to, gather_from, reduce_from
+from ..ops.overlap import exchange_grads
 from ..ops.ring_attention import ring_attention, ulysses_attention
 from ..parallel.linear import OVERLAP_MODES, apply_column_ring_fused
 from ..parallel.moe import aux_losses, aux_zeros
@@ -194,6 +195,22 @@ def resolve_tp_layout(sequence_parallel, tp_overlap: str, *, tp_size: int,
     if ov == "auto":
         ov = "ring" if chose_sp and sp and pp_size == 1 else "off"
     return bool(sp), ov
+
+
+def resolve_dp_reduce(*, dp_size: int, dense: bool, pp_size: int = 1) -> str:
+    """Who sums a layer's weight cotangents over 'dp' in the default step:
+    'none' at dp_size 1 (the layer body is the text it has always been),
+    'exchange' for a dense model with pp_size 1 (`ops/overlap.
+    exchange_grads`: a typed gather a leaf, asynchronous under the
+    backward's dots), else 'psum' (the all-reduce the transpose of the
+    varying cast inserts: MoE and pp > 1 keep it, untimed either way). The
+    rule rests on one timed shape, GPT-2 large at dp2 x tp2 on a v5e
+    (PERF.md section 6, PR 32); tp does not enter it. Read by the layer
+    body at trace time (`DecoderStack._exchanges_dp_grads`) and by train.py
+    for the line that names the layout."""
+    if dp_size == 1:
+        return "none"
+    return "exchange" if dense and pp_size == 1 else "psum"
 
 
 # The residuals a layer's backward may keep instead of recomputing, in the
@@ -549,6 +566,19 @@ class DecoderStack:
         return TPSublayers(self._mods, sp, sp and self.tp_overlap in _RING,
                            self.tp_overlap == "ring_q")
 
+    def _exchanges_dp_grads(self, x: jax.Array) -> bool:
+        """Read inside a trace: does the layer body hand its weights over
+        through `exchange_grads`? `resolve_dp_reduce` on the axis size the
+        trace sees, where the layer's input `x` varies over 'dp': a caller
+        that feeds every replica the same rows (evaluate.py's greedy
+        decode) has nothing to sum over it, and under check_vma=False
+        nothing is typed varying (the hand-reduced builders of
+        training/zero.py, which sum once, by hand)."""
+        return (resolve_dp_reduce(dp_size=lax.axis_size("dp"),
+                                  dense=not self.is_moe,
+                                  pp_size=self.pp_size) == "exchange"
+                and "dp" in jax.typeof(x).vma)
+
     # ---- parameter tree: a family's `init` / `specs` add its own leaves ----
 
     def _init_layers(self, key: jax.Array) -> Params:
@@ -660,6 +690,13 @@ class DecoderStack:
             from ..training.zero import zero3_layer_gather
             layer_params = zero3_layer_gather(self, layer_params,
                                               self.zero3_axis)
+        if self._exchanges_dp_grads(x):
+            # every module casts its leaves to the compute dtype before it
+            # uses them; cast once here, so the cotangent the exchange sums
+            # is the one the psum summed (bf16 on the wire where the compute
+            # is bf16, accumulated in float32 past the cast's transpose)
+            layer_params = exchange_grads(
+                jax.tree.map(lambda a: a.astype(dtype), layer_params), "dp")
         m, tp = self._mods, self._tp_sublayers
         h = self.cfg.head_dim
         b = x.shape[0]

@@ -536,8 +536,10 @@ def comm_attribution(cfg, batch: int, t: int, tp: int = 1, sp: bool = False,
             note = (f"bucketed ({dp_bucket_mb:g} MiB, {dp_reduce_dtype} "
                     f"wire): buckets overlap the remaining backward"
                     if bucketed else
-                    "end-of-step whole-tree blob: fully exposed "
-                    "(--dp_reduce_bucket_mb to overlap)")
+                    "priced as one exposed all-reduce of the tree; the "
+                    "default step gathers the layers' part leaf by leaf "
+                    "under the backward (ops/overlap.exchange_grads), "
+                    "which at dp 2 moves these bytes")
             add("DP grad reduce", "all-reduce", 1, nbytes, 2 * (dp - 1),
                 budget, note)
 
@@ -866,10 +868,10 @@ def expected_collectives(tp: int = 1, sp: bool = False,
     they are part of the stage's schedule even though the pricing
     attributes them to other records.
 
-    `dp_bucket_mb` is accepted for symmetry with `comm_attribution`'s
-    config surface (program configs pass through verbatim): bucketing
-    changes collective COUNTS and overlap, never the (axis, op)
-    inventory, so it does not alter the sets today.
+    `dp_bucket_mb` (program configs pass through verbatim) changes
+    collective COUNTS and overlap, and since PR 32 one entry: without it
+    (and below ZeRO-2) the default step sums the layers' gradients by typed
+    all-gathers over dp, which the hand-reduced builders do not have.
     """
     require: Dict[tuple, dict] = {}
     allow: Dict[tuple, str] = {}
@@ -955,6 +957,15 @@ def expected_collectives(tp: int = 1, sp: bool = False,
                 require[("dp", "all-reduce")] = {
                     "dtypes": wide,
                     "note": "the DP grad reduce (bucketed or whole-tree)"}
+                if not dp_bucket_mb and zero_stage <= 1:
+                    require[("dp", "all-gather")] = {
+                        "dtypes": wide,
+                        "note": "the default step's exchange of the "
+                                "layers' weight cotangents (ops/overlap."
+                                "exchange_grads): a typed gather a leaf, "
+                                "at dp 2 the bytes of the all-reduce that "
+                                "'DP grad reduce' prices; the non-layer "
+                                "leaves stay psums"}
             if zero_stage == 1:
                 require[("dp", "all-gather")] = {
                     "dtypes": {"f32"},

@@ -63,6 +63,12 @@ own inverse and its own transpose and fuses into what reads it.
   f32 scale per `quant.WIRE_GROUP` elements (<1% overhead), quarter the
   f32 wire bytes; the accumulate between hops stays f32 on-rank.
 
+* `exchange_grads(tree, axis)` — the default step's own sum of a layer's
+  weight cotangents over 'dp' (PR 32): the identity forward, and in the
+  backward one typed all-gather a leaf plus a sum in rank order, which the
+  TPU backend runs as start / done pairs under the backward's dots, where
+  the psum it replaces was one synchronous all-reduce at the layer's end.
+
 * `ring_all_gather(x, axis, dim)` / `bucketed_reduce_scatter(...)` /
   `quantized_reduce_scatter(...)` — the ZeRO-2/3 wires (training/zero.py).
   `ring_all_gather` is the per-layer ZeRO-3 param gather: n-1 explicit
@@ -97,6 +103,10 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+# jax 0.9.0 has the varying -> invariant gather under this name only;
+# `lax.all_gather(..., to="reduced")` returns the reduced type, which is not
+# a replicated weight's cotangent type (and `slice` has no rule for it)
+from jax._src.lax.parallel import all_gather_invariant
 
 from .collectives import ring_permute
 from .quant import (WIRE_GROUP, dequantize_groups, dequantize_rows,
@@ -124,8 +134,10 @@ def _like_primal(ct: jax.Array, primal: jax.Array) -> jax.Array:
     per-shard cotangent varies over those axes too; shard_map's typing
     wants it back as replicated as the weight, which is the psum autodiff
     itself inserts for a plain `x @ w` (the transpose of the implicit
-    varying cast on w). No-op where the types already agree, and under
-    check_vma=False, where nothing carries a type."""
+    varying cast on w). No-op where the types already agree (over 'dp'
+    they do wherever the layer handed its weights over through
+    `exchange_grads`), and under check_vma=False, where nothing carries a
+    type."""
     extra = tuple(sorted(jax.typeof(ct).vma - jax.typeof(primal).vma))
     return lax.psum(ct, extra) if extra else ct
 
@@ -428,6 +440,80 @@ def _matmul_rs_bwd(axis, quantized, res, dy):
 
 
 matmul_rs.defvjp(_matmul_rs_fwd, _matmul_rs_bwd)
+
+
+# ------------------------------------------------- the dp gradient exchange --
+
+# leaves under this many elements (biases, norm gains) share one gather
+SMALL_LEAF = 1 << 16
+
+
+def exchange_sum(x: jax.Array, axis: str) -> jax.Array:
+    """The sum of every rank's `x` over `axis`, typed replicated over it:
+    one gather of the n copies and a local sum in RANK ORDER, so every rank
+    adds the same values in the same order and the replicas hold the same
+    bits (the optimizer runs on each of them). At n = 2 the gather is one
+    exchange with the peer, moves the bytes an all-reduce moves, and
+    `a + b` rounded once is what the all-reduce returns. At n > 2 it moves
+    (n-1) copies where a reduce-scatter + all-gather ring would move
+    2(n-1)/n: that ring was written and compiled (PERF.md section 6,
+    PR 32) and the TPU compiler made synchronous all-gathers and an
+    all-reduce of it, so there is one form, timed at n = 2 only."""
+    g = all_gather_invariant(x, axis)          # (n, ...) in rank order
+    return functools.reduce(jnp.add, [g[r] for r in range(g.shape[0])])
+
+
+def _exchange_tree(cts, axis: str):
+    """`exchange_sum` of every leaf, each gather depending on its own leaf
+    only: it starts where that cotangent is made. The small leaves ride
+    together, grouped by dtype and by the axes they still vary over."""
+    leaves, treedef = jax.tree.flatten(cts)
+    out = list(leaves)
+    small: "dict[tuple, list[int]]" = {}
+    for i, g in enumerate(leaves):
+        if g.size < SMALL_LEAF:
+            key = (jnp.dtype(g.dtype).name, jax.typeof(g).vma)
+            small.setdefault(key, []).append(i)
+        else:
+            out[i] = exchange_sum(g, axis)
+    for idxs in small.values():
+        flat = exchange_sum(
+            jnp.concatenate([leaves[i].ravel() for i in idxs]), axis)
+        off = 0
+        for i in idxs:
+            n = leaves[i].size
+            out[i] = flat[off:off + n].reshape(leaves[i].shape)
+            off += n
+    return jax.tree.unflatten(treedef, out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def exchange_grads(tree, axis: str = "dp"):
+    """Hand a layer's weights, replicated over `axis`, to code whose
+    activations vary over it: the identity, typed varying. The backward
+    owns what the transpose of that cast would have been, the sum of the
+    cotangents over `axis`, and runs it as `exchange_sum` a leaf.
+
+    Why not the psum: XLA combines a layer's psums into one all-reduce that
+    waits for the last cotangent and is the last op of the backward body,
+    synchronous: on GPT-2 large at dp2 x tp2 16.0 ms of a 257 ms step in
+    which the chip only waits for one ICI link (19.7 MB of bf16 a layer a
+    chip at 44 GB/s). A v5e runs an all-gather as a start / done pair and
+    keeps them apart: `proj`, `fc` and the attention projection finish
+    under the rest of the layer's backward, two of q/k/v under the third's
+    dot (PERF.md section 6, PR 32: the step 12.9 ms shorter). Inside the
+    callee the weights vary over `axis`, so `_like_primal` and the
+    transposes find nothing left to sum over it; cp, ep and tp sums stay
+    where they were. For a caller whose activations are typed varying
+    over `axis`: one that feeds every replica the same rows has nothing to
+    sum, and under check_vma=False nothing is typed (the hand-reduced
+    builders of training/zero.py, which sum once, by hand)."""
+    return jax.tree.map(lambda a: lax.pcast(a, (axis,), to="varying"), tree)
+
+
+exchange_grads.defvjp(
+    lambda tree, axis: (exchange_grads(tree, axis), None),
+    lambda axis, _, cts: (_exchange_tree(cts, axis),))
 
 
 # ------------------------------------------------------ bucketed reduction --

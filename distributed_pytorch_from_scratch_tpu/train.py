@@ -552,6 +552,7 @@ def train(args: argparse.Namespace) -> dict:
         # picks: the model resolves it per trace, and the same resolver
         # answers here for the memory estimate, ZeRO's scope and the
         # analytic report, so all of them see the layout the step runs
+        from .models.stack import resolve_dp_reduce
         from .models.transformer import resolve_tp_layout
         sp_arg = {"auto": "auto", "on": True,
                   "off": False}[args.sequence_parallel]
@@ -559,6 +560,13 @@ def train(args: argparse.Namespace) -> dict:
             sp_arg, args.tp_overlap, tp_size=args.tp_size,
             t_local=(t_bucket or maxlen) // args.cp_size,
             dense=not cfg.num_experts, pp_size=args.pp_size)
+        # who sums the layers' gradients over dp: a hand-reduced builder
+        # where one was asked for, else what the layer body picks per trace
+        dp_reduce = (f"zero{zero_stage}" if zero_stage >= 2
+                     else "bucketed" if args.dp_reduce_bucket_mb
+                     else resolve_dp_reduce(dp_size=args.dp_size,
+                                            dense=not cfg.num_experts,
+                                            pp_size=args.pp_size))
         remat_key = args.remat
         if remat_key == "auto":
             from .training.memory import select_remat
@@ -651,6 +659,16 @@ def train(args: argparse.Namespace) -> dict:
                     if cfg.num_experts else "")
         dev0 = jax.devices()[0]
         attn_impl = resolve_attention_impl(model.attn_impl)
+        # what one chip puts on the dp wire for one layer: its shard of the
+        # layer's leaves in the compute dtype (ops/overlap.exchange_grads)
+        dp_layer_bytes = None
+        if dp_reduce == "exchange":
+            dp_layer_bytes = sum(
+                math.prod(sh.shard_shape(x.shape)[1:])
+                for x, sh in zip(
+                    jax.tree.leaves(params["layers"]),
+                    jax.tree.leaves(model.shardings(mesh)["layers"]))
+            ) * jnp.dtype(cfg.compute_dtype).itemsize
         print(f"model[{args.family}]: {n_params/1e6:.2f}M params{moe_note}, "
               f"vocab={vocab_size}, "
               f"mesh=dp{args.dp_size} x pp{args.pp_size} x cp{args.cp_size} x "
@@ -658,6 +676,10 @@ def train(args: argparse.Namespace) -> dict:
               f"compute={cfg.compute_dtype}, attn={attn_impl}"
               + (f", sp={'on' if sp else 'off'}, tp_overlap={tp_overlap}"
                  if args.tp_size > 1 else "")
+              + (f", dp_reduce={dp_reduce}"
+                 + (f" ({dp_layer_bytes / 1e6:.1f} MB a layer)"
+                    if dp_layer_bytes else "")
+                 if args.dp_size > 1 else "")
               + (f", zero={zero_stage}" if zero_stage else "")
               + f" on {jax.device_count()} x {dev0.platform} "
                 f"[{dev0.device_kind}]")
@@ -1299,6 +1321,8 @@ def train(args: argparse.Namespace) -> dict:
                         "tp": args.tp_size},
                "attn_impl": attn_impl,
                "sequence_parallel": sp, "tp_overlap": tp_overlap,
+               "dp_reduce": dp_reduce,
+               "dp_reduce_layer_bytes": dp_layer_bytes,
                "peak_flops_per_chip": peak_chip,
                "compile_s": aot["compile_s"],
                "collectives": aot["collectives"],

@@ -16,6 +16,10 @@ Three invariants pinned on the virtual 8-device CPU mesh:
    wire. A jax upgrade that changes shard_map's psum-transpose semantics
    breaks parity here LOUDLY (training/zero.build_bucketed_grad_fn
    normalises a trace-time-measured inflation factor).
+4. The default step's own sum over 'dp' (`exchange_grads`: a typed gather
+   and a sum in rank order, PR 32) gives the gradients the transpose's psum
+   gives, the same bits on every replica, and is not there at dp 1 or
+   inside the hand-reduced builders.
 """
 
 import jax
@@ -27,6 +31,7 @@ from jax.sharding import PartitionSpec as P
 from distributed_pytorch_from_scratch_tpu.config import (
     IGNORE_INDEX, MeshConfig, ModelConfig)
 from distributed_pytorch_from_scratch_tpu.models.gpt2 import GPT2Transformer
+from distributed_pytorch_from_scratch_tpu.models.stack import DecoderStack
 from distributed_pytorch_from_scratch_tpu.models.transformer import (
     Transformer, resolve_tp_layout)
 from distributed_pytorch_from_scratch_tpu.models.vanilla import (
@@ -392,3 +397,118 @@ def test_bucketed_reduce_scope_refusals():
     with pytest.raises(ValueError, match="MoE"):
         build_bucketed_grad_fn(
             Transformer(moe_cfg, tp_size=2, ep_size=2), mesh_ep)
+
+
+# ------------------------------------------ the dp gradient exchange (PR 32) --
+
+def _psum_reducer(monkeypatch):
+    """The parent's form, kept as the oracle: with the hand-over off, a
+    layer's weight cotangents are summed over 'dp' by the psum that the
+    transpose of the varying cast (and `_like_primal`) inserts."""
+    monkeypatch.setattr(DecoderStack, "_exchanges_dp_grads",
+                        lambda self, x: False)
+
+
+def _loss_and_grads(family, mesh_sizes, kw, params=None):
+    cls = GPT2Transformer if family == "gpt2" else Transformer
+    mesh = make_mesh(MeshConfig(**mesh_sizes))
+    model = cls(CFG, tp_size=mesh_sizes.get("tp", 1),
+                cp_size=mesh_sizes.get("cp", 1), **kw)
+    if params is None:
+        params = jax.device_put(model.init(jax.random.key(0)),
+                                model.shardings(mesh))
+    batch = make_batch(jax.random.key(2), batch=8)
+    fn = jax.jit(jax.value_and_grad(model.make_loss(mesh)))
+    return model, params, fn, batch
+
+
+EXCHANGE_CASES = [
+    ("gpt2", dict(dp=2, tp=2), {}),
+    ("llama", dict(dp=2, tp=2), {}),
+    ("gpt2", dict(dp=4, tp=1), {}),
+    ("llama", dict(dp=2, tp=2), dict(tp_overlap="ring_q")),
+    ("gpt2", dict(dp=2, cp=2), {}),
+]
+EXCHANGE_IDS = ["gpt2-dp2tp2", "llama-dp2tp2", "gpt2-dp4tp1",
+                "llama-dp2tp2-ring_q", "gpt2-dp2cp2"]
+
+
+@pytest.mark.parametrize("family,mesh_sizes,kw", EXCHANGE_CASES,
+                         ids=EXCHANGE_IDS)
+def test_dp_exchange_matches_the_psum_reducer(family, mesh_sizes, kw,
+                                              monkeypatch):
+    """Every gradient leaf of the default path against the psum reducer. At
+    dp 2 a leaf summed over 'dp' alone (one sharded over 'tp', with no cp)
+    is `a + b` either way: equal to the bit. A leaf the other axes sum too
+    (norm gains and row biases under sequence parallelism, everything under
+    cp) adds four terms in another order: the last place of a float32. At
+    dp 4 the rank-ordered sum against the all-reduce's order: the bound the
+    bucketed reducer is held to."""
+    model, params, fn, batch = _loss_and_grads(family, mesh_sizes, kw)
+    l1, g1 = fn(params, *batch)
+    _psum_reducer(monkeypatch)
+    _, _, oracle, _ = _loss_and_grads(family, mesh_sizes, kw, params)
+    l0, g0 = oracle(params, *batch)
+    assert float(l1) == float(l0)
+    layer_specs = jax.tree.leaves(model.specs()["layers"],
+                                  is_leaf=lambda x: isinstance(x, P))
+    if mesh_sizes["dp"] == 2:
+        assert_trees_close(g1, g0, rtol=1e-6, atol=1e-8)
+        if "cp" not in mesh_sizes:
+            for a, b, spec in zip(jax.tree.leaves(g1["layers"]),
+                                  jax.tree.leaves(g0["layers"]),
+                                  layer_specs):
+                if "tp" in jax.tree.leaves(tuple(spec)):
+                    np.testing.assert_array_equal(np.asarray(a),
+                                                  np.asarray(b))
+    else:
+        assert_trees_close(g1, g0, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("mesh_sizes", [dict(dp=2, tp=2), dict(dp=4, tp=2)],
+                         ids=["dp2", "dp4"])
+def test_dp_exchange_leaves_replicas_bitwise_identical(mesh_sizes):
+    """The optimizer runs on every replica: each has to hold the same
+    gradient to the bit. Every rank adds the gathered copies in rank order,
+    so they do; read from the devices' own buffers, shard by shard."""
+    _, params, fn, batch = _loss_and_grads("gpt2", mesh_sizes, {})
+    _, grads = fn(params, *batch)
+    for g in jax.tree.leaves(grads):
+        by_index = {}
+        for shard in g.addressable_shards:
+            by_index.setdefault(str(shard.index), []).append(
+                np.asarray(shard.data))
+        assert all(len(copies) >= mesh_sizes["dp"]
+                   for copies in by_index.values())
+        for copies in by_index.values():
+            for c in copies[1:]:
+                np.testing.assert_array_equal(c, copies[0])
+
+
+@pytest.mark.parametrize("mesh_sizes,kw,engages", [
+    (dict(dp=1, tp=2), {}, False),
+    (dict(dp=1, tp=1), {}, False),
+    (dict(dp=2, tp=2), {}, True),
+    # pp > 1 keeps the psum it has (untimed either way)
+    (dict(dp=2, pp=2), dict(pp_size=2), False),
+], ids=["dp1tp2", "dp1tp1", "dp2tp2", "dp2pp2"])
+def test_dp_exchange_engages_from_the_axis_size_alone(mesh_sizes, kw,
+                                                      engages, monkeypatch):
+    """At dp 1 (and under pp) the lowered loss-and-gradient is the text it
+    is with the hand-over off: no new collective, no new op; at dp 2 the
+    typed gather is there and the layers' psum over dp is not."""
+    mesh = make_mesh(MeshConfig(**mesh_sizes))
+    params = jax.eval_shape(GPT2Transformer(CFG).init, jax.random.key(0))
+    batch = make_batch(jax.random.key(2), batch=8)
+
+    def lowered():
+        model = GPT2Transformer(CFG, tp_size=mesh_sizes.get("tp", 1), **kw)
+        return jax.jit(jax.value_and_grad(model.make_loss(mesh))).lower(
+            params, *batch).as_text()
+
+    text = lowered()
+    _psum_reducer(monkeypatch)
+    off = lowered()
+    assert (text != off) == engages
+    gathers = lambda t: t.count("stablehlo.all_gather")
+    assert (gathers(text) > gathers(off)) == engages

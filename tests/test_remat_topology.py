@@ -10,6 +10,10 @@ what the runtime reserves: it charges some of the stacks that live from the
 forward loop to the backward loop twice (PERF.md section 5).
 """
 
+import contextlib
+import io
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -66,10 +70,15 @@ def described_tpu(monkeypatch):
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("cell", sorted(CELLS))
-def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
-                                                      described_tpu, capsys):
-    widths, mesh_sizes, batch, want_rung = CELLS[cell]
+_COMPILED = {}
+
+
+def compiled_step(cell, topo):
+    """The cell's step compiled for the described chip, once a cell for the
+    whole file: (model, compiled, its text, what the trace printed)."""
+    if cell in _COMPILED:
+        return _COMPILED[cell]
+    widths, mesh_sizes, batch, _ = CELLS[cell]
     chips = mesh_sizes["dp"] * mesh_sizes["tp"]
     mesh = make_mesh(MeshConfig(**mesh_sizes), devices=topo.devices[:chips])
     cfg = ModelConfig(vocab_size=50257, maxlen=1024,
@@ -88,10 +97,22 @@ def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
                                                               "cp")))
     step = build_train_step(model, mesh, OptimizerConfig(),
                             with_grad_norm=True)
-    compiled = step.lower(params, opt, ids, ids, ids).compile()
+    said = io.StringIO()
+    with contextlib.redirect_stderr(said):
+        compiled = step.lower(params, opt, ids, ids, ids).compile()
+    _COMPILED[cell] = (model, compiled, compiled.as_text(), said.getvalue())
+    return _COMPILED[cell]
 
-    assert f"remat auto: picked '{want_rung}'" in capsys.readouterr().err
-    text = compiled.as_text()
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
+                                                      described_tpu):
+    widths, mesh_sizes, batch, want_rung = CELLS[cell]
+    chips = mesh_sizes["dp"] * mesh_sizes["tp"]
+    model, compiled, text, said = compiled_step(cell, topo)
+    cfg = model.cfg
+
+    assert f"remat auto: picked '{want_rung}'" in said
     assert "tpu_custom_call" in text                     # the flash kernel
     # the ring collective matmuls' hops, asynchronous, at tp 2 and only there
     sp, overlap = model.tp_layout(1024)
@@ -109,3 +130,63 @@ def test_cells_step_fits_a_v5e_at_the_rung_auto_picks(cell, topo,
     assert estimate < planned * 1.01, (estimate, planned)
     # ... and with one more copy of the state (the snapshot) it still fits
     assert estimate + args < V5E_LIMIT_GIB, (estimate, args)
+
+
+COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|all-to-all|reduce-scatter|collective-permute)"
+    r"(-start)?\(")
+DP_PAIRS = "replica_groups={{0,2},{1,3}}"
+
+
+def computations(text):
+    """name -> instruction lines, metadata cut off, of each computation."""
+    out, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and "->" in line and not line.startswith(" "):
+            name = line.split(" (", 1)[0].replace("ENTRY ", "")
+            out[name] = []
+        elif name and " = " in line:
+            out[name].append(line.split(", metadata=")[0])
+    return out
+
+
+def test_cell_1_step_has_no_collective(topo, described_tpu):
+    """dp 1 x tp 1: the dp exchange does not engage (nor anything else that
+    talks to another chip)."""
+    _, _, text, _ = compiled_step("gpt2-medium.train-b12-t1024", topo)
+    for lines in computations(text).values():
+        assert not [l for l in lines if COLLECTIVE.search(l)]
+
+
+def test_cell_2_backward_body_exchanges_the_dp_gradients_under_dots(
+        topo, described_tpu):
+    """The layers' sum over 'dp' is no all-reduce at the end of the backward
+    body any more (PR 32): each leaf's gather over the dp pairs is issued as
+    a start / done pair, and dots run between the two."""
+    _, _, text, said = compiled_step("gpt2-large.train-dp2-tp2", topo)
+    assert "remat auto: picked 'dots'" in said
+    comps = computations(text)
+    # the backward body: the while body that holds the dp gathers
+    bodies = [lines for lines in comps.values()
+              if any(l.lstrip().startswith("%async-collective-start")
+                     for l in lines)
+              and any("collective-permute-start" in l for l in lines)]
+    assert len(bodies) == 1
+    body = bodies[0]
+    assert not [l for l in body if " all-reduce(" in l and DP_PAIRS in l]
+    # a gather's start names the fused computation that holds the
+    # all-gather; its done is the next `async-collective-done`
+    gathers = {name: lines for name, lines in comps.items()
+               if any(" all-gather(" in l and DP_PAIRS in l for l in lines)}
+    hidden = 0
+    for i, line in enumerate(body):
+        if not line.lstrip().startswith("%async-collective-start"):
+            continue
+        assert re.search(r"calls=(%[\w.\-]+)", line).group(1) in gathers
+        done = next(j for j in range(i + 1, len(body))
+                    if body[j].lstrip().startswith("%async-collective-done"))
+        dots = [l for l in body[i + 1:done]
+                if " convolution(" in l or "kind=kOutput" in l]
+        hidden += bool(dots)
+    # proj, fc, the attention projection and two of q/k/v at the least
+    assert hidden >= 5, hidden
