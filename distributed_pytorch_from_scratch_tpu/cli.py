@@ -11,6 +11,7 @@ the other direction (flags create the config the checkpoint will record).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 from .config import MODEL_PRESETS, ModelConfig, model_preset
 
@@ -42,7 +43,10 @@ def build_model_config(args: argparse.Namespace,
     """Preset-aware ModelConfig from the shared shape flags."""
     preset = model_preset(args.model) if args.model else ModelConfig()
     pick = lambda flag, dflt: dflt if flag is None else flag
-    return ModelConfig(
+    # the preset with the flags laid over it: what no flag names (the
+    # mla_moe family's `latent_moe`, rope_theta) stays the preset's
+    return dataclasses.replace(
+        preset,
         attn_dim=pick(args.attn_dim, preset.attn_dim),
         ffn_dim=pick(args.ffn_dim, preset.ffn_dim),
         num_heads=pick(args.num_heads, preset.num_heads),

@@ -38,6 +38,42 @@ def resolve_dtype(name: str):
 
 
 @dataclass(frozen=True)
+class LatentMoEConfig:
+    """What the `mla_moe` family (models/mla_moe.py) needs beyond
+    `ModelConfig`'s own fields: latent attention, a sigmoid router over
+    routed experts of which this job may hold a slice, a shared expert,
+    leading dense layers and multi-token-prediction modules. The keys are
+    DeepSeek-V3's `config.json` names where one exists. In `ModelConfig`,
+    `attn_dim` is the model width, `ffn_dim` the dense layers' SwiGLU
+    width, `num_layers` the main model's layers (dense + expert),
+    `num_experts` the ROUTED experts the router scores (all of them,
+    whatever is held here) and `moe_top_k` the experts a token takes."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    moe_intermediate_size: int
+    n_shared_experts: int = 1
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 1.0
+    # The job's share of an expert-parallel deployment: experts
+    # [expert_offset, expert_offset + experts_held) live here (None: all).
+    # The router still scores and ranks every routed expert; what an absent
+    # expert would have added is left out (parallel/moe.SharedRoutedFFN).
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
+    rms_norm_eps: float = 1e-6
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -69,6 +105,8 @@ class ModelConfig:
     moe_capacity_factor: float = 2.0
     moe_aux_coef: float = 0.01   # load-balance loss weight (Switch: 0.01)
     moe_z_coef: float = 1e-3     # router z-loss weight (ST-MoE: 1e-3)
+    # The `mla_moe` family's facts (None for every other family).
+    latent_moe: "LatentMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -84,6 +122,15 @@ class ModelConfig:
         """Output width of wk/wv: kv_heads * head_dim (== attn_dim for MHA)."""
         return self.kv_heads * self.head_dim
 
+    @property
+    def experts_held(self) -> int:
+        """Routed experts this job holds: all of them, unless the mla_moe
+        family's `latent_moe.experts_held` names a share."""
+        lm = self.latent_moe
+        if lm is None or lm.experts_held is None:
+            return self.num_experts
+        return lm.experts_held
+
     def padded_vocab_size(self, tp_size: int) -> int:
         """Vocab size rounded up to a multiple of tp_size.
 
@@ -95,6 +142,9 @@ class ModelConfig:
         return ((self.vocab_size + tp_size - 1) // tp_size) * tp_size
 
     def num_params(self) -> int:
+        if self.latent_moe is not None:
+            from .models.mla_moe import LatentMoETransformer
+            return LatentMoETransformer.num_params(self)
         d, f, v, L = self.attn_dim, self.ffn_dim, self.vocab_size, self.num_layers
         kd = self.kv_dim
         attn = 2 * d * d + 2 * d * kd + 2 * d + 2 * kd  # wq/wo + wk/wv (+ biases)
@@ -127,6 +177,16 @@ MODEL_PRESETS = {
     # state ~4.3 GiB f32, fits the 16 GiB chip with remat at b4xt1024
     "gpt2-355m": ModelConfig(attn_dim=1024, ffn_dim=4096, num_heads=16,
                              num_layers=24, vocab_size=50257, maxlen=1024),
+    # the `mla_moe` family at a CPU size: latent attention (q/k 24 wide, v
+    # 16), one dense layer then two expert layers of 8 routed experts
+    # (sigmoid top-2, a shared expert), one multi-token-prediction module
+    "tiny-mla-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_layers=3,
+        vocab_size=1024, maxlen=256, rope_theta=10000.0, num_experts=8,
+        moe_top_k=2, latent_moe=LatentMoEConfig(
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
+            routed_scaling_factor=2.5, num_nextn_predict_layers=1)),
 }
 
 

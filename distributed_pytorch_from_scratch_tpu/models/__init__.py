@@ -1,10 +1,12 @@
 """The model families, and the one place a family's name becomes a class."""
 
 from .gpt2 import GPT2Transformer
+from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
 from .transformer import Transformer
 
-FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer}
+FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer,
+            "mla_moe": LatentMoETransformer}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

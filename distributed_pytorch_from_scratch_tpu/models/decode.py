@@ -51,6 +51,20 @@ from .transformer import NEG_INF, Transformer
 Params = Dict[str, Any]
 
 
+def require_decodable(model) -> None:
+    """The decoder reads a family's `wq`/`wk`/`wv` by name and keeps k and v
+    per head in its caches; a family that says `decodable = False` (latent
+    attention, whose cache would hold the latents) is refused here, by
+    every entry point of this file and by the serving engines."""
+    if not getattr(model, "decodable", True):
+        raise ValueError(
+            f"the {type(model).__name__} family cannot be decoded or served "
+            f"yet: models/decode.py and serving/engine.py cache k and v per "
+            f"head from wq/wk/wv, and this family's attention is latent "
+            f"(ROADMAP: serving with latent pages). It trains "
+            f"(train.py, training/train_step.py) and evaluates its loss.")
+
+
 def _qkv(model: Transformer, lp: Params, y: jax.Array, dtype):
     """Project y (b, t, d) -> q (b, local_heads, t, hd) and k, v at
     (b, local_KV_heads, t, hd).
@@ -859,6 +873,7 @@ def make_generate(model: Transformer, mesh: Mesh, buf_len: int,
     contributing to their length and are padded with eos_id while other
     rows finish. One compile serves every prompt (prompt_len/eos/limit are
     traced; temperature/top_k/top_p are build-time constants)."""
+    require_decodable(model)
     cfg = model.cfg
     dtype = resolve_dtype(cfg.compute_dtype)
     # RoPE tables cover the whole decode buffer even past the model's
@@ -964,6 +979,7 @@ class GreedyDecoder:
     def __init__(self, model: Transformer, mesh: Mesh, buf_len: int,
                  temperature: float = 0.0, top_k: int = 0,
                  top_p: float = 0.0):
+        require_decodable(model)
         if model.cp_size > 1:
             # Long-context decode: the PREFILL runs the same ring-attention
             # path as training (sequence sharded over 'cp'), so prompts far

@@ -30,9 +30,11 @@ them) that supplies what differs, and nothing else:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from dataclasses import dataclass
+from types import SimpleNamespace
 from typing import Any, Dict, Tuple
 
 import jax
@@ -295,8 +297,10 @@ def resolve_remat(model, params: Params, ids_shape):
     from ..training.memory import select_remat_traced
     count = lambda tree: sum(int(x.size) for x in jax.tree.leaves(tree))
     b, t = ids_shape
-    return select_remat_traced(model, count(params), count(params["layers"]),
-                               int(b), int(t))
+    return select_remat_traced(
+        model, count(params),
+        sum(count(params[key]) for key in model._layer_keys),
+        int(b), int(t))
 
 
 @dataclass(frozen=True)
@@ -487,6 +491,25 @@ class DecoderStack:
     # path keeps params at model.specs() layouts and must leave it None.
     zero3_axis: "str | None" = None
 
+    # ---- what a family may say it cannot do (refused with a message where
+    # it is asked for; every family that says nothing can do all of it) ----
+    decodable = True            # models/decode.py and the serving engine
+    hand_reduced_grads = True   # training/zero.py's builders (ZeRO 2/3,
+                                # the bucketed reducer)
+    # the ModelConfig field that carries facts only this family reads
+    # (None: the config's own fields are all it needs)
+    config_extra = None
+    # The LAYER PATTERN: the keys of the parameter tree that hold stacked
+    # layers, in the order the forward scans them. One stack, one remat
+    # policy, one `_layer_body`; a segment's layers share one parameter
+    # structure, and what a layer's FFN is follows from what its
+    # parameters hold (`_ffn`). A pipeline splits one segment only.
+    _layer_keys = ("layers",)
+    # does the loss add the Switch load-balance and z terms of
+    # parallel/moe.MoEFFN's router sums (a family whose router balances
+    # without an auxiliary loss says no)
+    _router_aux_losses = True
+
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
         validate_remat(self.remat)
@@ -535,6 +558,14 @@ class DecoderStack:
     def is_moe(self) -> bool:
         return self.cfg.num_experts > 0
 
+    # what training/memory.py asks beside the config's widths
+    @property
+    def stacked_layers(self) -> int:
+        """Layers whose input the backward keeps, over all segments."""
+        return self.cfg.num_layers
+
+    layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
+
     def tp_layout(self, t_local: int) -> Tuple[bool, str]:
         """(sequence_parallel, tp_overlap) as a batch of cp-local sequence
         length `t_local` is traced: `resolve_tp_layout` on this model."""
@@ -581,20 +612,29 @@ class DecoderStack:
 
     # ---- parameter tree: a family's `init` / `specs` add its own leaves ----
 
-    def _init_layers(self, key: jax.Array) -> Params:
+    def _init_layers(self, key: jax.Array, segment: str = "layers",
+                     count: "int | None" = None, names=None) -> Params:
         """`_mods`' params stacked along a leading num_layers axis for scan
-        (in the schedule's layout under the interleaved pipeline)."""
-        layer_keys = jax.random.split(fold(key, "layers"),
-                                      self.cfg.num_layers)
+        (in the schedule's layout under the interleaved pipeline). A family
+        with a layer pattern calls it once a segment: `count` layers of the
+        modules `names`, keyed by the segment's name."""
+        layer_keys = jax.random.split(
+            fold(key, segment),
+            self.cfg.num_layers if count is None else count)
+        mods = self._segment_mods(names)
 
         def one_layer(k: jax.Array) -> Params:
             return {name: mod.init(fold(k, name))
-                    for name, mod in self._mods.items()}
+                    for name, mod in mods.items()}
 
         layers = jax.vmap(one_layer)(layer_keys)
         if self._interleaved:
             layers = self._layers_to_schedule(layers)
         return layers
+
+    def _segment_mods(self, names=None) -> Dict[str, Any]:
+        return (self._mods if names is None
+                else {n: self._mods[n] for n in names})
 
     @property
     def _interleaved(self) -> bool:
@@ -641,7 +681,7 @@ class DecoderStack:
             return self.specs()
         return dataclasses.replace(self, pp_schedule="gpipe").specs()
 
-    def _layer_specs(self) -> Params:
+    def _layer_specs(self, names=None) -> Params:
         """PartitionSpecs matching `_init_layers`."""
         lead = "pp" if self.pp_size > 1 else None
 
@@ -656,7 +696,8 @@ class DecoderStack:
                                     is_leaf=lambda x: isinstance(x, P))
             return jax.tree.map(lambda s: P(lead, *s), spec_dict,
                                 is_leaf=lambda x: isinstance(x, P))
-        return {name: stack(mod.specs()) for name, mod in self._mods.items()}
+        return {name: stack(mod.specs())
+                for name, mod in self._segment_mods(names).items()}
 
     def shardings(self, mesh: Mesh) -> Params:
         return jax.tree.map(lambda s: NamedSharding(mesh, s), self.specs(),
@@ -698,37 +739,20 @@ class DecoderStack:
             layer_params = exchange_grads(
                 jax.tree.map(lambda a: a.astype(dtype), layer_params), "dp")
         m, tp = self._mods, self._tp_sublayers
-        h = self.cfg.head_dim
         b = x.shape[0]
         t = pos.shape[1]  # full (cp-local) sequence length, not x.shape[1]
 
         def qkv(x):
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
-            q, k, v = tp.columns(layer_params, ("wq", "wk", "wv"), y, dtype)
-            # REMAT_LADDER's names, as the linears return them: (b, t,
-            # heads*h), the lane-dense shape; the positions and the head
-            # split are recomputed from them
-            q = checkpoint_name(q, "q_proj")
-            k = checkpoint_name(k, "k_proj")
-            v = checkpoint_name(v, "v_proj")
-            # (b, t, heads*h) -> (b, heads, t, h); under grouped-query
-            # attention wk/wv produce fewer heads and k/v STAY at the
-            # kv-head count — every attention impl handles the grouping
-            # itself (the flash kernel and ring path route query-head
-            # blocks onto kv rows with no HBM repeat; the XLA fallback
-            # expands at its own boundary, ops/attention.py).
-            split = lambda z, nh: z.reshape(b, t, nh, h).transpose(0, 2, 1, 3)
-            q = split(q, self.num_local_heads)
-            k = split(k, self.num_local_kv_heads)
-            v = split(v, self.num_local_kv_heads)
-            return self._position_qk(q, k, layer_pos) + (v,)
+            return self._qkv(layer_params, y, tp, layer_pos, dtype, b, t)
 
         def attn_out(args):
             x, o = args
-            o = o.transpose(0, 2, 1, 3).reshape(b, t,
-                                                self.num_local_heads * h)
-            a = tp.row(layer_params, "wo", o, dtype)
+            # (b, heads, t, v's width) -> (b, t, heads * width)
+            o = o.transpose(0, 2, 1, 3).reshape(
+                b, t, self.num_local_heads * o.shape[-1])
+            a = self._attn_project(layer_params, o, tp, dtype)
             if self.tp_size > 1:
                 # named PAST the row-linear's reduce, so keeping it drops
                 # the recomputed forward's collective with the matmul;
@@ -738,21 +762,8 @@ class DecoderStack:
 
             norm = self.ffn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
-            if self.is_moe:
-                ff, aux = m["moe"].apply(layer_params["moe"], y, dtype)
-                if tp.sp:
-                    # The router saw the tp-gathered full tokens (identical
-                    # on every tp rank, so routing agrees) and the expert
-                    # internals already all-reduced over tp — ff is the
-                    # full-value FFN output on every rank. Keep only this
-                    # rank's sequence slice so the residual stays
-                    # seq-sharded; the slice's transpose zero-pads,
-                    # composing with the gather's psum_scatter.
-                    tl = ff.shape[1] // self.tp_size
-                    ff = lax.dynamic_slice_in_dim(
-                        ff, lax.axis_index("tp") * tl, tl, axis=1)
-                return x + ff, aux
-            return x + self._mlp(layer_params, y, tp, dtype), None
+            ff, aux = self._ffn(layer_params, y, tp, dtype)
+            return x + ff, aux
 
         # Under ring overlap the dense segments run even on pipeline-bubble
         # steps (live is ignored except by ring attention): their tp
@@ -775,6 +786,63 @@ class DecoderStack:
                                      t_real=self._t_real(t))
             return attn_out((x, o))
         return self._live_gated_ring(x, qkv, attn_out, pos, live)
+
+    def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
+             dtype, b: int, t: int):
+        """The attention half's inputs from the normed activation `y`:
+        (q, k, v), each (b, heads, t, width), positions applied. This one
+        is multi-head / grouped-query attention over `wq`/`wk`/`wv`; a
+        family with another attention (latent) supplies its own."""
+        h = self.cfg.head_dim
+        q, k, v = tp.columns(lp, ("wq", "wk", "wv"), y, dtype)
+        # REMAT_LADDER's names, as the linears return them: (b, t,
+        # heads*h), the lane-dense shape; the positions and the head
+        # split are recomputed from them
+        q = checkpoint_name(q, "q_proj")
+        k = checkpoint_name(k, "k_proj")
+        v = checkpoint_name(v, "v_proj")
+        # (b, t, heads*h) -> (b, heads, t, h); under grouped-query
+        # attention wk/wv produce fewer heads and k/v STAY at the
+        # kv-head count — every attention impl handles the grouping
+        # itself (the flash kernel and ring path route query-head
+        # blocks onto kv rows with no HBM repeat; the XLA fallback
+        # expands at its own boundary, ops/attention.py).
+        split = lambda z, nh: z.reshape(b, t, nh, h).transpose(0, 2, 1, 3)
+        q = split(q, self.num_local_heads)
+        k = split(k, self.num_local_kv_heads)
+        v = split(v, self.num_local_kv_heads)
+        return self._position_qk(q, k, layer_pos) + (v,)
+
+    def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
+                      dtype) -> jax.Array:
+        """The heads' outputs (b, t, heads * width) through `wo`."""
+        return tp.row(lp, "wo", o, dtype)
+
+    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
+        """The FFN half of a layer on the normed activation `y`: (output,
+        aux or None). The routed experts of parallel/moe.MoEFFN where
+        cfg.num_experts > 0, else the family's dense `_mlp`."""
+        if self.is_moe:
+            ff, aux = self._mods["moe"].apply(lp["moe"], y, dtype)
+            if tp.sp:
+                # The router saw the tp-gathered full tokens (identical
+                # on every tp rank, so routing agrees) and the expert
+                # internals already all-reduced over tp — ff is the
+                # full-value FFN output on every rank. Keep only this
+                # rank's sequence slice so the residual stays
+                # seq-sharded; the slice's transpose zero-pads,
+                # composing with the gather's psum_scatter.
+                tl = ff.shape[1] // self.tp_size
+                ff = lax.dynamic_slice_in_dim(
+                    ff, lax.axis_index("tp") * tl, tl, axis=1)
+            return ff, aux
+        return self._mlp(lp, y, tp, dtype), None
+
+    def _fold_aux(self, auxs):
+        """What a segment's scan stacked per layer -> the segment's aux:
+        the router sums of parallel/moe.MoEFFN add up over layers."""
+        return (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
+                if self.is_moe else None)
 
     def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
         """(q, k) with the positions a layer takes at its attention: none
@@ -872,6 +940,17 @@ class DecoderStack:
         disjoint 1/pp batch chunk for norm/lm_head (see _pipeline_layers);
         the returned logits then have b/pp rows."""
         self = self._resolved(input_ids.shape[1])
+        x, aux, trunk = self._trunk(params, input_ids, position_ids,
+                                    head_layout)
+        return self._head(params, params["norm"], x, trunk.dtype), aux
+
+    def _trunk(self, params: Params, input_ids: jax.Array,
+               position_ids: jax.Array, head_layout: str = "replicated"):
+        """Embedding and every layer segment, on a `_resolved` model: (the
+        last layer's output, aux, what a further segment over the same
+        batch runs with: `SimpleNamespace(dtype, run)` where `run(z,
+        layers)` scans `layers` from `z` under this trace's remat rung and
+        positions). `_head` turns the output into logits."""
         dtype = resolve_dtype(self.cfg.compute_dtype)
         sp = self.sequence_parallel
         if sp and input_ids.shape[1] % self.tp_size != 0:
@@ -895,20 +974,29 @@ class DecoderStack:
                 return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live)
             z, auxs = lax.scan(body, z, layers)
             # auxs: None for dense; for MoE a dict of (L,...) stacked sums
-            aux = (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
-                   if self.is_moe else None)
-            return z, aux
+            return z, self._fold_aux(auxs)
 
+        run = lambda z, layers: stage_fn(z, layers, *layer_pos, position_ids)
         if self.pp_size > 1:
             x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
                                            (*layer_pos, position_ids),
                                            head_layout=head_layout)
         else:
-            x, aux = stage_fn(x, params["layers"], *layer_pos, position_ids)
-        # `head_loss`: the one boundary inside the loss that a device trace is
-        # split at (final norm, head, CE; benchmark/lib/program_trace.py)
-        with jax.named_scope("head_loss"):
-            x = self.final_norm.apply(params["norm"], x)
+            aux = None
+            for key in self._layer_keys:
+                x, seg_aux = run(x, params[key])
+                aux = aux if seg_aux is None else seg_aux
+        return x, aux, SimpleNamespace(dtype=dtype, run=run)
+
+    def _head(self, params: Params, norm_params: Params, x: jax.Array,
+              dtype, scope: "str | None" = "head_loss") -> jax.Array:
+        """Final norm (with `norm_params`) and the head: LOCAL logits.
+        `head_loss` is the one boundary inside the loss that a device trace
+        is split at (final norm, head, CE; benchmark/lib/program_trace.py);
+        a caller already inside a scope of its own passes None."""
+        with (jax.named_scope(scope) if scope
+              else contextlib.nullcontext()):
+            x = self.final_norm.apply(norm_params, x)
             logits = self._head_logits(params, x, dtype)
 
             # Mask padded vocab entries so they carry no probability mass.
@@ -918,7 +1006,7 @@ class DecoderStack:
                 col = start + jnp.arange(local_v)
                 logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
                                    logits, jnp.asarray(NEG_INF, logits.dtype))
-        return logits, aux
+        return logits
 
     def _pipeline_layers(self, stage_fn, x: jax.Array, layers: Params,
                          mb_arrays: Tuple[jax.Array, ...],
@@ -1202,12 +1290,18 @@ class DecoderStack:
     def loss_shard(self, params: Params, input_ids: jax.Array,
                    target_ids: jax.Array, position_ids: jax.Array,
                    mode: str = "vocab_parallel",
-                   batch_axes: Tuple[str, ...] = ("dp", "ep", "cp")) -> jax.Array:
+                   batch_axes: Tuple[str, ...] = ("dp", "ep", "cp"),
+                   with_counters: bool = False):
         """Mean cross-entropy over non-ignored tokens, global over the mesh.
 
         f32 loss with ignore-index masking, matching the reference's
         `F.cross_entropy(logits.float(), ..., ignore_index=-1, 'mean')`
         (`/root/reference/train.py:101-104`).
+
+        `with_counters` returns (loss, counters): a dict of what the loss
+        is made of and what the layers counted on the way (`loss_main`
+        always; a family adds its own: `_extra_loss`), summed over the
+        mesh. Nothing in it carries a gradient.
         """
         # Pipeline head layout: with a pp-divisible batch each stage computes
         # norm/lm_head/CE on a DISJOINT 1/pp chunk (no duplicated head FLOPs
@@ -1216,9 +1310,10 @@ class DecoderStack:
         self = self._resolved(input_ids.shape[1])
         pp_scatter = (self.pp_size > 1
                       and input_ids.shape[0] % self.pp_size == 0)
-        logits, aux = self._forward_with_aux(
+        x, aux, trunk = self._trunk(
             params, input_ids, position_ids,
             head_layout="pp_scatter" if pp_scatter else "replicated")
+        logits = self._head(params, params["norm"], x, trunk.dtype)
         if pp_scatter:
             chunk = input_ids.shape[0] // self.pp_size
             target_ids = lax.dynamic_slice_in_dim(
@@ -1247,7 +1342,7 @@ class DecoderStack:
         loss_sum = lax.psum(loss_sum, batch_axes)
         count = lax.psum(count, batch_axes)
         loss = loss_sum / jnp.maximum(count, 1.0)
-        if self.is_moe:
+        if self.is_moe and self._router_aux_losses:
             # Globally-summed router stats -> sharding-invariant aux losses
             # (load balance + z), added with their Switch/ST-MoE weights.
             if self.sequence_parallel:
@@ -1262,7 +1357,22 @@ class DecoderStack:
                                self.cfg.moe_top_k)
             loss = (loss + self.cfg.moe_aux_coef * lb
                     + self.cfg.moe_z_coef * z)
+        counters = {"loss_main": loss}
+        loss, more = self._extra_loss(
+            params, loss, x, aux, trunk, input_ids, target_ids,
+            position_ids, mode, batch_axes)
+        if with_counters:
+            return loss, jax.tree.map(lax.stop_gradient,
+                                      {**counters, **more})
         return loss
+
+    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
+                    aux, trunk, input_ids, target_ids, position_ids,
+                    mode: str, batch_axes):
+        """A family's further loss terms on top of the main CE (`x` is the
+        last layer's output, `trunk` what `_trunk` returned): (loss,
+        counters of its own). None here."""
+        return loss, {}
 
     # ---- global (jitted) entry points ----
 
@@ -1296,15 +1406,19 @@ class DecoderStack:
 
         return jax.jit(zz)
 
-    def make_loss(self, mesh: Mesh, mode: str = "vocab_parallel"):
+    def make_loss(self, mesh: Mesh, mode: str = "vocab_parallel",
+                  with_counters: bool = False):
+        """Jitted global loss; with `with_counters`, (loss, counters) as
+        `loss_shard` gives them (for `jax.value_and_grad(has_aux=True)`)."""
         from ..ops.ring_attention import zigzag_perm
 
-        loss = functools.partial(self.loss_shard, mode=mode)
+        loss = functools.partial(self.loss_shard, mode=mode,
+                                 with_counters=with_counters)
         fn = jax.shard_map(
             loss, mesh=mesh,
             in_specs=(self.specs(), P(("dp", "ep"), "cp"),
                       P(("dp", "ep"), "cp"), P(("dp", "ep"), "cp")),
-            out_specs=P(),
+            out_specs=(P(), P()) if with_counters else P(),
         )
         if not self._zigzag:
             return jax.jit(fn)

@@ -105,6 +105,10 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # ("this run's params came from THAT layout")
     "reshard_event": ("src_layout", "dst_layout", "bytes_moved",
                       "plan_ops", "wall_ms"),
+    # -- ISSUE 33: an expert model's step counters at the log interval
+    # (training/metrics.moe_counters_summary): the held experts' load as
+    # max over mean, and the rows computed here per token and expert layer
+    "moe_counters": ("load_max_over_mean", "rows_here_per_token"),
 }
 
 

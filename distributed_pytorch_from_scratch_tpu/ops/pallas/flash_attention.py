@@ -412,6 +412,7 @@ def _q_row(bkv, g, hq: int, hkv: int):
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
+    dv = v.shape[-1]            # v (and o) may be narrower than q/k
     num_qb = t_pad // block_q
     num_kb = t_pad // block_k
     scale = 1.0 / math.sqrt(d)
@@ -431,25 +432,26 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct((bh, t_pad, d), q.dtype, q),
+            _out_struct((bh, t_pad, dv), q.dtype, q),
             _out_struct((bh, t_pad, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ] if num_kb > 1 else [],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
-            flops=4 * d * entries, bytes_accessed=q.size * 3 * q.dtype.itemsize,
+            flops=2 * (d + dv) * entries,
+            bytes_accessed=(2 * q.size + bh * t_pad * dv) * q.dtype.itemsize,
             transcendentals=entries),
         interpret=interpret,
         name="flash_fwd",
@@ -587,12 +589,16 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_acc[:] = jnp.zeros_like(dv_acc)
 
     t_pad, d = dq_ref.shape
+    dv = dv_ref.shape[-1]
     dq_acc[:] = jnp.zeros_like(dq_acc)
     done = 0
     for c0, cols, rects in plan.columns:
         cs = slice(c0, c0 + cols)
         k, v = k_ref[cs, :], v_ref[cs, :]
-        dkt = dvt = jnp.zeros((d, cols), jnp.float32)
+        dkt = jnp.zeros((d, cols), jnp.float32)
+        # one zero where the widths agree: the kernel's text at equal widths
+        # is then the one it has always been
+        dvt = dkt if dv == d else jnp.zeros((dv, cols), jnp.float32)
         for r0, rows, masked in rects:
             rs = slice(r0, r0 + rows)
             q, do = q_ref[rs, :], do_ref[rs, :]
@@ -617,12 +623,13 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
     elif done < t_pad:                  # key sub-columns wholly past t_real
         dk_ref[done:, :] = jnp.zeros((t_pad - done, d), dk_ref.dtype)
-        dv_ref[done:, :] = jnp.zeros((t_pad - done, d), dv_ref.dtype)
+        dv_ref[done:, :] = jnp.zeros((t_pad - done, dv), dv_ref.dtype)
 
 
 def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
+    dv = v.shape[-1]
     bhkv = k.shape[0]
     group = hq // hkv
     num_qb = t_pad // block_q
@@ -647,18 +654,25 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
         kv_td = pl.BlockSpec((None, t_pad, d), lambda b, g: (b, 0, 0))
         acc = pltpu.VMEM((t_pad, d), jnp.float32)
+        if dv == d:
+            do_td, v_td, v_acc = q_td, kv_td, acc
+        else:
+            do_td = pl.BlockSpec((None, t_pad, dv),
+                                 lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
+            v_td = pl.BlockSpec((None, t_pad, dv), lambda b, g: (b, 0, 0))
+            v_acc = pltpu.VMEM((t_pad, dv), jnp.float32)
         return pl.pallas_call(
             functools.partial(
                 _bwd_fused_kernel, scale=scale,
                 plan=causal_subtile_plan(t_pad, t_pad, 0, 0, t_real, d,
                                          backward=True)),
             grid=(bhkv, group),
-            in_specs=[q_td, kv_td, kv_td, q_td, q_t1, q_t1],
-            out_specs=[q_td, kv_td, kv_td],
+            in_specs=[q_td, kv_td, v_td, do_td, q_t1, q_t1],
+            out_specs=[q_td, kv_td, v_td],
             out_shape=[_out_struct((bh, t_pad, d), q.dtype, q),
                        _out_struct((bhkv, t_pad, d), k.dtype, q),
-                       _out_struct((bhkv, t_pad, d), v.dtype, q)],
-            scratch_shapes=[acc] + ([acc, acc] if group > 1 else []),
+                       _out_struct((bhkv, t_pad, dv), v.dtype, q)],
+            scratch_shapes=[acc] + ([acc, v_acc] if group > 1 else []),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
@@ -675,8 +689,8 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
@@ -702,8 +716,8 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
             pl.BlockSpec((1, block_q, d),
                          lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, gq: (b, j, 0)),
+            pl.BlockSpec((1, block_q, dv),
                          lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
             pl.BlockSpec((1, block_q, 1),
                          lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
@@ -712,14 +726,14 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, j, gq: (b, j, 0)),
         ],
         out_shape=[
             _out_struct((bhkv, t_pad, d), k.dtype, q),
-            _out_struct((bhkv, t_pad, d), v.dtype, q),
+            _out_struct((bhkv, t_pad, dv), v.dtype, q),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
@@ -762,6 +776,12 @@ class BlockConfig:
 # DEFAULT_* constants (b*h=256, t→1024, hd=64, bf16).
 _BLOCK_TABLE: Dict[Tuple[int, int, str, str], BlockConfig] = {
     (1024, 64, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
+    # latent attention at its pre-training length, q/k 192 wide against v of
+    # 128 (the key's head_dim is q's): the first multi-block grid a cell
+    # runs, 4 x 4 tiles a head of which 10 are live. Set from the compile
+    # (Mosaic takes these shapes; PR 33) and NOT swept against other blocks
+    # on the chip yet: PERF.md section 7.
+    (4096, 192, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
 }
 # key -> {source: sweep|online, capture, ts} provenance (ISSUE 16): an
 # online retune must never silently shadow a swept entry
@@ -941,6 +961,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Causal flash attention. q: (b, heads, t, head_dim); k, v may carry
     FEWER heads (b, kv_heads, t, head_dim) with heads % kv_heads == 0 —
     grouped-query attention routed inside the kernels (no K/V repeat in HBM).
+    v may be of ANOTHER width than q and k (latent attention: q/k of 192
+    against v of 128); the output has v's width, the scores are scaled by
+    q's, and the block table is asked by q's.
 
     Drop-in replacement for `causal_attention_xla`
     (`/root/reference/models/model.py:73-77` semantics). Sequence length is
@@ -962,6 +985,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     _require_tpu("flash_attention", interpret)
     b, h, t, d = q.shape
     hkv = k.shape[1]
+    if k.shape[-1] != d:
+        raise ValueError(f"q and k widths differ: {d} vs {k.shape[-1]}")
     if h % hkv or v.shape[1] != hkv:
         raise ValueError(f"q heads {h} must be a multiple of kv heads "
                          f"{k.shape[1]}/{v.shape[1]}")
@@ -995,14 +1020,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     t_pad = _round_up(t, max(bq, bk, bbq, bbk))
 
     def prep(x, nh):
-        x = x.reshape(b * nh, t, d)
+        x = x.reshape(b * nh, t, x.shape[-1])
         if t_pad != t:
             x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
         return x
 
     o = _flash_with_t(prep(q, h), prep(k, hkv), prep(v, hkv), t_real,
                       bq, bk, bbq, bbk, h, hkv, interpret)
-    return o[:, :t, :].reshape(b, h, t, d)
+    return o[:, :t, :].reshape(b, h, t, v.shape[-1])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))

@@ -48,3 +48,29 @@ def apply_rotary(q: jax.Array, k: jax.Array, cos: jax.Array, sin: jax.Array) -> 
     q_rot = q * cos + rotate_half(q) * sin
     k_rot = k * cos + rotate_half(k) * sin
     return q_rot, k_rot
+
+
+def rope_angles(position_ids: jax.Array, dim: int,
+                base: float = 10000.0) -> Tuple[jax.Array, jax.Array]:
+    """(cos, sin), each (b, t, dim/2) float32, of pair i's angle
+    `pos * base^(-2i/dim)` at `position_ids` (b, t): what
+    `apply_rotary_interleaved` takes. Computed from the positions, so no
+    table caps the length."""
+    assert dim % 2 == 0
+    theta = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ang = position_ids.astype(jnp.float32)[..., None] * theta
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def apply_rotary_interleaved(x: jax.Array, cos: jax.Array,
+                             sin: jax.Array) -> jax.Array:
+    """RoPE over INTERLEAVED pairs (x_2i, x_2i+1) of x (b, heads, t, dim),
+    DeepSeek's `rope_interleave`: pair i turns by its angle and stays where
+    it was. cos/sin: (b, t, dim/2) (`rope_angles`)."""
+    *lead, dim = x.shape
+    pairs = x.reshape(*lead, dim // 2, 2)
+    a, b = pairs[..., 0], pairs[..., 1]
+    cos = cos[:, None].astype(x.dtype)
+    sin = sin[:, None].astype(x.dtype)
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                     axis=-1).reshape(x.shape)

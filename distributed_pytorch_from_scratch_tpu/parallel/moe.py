@@ -227,6 +227,251 @@ class MoEFFN:
         return y.reshape(b, t, d), aux
 
 
+# A chunk of `SharedRoutedFFN`'s sorted pairs holds this many times the
+# job's mean share of them. One reading set it, not a law: Zipf ids through
+# a freshly initialised router on a v5e, where no step's held rows passed
+# 5.4 times the mean share and a first chunk of 4 shares was crossed in a
+# tenth of one run's steps in twelve (PERF.md section 6, PR 33). It is
+# memory (the chunk's rows in and out) and, while a live chunk is computed
+# whole, time: see the class docstring.
+CHUNK_SHARES = 6
+
+
+@dataclass(frozen=True)
+class SharedRoutedFFN:
+    """A sigmoid router over `num_experts` routed SwiGLU experts, of which
+    this job HOLDS `held` (experts [offset, offset + held)), plus shared
+    experts every token takes: the DeepSeek-V3 FFN, as one chip of an
+    expert-parallel deployment computes it between two all-to-alls.
+
+    Routing (float32): `s = sigmoid(x W_r)` over all routed experts; the
+    `top_k` largest of `s + bias` are chosen (`bias` is the selection bias
+    of auxiliary-loss-free balancing: a leaf no gradient reaches, updated
+    by a rule outside the step; ROADMAP queues the rule); the weights are
+    `s[chosen]`, normalised over ALL chosen experts, held or not, times
+    `scaling`. The layer adds `w_e E_e(x)` for the chosen experts it holds
+    and the shared expert; what an absent expert would have added is left
+    out (with `held == num_experts` nothing is).
+
+    Dispatch is sorted and grouped, with no capacity and NO DROP: the
+    (token, choice) pairs are sorted by held expert (absent ones last) and
+    the held experts' rows go through grouped matrix products
+    (`lax.ragged_dot`: XLA:TPU makes it a grouped-matmul kernel whose grid
+    follows the group sizes). The sorted pairs are walked in chunks
+    (`chunk_rows`) under one `lax.scan`; a chunk past the last held row is
+    skipped by a `lax.cond`, so memory follows the chunk, while every pair
+    that exists is computed whatever the routing (tests force all tokens
+    onto a few experts). **A live chunk is computed WHOLE**: the rows past
+    its last held pair are zeros handed to the last held expert, so the
+    products' time follows the chunks that are live, not the rows: a step
+    whose routing stays inside the first chunk (`CHUNK_SHARES` times the
+    mean share) costs the same whatever it routes, one that crosses it
+    pays for another chunk. That is a price, paid for a steady step: on a
+    v5e the grouped products and the dispatch of a chunk cost 0.7 ms a
+    thousand rows, a held share's load differed ninefold between seeds and
+    drifted as the router trained, and a step that followed the rows was
+    7% shorter on average and spread by 1.2 - 2.3% (PERF.md section 6, PR
+    33). A grouped kernel that gathers its own rows would make the padding
+    nearly free and this policy moot (ROADMAP M1(b), queued first).
+
+    Tensor parallelism: every expert's gate/up are column-sharded and its
+    down row-sharded over `tp_axis`, like the dense FFN; the router and the
+    bias are replicated. There is no expert axis here: a job that holds
+    several shares runs several of these (ROADMAP, expert parallelism).
+    """
+
+    d: int
+    f: int                       # per-expert hidden width
+    num_experts: int             # routed experts the router scores
+    top_k: int
+    held: "int | None" = None    # None: all
+    offset: int = 0
+    n_shared: int = 1
+    scaling: float = 1.0
+    tp_size: int = 1
+    tp_axis: str = "tp"
+
+    def __post_init__(self):
+        held = self.num_held
+        if not (0 <= self.offset and self.offset + held <= self.num_experts):
+            raise ValueError(
+                f"held experts [{self.offset}, {self.offset + held}) are not "
+                f"among the {self.num_experts} routed experts")
+        if not (1 <= self.top_k <= self.num_experts):
+            raise ValueError(f"top_k {self.top_k} out of range for "
+                             f"{self.num_experts} experts")
+        if self.f % self.tp_size:
+            raise ValueError(f"expert width {self.f} not divisible by "
+                             f"tp_size {self.tp_size}")
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else self.held
+
+    # ---- init / specs ----
+
+    def init(self, key: jax.Array) -> Params:
+        d, f, H = self.d, self.f, self.num_held
+
+        def w(k, shape, idim):
+            bound = 1.0 / math.sqrt(idim)
+            return jax.random.uniform(k, shape, jnp.float32, -bound, bound)
+
+        p = {
+            # a RANDOM router (the zero one of MoEFFN would send every
+            # token to the first top_k experts: sigmoid ties at 0.5)
+            "router": w(fold(key, "router"), (d, self.num_experts), d),
+            "bias": jnp.zeros((self.num_experts,), jnp.float32),
+            "gate": w(fold(key, "gate"), (H, d, f), d),
+            "up": w(fold(key, "up"), (H, d, f), d),
+            "down": w(fold(key, "down"), (H, f, d), f),
+        }
+        if self.n_shared:
+            fs = self.n_shared * f
+            p["shared"] = {"gate": w(fold(key, "shared_gate"), (d, fs), d),
+                           "up": w(fold(key, "shared_up"), (d, fs), d),
+                           "down": w(fold(key, "shared_down"), (fs, d), fs)}
+        return p
+
+    def specs(self) -> Params:
+        tp = self.tp_axis
+        s = {"router": P(None, None), "bias": P(None),
+             "gate": P(None, None, tp), "up": P(None, None, tp),
+             "down": P(None, tp, None)}
+        if self.n_shared:
+            s["shared"] = {"gate": P(None, tp), "up": P(None, tp),
+                           "down": P(tp, None)}
+        return s
+
+    # ---- routing ----
+
+    def route(self, params: Params, xf: jax.Array
+              ) -> Tuple[jax.Array, jax.Array]:
+        """(S, d) tokens -> chosen experts (S, k) int32 and their combine
+        weights (S, k) float32. The router's product runs in float32 at
+        precision "highest": a bf16 pass moves scores by 2^-9, which flips
+        a top-k choice wherever two experts sit that close."""
+        s = jax.nn.sigmoid(jnp.dot(
+            xf.astype(jnp.float32), params["router"],
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(s + lax.stop_gradient(params["bias"]),
+                              self.top_k)
+        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * self.scaling
+        return chosen, w
+
+    @property
+    def chunk_share(self) -> float:
+        """The part of the (token, choice) pairs one chunk holds:
+        `CHUNK_SHARES` times this job's mean share of them, at most all
+        (the family sizes the dispatch's buffers from it for
+        `training/memory.py`)."""
+        return min(1.0, CHUNK_SHARES * self.num_held / self.num_experts)
+
+    def chunk_rows(self, pairs: int) -> int:
+        """Rows a chunk of `pairs` sorted pairs holds: `chunk_share` of
+        them, up to a multiple of 512 (the grouped kernel's row tile); all
+        of them where that is more than there are. The last chunk may run
+        past the pairs (`apply` pads)."""
+        want = max(512, -(-int(self.chunk_share * pairs) // 512) * 512)
+        return pairs if want >= pairs else want
+
+    # ---- forward (per-shard, inside shard_map) ----
+
+    def apply(self, params: Params, x: jax.Array,
+              compute_dtype: jnp.dtype = jnp.float32
+              ) -> Tuple[jax.Array, Params]:
+        """x (b, t, d), replicated over tp -> (y (b, t, d), counters):
+        `routed` (num_experts,) the pairs each routed expert was chosen
+        for, `rows_here` the pairs whose expert is held (the rows the
+        grouped products compute), both float32 and local to this shard."""
+        b, t, d = x.shape
+        S, k, H = b * t, self.top_k, self.num_held
+        xf = x.reshape(S, d)
+        xd = copy_to(xf.astype(compute_dtype), self.tp_axis)
+
+        with jax.named_scope("moe_route"):
+            chosen, w = self.route(params, xf)
+            local = chosen - self.offset
+            here = (local >= 0) & (local < H)
+            # sort the pairs by held expert; absent experts sort last
+            key = jnp.where(here, local, H).reshape(-1)        # (S*k,)
+            order = jnp.argsort(key, stable=True)
+            ends = jnp.cumsum(jnp.bincount(key, length=H + 1)[:H])
+            rows_here = ends[-1]
+            token = order // k
+            w_sorted = w.reshape(-1)[order]
+            counters = {
+                "routed": jnp.bincount(chosen.reshape(-1),
+                                       length=self.num_experts
+                                       ).astype(jnp.float32),
+                "rows_here": rows_here.astype(jnp.float32)}
+
+        M = self.chunk_rows(S * k)
+        chunks = -(-S * k // M)
+        if chunks * M > S * k:        # the last chunk runs past the pairs
+            token = jnp.pad(token, (0, chunks * M - S * k))
+            w_sorted = jnp.pad(w_sorted, (0, chunks * M - S * k))
+        f = params["gate"].shape[-1]                  # local expert width
+        # gate and up as one grouped product: one pass over the rows
+        gate_up = jnp.concatenate([params["gate"], params["up"]],
+                                  axis=-1).astype(compute_dtype)
+        down = params["down"].astype(compute_dtype)
+
+        def chunk(y, c):
+            lo = c * M
+
+            def live(y):
+                with jax.named_scope("moe_route"):
+                    tok = lax.dynamic_slice_in_dim(token, lo, M)
+                    wc = lax.dynamic_slice_in_dim(w_sorted, lo, M)
+                    # rows of each held expert inside [lo, lo + M); the
+                    # rows past the last held pair go to the last expert,
+                    # as zeros (see the class docstring)
+                    hi_e = jnp.clip(ends - lo, 0, M).at[-1].set(M)
+                    sizes = jnp.diff(hi_e, prepend=0).astype(jnp.int32)
+                    valid = ((lo + jnp.arange(M)) < rows_here)[:, None]
+                    # The grouped kernels write the rows of their groups
+                    # and NOTHING ELSE: a row no group holds comes back as
+                    # whatever the buffer held, from the forward products
+                    # and from their transposes alike (a 5,000-fold
+                    # gradient norm on the chip, PR 33; the CPU lowering
+                    # zero-fills). Every row has a group here; both sides
+                    # of the products are still SELECTED (never
+                    # multiplied) by `valid`, whose transpose drops the
+                    # padding's cotangent rows before the gather's
+                    # scatter-add.
+                    rows = jnp.where(valid, jnp.take(xd, tok, axis=0), 0)
+                with jax.named_scope("moe_experts"):
+                    gu = lax.ragged_dot(rows, gate_up, sizes)
+                    out = lax.ragged_dot(
+                        jax.nn.silu(gu[:, :f]) * gu[:, f:], down, sizes)
+                with jax.named_scope("moe_route"):
+                    # select BEFORE the weights multiply: a weight's
+                    # cotangent is the row itself
+                    out = (jnp.where(valid, out, 0)
+                           * wc[:, None].astype(out.dtype))
+                    return y.at[tok].add(out.astype(y.dtype))
+
+            return lax.cond(lo < rows_here, live, lambda y: y, y), None
+
+        # the carry varies over what the rows vary over (batch axes and tp)
+        vma = tuple(jax.typeof(xd).vma)
+        y = jnp.zeros((S, d), compute_dtype)
+        y = copy_to(y, vma) if vma else y
+        y, _ = lax.scan(jax.checkpoint(chunk), y,
+                        jnp.arange(chunks, dtype=jnp.int32))
+
+        if self.n_shared:
+            with jax.named_scope("moe_shared"):
+                sp = params["shared"]
+                g = xd @ sp["gate"].astype(compute_dtype)
+                u = xd @ sp["up"].astype(compute_dtype)
+                y = y + (jax.nn.silu(g) * u) @ sp["down"].astype(compute_dtype)
+        y = reduce_from(y, self.tp_axis)
+        return y.reshape(b, t, d), counters
+
+
 def aux_zeros(num_experts: int) -> Params:
     """Zero aux sums with the same structure `MoEFFN.apply` returns — used
     as the scan unit for dense layers so MoE and dense bodies scan alike."""

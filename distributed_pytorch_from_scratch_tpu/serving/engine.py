@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..models.decode import (_decode_one, _paged_decode_one,
+from ..models.decode import (require_decodable, _decode_one, _paged_decode_one,
                              _paged_prefill_chunk, _prefill,
                              host_sample_tokens, make_token_sampler,
                              rope_tables)
@@ -93,6 +93,7 @@ def _setup_decode_weights(engine, model, mesh, params, decode_weight_dtype):
     is int8). Sampling, caches, and every token produced stay governed by
     the engines' usual contracts; weight rounding shifts logits by a
     bounded amount (pinned in tests/test_quant.py)."""
+    require_decodable(model)
     if decode_weight_dtype in (None, "native"):
         engine._params_in = params
         engine._pspec = model.specs()

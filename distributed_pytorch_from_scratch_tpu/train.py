@@ -19,6 +19,7 @@ loop `train.py:55-146`), TPU-native:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import math
 import os
@@ -35,7 +36,7 @@ from .config import (IGNORE_INDEX, MODEL_PRESETS, REMAT_CHOICES, MeshConfig,
                      ModelConfig, OptimizerConfig, model_preset)
 from .data.dataset import get_dataloader
 from .data.prefetch import Prefetcher, stack_window, window_stream
-from .models import FAMILIES, build_model
+from .models import FAMILIES, build_model, family_class
 from .obs import TrainObserver, analyze_compiled, format_analysis
 from .obs.runindex import run_stamp
 from .ops.attention import resolve_attention_impl
@@ -45,6 +46,7 @@ from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
 from .training.checkpoint import (AsyncCheckpointer, latest_step,
                                   load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
+                               moe_counters_summary,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
                                param_bytes_by_device, publish_hbm)
@@ -509,18 +511,30 @@ def train(args: argparse.Namespace) -> dict:
             from .data.native import native_status
             print(f"data: {len(dataloader.dataset)} documents, collate = "
                   f"{native_status()}")
-        cfg = ModelConfig(attn_dim=pick(args.attn_dim, preset.attn_dim),
-                          ffn_dim=pick(args.ffn_dim, preset.ffn_dim),
-                          num_heads=pick(args.num_heads, preset.num_heads),
-                          num_kv_heads=pick(args.num_kv_heads,
-                                            preset.num_kv_heads),
-                          num_layers=pick(args.num_layers, preset.num_layers),
-                          num_experts=pick(args.num_experts, preset.num_experts),
-                          moe_top_k=pick(args.moe_top_k, preset.moe_top_k),
-                          moe_capacity_factor=pick(args.moe_capacity_factor,
-                                                   preset.moe_capacity_factor),
-                          vocab_size=vocab_size, maxlen=maxlen,
-                          compute_dtype="bfloat16" if args.bf16 else "float32")
+        # the preset with the flags laid over it: what no flag names (the
+        # mla_moe family's `latent_moe`, rope_theta) stays the preset's
+        cfg = dataclasses.replace(
+            preset,
+            attn_dim=pick(args.attn_dim, preset.attn_dim),
+            ffn_dim=pick(args.ffn_dim, preset.ffn_dim),
+            num_heads=pick(args.num_heads, preset.num_heads),
+            num_kv_heads=pick(args.num_kv_heads, preset.num_kv_heads),
+            num_layers=pick(args.num_layers, preset.num_layers),
+            num_experts=pick(args.num_experts, preset.num_experts),
+            moe_top_k=pick(args.moe_top_k, preset.moe_top_k),
+            moe_capacity_factor=pick(args.moe_capacity_factor,
+                                     preset.moe_capacity_factor),
+            vocab_size=vocab_size, maxlen=maxlen,
+            compute_dtype="bfloat16" if args.bf16 else "float32")
+        needs = family_class(args.family).config_extra
+        carries = "latent_moe" if cfg.latent_moe is not None else None
+        if needs != carries:
+            raise SystemExit(
+                f"--family {args.family} reads the config field {needs!r} "
+                f"and --model {args.model} carries {carries!r}: a family "
+                f"with facts of its own goes with a preset that has them "
+                f"(--family mla_moe --model tiny-mla-moe), and such a "
+                f"preset with no other family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
         zero_stage = resolve_zero_stage(args.zero, args.zero1)
@@ -856,7 +870,13 @@ def train(args: argparse.Namespace) -> dict:
             step_fn = build_train_step_multi(model, mesh, ocfg, args.loss_mode,
                                              **builder_kwargs)
         else:
+            # a family whose loss counts things (the mla_moe family's
+            # router) returns them with the plain step; logged below at the
+            # log interval, fetched with the loss
+            with_counters = (cfg.latent_moe is not None and zero_stage < 2
+                             and not args.dp_reduce_bucket_mb)
             step_fn = build_train_step(model, mesh, ocfg, args.loss_mode,
+                                       with_counters=with_counters,
                                        **builder_kwargs)
 
         # single-process: jnp.asarray; multi-host: global-array assembly from
@@ -988,6 +1008,7 @@ def train(args: argparse.Namespace) -> dict:
         # the sentinel piggybacks on the logging-interval sync: last dispatch's
         # on-device grad norm + the per-interval mean loss, no extra D2H
         last_gnorm = None
+        last_counters = None
         last_cum, last_log_n = 0.0, start_step
         t_start, tokens_since, steps_since = time.time(), 0, 0
         useful_since = 0  # non-IGNORE_INDEX targets: real tokens vs padding
@@ -1145,7 +1166,8 @@ def train(args: argparse.Namespace) -> dict:
                         loss = losses if accum > 1 else jnp.sum(losses)
                         last_gnorm = gnorms if accum > 1 else gnorms[-1]
                     else:
-                        loss, last_gnorm = out
+                        loss, last_gnorm, *rest = out
+                        last_counters = rest[0] if rest else None
                     n += 1 if accum > 1 else steps_in
                     tokens_since += window["input_ids"].size
                     useful_since += int((window["target_ids"]
@@ -1218,6 +1240,13 @@ def train(args: argparse.Namespace) -> dict:
                                  "available": marks is not None})
                         if gnorm is not None:
                             writer.scalar("train/grad_norm", gnorm, n)
+                        if last_counters is not None:
+                            moe = moe_counters_summary(
+                                jax.device_get(last_counters), cfg,
+                                window["input_ids"].size)
+                            print("  " + ", ".join(
+                                f"{k} {v:.4g}" for k, v in moe.items()))
+                            writer.event("moe_counters", step=n, **moe)
                         if telemetry is not None:
                             # same numbers the log line prints — the live
                             # endpoint view; the goodput buckets ride too

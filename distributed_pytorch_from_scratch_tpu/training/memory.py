@@ -80,7 +80,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                dtype_bytes: int = 2, ffn_inputs: int = 2,
                sequence_parallel: bool = False,
                state_bytes_per_param: float = 16.0,
-               grad_bytes_per_param: float = 4.0) -> Dict[str, float]:
+               grad_bytes_per_param: float = 4.0,
+               layer_extra_elems_per_token: float = 0.0) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -98,7 +99,10 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               stack of `flash_out` is not kept in the kernel's layout, which
               pads a head of 64 to the 128 lanes)
     head      logits in f32 and once more in the compute dtype
-    layer     one layer's recompute + backward working set
+    layer     one layer's recompute + backward working set; a family whose
+              layer holds more than the dense skeleton's six d-wide and
+              3.4 f-wide tensors says how many elements a token more
+              (`DecoderStack.layer_extra_elems_per_token`)
     The peak is resident + cast + stacks + max(head, grads + layer): the
     head's backward is over before the layers' gradients exist.
     """
@@ -138,7 +142,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                  else 0.0),
         "stacks": layers * per_layer,
         "head": tok * (vocab / tp) * (4 + dtype_bytes),
-        "layer": tok * dtype_bytes * (6 * d * act + 3.4 * f / tp),
+        "layer": tok * dtype_bytes * (6 * d * act + 3.4 * f / tp
+                                      + layer_extra_elems_per_token),
     }
     out["total"] = (out["resident"] + out["cast"] + out["stacks"]
                     + max(out["head"], out["grads"] + out["layer"]))
@@ -290,11 +295,12 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
         layer_param_count=layer_param_count, b=b,
         t=t, d=cfg.attn_dim, kd=cfg.kv_dim,
         f=cfg.ffn_dim, heads=cfg.num_heads, head_dim=cfg.head_dim,
-        layers=cfg.num_layers // model.pp_size,
+        layers=model.stacked_layers // model.pp_size,
         vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
         dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
         ffn_inputs=model.ffn_inputs,
-        sequence_parallel=model.tp_layout(t)[0])
+        sequence_parallel=model.tp_layout(t)[0],
+        layer_extra_elems_per_token=model.layer_extra_elems_per_token)
     return _pick(parts, model.remat_budget_gib, None, allow_false=False,
                  verbose=True,
                  note=f"; traced b{b} x t{t}, tp{model.tp_size}")

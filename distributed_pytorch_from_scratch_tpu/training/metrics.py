@@ -147,6 +147,25 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
     (the gpt2 family's 2-matmul MLP + tied head are a third of the FFN and
     a vocabulary short of the llama formula in `cfg.num_params()`)."""
     n = num_params
+    lm = getattr(cfg, "latent_moe", None)
+    if lm is not None:
+        # the mla_moe family: of the routed experts HELD a token takes
+        # top_k x held / routed on average (the rest of its top_k live on
+        # other chips of the deployment); the shared expert, the latent
+        # projections and the module are in `num_params` whole; q/k and v
+        # have their own widths; the embedding's lookup is no matmul but
+        # the head runs once more for the module
+        held = cfg.experts_held
+        expert = 3 * cfg.attn_dim * lm.moe_intermediate_size
+        expert_layers = (cfg.num_layers - lm.first_k_dense_replace
+                         + lm.num_nextn_predict_layers)
+        n -= expert_layers * (held - cfg.moe_top_k * held
+                              / cfg.num_experts) * expert
+        n += (lm.num_nextn_predict_layers - 1) * cfg.vocab_size * cfg.attn_dim
+        attn_layers = cfg.num_layers + lm.num_nextn_predict_layers
+        return (6 * n * batch * seqlen
+                + 6 * attn_layers * batch * cfg.num_heads * seqlen * seqlen
+                * (lm.qk_head_dim + lm.v_head_dim))
     if getattr(cfg, "num_experts", 0):
         inactive = ((cfg.num_experts - cfg.moe_top_k)
                     * 3 * cfg.attn_dim * cfg.ffn_dim)
@@ -154,6 +173,27 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
     return (6 * n * batch * seqlen
             + 12 * cfg.num_layers * batch * cfg.num_heads
             * seqlen * seqlen * cfg.head_dim)
+
+
+def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
+    """The step's counters of an expert model (`DecoderStack.loss_shard`
+    with `with_counters`, fetched to the host) as the few numbers a log
+    line carries: the main and multi-token-prediction losses apart, the
+    rows the held experts computed per token and expert layer (`top_k x
+    held / routed` under uniform routing), and the held experts' load as
+    max over mean, averaged over the expert layers (1.0 is balance)."""
+    import numpy as np
+
+    lo = cfg.latent_moe.expert_offset
+    routed = np.asarray(counters["routed"])[:, lo:lo + cfg.experts_held]
+    out = {"loss_main": float(counters["loss_main"])}
+    if "loss_mtp" in counters:
+        out["loss_mtp"] = float(counters["loss_mtp"])
+    out["rows_here_per_token"] = float(
+        np.mean(counters["rows_here"])) / max(tokens, 1)
+    out["load_max_over_mean"] = float(np.mean(
+        routed.max(-1) / np.maximum(routed.mean(-1), 1e-9)))
+    return out
 
 
 class ProfilerTrace:

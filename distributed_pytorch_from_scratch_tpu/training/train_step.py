@@ -40,8 +40,9 @@ _resolve_stage = resolve_zero_stage  # internal alias used by the builders
 
 def _make_grad_fn(model: Transformer, mesh, loss_mode: str,
                   dp_reduce_bucket_mb: float = 0.0, dp_reduce_dtype=None,
-                  zero_stage: int = 0):
-    """(params, ids, tgt, pos) -> (loss, grads): the transpose-derived
+                  zero_stage: int = 0, with_counters: bool = False):
+    """(params, ids, tgt, pos) -> (loss, grads), or with `with_counters`
+    ((loss, counters), grads): the transpose-derived
     whole-tree reducer by default; with dp_reduce_bucket_mb > 0 the
     bucketed-overlap reducer (training/zero.build_bucketed_grad_fn — DP
     psums issued per size-bounded bucket, optionally bf16/int8 on the
@@ -50,6 +51,19 @@ def _make_grad_fn(model: Transformer, mesh, loss_mode: str,
     zero_stage=3 is the gather-on-demand path (params AND grads dp-sharded,
     training/zero.build_zero3_grad_fn) — both default bucket_mb to 25 when
     the caller left it 0, since their wire IS the bucketed one."""
+    if zero_stage >= 2 or dp_reduce_bucket_mb:
+        what = (f"ZeRO stage {zero_stage}" if zero_stage >= 2
+                else "the bucketed gradient reducer")
+        if not model.hand_reduced_grads:
+            raise ValueError(
+                f"{what} is not made to work with the "
+                f"{type(model).__name__} family: training/zero.py's "
+                f"builders reduce per-shard gradients by hand over the "
+                f"parameter tree of a dense stack (use ZeRO stage 0 or 1)")
+        if with_counters:
+            raise ValueError(
+                f"with_counters needs the default gradient path; {what} "
+                f"builds its own loss call")
     if zero_stage >= 3:
         if dp_reduce_dtype is not None:
             # the CLIs refuse this with their own message; the builder is
@@ -72,13 +86,17 @@ def _make_grad_fn(model: Transformer, mesh, loss_mode: str,
         return build_bucketed_grad_fn(model, mesh, loss_mode,
                                       bucket_mb=dp_reduce_bucket_mb,
                                       reduce_dtype=dp_reduce_dtype)
+    if with_counters:
+        return jax.value_and_grad(
+            model.make_loss(mesh, mode=loss_mode, with_counters=True),
+            has_aux=True)
     return jax.value_and_grad(model.make_loss(mesh, mode=loss_mode))
 
 
 def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
                loss_mode: str, with_grad_norm: bool = False,
                dp_reduce_bucket_mb: float = 0.0, dp_reduce_dtype=None,
-               zero_stage: int = 0):
+               zero_stage: int = 0, with_counters: bool = False):
     """The one train-step body shared by both builders: grad + Adam/OneCycle.
     Keeping it single-sourced means the scanned (multi-step) program can
     never silently diverge from the per-step one.
@@ -86,10 +104,13 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
     `with_grad_norm=True` (the train CLI's mode) makes the third output
     `(loss, grad_norm)` instead of `loss` — computed on-device inside the
     same program, fetched only at the loop's logging-interval D2H, so the
-    sentinel costs no extra syncs."""
+    sentinel costs no extra syncs. `with_counters=True` appends the loss's
+    counters (`DecoderStack.loss_shard`) to that output: `(loss, grad_norm,
+    counters)`, or `(loss, counters)` without the norm."""
     grad_fn = _make_grad_fn(model, mesh, loss_mode,
                             dp_reduce_bucket_mb, dp_reduce_dtype,
-                            zero_stage=zero_stage)
+                            zero_stage=zero_stage,
+                            with_counters=with_counters)
 
     def step(params, opt_state: AdamState, input_ids, target_ids,
              position_ids):
@@ -99,14 +120,18 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
         with jax.named_scope("loss_and_grad"):
             loss, grads = grad_fn(params, input_ids, target_ids,
                                   position_ids)
+        extra = ()
+        if with_counters:
+            loss, counters = loss
+            extra = (counters,)
         # grad norm: optim.global_norm — the SAME reduction the clipper
         # uses, so the logged/sentinel-watched norm equals the one
         # acted on (and XLA can CSE the two when both are present)
         if with_grad_norm:
             with jax.named_scope("grad_norm"):
-                out = (loss, global_norm(grads))
+                out = (loss, global_norm(grads)) + extra
         else:
-            out = loss
+            out = (loss,) + extra if extra else loss
         with jax.named_scope("optimizer"):
             params, opt_state = adam_update(ocfg, params, grads, opt_state)
         return params, opt_state, out
@@ -183,11 +208,15 @@ def build_train_step(model: Transformer, mesh, ocfg: OptimizerConfig,
                      zero1: bool = False, moment_shardings=None,
                      with_grad_norm: bool = False,
                      dp_reduce_bucket_mb: float = 0.0, dp_reduce_dtype=None,
-                     zero: "int | None" = None):
+                     zero: "int | None" = None,
+                     with_counters: bool = False):
     """Returns jitted
     (params, opt_state, input_ids, target_ids, position_ids)
       -> (params, opt_state, loss)            [default]
       -> (params, opt_state, (loss, gnorm))   [with_grad_norm=True]
+      -> (params, opt_state, (loss, gnorm, counters))  [+ with_counters:
+         what the loss is made of and what the layers counted, a dict of
+         replicated arrays; off by default]
 
     `dp_reduce_bucket_mb > 0` swaps the whole-tree DP grad reduction for
     the bucketed-overlap reducer (with `dp_reduce_dtype=jnp.bfloat16` for
@@ -203,8 +232,12 @@ def build_train_step(model: Transformer, mesh, ocfg: OptimizerConfig,
     step = _step_body(model, mesh, ocfg, loss_mode,
                       with_grad_norm=with_grad_norm,
                       dp_reduce_bucket_mb=dp_reduce_bucket_mb,
-                      dp_reduce_dtype=dp_reduce_dtype, zero_stage=stage)
-    out_spec = (P(), P()) if with_grad_norm else P()
+                      dp_reduce_dtype=dp_reduce_dtype, zero_stage=stage,
+                      with_counters=with_counters)
+    # a P() stands for a whole subtree (the counters' dict)
+    out_spec = (P(),) * (1 + with_grad_norm + with_counters)
+    if len(out_spec) == 1:
+        out_spec = P()
     return _jit_with_zero(step, model, mesh, stage, moment_shardings,
                           out_spec)
 

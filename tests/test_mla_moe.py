@@ -1,0 +1,350 @@
+"""The `mla_moe` family (models/mla_moe.py): latent attention, the sigmoid
+router over held experts with a shared expert, the layer pattern, the
+multi-token-prediction module. CPU, tiny sizes, float32.
+
+* the program against the plain reference (models/vanilla_mla_moe.py): loss
+  and EVERY gradient leaf, at tp 1 and tp 2, on a job that holds a slice of
+  the experts; no top-k choice sits on a tie (the margin is asserted);
+* the flash kernel at unequal q/k and v widths against the XLA path,
+  forward and backward, one tile and a multi-block grid;
+* the share test: the routed parts of all the shares of a layer plus the
+  shared expert once add up to the uncut layer;
+* a router forced onto the same experts drops nothing;
+* what the family does not run is refused with a message;
+* the counts: parameters at the published widths (680.4M in all).
+"""
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_from_scratch_tpu.config import (
+    IGNORE_INDEX, LatentMoEConfig, MeshConfig, ModelConfig, OptimizerConfig,
+    model_preset)
+from distributed_pytorch_from_scratch_tpu.models import build_model
+from distributed_pytorch_from_scratch_tpu.models.mla_moe import param_counts
+from distributed_pytorch_from_scratch_tpu.models.vanilla_mla_moe import (
+    vanilla_loss)
+from distributed_pytorch_from_scratch_tpu.ops.attention import (
+    causal_attention_xla)
+from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
+    flash_attention)
+from distributed_pytorch_from_scratch_tpu.ops.rope import (
+    apply_rotary_interleaved, rope_angles)
+from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
+from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+from distributed_pytorch_from_scratch_tpu.training.metrics import (
+    model_flops_per_step, moe_counters_summary)
+from distributed_pytorch_from_scratch_tpu.training.optim import (
+    init_adam_state)
+from distributed_pytorch_from_scratch_tpu.training.train_step import (
+    build_train_step)
+
+
+def tiny(**latent):
+    cfg = model_preset("tiny-mla-moe")
+    return dataclasses.replace(
+        cfg, latent_moe=dataclasses.replace(cfg.latent_moe, **latent))
+
+
+def batch(cfg, b=2, t=128, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
+    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
+    return ids[:, :-1], ids[:, 1:], pos
+
+
+def on_mesh(cfg, tp, **kw):
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+    model = build_model("mla_moe", cfg, tp_size=tp, **kw)
+    return mesh, model
+
+
+# ---- the program against the plain reference ----
+
+@pytest.mark.parametrize("tp,impl", [(1, "xla"), (2, "xla"),
+                                     (1, "flash_interpret")])
+def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
+    """A job that holds experts 2..5 of 8: what the absent ones would add
+    is left out by program and reference alike."""
+    cfg = tiny(experts_held=4, expert_offset=2)
+    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
+    params = model.init(jax.random.key(3))
+    ids, tgt, pos = batch(cfg)
+    tgt = tgt.copy()
+    tgt[0, 5] = IGNORE_INDEX            # an ignored target in the middle
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.jit(jax.value_and_grad(
+            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
+        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g)) > 40
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
+    # the selection bias is read by top-k alone: no gradient reaches it
+    assert not np.any(np.asarray(got_g["layers"]["moe"]["bias"]))
+
+
+def test_no_top_k_choice_sits_on_a_tie():
+    """The comparison above means something only if float32 rounding cannot
+    flip a choice: the k-th and (k+1)-th scores are apart at every token of
+    the first expert layer's input (seeded weights, the test's batch)."""
+    cfg = tiny()
+    moe = SharedRoutedFFN(cfg.attn_dim, 32, cfg.num_experts, cfg.moe_top_k)
+    p = moe.init(jax.random.key(3))
+    x = jax.random.normal(jax.random.key(4), (256, cfg.attn_dim))
+    with jax.default_matmul_precision("highest"):
+        s = np.sort(np.asarray(jax.nn.sigmoid(x @ p["router"])), axis=-1)
+    margin = s[:, -cfg.moe_top_k] - s[:, -cfg.moe_top_k - 1]
+    assert margin.min() > 1e-5
+
+
+def test_interleaved_rope_turns_pairs():
+    """Pair (x_2i, x_2i+1) times e^{i pos theta_i}, as complex numbers."""
+    x = jax.random.normal(jax.random.key(0), (2, 3, 8, 6))
+    pos = jnp.tile(jnp.arange(8)[None], (2, 1))
+    cos, sin = rope_angles(pos, 6, 100.0)
+    got = np.asarray(apply_rotary_interleaved(x, cos, sin))
+    z = np.asarray(x[..., 0::2]) + 1j * np.asarray(x[..., 1::2])
+    theta = 100.0 ** (-np.arange(0, 6, 2) / 6)
+    w = z * np.exp(1j * np.arange(8)[None, None, :, None] * theta)
+    np.testing.assert_allclose(got[..., 0::2], w.real, atol=1e-5)
+    np.testing.assert_allclose(got[..., 1::2], w.imag, atol=1e-5)
+
+
+# ---- the flash kernel at two widths ----
+
+@pytest.mark.parametrize("t,block,kv_heads", [(128, None, 2), (384, 128, 2),
+                                              (256, 128, 1)])
+def test_flash_at_unequal_widths_equals_the_xla_path(t, block, kv_heads):
+    """q/k 48 wide against v of 32: one tile (the fused backward) and a
+    multi-block grid (scratch across key blocks, the split backward), with
+    and without grouped kv heads."""
+    key = jax.random.key(0)
+    q = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, t, 48))
+    k = jax.random.normal(jax.random.fold_in(key, 2), (1, kv_heads, t, 48))
+    v = jax.random.normal(jax.random.fold_in(key, 3), (1, kv_heads, t, 32))
+    blocks = dict.fromkeys(
+        ("block_q", "block_k", "bwd_block_q", "bwd_block_k"), block)
+    flash = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+        q, k, v, interpret=True, **(blocks if block else {}))))
+    plain = lambda q, k, v: jnp.sum(jnp.sin(causal_attention_xla(q, k, v)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    assert flash_attention(q, k, v, interpret=True).shape == (1, 2, t, 32)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+def test_flash_refuses_q_and_k_of_different_widths():
+    x = jnp.zeros((1, 1, 128, 16))
+    with pytest.raises(ValueError, match="q and k widths differ"):
+        flash_attention(x, jnp.zeros((1, 1, 128, 8)), x, interpret=True)
+
+
+# ---- the expert layer: shares, and no drop ----
+
+def apply_moe(moe, params, x):
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    from jax.sharding import PartitionSpec as P
+    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
+                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
+    return jax.jit(fn)(params, x)
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Four jobs hold two experts each of one layer's eight. Their routed
+    parts, plus the shared expert once, are the layer a job holding all
+    eight computes: the weights are normalised over all chosen experts,
+    held or not, so the parts are parts of one sum."""
+    d, f, E = 32, 16, 8
+    whole = SharedRoutedFFN(d, f, E, top_k=3, scaling=2.5)
+    p = whole.init(jax.random.key(1))
+    x = jax.random.normal(jax.random.key(2), (2, 64, d))
+    with jax.default_matmul_precision("highest"):
+        want, counters = apply_moe(whole, p, x)
+        xf, sh = x.reshape(-1, d), p["shared"]
+        shared_only = ((jax.nn.silu(xf @ sh["gate"]) * (xf @ sh["up"]))
+                       @ sh["down"]).reshape(x.shape)
+        total, rows = shared_only, 0.0
+        for lo in range(0, E, 2):
+            share = dataclasses.replace(whole, held=2, offset=lo)
+            ps = {**p, **{n: p[n][lo:lo + 2] for n in ("gate", "up", "down")}}
+            y, c = apply_moe(share, ps, x)
+            total = total + (y - shared_only)
+            rows += float(c["rows_here"])
+            np.testing.assert_array_equal(c["routed"], counters["routed"])
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert rows == float(counters["rows_here"]) == 2 * 64 * 3
+
+
+def test_a_router_forced_onto_the_same_experts_drops_nothing():
+    """The selection bias sends EVERY token to experts 0..2, all held and
+    far over any mean share: each (token, choice) pair is computed, in
+    several chunks of the sorted pairs, and the layer equals the dense
+    sum over those experts."""
+    d, f, E, k = 32, 16, 64, 3
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=4)
+    p = moe.init(jax.random.key(1))
+    p["bias"] = jnp.where(jnp.arange(E) < k, 10.0, 0.0)
+    x = jax.random.normal(jax.random.key(2), (4, 214, d))
+    # 2568 pairs, a chunk of 6 x 4/64 of them rounded up to 1024: three
+    # live chunks, the last of which runs past the pairs
+    assert moe.chunk_rows(4 * 214 * k) == 1024
+    with jax.default_matmul_precision("highest"):
+        got, counters = apply_moe(moe, p, x)
+        xf = x.reshape(-1, d)
+        s = jax.nn.sigmoid(xf @ p["router"])[:, :k]
+        w = s / jnp.sum(s, axis=-1, keepdims=True)
+        ffn = lambda g, u, dn: (jax.nn.silu(xf @ g) * (xf @ u)) @ dn
+        want = sum(w[:, e:e + 1] * ffn(p["gate"][e], p["up"][e], p["down"][e])
+                   for e in range(k))
+        sh = p["shared"]
+        want = want + ffn(sh["gate"], sh["up"], sh["down"])
+    assert float(counters["rows_here"]) == 4 * 214 * k     # 2568 pairs
+    np.testing.assert_array_equal(
+        counters["routed"], np.where(np.arange(E) < k, 4 * 214, 0))
+    np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
+
+
+# ---- the step, its counters, the entry point ----
+
+def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
+    cfg = tiny()
+    mesh, model = on_mesh(cfg, 2)
+    params = jax.device_put(model.init(jax.random.key(0)),
+                            model.shardings(mesh))
+    opt = init_adam_state(params)
+    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
+    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
+                            with_counters=True)
+    ids, tgt, pos = batch(cfg, t=64)
+    losses = []
+    for _ in range(6):
+        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
+        losses.append(float(loss))
+    assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
+    assert c["routed"].shape == (3, 8) and c["rows_here"].shape == (3,)
+    # every token takes top_k experts in each of the 2 + 1 expert layers
+    np.testing.assert_array_equal(c["routed"].sum(-1), [2 * 64 * 2] * 3)
+    assert abs(float(c["loss_main"] + 0.3 * c["loss_mtp"]) - losses[-1]) \
+        < 1e-5
+    summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
+    assert summary["rows_here_per_token"] == 2.0    # all experts held
+    assert summary["load_max_over_mean"] >= 1.0
+    # off by default: the step's output is what it has always been
+    plain = build_train_step(model, mesh, ocfg, with_grad_norm=True)
+    assert len(plain(params, opt, ids, tgt, pos)[2]) == 2
+
+
+def test_train_cli_runs_the_family(tmp_path, capsys):
+    from chip_smoke import write_tokens
+    from distributed_pytorch_from_scratch_tpu import train as train_mod
+    tokens = tmp_path / "tokens.json"
+    write_tokens(str(tokens), 503, 16, 65)
+    train_mod.main([
+        "--family", "mla_moe", "--model", "tiny-mla-moe", "--tp_size", "2",
+        "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
+        "--batch_size", "4", "--maxlen", "64", "--max_steps", "4",
+        "--log_interval", "2", "--save_interval", "100",
+        "--warmup_steps", "2"])
+    out = capsys.readouterr().out
+    assert "model[mla_moe]" in out and "rows_here_per_token" in out
+    events = [json.loads(line) for line in
+              open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
+    assert any(e.get("tag") == "moe_counters" for e in events)
+    with pytest.raises(SystemExit, match="reads the config field"):
+        train_mod.main(["--family", "llama", "--model", "tiny-mla-moe",
+                        "--data_path", str(tokens),
+                        "--save_dir", str(tmp_path / "x")])
+
+
+# ---- what is refused ----
+
+@pytest.mark.parametrize("kw,message", [
+    (dict(pp_size=2), "pp_size > 1"),
+    (dict(cp_size=2), "cp_size > 1"),
+    (dict(ep_size=2), "ep_size > 1"),
+    (dict(sequence_parallel=True), "sequence_parallel=True"),
+    (dict(tp_size=2, tp_overlap="ring"), "does not compose with MoE"),
+    (dict(attn_t_real=32), "attn_t_real"),
+    (dict(zero3_axis="dp"), "ZeRO stage 3"),
+])
+def test_the_model_refuses_what_it_does_not_run(kw, message):
+    with pytest.raises(ValueError, match=message):
+        build_model("mla_moe", tiny(), **kw)
+
+
+@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
+                                dict(dp_reduce_bucket_mb=1.0)])
+def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
+    mesh, model = on_mesh(tiny(), 1)
+    with pytest.raises(ValueError, match="not made to work with the "
+                                         "LatentMoETransformer family"):
+        build_train_step(model, mesh, OptimizerConfig(), **kw)
+
+
+def test_decode_and_serving_refuse_the_family():
+    from distributed_pytorch_from_scratch_tpu.models.decode import (
+        GreedyDecoder, make_generate)
+    from distributed_pytorch_from_scratch_tpu.serving.engine import (
+        ContinuousBatchingEngine, PagedEngine)
+    mesh, model = on_mesh(tiny(), 1)
+    params = model.init(jax.random.key(0))
+    for build in (lambda: GreedyDecoder(model, mesh, 32),
+                  lambda: make_generate(model, mesh, 32),
+                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
+                                                   32, 1),
+                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
+        with pytest.raises(ValueError, match="cannot be decoded or served"):
+            build()
+
+
+def test_a_family_needs_its_own_facts():
+    with pytest.raises(ValueError, match="needs cfg.latent_moe"):
+        build_model("mla_moe", ModelConfig(num_experts=8))
+
+
+# ---- the counts at the published widths ----
+
+def published(held=16, vocab=16160, layers=5):
+    return ModelConfig(
+        attn_dim=2048, ffn_dim=7168, num_heads=32, num_layers=layers,
+        vocab_size=vocab, maxlen=4096, rope_theta=3.2e7, num_experts=256,
+        moe_top_k=8, latent_moe=LatentMoEConfig(
+            q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, moe_intermediate_size=768,
+            routed_scaling_factor=2.5, experts_held=held,
+            num_nextn_predict_layers=1))
+
+
+def test_parameter_counts_at_the_published_widths():
+    """One chip's share (16 of 256 experts, an eighth of the vocabulary, 1
+    + 4 layers, the module): 680.4M, as `init` makes them."""
+    cfg = published()
+    parts = param_counts(cfg)
+    assert round(parts["dense_layers"] / 1e6, 1) == 70.4
+    assert round(parts["expert_layers"] / 4e6, 1) == 107.1
+    assert round(parts["mtp"] / 1e6, 1) == 115.5
+    assert round(parts["embedding_and_head"] / 1e6, 1) == 66.2
+    assert cfg.num_params() == 680_441_088          # 680.4M
+    model = build_model("mla_moe", cfg)
+    made = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
+    # uncut, an expert layer is 1,239.6M
+    uncut = param_counts(published(held=None))
+    assert round(uncut["expert_layers"] / 4e6, 1) == 1239.6
+    # the step's FLOPs count the held experts at a token's mean share of
+    # them (8 x 16/256 = 0.5 an expert layer), not all sixteen
+    flops = model_flops_per_step(cfg, 4, 4096, cfg.num_params())
+    assert 3.2e9 < flops / (4 * 4096) < 3.7e9

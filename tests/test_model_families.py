@@ -9,7 +9,8 @@ import re
 import jax
 import pytest
 
-from distributed_pytorch_from_scratch_tpu.config import ModelConfig
+from distributed_pytorch_from_scratch_tpu.config import (LatentMoEConfig,
+                                                         ModelConfig)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          DecoderStack,
                                                          build_model)
@@ -20,6 +21,22 @@ TINY = dict(attn_dim=32, ffn_dim=64, num_heads=4, num_layers=4,
             vocab_size=96, maxlen=64)
 CONFIGS = {"dense": ModelConfig(**TINY),
            "moe8": ModelConfig(num_experts=8, **TINY)}
+# a family that reads a config field of its own (`config_extra`) gets it:
+# the mla_moe family always has experts; "dense" holds all eight of them,
+# "moe8" a share of four, both behind one dense layer and with the module
+LATENT = dict(q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
+              qk_rope_head_dim=4, v_head_dim=8, moe_intermediate_size=16,
+              num_nextn_predict_layers=1)
+
+
+def config_for(family, config):
+    if FAMILIES[family].config_extra != "latent_moe":
+        return CONFIGS[config]
+    held = None if config == "dense" else 4
+    return ModelConfig(num_experts=8, **TINY, latent_moe=LatentMoEConfig(
+        experts_held=held, **LATENT))
+
+
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -35,7 +52,13 @@ def _shapes(model):
     dict(tp_size=2, pp_size=2, pp_schedule="interleaved", pp_virtual=2,
          pp_microbatches=2)], ids=["tp2", "pp2-interleaved"])
 def test_init_and_specs_have_the_same_tree(family, config, kw):
-    model = build_model(family, CONFIGS[config], **kw)
+    cfg = config_for(family, config)
+    if kw.get("pp_size", 1) > 1 and cfg.latent_moe is not None:
+        # a family with a layer pattern says so where it is built
+        with pytest.raises(ValueError, match="pp_size > 1"):
+            build_model(family, cfg, **kw)
+        return
+    model = build_model(family, cfg, **kw)
     params, specs = _shapes(model), model.specs()
     is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)
     assert (jax.tree.structure(params)
@@ -48,7 +71,7 @@ def test_init_and_specs_have_the_same_tree(family, config, kw):
 @families
 @configs
 def test_num_params_is_the_leaf_count_of_init(family, config):
-    cfg = CONFIGS[config]
+    cfg = config_for(family, config)
     leaves = jax.tree.leaves(_shapes(build_model(family, cfg)))
     assert FAMILIES[family].num_params(cfg) == sum(x.size for x in leaves)
 
@@ -56,16 +79,21 @@ def test_num_params_is_the_leaf_count_of_init(family, config):
 @families
 def test_declared_facts_agree_with_the_tree(family):
     cls = FAMILIES[family]
-    params = _shapes(build_model(family, CONFIGS["dense"]))
+    model = build_model(family, config_for(family, "dense"))
+    params = _shapes(model)
     mlp_inputs = [{"gate_proj", "up_proj"}, {"fc"}]
+    # the dense MLP of a family with a layer pattern sits in ONE segment
     reads_input = [names for names in mlp_inputs
-                   if names <= set(params["layers"])]
+                   if any(names <= set(params[key])
+                          for key in model._layer_keys)]
     assert [len(names) for names in reads_input] == [cls.ffn_inputs]
     assert ("lm_head" not in params) == cls.tied_head
     assert ("pos_embedding" not in params) == cls.uses_rope
-    for key in (cls.attn_norm_key, cls.ffn_norm_key,
-                "wq", "wk", "wv", "wo"):
-        assert key in params["layers"]
+    # the decoder reads the projections by these names; a family whose
+    # attention is another says it cannot be decoded
+    projections = ("wq", "wk", "wv") if cls.decodable else ()
+    for key in (cls.attn_norm_key, cls.ffn_norm_key, "wo", *projections):
+        assert all(key in params[seg] for seg in model._layer_keys)
 
 
 def test_build_model_refuses_an_unknown_name_with_the_known_ones():
