@@ -39,6 +39,16 @@ PR 30: inside a grid tile the kernels walk a static causal sub-tile plan
 (`causal_subtile_plan`), so the one-tile-a-head shape the table picks at
 t = 1024 no longer computes the dead half of its square, and `t_real`
 skips inside a tile too.
+
+PR 34: a head whose sequence spans several blocks keeps its K and V
+resident where they fit `KV_ROW_VMEM_BYTES` (t = 4096 at q/k 192, v 128
+does): the forward's grid is then (b*h, query blocks), K and V are fetched
+once a head, a query block runs its diagonal tile's static plan and loops
+over the key tiles left of it, and the online softmax never leaves
+registers (`_fwd_kernel`; 16.8 -> 6.1 ms a call at that shape). The
+forward's sub-tile follows q/k's width and whether a head has several key
+blocks (`_subtile_shape`). Longer rows keep the gridded walk, its K / V
+index maps clamped to the diagonal.
 """
 
 from __future__ import annotations
@@ -127,17 +137,77 @@ def _out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
 FWD_SUBTILE = (128, 256)
 BWD_SUBTILE = (256, 256)
 
+# The forward's sub-tile where q/k pass the MXU's 128 rows or a head has
+# several key blocks (`_subtile_shape`; the spot readings that drew the line
+# are at the end of this note).
+# Swept on v5e (TPU v5 lite, jax 0.9.0, PR 34) at the latent-attention
+# cell's shape: b*h 128, t = 4096, q/k 192 against v 128, bf16, a head's K
+# and V resident (`_fwd_call`'s row walk); device time of one call from a
+# capture (`python scripts/tune_flash_blocks.py --subtile --bh 128 --t 4096
+# --d 192 --dv 128 --blocks 512,1024,2048 --edges 128x256,128x512,256x256,
+# 256x512,512x256`). Forward ms a call by the grid's square block; the
+# backward (its own sub-tile set to the row's shape too) in brackets:
+#
+#   sub_q x sub_k   block 512        block 1024       block 2048
+#   before this PR                   16.81 (22.2)     gridded, 128 x 256
+#   128 x 256       12.00 (29.3)     10.78 (23.0)     13.00
+#   128 x 512        9.23 (29.9)      8.08 (23.8)      7.48
+#   256 x 256        8.36 (29.1)      7.54 (22.8)      7.09
+#   256 x 512        6.90 (29.9)      6.09 (23.7)      5.72
+#   512 x 256        7.55 (29.9)      6.50 (23.7)      over scoped VMEM
+#   512 x 512                         6.07             over scoped VMEM
+#   256 x 1024                        6.89             6.33
+#
+# (the backward's kernels pass the 16 MiB of scoped VMEM at block 2048.) What
+# bound the kernel before, by knocking parts out of the gridded walk at
+# blocks 1024, 128 x 256 (wrong numbers, right time; 16.81 ms whole): m and
+# l through their (block_q, 1) scratch 5.6 ms (acc alone through scratch
+# costs 0.08: one value in 128 lanes is what is dear, not the bytes), `p @ v`
+# 7.2, the rescale 0.3, the `exp` 0.1, the six dead tiles' K / V fetch 0.5
+# (index maps clamped to the diagonal), DMA alone 3.2. In the row walk at
+# 128 x 256: `p @ v` 4.4 of 10.78, `exp` 0.2, rescale 0.4: the matrix unit
+# binds at these widths as at 64, and a taller, wider sub-tile feeds it
+# better (a 128-row left operand streams through each latched weight tile
+# for no longer than the tile took to load). The plan at 256 x 512 computes
+# 1.125 of the causal triangle (1.06 at 128 x 256); the MXU's floor for it,
+# K = 192 in two passes, is ~4.7 ms. The loop over the key tiles left of the
+# diagonal wants a body of several sub-tiles: one sub-column an iteration
+# read 14.2 / 10.1 / 6.6 ms at 128 x 256 / 256 x 256 / 256 x 512 against
+# 10.8 / 7.5 / 6.1 with a key tile of 1024 as the body (some 200 cycles an
+# iteration with nothing in flight), two tiles an iteration bought nothing
+# more, the loop before the diagonal tile (every rectangle then rescales)
+# 14.3, every unmasked sub-tile in the loop 15.6. Block 2048 is 6% faster
+# than 1024 and is not taken: 8 s of Mosaic's time a kernel against 2, and
+# what it leaves of the scoped VMEM is under 2 MiB.
+#
+# Which shapes take it: the same tool at block 1024, forward ms a call at
+# 128 x 256 / 256 x 256 / 256 x 512. ONE tile a head (t = 1024): q/k 192, v
+# 128, b*h 128: 0.657 / 0.503 / 0.458; 128 / 128, b*h 128: 0.315 / 0.323 /
+# 0.332; 64 / 64, b*h 192 (FWD_SUBTILE's table): 0.475 / 0.487 / 0.498.
+# SEVERAL blocks a head, K and V resident: t = 4096 at 128 / 128, b*h 128:
+# 8.19 / 5.83 / 4.43; t = 4096 at 64 / 64, b*h 32: 2.09 / 1.49 / 1.13;
+# t = 8192 at 64 / 64, b*h 16: 3.89 / 2.74 / 2.05; and the gridded walk at
+# this note's shape 16.29 / - / 9.37. A loop over key tiles wants the long
+# dots at every width; one tile a head only where q/k pass 128.
+FWD_SUBTILE_WIDE = (256, 512)
+
 
 def _subtile_shape(block_q: int, block_k: int, head_dim: int,
-                   backward: bool) -> Tuple[int, int]:
+                   backward: bool, num_kb: int = 1) -> Tuple[int, int]:
     """(sub_q, sub_k) inside a (block_q x block_k) grid tile. A block no
     larger than the sub-tile is one sub-tile: the plan of a 128-token tile
     is a single masked sub-tile, the kernels' text before sub-tiles; it
-    skips something from 512-token blocks up. `head_dim` is an input so
-    that a sweep at another width has a place to land; only 64 has been
-    swept."""
-    del head_dim
-    sq, sk = BWD_SUBTILE if backward else FWD_SUBTILE
+    skips something from 512-token blocks up. The forward's shape follows
+    what the sweeps found (the two notes above): the wide one where q/k
+    pass the MXU's 128 rows (two passes a product) or a head has several
+    key blocks (`num_kb` > 1: the walk is then a loop, in the kernel or
+    the grid's, and wants long dots), the narrow one for one tile a head
+    at head_dim <= 128; the backward's was swept at 64 alone."""
+    if backward:
+        sq, sk = BWD_SUBTILE
+    else:
+        sq, sk = FWD_SUBTILE_WIDE if head_dim > 128 or num_kb > 1 \
+            else FWD_SUBTILE
     return min(block_q, sq), min(block_k, sk)
 
 
@@ -201,7 +271,8 @@ class SubtilePlan(NamedTuple):
 @functools.lru_cache(maxsize=None)
 def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
                         k_block: int, t_real: int, head_dim: int,
-                        backward: bool = False) -> SubtilePlan:
+                        backward: bool = False,
+                        num_kb: int = 1) -> SubtilePlan:
     """The static plan of grid tile (q_block, k_block): the ONE source for
     what the kernels walk, what `_fwd_call`'s cost_estimate counts and what
     `obs/attribution.flash_tile_stats` reports.
@@ -211,8 +282,9 @@ def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
     and `cut` = t_real - first row (rows at or past it are dead; a live
     row r < t_real only sees columns c <= r, so no column test is needed).
     Both are clamped to where they stop mattering, so every tile wholly
-    under the diagonal and inside t_real has the same plan."""
-    sq, sk = _subtile_shape(block_q, block_k, head_dim, backward)
+    under the diagonal and inside t_real has the same plan. `num_kb` is
+    the number of key blocks a head has (`_subtile_shape` asks)."""
+    sq, sk = _subtile_shape(block_q, block_k, head_dim, backward, num_kb)
     diag = max(-block_q, min(q_block * block_q - k_block * block_k,
                              block_k - 1))
     cut = max(0, min(t_real - q_block * block_q, block_q))
@@ -238,10 +310,11 @@ def causal_plan_stats(t_pad: int, block_q: int, block_k: int, t_real: int,
     """`causal_subtile_plan` summed over the grid of one head."""
     out = {"computed_unmasked": 0, "computed_masked": 0, "skipped": 0,
            "work_elems": 0}
+    num_kb = t_pad // block_k
     for qb in range(t_pad // block_q):
-        for kb in range(t_pad // block_k):
+        for kb in range(num_kb):
             plan = causal_subtile_plan(block_q, block_k, qb, kb, t_real,
-                                       head_dim, backward)
+                                       head_dim, backward, num_kb)
             out["computed_unmasked"] += plan.computed_unmasked
             out["computed_masked"] += plan.computed_masked
             out["skipped"] += plan.skipped
@@ -256,7 +329,7 @@ def _tile_plans(block_q: int, block_k: int, num_qb: int, num_kb: int,
     its clamped (diag, cut), which is how a kernel picks it from the
     program ids (`_plan_is`)."""
     plans = {causal_subtile_plan(block_q, block_k, qb, kb, t_real, head_dim,
-                                 backward)
+                                 backward, num_kb)
              for qb in range(num_qb) for kb in range(num_kb)}
     return sorted((p for p in plans if p.bands),
                   key=lambda p: (p.diag, p.cut))
@@ -269,6 +342,17 @@ def _plan_is(plan: SubtilePlan, qi, ki, block_q: int, block_k: int,
                                              block_k - 1))
     cut = jnp.maximum(0, jnp.minimum(t_real - qi * block_q, block_q))
     return (diag == plan.diag) & (cut == plan.cut)
+
+
+def _row_walk_plans(plans):
+    """The row walk's reading of a square-block grid's plans (`_fwd_kernel`):
+    ((plan of a tile on the diagonal, plan of the tiles left of it or None),
+    ...). Query block i walks the first at key tile i and the second at key
+    tiles 0 .. i - 1; the two are paired by the rows t_real leaves (`cut`),
+    and a cut that only the first query block has leaves no tile to its
+    left."""
+    under = {p.cut: p for p in plans if p.diag > 0}
+    return tuple((p, under.get(p.cut)) for p in plans if p.diag == 0)
 
 
 def _rect_live(plan: SubtilePlan, r0: int, rows: int, c0: int, cols: int,
@@ -327,18 +411,31 @@ def _softmax_step(state, s, v, masked: bool):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 scale: float, t_real: int, block_q: int, block_k: int,
-                num_kb: int, plans):
+                num_kb: int, plans, row_walk: bool = False):
     """A tile wholly above the diagonal or wholly padding has no plan: it is
     skipped. Every other tile runs the one plan that is its own; a query
     sub-row keeps ONE online softmax across its rectangles.
 
-    With one key block (the table's winner at t = 1024) a sub-row is
-    complete when its rectangles are done: it finalises from values and no
-    scratch exists. With several, (m, l, acc) live in scratch across the
-    grid's key blocks — the round trip costs (at t = 1024, one tile, it
-    doubled the kernel's time), so it is paid only where it is needed."""
+    A sub-row that sees its whole key row in one grid step finalises from
+    values: (m, l, acc) never leave registers and no scratch exists. That
+    is the one-key-block grid (the table's winner at t = 1024) and, with
+    `row_walk`, a head whose sequence spans several blocks but whose K and V
+    stay resident (`_fwd_call` decides): the key blocks are then the row of
+    tiles the plans describe, walked inside the step. The tile on the
+    diagonal runs its static plan; the tiles left of it all run the one
+    plan of a tile under the diagonal, so they are a loop over key tiles
+    with that plan's sub-row as its body (the kernel's text does not grow
+    with the sequence), and a sub-row's first rectangle, the diagonal
+    tile's, has nothing to rescale.
+
+    Where K and V of a head are too large for that, (m, l, acc) live in
+    scratch across the grid's key blocks. The round trip costs (at
+    t = 1024, one tile, it doubled the kernel's time; FWD_SUBTILE_WIDE's
+    note has it at t = 4096), so it is paid only there."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    # the key tile whose plan this step runs: the grid's, or in the row walk
+    # the one on the diagonal (block_q == block_k there)
+    ki = qi if row_walk else pl.program_id(2)
 
     def finalize(rs, m, l, acc):
         l_safe = jnp.where(l == 0.0, 1.0, l)  # dead (padded) q rows only
@@ -360,9 +457,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             m_ref[:] = jnp.full_like(m_ref, MASK)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-    for plan in plans:
+    def step(plan, state, q, r0, rows, c0, cols, masked, tile=None):
+        """One rectangle of `plan`, in key tile `tile` of the resident row
+        (None: the block the grid fetched)."""
+        cs = slice(c0, c0 + cols) if tile is None else pl.ds(
+            pl.multiple_of(tile * block_k + c0, cols), cols)
+        s = _dot(q, k_ref[0, cs, :], _NT) * scale
+        if masked:
+            s = jnp.where(_rect_live(plan, r0, rows, c0, cols), s, MASK)
+        return _softmax_step(state, s, v_ref[0, cs, :], masked)
+
+    for plan, left in (_row_walk_plans(plans) if row_walk
+                       else ((p, None) for p in plans)):
         @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
-        def _compute(plan=plan):
+        def _compute(plan=plan, left=left):
+            left_rects = {r0: rects for r0, _, rects in left.bands} \
+                if left else {}
             done = 0
             for r0, rows, rects in plan.bands:
                 rs = slice(r0, r0 + rows)
@@ -370,12 +480,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 state = (m_ref[rs], l_ref[rs], acc_ref[rs]) if scratch \
                     else None
                 for c0, cols, masked in rects:
-                    cs = slice(c0, c0 + cols)
-                    s = _dot(q, k_ref[0, cs, :], _NT) * scale
-                    if masked:
-                        s = jnp.where(_rect_live(plan, r0, rows, c0, cols),
-                                      s, MASK)
-                    state = _softmax_step(state, s, v_ref[0, cs, :], masked)
+                    state = step(plan, state, q, r0, rows, c0, cols, masked,
+                                 ki if row_walk else None)
+                if r0 in left_rects:
+                    def key_tile(kb, state, r0=r0, rows=rows, q=q):
+                        for c0, cols, masked in left_rects[r0]:
+                            state = step(left, state, q, r0, rows, c0, cols,
+                                         masked, kb)
+                        return state
+                    state = jax.lax.fori_loop(0, qi, key_tile, state)
                 if scratch:
                     m_ref[rs], l_ref[rs], acc_ref[rs] = state
                 else:
@@ -409,6 +522,17 @@ def _q_row(bkv, g, hq: int, hkv: int):
     return (bkv // hkv) * hq + (bkv % hkv) * group + g
 
 
+# What a head's K and V may take of VMEM, double-buffered by the pipeline and
+# each width padded to the 128 lanes a VMEM tile has, for the forward to
+# keep them resident while it walks the head's query blocks (`_fwd_call`).
+# Compiled for a v5e (16 MiB of scoped VMEM by default) beside 1024-row q, o
+# and lse blocks: t = 4096 at q/k 192 and v 128 in bf16 is 6 MiB; 8 MiB is
+# t = 8192 at 64 / 64 or 128 / 128 and t = 4096 at 256 / 256, and compiles;
+# so does 12 (t = 8192 at 192 / 128); 16 (t = 16384 at 64 / 64, t = 8192 at
+# 192 / 192) does not.
+KV_ROW_VMEM_BYTES = 8 * 2 ** 20
+
+
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
@@ -416,23 +540,42 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
     num_qb = t_pad // block_q
     num_kb = t_pad // block_k
     scale = 1.0 / math.sqrt(d)
+    # Several key blocks a head: where the head's K and V fit the budget the
+    # key block IS the row, fetched once a head (its block index no longer
+    # depends on the query block), and the kernel walks the row's tiles
+    # itself (`_fwd_kernel`: needs the diagonal to cross one tile a query
+    # block, the square one). Otherwise the grid walks them.
+    row_walk = (num_kb > 1 and block_q == block_k
+                and 2 * t_pad * (_round_up(d, 128) + _round_up(dv, 128))
+                * k.dtype.itemsize <= KV_ROW_VMEM_BYTES)
+    gridded = num_kb > 1 and not row_walk
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, t_real=t_real,
         block_q=block_q, block_k=block_k, num_kb=num_kb,
-        plans=_tile_plans(block_q, block_k, num_qb, num_kb, t_real, d))
+        plans=_tile_plans(block_q, block_k, num_qb, num_kb, t_real, d),
+        row_walk=row_walk)
 
-    kv = lambda b: _kv_row(b, hq, hkv)
+    def kv_index(b, i, j):
+        if row_walk:
+            return _kv_row(b, hq, hkv), 0, 0
+        if gridded:
+            # a tile above the diagonal is skipped: name the block that is
+            # already there, and the pipeline fetches nothing for it
+            j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        return _kv_row(b, hq, hkv), j, 0
+
+    kv_rows = t_pad if row_walk else block_k
     # what the plan computes, masked entries of a crossed sub-tile included
     entries = bh * causal_plan_stats(t_pad, block_q, block_k, t_real,
                                      d)["work_elems"]
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, num_qb, num_kb),
+        grid=(bh, num_qb, 1 if row_walk else num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, kv_rows, d), kv_index),
+            pl.BlockSpec((1, kv_rows, dv), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
@@ -446,7 +589,7 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-        ] if num_kb > 1 else [],
+        ] if gridded else [],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
@@ -777,10 +920,11 @@ class BlockConfig:
 _BLOCK_TABLE: Dict[Tuple[int, int, str, str], BlockConfig] = {
     (1024, 64, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
     # latent attention at its pre-training length, q/k 192 wide against v of
-    # 128 (the key's head_dim is q's): the first multi-block grid a cell
-    # runs, 4 x 4 tiles a head of which 10 are live. Set from the compile
-    # (Mosaic takes these shapes; PR 33) and NOT swept against other blocks
-    # on the chip yet: PERF.md section 7.
+    # 128 (the key's head_dim is q's): 4 x 4 tiles a head of which 10 are
+    # live. Swept in PR 34 (FWD_SUBTILE_WIDE's table, b*h 128): the forward
+    # 6.90 / 6.09 / 5.72 ms at blocks 512 / 1024 / 2048 with K and V
+    # resident, the backward's two kernels 29.1 / 22.8 ms at 512 / 1024 and
+    # over the scoped VMEM at 2048.
     (4096, 192, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
 }
 # key -> {source: sweep|online, capture, ts} provenance (ISSUE 16): an

@@ -15,13 +15,18 @@ the floor. Prints a table and the best combo per shape. Run on hardware:
 
     python scripts/tune_flash_blocks.py [--quick]
 
-`--subtile` sweeps the causal SUB-TILE edge inside the one grid tile a head
-(ops/pallas/flash_attention.py's FWD_SUBTILE / BWD_SUBTILE): the forward and the
-backward Mosaic call timed apart, at t=1024 hd64 bf16 and the b*h of
-`--bh` (192 = gpt2-medium b12 on one chip, 80 = gpt2-large dp2 x tp2 a
-chip). The readings behind the constants are in the note beside them:
+`--subtile` sweeps the causal SUB-TILE edge (ops/pallas/flash_attention.py's
+FWD_SUBTILE / FWD_SUBTILE_WIDE / BWD_SUBTILE) and the grid's square block:
+the forward and the backward Mosaic calls timed apart, bf16, at the shape
+of `--t --d --dv --blocks` (default t=1024 hd64, one tile a head) and the
+b*h of `--bh` (192 = gpt2-medium b12 on one chip, 80 = gpt2-large dp2 x tp2
+a chip, 128 = the latent-attention cell's 4 x 32). The readings behind the
+constants are in the notes beside them, one command a table:
 
     python scripts/tune_flash_blocks.py --subtile --bh 192,80
+    python scripts/tune_flash_blocks.py --subtile --bh 128 --t 4096 --d 192 \
+        --dv 128 --blocks 512,1024,2048 \
+        --edges 128x256,128x512,256x256,256x512,512x256
 
 `--paged` sweeps the PAGED-attention kernel instead (ISSUE 14):
 pages_per_block per (page_size, kv_dtype) serving decode shape
@@ -59,10 +64,11 @@ def time_fn(fn, *args, iters=20, warmup=3):
 
 
 def device_ms(fn, *args, match, iters=10):
-    """Mean device duration (ms) of the ops whose HLO text holds `match`,
-    from a profiler capture of `iters` calls. The host clock around a call
-    of under a millisecond also reads the dispatch; the capture reads the
-    kernel, as the benchmark's `kernels.flash_ms` does."""
+    """Device time (ms) a call of `fn` spends in the ops whose HLO text
+    holds `match` (a split backward is two kernels a call), from a profiler
+    capture of `iters` calls. The host clock around a call of under a
+    millisecond also reads the dispatch; the capture reads the kernel, as
+    the benchmark's `kernels.flash_ms` does."""
     import glob
     import tempfile
 
@@ -86,7 +92,7 @@ def device_ms(fn, *args, match, iters=10):
             for ev in line.events if match in ev.name]
     if not durs:
         raise RuntimeError(f"no op named like {match!r} in the capture")
-    return sum(durs) / len(durs) / 1e6
+    return sum(durs) / iters / 1e6
 
 
 def sweep_shape(name, b, h, hkv, t, d, blocks, iters):
@@ -157,37 +163,55 @@ def sweep_shape(name, b, h, hkv, t, d, blocks, iters):
     return best_fwd, bwd_results[0] if bwd_results else None
 
 
-def sweep_subtiles(bhs, edges, t=1024, d=64, iters=50):
-    """Forward and backward call times (ms) per sub-tile edge and b*h. The
-    edge is set on the module (it is no argument of the kernels: the code
-    picks it), the plan cache cleared and the calls jitted afresh. `edges`
-    are (sub_q, sub_k) pairs; (t, t) is one masked sub-tile: the kernels
-    before sub-tiles."""
+def sweep_subtiles(bhs, edges, t=1024, d=64, dv=None, blocks=None,
+                   iters=50):
+    """Forward and backward call times (ms) per grid block, sub-tile edge
+    and b*h, at q/k width `d` and v width `dv`. The edge is set on the
+    module (it is no argument of the kernels: the code picks it), the plan
+    cache cleared and the calls jitted afresh. `edges` are (sub_q, sub_k)
+    pairs; (t, t) is one masked sub-tile: the kernels before sub-tiles.
+    `blocks` are the grid's square blocks (default: one tile a head). A
+    combination Mosaic refuses is printed and skipped."""
     import distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
         as fa
+    dv = dv or d
     rows = []
+
+    def reading(tag, fn, args, match):
+        """(device ms, host-clock ms) of one call, None where Mosaic refuses
+        the combination (e.g. over its scoped VMEM)."""
+        try:
+            return (device_ms(fn, *args, match=match),
+                    time_fn(fn, *args, iters=iters))
+        except Exception as e:  # noqa: BLE001
+            print(f"{tag}  {match} FAILED {type(e).__name__}: "
+                  f"{str(e)[-200:]!r}", flush=True)
+
+    cell = lambda r, i: f"{r[i]:7.3f}" if r else "   -   "
     for bh in bhs:
         key = jax.random.PRNGKey(bh)
-        q, k, v, do = (jax.random.normal(kk, (bh, t, d), jnp.bfloat16)
-                       for kk in jax.random.split(key, 4))
-        kw = dict(t_real=t, block_q=t, block_k=t, hq=1, hkv=1,
-                  interpret=False)
-        for edge in edges:
-            fa.FWD_SUBTILE = fa.BWD_SUBTILE = edge
+        kq, kk, kv_, kd = jax.random.split(key, 4)
+        q, k = (jax.random.normal(x, (bh, t, d), jnp.bfloat16)
+                for x in (kq, kk))
+        v, do = (jax.random.normal(x, (bh, t, dv), jnp.bfloat16)
+                 for x in (kv_, kd))
+        # the backward's time does not depend on what o and lse hold
+        o, lse = jnp.zeros_like(v), jnp.zeros((bh, t, 1), jnp.float32)
+        for block, edge in itertools.product(blocks or [t], edges):
+            kw = dict(t_real=t, block_q=block, block_k=block, hq=1, hkv=1,
+                      interpret=False)
+            fa.FWD_SUBTILE = fa.FWD_SUBTILE_WIDE = fa.BWD_SUBTILE = edge
             fa.causal_subtile_plan.cache_clear()
             fwd = jax.jit(lambda q, k, v: fa._fwd_call(q, k, v, **kw))
             bwd = jax.jit(lambda q, k, v, o, lse, do: fa._bwd_call(
                 q, k, v, o, lse, do, **kw))
-            o, lse = fwd(q, k, v)
-            ms_f = device_ms(fwd, q, k, v, match="flash_fwd")
-            ms_b = device_ms(bwd, q, k, v, o, lse, do, match="flash_bwd")
-            host_f = time_fn(fwd, q, k, v, iters=iters)
-            host_b = time_fn(bwd, q, k, v, o, lse, do, iters=iters)
-            rows.append((bh, edge, ms_f, ms_b))
-            print(f"  bh{bh:4d} t{t} hd{d} sub {edge[0]:4d}x{edge[1]:<4d}  "
-                  f"fwd {ms_f:7.3f} ms"
-                  f"   bwd {ms_b:7.3f} ms   (host clock {host_f:.3f} / "
-                  f"{host_b:.3f})", flush=True)
+            tag = (f"  bh{bh:4d} t{t} d{d}/{dv} block {block:4d} "
+                   f"sub {edge[0]:4d}x{edge[1]:<4d}")
+            f = reading(tag, fwd, (q, k, v), "flash_fwd")
+            b = reading(tag, bwd, (q, k, v, o, lse, do), "flash_bwd")
+            rows.append((bh, block, edge, f and f[0], b and b[0]))
+            print(f"{tag}  fwd {cell(f, 0)} ms   bwd {cell(b, 0)} ms   "
+                  f"(host clock {cell(f, 1)} / {cell(b, 1)})", flush=True)
     return rows
 
 
@@ -201,6 +225,15 @@ def parse_args(argv=None):
     ap.add_argument("--edges", default="128,256,512,1024",
                     help="--subtile: comma-separated sub-tile shapes, "
                          "an edge (256) or sub_q x sub_k (128x256)")
+    ap.add_argument("--t", type=int, default=1024,
+                    help="--subtile: sequence length")
+    ap.add_argument("--d", type=int, default=64,
+                    help="--subtile: width of q and k")
+    ap.add_argument("--dv", type=int, default=None,
+                    help="--subtile: width of v (default: --d)")
+    ap.add_argument("--blocks", default=None,
+                    help="--subtile: comma-separated square grid blocks "
+                         "(default: --t, one tile a head)")
     ap.add_argument("--quick", action="store_true",
                     help="fewer block combos / iters")
     ap.add_argument("--iters", type=int, default=20)
@@ -262,6 +295,9 @@ def main():
         return sweep_subtiles([int(x) for x in args.bh.split(",")],
                               [tuple(int(e) for e in (x.split("x") * 2)[:2])
                                for x in args.edges.split(",")],
+                              t=args.t, d=args.d, dv=args.dv,
+                              blocks=[int(x) for x in args.blocks.split(",")]
+                              if args.blocks else None,
                               iters=max(args.iters, 50))
 
     sizes = [256, 512, 1024] if args.quick else [128, 256, 512, 1024, 2048]

@@ -38,7 +38,10 @@ def test_tile_stats_single_block_walks_its_plan():
 
 @pytest.mark.parametrize("t,t_real,blocks,hkv", [
     (1024, None, (1024, 1024), 2), (1024, 1000, (1024, 1024), 2),
-    (1024, 600, (512, 512), 1), (700, None, (128, 256), 2)])
+    (1024, 600, (512, 512), 1), (700, None, (128, 256), 2),
+    # four and eight resident blocks a head: the looped stretch is counted
+    # as the sub-tiles it runs
+    (2048, None, (512, 512), 2), (2048, 1500, (256, 256), 1)])
 def test_tile_stats_agree_with_kernel_cost_estimate(t, t_real, blocks, hkv):
     """One source: the FLOPs `_fwd_call` hands XLA as the kernel's
     cost_estimate are the plan's computed entries x 4 x head_dim, the same

@@ -468,9 +468,11 @@ def test_gqa_kernel_shape_sweep(group, t, dtype):
 # leave unmasked what the plan says.
 
 
-def _qkv(key, b, h, hkv, t, d, dtype=jnp.float32, n=3):
+def _qkv(key, b, h, hkv, t, d, dtype=jnp.float32, n=3, dv=None):
+    """q, k of width d; v and (n=4) a cotangent of width dv, d if None."""
+    dv = dv or d
     ks = jax.random.split(jax.random.key(key), 4)
-    shapes = [(b, h, t, d), (b, hkv, t, d), (b, hkv, t, d), (b, h, t, d)]
+    shapes = [(b, h, t, d), (b, hkv, t, d), (b, hkv, t, dv), (b, h, t, dv)]
     return [jax.random.normal(kk, s, dtype) for kk, s in zip(ks, shapes)][:n]
 
 
@@ -537,6 +539,54 @@ def test_subtile_plan_covers_live_and_unmasks_no_dead(
     fa_mod.causal_subtile_plan.cache_clear()
 
 
+@pytest.mark.parametrize("block,sub", [
+    (512, (128, 256)), (1024, (256, 512)), (256, (256, 256)),
+    (512, (128, 128)), (1024, (512, 256))])
+def test_row_walk_runs_each_tiles_plan_once(monkeypatch, block, sub):
+    """The looped stretch: query block i walks its diagonal tile's plan and,
+    for key tiles 0 .. i - 1, the one plan `_row_walk_plans` paired with it.
+    Each is THE plan of the tile it stands in (which the test above holds
+    to the live set), no tile right of the diagonal has one, so every live
+    entry is computed once and no dead one unmasked; and what is walked is
+    what `causal_plan_stats` counts."""
+    monkeypatch.setattr(fa_mod, "FWD_SUBTILE_WIDE", sub)
+    fa_mod.causal_subtile_plan.cache_clear()
+    t_pad, n = 2048, 2048 // block
+    tile = lambda qb, kb, t_real: fa_mod.causal_subtile_plan(
+        block, block, qb, kb, t_real, 64, num_kb=n)
+    for t_real in (1, 127, 128, 129, 700, 1000, 1024, 1025, 1536, 2048):
+        pairs = fa_mod._row_walk_plans(
+            fa_mod._tile_plans(block, block, n, n, t_real, 64))
+        row = np.arange(t_pad)[:, None]
+        live = (np.arange(t_pad)[None, :] <= row) & (row < t_real)
+        times = np.zeros(live.shape, np.int8)
+        walked = {"computed_unmasked": 0, "computed_masked": 0}
+
+        def walk(plan, qb, kb):
+            assert plan == tile(qb, kb, t_real)
+            for r0, rows, rects in plan.bands:
+                for c0, cols, _ in rects:
+                    times[qb * block + r0:qb * block + r0 + rows,
+                          kb * block + c0:kb * block + c0 + cols] += 1
+            walked["computed_unmasked"] += plan.computed_unmasked
+            walked["computed_masked"] += plan.computed_masked
+
+        for qi in range(n):
+            mine = [(p, left) for p, left in pairs
+                    if fa_mod._plan_is(p, qi, qi, block, block, t_real)]
+            assert len(mine) == (qi * block < t_real)
+            for plan, left in mine:
+                walk(plan, qi, qi)
+                for kb in range(qi):        # the kernel's fori_loop(0, qi)
+                    walk(left, qi, kb)
+            assert not any(tile(qi, kb, t_real).bands
+                           for kb in range(qi + 1, n))
+        assert (times[live] == 1).all() and times.max() == 1
+        stats = fa_mod.causal_plan_stats(t_pad, block, block, t_real, 64)
+        assert walked == {k: stats[k] for k in walked}
+    fa_mod.causal_subtile_plan.cache_clear()
+
+
 def test_subtile_plan_at_the_benchmark_shape():
     """t = 1024, head_dim 64, one tile a head: the forward walks 20 of 32
     128 x 256 sub-tiles (8 masked), the backward 10 of 16 256 x 256 (4
@@ -554,6 +604,50 @@ def test_subtile_plan_at_the_benchmark_shape():
     assert bwd.columns[-1] == (768, 256, ((768, 256, True),))
     small = fa_mod.causal_subtile_plan(128, 128, 0, 0, 128, 64)
     assert small.bands == ((0, 128, ((0, 128, True),)),)
+
+
+def test_subtile_plan_at_the_latent_benchmark_shape():
+    """t = 4096, q/k 192 against v 128, blocks 1024 (the table's entry), K
+    and V resident: a head walks 72 of 128 256 x 512 sub-tiles, 16 of them
+    masked (it was 272 of 512 at 128 x 256). Each of the four query blocks runs the one-tile plan on its
+    diagonal tile, 6 sub-tiles, and loops over the tiles left of it with a
+    body of two unmasked sub-tiles a sub-row; `flash_tile_stats` reports the
+    same walk."""
+    from distributed_pytorch_from_scratch_tpu.obs.attribution import (
+        flash_tile_stats)
+    stats = fa_mod.causal_plan_stats(4096, 1024, 1024, 4096, 192)
+    assert stats == {"computed_unmasked": 56, "computed_masked": 16,
+                     "skipped": 56, "work_elems": 72 * 256 * 512,
+                     "sub_q": 256, "sub_k": 512}
+    # several key blocks a head take the wide sub-tile at every width; one
+    # tile a head only where q/k pass the MXU's 128 rows
+    assert fa_mod.causal_plan_stats(4096, 1024, 1024, 4096, 64) == stats
+    shape = lambda t, d: tuple(fa_mod.causal_plan_stats(
+        t, 1024, 1024, t, d)[k] for k in ("sub_q", "sub_k"))
+    assert shape(1024, 64) == shape(1024, 128) == (128, 256)
+    assert shape(1024, 192) == shape(2048, 128) == (256, 512)
+    (diag, left), = fa_mod._row_walk_plans(
+        fa_mod._tile_plans(1024, 1024, 4, 4, 4096, 192))
+    assert diag == fa_mod.causal_subtile_plan(1024, 1024, 0, 0, 1024, 192,
+                                              num_kb=4)
+    assert diag.bands == (
+        (0, 256, ((0, 512, True),)), (256, 256, ((0, 512, True),)),
+        (512, 256, ((0, 512, False), (512, 512, True))),
+        (768, 256, ((0, 512, False), (512, 512, True))))
+    assert left.bands == tuple(
+        (r0, 256, ((0, 512, False), (512, 512, False)))
+        for r0 in (0, 256, 512, 768))
+    # 4 diagonal tiles + 6 tiles left of them
+    assert 4 * 6 + 6 * 8 == 72
+    s = flash_tile_stats(4096, head_dim=192)
+    assert (s["block_q"], s["block_k"], s["sub_q"], s["sub_k"]) == (
+        1024, 1024, 256, 512)
+    assert (s["live_tiles"], s["masked_tiles"], s["total_tiles"]) == (
+        72, 16, 128)
+    assert 1.12 < s["waste_ratio"] < 1.13
+    # the backward at these widths keeps the shape swept at 64
+    bwd = fa_mod.causal_plan_stats(4096, 1024, 1024, 4096, 192, True)
+    assert (bwd["sub_q"], bwd["sub_k"]) == (256, 256)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
@@ -658,3 +752,172 @@ def test_subtile_under_shard_map():
                      argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g_ref, g_fl):
         assert jnp.abs(a - b).max() < 1e-4
+
+
+# ------------------------------------------------- several blocks a head
+#
+# A head whose padded sequence spans several square blocks and whose K and V
+# fit `KV_ROW_VMEM_BYTES` keeps them resident: grid (b*h, query blocks, 1),
+# the diagonal tile's static plan plus a loop over the key tiles left of it
+# (`_fwd_kernel`'s row walk). The cases below run 4 and 8 query blocks at
+# two pairs of widths, one under each of the forward's two sub-tile shapes.
+
+
+def _fwd_grid(q, k, v, **kw):
+    """(grid, K's block shape) of the forward's pallas_call."""
+    def calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    jaxpr = jax.make_jaxpr(lambda *a: flash_attention(*a, **kw))(q, k, v)
+    gm = next(calls(jaxpr.jaxpr)).params["grid_mapping"]
+    return tuple(gm.grid), tuple(getattr(b, "block_size", b) for b in
+                                 gm.block_mappings[1].block_shape)
+
+
+def _rel(a, b):
+    a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return float(jnp.abs(a - b).max() / jnp.abs(a).max())
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("blocks", [4, 8])
+@pytest.mark.parametrize("d,dv", [(24, 16), (192, 128)])
+def test_row_walk_matches_oracle(d, dv, blocks, dtype, tol):
+    """Forward and gradients against the XLA oracle, q/k and v of two
+    widths: the walk is taken (K's block is the whole row, one grid step a
+    query block), a query block's stretch runs 0 .. blocks - 1 key tiles."""
+    t, blk = 256 * blocks, 256
+    q, k, v, g = _qkv(blocks, 1, 1, 1, t, d, dtype, n=4, dv=dv)
+    kw = dict(block_q=blk, block_k=blk, bwd_block_q=blk, bwd_block_k=blk)
+    assert _fwd_grid(q, k, v, **kw) == ((1, blocks, 1), (1, t, d))
+    ref = causal_attention_xla(q, k, v)
+    out = flash_attention(q, k, v, **kw)
+    assert out.shape == (1, 1, t, dv) and out.dtype == dtype
+    assert jnp.abs(ref.astype(jnp.float32)
+                   - out.astype(jnp.float32)).max() < tol
+    gr = jax.grad(lambda *a: jnp.vdot(causal_attention_xla(*a), g).real
+                  .astype(jnp.float32), (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g).real
+                  .astype(jnp.float32), (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert _rel(a, b) < 10 * tol
+
+
+@pytest.mark.parametrize("t_real", [
+    600,    # in the middle of the third block, cutting a sub-tile
+    512,    # on a block edge
+    100])   # in the first block: no tile left of any live diagonal tile
+def test_row_walk_t_real_exact_zeros_with_tail_cotangent(t_real):
+    """t_real against four resident blocks: live rows match the sliced
+    oracle, rows at or past it read o = 0 and lse = MASK exactly, and a
+    nonzero cotangent on them yields exact zero gradients."""
+    q, k, v, g = _qkv(t_real, 1, 2, 2, 1024, 24, n=4, dv=16)
+    kw = dict(block_q=256, block_k=256, bwd_block_q=256, bwd_block_k=256,
+              t_real=t_real)
+    out = flash_attention(q, k, v, **kw)
+    ref = _sliced_oracle(q, k, v, t_real)
+    assert jnp.abs(out - ref).max() < 1e-5
+    assert jnp.abs(out[:, :, t_real:]).max() == 0.0
+    flat = lambda x: x.reshape(2, 1024, x.shape[-1])
+    o, lse = fa_mod._fwd_call(flat(q), flat(k), flat(v), t_real=t_real,
+                              block_q=256, block_k=256, hq=2, hkv=2,
+                              interpret=True)
+    assert (lse[:, t_real:] == fa_mod.MASK).all()
+    assert (lse[:, :t_real] > fa_mod.MASK / 2).all()
+    assert not o[:, t_real:].any()
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert jnp.abs(b[:, :, t_real:]).max() == 0.0
+
+
+@pytest.mark.parametrize("group", [1, 2, 4])
+def test_row_walk_gqa_groups(group):
+    """Grouped query heads: the K / V row routing lives in an index map
+    that ignores the query block."""
+    q, k, v, g = _qkv(group, 2, 4, 4 // group, 1024, 24, n=4, dv=16)
+    tr = 900
+    kw = dict(block_q=256, block_k=256, bwd_block_q=256, bwd_block_k=256,
+              t_real=tr)
+    assert _fwd_grid(q, k, v, **kw) == ((8, 4, 1), (1, 1024, 24))
+    out = flash_attention(q, k, v, **kw)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, tr)).max() < 1e-5
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, tr), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+
+
+def test_row_walk_under_shard_map():
+    """Four resident blocks a shard, heads over tp: the loop's bound is a
+    program id and its carry is tp-varying."""
+    mesh = make_mesh(MeshConfig(dp=1, tp=4))
+    q, k, v = _qkv(11, 1, 4, 4, 1024, 24, dv=16)
+    kw = dict(block_q=256, block_k=256, bwd_block_q=256, bwd_block_k=256,
+              t_real=700)
+    sm = jax.shard_map(lambda q, k, v: flash_attention(q, k, v, **kw),
+                       mesh=mesh, in_specs=(P(None, "tp"),) * 3,
+                       out_specs=P(None, "tp"))
+    out = jax.jit(sm)(q, k, v)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, 700)).max() < 1e-5
+    loss = lambda fn: lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+    g_fl = jax.jit(jax.grad(loss(sm), argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.grad(loss(lambda *a: _sliced_oracle(*a, 700)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_ref, g_fl):
+        assert jnp.abs(a - b).max() < 1e-4
+
+
+@pytest.mark.parametrize("blocks,t_real", [((256, 256), 1024),
+                                           ((256, 256), 600),
+                                           ((256, 512), 1024)])
+def test_gridded_walk_where_the_row_does_not_fit(monkeypatch, blocks, t_real):
+    """K and V of a head over the VMEM budget (or blocks that are not
+    square): the grid walks the key blocks, (m, l, acc) through scratch,
+    the K / V index maps clamped to the diagonal. Same oracle."""
+    if blocks[0] == blocks[1]:
+        monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", 0)
+    q, k, v, g = _qkv(t_real, 1, 2, 1, 1024, 24, n=4, dv=16)
+    kw = dict(block_q=blocks[0], block_k=blocks[1], bwd_block_q=blocks[0],
+              bwd_block_k=blocks[1], t_real=t_real)
+    assert _fwd_grid(q, k, v, **kw) == (
+        (2, 1024 // blocks[0], 1024 // blocks[1]), (1, blocks[1], 24))
+    out = flash_attention(q, k, v, **kw)
+    assert jnp.abs(out - _sliced_oracle(q, k, v, t_real)).max() < 1e-5
+    assert not out[:, :, t_real:].any()
+    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
+                  (0, 1, 2))(q, k, v)
+    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
+                  (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+
+
+def test_the_row_fits_by_bytes_alone(monkeypatch):
+    """The walk is chosen from what the call sees: one byte under a head's
+    double-buffered K and V (each width padded to VMEM's 128 lanes) and the
+    grid walks; at it, the row is resident."""
+    q, k, v = _qkv(3, 1, 1, 1, 1024, 24, dv=16)
+    kw = dict(block_q=256, block_k=256)
+    need = 2 * 1024 * (128 + 128) * 4
+    monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", need)
+    assert _fwd_grid(q, k, v, **kw) == ((1, 4, 1), (1, 1024, 24))
+    monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", need - 1)
+    assert _fwd_grid(q, k, v, **kw) == ((1, 4, 4), (1, 256, 24))
+    # one key block a head is neither: the grid the table's winner has had
+    assert _fwd_grid(q, k, v) == ((1, 1, 1), (1, 1024, 24))
+    # the benchmark's latent shape sits inside the shipped budget, the same
+    # widths at twice the length do not
+    monkeypatch.undo()
+    fits = lambda t: 2 * t * (256 + 128) * 2 <= fa_mod.KV_ROW_VMEM_BYTES
+    assert fits(4096) and not fits(8192)

@@ -190,3 +190,28 @@ def test_cell_2_backward_body_exchanges_the_dp_gradients_under_dots(
         hidden += bool(dots)
     # proj, fc, the attention projection and two of q/k/v at the least
     assert hidden >= 5, hidden
+
+
+@pytest.mark.parametrize("t,d,dv,t_real", [
+    (4096, 192, 128, 4096),     # the latent-attention cell's forward
+    (4096, 192, 128, 4000),     # a second plan for the block t_real cuts
+    (8192, 64, 64, 8192)])      # as long a resident row as the budget takes
+def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
+        topo, described_tpu, t, d, dv, t_real):
+    """Mosaic takes the multi-block forward that keeps a head's K and V in
+    VMEM (PR 34) at the table's blocks and inside its default scoped VMEM:
+    one kernel an attention, grid (b*h, query blocks, 1)."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+        flash_attention as fa)
+    blocks = fa.get_block_config(t, d, jnp.bfloat16)
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda w: jax.ShapeDtypeStruct((8, t, w), jnp.bfloat16,
+                                         sharding=chip)
+    assert 2 * t * (fa._round_up(d, 128) + fa._round_up(dv, 128)) * 2 \
+        <= fa.KV_ROW_VMEM_BYTES
+    text = jax.jit(lambda q, k, v: fa._fwd_call(
+        q, k, v, t_real=t_real, block_q=blocks.block_q,
+        block_k=blocks.block_k, hq=1, hkv=1, interpret=False)).lower(
+            arg(d), arg(d), arg(dv)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
