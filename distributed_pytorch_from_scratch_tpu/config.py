@@ -74,6 +74,41 @@ class LatentMoEConfig:
 
 
 @dataclass(frozen=True)
+class GdnMoEConfig:
+    """What the `gdn_moe` family (models/gdn_moe.py) needs beyond
+    `ModelConfig`'s own fields: Gated DeltaNet linear-attention layers, one
+    gated grouped-query full-attention layer closing every period of
+    `full_attention_interval` layers, and in every layer a softmax top-k
+    router over routed experts (of which this job may hold a slice) with a
+    gated shared expert. The keys are Qwen3-Next's `config.json` names
+    where one exists. In `ModelConfig`, `attn_dim` is the model width,
+    `num_heads` / `num_kv_heads` the full-attention layers' query and
+    key-value heads, `num_layers` the layers (a whole number of periods),
+    `num_experts` the ROUTED experts the router scores, `moe_top_k` the
+    experts a token takes, and `ffn_dim` the shared expert's width (there is
+    no dense MLP)."""
+
+    head_dim: int                   # the full-attention heads' width
+    linear_num_key_heads: int
+    linear_num_value_heads: int
+    linear_key_head_dim: int
+    linear_value_head_dim: int
+    moe_intermediate_size: int
+    shared_expert_intermediate_size: int
+    linear_conv_kernel_dim: int = 4
+    full_attention_interval: int = 4
+    partial_rotary_factor: float = 0.25
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -107,6 +142,8 @@ class ModelConfig:
     moe_z_coef: float = 1e-3     # router z-loss weight (ST-MoE: 1e-3)
     # The `mla_moe` family's facts (None for every other family).
     latent_moe: "LatentMoEConfig | None" = None
+    # The `gdn_moe` family's facts (None for every other family).
+    gdn_moe: "GdnMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -124,12 +161,28 @@ class ModelConfig:
 
     @property
     def experts_held(self) -> int:
-        """Routed experts this job holds: all of them, unless the mla_moe
-        family's `latent_moe.experts_held` names a share."""
-        lm = self.latent_moe
-        if lm is None or lm.experts_held is None:
+        """Routed experts this job holds: all of them, unless the family's
+        facts (`latent_moe` / `gdn_moe`) name a share with `experts_held`."""
+        share = self.latent_moe or self.gdn_moe
+        if share is None or share.experts_held is None:
             return self.num_experts
-        return lm.experts_held
+        return share.experts_held
+
+    @property
+    def expert_offset(self) -> int:
+        """The first routed expert this job holds."""
+        share = self.latent_moe or self.gdn_moe
+        return 0 if share is None else share.expert_offset
+
+    @property
+    def family_facts(self) -> "str | None":
+        """The name of the field that carries one family's facts, if this
+        configuration has any (`DecoderStack.config_extra` names the one a
+        family reads)."""
+        for name in ("latent_moe", "gdn_moe"):
+            if getattr(self, name) is not None:
+                return name
+        return None
 
     def padded_vocab_size(self, tp_size: int) -> int:
         """Vocab size rounded up to a multiple of tp_size.
@@ -145,6 +198,9 @@ class ModelConfig:
         if self.latent_moe is not None:
             from .models.mla_moe import LatentMoETransformer
             return LatentMoETransformer.num_params(self)
+        if self.gdn_moe is not None:
+            from .models.gdn_moe import GdnMoETransformer
+            return GdnMoETransformer.num_params(self)
         d, f, v, L = self.attn_dim, self.ffn_dim, self.vocab_size, self.num_layers
         kd = self.kv_dim
         attn = 2 * d * d + 2 * d * kd + 2 * d + 2 * kd  # wq/wo + wk/wv (+ biases)
@@ -187,6 +243,18 @@ MODEL_PRESETS = {
             q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
             qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
             routed_scaling_factor=2.5, num_nextn_predict_layers=1)),
+    # the `gdn_moe` family at a CPU size: two periods of three Gated
+    # DeltaNet layers (2 key heads, 4 value heads, 16 wide) and one gated
+    # full-attention layer (4 query heads over 2 key-value heads, 32 wide, a
+    # quarter of it rotary); in every layer 8 routed experts (softmax top-2)
+    # and a gated shared expert
+    "tiny-gdn-moe": ModelConfig(
+        attn_dim=64, ffn_dim=32, num_heads=4, num_kv_heads=2, num_layers=8,
+        vocab_size=1024, maxlen=256, rope_theta=10000.0, num_experts=8,
+        moe_top_k=2, gdn_moe=GdnMoEConfig(
+            head_dim=32, linear_num_key_heads=2, linear_num_value_heads=4,
+            linear_key_head_dim=16, linear_value_head_dim=16,
+            moe_intermediate_size=32, shared_expert_intermediate_size=32)),
 }
 
 

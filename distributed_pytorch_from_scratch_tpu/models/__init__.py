@@ -1,12 +1,13 @@
 """The model families, and the one place a family's name becomes a class."""
 
+from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
 from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
 from .transformer import Transformer
 
 FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer,
-            "mla_moe": LatentMoETransformer}
+            "mla_moe": LatentMoETransformer, "gdn_moe": GdnMoETransformer}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -505,6 +505,16 @@ class DecoderStack:
     # structure, and what a layer's FFN is follows from what its
     # parameters hold (`_ffn`). A pipeline splits one segment only.
     _layer_keys = ("layers",)
+    # A pattern that REPEATS: ((parameter key, layers a period), ...) in
+    # the order a period runs them, or None. The keys are `_layer_keys`;
+    # each holds its layers stacked (periods, layers a period, ...), and
+    # the forward is ONE scan over periods whose body scans each key's
+    # layers in turn (`_scan_periods`): the cut to one period and the
+    # published depth are the same program.
+    _period = None
+    # Does the family's mixer hand back the sublayer's output itself
+    # (`_mix`), instead of (q, k, v) for the stack's attention dispatch?
+    _own_mixer = False
     # does the loss add the Switch load-balance and z terms of
     # parallel/moe.MoEFFN's router sums (a family whose router balances
     # without an auxiliary loss says no)
@@ -630,6 +640,11 @@ class DecoderStack:
         layers = jax.vmap(one_layer)(layer_keys)
         if self._interleaved:
             layers = self._layers_to_schedule(layers)
+        if self._period:
+            # (periods, layers a period, ...): `_scan_periods`
+            a_period = dict(self._period)[segment]
+            layers = jax.tree.map(
+                lambda a: a.reshape(-1, a_period, *a.shape[1:]), layers)
         return layers
 
     def _segment_mods(self, names=None) -> Dict[str, Any]:
@@ -683,7 +698,9 @@ class DecoderStack:
 
     def _layer_specs(self, names=None) -> Params:
         """PartitionSpecs matching `_init_layers`."""
-        lead = "pp" if self.pp_size > 1 else None
+        lead = ("pp" if self.pp_size > 1 else None,)
+        if self._period:
+            lead = (None, None)     # periods, layers a period
 
         def stack(spec_dict: Params) -> Params:
             # stacked num_layers axis: sharded over 'pp' when pipelining
@@ -694,7 +711,7 @@ class DecoderStack:
                 return jax.tree.map(lambda s: P(None, "pp", None, *s),
                                     spec_dict,
                                     is_leaf=lambda x: isinstance(x, P))
-            return jax.tree.map(lambda s: P(lead, *s), spec_dict,
+            return jax.tree.map(lambda s: P(*lead, *s), spec_dict,
                                 is_leaf=lambda x: isinstance(x, P))
         return {name: stack(mod.specs())
                 for name, mod in self._segment_mods(names).items()}
@@ -752,7 +769,9 @@ class DecoderStack:
             # (b, heads, t, v's width) -> (b, t, heads * width)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, t, self.num_local_heads * o.shape[-1])
-            a = self._attn_project(layer_params, o, tp, dtype)
+            return ffn_half(x, self._attn_project(layer_params, o, tp, dtype))
+
+        def ffn_half(x, a):
             if self.tp_size > 1:
                 # named PAST the row-linear's reduce, so keeping it drops
                 # the recomputed forward's collective with the matmul;
@@ -772,6 +791,10 @@ class DecoderStack:
         # the cp ring documents below. Bubble steps burn the layer FLOPs;
         # their outputs are structurally discarded (garbage flows only into
         # garbage — see _pipeline_layers).
+        if self._own_mixer:
+            norm = self.attn_norm_key
+            y = tp.gather(m[norm].apply(layer_params[norm], x))
+            return ffn_half(x, self._mix(layer_params, y, layer_pos, dtype))
         if live is None or tp.ring_ov:
             q, k, v = qkv(x)
             if self.cp_size > 1:
@@ -812,6 +835,12 @@ class DecoderStack:
         k = split(k, self.num_local_kv_heads)
         v = split(v, self.num_local_kv_heads)
         return self._position_qk(q, k, layer_pos) + (v,)
+
+    def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
+        """For a family with `_own_mixer`: the mixer sublayer's output (b,
+        t, d), reduced over 'tp', from the normed activation `y`. Which
+        mixer a layer runs follows from what its parameters hold."""
+        raise NotImplementedError
 
     def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
                       dtype) -> jax.Array:
@@ -981,12 +1010,33 @@ class DecoderStack:
             x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
                                            (*layer_pos, position_ids),
                                            head_layout=head_layout)
+        elif self._period:
+            x, aux = self._scan_periods(run, x, params)
         else:
             aux = None
             for key in self._layer_keys:
                 x, seg_aux = run(x, params[key])
                 aux = aux if seg_aux is None else seg_aux
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
+
+    def _scan_periods(self, run, x: jax.Array, params: Params):
+        """One scan over the periods of `_period`: the body runs each key's
+        layers of the period through `run` (the one layer skeleton under
+        the one remat policy), in the period's order. The aux comes back
+        one row a layer, in the order the layers ran."""
+        keys = [key for key, _ in self._period]
+
+        def period(z, layers):
+            auxs = []
+            for key in keys:
+                z, aux = run(z, layers[key])
+                auxs.append(aux)
+            return z, jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
+
+        x, aux = lax.scan(period, x, {key: params[key] for key in keys})
+        # (periods, layers a period, ...) -> (layers, ...)
+        return x, jax.tree.map(
+            lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), aux)
 
     def _head(self, params: Params, norm_params: Params, x: jax.Array,
               dtype, scope: "str | None" = "head_loss") -> jax.Array:

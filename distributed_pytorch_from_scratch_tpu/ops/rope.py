@@ -74,3 +74,18 @@ def apply_rotary_interleaved(x: jax.Array, cos: jax.Array,
     sin = sin[:, None].astype(x.dtype)
     return jnp.stack([a * cos - b * sin, b * cos + a * sin],
                      axis=-1).reshape(x.shape)
+
+
+def apply_rotary_leading(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                         rotary_dim: int) -> jax.Array:
+    """Partial RoPE: the first `rotary_dim` dimensions of every head of x
+    (b, heads, t, dim) turn, in the half-split layout (pairs `(x_i,
+    x_{i + rotary_dim/2})`, `rotate_half` over the slice); the rest pass
+    through untouched. cos/sin: (b, t, rotary_dim/2) (`rope_angles` of
+    `rotary_dim`)."""
+    half = rotary_dim // 2
+    cos = cos[:, None].astype(x.dtype)
+    sin = sin[:, None].astype(x.dtype)
+    a, b, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin, rest],
+                           axis=-1)

@@ -72,6 +72,14 @@ def _torch_linear_init(key: jax.Array, idim: int, odim: int) -> jax.Array:
     return jax.random.uniform(key, (idim, odim), jnp.float32, -bound, bound)
 
 
+def uniform_fan_in(key: jax.Array, shape, fan_in: int) -> jax.Array:
+    """`_torch_linear_init`'s distribution, Uniform(+-1/sqrt(fan_in)), for a
+    weight of any `shape` (a projection kept (d, heads, columns), a
+    depthwise convolution's taps)."""
+    bound = 1.0 / math.sqrt(fan_in)
+    return jax.random.uniform(key, shape, jnp.float32, -bound, bound)
+
+
 @dataclass(frozen=True)
 class ColumnParallelLinear:
     """Y = X @ W + b with W's output dim sharded over `axis`.

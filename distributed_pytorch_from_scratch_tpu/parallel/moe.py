@@ -239,10 +239,16 @@ CHUNK_SHARES = 6
 
 @dataclass(frozen=True)
 class SharedRoutedFFN:
-    """A sigmoid router over `num_experts` routed SwiGLU experts, of which
-    this job HOLDS `held` (experts [offset, offset + held)), plus shared
-    experts every token takes: the DeepSeek-V3 FFN, as one chip of an
+    """A router over `num_experts` routed SwiGLU experts, of which this job
+    HOLDS `held` (experts [offset, offset + held)), plus shared experts
+    every token takes: the DeepSeek-V3 FFN, as one chip of an
     expert-parallel deployment computes it between two all-to-alls.
+
+    Two facts a family states (fields, below the DeepSeek-V3 defaults):
+    `score` "softmax" scores by a softmax over all routed experts and has
+    no selection bias (no `bias` leaf); `shared_gate` multiplies the shared
+    expert's output by `sigmoid(x w_sg)`, one scalar a token (the leaf
+    `shared["gate_score"]`, d -> 1): Qwen3-Next's expert layer.
 
     Routing (float32): `s = sigmoid(x W_r)` over all routed experts; the
     `top_k` largest of `s + bias` are chosen (`bias` is the selection bias
@@ -290,9 +296,16 @@ class SharedRoutedFFN:
     scaling: float = 1.0
     tp_size: int = 1
     tp_axis: str = "tp"
+    score: str = "sigmoid"       # or "softmax" (class docstring)
+    shared_gate: bool = False
 
     def __post_init__(self):
         held = self.num_held
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score must be 'sigmoid' or 'softmax', got "
+                             f"{self.score!r}")
+        if self.shared_gate and not self.n_shared:
+            raise ValueError("shared_gate needs a shared expert")
         if not (0 <= self.offset and self.offset + held <= self.num_experts):
             raise ValueError(
                 f"held experts [{self.offset}, {self.offset + held}) are not "
@@ -326,11 +339,16 @@ class SharedRoutedFFN:
             "up": w(fold(key, "up"), (H, d, f), d),
             "down": w(fold(key, "down"), (H, f, d), f),
         }
+        if self.score == "softmax":
+            del p["bias"]
         if self.n_shared:
             fs = self.n_shared * f
             p["shared"] = {"gate": w(fold(key, "shared_gate"), (d, fs), d),
                            "up": w(fold(key, "shared_up"), (d, fs), d),
                            "down": w(fold(key, "shared_down"), (fs, d), fs)}
+            if self.shared_gate:
+                p["shared"]["gate_score"] = w(fold(key, "shared_gate_score"),
+                                              (d, 1), d)
         return p
 
     def specs(self) -> Params:
@@ -341,6 +359,10 @@ class SharedRoutedFFN:
         if self.n_shared:
             s["shared"] = {"gate": P(None, tp), "up": P(None, tp),
                            "down": P(tp, None)}
+            if self.shared_gate:
+                s["shared"]["gate_score"] = P(None, None)
+        if self.score == "softmax":
+            del s["bias"]
         return s
 
     # ---- routing ----
@@ -351,11 +373,15 @@ class SharedRoutedFFN:
         weights (S, k) float32. The router's product runs in float32 at
         precision "highest": a bf16 pass moves scores by 2^-9, which flips
         a top-k choice wherever two experts sit that close."""
-        s = jax.nn.sigmoid(jnp.dot(
-            xf.astype(jnp.float32), params["router"],
-            precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(s + lax.stop_gradient(params["bias"]),
-                              self.top_k)
+        logits = jnp.dot(xf.astype(jnp.float32), params["router"],
+                         precision=lax.Precision.HIGHEST)
+        if self.score == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, chosen = lax.top_k(s, self.top_k)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, chosen = lax.top_k(s + lax.stop_gradient(params["bias"]),
+                                  self.top_k)
         w = jnp.take_along_axis(s, chosen, axis=-1)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * self.scaling
         return chosen, w
@@ -467,7 +493,14 @@ class SharedRoutedFFN:
                 sp = params["shared"]
                 g = xd @ sp["gate"].astype(compute_dtype)
                 u = xd @ sp["up"].astype(compute_dtype)
-                y = y + (jax.nn.silu(g) * u) @ sp["down"].astype(compute_dtype)
+                out = (jax.nn.silu(g) * u) @ sp["down"].astype(compute_dtype)
+                if self.shared_gate:
+                    # the gate's product reads whole tokens on every tp
+                    # rank and scales this rank's partial sum
+                    gate = jax.nn.sigmoid(
+                        xd @ sp["gate_score"].astype(compute_dtype))
+                    out = out * gate
+                y = y + out
         y = reduce_from(y, self.tp_axis)
         return y.reshape(b, t, d), counters
 

@@ -5,6 +5,9 @@ LLama"): `scale * x * rsqrt(mean(x^2) + eps)`, f32 compute, cast back.
 LayerNorm (scale + bias, mean-centered) serves the GPT-2 model family
 (`models/gpt2.py`) — the reference has no GPT-2 family; this is a framework
 extension built on the same functional-module pattern. eps=1e-5 for both.
+`ZeroCenteredRMSNorm` (`x / rms * (1 + w)`, w from zeros) and `GatedRMSNorm`
+(`w * x / rms * silu(z)`, the gated delta rule's output norm) serve the
+`gdn_moe` family (`models/gdn_moe.py`).
 """
 
 from __future__ import annotations
@@ -57,3 +60,49 @@ class LayerNorm:
         normed = ((xf - mean) * jax.lax.rsqrt(var + self.eps)).astype(x.dtype)
         return (params["scale"].astype(x.dtype) * normed
                 + params["bias"].astype(x.dtype))
+
+
+def _rms_normed(x: jax.Array, eps: float) -> jax.Array:
+    """x / rms(x) over the last axis, float32."""
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+
+
+@dataclass(frozen=True)
+class ZeroCenteredRMSNorm:
+    """`x / rms(x) * (1 + w)` in float32, `w` from zeros (Qwen3-Next's
+    norms: the stored weight is the offset from one)."""
+
+    hdim: int
+    eps: float = 1e-6
+
+    def init(self, key: jax.Array) -> Params:
+        del key
+        return {"scale": jnp.zeros((self.hdim,), jnp.float32)}
+
+    def specs(self) -> Params:
+        return {"scale": P(None)}
+
+    def apply(self, params: Params, x: jax.Array) -> jax.Array:
+        return (_rms_normed(x, self.eps)
+                * (1.0 + params["scale"])).astype(x.dtype)
+
+
+@dataclass(frozen=True)
+class GatedRMSNorm:
+    """`w * x / rms(x) * silu(z)` in float32 over the last axis (one head),
+    `w` from ones: the plain norm, gated by `z` of x's shape."""
+
+    hdim: int
+    eps: float = 1e-6
+
+    def init(self, key: jax.Array) -> Params:
+        del key
+        return {"scale": jnp.ones((self.hdim,), jnp.float32)}
+
+    def specs(self) -> Params:
+        return {"scale": P(None)}
+
+    def apply(self, params: Params, x: jax.Array, z: jax.Array) -> jax.Array:
+        return (params["scale"] * _rms_normed(x, self.eps)
+                * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)

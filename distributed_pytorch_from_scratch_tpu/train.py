@@ -527,14 +527,15 @@ def train(args: argparse.Namespace) -> dict:
             vocab_size=vocab_size, maxlen=maxlen,
             compute_dtype="bfloat16" if args.bf16 else "float32")
         needs = family_class(args.family).config_extra
-        carries = "latent_moe" if cfg.latent_moe is not None else None
+        carries = cfg.family_facts
         if needs != carries:
             raise SystemExit(
                 f"--family {args.family} reads the config field {needs!r} "
                 f"and --model {args.model} carries {carries!r}: a family "
                 f"with facts of its own goes with a preset that has them "
-                f"(--family mla_moe --model tiny-mla-moe), and such a "
-                f"preset with no other family")
+                f"(--family mla_moe --model tiny-mla-moe, --family gdn_moe "
+                f"--model tiny-gdn-moe), and such a preset with no other "
+                f"family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
         zero_stage = resolve_zero_stage(args.zero, args.zero1)
@@ -870,10 +871,10 @@ def train(args: argparse.Namespace) -> dict:
             step_fn = build_train_step_multi(model, mesh, ocfg, args.loss_mode,
                                              **builder_kwargs)
         else:
-            # a family whose loss counts things (the mla_moe family's
-            # router) returns them with the plain step; logged below at the
-            # log interval, fetched with the loss
-            with_counters = (cfg.latent_moe is not None and zero_stage < 2
+            # a family whose loss counts things (the mla_moe and gdn_moe
+            # families' routers) returns them with the plain step; logged
+            # below at the log interval, fetched with the loss
+            with_counters = (cfg.family_facts is not None and zero_stage < 2
                              and not args.dp_reduce_bucket_mb)
             step_fn = build_train_step(model, mesh, ocfg, args.loss_mode,
                                        with_counters=with_counters,

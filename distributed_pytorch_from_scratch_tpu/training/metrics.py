@@ -166,6 +166,27 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
         return (6 * n * batch * seqlen
                 + 6 * attn_layers * batch * cfg.num_heads * seqlen * seqlen
                 * (lm.qk_head_dim + lm.v_head_dim))
+    gm = getattr(cfg, "gdn_moe", None)
+    if gm is not None:
+        # the gdn_moe family: the held experts at a token's mean share of
+        # them, as above; the embedding's lookup is no matmul; attention at
+        # the full T^2 in the full-attention layers only (q/k and v at
+        # `head_dim`); the chunked delta rule's own products
+        # (`ops/delta_rule.rule_flops_per_token`), forward and twice that
+        # backward
+        from ..ops.delta_rule import rule_flops_per_token
+        held = cfg.experts_held
+        n -= cfg.num_layers * (held - cfg.moe_top_k * held
+                               / cfg.num_experts) * (
+            3 * cfg.attn_dim * gm.moe_intermediate_size)
+        n -= cfg.vocab_size * cfg.attn_dim
+        full = cfg.num_layers // gm.full_attention_interval
+        rule = gm.linear_num_value_heads * rule_flops_per_token(
+            gm.linear_key_head_dim, gm.linear_value_head_dim)
+        return (6 * n * batch * seqlen
+                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
+                * gm.head_dim
+                + 3 * (cfg.num_layers - full) * rule * batch * seqlen)
     if getattr(cfg, "num_experts", 0):
         inactive = ((cfg.num_experts - cfg.moe_top_k)
                     * 3 * cfg.attn_dim * cfg.ffn_dim)
@@ -184,7 +205,7 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     max over mean, averaged over the expert layers (1.0 is balance)."""
     import numpy as np
 
-    lo = cfg.latent_moe.expert_offset
+    lo = cfg.expert_offset
     routed = np.asarray(counters["routed"])[:, lo:lo + cfg.experts_held]
     out = {"loss_main": float(counters["loss_main"])}
     if "loss_mtp" in counters:

@@ -19,6 +19,12 @@ flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = flags + " --xla_force_host_platform_device_count=8"
 
+# The CPU's `triangular_solve` (the chunked delta rule, ops/delta_rule.py) is
+# a LAPACK call into OpenBLAS, whose worker threads spin: six test workers
+# side by side made one 64 x 64 solve take 217 ms where it takes 0.16 alone
+# (PR 35). One thread a process, set before the library loads.
+os.environ.setdefault("OPENBLAS_NUM_THREADS", "1")
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -1,0 +1,446 @@
+"""The `gdn_moe` family (models/gdn_moe.py): Gated DeltaNet layers, a gated
+grouped-query full-attention layer closing every period, a softmax router
+over held experts with a gated shared expert, a scan over periods. CPU, tiny
+sizes, float32.
+
+* the program against the plain reference (models/vanilla_gdn_moe.py, which
+  LOOPS its eight layers and runs the rule token by token): loss and EVERY
+  gradient leaf, at tp 1 and tp 2, on a job that holds a slice of the
+  experts; no top-k choice sits on a tie (the margin is asserted);
+* the chunked rule against the token-by-token rule: outputs, final state and
+  all five gradients, at two chunk sizes, a ragged length, the decay's and
+  the write strength's extremes;
+* the flash kernel at width 256 and a group of 8 against the XLA path,
+  forward and backward, at a multi-block length;
+* partial RoPE; the two norms;
+* the share test and the forced router for the softmax router with a gated
+  shared expert;
+* what the family does not run is refused with a message;
+* the counts at the published widths (625,667,136 in all).
+"""
+
+import dataclasses
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_pytorch_from_scratch_tpu.config import (
+    GdnMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+from distributed_pytorch_from_scratch_tpu.models import build_model
+from distributed_pytorch_from_scratch_tpu.models.gdn_moe import param_counts
+from distributed_pytorch_from_scratch_tpu.models.vanilla_gdn_moe import (
+    vanilla_loss)
+from distributed_pytorch_from_scratch_tpu.ops.attention import (
+    causal_attention_xla)
+from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
+    delta_rule_recurrent, gated_delta_rule)
+from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
+    flash_attention)
+from distributed_pytorch_from_scratch_tpu.ops.rope import (
+    apply_rotary_leading, rope_angles)
+from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
+from distributed_pytorch_from_scratch_tpu.parallel.norm import (
+    GatedRMSNorm, ZeroCenteredRMSNorm)
+from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+from distributed_pytorch_from_scratch_tpu.training.metrics import (
+    model_flops_per_step, moe_counters_summary)
+from distributed_pytorch_from_scratch_tpu.training.optim import (
+    init_adam_state)
+from distributed_pytorch_from_scratch_tpu.training.train_step import (
+    build_train_step)
+
+
+def tiny(**facts):
+    cfg = model_preset("tiny-gdn-moe")
+    return dataclasses.replace(
+        cfg, gdn_moe=dataclasses.replace(cfg.gdn_moe, **facts))
+
+
+def batch(cfg, b=2, t=128, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
+    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
+    return ids[:, :-1], ids[:, 1:], pos
+
+
+def on_mesh(cfg, tp, **kw):
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+    return mesh, build_model("gdn_moe", cfg, tp_size=tp, **kw)
+
+
+# ---- the program against the plain reference ----
+
+@pytest.mark.parametrize("tp,impl", [(1, "xla"), (2, "xla"),
+                                     (1, "flash_interpret")])
+def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
+    """Two periods SCANNED (the program) against eight layers LOOPED (the
+    reference), the chunked rule against the token-by-token one, on a job
+    that holds experts 2..5 of 8. Leaves to 5e-5 of their largest entry:
+    the chunked rule sums a chunk's decays in another order than the
+    recurrence does, in float32 (1.8e-5 at the worst leaf)."""
+    cfg = tiny(experts_held=4, expert_offset=2)
+    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
+    assert model.periods == 2 and cfg.num_layers == 8
+    params = model.init(jax.random.key(3))
+    ids, tgt, pos = batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.jit(jax.value_and_grad(
+            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
+        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g)) == 36
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 5e-5 * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
+    # a softmax router has no selection bias
+    assert "bias" not in params["gdn_layers"]["moe"]
+    assert params["gdn_layers"]["gdn"]["w_qkvz"].shape[:2] == (2, 3)
+    assert params["attn_layers"]["attn"]["wq"].shape[:2] == (2, 1)
+
+
+def test_no_top_k_choice_sits_on_a_tie():
+    cfg = tiny()
+    moe = SharedRoutedFFN(cfg.attn_dim, 32, cfg.num_experts, cfg.moe_top_k,
+                          score="softmax", shared_gate=True)
+    p = moe.init(jax.random.key(3))
+    x = jax.random.normal(jax.random.key(4), (256, cfg.attn_dim))
+    with jax.default_matmul_precision("highest"):
+        s = np.sort(np.asarray(jax.nn.softmax(x @ p["router"])), axis=-1)
+    margin = s[:, -cfg.moe_top_k] - s[:, -cfg.moe_top_k - 1]
+    assert margin.min() > 1e-5
+
+
+# ---- the chunked rule against the token-by-token rule ----
+
+def rule_inputs(t, decay=1.0, beta_shift=0.0, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    b, h, dk, dv = 2, 3, 16, 8
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (b, h, t, dk))) / 4
+    k = unit(jax.random.normal(ks[1], (b, h, t, dk)))
+    v = jax.random.normal(ks[2], (b, h, t, dv))
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, h, t)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, t)) + beta_shift)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("t,chunk,decay,beta_shift", [
+    (128, 16, 1.0, 0.0), (128, 64, 1.0, 0.0),
+    (100, 64, 1.0, 0.0),            # a length that is no multiple of chunk
+    (128, 64, 16.0, 0.0),           # alpha near 0: exp(G) underflows
+    (128, 16, 1e-4, 6.0),           # alpha near 1, beta near 1
+])
+def test_the_chunked_rule_equals_the_token_by_token_rule(t, chunk, decay,
+                                                         beta_shift):
+    args = rule_inputs(t, decay, beta_shift)
+    scalar = lambda rule: lambda *a: (
+        lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S))(*rule(*a))
+    chunked = lambda *a: gated_delta_rule(*a, chunk=chunk)
+    with jax.default_matmul_precision("highest"):
+        o, S = chunked(*args)
+        o_want, S_want = delta_rule_recurrent(*args)
+        got = jax.grad(scalar(chunked), argnums=(0, 1, 2, 3, 4))(*args)
+        want = jax.grad(scalar(delta_rule_recurrent),
+                        argnums=(0, 1, 2, 3, 4))(*args)
+    rel = lambda a, b: float(jnp.max(jnp.abs(a - b))
+                             / jnp.maximum(jnp.max(jnp.abs(b)), 1e-9))
+    assert o.shape == (2, 3, t, 8) and S.shape == (2, 3, 16, 8)
+    assert np.all(np.isfinite(o)) and rel(o, o_want) < 1e-5
+    assert rel(S, S_want) < 1e-5
+    # with the decay at its cap a chunk's running sum of g reaches a
+    # thousand, whose float32 spacing is what is left of g's gradient
+    tol = 1e-4 if decay > 4 else 1e-5
+    for a, b in zip(got, want):
+        assert np.all(np.isfinite(a)) and rel(a, b) < tol
+
+
+# ---- the flash kernel at width 256 and a group of 8 ----
+
+def test_flash_at_width_256_and_a_group_of_8_equals_the_xla_path():
+    """16 query heads over 2 key-value heads, 256 wide, 384 tokens in
+    blocks of 128: a multi-block grid (the split backward, dk/dv summed
+    over the group of 8)."""
+    key = jax.random.key(0)
+    t = 384
+    q = jax.random.normal(jax.random.fold_in(key, 1), (1, 16, t, 256))
+    k = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, t, 256))
+    v = jax.random.normal(jax.random.fold_in(key, 3), (1, 2, t, 256))
+    blocks = dict.fromkeys(
+        ("block_q", "block_k", "bwd_block_q", "bwd_block_k"), 128)
+    flash = lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+        q, k, v, interpret=True, **blocks)))
+    plain = lambda q, k, v: jnp.sum(jnp.sin(causal_attention_xla(q, k, v)))
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(got[1], want[1]):
+        np.testing.assert_allclose(a, b, atol=5e-5)
+
+
+# ---- partial RoPE and the norms ----
+
+def test_partial_rope_turns_the_leading_slice_and_leaves_the_rest():
+    x = jax.random.normal(jax.random.key(0), (2, 3, 8, 16))
+    pos = jnp.tile(jnp.arange(8)[None], (2, 1))
+    cos, sin = rope_angles(pos, 4, 100.0)
+    got = np.asarray(apply_rotary_leading(x, cos, sin, 4))
+    np.testing.assert_array_equal(got[..., 4:], np.asarray(x[..., 4:]))
+    # half-split pairs (x_i, x_{i+2}) of the first four, as complex numbers
+    z = np.asarray(x[..., 0:2]) + 1j * np.asarray(x[..., 2:4])
+    theta = 100.0 ** (-np.arange(0, 4, 2) / 4)
+    w = z * np.exp(1j * np.arange(8)[None, None, :, None] * theta)
+    np.testing.assert_allclose(got[..., 0:2], w.real, atol=1e-5)
+    np.testing.assert_allclose(got[..., 2:4], w.imag, atol=1e-5)
+    np.testing.assert_array_equal(got[:, :, 0], np.asarray(x[:, :, 0]))
+
+
+def test_the_zero_centred_and_the_gated_norm():
+    x = jax.random.normal(jax.random.key(0), (4, 16)) * 3
+    z = jax.random.normal(jax.random.key(1), (4, 16))
+    rms = np.sqrt(np.mean(np.square(x), -1, keepdims=True) + 1e-6)
+    zc = ZeroCenteredRMSNorm(16)
+    p = zc.init(jax.random.key(2))
+    assert not np.any(p["scale"])                   # from zeros: (1 + w) = 1
+    np.testing.assert_allclose(zc.apply(p, x), x / rms, rtol=1e-5)
+    np.testing.assert_allclose(zc.apply({"scale": p["scale"] + 0.5}, x),
+                               1.5 * x / rms, rtol=1e-5)
+    gated = GatedRMSNorm(16)
+    w = gated.init(jax.random.key(2))
+    assert np.all(np.asarray(w["scale"]) == 1.0)
+    np.testing.assert_allclose(gated.apply(w, x, z),
+                               x / rms * jax.nn.silu(z), rtol=1e-5)
+
+
+# ---- the expert layer: shares, and no drop ----
+
+def apply_moe(moe, params, x):
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    from jax.sharding import PartitionSpec as P
+    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
+                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
+    return jax.jit(fn)(params, x)
+
+
+def gated_shared(p, x):
+    xf, sh = x.reshape(-1, x.shape[-1]), p["shared"]
+    out = ((jax.nn.silu(xf @ sh["gate"]) * (xf @ sh["up"])) @ sh["down"]
+           * jax.nn.sigmoid(xf @ sh["gate_score"]))
+    return out.reshape(x.shape)
+
+
+def test_the_sixteen_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Sixteen jobs hold two experts each of one layer's 32. Their routed
+    parts, plus the GATED shared expert once, are the layer a job holding
+    all 32 computes: the softmax weights are normalised over all chosen
+    experts, held or not."""
+    d, f, E = 32, 16, 32
+    whole = SharedRoutedFFN(d, f, E, top_k=5, score="softmax",
+                            shared_gate=True)
+    p = whole.init(jax.random.key(1))
+    assert "bias" not in p and p["shared"]["gate_score"].shape == (d, 1)
+    x = jax.random.normal(jax.random.key(2), (2, 64, d))
+    with jax.default_matmul_precision("highest"):
+        want, counters = apply_moe(whole, p, x)
+        shared_only = gated_shared(p, x)
+        total, rows = shared_only, 0.0
+        for lo in range(0, E, 2):
+            share = dataclasses.replace(whole, held=2, offset=lo)
+            ps = {**p, **{n: p[n][lo:lo + 2] for n in ("gate", "up", "down")}}
+            y, c = apply_moe(share, ps, x)
+            total = total + (y - shared_only)
+            rows += float(c["rows_here"])
+            np.testing.assert_array_equal(c["routed"], counters["routed"])
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert rows == float(counters["rows_here"]) == 2 * 64 * 5
+
+
+def test_a_softmax_router_forced_onto_the_same_experts_drops_nothing():
+    """Router columns that send EVERY token to experts 0..2, all held and
+    far over any mean share: each (token, choice) pair is computed, in
+    several chunks, and the layer equals the dense sum over those experts
+    with softmax weights renormalised over the three."""
+    d, f, E, k = 32, 16, 64, 3
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=4, score="softmax",
+                          shared_gate=True)
+    p = moe.init(jax.random.key(1))
+    x = jnp.abs(jax.random.normal(jax.random.key(2), (4, 214, d))) + 0.1
+    # positive tokens, and the first k columns large and positive
+    p["router"] = p["router"].at[:, :k].add(5.0)
+    assert moe.chunk_rows(4 * 214 * k) == 1024
+    with jax.default_matmul_precision("highest"):
+        got, counters = apply_moe(moe, p, x)
+        xf = x.reshape(-1, d)
+        s = jax.nn.softmax(xf @ p["router"], axis=-1)[:, :k]
+        w = s / jnp.sum(s, axis=-1, keepdims=True)
+        ffn = lambda g, u, dn: (jax.nn.silu(xf @ g) * (xf @ u)) @ dn
+        want = sum(w[:, e:e + 1] * ffn(p["gate"][e], p["up"][e], p["down"][e])
+                   for e in range(k))
+        want = want + gated_shared(p, x).reshape(-1, d)
+    assert float(counters["rows_here"]) == 4 * 214 * k
+    np.testing.assert_array_equal(
+        counters["routed"], np.where(np.arange(E) < k, 4 * 214, 0))
+    np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
+
+
+# ---- the step, its counters, the entry point ----
+
+def test_the_train_step_returns_a_row_of_counters_a_layer_and_the_loss_falls():
+    cfg = tiny()
+    mesh, model = on_mesh(cfg, 2)
+    params = jax.device_put(model.init(jax.random.key(0)),
+                            model.shardings(mesh))
+    opt = init_adam_state(params)
+    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
+    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
+                            with_counters=True)
+    ids, tgt, pos = batch(cfg, t=64)
+    losses = []
+    for _ in range(6):
+        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
+        losses.append(float(loss))
+    assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
+    # one row a layer, in the order the layers run (two periods of four)
+    assert c["routed"].shape == (8, 8) and c["rows_here"].shape == (8,)
+    np.testing.assert_array_equal(c["routed"].sum(-1), [2 * 64 * 2] * 8)
+    assert abs(float(c["loss_main"]) - losses[-1]) < 1e-5
+    summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
+    assert summary["rows_here_per_token"] == 2.0    # all experts held
+    assert summary["load_max_over_mean"] >= 1.0
+
+
+def test_train_cli_runs_the_family(tmp_path, capsys):
+    from chip_smoke import write_tokens
+    from distributed_pytorch_from_scratch_tpu import train as train_mod
+    tokens = tmp_path / "tokens.json"
+    write_tokens(str(tokens), 503, 16, 65)
+    train_mod.main([
+        "--family", "gdn_moe", "--model", "tiny-gdn-moe", "--tp_size", "2",
+        "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
+        "--batch_size", "4", "--maxlen", "64", "--max_steps", "4",
+        "--log_interval", "2", "--save_interval", "100",
+        "--warmup_steps", "2"])
+    out = capsys.readouterr().out
+    assert "model[gdn_moe]" in out and "rows_here_per_token" in out
+    events = [json.loads(line) for line in
+              open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
+    assert any(e.get("tag") == "moe_counters" for e in events)
+    with pytest.raises(SystemExit, match="reads the config field"):
+        train_mod.main(["--family", "mla_moe", "--model", "tiny-gdn-moe",
+                        "--data_path", str(tokens),
+                        "--save_dir", str(tmp_path / "x")])
+
+
+# ---- what is refused ----
+
+@pytest.mark.parametrize("kw,message", [
+    (dict(pp_size=2), "pp_size > 1"),
+    (dict(cp_size=2), "cp_size > 1"),
+    (dict(ep_size=2), "ep_size > 1"),
+    (dict(sequence_parallel=True), "sequence_parallel=True"),
+    (dict(tp_size=2, tp_overlap="ring"), "does not compose with MoE"),
+    (dict(attn_t_real=32), "attn_t_real"),
+    (dict(zero3_axis="dp"), "ZeRO stage 3"),
+    (dict(tp_size=4), "not divisible by tp_size"),   # 2 key-value heads
+])
+def test_the_model_refuses_what_it_does_not_run(kw, message):
+    with pytest.raises(ValueError, match=message):
+        build_model("gdn_moe", tiny(), **kw)
+
+
+@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
+                                dict(dp_reduce_bucket_mb=1.0)])
+def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
+    mesh, model = on_mesh(tiny(), 1)
+    with pytest.raises(ValueError, match="not made to work with the "
+                                         "GdnMoETransformer family"):
+        build_train_step(model, mesh, OptimizerConfig(), **kw)
+
+
+def test_decode_and_serving_refuse_the_family():
+    from distributed_pytorch_from_scratch_tpu.models.decode import (
+        GreedyDecoder, make_generate)
+    from distributed_pytorch_from_scratch_tpu.serving.engine import (
+        ContinuousBatchingEngine, PagedEngine)
+    mesh, model = on_mesh(tiny(), 1)
+    params = model.init(jax.random.key(0))
+    for build in (lambda: GreedyDecoder(model, mesh, 32),
+                  lambda: make_generate(model, mesh, 32),
+                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
+                                                   32, 1),
+                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
+        with pytest.raises(ValueError, match="cannot be decoded or served"):
+            build()
+
+
+@pytest.mark.parametrize("cfg,message", [
+    (ModelConfig(num_experts=8), "needs cfg.gdn_moe"),
+    (dataclasses.replace(model_preset("tiny-gdn-moe"), num_layers=6),
+     "whole periods"),
+])
+def test_a_family_needs_its_own_facts_and_whole_periods(cfg, message):
+    with pytest.raises(ValueError, match=message):
+        build_model("gdn_moe", cfg)
+
+
+# ---- the counts at the published widths ----
+
+def published(held=32, vocab=18992, layers=4):
+    return ModelConfig(
+        attn_dim=2048, ffn_dim=512, num_heads=16, num_kv_heads=2,
+        num_layers=layers, vocab_size=vocab, maxlen=8192, rope_theta=1e7,
+        num_experts=512, moe_top_k=10, gdn_moe=GdnMoEConfig(
+            head_dim=256, linear_num_key_heads=16, linear_num_value_heads=32,
+            linear_key_head_dim=128, linear_value_head_dim=128,
+            moe_intermediate_size=512, shared_expert_intermediate_size=512,
+            experts_held=held))
+
+
+def test_parameter_counts_at_the_published_widths():
+    """One chip's share (32 of 512 experts, an eighth of the vocabulary, one
+    period of three linear layers and one full layer): 625,667,136, as
+    `init` makes them."""
+    cfg = published()
+    parts = param_counts(cfg)
+    assert parts["gdn_layers"] == 3 * 138_582_208
+    assert parts["attn_layers"] == 132_127_232
+    assert parts["embedding_and_head"] == 77_791_232
+    assert cfg.num_params() == 625_667_136
+    model = build_model("gdn_moe", cfg)
+    assert model._mods["gdn"].num_params() == 33_718_464
+    assert model._mods["attn"].num_params() == 27_263_488
+    made = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
+    # uncut, a layer's FFN is 1,614,809,088
+    uncut = param_counts(published(held=None))
+    assert (uncut["attn_layers"] - 27_263_488 - 4096) == 1_614_809_088
+    # the step's FLOPs count the held experts at a token's mean share of
+    # them (10 x 32/512 = 0.625 a layer): 469 MFLOP a token forward with
+    # the scores counted causally, 536 with the full square the program's
+    # convention counts (4 x 16 heads x 8192 x 256 = 134 M, not 67)
+    flops = model_flops_per_step(cfg, 2, 8192, cfg.num_params())
+    assert abs(flops / (2 * 8192) / 3 / 536e6 - 1) < 0.01
+
+
+def test_remat_auto_picks_full_remat_for_the_benchmarks_cell(capsys):
+    """`remat="auto"` at the cell's shapes on a v5e's 15.75 GiB: rung
+    'true' (nothing beside the layer inputs is kept: the state is 7 GiB and
+    a snapshot of it must still fit), from an estimate of 13.4 GiB, where
+    the chip counted 13.68 GiB (in use + reserved) and the compiler's plan
+    for the described chip, an upper bound, 16.05 (PERF.md section 5)."""
+    from distributed_pytorch_from_scratch_tpu.training import memory
+    cfg = dataclasses.replace(published(), compute_dtype="bfloat16")
+    model = build_model("gdn_moe", cfg, remat_budget_gib=15.748)
+    layer_params = cfg.num_params() - 77_791_232 - 2048
+    memory.select_remat_traced.cache_clear()
+    assert memory.select_remat_traced(model, cfg.num_params(), layer_params,
+                                      2, 8192) == "true"
+    said = capsys.readouterr().err
+    estimate = float(said.split("true=")[1].split("GiB")[0])
+    assert 13.0 < estimate < 14.0, said

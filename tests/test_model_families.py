@@ -9,7 +9,8 @@ import re
 import jax
 import pytest
 
-from distributed_pytorch_from_scratch_tpu.config import (LatentMoEConfig,
+from distributed_pytorch_from_scratch_tpu.config import (GdnMoEConfig,
+                                                         LatentMoEConfig,
                                                          ModelConfig)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          DecoderStack,
@@ -29,10 +30,21 @@ LATENT = dict(q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
               num_nextn_predict_layers=1)
 
 
+# the gdn_moe family too: one period of four layers, 4 query heads over 2
+# key-value heads, all eight experts held ("dense") or a share of four
+GDN = dict(head_dim=16, linear_num_key_heads=2, linear_num_value_heads=4,
+           linear_key_head_dim=8, linear_value_head_dim=8,
+           moe_intermediate_size=16, shared_expert_intermediate_size=16)
+
+
 def config_for(family, config):
-    if FAMILIES[family].config_extra != "latent_moe":
-        return CONFIGS[config]
+    extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "gdn_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           gdn_moe=GdnMoEConfig(experts_held=held, **GDN))
+    if extra != "latent_moe":
+        return CONFIGS[config]
     return ModelConfig(num_experts=8, **TINY, latent_moe=LatentMoEConfig(
         experts_held=held, **LATENT))
 
@@ -53,7 +65,7 @@ def _shapes(model):
          pp_microbatches=2)], ids=["tp2", "pp2-interleaved"])
 def test_init_and_specs_have_the_same_tree(family, config, kw):
     cfg = config_for(family, config)
-    if kw.get("pp_size", 1) > 1 and cfg.latent_moe is not None:
+    if kw.get("pp_size", 1) > 1 and cfg.family_facts is not None:
         # a family with a layer pattern says so where it is built
         with pytest.raises(ValueError, match="pp_size > 1"):
             build_model(family, cfg, **kw)
@@ -86,13 +98,18 @@ def test_declared_facts_agree_with_the_tree(family):
     reads_input = [names for names in mlp_inputs
                    if any(names <= set(params[key])
                           for key in model._layer_keys)]
-    assert [len(names) for names in reads_input] == [cls.ffn_inputs]
+    # (a family with no dense MLP at all says 0 and has neither)
+    assert [len(names) for names in reads_input] == (
+        [cls.ffn_inputs] if cls.ffn_inputs else [])
     assert ("lm_head" not in params) == cls.tied_head
     assert ("pos_embedding" not in params) == cls.uses_rope
     # the decoder reads the projections by these names; a family whose
     # attention is another says it cannot be decoded
     projections = ("wq", "wk", "wv") if cls.decodable else ()
-    for key in (cls.attn_norm_key, cls.ffn_norm_key, "wo", *projections):
+    # (a family whose mixers hand back their own output keeps their
+    # projections inside the mixers' modules)
+    out = () if cls._own_mixer else ("wo",)
+    for key in (cls.attn_norm_key, cls.ffn_norm_key, *out, *projections):
         assert all(key in params[seg] for seg in model._layer_keys)
 
 
