@@ -37,6 +37,8 @@ from distributed_pytorch_from_scratch_tpu.ops.attention import (
     causal_attention_xla)
 from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
     delta_rule_recurrent, gated_delta_rule)
+from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+    delta_rule as rule_kernels)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
     flash_attention)
 from distributed_pytorch_from_scratch_tpu.ops.rope import (
@@ -118,46 +120,142 @@ def test_no_top_k_choice_sits_on_a_tie():
 
 # ---- the chunked rule against the token-by-token rule ----
 
-def rule_inputs(t, decay=1.0, beta_shift=0.0, seed=0):
+def rule_inputs(t, decay=1.0, beta_shift=0.0, seed=0, widths=(16, 8),
+                keys="random"):
     ks = jax.random.split(jax.random.key(seed), 5)
-    b, h, dk, dv = 2, 3, 16, 8
+    b, h = 2, 3
+    dk, dv = widths
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (b, h, t, dk))) / 4
     k = unit(jax.random.normal(ks[1], (b, h, t, dk)))
+    if keys == "collinear":     # one direction a head, either sign
+        k = k[:, :, :1] * jnp.where(jnp.arange(t) % 3 == 2, -1.0, 1.0)[:, None]
     v = jax.random.normal(ks[2], (b, h, t, dv))
     g = -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, h, t)))
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, h, t)) + beta_shift)
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("t,chunk,decay,beta_shift", [
-    (128, 16, 1.0, 0.0), (128, 64, 1.0, 0.0),
-    (100, 64, 1.0, 0.0),            # a length that is no multiple of chunk
-    (128, 64, 16.0, 0.0),           # alpha near 0: exp(G) underflows
-    (128, 16, 1e-4, 6.0),           # alpha near 1, beta near 1
+# the walk as the Pallas kernels, under the interpreter, at the widths they
+# hold: two heads a grid step for three heads (the last block hangs over),
+# two chunks a grid step, the state carried over several grid steps
+KERNELS = dict(widths=(128, 128), kernels=True)
+
+
+@pytest.mark.parametrize("t,chunk,decay,beta_shift,how", [
+    (128, 16, 1.0, 0.0, {}), (128, 64, 1.0, 0.0, {}),
+    (100, 64, 1.0, 0.0, {}),        # a length that is no multiple of chunk
+    (128, 64, 16.0, 0.0, {}),       # alpha near 0: exp(G) underflows
+    (128, 16, 1e-4, 6.0, {}),       # alpha near 1, beta near 1
+    # the solve: every key of a head the same direction, beta -> 1, hardly
+    # a decay: A is all ones under the diagonal, its powers grow as the
+    # binomials (A^32 passes 1e17) and (I + A)^-1 has two diagonals
+    (128, 64, 1e-4, 12.0, dict(keys="collinear")),
+    (200, 64, 1.0, 0.0, KERNELS),   # ragged; four chunks, two grid steps
+    (192, 64, 1.0, 0.0, KERNELS),   # three chunks: one a grid step
+    (256, 64, 16.0, 0.0, KERNELS),
+    (128, 16, 1e-4, 6.0, KERNELS),
+    (128, 64, 1e-4, 12.0, dict(KERNELS, keys="collinear")),
 ])
-def test_the_chunked_rule_equals_the_token_by_token_rule(t, chunk, decay,
-                                                         beta_shift):
-    args = rule_inputs(t, decay, beta_shift)
+def test_the_chunked_rule_equals_the_token_by_token_rule(
+        t, chunk, decay, beta_shift, how, monkeypatch):
+    how = dict(how)
+    kernels = how.pop("kernels", False)
+    monkeypatch.setattr(rule_kernels, "HEAD_BLOCK", 2)
+    args = rule_inputs(t, decay, beta_shift, **how)
+    dk, dv = args[0].shape[-1], args[2].shape[-1]
     scalar = lambda rule: lambda *a: (
         lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S))(*rule(*a))
-    chunked = lambda *a: gated_delta_rule(*a, chunk=chunk)
+    text = lambda *a: gated_delta_rule(*a, chunk=chunk)
+    chunked = (lambda *a: gated_delta_rule(*a, chunk=chunk, interpret=True)
+               ) if kernels else text
+    grads = lambda rule: jax.grad(scalar(rule), argnums=(0, 1, 2, 3, 4))(
+        *args)
     with jax.default_matmul_precision("highest"):
         o, S = chunked(*args)
         o_want, S_want = delta_rule_recurrent(*args)
-        got = jax.grad(scalar(chunked), argnums=(0, 1, 2, 3, 4))(*args)
-        want = jax.grad(scalar(delta_rule_recurrent),
-                        argnums=(0, 1, 2, 3, 4))(*args)
+        got, want = grads(chunked), grads(delta_rule_recurrent)
     rel = lambda a, b: float(jnp.max(jnp.abs(a - b))
                              / jnp.maximum(jnp.max(jnp.abs(b)), 1e-9))
-    assert o.shape == (2, 3, t, 8) and S.shape == (2, 3, 16, 8)
+    assert o.shape == (2, 3, t, dv) and S.shape == (2, 3, dk, dv)
     assert np.all(np.isfinite(o)) and rel(o, o_want) < 1e-5
     assert rel(S, S_want) < 1e-5
     # with the decay at its cap a chunk's running sum of g reaches a
     # thousand, whose float32 spacing is what is left of g's gradient
     tol = 1e-4 if decay > 4 else 1e-5
+    if how.get("keys") == "collinear":
+        # as beta -> 1 a write replaces all the state holds along the one
+        # key, so the decay's gradient vanishes (6e-5 at its largest where
+        # the others reach 5 to 35): what is left of it is the rounding of
+        # sums that size, and is held by their scale
+        top = max(float(jnp.max(jnp.abs(b))) for b in want)
+        assert float(jnp.max(jnp.abs(got[3] - want[3]))) < tol * top
+        got, want = got[:3] + got[4:], want[:3] + want[4:]
     for a, b in zip(got, want):
         assert np.all(np.isfinite(a)) and rel(a, b) < tol
+    if kernels:     # and the walk as the `lax.scan` it replaces
+        with jax.default_matmul_precision("highest"):
+            (o_text, S_text), g_text = text(*args), grads(text)
+        assert rel(o, o_text) < 1e-6 and rel(S, S_text) < 1e-6
+        # the same sums in another order: by the largest gradient's scale
+        top = max(float(jnp.max(jnp.abs(b))) for b in g_text)
+        for a, b in zip(grads(chunked), g_text):
+            assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(top, 1.0)
+
+
+def pallas_calls(jaxpr):
+    """(name, operands) of every `pallas_call` in a jaxpr, inner ones too
+    (a `custom_vjp_call`'s among them)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append((eqn.params["name"], len(eqn.invars)))
+            continue
+        for inner in jax.tree.leaves(
+                list(eqn.params.values()),
+                is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                found += pallas_calls(inner)
+    return found
+
+
+def rule_calls(*args, grad=False):
+    """The Mosaic calls of the rule (or its gradient) as it traces NOW: a
+    fresh function a call, because a trace is cached by its function."""
+    rule = lambda *a: gated_delta_rule(*a)
+    if grad:
+        rule = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a)[0]),
+                        (0, 1, 2, 3, 4))
+    return pallas_calls(jax.make_jaxpr(rule)(*args).jaxpr)
+
+
+def test_off_the_tpu_or_at_other_widths_the_rule_is_the_xla_text(
+        monkeypatch):
+    """The kernels engage from what the call sees: a TPU and widths that
+    are multiples of 128. Anything else lowers with no Mosaic call."""
+    wide, narrow = rule_inputs(128, widths=(128, 128)), rule_inputs(128)
+    assert jax.default_backend() != "tpu"
+    assert not rule_calls(*wide)
+    text = jax.jit(lambda *a: gated_delta_rule(*a)).lower(*wide).as_text()
+    assert "tpu_custom_call" not in text and "while" in text
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not rule_calls(*narrow)
+    assert len(rule_calls(*wide)) == 1
+    with pytest.raises(ValueError, match="multiples of 128"):
+        gated_delta_rule(*narrow, interpret=True)
+
+
+def test_the_rules_kernels_are_not_read_as_flash_calls(monkeypatch):
+    """benchmark/lib/kernels.py reads a Mosaic call named `flash_*`, or
+    with 3 or 6 operands, as a flash kernel's: the rule's are neither."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = rule_inputs(128, widths=(128, 128))
+    assert rule_calls(*args) == [("gdn_rule_fwd", 5)]
+    calls = rule_calls(*args, grad=True)
+    assert sorted(calls) == [("gdn_rule_bwd", 9), ("gdn_rule_fwd", 5)]
+    for name, operands in calls:
+        assert not name.startswith("flash_") and operands not in (3, 6)
 
 
 # ---- the flash kernel at width 256 and a group of 8 ----
