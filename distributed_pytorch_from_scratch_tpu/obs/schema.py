@@ -109,6 +109,10 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # (training/metrics.moe_counters_summary): the held experts' load as
     # max over mean, and the rows computed here per token and expert layer
     "moe_counters": ("load_max_over_mean", "rows_here_per_token"),
+    # -- ISSUE 37: `train()`'s step function built again after its steady
+    # program was in hand (a tail window, a new sequence bucket), at `step`;
+    # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)
+    "recompile": ("fun", "trace_s", "lower_s", "hit"),
 }
 
 

@@ -40,6 +40,7 @@ from .models import FAMILIES, build_model, family_class
 from .obs import TrainObserver, analyze_compiled, format_analysis
 from .obs.runindex import run_stamp
 from .ops.attention import resolve_attention_impl
+from .runtime import compile_cache
 from .runtime.compile_cache import compile_cache_stats, enable_compile_cache
 from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
                            process_info)
@@ -54,6 +55,11 @@ from .training.optim import init_adam_state, schedule_lr
 from .training.train_step import (build_grad_accum_step, build_train_step,
                                   build_train_step_multi, resolve_zero_stage)
 from .training.zero import zero1_moment_shardings
+
+
+# `recompile` events the record `train()` returns carries in full (the count
+# is of all of them; metrics.jsonl has every one)
+RECOMPILES_KEPT = 16
 
 
 def get_train_args(argv=None) -> argparse.Namespace:
@@ -936,10 +942,25 @@ def train(args: argparse.Namespace) -> dict:
         # "compile" span) and introspected once — cost_analysis FLOPs, bytes,
         # per-collective comm, peak HBM — then called directly each dispatch.
         # A compile failure (a Mosaic rejection lands here first) raises.
-        # Odd shapes (the max_steps tail window) go through the jit wrapper,
-        # whose recompile lands inside the "step" span.
+        # Odd shapes (the max_steps tail window) go through the jit wrapper:
+        # the "step" span that recompiles is as long as the build, and the
+        # build itself is a `recompile` event (below) and, on the timeline,
+        # `compile.*` spans inside that "step" (runtime/compile_cache.py).
         aot = {"shape": None, "fn": None, "compile_s": None,
                "collectives": None}
+        # The step function built again once its steady program is in hand,
+        # with the step the loop was at. The eager ops around a dispatch
+        # (`jnp.sum` over a shorter window's losses, the first log interval's
+        # schedule) are not the loop's program: the table of
+        # `compile_cache_stats()` and the timeline have them.
+        recompiles = []
+
+        def on_program(program):
+            if (aot["compile_s"] is not None
+                    and program["fun"] == step_fn.__name__):
+                recompiles.append({"step": n, **program})
+                writer.event("recompile", **recompiles[-1])
+                observer.instant("recompile", **recompiles[-1])
 
         def run_step(p, o, ids, tgt, pos, steps_in, step_no):
             # pin only the STEADY shape: a shrunk tail / partial epoch-end
@@ -1098,6 +1119,8 @@ def train(args: argparse.Namespace) -> dict:
         multi = accum > 1 or spd > 1
         host_wait, host_dispatches = 0.0, 0
         prefetcher = None  # closed in the finally on ANY exit (thread cleanup)
+        recompiles_logged = 0
+        compile_cache.subscribe(on_program)
         try:
             for epoch in range(start_epoch, max_epoch):
                 # One background thread assembles the NEXT dispatch's window
@@ -1215,10 +1238,15 @@ def train(args: argparse.Namespace) -> dict:
                         mem = device_memory_gib()
                         mem_s = (f"{mem:.2f} GiB" if mem is not None
                                  else "n/a (no memory stats)")
+                        rebuilt = recompiles[recompiles_logged:]
+                        recompiles_logged += len(rebuilt)
                         print(f"step {n}/{args.max_steps} -> avg loss {avg:.4f}, "
                               f"lr {float(lr):.8f}, {tps/1e3:.1f}k tok/s "
                               f"({useful*100:.0f}% useful), "
-                              f"{mfu_s}, mem {mem_s}")
+                              f"{mfu_s}, mem {mem_s}"
+                              + (f", {len(rebuilt)} recompile(s) at step "
+                                 f"{', '.join(str(r['step']) for r in rebuilt)}"
+                                 if rebuilt else ""))
                         writer.scalar("train/ce_loss", avg, n)
                         writer.scalar("train/lr", float(lr), n)
                         writer.scalar("train/tokens_per_sec", tps, n)
@@ -1296,6 +1324,7 @@ def train(args: argparse.Namespace) -> dict:
             # The observer closes here too, so a sentinel halt still leaves a
             # complete trace.json + goodput summary behind; the writer closes
             # last (the observer logs its summary through it).
+            compile_cache.unsubscribe(on_program)
             if prefetcher is not None:
                 prefetcher.close()
             shutdown.restore()
@@ -1359,6 +1388,8 @@ def train(args: argparse.Namespace) -> dict:
                "param_bytes_by_device": param_bytes_by_device(params),
                "hbm": hbm_watermarks(),
                "compile_cache": compile_cache_stats(),
+               "recompiles": {"count": len(recompiles),
+                              "events": recompiles[:RECOMPILES_KEPT]},
                **run_stamp(vars(args))}
         if advisor is not None:  # zero-cost off: no field when off
             out["control"] = advisor.summary()
