@@ -34,13 +34,22 @@ inside one chunk while the ratio between two near rows is an ordinary
 number. `S`, `G`, the solve and every sum are float32; the products'
 operands are `q`'s dtype (the model's compute dtype).
 
-The walk over the chunks, the part that reads `S`, is two Pallas kernels on
-a TPU at widths that are multiples of 128 (ops/pallas/delta_rule.py: the
-state stays in VMEM from a sequence's first chunk to its last; the backward
-is the reverse walk by hand, from the states the forward rule writes out,
-and JAX's transpose of the chunk-parallel text) and a `lax.scan`
-rematerialised by chunk everywhere else (its backward JAX's transpose of
-the whole text; its residuals the carried states, one a chunk).
+Where it is made. On a TPU at widths that are multiples of 128 the rule is
+two Pallas kernels (ops/pallas/delta_rule.py, `gdn_rule_fwd` and
+`gdn_rule_bwd`, a call for the whole batch's heads): XLA makes `G`, `A` and
+the inverse `T = (I + A)^-1` (`_unit_lower_inverse`: exact float32, nothing
+differentiates through it) and hands the kernels `q`, `k`, `v`, `[G; beta]`
+and `T`; the kernels make `[W | U] = T rhs`, `attn`, `q_in`, `k_out` and the
+decays per head and chunk in VMEM and walk the chunks with the state
+resident. The backward kernel makes a chunk's operands again from the same
+inputs, walks the chunks in reverse from the states the forward wrote out,
+and transposes the operands by hand, `A`'s lines among them: between
+forward and backward a head keeps its inputs, `T` and a state every other
+chunk, and no chunk-parallel array besides `A` and `T` ever exists in HBM.
+Everywhere else (off the TPU, other widths) the rule is the XLA text below,
+`_chunk_operands` and a `lax.scan` rematerialised by chunk (its backward
+JAX's transpose of the whole text; its residuals the carried states, one a
+chunk): the tests' oracle for the kernels.
 """
 
 from __future__ import annotations
@@ -53,8 +62,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from .collectives import copy_to
-from .pallas.delta_rule import (holds as kernels_hold, walk_backward,
-                                walk_forward)
+from .pallas.delta_rule import (ROWS, holds as kernels_hold, rule_backward,
+                                rule_forward, sequences_a_call)
 
 CHUNK = 64
 
@@ -99,28 +108,76 @@ def gated_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     length that is no multiple of `chunk` is padded with tokens that leave
     the state as it is (g = 0, beta = 0, k = 0) and cut off again.
 
-    The walk over the chunks is the Pallas kernels' on a TPU at a shape
-    they hold (`ops/pallas/delta_rule.holds`: widths that are multiples of
-    128) and a `lax.scan` everywhere else, decided here from what the call
-    sees; `interpret=True` asks for the kernels under the Pallas
+    The rule is the Pallas kernels' on a TPU at a shape they hold
+    (`ops/pallas/delta_rule.holds`: widths that are multiples of 128) and
+    the XLA text with a `lax.scan` everywhere else, decided here from what
+    the call sees; `interpret=True` asks for the kernels under the Pallas
     interpreter (the tests do, off the TPU).
 
-    ONE SEQUENCE AT A TIME (`lax.map` over b): what the rule holds between
-    its passes (`W`, `U`, the chunks' matrices, the carried states, and
-    their cotangents) is a sequence's and does not grow with the batch;
-    8192 tokens of 32 heads 128 wide hold about 2 GB that way. What the
-    kernels' forward leaves for their backward (a state a chunk and
-    `v_new`: 0.34 GB a sequence at that shape) is the batch's."""
+    The text runs ONE SEQUENCE AT A TIME (`lax.map` over b): what it holds
+    between its passes (`W`, `U`, the chunks' matrices, the carried states,
+    and their cotangents) is a sequence's and does not grow with the batch;
+    8192 tokens of 32 heads 128 wide hold about 2 GB that way. To the
+    kernels heads are all the same: a call takes the heads of as many
+    sequences as its scalar tables hold (`sequences_a_call`: the whole
+    batch of 2 x 8192 tokens), so that no sequence's inputs, outputs and
+    residuals are copied in and out of a loop (23 ms of a 700 ms step at
+    that shape); only `A` and the inverse's blocks, 0.6 GB a sequence, are
+    made a sequence at a time. What the kernels' forward leaves for their
+    backward (`T` and a state every other chunk: 0.27 GB a sequence at that
+    shape) is the batch's."""
     kernels = kernels_hold(q.shape[-1], v.shape[-1], chunk)
     if interpret and not kernels:
         raise ValueError(
             f"the delta rule's kernels do not hold d_k {q.shape[-1]}, d_v "
             f"{v.shape[-1]}, chunk {chunk}: widths must be multiples of 128")
-    if interpret or (kernels and jax.default_backend() == "tpu"):
-        one = functools.partial(_one_sequence_kernels, chunk, interpret)
-    else:
+    if not interpret and not (kernels and jax.default_backend() == "tpu"):
         one = jax.checkpoint(functools.partial(_one_sequence, chunk=chunk))
-    return lax.map(lambda row: one(*row), (q, k, v, g, beta))
+        return lax.map(lambda row: one(*row), (q, k, v, g, beta))
+    b, h, t, _ = q.shape
+    group = sequences_a_call(b, h, -(-t // chunk))
+    # (calls, a call's sequences x heads, ...)
+    fold = lambda z: z.reshape(b // group, group * h, *z.shape[2:])
+    call = functools.partial(_heads_kernels, chunk, interpret, group)
+    # the caller's fusions end here and begin again after: with no loop
+    # between them and the kernels, XLA otherwise lays the caller's float32
+    # intermediates out for the kernels' operands and copies them (7 ms of
+    # that step; half of it is left)
+    q, k, v, g, beta = lax.optimization_barrier((q, k, v, g, beta))
+    o, S = lax.map(lambda rows: call(*rows), tuple(map(fold, (q, k, v, g,
+                                                              beta))))
+    return lax.optimization_barrier((o.reshape(b, h, *o.shape[2:]),
+                                     S.reshape(b, h, *S.shape[2:])))
+
+
+def _in_chunks(q, k, v, g, beta, *, chunk: int):
+    """One sequence's inputs, q, k (h, t, d_k), v (h, t, d_v), g, beta (h,
+    t), as n chunks of C tokens, (h, n, C, ...), g and beta float32; the
+    length padded with tokens that leave the state as it is."""
+    h, t, _ = q.shape
+    pad = -t % chunk
+    if pad:
+        rows = lambda z: jnp.pad(z, ((0, 0), (0, pad))
+                                 + ((0, 0),) * (z.ndim - 2))
+        q, k, v, g, beta = map(rows, (q, k, v, g, beta))
+    chunks = lambda z: z.reshape(h, (t + pad) // chunk, chunk, *z.shape[2:])
+    return (chunks(q), chunks(k), chunks(v), chunks(g.astype(jnp.float32)),
+            chunks(beta.astype(jnp.float32)))
+
+
+def _chunk_matrix(k, G, beta):
+    """k (h, n, C, d_k) in the products' dtype, G (the running sum of g
+    inside a chunk) and beta (h, n, C) float32 -> (decay, k_beta, A): the
+    chunk's decays exp(G_i - G_j) on and under the diagonal, beta k, and A
+    (module docstring), float32."""
+    i = jnp.arange(G.shape[-1])
+    # exp of a masked difference: G_i - G_j <= 0 wherever i >= j
+    diff = G[..., :, None] - G[..., None, :]
+    decay = jnp.exp(jnp.where(i[:, None] >= i[None, :], diff, -jnp.inf))
+    k_beta = k.astype(jnp.float32) * beta[..., None]
+    A = jnp.where(i[:, None] > i[None, :],
+                  _dot(k.dtype, "hnik,hnjk->hnij", k_beta, k) * decay, 0.0)
+    return decay, k_beta, A
 
 
 def _chunk_operands(q, k, v, g, beta, *, chunk: int):
@@ -128,28 +185,11 @@ def _chunk_operands(q, k, v, g, beta, *, chunk: int):
     all chunks at once: q, k (h, t, d_k), v (h, t, d_v), g, beta (h, t) ->
     [W | U] (h, n, C, d_k + d_v), attn (h, n, C, C), q_in and k_out (h, n,
     C, d_k), G_end (h, n), float32, the length padded to n chunks of C."""
-    h, t, dk = q.shape
-    dtype = v.dtype
-    pad = -t % chunk
-    if pad:
-        rows = lambda z: jnp.pad(z, ((0, 0), (0, pad))
-                                 + ((0, 0),) * (z.ndim - 2))
-        q, k, v, g, beta = map(rows, (q, k, v, g, beta))
-    n = (t + pad) // chunk
-    chunks = lambda z: z.reshape(h, n, chunk, *z.shape[2:])
-    q, k, v = chunks(q), chunks(k), chunks(v)
-    g = chunks(g.astype(jnp.float32))
-    beta = chunks(beta.astype(jnp.float32))
-    dot = functools.partial(_dot, dtype)
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta, chunk=chunk)
+    dot = functools.partial(_dot, v.dtype)
 
     G = jnp.cumsum(g, axis=-1)                              # (h, n, C)
-    i = jnp.arange(chunk)
-    # exp of a masked difference: G_i - G_j <= 0 wherever i >= j
-    diff = G[..., :, None] - G[..., None, :]
-    decay = jnp.exp(jnp.where(i[:, None] >= i[None, :], diff, -jnp.inf))
-    k_beta = k.astype(jnp.float32) * beta[..., None]
-    A = jnp.where(i[:, None] > i[None, :],
-                  dot("hnik,hnjk->hnij", k_beta, k) * decay, 0.0)
+    decay, k_beta, A = _chunk_matrix(k, G, beta)
     rhs = jnp.concatenate([k_beta * jnp.exp(G)[..., None],
                            v.astype(jnp.float32) * beta[..., None]], axis=-1)
     WU = solve_unit_lower(A, rhs)
@@ -279,49 +319,69 @@ def _one_sequence(q, k, v, g, beta, *, chunk: int):
     return o[:, :t], S
 
 
-# ---- the walk as the Pallas kernels (ops/pallas/delta_rule.py) ----
+# ---- the rule as the Pallas kernels (ops/pallas/delta_rule.py) ----
 
-def _walk_operands(q, k, v, g, beta, *, chunk: int):
-    """`_chunk_operands` as the kernels take them: [W | U] float32 as the
-    solve leaves it, the other products' operands in the products' dtype,
-    the chunk's whole decay exp(G_end)."""
-    WU, attn, q_in, k_out, G_end = _chunk_operands(q, k, v, g, beta,
-                                                   chunk=chunk)
-    dtype = v.dtype
-    return (WU, attn.astype(dtype), q_in.astype(dtype), k_out.astype(dtype),
-            jnp.exp(G_end))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _one_sequence_kernels(chunk: int, interpret: bool, q, k, v, g, beta):
-    """`_one_sequence` with the walk as one kernel call. Its backward is by
-    hand for the walk alone (the reverse kernel, from the states and
-    `v_new` the forward rule wrote out) and JAX's for `_walk_operands`,
-    which it runs again: between forward and backward a sequence keeps its
-    inputs, a state a chunk and `v_new`, nothing chunk-parallel."""
-    return _kernels_fwd(chunk, interpret, q, k, v, g, beta)[0]
+def _kernel_inputs(q, k, v, g, beta, *, chunk: int):
+    """q ... beta (heads, t, .) as the kernels take them: q, k, v in chunks
+    and [G; beta] as the rows of one float32 tile a chunk, (heads, n,
+    `ROWS`, C), G the running sum of g inside a chunk."""
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta, chunk=chunk)
+    gb = jnp.pad(jnp.stack([jnp.cumsum(g, axis=-1), beta], axis=2),
+                 ((0, 0), (0, 0), (0, ROWS - 2), (0, 0)))
+    return q, k, v, gb
 
 
-def _kernels_fwd(chunk, interpret, q, k, v, g, beta, residuals=False):
+def _inverses(sequences: int, k, gb):
+    """T = (I + A)^-1 (heads, n, C, C) for the heads of `sequences`
+    sequences, from k and [G; beta] as `_kernel_inputs` leaves them, made
+    a sequence at a time (`A` and the inverse's blocks are 0.6 GB a
+    sequence of 32 heads and 128 chunks)."""
+    apart = lambda z: z.reshape(sequences, -1, *z.shape[1:])
+    T = lax.map(lambda one: _unit_lower_inverse(_chunk_matrix(*one)[2]),
+                (apart(k), apart(gb[:, :, 0]), apart(gb[:, :, 1])))
+    return T.reshape(-1, *T.shape[2:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _heads_kernels(chunk: int, interpret: bool, sequences: int, q, k, v, g,
+                   beta):
+    """`_one_sequence` as one kernel call, for the heads of `sequences`
+    sequences, (heads, t, .). Its backward is one more, by hand (the
+    operands made again in VMEM, the reverse walk from the states the
+    forward wrote out, the operands' transpose): between forward and
+    backward a head keeps its inputs, T and a state every other chunk."""
+    return _kernels_fwd(chunk, interpret, sequences, q, k, v, g, beta)[0]
+
+
+def _kernels_fwd(chunk, interpret, sequences, q, k, v, g, beta,
+                 residuals=False):
     h, t, _ = q.shape
-    o, S, *saved = walk_forward(
-        *_walk_operands(q, k, v, g, beta, chunk=chunk), out_dtype=v.dtype,
-        residuals=residuals, interpret=interpret)
-    return (o.reshape(h, -1, o.shape[-1])[:, :t], S), (q, k, v, g, beta,
-                                                       *saved)
+    inputs = _kernel_inputs(q, k, v, g, beta, chunk=chunk)
+    T = _inverses(sequences, inputs[1], inputs[3])
+    o, S, *S_in = rule_forward(*inputs, T, residuals=residuals,
+                               interpret=interpret)
+    return (o.reshape(h, -1, o.shape[-1])[:, :t], S), (q, k, v, g, beta, T,
+                                                       *S_in)
 
 
-def _kernels_bwd(chunk, interpret, saved, cotangents):
-    q, k, v, g, beta, S_in, v_new = saved
+def _kernels_bwd(chunk, interpret, sequences, saved, cotangents):
+    q, k, v, g, beta, T, S_in = saved
     do, dS = cotangents
-    operands, transpose = jax.vjp(
-        functools.partial(_walk_operands, chunk=chunk), q, k, v, g, beta)
-    h, n, C, _ = v_new.shape
-    do = jnp.pad(do, ((0, 0), (0, n * C - do.shape[1]), (0, 0)))
-    return transpose(walk_backward(
-        *operands, S_in, v_new, do.reshape(v_new.shape).astype(v_new.dtype),
-        dS, interpret=interpret))
+    h, t, _ = q.shape
+    n, C = T.shape[1:3]
+    # the inputs in chunks again (a pad and a reshape; G a cumsum): what
+    # the forward made of them was not kept
+    inputs = _kernel_inputs(q, k, v, g, beta, chunk=chunk)
+    do = jnp.pad(do, ((0, 0), (0, n * C - t), (0, 0))).reshape(
+        inputs[2].shape)
+    dq, dk, dv, dgb = rule_backward(*inputs, T, S_in, do.astype(v.dtype), dS,
+                                    interpret=interpret)
+    tokens = lambda z: z.reshape(h, n * C, *z.shape[3:])[:, :t]
+    # G is the running sum of g inside a chunk: its transpose runs back
+    dg = jnp.flip(jnp.cumsum(jnp.flip(dgb[:, :, 0], -1), axis=-1), -1)
+    return (tokens(dq), tokens(dk), tokens(dv), tokens(dg).astype(g.dtype),
+            tokens(dgb[:, :, 1]).astype(beta.dtype))
 
 
-_one_sequence_kernels.defvjp(
+_heads_kernels.defvjp(
     functools.partial(_kernels_fwd, residuals=True), _kernels_bwd)

@@ -7,27 +7,26 @@ chunk 64, ONE sequence: the rule runs a sequence at a time).
 prints, in device milliseconds from a profiler capture (the host clock
 around a call this short also reads the dispatch):
 
-  - the walk over the chunks as the Pallas kernels (ops/pallas/
-    delta_rule.py), forward, forward with residuals and backward, by the
-    kernels' names, for each `--blocks` (heads x chunks a grid step);
+  - the rule's Pallas kernels (ops/pallas/delta_rule.py: a chunk's
+    operands made in VMEM, then the walk), forward, forward with residuals
+    and backward, by the kernels' names, for each `--blocks` (heads x
+    chunks a grid step);
   - the whole rule for one sequence, forward and forward + backward, as the
     kernels' path and as the `lax.scan` text, with the scan's `while` ops
     and any XLA custom call apart;
-  - with `--knockouts`, the forward and backward kernels with one part of
-    the chunk step taken out (wrong numbers, right time): what the part
-    costs is the difference. The variants are built HERE, by replacing the
-    module's chunk-step functions; the program has no switch for them;
+  - with `--knockouts`, the kernels with one part of the chunk step taken
+    out (wrong numbers, right time): what the part costs is the
+    difference. The variants are built HERE, by replacing the module's
+    functions; the program has no switch for them;
+  - with `--check`, the kernels' path against the `lax.scan` text on the
+    chip (value, final state, all five gradients, at `--check_t` tokens):
+    Mosaic's numbers, which the interpreter's tests cannot see;
   - with `--solve`, (I + A)^-1 [W | U] alone: XLA's `triangular_solve`
     (what the rule had before PR 36) against `solve_unit_lower` at several
     base blocks and with its block products on the matrix unit.
 
-The readings behind the module's constants (PERF.md section 6, PR 36; one
-sequence, TPU v5 lite): the walk at 4x1 / 8x1 / 8x2 / 8x4 / 16x2 / 16x4
-heads x chunks a grid step 1.036 / 0.889 / 0.778 / 0.760 / 0.762 / 0.760
-ms forward, 1.999 / 1.766 / 1.687 / 1.674 / 1.667 / 1.706 backward; every
-knock-out within 0.1 ms of the whole (DMA alone 0.671 / 1.688): the DMA
-binds. The solve 4.31 ms as XLA's, 1.82 at base 8 (1.83 at 16, 1.84 at
-4), 4.64 with its block products on the matrix unit.
+The readings behind the module's constants are PERF.md's (section 6, PRs 36
+and 38; one sequence, TPU v5 lite).
 """
 
 import argparse
@@ -84,58 +83,96 @@ def named(ms, *parts):
     return sum(v for k, v in ms.items() if any(p in k for p in parts))
 
 
-def walk_operands(h, t, dk, dv, chunk, dtype, seed=0):
-    """Random operands of the walk at the shapes `_walk_operands` makes."""
-    n = t // chunk
-    keys = iter(jax.random.split(jax.random.key(seed), 9))
-    normal = lambda shape, dt, scale=1.0: (
-        scale * jax.random.normal(next(keys), shape, jnp.float32)).astype(dt)
-    WU = normal((h, n, chunk, dk + dv), jnp.float32, 0.1)
-    attn = normal((h, n, chunk, chunk), dtype, 0.1)
-    q_in = normal((h, n, chunk, dk), dtype, 0.1)
-    k_out = normal((h, n, chunk, dk), dtype, 0.1)
-    decay = jnp.exp(-jnp.abs(normal((h, n), jnp.float32)))
-    S_in = normal((h, n, dk, dv), jnp.float32)
-    v_new = normal((h, n, chunk, dv), dtype)
-    do = normal((h, n, chunk, dv), dtype)
-    dS = normal((h, dk, dv), jnp.float32)
-    return (WU, attn, q_in, k_out, decay), (S_in, v_new, do, dS)
-
-
-def time_walks(args, dtype, tag=""):
-    """The three kernel calls at the module's blocks as they stand."""
-    fwd_in, bwd_in = walk_operands(args.h, args.t, args.dk, args.dv,
-                                   args.chunk, dtype)
-    row = {}
-    for name, residuals in (("fwd", False), ("fwd_res", True)):
-        fn = jax.jit(lambda *a, r=residuals: kernels.walk_forward(
-            *a, out_dtype=dtype, residuals=r))
-        row[name] = named(capture_ms(fn, *fwd_in), kernels.FWD_NAME)
-    fn = jax.jit(lambda *a: kernels.walk_backward(*a))
-    row["bwd"] = named(capture_ms(fn, *fwd_in, *bwd_in), kernels.BWD_NAME)
-    print(f"  walk {tag:28s} fwd {row['fwd']:7.3f}  fwd+residuals "
-          f"{row['fwd_res']:7.3f}  bwd {row['bwd']:7.3f} ms", flush=True)
-    return row
-
-
-def time_rule(args, dtype):
-    """One sequence's whole rule, both paths, forward and with backward."""
-    keys = jax.random.split(jax.random.key(1), 5)
-    h, t, dk, dv = args.h, args.t, args.dk, args.dv
+def rule_inputs(h, t, dk, dv, dtype, seed=1):
+    """One sequence's q, k, v, g, beta as the layer makes them: unit keys,
+    queries scaled, (1, h, t, .)."""
+    keys = jax.random.split(jax.random.key(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = (unit(jax.random.normal(keys[0], (1, h, t, dk))) / dk ** 0.5)
     k = unit(jax.random.normal(keys[1], (1, h, t, dk)))
     v = jax.random.normal(keys[2], (1, h, t, dv))
     g = -jax.nn.softplus(jax.random.normal(keys[3], (1, h, t)))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, h, t)))
-    q, k, v = (z.astype(dtype) for z in (q, k, v))
-    paths = {
+    return (*(z.astype(dtype) for z in (q, k, v)), g, beta)
+
+
+def kernel_operands(args, dtype):
+    """The kernels' operands for one sequence, made as the rule makes them
+    (`_kernel_inputs`, `_inverses`: T is a real inverse), and random
+    residuals."""
+    h, t, dk, dv = args.h, args.t, args.dk, args.dv
+    def make(*a):
+        inputs = rule._kernel_inputs(*(z[0] for z in a), chunk=args.chunk)
+        return (*inputs, rule._inverses(1, inputs[1], inputs[3]))
+    inputs = jax.jit(make)(*rule_inputs(h, t, dk, dv, dtype))
+    n = inputs[0].shape[1]
+    keys = jax.random.split(jax.random.key(0), 3)
+    S_in = jax.random.normal(
+        keys[0], (h, n // kernels._blocks(h, n)[1], dk, dv), jnp.float32)
+    do = jax.random.normal(keys[1], (h, n, args.chunk, dv)).astype(dtype)
+    dS = jax.random.normal(keys[2], (h, dk, dv), jnp.float32)
+    return inputs, (S_in, do, dS)
+
+
+def time_kernels(args, dtype, tag="", operands=None):
+    """The three kernel calls at the module's blocks as they stand."""
+    fwd_in, bwd_in = operands or kernel_operands(args, dtype)
+    row = {}
+    for name, residuals in (("fwd", False), ("fwd_res", True)):
+        fn = jax.jit(lambda *a, r=residuals: kernels.rule_forward(
+            *a, residuals=r))
+        row[name] = named(capture_ms(fn, *fwd_in), kernels.FWD_NAME)
+    fn = jax.jit(lambda *a: kernels.rule_backward(*a))
+    row["bwd"] = named(capture_ms(fn, *fwd_in, *bwd_in), kernels.BWD_NAME)
+    print(f"  kernels {tag:30s} fwd {row['fwd']:7.3f}  fwd+residuals "
+          f"{row['fwd_res']:7.3f}  bwd {row['bwd']:7.3f} ms", flush=True)
+    return row
+
+
+def rule_paths(args):
+    """The whole rule as the program runs it on the chip and as the XLA
+    text with its `lax.scan`, which it runs everywhere else."""
+    return {
         "kernels": lambda *a: rule.gated_delta_rule(*a, chunk=args.chunk),
         "scan": lambda *a: jax.lax.map(
             lambda r: jax.checkpoint(lambda *s: rule._one_sequence(
-                *s, chunk=args.chunk))(*r), a),
-    }
-    for name, path in paths.items():
+                *s, chunk=args.chunk))(*r), a)}
+
+
+def check(args, dtype):
+    """The kernels' path against the `lax.scan` text, on the chip."""
+    a = rule_inputs(args.h, args.check_t, args.dk, args.dv, dtype, seed=2)
+    paths = rule_paths(args)
+    f32 = jnp.float32
+
+    def scalar(path):
+        def loss(*a):
+            o, S = path(*a)
+            o = o.astype(f32)
+            return jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S)
+        return loss
+    got, want = ((jax.jit(path)(*a), jax.jit(jax.grad(
+        scalar(path), argnums=(0, 1, 2, 3, 4)))(*a))
+        for path in (paths["kernels"], paths["scan"]))
+    rel = lambda x, y: float(
+        jnp.linalg.norm((x.astype(f32) - y.astype(f32)).ravel())
+        / jnp.maximum(jnp.linalg.norm(y.astype(f32).ravel()), 1e-30))
+    names = ("o", "S", "dq", "dk", "dv", "dg", "dbeta")
+    errs = {n: rel(x, y) for n, x, y in zip(
+        names, (*got[0], *got[1]), (*want[0], *want[1]))}
+    finite = all(bool(jnp.all(jnp.isfinite(x.astype(f32))))
+                 for x in (*got[0], *got[1]))
+    print(f"  check {dtype.name} t{args.check_t}: relative L2 against the "
+          f"scan text: " + ", ".join(f"{n} {e:.2e}" for n, e in errs.items())
+          + f"; finite {finite}", flush=True)
+
+
+def time_rule(args, dtype):
+    """One sequence's whole rule, both paths, forward and with backward."""
+    q, k, v, g, beta = rule_inputs(args.h, args.t, args.dk, args.dv, dtype)
+    paths = rule_paths(args)
+    for name in args.paths.split(","):
+        path = paths[name]
         loss = lambda *a, path=path: jnp.sum(
             path(*a)[0].astype(jnp.float32) ** 2)
         for what, fn in (("fwd", jax.jit(path)),
@@ -143,9 +180,9 @@ def time_rule(args, dtype):
                              loss, argnums=(0, 1, 2, 3, 4))))):
             ms = capture_ms(fn, q, k, v, g, beta, iters=3)
             top = sorted(((v_, k_) for k_, v_ in ms.items() if k_ != "busy"
-                          and not k_.startswith("while")), reverse=True)[:6]
+                          and not k_.startswith("while")), reverse=True)[:args.top]
             print(f"  rule {name:8s} {what:8s} busy {ms['busy']:8.3f} ms  "
-                  f"walk kernels {named(ms, 'gdn_rule_'):7.3f}  while "
+                  f"kernels {named(ms, 'gdn_rule_'):7.3f}  while "
                   f"{named(ms, 'while'):8.3f}  solve "
                   f"{named(ms, 'custom-call'):7.3f}  top: "
                   + ", ".join(f"{k_} {v_:.2f}" for v_, k_ in top),
@@ -197,42 +234,51 @@ def time_solves(args):
 
 def knockouts(args, dtype):
     """The kernels with one part of the chunk step taken out."""
-    fwd, bwd = kernels._fwd_chunk, kernels._bwd_chunk
-    dot, NN, TN, NT = kernels._dot, kernels._NN, kernels._TN, kernels._NT
+    fwd, bwd, dot32 = kernels._fwd_chunk, kernels._bwd_block, kernels._dot32
+    exp = jnp.exp
+    f32 = jnp.float32
 
-    def no_state_write(S, W, U, attn, q_in, k_out, e):
-        o, v_new, _ = fwd(S, W, U, attn, q_in, k_out, e)
-        return o, v_new, S
+    def fwd_dma(S, q, k, v, G, beta, g_end, e, T):       # DMA alone
+        yield       # a chain of one stretch, as `_in_turn` takes them
+        return v.astype(f32) + q[:, :1] + k[:, :1] + T[:, :1], S
 
-    def no_attn(S, W, U, attn, q_in, k_out, e):
-        Sb = S.astype(q_in.dtype)
-        v_new = (U - dot(W.astype(q_in.dtype), Sb, NN)).astype(q_in.dtype)
-        return dot(q_in, Sb, NN), v_new, e * S + dot(k_out, v_new, TN)
+    def bwd_dma(dS, S, chunks):
+        out = []
+        for q, k, v, do, G, beta, g_end, e, T in chunks:
+            x = q.astype(f32) + k + v[:, :1] + do[:, :1] + T[:, :1]
+            out.append((x, x, v.astype(f32) + do, G + S[:1, :1], beta))
+        yield       # a chain of one stretch, as `_in_turn` takes them
+        return out, dS
 
-    def no_products(S, W, U, attn, q_in, k_out, e):     # DMA alone
-        return U + q_in[:, :1] + k_out[:, :1] + W[:, :1] + attn[:, :1], \
-            U.astype(q_in.dtype), S
+    one_pass = lambda a, b, dims: kernels._dot(
+        a.astype(dtype), b.astype(dtype), dims)
 
-    def bwd_no_state(dS, S, W, attn, q_in, k_out, v_new, do, e):
-        *outs, _ = bwd(dS, S, W, attn, q_in, k_out, v_new, do, e)
-        return (*outs, dS)
-
-    def bwd_no_products(dS, S, W, attn, q_in, k_out, v_new, do, e):
-        f32 = jnp.float32
-        x = (do + v_new).astype(f32) + W + (q_in + k_out).astype(f32)
-        return (x, x, attn.astype(f32), x, x,
-                (dS * S)[:8], dS)
-
-    time_walks(args, dtype, "whole")
-    for name, f, b in (("no state update", no_state_write, bwd_no_state),
-                       ("no attn @ v_new (fwd)", no_attn, bwd),
-                       ("no products: DMA alone", no_products,
-                        bwd_no_products)):
-        kernels._fwd_chunk, kernels._bwd_chunk = f, b
+    operands = kernel_operands(args, dtype)
+    time_kernels(args, dtype, "whole", operands)
+    kernels._fwd_chunk, kernels._bwd_block = fwd_dma, bwd_dma
+    try:
+        time_kernels(args, dtype, "DMA alone", operands)
+    finally:
+        kernels._fwd_chunk, kernels._bwd_block = fwd, bwd
+    # T rhs and its two transposes as one bf16 pass, and as nothing
+    for name, dot in (("HIGHEST products in one pass", one_pass),
+                      ("no T rhs (no HIGHEST product)",
+                       lambda a, b, dims: a[:, :a.shape[0]]
+                       if dims == kernels._NT else b)):
+        kernels._dot32 = dot
         try:
-            time_walks(args, dtype, name)
+            time_kernels(args, dtype, name, operands)
+        except Exception as e:  # noqa: BLE001 - a knock-out Mosaic refuses
+            print(f"  kernels {name} FAILED {type(e).__name__}: "
+                  f"{str(e)[-300:]!r}", flush=True)
         finally:
-            kernels._fwd_chunk, kernels._bwd_chunk = fwd, bwd
+            kernels._dot32 = dot32
+    # the decays: no exponential (the masks and products stay)
+    jnp.exp = lambda x: x
+    try:
+        time_kernels(args, dtype, "no decay (exp taken out)", operands)
+    finally:
+        jnp.exp = exp
 
 
 def parse_args(argv=None):
@@ -246,12 +292,23 @@ def parse_args(argv=None):
     ap.add_argument("--blocks", default=None,
                     help="comma-separated heads x chunks a grid step to "
                          "sweep (8x2,8x4,16x1); default: the module's")
+    ap.add_argument("--turns", default=None,
+                    help="comma-separated heads whose backward chains are "
+                         "traced side by side (1,2,4,8); default: the "
+                         "module's")
     ap.add_argument("--knockouts", action="store_true")
+    ap.add_argument("--check", action="store_true",
+                    help="the kernels' path against the scan text's numbers")
+    ap.add_argument("--check_t", type=int, default=1000)
     ap.add_argument("--solve", action="store_true",
                     help="time the solve alone, its variants swept")
-    ap.add_argument("--no_walks", action="store_true")
+    ap.add_argument("--no_kernels", action="store_true")
+    ap.add_argument("--paths", default="kernels,scan",
+                    help="which whole rules to time")
+    ap.add_argument("--top", type=int, default=6,
+                    help="how many of the whole rule's longest ops to name")
     ap.add_argument("--no_rule", action="store_true",
-                    help="the walks alone, not the whole rule")
+                    help="the kernels alone, not the whole rule")
     return ap.parse_args(argv)
 
 
@@ -264,21 +321,29 @@ def main():
     dtype = jnp.dtype(args.dtype)
     print(f"device: {jax.devices()[0].device_kind}; h{args.h} t{args.t} "
           f"{args.dk}/{args.dv} chunk {args.chunk} {dtype.name}", flush=True)
+    if args.check:
+        for check_dtype in (dtype, jnp.dtype("float32")):
+            check(args, check_dtype)
     if args.solve:
         time_solves(args)
     was = kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK
-    for blocks in ([] if args.no_walks else args.blocks.split(",")
+    for blocks in ([] if args.no_kernels else args.blocks.split(",")
                    if args.blocks else [None]):
         if blocks:
             kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK = map(
                 int, blocks.split("x"))
         try:
-            time_walks(args, dtype, f"blocks {kernels.HEAD_BLOCK}x"
-                                    f"{kernels.CHUNK_BLOCK}")
+            time_kernels(args, dtype, f"blocks {kernels.HEAD_BLOCK}x"
+                                      f"{kernels.CHUNK_BLOCK}")
         except Exception as e:  # noqa: BLE001 - Mosaic refuses a block
             print(f"  blocks {blocks} FAILED {type(e).__name__}: "
                   f"{str(e)[-300:]!r}", flush=True)
     kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK = was
+    was = kernels.HEADS_IN_TURN
+    for turns in (args.turns.split(",") if args.turns else []):
+        kernels.HEADS_IN_TURN = int(turns)
+        time_kernels(args, dtype, f"{turns} heads in turn")
+    kernels.HEADS_IN_TURN = was
     if args.knockouts:
         knockouts(args, dtype)
     if not args.no_rule:

@@ -136,9 +136,12 @@ def rule_inputs(t, decay=1.0, beta_shift=0.0, seed=0, widths=(16, 8),
     return q, k, v, g, beta
 
 
-# the walk as the Pallas kernels, under the interpreter, at the widths they
-# hold: two heads a grid step for three heads (the last block hangs over),
-# two chunks a grid step, the state carried over several grid steps
+# the rule as the Pallas kernels (a chunk's operands made in the kernel, the
+# walk, and the backward's transpose by hand), under the interpreter, at the
+# widths they hold: four heads a grid step for the six heads of two sequences
+# (one call takes both; the last block hangs over), their chains traced two
+# at a time, two chunks a grid step, the state carried over several grid
+# steps
 KERNELS = dict(widths=(128, 128), kernels=True)
 
 
@@ -156,12 +159,15 @@ KERNELS = dict(widths=(128, 128), kernels=True)
     (256, 64, 16.0, 0.0, KERNELS),
     (128, 16, 1e-4, 6.0, KERNELS),
     (128, 64, 1e-4, 12.0, dict(KERNELS, keys="collinear")),
+    (128, 64, 16.0, 12.0, KERNELS),  # the decay at its cap, beta near 1
+    (128, 64, 1.0, 0.0, dict(KERNELS, widths=(128, 256))),  # d_k != d_v
 ])
 def test_the_chunked_rule_equals_the_token_by_token_rule(
         t, chunk, decay, beta_shift, how, monkeypatch):
     how = dict(how)
     kernels = how.pop("kernels", False)
-    monkeypatch.setattr(rule_kernels, "HEAD_BLOCK", 2)
+    monkeypatch.setattr(rule_kernels, "HEAD_BLOCK", 4)
+    monkeypatch.setattr(rule_kernels, "HEADS_IN_TURN", 2)
     args = rule_inputs(t, decay, beta_shift, **how)
     dk, dv = args[0].shape[-1], args[2].shape[-1]
     scalar = lambda rule: lambda *a: (
@@ -193,41 +199,53 @@ def test_the_chunked_rule_equals_the_token_by_token_rule(
         got, want = got[:3] + got[4:], want[:3] + want[4:]
     for a, b in zip(got, want):
         assert np.all(np.isfinite(a)) and rel(a, b) < tol
-    if kernels:     # and the walk as the `lax.scan` it replaces
+    if kernels:     # and the XLA text with its `lax.scan` that they replace
         with jax.default_matmul_precision("highest"):
             (o_text, S_text), g_text = text(*args), grads(text)
-        assert rel(o, o_text) < 1e-6 and rel(S, S_text) < 1e-6
+        # the kernels multiply T rhs in bfloat16 pieces, six passes, as
+        # `HIGHEST` does on the chip; the text's product here is float32's
+        assert rel(o, o_text) < 2e-6 and rel(S, S_text) < 2e-6
         # the same sums in another order: by the largest gradient's scale
         top = max(float(jnp.max(jnp.abs(b))) for b in g_text)
         for a, b in zip(grads(chunked), g_text):
             assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(top, 1.0)
 
 
-def pallas_calls(jaxpr):
-    """(name, operands) of every `pallas_call` in a jaxpr, inner ones too
-    (a `custom_vjp_call`'s among them)."""
-    found = []
+def eqns_outside_kernels(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (a
+    `custom_vjp_call`'s among them), a `pallas_call`'s body left out."""
     for eqn in jaxpr.eqns:
+        yield eqn
         if eqn.primitive.name == "pallas_call":
-            found.append((eqn.params["name"], len(eqn.invars)))
             continue
         for inner in jax.tree.leaves(
                 list(eqn.params.values()),
                 is_leaf=lambda x: hasattr(x, "eqns") or hasattr(x, "jaxpr")):
             inner = getattr(inner, "jaxpr", inner)
             if hasattr(inner, "eqns"):
-                found += pallas_calls(inner)
-    return found
+                yield from eqns_outside_kernels(inner)
+
+
+def pallas_calls(jaxpr):
+    """(name, operands) of every `pallas_call` in a jaxpr, inner ones
+    too."""
+    return [(eqn.params["name"], len(eqn.invars))
+            for eqn in eqns_outside_kernels(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
 
 
 def rule_calls(*args, grad=False):
     """The Mosaic calls of the rule (or its gradient) as it traces NOW: a
     fresh function a call, because a trace is cached by its function."""
+    return pallas_calls(rule_jaxpr(*args, grad=grad))
+
+
+def rule_jaxpr(*args, grad=False):
     rule = lambda *a: gated_delta_rule(*a)
     if grad:
         rule = jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a)[0]),
                         (0, 1, 2, 3, 4))
-    return pallas_calls(jax.make_jaxpr(rule)(*args).jaxpr)
+    return jax.make_jaxpr(rule)(*args).jaxpr
 
 
 def test_off_the_tpu_or_at_other_widths_the_rule_is_the_xla_text(
@@ -251,11 +269,98 @@ def test_the_rules_kernels_are_not_read_as_flash_calls(monkeypatch):
     with 3 or 6 operands, as a flash kernel's: the rule's are neither."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     args = rule_inputs(128, widths=(128, 128))
-    assert rule_calls(*args) == [("gdn_rule_fwd", 5)]
+    assert rule_calls(*args) == [("gdn_rule_fwd", 7)]
     calls = rule_calls(*args, grad=True)
-    assert sorted(calls) == [("gdn_rule_bwd", 9), ("gdn_rule_fwd", 5)]
+    assert sorted(calls) == [("gdn_rule_bwd", 10), ("gdn_rule_fwd", 7)]
     for name, operands in calls:
         assert not name.startswith("flash_") and operands not in (3, 6)
+
+
+def test_on_the_kernel_path_no_chunk_parallel_product_is_left_to_xla(
+        monkeypatch):
+    """The mechanism engaged: `[W | U]` is made in the kernels and the XLA
+    text of it is gone, not run beside them. The gradient's jaxpr on the
+    kernel path has no `dot_general` outside a `pallas_call` over an (h, n,
+    C, d_k + d_v) array, and none at `HIGHEST` (the solve's product and its
+    two cotangents were); what XLA still multiplies is `A`'s k k^T. The
+    XLA text has all three: the guard reads what it should."""
+    h, C, (dk, dv) = 3, 64, (128, 256)
+    args = rule_inputs(128, widths=(dk, dv))
+    wide = lambda eqn: [v.aval.shape for v in eqn.invars + eqn.outvars
+                        if v.aval.shape[-2:] == (C, dk + dv)]
+    highest = lambda eqn: "HIGHEST" in str(eqn.params["precision"])
+    dots = lambda jaxpr: [eqn for eqn in eqns_outside_kernels(jaxpr)
+                          if eqn.primitive.name == "dot_general"]
+    text = dots(rule_jaxpr(*args, grad=True))
+    assert sum(map(bool, map(wide, text))) >= 3 <= sum(map(highest, text))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernels = dots(rule_jaxpr(*args, grad=True))
+    assert kernels and not any(map(wide, kernels))
+    assert not any(map(highest, kernels))
+    # a sequence at a time (the kernels take both sequences' heads at once)
+    assert {eqn.outvars[0].aval.shape for eqn in kernels} == {(h, 2, C, C)}
+    assert {eqn.invars[2].aval.shape for eqn in eqns_outside_kernels(
+        rule_jaxpr(*args)) if eqn.primitive.name == "pallas_call"} == {
+            (2 * h, 2, C, dk)}
+
+
+@pytest.mark.parametrize("right,dims,passes", [
+    (jnp.float32, "NN", 6), (jnp.float32, "NT", 6), (jnp.bfloat16, "NN", 3)])
+def test_the_kernels_float32_products_reach_float32s_accuracy(
+        right, dims, passes, monkeypatch):
+    """`_dot32` multiplies bfloat16 pieces (what the matrix unit takes):
+    six stacked passes of two float32 sides, three where the right side is
+    bfloat16 and so exact, either way within a few float32 roundings of
+    the sum of magnitudes (one bfloat16 pass is at 4e-3, three of two
+    float32 sides at 1e-5); the sizes span many binades, as the inverse's
+    entries do against a decayed key."""
+    ks = jax.random.split(jax.random.key(0), 4)
+    a = jax.random.normal(ks[0], (64, 64)) * jnp.exp(
+        6 * jax.random.normal(ks[1], (64, 64)))
+    b = (jax.random.normal(ks[2], (64, 256)) * jnp.exp(
+        6 * jax.random.normal(ks[3], (64, 1)))).astype(right)
+    if dims == "NT":
+        a, b = jax.random.normal(ks[0], (64, 256)), b.astype(jnp.float32)
+    calls = []
+    dot = rule_kernels._dot
+    monkeypatch.setattr(rule_kernels, "_dot", lambda x, y, d: (
+        calls.append((x.shape, x.dtype, y.dtype)), dot(x, y, d))[1])
+    got = rule_kernels._dot32(a, b, getattr(rule_kernels, "_" + dims))
+    exact = np.asarray(a, np.float64) @ (
+        np.asarray(b.astype(jnp.float32), np.float64).T if dims == "NT"
+        else np.asarray(b.astype(jnp.float32), np.float64))
+    scale = np.abs(np.asarray(a, np.float64)) @ np.abs(
+        np.asarray(b.astype(jnp.float32), np.float64).T if dims == "NT"
+        else np.asarray(b.astype(jnp.float32), np.float64))
+    assert np.max(np.abs(np.asarray(got, np.float64) - exact) / scale) < 1e-6
+    # every pass bfloat16 x bfloat16, the left side's pieces stacked
+    assert all(x == y == jnp.bfloat16 for _, x, y in calls)
+    assert sum(shape[0] // 64 for shape, _, _ in calls) == passes
+    assert len(calls) == (1 if right == jnp.bfloat16 else 3)
+
+
+def test_a_batch_past_one_calls_tables_is_walked_a_call_at_a_time(
+        monkeypatch):
+    """A call takes the heads of as many sequences as its two scalar tables
+    hold (`sequences_a_call`); past that the batch goes a call at a time,
+    to the same value and gradients."""
+    args = rule_inputs(128, widths=(128, 128))
+    assert rule_kernels.sequences_a_call(2, 32, 128) == 2
+    assert rule_kernels.sequences_a_call(8, 32, 128) == 4
+    assert rule_kernels.sequences_a_call(6, 32, 128) == 3
+    assert rule_kernels.sequences_a_call(5, 32, 128) == 1
+    assert rule_kernels.sequences_a_call(2, 32, 1024) == 1
+    rule = lambda *a: gated_delta_rule(*a, interpret=True)
+    loss = lambda *a: (lambda o, S: jnp.sum(o * jnp.cos(o))
+                       + jnp.sum(S * S))(*rule(*a))
+    run = lambda: (rule(*args), jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
+        *args), [c for c in pallas_calls(jax.make_jaxpr(rule)(*args).jaxpr)])
+    (o, S), grads, calls = run()
+    monkeypatch.setattr(rule_kernels, "TABLE_SCALARS", 3 * 2)  # one sequence
+    (o1, S1), grads1, calls1 = run()
+    assert calls == calls1 == [("gdn_rule_fwd", 7)]     # there inside a loop
+    for a, b in zip((o, S, *grads), (o1, S1, *grads1)):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
 
 
 # ---- the flash kernel at width 256 and a group of 8 ----

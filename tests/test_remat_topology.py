@@ -219,11 +219,11 @@ def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
 
 def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
         topo, described_tpu):
-    """Mosaic takes the rule's two walks (PR 36) at one sequence of cell 6
-    (32 heads, 8192 tokens in chunks of 64, 128 / 128, bf16) at the module's
-    blocks; the compiled calls carry the names and operand counts that keep
-    them out of the benchmark's flash patterns (`^flash_`, 3 or 6
-    operands)."""
+    """Mosaic takes the rule's two kernels (PR 36; since PR 38 they make a
+    chunk's operands in VMEM too) at one sequence of cell 6 (32 heads, 8192
+    tokens in chunks of 64, 128 / 128, bf16) at the module's blocks; the
+    compiled calls carry the names and operand counts that keep them out of
+    the benchmark's flash patterns (`^flash_`, 3 or 6 operands)."""
     from jax.sharding import SingleDeviceSharding
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         delta_rule as rule)
@@ -232,20 +232,20 @@ def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
     arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
                                                      sharding=chip)
     bf16, f32 = jnp.bfloat16, jnp.float32
-    operands = (arg(f32, h, n, C, dk + dv), arg(bf16, h, n, C, C),
-                arg(bf16, h, n, C, dk), arg(bf16, h, n, C, dk),
-                arg(f32, h, n))
-    saved = (arg(f32, h, n, dk, dv), arg(bf16, h, n, C, dv),
-             arg(bf16, h, n, C, dv), arg(f32, h, dk, dv))
+    inputs = (arg(bf16, h, n, C, dk), arg(bf16, h, n, C, dk),
+              arg(bf16, h, n, C, dv), arg(f32, h, n, rule.ROWS, C),
+              arg(f32, h, n, C, C))
+    # one state a grid step's two chunks
+    saved = (arg(f32, h, n // 2, dk, dv), arg(bf16, h, n, C, dv),
+             arg(f32, h, dk, dv))
     calls = []
     for fn, args in (
-            (lambda *a: rule.walk_forward(*a, out_dtype=bf16,
-                                          residuals=True), operands),
-            (rule.walk_backward, operands + saved)):
+            (lambda *a: rule.rule_forward(*a, residuals=True), inputs),
+            (rule.rule_backward, inputs + saved)):
         text = jax.jit(fn).lower(*args).compile().as_text()
         calls += re.findall(
             r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
             r'custom_call_target="tpu_custom_call"', text)
     assert [(name.split(".")[0], operands.count("%"))
-            for name, operands in calls] == [("gdn_rule_fwd", 5),
-                                             ("gdn_rule_bwd", 9)]
+            for name, operands in calls] == [("gdn_rule_fwd", 7),
+                                             ("gdn_rule_bwd", 10)]
