@@ -109,6 +109,31 @@ class GdnMoEConfig:
 
 
 @dataclass(frozen=True)
+class ConvMoEConfig:
+    """What the `conv_moe` family (models/conv_moe.py) needs beyond
+    `ModelConfig`'s own fields: which layers mix by a gated short
+    convolution and which by grouped-query attention with q/k norms, how
+    many leading layers keep a dense SwiGLU, and a sigmoid top-k router
+    with a selection bias and no shared expert over routed experts of which
+    this job may hold a slice. The keys are LFM2's `config.json` names
+    (`lfm2_moe`). In `ModelConfig`, `attn_dim` is the model width,
+    `num_heads` / `num_kv_heads` the attention layers' heads (head width
+    `attn_dim / num_heads`), `num_layers` = `len(layer_types)`, `ffn_dim`
+    the leading dense layers' SwiGLU width, `num_experts` the ROUTED
+    experts the router scores and `moe_top_k` the experts a token takes."""
+
+    layer_types: tuple              # "conv" | "full_attention", a layer each
+    moe_intermediate_size: int
+    num_dense_layers: int = 2
+    conv_L_cache: int = 3           # the convolution's taps
+    routed_scaling_factor: float = 1.0
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    norm_eps: float = 1e-5
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -144,6 +169,8 @@ class ModelConfig:
     latent_moe: "LatentMoEConfig | None" = None
     # The `gdn_moe` family's facts (None for every other family).
     gdn_moe: "GdnMoEConfig | None" = None
+    # The `conv_moe` family's facts (None for every other family).
+    conv_moe: "ConvMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -160,29 +187,29 @@ class ModelConfig:
         return self.kv_heads * self.head_dim
 
     @property
+    def family_facts(self) -> "str | None":
+        """The name of the field that carries one family's facts, if this
+        configuration has any (`DecoderStack.config_extra` names the one a
+        family reads)."""
+        for name in FAMILY_FACTS:
+            if getattr(self, name) is not None:
+                return name
+        return None
+
+    @property
     def experts_held(self) -> int:
         """Routed experts this job holds: all of them, unless the family's
-        facts (`latent_moe` / `gdn_moe`) name a share with `experts_held`."""
-        share = self.latent_moe or self.gdn_moe
-        if share is None or share.experts_held is None:
+        facts name a share with `experts_held`."""
+        share = self.family_facts and getattr(self, self.family_facts)
+        if not share or share.experts_held is None:
             return self.num_experts
         return share.experts_held
 
     @property
     def expert_offset(self) -> int:
         """The first routed expert this job holds."""
-        share = self.latent_moe or self.gdn_moe
-        return 0 if share is None else share.expert_offset
-
-    @property
-    def family_facts(self) -> "str | None":
-        """The name of the field that carries one family's facts, if this
-        configuration has any (`DecoderStack.config_extra` names the one a
-        family reads)."""
-        for name in ("latent_moe", "gdn_moe"):
-            if getattr(self, name) is not None:
-                return name
-        return None
+        share = self.family_facts and getattr(self, self.family_facts)
+        return share.expert_offset if share else 0
 
     def padded_vocab_size(self, tp_size: int) -> int:
         """Vocab size rounded up to a multiple of tp_size.
@@ -201,6 +228,9 @@ class ModelConfig:
         if self.gdn_moe is not None:
             from .models.gdn_moe import GdnMoETransformer
             return GdnMoETransformer.num_params(self)
+        if self.conv_moe is not None:
+            from .models.conv_moe import ConvMoETransformer
+            return ConvMoETransformer.num_params(self)
         d, f, v, L = self.attn_dim, self.ffn_dim, self.vocab_size, self.num_layers
         kd = self.kv_dim
         attn = 2 * d * d + 2 * d * kd + 2 * d + 2 * kd  # wq/wo + wk/wv (+ biases)
@@ -211,6 +241,9 @@ class ModelConfig:
         norms = 2 * d
         return v * d + L * (attn + ffn + norms) + d + v * d + v  # emb + layers + final norm + lm_head
 
+
+# the ModelConfig fields that carry one family's facts each
+FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -255,6 +288,19 @@ MODEL_PRESETS = {
             head_dim=32, linear_num_key_heads=2, linear_num_value_heads=4,
             linear_key_head_dim=16, linear_value_head_dim=16,
             moe_intermediate_size=32, shared_expert_intermediate_size=32)),
+    # the `conv_moe` family at a CPU size: LFM2's pattern in small, two
+    # leading dense layers with convolution mixers, then (attention, conv,
+    # conv) twice and (attention, conv) twice: periods of two lengths; 4
+    # query heads over 2 key-value heads, 16 wide, with q/k norms; 8 routed
+    # experts (sigmoid top-2, a selection bias, no shared expert)
+    "tiny-conv-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_kv_heads=2, num_layers=12,
+        vocab_size=1024, maxlen=256, rope_theta=1000000.0, num_experts=8,
+        moe_top_k=2, conv_moe=ConvMoEConfig(
+            layer_types=("conv", "conv")
+            + ("full_attention", "conv", "conv") * 2
+            + ("full_attention", "conv") * 2,
+            moe_intermediate_size=32)),
 }
 
 

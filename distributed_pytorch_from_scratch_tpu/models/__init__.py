@@ -1,5 +1,6 @@
 """The model families, and the one place a family's name becomes a class."""
 
+from .conv_moe import ConvMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
 from .mla_moe import LatentMoETransformer
@@ -7,7 +8,8 @@ from .stack import DecoderStack
 from .transformer import Transformer
 
 FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer,
-            "mla_moe": LatentMoETransformer, "gdn_moe": GdnMoETransformer}
+            "mla_moe": LatentMoETransformer, "gdn_moe": GdnMoETransformer,
+            "conv_moe": ConvMoETransformer}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -1,0 +1,361 @@
+"""The `conv_moe` family: gated short-convolution mixers, about three to one
+with grouped-query attention layers whose q and k are normed per head,
+leading layers with a dense SwiGLU and then a sigmoid-routed expert FFN with
+a selection bias and no shared expert, a head tied to the embedding (the
+LFM2 architecture, `lfm2_moe`), on the same decoder stack as the other
+families.
+
+`ConvMoETransformer` is a subclass of `models/stack.DecoderStack` and holds
+only what differs:
+
+* **a pattern that is a leading segment and then periods**, derived from
+  `cfg.conv_moe.layer_types` and `num_dense_layers` by run length
+  (`layer_blocks`): the leading dense layers are one segment
+  (`params["dense_layers"]`), what follows is cut into periods that repeat
+  (`params["attn_layers_<i>"]`, `params["conv_layers_<i>"]` of the i-th
+  period block, stacked (periods, layers a period, ...)). The published 24
+  layers are 2 dense convolution layers, (attention, conv x 3) x 4 and
+  (attention, conv x 2) x 2; the benchmark's cut is 1 dense layer and one
+  period of four: the same program (`DecoderStack._pattern`);
+* **two kinds of mixer in one family**: a convolution layer's parameters
+  hold `conv` (`parallel/shortconv.ShortConv`) and no `wo`, so the stack
+  asks `_mix`; an attention layer holds `wq`/`wk`/`wv`/`wo` and goes through
+  the stack's own (q, k, v) dispatch (`_qkv`, `causal_attention`, so the
+  flash kernel with its native grouping on the TPU) with the `q_norm` /
+  `k_norm` it also holds applied per head before the rotation, and RoPE
+  (half-split pairs) over the whole head;
+* **the expert FFN**: `parallel/moe.SharedRoutedFFN(score="sigmoid",
+  n_shared=0)`: the router scores all `cfg.num_experts`, the job holds
+  `cfg.conv_moe.experts_held` of them (one chip's share of an
+  expert-parallel deployment; None = all); no token is dropped, no
+  auxiliary loss; the selection bias is a leaf no gradient reaches;
+* the plain RMSNorm (eps `norm_eps`) for both layer norms, the final norm
+  and the q/k norms; the head tied to the embedding (initialised at 0.02,
+  the published `initializer_range`); no bias anywhere.
+
+What is not made to work is refused where the model is built, with a
+message: pp > 1, cp > 1, ep > 1, sequence parallelism and its rings,
+pad-aware bucketing, ZeRO 2/3 and the bucketed reducer
+(`hand_reduced_grads`), `models/decode.py` and the serving engines
+(`decodable`: a convolution's last inputs are a state
+`serving/kv_manager.py` does not hold).
+
+Named scopes inside the step, for a device trace's `op_name`: `shortconv`
+(parallel/shortconv.py), `gqa_attn` (the projections, q/k norms, RoPE and
+`W_o`; the flash calls stay the kernels' own), `dense_ffn`, and `moe_route`,
+`moe_experts` (parallel/moe.py).
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import jax
+from jax import lax
+
+from ..config import ModelConfig
+from ..ops.rope import apply_rotary_leading, rope_angles
+from ..parallel.embedding import VocabParallelEmbedding
+from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
+from ..parallel.moe import SharedRoutedFFN
+from ..parallel.norm import RMSNorm
+from ..parallel.shortconv import ShortConv
+from ..runtime.prng import fold
+from .gpt2 import GPT2Transformer
+from .stack import DecoderStack, Params, TPSublayers
+from .transformer import Transformer
+
+KINDS = {"conv": "conv", "full_attention": "attn"}
+MIXER = {"conv": ("conv",),
+         "attn": ("wq", "wk", "wv", "q_norm", "k_norm", "wo")}
+DENSE = ("gate_proj", "up_proj", "down_proj")
+INIT_STD = 0.02     # the published `initializer_range`, of the tied embedding
+
+
+def module_names(kind: str, dense: bool) -> Tuple[str, ...]:
+    """The modules of a layer whose mixer is `kind` ("conv" | "attn")."""
+    return ("norm1", *MIXER[kind], "norm2", *(DENSE if dense else ("moe",)))
+
+
+def layer_blocks(layer_types, num_dense_layers: int):
+    """`layer_types` and `num_dense_layers` -> the blocks of the layer
+    pattern, by run length: ((repeats, ((parameter key, mixer kind, dense?,
+    layers), ...)), ...), `repeats` None for a segment scanned once.
+
+    The leading dense layers are one segment (one kind of mixer). What
+    follows is read as runs of one kind; a period starts at a run and ends
+    before that run's kind comes again, and it repeats while the same runs
+    follow: A C C C A C C C A C C A C C is ((A, C x 3) x 2, (A, C x 2) x
+    2)."""
+    kinds = []
+    for name in layer_types:
+        if name not in KINDS:
+            raise ValueError(f"layer_types holds {name!r}; the conv_moe "
+                             f"family has {sorted(KINDS)}")
+        kinds.append(KINDS[name])
+    lead, rest = kinds[:num_dense_layers], kinds[num_dense_layers:]
+    if len(set(lead)) > 1:
+        raise ValueError("the leading dense layers are one segment of one "
+                         f"kind of mixer, got {lead}")
+    blocks = [(None, (("dense_layers", lead[0], True, len(lead)),))] if lead \
+        else []
+    runs = []                                   # [kind, layers]
+    for kind in rest:
+        if runs and runs[-1][0] == kind:
+            runs[-1][1] += 1
+        else:
+            runs.append([kind, 1])
+    at = 0
+    while at < len(runs):
+        # two kinds of mixer: a period is one run, or two of unlike kinds
+        period = runs[at:at + 2]
+        n = len(period)
+        repeats = 1
+        while runs[at + repeats * n:at + (repeats + 1) * n] == period:
+            repeats += 1
+        i = sum(r is not None for r, _ in blocks)
+        blocks.append((repeats, tuple((f"{kind}_layers_{i}", kind, False, c)
+                                      for kind, c in period)))
+        at += repeats * n
+    return tuple(blocks)
+
+
+def layers_in_order(params: Params, blocks):
+    """The stacked layers of a parameter tree laid out by `blocks`
+    (`layer_blocks`), one pytree a layer, in the order the layers run: what
+    a reference that LOOPS its layers walks."""
+    out = []
+    for repeats, parts in blocks:
+        for p in range(repeats or 1):
+            for key, _, _, n in parts:
+                for j in range(n):
+                    at = (j,) if repeats is None else (p, j)
+                    out.append(jax.tree.map(lambda a: a[at], params[key]))
+    return out
+
+
+@dataclass(frozen=True)
+class ConvMoETransformer(DecoderStack):
+    """The conv_moe family (module docstring)."""
+
+    uses_rope = True
+    attn_norm_key = "norm1"
+    ffn_norm_key = "norm2"
+    ffn_inputs = 2            # the leading layers' SwiGLU: gate and up
+    tied_head = True
+    decodable = False
+    hand_reduced_grads = False
+    config_extra = "conv_moe"
+    _router_aux_losses = False
+
+    def __post_init__(self):
+        cm = self.cfg.conv_moe
+        if cm is None:
+            raise ValueError("the conv_moe family needs cfg.conv_moe "
+                             "(config.ConvMoEConfig)")
+        if not self.cfg.num_experts:
+            raise ValueError("the conv_moe family needs cfg.num_experts > 0 "
+                             "(the routed experts its router scores)")
+        if len(cm.layer_types) != self.cfg.num_layers:
+            raise ValueError(
+                f"layer_types names {len(cm.layer_types)} layers, num_layers "
+                f"is {self.cfg.num_layers}")
+        if not 0 <= cm.num_dense_layers < self.cfg.num_layers:
+            raise ValueError(
+                f"num_dense_layers {cm.num_dense_layers} must leave an "
+                f"expert layer among {self.cfg.num_layers} layers")
+        refused = [
+            (self.pp_size > 1, "pp_size > 1 (the pipeline splits one "
+             "segment of identical layers; this family has a leading "
+             "segment and then periods of two kinds of layer)"),
+            (self.cp_size > 1, "cp_size > 1 (the convolution's taps run "
+             "along the whole sequence; no exchange of a shard's last "
+             "inputs is written)"),
+            (self.ep_size > 1, "ep_size > 1 (a job holds one share of the "
+             "experts, cfg.conv_moe.experts_held; the all-to-all between "
+             "shares is not written)"),
+            (self.sequence_parallel is True, "sequence_parallel=True (the "
+             "router and the convolution read whole sequences)"),
+            (self.attn_t_real is not None, "attn_t_real (pad tokens would "
+             "be routed and would enter the convolution)"),
+            (self.zero3_axis is not None, "ZeRO stage 3"),
+        ]
+        for bad, what in refused:
+            if bad:
+                raise ValueError(f"the conv_moe family does not run with "
+                                 f"{what}")
+        self._blocks    # a pattern the family cannot cut is refused here
+        super().__post_init__()
+
+    # ---- the layer pattern ----
+
+    @functools.cached_property
+    def _blocks(self):
+        cm = self.cfg.conv_moe
+        return layer_blocks(cm.layer_types, cm.num_dense_layers)
+
+    @property
+    def _pattern(self):
+        return tuple(parts[0][0] if repeats is None
+                     else tuple((key, n) for key, _, _, n in parts)
+                     for repeats, parts in self._blocks)
+
+    @property
+    def _segments(self):
+        """(parameter key, layers, module names) of every stacked key."""
+        return tuple((key, (repeats or 1) * n, module_names(kind, dense))
+                     for repeats, parts in self._blocks
+                     for key, kind, dense, n in parts)
+
+    # ---- facts for training/memory.py ----
+
+    @property
+    def layer_extra_elems_per_token(self) -> float:
+        """What an expert layer's backward holds at its fullest, beside the
+        d-wide tensors the dense skeleton counts, in elements of the
+        compute dtype a token: a convolution layer's `[B | C | u]` and its
+        cotangent (6 d), the gated products `B u` and `C c` (2 d) and the
+        taps' float32 sum with its cotangent (4 d in elements of two
+        bytes); and one chunk of the expert dispatch
+        (`SharedRoutedFFN.chunk_share` of a token's pairs: rows in and
+        out, and the hidden activations `[gate | up]`, their product and
+        both cotangents). At a held share of 1/4 the chunk is ALL pairs,
+        so this is what sizes the step. An attention layer holds less."""
+        moe = self._mods["moe"]
+        chunk_rows = moe.chunk_share * moe.top_k
+        f = self.cfg.conv_moe.moe_intermediate_size / self.tp_size
+        return 12.0 * self.d / self.tp_size + chunk_rows * (
+            2 * self.d + 5 * f)
+
+    # ---- sub-module definitions ----
+
+    @functools.cached_property
+    def embedding(self) -> VocabParallelEmbedding:
+        return VocabParallelEmbedding(self.cfg.vocab_size, self.d,
+                                      tp_size=self.tp_size,
+                                      init_std=INIT_STD)
+
+    @functools.cached_property
+    def _mods(self) -> Dict[str, Any]:
+        cfg, cm = self.cfg, self.cfg.conv_moe
+        d, eps = self.d, cm.norm_eps
+        col = functools.partial(ColumnParallelLinear, add_bias=False,
+                                gather_output=False)
+        row = functools.partial(RowParallelLinear, add_bias=False,
+                                split_input=False)
+        return {
+            "norm1": RMSNorm(d, eps),
+            "norm2": RMSNorm(d, eps),
+            "conv": ShortConv(d, cm.conv_L_cache, tp_size=self.tp_size),
+            "wq": col(d, d),
+            "wk": col(d, cfg.kv_dim),
+            "wv": col(d, cfg.kv_dim),
+            # one weight vector for all query heads, one for all key heads
+            "q_norm": RMSNorm(cfg.head_dim, eps),
+            "k_norm": RMSNorm(cfg.head_dim, eps),
+            "wo": row(d, d),
+            "gate_proj": col(d, cfg.ffn_dim),
+            "up_proj": col(d, cfg.ffn_dim),
+            "down_proj": row(cfg.ffn_dim, d),
+            "moe": SharedRoutedFFN(
+                d, cm.moe_intermediate_size, cfg.num_experts,
+                top_k=cfg.moe_top_k, held=cm.experts_held,
+                offset=cm.expert_offset, n_shared=0,
+                scaling=cm.routed_scaling_factor, tp_size=self.tp_size,
+                score="sigmoid"),
+        }
+
+    @functools.cached_property
+    def final_norm(self) -> RMSNorm:
+        return RMSNorm(self.d, self.cfg.conv_moe.norm_eps)
+
+    # ---- init / specs ----
+
+    def init(self, key: jax.Array) -> Params:
+        return {
+            "embedding": self.embedding.init(fold(key, "embedding")),
+            **{name: self._init_layers(key, name, count, names)
+               for name, count, names in self._segments},
+            "norm": self.final_norm.init(fold(key, "norm")),
+        }
+
+    def specs(self) -> Params:
+        return {
+            "embedding": self.embedding.specs(),
+            **{name: self._layer_specs(names, name)
+               for name, _, names in self._segments},
+            "norm": self.final_norm.specs(),
+        }
+
+    @staticmethod
+    def num_params(cfg: ModelConfig) -> int:
+        return sum(param_counts(cfg).values())
+
+    # ---- what differs inside the forward (per-shard, inside shard_map) ----
+
+    def _positions(self, params: Params, x: jax.Array,
+                   position_ids: jax.Array, dtype):
+        """Nothing enters at the embedding; every layer gets the whole
+        head's (cos, sin) at `position_ids`, computed from the positions
+        (the attention layers read them)."""
+        return x.astype(dtype), rope_angles(
+            position_ids, self.cfg.head_dim, self.cfg.rope_theta)
+
+    def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
+        h = self.cfg.head_dim
+        return (apply_rotary_leading(q, *layer_pos, h),
+                apply_rotary_leading(k, *layer_pos, h))
+
+    def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
+             dtype, b: int, t: int):
+        with jax.named_scope("gqa_attn"):
+            return super()._qkv(lp, y, tp, layer_pos, dtype, b, t)
+
+    def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
+                      dtype) -> jax.Array:
+        with jax.named_scope("gqa_attn"):
+            return tp.row(lp, "wo", o, dtype)
+
+    def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
+        return self._mods["conv"].apply(lp["conv"], y, dtype)
+
+    _head_logits = GPT2Transformer._head_logits     # tied to the embedding
+
+    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
+        if "moe" in lp:
+            return self._mods["moe"].apply(lp["moe"], y, dtype)
+        with jax.named_scope("dense_ffn"):
+            return Transformer._mlp(self, lp, y, tp, dtype), None
+
+    def _fold_aux(self, auxs):
+        # the expert layers' counters stay one row a layer
+        return auxs
+
+    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
+                    aux, trunk, input_ids, target_ids, position_ids,
+                    mode: str, batch_axes):
+        return loss, jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
+
+
+def param_counts(cfg: ModelConfig) -> Dict[str, int]:
+    """The family's parameters by part, as `init` makes them for `cfg` (the
+    experts HELD, not the routed total; the tied embedding once): what
+    `num_params` sums, and what the benchmark's own count is pinned
+    against."""
+    cm = cfg.conv_moe
+    d, h = cfg.attn_dim, cfg.head_dim
+    mixer = {"conv": ShortConv(d, cm.conv_L_cache).num_params(),
+             "attn": 2 * d * d + 2 * d * cfg.kv_dim + 2 * h}
+    dense = 3 * d * cfg.ffn_dim
+    experts = (d * cfg.num_experts + cfg.num_experts      # router + bias
+               + cfg.experts_held * 3 * d * cm.moe_intermediate_size)
+    out = {"embedding": cfg.vocab_size * d, "final_norm": d,
+           "dense_layers": 0, "conv_layers": 0, "attn_layers": 0}
+    for i, name in enumerate(cm.layer_types):
+        is_dense = i < cm.num_dense_layers
+        key = "dense_layers" if is_dense else KINDS[name] + "_layers"
+        out[key] += mixer[KINDS[name]] + 2 * d + (dense if is_dense
+                                                  else experts)
+    return out

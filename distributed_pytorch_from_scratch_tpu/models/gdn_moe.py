@@ -13,8 +13,8 @@ only what differs:
   whose body scans the period's layers through the one layer skeleton and
   the one remat policy. Layer `i` is full attention where `(i + 1) %
   interval == 0`;
-* **the mixers** hand back their sublayer's output themselves (`_mix`;
-  `_own_mixer`): `parallel/gdn.GatedDeltaNet` (the chunked gated delta rule
+* **the mixers** hand back their sublayer's output themselves (`_mix`: a
+  layer's parameters hold no `wo` of the stack's): `parallel/gdn.GatedDeltaNet` (the chunked gated delta rule
   of ops/delta_rule.py) and `parallel/gated_attention.GatedAttention` (q/k
   norms per head, RoPE on the leading quarter of a head, a sigmoid output
   gate; the attention call itself is `ops/attention.causal_attention`, so
@@ -79,8 +79,6 @@ class GdnMoETransformer(DecoderStack):
     hand_reduced_grads = False
     config_extra = "gdn_moe"
     _router_aux_losses = False
-    _own_mixer = True
-    _layer_keys = ("gdn_layers", "attn_layers")
 
     def __post_init__(self):
         gm = self.cfg.gdn_moe
@@ -125,9 +123,10 @@ class GdnMoETransformer(DecoderStack):
     # ---- the layer pattern ----
 
     @property
-    def _period(self):
-        return (("gdn_layers", self.cfg.gdn_moe.full_attention_interval - 1),
-                ("attn_layers", 1))
+    def _pattern(self):
+        """One period that repeats."""
+        return ((("gdn_layers", self.cfg.gdn_moe.full_attention_interval - 1),
+                 ("attn_layers", 1)),)
 
     @property
     def periods(self) -> int:
@@ -137,7 +136,7 @@ class GdnMoETransformer(DecoderStack):
     def _segments(self):
         """(parameter key, layers, module names) of both stacked segments."""
         return tuple((key, self.periods * n, names) for (key, n), names
-                     in zip(self._period, (LINEAR, FULL)))
+                     in zip(self._pattern[0], (LINEAR, FULL)))
 
     # ---- facts for training/memory.py ----
 
@@ -226,7 +225,7 @@ class GdnMoETransformer(DecoderStack):
     def specs(self) -> Params:
         return {
             "embedding": self.embedding.specs(),
-            **{name: self._layer_specs(names)
+            **{name: self._layer_specs(names, name)
                for name, _, names in self._segments},
             "norm": self.final_norm.specs(),
             "lm_head": self.lm_head.specs(),

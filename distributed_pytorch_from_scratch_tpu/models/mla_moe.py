@@ -122,7 +122,8 @@ class LatentMoETransformer(DecoderStack):
     # ---- the layer pattern ----
 
     @property
-    def _layer_keys(self):
+    def _pattern(self):
+        """Two segments: the leading dense layers, the expert layers."""
         first = self.cfg.latent_moe.first_k_dense_replace
         return ("dense_layers", "layers") if first else ("layers",)
 

@@ -499,22 +499,24 @@ class DecoderStack:
     # the ModelConfig field that carries facts only this family reads
     # (None: the config's own fields are all it needs)
     config_extra = None
-    # The LAYER PATTERN: the keys of the parameter tree that hold stacked
-    # layers, in the order the forward scans them. One stack, one remat
-    # policy, one `_layer_body`; a segment's layers share one parameter
-    # structure, and what a layer's FFN is follows from what its
-    # parameters hold (`_ffn`). A pipeline splits one segment only.
-    _layer_keys = ("layers",)
-    # A pattern that REPEATS: ((parameter key, layers a period), ...) in
-    # the order a period runs them, or None. The keys are `_layer_keys`;
-    # each holds its layers stacked (periods, layers a period, ...), and
-    # the forward is ONE scan over periods whose body scans each key's
-    # layers in turn (`_scan_periods`): the cut to one period and the
-    # published depth are the same program.
-    _period = None
-    # Does the family's mixer hand back the sublayer's output itself
-    # (`_mix`), instead of (q, k, v) for the stack's attention dispatch?
-    _own_mixer = False
+    # The LAYER PATTERN, declared once: the blocks the forward runs, in
+    # order. One stack, one remat policy, one `_layer_body`; what a layer's
+    # mixer and FFN are follows from what its parameters hold
+    # (`_layer_body`, `_ffn`). A block is either
+    #   * a SEGMENT: the key (a str) of the parameter tree that holds its
+    #     layers stacked (layers, ...), scanned once; or
+    #   * a PERIOD that repeats: a tuple of (parameter key, layers a
+    #     period) pairs in the order a period runs them, each key holding
+    #     its layers stacked (periods, layers a period, ...): ONE scan over
+    #     periods whose body scans each key's layers in turn
+    #     (`_scan_periods`), so a cut to one period and the published depth
+    #     are the same program.
+    # A family derives it from its configuration: leading dense layers and
+    # then expert layers are two segments (`mla_moe`), one period that
+    # repeats is one block (`gdn_moe`), a leading segment and then periods
+    # of two lengths are three (`conv_moe`). A pipeline splits one segment
+    # only.
+    _pattern = ("layers",)
     # does the loss add the Switch load-balance and z terms of
     # parallel/moe.MoEFFN's router sums (a family whose router balances
     # without an auxiliary loss says no)
@@ -567,6 +569,19 @@ class DecoderStack:
     @property
     def is_moe(self) -> bool:
         return self.cfg.num_experts > 0
+
+    @property
+    def _layer_keys(self) -> Tuple[str, ...]:
+        """The keys of the parameter tree that hold stacked layers, in the
+        order `_pattern` runs them."""
+        return tuple(key for block in self._pattern for key in (
+            (block,) if isinstance(block, str) else (k for k, _ in block)))
+
+    @property
+    def _layers_a_period(self) -> Dict[str, int]:
+        """key -> layers a period, of the keys `_pattern`'s periods hold."""
+        return {key: n for block in self._pattern
+                if not isinstance(block, str) for key, n in block}
 
     # what training/memory.py asks beside the config's widths
     @property
@@ -640,9 +655,9 @@ class DecoderStack:
         layers = jax.vmap(one_layer)(layer_keys)
         if self._interleaved:
             layers = self._layers_to_schedule(layers)
-        if self._period:
+        a_period = self._layers_a_period.get(segment)
+        if a_period:
             # (periods, layers a period, ...): `_scan_periods`
-            a_period = dict(self._period)[segment]
             layers = jax.tree.map(
                 lambda a: a.reshape(-1, a_period, *a.shape[1:]), layers)
         return layers
@@ -696,10 +711,10 @@ class DecoderStack:
             return self.specs()
         return dataclasses.replace(self, pp_schedule="gpipe").specs()
 
-    def _layer_specs(self, names=None) -> Params:
+    def _layer_specs(self, names=None, segment: str = "layers") -> Params:
         """PartitionSpecs matching `_init_layers`."""
         lead = ("pp" if self.pp_size > 1 else None,)
-        if self._period:
+        if segment in self._layers_a_period:
             lead = (None, None)     # periods, layers a period
 
         def stack(spec_dict: Params) -> Params:
@@ -791,7 +806,9 @@ class DecoderStack:
         # the cp ring documents below. Bubble steps burn the layer FLOPs;
         # their outputs are structurally discarded (garbage flows only into
         # garbage — see _pipeline_layers).
-        if self._own_mixer:
+        if "wo" not in layer_params:
+            # no output projection of the stack's: the layer's mixer hands
+            # back the sublayer's output itself (`_mix`)
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
             return ffn_half(x, self._mix(layer_params, y, layer_pos, dtype))
@@ -834,12 +851,18 @@ class DecoderStack:
         q = split(q, self.num_local_heads)
         k = split(k, self.num_local_kv_heads)
         v = split(v, self.num_local_kv_heads)
+        if "q_norm" in lp:
+            # a family whose attention norms q and k per head, before the
+            # positions, holds the two norms in its layers
+            q = self._mods["q_norm"].apply(lp["q_norm"], q)
+            k = self._mods["k_norm"].apply(lp["k_norm"], k)
         return self._position_qk(q, k, layer_pos) + (v,)
 
     def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
-        """For a family with `_own_mixer`: the mixer sublayer's output (b,
-        t, d), reduced over 'tp', from the normed activation `y`. Which
-        mixer a layer runs follows from what its parameters hold."""
+        """For a layer whose parameters hold no `wo` (a mixer that is not
+        the stack's (q, k, v) dispatch): the mixer sublayer's output (b, t,
+        d), reduced over 'tp', from the normed activation `y`. Which mixer
+        a layer runs follows from what its parameters hold."""
         raise NotImplementedError
 
     def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
@@ -1010,21 +1033,25 @@ class DecoderStack:
             x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
                                            (*layer_pos, position_ids),
                                            head_layout=head_layout)
-        elif self._period:
-            x, aux = self._scan_periods(run, x, params)
         else:
-            aux = None
-            for key in self._layer_keys:
-                x, seg_aux = run(x, params[key])
-                aux = aux if seg_aux is None else seg_aux
+            auxs = []
+            for block in self._pattern:
+                x, aux = (run(x, params[block]) if isinstance(block, str)
+                          else self._scan_periods(run, x, params, block))
+                auxs.append(aux)
+            # a block of dense layers has none; where several blocks count
+            # (a row a layer), the rows follow the layers
+            auxs = [aux for aux in auxs if aux is not None]
+            aux = (jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
+                   if len(auxs) > 1 else auxs[0] if auxs else None)
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
 
-    def _scan_periods(self, run, x: jax.Array, params: Params):
-        """One scan over the periods of `_period`: the body runs each key's
-        layers of the period through `run` (the one layer skeleton under
-        the one remat policy), in the period's order. The aux comes back
-        one row a layer, in the order the layers ran."""
-        keys = [key for key, _ in self._period]
+    def _scan_periods(self, run, x: jax.Array, params: Params, period):
+        """One scan over the periods of a `_pattern` block: the body runs
+        each key's layers of the period through `run` (the one layer
+        skeleton under the one remat policy), in the period's order. The
+        aux comes back one row a layer, in the order the layers ran."""
+        keys = [key for key, _ in period]
 
         def period(z, layers):
             auxs = []

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.collectives import copy_to, reduce_from
+from ..ops.conv import causal_depthwise_conv
 from ..ops.delta_rule import CHUNK, gated_delta_rule
 from ..runtime.prng import fold
 from .linear import uniform_fan_in
@@ -178,13 +179,7 @@ class GatedDeltaNet:
                 g.transpose(0, 2, 1), beta.transpose(0, 2, 1))
 
     def _conv(self, w: jax.Array, u: jax.Array) -> jax.Array:
-        """Causal depthwise convolution over time then SiLU: u (b, t, heads,
-        channels), w (heads, channels, taps); tap `taps - 1` reads the token
-        itself, tap 0 the one `taps - 1` back (zeros before the sequence).
-        Summed in float32, handed on in u's dtype (a float32 copy of the
-        8192 channels and its cotangent are 1.5 GB at 16k tokens)."""
-        t, taps = u.shape[1], self.conv_kernel
-        u = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0), (0, 0)))
-        acc = sum(u[:, j:j + t].astype(jnp.float32) * w[..., j]
-                  for j in range(taps))
-        return jax.nn.silu(acc).astype(u.dtype)
+        """The causal depthwise convolution over time (ops/conv.py) then
+        SiLU: u (b, t, heads, channels), w (heads, channels, taps); handed
+        on in u's dtype."""
+        return jax.nn.silu(causal_depthwise_conv(u, w)).astype(u.dtype)

@@ -265,7 +265,8 @@ class SharedRoutedFFN:
     (`lax.ragged_dot`: XLA:TPU makes it a grouped-matmul kernel whose grid
     follows the group sizes). The sorted pairs are walked in chunks
     (`chunk_rows`) under one `lax.scan`; a chunk past the last held row is
-    skipped by a `lax.cond`, so memory follows the chunk, while every pair
+    skipped by a `lax.cond` (where there are several: a chunk of ALL the
+    pairs is always computed), so memory follows the chunk, while every pair
     that exists is computed whatever the routing (tests force all tokens
     onto a few experts). **A live chunk is computed WHOLE**: the rows past
     its last held pair are zeros handed to the last held expert, so the
@@ -479,6 +480,17 @@ class SharedRoutedFFN:
                            * wc[:, None].astype(out.dtype))
                     return y.at[tok].add(out.astype(y.dtype))
 
+            if chunks == 1:
+                # the one chunk is ALL the pairs (a held share of a sixth
+                # or more): there is no later chunk to skip to, and a layer
+                # whose held experts got nothing this step still computes
+                # its chunk, or the step would follow the routing after
+                # all: an untrained router with nothing to balance it sends
+                # every token of a step to the same few experts, none of
+                # them held in one layer of three, and the skipped chunk
+                # made such a step 13% shorter and the seeds' tokens per
+                # second spread by 1.7% (PERF.md section 6, PR 39)
+                return live(y), None
             return lax.cond(lo < rows_here, live, lambda y: y, y), None
 
         # the carry varies over what the rows vary over (batch axes and tp)

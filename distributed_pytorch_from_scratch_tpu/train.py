@@ -210,7 +210,15 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "(RoPE/RMSNorm/SwiGLU), 'gpt2' = LayerNorm/GELU/"
                         "learned positions/tied embeddings (models/gpt2.py; "
                         "composes with dp/tp/cp/SP/pp/ep like llama — GQA "
-                        "is the one llama-only feature)")
+                        "is the one llama-only feature); 'mla_moe', "
+                        "'gdn_moe' and 'conv_moe' each go with their own "
+                        "preset (--model tiny-mla-moe | tiny-gdn-moe | "
+                        "tiny-conv-moe: latent attention + experts; Gated "
+                        "DeltaNet + gated attention + experts; gated short "
+                        "convolutions + GQA with q/k norms + experts, a "
+                        "tied head) and train under dp/tp/ZeRO 1 only: "
+                        "pp/cp/ep > 1, SP, ZeRO 2/3, decode and serving "
+                        "refuse them with a message")
     g.add_argument("--model", choices=sorted(MODEL_PRESETS), default=None,
                    help="named shape preset (BASELINE configs: '45m' is the "
                         "reference shape, 'gpt2-124m' is config 3); explicit "
@@ -540,7 +548,8 @@ def train(args: argparse.Namespace) -> dict:
                 f"and --model {args.model} carries {carries!r}: a family "
                 f"with facts of its own goes with a preset that has them "
                 f"(--family mla_moe --model tiny-mla-moe, --family gdn_moe "
-                f"--model tiny-gdn-moe), and such a preset with no other "
+                f"--model tiny-gdn-moe, --family conv_moe --model "
+                f"tiny-conv-moe), and such a preset with no other "
                 f"family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
@@ -877,7 +886,7 @@ def train(args: argparse.Namespace) -> dict:
             step_fn = build_train_step_multi(model, mesh, ocfg, args.loss_mode,
                                              **builder_kwargs)
         else:
-            # a family whose loss counts things (the mla_moe and gdn_moe
+            # a family whose loss counts things (the mla_moe, gdn_moe and conv_moe
             # families' routers) returns them with the plain step; logged
             # below at the log interval, fetched with the loss
             with_counters = (cfg.family_facts is not None and zero_stage < 2

@@ -187,6 +187,22 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
                 + 12 * full * batch * cfg.num_heads * seqlen * seqlen
                 * gm.head_dim
                 + 3 * (cfg.num_layers - full) * rule * batch * seqlen)
+    cm = getattr(cfg, "conv_moe", None)
+    if cm is not None:
+        # the conv_moe family: the held experts at a token's mean share of
+        # them in the expert layers only, as above; the tied embedding's
+        # one matrix is the head's matmul (its lookup is none); attention
+        # at the full T^2 in the attention layers only; the convolution's
+        # taps are no matmul
+        held = cfg.experts_held
+        expert_layers = cfg.num_layers - cm.num_dense_layers
+        n -= expert_layers * (held - cfg.moe_top_k * held
+                              / cfg.num_experts) * (
+            3 * cfg.attn_dim * cm.moe_intermediate_size)
+        full = sum(kind == "full_attention" for kind in cm.layer_types)
+        return (6 * n * batch * seqlen
+                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
+                * cfg.head_dim)
     if getattr(cfg, "num_experts", 0):
         inactive = ((cfg.num_experts - cfg.moe_top_k)
                     * 3 * cfg.attn_dim * cfg.ffn_dim)

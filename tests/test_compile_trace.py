@@ -407,12 +407,19 @@ def tokens(tmp_path_factory):
 
 
 @pytest.mark.parametrize("max_steps,recompiled_at", [(5, [3]), (6, [])])
-def test_train_names_the_step_that_recompiled(tokens, tmp_path, max_steps,
-                                              recompiled_at):
+def test_train_names_the_step_that_recompiled(tokens, tmp_path, cache_dir,
+                                              max_steps, recompiled_at):
     """Windows of three steps: a run of five ends on a window of two, the
     step function is built again at step 3 and the run says so in
     `metrics.jsonl`, on the timeline and in its record; a run of six ends on
-    a full window and says nothing."""
+    a full window and says nothing.
+
+    On a cache directory of its own (`cache_dir`): `train()` turns the
+    persistent cache on, and in the checkout's `.jax_cache/` an earlier run
+    of this very test has left both `multi_step` programs, so the builds
+    this test waits for came back as loads (`compile.load` spans, `hit`
+    true) wherever the suite ran twice on one tree: it passed alone on a
+    fresh copy and failed in the driver's whole run (PR 39)."""
     from distributed_pytorch_from_scratch_tpu import train as train_mod
     save_dir = tmp_path / "ck"
     record = train_mod.train(train_mod.get_train_args(

@@ -9,7 +9,8 @@ import re
 import jax
 import pytest
 
-from distributed_pytorch_from_scratch_tpu.config import (GdnMoEConfig,
+from distributed_pytorch_from_scratch_tpu.config import (ConvMoEConfig,
+                                                         GdnMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
@@ -37,9 +38,18 @@ GDN = dict(head_dim=16, linear_num_key_heads=2, linear_num_value_heads=4,
            moe_intermediate_size=16, shared_expert_intermediate_size=16)
 
 
+# the conv_moe family: one dense convolution layer (a segment), then one
+# period of (attention, conv, conv)
+CONV = dict(layer_types=("conv", "full_attention", "conv", "conv"),
+            moe_intermediate_size=16, num_dense_layers=1)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "conv_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           conv_moe=ConvMoEConfig(experts_held=held, **CONV))
     if extra == "gdn_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            gdn_moe=GdnMoEConfig(experts_held=held, **GDN))
@@ -106,11 +116,16 @@ def test_declared_facts_agree_with_the_tree(family):
     # the decoder reads the projections by these names; a family whose
     # attention is another says it cannot be decoded
     projections = ("wq", "wk", "wv") if cls.decodable else ()
-    # (a family whose mixers hand back their own output keeps their
-    # projections inside the mixers' modules)
-    out = () if cls._own_mixer else ("wo",)
-    for key in (cls.attn_norm_key, cls.ffn_norm_key, *out, *projections):
+    for key in (cls.attn_norm_key, cls.ffn_norm_key, *projections):
         assert all(key in params[seg] for seg in model._layer_keys)
+    # a layer goes through the stack's (q, k, v) dispatch exactly where its
+    # parameters hold the stack's output projection (a mixer that hands
+    # back its own output keeps its projections inside its module); a
+    # family that can be decoded has no other kind of layer
+    stacks = [("wo" in params[seg]) for seg in model._layer_keys]
+    assert all(stacks) or not cls.decodable
+    assert all(("wq" in params[seg]) == ("wo" in params[seg])
+               or "wq_a" in params[seg] for seg in model._layer_keys)
 
 
 def test_build_model_refuses_an_unknown_name_with_the_known_ones():
