@@ -96,7 +96,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _NN, _NT, _dot, _out_struct
+from .flash_attention import _NN, _NT, _dot, _out_struct, _vmem_limit
 
 FWD_NAME = "gdn_rule_fwd"
 BWD_NAME = "gdn_rule_bwd"
@@ -139,11 +139,6 @@ def _blocks(h: int, n: int) -> Tuple[int, int]:
     written); a chunk block divides the chunks, which are one chain."""
     return min(HEAD_BLOCK, h), max(c for c in range(1, CHUNK_BLOCK + 1)
                                    if n % c == 0)
-
-
-def _vmem_limit(block_bytes: int) -> int:
-    """Blocks double-buffered plus room for the body's own values."""
-    return min(2 * block_bytes + 24 * 2 ** 20, 100 * 2 ** 20)
 
 
 def _pieces(x):
@@ -339,7 +334,7 @@ def rule_forward(q: jax.Array, k: jax.Array, v: jax.Array, gb: jax.Array,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(step_bytes)),
+            vmem_limit_bytes=_vmem_limit(2 * step_bytes)),
         cost_estimate=pl.CostEstimate(
             flops=2 * h * n * C * (C * (2 * dk + dv) + 3 * dk * dv + C * dv),
             bytes_accessed=h * n * step_bytes // (hb * cb),
@@ -511,7 +506,7 @@ def rule_backward(q: jax.Array, k: jax.Array, v: jax.Array, gb: jax.Array,
                    _out_struct(gb.shape, jnp.float32, v)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(step_bytes)),
+            vmem_limit_bytes=_vmem_limit(2 * step_bytes)),
         cost_estimate=pl.CostEstimate(
             flops=2 * h * n * C * (C * (6 * dk + 4 * dv) + 6 * dk * dv
                                    + 2 * C * dv),

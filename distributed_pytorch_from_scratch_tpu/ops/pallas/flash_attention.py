@@ -49,6 +49,14 @@ registers (`_fwd_kernel`; 16.8 -> 6.1 ms a call at that shape). The
 forward's sub-tile follows q/k's width and whether a head has several key
 blocks (`_subtile_shape`). Longer rows keep the gridded walk, its K / V
 index maps clamped to the diagonal.
+
+PR 40: the backward of such a head is ONE kernel where what it keeps of the
+head fits `BWD_ROW_VMEM_BYTES` (`_bwd_call` decides from the shapes and
+says which walk it took on the program's tracer, `flash_bwd_walk`): q, k,
+v, dO, lse and delta whole rows in VMEM, s, p, dp and ds formed once a
+rectangle and dq, dk and dv all fed from them (`_bwd_row_kernel`; five
+products where the split kernels run seven, 22.8 -> 12.5 ms a call at
+t = 4096, 192 / 128). Heads over the budget keep the split kernels.
 """
 
 from __future__ import annotations
@@ -63,6 +71,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ...obs.trace import current_tracer
 
 MASK = -1e30  # hard mask; equivalent to the XLA path's -10000 (see module doc)
 
@@ -191,6 +201,38 @@ BWD_SUBTILE = (256, 256)
 # dots at every width; one tile a head only where q/k pass 128.
 FWD_SUBTILE_WIDE = (256, 512)
 
+# The backward of several blocks a head, the head resident (`_bwd_row_kernel`,
+# PR 40). A call alone on v5e (TPU v5 lite, jax 0.9.0), bf16, device time
+# from a capture, `python scripts/tune_flash_blocks.py --backward --bh 128
+# --t 4096 --d 192 --dv 128 --blocks 1024,512` and `--backward --bh 64
+# --t 8192 --d 64 --group 4`; ms a backward, knock-outs each alone (wrong
+# numbers, right time):
+#
+#                           192 / 128, t 4096, b*h 128    64 / 64, t 8192,
+#                           block 1024     block 512      b*h 64, group 4
+#   split: dq + dkv         10.86 + 11.95  13.37 + 15.73  11.05 + 16.24
+#          = a backward     22.81          29.10          27.29
+#   resident, whole         12.49          13.43          13.80
+#   DMA alone                2.92           2.92           1.44
+#   no s  = q k^T            9.34 (-3.14)  10.72          11.24 (-2.56)
+#   no dp = dO v^T          10.80 (-1.69)  11.50          11.50 (-2.30)
+#   no dq = ds k            11.12 (-1.37)  12.45          11.42 (-2.38)
+#   no dk^T = q^T ds        10.41 (-2.08)  11.40          12.09 (-1.71)
+#   no dv^T = dO^T p        11.45 (-1.04)  11.66          12.04 (-1.76)
+#
+# Five products where the split kernels run seven, and each runs better:
+# under the diagonal a rectangle is a whole query tile's 1024 rows against a
+# key sub-column, so s, dp and dq stream 1024 rows through each latched
+# weight tile where the split kernels' sub-rows stream 256. At 192 / 128 the
+# five knock-outs sum to 9.3 of the 12.5 ms; s is the dearest (K = 192 is two
+# passes, the second half empty). The backward's sub-tile, re-read with the
+# head resident (`--subtile --bh 128 --t 4096 --d 192 --dv 128 --blocks 1024
+# --edges ...`; under the diagonal only sub_k matters, the rectangle being
+# the tile's height): 256 x 256 12.49 (128 x 256 is the same plan), 256 x
+# 512 and 512 x 512 12.92, 512 x 256 13.15, 256 x 128 14.90. BWD_SUBTILE
+# stands. Mosaic's time a kernel: 2.9 - 4.2 s with tracing and lowering
+# against 1.7 + 2.2 for the two it replaces.
+
 
 def _subtile_shape(block_q: int, block_k: int, head_dim: int,
                    backward: bool, num_kb: int = 1) -> Tuple[int, int]:
@@ -202,7 +244,9 @@ def _subtile_shape(block_q: int, block_k: int, head_dim: int,
     pass the MXU's 128 rows (two passes a product) or a head has several
     key blocks (`num_kb` > 1: the walk is then a loop, in the kernel or
     the grid's, and wants long dots), the narrow one for one tile a head
-    at head_dim <= 128; the backward's was swept at 64 alone."""
+    at head_dim <= 128; the backward's was swept at 64 with one tile a head
+    (BWD_SUBTILE's note) and at 192 / 128 with four, the head resident (the
+    note under FWD_SUBTILE_WIDE): one shape won both."""
     if backward:
         sq, sk = BWD_SUBTILE
     else:
@@ -532,6 +576,25 @@ def _q_row(bkv, g, hq: int, hkv: int):
 # 192 / 192) does not.
 KV_ROW_VMEM_BYTES = 8 * 2 ** 20
 
+# What a head's backward may keep in VMEM for ONE kernel to make dq, dk and dv
+# of it (`_bwd_call`'s row walk, `_bwd_resident_bytes`): q, k, v, dO, lse and
+# delta in, dq, dk and dv out, all whole rows, double-buffered by the
+# pipeline and each width padded to 128 lanes (lse and delta, (t, 1) float32,
+# are 128 lanes of one value), plus the float32 accumulators. The kernel
+# asks Mosaic for that much and the body's room (`_vmem_limit`; a v5e has
+# 128 MiB, the default scoped limit is 16). Compiled for a v5e: t = 4096 at
+# q/k 192 and v 128 in bf16 is 34 MiB; t = 8192 at 64 / 64 with a group of 4
+# is 56. t = 8192 at 256 / 256 with a group of 8 is 96 and keeps the split
+# kernels: with the body's room it passes what `_vmem_limit` will ask for.
+BWD_ROW_VMEM_BYTES = 64 * 2 ** 20
+
+
+def _vmem_limit(resident_bytes: int) -> int:
+    """The scoped VMEM a kernel asks Mosaic for where the default 16 MiB is
+    not enough: what it keeps there (its blocks double-buffered, its
+    scratch) and room for the body's own values."""
+    return min(resident_bytes + 24 * 2 ** 20, 100 * 2 ** 20)
+
 
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool):
@@ -769,6 +832,190 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[done:, :] = jnp.zeros((t_pad - done, dv), dv_ref.dtype)
 
 
+def _col_walk_plans(plans, block: int):
+    """The resident backward's reading of a square-block grid's plans
+    (`_bwd_row_kernel`), as `_row_walk_plans` is the forward's, by key tile
+    where that is by query block: (full, edge), each (plan of the tile on
+    the diagonal, plan of the tiles under it) or None. `full` is a key tile
+    whose own query block t_real leaves whole, `edge` the one t_real cuts:
+    key tile j walks its diagonal tile by `full[0]`, the whole query tiles
+    j + 1 .. under it by `full[1]` and the cut one, if there is one, by
+    `edge[1]`; the cut key tile has its diagonal tile, `edge[0]`, alone."""
+    by = {(p.diag, p.cut): p for p in plans}
+    cuts = sorted({p.cut for p in plans})
+    pair = lambda cut: (by[0, cut], by.get((block - 1, cut)))
+    full = pair(block) if (0, block) in by else None
+    edge = pair(cuts[0]) if cuts[0] < block else None
+    return full, edge
+
+
+def _rects_at(plan: Optional[SubtilePlan], c0: int):
+    """The query rectangles of `plan`'s key sub-column that holds column
+    `c0`. A plan fuses neighbouring sub-columns that hold the same unmasked
+    runs, so a sub-column of the diagonal tile's plan may be part of one of
+    the plan under it."""
+    if plan is None:
+        return ()
+    return next((rects for at, cols, rects in plan.columns
+                 if at <= c0 < at + cols), ())
+
+
+def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale: float,
+                    t_real: int, block: int, plans):
+    """Several blocks a head, the head resident: dq, dk and dv in ONE kernel,
+    what `_bwd_fused_kernel` is for one tile. s, p, dp and ds are formed
+    once a rectangle and feed all three (5 MXU dots where `_dq_kernel` and
+    `_dkv_kernel` run 7). Grid (b*hkv, group) and refs as there: q, do, lse
+    and delta of one query head, k and v of its kv head, whole rows.
+
+    The walk is key tile by key tile, and inside one key sub-column by key
+    sub-column: its rectangles in the tile on the diagonal by that tile's
+    static plan, then a loop over the query tiles under it whose body is
+    the sub-column of the unmasked tile's plan (one merged rectangle a
+    query tile), then the query tile t_real cuts, if any. dk and dv of the
+    sub-column, formed transposed, are the loop's carry and are complete
+    when it ends; dq accumulates in float32 scratch over the whole row. The
+    key tiles are a loop too: every one left of t_real's tile walks the
+    same plans, so the kernel's text is at most four tiles' plans whatever
+    the sequence's length (`_col_walk_plans`)."""
+    if kv_acc:
+        dk_acc, dv_acc = kv_acc
+        g, group = pl.program_id(1), pl.num_programs(1)
+
+        @pl.when(g == 0)
+        def _init():
+            dk_acc[:] = jnp.zeros_like(dk_acc)
+            dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    t_pad, d = dq_ref.shape
+    dv = dv_ref.shape[-1]
+    n_full = t_real // block                # query (and key) tiles left whole
+    full, edge = _col_walk_plans(plans, block)
+    dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def rows_of(tile, r0, rows):
+        if isinstance(tile, int):
+            return slice(tile * block + r0, tile * block + r0 + rows)
+        return pl.ds(pl.multiple_of(tile * block + r0, math.gcd(block, r0)),
+                     rows)
+
+    def rect(plan, tile, acc, k, v, r0, rows, c0, cols, masked):
+        """One rectangle of `plan` in query tile `tile`: its share of the
+        sub-column's (dk^T, dv^T) and of dq."""
+        rs = rows_of(tile, r0, rows)
+        q, do = q_ref[rs, :], do_ref[rs, :]
+        p, ds = _rect_p_ds(plan, (r0, rows, c0, cols, masked), q, k, v, do,
+                           lse_ref[rs, :], delta_ref[rs, :], scale)
+        dq_acc[rs] += _dot(ds, k, _NN)
+        return (acc[0] + _dot(jnp.transpose(q), ds, _NN),    # (d, cols)
+                acc[1] + _dot(jnp.transpose(do), p, _NN))
+
+    def key_tile(kb, diag, under, cut_tile):
+        """Key tile `kb`: its diagonal tile by `diag`, query tiles kb + 1 ..
+        n_full - 1 by `under` and query tile n_full by `cut_tile`, where
+        those exist."""
+        for c0, cols, rects in diag.columns:
+            cs = rows_of(kb, c0, cols)
+            k, v = k_ref[cs, :], v_ref[cs, :]
+            acc = (jnp.zeros((d, cols), jnp.float32),
+                   jnp.zeros((dv, cols), jnp.float32))
+            for r0, rows, masked in rects:
+                acc = rect(diag, kb, acc, k, v, r0, rows, c0, cols, masked)
+            under_rects = _rects_at(under, c0)
+            if under_rects:
+                def q_tile(qi, acc, c0=c0, cols=cols, k=k, v=v,
+                           under_rects=under_rects):
+                    for r0, rows, masked in under_rects:
+                        acc = rect(under, qi, acc, k, v, r0, rows, c0, cols,
+                                   masked)
+                    return acc
+                acc = jax.lax.fori_loop(kb + 1, n_full, q_tile, acc)
+            for r0, rows, masked in _rects_at(cut_tile, c0):
+                acc = rect(cut_tile, n_full, acc, k, v, r0, rows, c0, cols,
+                           masked)
+            dkt, dvt = acc
+            if kv_acc:
+                dk_acc[cs] += jnp.transpose(dkt)
+                dv_acc[cs] += jnp.transpose(dvt)
+            else:
+                dk_ref[cs, :] = jnp.transpose(dkt).astype(dk_ref.dtype)
+                dv_ref[cs, :] = jnp.transpose(dvt).astype(dv_ref.dtype)
+
+    done = n_full * block                   # key columns walked
+    if full:
+        def whole_key_tile(kb, carry):
+            key_tile(kb, full[0], full[1], edge and edge[1])
+            return carry
+        jax.lax.fori_loop(0, n_full, whole_key_tile, 0)
+    if edge:
+        key_tile(n_full, edge[0], None, None)
+        done += sum(edge[0].columns[-1][:2])
+    dq_ref[...] = dq_acc[:].astype(dq_ref.dtype)
+
+    if kv_acc:
+        @pl.when(g == group - 1)
+        def _finalize():
+            dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
+    elif done < t_pad:                      # key columns wholly past t_real
+        dk_ref[done:, :] = jnp.zeros((t_pad - done, d), dk_ref.dtype)
+        dv_ref[done:, :] = jnp.zeros((t_pad - done, dv), dv_ref.dtype)
+
+
+def _bwd_resident_bytes(t_pad: int, d: int, dv: int, itemsize: int,
+                        group: int) -> int:
+    """What `_bwd_row_kernel` keeps of a head in VMEM, as Mosaic lays it out
+    (each width padded to 128 lanes): the nine whole-row blocks
+    double-buffered, the float32 dq accumulator and, under grouped-query
+    attention, dk's and dv's."""
+    wide, narrow = _round_up(d, 128), _round_up(dv, 128)
+    blocks = t_pad * ((4 * wide + 3 * narrow) * itemsize + 2 * 128 * 4)
+    scratch = t_pad * 4 * (wide + (wide + narrow if group > 1 else 0))
+    return 2 * blocks + scratch
+
+
+def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
+                  hq: int, hkv: int, interpret: bool, resident: int):
+    bh, t_pad, d = q.shape
+    dv = v.shape[-1]
+    bhkv = k.shape[0]
+    group = hq // hkv
+    num_b = t_pad // block
+    row = lambda width, of_q: pl.BlockSpec(
+        (None, t_pad, width),
+        (lambda b, g: (_q_row(b, g, hq, hkv), 0, 0)) if of_q
+        else (lambda b, g: (b, 0, 0)))
+    acc = lambda width: pltpu.VMEM((t_pad, width), jnp.float32)
+    entries = bh * causal_plan_stats(t_pad, block, block, t_real, d,
+                                     backward=True)["work_elems"]
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_row_kernel, scale=1.0 / math.sqrt(d), t_real=t_real,
+            block=block,
+            plans=_tile_plans(block, block, num_b, num_b, t_real, d,
+                              backward=True)),
+        grid=(bhkv, group),
+        in_specs=[row(d, True), row(d, False), row(dv, False),
+                  row(dv, True), row(1, True), row(1, True)],
+        out_specs=[row(d, True), row(d, False), row(dv, False)],
+        out_shape=[_out_struct((bh, t_pad, d), q.dtype, q),
+                   _out_struct((bhkv, t_pad, d), k.dtype, q),
+                   _out_struct((bhkv, t_pad, dv), v.dtype, q)],
+        scratch_shapes=[acc(d)] + ([acc(d), acc(dv)] if group > 1 else []),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(resident)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * (3 * d + 2 * dv) * entries,
+            bytes_accessed=(2 * (q.size + k.size + v.size) + do.size)
+            * q.dtype.itemsize + 8 * bh * t_pad,
+            transcendentals=entries),
+        interpret=interpret,
+        name="flash_bwd",
+    )(q, k, v, do, lse, delta)
+
+
 def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool):
     bh, t_pad, d = q.shape
@@ -790,7 +1037,29 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     # never discharges, so real hardware always takes the fused path; the
     # CPU grad tests outside shard_map still cover its math.
     interp_vma = interpret and getattr(jax.typeof(q), "vma", None)
-    if num_qb == 1 and num_kb == 1 and not interp_vma:
+    # Several blocks a head: where the blocks are square and what the head's
+    # backward keeps in VMEM fits the budget, one kernel holds the head and
+    # walks its tiles itself (`_bwd_row_kernel`); otherwise the grid walks
+    # them, dq apart from dk and dv. Decided from what this call sees.
+    resident = _bwd_resident_bytes(t_pad, d, dv, q.dtype.itemsize, group)
+    if interp_vma:
+        walk = "grid"
+    elif num_qb == 1 and num_kb == 1:
+        walk = "tile"
+    elif block_q == block_k and resident <= BWD_ROW_VMEM_BYTES:
+        walk = "row"
+    else:
+        walk = "grid"
+    tracer = current_tracer()
+    if tracer is not None:
+        tracer.instant("flash_bwd_walk", walk=walk, t=t_pad, d=d, dv=dv,
+                       group=group, resident_bytes=resident,
+                       budget_bytes=BWD_ROW_VMEM_BYTES)
+    if walk == "row":
+        return _bwd_row_call(q, k, v, do, lse, delta, t_real=t_real,
+                             block=block_q, hq=hq, hkv=hkv,
+                             interpret=interpret, resident=resident)
+    if walk == "tile":
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
         q_t1 = pl.BlockSpec((None, t_pad, 1),
@@ -924,7 +1193,10 @@ _BLOCK_TABLE: Dict[Tuple[int, int, str, str], BlockConfig] = {
     # live. Swept in PR 34 (FWD_SUBTILE_WIDE's table, b*h 128): the forward
     # 6.90 / 6.09 / 5.72 ms at blocks 512 / 1024 / 2048 with K and V
     # resident, the backward's two kernels 29.1 / 22.8 ms at 512 / 1024 and
-    # over the scoped VMEM at 2048.
+    # over the scoped VMEM at 2048. Since PR 40 the backward here is one
+    # kernel with the head resident, 13.43 / 12.49 ms at 512 / 1024 (the note
+    # under FWD_SUBTILE_WIDE); the two kernels are what a head over
+    # `BWD_ROW_VMEM_BYTES` keeps.
     (4096, 192, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
 }
 # key -> {source: sweep|online, capture, ts} provenance (ISSUE 16): an

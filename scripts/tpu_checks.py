@@ -1,10 +1,11 @@
 """Compiled-on-hardware validation of the Pallas kernels against the XLA
-oracles: flash attention fwd+bwd (the train default, the fused and the
-split block paths, GQA routing), the positional block kernel (ring
-attention's building block) o + lse + bwd, and the paged-attention kernel
-(decode and a prefill chunk, native and int8 pools, `return_lse`) against
-`models.decode._gather_page_view`. Also asks the timer question every
-later measurement rests on: does `block_until_ready` wait for the device?
+oracles: flash attention fwd+bwd (the train default, the fused, the
+resident and the split block paths, GQA routing), the positional block
+kernel (ring attention's building block) o + lse + bwd, and the
+paged-attention kernel (decode and a prefill chunk, native and int8 pools,
+`return_lse`) against `models.decode._gather_page_view`. Also asks the
+timer question every later measurement rests on: does `block_until_ready`
+wait for the device?
 
 Usage: python scripts/tpu_checks.py [--out kernel_checks.json]
 Prints PASS/FAIL lines with per-kernel compile+run timings; exits nonzero
@@ -189,20 +190,24 @@ def main():
     loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
 
     # --- flash attention: the train default (t=1000 MHA, table blocks),
-    # then GQA-routed at the fused (t <= block) and split block paths
-    flash_cases = [("default", 1000, None, 8, 8),
-                   ("gqa fused", 512, 1024, 8, 2),
-                   ("gqa split", 1000, 512, 8, 2)]
-    if interp:  # one GQA case through the split kernels exercises it all
-        flash_cases = [("gqa split", 200, 128, 2, 1)]
-    for tag, t, blk, hq, hkv in flash_cases:
+    # then GQA-routed through the backward's three walks: one tile a head
+    # (t <= block), two square blocks with the head resident, and the split
+    # kernels (a backward key block of another size than the query block)
+    flash_cases = [("default", 1000, None, None, 8, 8),
+                   ("gqa fused", 512, 1024, 1024, 8, 2),
+                   ("gqa resident", 1000, 512, 512, 8, 2),
+                   ("gqa split", 1000, 512, 1024, 8, 2)]
+    if interp:  # GQA over several blocks, both walks, exercises it all
+        flash_cases = [("gqa resident", 200, 128, 128, 2, 1),
+                       ("gqa split", 200, 128, 256, 2, 1)]
+    for tag, t, blk, bwd_blk_k, hq, hkv in flash_cases:
         b, d = (1, 16) if interp else (2, 64)
         q = jax.random.normal(jax.random.fold_in(key, 1), (b, hq, t, d), dtype)
         k = jax.random.normal(jax.random.fold_in(key, 2), (b, hkv, t, d), dtype)
         v = jax.random.normal(jax.random.fold_in(key, 3), (b, hkv, t, d), dtype)
         flash = lambda q, k, v: flash_attention(
             q, k, v, block_q=blk, block_k=blk, bwd_block_q=blk,
-            bwd_block_k=blk, interpret=interp)
+            bwd_block_k=bwd_blk_k, interpret=interp)
         check(f"flash fwd [{tag}]", lambda: jax.jit(flash)(q, k, v),
               causal_attention_xla(q, k, v), tol)
         check_grads(f"flash [{tag}]", loss(flash),

@@ -28,6 +28,18 @@ constants are in the notes beside them, one command a table:
         --dv 128 --blocks 512,1024,2048 \
         --edges 128x256,128x512,256x256,256x512,512x256
 
+`--backward` takes the backward of several blocks a head apart, one call
+alone at `--bh --t --d --dv --group` (b*h counts query heads): the resident
+walk (`_bwd_row_kernel`, one `flash_bwd` call) whole, its DMA alone, each
+of its five products knocked out (wrong numbers, right time), and the two
+split kernels it replaces in the same process, with the largest difference
+between the two walks' dq, dk and dv. The tables beside BWD_SUBTILE:
+
+    python scripts/tune_flash_blocks.py --backward --bh 128 --t 4096 \
+        --d 192 --dv 128
+    python scripts/tune_flash_blocks.py --backward --bh 64 --t 8192 --d 64 \
+        --group 4
+
 `--paged` sweeps the PAGED-attention kernel instead (ISSUE 14):
 pages_per_block per (page_size, kv_dtype) serving decode shape
 (ops/pallas/paged_attention.py's autotuner table; --write_cache persists
@@ -215,11 +227,109 @@ def sweep_subtiles(bhs, edges, t=1024, d=64, dv=None, blocks=None,
     return rows
 
 
+# the order in which a rectangle of `_bwd_row_kernel` calls `_dot`
+BACKWARD_PRODUCTS = ("s", "dp", "dq", "dk", "dv")
+
+
+def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
+    """The backward at several blocks a head, a call alone (module
+    docstring). The knock-outs are made here and not in the kernel: `_dot`
+    is replaced by one that counts a rectangle's five calls and returns
+    zeros for one of them, and the kernel's body by one that only writes
+    its outputs for the DMA's time. Returns {reading: ms}."""
+    import distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
+        as fa
+    dv = dv or d
+    key = jax.random.PRNGKey(bh)
+    kq, kk, kv_, kd = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (bh, t, d), jnp.bfloat16)
+    k = jax.random.normal(kk, (bh // group, t, d), jnp.bfloat16)
+    v = jax.random.normal(kv_, (bh // group, t, dv), jnp.bfloat16)
+    do = jax.random.normal(kd, (bh, t, dv), jnp.bfloat16)
+    kw = dict(t_real=t, block_q=block, block_k=block, hq=group, hkv=1,
+              interpret=False)
+    # a real forward's o and lse: the two walks' numbers are compared
+    o, lse = jax.jit(lambda q, k, v: fa._fwd_call(q, k, v, **kw))(q, k, v)
+    args = (q, k, v, o, lse, do)
+    real_dot, real_kernel, budget = (fa._dot, fa._bwd_row_kernel,
+                                     fa.BWD_ROW_VMEM_BYTES)
+    resident = fa._bwd_resident_bytes(t, d, dv, 2, group)
+    print(f"backward alone: bh{bh} t{t} d{d}/{dv} group {group} block "
+          f"{block} sub {fa.BWD_SUBTILE}; the head resident is "
+          f"{resident / 2 ** 20:.1f} MiB of a budget of "
+          f"{budget / 2 ** 20:.0f}", flush=True)
+
+    def without(product):
+        calls = itertools.count()
+
+        def dot(a, b, dims):
+            if BACKWARD_PRODUCTS[next(calls) % len(BACKWARD_PRODUCTS)] \
+                    != product:
+                return real_dot(a, b, dims)
+            cols = b.shape[0] if dims == fa._NT else b.shape[1]
+            return jnp.zeros((a.shape[0], cols), jnp.float32)
+        return dot
+
+    def dma_alone(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                  dk_ref, dv_ref, *scratch, **static):
+        for ref in (dq_ref, dk_ref, dv_ref):
+            ref[...] = jnp.zeros(ref.shape, ref.dtype)
+
+    out, grads = {}, {}
+
+    def reading(tag, match="flash_bwd"):
+        fn = jax.jit(lambda *a: fa._bwd_call(*a, **kw))
+        t0 = time.perf_counter()
+        try:
+            compiled = fn.lower(*args).compile()
+        except Exception as e:  # noqa: BLE001
+            print(f"  {tag:28s} FAILED {type(e).__name__}: "
+                  f"{str(e)[-300:]!r}", flush=True)
+            return
+        compile_s = time.perf_counter() - t0
+        out[tag] = device_ms(compiled, *args, match=match, iters=iters)
+        grads[tag] = compiled(*args)
+        print(f"  {tag:28s} {out[tag]:8.3f} ms   (trace, lower and compile "
+              f"{compile_s:5.1f} s)", flush=True)
+
+    try:
+        reading("row walk, whole")
+        fa._bwd_row_kernel = dma_alone
+        reading("row walk, DMA alone")
+        fa._bwd_row_kernel = real_kernel
+        for product in BACKWARD_PRODUCTS:
+            fa._dot = without(product)
+            reading(f"row walk, no {product}")
+        fa._dot = real_dot
+        fa.BWD_ROW_VMEM_BYTES = 0
+        reading("split kernels, dq + dkv")
+        reading("split kernels, dq", match="flash_bwd_dq")
+        reading("split kernels, dkv", match="flash_bwd_dkv")
+    finally:
+        fa._dot, fa._bwd_row_kernel, fa.BWD_ROW_VMEM_BYTES = (
+            real_dot, real_kernel, budget)
+    if {"row walk, whole", "split kernels, dq + dkv"} <= set(grads):
+        for name, a, b in zip(("dq", "dk", "dv"), grads["row walk, whole"],
+                              grads["split kernels, dq + dkv"]):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            print(f"  {name}: row walk against split kernels, largest "
+                  f"difference {float(jnp.abs(a - b).max()):.3e} of "
+                  f"{float(jnp.abs(b).max()):.3e}", flush=True)
+    return out
+
+
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--subtile", action="store_true",
                     help="sweep the causal sub-tile edge inside one grid "
                          "tile (forward and backward calls timed apart)")
+    ap.add_argument("--backward", action="store_true",
+                    help="the backward of several blocks a head alone at "
+                         "--bh --t --d --dv --group: the resident walk "
+                         "whole, its DMA, each product knocked out, and "
+                         "the two split kernels")
+    ap.add_argument("--group", type=int, default=1,
+                    help="--backward: query heads a kv head")
     ap.add_argument("--bh", default="192,80",
                     help="--subtile: comma-separated batch*heads a chip")
     ap.add_argument("--edges", default="128,256,512,1024",
@@ -291,6 +401,12 @@ def main():
 
     if args.paged:
         return sweep_paged(args)
+    if args.backward:
+        for bh in args.bh.split(","):
+            for block in (args.blocks or "1024").split(","):
+                sweep_backward(int(bh), args.t, args.d, args.dv, args.group,
+                               int(block), iters=min(args.iters, 10))
+        return
     if args.subtile:
         return sweep_subtiles([int(x) for x in args.bh.split(",")],
                               [tuple(int(e) for e in (x.split("x") * 2)[:2])

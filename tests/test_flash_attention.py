@@ -921,3 +921,272 @@ def test_the_row_fits_by_bytes_alone(monkeypatch):
     monkeypatch.undo()
     fits = lambda t: 2 * t * (256 + 128) * 2 <= fa_mod.KV_ROW_VMEM_BYTES
     assert fits(4096) and not fits(8192)
+
+
+# ------------------------------- the backward of several blocks a head
+#
+# Where the blocks are square and what a head's backward keeps in VMEM fits
+# `BWD_ROW_VMEM_BYTES`, ONE kernel holds the head and makes dq, dk and dv
+# from one s and one dp a rectangle (`_bwd_row_kernel`, the call named
+# `flash_bwd`); rows over the budget keep `_dq_kernel` + `_dkv_kernel`, one
+# tile a head keeps `_bwd_fused_kernel`. The cases below run two and four
+# blocks a head: block 512 is four sub-tiles a tile (two key sub-columns,
+# the under-diagonal tile's fused into one), block 256 one.
+
+
+def _bwd_kernels(q, k, v, **kw):
+    """Names of the backward's pallas_calls, in order."""
+    def calls(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from calls(sub)
+
+    grad = jax.grad(lambda *a: jnp.sum(flash_attention(*a, **kw)
+                                       .astype(jnp.float32)), (0, 1, 2))
+    return [n for n in calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+            if n.startswith("flash_bwd")]
+
+
+def _bwd_call_jaxpr(bh, bhkv, t, d, dv, interpret):
+    """`_bwd_call` traced at bf16 shapes alone, the table's blocks."""
+    sds = jax.ShapeDtypeStruct
+    args = (sds((bh, t, d), jnp.bfloat16), sds((bhkv, t, d), jnp.bfloat16),
+            sds((bhkv, t, dv), jnp.bfloat16), sds((bh, t, dv), jnp.bfloat16),
+            sds((bh, t, 1), jnp.float32), sds((bh, t, dv), jnp.bfloat16))
+    return jax.make_jaxpr(lambda *a: fa_mod._bwd_call(
+        *a, t_real=t, block_q=1024, block_k=1024, hq=bh // bhkv, hkv=1,
+        interpret=interpret))(*args)
+
+
+def _square(block, **kw):
+    return dict(block_q=block, block_k=block, bwd_block_q=block,
+                bwd_block_k=block, **kw)
+
+
+def _grads(fn, q, k, v, g):
+    return jax.grad(lambda *a: jnp.vdot(fn(*a), g).real.astype(jnp.float32),
+                    (0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("block,blocks", [(512, 2), (256, 4)])
+@pytest.mark.parametrize("d,dv", [(64, 64), (128, 128), (192, 128)])
+def test_bwd_row_walk_matches_oracle(d, dv, block, blocks, dtype, tol):
+    """Gradients against the XLA oracle at the three pairs of widths the
+    benchmark's cells run, two and four blocks a head: the one kernel is
+    taken (one `flash_bwd` call where the split walk is two)."""
+    t = block * blocks
+    q, k, v, g = _qkv(d + blocks, 1, 1, 1, t, d, dtype, n=4, dv=dv)
+    kw = _square(block)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd"]
+    gr = _grads(causal_attention_xla, q, k, v, g)
+    gf = _grads(lambda *a: flash_attention(*a, **kw), q, k, v, g)
+    for a, b in zip(gr, gf):
+        assert b.dtype == dtype and _rel(a, b) < 10 * tol
+
+
+@pytest.mark.parametrize("group", [1, 4, 8])
+def test_bwd_row_walk_gqa_groups(group):
+    """Grouped query heads: dk and dv of a kv head accumulate in float32
+    scratch over the group's grid steps, dq leaves a query head a step."""
+    q, k, v, g = _qkv(group, 2, 8, 8 // group, 1024, 24, n=4, dv=16)
+    tr = 900
+    kw = _square(256, t_real=tr)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd"]
+    gr = _grads(lambda *a: _sliced_oracle(*a, tr), q, k, v, g)
+    gf = _grads(lambda *a: flash_attention(*a, **kw), q, k, v, g)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert not jnp.any(b[:, :, tr:])
+
+
+@pytest.mark.parametrize("block,t_real", [
+    (256, 600),     # in the third of four blocks, cutting its one sub-tile
+    (256, 512),     # on a block edge: two whole key tiles, two dead
+    (256, 100),     # in the first block: the cut key tile alone
+    (512, 700),     # in the second of two blocks, cutting a sub-tile
+    (512, 768),     # on a sub-tile edge inside the second block
+    (512, 1023)])   # one pad row
+@pytest.mark.parametrize("group", [1, 2])
+def test_bwd_row_walk_t_real_exact_zeros_with_tail_cotangent(block, t_real,
+                                                             group):
+    """t_real against a resident head: live rows match the sliced oracle,
+    and a nonzero cotangent on the rows at or past it yields exact zero
+    gradients — from the key tiles never walked, from the cut tile's masked
+    rectangles and, under grouped heads, from the accumulators' zeros."""
+    q, k, v, g = _qkv(t_real, 1, 2, 2 // group, 1024, 24, n=4, dv=16)
+    kw = _square(block, t_real=t_real)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd"]
+    gr = _grads(lambda *a: _sliced_oracle(*a, t_real), q, k, v, g)
+    gf = _grads(lambda *a: flash_attention(*a, **kw), q, k, v, g)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert jnp.abs(b[:, :, t_real:]).max() == 0.0
+
+
+@pytest.mark.parametrize("block,sub", [
+    (512, (256, 256)), (1024, (256, 256)), (256, (256, 256)),
+    (512, (128, 128)), (1024, (512, 256)), (1024, (256, 512))])
+def test_bwd_row_walk_runs_each_tiles_plan_once(monkeypatch, block, sub):
+    """The kernel's walk, by `_col_walk_plans` and `_rects_at` as it makes
+    it: key tile j left of t_real's tile walks the whole diagonal plan at
+    (j, j), the whole under-diagonal plan at query tiles j + 1 .. and the
+    cut one's at t_real's tile, sub-column by sub-column of the diagonal
+    plan; t_real's key tile walks its diagonal tile alone. Each plan is THE
+    plan of the tile it stands in, every live entry is computed once, no
+    dead one unmasked, no tile above the diagonal has a plan, and what is
+    walked is what `causal_plan_stats` counts."""
+    monkeypatch.setattr(fa_mod, "BWD_SUBTILE", sub)
+    fa_mod.causal_subtile_plan.cache_clear()
+    t_pad, n = 2048, 2048 // block
+    tile = lambda qb, kb, t_real: fa_mod.causal_subtile_plan(
+        block, block, qb, kb, t_real, 64, True, n)
+    for t_real in (1, 127, 128, 129, 700, 1000, 1024, 1025, 1536, 2048):
+        full, edge = fa_mod._col_walk_plans(
+            fa_mod._tile_plans(block, block, n, n, t_real, 64,
+                               backward=True), block)
+        n_full = t_real // block
+        assert (full is None) == (n_full == 0)
+        assert (edge is None) == (t_real % block == 0)
+        row = np.arange(t_pad)[:, None]
+        live = (np.arange(t_pad)[None, :] <= row) & (row < t_real)
+        times = np.zeros(live.shape, np.int8)
+        unmasked = np.zeros(live.shape, bool)
+        elems = 0
+
+        def walk(plan, qb, kb, c0, cols):
+            nonlocal elems
+            assert plan == tile(qb, kb, t_real)
+            for r0, rows, masked in fa_mod._rects_at(plan, c0):
+                at = (slice(qb * block + r0, qb * block + r0 + rows),
+                      slice(kb * block + c0, kb * block + c0 + cols))
+                times[at] += 1
+                unmasked[at] |= not masked
+                elems += rows * cols
+
+        def key_tile(kb, diag, under, cut_tile):
+            for c0, cols, _ in diag.columns:
+                walk(diag, kb, kb, c0, cols)
+                for qi in range(kb + 1, n_full):    # the kernel's fori_loop
+                    walk(under, qi, kb, c0, cols)
+                if cut_tile is not None:
+                    walk(cut_tile, n_full, kb, c0, cols)
+
+        for kb in range(n_full):                    # the kernel's fori_loop
+            if n_full > 1:
+                assert full[1] is not None
+            key_tile(kb, full[0], full[1], edge and edge[1])
+        if edge:
+            key_tile(n_full, edge[0], None, None)
+        assert not any(tile(qb, kb, t_real).bands
+                       for qb in range(n) for kb in range(qb + 1, n))
+        assert (times[live] == 1).all() and times.max() == 1
+        assert live[unmasked].all()
+        stats = fa_mod.causal_plan_stats(t_pad, block, block, t_real, 64,
+                                         backward=True)
+        assert elems == stats["work_elems"]
+    fa_mod.causal_subtile_plan.cache_clear()
+
+
+@pytest.mark.parametrize("block,t_real,group", [
+    (512, 1024, 1), (512, 900, 2), (256, 600, 1), (256, 1024, 4)])
+def test_split_kernels_where_the_head_does_not_fit(monkeypatch, block,
+                                                   t_real, group):
+    """A head over the budget keeps `_dq_kernel` and `_dkv_kernel`, square
+    blocks too, and they still match the oracle."""
+    monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", 0)
+    q, k, v, g = _qkv(t_real, 1, 4, 4 // group, 1024, 24, n=4, dv=16)
+    kw = _square(block, t_real=t_real)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd_dq", "flash_bwd_dkv"]
+    gr = _grads(lambda *a: _sliced_oracle(*a, t_real), q, k, v, g)
+    gf = _grads(lambda *a: flash_attention(*a, **kw), q, k, v, g)
+    for a, b in zip(gr, gf):
+        assert jnp.abs(a - b).max() < 1e-4
+        assert not jnp.any(b[:, :, t_real:])
+
+
+def test_the_head_fits_by_bytes_alone(monkeypatch):
+    """The backward's walk is chosen from what `_bwd_call` sees: one byte
+    under what the head keeps in VMEM (nine whole-row blocks double-buffered,
+    each width padded to 128 lanes, lse and delta 128 lanes of one value,
+    and the float32 accumulators) and the grid walks; at it, one kernel."""
+    q, k, v = _qkv(3, 1, 2, 1, 1024, 24, dv=16)
+    kw = _square(256)
+    lanes = 128
+    need = 1024 * (2 * ((4 * lanes + 3 * lanes) * 4 + 2 * lanes * 4)
+                   + 4 * (lanes + lanes + lanes))
+    assert fa_mod._bwd_resident_bytes(1024, 24, 16, 4, 2) == need
+    # without grouped heads dk and dv leave from values: one accumulator
+    assert fa_mod._bwd_resident_bytes(1024, 24, 16, 4, 1) \
+        == need - 1024 * 4 * 2 * lanes
+    monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", need)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd"]
+    monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", need - 1)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd_dq", "flash_bwd_dkv"]
+    # blocks that are not square leave the diagonal crossing several tiles
+    monkeypatch.undo()
+    assert _bwd_kernels(q, k, v, block_q=256, block_k=256, bwd_block_q=256,
+                        bwd_block_k=512) == ["flash_bwd_dq", "flash_bwd_dkv"]
+    # the benchmark's cells: latent attention at 4k (34 MiB) and a group of
+    # four heads of 64 at 8k (56) sit inside the shipped budget, a group of
+    # eight heads of 256 at 8k (96) does not
+    mib = lambda *a: fa_mod._bwd_resident_bytes(*a) / 2 ** 20
+    assert mib(4096, 192, 128, 2, 1) == 34 and mib(8192, 64, 64, 2, 4) == 56
+    assert mib(8192, 256, 256, 2, 8) == 96
+    assert 56 <= fa_mod.BWD_ROW_VMEM_BYTES / 2 ** 20 < 96
+    # and what the kernel asks Mosaic for leaves the body its room
+    assert fa_mod._vmem_limit(56 * 2 ** 20) == 80 * 2 ** 20
+
+
+def test_flash_bwd_walk_instant_says_which_walk(tmp_path):
+    """At trace time `_bwd_call` says on the program's tracer which walk it
+    took and the bytes it reckoned: `row` for a resident head, `grid` for
+    one over the budget (or blocks not square), `tile` for one tile."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    trace = functools.partial(_bwd_call_jaxpr, interpret=True)
+    tracer = SpanTracer(str(tmp_path))
+    try:
+        trace(2, 2, 4096, 192, 128)     # the latent-attention cell's head
+        trace(8, 1, 8192, 256, 256)     # the hybrid cell's: over the budget
+        trace(2, 2, 1024, 64, 64)       # the GPT-2 cells': one tile
+        trace(4, 1, 8192, 64, 64)       # the conv cell's group of four
+    finally:
+        tracer.close()
+    events = [json.loads(line)["args"] for line in
+              open(tmp_path / "trace.jsonl")
+              if json.loads(line)["name"] == "flash_bwd_walk"]
+    assert [(e["walk"], e["t"], e["d"], e["dv"], e["group"],
+             e["resident_bytes"] // 2 ** 20) for e in events] == [
+        ("row", 4096, 192, 128, 1, 34), ("grid", 8192, 256, 256, 8, 96),
+        ("tile", 1024, 64, 64, 1, 6), ("row", 8192, 64, 64, 4, 56)]
+    assert all(e["budget_bytes"] == fa_mod.BWD_ROW_VMEM_BYTES for e in events)
+
+
+# `_bwd_call`'s jaxpr, kernel bodies and all, as the commit before the
+# resident walk traced it (PR 39's tree; sha256 of the text with object
+# addresses stripped, first 16 digits): (q heads, kv heads, t, d, dv)
+BWD_JAXPR_BEFORE = {
+    "one tile a head (the GPT-2 cells)": ((4, 4, 1024, 64, 64),
+                                          "3375b09bb17b328d"),
+    "a head over the budget (the hybrid cell)": ((16, 2, 8192, 256, 256),
+                                                "11ba9961703d20f2"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(BWD_JAXPR_BEFORE))
+def test_bwd_call_keeps_the_other_walks_text(which):
+    """The resident walk took the rows it fits and nothing else: one tile a
+    head traces the one-tile kernel and a head over the budget the two split
+    kernels, to the character what they were. A PR that means to change
+    either changes the digest with it."""
+    import hashlib
+    import re
+    shape, digest = BWD_JAXPR_BEFORE[which]
+    text = str(_bwd_call_jaxpr(*shape, interpret=False))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

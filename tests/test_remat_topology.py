@@ -217,6 +217,44 @@ def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
+@pytest.mark.parametrize("t,d,dv,group,t_real,names", [
+    # the latent-attention cell's head, 34 MiB resident
+    (4096, 192, 128, 1, 4096, ["flash_bwd"]),
+    # t_real cuts the third block: the cut tiles' plans beside the whole ones
+    (4096, 192, 128, 1, 3000, ["flash_bwd"]),
+    # the conv cell's group of four heads of 64 at 8k, 56 MiB: the most the
+    # budget admits among the benchmark's cells
+    (8192, 64, 64, 4, 8192, ["flash_bwd"]),
+    # the hybrid cell's group of eight heads of 256 at 8k, 96 MiB: over it
+    (8192, 256, 256, 8, 8192, ["flash_bwd_dq", "flash_bwd_dkv"])])
+def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
+        topo, described_tpu, t, d, dv, group, t_real, names):
+    """Mosaic takes the multi-block backward that keeps the whole head in
+    VMEM (PR 40) at the table's blocks with the scoped VMEM `_bwd_row_call`
+    asks for, at the shapes `BWD_ROW_VMEM_BYTES` admits; a head over the
+    budget compiles the two split kernels as before."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+        flash_attention as fa)
+    blocks = fa.get_block_config(t, d, jnp.bfloat16)
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (rows, t, w), dtype, sharding=chip)
+    fits = fa._bwd_resident_bytes(t, d, dv, 2, group) <= fa.BWD_ROW_VMEM_BYTES
+    assert fits == (names == ["flash_bwd"])
+    text = jax.jit(lambda *a: fa._bwd_call(
+        *a, t_real=t_real, block_q=blocks.bwd_block_q,
+        block_k=blocks.bwd_block_k, hq=group, hkv=1,
+        interpret=False)).lower(
+            arg(2 * group, d), arg(2, d), arg(2, dv), arg(2 * group, dv),
+            arg(2 * group, 1, jnp.float32),
+            arg(2 * group, dv)).compile().as_text()
+    # the compiled text lists the two split calls in the scheduler's order
+    assert sorted(name.split(".")[0] for name in re.findall(
+        r"%([\w.\-]+) = [^\n]*? custom-call\([^)]*\), "
+        r'custom_call_target="tpu_custom_call"', text)) == sorted(names)
+
+
 def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
         topo, described_tpu):
     """Mosaic takes the rule's two kernels (PR 36; since PR 38 they make a
@@ -259,9 +297,10 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     picks there: the family's memory facts (`ffn_inputs`,
     `layer_extra_elems_per_token`: at a held share of 1/4 the dispatch's one
     chunk is all 65,536 pairs) are held to the compiler's plan, and Mosaic
-    takes the flash kernels at head 64 under a group of 4 over 16 blocks a
-    head (forward, dq, dk/dv). The chip itself counts 10.92 GiB for this
-    step (PERF.md section 5, PR 39)."""
+    takes the flash kernels at head 64 under a group of 4 over several
+    blocks a head (the forward and, since PR 40, ONE backward kernel with
+    the head resident where there were dq and dk/dv). The chip itself
+    counts 10.92 GiB for this step (PERF.md section 5, PR 39)."""
     from distributed_pytorch_from_scratch_tpu.config import ConvMoEConfig
     from distributed_pytorch_from_scratch_tpu.models import build_model
     cfg = ModelConfig(
@@ -299,4 +338,5 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     assert 11.0 < estimate < planned * 1.01, (estimate, planned)
     kernels = set(re.findall(r"%((?:flash|ragged)[\w\-]*?)[.\d]* = ",
                              compiled.as_text()))
-    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"} <= kernels, kernels
+    assert {"flash_fwd", "flash_bwd"} <= kernels, kernels
+    assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels, kernels
