@@ -134,6 +134,33 @@ class ConvMoEConfig:
 
 
 @dataclass(frozen=True)
+class BdMoEConfig:
+    """What the `bd_moe` family (models/bd_moe.py) needs beyond
+    `ModelConfig`'s own fields: a grouped-query expert decoder whose heads
+    are `head_dim` wide whatever the model's width (heads x head_dim need
+    not be `attn_dim`), with q/k norms and a softmax top-k router with no
+    shared expert over routed experts of which this job may hold a slice,
+    trained by BLOCK DIFFUSION: blocks of `block_length` positions, one
+    noise level a sequence, `mask_token_id` in place of a masked token. The
+    keys are SDAR's `config.json` names (`sdar_moe`) where one exists. In
+    `ModelConfig`, `attn_dim` is the model width, `num_heads` /
+    `num_kv_heads` the heads, `num_experts` the ROUTED experts the router
+    scores and `moe_top_k` the experts a token takes; `ffn_dim` is not
+    read (every layer is an expert layer)."""
+
+    head_dim: int
+    moe_intermediate_size: int
+    block_length: int = 4
+    mask_token_id: int = 0
+    # the noise level's floor: p = (1 - noise_eps) t + noise_eps, t ~ U(0, 1)
+    noise_eps: float = 1e-3
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -171,6 +198,8 @@ class ModelConfig:
     gdn_moe: "GdnMoEConfig | None" = None
     # The `conv_moe` family's facts (None for every other family).
     conv_moe: "ConvMoEConfig | None" = None
+    # The `bd_moe` family's facts (None for every other family).
+    bd_moe: "BdMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -231,6 +260,9 @@ class ModelConfig:
         if self.conv_moe is not None:
             from .models.conv_moe import ConvMoETransformer
             return ConvMoETransformer.num_params(self)
+        if self.bd_moe is not None:
+            from .models.bd_moe import BlockDiffusionMoETransformer
+            return BlockDiffusionMoETransformer.num_params(self)
         d, f, v, L = self.attn_dim, self.ffn_dim, self.vocab_size, self.num_layers
         kd = self.kv_dim
         attn = 2 * d * d + 2 * d * kd + 2 * d + 2 * kd  # wq/wo + wk/wv (+ biases)
@@ -243,7 +275,7 @@ class ModelConfig:
 
 
 # the ModelConfig fields that carry one family's facts each
-FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe")
+FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -301,6 +333,16 @@ MODEL_PRESETS = {
             + ("full_attention", "conv", "conv") * 2
             + ("full_attention", "conv") * 2,
             moe_intermediate_size=32)),
+    # the `bd_moe` family at a CPU size: block-diffusion training in blocks
+    # of 4 positions; 4 query heads over 2 key-value heads of 32 (heads x
+    # width = 128, not the model's 64), q/k norms; 8 routed experts
+    # (softmax top-2, no shared expert); token 1 is the mask token
+    "tiny-bd-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_kv_heads=2, num_layers=2,
+        vocab_size=1024, maxlen=256, rope_theta=1000000.0, num_experts=8,
+        moe_top_k=2, bd_moe=BdMoEConfig(
+            head_dim=32, moe_intermediate_size=32, block_length=4,
+            mask_token_id=1)),
 }
 
 

@@ -1,5 +1,6 @@
 """The model families, and the one place a family's name becomes a class."""
 
+from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
@@ -9,7 +10,8 @@ from .transformer import Transformer
 
 FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer,
             "mla_moe": LatentMoETransformer, "gdn_moe": GdnMoETransformer,
-            "conv_moe": ConvMoETransformer}
+            "conv_moe": ConvMoETransformer,
+            "bd_moe": BlockDiffusionMoETransformer}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -134,9 +134,14 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
                      t_real: Optional[int] = None,
                      head_dim: int = 64,
-                     dtype: str = "bfloat16") -> Dict[str, float]:
+                     dtype: str = "bfloat16", mask=None,
+                     backward: bool = False) -> Dict[str, float]:
     """MXU work the fwd flash kernel performs at this (t, blocks) vs the
     causal ideal — the quantified 't=1000 -> 1024 padding waste' suspect.
+    With `mask` (an `ops/attention.AttnMask` that is not the triangle) the
+    same under the declared mask: the ideal is the entries it leaves live,
+    the blocks the kernels' own clamp of the table's; `backward` reads the
+    backward's plan (merged rectangles, its own sub-tile).
 
     Reads the kernel's own static plan (`causal_plan_stats`, the function
     the kernels walk and `_fwd_call`'s cost_estimate prices): a tile is a
@@ -147,13 +152,24 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
     whole square was 2.0). `t_real` < t prices the pad-aware bucketed path
     (attn_t_real).
     """
-    from ..ops.pallas.flash_attention import causal_plan_stats
-    tiling = resolve_flash_tiling(t, block_q, block_k, head_dim, dtype)
-    t_pad, bq, bk = tiling["t_pad"], tiling["block_q"], tiling["block_k"]
+    from ..ops.pallas.flash_attention import (CAUSAL, get_block_config,
+                                              mask_block, plan_stats)
     tr = t if t_real is None else t_real
-    plan = causal_plan_stats(t_pad, bq, bk, tr, head_dim)
+    if mask is None or mask.kind == "causal":
+        mask = CAUSAL
+        tiling = resolve_flash_tiling(t, block_q, block_k, head_dim, dtype)
+        t_pad, bq, bk = tiling["t_pad"], tiling["block_q"], tiling["block_k"]
+        ideal = tr * (tr + 1) / 2
+    else:
+        tuned = get_block_config(t, head_dim, dtype)
+        asked = (min(block_q or tuned.bwd_block_q,
+                     block_k or tuned.bwd_block_k) if backward
+                 else min(block_q or tuned.block_q, block_k or tuned.block_k))
+        t_pad, bq = t, mask_block(mask, t, asked)
+        bk = bq
+        ideal = mask.half * (mask.half + mask.block)
+    plan = plan_stats(mask, t_pad, bq, bk, tr, head_dim, backward)
     live = plan["computed_unmasked"] + plan["computed_masked"]
-    ideal = tr * (tr + 1) / 2
     return {"t_pad": t_pad, "block_q": bq, "block_k": bk,
             "sub_q": plan["sub_q"], "sub_k": plan["sub_k"],
             "live_tiles": live, "total_tiles": live + plan["skipped"],

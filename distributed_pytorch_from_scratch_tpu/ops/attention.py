@@ -1,4 +1,11 @@
-"""Causal self-attention kernels.
+"""Self-attention under a declared mask: the dense XLA path and the dispatch.
+
+A mask is a DECLARATION (`AttnMask`): `CAUSAL`, the triangle every family
+but one runs, and `block_diffusion(B, L)`, the three-part mask of
+block-diffusion training over the 2L rows `[noised ; clean]` of a sequence
+(`mask_matrix` is its one dense definition). The flash kernels plan their
+tiles from the same declaration (ops/pallas/flash_attention.py); the dense
+path here is the CPU default and the kernels' oracle.
 
 `causal_attention_xla` mirrors the reference's naive O(T^2) attention
 (`/root/reference/models/model.py:73-77`): explicit q@k^T / sqrt(d), additive
@@ -11,11 +18,55 @@ fused HBM-friendly path the reference lacks; both produce the same math.
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 MASK_VALUE = -10000.0  # reference uses -10000., model.py:75
+
+
+class AttnMask(NamedTuple):
+    """Which (query row, key row) pairs of a sequence are live, as a static
+    description both attention paths read. `causal`: key <= query.
+    `block_diffusion`: the sequence is 2 * `half` rows, a NOISED copy and
+    then a CLEAN copy of the same `half` positions, in blocks of `block`
+    positions (`blk(i) = i // block`): noised to noised live inside one
+    block (both directions), noised to clean live for EARLIER blocks,
+    clean to clean live for earlier blocks and its own, clean to noised
+    dead. `half * (half + block)` of the `4 * half^2` entries are live."""
+
+    kind: str = "causal"
+    block: int = 1
+    half: int = 0
+
+
+CAUSAL = AttnMask()
+
+
+def block_diffusion(block: int, half: int) -> AttnMask:
+    if block < 1 or half < 1 or half % block:
+        raise ValueError(f"block_diffusion: the block length {block} must "
+                         f"divide the {half} positions of a sequence")
+    return AttnMask("block_diffusion", block, half)
+
+
+def mask_matrix(mask: AttnMask, t: int) -> jax.Array:
+    """(t, t) bool, [query row, key row] live: the declaration, densely."""
+    i = jnp.arange(t)
+    if mask.kind == "causal":
+        return i[None, :] <= i[:, None]
+    L, B = mask.half, mask.block
+    if t != 2 * L:
+        raise ValueError(f"a block_diffusion mask over {L} positions takes "
+                         f"{2 * L} rows, got {t}")
+    noised = i < L
+    blk = (i % L) // B
+    q_noised, k_noised = noised[:, None], noised[None, :]
+    q_blk, k_blk = blk[:, None], blk[None, :]
+    return jnp.where(q_noised,
+                     jnp.where(k_noised, k_blk == q_blk, k_blk < q_blk),
+                     ~k_noised & (k_blk <= q_blk))
 
 
 def repeat_kv(q: jax.Array, k: jax.Array, v: jax.Array):
@@ -48,6 +99,20 @@ def causal_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     mask = jnp.triu(jnp.ones((t, t), dtype=bool), k=1)
     scores = jnp.where(mask[None, None], jnp.asarray(MASK_VALUE, scores.dtype), scores)
+    probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
+
+
+def masked_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
+                         mask: AttnMask) -> jax.Array:
+    """`causal_attention_xla`'s math under a declared mask (every row of a
+    block_diffusion mask has a live entry, so the additive mask is exact
+    as it is there)."""
+    *_, t, head_dim = q.shape
+    k, v = repeat_kv(q, k, v)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(head_dim)
+    scores = jnp.where(mask_matrix(mask, t)[None, None], scores,
+                       jnp.asarray(MASK_VALUE, scores.dtype))
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
@@ -86,4 +151,17 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     # block sizes come from the tuned-block table (get_block_config)
     return flash_attention(q, k, v, t_real=t_real,
+                           interpret=impl == "flash_interpret")
+
+
+def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     mask: AttnMask, impl: str = "auto") -> jax.Array:
+    """`causal_attention` under a declared mask that is not the triangle
+    (a family says which: `DecoderStack._attn_mask`)."""
+    impl = resolve_attention_impl(impl)
+    if impl == "xla":
+        return masked_attention_xla(q, k, v, mask)
+    from .pallas.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, mask=mask,
                            interpret=impl == "flash_interpret")

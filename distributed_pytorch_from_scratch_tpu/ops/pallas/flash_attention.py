@@ -1,4 +1,11 @@
-"""Blockwise causal flash attention for TPU, written in Pallas.
+"""Blockwise flash attention under a declared mask for TPU, written in Pallas.
+
+The mask is a declaration (`ops/attention.AttnMask`), not a second copy of
+the kernels: a tile's static plan, the walks over a head's tiles, the
+cost_estimate and `obs/attribution.flash_tile_stats` all read it. Two
+instances: `CAUSAL` (everything below, as it was before the declaration
+existed) and `block_diffusion(B, L)` (PR 41; "The block-diffusion mask",
+under the sub-tile plan).
 
 The fused HBM-friendly attention path the reference lacks: its naive
 attention materialises the full (b, heads, t, t) score tensor in device
@@ -73,6 +80,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...obs.trace import current_tracer
+from ..attention import CAUSAL, AttnMask
 
 MASK = -1e30  # hard mask; equivalent to the XLA path's -10000 (see module doc)
 
@@ -287,7 +295,12 @@ def _runs(cells, major: int, minor: int, merge: bool):
 
 class SubtilePlan(NamedTuple):
     """What one (block_q x block_k) grid tile computes. Coordinates are
-    tile-relative; entry (r, c) is live iff c <= r + diag and r < cut.
+    tile-relative; entry (r, c) is live iff c <= (r | (stair - 1)) + diag
+    and r < cut, and, with `band`, c >= (r & ~(stair - 1)) + diag too.
+    `stair` is 1 under the causal mask (the plain diagonal) and the block
+    length under the block-diffusion mask (a block's rows share its last
+    row's bound: a STAIRCASE on the diagonal), where `role` says which of
+    the mask's tiles the plan is for (`_bd_role`).
 
     Two views of the same computed sub-tiles, for the two loop orders:
     `bands`: ((r0, rows, ((c0, cols, masked), ...)), ...) — query sub-rows,
@@ -304,6 +317,9 @@ class SubtilePlan(NamedTuple):
     computed_unmasked: int
     computed_masked: int
     skipped: int
+    stair: int = 1
+    band: bool = False
+    role: str = ""
 
     @property
     def work_elems(self) -> int:
@@ -332,12 +348,27 @@ def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
     diag = max(-block_q, min(q_block * block_q - k_block * block_k,
                              block_k - 1))
     cut = max(0, min(t_real - q_block * block_q, block_q))
+    return _plan_of(block_q, block_k, sq, sk, diag, cut, backward)
+
+
+def _plan_of(block_q: int, block_k: int, sq: int, sk: int, diag: int,
+             cut: int, backward: bool, stair: int = 1, band: bool = False,
+             role: str = "") -> SubtilePlan:
+    """The plan of a tile whose live entries are `SubtilePlan`'s rule: each
+    sub-tile skipped (no live entry), unmasked (all live) or masked (a
+    bound crosses it)."""
     cells = []
     for r0 in range(0, block_q, sq):
         last_live = min(r0 + sq, cut) - 1   # < r0: every row is dead
+        # the upper bound of the sub-row's last live row and of its first
+        # (bounds rise with the row); with `band`, the lower bounds too
+        hi_last = (last_live | (stair - 1)) + diag
+        hi_first = (r0 | (stair - 1)) + diag
         cells.append([
-            None if last_live < r0 or c0 > last_live + diag
-            else r0 + sq > cut or c0 + sk - 1 > r0 + diag
+            None if last_live < r0 or c0 > hi_last
+            or (band and c0 + sk - 1 < hi_first - (stair - 1))
+            else r0 + sq > cut or c0 + sk - 1 > hi_first
+            or (band and c0 < hi_last - (stair - 1))
             for c0 in range(0, block_k, sk)])
     flat = [f for row in cells for f in row]
     # the backward walks merged rectangles, the forward single sub-tiles
@@ -345,20 +376,101 @@ def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
     return SubtilePlan(
         sq, sk, diag, cut, _runs(cells, sq, sk, backward),
         _runs([list(col) for col in zip(*cells)], sk, sq, backward),
-        flat.count(False), flat.count(True), flat.count(None))
+        flat.count(False), flat.count(True), flat.count(None),
+        stair, band, role)
 
 
-def causal_plan_stats(t_pad: int, block_q: int, block_k: int, t_real: int,
-                      head_dim: int, backward: bool = False
-                      ) -> Dict[str, int]:
-    """`causal_subtile_plan` summed over the grid of one head."""
+# -------------------------------------------------- the block-diffusion mask
+#
+# `block_diffusion(B, L)` over the 2L rows [noised ; clean] of a sequence
+# (ops/attention.AttnMask has the rule). With square blocks that divide L,
+# no tile spans two quadrants, and with B dividing the sub-tile edges each
+# live quadrant is the causal one with a staircase on its diagonal:
+# `blk(j) <= blk(i)` is `c <= (r | (B - 1))`, `blk(j) < blk(i)` is
+# `c <= (r | (B - 1)) - B`, and the block diagonal is the first with a lower
+# bound `c >= (r & ~(B - 1))`. So a tile wholly under a quadrant's diagonal
+# keeps the causal mask's unmasked plan, only `_rect_live`'s compare changes
+# in the sub-tiles a diagonal crosses, and a tile has one of four plans by
+# its place (`_bd_role`), whatever the sequence's length:
+#
+#   "nn"    noised query block i, noised key tile i: the block diagonal
+#   "nc"    noised query block i, clean key tile i: strictly earlier blocks
+#   "cc"    clean query block i, clean key tile i: earlier blocks and its own
+#   "under" the clean key tiles 0 .. i - 1 of either: all live
+#
+# and every other tile (the clean-query / noised-key quadrant, noised /
+# noised off the diagonal, above the diagonals) is never computed: a query
+# block's walk is the short list (noised i: noised tile i, clean tiles
+# 0 .. i; clean i: clean tiles 0 .. i), by the kernels' loops where the head
+# is resident and by the grid's guards otherwise.
+
+
+def mask_block(mask: AttnMask, t: int, block: int) -> int:
+    """The square grid block the kernels run a block-diffusion mask over `t`
+    rows with, asked for `block`: clamped to the mask's half, so that a tile
+    lies in one quadrant. What they cannot plan is refused."""
+    if t != 2 * mask.half:
+        raise ValueError(f"a block_diffusion mask over {mask.half} positions "
+                         f"takes {2 * mask.half} rows, got {t}")
+    block = min(block, 1 << (mask.half.bit_length() - 1))
+    if 128 % mask.block or block % 128 or mask.half % block:
+        raise ValueError(
+            f"the flash kernels plan a block_diffusion mask whose block "
+            f"length divides 128 and whose half is a multiple of the grid "
+            f"block (128 at least), got block length {mask.block}, half "
+            f"{mask.half}, grid block {block}; use the XLA attention")
+    return block
+
+
+def _bd_role(mask: AttnMask, block: int, qb, kb) -> str:
+    """The role of grid tile (qb, kb), static ints, under the
+    block-diffusion mask; "" for a tile that is never computed."""
+    nh = mask.half // block         # tiles a half, along either side
+    if kb < nh:
+        return "nn" if kb == qb else ""
+    i = qb % nh                     # the query block's place in its half
+    if kb - nh == i:
+        return "nc" if qb < nh else "cc"
+    return "under" if kb - nh < i else ""
+
+
+@functools.lru_cache(maxsize=None)
+def _bd_plan(role: str, block: int, stair: int, head_dim: int,
+             backward: bool, num_kb: int) -> SubtilePlan:
+    sq, sk = _subtile_shape(block, block, head_dim, backward, num_kb)
+    if not role:
+        return _plan_of(block, block, sq, sk, 0, 0, backward)   # no band
+    if role == "under":
+        return _plan_of(block, block, sq, sk, block - 1, block, backward,
+                        role=role)
+    return _plan_of(block, block, sq, sk, -stair if role == "nc" else 0,
+                    block, backward, stair, role == "nn", role)
+
+
+def subtile_plan(mask: AttnMask, block_q: int, block_k: int, q_block: int,
+                 k_block: int, t_real: int, head_dim: int,
+                 backward: bool = False, num_kb: int = 1) -> SubtilePlan:
+    """`causal_subtile_plan` under a declared mask: the static plan of grid
+    tile (q_block, k_block)."""
+    if mask.kind == "causal":
+        return causal_subtile_plan(block_q, block_k, q_block, k_block,
+                                   t_real, head_dim, backward, num_kb)
+    return _bd_plan(_bd_role(mask, block_q, q_block, k_block), block_q,
+                    mask.block, head_dim, backward, num_kb)
+
+
+def plan_stats(mask: AttnMask, t_pad: int, block_q: int, block_k: int,
+               t_real: int, head_dim: int, backward: bool = False
+               ) -> Dict[str, int]:
+    """`subtile_plan` summed over the grid of one head."""
     out = {"computed_unmasked": 0, "computed_masked": 0, "skipped": 0,
            "work_elems": 0}
     num_kb = t_pad // block_k
     for qb in range(t_pad // block_q):
         for kb in range(num_kb):
-            plan = causal_subtile_plan(block_q, block_k, qb, kb, t_real,
-                                       head_dim, backward, num_kb)
+            plan = subtile_plan(mask, block_q, block_k, qb, kb, t_real,
+                                head_dim, backward, num_kb)
+            # a tile that is never computed has no plan: all of it skipped
             out["computed_unmasked"] += plan.computed_unmasked
             out["computed_masked"] += plan.computed_masked
             out["skipped"] += plan.skipped
@@ -367,21 +479,40 @@ def causal_plan_stats(t_pad: int, block_q: int, block_k: int, t_real: int,
     return out
 
 
+def causal_plan_stats(t_pad: int, block_q: int, block_k: int, t_real: int,
+                      head_dim: int, backward: bool = False
+                      ) -> Dict[str, int]:
+    """`causal_subtile_plan` summed over the grid of one head."""
+    return plan_stats(CAUSAL, t_pad, block_q, block_k, t_real, head_dim,
+                      backward)
+
+
 def _tile_plans(block_q: int, block_k: int, num_qb: int, num_kb: int,
-                t_real: int, head_dim: int, backward: bool = False):
+                t_real: int, head_dim: int, backward: bool = False,
+                mask: AttnMask = CAUSAL):
     """The distinct plans of the grid's live tiles. A plan is a function of
-    its clamped (diag, cut), which is how a kernel picks it from the
-    program ids (`_plan_is`)."""
-    plans = {causal_subtile_plan(block_q, block_k, qb, kb, t_real, head_dim,
-                                 backward, num_kb)
+    its clamped (diag, cut), or under the block-diffusion mask of its role,
+    which is how a kernel picks it from the program ids (`_plan_is`)."""
+    plans = {subtile_plan(mask, block_q, block_k, qb, kb, t_real, head_dim,
+                          backward, num_kb)
              for qb in range(num_qb) for kb in range(num_kb)}
     return sorted((p for p in plans if p.bands),
-                  key=lambda p: (p.diag, p.cut))
+                  key=lambda p: (p.diag, p.cut, p.role))
 
 
 def _plan_is(plan: SubtilePlan, qi, ki, block_q: int, block_k: int,
-             t_real: int):
+             t_real: int, mask: AttnMask = CAUSAL):
     """Does grid tile (qi, ki) — program ids — run `plan`?"""
+    if mask.kind != "causal":
+        nh = mask.half // block_q
+        own = ki - nh == qi % nh        # the clean tile of the block's place
+        if plan.role == "nn":
+            return (ki == qi) & (qi < nh)
+        if plan.role == "nc":
+            return own & (qi < nh)
+        if plan.role == "cc":
+            return own & (qi >= nh)
+        return (ki >= nh) & (ki - nh < qi % nh)
     diag = jnp.maximum(-block_q, jnp.minimum(qi * block_q - ki * block_k,
                                              block_k - 1))
     cut = jnp.maximum(0, jnp.minimum(t_real - qi * block_q, block_q))
@@ -399,19 +530,34 @@ def _row_walk_plans(plans):
     return tuple((p, under.get(p.cut)) for p in plans if p.diag == 0)
 
 
+def _band_at(plan: SubtilePlan, r0: int):
+    """The key rectangles of `plan`'s query sub-row at `r0` (the forward's
+    plans are not merged: a band is one sub-row); none where every entry of
+    the sub-row is dead."""
+    return next((rects for at, _, rects in plan.bands if at == r0), ())
+
+
 def _rect_live(plan: SubtilePlan, r0: int, rows: int, c0: int, cols: int,
                transposed: bool = False):
     """Mask of a masked rectangle, (rows, cols) or transposed, from the
     conditions that cross it alone."""
     shape = (cols, rows) if transposed else (rows, cols)
     rdim = 1 if transposed else 0
-    row = r0 + jax.lax.broadcasted_iota(jnp.int32, shape, rdim)
-    live = None
-    if c0 + cols - 1 > r0 + plan.diag:          # the diagonal crosses it
+    at = r0 + jax.lax.broadcasted_iota(jnp.int32, shape, rdim)
+    step = plan.stair - 1
+    # a staircase: the rows of a block share its last row's bound
+    row = at | step if step else at
+    live = col = None
+    if c0 + cols - 1 > (r0 | step) + plan.diag:  # the diagonal crosses it
         col = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rdim)
         live = col <= row + plan.diag
+    if plan.band and c0 < ((r0 + rows - 1) | step) - step + plan.diag:
+        if col is None:     # the band's lower staircase crosses it
+            col = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rdim)
+        above = col >= row + (plan.diag - step)
+        live = above if live is None else live & above
     if r0 + rows > plan.cut:                    # the t_real edge crosses it
-        inside = row < plan.cut
+        inside = at < plan.cut
         live = inside if live is None else live & inside
     return live
 
@@ -455,7 +601,8 @@ def _softmax_step(state, s, v, masked: bool):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 scale: float, t_real: int, block_q: int, block_k: int,
-                num_kb: int, plans, row_walk: bool = False):
+                num_kb: int, plans, row_walk: bool = False,
+                mask: AttnMask = CAUSAL):
     """A tile wholly above the diagonal or wholly padding has no plan: it is
     skipped. Every other tile runs the one plan that is its own; a query
     sub-row keeps ONE online softmax across its rectangles.
@@ -511,10 +658,34 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             s = jnp.where(_rect_live(plan, r0, rows, c0, cols), s, MASK)
         return _softmax_step(state, s, v_ref[0, cs, :], masked)
 
-    for plan, left in (_row_walk_plans(plans) if row_walk
-                       else ((p, None) for p in plans)):
-        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
-        def _compute(plan=plan, left=left):
+    # What a query block walks, a case a `when`: the tiles it runs by their
+    # static plans, the first one's sub-rows leading (key tile, plan; None
+    # is the block the grid fetched), and then the key tiles `first` ..
+    # `past` - 1 it loops over by the one unmasked plan `left`.
+    if not row_walk:
+        walks = [(lambda p=p: _plan_is(p, qi, ki, block_q, block_k, t_real,
+                                       mask), ((None, p),), None)
+                 for p in plans]
+    elif mask.kind == "causal":
+        walks = [(lambda p=p: _plan_is(p, qi, ki, block_q, block_k, t_real),
+                  ((ki, p),), left and (0, qi, left))
+                 for p, left in _row_walk_plans(plans)]
+    else:
+        nh = mask.half // block_q
+        by = {p.role: p for p in plans}
+        left = by.get("under")          # None with one tile a half
+        # (a block length of the sub-tile's edge leaves "nc" no live entry)
+        walks = [
+            (lambda: qi < nh, ((qi, by["nn"]),) + (
+                ((qi + nh, by["nc"]),) if "nc" in by else ()),
+             left and (nh, qi + nh, left)),
+            (lambda: qi >= nh, ((qi, by["cc"]),), left and (nh, qi, left))]
+
+    for when, tiles, loop in walks:
+        @pl.when(when())
+        def _compute(tiles=tiles, loop=loop):
+            plan = tiles[0][1]
+            left = loop[2] if loop else None
             left_rects = {r0: rects for r0, _, rects in left.bands} \
                 if left else {}
             done = 0
@@ -523,16 +694,19 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 q = q_ref[0, rs, :]
                 state = (m_ref[rs], l_ref[rs], acc_ref[rs]) if scratch \
                     else None
-                for c0, cols, masked in rects:
-                    state = step(plan, state, q, r0, rows, c0, cols, masked,
-                                 ki if row_walk else None)
+                for tile, of in tiles:
+                    for c0, cols, masked in (
+                            rects if of is plan else _band_at(of, r0)):
+                        state = step(of, state, q, r0, rows, c0, cols,
+                                     masked, tile)
                 if r0 in left_rects:
                     def key_tile(kb, state, r0=r0, rows=rows, q=q):
                         for c0, cols, masked in left_rects[r0]:
                             state = step(left, state, q, r0, rows, c0, cols,
                                          masked, kb)
                         return state
-                    state = jax.lax.fori_loop(0, qi, key_tile, state)
+                    state = jax.lax.fori_loop(loop[0], loop[1], key_tile,
+                                              state)
                 if scratch:
                     m_ref[rs], l_ref[rs], acc_ref[rs] = state
                 else:
@@ -597,7 +771,7 @@ def _vmem_limit(resident_bytes: int) -> int:
 
 
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
-              hq: int, hkv: int, interpret: bool):
+              hq: int, hkv: int, interpret: bool, mask: AttnMask = CAUSAL):
     bh, t_pad, d = q.shape
     dv = v.shape[-1]            # v (and o) may be narrower than q/k
     num_qb = t_pad // block_q
@@ -616,13 +790,14 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
     kernel = functools.partial(
         _fwd_kernel, scale=scale, t_real=t_real,
         block_q=block_q, block_k=block_k, num_kb=num_kb,
-        plans=_tile_plans(block_q, block_k, num_qb, num_kb, t_real, d),
-        row_walk=row_walk)
+        plans=_tile_plans(block_q, block_k, num_qb, num_kb, t_real, d,
+                          mask=mask),
+        row_walk=row_walk, mask=mask)
 
     def kv_index(b, i, j):
         if row_walk:
             return _kv_row(b, hq, hkv), 0, 0
-        if gridded:
+        if gridded and mask.kind == "causal":
             # a tile above the diagonal is skipped: name the block that is
             # already there, and the pipeline fetches nothing for it
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
@@ -630,8 +805,8 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
 
     kv_rows = t_pad if row_walk else block_k
     # what the plan computes, masked entries of a crossed sub-tile included
-    entries = bh * causal_plan_stats(t_pad, block_q, block_k, t_real,
-                                     d)["work_elems"]
+    entries = bh * plan_stats(mask, t_pad, block_q, block_k, t_real,
+                              d)["work_elems"]
     o, lse = pl.pallas_call(
         kernel,
         grid=(bh, num_qb, 1 if row_walk else num_kb),
@@ -691,7 +866,8 @@ def _rect_p_ds(plan, rect, q, k, v, do, lse, delta, scale):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                dq_acc, *, scale: float, t_real: int,
-               block_q: int, block_k: int, num_kb: int, plans):
+               block_q: int, block_k: int, num_kb: int, plans,
+               mask: AttnMask = CAUSAL):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -700,7 +876,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
     for plan in plans:
-        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
+        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real, mask))
         def _compute(plan=plan):
             for r0, rows, rects in plan.bands:
                 rs = slice(r0, r0 + rows)
@@ -721,7 +897,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float, t_real: int,
                 block_q: int, block_k: int, num_qb: int, plans,
-                group: int = 1):
+                group: int = 1, mask: AttnMask = CAUSAL):
     """dk/dv accumulate over the sequential grid dim 2 = (g, qi) — under
     grouped-query attention every one of a kv head's `group` query heads
     contributes; the index maps route each (g, qi) step to its query row.
@@ -737,7 +913,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     for plan in plans:
-        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real))
+        @pl.when(_plan_is(plan, qi, ki, block_q, block_k, t_real, mask))
         def _compute(plan=plan):
             for c0, cols, rects in plan.columns:
                 cs = slice(c0, c0 + cols)
@@ -862,7 +1038,8 @@ def _rects_at(plan: Optional[SubtilePlan], c0: int):
 
 def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, scale: float,
-                    t_real: int, block: int, plans):
+                    t_real: int, block: int, plans,
+                    mask: AttnMask = CAUSAL):
     """Several blocks a head, the head resident: dq, dk and dv in ONE kernel,
     what `_bwd_fused_kernel` is for one tile. s, p, dp and ds are formed
     once a rectangle and feed all three (5 MXU dots where `_dq_kernel` and
@@ -878,7 +1055,13 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     when it ends; dq accumulates in float32 scratch over the whole row. The
     key tiles are a loop too: every one left of t_real's tile walks the
     same plans, so the kernel's text is at most four tiles' plans whatever
-    the sequence's length (`_col_walk_plans`)."""
+    the sequence's length (`_col_walk_plans`).
+
+    Under the block-diffusion mask the walk is the same with other lists:
+    a noised key tile has its one tile on the block diagonal; clean key
+    tile j has the two diagonal tiles of place j (the clean query block's
+    and the noised one's) and, under them, the later query tiles of BOTH
+    halves by the unmasked plan, as one loop."""
     if kv_acc:
         dk_acc, dv_acc = kv_acc
         g, group = pl.program_id(1), pl.num_programs(1)
@@ -891,7 +1074,6 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     t_pad, d = dq_ref.shape
     dv = dv_ref.shape[-1]
     n_full = t_real // block                # query (and key) tiles left whole
-    full, edge = _col_walk_plans(plans, block)
     dq_acc[:] = jnp.zeros_like(dq_acc)
 
     def rows_of(tile, r0, rows):
@@ -911,26 +1093,35 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return (acc[0] + _dot(jnp.transpose(q), ds, _NN),    # (d, cols)
                 acc[1] + _dot(jnp.transpose(do), p, _NN))
 
-    def key_tile(kb, diag, under, cut_tile):
-        """Key tile `kb`: its diagonal tile by `diag`, query tiles kb + 1 ..
-        n_full - 1 by `under` and query tile n_full by `cut_tile`, where
-        those exist."""
-        for c0, cols, rects in diag.columns:
+    def key_tile(kb, diags, under, cut_tile, loop=None):
+        """Key tile `kb`: its diagonal tiles `diags`, ((query tile, plan),
+        ...), the first one's sub-columns leading; then the query tiles
+        under them by `under`, kb + 1 .. n_full - 1 or, with `loop` =
+        (first, past, query tile of an index), those; then query tile
+        n_full by `cut_tile`; each where it exists."""
+        tile_of = loop and loop[2]
+        for c0, cols, rects in diags[0][1].columns:
             cs = rows_of(kb, c0, cols)
             k, v = k_ref[cs, :], v_ref[cs, :]
             acc = (jnp.zeros((d, cols), jnp.float32),
                    jnp.zeros((dv, cols), jnp.float32))
-            for r0, rows, masked in rects:
-                acc = rect(diag, kb, acc, k, v, r0, rows, c0, cols, masked)
+            for at, (tile, diag) in enumerate(diags):
+                for r0, rows, masked in (_rects_at(diag, c0) if at
+                                         else rects):
+                    acc = rect(diag, tile, acc, k, v, r0, rows, c0, cols,
+                               masked)
             under_rects = _rects_at(under, c0)
             if under_rects:
                 def q_tile(qi, acc, c0=c0, cols=cols, k=k, v=v,
                            under_rects=under_rects):
+                    if tile_of:
+                        qi = tile_of(qi)
                     for r0, rows, masked in under_rects:
                         acc = rect(under, qi, acc, k, v, r0, rows, c0, cols,
                                    masked)
                     return acc
-                acc = jax.lax.fori_loop(kb + 1, n_full, q_tile, acc)
+                acc = jax.lax.fori_loop(
+                    *(loop[:2] if loop else (kb + 1, n_full)), q_tile, acc)
             for r0, rows, masked in _rects_at(cut_tile, c0):
                 acc = rect(cut_tile, n_full, acc, k, v, r0, rows, c0, cols,
                            masked)
@@ -943,14 +1134,34 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv_ref[cs, :] = jnp.transpose(dvt).astype(dv_ref.dtype)
 
     done = n_full * block                   # key columns walked
-    if full:
-        def whole_key_tile(kb, carry):
-            key_tile(kb, full[0], full[1], edge and edge[1])
+    if mask.kind == "causal":
+        full, edge = _col_walk_plans(plans, block)
+        if full:
+            def whole_key_tile(kb, carry):
+                key_tile(kb, ((kb, full[0]),), full[1], edge and edge[1])
+                return carry
+            jax.lax.fori_loop(0, n_full, whole_key_tile, 0)
+        if edge:
+            key_tile(n_full, ((n_full, edge[0]),), None, None)
+            done += sum(edge[0].columns[-1][:2])
+    else:
+        nh = mask.half // block
+        by = {p.role: p for p in plans}
+
+        def noised_key_tile(kb, carry):
+            key_tile(kb, ((kb, by["nn"]),), None, None)
             return carry
-        jax.lax.fori_loop(0, n_full, whole_key_tile, 0)
-    if edge:
-        key_tile(n_full, edge[0], None, None)
-        done += sum(edge[0].columns[-1][:2])
+
+        def clean_key_tile(kb, carry):
+            later = n_full - 1 - kb         # query tiles under it, a half
+            key_tile(kb, ((kb, by["cc"]),) + (
+                ((kb - nh, by["nc"]),) if "nc" in by else ()),
+                     by.get("under"), None,
+                     (0, 2 * later, lambda at: jnp.where(
+                         at < later, kb - nh + 1 + at, kb + 1 + at - later)))
+            return carry
+        jax.lax.fori_loop(0, nh, noised_key_tile, 0)
+        jax.lax.fori_loop(nh, n_full, clean_key_tile, 0)
     dq_ref[...] = dq_acc[:].astype(dq_ref.dtype)
 
     if kv_acc:
@@ -976,7 +1187,8 @@ def _bwd_resident_bytes(t_pad: int, d: int, dv: int, itemsize: int,
 
 
 def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
-                  hq: int, hkv: int, interpret: bool, resident: int):
+                  hq: int, hkv: int, interpret: bool, resident: int,
+                  mask: AttnMask = CAUSAL):
     bh, t_pad, d = q.shape
     dv = v.shape[-1]
     bhkv = k.shape[0]
@@ -987,14 +1199,14 @@ def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
         (lambda b, g: (_q_row(b, g, hq, hkv), 0, 0)) if of_q
         else (lambda b, g: (b, 0, 0)))
     acc = lambda width: pltpu.VMEM((t_pad, width), jnp.float32)
-    entries = bh * causal_plan_stats(t_pad, block, block, t_real, d,
-                                     backward=True)["work_elems"]
+    entries = bh * plan_stats(mask, t_pad, block, block, t_real, d,
+                              backward=True)["work_elems"]
     return pl.pallas_call(
         functools.partial(
             _bwd_row_kernel, scale=1.0 / math.sqrt(d), t_real=t_real,
             block=block,
             plans=_tile_plans(block, block, num_b, num_b, t_real, d,
-                              backward=True)),
+                              backward=True, mask=mask), mask=mask),
         grid=(bhkv, group),
         in_specs=[row(d, True), row(d, False), row(dv, False),
                   row(dv, True), row(1, True), row(1, True)],
@@ -1017,7 +1229,7 @@ def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
 
 
 def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
-              hq: int, hkv: int, interpret: bool):
+              hq: int, hkv: int, interpret: bool, mask: AttnMask = CAUSAL):
     bh, t_pad, d = q.shape
     dv = v.shape[-1]
     bhkv = k.shape[0]
@@ -1058,7 +1270,8 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     if walk == "row":
         return _bwd_row_call(q, k, v, do, lse, delta, t_real=t_real,
                              block=block_q, hq=hq, hkv=hkv,
-                             interpret=interpret, resident=resident)
+                             interpret=interpret, resident=resident,
+                             mask=mask)
     if walk == "tile":
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))
@@ -1092,11 +1305,11 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         )(q, k, v, do, lse, delta)
 
     plans = _tile_plans(block_q, block_k, num_qb, num_kb, t_real, d,
-                        backward=True)
+                        backward=True, mask=mask)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, t_real=t_real,
                           block_q=block_q, block_k=block_k, num_kb=num_kb,
-                          plans=plans),
+                          plans=plans, mask=mask),
         grid=(bh, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -1122,7 +1335,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, t_real=t_real,
                           block_q=block_q, block_k=block_k, num_qb=num_qb,
-                          plans=plans, group=group),
+                          plans=plans, group=group, mask=mask),
         grid=(bhkv, num_kb, group * num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, d),
@@ -1373,8 +1586,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     bwd_block_q: int = None,
                     bwd_block_k: int = None,
                     t_real: int = None,
-                    interpret: bool = False) -> jax.Array:
-    """Causal flash attention. q: (b, heads, t, head_dim); k, v may carry
+                    interpret: bool = False,
+                    mask: AttnMask = CAUSAL) -> jax.Array:
+    """Flash attention, causal unless `mask` declares another
+    (`ops/attention.block_diffusion`: the blocks are then square, clamped
+    to the mask's half so that no tile spans two quadrants, and must divide
+    it; its block length divides 128; no `t_real`). q: (b, heads, t,
+    head_dim); k, v may carry
     FEWER heads (b, kv_heads, t, head_dim) with heads % kv_heads == 0 —
     grouped-query attention routed inside the kernels (no K/V repeat in HBM).
     v may be of ANOTHER width than q and k (latent attention: q/k of 192
@@ -1429,10 +1647,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # guards, so over-padding costs only grid overhead. All four block
     # sizes share one t_pad, so the bwd blocks participate in the clamp.
     pow2 = max(128, 1 << (t - 1).bit_length())
-    bq = min(block_q, pow2)
-    bk = min(block_k, pow2)
-    bbq = min(bwd_block_q, pow2)
-    bbk = min(bwd_block_k, pow2)
+    if mask.kind != "causal":
+        if t_real != t:
+            raise ValueError("a block_diffusion mask takes no t_real, got "
+                             f"t={t}, t_real={t_real}")
+        bq = bk = mask_block(mask, t, min(block_q, block_k))
+        bbq = bbk = mask_block(mask, t, min(bwd_block_q, bwd_block_k))
+    else:
+        bq = min(block_q, pow2)
+        bk = min(block_k, pow2)
+        bbq = min(bwd_block_q, pow2)
+        bbk = min(bwd_block_k, pow2)
     t_pad = _round_up(t, max(bq, bk, bbq, bbk))
 
     def prep(x, nh):
@@ -1442,24 +1667,26 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return x
 
     o = _flash_with_t(prep(q, h), prep(k, hkv), prep(v, hkv), t_real,
-                      bq, bk, bbq, bbk, h, hkv, interpret)
+                      bq, bk, bbq, bbk, h, hkv, interpret, mask)
     return o[:, :t, :].reshape(b, h, t, v.shape[-1])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
 def _flash_with_t(q, k, v, t_real: int, block_q: int, block_k: int,
                   bwd_block_q: int, bwd_block_k: int, hq: int, hkv: int,
-                  interpret: bool):
+                  interpret: bool, mask: AttnMask = CAUSAL):
     o, _ = _fwd_call(q, k, v, t_real=t_real, block_q=block_q,
-                     block_k=block_k, hq=hq, hkv=hkv, interpret=interpret)
+                     block_k=block_k, hq=hq, hkv=hkv, interpret=interpret,
+                     mask=mask)
     return o
 
 
 def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
-                      bwd_block_q, bwd_block_k, hq, hkv, interpret):
+                      bwd_block_q, bwd_block_k, hq, hkv, interpret, mask):
     o, lse = _fwd_call(q, k, v, t_real=t_real,
                        block_q=block_q, block_k=block_k, hq=hq, hkv=hkv,
-                       interpret=interpret)
+                       interpret=interpret, mask=mask)
     # Name the kernel outputs so a remat policy can keep them: from the
     # 'flash' rung of models/transformer.REMAT_LADDER the backward finds
     # o/lse saved; below it, it re-runs the forward kernel to rebuild them.
@@ -1469,11 +1696,11 @@ def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
 
 
 def _flash_with_t_bwd(t_real, block_q, block_k, bwd_block_q, bwd_block_k,
-                      hq, hkv, interpret, res, do):
+                      hq, hkv, interpret, mask, res, do):
     q, k, v, o, lse = res
     return _bwd_call(q, k, v, o, lse, do, t_real=t_real,
                      block_q=bwd_block_q, block_k=bwd_block_k,
-                     hq=hq, hkv=hkv, interpret=interpret)
+                     hq=hq, hkv=hkv, interpret=interpret, mask=mask)
 
 
 _flash_with_t.defvjp(_flash_with_t_fwd, _flash_with_t_bwd)

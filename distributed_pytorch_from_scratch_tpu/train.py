@@ -47,7 +47,7 @@ from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
 from .training.checkpoint import (AsyncCheckpointer, latest_step,
                                   load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
-                               moe_counters_summary,
+                               bd_counters_summary, moe_counters_summary,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
                                param_bytes_by_device, publish_hbm)
@@ -211,12 +211,18 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "learned positions/tied embeddings (models/gpt2.py; "
                         "composes with dp/tp/cp/SP/pp/ep like llama — GQA "
                         "is the one llama-only feature); 'mla_moe', "
-                        "'gdn_moe' and 'conv_moe' each go with their own "
-                        "preset (--model tiny-mla-moe | tiny-gdn-moe | "
-                        "tiny-conv-moe: latent attention + experts; Gated "
-                        "DeltaNet + gated attention + experts; gated short "
-                        "convolutions + GQA with q/k norms + experts, a "
-                        "tied head) and train under dp/tp/ZeRO 1 only: "
+                        "'gdn_moe', 'conv_moe' and 'bd_moe' each go with "
+                        "their own preset (--model tiny-mla-moe | "
+                        "tiny-gdn-moe | tiny-conv-moe | tiny-bd-moe: latent "
+                        "attention + experts; Gated DeltaNet + gated "
+                        "attention + experts; gated short convolutions + "
+                        "GQA with q/k norms + experts, a tied head; a GQA "
+                        "expert decoder trained by BLOCK DIFFUSION: the "
+                        "step noises each sequence from (--random_seed, "
+                        "step, the batch), runs [noised ; clean] under the "
+                        "block-diffusion attention mask and weights the "
+                        "masked positions' CE by 1/p; tokens/s count data "
+                        "tokens) and train under dp/tp/ZeRO 1 only: "
                         "pp/cp/ep > 1, SP, ZeRO 2/3, decode and serving "
                         "refuse them with a message")
     g.add_argument("--model", choices=sorted(MODEL_PRESETS), default=None,
@@ -549,8 +555,8 @@ def train(args: argparse.Namespace) -> dict:
                 f"with facts of its own goes with a preset that has them "
                 f"(--family mla_moe --model tiny-mla-moe, --family gdn_moe "
                 f"--model tiny-gdn-moe, --family conv_moe --model "
-                f"tiny-conv-moe), and such a preset with no other "
-                f"family")
+                f"tiny-conv-moe, --family bd_moe --model tiny-bd-moe), "
+                f"and such a preset with no other family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
         zero_stage = resolve_zero_stage(args.zero, args.zero1)
@@ -673,7 +679,11 @@ def train(args: argparse.Namespace) -> dict:
             pp_schedule=args.pp_schedule,
             pp_virtual=args.pp_virtual,
             remat=REMAT_CHOICES.get(remat_key, remat_key),
-            attn_t_real=attn_t_real)
+            attn_t_real=attn_t_real,
+            # a family that draws noise inside its step draws it from the
+            # run's seed
+            **({"noise_seed": args.random_seed}
+               if family_class(args.family).draws_noise else {}))
         ocfg = OptimizerConfig(lr=args.lr, warmup_steps=args.warmup_steps,
                                max_steps=args.max_steps,
                                clip_grad_norm=args.clip_grad_norm,
@@ -1285,6 +1295,12 @@ def train(args: argparse.Namespace) -> dict:
                             print("  " + ", ".join(
                                 f"{k} {v:.4g}" for k, v in moe.items()))
                             writer.event("moe_counters", step=n, **moe)
+                            if "masked" in last_counters:
+                                bd = bd_counters_summary(
+                                    jax.device_get(last_counters))
+                                print("  " + ", ".join(
+                                    f"{k} {v:.4g}" for k, v in bd.items()))
+                                writer.event("bd_counters", step=n, **bd)
                         if telemetry is not None:
                             # same numbers the log line prints — the live
                             # endpoint view; the goodput buckets ride too

@@ -81,7 +81,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                sequence_parallel: bool = False,
                state_bytes_per_param: float = 16.0,
                grad_bytes_per_param: float = 4.0,
-               layer_extra_elems_per_token: float = 0.0) -> Dict[str, float]:
+               layer_extra_elems_per_token: float = 0.0,
+               head_rows_share: float = 1.0) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -98,7 +99,9 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               L of each, at their logical sizes (the chip's count: the
               stack of `flash_out` is not kept in the kernel's layout, which
               pads a head of 64 to the 128 lanes)
-    head      logits in f32 and once more in the compute dtype
+    head      logits in f32 and once more in the compute dtype, on
+              `head_rows_share` of the rows (a family whose loss reads part
+              of the rows the stack sees: `DecoderStack.head_rows_share`)
     layer     one layer's recompute + backward working set; a family whose
               layer holds more than the dense skeleton's six d-wide and
               3.4 f-wide tensors says how many elements a token more
@@ -111,7 +114,9 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     tok = b * t
     act = 1.0 / tp if sequence_parallel else 1.0
     wide = tok * d * dtype_bytes * act       # a (b, t, d) tensor
-    q_w = tok * (d / tp) * dtype_bytes       # a column-linear's output
+    # a column-linear's output (the heads' whole width: the model's, unless
+    # a family's heads are wider)
+    q_w = tok * (heads * head_dim / tp) * dtype_bytes
     kv_w = tok * (kd / tp) * dtype_bytes
     f_w = tok * (f / tp) * dtype_bytes
     h_local = heads / tp
@@ -141,7 +146,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         "cast": (layer_param_count * dtype_bytes if dtype_bytes < 4
                  else 0.0),
         "stacks": layers * per_layer,
-        "head": tok * (vocab / tp) * (4 + dtype_bytes),
+        "head": tok * head_rows_share * (vocab / tp) * (4 + dtype_bytes),
         "layer": tok * dtype_bytes * (6 * d * act + 3.4 * f / tp
                                       + layer_extra_elems_per_token),
     }
@@ -293,14 +298,15 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
     parts = functools.partial(
         step_bytes, param_count=param_count,
         layer_param_count=layer_param_count, b=b,
-        t=t, d=cfg.attn_dim, kd=cfg.kv_dim,
-        f=cfg.ffn_dim, heads=cfg.num_heads, head_dim=cfg.head_dim,
+        t=t, d=cfg.attn_dim, kd=model.kv_dim,
+        f=cfg.ffn_dim, heads=cfg.num_heads, head_dim=model.head_dim,
         layers=model.stacked_layers // model.pp_size,
         vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
         dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
         ffn_inputs=model.ffn_inputs,
         sequence_parallel=model.tp_layout(t)[0],
-        layer_extra_elems_per_token=model.layer_extra_elems_per_token)
+        layer_extra_elems_per_token=model.layer_extra_elems_per_token,
+        head_rows_share=model.head_rows_share)
     return _pick(parts, model.remat_budget_gib, None, allow_false=False,
                  verbose=True,
                  note=f"; traced b{b} x t{t}, tp{model.tp_size}")

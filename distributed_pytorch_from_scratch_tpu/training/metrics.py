@@ -203,6 +203,23 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
         return (6 * n * batch * seqlen
                 + 12 * full * batch * cfg.num_heads * seqlen * seqlen
                 * cfg.head_dim)
+    bd = getattr(cfg, "bd_moe", None)
+    if bd is not None:
+        # the bd_moe family, per DATA token (`batch` x `seqlen` of them): a
+        # token's two rows, noised and clean, through every layer's
+        # attention, router and held experts (at a row's mean share of
+        # them, as above); the head on the noised row only; the embedding's
+        # lookup is no matmul; attention at the entries the declared mask
+        # leaves live, `L + B` a head and data token (models/bd_moe.py)
+        held = cfg.experts_held
+        layers = n - 2 * cfg.vocab_size * cfg.attn_dim - cfg.attn_dim
+        layers -= cfg.num_layers * (held - cfg.moe_top_k * held
+                                    / cfg.num_experts) * (
+            3 * cfg.attn_dim * bd.moe_intermediate_size)
+        return (6 * (2 * layers + cfg.vocab_size * cfg.attn_dim)
+                * batch * seqlen
+                + 12 * cfg.num_layers * batch * cfg.num_heads * seqlen
+                * (seqlen + bd.block_length) * bd.head_dim)
     if getattr(cfg, "num_experts", 0):
         inactive = ((cfg.num_experts - cfg.moe_top_k)
                     * 3 * cfg.attn_dim * cfg.ffn_dim)
@@ -212,13 +229,28 @@ def model_flops_per_step(cfg, batch: int, seqlen: int,
             * seqlen * seqlen * cfg.head_dim)
 
 
+def bd_counters_summary(counters: dict) -> dict:
+    """The block-diffusion step's own counters (`models/bd_moe.py`) as a
+    log line's numbers: the share of positions masked (about a half: the
+    level is uniform), the batch's mean level, and the weighted MEAN CE of
+    the masked positions (the loss over its draw's factor `weight_sum /
+    positions`, which is 1 in expectation)."""
+    positions = float(counters["positions"])
+    return {"masked_share": float(counters["masked"]) / positions,
+            "p_mean": float(counters["p_sum"]) / positions,
+            "masked_ce_mean": float(counters["loss_main"]) * positions
+            / max(float(counters["weight_sum"]), 1e-9)}
+
+
 def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     """The step's counters of an expert model (`DecoderStack.loss_shard`
     with `with_counters`, fetched to the host) as the few numbers a log
     line carries: the main and multi-token-prediction losses apart, the
     rows the held experts computed per token and expert layer (`top_k x
-    held / routed` under uniform routing), and the held experts' load as
-    max over mean, averaged over the expert layers (1.0 is balance)."""
+    held / routed` under uniform routing; per DATA token, so twice that,
+    in the bd_moe family, whose layers see two rows a token), and the held
+    experts' load as max over mean, averaged over the expert layers (1.0 is
+    balance)."""
     import numpy as np
 
     lo = cfg.expert_offset

@@ -117,9 +117,12 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
         # The scopes are what a device trace's `op_name` can say that JAX
         # cannot know (jvp / transpose / rematted_computation it adds
         # itself): benchmark/lib/program_trace.py splits the step by them.
+        # a family whose loss draws noise gets the step's count to fold
+        # into its key (`DecoderStack.draws_noise`)
+        noise_step = (opt_state.step,) if model.draws_noise else ()
         with jax.named_scope("loss_and_grad"):
             loss, grads = grad_fn(params, input_ids, target_ids,
-                                  position_ids)
+                                  position_ids, *noise_step)
         extra = ()
         if with_counters:
             loss, counters = loss

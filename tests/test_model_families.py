@@ -9,7 +9,8 @@ import re
 import jax
 import pytest
 
-from distributed_pytorch_from_scratch_tpu.config import (ConvMoEConfig,
+from distributed_pytorch_from_scratch_tpu.config import (BdMoEConfig,
+                                                         ConvMoEConfig,
                                                          GdnMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig)
@@ -44,9 +45,17 @@ CONV = dict(layer_types=("conv", "full_attention", "conv", "conv"),
             moe_intermediate_size=16, num_dense_layers=1)
 
 
+# the bd_moe family: 4 query heads over 2 key-value heads of 16 (heads x
+# width = 64, not the model's 32), every layer an expert layer
+BD = dict(head_dim=16, moe_intermediate_size=16)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "bd_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           bd_moe=BdMoEConfig(experts_held=held, **BD))
     if extra == "conv_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            conv_moe=ConvMoEConfig(experts_held=held, **CONV))

@@ -340,3 +340,58 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
                              compiled.as_text()))
     assert {"flash_fwd", "flash_bwd"} <= kernels, kernels
     assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels, kernels
+
+
+def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(
+        topo, described_tpu):
+    """The sixth cell's step (`sdar-30b-a3b.train-ep8share-b2-t4096`: the
+    bd_moe family at the published widths, 16 of 128 experts held, 6
+    layers, 2 x 4096 data tokens = 2 x 8192 rows, bf16) compiled for the
+    described chip at the rung `remat="auto"` picks there, the floor: the
+    family's memory facts (2L rows a sequence, the 98,304-row chunk, logits
+    on half the rows) are held to the chip's own count of this step, 15.48
+    GiB (PERF.md section 5, PR 41; the compiler's plan charges more than
+    the runtime reserves, as in the hybrid cell, and reads over the limit),
+    and Mosaic takes the flash kernels under the block-diffusion mask at
+    head 128 and a group of 8 over eight blocks a head: the forward with
+    the key row resident and ONE backward kernel with the head resident."""
+    from distributed_pytorch_from_scratch_tpu.config import BdMoEConfig
+    from distributed_pytorch_from_scratch_tpu.models import build_model
+    cfg = ModelConfig(
+        attn_dim=2048, ffn_dim=768, num_heads=32, num_kv_heads=4,
+        num_layers=6, vocab_size=18992, maxlen=32768, rope_theta=1e6,
+        compute_dtype="bfloat16", num_experts=128, moe_top_k=8,
+        bd_moe=BdMoEConfig(head_dim=128, moe_intermediate_size=768,
+                           block_length=4, mask_token_id=1,
+                           experts_held=16))
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=topo.devices[:1])
+    model = build_model("bd_moe", cfg, remat_budget_gib=V5E_LIMIT_GIB)
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(model.init, jax.random.key(0)), model.shardings(mesh))
+    scalar = NamedSharding(mesh, P())
+    opt = AdamState(step=jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar),
+                    mu=params, nu=params)
+    ids = jax.ShapeDtypeStruct((2, 4096), jnp.int32, sharding=NamedSharding(
+        mesh, P(("dp", "ep"), "cp")))
+    step = build_train_step(model, mesh, OptimizerConfig(),
+                            with_grad_norm=True, with_counters=True)
+    said = io.StringIO()
+    with contextlib.redirect_stderr(said):
+        compiled = step.lower(params, opt, ids, ids, ids).compile()
+    # the model sizes itself by the 2 x 8192 ROWS it makes of the batch
+    assert "remat auto: picked 'true'" in said.getvalue()
+    assert "traced b2 x t8192" in said.getvalue()
+    estimate = float(re.search(r"true=([\d.]+)GiB", said.getvalue()).group(1))
+    plan = compiled.memory_analysis()
+    args = plan.argument_size_in_bytes / memory.GIB
+    assert args == pytest.approx(645_623_296 * 12 / memory.GIB, rel=1e-3)
+    planned = args + plan.temp_size_in_bytes / memory.GIB
+    # what the chip counted for this step, between the estimate's two sides
+    chip_gib = 15.48
+    assert 0.9 * chip_gib < estimate < 1.05 * chip_gib, estimate
+    assert chip_gib < planned, planned
+    kernels = set(re.findall(r"%((?:flash|ragged)[\w\-]*?)[.\d]* = ",
+                             compiled.as_text()))
+    assert {"flash_fwd", "flash_bwd"} <= kernels, kernels
+    assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels, kernels
