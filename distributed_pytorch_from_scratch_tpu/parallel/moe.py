@@ -227,8 +227,100 @@ class MoEFFN:
         return y.reshape(b, t, d), aux
 
 
+# ---- the sorted dispatch's row movers (SharedRoutedFFN) ----
+#
+# A chunk of the sorted pairs is a partial permutation of the (token,
+# choice) pairs: sorted row r of the chunk holds token `tok[r]`, and pair
+# (s, j) sits in row `idx[s, j]` of it, or `idx[s, j] == M` (one past the
+# chunk: it reads as a zero row) where its expert is absent or its row lies
+# in another chunk. So rows can move by GATHERS in both directions, and
+# each mover is the other's transpose and says so, where autodiff would
+# make the gather's a row scatter-add. The chunk's first `n` rows are the
+# held pairs'; the rows past them are PADDING, which the movers own:
+# `take_rows` makes them zeros, `sum_rows` does not read them, and so no
+# cotangent of a padding row is read either (the grouped products'
+# transposes write whatever they like there).
+#
+# What a 4 KB row costs on a v5e (bf16 x 2048; `scripts/
+# tune_moe_dispatch.py` alone on the chip at the four expert cells' shapes,
+# PERF.md section 6, PR 42): XLA:TPU's row scatter-add, which has to allow
+# for rows that collide, 75 - 82 ns a row of the M it adds, whatever the
+# shape; a gathered row of the S k that `sum_rows` reads from the chunk,
+# the sum over k included, 42 - 50 ns. The gathers win where the pairs are
+# under 1.6 times the chunk's rows (a held share of an eighth or more:
+# 3.28 ms against 5.15 a call at S k = M = 65,536, 6.25 against 7.36 at
+# 131,072 over 98,304) and lose at a sixteenth (6.31 against 4.04 at
+# 131,072 over 49,152, fifteen of sixteen gathered rows the zero row), so
+# `SharedRoutedFFN.apply` asks the two costs, on its static shapes, a layer
+# at a time, and keeps the scatter-add (its text of before) where they say.
+ROW_GATHER_NS = 48
+ROW_SCATTER_NS = 78
+
+
+def _held(tok: jax.Array, n: jax.Array) -> jax.Array:
+    return (jnp.arange(tok.shape[0]) < n)[:, None]
+
+
+@jax.custom_vjp
+def take_rows(x: jax.Array, tok: jax.Array, idx: jax.Array, n: jax.Array
+              ) -> jax.Array:
+    """(S, d) tokens -> the chunk's (M, d) rows: `x[tok]` in the first n,
+    SELECTED zeros past them."""
+    with jax.named_scope("moe_route"), jax.named_scope("take_rows"):
+        return jnp.where(_held(tok, n), jnp.take(x, tok, axis=0), 0)
+
+
+@jax.custom_vjp
+def sum_rows(y: jax.Array, r: jax.Array, tok: jax.Array, idx: jax.Array,
+             n: jax.Array) -> jax.Array:
+    """(S, d) sums and the chunk's (M, d) rows -> `y[s] + sum_j r[idx[s,
+    j]]`: k row gathers a token, summed in float32 and cast once."""
+    with jax.named_scope("moe_route"), jax.named_scope("sum_rows"):
+        (S, k), M = idx.shape, r.shape[0]
+        # The columns of `idx` are walked a gather of at most M - 2 S rows
+        # at a time: the gathered rows and the sum, in and out, then take
+        # what the chunk's rows take, which is what the scatter-add's own
+        # sorted copy of its updates took (the compiler's plan of this
+        # layer alone at S k = 131,072 over M = 98,304: 3,899 MB against
+        # the scatter-add's 3,897, where six columns at once read 4,032
+        # and all eight 4,065). A gather's columns are summed in float32
+        # as adds of (S, d) slabs: ONE element-wise fusion reads the
+        # gathered rows once (as a `reduce` over a float32 copy of them it
+        # ran three passes in the step where it ran one alone, PERF.md
+        # section 6, PR 42). The barrier keeps a gather behind the sum
+        # before it, which it hands on in y's dtype.
+        at_once = max(1, M // S - 2)
+        for a in range(0, k, at_once):
+            cols = idx.T[a:a + at_once]
+            if a:
+                y, cols = lax.optimization_barrier((y, cols))
+            picked = jnp.take(r, cols.reshape(-1), axis=0,
+                              mode="clip").reshape(-1, S, r.shape[1])
+            acc = y.astype(jnp.float32)
+            for j in range(cols.shape[0]):
+                acc = acc + jnp.where((cols[j] < M)[:, None], picked[j], 0)
+            y = acc.astype(y.dtype)
+        return y
+
+
+def _take_rows_bwd(res, g):
+    tok, idx, n = res
+    zeros = jnp.zeros((idx.shape[0], g.shape[1]), g.dtype)
+    return sum_rows(zeros, g, tok, idx, n), None, None, None
+
+
+take_rows.defvjp(
+    lambda x, tok, idx, n: (take_rows(x, tok, idx, n), (tok, idx, n)),
+    _take_rows_bwd)
+sum_rows.defvjp(
+    lambda y, r, tok, idx, n: (sum_rows(y, r, tok, idx, n), (tok, idx, n)),
+    lambda res, g: (g, take_rows(g, *res), None, None, None))
+
+
 # A chunk of `SharedRoutedFFN`'s sorted pairs holds this many times the
-# job's mean share of them. One reading set it, not a law: Zipf ids through
+# job's mean share of them (its rows move by the movers above: gathers both
+# ways at a share of an eighth or more, `ROW_GATHER_NS` / `ROW_SCATTER_NS`).
+# One reading set it, not a law: Zipf ids through
 # a freshly initialised router on a v5e, where no step's held rows passed
 # 5.4 times the mean share and a first chunk of 4 shares was crossed in a
 # tenth of one run's steps in twelve (PERF.md section 6, PR 33). It is
@@ -263,7 +355,18 @@ class SharedRoutedFFN:
     (token, choice) pairs are sorted by held expert (absent ones last) and
     the held experts' rows go through grouped matrix products
     (`lax.ragged_dot`: XLA:TPU makes it a grouped-matmul kernel whose grid
-    follows the group sizes). The sorted pairs are walked in chunks
+    follows the group sizes). The sort is a permutation and the layer
+    keeps both directions of it: `order` (the pair of a sorted row) and
+    its inverse `pos` (the sorted row of a pair, from a prefix sum over a
+    one-hot of the keys). Rows go in by a gather (`take_rows`: `x[tok]`,
+    zeros in the padding rows) and come back by `sum_rows`: k row gathers
+    a token through `pos`, summed in float32, where the pairs are under
+    1.6 times the chunk's rows (a held share of an eighth or more), the
+    row scatter-add `y.at[tok].add` where they are more (a sixteenth);
+    each mover is the other's transpose by `jax.custom_vjp`, so the
+    backward moves rows the same way and reads no padding row's cotangent
+    (`ROW_GATHER_NS` / `ROW_SCATTER_NS`, above `CHUNK_SHARES`: one rule
+    on static shapes, measured). The sorted pairs are walked in chunks
     (`chunk_rows`) under one `lax.scan`; a chunk past the last held row is
     skipped by a `lax.cond` (where there are several: a chunk of ALL the
     pairs is always computed), so memory follows the chunk, while every pair
@@ -417,6 +520,10 @@ class SharedRoutedFFN:
         xf = x.reshape(S, d)
         xd = copy_to(xf.astype(compute_dtype), self.tp_axis)
 
+        M = self.chunk_rows(S * k)
+        # rows move by gathers both ways or by the row scatter-add: the
+        # movers' two costs decide, on static shapes, a layer at a time
+        gathers = S * k * ROW_GATHER_NS <= M * ROW_SCATTER_NS
         with jax.named_scope("moe_route"):
             chosen, w = self.route(params, xf)
             local = chosen - self.offset
@@ -427,6 +534,13 @@ class SharedRoutedFFN:
             ends = jnp.cumsum(jnp.bincount(key, length=H + 1)[:H])
             rows_here = ends[-1]
             token = order // k
+            if gathers:
+                # the inverse permutation, the sorted row of pair (s, j):
+                # its expert's first row plus the earlier pairs of it
+                hot = jax.nn.one_hot(key, H + 1, dtype=jnp.int32)
+                first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
+                pos = jnp.sum((jnp.cumsum(hot, axis=0) - hot + first) * hot,
+                              axis=1).reshape(S, k)
             w_sorted = w.reshape(-1)[order]
             counters = {
                 "routed": jnp.bincount(chosen.reshape(-1),
@@ -434,7 +548,6 @@ class SharedRoutedFFN:
                                        ).astype(jnp.float32),
                 "rows_here": rows_here.astype(jnp.float32)}
 
-        M = self.chunk_rows(S * k)
         chunks = -(-S * k // M)
         if chunks * M > S * k:        # the last chunk runs past the pairs
             token = jnp.pad(token, (0, chunks * M - S * k))
@@ -463,12 +576,20 @@ class SharedRoutedFFN:
                     # whatever the buffer held, from the forward products
                     # and from their transposes alike (a 5,000-fold
                     # gradient norm on the chip, PR 33; the CPU lowering
-                    # zero-fills). Every row has a group here; both sides
-                    # of the products are still SELECTED (never
-                    # multiplied) by `valid`, whose transpose drops the
-                    # padding's cotangent rows before the gather's
-                    # scatter-add.
-                    rows = jnp.where(valid, jnp.take(xd, tok, axis=0), 0)
+                    # zero-fills). Every row has a group here, and padding
+                    # is still SELECTED away, never multiplied: going in
+                    # and on the cotangent side by the movers or by
+                    # `valid`, coming out by `valid` (the weights'
+                    # cotangent reads every row of `out`).
+                    if gathers:
+                        # a held pair whose row is in this chunk; every
+                        # other reads the zero row
+                        n, at = rows_here - lo, pos - lo
+                        idx = jnp.where(
+                            (at >= 0) & (at < jnp.minimum(n, M)), at, M)
+                        rows = take_rows(xd, tok, idx, n)
+                    else:
+                        rows = jnp.where(valid, jnp.take(xd, tok, axis=0), 0)
                 with jax.named_scope("moe_experts"):
                     gu = lax.ragged_dot(rows, gate_up, sizes)
                     out = lax.ragged_dot(
@@ -478,7 +599,10 @@ class SharedRoutedFFN:
                     # cotangent is the row itself
                     out = (jnp.where(valid, out, 0)
                            * wc[:, None].astype(out.dtype))
-                    return y.at[tok].add(out.astype(y.dtype))
+                    out = out.astype(y.dtype)
+                    if gathers:
+                        return sum_rows(y, out, tok, idx, n)
+                    return y.at[tok].add(out)
 
             if chunks == 1:
                 # the one chunk is ALL the pairs (a held share of a sixth

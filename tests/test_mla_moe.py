@@ -217,6 +217,203 @@ def test_a_router_forced_onto_the_same_experts_drops_nothing():
     np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
 
 
+# ---- the dispatch's row movers: gathers both ways ----
+
+def held(tok, n):
+    """The chunk's first n rows (the held pairs'); the rest is padding."""
+    return (jnp.arange(tok.shape[0]) < n)[:, None]
+
+
+def plain_take(x, tok, idx, n):
+    """The gather as the layer wrote it before the movers, its select
+    with it (the transpose is autodiff's: a select and a row
+    scatter-add)."""
+    return jnp.where(held(tok, n), jnp.take(x, tok, axis=0), 0)
+
+
+def plain_sum(y, r, tok, idx, n):
+    """The combine as the layer wrote it before the movers: a row
+    scatter-add over the chunk's tokens, of rows selected to zeros past
+    the held pairs'."""
+    return y.at[tok].add(jnp.where(held(tok, n), r, 0))
+
+
+def a_chunk(S, k, E, H, M, c, seed=0):
+    """Chunk `c` of a random routing's sorted pairs, by `apply`'s own
+    formulae: `tok` (padded past the pairs), `idx`, `n = rows_here - lo`."""
+    chosen = jnp.argsort(jax.random.uniform(jax.random.key(seed), (S, E)),
+                         axis=-1)[:, :k]
+    key = jnp.where(chosen < H, chosen, H).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    rows_here = int(jnp.sum(key < H))
+    pos = jnp.argsort(order).reshape(S, k)
+    chunks = -(-S * k // M)
+    tok = jnp.pad(order // k, (0, chunks * M - S * k))[c * M:(c + 1) * M]
+    lo = c * M
+    idx = jnp.where((pos < rows_here) & (pos >= lo) & (pos < lo + M),
+                    pos - lo, M)
+    return tok, idx, rows_here - lo, rows_here
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("case,S,k,E,H,M,c", [
+    ("one chunk of all the pairs", 64, 4, 8, 2, 256, 0),
+    ("first of three, all its rows held", 64, 4, 8, 5, 96, 0),
+    ("the chunk the held rows end in", 64, 4, 8, 5, 96, 1),
+    ("a chunk that runs past the pairs", 60, 3, 8, 8, 64, 2),
+    ("a chunk no held row reaches", 64, 4, 8, 2, 96, 2),
+])
+def test_the_row_movers_are_the_plain_forms_and_each_other_s_transpose(
+        case, S, k, E, H, M, c, tp):
+    """`take_rows` / `sum_rows` against `jax.vjp` of the plain gather and
+    the plain row scatter-add, values and all three cotangents, on chunks whose tokens repeat (a token with several held
+    experts), with pairs not held, padding rows past `rows_here` and past
+    the pairs THAT HOLD NaN going in and on the cotangent side (what a
+    grouped product may leave there on the chip: selected away, never
+    multiplied); under `shard_map` with the rows varying over tp, as the
+    layer's are."""
+    from jax.sharding import PartitionSpec as P
+    from distributed_pytorch_from_scratch_tpu.ops.collectives import copy_to
+    from distributed_pytorch_from_scratch_tpu.parallel.moe import (
+        sum_rows, take_rows)
+
+    d = 16
+    tok, idx, n, rows_here = a_chunk(S, k, E, H, M, c)
+    held_here = int(jnp.sum(idx < M))
+    assert held_here == max(0, min(rows_here - c * M, M))
+    if case == "a chunk no held row reaches":
+        assert held_here == 0
+    elif case.startswith("first of three"):
+        assert held_here == M
+    else:                               # padding rows past the held pairs
+        assert 0 < held_here < M
+    if c == 0:      # a token of several held experts is in the chunk twice
+        assert int(jnp.max(jnp.sum(idx < M, axis=1))) >= 2
+    keys = jax.random.split(jax.random.key(1), 5)
+    x, y, gy = (jax.random.normal(kk, (S, d)) for kk in keys[:3])
+    # what the grouped products leave in padding rows on the chip: anything
+    r, gr = (jnp.where(held(tok, n), jax.random.normal(kk, (M, d)), jnp.nan)
+             for kk in keys[3:])
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+
+    def both(take, add):
+        def shard(x, y, r, gr, gy):
+            # rows that differ between the tp ranks, like a partial sum
+            rank = 1.0 + jax.lax.axis_index("tp")
+            vary = lambda a: copy_to(a, "tp") * rank
+            out, pull = jax.vjp(lambda x, y, r: (
+                take(x, tok, idx, n), add(y, r, tok, idx, n)),
+                vary(x), vary(y), vary(r))
+            return jax.tree.map(lambda a: a[None],
+                                (out, pull((vary(gr), vary(gy)))))
+        return jax.jit(jax.shard_map(shard, mesh=mesh, in_specs=P(),
+                                     out_specs=P("tp")))(x, y, r, gr, gy)
+
+    got, want = both(take_rows, sum_rows), both(plain_take, plain_sum)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.shape[0] == tp
+        np.testing.assert_allclose(a, b, atol=1e-6)
+    if held_here == 0:
+        assert not np.any(np.asarray(got[1][0]))      # no cotangent to x
+        np.testing.assert_array_equal(                # nothing combined
+            got[0][1], y[None] * (1.0 + np.arange(tp))[:, None, None])
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("case,E,H,k,forced,chunks,gathers", [
+    ("one chunk of all the pairs", 8, 2, 2, False, 1, True),
+    ("two live chunks", 24, 3, 3, True, 2, True),
+    ("three live chunks", 64, 4, 3, True, 3, False),
+    ("chunks the routing does not reach", 64, 4, 3, False, 3, False),
+])
+def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
+        monkeypatch, case, E, H, k, forced, chunks, gathers, dtype, tol, tp):
+    """`SharedRoutedFFN.apply` moving its rows by the movers, whatever its
+    shape rule would pick (under 1.6 pairs a row of the chunk: gathers;
+    the rule's own verdict is asserted, then set aside), against itself
+    with the plain gather and row scatter-add in their place (the form
+    the layer had, kept HERE as the oracle): a scalar of the output and
+    the gradient of every leaf and of the input, float32 to 1e-6 of a
+    leaf's largest entry, bfloat16 (whose scatter-add sums in bf16 where
+    `sum_rows` sums in float32) within the family tests' tolerance."""
+    from jax.sharding import PartitionSpec as P
+    from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
+
+    d, f = 32, 16
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=H, tp_size=tp)
+    p = moe.init(jax.random.key(1))
+    if forced:
+        p["bias"] = jnp.where(jnp.arange(E) < k, 10.0, 0.0)
+    x = jax.random.normal(jax.random.key(2), (4, 214, d))
+    pairs = 4 * 214 * k
+    assert -(-pairs // moe.chunk_rows(pairs)) == chunks
+    assert (pairs * moe_mod.ROW_GATHER_NS
+            <= moe.chunk_rows(pairs) * moe_mod.ROW_SCATTER_NS) == gathers
+    monkeypatch.setattr(moe_mod, "ROW_SCATTER_NS", 10 ** 9)
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+
+    def value_and_grads():
+        def loss(p, x):
+            y, _ = jax.shard_map(
+                lambda p, x: moe.apply(p, x, jnp.dtype(dtype)), mesh=mesh,
+                in_specs=(moe.specs(), P()), out_specs=(P(), P()))(p, x)
+            return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(p, x)
+
+    got, got_g = value_and_grads()
+    monkeypatch.setattr(moe_mod, "take_rows", plain_take)
+    monkeypatch.setattr(moe_mod, "sum_rows", plain_sum)
+    want, want_g = value_and_grads()
+    assert abs(float(got) - float(want)) <= tol * max(abs(float(want)), 1.0)
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g)) == 9
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("cell,E,H,k,chunk,gathers", [
+    ("joyai-llm-flash.train-ep16share-b4-t4096", 256, 16, 8, 49152, False),
+    ("qwen3-next-80b-a3b.train-ep16share-b2-t8192", 512, 32, 10, 61440,
+     False),
+    ("lfm2-8b-a1b.train-ep4share-b2-t8192", 32, 8, 4, 65536, True),
+    ("sdar-30b-a3b.train-ep8share-b2-t4096", 128, 16, 8, 98304, True),
+])
+def test_the_gradient_s_text_scatters_rows_only_where_the_rule_says(
+        cell, E, H, k, chunk, gathers):
+    """The lowered gradient of the layer at each expert cell's routing
+    (16,384 tokens of 2048 in bf16, the cell's experts, held share and k)
+    holds NO scatter whose updates are rows of d where the shape rule
+    picks the gathers (a held share of an eighth or more), and holds the
+    transposed gather's where it keeps the scatter-add (a sixteenth); the
+    scalar scatters (the counts, the weights' cotangent) are everywhere."""
+    import re
+    from jax.sharding import PartitionSpec as P
+
+    d = 2048
+    moe = SharedRoutedFFN(d, 128, E, top_k=k, held=H, n_shared=0)
+    assert moe.chunk_rows(16384 * k) == chunk
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    params = jax.eval_shape(moe.init, jax.random.key(0))
+    x = jax.ShapeDtypeStruct((2, 8192, d), jnp.bfloat16)
+
+    def loss(p, x):
+        y, _ = jax.shard_map(
+            lambda p, x: moe.apply(p, x, jnp.bfloat16), mesh=mesh,
+            in_specs=(moe.specs(), P()), out_specs=(P(), P()))(p, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x).as_text()
+    updates = [sig.split(", ")[-1] for sig in re.findall(
+        r"stablehlo\.scatter.*?\}\) : \((.*?)\) ->", text, re.S)]
+    assert len(updates) >= 4, updates
+    rows = [u for u in updates if u.endswith(f"x{d}xbf16>")]
+    assert rows == ([] if gathers else [f"tensor<{chunk}x{d}xbf16>"]), rows
+
+
 # ---- the step, its counters, the entry point ----
 
 def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
