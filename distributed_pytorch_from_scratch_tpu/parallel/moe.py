@@ -317,6 +317,62 @@ sum_rows.defvjp(
     lambda res, g: (g, take_rows(g, *res), None, None, None))
 
 
+# ---- the sorted dispatch's index work (SharedRoutedFFN) ----
+#
+# Beside the rows, a layer moves four bytes a (token, choice) pair: the
+# chosen experts' scores, the weights into sorted order, the counts of the
+# held and of all routed experts. Written as `take_along_axis`, `w[order]`
+# and `bincount` each is an XLA scalar gather or scatter-add, which the
+# chip walks an element at a time: 8 - 10 ns an element in the step, what a
+# 4 KB row costs to move (PERF.md section 6, PR 43). So none is: a value
+# picked by an index is a compare against an iota and a reduce over a
+# one-hot that is never stored (one term is not zero: exact), a count is a
+# column sum of such a one-hot, and a value that follows the sort rides it
+# as an operand. Autodiff transposes the first into the same compare
+# (a select of the cotangent, summed over the choices); the sort says its
+# own transpose below.
+
+
+def pick_scores(s: jax.Array, chosen: jax.Array) -> jax.Array:
+    """(S, E) scores, (S, k) chosen -> `take_along_axis(s, chosen, -1)`."""
+    with jax.named_scope("index"):
+        hot = chosen[..., None] == jnp.arange(s.shape[-1], dtype=chosen.dtype)
+        return jnp.sum(jnp.where(hot, s[:, None, :], 0), axis=-1)
+
+
+def count_keys(key: jax.Array, length: int) -> jax.Array:
+    """(N,) keys in [0, length) -> `bincount(key, length=length)`, int32."""
+    hot = key[:, None] == jnp.arange(length, dtype=key.dtype)
+    return jnp.sum(hot, axis=0, dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def sort_pairs(key: jax.Array, w: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(N,) keys and weights -> `order = argsort(key, stable=True)` and
+    `w[order]`: ONE sort with the pair's number and its weight as operands.
+    The cotangent of `w` goes back by a sort on `order` (a permutation's
+    inverse is the sort of it), where autodiff would gather through the
+    sort and transpose that into a scalar scatter-add."""
+    with jax.named_scope("moe_route"), jax.named_scope("index"):
+        _, order, w_sorted = lax.sort(
+            (key, lax.iota(jnp.int32, key.shape[0]), w), num_keys=1,
+            is_stable=True)
+        return order, w_sorted
+
+
+def _sort_pairs_fwd(key, w):
+    order, w_sorted = sort_pairs(key, w)
+    return (order, w_sorted), order
+
+
+def _sort_pairs_bwd(order, g):
+    with jax.named_scope("moe_route"), jax.named_scope("index"):
+        return None, lax.sort((order, g[1]), num_keys=1)[1]
+
+
+sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
+
+
 # A chunk of `SharedRoutedFFN`'s sorted pairs holds this many times the
 # job's mean share of them (its rows move by the movers above: gathers both
 # ways at a share of an eighth or more, `ROW_GATHER_NS` / `ROW_SCATTER_NS`).
@@ -349,7 +405,13 @@ class SharedRoutedFFN:
     `s[chosen]`, normalised over ALL chosen experts, held or not, times
     `scaling`. The layer adds `w_e E_e(x)` for the chosen experts it holds
     and the shared expert; what an absent expert would have added is left
-    out (with `held == num_experts` nothing is).
+    out (with `held == num_experts` nothing is). No value here is looked up
+    by an index: `s[chosen]` is a compare of `chosen` against an iota and a
+    sum over the one-hot (`pick_scores`), the held and the routed experts'
+    counts are column sums of such one-hots (`count_keys`), and the
+    weights reach sorted order as an operand of the sort (`sort_pairs`):
+    a scalar gather or scatter-add costs the chip what a 4 KB row costs
+    (above `pick_scores`).
 
     Dispatch is sorted and grouped, with no capacity and NO DROP: the
     (token, choice) pairs are sorted by held expert (absent ones last) and
@@ -486,9 +548,39 @@ class SharedRoutedFFN:
             s = jax.nn.sigmoid(logits)
             _, chosen = lax.top_k(s + lax.stop_gradient(params["bias"]),
                                   self.top_k)
-        w = jnp.take_along_axis(s, chosen, axis=-1)
+        w = pick_scores(s, chosen)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * self.scaling
         return chosen, w
+
+    def index(self, chosen: jax.Array, w: jax.Array, inverse: bool):
+        """The sorted dispatch's index work over the (S, k) pairs, with no
+        scalar gather or scatter (above `sort_pairs`): `order`, the pair of
+        a sorted row, pairs sorted by held expert and absent experts last;
+        `w_sorted`, the weights in that order; `ends` (held,), the sorted
+        row each held expert's pairs end at; `pos` (S, k), the sorted row
+        of a pair (`order`'s inverse; None unless `inverse`); `routed`
+        (num_experts,) int32, the pairs each routed expert was chosen
+        for."""
+        S, k = chosen.shape
+        H = self.num_held
+        local = chosen - self.offset
+        here = (local >= 0) & (local < H)
+        key = jnp.where(here, local, H).reshape(-1)             # (S*k,)
+        order, w_sorted = sort_pairs(key, w.reshape(-1))
+        with jax.named_scope("index"):
+            pos = None
+            if inverse:
+                # its expert's first row plus the earlier pairs of it: a
+                # prefix sum over the one-hot whose column sums `ends` are
+                hot = jax.nn.one_hot(key, H + 1, dtype=jnp.int32)
+                ends = jnp.cumsum(jnp.sum(hot, axis=0)[:H])
+                first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
+                pos = jnp.sum((jnp.cumsum(hot, axis=0) - hot + first) * hot,
+                              axis=1).reshape(S, k)
+            else:
+                ends = jnp.cumsum(count_keys(key, H + 1)[:H])
+            routed = count_keys(chosen.reshape(-1), self.num_experts)
+        return order, w_sorted, ends, pos, routed
 
     @property
     def chunk_share(self) -> float:
@@ -516,7 +608,7 @@ class SharedRoutedFFN:
         for, `rows_here` the pairs whose expert is held (the rows the
         grouped products compute), both float32 and local to this shard."""
         b, t, d = x.shape
-        S, k, H = b * t, self.top_k, self.num_held
+        S, k = b * t, self.top_k
         xf = x.reshape(S, d)
         xd = copy_to(xf.astype(compute_dtype), self.tp_axis)
 
@@ -526,27 +618,12 @@ class SharedRoutedFFN:
         gathers = S * k * ROW_GATHER_NS <= M * ROW_SCATTER_NS
         with jax.named_scope("moe_route"):
             chosen, w = self.route(params, xf)
-            local = chosen - self.offset
-            here = (local >= 0) & (local < H)
-            # sort the pairs by held expert; absent experts sort last
-            key = jnp.where(here, local, H).reshape(-1)        # (S*k,)
-            order = jnp.argsort(key, stable=True)
-            ends = jnp.cumsum(jnp.bincount(key, length=H + 1)[:H])
+            order, w_sorted, ends, pos, routed = self.index(
+                chosen, w, inverse=gathers)
             rows_here = ends[-1]
             token = order // k
-            if gathers:
-                # the inverse permutation, the sorted row of pair (s, j):
-                # its expert's first row plus the earlier pairs of it
-                hot = jax.nn.one_hot(key, H + 1, dtype=jnp.int32)
-                first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
-                pos = jnp.sum((jnp.cumsum(hot, axis=0) - hot + first) * hot,
-                              axis=1).reshape(S, k)
-            w_sorted = w.reshape(-1)[order]
-            counters = {
-                "routed": jnp.bincount(chosen.reshape(-1),
-                                       length=self.num_experts
-                                       ).astype(jnp.float32),
-                "rows_here": rows_here.astype(jnp.float32)}
+            counters = {"routed": routed.astype(jnp.float32),
+                        "rows_here": rows_here.astype(jnp.float32)}
 
         chunks = -(-S * k // M)
         if chunks * M > S * k:        # the last chunk runs past the pairs
@@ -568,7 +645,8 @@ class SharedRoutedFFN:
                     # rows of each held expert inside [lo, lo + M); the
                     # rows past the last held pair go to the last expert,
                     # as zeros (see the class docstring)
-                    hi_e = jnp.clip(ends - lo, 0, M).at[-1].set(M)
+                    hi_e = jnp.concatenate([jnp.clip(ends[:-1] - lo, 0, M),
+                                            jnp.full((1,), M, ends.dtype)])
                     sizes = jnp.diff(hi_e, prepend=0).astype(jnp.int32)
                     valid = ((lo + jnp.arange(M)) < rows_here)[:, None]
                     # The grouped kernels write the rows of their groups

@@ -4,6 +4,7 @@ tokens of d = 2048 in bf16; k choices, E routed experts of which H are held,
 so a chunk of M = `chunk_rows(S k)` sorted rows):
 
     python scripts/tune_moe_dispatch.py [--cells 5,6,7,8] [--check]
+        [--forms rows|index|all]
 
 prints, a cell, device milliseconds from a profiler capture (the union of
 the ops' intervals a call, and the form's longest ops by name), and
@@ -27,13 +28,24 @@ columns compare):
   - the inverse permutation `pos` three ways (a second sort, a prefix sum
     over a one-hot of the keys, a scalar scatter of an iota) and the sort
     of the keys that the dispatch already makes;
+  - the INDEX work over the S k pairs (`--forms index` times it alone, a
+    minute a cell; ns an ELEMENT there is the ms over S k): the plain
+    forms, each an XLA scalar gather or scatter-add (`bincount` of the
+    keys and of the chosen experts, `take_along_axis(s, chosen)`,
+    `w[order]`, and the transposes of those two), beside the program's
+    (`count_keys`, `pick_scores`, `sort_pairs`, the cotangents by
+    `jax.vjp`), `SharedRoutedFFN.index` whole against
+    the plain forms whole, and the weights' cotangent taken back through
+    `pos` (a scalar gather) for the price of what was not taken;
   - with `--check`, ON THE CHIP, `take_rows` / `sum_rows` and their
     cotangents against the plain forms (float32 to 1e-6, bf16 to a
-    rounding of the float32 sum), the padding rows holding NaN.
+    rounding of the float32 sum), the padding rows holding NaN; and the
+    index forms against the plain ones, EXACTLY (the cotangents too: a
+    selection and a permutation round nothing).
 
 Each cell runs in a child process with a timeout (the parent touches no
 JAX: a chip belongs to one process). The table behind `parallel/moe.py`'s
-choice is PERF.md's (section 6, PR 42; TPU v5 lite).
+choice is PERF.md's (section 6, PRs 42 and 43; TPU v5 lite).
 """
 
 import argparse
@@ -95,6 +107,91 @@ def pos_ways(k, held):
     return {"pos by a second sort": by_sort,
             "pos by a prefix sum": by_prefix,
             "pos by a scalar scatter": by_scatter}
+
+
+def index_forms(s, k, experts, held, seed):
+    """{name: (fn, operands)} of the index work at a cell's shape, plain
+    and the program's, and the pairs `check_index` holds equal."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_pytorch_from_scratch_tpu.parallel.moe import (
+        SharedRoutedFFN, count_keys, pick_scores, sort_pairs)
+
+    moe = SharedRoutedFFN(8, 8, experts, top_k=k, held=held)
+    keys = jax.random.split(jax.random.key(seed + 2), 3)
+    scores = jax.nn.softmax(jax.random.normal(keys[0], (s, experts)))
+    _, chosen = jax.lax.top_k(scores, k)
+    w = jnp.take_along_axis(scores, chosen, axis=-1)
+    key = jnp.where(chosen < held, chosen, held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    pos = jnp.argsort(order).astype(jnp.int32)
+    g_w, g_flat = (jax.random.normal(kk, a.shape)
+                   for kk, a in zip(keys[1:], (w, key)))
+
+    def plain_index(chosen, w):
+        order = jnp.argsort(key, stable=True)
+        return (order, w.reshape(-1)[order],
+                jnp.cumsum(jnp.bincount(key, length=held + 1)[:held]),
+                jnp.bincount(chosen.reshape(-1), length=experts))
+
+    def program_index(chosen, w):
+        order, w_sorted, ends, _, routed = moe.index(chosen, w, False)
+        return order, w_sorted, ends, routed
+
+    pull = lambda fn: (lambda g, a, *rest: jax.vjp(
+        lambda a: fn(a, *rest), a)[1](g)[0])
+    take = lambda s, chosen: jnp.take_along_axis(s, chosen, axis=-1)
+    permute = lambda w, order: w[order]
+    by_sort = lambda w, key: sort_pairs(key, w)[1]
+    pairs = {       # plain form, the program's, operands
+        "counts of the keys": (
+            lambda key: jnp.bincount(key, length=held + 1),
+            lambda key: count_keys(key, held + 1), (key,)),
+        "counts of the chosen": (
+            lambda c: jnp.bincount(c.reshape(-1), length=experts),
+            lambda c: count_keys(c.reshape(-1), experts), (chosen,)),
+        "s[chosen]": (take, pick_scores, (scores, chosen)),
+        "cotangent of s[chosen]": (pull(take), pull(pick_scores),
+                                   (g_w, scores, chosen)),
+        "w[order]": (lambda w, key, order: permute(w, order),
+                     lambda w, key, order: by_sort(w, key),
+                     (w.reshape(-1), key, order)),
+        "cotangent of w[order]": (
+            lambda g, w, key, order: pull(permute)(g, w, order),
+            lambda g, w, key, order: pull(by_sort)(g, w, key),
+            (g_flat, w.reshape(-1), key, order)),
+        "the index work whole": (plain_index, program_index, (chosen, w)),
+        "cotangent of w through the index work": (
+            lambda g, chosen, w: jax.vjp(
+                lambda w: plain_index(chosen, w)[1], w)[1](g)[0],
+            lambda g, chosen, w: jax.vjp(
+                lambda w: program_index(chosen, w)[1], w)[1](g)[0],
+            (g_flat, chosen, w)),
+    }
+    timed = {}
+    for name, (plain, program, operands) in pairs.items():
+        timed[f"{name}, plain"] = (plain, operands)
+        timed[f"{name}, the program's"] = (program, operands)
+    timed["cotangent of w[order] as g[pos] (a scalar gather)"] = (
+        lambda g, pos: g[pos], (g_flat, pos))
+    return timed, pairs
+
+
+def check_index(pairs):
+    """The program's index forms against the plain ones on this backend:
+    every integer, every selection and every cotangent EXACTLY."""
+    import jax
+    import numpy as np
+
+    for name, (plain, program, operands) in pairs.items():
+        want = jax.tree.leaves(jax.jit(plain)(*operands))
+        got = jax.tree.leaves(jax.jit(program)(*operands))
+        assert len(want) == len(got)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=name)
+        print(f"  check {name}: equal", flush=True)
 
 
 def sum_rows_ways():
@@ -191,6 +288,7 @@ def child(args):
             return jax.vjp(at, operands[arg])[1](g)[0]
         return pulled
 
+    index, index_pairs = index_forms(s, k, experts, held, args.seed)
     timed = {
         "x[tok] (plain gather)": (plain_take, (x, tok)),
         "r[3 tok % M] (a gather from the chunk's rows)": (
@@ -221,8 +319,14 @@ def child(args):
 
     rows = {}
 
-    if args.check:
+    if args.forms == "index":
+        timed = {}
+    if args.check and args.forms != "index":
         check(x, y, r, tok, idx, n)
+    if args.check and args.forms != "rows":
+        check_index(index_pairs)
+    if args.forms != "rows":
+        timed.update(index)
     for name, (fn, operands) in timed.items():
         ops = capture_ms(jax.jit(fn), *operands, iters=args.iters)
         ms = ops.pop("busy")
@@ -230,9 +334,12 @@ def child(args):
             print(f"  {name:48s} not measured", flush=True)
             continue
         rows[name] = ms
+        # an index form walks the S k pairs, a mover the chunk's M rows
+        per, unit = ((s * k, "an element") if name in index
+                     else (m, "a row of M"))
         longest = sorted(ops.items(), key=lambda kv: -kv[1])[:args.top]
-        print(f"  {name:48s} {ms:8.3f} ms  {ms * 1e6 / m:7.1f} ns a row of "
-              f"M   " + ", ".join(f"{k} {v:.3f}" for k, v in longest),
+        print(f"  {name:48s} {ms:8.3f} ms  {ms * 1e6 / per:7.1f} ns {unit}"
+              f"   " + ", ".join(f"{k} {v:.3f}" for k, v in longest),
               flush=True)
     print(json.dumps({**head, "ms": rows}), flush=True)
 
@@ -287,6 +394,9 @@ def parse_args(argv=None):
                     help="a form's longest ops to name beside its time")
     ap.add_argument("--check", action="store_true",
                     help="hold the movers to the plain forms on this backend")
+    ap.add_argument("--forms", default="all",
+                    choices=("rows", "index", "all"),
+                    help="the row movers, the index work, or both")
     ap.add_argument("--timeout", type=int, default=600,
                     help="seconds a cell's child may take")
     ap.add_argument("--out", default=os.path.join(

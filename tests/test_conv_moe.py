@@ -529,8 +529,8 @@ def test_the_whole_chunk_is_what_the_memory_facts_count():
 # ---- the other pattern families lower to what they lowered to ----
 
 LOWERED_BEFORE = {"mla_moe": ("tiny-mla-moe", "latent_moe",
-                              "c1d2b88915d42414"),
-                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "4f130509c1068420")}
+                              "3d5a0d4ed5b64110"),
+                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "1114fa0293f11c58")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))
@@ -544,7 +544,8 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     `lax.cond` in PR 39. The optimised HLO of both tiny presets was
     compared once, parent and change, and was the same (PR 39). A PR that
     means to change either family's program changes the digest with it:
-    PR 42 did (the dispatch's row movers and the inverse permutation)."""
+    PR 42 did (the dispatch's row movers and the inverse permutation)
+    and PR 43 (its index work without a scalar gather or scatter)."""
     preset, facts, digest = LOWERED_BEFORE[family]
     cfg = model_preset(preset)
     cfg = dataclasses.replace(cfg, **{facts: dataclasses.replace(
