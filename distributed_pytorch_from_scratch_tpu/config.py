@@ -251,18 +251,10 @@ class ModelConfig:
         return ((self.vocab_size + tp_size - 1) // tp_size) * tp_size
 
     def num_params(self) -> int:
-        if self.latent_moe is not None:
-            from .models.mla_moe import LatentMoETransformer
-            return LatentMoETransformer.num_params(self)
-        if self.gdn_moe is not None:
-            from .models.gdn_moe import GdnMoETransformer
-            return GdnMoETransformer.num_params(self)
-        if self.conv_moe is not None:
-            from .models.conv_moe import ConvMoETransformer
-            return ConvMoETransformer.num_params(self)
-        if self.bd_moe is not None:
-            from .models.bd_moe import BlockDiffusionMoETransformer
-            return BlockDiffusionMoETransformer.num_params(self)
+        if self.family_facts is not None:
+            # the family that reads these facts counts what it makes
+            from .models import facts_family
+            return facts_family(self).num_params(self)
         d, f, v, L = self.attn_dim, self.ffn_dim, self.vocab_size, self.num_layers
         kd = self.kv_dim
         attn = 2 * d * d + 2 * d * kd + 2 * d + 2 * kd  # wq/wo + wk/wv (+ biases)

@@ -8,10 +8,9 @@ from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
 from .transformer import Transformer
 
-FAMILIES = {"llama": Transformer, "gpt2": GPT2Transformer,
-            "mla_moe": LatentMoETransformer, "gdn_moe": GdnMoETransformer,
-            "conv_moe": ConvMoETransformer,
-            "bd_moe": BlockDiffusionMoETransformer}
+FAMILIES = {cls.family: cls for cls in (
+    Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
+    ConvMoETransformer, BlockDiffusionMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":
@@ -19,6 +18,15 @@ def family_class(family: str) -> "type[DecoderStack]":
         raise ValueError(f"unknown model family {family!r}; expected one of "
                          f"{sorted(FAMILIES)}")
     return FAMILIES[family]
+
+
+def facts_family(cfg) -> "type[DecoderStack]":
+    """The family whose `config_extra` is `cfg.family_facts`; the stack
+    itself where there are none (llama and gpt2 share those)."""
+    if cfg.family_facts is None:
+        return DecoderStack
+    return next(cls for cls in FAMILIES.values()
+                if cls.config_extra == cfg.family_facts)
 
 
 def build_model(family: str, cfg, **kw) -> DecoderStack:

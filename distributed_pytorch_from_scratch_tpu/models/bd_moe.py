@@ -44,12 +44,11 @@ and holds only what differs:
 with their positions) and return logits for all of them: the tests of what
 the mask means read it.
 
-What is not made to work is refused where the model is built, with a
-message: pp > 1, cp > 1, ep > 1, sequence parallelism and its rings,
-pad-aware bucketing, ZeRO 2/3 and the bucketed reducer
-(`hand_reduced_grads`), `models/decode.py`, `generate.py` and the serving
-engines (`decodable`: a block decoded by denoising steps is not a token a
-step).
+What is not made to work is refused with a message: where the model is
+built (`refuses`), by ZeRO 2/3 and the bucketed reducer
+(`hand_reduced_grads`), by `models/decode.py`, `generate.py` and the
+serving engines (`decodable`: a block decoded by denoising steps is not a
+token a step).
 
 Named scopes inside the step, for a device trace's `op_name`: `bd_noise`
 (the draw, the select, the rows, positions and loss weights), `gqa_attn`
@@ -70,17 +69,10 @@ from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
 from ..ops.attention import block_diffusion
-from ..ops.rope import apply_rotary_leading, rope_angles
-from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import SharedRoutedFFN
 from ..parallel.norm import RMSNorm
-from ..runtime.prng import fold
-from .stack import DecoderStack, Params, TPSublayers
-from .transformer import Transformer
-
-MODULES = ("norm1", "wq", "wk", "wv", "q_norm", "k_norm", "wo", "norm2",
-           "moe")
+from .stack import DecoderStack, idle_expert_params
 
 
 def block_diffusion_noise(seed, step, x0: jax.Array, mask_token_id: int,
@@ -111,50 +103,36 @@ def block_diffusion_noise(seed, step, x0: jax.Array, mask_token_id: int,
 class BlockDiffusionMoETransformer(DecoderStack):
     """The bd_moe family (module docstring)."""
 
-    uses_rope = True
-    attn_norm_key = "norm1"
-    ffn_norm_key = "norm2"
+    family = "bd_moe"
     ffn_inputs = 0            # no dense MLP: every layer's FFN is routed
     tied_head = False
     decodable = False
     hand_reduced_grads = False
     config_extra = "bd_moe"
+    attn_scope = "gqa_attn"
     _router_aux_losses = False
     draws_noise = True
     head_rows_share = 0.5       # the head reads the noised half
+    refuses = {
+        "pp_size > 1": "a pipeline's microbatches would each need their "
+                       "noise and their doubled rows",
+        "cp_size > 1": "the ring and Ulysses paths mask by a causal order "
+                       "of positions; a sequence's two halves share theirs",
+        "ep_size > 1": "a job holds one share of the experts, "
+                       "cfg.bd_moe.experts_held; the all-to-all between "
+                       "shares is not written",
+        "sequence_parallel=True": "the router reads whole sequences and "
+                                  "the loss reads half of the rows",
+        "attn_t_real": "pad tokens would be routed, and the declared mask "
+                       "takes no real length",
+        "ZeRO stage 3": "",
+    }
 
-    def __post_init__(self):
+    def _check_facts(self):
         bd = self.cfg.bd_moe
-        if bd is None:
-            raise ValueError("the bd_moe family needs cfg.bd_moe "
-                             "(config.BdMoEConfig)")
-        if not self.cfg.num_experts:
-            raise ValueError("the bd_moe family needs cfg.num_experts > 0 "
-                             "(the routed experts its router scores)")
         if not 0 <= bd.mask_token_id < self.cfg.vocab_size:
             raise ValueError(f"mask_token_id {bd.mask_token_id} is not in "
                              f"the vocabulary of {self.cfg.vocab_size}")
-        refused = [
-            (self.pp_size > 1, "pp_size > 1 (a pipeline's microbatches "
-             "would each need their noise and their doubled rows)"),
-            (self.cp_size > 1, "cp_size > 1 (the ring and Ulysses paths "
-             "mask by a causal order of positions; a sequence's two halves "
-             "share theirs)"),
-            (self.ep_size > 1, "ep_size > 1 (a job holds one share of the "
-             "experts, cfg.bd_moe.experts_held; the all-to-all between "
-             "shares is not written)"),
-            (self.sequence_parallel is True, "sequence_parallel=True (the "
-             "router reads whole sequences and the loss reads half of the "
-             "rows)"),
-            (self.attn_t_real is not None, "attn_t_real (pad tokens would "
-             "be routed, and the declared mask takes no real length)"),
-            (self.zero3_axis is not None, "ZeRO stage 3"),
-        ]
-        for bad, what in refused:
-            if bad:
-                raise ValueError(f"the bd_moe family does not run with "
-                                 f"{what}")
-        super().__post_init__()
 
     # ---- facts for the stack and training/memory.py ----
 
@@ -186,11 +164,6 @@ class BlockDiffusionMoETransformer(DecoderStack):
     # ---- sub-module definitions ----
 
     @functools.cached_property
-    def embedding(self) -> VocabParallelEmbedding:
-        return VocabParallelEmbedding(self.cfg.vocab_size, self.d,
-                                      tp_size=self.tp_size)
-
-    @functools.cached_property
     def _mods(self) -> Dict[str, Any]:
         cfg, bd = self.cfg, self.cfg.bd_moe
         d, eps = self.d, bd.rms_norm_eps
@@ -201,7 +174,6 @@ class BlockDiffusionMoETransformer(DecoderStack):
                                 split_input=False)
         return {
             "norm1": RMSNorm(d, eps),
-            "norm2": RMSNorm(d, eps),
             "wq": col(d, qd),
             "wk": col(d, self.kv_dim),
             "wv": col(d, self.kv_dim),
@@ -209,6 +181,7 @@ class BlockDiffusionMoETransformer(DecoderStack):
             "q_norm": RMSNorm(bd.head_dim, eps),
             "k_norm": RMSNorm(bd.head_dim, eps),
             "wo": row(qd, d),
+            "norm2": RMSNorm(d, eps),
             "moe": SharedRoutedFFN(
                 d, bd.moe_intermediate_size, cfg.num_experts,
                 top_k=cfg.moe_top_k, held=bd.experts_held,
@@ -216,84 +189,11 @@ class BlockDiffusionMoETransformer(DecoderStack):
                 tp_size=self.tp_size, score="softmax"),
         }
 
-    @functools.cached_property
-    def final_norm(self) -> RMSNorm:
-        return RMSNorm(self.d, self.cfg.bd_moe.rms_norm_eps)
-
-    @functools.cached_property
-    def lm_head(self) -> ColumnParallelLinear:
-        return ColumnParallelLinear(self.d, self.vocab_padded,
-                                    add_bias=False, gather_output=False)
-
-    # ---- init / specs ----
-
-    def init(self, key: jax.Array) -> Params:
-        lm_head = self.lm_head.init(fold(key, "lm_head"))
-        if self.vocab_padded != self.cfg.vocab_size:
-            keep = jnp.arange(self.vocab_padded) < self.cfg.vocab_size
-            lm_head["weight"] = jnp.where(keep[None, :], lm_head["weight"],
-                                          0.0)
-        return {
-            "embedding": self.embedding.init(fold(key, "embedding")),
-            "layers": self._init_layers(key, names=MODULES),
-            "norm": self.final_norm.init(fold(key, "norm")),
-            "lm_head": lm_head,
-        }
-
-    def specs(self) -> Params:
-        return {
-            "embedding": self.embedding.specs(),
-            "layers": self._layer_specs(MODULES),
-            "norm": self.final_norm.specs(),
-            "lm_head": self.lm_head.specs(),
-        }
-
-    @staticmethod
-    def num_params(cfg: ModelConfig) -> int:
-        return sum(param_counts(cfg).values())
-
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
     def _attn_mask(self, t: int):
         """The rows of a sequence are its noised and its clean copy."""
         return block_diffusion(self.cfg.bd_moe.block_length, t // 2)
-
-    def _positions(self, params: Params, x: jax.Array,
-                   position_ids: jax.Array, dtype):
-        """Nothing enters at the embedding; every layer gets the whole
-        head's (cos, sin) at `position_ids`, computed from the positions
-        (they repeat: a sequence's two halves share theirs)."""
-        return x.astype(dtype), rope_angles(
-            position_ids, self.head_dim, self.cfg.rope_theta)
-
-    def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
-        h = self.head_dim
-        return (apply_rotary_leading(q, *layer_pos, h),
-                apply_rotary_leading(k, *layer_pos, h))
-
-    def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
-             dtype, b: int, t: int):
-        with jax.named_scope("gqa_attn"):
-            return super()._qkv(lp, y, tp, layer_pos, dtype, b, t)
-
-    def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
-                      dtype) -> jax.Array:
-        with jax.named_scope("gqa_attn"):
-            return tp.row(lp, "wo", o, dtype)
-
-    _head_logits = Transformer._head_logits
-
-    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
-        return self._mods["moe"].apply(lp["moe"], y, dtype)
-
-    def _fold_aux(self, auxs):
-        # the layers' counters stay one row a layer
-        return auxs
-
-    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
-                    aux, trunk, input_ids, target_ids, position_ids,
-                    mode: str, batch_axes):
-        return loss, jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
 
     def _noised_rows(self, input_ids: jax.Array, position_ids: jax.Array,
                      noise):
@@ -333,15 +233,29 @@ class BlockDiffusionMoETransformer(DecoderStack):
         batch = P(("dp", "ep"), "cp")
         return batch, batch, P(("dp", "ep"))
 
+    @staticmethod
+    def param_counts(cfg: ModelConfig) -> Dict[str, int]:
+        """The family's parameters by part (`DecoderStack.num_params`)."""
+        bd = cfg.bd_moe
+        d, h = cfg.attn_dim, bd.head_dim
+        attn = 2 * d * cfg.num_heads * h + 2 * d * cfg.kv_heads * h + 2 * h
+        experts = (d * cfg.num_experts                           # router
+                   + cfg.experts_held * 3 * d * bd.moe_intermediate_size)
+        return {"embedding_and_head": 2 * cfg.vocab_size * d, "final_norm": d,
+                "layers": cfg.num_layers * (attn + 2 * d + experts)}
 
-def param_counts(cfg: ModelConfig) -> Dict[str, int]:
-    """The family's parameters by part, as `init` makes them for `cfg` (the
-    experts HELD, not the routed total): what `num_params` sums, and what
-    the benchmark's own count is pinned against."""
-    bd = cfg.bd_moe
-    d, h = cfg.attn_dim, bd.head_dim
-    attn = 2 * d * cfg.num_heads * h + 2 * d * cfg.kv_heads * h + 2 * h
-    experts = (d * cfg.num_experts                           # router
-               + cfg.experts_held * 3 * d * bd.moe_intermediate_size)
-    return {"embedding_and_head": 2 * cfg.vocab_size * d, "final_norm": d,
-            "layers": cfg.num_layers * (attn + 2 * d + experts)}
+    @staticmethod
+    def flops_per_step(cfg, batch, seqlen, num_params) -> float:
+        """Per DATA token (`batch` x `seqlen` of them): a token's two rows,
+        noised and clean, through every layer's attention, router and held
+        experts (at a row's mean share of them); the head on the noised row
+        only; the embedding's lookup is no matmul; attention at the entries
+        the declared mask leaves live, `L + B` a head and data token."""
+        bd = cfg.bd_moe
+        layers = num_params - 2 * cfg.vocab_size * cfg.attn_dim - cfg.attn_dim
+        layers -= idle_expert_params(cfg, cfg.num_layers,
+                                     bd.moe_intermediate_size)
+        return (6 * (2 * layers + cfg.vocab_size * cfg.attn_dim)
+                * batch * seqlen
+                + 12 * cfg.num_layers * batch * cfg.num_heads * seqlen
+                * (seqlen + bd.block_length) * bd.head_dim)

@@ -33,10 +33,9 @@ only what differs:
   and the q/k norms; the head tied to the embedding (initialised at 0.02,
   the published `initializer_range`); no bias anywhere.
 
-What is not made to work is refused where the model is built, with a
-message: pp > 1, cp > 1, ep > 1, sequence parallelism and its rings,
-pad-aware bucketing, ZeRO 2/3 and the bucketed reducer
-(`hand_reduced_grads`), `models/decode.py` and the serving engines
+What is not made to work is refused with a message: where the model is
+built (`refuses`), by ZeRO 2/3 and the bucketed reducer
+(`hand_reduced_grads`), by `models/decode.py` and the serving engines
 (`decodable`: a convolution's last inputs are a state
 `serving/kv_manager.py` does not hold).
 
@@ -53,19 +52,14 @@ from dataclasses import dataclass
 from typing import Any, Dict, Tuple
 
 import jax
-from jax import lax
 
 from ..config import ModelConfig
-from ..ops.rope import apply_rotary_leading, rope_angles
 from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import SharedRoutedFFN
 from ..parallel.norm import RMSNorm
 from ..parallel.shortconv import ShortConv
-from ..runtime.prng import fold
-from .gpt2 import GPT2Transformer
-from .stack import DecoderStack, Params, TPSublayers
-from .transformer import Transformer
+from .stack import DecoderStack, Params, TPSublayers, idle_expert_params
 
 KINDS = {"conv": "conv", "full_attention": "attn"}
 MIXER = {"conv": ("conv",),
@@ -140,24 +134,33 @@ def layers_in_order(params: Params, blocks):
 class ConvMoETransformer(DecoderStack):
     """The conv_moe family (module docstring)."""
 
-    uses_rope = True
-    attn_norm_key = "norm1"
-    ffn_norm_key = "norm2"
+    family = "conv_moe"
     ffn_inputs = 2            # the leading layers' SwiGLU: gate and up
     tied_head = True
     decodable = False
     hand_reduced_grads = False
     config_extra = "conv_moe"
+    attn_scope = "gqa_attn"
     _router_aux_losses = False
+    refuses = {
+        "pp_size > 1": "the pipeline splits one segment of identical "
+                       "layers; this family has a leading segment and then "
+                       "periods of two kinds of layer",
+        "cp_size > 1": "the convolution's taps run along the whole "
+                       "sequence; no exchange of a shard's last inputs is "
+                       "written",
+        "ep_size > 1": "a job holds one share of the experts, "
+                       "cfg.conv_moe.experts_held; the all-to-all between "
+                       "shares is not written",
+        "sequence_parallel=True": "the router and the convolution read "
+                                  "whole sequences",
+        "attn_t_real": "pad tokens would be routed and would enter the "
+                       "convolution",
+        "ZeRO stage 3": "",
+    }
 
-    def __post_init__(self):
+    def _check_facts(self):
         cm = self.cfg.conv_moe
-        if cm is None:
-            raise ValueError("the conv_moe family needs cfg.conv_moe "
-                             "(config.ConvMoEConfig)")
-        if not self.cfg.num_experts:
-            raise ValueError("the conv_moe family needs cfg.num_experts > 0 "
-                             "(the routed experts its router scores)")
         if len(cm.layer_types) != self.cfg.num_layers:
             raise ValueError(
                 f"layer_types names {len(cm.layer_types)} layers, num_layers "
@@ -166,28 +169,7 @@ class ConvMoETransformer(DecoderStack):
             raise ValueError(
                 f"num_dense_layers {cm.num_dense_layers} must leave an "
                 f"expert layer among {self.cfg.num_layers} layers")
-        refused = [
-            (self.pp_size > 1, "pp_size > 1 (the pipeline splits one "
-             "segment of identical layers; this family has a leading "
-             "segment and then periods of two kinds of layer)"),
-            (self.cp_size > 1, "cp_size > 1 (the convolution's taps run "
-             "along the whole sequence; no exchange of a shard's last "
-             "inputs is written)"),
-            (self.ep_size > 1, "ep_size > 1 (a job holds one share of the "
-             "experts, cfg.conv_moe.experts_held; the all-to-all between "
-             "shares is not written)"),
-            (self.sequence_parallel is True, "sequence_parallel=True (the "
-             "router and the convolution read whole sequences)"),
-            (self.attn_t_real is not None, "attn_t_real (pad tokens would "
-             "be routed and would enter the convolution)"),
-            (self.zero3_axis is not None, "ZeRO stage 3"),
-        ]
-        for bad, what in refused:
-            if bad:
-                raise ValueError(f"the conv_moe family does not run with "
-                                 f"{what}")
         self._blocks    # a pattern the family cannot cut is refused here
-        super().__post_init__()
 
     # ---- the layer pattern ----
 
@@ -267,95 +249,47 @@ class ConvMoETransformer(DecoderStack):
                 score="sigmoid"),
         }
 
-    @functools.cached_property
-    def final_norm(self) -> RMSNorm:
-        return RMSNorm(self.d, self.cfg.conv_moe.norm_eps)
-
-    # ---- init / specs ----
-
-    def init(self, key: jax.Array) -> Params:
-        return {
-            "embedding": self.embedding.init(fold(key, "embedding")),
-            **{name: self._init_layers(key, name, count, names)
-               for name, count, names in self._segments},
-            "norm": self.final_norm.init(fold(key, "norm")),
-        }
-
-    def specs(self) -> Params:
-        return {
-            "embedding": self.embedding.specs(),
-            **{name: self._layer_specs(names, name)
-               for name, _, names in self._segments},
-            "norm": self.final_norm.specs(),
-        }
-
-    @staticmethod
-    def num_params(cfg: ModelConfig) -> int:
-        return sum(param_counts(cfg).values())
-
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
-
-    def _positions(self, params: Params, x: jax.Array,
-                   position_ids: jax.Array, dtype):
-        """Nothing enters at the embedding; every layer gets the whole
-        head's (cos, sin) at `position_ids`, computed from the positions
-        (the attention layers read them)."""
-        return x.astype(dtype), rope_angles(
-            position_ids, self.cfg.head_dim, self.cfg.rope_theta)
-
-    def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
-        h = self.cfg.head_dim
-        return (apply_rotary_leading(q, *layer_pos, h),
-                apply_rotary_leading(k, *layer_pos, h))
-
-    def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
-             dtype, b: int, t: int):
-        with jax.named_scope("gqa_attn"):
-            return super()._qkv(lp, y, tp, layer_pos, dtype, b, t)
-
-    def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
-                      dtype) -> jax.Array:
-        with jax.named_scope("gqa_attn"):
-            return tp.row(lp, "wo", o, dtype)
 
     def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
         return self._mods["conv"].apply(lp["conv"], y, dtype)
 
-    _head_logits = GPT2Transformer._head_logits     # tied to the embedding
+    def _mlp(self, lp: Params, y: jax.Array, tp: TPSublayers,
+             dtype) -> jax.Array:
+        with jax.named_scope("dense_ffn"):   # the leading layers' SwiGLU
+            return super()._mlp(lp, y, tp, dtype)
 
-    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
-        if "moe" in lp:
-            return self._mods["moe"].apply(lp["moe"], y, dtype)
-        with jax.named_scope("dense_ffn"):
-            return Transformer._mlp(self, lp, y, tp, dtype), None
+    @staticmethod
+    def param_counts(cfg: ModelConfig) -> Dict[str, int]:
+        """The family's parameters by part (`DecoderStack.num_params`); the
+        tied embedding counts once."""
+        cm = cfg.conv_moe
+        d, h = cfg.attn_dim, cfg.head_dim
+        mixer = {"conv": ShortConv(d, cm.conv_L_cache).num_params(),
+                 "attn": 2 * d * d + 2 * d * cfg.kv_dim + 2 * h}
+        dense = 3 * d * cfg.ffn_dim
+        experts = (d * cfg.num_experts + cfg.num_experts      # router + bias
+                   + cfg.experts_held * 3 * d * cm.moe_intermediate_size)
+        out = {"embedding": cfg.vocab_size * d, "final_norm": d,
+               "dense_layers": 0, "conv_layers": 0, "attn_layers": 0}
+        for i, name in enumerate(cm.layer_types):
+            is_dense = i < cm.num_dense_layers
+            key = "dense_layers" if is_dense else KINDS[name] + "_layers"
+            out[key] += mixer[KINDS[name]] + 2 * d + (dense if is_dense
+                                                      else experts)
+        return out
 
-    def _fold_aux(self, auxs):
-        # the expert layers' counters stay one row a layer
-        return auxs
-
-    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
-                    aux, trunk, input_ids, target_ids, position_ids,
-                    mode: str, batch_axes):
-        return loss, jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
-
-
-def param_counts(cfg: ModelConfig) -> Dict[str, int]:
-    """The family's parameters by part, as `init` makes them for `cfg` (the
-    experts HELD, not the routed total; the tied embedding once): what
-    `num_params` sums, and what the benchmark's own count is pinned
-    against."""
-    cm = cfg.conv_moe
-    d, h = cfg.attn_dim, cfg.head_dim
-    mixer = {"conv": ShortConv(d, cm.conv_L_cache).num_params(),
-             "attn": 2 * d * d + 2 * d * cfg.kv_dim + 2 * h}
-    dense = 3 * d * cfg.ffn_dim
-    experts = (d * cfg.num_experts + cfg.num_experts      # router + bias
-               + cfg.experts_held * 3 * d * cm.moe_intermediate_size)
-    out = {"embedding": cfg.vocab_size * d, "final_norm": d,
-           "dense_layers": 0, "conv_layers": 0, "attn_layers": 0}
-    for i, name in enumerate(cm.layer_types):
-        is_dense = i < cm.num_dense_layers
-        key = "dense_layers" if is_dense else KINDS[name] + "_layers"
-        out[key] += mixer[KINDS[name]] + 2 * d + (dense if is_dense
-                                                  else experts)
-    return out
+    @staticmethod
+    def flops_per_step(cfg, batch, seqlen, num_params) -> float:
+        """The held experts at a token's mean share of them, in the expert
+        layers only; the tied embedding's one matrix is the head's matmul
+        (its lookup is none); attention at the full T^2 in the attention
+        layers only; the convolution's taps are no matmul."""
+        cm = cfg.conv_moe
+        n = num_params - idle_expert_params(
+            cfg, cfg.num_layers - cm.num_dense_layers,
+            cm.moe_intermediate_size)
+        full = sum(kind == "full_attention" for kind in cm.layer_types)
+        return (6 * n * batch * seqlen
+                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
+                * cfg.head_dim)

@@ -124,12 +124,12 @@ def _logits_tokens(model: Transformer, params: Params, x: jax.Array,
                    dtype) -> jax.Array:
     """Final norm + head on (b, t, d); returns the LOCAL vocab shard
     (b, t, vocab_padded/tp) with padded columns masked (mirrors
-    forward_shard). Families without an lm_head module tie the head to the
+    forward_shard). A family that says `tied_head` ties the head to the
     vocab-parallel token embedding (gpt2) — same local-logits layout either
     way. t = 1 is the single-position decode step; the speculative verify
     step asks for all k+1 positions at once."""
     x = model.final_norm.apply(params["norm"], x)
-    if hasattr(model, "lm_head"):
+    if not model.tied_head:
         logits = model.lm_head.apply(params["lm_head"], x, dtype)
     else:
         w = params["embedding"]["weight"].astype(dtype)   # (vp/tp, d)

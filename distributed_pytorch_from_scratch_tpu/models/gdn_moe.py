@@ -28,10 +28,9 @@ only what differs:
   final norm and the q/k norms; an untied head; no bias anywhere; no
   multi-token-prediction module.
 
-What is not made to work is refused where the model is built, with a
-message: pp > 1, cp > 1, ep > 1, sequence parallelism and its rings,
-pad-aware bucketing, ZeRO 2/3 and the bucketed reducer
-(`hand_reduced_grads`), `models/decode.py` and the serving engines
+What is not made to work is refused with a message: where the model is
+built (`refuses`), by ZeRO 2/3 and the bucketed reducer
+(`hand_reduced_grads`), by `models/decode.py` and the serving engines
 (`decodable`: a recurrent state is not in `serving/kv_manager.py`).
 
 Named scopes inside the step, for a device trace's `op_name`: `gdn`,
@@ -46,21 +45,15 @@ from dataclasses import dataclass
 from typing import Any, Dict
 
 import jax
-import jax.numpy as jnp
-from jax import lax
 
 from ..config import ModelConfig
 from ..ops.attention import causal_attention
-from ..ops.rope import rope_angles
-from ..parallel.embedding import VocabParallelEmbedding
+from ..ops.delta_rule import rule_flops_per_token
 from ..parallel.gated_attention import GatedAttention
 from ..parallel.gdn import GatedDeltaNet
-from ..parallel.linear import ColumnParallelLinear
 from ..parallel.moe import SharedRoutedFFN
 from ..parallel.norm import ZeroCenteredRMSNorm
-from ..runtime.prng import fold
-from .stack import DecoderStack, Params, TPSublayers
-from .transformer import Transformer
+from .stack import DecoderStack, Params, idle_expert_params
 
 LINEAR = ("norm1", "gdn", "norm2", "moe")
 FULL = ("norm1", "attn", "norm2", "moe")
@@ -70,24 +63,31 @@ FULL = ("norm1", "attn", "norm2", "moe")
 class GdnMoETransformer(DecoderStack):
     """The gdn_moe family (module docstring)."""
 
-    uses_rope = True
-    attn_norm_key = "norm1"
-    ffn_norm_key = "norm2"
+    family = "gdn_moe"
     ffn_inputs = 0            # no dense MLP: every layer's FFN is routed
     tied_head = False
     decodable = False
     hand_reduced_grads = False
     config_extra = "gdn_moe"
     _router_aux_losses = False
+    refuses = {
+        "pp_size > 1": "the pipeline splits one segment of identical "
+                       "layers; this family scans periods of two kinds of "
+                       "layer",
+        "cp_size > 1": "the delta rule's state and the convolution's taps "
+                       "run along the whole sequence; no exchange of either "
+                       "between sequence shards is written",
+        "ep_size > 1": "a job holds one share of the experts, "
+                       "cfg.gdn_moe.experts_held; the all-to-all between "
+                       "shares is not written",
+        "sequence_parallel=True": "the router, the convolution and the "
+                                  "rule read whole sequences",
+        "attn_t_real": "pad tokens would be routed and would move the state",
+        "ZeRO stage 3": "",
+    }
 
-    def __post_init__(self):
+    def _check_facts(self):
         gm = self.cfg.gdn_moe
-        if gm is None:
-            raise ValueError("the gdn_moe family needs cfg.gdn_moe "
-                             "(config.GdnMoEConfig)")
-        if not self.cfg.num_experts:
-            raise ValueError("the gdn_moe family needs cfg.num_experts > 0 "
-                             "(the routed experts its router scores)")
         if (gm.full_attention_interval < 2
                 or self.cfg.num_layers % gm.full_attention_interval):
             raise ValueError(
@@ -98,27 +98,6 @@ class GdnMoETransformer(DecoderStack):
             raise ValueError(
                 "the shared expert's width must be a multiple of a routed "
                 "expert's")
-        refused = [
-            (self.pp_size > 1, "pp_size > 1 (the pipeline splits one "
-             "segment of identical layers; this family scans periods of two "
-             "kinds of layer)"),
-            (self.cp_size > 1, "cp_size > 1 (the delta rule's state and the "
-             "convolution's taps run along the whole sequence; no exchange "
-             "of either between sequence shards is written)"),
-            (self.ep_size > 1, "ep_size > 1 (a job holds one share of the "
-             "experts, cfg.gdn_moe.experts_held; the all-to-all between "
-             "shares is not written)"),
-            (self.sequence_parallel is True, "sequence_parallel=True (the "
-             "router, the convolution and the rule read whole sequences)"),
-            (self.attn_t_real is not None, "attn_t_real (pad tokens would "
-             "be routed and would move the state)"),
-            (self.zero3_axis is not None, "ZeRO stage 3"),
-        ]
-        for bad, what in refused:
-            if bad:
-                raise ValueError(f"the gdn_moe family does not run with "
-                                 f"{what}")
-        super().__post_init__()
 
     # ---- the layer pattern ----
 
@@ -166,11 +145,6 @@ class GdnMoETransformer(DecoderStack):
 
     # ---- sub-module definitions ----
 
-    @functools.cached_property
-    def embedding(self) -> VocabParallelEmbedding:
-        return VocabParallelEmbedding(self.cfg.vocab_size, self.d,
-                                      tp_size=self.tp_size)
-
     def _norm(self) -> ZeroCenteredRMSNorm:
         return ZeroCenteredRMSNorm(self.d, self.cfg.gdn_moe.rms_norm_eps)
 
@@ -197,53 +171,11 @@ class GdnMoETransformer(DecoderStack):
                 tp_size=self.tp_size, score="softmax", shared_gate=True),
         }
 
-    @functools.cached_property
-    def final_norm(self) -> ZeroCenteredRMSNorm:
-        return self._norm()
-
-    @functools.cached_property
-    def lm_head(self) -> ColumnParallelLinear:
-        return ColumnParallelLinear(self.d, self.vocab_padded,
-                                    add_bias=False, gather_output=False)
-
-    # ---- init / specs ----
-
-    def init(self, key: jax.Array) -> Params:
-        lm_head = self.lm_head.init(fold(key, "lm_head"))
-        if self.vocab_padded != self.cfg.vocab_size:
-            keep = jnp.arange(self.vocab_padded) < self.cfg.vocab_size
-            lm_head["weight"] = jnp.where(keep[None, :], lm_head["weight"],
-                                          0.0)
-        return {
-            "embedding": self.embedding.init(fold(key, "embedding")),
-            **{name: self._init_layers(key, name, count, names)
-               for name, count, names in self._segments},
-            "norm": self.final_norm.init(fold(key, "norm")),
-            "lm_head": lm_head,
-        }
-
-    def specs(self) -> Params:
-        return {
-            "embedding": self.embedding.specs(),
-            **{name: self._layer_specs(names, name)
-               for name, _, names in self._segments},
-            "norm": self.final_norm.specs(),
-            "lm_head": self.lm_head.specs(),
-        }
-
-    @staticmethod
-    def num_params(cfg: ModelConfig) -> int:
-        return sum(param_counts(cfg).values())
-
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
-    def _positions(self, params: Params, x: jax.Array,
-                   position_ids: jax.Array, dtype):
-        """Nothing enters at the embedding; every layer gets the rotary
-        slice's (cos, sin) at `position_ids` (the full-attention layers
-        read them)."""
-        return x.astype(dtype), rope_angles(
-            position_ids, self.cfg.gdn_moe.rotary_dim, self.cfg.rope_theta)
+    @property
+    def rotary_dim(self) -> int:     # the full-attention layers read it
+        return self.cfg.gdn_moe.rotary_dim
 
     def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
         if "gdn" in lp:
@@ -253,39 +185,43 @@ class GdnMoETransformer(DecoderStack):
         o = causal_attention(q, k, v, impl=self.attn_impl)
         return attn.project(lp["attn"], o, gate, dtype)
 
-    _head_logits = Transformer._head_logits
+    @staticmethod
+    def param_counts(cfg: ModelConfig) -> Dict[str, int]:
+        """The family's parameters by part (`DecoderStack.num_params`)."""
+        gm = cfg.gdn_moe
+        d = cfg.attn_dim
+        gdn = GatedDeltaNet(
+            d, gm.linear_num_key_heads, gm.linear_num_value_heads,
+            gm.linear_key_head_dim, gm.linear_value_head_dim,
+            gm.linear_conv_kernel_dim).num_params()
+        attn = GatedAttention(d, cfg.num_heads, cfg.kv_heads, gm.head_dim,
+                              gm.rotary_dim).num_params()
+        ffn = (d * cfg.num_experts                              # router
+               + 3 * d * gm.shared_expert_intermediate_size + d  # shared, gate
+               + cfg.experts_held * 3 * d * gm.moe_intermediate_size)
+        full = cfg.num_layers // gm.full_attention_interval
+        return {
+            "embedding_and_head": 2 * cfg.vocab_size * d,
+            "final_norm": d,
+            "gdn_layers": (cfg.num_layers - full) * (gdn + ffn + 2 * d),
+            "attn_layers": full * (attn + ffn + 2 * d),
+        }
 
-    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
-        return self._mods["moe"].apply(lp["moe"], y, dtype)
-
-    def _fold_aux(self, auxs):
-        # the layers' counters stay one row a layer
-        return auxs
-
-    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
-                    aux, trunk, input_ids, target_ids, position_ids,
-                    mode: str, batch_axes):
-        return loss, jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
-
-
-def param_counts(cfg: ModelConfig) -> Dict[str, int]:
-    """The family's parameters by part, as `init` makes them for `cfg` (the
-    experts HELD, not the routed total): what `num_params` sums, and what
-    the benchmark's own count is pinned against."""
-    gm = cfg.gdn_moe
-    d = cfg.attn_dim
-    gdn = GatedDeltaNet(d, gm.linear_num_key_heads, gm.linear_num_value_heads,
-                        gm.linear_key_head_dim, gm.linear_value_head_dim,
-                        gm.linear_conv_kernel_dim).num_params()
-    attn = GatedAttention(d, cfg.num_heads, cfg.kv_heads, gm.head_dim,
-                          gm.rotary_dim).num_params()
-    ffn = (d * cfg.num_experts                               # router
-           + 3 * d * gm.shared_expert_intermediate_size + d  # shared + gate
-           + cfg.experts_held * 3 * d * gm.moe_intermediate_size)
-    full = cfg.num_layers // gm.full_attention_interval
-    return {
-        "embedding_and_head": 2 * cfg.vocab_size * d,
-        "final_norm": d,
-        "gdn_layers": (cfg.num_layers - full) * (gdn + ffn + 2 * d),
-        "attn_layers": full * (attn + ffn + 2 * d),
-    }
+    @staticmethod
+    def flops_per_step(cfg, batch, seqlen, num_params) -> float:
+        """The held experts at a token's mean share of them; the
+        embedding's lookup is no matmul; attention at the full T^2 in the
+        full-attention layers only (q/k and v at `head_dim`); the chunked
+        delta rule's own products (`ops/delta_rule.rule_flops_per_token`),
+        forward and twice that backward."""
+        gm = cfg.gdn_moe
+        n = num_params - idle_expert_params(cfg, cfg.num_layers,
+                                            gm.moe_intermediate_size)
+        n -= cfg.vocab_size * cfg.attn_dim
+        full = cfg.num_layers // gm.full_attention_interval
+        rule = gm.linear_num_value_heads * rule_flops_per_token(
+            gm.linear_key_head_dim, gm.linear_value_head_dim)
+        return (6 * n * batch * seqlen
+                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
+                * gm.head_dim
+                + 3 * (cfg.num_layers - full) * rule * batch * seqlen)

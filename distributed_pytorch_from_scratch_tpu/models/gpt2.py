@@ -44,7 +44,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..config import ModelConfig
-from ..ops.overlap import ag_matmul, ring_order
 from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import MoEFFN
@@ -62,6 +61,7 @@ class GPT2Transformer(DecoderStack):
 
     # what the stack, the decoder (models/decode.py), training/memory.py and
     # obs/attribution.py ask a family
+    family = "gpt2"
     uses_rope = False         # learned position embeddings instead of RoPE
     attn_norm_key = "ln1"
     ffn_norm_key = "ln2"
@@ -134,10 +134,6 @@ class GPT2Transformer(DecoderStack):
             })
         return mods
 
-    @functools.cached_property
-    def final_norm(self) -> LayerNorm:
-        return LayerNorm(self.d)
-
     # ---- init / specs ----
 
     def init(self, key: jax.Array) -> Params:
@@ -181,18 +177,3 @@ class GPT2Transformer(DecoderStack):
         fc = checkpoint_name(fc, "ffn_fc")
         return tp.row(lp, "proj", jax.nn.gelu(fc, approximate=True), dtype,
                       **tp.ffn_order)
-
-    def _head_logits(self, params: Params, x: jax.Array, dtype) -> jax.Array:
-        """Tied head: local logits against this shard's embedding rows."""
-        tp = self._tp_sublayers
-        w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
-        if tp.ring_ov:
-            # ring collective matmul for the tied head too: the gather's
-            # hops hide under the per-chunk logits dots, and the VJP's
-            # reverse ring reduce-scatters the head's input cotangent
-            return ring_order(ag_matmul(x.astype(dtype), (w.T,), "tp",
-                                        tp.ring_quant)[0], "tp")
-        # under sequence parallelism the tied head consumes full-sequence
-        # activations; the gather's transpose reduce-scatters the input
-        # cotangent
-        return tp.gather(x).astype(dtype) @ w.T           # (b, t, vp/tp)

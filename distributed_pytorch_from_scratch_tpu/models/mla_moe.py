@@ -28,11 +28,9 @@ holds only what differs:
   state comes first in the concatenation (the report's order);
 * an untied head, RMSNorm (eps `rms_norm_eps`), no bias anywhere.
 
-What is not made to work is refused where the model is built, with a
-message: pp > 1, cp > 1, ep > 1 (a job holds ONE share; the all-to-all
-between shares is not written), sequence parallelism and its rings,
-pad-aware bucketing, ZeRO 2/3 and the bucketed reducer
-(`hand_reduced_grads`), `models/decode.py` and the serving engine
+What is not made to work is refused with a message: where the model is
+built (`refuses`), by ZeRO 2/3 and the bucketed reducer
+(`hand_reduced_grads`), by `models/decode.py` and the serving engines
 (`decodable`).
 
 Named scopes inside the step, for a device trace's `op_name`: `mla`
@@ -51,15 +49,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..config import IGNORE_INDEX, ModelConfig
-from ..ops.rope import rope_angles
-from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.mla import LatentAttention, ReplicatedLinear
 from ..parallel.moe import SharedRoutedFFN
 from ..parallel.norm import RMSNorm
 from ..runtime.prng import fold
-from .stack import DecoderStack, Params, TPSublayers
-from .transformer import Transformer
+from .stack import DecoderStack, Params, TPSublayers, idle_expert_params
 
 ATTN = ("norm1", "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
         "norm2")
@@ -71,24 +66,32 @@ EXPERT = ATTN + ("moe",)
 class LatentMoETransformer(DecoderStack):
     """The mla_moe family (module docstring)."""
 
-    uses_rope = True
-    attn_norm_key = "norm1"
-    ffn_norm_key = "norm2"
+    family = "mla_moe"
     ffn_inputs = 2            # gate and up both read the dense MLP's input
     tied_head = False
     decodable = False
     hand_reduced_grads = False
     config_extra = "latent_moe"
+    attn_scope = "mla"
     _router_aux_losses = False
+    refuses = {
+        "pp_size > 1": "the pipeline splits one segment of identical "
+                       "layers; this family has a layer pattern and a "
+                       "multi-token-prediction module behind it",
+        "cp_size > 1": "the multi-token-prediction targets shift across "
+                       "sequence shards, and the ring kernels take one head "
+                       "width",
+        "ep_size > 1": "a job holds one share of the experts, "
+                       "cfg.latent_moe.experts_held; the all-to-all between "
+                       "shares is not written",
+        "sequence_parallel=True": "the router and the latent projections "
+                                  "read whole tokens",
+        "attn_t_real": "pad tokens would be routed",
+        "ZeRO stage 3": "",
+    }
 
-    def __post_init__(self):
+    def _check_facts(self):
         lm = self.cfg.latent_moe
-        if lm is None:
-            raise ValueError("the mla_moe family needs cfg.latent_moe "
-                             "(config.LatentMoEConfig)")
-        if not self.cfg.num_experts:
-            raise ValueError("the mla_moe family needs cfg.num_experts > 0 "
-                             "(the routed experts its router scores)")
         if not 0 <= lm.first_k_dense_replace < self.cfg.num_layers:
             raise ValueError(
                 f"first_k_dense_replace {lm.first_k_dense_replace} must "
@@ -97,27 +100,6 @@ class LatentMoETransformer(DecoderStack):
             raise ValueError("multi-token prediction is written for depth "
                              "0 or 1, got "
                              f"{lm.num_nextn_predict_layers}")
-        refused = [
-            (self.pp_size > 1, "pp_size > 1 (the pipeline splits one "
-             "segment of identical layers; this family has a layer pattern "
-             "and a multi-token-prediction module behind it)"),
-            (self.cp_size > 1, "cp_size > 1 (the multi-token-prediction "
-             "targets shift across sequence shards, and the ring kernels "
-             "take one head width)"),
-            (self.ep_size > 1, "ep_size > 1 (a job holds one share of the "
-             "experts, cfg.latent_moe.experts_held; the all-to-all between "
-             "shares is not written)"),
-            (self.sequence_parallel is True, "sequence_parallel=True (the "
-             "router and the latent projections read whole tokens)"),
-            (self.attn_t_real is not None, "attn_t_real (pad tokens would "
-             "be routed)"),
-            (self.zero3_axis is not None, "ZeRO stage 3"),
-        ]
-        for bad, what in refused:
-            if bad:
-                raise ValueError(f"the mla_moe family does not run with "
-                                 f"{what}")
-        super().__post_init__()
 
     # ---- the layer pattern ----
 
@@ -173,11 +155,6 @@ class LatentMoETransformer(DecoderStack):
             lm.rms_norm_eps)
 
     @functools.cached_property
-    def embedding(self) -> VocabParallelEmbedding:
-        return VocabParallelEmbedding(self.cfg.vocab_size, self.d,
-                                      tp_size=self.tp_size)
-
-    @functools.cached_property
     def _mods(self) -> Dict[str, Any]:
         cfg, lm = self.cfg, self.cfg.latent_moe
         d, f = self.d, cfg.ffn_dim
@@ -199,97 +176,43 @@ class LatentMoETransformer(DecoderStack):
         }
 
     @functools.cached_property
-    def final_norm(self) -> RMSNorm:
-        return RMSNorm(self.d, self.cfg.latent_moe.rms_norm_eps)
-
-    @functools.cached_property
-    def lm_head(self) -> ColumnParallelLinear:
-        return ColumnParallelLinear(self.d, self.vocab_padded,
-                                    add_bias=False, gather_output=False)
-
-    @functools.cached_property
     def eh_proj(self) -> ReplicatedLinear:
         return ReplicatedLinear(2 * self.d, self.d)
 
-    # ---- init / specs ----
+    # ---- the module's own leaves, beside the stack's tree ----
 
-    def init(self, key: jax.Array) -> Params:
-        lm_head = self.lm_head.init(fold(key, "lm_head"))
-        if self.vocab_padded != self.cfg.vocab_size:
-            keep = jnp.arange(self.vocab_padded) < self.cfg.vocab_size
-            lm_head["weight"] = jnp.where(keep[None, :], lm_head["weight"],
-                                          0.0)
-        params = {
-            "embedding": self.embedding.init(fold(key, "embedding")),
-            **{name: self._init_layers(key, name, count, names)
-               for name, count, names in self._segments},
-            "norm": self.final_norm.init(fold(key, "norm")),
-            "lm_head": lm_head,
-        }
-        if self.cfg.latent_moe.num_nextn_predict_layers:
-            k = fold(key, "mtp")
-            params["mtp"] = {
-                "hnorm": self.final_norm.init(k),
-                "enorm": self.final_norm.init(k),
-                "eh_proj": self.eh_proj.init(fold(k, "eh_proj")),
-                "norm": self.final_norm.init(k),
-            }
-        return params
+    def _init_more(self, key: jax.Array) -> Params:
+        if not self.cfg.latent_moe.num_nextn_predict_layers:
+            return {}
+        k = fold(key, "mtp")
+        return {"mtp": {
+            "hnorm": self.final_norm.init(k),
+            "enorm": self.final_norm.init(k),
+            "eh_proj": self.eh_proj.init(fold(k, "eh_proj")),
+            "norm": self.final_norm.init(k),
+        }}
 
-    def specs(self) -> Params:
-        specs = {
-            "embedding": self.embedding.specs(),
-            **{name: self._layer_specs(names)
-               for name, _, names in self._segments},
-            "norm": self.final_norm.specs(),
-            "lm_head": self.lm_head.specs(),
-        }
-        if self.cfg.latent_moe.num_nextn_predict_layers:
-            norm = self.final_norm.specs()
-            specs["mtp"] = {"hnorm": norm, "enorm": norm,
-                            "eh_proj": self.eh_proj.specs(), "norm": norm}
-        return specs
-
-    @staticmethod
-    def num_params(cfg: ModelConfig) -> int:
-        return sum(param_counts(cfg).values())
+    def _more_specs(self) -> Params:
+        if not self.cfg.latent_moe.num_nextn_predict_layers:
+            return {}
+        norm = self.final_norm.specs()
+        return {"mtp": {"hnorm": norm, "enorm": norm,
+                        "eh_proj": self.eh_proj.specs(), "norm": norm}}
 
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
-    def _positions(self, params: Params, x: jax.Array,
-                   position_ids: jax.Array, dtype):
-        """Nothing enters at the embedding; every layer gets the rotary
-        pairs' (cos, sin) at `position_ids`."""
-        lm = self.cfg.latent_moe
-        return x.astype(dtype), rope_angles(
-            position_ids, lm.qk_rope_head_dim, self.cfg.rope_theta)
+    @property
+    def rotary_dim(self) -> int:
+        return self.cfg.latent_moe.qk_rope_head_dim
 
     def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
              dtype, b: int, t: int):
-        with jax.named_scope("mla"):
-            return self.attention.qkv(self._mods, lp, y, *layer_pos, dtype)
-
-    def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
-                      dtype) -> jax.Array:
-        with jax.named_scope("mla"):
-            return tp.row(lp, "wo", o, dtype)
-
-    _mlp = Transformer._mlp                 # the dense layers' SwiGLU
-    _head_logits = Transformer._head_logits
-
-    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
-        if "moe" in lp:
-            return self._mods["moe"].apply(lp["moe"], y, dtype)
-        return self._mlp(lp, y, tp, dtype), None
-
-    def _fold_aux(self, auxs):
-        # the expert layers' counters stay one row a layer
-        return auxs
+        return self.attention.qkv(self._mods, lp, y, *layer_pos, dtype)
 
     def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
                     aux, trunk, input_ids, target_ids, position_ids,
                     mode: str, batch_axes):
-        counters = jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
+        counters = self._counters(aux, batch_axes)
         if not self.cfg.latent_moe.num_nextn_predict_layers:
             return loss, counters
         with jax.named_scope("mtp"):
@@ -317,32 +240,47 @@ class LatentMoETransformer(DecoderStack):
                              batch_axes)
             count = lax.psum(jnp.sum(valid.astype(jnp.float32)), batch_axes)
             mtp_loss = total / jnp.maximum(count, 1.0)
-        mtp_aux = jax.tree.map(lambda a: lax.psum(a, batch_axes), mtp_aux)
+        mtp_aux = self._counters(mtp_aux, batch_axes)
         counters = jax.tree.map(lambda a, m: jnp.concatenate([a, m]),
                                 counters, mtp_aux)
         return (loss + self.cfg.latent_moe.mtp_loss_weight * mtp_loss,
                 {**counters, "loss_mtp": mtp_loss})
 
+    @staticmethod
+    def param_counts(cfg: ModelConfig) -> Dict[str, int]:
+        """The family's parameters by part (`DecoderStack.num_params`)."""
+        lm = cfg.latent_moe
+        d = cfg.attn_dim
+        attn = LatentAttention(
+            d, cfg.num_heads, lm.q_lora_rank, lm.kv_lora_rank,
+            lm.qk_nope_head_dim, lm.qk_rope_head_dim,
+            lm.v_head_dim).num_params() + 2 * d      # + the layer's 2 norms
+        expert = 3 * d * lm.moe_intermediate_size
+        expert_layer = (attn + d * cfg.num_experts + cfg.num_experts
+                        + (cfg.experts_held + lm.n_shared_experts) * expert)
+        first = lm.first_k_dense_replace
+        return {
+            "embedding_and_head": 2 * cfg.vocab_size * d,
+            "final_norm": d,
+            "dense_layers": first * (attn + 3 * d * cfg.ffn_dim),
+            "expert_layers": (cfg.num_layers - first) * expert_layer,
+            "mtp": lm.num_nextn_predict_layers * (expert_layer + 2 * d * d
+                                                  + 3 * d),
+        }
 
-def param_counts(cfg: ModelConfig) -> Dict[str, int]:
-    """The family's parameters by part, as `init` makes them for `cfg` (the
-    experts HELD, not the routed total): what `num_params` sums, and what
-    the benchmark's own count is pinned against."""
-    lm = cfg.latent_moe
-    d = cfg.attn_dim
-    attn = LatentAttention(
-        d, cfg.num_heads, lm.q_lora_rank, lm.kv_lora_rank,
-        lm.qk_nope_head_dim, lm.qk_rope_head_dim,
-        lm.v_head_dim).num_params() + 2 * d          # + the layer's 2 norms
-    expert = 3 * d * lm.moe_intermediate_size
-    expert_layer = (attn + d * cfg.num_experts + cfg.num_experts
-                    + (cfg.experts_held + lm.n_shared_experts) * expert)
-    first = lm.first_k_dense_replace
-    return {
-        "embedding_and_head": 2 * cfg.vocab_size * d,
-        "final_norm": d,
-        "dense_layers": first * (attn + 3 * d * cfg.ffn_dim),
-        "expert_layers": (cfg.num_layers - first) * expert_layer,
-        "mtp": lm.num_nextn_predict_layers * (expert_layer + 2 * d * d
-                                              + 3 * d),
-    }
+    @staticmethod
+    def flops_per_step(cfg, batch, seqlen, num_params) -> float:
+        """The held experts at a token's mean share of them; the shared
+        expert, the latent projections and the module are in `num_params`
+        whole; q/k and v have their own widths; the embedding's lookup is
+        no matmul but the head runs once more for the module."""
+        lm = cfg.latent_moe
+        expert_layers = (cfg.num_layers - lm.first_k_dense_replace
+                         + lm.num_nextn_predict_layers)
+        n = num_params - idle_expert_params(cfg, expert_layers,
+                                            lm.moe_intermediate_size)
+        n += (lm.num_nextn_predict_layers - 1) * cfg.vocab_size * cfg.attn_dim
+        attn_layers = cfg.num_layers + lm.num_nextn_predict_layers
+        return (6 * n * batch * seqlen
+                + 6 * attn_layers * batch * cfg.num_heads * seqlen * seqlen
+                * (lm.qk_head_dim + lm.v_head_dim))

@@ -8,24 +8,27 @@ dispatch with the pipeline gate, the MoE branch), the forward (embed, scan
 or pipeline, `head_loss`), both pipeline schedules, the losses and the
 jitted entry points. A change to any of these is one edit here.
 
-A family is a subclass (`models/transformer.Transformer` is the llama
-family, `models/gpt2.GPT2Transformer` the GPT-2 one; `models.FAMILIES` names
-them) that supplies what differs, and nothing else:
+A family is a subclass (`models.FAMILIES` names them) that supplies what
+differs and nothing else (docs/DESIGN.md, "What a family file holds"):
 
-* its modules: `embedding`, `_mods` (the per-layer modules; the attention
-  projections are `wq`/`wk`/`wv`/`wo` in every family, which
-  `models/decode.py` and `interop.py` read by name), `final_norm`, and the
-  keys of its two norms (`attn_norm_key`, `ffn_norm_key`);
-* `init` and `specs`: its parameter tree (`_init_layers` / `_layer_specs`
-  give the stacked layers);
-* how positions enter: `_positions` (at the embedding, and/or as arrays
-  handed to every layer) and `_position_qk` (what a layer does with those);
-* `_mlp`, the dense feed-forward of a block;
-* `_head_logits`, the local vocabulary shard of the logits;
-* its facts: `ffn_inputs` (matrices that read the MLP's input: 2 for
-  SwiGLU, 1 for a two-matrix MLP), `tied_head`, `num_params(cfg)`, and
-  `uses_rope` for the decoder. `training/memory.py` and
-  `obs/attribution.py` ask the class; nothing infers one from another.
+* its name (`family`) and its declarations, which `training/memory.py`,
+  `obs/attribution.py` and this file ask the class (nothing infers one from
+  another): the layer pattern (`_pattern`, `_segments`), the `ModelConfig`
+  field with its facts (`config_extra`), what it does not run with and why
+  (`refuses`), `ffn_inputs`, `tied_head`, `uses_rope`, `rotary_dim`,
+  `decodable`, `draws_noise`, `layer_extra_elems_per_token`;
+* `_mods`, the per-layer modules (the attention projections are
+  `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
+  `models/decode.py` and `interop.py` read by name), and its mixer where
+  that is not the stack's (`_qkv`, `_mix`); `_mlp`, a dense block's;
+* how positions enter where not by RoPE from the ids (`_positions`,
+  `_position_qk`);
+* its counts: `param_counts(cfg)` (or `num_params(cfg)`) and
+  `flops_per_step(cfg, batch, seqlen, num_params)`.
+
+The parameter tree (`init`, `specs`), the facts check, the refusals, the
+head and the counters of a family with facts of its own are made here; the
+two older families keep the trees they have always made.
 """
 
 from __future__ import annotations
@@ -46,9 +49,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..config import IGNORE_INDEX, ModelConfig, resolve_dtype
 from ..ops.attention import causal_attention, masked_attention
 from ..ops.collectives import copy_to, gather_from, reduce_from
-from ..ops.overlap import exchange_grads
+from ..ops.overlap import ag_matmul, exchange_grads, ring_order
 from ..ops.ring_attention import ring_attention, ulysses_attention
-from ..parallel.linear import OVERLAP_MODES, apply_column_ring_fused
+from ..ops.rope import apply_rotary_leading, rope_angles
+from ..parallel.embedding import VocabParallelEmbedding
+from ..parallel.linear import (OVERLAP_MODES, ColumnParallelLinear,
+                               apply_column_ring_fused)
 from ..parallel.moe import aux_losses, aux_zeros
 from ..runtime.prng import fold
 
@@ -303,6 +309,29 @@ def resolve_remat(model, params: Params, ids_shape):
         int(b), int(t))
 
 
+# What a family may say it does not run with (`DecoderStack.refuses`): the
+# name the refusal prints -> does the model being built ask for it
+REFUSABLE = {
+    "pp_size > 1": lambda m: m.pp_size > 1,
+    "cp_size > 1": lambda m: m.cp_size > 1,
+    "ep_size > 1": lambda m: m.ep_size > 1,
+    "sequence_parallel=True": lambda m: m.sequence_parallel is True,
+    "attn_t_real": lambda m: m.attn_t_real is not None,
+    "ZeRO stage 3": lambda m: m.zero3_axis is not None,
+}
+
+
+def idle_expert_params(cfg: ModelConfig, expert_layers: int,
+                       width: int) -> float:
+    """What `flops_per_step` takes off a count for the experts HELD: of
+    them a token takes `top_k x held / routed` on average (the rest of its
+    top_k live on other chips), over `expert_layers` layers of SwiGLU
+    experts `width` wide."""
+    held = cfg.experts_held
+    return expert_layers * (held - cfg.moe_top_k * held
+                            / cfg.num_experts) * (3 * cfg.attn_dim * width)
+
+
 @dataclass(frozen=True)
 class TPSublayers:
     """How a layer's sublayers meet the 'tp' axis, from the resolved
@@ -495,8 +524,17 @@ class DecoderStack:
     # it.
     noise_seed: int = 0
 
+    family = None               # the family's name: its key in models.FAMILIES
+    uses_rope = True            # RoPE on q/k (vs positions at the embedding)
+    attn_norm_key = "norm1"     # the pre-attention norm's key in `_mods`
+    ffn_norm_key = "norm2"      # the pre-FFN norm's
+    # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
+    attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
     # it is asked for; every family that says nothing can do all of it) ----
+    # the fields of the stack it does not run with: a name of REFUSABLE ->
+    # the family's own reason ("" for none), refused where the model is built
+    refuses = {}
     decodable = True            # models/decode.py and the serving engine
     hand_reduced_grads = True   # training/zero.py's builders (ZeRO 2/3,
                                 # the bucketed reducer)
@@ -506,7 +544,8 @@ class DecoderStack:
     # hands `loss_shard` the draw (`noise`)
     draws_noise = False
     # the ModelConfig field that carries facts only this family reads
-    # (None: the config's own fields are all it needs)
+    # (None: the config's own fields are all it needs). A family that names
+    # one holds a SHARE of the routed experts its router scores
     config_extra = None
     # The LAYER PATTERN, declared once: the blocks the forward runs, in
     # order. One stack, one remat policy, one `_layer_body`; what a layer's
@@ -527,12 +566,24 @@ class DecoderStack:
     # only.
     _pattern = ("layers",)
     # does the loss add the Switch load-balance and z terms of
-    # parallel/moe.MoEFFN's router sums (a family whose router balances
-    # without an auxiliary loss says no)
+    # parallel/moe.MoEFFN's router sums. A family whose router balances
+    # without one says no, and its expert layers hand back COUNTERS: one
+    # row a layer (`_fold_aux`), summed over the batch axes (`_counters`)
     _router_aux_losses = True
 
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
+        if self.config_extra:
+            if getattr(cfg, self.config_extra) is None:
+                raise ValueError(f"the {self.family} family needs "
+                                 f"cfg.{self.config_extra} (its facts: "
+                                 f"config.py)")
+            if not cfg.num_experts:
+                raise ValueError(
+                    f"the {self.family} family needs cfg.num_experts > 0 "
+                    f"(the routed experts its router scores)")
+        self._check_facts()
+        self._refuse()
         validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -556,6 +607,17 @@ class DecoderStack:
         validate_pp(cfg.num_layers, self.pp_size, self.pp_microbatches,
                     self.pp_schedule, self.pp_virtual)
         validate_t_real(self.attn_t_real, self.cp_size, cfg.num_experts)
+
+    def _check_facts(self) -> None:
+        """A family's own checks of its facts, before anything else."""
+
+    def _refuse(self) -> None:
+        """Raise for the first of `refuses` this model asks for."""
+        for what, why in self.refuses.items():
+            if REFUSABLE[what](self):
+                raise ValueError(
+                    f"the {self.family} family does not run with {what}"
+                    + (f" ({why})" if why else ""))
 
     @property
     def d(self) -> int:
@@ -598,6 +660,12 @@ class DecoderStack:
             (block,) if isinstance(block, str) else (k for k, _ in block)))
 
     @property
+    def _segments(self):
+        """(parameter key, layers, module names or None for all of `_mods`)
+        of every stacked key: what `init` and `specs` make."""
+        return (("layers", self.cfg.num_layers, None),)
+
+    @property
     def _layers_a_period(self) -> Dict[str, int]:
         """key -> layers a period, of the keys `_pattern`'s periods hold."""
         return {key: n for block in self._pattern
@@ -611,6 +679,28 @@ class DecoderStack:
 
     layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
     head_rows_share = 1.0       # the part of a batch's rows the head reads
+
+    @classmethod
+    def num_params(cls, cfg: ModelConfig) -> int:
+        """The leaves of `init`'s tree: the sum of the family's
+        `param_counts(cfg)`, its parameters by part (the experts HELD)."""
+        return sum(cls.param_counts(cfg).values())
+
+    @staticmethod
+    def flops_per_step(cfg, batch, seqlen, num_params) -> float:
+        """Model FLOPs of one forward and backward (no remat recompute): 6
+        N_active a token and the 12 L h T^2 hd attention term, `num_params`
+        the family's count of `cfg`. Of parallel/moe.MoEFFN's experts only
+        the top_k a token is routed through count (dropped tokens are
+        ignored). A family whose layers are not these states its own."""
+        n = num_params
+        if cfg.num_experts:
+            inactive = ((cfg.num_experts - cfg.moe_top_k)
+                        * 3 * cfg.attn_dim * cfg.ffn_dim)
+            n -= cfg.num_layers * max(0, inactive)
+        return (6 * n * batch * seqlen
+                + 12 * cfg.num_layers * batch * cfg.num_heads
+                * seqlen * seqlen * cfg.head_dim)
 
     def tp_layout(self, t_local: int) -> Tuple[bool, str]:
         """(sequence_parallel, tp_overlap) as a batch of cp-local sequence
@@ -656,7 +746,64 @@ class DecoderStack:
                                   pp_size=self.pp_size) == "exchange"
                 and "dp" in jax.typeof(x).vma)
 
-    # ---- parameter tree: a family's `init` / `specs` add its own leaves ----
+    # ---- top-level modules: a family overrides the one that differs ----
+
+    @functools.cached_property
+    def embedding(self) -> VocabParallelEmbedding:
+        return VocabParallelEmbedding(self.cfg.vocab_size, self.d,
+                                      tp_size=self.tp_size)
+
+    @property
+    def final_norm(self):
+        """The final norm is a layer's norm."""
+        return self._mods[self.ffn_norm_key]
+
+    @functools.cached_property
+    def lm_head(self) -> ColumnParallelLinear:
+        """The head of a family that does not tie it to the embedding."""
+        return ColumnParallelLinear(self.d, self.vocab_padded,
+                                    add_bias=False, gather_output=False)
+
+    # ---- parameter tree ----
+
+    def init(self, key: jax.Array) -> Params:
+        """Full (global) parameter pytree, float32: embedding, `_segments`
+        stacked for scan, final norm, the head unless tied, `_init_more`."""
+        head = {} if self.tied_head else {"lm_head": self._init_head(key)}
+        return {"embedding": self.embedding.init(fold(key, "embedding")),
+                **{name: self._init_layers(key, name, count, names)
+                   for name, count, names in self._segments},
+                "norm": self.final_norm.init(fold(key, "norm")),
+                **head, **self._init_more(key)}
+
+    def specs(self) -> Params:
+        """PartitionSpec pytree matching `init`'s structure."""
+        head = {} if self.tied_head else {"lm_head": self.lm_head.specs()}
+        return {"embedding": self.embedding.specs(),
+                **{name: self._layer_specs(names, name)
+                   for name, _, names in self._segments},
+                "norm": self.final_norm.specs(),
+                **head, **self._more_specs()}
+
+    def _init_more(self, key: jax.Array) -> Params:
+        """Top-level groups of the family's own, after the head."""
+        return {}
+
+    def _more_specs(self) -> Params:
+        return {}
+
+    def _init_head(self, key: jax.Array) -> Params:
+        lm_head = self.lm_head.init(fold(key, "lm_head"))
+        if self.vocab_padded != self.cfg.vocab_size:
+            # zero the padded output columns so checkpoints stay
+            # permutation-stable; padded logits are masked to NEG_INF anyway.
+            w = lm_head["weight"]
+            mask = (jnp.arange(self.vocab_padded)
+                    < self.cfg.vocab_size)[None, :]
+            lm_head["weight"] = jnp.where(mask, w, 0.0)
+            if "bias" in lm_head:
+                lm_head["bias"] = jnp.where(mask[0], lm_head["bias"], 0.0)
+        return lm_head
 
     def _init_layers(self, key: jax.Array, segment: str = "layers",
                      count: "int | None" = None, names=None) -> Params:
@@ -798,14 +945,17 @@ class DecoderStack:
         def qkv(x):
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
-            return self._qkv(layer_params, y, tp, layer_pos, dtype, b, t)
+            with self._attn_scoped():
+                return self._qkv(layer_params, y, tp, layer_pos, dtype, b, t)
 
         def attn_out(args):
             x, o = args
             # (b, heads, t, v's width) -> (b, t, heads * width)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, t, self.num_local_heads * o.shape[-1])
-            return ffn_half(x, self._attn_project(layer_params, o, tp, dtype))
+            with self._attn_scoped():
+                a = self._attn_project(layer_params, o, tp, dtype)
+            return ffn_half(x, a)
 
         def ffn_half(x, a):
             if self.tp_size > 1:
@@ -893,11 +1043,15 @@ class DecoderStack:
         """The heads' outputs (b, t, heads * width) through `wo`."""
         return tp.row(lp, "wo", o, dtype)
 
+    def _attn_scoped(self):
+        return (jax.named_scope(self.attn_scope) if self.attn_scope
+                else contextlib.nullcontext())
+
     def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
         """The FFN half of a layer on the normed activation `y`: (output,
-        aux or None). The routed experts of parallel/moe.MoEFFN where
-        cfg.num_experts > 0, else the family's dense `_mlp`."""
-        if self.is_moe:
+        aux or None). The routed experts (`_mods["moe"]`) where the layer's
+        parameters hold them, else the family's dense `_mlp`."""
+        if "moe" in lp:
             ff, aux = self._mods["moe"].apply(lp["moe"], y, dtype)
             if tp.sp:
                 # The router saw the tp-gathered full tokens (identical
@@ -915,14 +1069,44 @@ class DecoderStack:
 
     def _fold_aux(self, auxs):
         """What a segment's scan stacked per layer -> the segment's aux:
-        the router sums of parallel/moe.MoEFFN add up over layers."""
-        return (jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
-                if self.is_moe else None)
+        the router sums of parallel/moe.MoEFFN add up over layers; a
+        family's counters stay one row a layer (`_router_aux_losses`)."""
+        if not self.is_moe or not self._router_aux_losses:
+            return auxs
+        return jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
+
+    @property
+    def rotary_dim(self) -> int:
+        """The leading part of a head that RoPE rotates."""
+        return self.head_dim
+
+    def _positions(self, params: Params, x: jax.Array,
+                   position_ids: jax.Array, dtype):
+        """(x in the compute dtype with the positions that enter at the
+        embedding, the arrays every layer gets). Here nothing enters at the
+        embedding and a layer gets `rotary_dim`'s (cos, sin) at
+        `position_ids`, computed from the positions."""
+        return x.astype(dtype), rope_angles(position_ids, self.rotary_dim,
+                                            self.cfg.rope_theta)
 
     def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
         """(q, k) with the positions a layer takes at its attention: none
         where they all entered at the embedding."""
-        return q, k
+        if not layer_pos:
+            return q, k
+        return (apply_rotary_leading(q, *layer_pos, self.rotary_dim),
+                apply_rotary_leading(k, *layer_pos, self.rotary_dim))
+
+    def _mlp(self, lp: Params, y: jax.Array, tp: TPSublayers,
+             dtype) -> jax.Array:
+        """A dense block's feed-forward; SwiGLU here, down(silu(gate(y)) *
+        up(y)) (model.py:94-95): `ffn_inputs` 2."""
+        g, u = tp.columns(lp, ("gate_proj", "up_proj"), y, dtype,
+                          **tp.ffn_order)
+        g = checkpoint_name(g, "ffn_gate")
+        u = checkpoint_name(u, "ffn_up")
+        return tp.row(lp, "down_proj", jax.nn.silu(g) * u, dtype,
+                      **tp.ffn_order)
 
     def _attn_mask(self, t: int):
         """The attention mask a family declares over a sequence of `t` rows
@@ -1113,6 +1297,25 @@ class DecoderStack:
                 logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
                                    logits, jnp.asarray(NEG_INF, logits.dtype))
         return logits
+
+    def _head_logits(self, params: Params, x: jax.Array, dtype) -> jax.Array:
+        """The local vocabulary shard of the logits: through `lm_head`, or,
+        where the head is tied, against this shard's embedding rows."""
+        if not self.tied_head:
+            return self.lm_head.apply(
+                params["lm_head"], x, dtype,
+                input_layout="seq_sharded" if self.sequence_parallel
+                else "replicated")
+        tp = self._tp_sublayers
+        w = params["embedding"]["weight"].astype(dtype)  # (vp/tp, d)
+        if tp.ring_ov:
+            # the gather's hops hide under the per-chunk logits dots; the
+            # VJP's reverse ring reduce-scatters the head's input cotangent
+            return ring_order(ag_matmul(x.astype(dtype), (w.T,), "tp",
+                                        tp.ring_quant)[0], "tp")
+        # under sequence parallelism the tied head takes the full sequence;
+        # the gather's transpose reduce-scatters the input cotangent
+        return tp.gather(x).astype(dtype) @ w.T           # (b, t, vp/tp)
 
     def _pipeline_layers(self, stage_fn, x: jax.Array, layers: Params,
                          mb_arrays: Tuple[jax.Array, ...],
@@ -1496,8 +1699,16 @@ class DecoderStack:
                     mode: str, batch_axes):
         """A family's further loss terms on top of the main CE (`x` is the
         last layer's output, `trunk` what `_trunk` returned): (loss,
-        counters of its own). None here."""
-        return loss, {}
+        counters of its own). No further term here; the layers' counters
+        of a family that carries them."""
+        return loss, self._counters(aux, batch_axes)
+
+    def _counters(self, aux, batch_axes) -> Params:
+        """The layers' counters summed over the batch axes; none where the
+        aux is the router sums of the auxiliary losses, or nothing."""
+        if not self.is_moe or self._router_aux_losses:
+            return {}
+        return jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
 
     # ---- global (jitted) entry points ----
 

@@ -43,11 +43,9 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from ..config import ModelConfig
 from ..ops.rope import apply_rotary, rope_tables
-from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.moe import MoEFFN
 from ..parallel.norm import RMSNorm
@@ -66,9 +64,7 @@ class Transformer(DecoderStack):
 
     # what the stack, the decoder (models/decode.py), training/memory.py and
     # obs/attribution.py ask a family
-    uses_rope = True          # RoPE on q/k (vs learned position embeddings)
-    attn_norm_key = "norm1"   # pre-attention norm's module-dict key
-    ffn_norm_key = "norm2"    # pre-FFN norm's key
+    family = "llama"
     ffn_inputs = 2            # gate and up both read the MLP's input
     tied_head = False
 
@@ -77,11 +73,6 @@ class Transformer(DecoderStack):
         return cfg.num_params()
 
     # ---- sub-module definitions (static, cheap to rebuild) ----
-
-    @functools.cached_property
-    def embedding(self) -> VocabParallelEmbedding:
-        return VocabParallelEmbedding(self.cfg.vocab_size, self.d, tp_size=self.tp_size)
-
 
     @functools.cached_property
     def _mods(self) -> Dict[str, Any]:
@@ -114,10 +105,6 @@ class Transformer(DecoderStack):
         return mods
 
     @functools.cached_property
-    def final_norm(self) -> RMSNorm:
-        return RMSNorm(self.d)
-
-    @functools.cached_property
     def lm_head(self) -> ColumnParallelLinear:
         # gather_output handled at the shard_map boundary; see module docstring.
         return ColumnParallelLinear(self.d, self.vocab_padded,
@@ -132,15 +119,7 @@ class Transformer(DecoderStack):
         Layer params are stacked along a leading num_layers axis for scan.
         """
         layers = self._init_layers(key)
-        lm_head = self.lm_head.init(fold(key, "lm_head"))
-        if self.vocab_padded != self.cfg.vocab_size:
-            # zero the padded output columns so checkpoints stay
-            # permutation-stable; padded logits are masked to NEG_INF anyway.
-            w = lm_head["weight"]
-            mask = (jnp.arange(self.vocab_padded) < self.cfg.vocab_size)[None, :]
-            lm_head["weight"] = jnp.where(mask, w, 0.0)
-            if "bias" in lm_head:
-                lm_head["bias"] = jnp.where(mask[0], lm_head["bias"], 0.0)
+        lm_head = self._init_head(key)
         return {
             "embedding": self.embedding.init(fold(key, "embedding")),
             "layers": layers,
@@ -176,18 +155,3 @@ class Transformer(DecoderStack):
     def _position_qk(self, q: jax.Array, k: jax.Array, layer_pos):
         return apply_rotary(q, k, *layer_pos)
 
-    def _mlp(self, lp: Params, y: jax.Array, tp: TPSublayers,
-             dtype) -> jax.Array:
-        """down(silu(gate(y)) * up(y))   (model.py:94-95)"""
-        g, u = tp.columns(lp, ("gate_proj", "up_proj"), y, dtype,
-                          **tp.ffn_order)
-        g = checkpoint_name(g, "ffn_gate")
-        u = checkpoint_name(u, "ffn_up")
-        return tp.row(lp, "down_proj", jax.nn.silu(g) * u, dtype,
-                      **tp.ffn_order)
-
-    def _head_logits(self, params: Params, x: jax.Array, dtype) -> jax.Array:
-        return self.lm_head.apply(
-            params["lm_head"], x, dtype,
-            input_layout="seq_sharded" if self.sequence_parallel
-            else "replicated")

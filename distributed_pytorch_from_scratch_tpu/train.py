@@ -549,14 +549,16 @@ def train(args: argparse.Namespace) -> dict:
         needs = family_class(args.family).config_extra
         carries = cfg.family_facts
         if needs != carries:
+            pairs = ", ".join(
+                f"--family {name} --model {preset}"
+                for name, cls in FAMILIES.items() if cls.config_extra
+                for preset, c in MODEL_PRESETS.items()
+                if c.family_facts == cls.config_extra)
             raise SystemExit(
                 f"--family {args.family} reads the config field {needs!r} "
                 f"and --model {args.model} carries {carries!r}: a family "
                 f"with facts of its own goes with a preset that has them "
-                f"(--family mla_moe --model tiny-mla-moe, --family gdn_moe "
-                f"--model tiny-gdn-moe, --family conv_moe --model "
-                f"tiny-conv-moe, --family bd_moe --model tiny-bd-moe), "
-                f"and such a preset with no other family")
+                f"({pairs}), and such a preset with no other family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias
         # (the precedence rule lives in training/train_step.py)
         zero_stage = resolve_zero_stage(args.zero, args.zero1)

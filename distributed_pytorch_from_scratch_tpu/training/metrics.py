@@ -138,95 +138,14 @@ def chip_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
 
 def model_flops_per_step(cfg, batch: int, seqlen: int,
                          num_params: int) -> float:
-    """Model FLOPs for one fwd+bwd train step (no remat recompute counted):
-    6N_active per token + the 12*L*h*T^2*hd attention term. For MoE models
-    only the top_k experts a token is routed through count (the standard
-    active-parameter MFU convention); dropped-token underflow is ignored.
-
+    """Model FLOPs for one fwd+bwd train step (no remat recompute counted),
+    as the family that reads `cfg` states them (its `flops_per_step`; the
+    dense and capacity-MoE formula is `models/stack.DecoderStack`'s).
     `num_params` is the family's count of `cfg`, `model.num_params(cfg)`
-    (the gpt2 family's 2-matmul MLP + tied head are a third of the FFN and
-    a vocabulary short of the llama formula in `cfg.num_params()`)."""
-    n = num_params
-    lm = getattr(cfg, "latent_moe", None)
-    if lm is not None:
-        # the mla_moe family: of the routed experts HELD a token takes
-        # top_k x held / routed on average (the rest of its top_k live on
-        # other chips of the deployment); the shared expert, the latent
-        # projections and the module are in `num_params` whole; q/k and v
-        # have their own widths; the embedding's lookup is no matmul but
-        # the head runs once more for the module
-        held = cfg.experts_held
-        expert = 3 * cfg.attn_dim * lm.moe_intermediate_size
-        expert_layers = (cfg.num_layers - lm.first_k_dense_replace
-                         + lm.num_nextn_predict_layers)
-        n -= expert_layers * (held - cfg.moe_top_k * held
-                              / cfg.num_experts) * expert
-        n += (lm.num_nextn_predict_layers - 1) * cfg.vocab_size * cfg.attn_dim
-        attn_layers = cfg.num_layers + lm.num_nextn_predict_layers
-        return (6 * n * batch * seqlen
-                + 6 * attn_layers * batch * cfg.num_heads * seqlen * seqlen
-                * (lm.qk_head_dim + lm.v_head_dim))
-    gm = getattr(cfg, "gdn_moe", None)
-    if gm is not None:
-        # the gdn_moe family: the held experts at a token's mean share of
-        # them, as above; the embedding's lookup is no matmul; attention at
-        # the full T^2 in the full-attention layers only (q/k and v at
-        # `head_dim`); the chunked delta rule's own products
-        # (`ops/delta_rule.rule_flops_per_token`), forward and twice that
-        # backward
-        from ..ops.delta_rule import rule_flops_per_token
-        held = cfg.experts_held
-        n -= cfg.num_layers * (held - cfg.moe_top_k * held
-                               / cfg.num_experts) * (
-            3 * cfg.attn_dim * gm.moe_intermediate_size)
-        n -= cfg.vocab_size * cfg.attn_dim
-        full = cfg.num_layers // gm.full_attention_interval
-        rule = gm.linear_num_value_heads * rule_flops_per_token(
-            gm.linear_key_head_dim, gm.linear_value_head_dim)
-        return (6 * n * batch * seqlen
-                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
-                * gm.head_dim
-                + 3 * (cfg.num_layers - full) * rule * batch * seqlen)
-    cm = getattr(cfg, "conv_moe", None)
-    if cm is not None:
-        # the conv_moe family: the held experts at a token's mean share of
-        # them in the expert layers only, as above; the tied embedding's
-        # one matrix is the head's matmul (its lookup is none); attention
-        # at the full T^2 in the attention layers only; the convolution's
-        # taps are no matmul
-        held = cfg.experts_held
-        expert_layers = cfg.num_layers - cm.num_dense_layers
-        n -= expert_layers * (held - cfg.moe_top_k * held
-                              / cfg.num_experts) * (
-            3 * cfg.attn_dim * cm.moe_intermediate_size)
-        full = sum(kind == "full_attention" for kind in cm.layer_types)
-        return (6 * n * batch * seqlen
-                + 12 * full * batch * cfg.num_heads * seqlen * seqlen
-                * cfg.head_dim)
-    bd = getattr(cfg, "bd_moe", None)
-    if bd is not None:
-        # the bd_moe family, per DATA token (`batch` x `seqlen` of them): a
-        # token's two rows, noised and clean, through every layer's
-        # attention, router and held experts (at a row's mean share of
-        # them, as above); the head on the noised row only; the embedding's
-        # lookup is no matmul; attention at the entries the declared mask
-        # leaves live, `L + B` a head and data token (models/bd_moe.py)
-        held = cfg.experts_held
-        layers = n - 2 * cfg.vocab_size * cfg.attn_dim - cfg.attn_dim
-        layers -= cfg.num_layers * (held - cfg.moe_top_k * held
-                                    / cfg.num_experts) * (
-            3 * cfg.attn_dim * bd.moe_intermediate_size)
-        return (6 * (2 * layers + cfg.vocab_size * cfg.attn_dim)
-                * batch * seqlen
-                + 12 * cfg.num_layers * batch * cfg.num_heads * seqlen
-                * (seqlen + bd.block_length) * bd.head_dim)
-    if getattr(cfg, "num_experts", 0):
-        inactive = ((cfg.num_experts - cfg.moe_top_k)
-                    * 3 * cfg.attn_dim * cfg.ffn_dim)
-        n -= cfg.num_layers * max(0, inactive)
-    return (6 * n * batch * seqlen
-            + 12 * cfg.num_layers * batch * cfg.num_heads
-            * seqlen * seqlen * cfg.head_dim)
+    (gpt2's 2-matmul MLP + tied head are a third of the FFN and a
+    vocabulary short of the llama formula in `cfg.num_params()`)."""
+    from ..models import facts_family
+    return facts_family(cfg).flops_per_step(cfg, batch, seqlen, num_params)
 
 
 def bd_counters_summary(counters: dict) -> dict:

@@ -37,7 +37,7 @@ from distributed_pytorch_from_scratch_tpu.config import (
     BdMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.bd_moe import (
-    block_diffusion_noise, param_counts)
+    BlockDiffusionMoETransformer, block_diffusion_noise)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_bd_moe import (
     bd_mask, reference_hidden, sizes_of, vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.obs.attribution import (
@@ -477,31 +477,6 @@ def test_a_family_needs_its_own_facts(cfg, message):
         build_model("bd_moe", cfg)
 
 
-@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
-                                dict(dp_reduce_bucket_mb=1.0)])
-def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
-    mesh, model = on_mesh(tiny(), 1)
-    with pytest.raises(ValueError, match="not made to work with the "
-                       "BlockDiffusionMoETransformer family"):
-        build_train_step(model, mesh, OptimizerConfig(), **kw)
-
-
-def test_decode_and_serving_refuse_the_family():
-    from distributed_pytorch_from_scratch_tpu.models.decode import (
-        GreedyDecoder, make_generate)
-    from distributed_pytorch_from_scratch_tpu.serving.engine import (
-        ContinuousBatchingEngine, PagedEngine)
-    mesh, model = on_mesh(tiny(), 1)
-    params = model.init(jax.random.key(0))
-    for build in (lambda: GreedyDecoder(model, mesh, 32),
-                  lambda: make_generate(model, mesh, 32),
-                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
-                                                   32, 1),
-                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
-        with pytest.raises(ValueError, match="cannot be decoded or served"):
-            build()
-
-
 # ---- the step: counters, memory facts, the CLI ----
 
 def test_the_train_step_trains_and_counts_rows_and_masked_positions():
@@ -625,7 +600,7 @@ def test_the_cut_at_the_published_widths_counts_645_623_296():
         num_layers=6, vocab_size=18992, num_experts=128, moe_top_k=8,
         bd_moe=BdMoEConfig(head_dim=128, moe_intermediate_size=768,
                            experts_held=16))
-    parts = param_counts(cfg)
+    parts = BlockDiffusionMoETransformer.param_counts(cfg)
     assert parts["layers"] == 6 * 94_638_336
     assert parts["embedding_and_head"] == 77_791_232
     assert cfg.num_params() == sum(parts.values()) == 645_623_296

@@ -37,7 +37,7 @@ from distributed_pytorch_from_scratch_tpu.config import (
     ConvMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.conv_moe import (
-    layer_blocks, layers_in_order, param_counts)
+    ConvMoETransformer, layer_blocks, layers_in_order)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_conv_moe import (
     vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
@@ -429,31 +429,6 @@ def test_a_family_needs_its_own_facts_and_a_pattern_it_can_cut(cfg, message):
         build_model("conv_moe", cfg)
 
 
-@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
-                                dict(dp_reduce_bucket_mb=1.0)])
-def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
-    mesh, model = on_mesh(tiny(), 1)
-    with pytest.raises(ValueError, match="not made to work with the "
-                                         "ConvMoETransformer family"):
-        build_train_step(model, mesh, OptimizerConfig(), **kw)
-
-
-def test_decode_and_serving_refuse_the_family():
-    from distributed_pytorch_from_scratch_tpu.models.decode import (
-        GreedyDecoder, make_generate)
-    from distributed_pytorch_from_scratch_tpu.serving.engine import (
-        ContinuousBatchingEngine, PagedEngine)
-    mesh, model = on_mesh(tiny(), 1)
-    params = model.init(jax.random.key(0))
-    for build in (lambda: GreedyDecoder(model, mesh, 32),
-                  lambda: make_generate(model, mesh, 32),
-                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
-                                                   32, 1),
-                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
-        with pytest.raises(ValueError, match="cannot be decoded or served"):
-            build()
-
-
 # ---- the step: counters, memory facts ----
 
 def test_the_train_step_trains_and_counts_a_row_an_expert_layer():
@@ -599,7 +574,7 @@ def test_the_cut_at_the_published_widths_counts_507_820_288():
         num_experts=32, moe_top_k=4, conv_moe=ConvMoEConfig(
             layer_types=PUBLISHED[1:6], moe_intermediate_size=1792,
             num_dense_layers=1, experts_held=8))
-    counts = param_counts(cfg)
+    counts = ConvMoETransformer.param_counts(cfg)
     assert counts == {"embedding": 33_554_432, "final_norm": 2048,
                       "dense_layers": 60_827_648,
                       "conv_layers": 3 * (104_933_376 + 32),

@@ -30,7 +30,8 @@ import pytest
 from distributed_pytorch_from_scratch_tpu.config import (
     GdnMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
-from distributed_pytorch_from_scratch_tpu.models.gdn_moe import param_counts
+from distributed_pytorch_from_scratch_tpu.models.gdn_moe import (
+    GdnMoETransformer)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_gdn_moe import (
     vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
@@ -557,31 +558,6 @@ def test_the_model_refuses_what_it_does_not_run(kw, message):
         build_model("gdn_moe", tiny(), **kw)
 
 
-@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
-                                dict(dp_reduce_bucket_mb=1.0)])
-def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
-    mesh, model = on_mesh(tiny(), 1)
-    with pytest.raises(ValueError, match="not made to work with the "
-                                         "GdnMoETransformer family"):
-        build_train_step(model, mesh, OptimizerConfig(), **kw)
-
-
-def test_decode_and_serving_refuse_the_family():
-    from distributed_pytorch_from_scratch_tpu.models.decode import (
-        GreedyDecoder, make_generate)
-    from distributed_pytorch_from_scratch_tpu.serving.engine import (
-        ContinuousBatchingEngine, PagedEngine)
-    mesh, model = on_mesh(tiny(), 1)
-    params = model.init(jax.random.key(0))
-    for build in (lambda: GreedyDecoder(model, mesh, 32),
-                  lambda: make_generate(model, mesh, 32),
-                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
-                                                   32, 1),
-                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
-        with pytest.raises(ValueError, match="cannot be decoded or served"):
-            build()
-
-
 @pytest.mark.parametrize("cfg,message", [
     (ModelConfig(num_experts=8), "needs cfg.gdn_moe"),
     (dataclasses.replace(model_preset("tiny-gdn-moe"), num_layers=6),
@@ -610,7 +586,7 @@ def test_parameter_counts_at_the_published_widths():
     period of three linear layers and one full layer): 625,667,136, as
     `init` makes them."""
     cfg = published()
-    parts = param_counts(cfg)
+    parts = GdnMoETransformer.param_counts(cfg)
     assert parts["gdn_layers"] == 3 * 138_582_208
     assert parts["attn_layers"] == 132_127_232
     assert parts["embedding_and_head"] == 77_791_232
@@ -621,7 +597,7 @@ def test_parameter_counts_at_the_published_widths():
     made = jax.eval_shape(model.init, jax.random.key(0))
     assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
     # uncut, a layer's FFN is 1,614,809,088
-    uncut = param_counts(published(held=None))
+    uncut = GdnMoETransformer.param_counts(published(held=None))
     assert (uncut["attn_layers"] - 27_263_488 - 4096) == 1_614_809_088
     # the step's FLOPs count the held experts at a token's mean share of
     # them (10 x 32/512 = 0.625 a layer): 469 MFLOP a token forward with

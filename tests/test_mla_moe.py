@@ -26,7 +26,8 @@ from distributed_pytorch_from_scratch_tpu.config import (
     IGNORE_INDEX, LatentMoEConfig, MeshConfig, ModelConfig, OptimizerConfig,
     model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
-from distributed_pytorch_from_scratch_tpu.models.mla_moe import param_counts
+from distributed_pytorch_from_scratch_tpu.models.mla_moe import (
+    LatentMoETransformer)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_mla_moe import (
     vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
@@ -641,31 +642,6 @@ def test_the_model_refuses_what_it_does_not_run(kw, message):
         build_model("mla_moe", tiny(), **kw)
 
 
-@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
-                                dict(dp_reduce_bucket_mb=1.0)])
-def test_the_hand_reduced_gradient_builders_refuse_the_family(kw):
-    mesh, model = on_mesh(tiny(), 1)
-    with pytest.raises(ValueError, match="not made to work with the "
-                                         "LatentMoETransformer family"):
-        build_train_step(model, mesh, OptimizerConfig(), **kw)
-
-
-def test_decode_and_serving_refuse_the_family():
-    from distributed_pytorch_from_scratch_tpu.models.decode import (
-        GreedyDecoder, make_generate)
-    from distributed_pytorch_from_scratch_tpu.serving.engine import (
-        ContinuousBatchingEngine, PagedEngine)
-    mesh, model = on_mesh(tiny(), 1)
-    params = model.init(jax.random.key(0))
-    for build in (lambda: GreedyDecoder(model, mesh, 32),
-                  lambda: make_generate(model, mesh, 32),
-                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
-                                                   32, 1),
-                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
-        with pytest.raises(ValueError, match="cannot be decoded or served"):
-            build()
-
-
 def test_a_family_needs_its_own_facts():
     with pytest.raises(ValueError, match="needs cfg.latent_moe"):
         build_model("mla_moe", ModelConfig(num_experts=8))
@@ -688,7 +664,7 @@ def test_parameter_counts_at_the_published_widths():
     """One chip's share (16 of 256 experts, an eighth of the vocabulary, 1
     + 4 layers, the module): 680.4M, as `init` makes them."""
     cfg = published()
-    parts = param_counts(cfg)
+    parts = LatentMoETransformer.param_counts(cfg)
     assert round(parts["dense_layers"] / 1e6, 1) == 70.4
     assert round(parts["expert_layers"] / 4e6, 1) == 107.1
     assert round(parts["mtp"] / 1e6, 1) == 115.5
@@ -698,7 +674,7 @@ def test_parameter_counts_at_the_published_widths():
     made = jax.eval_shape(model.init, jax.random.key(0))
     assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
     # uncut, an expert layer is 1,239.6M
-    uncut = param_counts(published(held=None))
+    uncut = LatentMoETransformer.param_counts(published(held=None))
     assert round(uncut["expert_layers"] / 4e6, 1) == 1239.6
     # the step's FLOPs count the held experts at a token's mean share of
     # them (8 x 16/256 = 0.5 an expert layer), not all sixteen

@@ -9,11 +9,13 @@ import re
 import jax
 import pytest
 
-from distributed_pytorch_from_scratch_tpu.config import (BdMoEConfig,
+from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
+                                                         BdMoEConfig,
                                                          ConvMoEConfig,
                                                          GdnMoEConfig,
                                                          LatentMoEConfig,
-                                                         ModelConfig)
+                                                         ModelConfig,
+                                                         model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          DecoderStack,
                                                          build_model)
@@ -68,6 +70,9 @@ def config_for(family, config):
         experts_held=held, **LATENT))
 
 
+TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
+                "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
+                "bd_moe": "tiny-bd-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -154,7 +159,20 @@ STACK_OWNS = ("tp_layout", "_resolved", "_linear_overlap",
               "_pp_vary_axes", "_live_gated_ring", "to_canonical",
               "from_canonical", "canonical_specs", "_token_ce", "loss_shard",
               "doc_loss_shard", "make_forward", "make_loss", "make_doc_loss",
-              "shardings")
+              "shardings",
+              # what every expert-share family wrote out again until PR 45:
+              # the refusals' one function, the head's zeroed padding, the
+              # FFN dispatch, the counters' defaults, the head, the final norm
+              "_refuse", "_init_head", "_init_layers", "_layer_specs", "_ffn",
+              "_fold_aux", "_counters", "_head_logits", "final_norm")
+# and what a family with facts of its own (`config_extra`) gets from the
+# stack on top: its parameter tree from its declarations (`_segments`, and
+# `_init_more` for a group of its own), its count from its `param_counts`,
+# and the check that its facts are there (`_check_facts` is its hook)
+SHARE_FAMILY_OWNS = ("init", "specs", "num_params", "__post_init__",
+                     "lm_head")
+DRAWN = sorted(name for name, cls in FAMILIES.items() if cls.config_extra)
+drawn = pytest.mark.parametrize("family", DRAWN)
 
 
 @families
@@ -163,6 +181,98 @@ def test_family_resolves_to_the_stacks_function(family, name):
     cls = FAMILIES[family]
     assert issubclass(cls, DecoderStack)
     assert getattr(cls, name) is getattr(DecoderStack, name)
+
+
+@drawn
+@pytest.mark.parametrize("name", SHARE_FAMILY_OWNS)
+def test_a_family_with_facts_takes_its_tree_from_the_stack(family, name):
+    assert name not in vars(FAMILIES[family]), (
+        f"{family} defines {name}: the stack builds it from the family's "
+        f"declarations")
+    assert name in vars(DecoderStack)
+
+
+REFUSED_BY = {"pp_size > 1": dict(pp_size=2), "cp_size > 1": dict(cp_size=2),
+              "ep_size > 1": dict(ep_size=2),
+              "sequence_parallel=True": dict(tp_size=2,
+                                             sequence_parallel=True),
+              "attn_t_real": dict(attn_t_real=32),
+              "ZeRO stage 3": dict(zero3_axis="dp")}
+
+
+@drawn
+@pytest.mark.parametrize("what", sorted(REFUSED_BY))
+def test_the_stack_raises_a_familys_refusal_in_the_familys_words(family,
+                                                                 what):
+    from distributed_pytorch_from_scratch_tpu.models.stack import REFUSABLE
+    assert set(REFUSED_BY) == set(REFUSABLE)
+    cls = FAMILIES[family]
+    assert set(cls.refuses) <= set(REFUSABLE)
+    why = cls.refuses[what]
+    said = (f"the {family} family does not run with {what}"
+            + (f" ({why})" if why else ""))
+    with pytest.raises(ValueError, match=re.escape(said)):
+        build_model(family, config_for(family, "moe8"), **REFUSED_BY[what])
+
+
+@drawn
+def test_a_family_with_facts_needs_them_and_its_experts(family):
+    import dataclasses
+    extra = FAMILIES[family].config_extra
+    with pytest.raises(ValueError, match=f"the {family} family needs "
+                                         f"cfg.{extra} "):
+        build_model(family, CONFIGS["moe8"])
+    with pytest.raises(ValueError, match=f"the {family} family needs "
+                                         f"cfg.num_experts > 0"):
+        build_model(family, dataclasses.replace(config_for(family, "dense"),
+                                                num_experts=0))
+
+
+def _tiny_on_one_device(family):
+    from distributed_pytorch_from_scratch_tpu.config import MeshConfig
+    from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    return mesh, build_model(family, model_preset(TINY_PRESETS[family]))
+
+
+@drawn
+@pytest.mark.parametrize("kw", [dict(zero=2), dict(zero=3),
+                                dict(dp_reduce_bucket_mb=1.0)],
+                         ids=["zero2", "zero3", "bucketed"])
+def test_the_hand_reduced_gradient_builders_refuse_the_family(family, kw):
+    from distributed_pytorch_from_scratch_tpu.config import OptimizerConfig
+    from distributed_pytorch_from_scratch_tpu.training.train_step import (
+        build_train_step)
+    mesh, model = _tiny_on_one_device(family)
+    with pytest.raises(ValueError, match="not made to work with the "
+                                         f"{type(model).__name__} family"):
+        build_train_step(model, mesh, OptimizerConfig(), **kw)
+
+
+@drawn
+def test_decode_and_serving_refuse_the_family(family):
+    from distributed_pytorch_from_scratch_tpu.models.decode import (
+        GreedyDecoder, make_generate)
+    from distributed_pytorch_from_scratch_tpu.serving.engine import (
+        ContinuousBatchingEngine, PagedEngine)
+    mesh, model = _tiny_on_one_device(family)
+    params = model.init(jax.random.key(0))
+    for build in (lambda: GreedyDecoder(model, mesh, 32),
+                  lambda: make_generate(model, mesh, 32),
+                  lambda: ContinuousBatchingEngine(model, mesh, params, 2,
+                                                   32, 1),
+                  lambda: PagedEngine(model, mesh, params, 2, 32, 1)):
+        with pytest.raises(ValueError, match="cannot be decoded or served"):
+            build()
+
+
+@families
+def test_families_is_keyed_by_the_name_a_family_states(family):
+    from distributed_pytorch_from_scratch_tpu.models import facts_family
+    cls = FAMILIES[family]
+    assert cls.family == family
+    cfg = config_for(family, "dense")
+    assert facts_family(cfg) is (cls if cls.config_extra else DecoderStack)
 
 
 @families
@@ -192,6 +302,34 @@ def test_no_program_file_outside_models_decides_by_family(pattern, why):
             for i, line in enumerate(p.read_text().splitlines(), 1)
             if re.search(pattern, line)]
     assert not hits, f"{why}:\n" + "\n".join(hits)
+
+
+@pytest.mark.parametrize("facts", FAMILY_FACTS)
+def test_no_program_file_outside_models_reads_a_familys_facts(facts):
+    """PR 31's guard looks for `family ==`; `cfg.<facts> is not None` asks
+    the same question and got past it (training/metrics.py had an arm a
+    family until PR 45). A family's facts are read in `models/` and defined
+    in config.py, nowhere else: not as an attribute, not through getattr."""
+    pkg = ROOT / "distributed_pytorch_from_scratch_tpu"
+    pattern = (rf"\.{facts}\b(?!\.py)|getattr\([^)]*[\"']{facts}[\"']")
+    hits = [f"{p.relative_to(ROOT)}:{i}: {line.strip()}"
+            for p in _program_sources() if p != pkg / "config.py"
+            for i, line in enumerate(p.read_text().splitlines(), 1)
+            if re.search(pattern, line.split("#")[0])]
+    assert not hits, ("ask the family (models.facts_family, the model's "
+                      "own attributes):\n" + "\n".join(hits))
+
+
+def test_config_imports_no_family_by_name():
+    """The lowest module of the package knows no higher one by name: one
+    lazy lookup in the registry (`models.facts_family`), no
+    `from .models.<family> import`."""
+    source = (ROOT / "distributed_pytorch_from_scratch_tpu"
+              / "config.py").read_text()
+    imports = re.findall(r"^\s*(?:from|import)\s+\.models\S*.*$", source,
+                         flags=re.M)
+    assert [line.strip() for line in imports] == [
+        "from .models import facts_family"]
 
 
 def test_memory_does_not_infer_the_mlp_from_the_positions():
@@ -264,3 +402,136 @@ def test_traced_rung_is_the_parents(shape, tp, local_batch, want):
     assert memory.select_remat_traced(
         model, count(shapes), count(shapes["layers"]), local_batch,
         seqlen) == want
+
+
+# ---- the numbers a family states, as the parent stated them (PR 45) ----
+#
+# `model_flops_per_step` feeds the MFU a run prints; `num_params`,
+# `layer_extra_elems_per_token`, `head_rows_share` and `stacked_layers` feed
+# `training/memory.select_remat_traced`, which picks the remat rung and with
+# it the step's text. The literals were taken from the parent of the PR that
+# moved the formulas into the families' own files; a moved formula that
+# drifts by one ulp fails here.
+
+def cell_config(name):
+    """(family, ModelConfig) of a benchmark configuration at its published
+    widths (`benchmark/configs/<name>.json`), built through config.py only:
+    the mapping `benchmark/families/<family>.build` makes, the experts HELD
+    under the routed total, the compute dtype the cells run."""
+    import json
+    c = json.loads((ROOT / "benchmark" / "configs"
+                    / f"{name}.json").read_text())
+    routed_key = ("n_routed_experts" if "n_routed_experts" in c
+                  else "num_experts")
+    share = dict(experts_held=c[routed_key],
+                 expert_offset=c["deployment_share"]["expert_offset"])
+    common = dict(
+        attn_dim=c["hidden_size"], num_heads=c["num_attention_heads"],
+        num_layers=c["num_layers"], vocab_size=c["vocab_size"],
+        maxlen=c["max_position_embeddings"],
+        rope_theta=float(c["rope_theta"]), compute_dtype="bfloat16",
+        num_experts=c["published"][routed_key],
+        moe_top_k=c["num_experts_per_tok"])
+    family = c["family"]
+    if family == "mla_moe":
+        return family, ModelConfig(
+            ffn_dim=c["intermediate_size"], **common,
+            latent_moe=LatentMoEConfig(
+                q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+                qk_nope_head_dim=c["qk_nope_head_dim"],
+                qk_rope_head_dim=c["qk_rope_head_dim"],
+                v_head_dim=c["v_head_dim"],
+                moe_intermediate_size=c["moe_intermediate_size"],
+                n_shared_experts=c["n_shared_experts"],
+                first_k_dense_replace=c["first_k_dense_replace"],
+                routed_scaling_factor=float(c["routed_scaling_factor"]),
+                num_nextn_predict_layers=c["num_nextn_predict_layers"],
+                rms_norm_eps=float(c["rms_norm_eps"]), **share))
+    if family == "gdn_moe":
+        return family, ModelConfig(
+            ffn_dim=c["shared_expert_intermediate_size"],
+            num_kv_heads=c["num_key_value_heads"], **common,
+            gdn_moe=GdnMoEConfig(
+                head_dim=c["head_dim"],
+                linear_num_key_heads=c["linear_num_key_heads"],
+                linear_num_value_heads=c["linear_num_value_heads"],
+                linear_key_head_dim=c["linear_key_head_dim"],
+                linear_value_head_dim=c["linear_value_head_dim"],
+                moe_intermediate_size=c["moe_intermediate_size"],
+                shared_expert_intermediate_size=c[
+                    "shared_expert_intermediate_size"],
+                linear_conv_kernel_dim=c["linear_conv_kernel_dim"],
+                full_attention_interval=c["full_attention_interval"],
+                partial_rotary_factor=float(c["partial_rotary_factor"]),
+                rms_norm_eps=float(c["rms_norm_eps"]), **share))
+    if family == "conv_moe":
+        return family, ModelConfig(
+            ffn_dim=c["intermediate_size"],
+            num_kv_heads=c["num_key_value_heads"], **common,
+            conv_moe=ConvMoEConfig(
+                layer_types=tuple(c["layer_types"]),
+                moe_intermediate_size=c["moe_intermediate_size"],
+                num_dense_layers=c["num_dense_layers"],
+                conv_L_cache=c["conv_L_cache"],
+                routed_scaling_factor=float(c["routed_scaling_factor"]),
+                norm_eps=float(c["norm_eps"]), **share))
+    assert family == "bd_moe", family
+    return family, ModelConfig(
+        ffn_dim=c["moe_intermediate_size"],
+        num_kv_heads=c["num_key_value_heads"], **common,
+        bd_moe=BdMoEConfig(
+            head_dim=c["head_dim"],
+            moe_intermediate_size=c["moe_intermediate_size"],
+            block_length=c["block_length"],
+            mask_token_id=c["mask_token_id"],
+            noise_eps=float(c["noise_eps"]),
+            rms_norm_eps=float(c["rms_norm_eps"]), **share))
+
+
+# name: (batch, seqlen, (model_flops_per_step, num_params,
+#        layer_extra_elems_per_token, head_rows_share, stacked_layers))
+FACTS_PINNED = {
+    "tiny/llama": (4, 64, (1265958912, 791424, 0.0, 1.0, 2)),
+    "tiny/gpt2": (4, 64, (911474688, 560640, 0.0, 1.0, 2)),
+    "tiny/mla_moe": (4, 64, (482021376.0, 383448, 768.0, 1.0, 4)),
+    "tiny/gdn_moe": (4, 64, (827056128.0, 749392, 1344.0, 1.0, 8)),
+    "tiny/conv_moe": (4, 64, (705060864.0, 794896, 1344.0, 1.0, 12)),
+    "tiny/bd_moe": (4, 64, (384958464.0, 280000, 1984.0, 0.5, 2)),
+    # the same at tp 2: what a layer holds is divided over the tp ranks
+    "tiny-tp2/mla_moe": (4, 64, (482021376.0, 383448, 512.0, 1.0, 4)),
+    "tiny-tp2/gdn_moe": (4, 64, (827056128.0, 749392, 800.0, 1.0, 8)),
+    "tiny-tp2/conv_moe": (4, 64, (705060864.0, 794896, 800.0, 1.0, 12)),
+    "tiny-tp2/bd_moe": (4, 64, (384958464.0, 280000, 1376.0, 0.5, 2)),
+    # the four drawn families at their cell's published widths and shape
+    "joyai-llm-flash": (4, 4096,
+        (55680216072192.0, 680441088, 39680.0, 1.0, 6)),
+    "qwen3-next-80b-a3b": (2, 8192,
+        (26242826895360.0, 625667136, 78464.0, 1.0, 4)),
+    "lfm2-8b-a1b": (2, 8192, (22914011234304.0, 507820288, 76800.0, 1.0, 5)),
+    "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 116224.0, 0.5, 6)),
+}
+
+
+def _stated_facts(family, cfg, batch, seqlen, tp_size=1):
+    from distributed_pytorch_from_scratch_tpu.training.metrics import (
+        model_flops_per_step)
+    model = build_model(family, cfg, tp_size=tp_size)
+    n = model.num_params(cfg)
+    return (model_flops_per_step(cfg, batch, seqlen, num_params=n), n,
+            model.layer_extra_elems_per_token, model.head_rows_share,
+            model.stacked_layers)
+
+
+@pytest.mark.parametrize("name", sorted(FACTS_PINNED))
+def test_stated_facts_are_the_parents_to_the_last_bit(name):
+    batch, seqlen, want = FACTS_PINNED[name]
+    if name.startswith("tiny"):
+        family = name.split("/")[1]
+        cfg = model_preset(TINY_PRESETS[family])
+    else:
+        family, cfg = cell_config(name)
+    got = _stated_facts(family, cfg, batch, seqlen,
+                        tp_size=2 if name.startswith("tiny-tp2/") else 1)
+    assert got == want
+    # equal AND of the same type: 6.0 == 6 would let an int become a float
+    assert [type(g) for g in got] == [type(w) for w in want]
