@@ -161,6 +161,41 @@ class BdMoEConfig:
 
 
 @dataclass(frozen=True)
+class SwaMoEConfig:
+    """What the `swa_moe` family (models/swa_moe.py) needs beyond
+    `ModelConfig`'s own fields: a grouped-query expert decoder whose
+    attention layers are of two kinds over ONE parameter tree,
+    `sliding_attention` (a row sees itself and the `sliding_window` - 1 rows
+    before it, RoPE on q and k) and `full_attention` (the whole past, no
+    positions at all), with heads `head_dim` wide whatever the model's
+    width, q/k norms, an output gate, four norms a layer, leading layers
+    with a dense SwiGLU, and then a sigmoid top-k router with a selection
+    bias over routed experts of which this job may hold a slice, beside
+    `num_shared_experts` shared ones. The keys are Trinity's `config.json`
+    names (`afmoe`). In `ModelConfig`, `attn_dim` is the model width,
+    `num_heads` / `num_kv_heads` the heads, `num_layers` =
+    `len(layer_types)`, `ffn_dim` the leading dense layers' SwiGLU width,
+    `num_experts` the ROUTED experts the router scores and `moe_top_k` the
+    experts a token takes."""
+
+    layer_types: tuple      # "sliding_attention" | "full_attention" a layer
+    head_dim: int
+    moe_intermediate_size: int
+    sliding_window: int
+    num_dense_layers: int = 2
+    num_shared_experts: int = 1
+    route_scale: float = 1.0
+    # the speed of the selection bias's update after every optimizer step
+    # (training/optim.router_bias_step); None: nothing updates the bias
+    load_balance_coeff: "float | None" = None
+    mup_enabled: bool = True        # the embedding's rows times sqrt(width)
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-5
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -200,6 +235,8 @@ class ModelConfig:
     conv_moe: "ConvMoEConfig | None" = None
     # The `bd_moe` family's facts (None for every other family).
     bd_moe: "BdMoEConfig | None" = None
+    # The `swa_moe` family's facts (None for every other family).
+    swa_moe: "SwaMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -267,7 +304,7 @@ class ModelConfig:
 
 
 # the ModelConfig fields that carry one family's facts each
-FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe")
+FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -335,6 +372,22 @@ MODEL_PRESETS = {
         moe_top_k=2, bd_moe=BdMoEConfig(
             head_dim=32, moe_intermediate_size=32, block_length=4,
             mask_token_id=1)),
+    # the `swa_moe` family at a CPU size: Trinity's pattern in small, two
+    # leading dense layers, then (window, window, window, full) twice; a
+    # window of 16 rows, shorter than any test's sequence; 4 query heads
+    # over 2 key-value heads of 32 (heads x width = 128, not the model's
+    # 64), q/k norms, an output gate, four norms a layer; 8 routed experts
+    # (sigmoid top-2, a selection bias updated at 0.001 a step, one shared
+    # expert)
+    "tiny-swa-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_kv_heads=2, num_layers=10,
+        vocab_size=1024, maxlen=256, rope_theta=10000.0, num_experts=8,
+        moe_top_k=2, swa_moe=SwaMoEConfig(
+            layer_types=("sliding_attention",) * 2
+            + ("sliding_attention",) * 3 + ("full_attention",)
+            + ("sliding_attention",) * 3 + ("full_attention",),
+            head_dim=32, moe_intermediate_size=32, sliding_window=16,
+            route_scale=2.826, load_balance_coeff=0.001)),
 }
 
 

@@ -6,11 +6,13 @@ from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
 from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
+from .swa_moe import SlidingWindowMoETransformer
 from .transformer import Transformer
 
 FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
-    ConvMoETransformer, BlockDiffusionMoETransformer)}
+    ConvMoETransformer, BlockDiffusionMoETransformer,
+    SlidingWindowMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

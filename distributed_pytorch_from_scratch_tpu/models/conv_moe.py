@@ -73,22 +73,26 @@ def module_names(kind: str, dense: bool) -> Tuple[str, ...]:
     return ("norm1", *MIXER[kind], "norm2", *(DENSE if dense else ("moe",)))
 
 
-def layer_blocks(layer_types, num_dense_layers: int):
+def layer_blocks(layer_types, num_dense_layers: int, kinds=None,
+                 family: str = "conv_moe"):
     """`layer_types` and `num_dense_layers` -> the blocks of the layer
     pattern, by run length: ((repeats, ((parameter key, mixer kind, dense?,
     layers), ...)), ...), `repeats` None for a segment scanned once.
+    `kinds` names the two kinds of layer a family has (this family's
+    `KINDS`; `models/swa_moe.py` cuts its own two the same way).
 
     The leading dense layers are one segment (one kind of mixer). What
     follows is read as runs of one kind; a period starts at a run and ends
     before that run's kind comes again, and it repeats while the same runs
     follow: A C C C A C C C A C C A C C is ((A, C x 3) x 2, (A, C x 2) x
     2)."""
+    named = KINDS if kinds is None else kinds
     kinds = []
     for name in layer_types:
-        if name not in KINDS:
-            raise ValueError(f"layer_types holds {name!r}; the conv_moe "
-                             f"family has {sorted(KINDS)}")
-        kinds.append(KINDS[name])
+        if name not in named:
+            raise ValueError(f"layer_types holds {name!r}; the {family} "
+                             f"family has {sorted(named)}")
+        kinds.append(named[name])
     lead, rest = kinds[:num_dense_layers], kinds[num_dense_layers:]
     if len(set(lead)) > 1:
         raise ValueError("the leading dense layers are one segment of one "
@@ -114,6 +118,14 @@ def layer_blocks(layer_types, num_dense_layers: int):
                                       for kind, c in period)))
         at += repeats * n
     return tuple(blocks)
+
+
+def pattern_of(blocks):
+    """`DecoderStack._pattern` of `layer_blocks`' blocks: a segment's key, a
+    period's (key, layers a period) pairs."""
+    return tuple(parts[0][0] if repeats is None
+                 else tuple((key, n) for key, _, _, n in parts)
+                 for repeats, parts in blocks)
 
 
 def layers_in_order(params: Params, blocks):
@@ -180,9 +192,7 @@ class ConvMoETransformer(DecoderStack):
 
     @property
     def _pattern(self):
-        return tuple(parts[0][0] if repeats is None
-                     else tuple((key, n) for key, _, _, n in parts)
-                     for repeats, parts in self._blocks)
+        return pattern_of(self._blocks)
 
     @property
     def _segments(self):

@@ -16,7 +16,12 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   another): the layer pattern (`_pattern`, `_segments`), the `ModelConfig`
   field with its facts (`config_extra`), what it does not run with and why
   (`refuses`), `ffn_inputs`, `tied_head`, `uses_rope`, `rotary_dim`,
-  `decodable`, `draws_noise`, `layer_extra_elems_per_token`;
+  `decodable`, `draws_noise`, `layer_extra_elems_per_token`; and, where it
+  has them, the norms after a sublayer (`post_attn_norm_key`,
+  `post_ffn_norm_key`), the embedding's multiplier (`embed_scale`), the
+  kinds of attention layer by `_pattern` key (`_kind`, `_attn_mask(t,
+  kind)`, `unrotated_kinds`) and the speed of its routers' selection bias
+  (`router_bias_speed`);
 * `_mods`, the per-layer modules (the attention projections are
   `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
   `models/decode.py` and `interop.py` read by name), and its mixer where
@@ -53,6 +58,7 @@ from ..ops.overlap import ag_matmul, exchange_grads, ring_order
 from ..ops.ring_attention import ring_attention, ulysses_attention
 from ..ops.rope import apply_rotary_leading, rope_angles
 from ..parallel.embedding import VocabParallelEmbedding
+from ..parallel.gated_attention import gate_heads
 from ..parallel.linear import (OVERLAP_MODES, ColumnParallelLinear,
                                apply_column_ring_fused)
 from ..parallel.moe import aux_losses, aux_zeros
@@ -528,6 +534,21 @@ class DecoderStack:
     uses_rope = True            # RoPE on q/k (vs positions at the embedding)
     attn_norm_key = "norm1"     # the pre-attention norm's key in `_mods`
     ffn_norm_key = "norm2"      # the pre-FFN norm's
+    # a family that norms a sublayer's OUTPUT before the residual adds it
+    # (x + N(attn(N(x))), x + N(ffn(N(x)))) names those norms' keys too
+    post_attn_norm_key = None
+    post_ffn_norm_key = None
+    # what the embedding's rows are multiplied by as they enter (None: 1)
+    embed_scale = None
+    # the kinds of attention layer (`_kind`) that take NO positions at q
+    # and k, of a family with two kinds of attention layer over one
+    # parameter tree; the mask by kind is `_attn_mask`
+    unrotated_kinds = ()
+    # the speed of the rule that updates the routers' selection bias after
+    # every optimizer step from the step's own counts
+    # (training/optim.router_bias_step); None: the family's configuration
+    # publishes none and nothing updates a bias it may hold
+    router_bias_speed = None
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -906,10 +927,15 @@ class DecoderStack:
     # ---- per-shard forward (call inside shard_map) ----
 
     def _layer_body(self, x: jax.Array, layer_params: Params, layer_pos,
-                    pos: jax.Array, dtype, live=None) -> jax.Array:
+                    pos: jax.Array, dtype, live=None,
+                    kind: "str | None" = None) -> jax.Array:
         """One decoder layer: x + attn(norm(x)), then x + mlp(norm(x)) or,
-        with cfg.num_experts > 0, x + MoE(norm(x)) (parallel/moe.py).
-        `layer_pos` is what the family's `_positions` hands every layer.
+        with cfg.num_experts > 0, x + MoE(norm(x)) (parallel/moe.py); a
+        family that names post-norms adds N(attn(..)) and N(mlp(..)).
+        `layer_pos` is what the family's `_positions` hands every layer;
+        `kind` is the kind of the layer's `_pattern` key (`_kind`; static):
+        a family with two kinds of attention layer over one parameter tree
+        tells them apart by it (`_attn_mask`, `unrotated_kinds`).
 
         `live` (optional scalar bool) is the
         pipeline-bubble gate used ONLY on pp meshes with ring CP: the dense
@@ -946,15 +972,18 @@ class DecoderStack:
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
             with self._attn_scoped():
-                return self._qkv(layer_params, y, tp, layer_pos, dtype, b, t)
+                return self._qkv(
+                    layer_params, y, tp,
+                    () if kind in self.unrotated_kinds else layer_pos,
+                    dtype, b, t)
 
         def attn_out(args):
-            x, o = args
+            x, o, *gate = args
             # (b, heads, t, v's width) -> (b, t, heads * width)
             o = o.transpose(0, 2, 1, 3).reshape(
                 b, t, self.num_local_heads * o.shape[-1])
             with self._attn_scoped():
-                a = self._attn_project(layer_params, o, tp, dtype)
+                a = self._attn_project(layer_params, o, tp, dtype, *gate)
             return ffn_half(x, a)
 
         def ffn_half(x, a):
@@ -963,11 +992,15 @@ class DecoderStack:
                 # the recomputed forward's collective with the matmul;
                 # not named where there is no reduce (REMAT_LADDER)
                 a = checkpoint_name(a, "attn_proj")
+            if norm := self.post_attn_norm_key:
+                a = m[norm].apply(layer_params[norm], a)
             x = x + a
 
             norm = self.ffn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
             ff, aux = self._ffn(layer_params, y, tp, dtype)
+            if norm := self.post_ffn_norm_key:
+                ff = m[norm].apply(layer_params[norm], ff)
             return x + ff, aux
 
         # Under ring overlap the dense segments run even on pipeline-bubble
@@ -984,7 +1017,7 @@ class DecoderStack:
             y = tp.gather(m[norm].apply(layer_params[norm], x))
             return ffn_half(x, self._mix(layer_params, y, layer_pos, dtype))
         if live is None or tp.ring_ov:
-            q, k, v = qkv(x)
+            q, k, v, *gate = qkv(x)
             if self.cp_size > 1:
                 if self.cp_impl == "ring":
                     o = ring_attention(q, k, v, pos, axis="cp",
@@ -992,12 +1025,15 @@ class DecoderStack:
                 else:
                     o = ulysses_attention(q, k, v, axis="cp",
                                           impl=self.attn_impl)
-            elif (mask := self._attn_mask(t)) is not None:
+            # (a family of one kind of layer is asked as it always was,
+            # `_attn_mask(t)`: the benchmark's controls patch that form)
+            elif (mask := self._attn_mask(t) if kind is None
+                  else self._attn_mask(t, kind)) is not None:
                 o = masked_attention(q, k, v, mask, impl=self.attn_impl)
             else:
                 o = causal_attention(q, k, v, impl=self.attn_impl,
                                      t_real=self._t_real(t))
-            return attn_out((x, o))
+            return attn_out((x, o, *gate))
         return self._live_gated_ring(x, qkv, attn_out, pos, live)
 
     def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
@@ -1005,9 +1041,14 @@ class DecoderStack:
         """The attention half's inputs from the normed activation `y`:
         (q, k, v), each (b, heads, t, width), positions applied. This one
         is multi-head / grouped-query attention over `wq`/`wk`/`wv`; a
-        family with another attention (latent) supplies its own."""
+        family with another attention (latent) supplies its own. A layer
+        whose parameters hold `wg` gates its heads' outputs: the gate's
+        logits (b, t, heads * width) come back fourth, for
+        `_attn_project`."""
         h = self.head_dim
-        q, k, v = tp.columns(lp, ("wq", "wk", "wv"), y, dtype)
+        q, k, v, *gate = tp.columns(
+            lp, ("wq", "wk", "wv") + (("wg",) if "wg" in lp else ()), y,
+            dtype)
         # REMAT_LADDER's names, as the linears return them: (b, t,
         # heads*h), the lane-dense shape; the positions and the head
         # split are recomputed from them
@@ -1029,7 +1070,8 @@ class DecoderStack:
             # positions, holds the two norms in its layers
             q = self._mods["q_norm"].apply(lp["q_norm"], q)
             k = self._mods["k_norm"].apply(lp["k_norm"], k)
-        return self._position_qk(q, k, layer_pos) + (v,)
+        # (the gate's logits are on no rung of REMAT_LADDER: recomputed)
+        return self._position_qk(q, k, layer_pos) + (v, *gate)
 
     def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
         """For a layer whose parameters hold no `wo` (a mixer that is not
@@ -1039,8 +1081,11 @@ class DecoderStack:
         raise NotImplementedError
 
     def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
-                      dtype) -> jax.Array:
-        """The heads' outputs (b, t, heads * width) through `wo`."""
+                      dtype, gate: "jax.Array | None" = None) -> jax.Array:
+        """The heads' outputs (b, t, heads * width) through `wo`, times
+        the sigmoid of `gate`'s logits first where the layer has a gate."""
+        if gate is not None:
+            o = gate_heads(o, gate)
         return tp.row(lp, "wo", o, dtype)
 
     def _attn_scoped(self):
@@ -1084,8 +1129,11 @@ class DecoderStack:
                    position_ids: jax.Array, dtype):
         """(x in the compute dtype with the positions that enter at the
         embedding, the arrays every layer gets). Here nothing enters at the
-        embedding and a layer gets `rotary_dim`'s (cos, sin) at
+        embedding (a family's `embed_scale` multiplies the rows, in
+        float32) and a layer gets `rotary_dim`'s (cos, sin) at
         `position_ids`, computed from the positions."""
+        if self.embed_scale is not None:
+            x = x * self.embed_scale
         return x.astype(dtype), rope_angles(position_ids, self.rotary_dim,
                                             self.cfg.rope_theta)
 
@@ -1108,10 +1156,16 @@ class DecoderStack:
         return tp.row(lp, "down_proj", jax.nn.silu(g) * u, dtype,
                       **tp.ffn_order)
 
-    def _attn_mask(self, t: int):
+    def _attn_mask(self, t: int, kind: "str | None" = None):
         """The attention mask a family declares over a sequence of `t` rows
-        (`ops/attention.AttnMask`); None is the causal triangle, with
-        `attn_t_real`."""
+        for its layers of kind `kind` (`ops/attention.AttnMask`); None is
+        the causal triangle, with `attn_t_real`."""
+        return None
+
+    def _kind(self, key: str) -> "str | None":
+        """The kind of the layers a `_pattern` key holds, which the layer
+        body is told: None where a layer's parameters say all there is to
+        say (every family with one kind of attention layer)."""
         return None
 
     def _t_real(self, t: int) -> "int | None":
@@ -1230,18 +1284,21 @@ class DecoderStack:
 
         layer_fn = remat_wrap(
             self._layer_body, resolve_remat(self, params, input_ids.shape),
-            static_argnums=(4,))
+            static_argnums=(4, 6))
 
-        def stage_fn(z, layers, *mb, live=None):
-            # one scan over `layers`; `mb` is (*layer_pos, position_ids),
-            # whole or, under the pipeline, one microbatch's rows
+        def stage_fn(z, layers, *mb, live=None, kind=None):
+            # one scan over `layers`, layers of one `kind`; `mb` is
+            # (*layer_pos, position_ids), whole or, under the pipeline, one
+            # microbatch's rows
             def body(carry, lp):
-                return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live)
+                return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind)
             z, auxs = lax.scan(body, z, layers)
             # auxs: None for dense; for MoE a dict of (L,...) stacked sums
             return z, self._fold_aux(auxs)
 
-        run = lambda z, layers: stage_fn(z, layers, *layer_pos, position_ids)
+        run = lambda z, layers, key=None: stage_fn(
+            z, layers, *layer_pos, position_ids,
+            kind=key and self._kind(key))
         if self.pp_size > 1:
             x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
                                            (*layer_pos, position_ids),
@@ -1249,7 +1306,8 @@ class DecoderStack:
         else:
             auxs = []
             for block in self._pattern:
-                x, aux = (run(x, params[block]) if isinstance(block, str)
+                x, aux = (run(x, params[block], block)
+                          if isinstance(block, str)
                           else self._scan_periods(run, x, params, block))
                 auxs.append(aux)
             # a block of dense layers has none; where several blocks count
@@ -1262,14 +1320,15 @@ class DecoderStack:
     def _scan_periods(self, run, x: jax.Array, params: Params, period):
         """One scan over the periods of a `_pattern` block: the body runs
         each key's layers of the period through `run` (the one layer
-        skeleton under the one remat policy), in the period's order. The
+        skeleton under the one remat policy; it is told the key, for the
+        layers' kind), in the period's order. The
         aux comes back one row a layer, in the order the layers ran."""
         keys = [key for key, _ in period]
 
         def period(z, layers):
             auxs = []
             for key in keys:
-                z, aux = run(z, layers[key])
+                z, aux = run(z, layers[key], key)
                 auxs.append(aux)
             return z, jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
 
@@ -1709,6 +1768,31 @@ class DecoderStack:
         if not self.is_moe or self._router_aux_losses:
             return {}
         return jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
+
+    def expert_layer_rows(self, params: Params, rows: jax.Array) -> Params:
+        """What the expert layers counted, one row a layer in the order
+        the layers ran (`_counters`), as {parameter key: the key's rows,
+        stacked as the key's layers are}: how a rule outside the gradient
+        finds the leaf a row belongs to (`router_bias_speed`)."""
+        out, at = {}, 0
+        for block in self._pattern:
+            if isinstance(block, str):
+                if "moe" in params[block]:
+                    n = jax.tree.leaves(params[block])[0].shape[0]
+                    out[block] = rows[at:at + n]
+                    at += n
+                continue
+            keys = [(key, n) for key, n in block if "moe" in params[key]]
+            periods = jax.tree.leaves(params[block[0][0]])[0].shape[0]
+            a_period = sum(n for _, n in keys)
+            of_block = rows[at:at + periods * a_period].reshape(
+                periods, a_period, *rows.shape[1:])
+            at += periods * a_period
+            first = 0
+            for key, n in keys:
+                out[key] = of_block[:, first:first + n]
+                first += n
+        return out
 
     # ---- global (jitted) entry points ----
 

@@ -152,10 +152,13 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
     whole square was 2.0). `t_real` < t prices the pad-aware bucketed path
     (attn_t_real).
     """
+    from ..ops.attention import live_entries
     from ..ops.pallas.flash_attention import (CAUSAL, get_block_config,
-                                              mask_block, plan_stats)
+                                              mask_block, plan_stats,
+                                              window_block)
     tr = t if t_real is None else t_real
-    if mask is None or mask.kind == "causal":
+    if (mask is None or mask.kind == "causal"
+            or mask.kind == "sliding_window" and mask.window >= t):
         mask = CAUSAL
         tiling = resolve_flash_tiling(t, block_q, block_k, head_dim, dtype)
         t_pad, bq, bk = tiling["t_pad"], tiling["block_q"], tiling["block_k"]
@@ -165,9 +168,10 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
         asked = (min(block_q or tuned.bwd_block_q,
                      block_k or tuned.bwd_block_k) if backward
                  else min(block_q or tuned.block_q, block_k or tuned.block_k))
-        t_pad, bq = t, mask_block(mask, t, asked)
+        clamp = window_block if mask.kind == "sliding_window" else mask_block
+        t_pad, bq = t, clamp(mask, t, asked)
         bk = bq
-        ideal = mask.half * (mask.half + mask.block)
+        ideal = live_entries(mask, t)
     plan = plan_stats(mask, t_pad, bq, bk, tr, head_dim, backward)
     live = plan["computed_unmasked"] + plan["computed_masked"]
     return {"t_pad": t_pad, "block_q": bq, "block_k": bk,

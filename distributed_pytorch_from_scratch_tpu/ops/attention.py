@@ -1,9 +1,10 @@
 """Self-attention under a declared mask: the dense XLA path and the dispatch.
 
-A mask is a DECLARATION (`AttnMask`): `CAUSAL`, the triangle every family
-but one runs, and `block_diffusion(B, L)`, the three-part mask of
-block-diffusion training over the 2L rows `[noised ; clean]` of a sequence
-(`mask_matrix` is its one dense definition). The flash kernels plan their
+A mask is a DECLARATION (`AttnMask`): `CAUSAL`, the triangle most layers
+run, `block_diffusion(B, L)`, the three-part mask of block-diffusion
+training over the 2L rows `[noised ; clean]` of a sequence, and
+`sliding_window(W)`, the triangle's band of the last W keys a row
+(`mask_matrix` is their one dense definition). The flash kernels plan their
 tiles from the same declaration (ops/pallas/flash_attention.py); the dense
 path here is the CPU default and the kernels' oracle.
 
@@ -34,11 +35,15 @@ class AttnMask(NamedTuple):
     positions (`blk(i) = i // block`): noised to noised live inside one
     block (both directions), noised to clean live for EARLIER blocks,
     clean to clean live for earlier blocks and its own, clean to noised
-    dead. `half * (half + block)` of the `4 * half^2` entries are live."""
+    dead. `half * (half + block)` of the `4 * half^2` entries are live.
+    `sliding_window`: key <= query and query - key < `window` (a row sees
+    itself and the `window` - 1 rows before it); over t >= window rows
+    `window * (2 t - window + 1) / 2` entries are live."""
 
     kind: str = "causal"
     block: int = 1
     half: int = 0
+    window: int = 0
 
 
 CAUSAL = AttnMask()
@@ -51,11 +56,29 @@ def block_diffusion(block: int, half: int) -> AttnMask:
     return AttnMask("block_diffusion", block, half)
 
 
+def sliding_window(window: int) -> AttnMask:
+    if window < 1:
+        raise ValueError(f"sliding_window: a row sees itself at least, got "
+                         f"a window of {window}")
+    return AttnMask("sliding_window", window=window)
+
+
+def live_entries(mask: AttnMask, t: int) -> int:
+    """The live (query, key) pairs of one head over `t` rows."""
+    if mask.kind == "block_diffusion":
+        return mask.half * (mask.half + mask.block)
+    w = min(mask.window, t) if mask.kind == "sliding_window" else t
+    return w * (2 * t - w + 1) // 2
+
+
 def mask_matrix(mask: AttnMask, t: int) -> jax.Array:
     """(t, t) bool, [query row, key row] live: the declaration, densely."""
     i = jnp.arange(t)
     if mask.kind == "causal":
         return i[None, :] <= i[:, None]
+    if mask.kind == "sliding_window":
+        back = i[:, None] - i[None, :]
+        return (back >= 0) & (back < mask.window)
     L, B = mask.half, mask.block
     if t != 2 * L:
         raise ValueError(f"a block_diffusion mask over {L} positions takes "
@@ -105,9 +128,9 @@ def causal_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
 
 def masked_attention_xla(q: jax.Array, k: jax.Array, v: jax.Array,
                          mask: AttnMask) -> jax.Array:
-    """`causal_attention_xla`'s math under a declared mask (every row of a
-    block_diffusion mask has a live entry, so the additive mask is exact
-    as it is there)."""
+    """`causal_attention_xla`'s math under a declared mask (every row of
+    every declared mask has a live entry, itself at least, so the additive
+    mask is exact as it is there)."""
     *_, t, head_dim = q.shape
     k, v = repeat_kv(q, k, v)
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / math.sqrt(head_dim)
@@ -157,7 +180,7 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def masked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      mask: AttnMask, impl: str = "auto") -> jax.Array:
     """`causal_attention` under a declared mask that is not the triangle
-    (a family says which: `DecoderStack._attn_mask`)."""
+    (a family says which, a kind of layer: `DecoderStack._attn_mask`)."""
     impl = resolve_attention_impl(impl)
     if impl == "xla":
         return masked_attention_xla(q, k, v, mask)

@@ -2,10 +2,11 @@
 
 The mask is a declaration (`ops/attention.AttnMask`), not a second copy of
 the kernels: a tile's static plan, the walks over a head's tiles, the
-cost_estimate and `obs/attribution.flash_tile_stats` all read it. Two
+cost_estimate and `obs/attribution.flash_tile_stats` all read it. Three
 instances: `CAUSAL` (everything below, as it was before the declaration
-existed) and `block_diffusion(B, L)` (PR 41; "The block-diffusion mask",
-under the sub-tile plan).
+existed), `block_diffusion(B, L)` (PR 41; "The block-diffusion mask",
+under the sub-tile plan) and `sliding_window(W)` (PR 46; "The sliding
+window", under that).
 
 The fused HBM-friendly attention path the reference lacks: its naive
 attention materialises the full (b, heads, t, t) score tensor in device
@@ -296,7 +297,8 @@ def _runs(cells, major: int, minor: int, merge: bool):
 class SubtilePlan(NamedTuple):
     """What one (block_q x block_k) grid tile computes. Coordinates are
     tile-relative; entry (r, c) is live iff c <= (r | (stair - 1)) + diag
-    and r < cut, and, with `band`, c >= (r & ~(stair - 1)) + diag too.
+    and r < cut, and, with `band`, c >= (r & ~(stair - 1)) + diag too, and,
+    with `low`, c >= r + low too (a sliding window's left edge).
     `stair` is 1 under the causal mask (the plain diagonal) and the block
     length under the block-diffusion mask (a block's rows share its last
     row's bound: a STAIRCASE on the diagonal), where `role` says which of
@@ -320,6 +322,7 @@ class SubtilePlan(NamedTuple):
     stair: int = 1
     band: bool = False
     role: str = ""
+    low: Optional[int] = None
 
     @property
     def work_elems(self) -> int:
@@ -353,7 +356,7 @@ def causal_subtile_plan(block_q: int, block_k: int, q_block: int,
 
 def _plan_of(block_q: int, block_k: int, sq: int, sk: int, diag: int,
              cut: int, backward: bool, stair: int = 1, band: bool = False,
-             role: str = "") -> SubtilePlan:
+             role: str = "", low: Optional[int] = None) -> SubtilePlan:
     """The plan of a tile whose live entries are `SubtilePlan`'s rule: each
     sub-tile skipped (no live entry), unmasked (all live) or masked (a
     bound crosses it)."""
@@ -367,8 +370,10 @@ def _plan_of(block_q: int, block_k: int, sq: int, sk: int, diag: int,
         cells.append([
             None if last_live < r0 or c0 > hi_last
             or (band and c0 + sk - 1 < hi_first - (stair - 1))
+            or (low is not None and c0 + sk - 1 < r0 + low)
             else r0 + sq > cut or c0 + sk - 1 > hi_first
             or (band and c0 < hi_last - (stair - 1))
+            or (low is not None and c0 < r0 + sq - 1 + low)
             for c0 in range(0, block_k, sk)])
     flat = [f for row in cells for f in row]
     # the backward walks merged rectangles, the forward single sub-tiles
@@ -377,7 +382,7 @@ def _plan_of(block_q: int, block_k: int, sq: int, sk: int, diag: int,
         sq, sk, diag, cut, _runs(cells, sq, sk, backward),
         _runs([list(col) for col in zip(*cells)], sk, sq, backward),
         flat.count(False), flat.count(True), flat.count(None),
-        stair, band, role)
+        stair, band, role, low)
 
 
 # -------------------------------------------------- the block-diffusion mask
@@ -447,6 +452,70 @@ def _bd_plan(role: str, block: int, stair: int, head_dim: int,
                     block, backward, stair, role == "nn", role)
 
 
+# ---------------------------------------------------------- the sliding window
+#
+# `sliding_window(W)`: key <= query and query - key < W. With square blocks
+# that divide the sequence (no row of padding), the plan of grid tile (qb,
+# kb) depends on D = qb - kb alone: live iff c <= r + D * block (the
+# diagonal: binds in tile D = 0 only) and c >= r + D * block - W + 1 (the
+# window's LEFT edge, `SubtilePlan.low`). A query block's row of tiles is
+#
+#   D = 0                   the tile on the diagonal (the causal plan, with a
+#                           left edge too where W < block)
+#   0 < D <= W // block - 1 wholly inside the band: the causal mask's
+#                           unmasked plan, one loop
+#   the next one or two     the tiles the left edge crosses (one where block
+#                           divides W: its strict upper triangle is live),
+#                           planned at sub-tile grain like the diagonal
+#
+# and every tile further left is never computed: skipped by the kernels'
+# loops where the head is resident (the forward's loop over key tiles starts
+# at the band's first whole tile, the backward's over query tiles ends at
+# its last), by the grid's guards and clamped index maps otherwise. A window
+# that covers the sequence is the triangle and takes `CAUSAL`'s own text
+# (`flash_attention`).
+
+
+def window_block(mask: AttnMask, t: int, block: int) -> int:
+    """The square grid block the kernels run a sliding window over `t` rows
+    with, asked for `block`. What they cannot plan is refused."""
+    block = min(block, max(128, 1 << (t - 1).bit_length()))
+    if t % block:
+        raise ValueError(
+            f"the flash kernels plan a sliding window over a sequence that "
+            f"is a multiple of the grid block (no padded rows), got {t} rows, "
+            f"grid block {block}, window {mask.window}; use the XLA "
+            f"attention")
+    return block
+
+
+@functools.lru_cache(maxsize=None)
+def _window_plan(block: int, tiles_back: int, window: int, head_dim: int,
+                 backward: bool, num_kb: int) -> SubtilePlan:
+    """The plan of a tile `tiles_back` = query block - key block tiles left
+    of the diagonal; no band where nothing of the tile is live."""
+    sq, sk = _subtile_shape(block, block, head_dim, backward, num_kb)
+    diag = max(-block, min(tiles_back * block, block - 1))
+    low = tiles_back * block - window + 1
+    # a left edge at or before the tile's last row's first column never binds
+    low = None if low <= -(block - 1) else min(low, block)
+    return _plan_of(block, block, sq, sk, diag, block, backward, low=low)
+
+
+def _window_walk(mask: AttnMask, block: int, num_b: int, head_dim: int,
+                 backward: bool):
+    """A sliding window's row of tiles, by tiles back from the diagonal:
+    (the diagonal tile's plan, the unmasked plan or None, how many tiles
+    back it reaches, ((tiles back, plan of a tile the left edge crosses),
+    ...))."""
+    at = lambda back: _window_plan(block, back, mask.window, head_dim,
+                                   backward, num_b)
+    whole = max(mask.window // block - 1, 0)
+    edges = tuple((back, at(back)) for back in range(whole + 1, num_b)
+                  if at(back).bands)
+    return at(0), (at(1) if whole else None), whole, edges
+
+
 def subtile_plan(mask: AttnMask, block_q: int, block_k: int, q_block: int,
                  k_block: int, t_real: int, head_dim: int,
                  backward: bool = False, num_kb: int = 1) -> SubtilePlan:
@@ -455,6 +524,9 @@ def subtile_plan(mask: AttnMask, block_q: int, block_k: int, q_block: int,
     if mask.kind == "causal":
         return causal_subtile_plan(block_q, block_k, q_block, k_block,
                                    t_real, head_dim, backward, num_kb)
+    if mask.kind == "sliding_window":
+        return _window_plan(block_q, q_block - k_block, mask.window,
+                            head_dim, backward, num_kb)
     return _bd_plan(_bd_role(mask, block_q, q_block, k_block), block_q,
                     mask.block, head_dim, backward, num_kb)
 
@@ -497,12 +569,18 @@ def _tile_plans(block_q: int, block_k: int, num_qb: int, num_kb: int,
                           backward, num_kb)
              for qb in range(num_qb) for kb in range(num_kb)}
     return sorted((p for p in plans if p.bands),
-                  key=lambda p: (p.diag, p.cut, p.role))
+                  key=lambda p: (p.diag, p.cut, p.role, p.low is not None,
+                                 p.low or 0))
 
 
 def _plan_is(plan: SubtilePlan, qi, ki, block_q: int, block_k: int,
              t_real: int, mask: AttnMask = CAUSAL):
     """Does grid tile (qi, ki) — program ids — run `plan`?"""
+    if mask.kind == "sliding_window":
+        reach = (qi - ki) * block_q
+        low = jnp.clip(reach - mask.window + 1, -(block_q - 1), block_q)
+        return ((jnp.clip(reach, -block_q, block_q - 1) == plan.diag)
+                & (low == (-(block_q - 1) if plan.low is None else plan.low)))
     if mask.kind != "causal":
         nh = mask.half // block_q
         own = ki - nh == qi % nh        # the clean tile of the block's place
@@ -556,6 +634,11 @@ def _rect_live(plan: SubtilePlan, r0: int, rows: int, c0: int, cols: int,
             col = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rdim)
         above = col >= row + (plan.diag - step)
         live = above if live is None else live & above
+    if plan.low is not None and c0 < r0 + rows - 1 + plan.low:
+        if col is None:     # the window's left edge crosses it
+            col = c0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rdim)
+        inside = col >= at + plan.low
+        live = inside if live is None else live & inside
     if r0 + rows > plan.cut:                    # the t_real edge crosses it
         inside = at < plan.cut
         live = inside if live is None else live & inside
@@ -670,6 +753,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         walks = [(lambda p=p: _plan_is(p, qi, ki, block_q, block_k, t_real),
                   ((ki, p),), left and (0, qi, left))
                  for p, left in _row_walk_plans(plans)]
+    elif mask.kind == "sliding_window":
+        diagonal, left, whole, edges = _window_walk(mask, block_q, num_kb,
+                                                    q_ref.shape[-1], False)
+        # a case for each count of edge tiles the query block has to its
+        # left (the first query blocks have none); the loop starts at the
+        # band's first whole tile
+        backs = [back for back, _ in edges]
+
+        def has(n):     # does the query block have exactly n edge tiles
+            if n == len(edges):
+                return qi >= backs[-1]
+            return (qi < backs[n]) & (qi >= backs[n - 1]) if n \
+                else qi < backs[0]
+
+        walks = [
+            (functools.partial(has, n),
+             ((qi, diagonal),) + tuple((qi - back, p)
+                                       for back, p in edges[:n]),
+             left and (jnp.maximum(qi - whole, 0), qi, left))
+            for n in range(len(edges) + 1)]
     else:
         nh = mask.half // block_q
         by = {p.role: p for p in plans}
@@ -723,6 +826,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         @pl.when(qi * block_q >= t_real)       # a query block of padding
         def _dead_tile():
             dead(slice(None), block_q)
+
+
+def _call_name(name: str, mask: AttnMask) -> str:
+    """A kernel call's name in a device trace: a window layer's calls say
+    so, so that a trace can tell them from the full layers' in one step."""
+    return name + "_window" if mask.kind == "sliding_window" else name
+
+
+def _window_clamp(mask: AttnMask, block: int, j, i, num_b: int,
+                  key_of_query: bool):
+    """The gridded walks' index maps under a sliding window: the block index
+    `j` a grid step names, clamped to the tiles that are computed beside
+    block `i` of the other side (the key tiles of query block i, or the query
+    blocks of key tile i), so that the pipeline fetches nothing for a tile
+    the guards skip."""
+    reach = (mask.window + block - 2) // block      # tiles back with a band
+    if key_of_query:
+        return jnp.clip(j, jnp.maximum(i - reach, 0), i)
+    return jnp.clip(j, i, jnp.minimum(i + reach, num_b - 1))
 
 
 def _kv_row(bh, hq: int, hkv: int):
@@ -801,6 +923,8 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             # a tile above the diagonal is skipped: name the block that is
             # already there, and the pipeline fetches nothing for it
             j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+        if gridded and mask.kind == "sliding_window":
+            j = _window_clamp(mask, block_q, j, i, num_kb, key_of_query=True)
         return _kv_row(b, hq, hkv), j, 0
 
     kv_rows = t_pad if row_walk else block_k
@@ -835,7 +959,7 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             bytes_accessed=(2 * q.size + bh * t_pad * dv) * q.dtype.itemsize,
             transcendentals=entries),
         interpret=interpret,
-        name="flash_fwd",
+        name=_call_name("flash_fwd", mask),
     )(q, k, v)
     return o, lse
 
@@ -1144,6 +1268,22 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if edge:
             key_tile(n_full, ((n_full, edge[0]),), None, None)
             done += sum(edge[0].columns[-1][:2])
+    elif mask.kind == "sliding_window":
+        diagonal, under, whole, edges = _window_walk(mask, block, n_full, d,
+                                                     True)
+        # a loop over key tiles for each count of edge tiles under one (the
+        # last key tiles have none); the loop over the query tiles under a
+        # key tile ends at the band's last whole tile
+        bounds = [n_full] + [n_full - back for back, _ in edges]
+        for n in range(len(edges) + 1):
+            def window_key_tile(kb, carry, n=n):
+                key_tile(kb, ((kb, diagonal),) + tuple(
+                    (kb + back, p) for back, p in edges[:n]), under, None,
+                         (kb + 1, jnp.minimum(kb + whole + 1, n_full), None))
+                return carry
+            first = bounds[n + 1] if n < len(edges) else 0
+            if first < bounds[n]:
+                jax.lax.fori_loop(first, bounds[n], window_key_tile, 0)
     else:
         nh = mask.half // block
         by = {p.role: p for p in plans}
@@ -1224,7 +1364,7 @@ def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
             * q.dtype.itemsize + 8 * bh * t_pad,
             transcendentals=entries),
         interpret=interpret,
-        name="flash_bwd",
+        name=_call_name("flash_bwd", mask),
     )(q, k, v, do, lse, delta)
 
 
@@ -1266,7 +1406,8 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     if tracer is not None:
         tracer.instant("flash_bwd_walk", walk=walk, t=t_pad, d=d, dv=dv,
                        group=group, resident_bytes=resident,
-                       budget_bytes=BWD_ROW_VMEM_BYTES)
+                       budget_bytes=BWD_ROW_VMEM_BYTES, mask=mask.kind,
+                       window=mask.window)
     if walk == "row":
         return _bwd_row_call(q, k, v, do, lse, delta, t_real=t_real,
                              block=block_q, hq=hq, hkv=hkv,
@@ -1289,8 +1430,8 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         return pl.pallas_call(
             functools.partial(
                 _bwd_fused_kernel, scale=scale,
-                plan=causal_subtile_plan(t_pad, t_pad, 0, 0, t_real, d,
-                                         backward=True)),
+                plan=subtile_plan(mask, t_pad, t_pad, 0, 0, t_real, d,
+                                  backward=True)),
             grid=(bhkv, group),
             in_specs=[q_td, kv_td, v_td, do_td, q_t1, q_t1],
             out_specs=[q_td, kv_td, v_td],
@@ -1301,11 +1442,16 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")),
             interpret=interpret,
-            name="flash_bwd",
+            name=_call_name("flash_bwd", mask),
         )(q, k, v, do, lse, delta)
 
     plans = _tile_plans(block_q, block_k, num_qb, num_kb, t_real, d,
                         backward=True, mask=mask)
+    # under a sliding window a grid step names only blocks of tiles that
+    # are computed (`_window_clamp`)
+    kblk = lambda i, j: j
+    if mask.kind == "sliding_window":
+        kblk = lambda i, j: _window_clamp(mask, block_q, j, i, num_kb, True)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, t_real=t_real,
                           block_q=block_q, block_k=block_k, num_kb=num_kb,
@@ -1313,8 +1459,10 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         grid=(bh, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda b, i, j: (kv(b), kblk(i, j), 0)),
+            pl.BlockSpec((1, block_k, dv),
+                         lambda b, i, j: (kv(b), kblk(i, j), 0)),
             pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
@@ -1325,13 +1473,18 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_call_name("flash_bwd_dq", mask),
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid dim 2 runs (group x num_qb) sequential steps per kv block;
     # the index maps pick query head `hk*group + g` at q-block `qi`.
     qrow = lambda b, gq: _q_row(b, gq // num_qb, hq, hkv)
     qblk = lambda gq: gq % num_qb
+    if mask.kind == "sliding_window":
+        qblk_of = lambda j, gq: _window_clamp(mask, block_q, gq % num_qb, j,
+                                              num_qb, False)
+    else:
+        qblk_of = lambda j, gq: qblk(gq)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, t_real=t_real,
                           block_q=block_q, block_k=block_k, num_qb=num_qb,
@@ -1339,15 +1492,15 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         grid=(bhkv, num_kb, group * num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, d),
-                         lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
+                         lambda b, j, gq: (qrow(b, gq), qblk_of(j, gq), 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda b, j, gq: (b, j, 0)),
             pl.BlockSpec((1, block_q, dv),
-                         lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
+                         lambda b, j, gq: (qrow(b, gq), qblk_of(j, gq), 0)),
             pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
+                         lambda b, j, gq: (qrow(b, gq), qblk_of(j, gq), 0)),
             pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, gq: (qrow(b, gq), qblk(gq), 0)),
+                         lambda b, j, gq: (qrow(b, gq), qblk_of(j, gq), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, gq: (b, j, 0)),
@@ -1362,7 +1515,7 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_call_name("flash_bwd_dkv", mask),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -1591,7 +1744,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Flash attention, causal unless `mask` declares another
     (`ops/attention.block_diffusion`: the blocks are then square, clamped
     to the mask's half so that no tile spans two quadrants, and must divide
-    it; its block length divides 128; no `t_real`). q: (b, heads, t,
+    it; its block length divides 128; no `t_real`.
+    `ops/attention.sliding_window`: square blocks that divide the sequence;
+    no `t_real`; a window that covers the sequence is `CAUSAL`). q: (b, heads, t,
     head_dim); k, v may carry
     FEWER heads (b, kv_heads, t, head_dim) with heads % kv_heads == 0 —
     grouped-query attention routed inside the kernels (no K/V repeat in HBM).
@@ -1647,12 +1802,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # guards, so over-padding costs only grid overhead. All four block
     # sizes share one t_pad, so the bwd blocks participate in the clamp.
     pow2 = max(128, 1 << (t - 1).bit_length())
+    if mask.kind == "sliding_window" and mask.window >= t:
+        mask = CAUSAL       # every earlier row is inside the window
     if mask.kind != "causal":
         if t_real != t:
-            raise ValueError("a block_diffusion mask takes no t_real, got "
+            raise ValueError(f"a {mask.kind} mask takes no t_real, got "
                              f"t={t}, t_real={t_real}")
-        bq = bk = mask_block(mask, t, min(block_q, block_k))
-        bbq = bbk = mask_block(mask, t, min(bwd_block_q, bwd_block_k))
+        clamp = window_block if mask.kind == "sliding_window" else mask_block
+        bq = bk = clamp(mask, t, min(block_q, block_k))
+        bbq = bbk = clamp(mask, t, min(bwd_block_q, bwd_block_k))
     else:
         bq = min(block_q, pow2)
         bk = min(block_k, pow2)

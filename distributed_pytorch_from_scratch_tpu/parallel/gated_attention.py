@@ -39,6 +39,13 @@ from .norm import ZeroCenteredRMSNorm
 Params = Dict[str, Any]
 
 
+def gate_heads(o: jax.Array, gate: jax.Array) -> jax.Array:
+    """The heads' outputs `o` (b, t, heads * width) times the sigmoid of
+    the gate's logits, taken in float32: the output gate's text, shared
+    with the stack's (q, k, v) dispatch (a layer that holds `wg`)."""
+    return o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+
+
 @dataclass(frozen=True)
 class GatedAttention:
     d: int
@@ -122,6 +129,6 @@ class GatedAttention:
         b, _, t, _ = o.shape
         with jax.named_scope("gated_attn"):
             o = o.transpose(0, 2, 1, 3).reshape(b, t, -1)
-            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
+            o = gate_heads(o, gate)
             return reduce_from(o.astype(dtype) @ params["wo"].astype(dtype),
                                self.tp_axis)

@@ -400,8 +400,11 @@ class SharedRoutedFFN:
 
     Routing (float32): `s = sigmoid(x W_r)` over all routed experts; the
     `top_k` largest of `s + bias` are chosen (`bias` is the selection bias
-    of auxiliary-loss-free balancing: a leaf no gradient reaches, updated
-    by a rule outside the step; ROADMAP queues the rule); the weights are
+    of auxiliary-loss-free balancing: a leaf no gradient reaches, moved
+    after every optimizer step by `training/optim.router_bias_step` from
+    this layer's `routed` counter where the family's configuration
+    publishes the rule's speed, `DecoderStack.router_bias_speed`, and left
+    at zero where it does not); the weights are
     `s[chosen]`, normalised over ALL chosen experts, held or not, times
     `scaling`. The layer adds `w_e E_e(x)` for the chosen experts it holds
     and the shared expert; what an absent expert would have added is left
@@ -500,6 +503,8 @@ class SharedRoutedFFN:
             # a RANDOM router (the zero one of MoEFFN would send every
             # token to the first top_k experts: sigmoid ties at 0.5)
             "router": w(fold(key, "router"), (d, self.num_experts), d),
+            # the selection bias: zeros, which only the rule of
+            # training/optim.router_bias_step moves (never a gradient)
             "bias": jnp.zeros((self.num_experts,), jnp.float32),
             "gate": w(fold(key, "gate"), (H, d, f), d),
             "up": w(fold(key, "up"), (H, d, f), d),

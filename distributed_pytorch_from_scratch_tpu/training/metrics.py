@@ -169,7 +169,8 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     held / routed` under uniform routing; per DATA token, so twice that,
     in the bd_moe family, whose layers see two rows a token), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
-    balance)."""
+    balance); and, where the step ran the selection bias's rule, the mean
+    size of a bias entry's step (`router_bias_step`)."""
     import numpy as np
 
     lo = cfg.expert_offset
@@ -181,6 +182,8 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
         np.mean(counters["rows_here"])) / max(tokens, 1)
     out["load_max_over_mean"] = float(np.mean(
         routed.max(-1) / np.maximum(routed.mean(-1), 1e-9)))
+    if "router_bias_step" in counters:
+        out["router_bias_step"] = float(counters["router_bias_step"])
     return out
 
 

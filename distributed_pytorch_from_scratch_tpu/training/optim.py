@@ -141,6 +141,35 @@ def clip_by_global_norm(grads: Any, max_norm: float) -> Any:
                         grads)
 
 
+def router_bias_step(routed: jax.Array, speed: float) -> jax.Array:
+    """What a router's selection bias moves by after a step in which its
+    layer's routed experts were chosen for `routed` (..., experts) pairs
+    (the step's own counts, over the whole batch): `speed` towards the
+    experts under the mean load and away from those over it, zero-mean
+    over the experts, in float32 (auxiliary-loss-free balancing as
+    torchtitan writes it). Outside the gradient and outside Adam."""
+    n = routed.astype(jnp.float32)
+    delta = speed * jnp.sign(jnp.mean(n, axis=-1, keepdims=True) - n)
+    return delta - jnp.mean(delta, axis=-1, keepdims=True)
+
+
+def update_router_bias(params: Any, routed_by_key: Any, speed: float
+                       ) -> Tuple[Any, jax.Array]:
+    """`params` with `router_bias_step` added to the selection bias of every
+    expert layer: `routed_by_key` is {parameter key: the counts of the key's
+    layers, stacked as the layers are} (`DecoderStack.expert_layer_rows`).
+    Also the mean size of a bias entry's step, which is 0 only where the
+    rule did not run or every expert sat on the mean."""
+    out, sizes = dict(params), []
+    for key, routed in routed_by_key.items():
+        moe = dict(out[key]["moe"])
+        delta = router_bias_step(routed, speed)
+        moe["bias"] = moe["bias"] + delta.astype(moe["bias"].dtype)
+        out[key] = {**out[key], "moe": moe}
+        sizes.append(jnp.abs(delta).reshape(-1))
+    return out, jnp.mean(jnp.concatenate(sizes))
+
+
 def adam_update(cfg: OptimizerConfig, params: Any, grads: Any,
                 state: AdamState) -> Tuple[Any, AdamState]:
     """One Adam(W) step with this step's scheduled (lr, beta1)

@@ -17,7 +17,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config import OptimizerConfig
 from ..models.transformer import Transformer
-from .optim import AdamState, adam_update, global_norm
+from .optim import (AdamState, adam_update, global_norm,
+                    update_router_bias)
 from .zero import (build_bucketed_grad_fn, build_zero3_grad_fn,
                    zero1_moment_shardings, zero3_shardings)
 
@@ -106,11 +107,21 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
     same program, fetched only at the loop's logging-interval D2H, so the
     sentinel costs no extra syncs. `with_counters=True` appends the loss's
     counters (`DecoderStack.loss_shard`) to that output: `(loss, grad_norm,
-    counters)`, or `(loss, counters)` without the norm."""
+    counters)`, or `(loss, counters)` without the norm.
+
+    A family whose configuration publishes the speed of its routers'
+    selection bias (`DecoderStack.router_bias_speed`) gets the rule run
+    after Adam, inside `optimizer` under the scope `router_bias`, from the
+    `routed` counts this same step returned (summed over the batch axes as
+    every counter is): the step then always asks the loss for its counters,
+    and hands them on only `with_counters`, with `router_bias_step` (the
+    mean size of a bias entry's step) among them."""
+    bias_speed = model.router_bias_speed
     grad_fn = _make_grad_fn(model, mesh, loss_mode,
                             dp_reduce_bucket_mb, dp_reduce_dtype,
                             zero_stage=zero_stage,
-                            with_counters=with_counters)
+                            with_counters=with_counters
+                            or bias_speed is not None)
 
     def step(params, opt_state: AdamState, input_ids, target_ids,
              position_ids):
@@ -124,8 +135,9 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
             loss, grads = grad_fn(params, input_ids, target_ids,
                                   position_ids, *noise_step)
         extra = ()
-        if with_counters:
+        if with_counters or bias_speed is not None:
             loss, counters = loss
+        if with_counters:
             extra = (counters,)
         # grad norm: optim.global_norm — the SAME reduction the clipper
         # uses, so the logged/sentinel-watched norm equals the one
@@ -137,6 +149,14 @@ def _step_body(model: Transformer, mesh, ocfg: OptimizerConfig,
             out = (loss,) + extra if extra else loss
         with jax.named_scope("optimizer"):
             params, opt_state = adam_update(ocfg, params, grads, opt_state)
+            if bias_speed is not None:
+                with jax.named_scope("router_bias"):
+                    params, moved = update_router_bias(
+                        params, model.expert_layer_rows(
+                            params, counters["routed"]), bias_speed)
+                if with_counters:
+                    out = out[:-1] + ({**counters,
+                                       "router_bias_step": moved},)
         return params, opt_state, out
 
     return step
@@ -310,6 +330,13 @@ def build_grad_accum_step(model: Transformer, mesh, ocfg: OptimizerConfig,
     non-goals); this is the TPU-native extension of its loop.
     """
     stage = _resolve_stage(zero, zero1)
+    if model.router_bias_speed is not None:
+        raise ValueError(
+            f"gradient accumulation is not made to work with the "
+            f"{type(model).__name__} family: the rule that updates its "
+            f"routers' selection bias reads ONE step's counts "
+            f"(training/optim.router_bias_step), and the microbatches' "
+            f"counters are not summed")
     grad_fn = _make_grad_fn(model, mesh, loss_mode,
                             dp_reduce_bucket_mb, dp_reduce_dtype,
                             zero_stage=stage)

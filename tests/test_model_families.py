@@ -15,6 +15,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          GdnMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
+                                                         SwaMoEConfig,
                                                          model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          DecoderStack,
@@ -52,9 +53,19 @@ CONV = dict(layer_types=("conv", "full_attention", "conv", "conv"),
 BD = dict(head_dim=16, moe_intermediate_size=16)
 
 
+# the swa_moe family: one dense window layer (a segment), then one period
+# of (window, window, full); heads of 16 like bd_moe's; a window of 8 rows
+SWA = dict(layer_types=("sliding_attention",) * 3 + ("full_attention",),
+           head_dim=16, moe_intermediate_size=16, sliding_window=8,
+           num_dense_layers=1, load_balance_coeff=0.001)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "swa_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           swa_moe=SwaMoEConfig(experts_held=held, **SWA))
     if extra == "bd_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            bd_moe=BdMoEConfig(experts_held=held, **BD))
@@ -72,7 +83,7 @@ def config_for(family, config):
 
 TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
-                "bd_moe": "tiny-bd-moe"}
+                "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -130,8 +141,12 @@ def test_declared_facts_agree_with_the_tree(family):
     # the decoder reads the projections by these names; a family whose
     # attention is another says it cannot be decoded
     projections = ("wq", "wk", "wv") if cls.decodable else ()
-    for key in (cls.attn_norm_key, cls.ffn_norm_key, *projections):
+    post = [k for k in (cls.post_attn_norm_key, cls.post_ffn_norm_key) if k]
+    for key in (cls.attn_norm_key, cls.ffn_norm_key, *post, *projections):
         assert all(key in params[seg] for seg in model._layer_keys)
+    # a layer's kind is told apart by key only where its parameters cannot
+    assert all(model._kind(key) is None for key in model._layer_keys) or (
+        cls._attn_mask is not DecoderStack._attn_mask)
     # a layer goes through the stack's (q, k, v) dispatch exactly where its
     # parameters hold the stack's output projection (a mixer that hands
     # back its own output keeps its projections inside its module); a
@@ -164,7 +179,13 @@ STACK_OWNS = ("tp_layout", "_resolved", "_linear_overlap",
               # the refusals' one function, the head's zeroed padding, the
               # FFN dispatch, the counters' defaults, the head, the final norm
               "_refuse", "_init_head", "_init_layers", "_layer_specs", "_ffn",
-              "_fold_aux", "_counters", "_head_logits", "final_norm")
+              "_fold_aux", "_counters", "_head_logits", "final_norm",
+              # PR 46: the residual path with a norm after a sublayer, the
+              # output gate's place in the (q, k, v) dispatch, the scan over
+              # periods that tells a layer its key's kind, and the map from
+              # the expert layers' counters to their leaves (the bias rule)
+              "_attn_project", "_scan_periods", "_trunk",
+              "expert_layer_rows")
 # and what a family with facts of its own (`config_extra`) gets from the
 # stack on top: its parameter tree from its declarations (`_segments`, and
 # `_init_more` for a group of its own), its count from its `param_counts`,

@@ -255,6 +255,44 @@ def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
         r'custom_call_target="tpu_custom_call"', text)) == sorted(names)
 
 
+@pytest.mark.parametrize("walk,names", [
+    ("row", ["flash_bwd_window", "flash_fwd_window"]),
+    ("grid", ["flash_bwd_dkv_window", "flash_bwd_dq_window",
+              "flash_fwd_window"])])
+def test_the_window_kernels_compile_at_the_seventh_cells_shape(
+        topo, described_tpu, monkeypatch, walk, names):
+    """Mosaic takes the flash kernels under `sliding_window(2048)` at a
+    window layer's shape in the seventh cell (8192 rows, 128 / 128, bf16, a
+    group of 8 query heads a key-value head, blocks of 1024): the forward
+    with K and V resident and the ONE backward kernel, and the gridded
+    forward with the split backward a longer row would take. The calls
+    carry `_window` in their names, which is how a device trace tells a
+    window layer's from a full layer's."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        sliding_window)
+    from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+        flash_attention as fa)
+    if walk == "grid":
+        monkeypatch.setattr(fa, "KV_ROW_VMEM_BYTES", 0)
+        monkeypatch.setattr(fa, "BWD_ROW_VMEM_BYTES", 0)
+    t, d, group, mask = 8192, 128, 8, sliding_window(2048)
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (rows, t, w), dtype, sharding=chip)
+    kw = dict(t_real=t, block_q=1024, block_k=1024, hq=group, hkv=1,
+              interpret=False, mask=mask)
+    text = jax.jit(lambda q, k, v: fa._fwd_call(q, k, v, **kw)).lower(
+        arg(2 * group, d), arg(2, d), arg(2, d)).compile().as_text()
+    text += jax.jit(lambda *a: fa._bwd_call(*a, **kw)).lower(
+        arg(2 * group, d), arg(2, d), arg(2, d), arg(2 * group, d),
+        arg(2 * group, 1, jnp.float32),
+        arg(2 * group, d)).compile().as_text()
+    assert sorted(name.split(".")[0] for name in re.findall(
+        r"%([\w.\-]+) = [^\n]*? custom-call\([^)]*\), "
+        r'custom_call_target="tpu_custom_call"', text)) == names
+
+
 def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
         topo, described_tpu):
     """Mosaic takes the rule's two kernels (PR 36; since PR 38 they make a
