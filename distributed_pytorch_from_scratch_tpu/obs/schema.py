@@ -107,8 +107,10 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
                       "plan_ops", "wall_ms"),
     # -- ISSUE 33: an expert model's step counters at the log interval
     # (training/metrics.moe_counters_summary): the held experts' load as
-    # max over mean, and the rows computed here per token and expert layer
-    "moe_counters": ("load_max_over_mean", "rows_here_per_token"),
+    # max over mean, and the rows held here per token and expert layer
+    # beside the rows the grouped products' groups covered (ISSUE 47)
+    "moe_counters": ("load_max_over_mean", "rows_here_per_token",
+                     "rows_computed_per_token"),
     # -- ISSUE 37: `train()`'s step function built again after its steady
     # program was in hand (a tail window, a new sequence bucket), at `step`;
     # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)

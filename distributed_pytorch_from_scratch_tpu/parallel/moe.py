@@ -380,8 +380,9 @@ sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 # a freshly initialised router on a v5e, where no step's held rows passed
 # 5.4 times the mean share and a first chunk of 4 shares was crossed in a
 # tenth of one run's steps in twelve (PERF.md section 6, PR 33). It is
-# memory (the chunk's rows in and out) and, while a live chunk is computed
-# whole, time: see the class docstring.
+# MEMORY (the chunk's rows in and out, and the movers' and the route's
+# passes over them) and the unit the `cond` skips by; the grouped products'
+# time follows the held rows, not the chunk: see the class docstring.
 CHUNK_SHARES = 6
 
 
@@ -434,20 +435,21 @@ class SharedRoutedFFN:
     on static shapes, measured). The sorted pairs are walked in chunks
     (`chunk_rows`) under one `lax.scan`; a chunk past the last held row is
     skipped by a `lax.cond` (where there are several: a chunk of ALL the
-    pairs is always computed), so memory follows the chunk, while every pair
+    pairs runs without one), so memory follows the chunk, while every pair
     that exists is computed whatever the routing (tests force all tokens
-    onto a few experts). **A live chunk is computed WHOLE**: the rows past
-    its last held pair are zeros handed to the last held expert, so the
-    products' time follows the chunks that are live, not the rows: a step
-    whose routing stays inside the first chunk (`CHUNK_SHARES` times the
-    mean share) costs the same whatever it routes, one that crosses it
-    pays for another chunk. That is a price, paid for a steady step: on a
-    v5e the grouped products and the dispatch of a chunk cost 0.7 ms a
-    thousand rows, a held share's load differed ninefold between seeds and
-    drifted as the router trained, and a step that followed the rows was
-    7% shorter on average and spread by 1.2 - 2.3% (PERF.md section 6, PR
-    33). A grouped kernel that gathers its own rows would make the padding
-    nearly free and this policy moot (ROADMAP M1(b), queued first).
+    onto a few experts). **The products follow the rows**: each held
+    expert's group ends at its own last row, the rows of a live chunk past
+    its last held pair belong to NO group, and XLA:TPU's grouped kernel
+    walks the groups it is given, so the products' time is the held rows'
+    (at a held share of an eighth a chunk is 98,304 rows for some 20,000
+    held: handed whole, four rows in five were zeros and the products ran
+    at 6 - 8% of their roofline, PERF.md section 6, PR 47). The chunk is
+    MEMORY and the unit the `cond` skips by. What that makes load-bearing:
+    a row no group holds comes back from a product AND from its transposes
+    as whatever the buffer held, so every such row is selected, never
+    multiplied, on both sides of the products (`live`, below). The step's
+    time now follows the routing, seed by seed, where nothing balances the
+    router (PR 33 read 1.2 - 2.3% between seeds).
 
     Tensor parallelism: every expert's gate/up are column-sharded and its
     down row-sharded over `tp_axis`, like the dense FFN; the router and the
@@ -610,8 +612,10 @@ class SharedRoutedFFN:
               ) -> Tuple[jax.Array, Params]:
         """x (b, t, d), replicated over tp -> (y (b, t, d), counters):
         `routed` (num_experts,) the pairs each routed expert was chosen
-        for, `rows_here` the pairs whose expert is held (the rows the
-        grouped products compute), both float32 and local to this shard."""
+        for, `rows_here` the pairs whose expert is held, `rows_computed`
+        the rows of the groups the grouped products were handed, over the
+        chunks (the held rows: the counter says so of the program that
+        ran), all float32 and local to this shard."""
         b, t, d = x.shape
         S, k = b * t, self.top_k
         xf = x.reshape(S, d)
@@ -642,28 +646,30 @@ class SharedRoutedFFN:
 
         def chunk(y, c):
             lo = c * M
+            with jax.named_scope("moe_route"):
+                # rows of each held expert inside [lo, lo + M): every
+                # group ends at its expert's own last row, so the groups
+                # cover the chunk's held rows and nothing more
+                sizes = jnp.diff(jnp.clip(ends - lo, 0, M),
+                                 prepend=0).astype(jnp.int32)
 
             def live(y):
                 with jax.named_scope("moe_route"):
                     tok = lax.dynamic_slice_in_dim(token, lo, M)
                     wc = lax.dynamic_slice_in_dim(w_sorted, lo, M)
-                    # rows of each held expert inside [lo, lo + M); the
-                    # rows past the last held pair go to the last expert,
-                    # as zeros (see the class docstring)
-                    hi_e = jnp.concatenate([jnp.clip(ends[:-1] - lo, 0, M),
-                                            jnp.full((1,), M, ends.dtype)])
-                    sizes = jnp.diff(hi_e, prepend=0).astype(jnp.int32)
                     valid = ((lo + jnp.arange(M)) < rows_here)[:, None]
                     # The grouped kernels write the rows of their groups
                     # and NOTHING ELSE: a row no group holds comes back as
                     # whatever the buffer held, from the forward products
                     # and from their transposes alike (a 5,000-fold
                     # gradient norm on the chip, PR 33; the CPU lowering
-                    # zero-fills). Every row has a group here, and padding
-                    # is still SELECTED away, never multiplied: going in
-                    # and on the cotangent side by the movers or by
-                    # `valid`, coming out by `valid` (the weights'
-                    # cotangent reads every row of `out`).
+                    # zero-fills). The rows past the held pairs have NO
+                    # group, so they are SELECTED away, never multiplied:
+                    # going in and on the cotangent side by the movers or
+                    # by `valid`, coming out by `valid` (the weights'
+                    # cotangent reads every row of `out`). Between the two
+                    # products they are garbage that nothing reads: a
+                    # product and its transposes read their groups' rows.
                     if gathers:
                         # a held pair whose row is in this chunk; every
                         # other reads the zero row
@@ -690,22 +696,20 @@ class SharedRoutedFFN:
             if chunks == 1:
                 # the one chunk is ALL the pairs (a held share of a sixth
                 # or more): there is no later chunk to skip to, and a layer
-                # whose held experts got nothing this step still computes
-                # its chunk, or the step would follow the routing after
-                # all: an untrained router with nothing to balance it sends
-                # every token of a step to the same few experts, none of
-                # them held in one layer of three, and the skipped chunk
-                # made such a step 13% shorter and the seeds' tokens per
-                # second spread by 1.7% (PERF.md section 6, PR 39)
-                return live(y), None
-            return lax.cond(lo < rows_here, live, lambda y: y, y), None
+                # whose held experts got nothing this step runs its
+                # products over zero groups (a `cond` around the one chunk
+                # cost 25 ms a step and 1.0 GiB, PERF.md section 6, PR 39)
+                return live(y), jnp.sum(sizes)
+            return (lax.cond(lo < rows_here, live, lambda y: y, y),
+                    jnp.sum(sizes))
 
         # the carry varies over what the rows vary over (batch axes and tp)
         vma = tuple(jax.typeof(xd).vma)
         y = jnp.zeros((S, d), compute_dtype)
         y = copy_to(y, vma) if vma else y
-        y, _ = lax.scan(jax.checkpoint(chunk), y,
-                        jnp.arange(chunks, dtype=jnp.int32))
+        y, computed = lax.scan(jax.checkpoint(chunk), y,
+                               jnp.arange(chunks, dtype=jnp.int32))
+        counters["rows_computed"] = jnp.sum(computed).astype(jnp.float32)
 
         if self.n_shared:
             with jax.named_scope("moe_shared"):

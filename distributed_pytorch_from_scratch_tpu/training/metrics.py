@@ -165,9 +165,10 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     """The step's counters of an expert model (`DecoderStack.loss_shard`
     with `with_counters`, fetched to the host) as the few numbers a log
     line carries: the main and multi-token-prediction losses apart, the
-    rows the held experts computed per token and expert layer (`top_k x
+    rows routed to the held experts per token and expert layer (`top_k x
     held / routed` under uniform routing; per DATA token, so twice that,
-    in the bd_moe family, whose layers see two rows a token), and the held
+    in the bd_moe family, whose layers see two rows a token) beside the
+    rows the grouped products' groups covered (the same), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
     balance); and, where the step ran the selection bias's rule, the mean
     size of a bias entry's step (`router_bias_step`)."""
@@ -178,8 +179,9 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     out = {"loss_main": float(counters["loss_main"])}
     if "loss_mtp" in counters:
         out["loss_mtp"] = float(counters["loss_mtp"])
-    out["rows_here_per_token"] = float(
-        np.mean(counters["rows_here"])) / max(tokens, 1)
+    for name in ("rows_here", "rows_computed"):
+        out[f"{name}_per_token"] = float(
+            np.mean(counters[name])) / max(tokens, 1)
     out["load_max_over_mean"] = float(np.mean(
         routed.max(-1) / np.maximum(routed.mean(-1), 1e-9)))
     if "router_bias_step" in counters:

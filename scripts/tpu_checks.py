@@ -3,7 +3,12 @@ oracles: flash attention fwd+bwd (the train default, the fused, the
 resident and the split block paths, GQA routing), the positional block
 kernel (ring attention's building block) o + lse + bwd, and the
 paged-attention kernel (decode and a prefill chunk, native and int8 pools,
-`return_lse`) against `models.decode._gather_page_view`. Also asks the
+`return_lse`) against `models.decode._gather_page_view`; and the sorted
+expert layer (`parallel/moe.SharedRoutedFFN`) at an expert cell's shape
+against one expert at a time, the device's free memory filled with NaN
+first: XLA:TPU's grouped products write their groups' rows only, and the
+layer's selects are what keeps the rest out (a second case takes the
+movers' selects out and MUST differ). Also asks the
 timer question every later measurement rests on: does `block_until_ready`
 wait for the device?
 
@@ -40,14 +45,17 @@ from distributed_pytorch_from_scratch_tpu.ops.pallas.paged_attention import (  #
     paged_attention)
 from distributed_pytorch_from_scratch_tpu.ops.ring_attention import (  # noqa: E402
     _block_attn_xla)
+from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod  # noqa: E402
 from distributed_pytorch_from_scratch_tpu.runtime.compile_cache import (  # noqa: E402
     compile_cache_stats, enable_compile_cache)
 
 RESULTS = []
 
 
-def record(name, err, atol, secs):
-    passed = bool(err <= atol)  # a NaN error fails
+def record(name, err, atol, secs, must_differ=False):
+    # a NaN error fails; `must_differ` is a case built to fail, which passes
+    # only if it does
+    passed = bool(err <= atol) != must_differ
     RESULTS.append({"name": name, "err": err, "atol": atol, "ok": passed,
                     "secs": round(secs, 2)})
     print(f"{'PASS' if passed else 'FAIL'} {name}: max err {err:.2e} "
@@ -111,6 +119,121 @@ def paged_pool(rng, pages, kvh, ps, hd, int8, dtype):
         return one(), one()
     return (jnp.asarray(rng.normal(size=(pages + 1, kvh, ps, hd)), dtype),
             jnp.asarray(rng.normal(size=(pages + 1, kvh, ps, hd)), dtype))
+
+
+def one_expert_at_a_time(moe, params, x, dtype):
+    """`SharedRoutedFFN.apply` with no shared expert as a sum over the held
+    experts, each a dense FFN over EVERY token times the token's weight
+    for it (zero where it was not chosen): no sort, no group, no chunk."""
+    xf = x.reshape(-1, x.shape[-1])
+    chosen, w = moe.route(params, xf)
+    xd, y = xf.astype(dtype), 0.0
+    for e in range(moe.num_held):
+        w_e = jnp.sum(jnp.where(chosen == moe.offset + e, w, 0), axis=-1)
+        h = (jax.nn.silu(xd @ params["gate"][e].astype(dtype))
+             * (xd @ params["up"][e].astype(dtype)))
+        y = y + (w_e[:, None]
+                 * (h @ params["down"][e].astype(dtype)).astype(jnp.float32))
+    return y.reshape(x.shape)
+
+
+def fill_free_memory_with_nan():
+    """What the device has free, written with NaN and freed again: a buffer
+    that a program allocates there and does not write then reads NaN."""
+    stats = jax.devices()[0].memory_stats() or {}
+    free = stats.get("bytes_limit", 0) - stats.get("bytes_in_use", 0)
+    jax.block_until_ready([jnp.full((2 ** 28,), jnp.nan, jnp.float32)
+                           for _ in range(int(free * 0.9) // 2 ** 30)])
+
+
+def grouped_rows_check(interp: bool, dtype, tol: float):
+    """A grouped product and its two transposes read their groups' rows and
+    no other: NaN in every row past the groups, of the left side and of
+    the cotangent, reaches neither the groups' rows nor the weights'
+    gradient (what `SharedRoutedFFN` leaves between its two products)."""
+    M, d, f, H = (512, 32, 16, 4) if interp else (98304, 2048, 1536, 16)
+    sizes = jnp.full((H,), M // (5 * H), jnp.int32)
+    inside = (jnp.arange(M) < jnp.sum(sizes))[:, None]
+    keys = jax.random.split(jax.random.key(21), 3)
+    lhs = jax.random.normal(keys[0], (M, d), dtype)
+    rhs = jax.random.normal(keys[1], (H, d, f), dtype) / math.sqrt(d)
+    g = jax.random.normal(keys[2], (M, f), dtype)
+
+    @jax.jit
+    def run(lhs, g):
+        out, pull = jax.vjp(
+            lambda l, r: jax.lax.ragged_dot(l, r, sizes), lhs, rhs)
+        d_lhs, d_rhs = pull(g)
+        return jnp.where(inside, out, 0), jnp.where(inside, d_lhs, 0), d_rhs
+
+    want = run(jnp.where(inside, lhs, 0), jnp.where(inside, g, 0))
+    fill_free_memory_with_nan()
+    t0 = time.time()
+    got = jax.block_until_ready(run(jnp.where(inside, lhs, jnp.nan),
+                                    jnp.where(inside, g, jnp.nan)))
+    for name, a, b in zip(("rows", "d_lhs", "d_rhs"), want, got):
+        record(f"grouped product, NaN past its groups: {name}",
+               max_err(b, a), tol * float(jnp.max(jnp.abs(a))),
+               time.time() - t0)
+
+
+def expert_layer_checks(interp: bool, dtype, tol: float):
+    """The layer at cells 8 and 9's shape (16,384 tokens of 2048, top-8 of
+    128 experts, 16 held: a chunk of 98,304 sorted rows of which a fifth
+    or so are held and the rest have no group) against
+    `one_expert_at_a_time`: the output, and the gradient of every leaf and
+    of the input. Then with the movers' selects taken out, which lets the
+    rows no group holds into the input's gradient: that one must DIFFER,
+    or this check would not see what the selects keep out."""
+    from jax.sharding import PartitionSpec as P
+    from distributed_pytorch_from_scratch_tpu.config import MeshConfig
+    from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+
+    b, t, d, f, E, H, k = (2, 256, 32, 16, 16, 2, 2) if interp else \
+        (2, 8192, 2048, 768, 128, 16, 8)
+    moe = moe_mod.SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
+    params = moe.init(jax.random.key(11))
+    x = jax.random.normal(jax.random.key(12), (b, t, d), jnp.float32)
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    held = int(jnp.sum(moe.route(params, x.reshape(-1, d))[0] < H))
+    shape = f"{held} held rows of a chunk of {moe.chunk_rows(b * t * k)}"
+
+    def layer(params, x):
+        return jax.shard_map(
+            lambda p, x: moe.apply(p, x, dtype)[0], mesh=mesh,
+            in_specs=(moe.specs(), P()), out_specs=P())(params, x)
+
+    def value_and_grads(fn, params, x):
+        sq = lambda p, x: jnp.sum(fn(p, x).astype(jnp.float32) ** 2)
+        d_params, d_x = jax.grad(sq, (0, 1))(params, x)
+        return {"y": fn(params, x), "d_x": d_x,
+                **{f"d_{name}": g for name, g in d_params.items()}}
+
+    def run(fn):
+        fill_free_memory_with_nan()
+        t0 = time.time()
+        # traced anew a run: the last one runs `layer` over other movers
+        got = jax.block_until_ready(jax.jit(
+            lambda p, x: value_and_grads(fn, p, x))(params, x))
+        return got, time.time() - t0
+
+    want, _ = run(lambda p, x: one_expert_at_a_time(moe, p, x, dtype))
+    got, secs = run(layer)
+    for name, a in want.items():
+        record(f"expert layer [{shape}] {name}", max_err(got[name], a),
+               tol * float(jnp.max(jnp.abs(a))), secs)
+    if interp:      # the CPU lowering zero-fills: nothing there to see
+        return
+    selected = moe_mod.take_rows, moe_mod.sum_rows
+    moe_mod.take_rows = lambda x, tok, idx, n: jnp.take(x, tok, axis=0)
+    moe_mod.sum_rows = lambda y, r, tok, idx, n: y.at[tok].add(r)
+    try:
+        got, secs = run(layer)
+    finally:
+        moe_mod.take_rows, moe_mod.sum_rows = selected
+    record("expert layer, the movers' selects out: d_x MUST differ",
+           max_err(got["d_x"], want["d_x"]),
+           tol * float(jnp.max(jnp.abs(want["d_x"]))), secs, must_differ=True)
 
 
 def timer_check(interpret: bool) -> dict:
@@ -269,6 +392,11 @@ def main():
               tol * max(1.0, float(jnp.max(jnp.abs(o_ref)))))
         check(f"paged {tag} lse", lambda: fn(q, kpool, vpool)[1], lse_ref,
               tol)
+
+    # --- the sorted expert layer: its grouped products end at the last
+    # held row, and what they leave in the rows past it stays out
+    grouped_rows_check(interp, dtype, tol)
+    expert_layer_checks(interp, dtype, tol)
 
     timer = timer_check(interp)
 

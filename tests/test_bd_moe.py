@@ -502,9 +502,12 @@ def test_the_train_step_trains_and_counts_rows_and_masked_positions():
                                   np.full(2, 2 * 4 * 64 * cfg.moe_top_k))
     np.testing.assert_array_equal(c["rows_here"],
                                   c["routed"][:, 2:6].sum(-1))
+    # the grouped products' groups cover the held rows and nothing more
+    np.testing.assert_array_equal(c["rows_computed"], c["rows_here"])
     assert float(c["positions"]) == 4 * 64
     said = moe_counters_summary(c, cfg, 4 * 64)
     assert 1.0 < said["rows_here_per_token"] < 3.0      # per DATA token
+    assert said["rows_computed_per_token"] == said["rows_here_per_token"]
     assert model_flops_per_step(cfg, 4, 64, model.num_params(cfg)) > 0
 
 
@@ -522,6 +525,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
         "--warmup_steps", "2"])
     out = capsys.readouterr().out
     assert "model[bd_moe]" in out and "rows_here_per_token" in out
+    assert "rows_computed_per_token" in out
     assert "masked_share" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
@@ -558,9 +562,9 @@ def test_the_memory_facts_count_the_rows_the_chunk_and_half_the_logits():
 
 LOWERED_BEFORE = {"llama": ("tiny", "14bb75356a403459"),
                   "gpt2": ("tiny", "557e9d12313622a3"),
-                  "mla_moe": ("tiny-mla-moe", "ab773ca5b3479182"),
-                  "gdn_moe": ("tiny-gdn-moe", "325140e3bfb1e717"),
-                  "conv_moe": ("tiny-conv-moe", "52cdcd4299dd471d")}
+                  "mla_moe": ("tiny-mla-moe", "d720c08462d95787"),
+                  "gdn_moe": ("tiny-gdn-moe", "22688b83258d3e80"),
+                  "conv_moe": ("tiny-conv-moe", "6216bbf972ffa7f1")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))
@@ -574,9 +578,10 @@ def test_the_mask_declaration_left_the_other_families_text_alone(family):
     real length, groups; PR 41). A PR that means to change a family's
     program changes the digest with it: PR 42 changed the three expert
     families' (the dispatch's row movers and the inverse permutation)
-    and so did PR 43 (its index work without a scalar gather or scatter);
-    `llama` and `gpt2`, which run no expert layer, stand as PR 40 left
-    them."""
+    and so did PR 43 (its index work without a scalar gather or scatter)
+    and PR 47 (the grouped products' groups end at the held rows, and the
+    layer counts `rows_computed`); `llama` and `gpt2`, which run no expert
+    layer, stand as PR 40 left them."""
     preset, digest = LOWERED_BEFORE[family]
     cfg = model_preset(preset)
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])

@@ -341,7 +341,7 @@ def test_a_router_forced_onto_the_same_four_experts_drops_nothing():
         want = sum(w[:, e:e + 1] * (
             (jax.nn.silu(xf @ p["gate"][e]) * (xf @ p["up"][e]))
             @ p["down"][e]) for e in range(k))
-    assert float(c["rows_here"]) == 2 * 64 * k
+    assert float(c["rows_here"]) == float(c["rows_computed"]) == 2 * 64 * k
     np.testing.assert_array_equal(c["routed"][:k], np.full(k, 128.0))
     np.testing.assert_allclose(out.reshape(-1, d), want, atol=1e-5)
 
@@ -451,8 +451,12 @@ def test_the_train_step_trains_and_counts_a_row_an_expert_layer():
                                   np.full(10, 2 * 64 * cfg.moe_top_k))
     np.testing.assert_array_equal(counters["rows_here"],
                                   counters["routed"][:, 2:6].sum(-1))
+    # the grouped products' groups cover the held rows and nothing more
+    np.testing.assert_array_equal(counters["rows_computed"],
+                                  counters["rows_here"])
     said = moe_counters_summary(counters, cfg, 2 * 64)
     assert 0.3 < said["rows_here_per_token"] < 2.0
+    assert said["rows_computed_per_token"] == said["rows_here_per_token"]
     assert model_flops_per_step(cfg, 2, 64, model.num_params(cfg)) > 0
 
 
@@ -470,6 +474,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
         "--warmup_steps", "2"])
     out = capsys.readouterr().out
     assert "model[conv_moe]" in out and "rows_here_per_token" in out
+    assert "rows_computed_per_token" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
     assert any(e.get("tag") == "moe_counters" for e in events)
@@ -504,8 +509,8 @@ def test_the_whole_chunk_is_what_the_memory_facts_count():
 # ---- the other pattern families lower to what they lowered to ----
 
 LOWERED_BEFORE = {"mla_moe": ("tiny-mla-moe", "latent_moe",
-                              "3d5a0d4ed5b64110"),
-                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "1114fa0293f11c58")}
+                              "75d13ca8f48b85a3"),
+                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "a9212bf2bfaaba29")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))
@@ -520,7 +525,9 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     compared once, parent and change, and was the same (PR 39). A PR that
     means to change either family's program changes the digest with it:
     PR 42 did (the dispatch's row movers and the inverse permutation)
-    and PR 43 (its index work without a scalar gather or scatter)."""
+    and PR 43 (its index work without a scalar gather or scatter) and PR
+    47 (the grouped products' groups end at the held rows; `rows_computed`
+    beside `rows_here`)."""
     preset, facts, digest = LOWERED_BEFORE[family]
     cfg = model_preset(preset)
     cfg = dataclasses.replace(cfg, **{facts: dataclasses.replace(
@@ -542,9 +549,9 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
 def test_a_chunk_of_all_the_pairs_is_computed_whatever_is_routed():
     """Where the one chunk is ALL the pairs there is nothing to skip to:
     the products run with no `cond` around them, also in a step that routes
-    nothing to the experts held (every row padding, the output zero);
-    several chunks keep the `cond` that skips those past the last held
-    row."""
+    nothing to the experts held (since PR 47 over ZERO groups: no row is
+    computed, the output zero, every gradient a finite zero); several
+    chunks keep the `cond` that skips those past the last held row."""
     d, f, E, k = 32, 16, 32, 4
     x = jax.random.normal(jax.random.key(1), (2, 512, d))
     quarter = SharedRoutedFFN(d, f, E, k, held=8, n_shared=0)
@@ -559,7 +566,8 @@ def test_a_chunk_of_all_the_pairs_is_computed_whatever_is_routed():
     p["bias"] = jnp.where((jnp.arange(E) >= 8) & (jnp.arange(E) < 12),
                           100.0, 0.0)
     out, c = apply_moe(quarter, p, x)
-    assert float(c["rows_here"]) == 0 and not np.any(out)
+    assert float(c["rows_here"]) == float(c["rows_computed"]) == 0
+    assert not np.any(out)
     grads = jax.grad(lambda p: jnp.sum(apply_moe(quarter, p, x)[0] ** 2))(p)
     assert all(np.all(np.isfinite(g)) and not np.any(g)
                for g in jax.tree.leaves(grads))

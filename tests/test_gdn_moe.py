@@ -516,6 +516,7 @@ def test_the_train_step_returns_a_row_of_counters_a_layer_and_the_loss_falls():
     assert abs(float(c["loss_main"]) - losses[-1]) < 1e-5
     summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
     assert summary["rows_here_per_token"] == 2.0    # all experts held
+    assert summary["rows_computed_per_token"] == 2.0
     assert summary["load_max_over_mean"] >= 1.0
 
 
@@ -532,6 +533,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
         "--warmup_steps", "2"])
     out = capsys.readouterr().out
     assert "model[gdn_moe]" in out and "rows_here_per_token" in out
+    assert "rows_computed_per_token" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
     assert any(e.get("tag") == "moe_counters" for e in events)

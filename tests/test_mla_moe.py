@@ -10,6 +10,9 @@ multi-token-prediction module. CPU, tiny sizes, float32.
 * the share test: the routed parts of all the shares of a layer plus the
   shared expert once add up to the uncut layer;
 * a router forced onto the same experts drops nothing;
+* the grouped products' groups cover the held rows and nothing more, and no
+  row past them is read: the layer with NaN in every such row (what the
+  chip's kernel may leave there) equals the plain run, every gradient too;
 * what the family does not run is refused with a message;
 * the counts: parameters at the published widths (680.4M in all).
 """
@@ -213,6 +216,7 @@ def test_a_router_forced_onto_the_same_experts_drops_nothing():
         sh = p["shared"]
         want = want + ffn(sh["gate"], sh["up"], sh["down"])
     assert float(counters["rows_here"]) == 4 * 214 * k     # 2568 pairs
+    assert float(counters["rows_computed"]) == 4 * 214 * k
     np.testing.assert_array_equal(
         counters["routed"], np.where(np.arange(E) < k, 4 * 214, 0))
     np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
@@ -373,6 +377,112 @@ def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
     for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
         a, b = np.asarray(a), np.asarray(b)
         assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
+
+
+def garbage_past_the_groups(seen):
+    """`lax.ragged_dot` as the chip runs it: a row no group holds comes
+    back as NaN, from the product and from its operand's cotangent (the
+    CPU lowering zero-fills both). `seen` gets (sum of the sizes, rows) of
+    every product the forward pass runs."""
+    real = jax.lax.ragged_dot
+
+    def poison(rows, sizes):
+        inside = jnp.arange(rows.shape[0]) < jnp.sum(sizes)
+        return jnp.where(inside[:, None], rows, jnp.nan)
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, sizes):
+        jax.debug.callback(
+            lambda n, m=lhs.shape[0]: seen.append((int(n), m)),
+            jnp.sum(sizes))
+        return poison(real(lhs, rhs, sizes), sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        d_lhs, d_rhs = jax.vjp(lambda l, r: real(l, r, sizes), lhs, rhs)[1](g)
+        return poison(d_lhs, sizes), d_rhs, None
+
+    ragged_dot.defvjp(
+        lambda lhs, rhs, sizes: (ragged_dot(lhs, rhs, sizes),
+                                 (lhs, rhs, sizes)), bwd)
+    return ragged_dot
+
+
+@pytest.mark.parametrize("case,E,H,k,S,forced,chunks,gathers,rows", [
+    ("gathers, a quarter held, one chunk", 8, 2, 2, 856, (), 1, True, None),
+    ("gathers, an eighth held, two live chunks", 24, 3, 3, 856, (0, 1, 2),
+     2, True, 2568),
+    ("scatter-add, a sixteenth held, three live chunks", 64, 4, 3, 856,
+     (0, 1, 2), 3, False, 2568),
+    ("scatter-add, chunks the routing does not reach", 64, 4, 3, 856, (), 3,
+     False, None),
+    ("no held row, one chunk", 8, 2, 2, 856, (4, 5), 1, True, 0),
+    ("no held row, every chunk skipped", 64, 4, 3, 856, (8, 9, 10), 3, False,
+     0),
+    ("the held rows end on the kernel's tile", 8, 2, 2, 512, (0, 4), 1, True,
+     512),
+    ("the held rows end on the chunk's last row", 64, 4, 2, 1024, (0, 8), 2,
+     False, 1024),
+    ("every pair held", 4, 4, 2, 856, (), 1, True, 1712),
+])
+def test_no_row_past_the_groups_is_read_anywhere(
+        monkeypatch, case, E, H, k, S, forced, chunks, gathers, rows):
+    """The groups the two products are handed cover the chunk's HELD rows
+    and nothing more (their sizes are read where the products are called),
+    and the layer reads no other row of what the products and their
+    transposes return: with NaN in every row past the groups, as the chip
+    may leave there (PR 33), the output and the gradient of every leaf and
+    of the input are finite and are the plain run's."""
+    from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
+
+    d, f = 32, 16
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=H)
+    p = moe.init(jax.random.key(1))
+    if forced:
+        p["bias"] = jnp.zeros(E).at[jnp.array(forced)].set(10.0)
+    x = jax.random.normal(jax.random.key(2), (2, S // 2, d))
+    M = moe.chunk_rows(S * k)
+    assert -(-S * k // M) == chunks
+    assert (S * k * moe_mod.ROW_GATHER_NS
+            <= M * moe_mod.ROW_SCATTER_NS) == gathers
+
+    def value_and_grads():
+        def loss(p, x):
+            y, c = apply_moe(moe, p, x)
+            return jnp.sum(jnp.sin(y)), (y, c)
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+
+    (_, (want, _)), want_g = value_and_grads()
+    seen = []
+    monkeypatch.setattr(jax.lax, "ragged_dot", garbage_past_the_groups(seen))
+    with jax.default_matmul_precision("highest"):
+        got, c = apply_moe(moe, p, x)
+        jax.effects_barrier()
+    forward = list(seen)
+    (_, (again, _)), got_g = value_and_grads()
+
+    held = float(c["rows_here"])
+    assert held == float(c["rows_computed"])
+    if rows is not None:
+        assert held == rows
+    else:
+        assert 0 < held < S * k
+    # two products a live chunk, none for a chunk the `cond` skips
+    live = chunks if chunks == 1 else -(-int(held) // M)
+    assert len(forward) == 2 * live
+    assert all(n <= m == M for n, m in forward)
+    assert sum(n for n, _ in forward) == 2 * held
+    np.testing.assert_array_equal(got, again)
+    assert np.all(np.isfinite(got))
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g)) == 9
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.all(np.isfinite(b)), jax.tree_util.keystr(path)
+        assert np.max(np.abs(a - b)) <= 1e-6 * max(np.max(np.abs(a)), 1e-6), \
             jax.tree_util.keystr(path)
 
 
@@ -598,6 +708,7 @@ def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
         < 1e-5
     summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
     assert summary["rows_here_per_token"] == 2.0    # all experts held
+    assert summary["rows_computed_per_token"] == 2.0
     assert summary["load_max_over_mean"] >= 1.0
     # off by default: the step's output is what it has always been
     plain = build_train_step(model, mesh, ocfg, with_grad_norm=True)
@@ -617,6 +728,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
         "--warmup_steps", "2"])
     out = capsys.readouterr().out
     assert "model[mla_moe]" in out and "rows_here_per_token" in out
+    assert "rows_computed_per_token" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
     assert any(e.get("tag") == "moe_counters" for e in events)

@@ -456,6 +456,7 @@ def test_the_train_step_trains_and_counts_rows():
                                   np.full(8, 4 * 64 * cfg.moe_top_k))
     np.testing.assert_array_equal(c["rows_here"],
                                   c["routed"][:, 2:6].sum(-1))
+    np.testing.assert_array_equal(c["rows_computed"], c["rows_here"])
     flops = model_flops_per_step(cfg, 4, 64, model.num_params(cfg))
     # attention at each kind's live entries: 8 window layers of 16 rows, 2
     # full layers of the triangle
@@ -482,6 +483,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
         "--warmup_steps", "2"])
     out = capsys.readouterr().out
     assert "model[swa_moe]" in out and "rows_here_per_token" in out
+    assert "rows_computed_per_token" in out
     assert "router_bias_step" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
