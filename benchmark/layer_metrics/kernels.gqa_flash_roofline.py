@@ -1,10 +1,13 @@
-"""Share of its roofline the flash kernel reaches under grouped queries at
-width 256: the least time the chip could take for the traced calls (per
-call the larger of causal FLOPs, QK^T and PV at 256 for every query head,
-over the bf16 peak and the operands' bytes, K and V once a key-value head,
-over the HBM peak; benchmark/lib/gdn_moe_counts.gqa_flash_call_cost) over
-the time they took. The backward of a multi-block grid is two calls (dq; dk
-and dv), which together do the backward's work. Chip 0."""
+"""Share of its roofline the flash kernel reaches under grouped queries (width
+256 and 16 heads over 2 in cell 6, 64 and 32 over 8 in cell 7: read from
+`sizes`): the least time the chip could take for the traced calls (per call
+the larger of causal FLOPs, QK^T and PV at the head's width for every query
+head, over the bf16 peak and the operands' bytes, K and V once a key-value
+head, over the HBM peak; benchmark/lib/gdn_moe_counts.gqa_flash_call_cost)
+over the time they took. The backward of a multi-block grid is one call
+where the head stays resident (since PR 40: cells 5 and 7) and two (dq; dk
+and dv) where it does not (cell 6), which together do the backward's work; a
+cell that mixed the two would be misread. Chip 0."""
 
 from benchmark.lib.flops import roofline_seconds
 from benchmark.lib.kernels import FLASH_BACKWARD, FLASH_FORWARD

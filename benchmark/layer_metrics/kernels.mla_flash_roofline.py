@@ -3,8 +3,10 @@ widths: the least time the chip could take for the traced calls (per call
 the larger of causal FLOPs, QK^T at q/k's 192 and PV at v's 128, over the
 bf16 peak and the operands' bytes over the HBM peak;
 benchmark/lib/mla_moe_counts.flash_call_cost) over the time they took. The
-backward of a multi-block grid is two calls (dq; dk and dv), which together
-do the backward's work. Chip 0."""
+backward of a multi-block grid is one call where the head stays resident
+(since PR 40: cells 5 and 7) and two (dq; dk and dv) where it does not (cell
+6), which together do the backward's work; a cell that mixed the two would
+be misread. Chip 0."""
 
 from benchmark.lib.flops import roofline_seconds
 from benchmark.lib.kernels import FLASH_BACKWARD, FLASH_FORWARD

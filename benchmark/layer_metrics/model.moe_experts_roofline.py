@@ -4,10 +4,11 @@ time the chip could take for the rows the step's counter says were computed
 three passes over the held experts' weights and the rows over the HBM peak;
 benchmark/lib/mla_moe_counts.expert_products_cost) over
 `model.moe_experts_ms`. Recompute under remat is time and not work, so it
-lowers the share; so do the padding rows of a chunk computed whole (time,
-not work: the chunk holds six times the mean share of rows), and products
-too small to fill the MXU (a held expert
-sees 512 rows a step here against 8192 in the deployment: the cell's cut)."""
+lowers the share; so do the `silu * up` pass between the two products, which
+runs over the chunk's rows and not the held ones (time, not work: since PR
+47 the products themselves stop at the last held row), and groups of a few
+hundred rows, too small to fill the MXU (a held expert sees 512 rows a step
+in cell 5 against 8192 in the deployment: the cell's cut)."""
 
 from benchmark.lib.flops import roofline_seconds
 from benchmark.lib.mla_moe_counts import expert_products_cost

@@ -1,12 +1,13 @@
 """(token, choice) pairs whose expert is held here, per token and expert
 layer: the rows the grouped products NEED (the step's `rows_here` counter
-over the window). top_k x held / routed = 0.5 under uniform routing at this
-cell's share; what the deployment's other chips compute is the rest of
-top_k. The products compute more: a live chunk of the sorted pairs is
-computed whole (six times the mean share, 3 rows a token and layer:
-`parallel/moe.CHUNK_SHARES`), so today this counter moves the step only
-where a second chunk goes live; it is what a kernel that follows the rows
-will be sized by."""
+over the window). top_k x held / routed under uniform routing (0.5 at a
+share of a sixteenth and top-8); what the deployment's other chips compute
+is the rest of top_k. Since PR 47 the products compute exactly these rows:
+each held expert's group ends at its own last row and the rest of a live
+chunk has no group, so the experts' time follows this counter. The chunk
+(`parallel/moe.CHUNK_SHARES`: six times the mean share) is still what the
+movers, the route's passes and the `silu * up` pass between the products
+walk, and a second chunk going live still adds its own."""
 
 
 def read(m):

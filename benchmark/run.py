@@ -13,14 +13,19 @@ per-layer metric is a file found by the name in `BENCHMARK.json`:
     benchmark/layer_metrics/<metric>.py    read(measured) -> number or None
 
 The last line of standard output is one JSON object: `correct`, `attempted`,
-`failed`, `metrics`, `device`, and with `--trace 1` `breakdown`. With
-`--trace 0` the metrics are the cell's end-to-end metrics, with `--trace 1`
-its per-layer metrics. Earlier lines are a log for people.
+`failed`, `metrics`, `device`, with `--trace 1` `breakdown`, and last
+`compared`: each number `correct` rests on beside its limit (the last lines
+of standard error say the same, one number a line). With `--trace 0` the
+metrics are the cell's end-to-end metrics, with `--trace 1` its per-layer
+metrics. Earlier lines are a log for people.
 
 `--rehearse` (not part of the driver's command) runs the same control flow at
 the tiny shape the workload file gives under `rehearse`, on any backend, and
 prints every metric that is a time or a share of the device as `null`.
 Without it a backend that is not a TPU is a non-zero exit and no result.
+`--unpinned` (the tools' under `benchmark/tools/`, not the driver's) takes
+weights and batches from `--seed` whatever the workload file pins: the
+check's limits were read over many weights, and a control is read so again.
 """
 
 from __future__ import annotations
@@ -38,7 +43,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark.lib.files import load_json, load_module  # noqa: E402
+from benchmark.lib.cells import load_cell  # noqa: E402
+from benchmark.lib.files import load_module  # noqa: E402
 from benchmark.lib.job import Job  # noqa: E402 (after the path)
 
 # sources whose numbers mean something only on the chip
@@ -56,6 +62,10 @@ def parse_args(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--unpinned", action="store_true",
+                    help="not the driver's: take weights and batches from "
+                         "--seed whatever the workload file pins, as the "
+                         "check's limits were read (benchmark/tools/)")
     ap.add_argument("--dump", default=None, metavar="DIR",
                     help="with --trace 1, also write the capture as plain "
                          "JSON there (how the test fixture was recorded)")
@@ -70,13 +80,11 @@ def main(argv=None) -> int:
     if args.workload not in cells:
         raise SystemExit(f"benchmark: {args.workload!r} is not a workload of "
                          f"BENCHMARK.json (has: {sorted(cells)})")
-    workload = load_json("workloads", args.workload + ".json")
-    config = load_json("configs", cells[args.workload]["config"] + ".json")
-    if args.rehearse:
-        tiny = workload["rehearse"]
-        config = {**config, **tiny.get("config", {})}
-        workload = {**workload, **{k: v for k, v in tiny.items()
-                                   if k != "config"}}
+    workload, config = load_cell(args.workload, args.rehearse)
+    if args.unpinned:
+        workload = {k: v for k, v in workload.items() if k != "init_seed"}
+        workload["data"] = {k: v for k, v in workload["data"].items()
+                            if k != "seed"}
     job = Job(T_PROCESS_START, args.workload, workload, config,
               load_module("families", config["family"]), args.seed,
               args.seconds, bool(args.trace), args.rehearse, args.dump)
@@ -104,6 +112,12 @@ def main(argv=None) -> int:
             "device": outcome.device}
     if outcome.breakdown is not None:
         line["breakdown"] = outcome.breakdown
+    if outcome.compared is not None:
+        # each number `correct` rests on beside its limit: the line's last
+        # key, and the last lines of standard error
+        line["compared"] = outcome.compared
+        for name, (value, limit) in outcome.compared.items():
+            print(f"compared {name} {value} limit {limit}", file=sys.stderr)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -3,7 +3,9 @@
 Set-up (everything before the window; `setup_s` is process start to the
 window's first stamp): reach the chip, build the mesh and the program's model
 from the configuration file, make the weights on the device in one jitted
-call from `--seed`, take the loss and gradient norm of the family's plain float32
+call from `--seed` (or from the workload file's `init_seed`, where a cell
+pins its weights: `benchmark/lib/job.init_seed`), take the loss and
+gradient norm of the family's plain float32
 reference on the check batch, make the Adam state, build and compile (or
 load) the program's train step, **check** it (its first call runs on the
 check batch and its own loss and gradient norm are held to the
@@ -49,7 +51,7 @@ import numpy as np
 
 from benchmark.lib import flops, peaks, timing, trace
 from benchmark.lib.files import load_module
-from benchmark.lib.job import Job, Outcome
+from benchmark.lib.job import Job, Outcome, data_seed, init_seed
 
 # The check batch is CHECK_SEQUENCES seeded sequences, which is what the
 # float32 reference of a 36-layer model can hold beside the job. The timed
@@ -97,6 +99,17 @@ def _mean(xs) -> float:
     return sum(xs) / len(xs)
 
 
+def compared(check: dict, first10: float, last10: float,
+             not_finite: int) -> dict:
+    """name -> [number, its limit], for the result's line: each reading of
+    the check beside its tolerance, then what the window adds to `correct`
+    (every loss finite, the last ten under the first ten)."""
+    return {**{k: [v, check["rtol"].get(k)]
+               for k, v in check["rel_err"].items()},
+            "losses_not_finite": [not_finite, 0],
+            "loss_last10": [last10, first10]}
+
+
 def run(job: Job) -> Outcome:
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -139,13 +152,13 @@ def run(job: Job) -> Outcome:
 
     param_sh = model.shardings(mesh)
     params = jax.jit(model.init, out_shardings=param_sh)(
-        jax.random.key(job.seed))
+        jax.random.key(init_seed(job)))
     feed = batch_feeder(mesh)
     mark("weights", params)
 
     batches = load_module("data", w["data"]["kind"]).TokenBatches
     ids, tgt, check_pos = batches(w["data"], family.sizes.vocab,
-                                  CHECK_SEQUENCES, seqlen, job.seed + 1).next()
+                                  CHECK_SEQUENCES, seqlen, data_seed(job) + 1).next()
     want = _reference(family, mesh, params, ids, tgt, check_pos)
     mark("reference")
 
@@ -156,7 +169,8 @@ def run(job: Job) -> Outcome:
                                with_grad_norm=True)
     mark("adam_state", opt_state)
 
-    stream = batches(w["data"], family.sizes.vocab, batch, seqlen, job.seed)
+    stream = batches(w["data"], family.sizes.vocab, batch, seqlen,
+                     data_seed(job))
     pos = feed(stream.next()[2])
     annotate = jax.profiler.TraceAnnotation
 
@@ -261,6 +275,7 @@ def run(job: Job) -> Outcome:
              loss_first10=first10, loss_last10=last10,
              losses_finite=all(finite), loss_fell=falling),
         dict(event="setup", setup_s=setup_s,
+             init_seed=init_seed(job), data_seed=data_seed(job),
              phases_s={phase: t - t_before for (phase, t), t_before in zip(
                  marks, [job.t_process_start] + [t for _, t in marks])},
              compile_cache={"dir": cache_dir, **cache_setup},
@@ -294,7 +309,9 @@ def run(job: Job) -> Outcome:
         peak=peak, peak_bytes=peak_bytes, devices=devs)
     return Outcome(correct=correct, attempted=window.steps,
                    failed=finite.count(False), end_to_end=end_to_end,
-                   measured=measured, device=device, breakdown=breakdown)
+                   measured=measured, device=device, breakdown=breakdown,
+                   compared=compared(check, first10, last10,
+                                     finite.count(False)))
 
 
 def _peak_bytes(stats: dict) -> int:

@@ -63,11 +63,11 @@ import numpy as np
 from benchmark.lib import bd_scopes, peaks, program_trace, timing, trace
 from benchmark.lib.bd_moe_counts import train_flops_per_token
 from benchmark.lib.files import load_module
-from benchmark.lib.job import Job, Outcome
+from benchmark.lib.job import Job, Outcome, data_seed, init_seed
 from benchmark.lib.memory import phase_peak_bytes
 from benchmark.runners.train import (CHECK_SEQUENCES, WARMUP_STEPS,
                                      _compare, _mean, _memory, _no_times,
-                                     log)
+                                     compared, log)
 from benchmark.runners.train_hybrid import GRAD_STRIDE
 
 # What this runner's check holds beside `train`'s two scalars (whose
@@ -160,13 +160,13 @@ def run(job: Job) -> Outcome:
 
     param_sh = model.shardings(mesh)
     params = jax.jit(model.init, out_shardings=param_sh)(
-        jax.random.key(job.seed))
+        jax.random.key(init_seed(job)))
     feed = batch_feeder(mesh)
     mark("weights", params)
 
     batches = load_module("data", w["data"]["kind"]).TokenBatches
     ids, tgt, check_pos = batches(w["data"], sizes.vocab, CHECK_SEQUENCES,
-                                  seqlen, job.seed + 1).next()
+                                  seqlen, data_seed(job) + 1).next()
     # the step's own draw at its first call (step count 0), as arrays
     draw = jax.device_get(jax.jit(family.noise)(0, ids))
     want, want_routed, want_attn_grads = _reference(
@@ -182,7 +182,8 @@ def run(job: Job) -> Outcome:
                                with_grad_norm=True, with_counters=True)
     mark("adam_state", opt_state)
 
-    stream = batches(w["data"], sizes.vocab, batch, seqlen, job.seed)
+    stream = batches(w["data"], sizes.vocab, batch, seqlen,
+                     data_seed(job))
     pos = feed(stream.next()[2])
     annotate = jax.profiler.TraceAnnotation
 
@@ -310,6 +311,7 @@ def run(job: Job) -> Outcome:
                  int(max(c["rows_here"].max() for c in counters))],
              load_max_over_mean=balance),
         dict(event="setup", setup_s=setup_s,
+             init_seed=init_seed(job), data_seed=data_seed(job),
              phases_s={phase: t - t_before for (phase, t), t_before in zip(
                  marks, [job.t_process_start] + [t for _, t in marks])},
              compile_cache={"dir": cache_dir, **cache_setup},
@@ -372,7 +374,9 @@ def run(job: Job) -> Outcome:
         flash_plan=flash_plan)
     return Outcome(correct=correct, attempted=window.steps,
                    failed=finite.count(False), end_to_end=end_to_end,
-                   measured=measured, device=device, breakdown=breakdown)
+                   measured=measured, device=device, breakdown=breakdown,
+                   compared=compared(check, first10, last10,
+                                     finite.count(False)))
 
 
 def _attn_named(tree: dict) -> dict:

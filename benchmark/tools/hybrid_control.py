@@ -84,6 +84,7 @@ def reading(workload: str, seed: int, control=None, rehearse=False) -> dict:
     """The runner's `check` log line for one run of the cell."""
     from benchmark import run
     argv = ["--workload", workload, "--seed", str(seed), "--seconds", "0"]
+    argv.append("--unpinned")      # weights and batches from the seed
     if rehearse:
         argv.append("--rehearse")
     with contextlib.ExitStack() as undo:

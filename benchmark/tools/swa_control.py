@@ -129,6 +129,7 @@ def reading(workload: str, seed: int, control=None, rehearse=False,
     from benchmark import run
     argv = ["--workload", workload, "--seed", str(seed),
             "--seconds", str(seconds), "--trace", str(trace)]
+    argv.append("--unpinned")      # weights and batches from the seed
     if rehearse:
         argv.append("--rehearse")
     with contextlib.ExitStack() as undo:
