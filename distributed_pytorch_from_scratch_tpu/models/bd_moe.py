@@ -152,8 +152,9 @@ class BlockDiffusionMoETransformer(DecoderStack):
         pairs): rows in and out with their cotangents, the outputs and the
         scatter's operand in float32 (twice an element), and the hidden
         activations `[gate | up]`, their product and both cotangents. At a
-        held share of 1/8 the chunk is three quarters of all pairs, 6 rows
-        a row: the chunk is what sizes the step."""
+        held share of 1/8 the chunk is one mean share, an eighth of all
+        pairs, 1 row a row (six shares, 6 rows a row, until PR 50, when
+        the chunk was what sized the step)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.bd_moe.moe_intermediate_size / self.tp_size

@@ -213,7 +213,8 @@ class ConvMoETransformer(DecoderStack):
         bytes); and one chunk of the expert dispatch
         (`SharedRoutedFFN.chunk_share` of a token's pairs: rows in and
         out, and the hidden activations `[gate | up]`, their product and
-        both cotangents). At a held share of 1/4 the chunk is ALL pairs,
+        both cotangents). At a held share of 1/4 the chunk is ALL pairs
+        (under a sixth it is one mean share: `parallel/moe.CHUNK_SHARES`),
         so this is what sizes the step. An attention layer holds less."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k

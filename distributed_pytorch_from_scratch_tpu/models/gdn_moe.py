@@ -131,8 +131,10 @@ class GdnMoETransformer(DecoderStack):
         makes the rule's inputs again (`GatedDeltaNet.apply` keeps it apart
         from the rule's own backward, which runs a sequence at a time and
         holds 2 GB whatever the batch); the full-attention layer holds
-        less. One reading: the benchmark's cell on a v5e counts 13.68 GiB
-        for a step this makes 13.42 (PERF.md section 5, PR 35)."""
+        less. Two readings: the benchmark's cell on a v5e counted 13.68
+        GiB for a step this made 13.42 (PERF.md section 5, PR 35) and, with
+        the chunk one mean share, counts 14.11 for a step this makes 12.88
+        (section 7, PR 50)."""
         gm, gdn, moe = self.cfg.gdn_moe, self._mods["gdn"], self._mods["moe"]
         hk = gm.linear_num_key_heads / self.tp_size
         hv = gm.linear_num_value_heads / self.tp_size

@@ -135,8 +135,10 @@ class LatentMoETransformer(DecoderStack):
         the narrow one, materialised per head (q/k alone are three times d
         here), and one chunk of the expert dispatch (its rows in and out
         and the experts' hidden activations; `SharedRoutedFFN.chunk_share`
-        of a token's pairs). One reading: the benchmark's cell on a v5e counts 14.38
-        GiB for a step this makes 13.99 (PERF.md section 5, PR 33)."""
+        of a token's pairs). Two readings: the benchmark's cell on a v5e
+        counted 14.38 GiB for a step this made 13.99 (PERF.md section 5, PR
+        33) and, with the chunk one mean share, counts 14.23 for a step
+        this makes 13.28 (section 7, PR 50)."""
         lm, moe = self.cfg.latent_moe, self._mods["moe"]
         attention = self.num_local_heads * 2.0 * (lm.qk_head_dim
                                                   + lm.v_head_dim)

@@ -179,8 +179,9 @@ class SlidingWindowMoETransformer(DecoderStack):
         out with their cotangents, the outputs and the scatter's operand in
         float32 (twice an element), and the hidden activations `[gate |
         up]`, their product and both cotangents, beside the shared
-        expert's. At a held share of 1/8 the chunk is three quarters of all
-        pairs, 6 rows a token: the chunk is what sizes the step."""
+        expert's. At a held share of 1/8 the chunk is one mean share, an
+        eighth of all pairs, 1 row a token (six shares, 6 rows a token,
+        until PR 50, when the chunk was what sized the step)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.swa_moe.moe_intermediate_size / self.tp_size

@@ -108,9 +108,10 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # -- ISSUE 33: an expert model's step counters at the log interval
     # (training/metrics.moe_counters_summary): the held experts' load as
     # max over mean, and the rows held here per token and expert layer
-    # beside the rows the grouped products' groups covered (ISSUE 47)
+    # beside the rows the grouped products' groups covered (ISSUE 47) and
+    # the rows of the chunks the dispatch walked (ISSUE 50)
     "moe_counters": ("load_max_over_mean", "rows_here_per_token",
-                     "rows_computed_per_token"),
+                     "rows_computed_per_token", "rows_walked_per_token"),
     # -- ISSUE 37: `train()`'s step function built again after its steady
     # program was in hand (a tail window, a new sequence bucket), at `step`;
     # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)

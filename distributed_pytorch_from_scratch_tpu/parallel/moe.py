@@ -47,6 +47,7 @@ how tokens are sharded (tests assert this).
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, Tuple
@@ -253,6 +254,15 @@ class MoEFFN:
 # 131,072 over 49,152, fifteen of sixteen gathered rows the zero row), so
 # `SharedRoutedFFN.apply` asks the two costs, on its static shapes, a layer
 # at a time, and keeps the scatter-add (its text of before) where they say.
+# Since PR 50 a chunk is one mean share of the pairs or all of them, so
+# the rule says gathers for the one chunk of all pairs and the scatter-add
+# for every chunk of a share, and the same table read again at those
+# chunks agrees by a factor of 1.8 - 2.1, though neither constant is the
+# price there: at M = 16,384 of S k = 131,072 the scatter-add is 1.80 ms
+# (110 ns a row of M; 155 at M = 8,192, 137 at 10,240 of 163,840: a short
+# scatter pays its start-up) and `sum_rows` 3.20 (24 ns a PAIR, fifteen
+# gathered rows in sixteen the clipped zero row), 1.27 against 2.38 and
+# 1.40 against 2.98 (PERF.md section 6, PR 50).
 ROW_GATHER_NS = 48
 ROW_SCATTER_NS = 78
 
@@ -309,12 +319,163 @@ def _take_rows_bwd(res, g):
     return sum_rows(zeros, g, tok, idx, n), None, None, None
 
 
+def take_held(x: jax.Array, tok: jax.Array, valid: jax.Array) -> jax.Array:
+    """`take_rows` where the rows come back by the row scatter-add: plain
+    `x[tok]` with the padding rows SELECTED to zeros."""
+    return jnp.where(valid, jnp.take(x, tok, axis=0), 0)
+
+
+def add_held(y: jax.Array, r: jax.Array, tok: jax.Array, valid: jax.Array
+             ) -> jax.Array:
+    """`sum_rows` as the row scatter-add, and `take_held`'s transpose onto
+    running sums: `y.at[tok].add(r)` over the chunk's HELD rows. A padding
+    row is aimed past `y`'s last row, where a scatter drops it: it is
+    never added, whatever it holds, with no select pass over the rows.
+    (It still costs the scatter its 110 - 155 ns: XLA:TPU walks the M
+    updates whatever their indices, PERF.md section 6, PR 50.)"""
+    at = jnp.where(valid[:, 0], tok, y.shape[0])
+    return y.at[at].add(r, mode="drop")
+
+
 take_rows.defvjp(
     lambda x, tok, idx, n: (take_rows(x, tok, idx, n), (tok, idx, n)),
     _take_rows_bwd)
 sum_rows.defvjp(
     lambda y, r, tok, idx, n: (sum_rows(y, r, tok, idx, n), (tok, idx, n)),
     lambda res, g: (g, take_rows(g, *res), None, None, None))
+
+
+# ---- the sorted dispatch's walk over its chunks (SharedRoutedFFN) ----
+#
+# Chunk c of the sorted pairs is rows [c M, c M + M): its tokens and
+# weights, the rows of each held expert inside it (every group ends at its
+# expert's own last row, so the groups cover the chunk's held rows and
+# nothing more), and which rows are held at all.
+
+
+def chunk_of(c, M: int, token: jax.Array, w_sorted: jax.Array,
+             ends: jax.Array, rows_here: jax.Array):
+    with jax.named_scope("moe_route"):
+        lo = c * M
+        sizes = jnp.diff(jnp.clip(ends - lo, 0, M),
+                         prepend=0).astype(jnp.int32)
+        tok = lax.dynamic_slice_in_dim(token, lo, M)
+        wc = lax.dynamic_slice_in_dim(w_sorted, lo, M)
+        valid = ((lo + jnp.arange(M)) < rows_here)[:, None]
+        return lo, sizes, tok, wc, valid
+
+
+def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
+                 wc: jax.Array, sizes: jax.Array, valid: jax.Array
+                 ) -> jax.Array:
+    """A chunk's (M, d) rows through the held experts' two grouped products
+    and times their weights. The grouped kernels write the rows of their
+    groups and NOTHING ELSE: a row no group holds comes back as whatever
+    the buffer held, from the forward products and from their transposes
+    alike (a 5,000-fold gradient norm on the chip, PR 33; the CPU lowering
+    zero-fills). The rows past the held pairs have NO group, so they are
+    SELECTED away, never multiplied: going in and on the cotangent side by
+    the movers, coming out by `valid` here (the weights' cotangent reads
+    every row of `out`, so the select comes BEFORE the multiply: a
+    weight's cotangent is the row itself). Between the two products they
+    are garbage that nothing reads: a product and its transposes read
+    their groups' rows."""
+    f = down.shape[1]
+    with jax.named_scope("moe_experts"):
+        gu = lax.ragged_dot(rows, gate_up, sizes)
+        out = lax.ragged_dot(jax.nn.silu(gu[:, :f]) * gu[:, f:], down, sizes)
+    with jax.named_scope("moe_route"):
+        return jnp.where(valid, out, 0) * wc[:, None].astype(out.dtype)
+
+
+def _like(a: jax.Array) -> jax.Array:
+    """Zeros that vary over the mesh axes `a` varies over: a loop's carry."""
+    vma = tuple(jax.typeof(a).vma)
+    return copy_to(jnp.zeros_like(a), vma) if vma else jnp.zeros_like(a)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def walk_chunks(M: int, xd: jax.Array, gate_up: jax.Array, down: jax.Array,
+                w_sorted: jax.Array, token: jax.Array, ends: jax.Array,
+                rows_here: jax.Array):
+    """The chunks of a share: rows in by `take_held`, through
+    `held_experts`, back by `add_held`, chunk after chunk UP TO
+    THE LAST HELD ROW: a loop of `ceil(rows_here / M)` steps, so a chunk
+    past the held rows costs nothing, forward or backward. Returns the
+    (S, d) sums, the rows the groups covered and the rows walked (M a
+    step). The float operands vary over the same mesh axes (the caller
+    casts them), so no collective runs inside a loop whose length differs
+    between data shards.
+
+    The transpose is written out, because autodiff's is what a fine chunk
+    cannot afford: a `scan` over ALL the chunks whose every step, live or
+    skipped by a `cond`, adds a chunk's cotangents of `gate_up`, `down`
+    and the input (zeros, for a skipped one) to the running sums: 1.6 -
+    2.2 ms a chunk and layer on a v5e at cells 5, 8 and 9's shapes,
+    whatever the chunk holds (PERF.md section 6, PR 50). Here the sums
+    are the loop's carry: the rows' cotangents are scatter-added straight
+    into the input's, a live chunk's weight cotangents are added once,
+    and there is no other chunk."""
+    n_live = -(-rows_here // M)
+
+    def body(carry):
+        c, y, computed = carry
+        _, sizes, tok, wc, valid = chunk_of(c, M, token, w_sorted, ends,
+                                            rows_here)
+        with jax.named_scope("moe_route"):
+            rows = take_held(xd, tok, valid)
+        out = held_experts(rows, gate_up, down, wc, sizes, valid)
+        with jax.named_scope("moe_route"):
+            y = add_held(y, out.astype(y.dtype), tok, valid)
+        return c + 1, y, computed + jnp.sum(sizes)
+
+    zero = rows_here * 0
+    c, y, computed = lax.while_loop(lambda carry: carry[0] < n_live, body,
+                                    (zero, _like(xd), zero))
+    return y, computed, c * M
+
+
+def _walk_chunks_fwd(M, xd, gate_up, down, w_sorted, token, ends, rows_here):
+    return (walk_chunks(M, xd, gate_up, down, w_sorted, token, ends,
+                        rows_here),
+            (xd, gate_up, down, w_sorted, token, ends, rows_here))
+
+
+def _walk_chunks_bwd(M, res, g):
+    xd, gate_up, down, w_sorted, token, ends, rows_here = res
+    d_y = g[0]
+    n_live = -(-rows_here // M)
+
+    def body(carry):
+        c, d_x, d_gate_up, d_down, d_w = carry
+        lo, sizes, tok, wc, valid = chunk_of(c, M, token, w_sorted, ends,
+                                             rows_here)
+        with jax.named_scope("moe_route"):
+            # the chunk's rows again (nothing of a chunk is kept), and the
+            # scatter-add's transpose: the sums' cotangent at its tokens
+            rows = take_held(xd, tok, valid)
+            d_out = jnp.take(d_y, tok, axis=0)
+        _, pull = jax.vjp(
+            lambda rows, gate_up, down, wc: held_experts(
+                rows, gate_up, down, wc, sizes, valid).astype(d_y.dtype),
+            rows, gate_up, down, wc)
+        d_rows, d_gu, d_dn, d_wc = pull(d_out)
+        with jax.named_scope("moe_route"):
+            # `take_held`'s transpose, onto the running sum
+            d_x = add_held(d_x, d_rows, tok, valid)
+            d_w = lax.dynamic_update_slice_in_dim(d_w, d_wc, lo, 0)
+        with jax.named_scope("moe_experts"):
+            d_gate_up, d_down = d_gate_up + d_gu, d_down + d_dn
+        return c + 1, d_x, d_gate_up, d_down, d_w
+
+    _, d_x, d_gate_up, d_down, d_w = lax.while_loop(
+        lambda carry: carry[0] < n_live, body,
+        (rows_here * 0, _like(xd), _like(gate_up), _like(down),
+         _like(w_sorted)))
+    return d_x, d_gate_up, d_down, d_w, None, None, None
+
+
+walk_chunks.defvjp(_walk_chunks_fwd, _walk_chunks_bwd)
 
 
 # ---- the sorted dispatch's index work (SharedRoutedFFN) ----
@@ -373,17 +534,40 @@ def _sort_pairs_bwd(order, g):
 sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
-# A chunk of `SharedRoutedFFN`'s sorted pairs holds this many times the
-# job's mean share of them (its rows move by the movers above: gathers both
-# ways at a share of an eighth or more, `ROW_GATHER_NS` / `ROW_SCATTER_NS`).
-# One reading set it, not a law: Zipf ids through
-# a freshly initialised router on a v5e, where no step's held rows passed
-# 5.4 times the mean share and a first chunk of 4 shares was crossed in a
-# tenth of one run's steps in twelve (PERF.md section 6, PR 33). It is
-# MEMORY (the chunk's rows in and out, and the movers' and the route's
-# passes over them) and the unit the `cond` skips by; the grouped products'
-# time follows the held rows, not the chunk: see the class docstring.
-CHUNK_SHARES = 6
+# A chunk of `SharedRoutedFFN`'s sorted pairs: the unit of the layer's WORK
+# and of its MEMORY. Every mover and pass of a live chunk (the `x[tok]`
+# gather, the selects, the weights' multiply, `silu * up` between the two
+# grouped products, the row scatter-add, and the transposes of them all)
+# walks the chunk's M rows whatever it holds, and the walk stops at the
+# last held row (`walk_chunks`); the grouped products alone follow the held
+# rows inside a chunk (the class docstring). So a layer pays for
+# `M * ceil(rows_here / M)` rows (its `rows_walked` counter), and the
+# chunk is `CHUNK_SHARES` times the job's mean share of the pairs. A finer
+# grain walks less padding (about M / 2 a layer) and makes the step's
+# staircase in a layer's held rows finer, at the price of more live chunks,
+# each of which adds its cotangents of `gate_up` and `down` (whole, zeros
+# for the experts it does not reach) to the running sums and calls the
+# grouped kernel on fewer rows. One reading set it, not a law (TPU v5
+# lite, 15 s windows at one data seed, `step_ms_p90` at 1 / 0.5 / 0.25 of
+# a share against 707.8 / 625.9 / 705.1 at six shares: PERF.md section 6,
+# PR 50): cell 9 559.1 / 554.9 / 586.5 (its balanced router holds 1.05
+# shares a layer, so a second chunk of one share is nearly empty), cell 8
+# 493.9 / 510.0 / 561.5, cell 5 632.5 / 655.6 / 667.9; `rows_walked /
+# rows_here` 1.75 / 1.34 / 1.15, 1.45 / 1.23 / 1.10, 1.46 / 1.17 / 1.10.
+# With autodiff's transpose (a `scan` over ALL the chunks under a `cond`)
+# the same three grains read 628.7 / 680.1 / 822.2, 574.6 / 667.4 / 846.1
+# and 775.4 / 909.6 / 1129.5: there every chunk, live or skipped, cost 1.6
+# - 2.2 ms a layer, which is why the walk's transpose is written by hand.
+CHUNK_SHARES = 1
+# Where the job holds a sixth of the experts or more (this many mean shares
+# are all the pairs) the chunk is ALL the pairs and runs with no `cond`: the
+# boundary the policy had when a chunk was six shares, kept as a rule on the
+# static share. A `cond` around that one chunk read +25 ms a step and +1.0
+# GiB (PERF.md section 6, PR 39), and the held rows there are a third of
+# the pairs or more, so there is less padding to skip. Its rows move by the
+# gathers (`ROW_GATHER_NS` / `ROW_SCATTER_NS` above: one rule on static
+# shapes, which picks the row scatter-add for every chunk of a share).
+WHOLE_FROM_SHARES = 6
 
 
 @dataclass(frozen=True)
@@ -424,30 +608,39 @@ class SharedRoutedFFN:
     follows the group sizes). The sort is a permutation and the layer
     keeps both directions of it: `order` (the pair of a sorted row) and
     its inverse `pos` (the sorted row of a pair, from a prefix sum over a
-    one-hot of the keys). Rows go in by a gather (`take_rows`: `x[tok]`,
-    zeros in the padding rows) and come back by `sum_rows`: k row gathers
-    a token through `pos`, summed in float32, where the pairs are under
-    1.6 times the chunk's rows (a held share of an eighth or more), the
-    row scatter-add `y.at[tok].add` where they are more (a sixteenth);
-    each mover is the other's transpose by `jax.custom_vjp`, so the
-    backward moves rows the same way and reads no padding row's cotangent
-    (`ROW_GATHER_NS` / `ROW_SCATTER_NS`, above `CHUNK_SHARES`: one rule
-    on static shapes, measured). The sorted pairs are walked in chunks
-    (`chunk_rows`) under one `lax.scan`; a chunk past the last held row is
-    skipped by a `lax.cond` (where there are several: a chunk of ALL the
-    pairs runs without one), so memory follows the chunk, while every pair
-    that exists is computed whatever the routing (tests force all tokens
-    onto a few experts). **The products follow the rows**: each held
+    one-hot of the keys; made only where the gathers want it). The sorted
+    pairs are walked in chunks (`chunk_rows`), and a chunk is the unit of
+    the layer's WORK and of its MEMORY (above `CHUNK_SHARES`): where under
+    a sixth of the experts are held it is the job's mean share of the
+    pairs and the walk is a loop that STOPS at the last held row
+    (`walk_chunks`, forward and its hand-written transpose), so the
+    movers, the selects, the weights' multiply, `silu * up` and their
+    transposes walk `M * ceil(rows_here / M)` rows (`rows_walked`) and a
+    chunk past the held rows costs nothing; where a sixth or more are held
+    (`WHOLE_FROM_SHARES`) the one chunk is ALL the pairs, a `scan` of one
+    step with no `cond`. Every pair that exists is computed whatever the
+    routing (tests force all tokens onto a few experts).
+    Rows go in by a gather and come back by one of two movers, picked by
+    one rule on static shapes (`ROW_GATHER_NS` / `ROW_SCATTER_NS`,
+    measured): in a chunk of a share by `take_held` (`x[tok]`, zeros
+    SELECTED into the padding rows) and `add_held` (the row scatter-add
+    `y.at[tok].add`), which costs by the chunk's M rows where gathers
+    through `pos` cost by all S k pairs a live chunk; in the one chunk of
+    all the pairs by `take_rows` and `sum_rows`: k row gathers a token
+    through `pos`, summed in float32, each mover the other's transpose by
+    `jax.custom_vjp`, so the backward moves rows the same way and reads no
+    padding row's cotangent. **The products follow the rows**: each held
     expert's group ends at its own last row, the rows of a live chunk past
     its last held pair belong to NO group, and XLA:TPU's grouped kernel
     walks the groups it is given, so the products' time is the held rows'
-    (at a held share of an eighth a chunk is 98,304 rows for some 20,000
-    held: handed whole, four rows in five were zeros and the products ran
-    at 6 - 8% of their roofline, PERF.md section 6, PR 47). The chunk is
-    MEMORY and the unit the `cond` skips by. What that makes load-bearing:
+    (PERF.md section 6, PR 47; before PR 50 a chunk was six mean shares,
+    98,304 rows for some 20,000 held at a share of an eighth, and
+    everything but the products walked them all: PR 50). What that makes
+    load-bearing:
     a row no group holds comes back from a product AND from its transposes
     as whatever the buffer held, so every such row is selected, never
-    multiplied, on both sides of the products (`live`, below). The step's
+    multiplied, on both sides of the products (`held_experts` and the
+    movers around it). The step's
     time now follows the routing, seed by seed, where nothing balances the
     router (PR 33 read 1.2 - 2.3% between seeds).
 
@@ -592,10 +785,12 @@ class SharedRoutedFFN:
     @property
     def chunk_share(self) -> float:
         """The part of the (token, choice) pairs one chunk holds:
-        `CHUNK_SHARES` times this job's mean share of them, at most all
-        (the family sizes the dispatch's buffers from it for
-        `training/memory.py`)."""
-        return min(1.0, CHUNK_SHARES * self.num_held / self.num_experts)
+        `CHUNK_SHARES` times this job's mean share of them, and all of
+        them where `WHOLE_FROM_SHARES` shares are (the family sizes the
+        dispatch's buffers from it for `training/memory.py`)."""
+        if WHOLE_FROM_SHARES * self.num_held >= self.num_experts:
+            return 1.0
+        return CHUNK_SHARES * self.num_held / self.num_experts
 
     def chunk_rows(self, pairs: int) -> int:
         """Rows a chunk of `pairs` sorted pairs holds: `chunk_share` of
@@ -615,7 +810,10 @@ class SharedRoutedFFN:
         for, `rows_here` the pairs whose expert is held, `rows_computed`
         the rows of the groups the grouped products were handed, over the
         chunks (the held rows: the counter says so of the program that
-        ran), all float32 and local to this shard."""
+        ran), `rows_walked` the rows of the chunks whose body ran (what
+        the movers and the passes paid for: `M * ceil(rows_here / M)`,
+        and M where the one chunk is all the pairs), all float32 and
+        local to this shard."""
         b, t, d = x.shape
         S, k = b * t, self.top_k
         xf = x.reshape(S, d)
@@ -638,78 +836,52 @@ class SharedRoutedFFN:
         if chunks * M > S * k:        # the last chunk runs past the pairs
             token = jnp.pad(token, (0, chunks * M - S * k))
             w_sorted = jnp.pad(w_sorted, (0, chunks * M - S * k))
-        f = params["gate"].shape[-1]                  # local expert width
         # gate and up as one grouped product: one pass over the rows
         gate_up = jnp.concatenate([params["gate"], params["up"]],
                                   axis=-1).astype(compute_dtype)
         down = params["down"].astype(compute_dtype)
+        if gathers:
+            def chunk(y, c):
+                lo, sizes, tok, wc, valid = chunk_of(c, M, token, w_sorted,
+                                                     ends, rows_here)
 
-        def chunk(y, c):
-            lo = c * M
-            with jax.named_scope("moe_route"):
-                # rows of each held expert inside [lo, lo + M): every
-                # group ends at its expert's own last row, so the groups
-                # cover the chunk's held rows and nothing more
-                sizes = jnp.diff(jnp.clip(ends - lo, 0, M),
-                                 prepend=0).astype(jnp.int32)
-
-            def live(y):
-                with jax.named_scope("moe_route"):
-                    tok = lax.dynamic_slice_in_dim(token, lo, M)
-                    wc = lax.dynamic_slice_in_dim(w_sorted, lo, M)
-                    valid = ((lo + jnp.arange(M)) < rows_here)[:, None]
-                    # The grouped kernels write the rows of their groups
-                    # and NOTHING ELSE: a row no group holds comes back as
-                    # whatever the buffer held, from the forward products
-                    # and from their transposes alike (a 5,000-fold
-                    # gradient norm on the chip, PR 33; the CPU lowering
-                    # zero-fills). The rows past the held pairs have NO
-                    # group, so they are SELECTED away, never multiplied:
-                    # going in and on the cotangent side by the movers or
-                    # by `valid`, coming out by `valid` (the weights'
-                    # cotangent reads every row of `out`). Between the two
-                    # products they are garbage that nothing reads: a
-                    # product and its transposes read their groups' rows.
-                    if gathers:
+                def live(y):
+                    with jax.named_scope("moe_route"):
                         # a held pair whose row is in this chunk; every
                         # other reads the zero row
                         n, at = rows_here - lo, pos - lo
                         idx = jnp.where(
                             (at >= 0) & (at < jnp.minimum(n, M)), at, M)
                         rows = take_rows(xd, tok, idx, n)
-                    else:
-                        rows = jnp.where(valid, jnp.take(xd, tok, axis=0), 0)
-                with jax.named_scope("moe_experts"):
-                    gu = lax.ragged_dot(rows, gate_up, sizes)
-                    out = lax.ragged_dot(
-                        jax.nn.silu(gu[:, :f]) * gu[:, f:], down, sizes)
-                with jax.named_scope("moe_route"):
-                    # select BEFORE the weights multiply: a weight's
-                    # cotangent is the row itself
-                    out = (jnp.where(valid, out, 0)
-                           * wc[:, None].astype(out.dtype))
-                    out = out.astype(y.dtype)
-                    if gathers:
-                        return sum_rows(y, out, tok, idx, n)
-                    return y.at[tok].add(out)
+                    out = held_experts(rows, gate_up, down, wc, sizes, valid)
+                    with jax.named_scope("moe_route"):
+                        return sum_rows(y, out.astype(y.dtype), tok, idx, n)
 
-            if chunks == 1:
-                # the one chunk is ALL the pairs (a held share of a sixth
-                # or more): there is no later chunk to skip to, and a layer
-                # whose held experts got nothing this step runs its
-                # products over zero groups (a `cond` around the one chunk
-                # cost 25 ms a step and 1.0 GiB, PERF.md section 6, PR 39)
-                return live(y), jnp.sum(sizes)
-            return (lax.cond(lo < rows_here, live, lambda y: y, y),
-                    jnp.sum(sizes))
+                if chunks == 1:
+                    # the one chunk is ALL the pairs (`WHOLE_FROM_SHARES`: a
+                    # held share of a sixth or more): none to skip to, and a
+                    # layer whose held experts got nothing this step runs
+                    # its products over zero groups (a `cond` around the one
+                    # chunk cost 25 ms a step and 1.0 GiB, PERF.md section
+                    # 6, PR 39)
+                    return live(y), (jnp.sum(sizes), jnp.int32(M))
+                return (lax.cond(lo < rows_here, live, lambda y: y, y),
+                        (jnp.sum(sizes), jnp.where(lo < rows_here, M, 0)))
 
-        # the carry varies over what the rows vary over (batch axes and tp)
-        vma = tuple(jax.typeof(xd).vma)
-        y = jnp.zeros((S, d), compute_dtype)
-        y = copy_to(y, vma) if vma else y
-        y, computed = lax.scan(jax.checkpoint(chunk), y,
-                               jnp.arange(chunks, dtype=jnp.int32))
-        counters["rows_computed"] = jnp.sum(computed).astype(jnp.float32)
+            y, (computed, walked) = lax.scan(
+                jax.checkpoint(chunk), _like(xd),
+                jnp.arange(chunks, dtype=jnp.int32))
+            computed, walked = jnp.sum(computed), jnp.sum(walked)
+        else:
+            # one set of mesh axes for the walk's float operands: the
+            # rows' (batch axes and tp)
+            vma = tuple(jax.typeof(xd).vma)
+            vary = lambda a: copy_to(a, vma) if vma else a
+            y, computed, walked = walk_chunks(
+                M, xd, vary(gate_up), vary(down), vary(w_sorted), token, ends,
+                rows_here)
+        counters["rows_computed"] = computed.astype(jnp.float32)
+        counters["rows_walked"] = walked.astype(jnp.float32)
 
         if self.n_shared:
             with jax.named_scope("moe_shared"):

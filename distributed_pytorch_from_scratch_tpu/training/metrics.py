@@ -168,7 +168,9 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     rows routed to the held experts per token and expert layer (`top_k x
     held / routed` under uniform routing; per DATA token, so twice that,
     in the bd_moe family, whose layers see two rows a token) beside the
-    rows the grouped products' groups covered (the same), and the held
+    rows the grouped products' groups covered (the same) and the rows the
+    dispatch's movers and passes walked (whole chunks: over `rows_here` it
+    is the padding they still pay), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
     balance); and, where the step ran the selection bias's rule, the mean
     size of a bias entry's step (`router_bias_step`)."""
@@ -179,7 +181,7 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     out = {"loss_main": float(counters["loss_main"])}
     if "loss_mtp" in counters:
         out["loss_mtp"] = float(counters["loss_mtp"])
-    for name in ("rows_here", "rows_computed"):
+    for name in ("rows_here", "rows_computed", "rows_walked"):
         out[f"{name}_per_token"] = float(
             np.mean(counters[name])) / max(tokens, 1)
     out["load_max_over_mean"] = float(np.mean(

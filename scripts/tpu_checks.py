@@ -151,7 +151,7 @@ def grouped_rows_check(interp: bool, dtype, tol: float):
     no other: NaN in every row past the groups, of the left side and of
     the cotangent, reaches neither the groups' rows nor the weights'
     gradient (what `SharedRoutedFFN` leaves between its two products)."""
-    M, d, f, H = (512, 32, 16, 4) if interp else (98304, 2048, 1536, 16)
+    M, d, f, H = (512, 32, 16, 4) if interp else (16384, 2048, 1536, 16)
     sizes = jnp.full((H,), M // (5 * H), jnp.int32)
     inside = (jnp.arange(M) < jnp.sum(sizes))[:, None]
     keys = jax.random.split(jax.random.key(21), 3)
@@ -179,12 +179,13 @@ def grouped_rows_check(interp: bool, dtype, tol: float):
 
 def expert_layer_checks(interp: bool, dtype, tol: float):
     """The layer at cells 8 and 9's shape (16,384 tokens of 2048, top-8 of
-    128 experts, 16 held: a chunk of 98,304 sorted rows of which a fifth
-    or so are held and the rest have no group) against
-    `one_expert_at_a_time`: the output, and the gradient of every leaf and
-    of the input. Then with the movers' selects taken out, which lets the
-    rows no group holds into the input's gradient: that one must DIFFER,
-    or this check would not see what the selects keep out."""
+    128 experts, 16 held: eight chunks of 16,384 sorted rows, of which the
+    first one or two are live and the last live one ends in rows no
+    group holds) against `one_expert_at_a_time`: the output, and the
+    gradient of every leaf and of the input. Then with the movers' selects
+    taken out, which lets the rows no group holds into the input's
+    gradient: that one must DIFFER, or this check would not see what the
+    selects keep out."""
     from jax.sharding import PartitionSpec as P
     from distributed_pytorch_from_scratch_tpu.config import MeshConfig
     from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
@@ -196,7 +197,7 @@ def expert_layer_checks(interp: bool, dtype, tol: float):
     x = jax.random.normal(jax.random.key(12), (b, t, d), jnp.float32)
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
     held = int(jnp.sum(moe.route(params, x.reshape(-1, d))[0] < H))
-    shape = f"{held} held rows of a chunk of {moe.chunk_rows(b * t * k)}"
+    shape = f"{held} held rows in chunks of {moe.chunk_rows(b * t * k)}"
 
     def layer(params, x):
         return jax.shard_map(
@@ -224,13 +225,19 @@ def expert_layer_checks(interp: bool, dtype, tol: float):
                tol * float(jnp.max(jnp.abs(a))), secs)
     if interp:      # the CPU lowering zero-fills: nothing there to see
         return
-    selected = moe_mod.take_rows, moe_mod.sum_rows
+    # whichever movers the layer's shape rule picks (chunks of a share:
+    # `take_held` / `add_held`)
+    movers = ("take_rows", "sum_rows", "take_held", "add_held")
+    selected = [getattr(moe_mod, name) for name in movers]
     moe_mod.take_rows = lambda x, tok, idx, n: jnp.take(x, tok, axis=0)
     moe_mod.sum_rows = lambda y, r, tok, idx, n: y.at[tok].add(r)
+    moe_mod.take_held = lambda x, tok, valid: jnp.take(x, tok, axis=0)
+    moe_mod.add_held = lambda y, r, tok, valid: y.at[tok].add(r)
     try:
         got, secs = run(layer)
     finally:
-        moe_mod.take_rows, moe_mod.sum_rows = selected
+        for name, mover in zip(movers, selected):
+            setattr(moe_mod, name, mover)
     record("expert layer, the movers' selects out: d_x MUST differ",
            max_err(got["d_x"], want["d_x"]),
            tol * float(jnp.max(jnp.abs(want["d_x"]))), secs, must_differ=True)

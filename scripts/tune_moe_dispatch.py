@@ -1,9 +1,10 @@
 """Time the row movers of `parallel/moe.SharedRoutedFFN`'s sorted dispatch
 alone on the attached TPU chip, at the expert cells' shapes (S = 16,384
 tokens of d = 2048 in bf16; k choices, E routed experts of which H are held,
-so a chunk of M = `chunk_rows(S k)` sorted rows):
+so a chunk of M = `chunk_rows(S k)` sorted rows: one mean share of the
+pairs in cells 5, 6, 8 and 9 since PR 50, all of them in cell 7):
 
-    python scripts/tune_moe_dispatch.py [--cells 5,6,7,8] [--check]
+    python scripts/tune_moe_dispatch.py [--cells 5,6,7,8,9] [--check]
         [--forms rows|index|all]
 
 prints, a cell, device milliseconds from a profiler capture (the union of
@@ -45,7 +46,8 @@ columns compare):
 
 Each cell runs in a child process with a timeout (the parent touches no
 JAX: a chip belongs to one process). The table behind `parallel/moe.py`'s
-choice is PERF.md's (section 6, PRs 42 and 43; TPU v5 lite).
+choice is PERF.md's (section 6, PRs 42 and 43, and PR 50 at chunks of a
+share or less; TPU v5 lite).
 """
 
 import argparse
@@ -58,7 +60,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 # cell: (top_k, routed experts, experts held) of BENCHMARK.json's cells
-CELLS = {5: (8, 256, 16), 6: (10, 512, 32), 7: (4, 32, 8), 8: (8, 128, 16)}
+CELLS = {5: (8, 256, 16), 6: (10, 512, 32), 7: (4, 32, 8), 8: (8, 128, 16),
+         9: (8, 128, 16)}
 
 
 def routing(s, k, experts, held, seed):

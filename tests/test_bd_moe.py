@@ -508,6 +508,8 @@ def test_the_train_step_trains_and_counts_rows_and_masked_positions():
     said = moe_counters_summary(c, cfg, 4 * 64)
     assert 1.0 < said["rows_here_per_token"] < 3.0      # per DATA token
     assert said["rows_computed_per_token"] == said["rows_here_per_token"]
+    # whole chunks: never fewer rows than are held
+    assert said["rows_walked_per_token"] >= said["rows_here_per_token"]
     assert model_flops_per_step(cfg, 4, 64, model.num_params(cfg)) > 0
 
 
@@ -526,6 +528,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "model[bd_moe]" in out and "rows_here_per_token" in out
     assert "rows_computed_per_token" in out
+    assert "rows_walked_per_token" in out
     assert "masked_share" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
@@ -539,16 +542,16 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
 
 
 def test_the_memory_facts_count_the_rows_the_chunk_and_half_the_logits():
-    """At a held share of 1/8 the chunk is three quarters of all pairs;
-    the head reads half of the rows the stack sees."""
+    """At a held share of 1/8 the chunk is one mean share, an eighth of
+    all pairs; the head reads half of the rows the stack sees."""
     from distributed_pytorch_from_scratch_tpu.training.memory import (
         step_bytes)
     eighth = build_model("bd_moe", tiny(experts_held=1))
     moe = eighth._mods["moe"]
-    assert moe.chunk_share == 6 * 1 / 8
-    assert moe.chunk_rows(131072) == 98304
+    assert moe.chunk_share == 1 / 8
+    assert moe.chunk_rows(131072) == 16384
     attn = 5 * 4 * 32 + 6 * 2 * 32 - 2 * 64
-    assert eighth.layer_extra_elems_per_token == attn + 0.75 * 2 * (
+    assert eighth.layer_extra_elems_per_token == attn + 0.125 * 2 * (
         6 * 64 + 5 * 32)
     assert (eighth.head_dim, eighth.kv_dim) == (32, 64)
     assert eighth.head_rows_share == 0.5 and eighth.draws_noise
@@ -562,9 +565,9 @@ def test_the_memory_facts_count_the_rows_the_chunk_and_half_the_logits():
 
 LOWERED_BEFORE = {"llama": ("tiny", "14bb75356a403459"),
                   "gpt2": ("tiny", "557e9d12313622a3"),
-                  "mla_moe": ("tiny-mla-moe", "d720c08462d95787"),
-                  "gdn_moe": ("tiny-gdn-moe", "22688b83258d3e80"),
-                  "conv_moe": ("tiny-conv-moe", "6216bbf972ffa7f1")}
+                  "mla_moe": ("tiny-mla-moe", "079ae8e4c6b05747"),
+                  "gdn_moe": ("tiny-gdn-moe", "f32e06a3ba75f3b4"),
+                  "conv_moe": ("tiny-conv-moe", "7bd8e57be282b8e0")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))
@@ -580,7 +583,9 @@ def test_the_mask_declaration_left_the_other_families_text_alone(family):
     families' (the dispatch's row movers and the inverse permutation)
     and so did PR 43 (its index work without a scalar gather or scatter)
     and PR 47 (the grouped products' groups end at the held rows, and the
-    layer counts `rows_computed`); `llama` and `gpt2`, which run no expert
+    layer counts `rows_computed`) and PR 50 (the layer counts `rows_walked`;
+    every tiny preset holds all its experts, so its one chunk is all the
+    pairs as before); `llama` and `gpt2`, which run no expert
     layer, stand as PR 40 left them."""
     preset, digest = LOWERED_BEFORE[family]
     cfg = model_preset(preset)

@@ -457,6 +457,8 @@ def test_the_train_step_trains_and_counts_a_row_an_expert_layer():
     said = moe_counters_summary(counters, cfg, 2 * 64)
     assert 0.3 < said["rows_here_per_token"] < 2.0
     assert said["rows_computed_per_token"] == said["rows_here_per_token"]
+    # whole chunks: never fewer rows than are held
+    assert said["rows_walked_per_token"] >= said["rows_here_per_token"]
     assert model_flops_per_step(cfg, 2, 64, model.num_params(cfg)) > 0
 
 
@@ -475,6 +477,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "model[conv_moe]" in out and "rows_here_per_token" in out
     assert "rows_computed_per_token" in out
+    assert "rows_walked_per_token" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
     assert any(e.get("tag") == "moe_counters" for e in events)
@@ -491,17 +494,17 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
 def test_the_whole_chunk_is_what_the_memory_facts_count():
     """At a held share of 1/4 the dispatch's one chunk is ALL pairs
     (`chunk_share` 1): `layer_extra_elems_per_token` sizes its buffers by
-    top_k rows a token, at 1/16 by 6/16 of them."""
+    top_k rows a token, at 1/16 by one mean share, 1/16, of them."""
     quarter = build_model("conv_moe", tiny(experts_held=2))
     sixteenth = build_model("conv_moe", dataclasses.replace(
         tiny(experts_held=2), num_experts=32))
     assert quarter._mods["moe"].chunk_share == 1.0
-    assert sixteenth._mods["moe"].chunk_share == 6 * 2 / 32
+    assert sixteenth._mods["moe"].chunk_share == 2 / 32
     assert quarter._mods["moe"].chunk_rows(4096) == 4096
     conv = 12.0 * 64
     rows = lambda m: (m.layer_extra_elems_per_token - conv) / (
         2 * 64 + 5 * 32)
-    assert rows(quarter) == 2.0 and rows(sixteenth) == 2 * 6 * 2 / 32
+    assert rows(quarter) == 2.0 and rows(sixteenth) == 2 * 2 / 32
     assert quarter.ffn_inputs == 2 and quarter.tied_head
     assert quarter.stacked_layers == 12
 
@@ -509,8 +512,8 @@ def test_the_whole_chunk_is_what_the_memory_facts_count():
 # ---- the other pattern families lower to what they lowered to ----
 
 LOWERED_BEFORE = {"mla_moe": ("tiny-mla-moe", "latent_moe",
-                              "75d13ca8f48b85a3"),
-                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "a9212bf2bfaaba29")}
+                              "e547079c031048c1"),
+                  "gdn_moe": ("tiny-gdn-moe", "gdn_moe", "50273c3169523b0e")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))
@@ -518,8 +521,10 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     """`mla_moe`'s two segments and `gdn_moe`'s one period are the one
     declaration (`DecoderStack._pattern`) and lower to the StableHLO they
     lowered to at the commit before it (PR 38's tree; locations stripped;
-    sha256, first 16 digits), at a shape whose dispatch walks TWO chunks as
-    cells 5 and 6 walk three (one expert of eight held, 4 x 256 tokens): a
+    sha256, first 16 digits), at a shape whose dispatch walks SEVERAL
+    chunks, as cells 5 and 6 do (one expert of eight held, 4 x 256 tokens:
+    a loop over up to four chunks of 512 rows since PR 50, a `scan` over two
+    under a `cond` before it): a
     dispatch of ONE chunk, which only the fifth family's cell has, lost its
     `lax.cond` in PR 39. The optimised HLO of both tiny presets was
     compared once, parent and change, and was the same (PR 39). A PR that
@@ -527,7 +532,9 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     PR 42 did (the dispatch's row movers and the inverse permutation)
     and PR 43 (its index work without a scalar gather or scatter) and PR
     47 (the grouped products' groups end at the held rows; `rows_computed`
-    beside `rows_here`)."""
+    beside `rows_here`) and PR 50 (a chunk is one mean share of the pairs
+    where under a sixth of the experts are held, walked by `walk_chunks` up
+    to the last held row; `rows_walked`)."""
     preset, facts, digest = LOWERED_BEFORE[family]
     cfg = model_preset(preset)
     cfg = dataclasses.replace(cfg, **{facts: dataclasses.replace(
@@ -546,27 +553,84 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
+# cell 7's rehearsal shape (benchmark/workloads/lfm2-8b-a1b...: `rehearse`),
+# three steps of the parent's tree (PR 49) on the CPU, run side by side
+# with PR 50's: loss and gradient norm, the same to the bit
+PARENTS_THREE_STEPS = [("0x1.8f1b4c0000000p+2", "0x1.c6cb860000000p+1"),
+                       ("0x1.9031100000000p+2", "0x1.0395da0000000p+2"),
+                       ("0x1.8eb4e20000000p+2", "0x1.9b247a0000000p+1")]
+
+
+def test_the_quarter_share_cells_steps_are_the_parents_whatever_the_grain(
+        monkeypatch):
+    """Cell 7 holds a quarter of its experts: its one chunk is ALL the
+    pairs with no `cond`, the text the layer had before chunks were a
+    share or less, so three steps at its rehearsal shape (float32) read
+    the losses and gradient norms the parent's tree read (side by side on
+    one machine they were equal to the bit; held here to a float32's last
+    digits, since another CPU may order a product's sums otherwise), and
+    read the SAME BITS under the grain the policy had (six shares) and
+    under a quarter of a share: no grain reaches this shape."""
+    from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
+
+    cfg = ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_kv_heads=2, num_layers=5,
+        vocab_size=503, maxlen=64, rope_theta=1e6, compute_dtype="float32",
+        num_experts=8, moe_top_k=2, conv_moe=ConvMoEConfig(
+            layer_types=("conv", "full_attention", "conv", "conv", "conv"),
+            moe_intermediate_size=32, num_dense_layers=1, experts_held=2))
+
+    def three_steps():
+        mesh, model = on_mesh(cfg, 1)
+        moe = model._mods["moe"]
+        assert moe.chunk_share == 1.0 and moe.chunk_rows(256) == 256
+        params = model.init(jax.random.key(0))
+        opt = init_adam_state(params)
+        step = build_train_step(model, mesh, OptimizerConfig(),
+                                with_grad_norm=True, with_counters=True)
+        rng, read = np.random.default_rng(0), []
+        for _ in range(3):
+            ids = rng.integers(0, cfg.vocab_size, (2, 65)).astype(np.int32)
+            pos = np.tile(np.arange(64, dtype=np.int32), (2, 1))
+            params, opt, (loss, gnorm, c) = step(params, opt, ids[:, :-1],
+                                                 ids[:, 1:], pos)
+            np.testing.assert_array_equal(c["rows_walked"], [256.0] * 4)
+            read.append((float(loss), float(gnorm)))
+        return read
+
+    got = three_steps()
+    np.testing.assert_allclose(
+        got, [[float.fromhex(v) for v in row] for row in PARENTS_THREE_STEPS],
+        rtol=2e-6)
+    for grain in (6, 0.25):
+        monkeypatch.setattr(moe_mod, "CHUNK_SHARES", grain)
+        assert three_steps() == got
+
+
 def test_a_chunk_of_all_the_pairs_is_computed_whatever_is_routed():
     """Where the one chunk is ALL the pairs there is nothing to skip to:
     the products run with no `cond` around them, also in a step that routes
     nothing to the experts held (since PR 47 over ZERO groups: no row is
     computed, the output zero, every gradient a finite zero); several
-    chunks keep the `cond` that skips those past the last held row."""
+    chunks are a loop that stops at the last held row (PR 50: until then a
+    `scan` over all of them with a `cond` that skipped those past it)."""
     d, f, E, k = 32, 16, 32, 4
     x = jax.random.normal(jax.random.key(1), (2, 512, d))
     quarter = SharedRoutedFFN(d, f, E, k, held=8, n_shared=0)
     sixteenth = SharedRoutedFFN(d, f, E, k, held=2, n_shared=0)
     assert quarter.chunk_rows(4096) == 4096
-    assert sixteenth.chunk_rows(4096) == 1536
-    conds = lambda moe: str(jax.make_jaxpr(lambda p, x: apply_moe(
-        moe, p, x))(moe.init(jax.random.key(0)), x)).count(" cond[")
-    assert conds(quarter) == 0 and conds(sixteenth) >= 1
+    assert sixteenth.chunk_rows(4096) == 512
+    count = lambda moe, op: str(jax.make_jaxpr(lambda p, x: apply_moe(
+        moe, p, x))(moe.init(jax.random.key(0)), x)).count(f" {op}[")
+    assert count(quarter, "cond") == 0 == count(quarter, "while")
+    assert count(sixteenth, "cond") == 0 and count(sixteenth, "while") == 1
     p = quarter.init(jax.random.key(0))
     # the selection bias sends every token to experts 8..11: none held
     p["bias"] = jnp.where((jnp.arange(E) >= 8) & (jnp.arange(E) < 12),
                           100.0, 0.0)
     out, c = apply_moe(quarter, p, x)
     assert float(c["rows_here"]) == float(c["rows_computed"]) == 0
+    assert float(c["rows_walked"]) == 4096      # no `cond`: the chunk ran
     assert not np.any(out)
     grads = jax.grad(lambda p: jnp.sum(apply_moe(quarter, p, x)[0] ** 2))(p)
     assert all(np.all(np.isfinite(g)) and not np.any(g)

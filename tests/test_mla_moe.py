@@ -202,9 +202,9 @@ def test_a_router_forced_onto_the_same_experts_drops_nothing():
     p = moe.init(jax.random.key(1))
     p["bias"] = jnp.where(jnp.arange(E) < k, 10.0, 0.0)
     x = jax.random.normal(jax.random.key(2), (4, 214, d))
-    # 2568 pairs, a chunk of 6 x 4/64 of them rounded up to 1024: three
-    # live chunks, the last of which runs past the pairs
-    assert moe.chunk_rows(4 * 214 * k) == 1024
+    # 2568 pairs, a chunk of 4/64 of them rounded up to the kernel's
+    # 512-row tile: six live chunks, the last of which runs past the pairs
+    assert moe.chunk_rows(4 * 214 * k) == 512
     with jax.default_matmul_precision("highest"):
         got, counters = apply_moe(moe, p, x)
         xf = x.reshape(-1, d)
@@ -217,6 +217,7 @@ def test_a_router_forced_onto_the_same_experts_drops_nothing():
         want = want + ffn(sh["gate"], sh["up"], sh["down"])
     assert float(counters["rows_here"]) == 4 * 214 * k     # 2568 pairs
     assert float(counters["rows_computed"]) == 4 * 214 * k
+    assert float(counters["rows_walked"]) == 6 * 512       # every chunk live
     np.testing.assert_array_equal(
         counters["routed"], np.where(np.arange(E) < k, 4 * 214, 0))
     np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
@@ -328,15 +329,17 @@ def test_the_row_movers_are_the_plain_forms_and_each_other_s_transpose(
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 2e-2)])
 @pytest.mark.parametrize("case,E,H,k,forced,chunks,gathers", [
     ("one chunk of all the pairs", 8, 2, 2, False, 1, True),
-    ("two live chunks", 24, 3, 3, True, 2, True),
-    ("three live chunks", 64, 4, 3, True, 3, False),
-    ("chunks the routing does not reach", 64, 4, 3, False, 3, False),
+    ("an eighth held, six live chunks", 24, 3, 3, True, 6, False),
+    ("a sixteenth held, six live chunks", 64, 4, 3, True, 6, False),
+    ("chunks the routing does not reach", 64, 4, 3, False, 6, False),
 ])
 def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
         monkeypatch, case, E, H, k, forced, chunks, gathers, dtype, tol, tp):
     """`SharedRoutedFFN.apply` moving its rows by the movers, whatever its
-    shape rule would pick (under 1.6 pairs a row of the chunk: gathers;
-    the rule's own verdict is asserted, then set aside), against itself
+    shape rule would pick (under 1.6 pairs a row of the chunk: gathers,
+    which since chunks are a share or less is the one chunk of all the
+    pairs; the rule's own verdict is asserted, then set aside, so the
+    gathers still walk several chunks HERE), against itself
     with the plain gather and row scatter-add in their place (the form
     the layer had, kept HERE as the oracle): a scalar of the output and
     the gradient of every leaf and of the input, float32 to 1e-6 of a
@@ -411,18 +414,18 @@ def garbage_past_the_groups(seen):
 
 @pytest.mark.parametrize("case,E,H,k,S,forced,chunks,gathers,rows", [
     ("gathers, a quarter held, one chunk", 8, 2, 2, 856, (), 1, True, None),
-    ("gathers, an eighth held, two live chunks", 24, 3, 3, 856, (0, 1, 2),
-     2, True, 2568),
-    ("scatter-add, a sixteenth held, three live chunks", 64, 4, 3, 856,
-     (0, 1, 2), 3, False, 2568),
-    ("scatter-add, chunks the routing does not reach", 64, 4, 3, 856, (), 3,
+    ("scatter-add, an eighth held, every chunk live", 24, 3, 3, 856,
+     (0, 1, 2), 6, False, 2568),
+    ("scatter-add, a sixteenth held, every chunk live", 64, 4, 3, 856,
+     (0, 1, 2), 6, False, 2568),
+    ("scatter-add, chunks the routing does not reach", 64, 4, 3, 856, (), 6,
      False, None),
     ("no held row, one chunk", 8, 2, 2, 856, (4, 5), 1, True, 0),
-    ("no held row, every chunk skipped", 64, 4, 3, 856, (8, 9, 10), 3, False,
+    ("no held row, every chunk skipped", 64, 4, 3, 856, (8, 9, 10), 6, False,
      0),
     ("the held rows end on the kernel's tile", 8, 2, 2, 512, (0, 4), 1, True,
      512),
-    ("the held rows end on the chunk's last row", 64, 4, 2, 1024, (0, 8), 2,
+    ("the held rows end on a chunk's last row", 64, 4, 2, 1024, (0, 8), 4,
      False, 1024),
     ("every pair held", 4, 4, 2, 856, (), 1, True, 1712),
 ])
@@ -465,6 +468,9 @@ def test_no_row_past_the_groups_is_read_anywhere(
 
     held = float(c["rows_here"])
     assert held == float(c["rows_computed"])
+    # the movers and the passes walked whole chunks up to the last held row
+    assert float(c["rows_walked"]) == (M if chunks == 1
+                                       else M * -(-int(held) // M))
     if rows is not None:
         assert held == rows
     else:
@@ -486,27 +492,40 @@ def test_no_row_past_the_groups_is_read_anywhere(
             jax.tree_util.keystr(path)
 
 
-@pytest.mark.parametrize("cell,E,H,k,chunk,gathers", [
-    ("joyai-llm-flash.train-ep16share-b4-t4096", 256, 16, 8, 49152, False),
-    ("qwen3-next-80b-a3b.train-ep16share-b2-t8192", 512, 32, 10, 61440,
+@pytest.mark.parametrize("cell,E,H,k,chunk,chunks,gathers", [
+    ("joyai-llm-flash.train-ep16share-b4-t4096", 256, 16, 8, 8192, 16,
      False),
-    ("lfm2-8b-a1b.train-ep4share-b2-t8192", 32, 8, 4, 65536, True),
-    ("sdar-30b-a3b.train-ep8share-b2-t4096", 128, 16, 8, 98304, True),
+    ("qwen3-next-80b-a3b.train-ep16share-b2-t8192", 512, 32, 10, 10240, 16,
+     False),
+    ("lfm2-8b-a1b.train-ep4share-b2-t8192", 32, 8, 4, 65536, 1, True),
+    ("sdar-30b-a3b.train-ep8share-b2-t4096", 128, 16, 8, 16384, 8, False),
+    ("trinity-mini.train-epshare-b2-t8192", 128, 16, 8, 16384, 8, False),
 ])
 def test_the_gradient_s_text_scatters_rows_only_where_the_rule_says(
-        cell, E, H, k, chunk, gathers):
-    """The lowered gradient of the layer at each expert cell's routing
-    (16,384 tokens of 2048 in bf16, the cell's experts, held share and k)
-    holds NO scatter whose updates are rows of d where the shape rule
-    picks the gathers (a held share of an eighth or more), and holds the
-    transposed gather's where it keeps the scatter-add (a sixteenth); a
-    scalar scatter is nowhere."""
+        cell, E, H, k, chunk, chunks, gathers):
+    """The chunk rule at each expert cell's routing (16,384 tokens of 2048
+    in bf16, the cell's experts, held share and k): a chunk is ONE mean
+    share of the pairs where under a sixth of the experts are held, walked
+    by a loop up to the last held row, and ALL the pairs with no `cond`
+    anywhere where a sixth or more are (cell 7). The lowered gradient holds
+    NO scatter whose
+    updates are rows of d where the shape rule picks the gathers (the one
+    chunk of all pairs), and holds the transposed gather's where it keeps
+    the scatter-add (every chunk of a share or less); a scalar scatter is
+    nowhere."""
     import re
     from jax.sharding import PartitionSpec as P
+    from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 
     d = 2048
     moe = SharedRoutedFFN(d, 128, E, top_k=k, held=H, n_shared=0)
-    assert moe.chunk_rows(16384 * k) == chunk
+    pairs = 16384 * k
+    assert moe.chunk_rows(pairs) == chunk and chunk % 512 == 0
+    assert -(-pairs // chunk) == chunks
+    assert chunk == (pairs if 6 * H >= E else
+                     moe_mod.CHUNK_SHARES * pairs * H // E)
+    assert (pairs * moe_mod.ROW_GATHER_NS
+            <= chunk * moe_mod.ROW_SCATTER_NS) == gathers
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
     params = jax.eval_shape(moe.init, jax.random.key(0))
     x = jax.ShapeDtypeStruct((2, 8192, d), jnp.bfloat16)
@@ -521,6 +540,157 @@ def test_the_gradient_s_text_scatters_rows_only_where_the_rule_says(
     updates = [sig.split(", ")[-1] for sig in re.findall(
         r"stablehlo\.scatter.*?\}\) : \((.*?)\) ->", text, re.S)]
     assert updates == ([] if gathers else [f"tensor<{chunk}x{d}xbf16>"])
+    # no `cond` in either: the one chunk of all pairs runs bare, the chunks
+    # of a share are a loop that STOPS at the last held row
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert chunks == 1 or "stablehlo.while" in text
+
+
+# ---- chunks of a share or less: the walk stops where the held rows do ----
+
+def one_expert_at_a_time(moe, params, x):
+    """The layer's routed part as a sum over the held experts, each a
+    dense FFN over EVERY token times the token's weight for it (zero where
+    it was not chosen): no sort, no group, no chunk (`scripts/
+    tpu_checks.py` holds the layer to the same form on the chip)."""
+    xf = x.reshape(-1, x.shape[-1])
+    chosen, w = moe.route(params, xf)
+    y = 0.0
+    for e in range(moe.num_held):
+        w_e = jnp.sum(jnp.where(chosen == moe.offset + e, w, 0), axis=-1)
+        h = jax.nn.silu(xf @ params["gate"][e]) * (xf @ params["up"][e])
+        y = y + w_e[:, None] * (h @ params["down"][e])
+    return y.reshape(x.shape)
+
+
+@pytest.mark.parametrize("case,forced,S,rows,live", [
+    ("every token on two held experts: every chunk live", (0, 1), 1024, 2048,
+     4),
+    ("no token on a held expert: no chunk live", (8, 9), 1024, 0, 0),
+    ("the held rows end exactly on a chunk's edge", (0, 8), 1024, 1024, 2),
+    ("one row past a chunk's edge", (0, 8), 1025, 1025, 3),
+    ("an untrained router: the chunks past the held rows skipped", (), 1024,
+     None, None),
+])
+def test_fine_chunks_equal_one_expert_at_a_time_in_value_and_every_gradient(
+        case, forced, S, rows, live):
+    """A sixteenth of the experts held, so chunks of 512 rows (the grain's
+    floor at this size) walked up to the last held row: the output and the
+    gradient
+    of every leaf and of the input are the dense sum's over the held
+    experts whichever chunks are live, every pair that exists is computed
+    (`rows_computed == rows_here`), and the movers and passes walked whole
+    chunks up to the last held row and no further (`rows_walked == M *
+    ceil(rows_here / M)`)."""
+    d, f, E, H, k = 32, 16, 64, 4, 2
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
+    p = moe.init(jax.random.key(1))
+    if forced:
+        p["bias"] = jnp.zeros(E).at[jnp.array(forced)].set(10.0)
+    x = jax.random.normal(jax.random.key(2), (1, S, d))
+    M = moe.chunk_rows(S * k)
+    assert M == 512 and -(-S * k // M) >= 4
+
+    def value_and_grads(layer):
+        def loss(p, x):
+            y, c = layer(p, x)
+            return jnp.sum(jnp.sin(y)), (y, c)
+        with jax.default_matmul_precision("highest"):
+            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+
+    (_, (got, c)), got_g = value_and_grads(lambda p, x: apply_moe(moe, p, x))
+    (_, (want, _)), want_g = value_and_grads(
+        lambda p, x: (one_expert_at_a_time(moe, p, x), None))
+    held = int(c["rows_here"])
+    assert held == int(c["rows_computed"])
+    if rows is None:
+        live = -(-held // M)
+        assert 0 < live < -(-S * k // M)
+    else:
+        assert held == rows
+    assert int(c["rows_walked"]) == M * live == M * -(-held // M)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g)) == 6
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 2e-5 * max(np.max(np.abs(a)), 1.0), \
+            jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("dp,tp", [(2, 1), (1, 2), (2, 2)])
+def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp):
+    """Under a mesh the loop's length is a data shard's own (`rows_here`
+    differs between them, so no collective may run inside it: the float
+    operands are cast to one set of mesh axes before the walk and the
+    sums over them happen once, outside): batch rows over dp, the experts'
+    width over tp, against one expert at a time on one device, in value
+    and every gradient; the shards' counters add up."""
+    from jax.sharding import PartitionSpec as P
+    d, f, E, H, k, S = 32, 16, 64, 4, 2, 1024
+    moe = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0, tp_size=tp)
+    whole = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
+    p = whole.init(jax.random.key(1))
+    # the first sequence's tokens favour a held expert, the second's none
+    x = jax.random.normal(jax.random.key(2), (2, S, d))
+    p["router"] = p["router"].at[:, 0].set(0.0)
+    x = x.at[0, :, 0].set(3.0).at[1, :, 0].set(-3.0)
+    p["router"] = p["router"].at[0, 0].set(4.0)
+    mesh = make_mesh(MeshConfig(dp=dp, tp=tp), devices=jax.devices()[:dp * tp])
+
+    def layer(p, x):
+        def shard(p, x):
+            y, c = moe.apply(p, x)
+            return y, jax.tree.map(lambda a: jax.lax.psum(a, "dp"), c)
+        return jax.shard_map(shard, mesh=mesh, in_specs=(moe.specs(), P("dp")),
+                             out_specs=(P("dp"), P()))(p, x)
+
+    def value_and_grads(fn):
+        def loss(p, x):
+            y, c = fn(p, x)
+            return jnp.sum(jnp.sin(y)), (y, c)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                              has_aux=True))(p, x)
+
+    (_, (got, c)), got_g = value_and_grads(layer)
+    (_, (want, _)), want_g = value_and_grads(
+        lambda p, x: (one_expert_at_a_time(whole, p, x), None))
+    per_shard = [int(jnp.sum(whole.route(p, xs.reshape(-1, d))[0] < H))
+                 for xs in x.reshape(dp, -1, S, d)]
+    assert len(set(per_shard)) == dp            # the shards' loops differ
+    M = moe.chunk_rows(2 * S * k // dp)
+    assert int(c["rows_here"]) == int(c["rows_computed"]) == sum(per_shard)
+    assert int(c["rows_walked"]) == sum(M * -(-n // M) for n in per_shard)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
+                            jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 2e-5 * max(np.max(np.abs(a)), 1.0), \
+            jax.tree_util.keystr(path)
+
+
+@pytest.mark.parametrize("E,H,k,pairs,chunk", [
+    (256, 16, 8, 131072, 8192), (512, 32, 10, 163840, 10240),
+    (128, 16, 8, 131072, 16384), (64, 4, 3, 2568, 512), (24, 3, 3, 2568, 512),
+    (7, 1, 2, 8192, 1536), (6, 1, 2, 8192, 8192), (32, 8, 4, 65536, 65536),
+    (8, 8, 2, 4096, 4096), (4, 1, 2, 256, 256),
+])
+def test_a_chunk_is_a_mean_share_or_all_the_pairs(E, H, k, pairs, chunk):
+    """The grain of the dispatch: under a sixth of the experts held, ONE
+    of the job's mean shares of the pairs up to the grouped kernel's 512-row
+    tile (the expert cells 5, 6, 8 and 9, and the tests' tiny shapes at the
+    tile's floor), walked by a loop that stops at the last held row; a
+    sixth or more, ALL the pairs in one chunk (cell 7, every all-held
+    shape). Neither text has a `cond`."""
+    moe = SharedRoutedFFN(32, 16, E, top_k=k, held=H, n_shared=0)
+    assert moe.chunk_rows(pairs) == chunk
+    assert (moe.chunk_share == 1.0) == (6 * H >= E) == (chunk == pairs)
+    x = jax.ShapeDtypeStruct((1, pairs // k, 32), jnp.float32)
+    text = str(jax.make_jaxpr(lambda p, x: apply_moe(moe, p, x))(
+        jax.eval_shape(moe.init, jax.random.key(0)), x))
+    assert " cond[" not in text
+    assert (" while[" in text) == (chunk < pairs)
 
 
 # ---- the dispatch's index work: no scalar gather, no scalar scatter ----
@@ -709,6 +879,7 @@ def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
     summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
     assert summary["rows_here_per_token"] == 2.0    # all experts held
     assert summary["rows_computed_per_token"] == 2.0
+    assert summary["rows_walked_per_token"] == 2.0  # one chunk of all pairs
     assert summary["load_max_over_mean"] >= 1.0
     # off by default: the step's output is what it has always been
     plain = build_train_step(model, mesh, ocfg, with_grad_norm=True)
@@ -729,6 +900,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "model[mla_moe]" in out and "rows_here_per_token" in out
     assert "rows_computed_per_token" in out
+    assert "rows_walked_per_token" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
     assert any(e.get("tag") == "moe_counters" for e in events)

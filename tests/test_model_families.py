@@ -524,12 +524,16 @@ FACTS_PINNED = {
     "tiny-tp2/conv_moe": (4, 64, (705060864.0, 794896, 800.0, 1.0, 12)),
     "tiny-tp2/bd_moe": (4, 64, (384958464.0, 280000, 1376.0, 0.5, 2)),
     # the four drawn families at their cell's published widths and shape
+    # (what a layer holds beside the skeleton fell in PR 50 where under a
+    # sixth of the experts are held, the dispatch's chunk one mean share of
+    # the pairs and not six: 39680.0, 78464.0 and 116224.0 until then; the
+    # fifth family's cell holds a quarter and keeps its one chunk of all)
     "joyai-llm-flash": (4, 4096,
-        (55680216072192.0, 680441088, 39680.0, 1.0, 6)),
+        (55680216072192.0, 680441088, 23680.0, 1.0, 6)),
     "qwen3-next-80b-a3b": (2, 8192,
-        (26242826895360.0, 625667136, 78464.0, 1.0, 4)),
+        (26242826895360.0, 625667136, 60864.0, 1.0, 4)),
     "lfm2-8b-a1b": (2, 8192, (22914011234304.0, 507820288, 76800.0, 1.0, 5)),
-    "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 116224.0, 0.5, 6)),
+    "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 35584.0, 0.5, 6)),
 }
 
 

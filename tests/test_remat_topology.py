@@ -386,10 +386,11 @@ def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(
     bd_moe family at the published widths, 16 of 128 experts held, 6
     layers, 2 x 4096 data tokens = 2 x 8192 rows, bf16) compiled for the
     described chip at the rung `remat="auto"` picks there, the floor: the
-    family's memory facts (2L rows a sequence, the 98,304-row chunk, logits
-    on half the rows) are held to the chip's own count of this step, 15.48
-    GiB (PERF.md section 5, PR 41; the compiler's plan charges more than
-    the runtime reserves, as in the hybrid cell, and reads over the limit),
+    family's memory facts (2L rows a sequence, the chunk of one mean share,
+    16,384 rows, logits on half the rows) are held to the chip's own count
+    of this step, 13.46 GiB (PERF.md section 5, PR 50; 15.48 while the chunk
+    was six shares, PR 41; the compiler's plan charges more than the
+    runtime reserves, as in the hybrid cell),
     and Mosaic takes the flash kernels under the block-diffusion mask at
     head 128 and a group of 8 over eight blocks a head: the forward with
     the key row resident and ONE backward kernel with the head resident."""
@@ -426,7 +427,7 @@ def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(
     assert args == pytest.approx(645_623_296 * 12 / memory.GIB, rel=1e-3)
     planned = args + plan.temp_size_in_bytes / memory.GIB
     # what the chip counted for this step, between the estimate's two sides
-    chip_gib = 15.48
+    chip_gib = 13.46
     assert 0.9 * chip_gib < estimate < 1.05 * chip_gib, estimate
     assert chip_gib < planned, planned
     kernels = set(re.findall(r"%((?:flash|ragged)[\w\-]*?)[.\d]* = ",

@@ -484,6 +484,7 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
     out = capsys.readouterr().out
     assert "model[swa_moe]" in out and "rows_here_per_token" in out
     assert "rows_computed_per_token" in out
+    assert "rows_walked_per_token" in out
     assert "router_bias_step" in out
     events = [json.loads(line) for line in
               open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
@@ -498,10 +499,10 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
 def test_the_memory_facts_count_the_gate_the_chunk_and_the_shared_expert():
     eighth = build_model("swa_moe", tiny(experts_held=1))
     moe = eighth._mods["moe"]
-    assert moe.chunk_share == 6 * 1 / 8 and moe.n_shared == 1
+    assert moe.chunk_share == 1 / 8 and moe.n_shared == 1
     assert moe.scaling == 2.826 and moe.score == "sigmoid"
     attn = 7 * 4 * 32 + 6 * 2 * 32
-    assert eighth.layer_extra_elems_per_token == attn + (0.75 * 2 + 1) * (
+    assert eighth.layer_extra_elems_per_token == attn + (0.125 * 2 + 1) * (
         6 * 64 + 5 * 32)
     assert (eighth.head_dim, eighth.kv_dim) == (32, 64)
 
