@@ -196,6 +196,38 @@ class SwaMoEConfig:
 
 
 @dataclass(frozen=True)
+class EarlyMoEConfig:
+    """What the `early_moe` family (models/early_moe.py) needs beyond
+    `ModelConfig`'s own fields: a grouped-query expert decoder whose
+    attention layers are of two kinds over ONE parameter tree, by two
+    layouts of 0 / 1 a layer that this family takes only where they agree:
+    a layer with `sliding_window_layout` 1 attends under a window (a row
+    sees itself and the `sliding_window_size` - 1 rows before it) and, by
+    `rope_layout` 1, takes RoPE on q and k; a layer with 0 in both attends
+    to its whole past and takes no positions at all. Heads `head_dim` wide
+    whatever the model's width, no q/k norms, no gate, two norms a layer,
+    every layer an expert layer: a softmax top-k router that reads the
+    LAYER'S INPUT (before attention) over ReLU-gated routed experts of
+    which this job may hold a slice, no shared expert, no bias. The keys
+    are SmallThinker's `config.json` names (`smallthinker`). In
+    `ModelConfig`, `attn_dim` is the model width, `num_heads` /
+    `num_kv_heads` the heads, `num_layers` = `len(sliding_window_layout)`,
+    `num_experts` the ROUTED experts the router scores
+    (`moe_num_primary_experts`) and `moe_top_k` the experts a token takes
+    (`moe_num_active_primary_experts`); `ffn_dim` is not read."""
+
+    sliding_window_layout: tuple    # 1: a window layer, 0: a full layer
+    rope_layout: tuple              # 1: RoPE on q and k, 0: no positions
+    head_dim: int
+    moe_ffn_hidden_size: int
+    sliding_window_size: int
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -237,6 +269,8 @@ class ModelConfig:
     bd_moe: "BdMoEConfig | None" = None
     # The `swa_moe` family's facts (None for every other family).
     swa_moe: "SwaMoEConfig | None" = None
+    # The `early_moe` family's facts (None for every other family).
+    early_moe: "EarlyMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -304,7 +338,8 @@ class ModelConfig:
 
 
 # the ModelConfig fields that carry one family's facts each
-FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe")
+FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
+                "early_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -388,6 +423,19 @@ MODEL_PRESETS = {
             + ("sliding_attention",) * 3 + ("full_attention",),
             head_dim=32, moe_intermediate_size=32, sliding_window=16,
             route_scale=2.826, load_balance_coeff=0.001)),
+    # the `early_moe` family at a CPU size: SmallThinker's pattern in small,
+    # (full, window, window, window) twice with no leading dense layer; a
+    # window of 16 rows, shorter than any test's sequence; 6 query heads
+    # over 2 key-value heads of 32 (a group of 3: heads x width = 192, not
+    # the model's 64), two norms a layer; 8 routed ReLU-gated experts
+    # (softmax top-2 from the layer's input, no shared expert, no bias)
+    "tiny-early-moe": ModelConfig(
+        attn_dim=64, ffn_dim=0, num_heads=6, num_kv_heads=2, num_layers=8,
+        vocab_size=1024, maxlen=256, rope_theta=1.5e6, num_experts=8,
+        moe_top_k=2, early_moe=EarlyMoEConfig(
+            sliding_window_layout=(0, 1, 1, 1) * 2,
+            rope_layout=(0, 1, 1, 1) * 2, head_dim=32,
+            moe_ffn_hidden_size=32, sliding_window_size=16)),
 }
 
 

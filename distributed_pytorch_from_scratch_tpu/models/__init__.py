@@ -2,6 +2,7 @@
 
 from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
+from .early_moe import EarlyRouterMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
 from .mla_moe import LatentMoETransformer
@@ -12,7 +13,7 @@ from .transformer import Transformer
 FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
     ConvMoETransformer, BlockDiffusionMoETransformer,
-    SlidingWindowMoETransformer)}
+    SlidingWindowMoETransformer, EarlyRouterMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

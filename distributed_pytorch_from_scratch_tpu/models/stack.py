@@ -20,8 +20,9 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   has them, the norms after a sublayer (`post_attn_norm_key`,
   `post_ffn_norm_key`), the embedding's multiplier (`embed_scale`), the
   kinds of attention layer by `_pattern` key (`_kind`, `_attn_mask(t,
-  kind)`, `unrotated_kinds`) and the speed of its routers' selection bias
-  (`router_bias_speed`);
+  kind)`, `unrotated_kinds`), the speed of its routers' selection bias
+  (`router_bias_speed`) and whether its routers read the layer's input
+  (`router_reads_layer_input`);
 * `_mods`, the per-layer modules (the attention projections are
   `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
   `models/decode.py` and `interop.py` read by name), and its mixer where
@@ -549,6 +550,13 @@ class DecoderStack:
     # (training/optim.router_bias_step); None: the family's configuration
     # publishes none and nothing updates a bias it may hold
     router_bias_speed = None
+    # does an expert layer's router read the LAYER'S INPUT (the residual
+    # stream as it enters the layer, before the attention half and before
+    # any norm) where its experts read the normed post-attention stream:
+    # `_layer_body` then carries that input past the attention half to
+    # `_ffn` (it is the remat boundary's own operand: no new saved tensor),
+    # and the router's gradient enters the residual stream before attention
+    router_reads_layer_input = False
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -994,11 +1002,13 @@ class DecoderStack:
                 a = checkpoint_name(a, "attn_proj")
             if norm := self.post_attn_norm_key:
                 a = m[norm].apply(layer_params[norm], a)
+            # (what entered the layer, for a router that reads it)
+            router_x = x if self.router_reads_layer_input else None
             x = x + a
 
             norm = self.ffn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
-            ff, aux = self._ffn(layer_params, y, tp, dtype)
+            ff, aux = self._ffn(layer_params, y, tp, dtype, router_x)
             if norm := self.post_ffn_norm_key:
                 ff = m[norm].apply(layer_params[norm], ff)
             return x + ff, aux
@@ -1092,12 +1102,16 @@ class DecoderStack:
         return (jax.named_scope(self.attn_scope) if self.attn_scope
                 else contextlib.nullcontext())
 
-    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype):
+    def _ffn(self, lp: Params, y: jax.Array, tp: TPSublayers, dtype,
+             router_x: "jax.Array | None" = None):
         """The FFN half of a layer on the normed activation `y`: (output,
         aux or None). The routed experts (`_mods["moe"]`) where the layer's
-        parameters hold them, else the family's dense `_mlp`."""
+        parameters hold them, else the family's dense `_mlp`. `router_x` is
+        the layer's input, handed on by a family whose routers read it
+        (`router_reads_layer_input`)."""
         if "moe" in lp:
-            ff, aux = self._mods["moe"].apply(lp["moe"], y, dtype)
+            early = {} if router_x is None else {"router_x": router_x}
+            ff, aux = self._mods["moe"].apply(lp["moe"], y, dtype, **early)
             if tp.sp:
                 # The router saw the tp-gathered full tokens (identical
                 # on every tp rank, so routing agrees) and the expert

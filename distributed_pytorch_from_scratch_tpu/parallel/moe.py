@@ -47,6 +47,7 @@ how tokens are sharded (tests assert this).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from dataclasses import dataclass
@@ -366,10 +367,11 @@ def chunk_of(c, M: int, token: jax.Array, w_sorted: jax.Array,
 
 
 def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
-                 wc: jax.Array, sizes: jax.Array, valid: jax.Array
-                 ) -> jax.Array:
+                 wc: jax.Array, sizes: jax.Array, valid: jax.Array,
+                 act=jax.nn.silu) -> jax.Array:
     """A chunk's (M, d) rows through the held experts' two grouped products
-    and times their weights. The grouped kernels write the rows of their
+    (`act(gate) * up` between them: the family's `activation`) and times
+    their weights. The grouped kernels write the rows of their
     groups and NOTHING ELSE: a row no group holds comes back as whatever
     the buffer held, from the forward products and from their transposes
     alike (a 5,000-fold gradient norm on the chip, PR 33; the CPU lowering
@@ -383,7 +385,7 @@ def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
     f = down.shape[1]
     with jax.named_scope("moe_experts"):
         gu = lax.ragged_dot(rows, gate_up, sizes)
-        out = lax.ragged_dot(jax.nn.silu(gu[:, :f]) * gu[:, f:], down, sizes)
+        out = lax.ragged_dot(act(gu[:, :f]) * gu[:, f:], down, sizes)
     with jax.named_scope("moe_route"):
         return jnp.where(valid, out, 0) * wc[:, None].astype(out.dtype)
 
@@ -394,10 +396,10 @@ def _like(a: jax.Array) -> jax.Array:
     return copy_to(jnp.zeros_like(a), vma) if vma else jnp.zeros_like(a)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def walk_chunks(M: int, xd: jax.Array, gate_up: jax.Array, down: jax.Array,
-                w_sorted: jax.Array, token: jax.Array, ends: jax.Array,
-                rows_here: jax.Array):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def walk_chunks(M: int, act, xd: jax.Array, gate_up: jax.Array,
+                down: jax.Array, w_sorted: jax.Array, token: jax.Array,
+                ends: jax.Array, rows_here: jax.Array):
     """The chunks of a share: rows in by `take_held`, through
     `held_experts`, back by `add_held`, chunk after chunk UP TO
     THE LAST HELD ROW: a loop of `ceil(rows_here / M)` steps, so a chunk
@@ -424,7 +426,7 @@ def walk_chunks(M: int, xd: jax.Array, gate_up: jax.Array, down: jax.Array,
                                             rows_here)
         with jax.named_scope("moe_route"):
             rows = take_held(xd, tok, valid)
-        out = held_experts(rows, gate_up, down, wc, sizes, valid)
+        out = held_experts(rows, gate_up, down, wc, sizes, valid, act)
         with jax.named_scope("moe_route"):
             y = add_held(y, out.astype(y.dtype), tok, valid)
         return c + 1, y, computed + jnp.sum(sizes)
@@ -435,13 +437,14 @@ def walk_chunks(M: int, xd: jax.Array, gate_up: jax.Array, down: jax.Array,
     return y, computed, c * M
 
 
-def _walk_chunks_fwd(M, xd, gate_up, down, w_sorted, token, ends, rows_here):
-    return (walk_chunks(M, xd, gate_up, down, w_sorted, token, ends,
+def _walk_chunks_fwd(M, act, xd, gate_up, down, w_sorted, token, ends,
+                     rows_here):
+    return (walk_chunks(M, act, xd, gate_up, down, w_sorted, token, ends,
                         rows_here),
             (xd, gate_up, down, w_sorted, token, ends, rows_here))
 
 
-def _walk_chunks_bwd(M, res, g):
+def _walk_chunks_bwd(M, act, res, g):
     xd, gate_up, down, w_sorted, token, ends, rows_here = res
     d_y = g[0]
     n_live = -(-rows_here // M)
@@ -457,7 +460,7 @@ def _walk_chunks_bwd(M, res, g):
             d_out = jnp.take(d_y, tok, axis=0)
         _, pull = jax.vjp(
             lambda rows, gate_up, down, wc: held_experts(
-                rows, gate_up, down, wc, sizes, valid).astype(d_y.dtype),
+                rows, gate_up, down, wc, sizes, valid, act).astype(d_y.dtype),
             rows, gate_up, down, wc)
         d_rows, d_gu, d_dn, d_wc = pull(d_out)
         with jax.named_scope("moe_route"):
@@ -534,9 +537,14 @@ def _sort_pairs_bwd(order, g):
 sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
+# The gate activations a family may state for `SharedRoutedFFN`'s experts
+# (`activation`): SwiGLU's, and ReGLU's (its zeros are computed like any
+# other value: the grouped products are dense over a row's hidden width).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
 # A chunk of `SharedRoutedFFN`'s sorted pairs: the unit of the layer's WORK
 # and of its MEMORY. Every mover and pass of a live chunk (the `x[tok]`
-# gather, the selects, the weights' multiply, `silu * up` between the two
+# gather, the selects, the weights' multiply, `act(gate) * up` between the two
 # grouped products, the row scatter-add, and the transposes of them all)
 # walks the chunk's M rows whatever it holds, and the walk stops at the
 # last held row (`walk_chunks`); the grouped products alone follow the held
@@ -572,16 +580,28 @@ WHOLE_FROM_SHARES = 6
 
 @dataclass(frozen=True)
 class SharedRoutedFFN:
-    """A router over `num_experts` routed SwiGLU experts, of which this job
+    """A router over `num_experts` routed gated experts (`act(gate) * up`,
+    then `down`: SwiGLU unless the family says otherwise), of which this job
     HOLDS `held` (experts [offset, offset + held)), plus shared experts
     every token takes: the DeepSeek-V3 FFN, as one chip of an
     expert-parallel deployment computes it between two all-to-alls.
 
-    Two facts a family states (fields, below the DeepSeek-V3 defaults):
+    Facts a family states (fields, below the DeepSeek-V3 defaults):
     `score` "softmax" scores by a softmax over all routed experts and has
     no selection bias (no `bias` leaf); `shared_gate` multiplies the shared
     expert's output by `sigmoid(x w_sg)`, one scalar a token (the leaf
-    `shared["gate_score"]`, d -> 1): Qwen3-Next's expert layer.
+    `shared["gate_score"]`, d -> 1): Qwen3-Next's expert layer;
+    `activation` is the experts' gate activation, held and shared alike
+    (`ACTIVATIONS`: "silu", or "relu" for a ReGLU expert), which reaches
+    both movers' paths and `walk_chunks`' hand-written transpose through
+    `held_experts`. And one the CALLER states, a call at a time: `apply`'s
+    optional `router_x` is what the router reads where that is not what
+    the experts read (a family whose router reads the layer's input,
+    before attention: the routing's index work, `route`, `sort_pairs` and
+    `index`, then depends on nothing the attention half computes, runs
+    under the inner scope `moe_route/early`, and the compiler places it
+    where it likes; the router's gradient enters the residual stream
+    through `router_x`).
 
     Routing (float32): `s = sigmoid(x W_r)` over all routed experts; the
     `top_k` largest of `s + bias` are chosen (`bias` is the selection bias
@@ -614,7 +634,7 @@ class SharedRoutedFFN:
     a sixth of the experts are held it is the job's mean share of the
     pairs and the walk is a loop that STOPS at the last held row
     (`walk_chunks`, forward and its hand-written transpose), so the
-    movers, the selects, the weights' multiply, `silu * up` and their
+    movers, the selects, the weights' multiply, `act(gate) * up` and their
     transposes walk `M * ceil(rows_here / M)` rows (`rows_walked`) and a
     chunk past the held rows costs nothing; where a sixth or more are held
     (`WHOLE_FROM_SHARES`) the one chunk is ALL the pairs, a `scan` of one
@@ -662,12 +682,17 @@ class SharedRoutedFFN:
     tp_axis: str = "tp"
     score: str = "sigmoid"       # or "softmax" (class docstring)
     shared_gate: bool = False
+    activation: str = "silu"     # a key of ACTIVATIONS
 
     def __post_init__(self):
         held = self.num_held
         if self.score not in ("sigmoid", "softmax"):
             raise ValueError(f"score must be 'sigmoid' or 'softmax', got "
                              f"{self.score!r}")
+        if self.activation not in ACTIVATIONS:
+            raise ValueError(f"activation must be one of "
+                             f"{sorted(ACTIVATIONS)}, got "
+                             f"{self.activation!r}")
         if self.shared_gate and not self.n_shared:
             raise ValueError("shared_gate needs a shared expert")
         if not (0 <= self.offset and self.offset + held <= self.num_experts):
@@ -803,9 +828,12 @@ class SharedRoutedFFN:
     # ---- forward (per-shard, inside shard_map) ----
 
     def apply(self, params: Params, x: jax.Array,
-              compute_dtype: jnp.dtype = jnp.float32
+              compute_dtype: jnp.dtype = jnp.float32,
+              router_x: "jax.Array | None" = None
               ) -> Tuple[jax.Array, Params]:
-        """x (b, t, d), replicated over tp -> (y (b, t, d), counters):
+        """x (b, t, d), replicated over tp -> (y (b, t, d), counters); the
+        router reads `router_x` (b, t, d) where one is given, `x` otherwise
+        (class docstring):
         `routed` (num_experts,) the pairs each routed expert was chosen
         for, `rows_here` the pairs whose expert is held, `rows_computed`
         the rows of the groups the grouped products were handed, over the
@@ -823,8 +851,12 @@ class SharedRoutedFFN:
         # rows move by gathers both ways or by the row scatter-add: the
         # movers' two costs decide, on static shapes, a layer at a time
         gathers = S * k * ROW_GATHER_NS <= M * ROW_SCATTER_NS
-        with jax.named_scope("moe_route"):
-            chosen, w = self.route(params, xf)
+        act = ACTIVATIONS[self.activation]
+        early = (contextlib.nullcontext() if router_x is None
+                 else jax.named_scope("early"))
+        with jax.named_scope("moe_route"), early:
+            chosen, w = self.route(
+                params, xf if router_x is None else router_x.reshape(S, d))
             order, w_sorted, ends, pos, routed = self.index(
                 chosen, w, inverse=gathers)
             rows_here = ends[-1]
@@ -853,7 +885,8 @@ class SharedRoutedFFN:
                         idx = jnp.where(
                             (at >= 0) & (at < jnp.minimum(n, M)), at, M)
                         rows = take_rows(xd, tok, idx, n)
-                    out = held_experts(rows, gate_up, down, wc, sizes, valid)
+                    out = held_experts(rows, gate_up, down, wc, sizes, valid,
+                                       act)
                     with jax.named_scope("moe_route"):
                         return sum_rows(y, out.astype(y.dtype), tok, idx, n)
 
@@ -878,8 +911,8 @@ class SharedRoutedFFN:
             vma = tuple(jax.typeof(xd).vma)
             vary = lambda a: copy_to(a, vma) if vma else a
             y, computed, walked = walk_chunks(
-                M, xd, vary(gate_up), vary(down), vary(w_sorted), token, ends,
-                rows_here)
+                M, act, xd, vary(gate_up), vary(down), vary(w_sorted), token,
+                ends, rows_here)
         counters["rows_computed"] = computed.astype(jnp.float32)
         counters["rows_walked"] = walked.astype(jnp.float32)
 
@@ -888,7 +921,7 @@ class SharedRoutedFFN:
                 sp = params["shared"]
                 g = xd @ sp["gate"].astype(compute_dtype)
                 u = xd @ sp["up"].astype(compute_dtype)
-                out = (jax.nn.silu(g) * u) @ sp["down"].astype(compute_dtype)
+                out = (act(g) * u) @ sp["down"].astype(compute_dtype)
                 if self.shared_gate:
                     # the gate's product reads whole tokens on every tp
                     # rank and scales this rank's partial sum

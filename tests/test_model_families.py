@@ -12,6 +12,7 @@ import pytest
 from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          BdMoEConfig,
                                                          ConvMoEConfig,
+                                                         EarlyMoEConfig,
                                                          GdnMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
@@ -60,9 +61,19 @@ SWA = dict(layer_types=("sliding_attention",) * 3 + ("full_attention",),
            num_dense_layers=1, load_balance_coeff=0.001)
 
 
+# the early_moe family: one period of (full, window, window, window), every
+# layer an expert layer; heads of 16; a window of 8 rows
+EARLY = dict(sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
+             head_dim=16, moe_ffn_hidden_size=16, sliding_window_size=8)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "early_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           early_moe=EarlyMoEConfig(experts_held=held,
+                                                    **EARLY))
     if extra == "swa_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            swa_moe=SwaMoEConfig(experts_held=held, **SWA))
@@ -83,7 +94,8 @@ def config_for(family, config):
 
 TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
-                "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe"}
+                "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
+                "early_moe": "tiny-early-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 

@@ -255,19 +255,25 @@ def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
         r'custom_call_target="tpu_custom_call"', text)) == sorted(names)
 
 
-@pytest.mark.parametrize("walk,names", [
-    ("row", ["flash_bwd_window", "flash_fwd_window"]),
-    ("grid", ["flash_bwd_dkv_window", "flash_bwd_dq_window",
-              "flash_fwd_window"])])
-def test_the_window_kernels_compile_at_the_seventh_cells_shape(
-        topo, described_tpu, monkeypatch, walk, names):
+SPLIT = ["flash_bwd_dkv_window", "flash_bwd_dq_window", "flash_fwd_window"]
+
+
+@pytest.mark.parametrize("t,window,group,walk,names", [
+    (8192, 2048, 8, "row", ["flash_bwd_window", "flash_fwd_window"]),
+    (8192, 2048, 8, "grid", SPLIT),
+    (16384, 4096, 7, "own", SPLIT)])
+def test_the_window_kernels_compile_at_the_window_cells_shapes(
+        topo, described_tpu, monkeypatch, t, window, group, walk, names):
     """Mosaic takes the flash kernels under `sliding_window(2048)` at a
     window layer's shape in the seventh cell (8192 rows, 128 / 128, bf16, a
     group of 8 query heads a key-value head, blocks of 1024): the forward
     with K and V resident and the ONE backward kernel, and the gridded
-    forward with the split backward a longer row would take. The calls
-    carry `_window` in their names, which is how a device trace tells a
-    window layer's from a full layer's."""
+    forward with the split backward a longer row would take; and under
+    `sliding_window(4096)` at the eighth cell's (16,384 rows, a group of
+    7), which takes the gridded forward and the split backward by its OWN
+    size, no budget patched. The calls carry `_window` in their names,
+    which is how a device trace tells a window layer's from a full
+    layer's."""
     from jax.sharding import SingleDeviceSharding
     from distributed_pytorch_from_scratch_tpu.ops.attention import (
         sliding_window)
@@ -276,7 +282,7 @@ def test_the_window_kernels_compile_at_the_seventh_cells_shape(
     if walk == "grid":
         monkeypatch.setattr(fa, "KV_ROW_VMEM_BYTES", 0)
         monkeypatch.setattr(fa, "BWD_ROW_VMEM_BYTES", 0)
-    t, d, group, mask = 8192, 128, 8, sliding_window(2048)
+    d, mask = 128, sliding_window(window)
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (rows, t, w), dtype, sharding=chip)

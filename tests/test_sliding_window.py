@@ -75,6 +75,12 @@ CASES = [
     (640, 384, 128, 1, 1, "row"),
     (1536, 1024, 512, 2, 1, "row"), (1536, 640, 512, 1, 1, "grid"),
     (256, 100, 256, 2, 1, "row"),
+    # a group of 7 query heads a key-value head (not a power of two: the
+    # group is a sequential grid dimension of the dk/dv kernel), the
+    # backward SPLIT under the window (`flash_bwd_dq_window` /
+    # `flash_bwd_dkv_window`, planned by the grid's guards) and resident
+    (384, 200, 128, 14, 2, "grid"), (384, 200, 128, 7, 1, "row"),
+    (512, 256, 128, 7, 1, "grid"),
 ]
 
 
@@ -86,7 +92,10 @@ def test_the_kernels_under_the_window_equal_the_dense_path(
     is no multiple of the block (two edge tiles, none whole); the third a
     window inside one block (the diagonal tile has a left edge too); then a
     window of three blocks (the whole tiles are a loop of two), blocks of
-    several sub-tiles, and one tile a head (the fused backward)."""
+    several sub-tiles, and one tile a head (the fused backward); last the
+    eighth cell's grouping, 7 query heads a key-value head, under the split
+    backward (two key-value heads, a window that is no multiple of the
+    block; and a window of two blocks) and the resident one."""
     _walks(monkeypatch, walk)
     mask = sliding_window(w)
     q, k, v, wt = _operands(t, hq, hkv)
@@ -180,6 +189,57 @@ def test_the_cells_shape_computes_under_a_third_over_the_live_entries():
     assert both < 1.35
     assert flash_tile_stats(8192, head_dim=128)["work_elems"] \
         > 1.8 * fwd["work_elems"]
+
+
+def test_the_eighth_cells_shape_takes_the_gridded_forward_and_split_backward(
+        tmp_path):
+    """16,384 rows under a window of 4096 at head 128 and a group of 7,
+    blocks of 1024: a head's K and V, double-buffered, are 16 MiB (over
+    `KV_ROW_VMEM_BYTES`: the grid walks the key tiles, its index maps
+    clamped to the 5 a window row computes of 16) and what the resident
+    backward would keep is 117 MB (over `BWD_ROW_VMEM_BYTES`: the split
+    kernels). Both walks run the SAME tile plans, which is what
+    `flash_tile_stats` reports: the forward computes 1.125 of the band's
+    58,722,304 live entries, the backward 1.062; the full layer's triangle
+    beside it 1.031 and 1.016. The tracer says which walk the backward
+    took."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    t, d, group, mask = 16384, 128, 7, sliding_window(4096)
+    assert 2 * t * (d + d) * 2 == 2 * fa_mod.KV_ROW_VMEM_BYTES
+    assert fa_mod._bwd_resident_bytes(t, d, d, 2, group) == 117_440_512 \
+        > fa_mod.BWD_ROW_VMEM_BYTES
+    fwd = flash_tile_stats(t, head_dim=d, mask=mask)
+    bwd = flash_tile_stats(t, head_dim=d, mask=mask, backward=True)
+    assert (fwd["block_q"], fwd["sub_q"], fwd["sub_k"]) == (1024, 256, 512)
+    assert (bwd["block_q"], bwd["sub_q"], bwd["sub_k"]) == (1024, 256, 256)
+    assert fwd["ideal_elems"] == 4096 * (2 * t - 4095) // 2 == 58_722_304
+    assert fwd["work_elems"] == 66_060_288 and bwd["work_elems"] == 62_390_272
+    full = flash_tile_stats(t, head_dim=d)
+    assert full["ideal_elems"] == t * (t + 1) // 2 == 134_225_920
+    assert full["work_elems"] == 138_412_032
+    assert fwd["ideal_elems"] / full["ideal_elems"] == pytest.approx(0.4375,
+                                                                     abs=1e-4)
+    # the walk the backward takes at this shape, as the program's tracer
+    # records it (shapes only: nothing runs)
+    tracer = SpanTracer(str(tmp_path))
+    arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (rows, t, w), dtype)
+    try:
+        jax.eval_shape(
+            lambda *a: fa_mod._bwd_call(
+                *a, t_real=t, block_q=1024, block_k=1024, hq=group, hkv=1,
+                interpret=True, mask=mask),
+            arg(group, d), arg(1, d), arg(1, d), arg(group, d),
+            arg(group, 1, jnp.float32), arg(group, d))
+    finally:
+        tracer.close()
+    events = [json.loads(line)["args"] for line in
+              open(tmp_path / "trace.jsonl")
+              if json.loads(line)["name"] == "flash_bwd_walk"]
+    assert [(e["walk"], e["window"], e["group"]) for e in events] == [
+        ("grid", 4096, 7)]
 
 
 def test_a_window_over_the_whole_sequence_is_the_triangles_text():
