@@ -65,6 +65,18 @@ v, dO, lse and delta whole rows in VMEM, s, p, dp and ds formed once a
 rectangle and dq, dk and dv all fed from them (`_bwd_row_kernel`; five
 products where the split kernels run seven, 22.8 -> 12.5 ms a call at
 t = 4096, 192 / 128). Heads over the budget keep the split kernels.
+
+PR 52: the forward's row walk is admitted by what a v5e's VMEM holds, not
+by what Mosaic's default scoped limit does: `KV_ROW_VMEM_BYTES` went from 8
+to 32 MiB, and a row over `KV_ROW_SCOPED_BYTES` asks Mosaic for the scoped
+VMEM it needs (`_vmem_limit`, as the resident backward has since PR 40; a
+row inside it asks for nothing and its call is the one it was). t = 16,384
+at 128 / 128 and t = 8192 at 256 / 256, 16 MiB both, left the gridded walk
+(25.7 -> 13.6 ms a causal call and 13.5 -> 6.5 under `sliding_window(4096)`
+at the first shape, 9.7 -> 7.4 at the second: `KV_ROW_VMEM_BYTES`' note).
+`_fwd_call` says which walk it took on the program's tracer too
+(`flash_fwd_walk`: `tile` / `row` / `grid`); the kernel body and the calls'
+names did not change.
 """
 
 from __future__ import annotations
@@ -864,13 +876,46 @@ def _q_row(bkv, g, hq: int, hkv: int):
 
 # What a head's K and V may take of VMEM, double-buffered by the pipeline and
 # each width padded to the 128 lanes a VMEM tile has, for the forward to
-# keep them resident while it walks the head's query blocks (`_fwd_call`).
-# Compiled for a v5e (16 MiB of scoped VMEM by default) beside 1024-row q, o
-# and lse blocks: t = 4096 at q/k 192 and v 128 in bf16 is 6 MiB; 8 MiB is
-# t = 8192 at 64 / 64 or 128 / 128 and t = 4096 at 256 / 256, and compiles;
-# so does 12 (t = 8192 at 192 / 128); 16 (t = 16384 at 64 / 64, t = 8192 at
-# 192 / 192) does not.
-KV_ROW_VMEM_BYTES = 8 * 2 ** 20
+# keep them resident while it walks the head's query blocks (`_fwd_call`,
+# `_fwd_resident_bytes`). A v5e has 128 MiB of VMEM; the budget is what a
+# chip run has read beside the gridded walk, and no size past it has been
+# timed. Compiled for a v5e beside 1024-row q, o and lse blocks (Mosaic's own
+# count of the kernel's scoped VMEM is the row plus some 3.5 MiB: 11.4 MB at
+# 8 MiB, 19.7 at 16): INSIDE the 16 MiB of scoped VMEM a kernel gets that
+# asks for nothing, t = 4096 at q/k 192 and v 128 in bf16 (6 MiB), t = 8192
+# at 64 / 64 or 128 / 128 and t = 4096 at 256 / 256 (8), t = 8192 at 192 /
+# 128 (12); 16 does not. WITH the limit `_fwd_call` asks for since PR 52
+# (`_vmem_limit`: the row and 24 MiB, so 40 at 16 and 56 at 32): 16 MiB (t =
+# 16,384 at 64 / 64 or 128 / 128, t = 8192 at 192 / 192 or 256 / 256) and 32
+# (t = 32,768 at 128 / 128, t = 16,384 at 256 / 256).
+# A call alone on v5e (TPU v5 lite, jax 0.9.0, PR 52), bf16, blocks of 1024,
+# device time from a capture, the gridded walk's budget set to 0 in the same
+# process (`python scripts/tune_flash_blocks.py --forward --bh 28 --t 16384
+# --d 128 --group 7 [--window 4096]`, `--bh 32 --t 8192 --d 256 --group 8`);
+# ms a forward, o and lse of the two walks within a bf16 ulp of each other:
+#
+#   K and V   shape (t, q/k / v, b*h, group)        mask          grid    row
+#   16 MiB    16,384  128 / 128   28   7            causal       25.67  13.62
+#             the same                              window 4096  13.54   6.47
+#              8,192  256 / 256   32   8            causal        9.67   7.38
+#             16,384   64 /  64   32   4            causal       28.90  15.48
+#   12 MiB     8,192  192 / 128   32   1            causal        8.61   5.59
+#   32 MiB    32,768  128 / 128    8   1            causal       28.21  15.17
+#             the same                              window 4096   8.54   4.01
+#             16,384  256 / 256   16   8            causal       18.06  14.08
+#
+# The row walk wins at every shape read: by half where q/k are up to 128 wide
+# (m and l through their (block_q, 1) scratch are what the gridded walk pays,
+# FWD_SUBTILE_WIDE's note, and under a window 5,208 of its 7,168 grid steps
+# at t = 16,384 fetch and compute nothing), by a quarter at 256 / 256, whose
+# dots are twice as long a round trip through scratch.
+KV_ROW_VMEM_BYTES = 32 * 2 ** 20
+
+# The resident K and V up to which `_fwd_call` asks Mosaic for nothing: every
+# row it admitted before PR 52 (they compile inside the default scoped VMEM,
+# the note above) keeps the call it had. A row over it asks for its own size
+# and the body's room (`_vmem_limit`).
+KV_ROW_SCOPED_BYTES = 8 * 2 ** 20
 
 # What a head's backward may keep in VMEM for ONE kernel to make dq, dk and dv
 # of it (`_bwd_call`'s row walk, `_bwd_resident_bytes`): q, k, v, dO, lse and
@@ -892,6 +937,13 @@ def _vmem_limit(resident_bytes: int) -> int:
     return min(resident_bytes + 24 * 2 ** 20, 100 * 2 ** 20)
 
 
+def _fwd_resident_bytes(t_pad: int, d: int, dv: int, itemsize: int) -> int:
+    """What the forward's row walk keeps of a head in VMEM beside its q, o
+    and lse blocks, as Mosaic lays it out: K and V whole rows, each width
+    padded to 128 lanes, double-buffered."""
+    return 2 * t_pad * (_round_up(d, 128) + _round_up(dv, 128)) * itemsize
+
+
 def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
               hq: int, hkv: int, interpret: bool, mask: AttnMask = CAUSAL):
     bh, t_pad, d = q.shape
@@ -903,11 +955,26 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
     # key block IS the row, fetched once a head (its block index no longer
     # depends on the query block), and the kernel walks the row's tiles
     # itself (`_fwd_kernel`: needs the diagonal to cross one tile a query
-    # block, the square one). Otherwise the grid walks them.
-    row_walk = (num_kb > 1 and block_q == block_k
-                and 2 * t_pad * (_round_up(d, 128) + _round_up(dv, 128))
-                * k.dtype.itemsize <= KV_ROW_VMEM_BYTES)
-    gridded = num_kb > 1 and not row_walk
+    # block, the square one). Otherwise the grid walks them. Decided from
+    # what this call sees.
+    resident = _fwd_resident_bytes(t_pad, d, dv, k.dtype.itemsize)
+    if num_kb == 1:
+        walk = "tile"
+    elif block_q == block_k and resident <= KV_ROW_VMEM_BYTES:
+        walk = "row"
+    else:
+        walk = "grid"
+    tracer = current_tracer()
+    if tracer is not None:
+        tracer.instant("flash_fwd_walk", walk=walk, t=t_pad, d=d, dv=dv,
+                       group=hq // hkv, resident_bytes=resident,
+                       budget_bytes=KV_ROW_VMEM_BYTES, mask=mask.kind,
+                       window=mask.window)
+    row_walk, gridded = walk == "row", walk == "grid"
+    # a row the default scoped VMEM holds asks for nothing: the call is then
+    # the one it has been since PR 34
+    vmem_limit = _vmem_limit(resident) \
+        if row_walk and resident > KV_ROW_SCOPED_BYTES else None
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, t_real=t_real,
@@ -953,7 +1020,8 @@ def _fwd_call(q, k, v, *, t_real: int, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ] if gridded else [],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit),
         cost_estimate=pl.CostEstimate(
             flops=2 * (d + dv) * entries,
             bytes_accessed=(2 * q.size + bh * t_pad * dv) * q.dtype.itemsize,

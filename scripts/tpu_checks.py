@@ -1,6 +1,7 @@
 """Compiled-on-hardware validation of the Pallas kernels against the XLA
 oracles: flash attention fwd+bwd (the train default, the fused, the
-resident and the split block paths, GQA routing), the positional block
+resident and the split block paths, GQA routing; the forward's row walk at
+16 MiB of resident K and V against its gridded walk), the positional block
 kernel (ring attention's building block) o + lse + bwd, and the
 paged-attention kernel (decode and a prefill chunk, native and int8 pools,
 `return_lse`) against `models.decode._gather_page_view`; and the sorted
@@ -38,7 +39,9 @@ import numpy as np
 from distributed_pytorch_from_scratch_tpu.models.decode import (  # noqa: E402
     _gather_page_view)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (  # noqa: E402
-    causal_attention_xla)
+    CAUSAL, causal_attention_xla, sliding_window)
+from distributed_pytorch_from_scratch_tpu.ops.pallas import (  # noqa: E402
+    flash_attention as fa_mod)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (  # noqa: E402
     block_attention, flash_attention)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.paged_attention import (  # noqa: E402
@@ -84,6 +87,58 @@ def check_grads(name, loss_got, loss_ref, args):
     for n_, ref_g, got_g in zip("qkv", g_ref, g_got):
         atol = 3e-1 * max(1.0, float(jnp.max(jnp.abs(ref_g))))
         record(f"{name} d{n_}", max_err(got_g, ref_g), atol, secs)
+
+
+def flash_walk_checks(interp: bool, dtype, tol):
+    """The forward's two walks at the shapes where the row walk asks Mosaic
+    for more than its default scoped VMEM (a head's K and V resident are 16
+    MiB): the sixteen-thousand-row cell's, causal and under its window, and
+    the hybrid cell's 256-wide head. The gridded walk (the budget at 0) is
+    the reference: o and lse of `_fwd_call`, and the three gradients through
+    `flash_attention`, whose backward reads the forward's o and lse."""
+    cases = [("16k 128/128 group 7 causal", 16384, 128, 7, 0, 1024),
+             ("16k 128/128 group 7 window 4096", 16384, 128, 7, 4096, 1024),
+             ("8k 256/256 group 8 causal", 8192, 256, 8, 0, 1024)]
+    if interp:
+        cases = [("group 7 window", 1024, 16, 7, 600, 128)]
+    key = jax.random.key(52)
+    budget = fa_mod.KV_ROW_VMEM_BYTES
+    for tag, t, d, group, window, blk in cases:
+        mask = sliding_window(window) if window else CAUSAL
+        q = jax.random.normal(jax.random.fold_in(key, 1), (1, 2 * group, t, d),
+                              dtype)
+        k = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, t, d), dtype)
+        v = jax.random.normal(jax.random.fold_in(key, 3), (1, 2, t, d), dtype)
+        flat = lambda x: x.reshape(-1, t, d)
+        fwd = lambda q, k, v: fa_mod._fwd_call(
+            flat(q), flat(k), flat(v), t_real=t, block_q=blk, block_k=blk,
+            hq=2 * group, hkv=2, interpret=interp, mask=mask)
+        flash = lambda q, k, v: flash_attention(
+            q, k, v, block_q=blk, block_k=blk, bwd_block_q=blk,
+            bwd_block_k=blk, interpret=interp, mask=mask)
+        grads = lambda: jax.jit(jax.grad(
+            lambda *a: jnp.sum(flash(*a).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2)))(q, k, v)
+        assert fa_mod._fwd_resident_bytes(t, d, d, q.dtype.itemsize) \
+            <= budget, "the row walk is not taken at this shape"
+        try:
+            fa_mod.KV_ROW_VMEM_BYTES = 0
+            o_ref, lse_ref = jax.jit(fwd)(q, k, v)
+            g_ref = grads()
+        finally:
+            fa_mod.KV_ROW_VMEM_BYTES = budget
+        row = jax.jit(fwd)
+        check(f"flash fwd row against grid [{tag}] o",
+              lambda: row(q, k, v)[0], o_ref, tol)
+        check(f"flash fwd row against grid [{tag}] lse",
+              lambda: row(q, k, v)[1], lse_ref, tol)
+        t0 = time.time()
+        g_got = jax.block_until_ready(grads())
+        for n_, ref_g, got_g in zip("qkv", g_ref, g_got):
+            record(f"flash row against grid [{tag}] d{n_}",
+                   max_err(got_g, ref_g),
+                   tol * max(1.0, float(jnp.max(jnp.abs(ref_g)))),
+                   time.time() - t0)
 
 
 def paged_oracle(q, k_pool, v_pool, tbl, start, ps):
@@ -342,6 +397,10 @@ def main():
               causal_attention_xla(q, k, v), tol)
         check_grads(f"flash [{tag}]", loss(flash),
                     loss(causal_attention_xla), (q, k, v))
+
+    # --- the forward's row walk where it asks for scoped VMEM of its own,
+    # against the gridded walk
+    flash_walk_checks(interp, dtype, tol)
 
     # --- positional block kernel (ring attention building block)
     b, hq, hkv, tq, tk, d = (1, 2, 1, 100, 100, 16) if interp else \

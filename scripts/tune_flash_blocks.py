@@ -40,6 +40,19 @@ between the two walks' dq, dk and dv. The tables beside BWD_SUBTILE:
     python scripts/tune_flash_blocks.py --backward --bh 64 --t 8192 --d 64 \
         --group 4
 
+`--forward` times the forward of several blocks a head, one call alone at
+`--bh --t --d --dv --group --window` (0: causal): the row walk (a head's K
+and V resident; the budget is opened to the row's size where the shipped
+`KV_ROW_VMEM_BYTES` is under it, which is how a size is read BEFORE it is
+admitted) beside the gridded walk in the same process, with the largest
+difference between the two walks' o and lse. The readings beside
+`KV_ROW_VMEM_BYTES`:
+
+    python scripts/tune_flash_blocks.py --forward --bh 28 --t 16384 \
+        --d 128 --group 7 --window 4096
+    python scripts/tune_flash_blocks.py --forward --bh 32 --t 8192 --d 256 \
+        --group 8
+
 `--paged` sweeps the PAGED-attention kernel instead (ISSUE 14):
 pages_per_block per (page_size, kv_dtype) serving decode shape
 (ops/pallas/paged_attention.py's autotuner table; --write_cache persists
@@ -318,6 +331,59 @@ def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
     return out
 
 
+def sweep_forward(bh, t, d, dv=None, group=1, window=0, block=1024,
+                  iters=10):
+    """The forward at several blocks a head, a call alone (module
+    docstring): ms a call of the row walk and of the gridded walk, and how
+    far their o and lse lie apart. Returns {walk: ms}."""
+    import distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
+        as fa
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        CAUSAL, sliding_window)
+    dv = dv or d
+    mask = sliding_window(window) if window else CAUSAL
+    key = jax.random.PRNGKey(bh)
+    kq, kk, kv_ = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (bh, t, d), jnp.bfloat16)
+    k = jax.random.normal(kk, (bh // group, t, d), jnp.bfloat16)
+    v = jax.random.normal(kv_, (bh // group, t, dv), jnp.bfloat16)
+    kw = dict(t_real=t, block_q=block, block_k=block, hq=group, hkv=1,
+              interpret=False, mask=mask)
+    budget = fa.KV_ROW_VMEM_BYTES
+    resident = fa._fwd_resident_bytes(t, d, dv, 2)
+    print(f"forward alone: bh{bh} t{t} d{d}/{dv} group {group} "
+          f"{'window ' + str(window) if window else 'causal'} block {block}; "
+          f"K and V resident are {resident / 2 ** 20:.1f} MiB of a budget "
+          f"of {budget / 2 ** 20:.0f}", flush=True)
+    out, got = {}, {}
+    try:
+        for walk, opened in (("row", max(budget, resident)), ("grid", 0)):
+            fa.KV_ROW_VMEM_BYTES = opened
+            fn = jax.jit(lambda *a: fa._fwd_call(*a, **kw))
+            t0 = time.perf_counter()
+            try:
+                compiled = fn.lower(q, k, v).compile()
+            except Exception as e:  # noqa: BLE001
+                print(f"  {walk:5s} FAILED {type(e).__name__}: "
+                      f"{str(e)[-300:]!r}", flush=True)
+                continue
+            compile_s = time.perf_counter() - t0
+            out[walk] = device_ms(compiled, q, k, v, match="flash_fwd",
+                                  iters=iters)
+            got[walk] = compiled(q, k, v)
+            print(f"  {walk:5s} walk {out[walk]:8.3f} ms   (trace, lower and "
+                  f"compile {compile_s:5.1f} s)", flush=True)
+    finally:
+        fa.KV_ROW_VMEM_BYTES = budget
+    if len(got) == 2:
+        for name, a, b in zip(("o", "lse"), got["row"], got["grid"]):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            print(f"  {name}: row walk against gridded, largest difference "
+                  f"{float(jnp.abs(a - b).max()):.3e} of "
+                  f"{float(jnp.abs(b).max()):.3e}", flush=True)
+    return out
+
+
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--subtile", action="store_true",
@@ -328,8 +394,14 @@ def parse_args(argv=None):
                          "--bh --t --d --dv --group: the resident walk "
                          "whole, its DMA, each product knocked out, and "
                          "the two split kernels")
+    ap.add_argument("--forward", action="store_true",
+                    help="the forward of several blocks a head alone at "
+                         "--bh --t --d --dv --group --window: the row walk "
+                         "beside the gridded walk")
+    ap.add_argument("--window", type=int, default=0,
+                    help="--forward: a sliding window (0: causal)")
     ap.add_argument("--group", type=int, default=1,
-                    help="--backward: query heads a kv head")
+                    help="--backward, --forward: query heads a kv head")
     ap.add_argument("--bh", default="192,80",
                     help="--subtile: comma-separated batch*heads a chip")
     ap.add_argument("--edges", default="128,256,512,1024",
@@ -406,6 +478,12 @@ def main():
             for block in (args.blocks or "1024").split(","):
                 sweep_backward(int(bh), args.t, args.d, args.dv, args.group,
                                int(block), iters=min(args.iters, 10))
+        return
+    if args.forward:
+        for bh in args.bh.split(","):
+            sweep_forward(int(bh), args.t, args.d, args.dv, args.group,
+                          args.window, int(args.blocks or 1024),
+                          iters=min(args.iters, 10))
         return
     if args.subtile:
         return sweep_subtiles([int(x) for x in args.bh.split(",")],

@@ -763,8 +763,8 @@ def test_subtile_under_shard_map():
 # two pairs of widths, one under each of the forward's two sub-tile shapes.
 
 
-def _fwd_grid(q, k, v, **kw):
-    """(grid, K's block shape) of the forward's pallas_call."""
+def _fwd_pallas_call(q, k, v, **kw):
+    """The forward's pallas_call equation (q, k, v: arrays or shapes)."""
     def calls(jaxpr):
         for e in jaxpr.eqns:
             if e.primitive.name == "pallas_call":
@@ -773,7 +773,12 @@ def _fwd_grid(q, k, v, **kw):
                 yield from calls(sub)
 
     jaxpr = jax.make_jaxpr(lambda *a: flash_attention(*a, **kw))(q, k, v)
-    gm = next(calls(jaxpr.jaxpr)).params["grid_mapping"]
+    return next(calls(jaxpr.jaxpr))
+
+
+def _fwd_grid(q, k, v, **kw):
+    """(grid, K's block shape) of the forward's pallas_call."""
+    gm = _fwd_pallas_call(q, k, v, **kw).params["grid_mapping"]
     return tuple(gm.grid), tuple(getattr(b, "block_size", b) for b in
                                  gm.block_mappings[1].block_shape)
 
@@ -916,11 +921,50 @@ def test_the_row_fits_by_bytes_alone(monkeypatch):
     assert _fwd_grid(q, k, v, **kw) == ((1, 4, 4), (1, 256, 24))
     # one key block a head is neither: the grid the table's winner has had
     assert _fwd_grid(q, k, v) == ((1, 1, 1), (1, 1024, 24))
-    # the benchmark's latent shape sits inside the shipped budget, the same
-    # widths at twice the length do not
+    # the shipped budget is what a chip run has read beside the gridded walk
+    # (PR 52), not what Mosaic's default scoped limit compiles: 32 MiB.
+    # Inside it, in bf16: the latent shape (4096 at 192 / 128: 6 MiB), 8192
+    # at 192 / 128 (12), the two cells whose rows are 16 (16,384 at 128 /
+    # 128, 8192 at 256 / 256), and at the edge 32,768 at 128 / 128 and
+    # 16,384 at 256 / 256; outside it either edge shape at twice the length
+    # (64), which nothing has timed
     monkeypatch.undo()
-    fits = lambda t: 2 * t * (256 + 128) * 2 <= fa_mod.KV_ROW_VMEM_BYTES
-    assert fits(4096) and not fits(8192)
+    mib = lambda t, d, dv: fa_mod._fwd_resident_bytes(t, d, dv, 2) / 2 ** 20
+    assert (mib(4096, 192, 128), mib(8192, 192, 128)) == (6, 12)
+    assert mib(16384, 128, 128) == mib(8192, 256, 256) == 16
+    assert mib(32768, 128, 128) == mib(16384, 256, 256) == 32 \
+        == fa_mod.KV_ROW_VMEM_BYTES / 2 ** 20
+    assert mib(65536, 128, 128) == mib(32768, 256, 256) == 64
+    # a row the default scoped limit compiles asks Mosaic for nothing, as
+    # before PR 52; a row over it asks for its size and the body's room
+    assert fa_mod.KV_ROW_SCOPED_BYTES == 8 * 2 ** 20 < fa_mod.KV_ROW_VMEM_BYTES
+    assert fa_mod._vmem_limit(16 * 2 ** 20) == 40 * 2 ** 20
+
+
+def _fwd_limit(t, d, dv, **kw):
+    """The scoped VMEM the forward's pallas_call asks Mosaic for (None:
+    nothing asked, the default)."""
+    shape = lambda w: jax.ShapeDtypeStruct((1, 1, t, w), jnp.bfloat16)
+    params = _fwd_pallas_call(shape(d), shape(d), shape(dv), interpret=True,
+                              **kw).params["compiler_params"]
+    return params["mosaic_tpu"].vmem_limit_bytes
+
+
+@pytest.mark.parametrize("t,d,dv,limit", [
+    (1024, 64, 64, None),           # one tile a head
+    (4096, 192, 128, None),         # the latent cell's row, 6 MiB
+    (8192, 128, 128, None),         # 8 MiB: the most the default compiles
+    (8192, 192, 128, 36),           # 12 MiB: over it, so it asks
+    (16384, 128, 128, 40),          # the sixteen-thousand-row cell's
+    (8192, 256, 256, 40),           # the hybrid cell's
+    (32768, 128, 128, 56),          # at the budget
+    (65536, 128, 128, None)])       # over the budget: gridded, asks nothing
+def test_the_row_asks_for_scoped_vmem_only_past_the_default(t, d, dv, limit):
+    """`_fwd_call` hands Mosaic a `vmem_limit_bytes` where the resident row
+    is over what the default scoped VMEM compiles, and nowhere else: the
+    calls of every row up to 8 MiB are the ones they were."""
+    got = _fwd_limit(t, d, dv, block_q=1024, block_k=1024)
+    assert got == (limit and limit * 2 ** 20)
 
 
 # ------------------------------- the backward of several blocks a head
@@ -1165,6 +1209,51 @@ def test_flash_bwd_walk_instant_says_which_walk(tmp_path):
         ("row", 4096, 192, 128, 1, 34), ("grid", 8192, 256, 256, 8, 96),
         ("tile", 1024, 64, 64, 1, 6), ("row", 8192, 64, 64, 4, 56)]
     assert all(e["budget_bytes"] == fa_mod.BWD_ROW_VMEM_BYTES for e in events)
+
+
+def test_flash_fwd_walk_instant_says_which_walk(tmp_path, monkeypatch):
+    """At trace time `_fwd_call` says on the program's tracer which walk it
+    took and the bytes it reckoned: `tile` for one key block a head, `row`
+    for a head whose K and V stay resident, `grid` for one over the budget
+    (or blocks not square)."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        sliding_window)
+
+    def trace(hq, hkv, t, d, dv, block_k=1024, **kw):
+        arg = lambda rows, w: jax.ShapeDtypeStruct((rows, t, w), jnp.bfloat16)
+        jax.eval_shape(lambda *a: fa_mod._fwd_call(
+            *a, t_real=t, block_q=1024, block_k=block_k, hq=hq, hkv=hkv,
+            interpret=True, **kw), arg(hq, d), arg(hkv, d), arg(hkv, dv))
+
+    tracer = SpanTracer(str(tmp_path))
+    try:
+        trace(2, 2, 1024, 64, 64)       # the GPT-2 cells': one tile
+        trace(2, 2, 4096, 192, 128)     # the latent-attention cell's head
+        trace(8, 1, 8192, 256, 256)     # the hybrid cell's, 16 MiB
+        trace(7, 1, 16384, 128, 128, mask=sliding_window(4096))
+        trace(1, 1, 65536, 128, 128)    # four times that: over the budget
+        trace(2, 2, 4096, 64, 64, block_k=2048)     # blocks not square
+        monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", 0)
+        trace(2, 2, 4096, 192, 128)
+    finally:
+        tracer.close()
+    events = [json.loads(line)["args"] for line in
+              open(tmp_path / "trace.jsonl")
+              if json.loads(line)["name"] == "flash_fwd_walk"]
+    assert [(e["walk"], e["t"], e["d"], e["dv"], e["group"],
+             e["resident_bytes"] // 2 ** 20, e["mask"], e["window"])
+            for e in events] == [
+        ("tile", 1024, 64, 64, 1, 1, "causal", 0),
+        ("row", 4096, 192, 128, 1, 6, "causal", 0),
+        ("row", 8192, 256, 256, 8, 16, "causal", 0),
+        ("row", 16384, 128, 128, 7, 16, "sliding_window", 4096),
+        ("grid", 65536, 128, 128, 1, 64, "causal", 0),
+        ("grid", 4096, 64, 64, 1, 4, "causal", 0),
+        ("grid", 4096, 192, 128, 1, 6, "causal", 0)]
+    assert [e["budget_bytes"] for e in events] == [32 * 2 ** 20] * 6 + [0]
 
 
 # `_bwd_call`'s jaxpr, kernel bodies and all, as the commit before the

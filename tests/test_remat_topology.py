@@ -192,15 +192,21 @@ def test_cell_2_backward_body_exchanges_the_dp_gradients_under_dots(
     assert hidden >= 5, hidden
 
 
-@pytest.mark.parametrize("t,d,dv,t_real", [
-    (4096, 192, 128, 4096),     # the latent-attention cell's forward
-    (4096, 192, 128, 4000),     # a second plan for the block t_real cuts
-    (8192, 64, 64, 8192)])      # as long a resident row as the budget takes
+@pytest.mark.parametrize("t,d,dv,t_real,asks", [
+    (4096, 192, 128, 4096, False),  # the latent-attention cell's forward
+    (4096, 192, 128, 4000, False),  # a second plan for the block t_real cuts
+    (8192, 64, 64, 8192, False),    # as long a row as the default limit takes
+    (16384, 128, 128, 16384, True),     # the sixteen-thousand-row cell's
+    (8192, 256, 256, 8192, True),       # the hybrid cell's attention layer
+    (32768, 128, 128, 32768, True)])    # as long a row as the budget takes
 def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
-        topo, described_tpu, t, d, dv, t_real):
+        topo, described_tpu, t, d, dv, t_real, asks):
     """Mosaic takes the multi-block forward that keeps a head's K and V in
-    VMEM (PR 34) at the table's blocks and inside its default scoped VMEM:
-    one kernel an attention, grid (b*h, query blocks, 1)."""
+    VMEM (PR 34) at the table's blocks: one kernel an attention, grid (b*h,
+    query blocks, 1). Rows of up to 8 MiB compile inside its default scoped
+    VMEM and their call names no limit; the two cells whose row is 16 MiB
+    (PR 52) carry the limit `_fwd_call` asks for, 40 MiB, and a row of 32
+    MiB, the budget, 56."""
     from jax.sharding import SingleDeviceSharding
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         flash_attention as fa)
@@ -208,13 +214,22 @@ def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda w: jax.ShapeDtypeStruct((8, t, w), jnp.bfloat16,
                                          sharding=chip)
-    assert 2 * t * (fa._round_up(d, 128) + fa._round_up(dv, 128)) * 2 \
-        <= fa.KV_ROW_VMEM_BYTES
+    resident = fa._fwd_resident_bytes(t, d, dv, 2)
+    assert resident <= fa.KV_ROW_VMEM_BYTES
+    assert asks == (resident > fa.KV_ROW_SCOPED_BYTES)
     text = jax.jit(lambda q, k, v: fa._fwd_call(
         q, k, v, t_real=t_real, block_q=blocks.block_q,
         block_k=blocks.block_k, hq=1, hkv=1, interpret=False)).lower(
             arg(d), arg(d), arg(dv)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    scoped = re.search(r'"scoped_memory_configs":\[([^\]]*)\]', call).group(1)
+    if asks:
+        assert f'"size":"{fa._vmem_limit(resident)}"' in scoped
+        assert fa._vmem_limit(resident) == resident + 24 * 2 ** 20
+    else:
+        assert scoped == ""
 
 
 @pytest.mark.parametrize("t,d,dv,group,t_real,names", [

@@ -81,6 +81,10 @@ CASES = [
     # `flash_bwd_dkv_window`, planned by the grid's guards) and resident
     (384, 200, 128, 14, 2, "grid"), (384, 200, 128, 7, 1, "row"),
     (512, 256, 128, 7, 1, "grid"),
+    # the eighth cell's forward since PR 52, in small: a group of 7, the head
+    # resident, a window that reaches back over five key tiles of the six
+    # (one edge tile, a loop over four whole ones, the diagonal)
+    (768, 600, 128, 7, 1, "row"),
 ]
 
 
@@ -95,7 +99,9 @@ def test_the_kernels_under_the_window_equal_the_dense_path(
     several sub-tiles, and one tile a head (the fused backward); last the
     eighth cell's grouping, 7 query heads a key-value head, under the split
     backward (two key-value heads, a window that is no multiple of the
-    block; and a window of two blocks) and the resident one."""
+    block; and a window of two blocks) and the resident one; and that
+    grouping with the head resident under a window of more than four key
+    tiles."""
     _walks(monkeypatch, walk)
     mask = sliding_window(w)
     q, k, v, wt = _operands(t, hq, hkv)
@@ -191,23 +197,25 @@ def test_the_cells_shape_computes_under_a_third_over_the_live_entries():
         > 1.8 * fwd["work_elems"]
 
 
-def test_the_eighth_cells_shape_takes_the_gridded_forward_and_split_backward(
+def test_the_eighth_cells_shape_takes_the_resident_forward_and_split_backward(
         tmp_path):
     """16,384 rows under a window of 4096 at head 128 and a group of 7,
-    blocks of 1024: a head's K and V, double-buffered, are 16 MiB (over
-    `KV_ROW_VMEM_BYTES`: the grid walks the key tiles, its index maps
-    clamped to the 5 a window row computes of 16) and what the resident
+    blocks of 1024: a head's K and V, double-buffered, are 16 MiB, inside
+    `KV_ROW_VMEM_BYTES` since PR 52 (the forward keeps them resident and
+    asks Mosaic for the scoped VMEM: they are over `KV_ROW_SCOPED_BYTES`;
+    until then the grid walked the key tiles), and what the resident
     backward would keep is 117 MB (over `BWD_ROW_VMEM_BYTES`: the split
     kernels). Both walks run the SAME tile plans, which is what
     `flash_tile_stats` reports: the forward computes 1.125 of the band's
     58,722,304 live entries, the backward 1.062; the full layer's triangle
-    beside it 1.031 and 1.016. The tracer says which walk the backward
-    took."""
+    beside it 1.031 and 1.016. The tracer says which walk each took."""
     import json
 
     from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
     t, d, group, mask = 16384, 128, 7, sliding_window(4096)
-    assert 2 * t * (d + d) * 2 == 2 * fa_mod.KV_ROW_VMEM_BYTES
+    assert fa_mod.KV_ROW_VMEM_BYTES >= fa_mod._fwd_resident_bytes(
+        t, d, d, 2) == 2 * t * (d + d) * 2 == 16 * 2 ** 20 \
+        > fa_mod.KV_ROW_SCOPED_BYTES
     assert fa_mod._bwd_resident_bytes(t, d, d, 2, group) == 117_440_512 \
         > fa_mod.BWD_ROW_VMEM_BYTES
     fwd = flash_tile_stats(t, head_dim=d, mask=mask)
@@ -221,25 +229,27 @@ def test_the_eighth_cells_shape_takes_the_gridded_forward_and_split_backward(
     assert full["work_elems"] == 138_412_032
     assert fwd["ideal_elems"] / full["ideal_elems"] == pytest.approx(0.4375,
                                                                      abs=1e-4)
-    # the walk the backward takes at this shape, as the program's tracer
-    # records it (shapes only: nothing runs)
+    # the walks taken at this shape, as the program's tracer records them
+    # (shapes only: nothing runs)
     tracer = SpanTracer(str(tmp_path))
     arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (rows, t, w), dtype)
+    kw = dict(t_real=t, block_q=1024, block_k=1024, hq=group, hkv=1,
+              interpret=True, mask=mask)
     try:
+        jax.eval_shape(lambda *a: fa_mod._fwd_call(*a, **kw),
+                       arg(group, d), arg(1, d), arg(1, d))
         jax.eval_shape(
-            lambda *a: fa_mod._bwd_call(
-                *a, t_real=t, block_q=1024, block_k=1024, hq=group, hkv=1,
-                interpret=True, mask=mask),
+            lambda *a: fa_mod._bwd_call(*a, **kw),
             arg(group, d), arg(1, d), arg(1, d), arg(group, d),
             arg(group, 1, jnp.float32), arg(group, d))
     finally:
         tracer.close()
-    events = [json.loads(line)["args"] for line in
-              open(tmp_path / "trace.jsonl")
-              if json.loads(line)["name"] == "flash_bwd_walk"]
-    assert [(e["walk"], e["window"], e["group"]) for e in events] == [
-        ("grid", 4096, 7)]
+    events = [json.loads(line) for line in open(tmp_path / "trace.jsonl")]
+    assert [(e["name"], e["args"]["walk"], e["args"]["window"],
+             e["args"]["group"]) for e in events
+            if e["name"].startswith("flash_")] == [
+        ("flash_fwd_walk", "row", 4096, 7), ("flash_bwd_walk", "grid", 4096, 7)]
 
 
 def test_a_window_over_the_whole_sequence_is_the_triangles_text():
