@@ -102,8 +102,9 @@ class TrainObserver:
         self._local.depth = depth + 1
         t0 = time.perf_counter()
         try:
-            with self.tracer.span(name or bucket, cat=bucket, **args):
-                yield
+            with self.tracer.span(name or bucket, cat=bucket,
+                                  **args) as found:
+                yield found
         finally:
             self._local.depth = depth
             if depth == 0:

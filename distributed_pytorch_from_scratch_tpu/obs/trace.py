@@ -58,7 +58,8 @@ def current_tracer() -> "Optional[SpanTracer]":
 
 def span_of(tracer, name: str, cat: Optional[str] = None, **args):
     """`tracer.span(...)`, or nothing at all where a module that times its
-    own work was handed no tracer."""
+    own work was handed no tracer (`as found` is then None: there is no
+    event to add an argument to)."""
     if tracer is None:
         return nullcontext()
     return tracer.span(name, cat=cat, **args)
@@ -135,16 +136,24 @@ class SpanTracer:
         """The with-block as a profiler annotation `prog.<name>` carrying
         `args` (what caused the span: `step=` in the loop, the save's step
         on the writer thread), and, when the timeline is on, as a complete
-        event `<name>` of trace.jsonl."""
+        event `<name>` of trace.jsonl.
+
+        Yields a dict for what the block learns only as it runs (the bytes
+        a transfer moved): `with tracer.span(...) as found: found["bytes"]
+        = n`. The event is written when the span ends and carries `found`
+        beside `args`; the annotation was entered before and keeps `args`
+        alone."""
+        found: dict = {}
         with TraceAnnotation(ANNOTATION_PREFIX + name, **args):
             if not self.enabled:
-                yield
+                yield found
                 return
             t0 = self._clock()
             try:
-                yield
+                yield found
             finally:
-                self.complete_span(name, t0, self._clock(), cat=cat, **args)
+                self.complete_span(name, t0, self._clock(), cat=cat,
+                                   **{**args, **found})
 
     def complete_span(self, name: str, start: float, end: float,
                       cat: Optional[str] = None, tid: Optional[int] = None,

@@ -144,7 +144,10 @@ def save_checkpoint(save_dir: str, step: int, avg_loss: float, params: Any,
     a "ckpt.d2h" span and the slicing, npz writes and pruning a
     "ckpt.write" span, both carrying the save's `step`, on whichever thread
     performs them (the async writer shows up as its own track in the
-    timeline); the async path's on-device copy is a "ckpt.snapshot" span on
+    timeline), and in the timeline's event what they moved: `bytes` over
+    D2H, `bytes` and `files` on disk, the numbers `AsyncCheckpointer` adds
+    to its counters, so a save's two rates can be read from the timeline
+    alone. The async path's on-device copy is a "ckpt.snapshot" span on
     the caller's thread.
 
     `mesh_axes`: the saving mesh (a live Mesh, or (axis, size) pairs) for
@@ -163,17 +166,24 @@ def save_checkpoint(save_dir: str, step: int, avg_loss: float, params: Any,
     stats: Dict[str, int] = {}
 
     def write(params, opt_state) -> List[str]:
-        with span_of(tracer, "ckpt.d2h", cat="checkpoint", step=step):
+        with span_of(tracer, "ckpt.d2h", cat="checkpoint",
+                     step=step) as found:
             params_np = _get_leafwise(params)
             moments_np = (None if opt_state is None else
                           (_get_leafwise(opt_state.mu),
                            _get_leafwise(opt_state.nu)))
             stats["bytes_moved"] = sum(
                 x.nbytes for x in jax.tree.leaves((params_np, moments_np)))
-        with span_of(tracer, "ckpt.write", cat="checkpoint", step=step):
+            if found is not None:
+                found["bytes"] = stats["bytes_moved"]
+        with span_of(tracer, "ckpt.write", cat="checkpoint",
+                     step=step) as found:
             paths = _write(params_np, moments_np)
             stats["files"] = len(paths)
             stats["bytes_written"] = sum(os.path.getsize(p) for p in paths)
+            if found is not None:
+                found.update(bytes=stats["bytes_written"],
+                             files=stats["files"])
         return paths
 
     def _write(params_np, moments_np) -> List[str]:
@@ -248,7 +258,9 @@ class AsyncCheckpointer:
     moments to the checkpoint's canonical layout, run `gather` if given,
     and start `save_checkpoint(async_write=True)`, whose on-device copy is
     "ckpt.snapshot". The writer thread then records "ckpt.d2h" and
-    "ckpt.write". All five carry the save's `step`, cat "checkpoint".
+    "ckpt.write", whose events also carry the `bytes` (and `files`) this
+    object's counters grow by. All five carry the save's `step`, cat
+    "checkpoint".
 
     `gather(params, opt_state) -> (params, opt_state) | None`: the
     multi-host hook. Cross-host shards are not addressable from one

@@ -17,6 +17,7 @@ import json
 import os
 import re
 import threading
+import time
 from contextlib import contextmanager
 
 import jax
@@ -44,15 +45,18 @@ CFG = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=4, num_layers=2,
 
 
 class Recorder:
-    """Stands in for a SpanTracer: keeps (name, cat, args, thread)."""
+    """Stands in for a SpanTracer: keeps (name, cat, args, thread), the
+    args with what the block added to the dict the span yields."""
 
     def __init__(self):
         self.spans = []
 
     @contextmanager
     def span(self, name, cat=None, **args):
-        yield
-        self.spans.append((name, cat, args, threading.get_ident()))
+        found = {}
+        yield found
+        self.spans.append((name, cat, {**args, **found},
+                           threading.get_ident()))
 
     def named(self, name):
         return [s for s in self.spans if s[0] == name]
@@ -86,8 +90,8 @@ def test_span_is_a_profiler_annotation_on_its_thread(tmp_path, jsonl):
     capture.maybe_start(0)
 
     def writer():
-        with tracer.span("ckpt.write", cat="checkpoint", step=25):
-            pass
+        with tracer.span("ckpt.write", cat="checkpoint", step=25) as found:
+            found["bytes"] = 4096       # known only when the span ends
 
     with tracer.span("data_wait", cat="data_wait", step=7):
         pass
@@ -104,6 +108,8 @@ def test_span_is_a_profiler_annotation_on_its_thread(tmp_path, jsonl):
     assert set(by_name) == {"prog.data_wait", "prog.h2d", "prog.ckpt.write"}
     assert int(by_name["prog.data_wait"][1]["step"]) == 7
     assert int(by_name["prog.ckpt.write"][1]["step"]) == 25
+    # the annotation was entered before the block ran: its entry arguments
+    assert "bytes" not in by_name["prog.ckpt.write"][1]
     # the loop's two spans share a line; the writer thread has its own
     assert by_name["prog.data_wait"][0] == by_name["prog.h2d"][0]
     assert by_name["prog.ckpt.write"][0] != by_name["prog.h2d"][0]
@@ -118,7 +124,9 @@ def test_span_is_a_profiler_annotation_on_its_thread(tmp_path, jsonl):
         # its `compile.*` spans are here too)
         evs = [e for e in evs if e.get("cat") != "compile"]
         assert [e["name"] for e in evs] == ["data_wait", "h2d", "ckpt.write"]
-        assert evs[2]["args"] == {"step": 25} and evs[2]["tid"] != evs[0]["tid"]
+        # the event is written when the span ends: entry and late arguments
+        assert evs[2]["args"] == {"step": 25, "bytes": 4096}
+        assert evs[0]["args"] == {"step": 7} and evs[2]["tid"] != evs[0]["tid"]
     else:
         assert not os.path.exists(tmp_path / "timeline")
 
@@ -259,7 +267,146 @@ def test_async_checkpointer_writes_the_file_save_checkpoint_writes(
     written = [(s[0], s[2]["step"]) for s in rec.spans if s[3] != me]
     assert written == [("ckpt.d2h", 50), ("ckpt.write", 50),
                        ("ckpt.d2h", 60), ("ckpt.write", 60)]
+    # only the writer's two spans learn anything as they run
+    assert {s[0] for s in rec.spans if set(s[2]) != {"step"}} == {
+        "ckpt.d2h", "ckpt.write"}
     assert {s[1] for s in rec.spans} == {"checkpoint"}
+
+
+def _events(log_dir, *names):
+    """The complete events of a closed tracer's trace.jsonl called one of
+    `names`, in the order they were written (the order they ended)."""
+    with open(os.path.join(log_dir, "trace.jsonl")) as f:
+        evs = [json.loads(line) for line in f]
+    return [e for e in evs if e.get("ph") == "X" and e["name"] in names]
+
+
+def test_a_saves_writer_spans_carry_what_the_counters_grow_by(tmp_path):
+    """`ckpt.d2h` and `ckpt.write` in trace.jsonl: `bytes` (and `files`)
+    equal, save by save, to what `AsyncCheckpointer.bytes_moved`,
+    `bytes_written` and `files` grew by at that save's join."""
+    model, params, opt = _tiny_state()
+    tracer = SpanTracer(str(tmp_path / "timeline"))
+    ckpt = AsyncCheckpointer(str(tmp_path / "ckpt"), model, 1, tracer=tracer)
+    grew = {}
+    for step in (10, 20):
+        before = (ckpt.bytes_moved, ckpt.bytes_written, ckpt.files)
+        ckpt.save(step, jnp.asarray(1.0), params, opt)
+        ckpt.join()
+        grew[step] = tuple(now - was for now, was in zip(
+            (ckpt.bytes_moved, ckpt.bytes_written, ckpt.files), before))
+    tracer.close()
+    d2h = _events(str(tmp_path / "timeline"), "ckpt.d2h")
+    write = _events(str(tmp_path / "timeline"), "ckpt.write")
+    assert [e["args"]["step"] for e in d2h] == [10, 20]
+    for moved, written in zip(d2h, write):
+        step = moved["args"]["step"]
+        assert written["args"]["step"] == step
+        assert set(moved["args"]) == {"step", "bytes"}
+        assert set(written["args"]) == {"step", "bytes", "files"}
+        assert (moved["args"]["bytes"], written["args"]["bytes"],
+                written["args"]["files"]) == grew[step]
+    assert grew[10][0] == 3 * sum(
+        x.nbytes for x in jax.tree.leaves(params)) and grew[10][2] == 1
+    # the caller-side spans learn nothing as they run: `step` alone
+    for e in _events(str(tmp_path / "timeline"), "ckpt.loss_sync",
+                     "ckpt.join_prev", "ckpt.snapshot"):
+        assert set(e["args"]) == {"step"}
+
+
+def test_a_plain_save_on_the_loops_thread_carries_its_bytes_too(tmp_path):
+    """`save_checkpoint(async_write=False)` under what `train()` hands out:
+    on the loop's thread a span goes through `TrainObserver.span`, which
+    yields what the tracer's span yields."""
+    model, params, opt = _tiny_state()
+    observer = TrainObserver(str(tmp_path / "obs"), sentinel=False)
+    (path,) = save_checkpoint(str(tmp_path / "ckpt"), 5, 1.0, params,
+                              model.canonical_specs(), 1, opt_state=opt,
+                              tracer=observer.loop_spans)
+    observer.close(print_summary=False)
+    (moved,) = _events(str(tmp_path / "obs"), "ckpt.d2h")
+    (written,) = _events(str(tmp_path / "obs"), "ckpt.write")
+    assert moved["args"] == {"step": 5, "bytes": 3 * sum(
+        x.nbytes for x in jax.tree.leaves(params))}
+    assert written["args"] == {"step": 5, "bytes": os.path.getsize(path),
+                               "files": 1}
+
+
+class SteppedClock:
+    """A clock that moves only when told to, from any thread, and counts
+    how often it was read (a span reads it when it starts and when it
+    ends)."""
+
+    def __init__(self):
+        self._t, self.reads, self._lock = 0.0, 0, threading.Lock()
+
+    def __call__(self):
+        with self._lock:
+            self.reads += 1
+            return self._t
+
+    def advance(self, seconds):
+        with self._lock:
+            self._t += seconds
+
+
+def test_prefetch_window_covers_the_draw_and_the_transform_not_the_put(
+        tmp_path):
+    """On a clock that moves 1 s in every draw, 2 s in every transform and
+    100 s while the worker waits for room in the queue, every
+    `prefetch_window` lasts 3 s."""
+    clock = SteppedClock()
+    tracer = SpanTracer(str(tmp_path), clock=clock)
+    made_second = threading.Event()
+
+    def draws():
+        for i in range(4):
+            clock.advance(1.0)
+            yield i
+
+    def transform(i):
+        clock.advance(2.0)
+        if i == 1:
+            made_second.set()
+        return i
+
+    pf = Prefetcher(draws(), depth=1, transform=transform, tracer=tracer)
+    # item 0 fills the queue; the worker makes item 1 and waits to put it
+    assert made_second.wait(timeout=30)
+    deadline = time.monotonic() + 30
+    while clock.reads < 1 + 2 * 2:      # the tracer's epoch, two whole spans
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    clock.advance(100.0)
+    assert list(pf) == [0, 1, 2, 3]
+    tracer.close()
+    made = _events(str(tmp_path), "prefetch_window")
+    # four items (a fifth span, the draw that found the source empty, made
+    # nothing and moved no clock)
+    assert [e["dur"] for e in made if e["dur"]] == [3e6] * 4
+    assert {e["cat"] for e in made} == {"data_prep"}
+
+
+@pytest.mark.parametrize("module", ["prefetch", "checkpoint"])
+def test_without_a_tracer_a_span_is_nothing_at_all(module, tmp_path):
+    """`span_of(None, ...)` is the `nullcontext()` it was: no object with a
+    clock, no event, and `as found` is None, which is how the writer knows
+    there is nothing to add its bytes to."""
+    from contextlib import nullcontext
+    from distributed_pytorch_from_scratch_tpu.obs.trace import span_of
+    cm = span_of(None, "ckpt.d2h", cat="checkpoint", step=1)
+    assert isinstance(cm, nullcontext)
+    with cm as found:
+        assert found is None
+    if module == "prefetch":
+        assert list(Prefetcher(iter(range(3)), depth=1)) == [0, 1, 2]
+    else:
+        model, params, opt = _tiny_state()
+        ckpt = AsyncCheckpointer(str(tmp_path), model, 1)
+        ckpt.save(10, jnp.asarray(1.0), params, opt)
+        assert len(ckpt.join()) == 1 and ckpt.bytes_moved > 0
+    assert not glob.glob(str(tmp_path / "**" / "trace.jsonl"),
+                         recursive=True)
 
 
 def test_checkpointer_gather_hook_decides_who_writes(tmp_path):
