@@ -56,9 +56,9 @@ def test_the_program_counts_the_same(sizes):
     assert built.model.noise_seed == 5
     mask = built.model._attn_mask(8192)
     assert (mask.kind, mask.block, mask.half) == ("block_diffusion", 4, 4096)
-    # the chunk policy at this share: three quarters of all pairs
+    # the chunk policy at this share: one mean share of the pairs a chunk
     moe = built.model._mods["moe"]
-    assert moe.chunk_share == 0.75 and moe.chunk_rows(131072) == 98304
+    assert moe.chunk_share == 0.125 and moe.chunk_rows(131072) == 16384
 
 
 def test_the_configuration_holds_every_published_number():

@@ -167,3 +167,21 @@ def test_a_cell_that_pins_its_stream_says_replay(cell, manifest):
     if pinned:
         assert type(body["data"]["seed"]) is int and "init_seed" in body
         assert "replay" in body["why"].lower()
+
+
+REPLAYS = {"lfm2-8b-a1b.train-ep4share-b2-t8192",                 # PR 49
+           "joyai-llm-flash.train-ep16share-b4-t4096",            # PR 55
+           "sdar-30b-a3b.train-ep8share-b2-t4096"}                # PR 55
+
+
+def test_three_expert_cells_are_replays_and_three_draw_their_batches():
+    """The replays' files hold an integer `data.seed` beside `init_seed`;
+    the three other expert cells hold no `data.seed`, so `correct` is still
+    read on fresh batches there (PERF.md section 2)."""
+    bodies = {c: load("workloads", c + ".json") for c in _cells()}
+    expert = {c: b for c, b in bodies.items() if "init_seed" in b}
+    assert len(expert) == 6
+    assert {c for c, b in expert.items() if "seed" in b["data"]} == REPLAYS
+    for cell in REPLAYS:
+        assert type(expert[cell]["data"]["seed"]) is int
+        assert type(expert[cell]["init_seed"]) is int

@@ -77,7 +77,10 @@ def test_rehearse_prints_the_contracts_line(cell, chips, trace):
         if m["source"] in DEVICE_SOURCES:
             assert got["value"] is None, m["name"]
     if trace:
-        assert line["metrics"]["entry.compiles_in_window"]["value"] == 0
+        # (it moves `step_ms_p90`, so a cell that does not report that
+        # metric does not list it: test_manifest.py)
+        if "entry.compiles_in_window" in line["metrics"]:
+            assert line["metrics"]["entry.compiles_in_window"]["value"] == 0
         assert line["device"]["busy_s"] is None
     # no time taken on the CPU stands on a log line either
     for text in done.stdout.strip().splitlines()[:-1]:

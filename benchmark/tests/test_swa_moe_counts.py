@@ -65,8 +65,8 @@ def test_the_program_counts_the_same(sizes):
     assert built.model.embed_scale == pytest.approx(45.2548, abs=1e-4)
     moe = built.model._mods["moe"]
     assert (moe.score, moe.n_shared, moe.scaling) == ("sigmoid", 1, 2.826)
-    # the chunk policy at this share: three quarters of all pairs
-    assert moe.chunk_share == 0.75 and moe.chunk_rows(131072) == 98304
+    # the chunk policy at this share: one mean share of the pairs a chunk
+    assert moe.chunk_share == 0.125 and moe.chunk_rows(131072) == 16384
     # the program's FLOPs count attention at each kind's live entries
     from distributed_pytorch_from_scratch_tpu.training.metrics import (
         model_flops_per_step)
