@@ -77,6 +77,18 @@ at the first shape, 9.7 -> 7.4 at the second: `KV_ROW_VMEM_BYTES`' note).
 `_fwd_call` says which walk it took on the program's tracer too
 (`flash_fwd_walk`: `tile` / `row` / `grid`); the kernel body and the calls'
 names did not change.
+
+PR 56: the backward's row walk is admitted twice. A head whose nine
+whole-row blocks fit `BWD_ROW_VMEM_BYTES` double-buffered keeps the call it
+had; one that does not, but fits `BWD_ROW_ONCE_VMEM_BYTES` with every block
+kept ONCE (`pipeline_mode=pl.Buffered(1)`: a whole-row block changes once a
+head, so the second buffer hides one head's DMA and nothing else), takes
+the same kernel body single-buffered. t = 16,384 at 128 / 128 with a group
+of 7 (112 MiB twice, 68 once) and t = 8192 at 256 / 256 with a group of 8
+(96, 60) left the split kernels (20.9 -> 14.2 ms a call under
+`sliding_window(4096)` and 46.7 -> 29.1 causal at the first shape, 24.5 ->
+16.7 at the second: `BWD_ROW_ONCE_VMEM_BYTES`' note). `flash_bwd_walk` says
+`buffers`.
 """
 
 from __future__ import annotations
@@ -925,9 +937,53 @@ KV_ROW_SCOPED_BYTES = 8 * 2 ** 20
 # asks Mosaic for that much and the body's room (`_vmem_limit`; a v5e has
 # 128 MiB, the default scoped limit is 16). Compiled for a v5e: t = 4096 at
 # q/k 192 and v 128 in bf16 is 34 MiB; t = 8192 at 64 / 64 with a group of 4
-# is 56. t = 8192 at 256 / 256 with a group of 8 is 96 and keeps the split
-# kernels: with the body's room it passes what `_vmem_limit` will ask for.
+# or at 128 / 128 with a group of 8 is 56. t = 8192 at 256 / 256 with a
+# group of 8 is 96 and t = 16,384 at 128 / 128 with a group of 7 is 112:
+# with the body's room they pass what `_vmem_limit` will ask for, and are
+# held to `BWD_ROW_ONCE_VMEM_BYTES` instead.
 BWD_ROW_VMEM_BYTES = 64 * 2 ** 20
+
+# What a head over `BWD_ROW_VMEM_BYTES` may keep in VMEM for the same ONE
+# kernel with its nine whole-row blocks kept ONCE (`pl.Buffered(1)` on each
+# `BlockSpec` of `_bwd_row_call`; the float32 accumulators are scratch and
+# were never doubled). A block's index changes once a query head (q, dO,
+# lse, delta, dq) or once a key-value head (k, v, dk, dv), so the second
+# buffer hides one head's DMA behind the head before it and nothing else;
+# without it the kernel waits for its rows at each head's start. 68 MiB is
+# t = 16,384 at 128 / 128 with a group of 7 (44 of blocks + 24 of
+# accumulators; 112 with two buffers); t = 8192 at 256 / 256 with a group of
+# 8 is 60 (36 + 24; 96). What is asked of Mosaic is `_vmem_limit` of the
+# bytes as taken: 92 and 84 MiB of the chip's 128. t = 65,536 at 128 / 128
+# (272 MiB once) stays with the split kernels.
+# A backward call alone on v5e (TPU v5 lite, jax 0.9.0), bf16, blocks of
+# 1024, device time from a capture, the split kernels in the same process
+# with both budgets at 0 (`python scripts/tune_flash_blocks.py --backward
+# --bh 28 --t 16384 --d 128 --group 7 [--window 4096]`, `--bh 32 --t 8192
+# --d 256 --group 8`, `--bh 32 --t 8192 --d 128 --group 8`); ms a backward,
+# the row walk's dq / dk / dv within 9.8e-4 / 3.9e-3 / 3.9e-3 of the split
+# kernels' (values up to 3.1 / 6.9 / 12.8; one kernel sums dq over the key
+# tiles in float32 VMEM where the split one sums it a grid step at a time).
+# PR 56's readings; PR 54, whose change was lost to the yardstick, read the
+# same to 0.01 ms (PERF.md section 6):
+#
+#  twice / once  t, q/k / v, b*h, group  mask         split  row (buffers)  DMA
+#  112 / 68 MiB  16,384 128/128  28  7   window 4096  20.95  14.20 (1)     1.28
+#                the same                causal       46.66  29.08 (1)     1.28
+#   96 / 60 MiB   8,192 256/256  32  8   causal       24.48  16.67 (1)     1.04
+#                the same, two buffers by hand               15.75 (2)
+#   56 / 34 MiB   8,192 128/128  32  8   causal       13.10   8.24 (2)     0.67
+#                the same, kept once by hand                  8.82 (1)
+#
+# The last pair is the control: a head that fits twice loses 7% of its call
+# to the waits the second buffer hid, so it keeps both; a head that does not
+# fit twice gains a third by leaving the split kernels (seven products a
+# rectangle where the row walk runs five, and under the window 5,208 of the
+# split kernels' 7,168 grid steps fetch and compute nothing). At 96 MiB
+# Mosaic still takes two buffers a block under `_vmem_limit`'s cap of 100
+# and reads 6% faster than kept once; at 112 it refuses ("scoped allocation
+# with size 104.00M and limit 100.00M"), so the first budget stays where
+# the body's room is sure, and 96 is held to the second.
+BWD_ROW_ONCE_VMEM_BYTES = 68 * 2 ** 20
 
 
 def _vmem_limit(resident_bytes: int) -> int:
@@ -1383,29 +1439,31 @@ def _bwd_row_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_resident_bytes(t_pad: int, d: int, dv: int, itemsize: int,
-                        group: int) -> int:
+                        group: int, buffers: int = 2) -> int:
     """What `_bwd_row_kernel` keeps of a head in VMEM, as Mosaic lays it out
-    (each width padded to 128 lanes): the nine whole-row blocks
-    double-buffered, the float32 dq accumulator and, under grouped-query
-    attention, dk's and dv's."""
+    (each width padded to 128 lanes): the nine whole-row blocks, `buffers`
+    of each (the pipeline's two, or one), the float32 dq accumulator and,
+    under grouped-query attention, dk's and dv's."""
     wide, narrow = _round_up(d, 128), _round_up(dv, 128)
     blocks = t_pad * ((4 * wide + 3 * narrow) * itemsize + 2 * 128 * 4)
     scratch = t_pad * 4 * (wide + (wide + narrow if group > 1 else 0))
-    return 2 * blocks + scratch
+    return buffers * blocks + scratch
 
 
 def _bwd_row_call(q, k, v, do, lse, delta, *, t_real: int, block: int,
                   hq: int, hkv: int, interpret: bool, resident: int,
-                  mask: AttnMask = CAUSAL):
+                  buffers: int = 2, mask: AttnMask = CAUSAL):
     bh, t_pad, d = q.shape
     dv = v.shape[-1]
     bhkv = k.shape[0]
     group = hq // hkv
     num_b = t_pad // block
+    # two buffers a block are the pipeline's own: the spec names none then
+    once = {"pipeline_mode": pl.Buffered(1)} if buffers == 1 else {}
     row = lambda width, of_q: pl.BlockSpec(
         (None, t_pad, width),
         (lambda b, g: (_q_row(b, g, hq, hkv), 0, 0)) if of_q
-        else (lambda b, g: (b, 0, 0)))
+        else (lambda b, g: (b, 0, 0)), **once)
     acc = lambda width: pltpu.VMEM((t_pad, width), jnp.float32)
     entries = bh * plan_stats(mask, t_pad, block, block, t_real, d,
                               backward=True)["work_elems"]
@@ -1458,29 +1516,38 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     # CPU grad tests outside shard_map still cover its math.
     interp_vma = interpret and getattr(jax.typeof(q), "vma", None)
     # Several blocks a head: where the blocks are square and what the head's
-    # backward keeps in VMEM fits the budget, one kernel holds the head and
-    # walks its tiles itself (`_bwd_row_kernel`); otherwise the grid walks
-    # them, dq apart from dk and dv. Decided from what this call sees.
-    resident = _bwd_resident_bytes(t_pad, d, dv, q.dtype.itemsize, group)
+    # backward keeps in VMEM fits a budget, one kernel holds the head and
+    # walks its tiles itself (`_bwd_row_kernel`): its whole-row blocks
+    # double-buffered where that fits `BWD_ROW_VMEM_BYTES`, kept once where
+    # only that fits `BWD_ROW_ONCE_VMEM_BYTES`; otherwise the grid walks the
+    # tiles, dq apart from dk and dv. Decided from what this call sees.
+    twice, kept_once = (_bwd_resident_bytes(
+        t_pad, d, dv, q.dtype.itemsize, group, buffers=n) for n in (2, 1))
+    walk, buffers = "grid", 2
     if interp_vma:
-        walk = "grid"
+        pass
     elif num_qb == 1 and num_kb == 1:
         walk = "tile"
-    elif block_q == block_k and resident <= BWD_ROW_VMEM_BYTES:
+    elif block_q == block_k and twice <= BWD_ROW_VMEM_BYTES:
         walk = "row"
-    else:
-        walk = "grid"
+    elif block_q == block_k and kept_once <= BWD_ROW_ONCE_VMEM_BYTES:
+        walk, buffers = "row", 1
+    resident, budget = ((twice, BWD_ROW_VMEM_BYTES) if buffers == 2 else
+                        (kept_once, BWD_ROW_ONCE_VMEM_BYTES))
     tracer = current_tracer()
     if tracer is not None:
-        tracer.instant("flash_bwd_walk", walk=walk, t=t_pad, d=d, dv=dv,
-                       group=group, resident_bytes=resident,
-                       budget_bytes=BWD_ROW_VMEM_BYTES, mask=mask.kind,
+        # the bytes as taken and the budget they were held to; a walk that
+        # is not `row` says what the row walk would have kept with two
+        tracer.instant("flash_bwd_walk", walk=walk, buffers=buffers,
+                       t=t_pad, d=d, dv=dv, group=group,
+                       resident_bytes=resident, budget_bytes=budget,
+                       kept_once_bytes=kept_once, mask=mask.kind,
                        window=mask.window)
     if walk == "row":
         return _bwd_row_call(q, k, v, do, lse, delta, t_real=t_real,
                              block=block_q, hq=hq, hkv=hkv,
                              interpret=interpret, resident=resident,
-                             mask=mask)
+                             buffers=buffers, mask=mask)
     if walk == "tile":
         q_td = pl.BlockSpec((None, t_pad, d),
                             lambda b, g: (_q_row(b, g, hq, hkv), 0, 0))

@@ -90,19 +90,25 @@ def check_grads(name, loss_got, loss_ref, args):
 
 
 def flash_walk_checks(interp: bool, dtype, tol):
-    """The forward's two walks at the shapes where the row walk asks Mosaic
-    for more than its default scoped VMEM (a head's K and V resident are 16
-    MiB): the sixteen-thousand-row cell's, causal and under its window, and
-    the hybrid cell's 256-wide head. The gridded walk (the budget at 0) is
-    the reference: o and lse of `_fwd_call`, and the three gradients through
-    `flash_attention`, whose backward reads the forward's o and lse."""
+    """The two walks of the forward and of the backward at the shapes where
+    the row walk asks Mosaic for more than its default scoped VMEM (a head's
+    K and V resident are 16 MiB) and the resident backward keeps its
+    whole-row blocks once (68 and 60 MiB, PR 56): the sixteen-thousand-row
+    cell's, causal and under its window, and the hybrid cell's 256-wide
+    head. The gridded walks (every budget at 0: the gridded forward, the
+    split backward) are the reference: o and lse of `_fwd_call`, and the
+    three gradients through `flash_attention`, whose backward reads the
+    forward's o and lse."""
     cases = [("16k 128/128 group 7 causal", 16384, 128, 7, 0, 1024),
              ("16k 128/128 group 7 window 4096", 16384, 128, 7, 4096, 1024),
              ("8k 256/256 group 8 causal", 8192, 256, 8, 0, 1024)]
     if interp:
         cases = [("group 7 window", 1024, 16, 7, 600, 128)]
     key = jax.random.key(52)
-    budget = fa_mod.KV_ROW_VMEM_BYTES
+    names = ("KV_ROW_VMEM_BYTES", "BWD_ROW_VMEM_BYTES",
+             "BWD_ROW_ONCE_VMEM_BYTES")
+    budgets = [getattr(fa_mod, name) for name in names]
+    budget = budgets[0]
     for tag, t, d, group, window, blk in cases:
         mask = sliding_window(window) if window else CAUSAL
         q = jax.random.normal(jax.random.fold_in(key, 1), (1, 2 * group, t, d),
@@ -122,11 +128,13 @@ def flash_walk_checks(interp: bool, dtype, tol):
         assert fa_mod._fwd_resident_bytes(t, d, d, q.dtype.itemsize) \
             <= budget, "the row walk is not taken at this shape"
         try:
-            fa_mod.KV_ROW_VMEM_BYTES = 0
+            for name in names:
+                setattr(fa_mod, name, 0)
             o_ref, lse_ref = jax.jit(fwd)(q, k, v)
             g_ref = grads()
         finally:
-            fa_mod.KV_ROW_VMEM_BYTES = budget
+            for name, was in zip(names, budgets):
+                setattr(fa_mod, name, was)
         row = jax.jit(fwd)
         check(f"flash fwd row against grid [{tag}] o",
               lambda: row(q, k, v)[0], o_ref, tol)
@@ -398,8 +406,9 @@ def main():
         check_grads(f"flash [{tag}]", loss(flash),
                     loss(causal_attention_xla), (q, k, v))
 
-    # --- the forward's row walk where it asks for scoped VMEM of its own,
-    # against the gridded walk
+    # --- the row walks where they ask for scoped VMEM of their own (the
+    # forward's K and V, the backward's head kept once), against the
+    # gridded forward and the split backward
     flash_walk_checks(interp, dtype, tol)
 
     # --- positional block kernel (ring attention building block)

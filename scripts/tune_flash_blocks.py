@@ -29,16 +29,27 @@ constants are in the notes beside them, one command a table:
         --edges 128x256,128x512,256x256,256x512,512x256
 
 `--backward` takes the backward of several blocks a head apart, one call
-alone at `--bh --t --d --dv --group` (b*h counts query heads): the resident
-walk (`_bwd_row_kernel`, one `flash_bwd` call) whole, its DMA alone, each
-of its five products knocked out (wrong numbers, right time), and the two
-split kernels it replaces in the same process, with the largest difference
-between the two walks' dq, dk and dv. The tables beside BWD_SUBTILE:
+alone at `--bh --t --d --dv --group --window` (b*h counts query heads; 0:
+causal): the resident walk (`_bwd_row_kernel`, one `flash_bwd` call) whole
+as `_bwd_call` takes it at that shape (it prints the bytes with two buffers
+a block and as taken, and which blocks are kept once), the same walk with
+the OTHER buffer count (the budgets opened or shut by hand: how a size is
+read before a rule admits it), its DMA alone, each of its five products
+knocked out (wrong numbers, right time), and the two split kernels it
+replaces in the same process, with the largest difference between the two
+walks' dq, dk and dv. The tables beside BWD_SUBTILE, and the four readings
+beside `BWD_ROW_ONCE_VMEM_BYTES` (PR 56):
 
     python scripts/tune_flash_blocks.py --backward --bh 128 --t 4096 \
         --d 192 --dv 128
     python scripts/tune_flash_blocks.py --backward --bh 64 --t 8192 --d 64 \
         --group 4
+    python scripts/tune_flash_blocks.py --backward --bh 28 --t 16384 \
+        --d 128 --group 7 [--window 4096]
+    python scripts/tune_flash_blocks.py --backward --bh 32 --t 8192 --d 256 \
+        --group 8
+    python scripts/tune_flash_blocks.py --backward --bh 32 --t 8192 --d 128 \
+        --group 8
 
 `--forward` times the forward of several blocks a head, one call alone at
 `--bh --t --d --dv --group --window` (0: causal): the row walk (a head's K
@@ -244,7 +255,8 @@ def sweep_subtiles(bhs, edges, t=1024, d=64, dv=None, blocks=None,
 BACKWARD_PRODUCTS = ("s", "dp", "dq", "dk", "dv")
 
 
-def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
+def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10,
+                   window=0):
     """The backward at several blocks a head, a call alone (module
     docstring). The knock-outs are made here and not in the kernel: `_dot`
     is replaced by one that counts a rectangle's five calls and returns
@@ -252,7 +264,10 @@ def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
     its outputs for the DMA's time. Returns {reading: ms}."""
     import distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
         as fa
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        CAUSAL, sliding_window)
     dv = dv or d
+    mask = sliding_window(window) if window else CAUSAL
     key = jax.random.PRNGKey(bh)
     kq, kk, kv_, kd = jax.random.split(key, 4)
     q = jax.random.normal(kq, (bh, t, d), jnp.bfloat16)
@@ -260,17 +275,34 @@ def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
     v = jax.random.normal(kv_, (bh // group, t, dv), jnp.bfloat16)
     do = jax.random.normal(kd, (bh, t, dv), jnp.bfloat16)
     kw = dict(t_real=t, block_q=block, block_k=block, hq=group, hkv=1,
-              interpret=False)
+              interpret=False, mask=mask)
     # a real forward's o and lse: the two walks' numbers are compared
     o, lse = jax.jit(lambda q, k, v: fa._fwd_call(q, k, v, **kw))(q, k, v)
     args = (q, k, v, o, lse, do)
-    real_dot, real_kernel, budget = (fa._dot, fa._bwd_row_kernel,
-                                     fa.BWD_ROW_VMEM_BYTES)
-    resident = fa._bwd_resident_bytes(t, d, dv, 2, group)
-    print(f"backward alone: bh{bh} t{t} d{d}/{dv} group {group} block "
+    real_dot, real_kernel = fa._dot, fa._bwd_row_kernel
+    budgets = (fa.BWD_ROW_VMEM_BYTES, fa.BWD_ROW_ONCE_VMEM_BYTES)
+    twice, once = (fa._bwd_resident_bytes(t, d, dv, 2, group, buffers=n)
+                   for n in (2, 1))
+    # the rule of `_bwd_call`: the buffers a block it takes here (0: over
+    # both budgets, and the second is opened to the head for the row walk),
+    # the budgets that give that walk and those that give the other count
+    own = 2 if twice <= budgets[0] else 1 if once <= budgets[1] else 0
+    home = budgets if own else (0, once)
+    other = (0, max(once, budgets[1])) if own == 2 else (twice, 0)
+    print(f"backward alone: bh{bh} t{t} d{d}/{dv} group {group} "
+          f"{'window ' + str(window) if window else 'causal'} block "
           f"{block} sub {fa.BWD_SUBTILE}; the head resident is "
-          f"{resident / 2 ** 20:.1f} MiB of a budget of "
-          f"{budget / 2 ** 20:.0f}", flush=True)
+          f"{twice / 2 ** 20:.1f} MiB with two buffers a block and "
+          f"{(twice if own == 2 else once) / 2 ** 20:.1f} as taken ("
+          + {2: "2 buffers: none kept once",
+             1: "1 buffer: all nine blocks kept once",
+             0: "over both budgets, the second opened to it: all nine "
+                "blocks kept once"}[own]
+          + f") of budgets of {budgets[0] / 2 ** 20:.0f} twice and "
+          f"{budgets[1] / 2 ** 20:.0f} once", flush=True)
+
+    def shut(first, second):
+        fa.BWD_ROW_VMEM_BYTES, fa.BWD_ROW_ONCE_VMEM_BYTES = first, second
 
     def without(product):
         calls = itertools.count()
@@ -306,7 +338,11 @@ def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
               f"{compile_s:5.1f} s)", flush=True)
 
     try:
+        shut(*home)
         reading("row walk, whole")
+        shut(*other)    # the other buffer count, the budgets set by hand
+        reading(f"row walk, {9 if own == 2 else 0} blocks kept once")
+        shut(*home)
         fa._bwd_row_kernel = dma_alone
         reading("row walk, DMA alone")
         fa._bwd_row_kernel = real_kernel
@@ -314,13 +350,13 @@ def sweep_backward(bh, t, d, dv=None, group=1, block=1024, iters=10):
             fa._dot = without(product)
             reading(f"row walk, no {product}")
         fa._dot = real_dot
-        fa.BWD_ROW_VMEM_BYTES = 0
+        shut(0, 0)
         reading("split kernels, dq + dkv")
         reading("split kernels, dq", match="flash_bwd_dq")
         reading("split kernels, dkv", match="flash_bwd_dkv")
     finally:
-        fa._dot, fa._bwd_row_kernel, fa.BWD_ROW_VMEM_BYTES = (
-            real_dot, real_kernel, budget)
+        fa._dot, fa._bwd_row_kernel = real_dot, real_kernel
+        shut(*budgets)
     if {"row walk, whole", "split kernels, dq + dkv"} <= set(grads):
         for name, a, b in zip(("dq", "dk", "dv"), grads["row walk, whole"],
                               grads["split kernels, dq + dkv"]):
@@ -399,7 +435,8 @@ def parse_args(argv=None):
                          "--bh --t --d --dv --group --window: the row walk "
                          "beside the gridded walk")
     ap.add_argument("--window", type=int, default=0,
-                    help="--forward: a sliding window (0: causal)")
+                    help="--forward, --backward: a sliding window (0: "
+                         "causal)")
     ap.add_argument("--group", type=int, default=1,
                     help="--backward, --forward: query heads a kv head")
     ap.add_argument("--bh", default="192,80",
@@ -477,7 +514,8 @@ def main():
         for bh in args.bh.split(","):
             for block in (args.blocks or "1024").split(","):
                 sweep_backward(int(bh), args.t, args.d, args.dv, args.group,
-                               int(block), iters=min(args.iters, 10))
+                               int(block), iters=min(args.iters, 10),
+                               window=args.window)
         return
     if args.forward:
         for bh in args.bh.split(","):

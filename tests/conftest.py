@@ -32,6 +32,27 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+@pytest.fixture
+def flash_bwd_calls():
+    """`calls(fn, q, k, v)`: the flash backward's `pallas_call`s in the
+    jaxpr of grad(sum(fn(q, k, v))), in order, as (name, the buffer count
+    each of its blocks names: None where it leaves the pipeline its two)."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["name"], [
+                    m.pipeline_mode and m.pipeline_mode.buffer_count
+                    for m in e.params["grid_mapping"].block_mappings]
+            for sub in jax.core.jaxprs_in_params(e.params):
+                yield from walk(sub)
+
+    def calls(fn, q, k, v):
+        grad = jax.grad(lambda *a: fn(*a).astype("float32").sum(), (0, 1, 2))
+        return [c for c in walk(jax.make_jaxpr(grad)(q, k, v).jaxpr)
+                if c[0].startswith("flash_bwd")]
+    return calls
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _check_devices():
     assert jax.device_count() >= 8, (

@@ -257,15 +257,20 @@ def test_the_declaration_is_the_three_rules():
 @pytest.mark.parametrize("L,B,block,hq,hkv,walk", [
     (256, 4, 128, 2, 2, "row"), (256, 4, 128, 2, 2, "grid"),
     (384, 32, 128, 8, 1, "row"), (384, 32, 128, 8, 1, "grid"),
-    (128, 128, 128, 1, 1, "row")])
+    (128, 128, 128, 1, 1, "row"),
+    (256, 4, 128, 2, 2, "once"), (384, 32, 128, 8, 1, "once")])
 def test_the_kernels_under_the_declared_mask_equal_the_dense_path(
-        L, B, block, hq, hkv, walk, monkeypatch):
+        L, B, block, hq, hkv, walk, monkeypatch, flash_bwd_calls):
     """Several tiles a half, groups of 1 and 8, block lengths 4, 32 and the
     sub-tile's own edge; the head resident (`row`: the forward's row walk
-    and the one backward kernel) and the gridded walk with the split
-    backward. Forward and all three gradients."""
+    and the one backward kernel; `once`: that kernel with its whole-row
+    blocks single-buffered, what a head over the first budget takes since
+    PR 56) and the gridded walk with the split backward. Forward and all
+    three gradients."""
     if walk == "grid":
         monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", 0)
+        monkeypatch.setattr(fa_mod, "BWD_ROW_ONCE_VMEM_BYTES", 0)
+    if walk in ("grid", "once"):
         monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", 0)
     mask = block_diffusion(B, L)
     key = jax.random.key(0)
@@ -278,6 +283,10 @@ def test_the_kernels_under_the_declared_mask_equal_the_dense_path(
         q, k, v, block, block, block, block, interpret=True, mask=mask)
     dense = lambda q, k, v: masked_attention_xla(q, k, v, mask)
     np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
+    assert flash_bwd_calls(kernel, q, k, v) == {
+        "row": [("flash_bwd", [None] * 9)], "once": [("flash_bwd", [1] * 9)],
+        "grid": [("flash_bwd_dq", [None] * 7), ("flash_bwd_dkv", [None] * 8)],
+    }[walk if L > block else "row"]
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
     want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):

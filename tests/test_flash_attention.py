@@ -972,8 +972,10 @@ def test_the_row_asks_for_scoped_vmem_only_past_the_default(t, d, dv, limit):
 # Where the blocks are square and what a head's backward keeps in VMEM fits
 # `BWD_ROW_VMEM_BYTES`, ONE kernel holds the head and makes dq, dk and dv
 # from one s and one dp a rectangle (`_bwd_row_kernel`, the call named
-# `flash_bwd`); rows over the budget keep `_dq_kernel` + `_dkv_kernel`, one
-# tile a head keeps `_bwd_fused_kernel`. The cases below run two and four
+# `flash_bwd`); a head over it that fits `BWD_ROW_ONCE_VMEM_BYTES` with its
+# whole-row blocks kept once takes the same kernel single-buffered (PR 56);
+# rows over both keep `_dq_kernel` + `_dkv_kernel`, one tile a head keeps
+# `_bwd_fused_kernel`. The cases below run two and four
 # blocks a head: block 512 is four sub-tiles a tile (two key sub-columns,
 # the under-diagonal tile's fused into one), block 256 one.
 
@@ -991,6 +993,15 @@ def _bwd_kernels(q, k, v, **kw):
                                        .astype(jnp.float32)), (0, 1, 2))
     return [n for n in calls(jax.make_jaxpr(grad)(q, k, v).jaxpr)
             if n.startswith("flash_bwd")]
+
+
+def _no_row_walk(monkeypatch, once=False):
+    """Both of the resident backward's budgets shut: the split kernels. With
+    `once`, the second is left as shipped: the row walk, its blocks kept
+    once."""
+    monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", 0)
+    if not once:
+        monkeypatch.setattr(fa_mod, "BWD_ROW_ONCE_VMEM_BYTES", 0)
 
 
 def _bwd_call_jaxpr(bh, bhkv, t, d, dv, interpret):
@@ -1139,9 +1150,9 @@ def test_bwd_row_walk_runs_each_tiles_plan_once(monkeypatch, block, sub):
     (512, 1024, 1), (512, 900, 2), (256, 600, 1), (256, 1024, 4)])
 def test_split_kernels_where_the_head_does_not_fit(monkeypatch, block,
                                                    t_real, group):
-    """A head over the budget keeps `_dq_kernel` and `_dkv_kernel`, square
+    """A head over both budgets keeps `_dq_kernel` and `_dkv_kernel`, square
     blocks too, and they still match the oracle."""
-    monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", 0)
+    _no_row_walk(monkeypatch)
     q, k, v, g = _qkv(t_real, 1, 4, 4 // group, 1024, 24, n=4, dv=16)
     kw = _square(block, t_real=t_real)
     assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd_dq", "flash_bwd_dkv"]
@@ -1152,43 +1163,95 @@ def test_split_kernels_where_the_head_does_not_fit(monkeypatch, block,
         assert not jnp.any(b[:, :, t_real:])
 
 
-def test_the_head_fits_by_bytes_alone(monkeypatch):
+@pytest.mark.parametrize("block,t_real,group", [
+    (512, 1024, 1), (512, 900, 2), (256, 600, 1), (256, 1024, 4),
+    (256, 1024, 7)])
+def test_the_row_walk_kept_once_where_the_head_does_not_fit_twice(
+        monkeypatch, flash_bwd_calls, block, t_real, group):
+    """A head over the first budget and inside the second takes the ONE
+    kernel with each of its nine whole-row blocks single-buffered (PR 56):
+    the same numbers as the double-buffered walk's to the bit (the body is
+    the same and the interpreter has no pipeline), the split kernels' and
+    the oracle's to rounding."""
+    q, k, v, g = _qkv(t_real, 1, 2 * group, 2, 1024, 24, n=4, dv=16)
+    kw = _square(block, t_real=t_real)
+    flash = lambda *a: flash_attention(*a, **kw)
+    grads = lambda: _grads(flash, q, k, v, g)
+    assert flash_bwd_calls(flash, q, k, v) == [("flash_bwd", [None] * 9)]
+    twice = grads()
+    _no_row_walk(monkeypatch, once=True)
+    assert flash_bwd_calls(flash, q, k, v) == [("flash_bwd", [1] * 9)]
+    once = grads()
+    _no_row_walk(monkeypatch)
+    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd_dq", "flash_bwd_dkv"]
+    split = grads()
+    oracle = _grads(lambda *a: _sliced_oracle(*a, t_real), q, k, v, g)
+    for a, b, c, d in zip(once, twice, split, oracle):
+        assert jnp.array_equal(a, b)
+        assert jnp.abs(a - c).max() < 1e-4 and jnp.abs(a - d).max() < 1e-4
+        assert not jnp.any(a[:, :, t_real:])
+
+
+def test_the_head_fits_by_bytes_alone(monkeypatch, flash_bwd_calls):
     """The backward's walk is chosen from what `_bwd_call` sees: one byte
     under what the head keeps in VMEM (nine whole-row blocks double-buffered,
     each width padded to 128 lanes, lse and delta 128 lanes of one value,
-    and the float32 accumulators) and the grid walks; at it, one kernel."""
+    and the float32 accumulators) and the blocks are kept once, if THAT fits
+    the second budget; one byte under what it keeps so and the grid walks;
+    at either, one kernel."""
     q, k, v = _qkv(3, 1, 2, 1, 1024, 24, dv=16)
     kw = _square(256)
     lanes = 128
-    need = 1024 * (2 * ((4 * lanes + 3 * lanes) * 4 + 2 * lanes * 4)
-                   + 4 * (lanes + lanes + lanes))
+    blocks = 1024 * ((4 * lanes + 3 * lanes) * 4 + 2 * lanes * 4)
+    need = 2 * blocks + 1024 * 4 * (lanes + lanes + lanes)
     assert fa_mod._bwd_resident_bytes(1024, 24, 16, 4, 2) == need
+    assert fa_mod._bwd_resident_bytes(1024, 24, 16, 4, 2, buffers=1) \
+        == need - blocks
     # without grouped heads dk and dv leave from values: one accumulator
     assert fa_mod._bwd_resident_bytes(1024, 24, 16, 4, 1) \
         == need - 1024 * 4 * 2 * lanes
+    once = [("flash_bwd", [1] * 9)]
+    calls = lambda: flash_bwd_calls(lambda *a: flash_attention(*a, **kw),
+                                    q, k, v)
     monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", need)
-    assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd"]
+    assert calls() == [("flash_bwd", [None] * 9)]
     monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", need - 1)
+    assert calls() == once
+    monkeypatch.setattr(fa_mod, "BWD_ROW_ONCE_VMEM_BYTES", need - blocks)
+    assert calls() == once
+    monkeypatch.setattr(fa_mod, "BWD_ROW_ONCE_VMEM_BYTES", need - blocks - 1)
     assert _bwd_kernels(q, k, v, **kw) == ["flash_bwd_dq", "flash_bwd_dkv"]
     # blocks that are not square leave the diagonal crossing several tiles
     monkeypatch.undo()
     assert _bwd_kernels(q, k, v, block_q=256, block_k=256, bwd_block_q=256,
                         bwd_block_k=512) == ["flash_bwd_dq", "flash_bwd_dkv"]
     # the benchmark's cells: latent attention at 4k (34 MiB) and a group of
-    # four heads of 64 at 8k (56) sit inside the shipped budget, a group of
-    # eight heads of 256 at 8k (96) does not
-    mib = lambda *a: fa_mod._bwd_resident_bytes(*a) / 2 ** 20
+    # four heads of 64 or eight of 128 at 8k (56) fit the shipped budget
+    # twice; a group of eight heads of 256 at 8k (96) and seven of 128 at
+    # 16k (112) do not, and fit the second kept once (60 and 68); four times
+    # that row (272 kept once) fits neither
+    mib = lambda *a, **kw: fa_mod._bwd_resident_bytes(*a, **kw) / 2 ** 20
+    first, second = (fa_mod.BWD_ROW_VMEM_BYTES / 2 ** 20,
+                     fa_mod.BWD_ROW_ONCE_VMEM_BYTES / 2 ** 20)
     assert mib(4096, 192, 128, 2, 1) == 34 and mib(8192, 64, 64, 2, 4) == 56
-    assert mib(8192, 256, 256, 2, 8) == 96
-    assert 56 <= fa_mod.BWD_ROW_VMEM_BYTES / 2 ** 20 < 96
-    # and what the kernel asks Mosaic for leaves the body its room
+    assert mib(8192, 128, 128, 2, 8) == 56 <= first
+    assert mib(8192, 256, 256, 2, 8) == 96 > first
+    assert mib(16384, 128, 128, 2, 7) == 112 > first
+    assert mib(8192, 256, 256, 2, 8, buffers=1) == 60 <= second
+    assert mib(16384, 128, 128, 2, 7, buffers=1) == 68 <= second
+    assert mib(65536, 128, 128, 2, 7, buffers=1) == 272 > second
+    # and what the kernel asks Mosaic for leaves the body its room, inside
+    # the chip's 128 MiB
     assert fa_mod._vmem_limit(56 * 2 ** 20) == 80 * 2 ** 20
+    assert fa_mod._vmem_limit(fa_mod.BWD_ROW_ONCE_VMEM_BYTES) == 92 * 2 ** 20
 
 
 def test_flash_bwd_walk_instant_says_which_walk(tmp_path):
     """At trace time `_bwd_call` says on the program's tracer which walk it
-    took and the bytes it reckoned: `row` for a resident head, `grid` for
-    one over the budget (or blocks not square), `tile` for one tile."""
+    took, with how many buffers a whole-row block, the bytes as taken and
+    the budget they were held to: `row` for a resident head (`buffers` 2
+    where it fits twice, 1 where only once), `grid` for one over both
+    budgets (or blocks not square), `tile` for one tile."""
     import json
 
     from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
@@ -1196,19 +1259,29 @@ def test_flash_bwd_walk_instant_says_which_walk(tmp_path):
     tracer = SpanTracer(str(tmp_path))
     try:
         trace(2, 2, 4096, 192, 128)     # the latent-attention cell's head
-        trace(8, 1, 8192, 256, 256)     # the hybrid cell's: over the budget
+        trace(8, 1, 8192, 256, 256)     # the hybrid cell's: fits once
         trace(2, 2, 1024, 64, 64)       # the GPT-2 cells': one tile
         trace(4, 1, 8192, 64, 64)       # the conv cell's group of four
+        trace(8, 1, 8192, 128, 128)     # the window cells' group of eight
+        trace(7, 1, 16384, 128, 128)    # the 16k cell's: fits once
+        trace(7, 1, 65536, 128, 128)    # four times that: over both
     finally:
         tracer.close()
     events = [json.loads(line)["args"] for line in
               open(tmp_path / "trace.jsonl")
               if json.loads(line)["name"] == "flash_bwd_walk"]
-    assert [(e["walk"], e["t"], e["d"], e["dv"], e["group"],
-             e["resident_bytes"] // 2 ** 20) for e in events] == [
-        ("row", 4096, 192, 128, 1, 34), ("grid", 8192, 256, 256, 8, 96),
-        ("tile", 1024, 64, 64, 1, 6), ("row", 8192, 64, 64, 4, 56)]
-    assert all(e["budget_bytes"] == fa_mod.BWD_ROW_VMEM_BYTES for e in events)
+    first, second = (fa_mod.BWD_ROW_VMEM_BYTES // 2 ** 20,
+                     fa_mod.BWD_ROW_ONCE_VMEM_BYTES // 2 ** 20)
+    assert [(e["walk"], e["buffers"], e["t"], e["d"], e["dv"], e["group"],
+             e["resident_bytes"] // 2 ** 20, e["budget_bytes"] // 2 ** 20,
+             e["kept_once_bytes"] // 2 ** 20) for e in events] == [
+        ("row", 2, 4096, 192, 128, 1, 34, first, 19),
+        ("row", 1, 8192, 256, 256, 8, 60, second, 60),
+        ("tile", 2, 1024, 64, 64, 1, 6, first, 3),
+        ("row", 2, 8192, 64, 64, 4, 56, first, 34),
+        ("row", 2, 8192, 128, 128, 8, 56, first, 34),
+        ("row", 1, 16384, 128, 128, 7, 68, second, 68),
+        ("grid", 2, 65536, 128, 128, 7, 448, first, 272)]
 
 
 def test_flash_fwd_walk_instant_says_which_walk(tmp_path, monkeypatch):
@@ -1258,7 +1331,9 @@ def test_flash_fwd_walk_instant_says_which_walk(tmp_path, monkeypatch):
 
 # `_bwd_call`'s jaxpr, kernel bodies and all, as the commit before the
 # resident walk traced it (PR 39's tree; sha256 of the text with object
-# addresses stripped, first 16 digits): (q heads, kv heads, t, d, dv)
+# addresses stripped, first 16 digits): (q heads, kv heads, t, d, dv). The
+# second shape fits the second budget since PR 56 and is traced with both
+# shut: the split kernels' text is what a head over both still runs.
 BWD_JAXPR_BEFORE = {
     "one tile a head (the GPT-2 cells)": ((4, 4, 1024, 64, 64),
                                           "3375b09bb17b328d"),
@@ -1268,11 +1343,13 @@ BWD_JAXPR_BEFORE = {
 
 
 @pytest.mark.parametrize("which", sorted(BWD_JAXPR_BEFORE))
-def test_bwd_call_keeps_the_other_walks_text(which):
+def test_bwd_call_keeps_the_other_walks_text(which, monkeypatch):
     """The resident walk took the rows it fits and nothing else: one tile a
-    head traces the one-tile kernel and a head over the budget the two split
-    kernels, to the character what they were. A PR that means to change
-    either changes the digest with it."""
+    head traces the one-tile kernel and a head over the budgets the two
+    split kernels, to the character what they were. A PR that means to
+    change either changes the digest with it."""
+    if "over the budget" in which:
+        _no_row_walk(monkeypatch)
     import hashlib
     import re
     shape, digest = BWD_JAXPR_BEFORE[which]

@@ -232,42 +232,85 @@ def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
         assert scoped == ""
 
 
-@pytest.mark.parametrize("t,d,dv,group,t_real,names", [
+def _mosaic_body(lowered_text):
+    """The one Mosaic kernel of a lowered text, its serialized body decoded."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    raw, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                      lowered_text)
+    with mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        return ir.Module.parse(base64.b64decode(raw)).operation.get_asm(
+            enable_debug_info=False)
+
+
+@pytest.mark.parametrize("t,d,dv,group,t_real,window,buffers,names", [
     # the latent-attention cell's head, 34 MiB resident
-    (4096, 192, 128, 1, 4096, ["flash_bwd"]),
+    (4096, 192, 128, 1, 4096, 0, 2, ["flash_bwd"]),
     # t_real cuts the third block: the cut tiles' plans beside the whole ones
-    (4096, 192, 128, 1, 3000, ["flash_bwd"]),
+    (4096, 192, 128, 1, 3000, 0, 2, ["flash_bwd"]),
     # the conv cell's group of four heads of 64 at 8k, 56 MiB: the most the
-    # budget admits among the benchmark's cells
-    (8192, 64, 64, 4, 8192, ["flash_bwd"]),
-    # the hybrid cell's group of eight heads of 256 at 8k, 96 MiB: over it
-    (8192, 256, 256, 8, 8192, ["flash_bwd_dq", "flash_bwd_dkv"])])
+    # first budget admits among the benchmark's cells
+    (8192, 64, 64, 4, 8192, 0, 2, ["flash_bwd"]),
+    # the hybrid cell's group of eight heads of 256 at 8k, 96 MiB with two
+    # buffers a block: kept once it is 60 (PR 56)
+    (8192, 256, 256, 8, 8192, 0, 1, ["flash_bwd"]),
+    # the sixteen-thousand-row cell's group of seven heads of 128: 112 MiB
+    # twice, 68 once, its full layer and its window layers
+    (16384, 128, 128, 7, 16384, 0, 1, ["flash_bwd"]),
+    (16384, 128, 128, 7, 16384, 4096, 1, ["flash_bwd_window"]),
+    # twice that row is over both budgets (224 MiB, 136 once)
+    (32768, 128, 128, 7, 32768, 0, 0, ["flash_bwd_dq", "flash_bwd_dkv"])])
 def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
-        topo, described_tpu, t, d, dv, group, t_real, names):
+        topo, described_tpu, t, d, dv, group, t_real, window, buffers,
+        names):
     """Mosaic takes the multi-block backward that keeps the whole head in
     VMEM (PR 40) at the table's blocks with the scoped VMEM `_bwd_row_call`
-    asks for, at the shapes `BWD_ROW_VMEM_BYTES` admits; a head over the
-    budget compiles the two split kernels as before."""
+    asks for: double-buffered at the shapes `BWD_ROW_VMEM_BYTES` admits,
+    and with its nine whole-row blocks kept once (PR 56) at the shapes only
+    `BWD_ROW_ONCE_VMEM_BYTES` does, whose call says so block by block and
+    asks for the bytes as taken and the body's room; a head over both
+    budgets compiles the two split kernels as before."""
     from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        CAUSAL, sliding_window)
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         flash_attention as fa)
     blocks = fa.get_block_config(t, d, jnp.bfloat16)
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (rows, t, w), dtype, sharding=chip)
-    fits = fa._bwd_resident_bytes(t, d, dv, 2, group) <= fa.BWD_ROW_VMEM_BYTES
-    assert fits == (names == ["flash_bwd"])
-    text = jax.jit(lambda *a: fa._bwd_call(
+    twice, once = (fa._bwd_resident_bytes(t, d, dv, 2, group, buffers=n)
+                   for n in (2, 1))
+    # `buffers` 0: over both budgets, the two split kernels
+    assert buffers == (2 if twice <= fa.BWD_ROW_VMEM_BYTES else
+                       1 if once <= fa.BWD_ROW_ONCE_VMEM_BYTES else 0)
+    lowered = jax.jit(lambda *a: fa._bwd_call(
         *a, t_real=t_real, block_q=blocks.bwd_block_q,
-        block_k=blocks.bwd_block_k, hq=group, hkv=1,
-        interpret=False)).lower(
+        block_k=blocks.bwd_block_k, hq=group, hkv=1, interpret=False,
+        mask=sliding_window(window) if window else CAUSAL)).lower(
             arg(2 * group, d), arg(2, d), arg(2, dv), arg(2 * group, dv),
-            arg(2 * group, 1, jnp.float32),
-            arg(2 * group, dv)).compile().as_text()
+            arg(2 * group, 1, jnp.float32), arg(2 * group, dv))
+    text = lowered.compile().as_text()
     # the compiled text lists the two split calls in the scheduler's order
     assert sorted(name.split(".")[0] for name in re.findall(
         r"%([\w.\-]+) = [^\n]*? custom-call\([^)]*\), "
         r'custom_call_target="tpu_custom_call"', text)) == sorted(names)
+    if not buffers:
+        return
+    call = next(line for line in text.splitlines()
+                if 'custom_call_target="tpu_custom_call"' in line)
+    scoped = re.search(r'"scoped_memory_configs":\[([^\]]*)\]', call).group(1)
+    taken = twice if buffers == 2 else once
+    assert f'"size":"{fa._vmem_limit(taken)}"' in scoped
+    assert fa._vmem_limit(taken) == taken + 24 * 2 ** 20 <= 92 * 2 ** 20
+    # the kernel as Mosaic is handed it: nine window parameters, each with
+    # the single buffer or none with it
+    body = _mosaic_body(lowered.as_text())
+    assert body.count("pipeline_mode<synchronous>") == (9 if buffers == 1
+                                                        else 0)
 
 
 SPLIT = ["flash_bwd_dkv_window", "flash_bwd_dq_window", "flash_fwd_window"]
@@ -276,7 +319,7 @@ SPLIT = ["flash_bwd_dkv_window", "flash_bwd_dq_window", "flash_fwd_window"]
 @pytest.mark.parametrize("t,window,group,walk,names", [
     (8192, 2048, 8, "row", ["flash_bwd_window", "flash_fwd_window"]),
     (8192, 2048, 8, "grid", SPLIT),
-    (16384, 4096, 7, "own", SPLIT)])
+    (16384, 4096, 7, "own", ["flash_bwd_window", "flash_fwd_window"])])
 def test_the_window_kernels_compile_at_the_window_cells_shapes(
         topo, described_tpu, monkeypatch, t, window, group, walk, names):
     """Mosaic takes the flash kernels under `sliding_window(2048)` at a
@@ -285,8 +328,9 @@ def test_the_window_kernels_compile_at_the_window_cells_shapes(
     with K and V resident and the ONE backward kernel, and the gridded
     forward with the split backward a longer row would take; and under
     `sliding_window(4096)` at the eighth cell's (16,384 rows, a group of
-    7), which takes the gridded forward and the split backward by its OWN
-    size, no budget patched. The calls carry `_window` in their names,
+    7), which takes the resident forward (PR 52) and the ONE backward
+    kernel, its blocks kept once (PR 56), by its OWN size, no budget
+    patched. The calls carry `_window` in their names,
     which is how a device trace tells a window layer's from a full
     layer's."""
     from jax.sharding import SingleDeviceSharding
@@ -297,6 +341,7 @@ def test_the_window_kernels_compile_at_the_window_cells_shapes(
     if walk == "grid":
         monkeypatch.setattr(fa, "KV_ROW_VMEM_BYTES", 0)
         monkeypatch.setattr(fa, "BWD_ROW_VMEM_BYTES", 0)
+        monkeypatch.setattr(fa, "BWD_ROW_ONCE_VMEM_BYTES", 0)
     d, mask = 128, sliding_window(window)
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(

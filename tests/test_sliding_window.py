@@ -62,8 +62,13 @@ def _operands(t, hq, hkv, width=64):
 
 
 def _walks(monkeypatch, walk):
+    """`grid`: every budget shut, the gridded forward and the split
+    backward. `once`: the backward's first budget shut alone, so the one
+    kernel keeps its whole-row blocks once (PR 56)."""
     if walk == "grid":
         monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", 0)
+        monkeypatch.setattr(fa_mod, "BWD_ROW_ONCE_VMEM_BYTES", 0)
+    if walk in ("grid", "once"):
         monkeypatch.setattr(fa_mod, "BWD_ROW_VMEM_BYTES", 0)
 
 
@@ -85,12 +90,17 @@ CASES = [
     # resident, a window that reaches back over five key tiles of the six
     # (one edge tile, a loop over four whole ones, the diagonal)
     (768, 600, 128, 7, 1, "row"),
+    # the eighth cell's backward since PR 56, in small: the same head over
+    # the first budget, its blocks kept once; a group of 1 and two key-value
+    # heads beside it, a window that is no multiple of the block
+    (768, 600, 128, 7, 1, "once"), (512, 200, 128, 2, 2, "once"),
+    (1536, 1024, 512, 4, 2, "once"),
 ]
 
 
 @pytest.mark.parametrize("t,w,block,hq,hkv,walk", CASES)
 def test_the_kernels_under_the_window_equal_the_dense_path(
-        t, w, block, hq, hkv, walk, monkeypatch):
+        t, w, block, hq, hkv, walk, monkeypatch, flash_bwd_calls):
     """Forward and all three gradients. The first case a pair has a window
     of two blocks (diagonal, whole, edge, dead tiles); the second one that
     is no multiple of the block (two edge tiles, none whole); the third a
@@ -101,12 +111,16 @@ def test_the_kernels_under_the_window_equal_the_dense_path(
     backward (two key-value heads, a window that is no multiple of the
     block; and a window of two blocks) and the resident one; and that
     grouping with the head resident under a window of more than four key
-    tiles."""
+    tiles; and the resident backward with its blocks kept once, whose
+    call says so."""
     _walks(monkeypatch, walk)
     mask = sliding_window(w)
     q, k, v, wt = _operands(t, hq, hkv)
     kernel = lambda q, k, v: fa_mod.flash_attention(
         q, k, v, block, block, block, block, interpret=True, mask=mask)
+    if walk == "once":
+        assert flash_bwd_calls(kernel, q, k, v) == [
+            ("flash_bwd_window", [1] * 9)]
     dense = lambda q, k, v: masked_attention_xla(q, k, v, mask)
     np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
     got = jax.grad(lambda *a: jnp.sum(kernel(*a) * wt), (0, 1, 2))(q, k, v)
@@ -197,15 +211,18 @@ def test_the_cells_shape_computes_under_a_third_over_the_live_entries():
         > 1.8 * fwd["work_elems"]
 
 
-def test_the_eighth_cells_shape_takes_the_resident_forward_and_split_backward(
+def test_the_eighth_cells_shape_takes_the_resident_forward_and_backward(
         tmp_path):
     """16,384 rows under a window of 4096 at head 128 and a group of 7,
     blocks of 1024: a head's K and V, double-buffered, are 16 MiB, inside
     `KV_ROW_VMEM_BYTES` since PR 52 (the forward keeps them resident and
     asks Mosaic for the scoped VMEM: they are over `KV_ROW_SCOPED_BYTES`;
     until then the grid walked the key tiles), and what the resident
-    backward would keep is 117 MB (over `BWD_ROW_VMEM_BYTES`: the split
-    kernels). Both walks run the SAME tile plans, which is what
+    backward would keep with two buffers a block is 117 MB, over
+    `BWD_ROW_VMEM_BYTES`; kept once it is 71 MB, inside
+    `BWD_ROW_ONCE_VMEM_BYTES`, so since PR 56 the backward is the ONE
+    kernel too, single-buffered (the split kernels until then). Both walks
+    run the SAME tile plans, which is what
     `flash_tile_stats` reports: the forward computes 1.125 of the band's
     58,722,304 live entries, the backward 1.062; the full layer's triangle
     beside it 1.031 and 1.016. The tracer says which walk each took."""
@@ -218,6 +235,8 @@ def test_the_eighth_cells_shape_takes_the_resident_forward_and_split_backward(
         > fa_mod.KV_ROW_SCOPED_BYTES
     assert fa_mod._bwd_resident_bytes(t, d, d, 2, group) == 117_440_512 \
         > fa_mod.BWD_ROW_VMEM_BYTES
+    assert fa_mod._bwd_resident_bytes(t, d, d, 2, group, buffers=1) \
+        == 71_303_168 <= fa_mod.BWD_ROW_ONCE_VMEM_BYTES
     fwd = flash_tile_stats(t, head_dim=d, mask=mask)
     bwd = flash_tile_stats(t, head_dim=d, mask=mask, backward=True)
     assert (fwd["block_q"], fwd["sub_q"], fwd["sub_k"]) == (1024, 256, 512)
@@ -249,7 +268,11 @@ def test_the_eighth_cells_shape_takes_the_resident_forward_and_split_backward(
     assert [(e["name"], e["args"]["walk"], e["args"]["window"],
              e["args"]["group"]) for e in events
             if e["name"].startswith("flash_")] == [
-        ("flash_fwd_walk", "row", 4096, 7), ("flash_bwd_walk", "grid", 4096, 7)]
+        ("flash_fwd_walk", "row", 4096, 7), ("flash_bwd_walk", "row", 4096, 7)]
+    bwd_walk = events[-1]["args"]
+    assert (bwd_walk["buffers"], bwd_walk["resident_bytes"],
+            bwd_walk["budget_bytes"]) == (1, 71_303_168,
+                                          fa_mod.BWD_ROW_ONCE_VMEM_BYTES)
 
 
 def test_a_window_over_the_whole_sequence_is_the_triangles_text():
