@@ -16,6 +16,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from .ops.rope import YarnScaling
+
 # Special-token conventions, byte-compatible with the reference
 # (`/root/reference/constants.py:3-6`) so its tokenizer.json and token-JSON
 # files interoperate.
@@ -67,10 +69,30 @@ class LatentMoEConfig:
     num_nextn_predict_layers: int = 0
     mtp_loss_weight: float = 0.3
     rms_norm_eps: float = 1e-6
+    # `rope_scaling` of type `yarn` (ops/rope.YarnScaling: the blended
+    # frequencies, and the softmax scale's mscale^2 in parallel/mla.py);
+    # None: plain RoPE
+    rope_scaling: "YarnScaling | None" = None
+    # Manifold-constrained hyper-connections: the residual state as
+    # `hc_mult` streams (parallel/hyper.py). Only the `mhc_mla_moe` family
+    # (models/mhc_mla_moe.py) reads these facts, and `mla_moe` refuses them
+    hyper: "HyperConnectionConfig | None" = None
 
     @property
     def qk_head_dim(self) -> int:
         return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
+class HyperConnectionConfig:
+    """The `hc_*` / `mhc_*` keys of a `config.json` (Xing4.0's,
+    DeepSeek-V4's): mHC, arXiv:2512.24880."""
+
+    hc_mult: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
 
 @dataclass(frozen=True)
@@ -372,6 +394,21 @@ MODEL_PRESETS = {
             q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
             qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
             routed_scaling_factor=2.5, num_nextn_predict_layers=1)),
+    # the `mhc_mla_moe` family at a CPU size: `tiny-mla-moe` with its
+    # residual state as 4 hyper-connection streams (20 Sinkhorn rounds) and
+    # YaRN positions (factor 8 over an original context of 64 rows, shorter
+    # than any test's sequence: two of the four rotary pairs are blended)
+    "tiny-mhc-mla-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_layers=3,
+        vocab_size=1024, maxlen=256, rope_theta=10000.0, num_experts=8,
+        moe_top_k=2, latent_moe=LatentMoEConfig(
+            q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
+            routed_scaling_factor=2.5, num_nextn_predict_layers=1,
+            rope_scaling=YarnScaling(
+                factor=8.0, original_max_position_embeddings=64,
+                mscale=1.0, mscale_all_dim=1.0),
+            hyper=HyperConnectionConfig())),
     # the `gdn_moe` family at a CPU size: two periods of three Gated
     # DeltaNet layers (2 key heads, 4 value heads, 16 wide) and one gated
     # full-attention layer (4 query heads over 2 key-value heads, 32 wide, a

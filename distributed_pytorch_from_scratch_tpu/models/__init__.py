@@ -5,6 +5,7 @@ from .conv_moe import ConvMoETransformer
 from .early_moe import EarlyRouterMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
+from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
 from .swa_moe import SlidingWindowMoETransformer
@@ -13,7 +14,8 @@ from .transformer import Transformer
 FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
     ConvMoETransformer, BlockDiffusionMoETransformer,
-    SlidingWindowMoETransformer, EarlyRouterMoETransformer)}
+    SlidingWindowMoETransformer, EarlyRouterMoETransformer,
+    HyperLatentMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":
@@ -28,8 +30,10 @@ def facts_family(cfg) -> "type[DecoderStack]":
     itself where there are none (llama and gpt2 share those)."""
     if cfg.family_facts is None:
         return DecoderStack
+    # (where two families read one field, each says whose the facts are)
     return next(cls for cls in FAMILIES.values()
-                if cls.config_extra == cfg.family_facts)
+                if cls.config_extra == cfg.family_facts
+                and cls.owns_facts(cfg))
 
 
 def build_model(family: str, cfg, **kw) -> DecoderStack:

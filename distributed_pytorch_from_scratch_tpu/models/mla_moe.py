@@ -26,7 +26,16 @@ holds only what differs:
   under the scope `mtp`). `h_i` is the last layer's output BEFORE the main
   final norm (the report's output head holds that norm), and the hidden
   state comes first in the concatenation (the report's order);
+* **YaRN** where `cfg.latent_moe.rope_scaling` says so: the blended
+  frequencies (`ops/rope.yarn_inv_freq`, `_positions`) and the softmax
+  scale's mscale^2 (`parallel/mla.LatentAttention.softmax_scale`); None is
+  plain RoPE, the tables computed as they always were;
 * an untied head, RMSNorm (eps `rms_norm_eps`), no bias anywhere.
+
+`models/mhc_mla_moe.py` subclasses this family with the residual state as
+hyper-connection streams (`DecoderStack.stream_mixer`): the
+multi-token-prediction module here norms and projects whatever `x` holds,
+one stream or several.
 
 What is not made to work is refused with a message: where the model is
 built (`refuses`), by ZeRO 2/3 and the bucketed reducer
@@ -52,6 +61,7 @@ from ..config import IGNORE_INDEX, ModelConfig
 from ..parallel.linear import ColumnParallelLinear, RowParallelLinear
 from ..parallel.mla import LatentAttention, ReplicatedLinear
 from ..parallel.moe import SharedRoutedFFN
+from ..ops.rope import rope_angles
 from ..parallel.norm import RMSNorm
 from ..runtime.prng import fold
 from .stack import DecoderStack, Params, TPSublayers, idle_expert_params
@@ -100,6 +110,20 @@ class LatentMoETransformer(DecoderStack):
             raise ValueError("multi-token prediction is written for depth "
                              "0 or 1, got "
                              f"{lm.num_nextn_predict_layers}")
+        if not self.owns_facts(self.cfg):
+            raise ValueError(
+                f"cfg.latent_moe.hyper is {lm.hyper}: the mhc_mla_moe "
+                f"family carries hyper-connection streams and needs it, "
+                f"the mla_moe family carries one and refuses it; this is "
+                f"{self.family}")
+
+    @classmethod
+    def owns_facts(cls, cfg: ModelConfig) -> bool:
+        """This family and its subclass with residual streams
+        (models/mhc_mla_moe.py) both read `latent_moe`: the facts with
+        `hyper` are the family's that carries streams."""
+        return (cfg.latent_moe.hyper is not None) == (
+            cls.stream_mixer is not None)
 
     # ---- the layer pattern ----
 
@@ -151,10 +175,12 @@ class LatentMoETransformer(DecoderStack):
     @functools.cached_property
     def attention(self) -> LatentAttention:
         lm = self.cfg.latent_moe
+        scaling = lm.rope_scaling
         return LatentAttention(
             self.d, self.cfg.num_heads, lm.q_lora_rank, lm.kv_lora_rank,
             lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim,
-            lm.rms_norm_eps)
+            lm.rms_norm_eps,
+            softmax_scale=scaling.softmax_scale if scaling else 1.0)
 
     @functools.cached_property
     def _mods(self) -> Dict[str, Any]:
@@ -187,11 +213,13 @@ class LatentMoETransformer(DecoderStack):
         if not self.cfg.latent_moe.num_nextn_predict_layers:
             return {}
         k = fold(key, "mtp")
+        # (the module's streams leave through an exit mixer of its own)
+        leave = self._exit_leaves(lambda m: m.init(fold(k, "hc_exit")))
         return {"mtp": {
             "hnorm": self.final_norm.init(k),
             "enorm": self.final_norm.init(k),
             "eh_proj": self.eh_proj.init(fold(k, "eh_proj")),
-            "norm": self.final_norm.init(k),
+            "norm": self.final_norm.init(k), **leave,
         }}
 
     def _more_specs(self) -> Params:
@@ -199,13 +227,22 @@ class LatentMoETransformer(DecoderStack):
             return {}
         norm = self.final_norm.specs()
         return {"mtp": {"hnorm": norm, "enorm": norm,
-                        "eh_proj": self.eh_proj.specs(), "norm": norm}}
+                        "eh_proj": self.eh_proj.specs(), "norm": norm,
+                        **self._exit_leaves(lambda m: m.specs())}}
 
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
     @property
     def rotary_dim(self) -> int:
         return self.cfg.latent_moe.qk_rope_head_dim
+
+    def _positions(self, params: Params, x: jax.Array,
+                   position_ids: jax.Array, dtype):
+        scaling = self.cfg.latent_moe.rope_scaling
+        if scaling is None:
+            return super()._positions(params, x, position_ids, dtype)
+        return x.astype(dtype), rope_angles(
+            position_ids, self.rotary_dim, self.cfg.rope_theta, scaling)
 
     def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
              dtype, b: int, t: int):
@@ -225,14 +262,16 @@ class LatentMoETransformer(DecoderStack):
             known = target_ids != IGNORE_INDEX
             nxt = self.embedding.apply(params["embedding"],
                                        jnp.where(known, target_ids, 0))
-            h = jnp.concatenate(
-                [self.final_norm.apply(mp["hnorm"], x),
-                 self.final_norm.apply(mp["enorm"], nxt.astype(trunk.dtype))],
-                axis=-1)
+            # (of residual streams, each is normed by the one `hnorm` and
+            # projected with the embedding by the one matrix; the module's
+            # layer mixes them and they leave through its own exit mixer)
+            h = self.final_norm.apply(mp["hnorm"], x)
+            e = self.final_norm.apply(mp["enorm"], nxt.astype(trunk.dtype))
+            h = jnp.concatenate([h, jnp.broadcast_to(e, h.shape)], axis=-1)
             h = self.eh_proj.apply(mp["eh_proj"], h, trunk.dtype)
             h, mtp_aux = trunk.run(h, params["mtp_layers"])
             logits = self._head(params, mp["norm"], h, trunk.dtype,
-                                scope=None)
+                                scope=None, exit_params=mp.get("hc_exit"))
             after = jnp.concatenate(
                 [target_ids[:, 1:],
                  jnp.full_like(target_ids[:, :1], IGNORE_INDEX)], axis=1)

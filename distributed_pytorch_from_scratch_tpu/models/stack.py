@@ -21,8 +21,9 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   `post_ffn_norm_key`), the embedding's multiplier (`embed_scale`), the
   kinds of attention layer by `_pattern` key (`_kind`, `_attn_mask(t,
   kind)`, `unrotated_kinds`), the speed of its routers' selection bias
-  (`router_bias_speed`) and whether its routers read the layer's input
-  (`router_reads_layer_input`);
+  (`router_bias_speed`), whether its routers read the layer's input
+  (`router_reads_layer_input`) and the mixer of its residual streams where
+  a layer's residual state is several (`stream_mixer`);
 * `_mods`, the per-layer modules (the attention projections are
   `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
   `models/decode.py` and `interop.py` read by name), and its mixer where
@@ -557,6 +558,17 @@ class DecoderStack:
     # `_ffn` (it is the remat boundary's own operand: no new saved tensor),
     # and the router's gradient enters the residual stream before attention
     router_reads_layer_input = False
+    # Hyper-connections: the mixer (`parallel/hyper.StreamMixer`) of a family
+    # whose residual state is n STREAMS, (n, b, t, d) from the embedding's
+    # rows n times to the head. A sublayer F then reads the mixer's weighted
+    # sum of the streams and its output joins them through the mixer's maps,
+    # X' = H X + post F(sum_i pre_i X[i]), where every other family computes
+    # x + F(x): `_layer_body`'s two joints, what `_trunk` carries from layer
+    # to layer and where `_head` leaves. Every layer then holds two mixers'
+    # parameters (`hc_attn`, `hc_ffn`) and the tree one more for the exit
+    # (`hc_exit`); the layers count `StreamMixer.counters`, one row a layer.
+    # None: one stream, and the program is the one it has always been
+    stream_mixer = None
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -613,6 +625,13 @@ class DecoderStack:
                     f"(the routed experts its router scores)")
         self._check_facts()
         self._refuse()
+        if self.stream_mixer is not None and (
+                self.pp_size > 1 or self.router_reads_layer_input):
+            raise ValueError(
+                f"the {self.family} family carries "
+                f"{self.residual_streams} residual streams: the pipeline's "
+                f"carries and a router that reads the layer's input take "
+                f"one")
         validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -639,6 +658,13 @@ class DecoderStack:
 
     def _check_facts(self) -> None:
         """A family's own checks of its facts, before anything else."""
+
+    @classmethod
+    def owns_facts(cls, cfg: ModelConfig) -> bool:
+        """Of the families that read ONE `config_extra` field: are `cfg`'s
+        facts this family's (`models.facts_family`, `train.py`'s preset
+        check). A field only one family reads is that family's."""
+        return True
 
     def _refuse(self) -> None:
         """Raise for the first of `refuses` this model asks for."""
@@ -708,6 +734,11 @@ class DecoderStack:
 
     layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
     head_rows_share = 1.0       # the part of a batch's rows the head reads
+
+    @property
+    def residual_streams(self) -> int:
+        """How many d-wide streams a kept layer input is (`stream_mixer`)."""
+        return self.stream_mixer.n if self.stream_mixer else 1
 
     @classmethod
     def num_params(cls, cfg: ModelConfig) -> int:
@@ -799,20 +830,22 @@ class DecoderStack:
         """Full (global) parameter pytree, float32: embedding, `_segments`
         stacked for scan, final norm, the head unless tied, `_init_more`."""
         head = {} if self.tied_head else {"lm_head": self._init_head(key)}
+        leave = self._exit_leaves(lambda m: m.init(fold(key, "hc_exit")))
         return {"embedding": self.embedding.init(fold(key, "embedding")),
                 **{name: self._init_layers(key, name, count, names)
                    for name, count, names in self._segments},
                 "norm": self.final_norm.init(fold(key, "norm")),
-                **head, **self._init_more(key)}
+                **head, **leave, **self._init_more(key)}
 
     def specs(self) -> Params:
         """PartitionSpec pytree matching `init`'s structure."""
         head = {} if self.tied_head else {"lm_head": self.lm_head.specs()}
+        leave = self._exit_leaves(lambda m: m.specs())
         return {"embedding": self.embedding.specs(),
                 **{name: self._layer_specs(names, name)
                    for name, _, names in self._segments},
                 "norm": self.final_norm.specs(),
-                **head, **self._more_specs()}
+                **head, **leave, **self._more_specs()}
 
     def _init_more(self, key: jax.Array) -> Params:
         """Top-level groups of the family's own, after the head."""
@@ -860,8 +893,25 @@ class DecoderStack:
         return layers
 
     def _segment_mods(self, names=None) -> Dict[str, Any]:
-        return (self._mods if names is None
+        """The modules a layer's parameters are made of: `names` of `_mods`
+        and, where the residual state is streams, the two mixers."""
+        mods = (self._mods if names is None
                 else {n: self._mods[n] for n in names})
+        if self.stream_mixer:
+            mods = {**mods, "hc_attn": self.stream_mixer,
+                    "hc_ffn": self.stream_mixer}
+        return mods
+
+    @property
+    def exit_mixer(self):
+        """The mixer behind the last layer: its `pre` alone."""
+        return dataclasses.replace(self.stream_mixer, exit_only=True)
+
+    def _exit_leaves(self, make) -> Params:
+        """`{"hc_exit": make(the exit mixer)}` where the residual state is
+        streams, else nothing: the group a tree (or a family's module with
+        a head of its own) holds for where its streams leave."""
+        return {"hc_exit": make(self.exit_mixer)} if self.stream_mixer else {}
 
     @property
     def _interleaved(self) -> bool:
@@ -973,12 +1023,28 @@ class DecoderStack:
             layer_params = exchange_grads(
                 jax.tree.map(lambda a: a.astype(dtype), layer_params), "dp")
         m, tp = self._mods, self._tp_sublayers
-        b = x.shape[0]
-        t = pos.shape[1]  # full (cp-local) sequence length, not x.shape[1]
+        # full (cp-local) sequence length, not x.shape[1]
+        b, t = pos.shape
+        mixer, mixed = self.stream_mixer, {}
+
+        def read(x, name):
+            """What the sublayer behind the joint `name` reads of the
+            residual state: the stream or, of several, the mixer's sum."""
+            if mixer is None:
+                return x
+            mixed[name] = mixer.maps(layer_params[name], x)
+            return mixer.pre(mixed[name], x)
+
+        def join(x, y, name):
+            """The residual state past the sublayer whose output is `y`."""
+            if mixer is None:
+                return x + y
+            return mixer.post(mixed[name], x, y)
 
         def qkv(x):
             norm = self.attn_norm_key
-            y = tp.gather(m[norm].apply(layer_params[norm], x))
+            y = tp.gather(m[norm].apply(layer_params[norm],
+                                        read(x, "hc_attn")))
             with self._attn_scoped():
                 return self._qkv(
                     layer_params, y, tp,
@@ -1004,14 +1070,17 @@ class DecoderStack:
                 a = m[norm].apply(layer_params[norm], a)
             # (what entered the layer, for a router that reads it)
             router_x = x if self.router_reads_layer_input else None
-            x = x + a
+            x = join(x, a, "hc_attn")
 
             norm = self.ffn_norm_key
-            y = tp.gather(m[norm].apply(layer_params[norm], x))
+            y = tp.gather(m[norm].apply(layer_params[norm],
+                                        read(x, "hc_ffn")))
             ff, aux = self._ffn(layer_params, y, tp, dtype, router_x)
             if norm := self.post_ffn_norm_key:
                 ff = m[norm].apply(layer_params[norm], ff)
-            return x + ff, aux
+            if mixer is not None:
+                aux = {**(aux or {}), **mixer.counters(*mixed.values())}
+            return join(x, ff, "hc_ffn"), aux
 
         # Under ring overlap the dense segments run even on pipeline-bubble
         # steps (live is ignored except by ring attention): their tp
@@ -1024,7 +1093,8 @@ class DecoderStack:
             # no output projection of the stack's: the layer's mixer hands
             # back the sublayer's output itself (`_mix`)
             norm = self.attn_norm_key
-            y = tp.gather(m[norm].apply(layer_params[norm], x))
+            y = tp.gather(m[norm].apply(layer_params[norm],
+                                        read(x, "hc_attn")))
             return ffn_half(x, self._mix(layer_params, y, layer_pos, dtype))
         if live is None or tp.ring_ov:
             q, k, v, *gate = qkv(x)
@@ -1295,6 +1365,9 @@ class DecoderStack:
         # x in the compute dtype with the family's positions in it, and the
         # (b, t, ...) arrays every layer gets (`_position_qk`), if any
         x, layer_pos = self._positions(params, x, position_ids, dtype)
+        if self.stream_mixer:
+            # X_0: the embedding's row, n times
+            x = jnp.broadcast_to(x, (self.residual_streams, *x.shape))
 
         layer_fn = remat_wrap(
             self._layer_body, resolve_remat(self, params, input_ids.shape),
@@ -1327,8 +1400,14 @@ class DecoderStack:
             # a block of dense layers has none; where several blocks count
             # (a row a layer), the rows follow the layers
             auxs = [aux for aux in auxs if aux is not None]
-            aux = (jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
-                   if len(auxs) > 1 else auxs[0] if auxs else None)
+            if self.stream_mixer and len(auxs) > 1:
+                # every layer counts its mixers, an expert layer its router
+                # too: each counter's rows follow the layers that count it
+                aux = {k: jnp.concatenate([a[k] for a in auxs if k in a])
+                       for k in sorted(set().union(*auxs))}
+            else:
+                aux = (jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
+                       if len(auxs) > 1 else auxs[0] if auxs else None)
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
 
     def _scan_periods(self, run, x: jax.Array, params: Params, period):
@@ -1352,11 +1431,17 @@ class DecoderStack:
             lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), aux)
 
     def _head(self, params: Params, norm_params: Params, x: jax.Array,
-              dtype, scope: "str | None" = "head_loss") -> jax.Array:
+              dtype, scope: "str | None" = "head_loss",
+              exit_params: "Params | None" = None) -> jax.Array:
         """Final norm (with `norm_params`) and the head: LOCAL logits.
         `head_loss` is the one boundary inside the loss that a device trace
         is split at (final norm, head, CE; benchmark/lib/program_trace.py);
-        a caller already inside a scope of its own passes None."""
+        a caller already inside a scope of its own passes None. Residual
+        streams leave through the exit mixer first (with `exit_params`;
+        None: the tree's own `hc_exit`)."""
+        if self.stream_mixer:
+            x = self.exit_mixer.exit(
+                params["hc_exit"] if exit_params is None else exit_params, x)
         with (jax.named_scope(scope) if scope
               else contextlib.nullcontext()):
             x = self.final_norm.apply(norm_params, x)
@@ -1781,6 +1866,12 @@ class DecoderStack:
         aux is the router sums of the auxiliary losses, or nothing."""
         if not self.is_moe or self._router_aux_losses:
             return {}
+        if self.stream_mixer:
+            # the mixers' rows are maxima and a mean over the tokens
+            over = {"hc_sinkhorn_err": lax.pmax, "hc_colsum_err": lax.pmax,
+                    "hc_res_offdiag": lax.pmean}
+            return {k: over.get(k, lax.psum)(a, batch_axes)
+                    for k, a in aux.items()}
         return jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)
 
     def expert_layer_rows(self, params: Params, rows: jax.Array) -> Params:

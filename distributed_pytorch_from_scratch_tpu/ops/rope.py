@@ -15,7 +15,8 @@ deviations from the reference:
 
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,16 +51,77 @@ def apply_rotary(q: jax.Array, k: jax.Array, cos: jax.Array, sin: jax.Array) -> 
     return q_rot, k_rot
 
 
-def rope_angles(position_ids: jax.Array, dim: int,
-                base: float = 10000.0) -> Tuple[jax.Array, jax.Array]:
+class YarnScaling(NamedTuple):
+    """A `rope_scaling` of type `yarn` (Peng et al., arXiv:2309.00071) in
+    DeepSeek-V2/V3's keys, as their published modelling code reads them."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, mscale: float) -> float:
+        """`yarn_get_mscale`."""
+        return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def table_scale(self) -> float:
+        """What cos and sin are multiplied by."""
+        return (self._mscale(self.factor, self.mscale)
+                / self._mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the scores' 1/sqrt(width) is multiplied by: the square of
+        `yarn_get_mscale(factor, mscale_all_dim)` (1 where that key is 0)."""
+        if not self.mscale_all_dim:
+            return 1.0
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+
+def yarn_inv_freq(dim: int, base: float, scaling: YarnScaling) -> jax.Array:
+    """The `dim / 2` inverse frequencies under YaRN, float32: pair i's
+    `base^(-2i/dim)` kept where its wavelength makes more than `beta_fast`
+    turns in the original context, divided by `factor` where it makes fewer
+    than `beta_slow`, and blended by a linear ramp over the pairs between
+    (the two bounds rounded outward to whole pairs)."""
+    def pair_of(turns: float) -> float:     # `yarn_find_correction_dim`
+        return (dim * math.log(scaling.original_max_position_embeddings
+                               / (turns * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(pair_of(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_of(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    kept = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return kept / scaling.factor * ramp + kept * (1.0 - ramp)
+
+
+def rope_angles(position_ids: jax.Array, dim: int, base: float = 10000.0,
+                scaling: "YarnScaling | None" = None
+                ) -> Tuple[jax.Array, jax.Array]:
     """(cos, sin), each (b, t, dim/2) float32, of pair i's angle
     `pos * base^(-2i/dim)` at `position_ids` (b, t): what
     `apply_rotary_interleaved` takes. Computed from the positions, so no
-    table caps the length."""
+    table caps the length. Under a `scaling` the frequencies are
+    `yarn_inv_freq`'s and cos and sin carry its `table_scale`."""
     assert dim % 2 == 0
-    theta = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        theta = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32)
+                                / dim))
+    else:
+        theta = yarn_inv_freq(dim, base, scaling)
     ang = position_ids.astype(jnp.float32)[..., None] * theta
-    return jnp.cos(ang), jnp.sin(ang)
+    if scaling is None or scaling.table_scale == 1.0:
+        return jnp.cos(ang), jnp.sin(ang)
+    return (jnp.cos(ang) * scaling.table_scale,
+            jnp.sin(ang) * scaling.table_scale)
 
 
 def apply_rotary_interleaved(x: jax.Array, cos: jax.Array,

@@ -13,7 +13,8 @@ of every head and the ONE `k_r`, which all heads share. `q = [q_nope |
 q_rope]`, `k = [k_nope | k_r]` are `qk_nope + qk_rope` wide, `v` is
 `v_head_dim` wide: the attention kernels take the two widths
 (ops/pallas/flash_attention.py), scale the scores by 1/sqrt(q's width) and
-return v's. The heads' outputs go through `wo` (heads * v -> d).
+return v's; where the positions are YaRN's, q carries the rest of the
+scale (`softmax_scale`). The heads' outputs go through `wo` (heads * v -> d).
 
 Tensor parallelism: `wq_b` and `wkv_b` are column-parallel over heads (a
 head's columns are contiguous) and `wo` row-parallel, the Megatron pattern;
@@ -77,6 +78,10 @@ class LatentAttention:
     qk_rope_head_dim: int
     v_head_dim: int
     eps: float = 1e-6
+    # what the scores' 1/sqrt(q's width) is multiplied by (YaRN's mscale^2:
+    # `ops/rope.YarnScaling.softmax_scale`). The attention kernels scale by
+    # 1/sqrt(width) themselves, so q carries the rest
+    softmax_scale: float = 1.0
 
     @property
     def qk_head_dim(self) -> int:
@@ -140,4 +145,6 @@ class LatentAttention:
         k_r = jnp.broadcast_to(k_r, k_nope.shape[:-1] + (rope,))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, k_r], axis=-1)
+        if self.softmax_scale != 1.0:
+            q = q * jnp.asarray(self.softmax_scale, q.dtype)
         return q, k, v

@@ -36,7 +36,7 @@ from .config import (IGNORE_INDEX, MODEL_PRESETS, REMAT_CHOICES, MeshConfig,
                      ModelConfig, OptimizerConfig, model_preset)
 from .data.dataset import get_dataloader
 from .data.prefetch import Prefetcher, stack_window, window_stream
-from .models import FAMILIES, build_model, family_class
+from .models import FAMILIES, build_model, facts_family, family_class
 from .obs import TrainObserver, analyze_compiled, format_analysis
 from .obs.runindex import run_stamp
 from .ops.attention import resolve_attention_impl
@@ -548,15 +548,21 @@ def train(args: argparse.Namespace) -> dict:
             compute_dtype="bfloat16" if args.bf16 else "float32")
         needs = family_class(args.family).config_extra
         carries = cfg.family_facts
-        if needs != carries:
+        # (two families may read one field: `facts_family` says whose the
+        # preset's facts are)
+        owner = facts_family(cfg) if carries else None
+        if needs != carries or (owner
+                                and owner is not family_class(args.family)):
             pairs = ", ".join(
                 f"--family {name} --model {preset}"
                 for name, cls in FAMILIES.items() if cls.config_extra
                 for preset, c in MODEL_PRESETS.items()
-                if c.family_facts == cls.config_extra)
+                if c.family_facts and facts_family(c) is cls)
             raise SystemExit(
                 f"--family {args.family} reads the config field {needs!r} "
-                f"and --model {args.model} carries {carries!r}: a family "
+                f"and --model {args.model} carries {carries!r}"
+                + (f" for --family {owner.family}" if owner else "")
+                + ": a family "
                 f"with facts of its own goes with a preset that has them "
                 f"({pairs}), and such a preset with no other family")
         # ZeRO stage: explicit --zero wins; --zero1 is the stage-1 alias

@@ -82,7 +82,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                state_bytes_per_param: float = 16.0,
                grad_bytes_per_param: float = 4.0,
                layer_extra_elems_per_token: float = 0.0,
-               head_rows_share: float = 1.0) -> Dict[str, float]:
+               head_rows_share: float = 1.0,
+               residual_streams: int = 1) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -95,7 +96,9 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     grads     the f32 gradient tree, live from the backward to Adam
     cast      the stacked layer weights in the compute dtype (the compiler
               hoists the casts out of the layer loops)
-    stacks    the layer input every rung keeps, plus the rung's residuals,
+    stacks    the layer input every rung keeps (`residual_streams` x d wide:
+              a family of hyper-connections carries several,
+              `DecoderStack.stream_mixer`), plus the rung's residuals,
               L of each, at their logical sizes (the chip's count: the
               stack of `flash_out` is not kept in the kernel's layout, which
               pads a head of 64 to the 128 lanes)
@@ -134,10 +137,11 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         # input, 2 norm outputs, q/k/v and their head-split copies, flash
         # o/lse + the projection's input, both row-linear outputs, the
         # FFN's inputs and activation
-        per_layer = (5 * wide + 2 * q_w + 4 * kv_w + names["flash_out"]
-                     + names["flash_lse"] + (ffn_inputs + 1) * f_w)
+        per_layer = ((4 + residual_streams) * wide + 2 * q_w + 4 * kv_w
+                     + names["flash_out"] + names["flash_lse"]
+                     + (ffn_inputs + 1) * f_w)
     else:
-        per_layer = wide + sum(names[n] for _, ns in REMAT_LADDER[:rung + 1]
+        per_layer = residual_streams * wide + sum(names[n] for _, ns in REMAT_LADDER[:rung + 1]
                                for n in ns)
     out = {
         "resident": param_count * (state_bytes_per_param
@@ -306,7 +310,8 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
         ffn_inputs=model.ffn_inputs,
         sequence_parallel=model.tp_layout(t)[0],
         layer_extra_elems_per_token=model.layer_extra_elems_per_token,
-        head_rows_share=model.head_rows_share)
+        head_rows_share=model.head_rows_share,
+        residual_streams=model.residual_streams)
     return _pick(parts, model.remat_budget_gib, None, allow_false=False,
                  verbose=True,
                  note=f"; traced b{b} x t{t}, tp{model.tp_size}")

@@ -173,7 +173,8 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     is the padding they still pay), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
     balance); and, where the step ran the selection bias's rule, the mean
-    size of a bias entry's step (`router_bias_step`)."""
+    size of a bias entry's step (`router_bias_step`); and the mixers'
+    counters of a family with residual streams."""
     import numpy as np
 
     lo = cfg.expert_offset
@@ -188,6 +189,11 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
         routed.max(-1) / np.maximum(routed.mean(-1), 1e-9)))
     if "router_bias_step" in counters:
         out["router_bias_step"] = float(counters["router_bias_step"])
+    if "hc_sinkhorn_err" in counters:
+        # a family of hyper-connection streams (parallel/hyper.py): the
+        # worst layer's Sinkhorn error, the layers' mean mixing
+        out["hc_sinkhorn_err"] = float(np.max(counters["hc_sinkhorn_err"]))
+        out["hc_res_offdiag"] = float(np.mean(counters["hc_res_offdiag"]))
     return out
 
 

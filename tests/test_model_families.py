@@ -14,6 +14,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          ConvMoEConfig,
                                                          EarlyMoEConfig,
                                                          GdnMoEConfig,
+                                                         HyperConnectionConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
                                                          SwaMoEConfig,
@@ -88,14 +89,19 @@ def config_for(family, config):
                            gdn_moe=GdnMoEConfig(experts_held=held, **GDN))
     if extra != "latent_moe":
         return CONFIGS[config]
+    # (two families read these facts: the one with residual streams takes
+    # them with `hyper`, four streams and three Sinkhorn rounds)
+    hyper = (HyperConnectionConfig(hc_sinkhorn_iters=3)
+             if FAMILIES[family].stream_mixer is not None else None)
     return ModelConfig(num_experts=8, **TINY, latent_moe=LatentMoEConfig(
-        experts_held=held, **LATENT))
+        experts_held=held, hyper=hyper, **LATENT))
 
 
 TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
                 "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
-                "early_moe": "tiny-early-moe"}
+                "early_moe": "tiny-early-moe",
+                "mhc_mla_moe": "tiny-mhc-mla-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
