@@ -29,7 +29,8 @@ written (`decodable` is False, as `mla_moe`'s).
 
 Named scopes inside the step, beside `mla_moe`'s: `mhc` around every mixer
 with `mhc/maps`, `mhc/sinkhorn`, `mhc/pre`, `mhc/post`, `mhc/exit` beneath
-(parallel/hyper.py). Counters, one row a layer (dense layers too):
+(parallel/hyper.py; on a TPU the passes over the streams are the Pallas
+kernels of ops/pallas/stream_mixer.py, under the same scopes). Counters, one row a layer (dense layers too):
 `hc_sinkhorn_err`, `hc_colsum_err`, `hc_res_offdiag`.
 """
 

@@ -4,8 +4,9 @@ positions (ops/rope.py, parallel/mla.py). CPU, tiny sizes, seeded weights.
 
 * the program against the plain reference (models/vanilla_mhc_mla_moe.py):
   loss, logits and EVERY gradient leaf, with and without the
-  multi-token-prediction module, at tp 1 and tp 2 and through the flash
-  kernel's interpreter, float32; the loss in bfloat16;
+  multi-token-prediction module, at tp 1 and tp 2, through the flash
+  kernel's interpreter and through the mixers' kernels' interpreter,
+  float32; the loss in bfloat16;
 * the mixer alone: H's rows and columns sum to one within the counter's own
   reading after 20 rounds, the clamp holds at +-30, the maps are float32
   whatever the streams' dtype, an exit mixer has `pre` alone;
@@ -24,6 +25,7 @@ positions (ops/rope.py, parallel/mla.py). CPU, tiny sizes, seeded weights.
 """
 
 import dataclasses
+import functools
 import hashlib
 import json
 import math
@@ -78,17 +80,37 @@ def on_mesh(cfg, tp, **kw):
     return mesh, build_model(FAMILY, cfg, tp_size=tp, **kw)
 
 
+class MixersInterpreted(HyperLatentMoETransformer):
+    """The family with its mixers' kernels under the Pallas interpreter:
+    steered here, the program has no option for it (on a TPU the mixers take
+    their kernels by themselves)."""
+
+    @functools.cached_property
+    def stream_mixer(self):
+        return dataclasses.replace(
+            HyperLatentMoETransformer.stream_mixer.func(self), interpret=True)
+
+
 # ---- the program against the plain reference ----
 
 @pytest.mark.parametrize("mtp", [1, 0], ids=["module", "no-module"])
 @pytest.mark.parametrize("tp,impl", [(1, "xla"), (2, "xla"),
-                                     (1, "flash_interpret")])
+                                     (1, "flash_interpret"),
+                                     (1, "mixers_interpret")])
 def test_loss_logits_and_every_gradient_leaf_equal_the_reference(tp, impl,
                                                                  mtp):
     """A job that holds experts 2..5 of 8; four streams, 20 Sinkhorn
-    rounds, YaRN over an original context shorter than the sequence."""
+    rounds, YaRN over an original context shorter than the sequence.
+    `mixers_interpret`: the mixers' Pallas kernels under the interpreter
+    (ops/pallas/stream_mixer.py), at a width they hold."""
     cfg = tiny(experts_held=4, expert_offset=2, num_nextn_predict_layers=mtp)
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
+    if impl == "mixers_interpret":
+        cfg = dataclasses.replace(cfg, attn_dim=128)
+        mesh, _ = on_mesh(cfg, tp)
+        model = MixersInterpreted(cfg, tp_size=tp, attn_impl="xla")
+        assert model.stream_mixer.interpret and model.exit_mixer.interpret
+    else:
+        mesh, model = on_mesh(cfg, tp, attn_impl=impl)
     params = model.init(jax.random.key(3))
     ids, tgt, pos = batch(cfg)
     tgt = tgt.copy()
@@ -111,8 +133,17 @@ def test_loss_logits_and_every_gradient_leaf_equal_the_reference(tp, impl,
     for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
         a, b = np.asarray(a), np.asarray(b)
         name = jax.tree_util.keystr(path)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            name
+        # (a mixer's alpha is ONE number, a sum over every token and stream
+        # that cancels to a hundredth of its terms or less. The text's
+        # transpose adds the reference's terms in the reference's order;
+        # the kernels' backward does not, and is held to 1e-5 of what a
+        # mixer's b and W read, 3e-4, where the sum itself is smaller: the
+        # text reads the same 1.6e-9 off the reference once x64 reorders
+        # it)
+        floor = 3e-4 if impl == "mixers_interpret" and "alpha" in name \
+            else 1e-6
+        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)),
+                                                   floor), name
         if "hc_" in name:
             # every mixer leaf is reached: W, alpha and b of each joint
             assert np.any(a), name
@@ -136,7 +167,7 @@ def test_the_loss_in_bfloat16_is_near_the_float32_reference():
     X = jnp.ones((4, 1, 8, 64), jnp.bfloat16)
     p = mixer.init(jax.random.key(0))
     maps = mixer.maps(p, X)
-    assert {m.dtype for m in maps} == {jnp.dtype("float32")}
+    assert {m.dtype for m in maps[:3]} == {jnp.dtype("float32")}
     assert mixer.pre(maps, X).dtype == mixer.post(
         maps, X, X[0]).dtype == jnp.bfloat16
 

@@ -359,6 +359,55 @@ def test_the_window_kernels_compile_at_the_window_cells_shapes(
         r'custom_call_target="tpu_custom_call"', text)) == names
 
 
+def test_the_mixers_kernels_compile_at_the_mhc_cells_shape(topo,
+                                                           described_tpu):
+    """Mosaic takes the hyper-connection mixers' four kernels (PR 58) at
+    cell 11's shape (4 streams, 4096 tokens, 3584 wide, bf16; a layer's
+    mixer of 24 maps and the exit's of 4) at the module's blocks, within
+    the VMEM each call asks for; the compiled calls carry the names and
+    operand counts that keep them out of the benchmark's flash patterns
+    (`^flash_`, 3 or 6 operands)."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.pallas import (
+        stream_mixer as mixer)
+    n, t, d = 4, 4096, 3584
+    assert mixer.holds(n, d, jnp.dtype("bfloat16"))
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    X, row = arg(bf16, n, t, d), arg(bf16, t, d)
+    maps = (arg(f32, t, 128), arg(f32, t, 128))
+    calls = []
+    for width, passes in ((24, True), (4, False)):
+        params = (arg(f32, n * d, width), arg(f32), arg(f32, n))
+        kw = dict(width=width, eps=1e-6)
+        read = lambda X, *p: mixer.read_forward(X, *p, norm_eps=1e-6, **kw)
+        back = lambda X, w, a, b, tok, dm, du, *through: \
+            mixer.read_backward(X, w, a, b, tok, dm, du, through or None,
+                                **kw)
+        for fn, args in (
+                (read, (X, *params)),
+                (back, (X, *params, arg(f32, t, 128), arg(f32, width, t),
+                        row) + ((X, maps[0]) if passes else ()))):
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            calls += re.findall(
+                r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
+                r'custom_call_target="tpu_custom_call"', text)
+    for fn, args in (
+            (mixer.write_forward, (X, row, *maps)),
+            (lambda *a: mixer.write_backward(*a, part=False)[1:],
+             (X, row, *maps, X))):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls += re.findall(
+            r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
+            r'custom_call_target="tpu_custom_call"', text)
+    assert [(name.split(".")[0], operands.count("%"))
+            for name, operands in calls] == [
+        ("mhc_read_fwd", 4), ("mhc_read_bwd", 9), ("mhc_read_fwd", 4),
+        ("mhc_read_bwd", 7), ("mhc_write_fwd", 4), ("mhc_write_bwd", 5)]
+
+
 def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
         topo, described_tpu):
     """Mosaic takes the rule's two kernels (PR 36; since PR 38 they make a
