@@ -131,6 +131,49 @@ class GdnMoEConfig:
 
 
 @dataclass(frozen=True)
+class KdaMlaMoEConfig:
+    """What the `kda_mla_moe` family (models/kda_mla_moe.py) needs beyond
+    `ModelConfig`'s own fields: Kimi Delta Attention layers (a delta rule
+    whose decay is a channel's, under a bounded gate) with one head-gated
+    latent-attention layer closing every `layer_group_size` layers, leading
+    dense layers, a sigmoid router with a group-limited selection over
+    routed experts (of which this job may hold a slice) with a shared
+    expert, and a multi-token-prediction module. The keys are Ling-3.0's
+    `config.json` names (`bailing_hybrid`) where one exists. In
+    `ModelConfig`, `attn_dim` is the model width, `num_heads` the heads of
+    BOTH mixers, `ffn_dim` the leading dense layers' SwiGLU width,
+    `num_layers` the main model's layers (whole groups), `num_experts` the
+    ROUTED experts the router scores and `moe_top_k` the experts a token
+    takes."""
+
+    head_dim: int                   # a KDA head's width: d_k = d_v
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    moe_intermediate_size: int
+    q_lora_rank: "int | None" = None
+    layer_group_size: int = 6       # layer i is latent where (i+1) % it == 0
+    first_k_dense_replace: int = 2
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
+    n_shared_experts: int = 1
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.0    # `mtp_loss_scaling_factor`
+    rms_norm_eps: float = 1e-6
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+
+@dataclass(frozen=True)
 class ConvMoEConfig:
     """What the `conv_moe` family (models/conv_moe.py) needs beyond
     `ModelConfig`'s own fields: which layers mix by a gated short
@@ -293,6 +336,8 @@ class ModelConfig:
     swa_moe: "SwaMoEConfig | None" = None
     # The `early_moe` family's facts (None for every other family).
     early_moe: "EarlyMoEConfig | None" = None
+    # The `kda_mla_moe` family's facts (None for every other family).
+    kda_mla_moe: "KdaMlaMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -361,7 +406,7 @@ class ModelConfig:
 
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
-                "early_moe")
+                "early_moe", "kda_mla_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -473,6 +518,20 @@ MODEL_PRESETS = {
             sliding_window_layout=(0, 1, 1, 1) * 2,
             rope_layout=(0, 1, 1, 1) * 2, head_dim=32,
             moe_ffn_hidden_size=32, sliding_window_size=16)),
+    # the `kda_mla_moe` family at a CPU size: Ling-3.0's pattern in small,
+    # groups of three layers (two Kimi Delta Attention layers, 4 heads 16
+    # wide, then one head-gated latent-attention layer, q/k 24 wide over a
+    # 16-wide latent with no q latent), the first layer dense; 16 routed
+    # experts in 4 groups of which a token keeps 2 (sigmoid top-2, a shared
+    # expert); one multi-token-prediction module
+    "tiny-kda-mla-moe": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_layers=6,
+        vocab_size=1024, maxlen=256, rope_theta=10000.0, num_experts=16,
+        moe_top_k=2, kda_mla_moe=KdaMlaMoEConfig(
+            head_dim=16, kv_lora_rank=16, qk_nope_head_dim=16,
+            qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
+            layer_group_size=3, first_k_dense_replace=1, n_group=4,
+            topk_group=2, num_nextn_predict_layers=1, mtp_loss_weight=0.3)),
 }
 
 

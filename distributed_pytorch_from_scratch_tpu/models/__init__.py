@@ -1,10 +1,16 @@
-"""The model families, and the one place a family's name becomes a class."""
+"""The model families, and the one place a family's name becomes a class:
+`llama` (the reference's block) and `gpt2`, and eight drawn from published
+configurations, each holding one share of the experts its router scores:
+`mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
+`mhc_mla_moe` and `kda_mla_moe` (docs/DESIGN.md, "What a family file
+holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
 from .early_moe import EarlyRouterMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
+from .kda_mla_moe import KdaMlaMoETransformer
 from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
 from .stack import DecoderStack
@@ -15,7 +21,7 @@ FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
     ConvMoETransformer, BlockDiffusionMoETransformer,
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
-    HyperLatentMoETransformer)}
+    HyperLatentMoETransformer, KdaMlaMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -72,8 +72,89 @@ DENSE = ATTN + ("gate_proj", "up_proj", "down_proj")
 EXPERT = ATTN + ("moe",)
 
 
+class MultiTokenPrediction:
+    """The multi-token-prediction module (module docstring) of a family
+    whose facts (`config_extra`) hold `num_nextn_predict_layers` (0 or 1)
+    and `mtp_loss_weight` and whose `_segments` end in `mtp_layers`: its own
+    leaves beside the stack's tree and `_extra_loss`. `models/kda_mla_moe.py`
+    takes it too."""
+
+    @property
+    def _facts(self):
+        return getattr(self.cfg, self.config_extra)
+
+    @functools.cached_property
+    def eh_proj(self) -> ReplicatedLinear:
+        return ReplicatedLinear(2 * self.d, self.d)
+
+    # ---- the module's own leaves, beside the stack's tree ----
+
+    def _init_more(self, key: jax.Array) -> Params:
+        if not self._facts.num_nextn_predict_layers:
+            return {}
+        k = fold(key, "mtp")
+        # (the module's streams leave through an exit mixer of its own)
+        leave = self._exit_leaves(lambda m: m.init(fold(k, "hc_exit")))
+        return {"mtp": {
+            "hnorm": self.final_norm.init(k),
+            "enorm": self.final_norm.init(k),
+            "eh_proj": self.eh_proj.init(fold(k, "eh_proj")),
+            "norm": self.final_norm.init(k), **leave,
+        }}
+
+    def _more_specs(self) -> Params:
+        if not self._facts.num_nextn_predict_layers:
+            return {}
+        norm = self.final_norm.specs()
+        return {"mtp": {"hnorm": norm, "enorm": norm,
+                        "eh_proj": self.eh_proj.specs(), "norm": norm,
+                        **self._exit_leaves(lambda m: m.specs())}}
+
+    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
+                    aux, trunk, input_ids, target_ids, position_ids,
+                    mode: str, batch_axes):
+        counters = self._counters(aux, batch_axes)
+        if not self._facts.num_nextn_predict_layers:
+            return loss, counters
+        with jax.named_scope("mtp"):
+            mp = params["mtp"]
+            # position i: h_i with the embedding of token i+1 (its target)
+            # predicts token i+2 (the next position's target); the last
+            # position has none, nor has one whose next token is ignored
+            known = target_ids != IGNORE_INDEX
+            nxt = self.embedding.apply(params["embedding"],
+                                       jnp.where(known, target_ids, 0))
+            # (of residual streams, each is normed by the one `hnorm` and
+            # projected with the embedding by the one matrix; the module's
+            # layer mixes them and they leave through its own exit mixer)
+            h = self.final_norm.apply(mp["hnorm"], x)
+            e = self.final_norm.apply(mp["enorm"], nxt.astype(trunk.dtype))
+            h = jnp.concatenate([h, jnp.broadcast_to(e, h.shape)], axis=-1)
+            h = self.eh_proj.apply(mp["eh_proj"], h, trunk.dtype)
+            h, mtp_aux = trunk.run(h, params["mtp_layers"])
+            logits = self._head(params, mp["norm"], h, trunk.dtype,
+                                scope=None, exit_params=mp.get("hc_exit"))
+            after = jnp.concatenate(
+                [target_ids[:, 1:],
+                 jnp.full_like(target_ids[:, :1], IGNORE_INDEX)], axis=1)
+            after = jnp.where(known, after, IGNORE_INDEX)
+            token_loss, valid = self._token_ce(logits, after, mode)
+            total = lax.psum(jnp.sum(jnp.where(valid, token_loss, 0.0)),
+                             batch_axes)
+            count = lax.psum(jnp.sum(valid.astype(jnp.float32)), batch_axes)
+            mtp_loss = total / jnp.maximum(count, 1.0)
+        mtp_aux = self._counters(mtp_aux, batch_axes)
+        # (the module's layer counts what an expert layer counts; a row of
+        # a counter only other layers keep, a mixer's, has none to add)
+        counters = {k: (jnp.concatenate([counters[k], mtp_aux[k]])
+                        if k in mtp_aux else counters[k])
+                    for k in sorted(counters)}
+        return (loss + self._facts.mtp_loss_weight * mtp_loss,
+                {**counters, "loss_mtp": mtp_loss})
+
+
 @dataclass(frozen=True)
-class LatentMoETransformer(DecoderStack):
+class LatentMoETransformer(MultiTokenPrediction, DecoderStack):
     """The mla_moe family (module docstring)."""
 
     family = "mla_moe"
@@ -203,33 +284,6 @@ class LatentMoETransformer(DecoderStack):
                 scaling=lm.routed_scaling_factor, tp_size=self.tp_size),
         }
 
-    @functools.cached_property
-    def eh_proj(self) -> ReplicatedLinear:
-        return ReplicatedLinear(2 * self.d, self.d)
-
-    # ---- the module's own leaves, beside the stack's tree ----
-
-    def _init_more(self, key: jax.Array) -> Params:
-        if not self.cfg.latent_moe.num_nextn_predict_layers:
-            return {}
-        k = fold(key, "mtp")
-        # (the module's streams leave through an exit mixer of its own)
-        leave = self._exit_leaves(lambda m: m.init(fold(k, "hc_exit")))
-        return {"mtp": {
-            "hnorm": self.final_norm.init(k),
-            "enorm": self.final_norm.init(k),
-            "eh_proj": self.eh_proj.init(fold(k, "eh_proj")),
-            "norm": self.final_norm.init(k), **leave,
-        }}
-
-    def _more_specs(self) -> Params:
-        if not self.cfg.latent_moe.num_nextn_predict_layers:
-            return {}
-        norm = self.final_norm.specs()
-        return {"mtp": {"hnorm": norm, "enorm": norm,
-                        "eh_proj": self.eh_proj.specs(), "norm": norm,
-                        **self._exit_leaves(lambda m: m.specs())}}
-
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
 
     @property
@@ -247,45 +301,6 @@ class LatentMoETransformer(DecoderStack):
     def _qkv(self, lp: Params, y: jax.Array, tp: TPSublayers, layer_pos,
              dtype, b: int, t: int):
         return self.attention.qkv(self._mods, lp, y, *layer_pos, dtype)
-
-    def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
-                    aux, trunk, input_ids, target_ids, position_ids,
-                    mode: str, batch_axes):
-        counters = self._counters(aux, batch_axes)
-        if not self.cfg.latent_moe.num_nextn_predict_layers:
-            return loss, counters
-        with jax.named_scope("mtp"):
-            mp = params["mtp"]
-            # position i: h_i with the embedding of token i+1 (its target)
-            # predicts token i+2 (the next position's target); the last
-            # position has none, nor has one whose next token is ignored
-            known = target_ids != IGNORE_INDEX
-            nxt = self.embedding.apply(params["embedding"],
-                                       jnp.where(known, target_ids, 0))
-            # (of residual streams, each is normed by the one `hnorm` and
-            # projected with the embedding by the one matrix; the module's
-            # layer mixes them and they leave through its own exit mixer)
-            h = self.final_norm.apply(mp["hnorm"], x)
-            e = self.final_norm.apply(mp["enorm"], nxt.astype(trunk.dtype))
-            h = jnp.concatenate([h, jnp.broadcast_to(e, h.shape)], axis=-1)
-            h = self.eh_proj.apply(mp["eh_proj"], h, trunk.dtype)
-            h, mtp_aux = trunk.run(h, params["mtp_layers"])
-            logits = self._head(params, mp["norm"], h, trunk.dtype,
-                                scope=None, exit_params=mp.get("hc_exit"))
-            after = jnp.concatenate(
-                [target_ids[:, 1:],
-                 jnp.full_like(target_ids[:, :1], IGNORE_INDEX)], axis=1)
-            after = jnp.where(known, after, IGNORE_INDEX)
-            token_loss, valid = self._token_ce(logits, after, mode)
-            total = lax.psum(jnp.sum(jnp.where(valid, token_loss, 0.0)),
-                             batch_axes)
-            count = lax.psum(jnp.sum(valid.astype(jnp.float32)), batch_axes)
-            mtp_loss = total / jnp.maximum(count, 1.0)
-        mtp_aux = self._counters(mtp_aux, batch_axes)
-        counters = jax.tree.map(lambda a, m: jnp.concatenate([a, m]),
-                                counters, mtp_aux)
-        return (loss + self.cfg.latent_moe.mtp_loss_weight * mtp_loss,
-                {**counters, "loss_mtp": mtp_loss})
 
     @staticmethod
     def param_counts(cfg: ModelConfig) -> Dict[str, int]:

@@ -404,6 +404,19 @@ class TPSublayers:
                                      output_layout=self.out_layout, **order)
 
 
+def _rows_in_order(auxs):
+    """Several blocks' (or a period's keys') counters, a row a layer each,
+    as one: the rows in the order the layers ran. Where the layers do not
+    all count the same (every layer its mixers and an expert layer its
+    router too; a linear-attention layer its decay), each counter's rows
+    follow the layers that count it."""
+    keys = [set(aux) if isinstance(aux, dict) else None for aux in auxs]
+    if None not in keys and any(k != keys[0] for k in keys):
+        return {k: jnp.concatenate([a[k] for a in auxs if k in a])
+                for k in sorted(set().union(*keys))}
+    return jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
+
+
 @dataclass(frozen=True)
 class DecoderStack:
     """Static model definition; params live in an explicit pytree. The
@@ -611,6 +624,13 @@ class DecoderStack:
     # without one says no, and its expert layers hand back COUNTERS: one
     # row a layer (`_fold_aux`), summed over the batch axes (`_counters`)
     _router_aux_losses = True
+    # the counters that are not sums over the batch: name -> the collective
+    # that joins the shards' rows (`_counters`). The stack's own are its
+    # stream mixers' (maxima and a mean over the tokens); a family whose
+    # mixer counts (`_mix_counted`) names its own
+    _counter_reduces = {"hc_sinkhorn_err": lax.pmax,
+                        "hc_colsum_err": lax.pmax,
+                        "hc_res_offdiag": lax.pmean}
 
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
@@ -1060,7 +1080,7 @@ class DecoderStack:
                 a = self._attn_project(layer_params, o, tp, dtype, *gate)
             return ffn_half(x, a)
 
-        def ffn_half(x, a):
+        def ffn_half(x, a, counted=None):
             if self.tp_size > 1:
                 # named PAST the row-linear's reduce, so keeping it drops
                 # the recomputed forward's collective with the matmul;
@@ -1080,6 +1100,8 @@ class DecoderStack:
                 ff = m[norm].apply(layer_params[norm], ff)
             if mixer is not None:
                 aux = {**(aux or {}), **mixer.counters(*mixed.values())}
+            if counted:     # what the layer's own mixer counted (`_mix`)
+                aux = {**(aux or {}), **counted}
             return join(x, ff, "hc_ffn"), aux
 
         # Under ring overlap the dense segments run even on pipeline-bubble
@@ -1095,7 +1117,8 @@ class DecoderStack:
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm],
                                         read(x, "hc_attn")))
-            return ffn_half(x, self._mix(layer_params, y, layer_pos, dtype))
+            return ffn_half(x, *self._mix_counted(layer_params, y, layer_pos,
+                                                  dtype))
         if live is None or tp.ring_ov:
             q, k, v, *gate = qkv(x)
             if self.cp_size > 1:
@@ -1159,6 +1182,12 @@ class DecoderStack:
         d), reduced over 'tp', from the normed activation `y`. Which mixer
         a layer runs follows from what its parameters hold."""
         raise NotImplementedError
+
+    def _mix_counted(self, lp: Params, y: jax.Array, layer_pos, dtype):
+        """(`_mix`'s output, what the mixer counted or None). A family whose
+        mixer counts something writes this one instead: the counters join
+        the layer's aux, a row a layer (`_counter_reduces`)."""
+        return self._mix(lp, y, layer_pos, dtype), None
 
     def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
                       dtype, gate: "jax.Array | None" = None) -> jax.Array:
@@ -1400,14 +1429,8 @@ class DecoderStack:
             # a block of dense layers has none; where several blocks count
             # (a row a layer), the rows follow the layers
             auxs = [aux for aux in auxs if aux is not None]
-            if self.stream_mixer and len(auxs) > 1:
-                # every layer counts its mixers, an expert layer its router
-                # too: each counter's rows follow the layers that count it
-                aux = {k: jnp.concatenate([a[k] for a in auxs if k in a])
-                       for k in sorted(set().union(*auxs))}
-            else:
-                aux = (jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
-                       if len(auxs) > 1 else auxs[0] if auxs else None)
+            aux = (_rows_in_order(auxs) if len(auxs) > 1
+                   else auxs[0] if auxs else None)
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
 
     def _scan_periods(self, run, x: jax.Array, params: Params, period):
@@ -1423,7 +1446,7 @@ class DecoderStack:
             for key in keys:
                 z, aux = run(z, layers[key], key)
                 auxs.append(aux)
-            return z, jax.tree.map(lambda *a: jnp.concatenate(a), *auxs)
+            return z, _rows_in_order(auxs)
 
         x, aux = lax.scan(period, x, {key: params[key] for key in keys})
         # (periods, layers a period, ...) -> (layers, ...)
@@ -1866,10 +1889,8 @@ class DecoderStack:
         aux is the router sums of the auxiliary losses, or nothing."""
         if not self.is_moe or self._router_aux_losses:
             return {}
-        if self.stream_mixer:
-            # the mixers' rows are maxima and a mean over the tokens
-            over = {"hc_sinkhorn_err": lax.pmax, "hc_colsum_err": lax.pmax,
-                    "hc_res_offdiag": lax.pmean}
+        over = self._counter_reduces
+        if any(k in over for k in aux):
             return {k: over.get(k, lax.psum)(a, batch_axes)
                     for k, a in aux.items()}
         return jax.tree.map(lambda a: lax.psum(a, batch_axes), aux)

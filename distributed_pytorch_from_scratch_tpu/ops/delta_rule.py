@@ -50,6 +50,25 @@ Everywhere else (off the TPU, other widths) the rule is the XLA text below,
 `_chunk_operands` and a `lax.scan` rematerialised by chunk (its backward
 JAX's transpose of the whole text; its residuals the carried states, one a
 chunk): the tests' oracle for the kernels.
+
+**A decay a CHANNEL** (Kimi Delta Attention, arXiv:2510.26692):
+`channel_delta_rule` is the same rule with `g_t` a vector, `S~ =
+Diag(exp(g_t)) S_{t-1}`, one decay a row of the state. `G` is then (C, d_k) a
+chunk and the ratio `exp(G_i - G_j)` differs by channel, so `A` and the
+scores are no longer `(K K^T) * D`; they are products of `K * exp(G - G_r)`
+with `K * exp(G_r - G)` about reference rows `r`: a chunk is cut in
+sub-blocks of `SUB` rows, a sub-block's rows take its FIRST row as `r`, and
+against them every column up to the sub-block's end. For a column of an
+earlier sub-block both factors are at most 1; for a column of the sub-block
+itself the second is at most `exp(-(SUB - 1) g_min)`. **That relies on a
+bounded gate**: with `g >= -5` (the `kda_lower_bound` the model publishes and
+`parallel/kda.py` holds `g` to) the factor is at most `exp(75)` = 3.7e32,
+inside float32 and bfloat16 alike; a gate without a bound overflows it. The
+entries above the diagonal are such products too and are SELECTED away, never
+multiplied. Everything else is the scalar rule's text: the solve, the walk
+(`_walk_chunks`, the state's rows decayed each by its own `exp(G_C)`), the
+padding, the precisions. There is no kernel for it: XLA text on every
+backend.
 """
 
 from __future__ import annotations
@@ -80,15 +99,19 @@ def delta_rule_recurrent(q: jax.Array, k: jax.Array, v: jax.Array,
                          g: jax.Array, beta: jax.Array
                          ) -> Tuple[jax.Array, jax.Array]:
     """q, k (..., t, d_k), v (..., t, d_v), g, beta (..., t) -> (o (..., t,
-    d_v), the final state (..., d_k, d_v)), float32, one token at a time."""
+    d_v), the final state (..., d_k, d_v)), float32, one token at a time. A
+    `g` of q's shape, (..., t, d_k), is a decay a CHANNEL: row c of the
+    state decays by `exp(g_t[c])`."""
     f32 = lambda z: z.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     lead = q.shape[:-2]
     time_first = lambda z: jnp.moveaxis(z, len(lead), 0)
+    a_channel = g.ndim == q.ndim
 
     def token(S, row):
         q_t, k_t, v_t, g_t, b_t = row
-        S = jnp.exp(g_t)[..., None, None] * S
+        S = (jnp.exp(g_t)[..., None] if a_channel
+             else jnp.exp(g_t)[..., None, None]) * S
         delta = b_t[..., None] * (v_t - jnp.einsum("...kv,...k->...v", S, k_t))
         S = S + k_t[..., :, None] * delta[..., None, :]
         return S, jnp.einsum("...kv,...k->...v", S, q_t)
@@ -289,11 +312,18 @@ def _one_sequence(q, k, v, g, beta, *, chunk: int):
     rematerialised by chunk (the backward is JAX's transpose of this text;
     its residuals are the carried states, one a chunk): q, k (h, t, d_k),
     v (h, t, d_v), g, beta (h, t)."""
-    h, t, dk = q.shape
-    dv = v.shape[-1]
-    dtype = v.dtype
     WU, attn, q_in, k_out, G_end = _chunk_operands(q, k, v, g, beta,
                                                    chunk=chunk)
+    return _walk_chunks(WU, attn, q_in, k_out, G_end, q.shape[1], v.dtype)
+
+
+def _walk_chunks(WU, attn, q_in, k_out, G_end, t: int, dtype):
+    """The walk over one sequence's chunks from `_chunk_operands`' (or
+    `_channel_chunk_operands`') arrays, a `lax.scan` rematerialised by
+    chunk: -> (o (h, t, d_v) in `dtype`, the final state (h, d_k, d_v)).
+    `G_end` (h, n) decays the whole state, (h, n, d_k) each of its rows."""
+    h, dk = WU.shape[0], q_in.shape[-1]
+    dv = WU.shape[-1] - dk
     dot = functools.partial(_dot, dtype)
 
     @jax.checkpoint
@@ -301,8 +331,9 @@ def _one_sequence(q, k, v, g, beta, *, chunk: int):
         W_c, U_c, attn_c, q_c, k_c, end_c = c
         v_new = U_c - dot("hik,hkv->hiv", W_c, S)
         o = dot("hik,hkv->hiv", q_c, S) + dot("hij,hjv->hiv", attn_c, v_new)
-        S = (jnp.exp(end_c)[..., None, None] * S
-             + dot("hik,hiv->hkv", k_c, v_new))
+        decay = (jnp.exp(end_c)[..., None, None] if end_c.ndim == 1
+                 else jnp.exp(end_c)[..., None])
+        S = decay * S + dot("hik,hiv->hkv", k_c, v_new)
         return S, o.astype(dtype)
 
     chunk_first = lambda z: jnp.moveaxis(z, 1, 0)
@@ -317,6 +348,88 @@ def _one_sequence(q, k, v, g, beta, *, chunk: int):
         operand(q_in), operand(k_out), chunk_first(G_end)))
     o = jnp.moveaxis(o, 0, 1).reshape(h, -1, dv)
     return o[:, :t], S
+
+
+# ---- a decay a channel (module docstring) ----
+
+# rows of a sub-block: what shares one reference row. The bound on the gate
+# (`g >= -5`) times `SUB - 1` rows must stay inside float32's exponent
+SUB = 16
+
+
+def channel_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+                       beta: jax.Array, chunk: int = CHUNK, sub: int = SUB
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """The rule with a decay a channel, in chunks (module docstring). q, k
+    (b, h, t, d_k), v (b, h, t, d_v) in the compute dtype; g (b, h, t, d_k)
+    float32, `-5 <= g <= 0` (the bound the sub-blocks rely on); beta (b, h,
+    t) float32. Returns (o (b, h, t, d_v) in v's dtype, the final state (b,
+    h, d_k, d_v) float32). One sequence at a time, as the scalar rule's
+    text; a length that is no multiple of `chunk` is padded the same way."""
+    if chunk % sub:
+        raise ValueError(f"sub-blocks of {sub} rows do not divide a chunk "
+                         f"of {chunk}")
+    one = jax.checkpoint(functools.partial(_one_sequence_channel,
+                                           chunk=chunk, sub=sub))
+    return lax.map(lambda row: one(*row), (q, k, v, g, beta))
+
+
+def _running_decay(g: jax.Array) -> jax.Array:
+    """`G`: the running sum of g inside a chunk (inclusive), g (h, n, C,
+    d_k) float32. Every decay ratio of a chunk is the exponential of a
+    DIFFERENCE of two of its rows, so its rounding is the ratio's relative
+    error: float32 (benchmark/tools/kda_control.py rounds it to bfloat16
+    and the cell's check fails)."""
+    return jnp.cumsum(g, axis=2)
+
+
+def _channel_chunk_operands(q, k, v, g, beta, *, chunk: int, sub: int):
+    """`_chunk_operands` with g (h, t, d_k): the same arrays, `G_end` (h,
+    n, d_k). `A` and `attn` are made a sub-block of rows at a time about
+    the sub-block's first row (module docstring)."""
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta, chunk=chunk)
+    h, n, C, dk = k.shape
+    B = C // sub
+    dot = functools.partial(_dot, v.dtype)
+    f32 = lambda z: z.astype(jnp.float32)
+
+    with jax.named_scope("operands"):
+        G = _running_decay(g)                               # (h, n, C, d_k)
+        blocks = lambda z: z.reshape(h, n, B, sub, dk)
+        G_ref = blocks(G)[:, :, :, :1]                      # (h, n, B, 1, .)
+        # a sub-block's rows about its first row: exp(G_i - G_r) <= 1
+        rows_in = jnp.exp(blocks(G) - G_ref)
+        # every column up to the sub-block's end about that row: at most 1
+        # before the sub-block, at most exp(-(sub - 1) g_min) inside it;
+        # the columns past it are masked (there the exponent has no bound)
+        i = jnp.arange(C)
+        seen = i[None, :] < (jnp.arange(B)[:, None] + 1) * sub  # (B, C)
+        cols_out = jnp.exp(jnp.where(seen[..., None],
+                                     G_ref - G[:, :, None], -jnp.inf))
+        k_cols = f32(k)[:, :, None] * cols_out              # (h, n, B, C, .)
+        k_beta = f32(k) * beta[..., None]
+        pairs = lambda rows: dot(
+            "hnbik,hnbjk->hnbij", blocks(rows) * rows_in,
+            k_cols).reshape(h, n, C, C)
+        A = jnp.where(i[:, None] > i[None, :], pairs(k_beta), 0.0)
+        attn = jnp.where(i[:, None] >= i[None, :], pairs(f32(q)), 0.0)
+        rhs = jnp.concatenate([k_beta * jnp.exp(G),
+                               f32(v) * beta[..., None]], axis=-1)
+        WU = solve_unit_lower(A, rhs)
+        q_in = f32(q) * jnp.exp(G)
+        G_end = G[:, :, -1]                                 # (h, n, d_k)
+        k_out = f32(k) * jnp.exp(G_end[:, :, None] - G)
+    return WU, attn, q_in, k_out, G_end
+
+
+def _one_sequence_channel(q, k, v, g, beta, *, chunk: int, sub: int):
+    """`channel_delta_rule` for one sequence: q, k, g (h, t, d_k), v (h, t,
+    d_v), beta (h, t)."""
+    WU, attn, q_in, k_out, G_end = _channel_chunk_operands(
+        q, k, v, g, beta, chunk=chunk, sub=sub)
+    with jax.named_scope("walk"):
+        return _walk_chunks(WU, attn, q_in, k_out, G_end, q.shape[1],
+                            v.dtype)
 
 
 # ---- the rule as the Pallas kernels (ops/pallas/delta_rule.py) ----

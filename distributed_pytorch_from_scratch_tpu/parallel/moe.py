@@ -594,7 +594,16 @@ class SharedRoutedFFN:
     `activation` is the experts' gate activation, held and shared alike
     (`ACTIVATIONS`: "silu", or "relu" for a ReGLU expert), which reaches
     both movers' paths and `walk_chunks`' hand-written transpose through
-    `held_experts`. And one the CALLER states, a call at a time: `apply`'s
+    `held_experts`; `n_group` > 1 limits the selection to groups
+    (DeepSeek-V3's `noaux_tc` rule, which Ling-3.0 publishes too: the routed
+    experts stand in `n_group` groups of equal size, one a node of the
+    deployment; a group's score is the sum of its two largest biased
+    scores, the `topk_group` best groups are kept and the `top_k` largest
+    biased scores INSIDE them chosen, `select`, under the scope
+    `moe_route/groups`; the layer then counts `groups_hit` (n_group,), the
+    tokens of which a group got at least one choice), and `n_group` 1 is
+    the selection over all of them, the program it has always been. And
+    one the CALLER states, a call at a time: `apply`'s
     optional `router_x` is what the router reads where that is not what
     the experts read (a family whose router reads the layer's input,
     before attention: the routing's index work, `route`, `sort_pairs` and
@@ -683,9 +692,27 @@ class SharedRoutedFFN:
     score: str = "sigmoid"       # or "softmax" (class docstring)
     shared_gate: bool = False
     activation: str = "silu"     # a key of ACTIVATIONS
+    n_group: int = 1             # groups the selection is limited to
+    topk_group: int = 1          # ... of which a token keeps this many
 
     def __post_init__(self):
         held = self.num_held
+        if self.n_group > 1:
+            if self.score != "sigmoid":
+                raise ValueError("a group-limited selection is written for "
+                                 "sigmoid scores")
+            if (self.num_experts % self.n_group
+                    or not 1 <= self.topk_group <= self.n_group):
+                raise ValueError(
+                    f"{self.num_experts} experts in n_group {self.n_group} "
+                    f"groups of which topk_group {self.topk_group}: the "
+                    f"groups must divide the experts and hold the kept")
+            if (self.num_experts // self.n_group < 2 or self.topk_group
+                    * (self.num_experts // self.n_group) < self.top_k):
+                raise ValueError(
+                    f"the {self.topk_group} kept groups of "
+                    f"{self.num_experts // self.n_group} experts must hold "
+                    f"two experts each and top_k {self.top_k} together")
         if self.score not in ("sigmoid", "softmax"):
             raise ValueError(f"score must be 'sigmoid' or 'softmax', got "
                              f"{self.score!r}")
@@ -771,11 +798,34 @@ class SharedRoutedFFN:
             _, chosen = lax.top_k(s, self.top_k)
         else:
             s = jax.nn.sigmoid(logits)
-            _, chosen = lax.top_k(s + lax.stop_gradient(params["bias"]),
-                                  self.top_k)
+            chosen = self.select(s + lax.stop_gradient(params["bias"]))
         w = pick_scores(s, chosen)
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * self.scaling
         return chosen, w
+
+    def select(self, biased: jax.Array) -> jax.Array:
+        """The `top_k` experts a token takes, (S, k) int32, from its biased
+        scores (S, num_experts): the largest of them all, or, with
+        `n_group` > 1, the largest inside the `topk_group` groups whose two
+        largest scores sum highest (class docstring)."""
+        if self.n_group > 1:
+            with jax.named_scope("groups"):
+                S = biased.shape[0]
+                grouped = biased.reshape(S, self.n_group, -1)
+                of_group = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+                _, kept = lax.top_k(of_group, self.topk_group)
+                keep = jnp.any(kept[..., None] == jnp.arange(self.n_group),
+                               axis=1)                      # (S, n_group)
+                biased = jnp.where(keep[..., None], grouped,
+                                   -jnp.inf).reshape(S, -1)
+        return lax.top_k(biased, self.top_k)[1]
+
+    def groups_hit(self, chosen: jax.Array) -> jax.Array:
+        """(n_group,) float32: the tokens of which a group got at least one
+        choice (a token is sent to the nodes of the groups it hits)."""
+        group = chosen // (self.num_experts // self.n_group)
+        hit = jnp.any(group[..., None] == jnp.arange(self.n_group), axis=1)
+        return jnp.sum(hit.astype(jnp.float32), axis=0)
 
     def index(self, chosen: jax.Array, w: jax.Array, inverse: bool):
         """The sorted dispatch's index work over the (S, k) pairs, with no
@@ -863,6 +913,9 @@ class SharedRoutedFFN:
             token = order // k
             counters = {"routed": routed.astype(jnp.float32),
                         "rows_here": rows_here.astype(jnp.float32)}
+            if self.n_group > 1:
+                with jax.named_scope("groups"):
+                    counters["groups_hit"] = self.groups_hit(chosen)
 
         chunks = -(-S * k // M)
         if chunks * M > S * k:        # the last chunk runs past the pairs

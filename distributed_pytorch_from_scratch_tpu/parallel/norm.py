@@ -91,10 +91,13 @@ class ZeroCenteredRMSNorm:
 @dataclass(frozen=True)
 class GatedRMSNorm:
     """`w * x / rms(x) * silu(z)` in float32 over the last axis (one head),
-    `w` from ones: the plain norm, gated by `z` of x's shape."""
+    `w` from ones: the plain norm, gated by `z` of x's shape. `gate` names
+    the gate's activation: "silu" (Gated DeltaNet's) or "sigmoid" (Kimi
+    Delta Attention's)."""
 
     hdim: int
     eps: float = 1e-6
+    gate: str = "silu"
 
     def init(self, key: jax.Array) -> Params:
         del key
@@ -104,5 +107,6 @@ class GatedRMSNorm:
         return {"scale": P(None)}
 
     def apply(self, params: Params, x: jax.Array, z: jax.Array) -> jax.Array:
+        act = {"silu": jax.nn.silu, "sigmoid": jax.nn.sigmoid}[self.gate]
         return (params["scale"] * _rms_normed(x, self.eps)
-                * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
+                * act(z.astype(jnp.float32))).astype(x.dtype)

@@ -173,8 +173,10 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     is the padding they still pay), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
     balance); and, where the step ran the selection bias's rule, the mean
-    size of a bias entry's step (`router_bias_step`); and the mixers'
-    counters of a family with residual streams."""
+    size of a bias entry's step (`router_bias_step`); the mixers'
+    counters of a family with residual streams; a delta layer's decay
+    (`kda_g_min`, `kda_g_spread`) and the groups' load (`groups_hit_max`)
+    of the `kda_mla_moe` family."""
     import numpy as np
 
     lo = cfg.expert_offset
@@ -194,6 +196,19 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
         # worst layer's Sinkhorn error, the layers' mean mixing
         out["hc_sinkhorn_err"] = float(np.max(counters["hc_sinkhorn_err"]))
         out["hc_res_offdiag"] = float(np.mean(counters["hc_res_offdiag"]))
+    if "kda_g_min" in counters:
+        # a family of Kimi Delta Attention layers (parallel/kda.py): the
+        # worst layer's most negative decay (never under the gate's bound)
+        # and the layers' mean spread of the decay over a head's channels
+        out["kda_g_min"] = float(np.min(counters["kda_g_min"]))
+        out["kda_g_spread"] = float(np.mean(counters["kda_g_spread"]))
+    if "groups_hit" in counters:
+        # a group-limited selection (parallel/moe.SharedRoutedFFN.select):
+        # the tokens the busiest group got a choice of, over the groups'
+        # mean, averaged over the expert layers (1.0 is balance)
+        hit = np.asarray(counters["groups_hit"])
+        out["groups_hit_max"] = float(np.mean(
+            hit.max(-1) / np.maximum(hit.mean(-1), 1e-9)))
     return out
 
 

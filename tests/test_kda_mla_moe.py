@@ -1,0 +1,677 @@
+"""The `kda_mla_moe` family (models/kda_mla_moe.py): Kimi Delta Attention
+layers (a delta rule whose decay is a channel's, under a bounded gate), a
+head-gated latent-attention layer closing every group, leading dense layers,
+a sigmoid router whose selection is limited to groups of experts, a shared
+expert, a multi-token-prediction module. CPU, tiny sizes.
+
+* the program against the plain reference (models/vanilla_kda_mla_moe.py,
+  which LOOPS its layers and runs the rule token by token): loss, logits and
+  EVERY gradient leaf, with and without the module, at tp 1 and tp 2, on a
+  job that holds a slice of the experts; in bfloat16 to bfloat16's rounding;
+* the chunked rule with a decay a channel against the token-by-token rule:
+  outputs, final state and all five gradients, at the gate's bound on every
+  channel for whole chunks (what the sub-blocks rely on), near 0, a ragged
+  length; with every channel's decay equal it is the scalar rule;
+* the group-limited selection against a sort-based one, and today's at one
+  group; `LatentAttention` with no q latent and a gate a head;
+* the shares of an expert layer add up to the uncut layer;
+* parameters round-trip through the canonical form and a checkpoint;
+* what the family does not run is refused with a message;
+* the counts at the published widths;
+* **what must not move**: the ninth standing family's lowered digest and
+  remat pick (tests/test_mhc_mla_moe.py holds the other eight's).
+"""
+
+import dataclasses
+import hashlib
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
+    load_checkpoint, save_checkpoint)
+from distributed_pytorch_from_scratch_tpu.config import (
+    KdaMlaMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
+                                                         build_model)
+from distributed_pytorch_from_scratch_tpu.models.kda_mla_moe import (
+    KdaMlaMoETransformer, layer_counts)
+from distributed_pytorch_from_scratch_tpu.models.vanilla_kda_mla_moe import (
+    layers_in_order, vanilla_logits, vanilla_loss)
+from distributed_pytorch_from_scratch_tpu.obs import trace as obs_trace
+from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
+    SUB, channel_delta_rule, delta_rule_recurrent, gated_delta_rule)
+from distributed_pytorch_from_scratch_tpu.ops.rope import rope_angles
+from distributed_pytorch_from_scratch_tpu.parallel.kda import (
+    KimiDeltaAttention)
+from distributed_pytorch_from_scratch_tpu.parallel.mla import LatentAttention
+from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
+from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
+from distributed_pytorch_from_scratch_tpu.training import memory
+from distributed_pytorch_from_scratch_tpu.training.metrics import (
+    model_flops_per_step, moe_counters_summary)
+from distributed_pytorch_from_scratch_tpu.training.optim import (
+    init_adam_state)
+from distributed_pytorch_from_scratch_tpu.training.train_step import (
+    build_train_step)
+
+FAMILY = "kda_mla_moe"
+
+
+def tiny(dtype="float32", **facts):
+    cfg = model_preset("tiny-kda-mla-moe", compute_dtype=dtype)
+    return dataclasses.replace(
+        cfg, kda_mla_moe=dataclasses.replace(cfg.kda_mla_moe, **facts))
+
+
+def batch(cfg, b=2, t=96, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
+    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
+    return ids[:, :-1], ids[:, 1:], pos
+
+
+def on_mesh(cfg, tp, **kw):
+    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+    return mesh, build_model(FAMILY, cfg, tp_size=tp, **kw)
+
+
+# ---- the program against the plain reference ----
+
+@pytest.mark.parametrize("tp,impl,mtp", [
+    (1, "xla", 1), (2, "xla", 1), (1, "xla", 0), (2, "xla", 0),
+    (1, "flash_interpret", 1)])
+def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, mtp):
+    """Segments and a period SCANNED (the program) against six layers
+    LOOPED (the reference), the chunked rule against the token-by-token
+    one, the top_k inside kept groups against a sort, on a job that holds
+    experts 4..11 of 16 (the second and third groups of four). Leaves to
+    5e-5 of their largest entry, as the third family's rule."""
+    cfg = tiny(experts_held=8, expert_offset=4,
+               num_nextn_predict_layers=mtp)
+    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
+    params = model.init(jax.random.key(3))
+    assert len(layers_in_order(params)) == cfg.num_layers == 6
+    ids, tgt, pos = batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        want, want_g = jax.jit(jax.value_and_grad(
+            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
+        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
+    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    flat = jax.tree_util.tree_leaves_with_path(want_g)
+    assert len(flat) == len(jax.tree.leaves(got_g))
+    moved = 0
+    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 5e-5 * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
+        moved += bool(np.any(a != 0))
+    # every leaf but the selection biases has a gradient (A_log, dt_bias,
+    # the convolutions and the head gate among them)
+    biases = sum("bias" in jax.tree_util.keystr(p) for p, _ in flat
+                 if "dt_bias" not in jax.tree_util.keystr(p))
+    assert moved == len(flat) - biases
+    assert ("mtp" in params) == bool(mtp)
+    assert params["kda_layers"]["kda"]["w_f"].shape[:2] == (1, 2)
+    assert params["mla_layers"]["mla"]["w_gate"]["weight"].shape == (1, 1,
+                                                                     64, 4)
+    assert "wq_a" not in params["mla_layers"]["mla"]
+
+
+def test_the_logits_equal_the_reference():
+    cfg = tiny(num_nextn_predict_layers=0)
+    mesh, model = on_mesh(cfg, 2)
+    params = model.init(jax.random.key(5))
+    ids, _, pos = batch(cfg, t=80)
+    with jax.default_matmul_precision("highest"):
+        want = vanilla_logits(cfg, params, ids, pos)
+        got = jax.jit(model.make_forward(mesh))(
+            jax.device_put(params, model.shardings(mesh)), ids, pos)
+    np.testing.assert_allclose(got[..., :cfg.vocab_size], want, atol=3e-5)
+
+
+def test_in_bfloat16_the_loss_is_the_references_to_bfloat16s_rounding():
+    cfg = tiny("bfloat16", experts_held=8, expert_offset=4)
+    mesh, model = on_mesh(cfg, 1)
+    params = model.init(jax.random.key(3))
+    ids, tgt, pos = batch(cfg)
+    want = vanilla_loss(cfg, params, ids, tgt, pos)
+    got = jax.jit(model.make_loss(mesh))(params, ids, tgt, pos)
+    assert abs(float(got) - float(want)) <= 2e-2 * abs(float(want))
+
+
+def test_no_top_k_choice_sits_on_a_tie():
+    """The comparison above is meaningful only if no token's last kept
+    group or last chosen expert is within rounding of the next."""
+    cfg = tiny()
+    moe = build_model(FAMILY, cfg)._mods["moe"]
+    p = moe.init(jax.random.key(1))
+    x = jax.random.normal(jax.random.key(2), (192, cfg.attn_dim))
+    s = np.asarray(jax.nn.sigmoid(x @ p["router"]))
+    groups = np.sort(s.reshape(192, 4, 4), -1)[..., -2:].sum(-1)
+    ranked = np.sort(groups, -1)
+    assert np.min(ranked[:, -2] - ranked[:, -3]) > 1e-5
+
+
+# ---- the chunked rule with a decay a channel ----
+
+def rule_inputs(seed, t, g="drawn", b=2, h=2, dk=16, dv=8):
+    ks = jax.random.split(jax.random.key(seed), 5)
+    unit = lambda z: z / jnp.linalg.norm(z, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (b, h, t, dk))) / 4.0
+    k = unit(jax.random.normal(ks[1], (b, h, t, dk)))
+    v = jax.random.normal(ks[2], (b, h, t, dv))
+    beta = jax.nn.sigmoid(2.0 * jax.random.normal(ks[3], (b, h, t)))
+    if g == "drawn":       # anywhere between the bound and 0
+        gate = -5.0 * jax.nn.sigmoid(3.0 * jax.random.normal(
+            ks[4], (b, h, t, dk)))
+    elif g == "bound":     # the bound on EVERY channel for whole chunks
+        gate = jnp.full((b, h, t, dk), -5.0).at[:, :, 64:70].set(-0.01)
+    else:                  # near 0: the state forgets nothing
+        gate = -1e-3 * jax.random.uniform(ks[4], (b, h, t, dk))
+    return q, k, v, gate, beta
+
+
+@pytest.mark.parametrize("g", ["drawn", "bound", "near_zero"])
+@pytest.mark.parametrize("t,chunk", [(192, 64), (150, 64), (96, 32)])
+def test_the_chunked_channel_rule_equals_the_token_by_token_rule(g, t,
+                                                                 chunk):
+    """Outputs, the final state and all five gradients. At the bound a
+    sub-block's factor about its first row reaches exp(75): the products
+    stay finite and the entries above the diagonal are selected away."""
+    args = rule_inputs(7, t, g)
+    loss = lambda rule: lambda *a: jnp.sum(jnp.sin(rule(*a)[0]))
+    with jax.default_matmul_precision("highest"):
+        o, S = channel_delta_rule(*args, chunk=chunk)
+        o_ref, S_ref = delta_rule_recurrent(*args)
+        grads = jax.grad(loss(lambda *a: channel_delta_rule(
+            *a, chunk=chunk)), argnums=range(5))(*args)
+        grads_ref = jax.grad(loss(delta_rule_recurrent),
+                             argnums=range(5))(*args)
+    scale = float(jnp.max(jnp.abs(o_ref)))
+    assert float(jnp.max(jnp.abs(o - o_ref))) <= 2e-6 * max(scale, 1.0)
+    assert float(jnp.max(jnp.abs(S - S_ref))) <= 1e-5 * max(
+        float(jnp.max(jnp.abs(S_ref))), 1.0)
+    for a, b in zip(grads, grads_ref):
+        assert bool(jnp.all(jnp.isfinite(a)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * max(
+            float(jnp.max(jnp.abs(b))), 1e-6)
+
+
+def test_with_every_channels_decay_equal_it_is_the_scalar_rule():
+    q, k, v, g, beta = rule_inputs(11, 160)
+    one = g[..., 0]
+    with jax.default_matmul_precision("highest"):
+        o, S = channel_delta_rule(q, k, v, jnp.broadcast_to(
+            one[..., None], g.shape), beta)
+        o_s, S_s = gated_delta_rule(q, k, v, one, beta)
+    np.testing.assert_allclose(o, o_s, atol=2e-6)
+    np.testing.assert_allclose(S, S_s, atol=2e-6)
+
+
+def test_the_sub_blocks_rely_on_the_bound_and_the_mixer_says_so():
+    with pytest.raises(ValueError, match="sub-blocks rely on it"):
+        KimiDeltaAttention(64, 4, 16, 16, lower_bound=-6.0)
+    with pytest.raises(ValueError, match="sub-blocks rely on it"):
+        KimiDeltaAttention(64, 4, 16, 16, lower_bound=0.5)
+    assert (SUB - 1) * 5.0 < 87.0
+    with pytest.raises(ValueError, match="do not divide a chunk"):
+        channel_delta_rule(*rule_inputs(0, 64), chunk=40)
+
+
+def test_the_mixers_gate_stays_over_its_bound_and_differs_by_channel():
+    kda = KimiDeltaAttention(64, 4, 16, 16)
+    p = kda.init(jax.random.key(0))
+    # a gate driven hard: the decay's projection a hundred times its size
+    p = {**p, "w_f": 100.0 * p["w_f"]}
+    x = jax.random.normal(jax.random.key(1), (2, 70, 64))
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    fn = jax.shard_map(lambda p, x: kda.apply(p, x), mesh=mesh,
+                       in_specs=(kda.specs(), P()), out_specs=(P(), P()))
+    y, c = jax.jit(fn)(p, x)
+    assert bool(jnp.all(jnp.isfinite(y)))
+    assert -5.0 <= float(c["kda_g_min"]) < -4.9
+    assert float(c["kda_g_spread"]) > 0.5
+
+
+# ---- the selection, and latent attention's two facts ----
+
+def test_the_group_limited_selection_equals_a_sort_and_one_group_is_todays():
+    d, E = 32, 32
+    grouped = SharedRoutedFFN(d, 16, E, top_k=4, n_group=4, topk_group=2)
+    p = grouped.init(jax.random.key(1))
+    p["bias"] = 0.2 * jax.random.normal(jax.random.key(5), (E,))
+    x = jax.random.normal(jax.random.key(2), (256, d))
+    chosen, w = grouped.route(p, x)
+    s = np.asarray(jax.nn.sigmoid(x @ p["router"]))
+    biased = s + np.asarray(p["bias"])
+    of_group = np.sort(biased.reshape(256, 4, 8), -1)[..., -2:].sum(-1)
+    kept = np.argsort(-of_group, -1)[:, :2]
+    for row in range(256):
+        allowed = [e for e in range(E) if e // 8 in kept[row]]
+        want = sorted(allowed, key=lambda e: -biased[row, e])[:4]
+        assert sorted(np.asarray(chosen[row])) == sorted(want)
+        picked = s[row, np.asarray(chosen[row])]
+        np.testing.assert_allclose(w[row], picked / picked.sum(), rtol=1e-5)
+    # a token's choices hit at most topk_group groups
+    assert int(jnp.max(jnp.sum(jnp.any(
+        (chosen // 8)[..., None] == jnp.arange(4), axis=1), -1))) <= 2
+    hit = grouped.groups_hit(chosen)
+    assert hit.shape == (4,) and 256 <= float(hit.sum()) <= 512
+    # one group: the largest of them all, the selection as it always was
+    plain = dataclasses.replace(grouped, n_group=1, topk_group=1)
+    chosen_1, _ = plain.route(p, x)
+    _, want_1 = jax.lax.top_k(jnp.asarray(biased), 4)
+    np.testing.assert_array_equal(chosen_1, want_1)
+    assert float(jnp.mean((jnp.sort(chosen_1) != jnp.sort(chosen))
+                          .astype(jnp.float32))) > 0.05
+
+
+@pytest.mark.parametrize("kw,message", [
+    (dict(n_group=3, topk_group=1), "groups must divide"),
+    (dict(n_group=4, topk_group=5), "groups must divide"),
+    (dict(n_group=32, topk_group=8), "two experts each"),
+    (dict(n_group=4, topk_group=1, top_k=12), "two experts each"),
+    (dict(n_group=4, topk_group=2, score="softmax"), "sigmoid scores"),
+])
+def test_a_selection_its_groups_cannot_hold_is_refused(kw, message):
+    with pytest.raises(ValueError, match=message):
+        SharedRoutedFFN(32, 16, 32, **{"top_k": 4, **kw})
+
+
+def test_latent_attention_with_no_q_latent_and_a_gate_a_head():
+    attn = LatentAttention(64, 4, None, 16, 16, 8, 16, head_gate=True)
+    assert set(attn.modules()) == {"wq", "w_gate", "wkv_a", "kv_norm",
+                                   "wkv_b", "wo"}
+    assert attn.num_params() == (64 * 4 * 24 + 64 * 4 + 64 * 24 + 16
+                                 + 16 * 4 * 32 + 64 * 64)
+    # (with a latent and no gate: the third family's modules)
+    assert set(LatentAttention(64, 4, 32, 16, 16, 8, 16).modules()) == {
+        "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo"}
+    p = attn.init(jax.random.key(0))
+    y = jax.random.normal(jax.random.key(1), (2, 48, 64))
+    pos = jnp.tile(jnp.arange(48), (2, 1))
+    cos, sin = rope_angles(pos, 8, 10000.0)
+    mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices()[:2])
+    fn = jax.shard_map(
+        lambda p, y, cos, sin: attn.apply(p, y, cos, sin, jnp.float32,
+                                          attn_impl="xla"),
+        mesh=mesh, in_specs=(attn.specs(), P(), P(), P()), out_specs=P())
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(fn)(p, y, cos, sin)
+        # plainly
+        heads = lambda z, w: z.reshape(2, 48, 4, w).transpose(0, 2, 1, 3)
+        rot = lambda z: jnp.stack(
+            [z[..., 0::2] * cos[:, None] - z[..., 1::2] * sin[:, None],
+             z[..., 1::2] * cos[:, None] + z[..., 0::2] * sin[:, None]],
+            -1).reshape(z.shape)
+        q = heads(y @ p["wq"]["weight"], 24)
+        ckv = y @ p["wkv_a"]["weight"]
+        c = ckv[..., :16]
+        c = p["kv_norm"]["scale"] * c / jnp.sqrt(
+            jnp.mean(c * c, -1, keepdims=True) + 1e-6)
+        kv = heads(c @ p["wkv_b"]["weight"], 32)
+        q = jnp.concatenate([q[..., :16], rot(q[..., 16:])], -1)
+        k = jnp.concatenate([kv[..., :16], jnp.broadcast_to(
+            rot(ckv[..., 16:][:, None]), (2, 4, 48, 8))], -1)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(24.0)
+        scores = jnp.where(jnp.tril(jnp.ones((48, 48), bool)), scores,
+                           -jnp.inf)
+        o = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1),
+                       kv[..., 16:])
+        gate = jax.nn.sigmoid(y @ p["w_gate"]["weight"])
+        o = o * gate.transpose(0, 2, 1)[..., None]
+        want = o.transpose(0, 2, 1, 3).reshape(2, 48, 64) @ p["wo"]["weight"]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+# ---- the shares add up ----
+
+def apply_moe(moe, params, x):
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
+                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
+    return jax.jit(fn)(params, x)
+
+
+def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """Four jobs hold four experts each of one layer's 16 in 2 groups (a
+    group on two shares). Their routed parts, plus the shared expert once,
+    are the layer a job holding all 16 computes: the router, its groups and
+    the weights' normalisation see all the experts on every share."""
+    d, f, E = 32, 16, 16
+    whole = SharedRoutedFFN(d, f, E, top_k=3, scaling=2.5, n_group=2,
+                            topk_group=1)
+    p = whole.init(jax.random.key(1))
+    x = jax.random.normal(jax.random.key(2), (2, 64, d))
+    shared = lambda p, x: ((jax.nn.silu(x @ p["shared"]["gate"])
+                            * (x @ p["shared"]["up"]))
+                           @ p["shared"]["down"])
+    with jax.default_matmul_precision("highest"):
+        want, counters = apply_moe(whole, p, x)
+        shared_only = shared(p, x)
+        total, rows = shared_only, 0.0
+        for lo in range(0, E, 4):
+            share = dataclasses.replace(whole, held=4, offset=lo)
+            ps = {**p, **{n: p[n][lo:lo + 4] for n in ("gate", "up", "down")}}
+            y, c = apply_moe(share, ps, x)
+            total = total + (y - shared_only)
+            rows += float(c["rows_here"])
+            np.testing.assert_array_equal(c["routed"], counters["routed"])
+            np.testing.assert_array_equal(c["groups_hit"],
+                                          counters["groups_hit"])
+    np.testing.assert_allclose(total, want, atol=2e-5)
+    assert rows == float(counters["rows_here"]) == 2 * 64 * 3
+    # one group a token: every token hits exactly one
+    assert float(counters["groups_hit"].sum()) == 2 * 64
+
+
+def test_the_shares_of_the_models_expert_layer_equal_the_uncut_references():
+    """The same through the model: a one-group model cut in four shares of
+    four experts; the losses' routed parts differ, so compare the LAYER: the
+    mixer, the router and the shared expert counted once, the four shares'
+    expert parts summed, against the uncut reference's layer."""
+    from distributed_pytorch_from_scratch_tpu.models import (
+        vanilla_kda_mla_moe as ref)
+    cfg = tiny(num_nextn_predict_layers=0)
+    km = cfg.kda_mla_moe
+    model = build_model(FAMILY, cfg)
+    params = model.init(jax.random.key(2))
+    lp = jax.tree.map(lambda a: a[0], params["lead_kda_layers"])
+    y = jax.random.normal(jax.random.key(4), (2, 64, cfg.attn_dim))
+    with jax.default_matmul_precision("highest"):
+        want = ref._expert_ffn(lp["moe"], y, km, cfg.moe_top_k)
+        sh = lp["moe"]["shared"]
+        shared_only = ref._swiglu(y.reshape(-1, 64), sh["gate"], sh["up"],
+                                  sh["down"]).reshape(y.shape)
+        total = shared_only
+        for lo in range(0, 16, 4):
+            moe = dataclasses.replace(model._mods["moe"], held=4, offset=lo)
+            ps = {**lp["moe"], **{n: lp["moe"][n][lo:lo + 4]
+                                  for n in ("gate", "up", "down")}}
+            total = total + (apply_moe(moe, ps, y)[0] - shared_only)
+    np.testing.assert_allclose(total, want, atol=3e-5)
+
+
+# ---- the parameters' other forms ----
+
+def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
+        tmp_path):
+    cfg = tiny()
+    mesh, model = on_mesh(cfg, 2)
+    params = model.init(jax.random.key(1))
+    n = layer_counts(cfg)
+    assert n == {"dense_layers": 1, "lead_kda_layers": 1,
+                 "lead_mla_layers": 1, "kda_layers": 2, "mla_layers": 1,
+                 "mtp_layers": 1}
+    assert params["kda_layers"]["kda"]["dt_bias"].shape == (1, 2, 4, 16)
+    assert params["dense_layers"]["gate_proj"]["weight"].shape == (1, 64,
+                                                                   128)
+    assert "moe" not in params["dense_layers"]
+    # two layers' decays start apart
+    a = params["kda_layers"]["kda"]["A_log"]
+    assert float(jnp.abs(a[0, 0] - a[0, 1]).max()) > 1e-3
+    canonical = model.to_canonical(params)
+    back = model.from_canonical(canonical)
+    jax.tree.map(np.testing.assert_array_equal, back, params)
+    save_checkpoint(str(tmp_path), 3, 1.0, canonical,
+                    model.canonical_specs(), 1)
+    fresh = model.init(jax.random.key(9))
+    restored, _, at = load_checkpoint(str(tmp_path), 3, fresh,
+                                      model.canonical_specs())
+    assert at == 3
+    jax.tree.map(np.testing.assert_array_equal, restored, params)
+
+
+# ---- the step, its counters, the entry point ----
+
+def test_the_train_step_returns_the_decays_and_the_groups_rows_and_the_loss_falls():
+    cfg = tiny()
+    mesh, model = on_mesh(cfg, 2)
+    params = jax.device_put(model.init(jax.random.key(0)),
+                            model.shardings(mesh))
+    opt = init_adam_state(params)
+    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
+    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
+                            with_counters=True)
+    ids, tgt, pos = batch(cfg, t=64)
+    losses = []
+    for _ in range(6):
+        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
+        losses.append(float(loss))
+    assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
+    # a row a delta layer (4 of the 6), a row an expert layer (5 and the
+    # module's), in the order the layers run
+    assert c["kda_g_min"].shape == c["kda_g_spread"].shape == (4,)
+    assert c["routed"].shape == (6, 16) and c["groups_hit"].shape == (6, 4)
+    assert float(jnp.min(c["kda_g_min"])) >= -5.0
+    assert float(jnp.min(c["kda_g_spread"])) > 0.0
+    np.testing.assert_array_equal(c["routed"].sum(-1), [2 * 64 * 2] * 6)
+    assert float(c["groups_hit"].sum(-1).max()) <= 2 * 64 * 2
+    summary = moe_counters_summary(jax.device_get(c), cfg, 2 * 64)
+    assert summary["rows_here_per_token"] == 2.0    # all experts held
+    assert -5.0 <= summary["kda_g_min"] < 0.0 < summary["kda_g_spread"]
+    assert summary["groups_hit_max"] >= 1.0
+
+
+def test_train_cli_runs_the_family(tmp_path, capsys):
+    from chip_smoke import write_tokens
+    from distributed_pytorch_from_scratch_tpu import train as train_mod
+    tokens = tmp_path / "tokens.json"
+    write_tokens(str(tokens), 503, 16, 65)
+    train_mod.main([
+        "--family", FAMILY, "--model", "tiny-kda-mla-moe", "--tp_size", "2",
+        "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
+        "--batch_size", "4", "--maxlen", "64", "--max_steps", "4",
+        "--log_interval", "2", "--save_interval", "100",
+        "--warmup_steps", "2"])
+    out = capsys.readouterr().out
+    assert f"model[{FAMILY}]" in out and "kda_g_min" in out
+    events = [json.loads(line) for line in
+              open(tmp_path / "ckpt" / "logs" / "metrics.jsonl")]
+    assert any(e.get("tag") == "moe_counters" for e in events)
+    with pytest.raises(SystemExit, match="reads the config field"):
+        train_mod.main(["--family", "gdn_moe", "--model", "tiny-kda-mla-moe",
+                        "--data_path", str(tokens),
+                        "--save_dir", str(tmp_path / "x")])
+
+
+# ---- what is refused ----
+
+@pytest.mark.parametrize("kw,message", [
+    (dict(pp_size=2), "pp_size > 1"),
+    (dict(cp_size=2), "cp_size > 1"),
+    (dict(ep_size=2), "ep_size > 1"),
+    (dict(sequence_parallel=True), "sequence_parallel=True"),
+    (dict(tp_size=2, tp_overlap="ring"), "does not compose with MoE"),
+    (dict(attn_t_real=32), "attn_t_real"),
+    (dict(zero3_axis="dp"), "ZeRO stage 3"),
+    (dict(tp_size=8), "not divisible by tp"),
+])
+def test_the_model_refuses_what_it_does_not_run(kw, message):
+    with pytest.raises(ValueError, match=message):
+        build_model(FAMILY, tiny(), **kw)
+
+
+def test_decoding_and_the_hand_reduced_gradients_are_refused():
+    from distributed_pytorch_from_scratch_tpu.models.decode import (
+        require_decodable)
+    mesh, model = on_mesh(tiny(), 1)
+    assert not model.decodable and not model.hand_reduced_grads
+    with pytest.raises(ValueError, match="cannot be decoded or served"):
+        require_decodable(model)
+    for kw, what in ((dict(zero=2), "ZeRO stage 2"),
+                     (dict(zero=3), "ZeRO stage 3"),
+                     (dict(dp_reduce_bucket_mb=25.0), "bucketed")):
+        with pytest.raises(ValueError, match=what):
+            build_train_step(model, mesh, OptimizerConfig(), **kw)
+
+
+@pytest.mark.parametrize("cfg,message", [
+    (ModelConfig(num_experts=8), "needs cfg.kda_mla_moe"),
+    (dataclasses.replace(model_preset("tiny-kda-mla-moe"), num_layers=5),
+     "whole groups"),
+    (tiny(first_k_dense_replace=3), "latent layer its experts"),
+    (tiny(num_nextn_predict_layers=2), "depth 0 or 1"),
+    (tiny(kda_lower_bound=-8.0), "sub-blocks rely on it"),
+])
+def test_a_family_needs_its_own_facts_and_whole_groups(cfg, message):
+    with pytest.raises(ValueError, match=message):
+        build_model(FAMILY, cfg)
+
+
+def test_with_no_dense_layer_every_group_is_a_period():
+    cfg = tiny(first_k_dense_replace=0, num_nextn_predict_layers=0)
+    model = build_model(FAMILY, cfg)
+    assert model._pattern == ((("kda_layers", 2), ("mla_layers", 1)),)
+    assert build_model(FAMILY, tiny())._pattern == (
+        "dense_layers", "lead_kda_layers", "lead_mla_layers",
+        (("kda_layers", 2), ("mla_layers", 1)))
+    made = jax.eval_shape(model.init, jax.random.key(0))
+    assert made["kda_layers"]["kda"]["w_q"].shape[:2] == (2, 2)
+    assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
+
+
+# ---- the counts at the published widths ----
+
+def published(held=8, vocab=19648, layers=6, dense=1, mtp=0):
+    return ModelConfig(
+        attn_dim=2560, ffn_dim=6144, num_heads=32, num_layers=layers,
+        vocab_size=vocab, maxlen=4096, rope_theta=6e6, num_experts=512,
+        moe_top_k=8, kda_mla_moe=KdaMlaMoEConfig(
+            head_dim=128, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, moe_intermediate_size=768,
+            first_k_dense_replace=dense, experts_held=held,
+            num_nextn_predict_layers=mtp))
+
+
+def test_parameter_counts_at_the_published_widths():
+    """One chip's share (8 of 512 experts, an eighth of the vocabulary, the
+    first group with its dense layers counted once: 1 dense delta layer, 4
+    delta expert layers, 1 latent expert layer), as `init` makes them."""
+    cfg = published()
+    model = build_model(FAMILY, cfg)
+    kda, mla = model._mods["kda"], model._mods["mla"]
+    # q, k, v, the decay's and the output gate 5 x 2560 x 4096, W_o, beta,
+    # three convolutions of 4 taps, A_log, dt_bias, the norm
+    assert kda.num_params() == (6 * 2560 * 4096 + 2560 * 32
+                                + 3 * 4096 * 4 + 32 + 4096 + 128)
+    assert kda.num_params() == 63_049_888
+    assert mla.num_params() == (2560 * 32 * 192 + 2560 * 32 + 2560 * 576
+                                + 512 + 512 * 8192 + 4096 * 2560)
+    assert mla.num_params() == 31_965_696
+    parts = KdaMlaMoETransformer.param_counts(cfg)
+    ffn = 2560 * 512 + 512 + 9 * 3 * 2560 * 768
+    assert parts["dense_layers"] == 63_049_888 + 5120 + 3 * 2560 * 6144
+    assert parts["kda_expert_layers"] == 4 * (63_049_888 + 5120 + ffn)
+    assert parts["mla_expert_layers"] == 31_965_696 + 5120 + ffn
+    assert parts["embedding_and_head"] == 2 * 19648 * 2560
+    assert cfg.num_params() == 767_009_056
+    made = jax.eval_shape(model.init, jax.random.key(0))
+    assert sum(x.size for x in jax.tree.leaves(made)) == cfg.num_params()
+    # x 16 bytes (weights, gradients, two Adam moments): 12.27 GB
+    assert 11.8e9 < cfg.num_params() * 16 < 12.8e9
+    # the module is one more latent expert layer and the 5120 -> 2560
+    # projection: 1.59 GB more
+    with_module = published(mtp=1).num_params() - cfg.num_params()
+    assert with_module == 31_965_696 + 5120 + ffn + 2 * 2560 * 2560 + 3 * 2560
+    # forward FLOPs a token by the program's convention (the scores' full
+    # square): 6 N_active + the latent layer's scores + five layers' rules
+    flops = model_flops_per_step(cfg, 1, 4096, cfg.num_params())
+    assert 1.0e9 < flops / 4096 / 3 < 1.25e9
+
+
+def test_remat_auto_sizes_the_benchmarks_cell(capsys):
+    """`remat="auto"` at the cell's shapes on a v5e's 15.75 GiB walks the
+    ladder with the delta layer's own extra (`layer_extra_elems_per_token`:
+    a float32 decay 4096 wide a token among it) and fits a rung."""
+    cfg = dataclasses.replace(published(), compute_dtype="bfloat16")
+    model = build_model(FAMILY, cfg, remat_budget_gib=15.748)
+    assert model.layer_extra_elems_per_token > 20 * 4096
+    layer_params = cfg.num_params() - 2 * 19648 * 2560 - 2560
+    memory.select_remat_traced.cache_clear()
+    rung = memory.select_remat_traced(model, cfg.num_params(), layer_params,
+                                      1, 4096)
+    said = capsys.readouterr().err
+    assert rung in ("true", "attn_proj", "ffn", "flash", "dots"), said
+    estimate = float(said.split(f"{rung}=")[1].split("GiB")[0])
+    assert 11.4 < estimate < 14.7, said
+
+
+# ---- what must not move ----
+
+# the ninth standing family's train step at its tiny preset, as
+# tests/test_mhc_mla_moe.py holds the other eight's (its `STANDING`): the
+# StableHLO's digest at the commit before `parallel/mla.py`,
+# `parallel/moe.py` and `ops/delta_rule.py` were edited for this family (PR
+# 58's tree), and what `select_remat_traced` picked there.
+NINTH = ("mhc_mla_moe", "tiny-mhc-mla-moe", "c96270e5156e5692", "true",
+         0.014785466343164444)
+
+
+def lowered_text(family, cfg):
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    model = build_model(family, cfg)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    opt = jax.eval_shape(init_adam_state, params)
+    ids = jax.ShapeDtypeStruct((4, 256), np.int32)
+    step = build_train_step(model, mesh, OptimizerConfig(),
+                            with_grad_norm=True, with_counters=True)
+    text = step.lower(params, opt, ids, ids, ids).as_text()
+    return re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
+
+
+def test_the_ninth_standing_family_lowers_to_the_text_it_lowered_to():
+    family, preset, digest, _, _ = NINTH
+    text = lowered_text(family, model_preset(preset))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    assert "kda" not in text and "groups_hit" not in text
+
+
+class _Seen:
+    def instant(self, name, **fields):
+        self.fields = fields
+
+
+def test_the_ninth_standing_family_is_picked_the_rung_it_was(monkeypatch):
+    family, preset, _, rung, estimate = NINTH
+    model = build_model(family, model_preset(preset,
+                                             compute_dtype="bfloat16"),
+                        remat_budget_gib=0.02)
+    shapes = jax.eval_shape(model.init, jax.random.key(0))
+    count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))
+    seen = _Seen()
+    monkeypatch.setattr(obs_trace, "_current", seen)
+    memory.select_remat_traced.cache_clear()
+    got = memory.select_remat_traced(
+        model, count(shapes),
+        sum(count(shapes[k]) for k in model._layer_keys), 4, 256)
+    assert (got, seen.fields["estimate_gib"]) == (rung, estimate)
+
+
+def test_the_new_familys_step_names_its_scopes():
+    """The named scopes a device trace splits the step by are the name
+    stacks of the lowered text's debug info."""
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    model = build_model(FAMILY, tiny())
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    opt = jax.eval_shape(init_adam_state, params)
+    ids = jax.ShapeDtypeStruct((2, 128), np.int32)
+    step = build_train_step(model, mesh, OptimizerConfig(),
+                            with_counters=True)
+    text = step.lower(params, opt, ids, ids, ids).as_text(debug_info=True)
+    # (a name stack is cut where a function is called: the rule's inner
+    # scopes stand under its `checkpoint`, the call under `kda_rule`; a
+    # device trace's op_name has them joined)
+    for scope in ("kda/", "kda/gate", "kda_rule/", "checkpoint/operands/",
+                  "checkpoint/walk/", "mla/", "mla/gate", "moe_route/",
+                  "groups/", "moe_experts", "moe_shared", "dense_ffn",
+                  "mtp/"):
+        assert scope in text, scope
+    assert FAMILY in FAMILIES and len(FAMILIES) == 10

@@ -15,6 +15,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          EarlyMoEConfig,
                                                          GdnMoEConfig,
                                                          HyperConnectionConfig,
+                                                         KdaMlaMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
                                                          SwaMoEConfig,
@@ -68,9 +69,21 @@ EARLY = dict(sliding_window_layout=(0, 1, 1, 1), rope_layout=(0, 1, 1, 1),
              head_dim=16, moe_ffn_hidden_size=16, sliding_window_size=8)
 
 
+# the kda_mla_moe family: one group of (delta, delta, latent), the first
+# layer dense; heads of 16; 8 experts in 2 groups of which a token keeps 1
+KDA = dict(head_dim=16, kv_lora_rank=16, qk_nope_head_dim=16,
+           qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=16,
+           layer_group_size=3, first_k_dense_replace=1, n_group=2,
+           topk_group=1)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "kda_mla_moe":
+        return ModelConfig(num_experts=8, **{**TINY, "num_layers": 3},
+                           kda_mla_moe=KdaMlaMoEConfig(experts_held=held,
+                                                       **KDA))
     if extra == "early_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            early_moe=EarlyMoEConfig(experts_held=held,
@@ -101,7 +114,8 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
                 "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
                 "early_moe": "tiny-early-moe",
-                "mhc_mla_moe": "tiny-mhc-mla-moe"}
+                "mhc_mla_moe": "tiny-mhc-mla-moe",
+                "kda_mla_moe": "tiny-kda-mla-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
