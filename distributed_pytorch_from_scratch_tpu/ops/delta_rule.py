@@ -67,8 +67,23 @@ inside float32 and bfloat16 alike; a gate without a bound overflows it. The
 entries above the diagonal are such products too and are SELECTED away, never
 multiplied. Everything else is the scalar rule's text: the solve, the walk
 (`_walk_chunks`, the state's rows decayed each by its own `exp(G_C)`), the
-padding, the precisions. There is no kernel for it: XLA text on every
-backend.
+padding, the precisions.
+
+Where that one is made (PR 60). On a TPU at widths that are multiples of
+128, chunks of 64 and sub-blocks of 16 the rule is three Pallas kernels
+(ops/pallas/kda_rule.py, a call for the whole batch's heads): XLA makes `G`
+(a cumsum) and hands it with `beta` as one float32 tile a chunk;
+`kda_rule_pairs` makes `A` from a sub-block's two factors in VMEM; XLA
+inverts it (`_unit_lower_inverse`, as the scalar rule's); `kda_rule_fwd`
+makes the factors again, `attn`, `[W | U] = T rhs`, `q_in`, `k_out` and
+walks the chunks with the state resident; `kda_rule_bwd` makes a chunk's
+operands once more, walks back from the states the forward wrote out and
+transposes the operands by hand (the decay's cotangent elementwise, a
+channel's). No array the size of the factors, `(h, n, B, C, d_k)`, ever
+exists in HBM on that path; between forward and backward a head keeps its
+inputs, `T` and one state a block of chunks. Everywhere else it is the XLA
+text, one sequence at a time: the kernels' oracle. `channel_delta_rule`
+decides from what the call sees and says which on the program's tracer.
 """
 
 from __future__ import annotations
@@ -80,7 +95,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..obs.trace import current_tracer
 from .collectives import copy_to
+from .pallas import kda_rule
 from .pallas.delta_rule import (ROWS, holds as kernels_hold, rule_backward,
                                 rule_forward, sequences_a_call)
 
@@ -358,20 +375,55 @@ SUB = 16
 
 
 def channel_delta_rule(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-                       beta: jax.Array, chunk: int = CHUNK, sub: int = SUB
+                       beta: jax.Array, chunk: int = CHUNK, sub: int = SUB,
+                       interpret: bool = False
                        ) -> Tuple[jax.Array, jax.Array]:
     """The rule with a decay a channel, in chunks (module docstring). q, k
     (b, h, t, d_k), v (b, h, t, d_v) in the compute dtype; g (b, h, t, d_k)
     float32, `-5 <= g <= 0` (the bound the sub-blocks rely on); beta (b, h,
     t) float32. Returns (o (b, h, t, d_v) in v's dtype, the final state (b,
-    h, d_k, d_v) float32). One sequence at a time, as the scalar rule's
-    text; a length that is no multiple of `chunk` is padded the same way."""
+    h, d_k, d_v) float32). A length that is no multiple of `chunk` is
+    padded as the scalar rule's.
+
+    The rule is the Pallas kernels' on a TPU at a shape they hold
+    (`ops/pallas/kda_rule.holds`: widths that are multiples of 128, chunks
+    of 64 in sub-blocks of 16) and the XLA text, one sequence at a time,
+    everywhere else: decided here from what the call sees, and said on the
+    program's tracer (the instant `kda_rule`, once a trace). `interpret=
+    True` asks for the kernels under the Pallas interpreter (the tests do,
+    off the TPU). To the kernels heads are all the same: one call takes the
+    whole batch's."""
     if chunk % sub:
         raise ValueError(f"sub-blocks of {sub} rows do not divide a chunk "
                          f"of {chunk}")
-    one = jax.checkpoint(functools.partial(_one_sequence_channel,
-                                           chunk=chunk, sub=sub))
-    return lax.map(lambda row: one(*row), (q, k, v, g, beta))
+    b, h, t, dk = q.shape
+    dv = v.shape[-1]
+    held = kda_rule.holds(dk, dv, chunk, sub)
+    if interpret and not held:
+        raise ValueError(
+            f"the channel rule's kernels do not hold d_k {dk}, d_v {dv}, "
+            f"chunk {chunk}, sub {sub}: widths must be multiples of 128, a "
+            f"chunk 64 rows in sub-blocks of 16")
+    kernels = interpret or (held and jax.default_backend() == "tpu")
+    tracer = current_tracer()
+    if tracer is not None:
+        tracer.instant(
+            "kda_rule", path="kernel" if kernels else "xla", heads=b * h,
+            tokens=t, d_k=dk, d_v=dv, chunk=chunk, sub=sub,
+            dtype=str(q.dtype), block=kda_rule.blocks(
+                b * h, -(-t // chunk)) if kernels else None)
+    if not kernels:
+        one = jax.checkpoint(functools.partial(_one_sequence_channel,
+                                               chunk=chunk, sub=sub))
+        return lax.map(lambda row: one(*row), (q, k, v, g, beta))
+    heads = lambda z: z.reshape(b * h, *z.shape[2:])
+    # the caller's fusions end here and begin again after, as the scalar
+    # rule's
+    q, k, v, g, beta = lax.optimization_barrier((q, k, v, g, beta))
+    o, S = _channel_kernels(chunk, sub, interpret, b, *map(
+        heads, (q, k, v, g, beta)))
+    return lax.optimization_barrier((o.reshape(b, h, t, dv),
+                                     S.reshape(b, h, dk, dv)))
 
 
 def _running_decay(g: jax.Array) -> jax.Array:
@@ -498,3 +550,75 @@ def _kernels_bwd(chunk, interpret, sequences, saved, cotangents):
 
 _heads_kernels.defvjp(
     functools.partial(_kernels_fwd, residuals=True), _kernels_bwd)
+
+
+# ---- a decay a channel as the Pallas kernels (ops/pallas/kda_rule.py) ----
+
+def _channel_kernel_inputs(q, k, v, g, beta, *, chunk: int):
+    """q ... beta (heads, t, .) as the channel kernels take them: q, k, v in
+    chunks and `Gb` (heads, n, C + `ROWS`, d_k) float32: rows 0 .. C - 1 the
+    running sum of g inside a chunk, row C beta in its first C lanes."""
+    q, k, v, g, beta = _in_chunks(q, k, v, g, beta, chunk=chunk)
+    tile = jnp.pad(beta[:, :, None], ((0, 0), (0, 0), (0, ROWS - 1),
+                                      (0, q.shape[-1] - chunk)))
+    return q, k, v, jnp.concatenate([_running_decay(g), tile], axis=2)
+
+
+def _channel_inverses(sequences: int, k, Gb, *, sub: int, interpret: bool):
+    """T = (I + A)^-1 (heads, n, C, C) for the heads of `sequences`
+    sequences: `A` from the pairs kernel (one call), its inverse XLA's, a
+    sequence at a time (the inverse's blocks are 0.3 GB a sequence of 32
+    heads and 64 chunks)."""
+    A = kda_rule.rule_pairs(k, Gb, sub=sub, interpret=interpret)
+    T = lax.map(_unit_lower_inverse,
+                A.reshape(sequences, -1, *A.shape[1:]))
+    return T.reshape(A.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _channel_kernels(chunk: int, sub: int, interpret: bool, sequences: int,
+                     q, k, v, g, beta):
+    """`_one_sequence_channel` as kernel calls for the heads of `sequences`
+    sequences, (heads, t, .). Its backward is one more, by hand: between
+    forward and backward a head keeps its inputs, T and one state a block of
+    chunks (0.27 GB a layer at 32 heads x 4096 tokens)."""
+    return _channel_fwd(chunk, sub, interpret, sequences, q, k, v, g,
+                        beta)[0]
+
+
+def _channel_fwd(chunk, sub, interpret, sequences, q, k, v, g, beta,
+                 residuals=False):
+    h, t, _ = q.shape
+    inputs = _channel_kernel_inputs(q, k, v, g, beta, chunk=chunk)
+    T = _channel_inverses(sequences, inputs[1], inputs[3], sub=sub,
+                          interpret=interpret)
+    o, St, *S_in = kda_rule.rule_forward(*inputs, T, sub=sub,
+                                         residuals=residuals,
+                                         interpret=interpret)
+    # the kernels keep the state transposed (their docstring)
+    return (o.reshape(h, -1, o.shape[-1])[:, :t], St.swapaxes(1, 2)), (
+        q, k, v, g, beta, T, *S_in)
+
+
+def _channel_bwd(chunk, sub, interpret, sequences, saved, cotangents):
+    q, k, v, g, beta, T, S_in = saved
+    do, dS = cotangents
+    h, t, _ = q.shape
+    n, C = T.shape[1:3]
+    # the inputs in chunks again (a pad and a reshape; G a cumsum): what
+    # the forward made of them was not kept
+    inputs = _channel_kernel_inputs(q, k, v, g, beta, chunk=chunk)
+    do = jnp.pad(do, ((0, 0), (0, n * C - t), (0, 0))).reshape(
+        inputs[2].shape)
+    dq, dk, dv, dG, dbeta = kda_rule.rule_backward(
+        *inputs, T, S_in, do.astype(v.dtype), dS.swapaxes(1, 2), sub=sub,
+        interpret=interpret)
+    tokens = lambda z: z.reshape(h, n * C, *z.shape[3:])[:, :t]
+    # G is the running sum of g inside a chunk: its transpose runs back
+    dg = lax.cumsum(dG, axis=2, reverse=True)
+    return (tokens(dq), tokens(dk), tokens(dv), tokens(dg).astype(g.dtype),
+            tokens(dbeta[:, :, 0]).astype(beta.dtype))
+
+
+_channel_kernels.defvjp(
+    functools.partial(_channel_fwd, residuals=True), _channel_bwd)

@@ -28,8 +28,20 @@ taps), `A_log` (H,), `dt_bias` (H, d_k), `o_norm` (d_v,), `w_out` (H d_v,
 d). Tensor parallelism shards the head axis and `w_out` by rows, the
 Megatron pattern: one all-reduce after `w_out`.
 
+**Where the rule is made.** `channel_delta_rule` picks from what it sees: on
+a TPU at these widths (multiples of 128) three Pallas kernels
+(ops/pallas/kda_rule.py: a sub-block's decay factors, the chunk's operands
+and the walk all in VMEM, the backward by hand), the XLA text everywhere
+else; the instant `kda_rule` on the program's tracer says which, once a
+trace. On the kernel path a head keeps between forward and backward its
+inputs (`q`, `k`, `v`, `g`, `beta`), the chunks' inverses `T` and one
+float32 state a block of chunks: 0.27 GB a layer at 32 heads x 4096 tokens,
+live only inside that layer's backward.
+
 Scopes for a device trace: `kda` (everything but the rule, `kda/gate` the
-decay's passes) and `kda_rule` (`kda_rule/operands`, `kda_rule/walk`).
+decay's passes) and `kda_rule` (the kernels `kda_rule_pairs`, `kda_rule_fwd`,
+`kda_rule_bwd` and XLA's cumsum and inverse around them; in the XLA text
+`kda_rule/operands`, `kda_rule/walk`).
 `apply` also hands back two counters of the decay it made: `kda_g_min` (the
 most negative `g`: never under the bound) and `kda_g_spread` (the mean over
 tokens and heads of the channels' standard deviation of `g`: 0 says the

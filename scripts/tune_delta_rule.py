@@ -23,10 +23,17 @@ around a call this short also reads the dispatch):
     Mosaic's numbers, which the interpreter's tests cannot see;
   - with `--solve`, (I + A)^-1 [W | U] alone: XLA's `triangular_solve`
     (what the rule had before PR 36) against `solve_unit_lower` at several
-    base blocks and with its block products on the matrix unit.
+    base blocks and with its block products on the matrix unit;
+  - with `--channel`, all of the above but the knock-outs and the solve
+    for the rule with a decay a CHANNEL (ops/pallas/kda_rule.py:
+    `kda_rule_pairs`, `kda_rule_fwd`, `kda_rule_bwd`) at Kimi Delta
+    Attention's cell (default 32 heads x 4096 tokens, 128 / 128, bf16):
+    `--blocks` and `--turns` sweep that module's constants, the whole rule
+    is `channel_delta_rule` as kernels and as its XLA text, and `--check`
+    compares them on the chip.
 
-The readings behind the module's constants are PERF.md's (section 6, PRs 36
-and 38; one sequence, TPU v5 lite).
+The readings behind the modules' constants are PERF.md's (section 6, PRs 36
+and 38, one sequence; PR 60 for `--channel`; TPU v5 lite).
 """
 
 import argparse
@@ -42,7 +49,7 @@ import jax.numpy as jnp
 
 from distributed_pytorch_from_scratch_tpu.ops import delta_rule as rule
 from distributed_pytorch_from_scratch_tpu.ops.pallas import (
-    delta_rule as kernels)
+    delta_rule as kernels, kda_rule as channel_kernels)
 from distributed_pytorch_from_scratch_tpu.runtime.compile_cache import (
     enable_compile_cache)
 
@@ -83,15 +90,18 @@ def named(ms, *parts):
     return sum(v for k, v in ms.items() if any(p in k for p in parts))
 
 
-def rule_inputs(h, t, dk, dv, dtype, seed=1):
+def rule_inputs(h, t, dk, dv, dtype, seed=1, channel=False):
     """One sequence's q, k, v, g, beta as the layer makes them: unit keys,
-    queries scaled, (1, h, t, .)."""
+    queries scaled, (1, h, t, .); with `channel` a decay a channel under
+    the bounded gate, -5 < g < 0."""
     keys = jax.random.split(jax.random.key(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = (unit(jax.random.normal(keys[0], (1, h, t, dk))) / dk ** 0.5)
     k = unit(jax.random.normal(keys[1], (1, h, t, dk)))
     v = jax.random.normal(keys[2], (1, h, t, dv))
-    g = -jax.nn.softplus(jax.random.normal(keys[3], (1, h, t)))
+    g = (-5.0 * jax.nn.sigmoid(jax.random.normal(keys[3], (1, h, t, dk)) - 2.0)
+         if channel else
+         -jax.nn.softplus(jax.random.normal(keys[3], (1, h, t))))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, h, t)))
     return (*(z.astype(dtype) for z in (q, k, v)), g, beta)
 
@@ -129,9 +139,57 @@ def time_kernels(args, dtype, tag="", operands=None):
     return row
 
 
+def channel_operands(args, dtype):
+    """The channel kernels' operands for one sequence, made as the rule
+    makes them (T a real inverse), and random residuals."""
+    h, dk, dv = args.h, args.dk, args.dv
+    def make(*a):
+        inputs = rule._channel_kernel_inputs(*(z[0] for z in a),
+                                             chunk=args.chunk)
+        return (*inputs, rule._channel_inverses(
+            1, inputs[1], inputs[3], sub=rule.SUB, interpret=False))
+    inputs = jax.jit(make)(*rule_inputs(h, args.t, dk, dv, dtype,
+                                        channel=True))
+    n = inputs[0].shape[1]
+    keys = jax.random.split(jax.random.key(0), 3)
+    S_in = jax.random.normal(
+        keys[0], (h, n // channel_kernels.blocks(h, n)[1], dv, dk),
+        jnp.float32)
+    do = jax.random.normal(keys[1], (h, n, args.chunk, dv)).astype(dtype)
+    dS = jax.random.normal(keys[2], (h, dv, dk), jnp.float32)
+    return inputs, (S_in, do, dS)
+
+
+def time_channel_kernels(args, dtype, tag=""):
+    """The channel rule's four kernel calls at the module's blocks as they
+    stand, by name."""
+    K = channel_kernels
+    fwd_in, bwd_in = channel_operands(args, dtype)
+    sub = rule.SUB
+    row = {"pairs": named(capture_ms(jax.jit(lambda k, gb: K.rule_pairs(
+        k, gb, sub=sub)), fwd_in[1], fwd_in[3]), K.PAIRS_NAME)}
+    for name, residuals in (("fwd", False), ("fwd_res", True)):
+        fn = jax.jit(lambda *a, r=residuals: K.rule_forward(
+            *a, sub=sub, residuals=r))
+        row[name] = named(capture_ms(fn, *fwd_in), K.FWD_NAME)
+    fn = jax.jit(lambda *a: K.rule_backward(*a, sub=sub))
+    row["bwd"] = named(capture_ms(fn, *fwd_in, *bwd_in), K.BWD_NAME)
+    print(f"  channel kernels {tag:24s} pairs {row['pairs']:7.3f}  fwd "
+          f"{row['fwd']:7.3f}  fwd+residuals {row['fwd_res']:7.3f}  bwd "
+          f"{row['bwd']:7.3f} ms", flush=True)
+    return row
+
+
 def rule_paths(args):
     """The whole rule as the program runs it on the chip and as the XLA
     text with its `lax.scan`, which it runs everywhere else."""
+    if args.channel:
+        return {
+            "kernels": lambda *a: rule.channel_delta_rule(
+                *a, chunk=args.chunk),
+            "scan": lambda *a: jax.lax.map(
+                lambda r: jax.checkpoint(lambda *s: rule._one_sequence_channel(
+                    *s, chunk=args.chunk, sub=rule.SUB))(*r), a)}
     return {
         "kernels": lambda *a: rule.gated_delta_rule(*a, chunk=args.chunk),
         "scan": lambda *a: jax.lax.map(
@@ -141,7 +199,8 @@ def rule_paths(args):
 
 def check(args, dtype):
     """The kernels' path against the `lax.scan` text, on the chip."""
-    a = rule_inputs(args.h, args.check_t, args.dk, args.dv, dtype, seed=2)
+    a = rule_inputs(args.h, args.check_t, args.dk, args.dv, dtype, seed=2,
+                    channel=args.channel)
     paths = rule_paths(args)
     f32 = jnp.float32
 
@@ -169,8 +228,10 @@ def check(args, dtype):
 
 def time_rule(args, dtype):
     """One sequence's whole rule, both paths, forward and with backward."""
-    q, k, v, g, beta = rule_inputs(args.h, args.t, args.dk, args.dv, dtype)
+    q, k, v, g, beta = rule_inputs(args.h, args.t, args.dk, args.dv, dtype,
+                                   channel=args.channel)
     paths = rule_paths(args)
+    prefix = "kda_rule_" if args.channel else "gdn_rule_"
     for name in args.paths.split(","):
         path = paths[name]
         loss = lambda *a, path=path: jnp.sum(
@@ -182,7 +243,7 @@ def time_rule(args, dtype):
             top = sorted(((v_, k_) for k_, v_ in ms.items() if k_ != "busy"
                           and not k_.startswith("while")), reverse=True)[:args.top]
             print(f"  rule {name:8s} {what:8s} busy {ms['busy']:8.3f} ms  "
-                  f"kernels {named(ms, 'gdn_rule_'):7.3f}  while "
+                  f"kernels {named(ms, prefix):7.3f}  while "
                   f"{named(ms, 'while'):8.3f}  solve "
                   f"{named(ms, 'custom-call'):7.3f}  top: "
                   + ", ".join(f"{k_} {v_:.2f}" for v_, k_ in top),
@@ -283,8 +344,12 @@ def knockouts(args, dtype):
 
 def parse_args(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--channel", action="store_true",
+                    help="the rule with a decay a channel and its kernels "
+                         "(ops/pallas/kda_rule.py), at --t 4096 by default")
     ap.add_argument("--h", type=int, default=32)
-    ap.add_argument("--t", type=int, default=8192)
+    ap.add_argument("--t", type=int, default=None,
+                    help="tokens (default 8192; 4096 with --channel)")
     ap.add_argument("--dk", type=int, default=128)
     ap.add_argument("--dv", type=int, default=128)
     ap.add_argument("--chunk", type=int, default=rule.CHUNK)
@@ -309,7 +374,12 @@ def parse_args(argv=None):
                     help="how many of the whole rule's longest ops to name")
     ap.add_argument("--no_rule", action="store_true",
                     help="the kernels alone, not the whole rule")
-    return ap.parse_args(argv)
+    args = ap.parse_args(argv)
+    if args.t is None:
+        args.t = 4096 if args.channel else 8192
+    if args.channel and (args.knockouts or args.solve):
+        ap.error("--knockouts and --solve are the scalar rule's")
+    return args
 
 
 def main():
@@ -326,24 +396,32 @@ def main():
             check(args, check_dtype)
     if args.solve:
         time_solves(args)
-    was = kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK
+    K = channel_kernels if args.channel else kernels
+    time_them = time_channel_kernels if args.channel else time_kernels
+    was = K.HEAD_BLOCK, K.CHUNK_BLOCK
     for blocks in ([] if args.no_kernels else args.blocks.split(",")
                    if args.blocks else [None]):
         if blocks:
-            kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK = map(
-                int, blocks.split("x"))
+            K.HEAD_BLOCK, K.CHUNK_BLOCK = map(int, blocks.split("x"))
         try:
-            time_kernels(args, dtype, f"blocks {kernels.HEAD_BLOCK}x"
-                                      f"{kernels.CHUNK_BLOCK}")
+            time_them(args, dtype, f"blocks {K.HEAD_BLOCK}x{K.CHUNK_BLOCK}")
         except Exception as e:  # noqa: BLE001 - Mosaic refuses a block
             print(f"  blocks {blocks} FAILED {type(e).__name__}: "
                   f"{str(e)[-300:]!r}", flush=True)
-    kernels.HEAD_BLOCK, kernels.CHUNK_BLOCK = was
-    was = kernels.HEADS_IN_TURN
+    K.HEAD_BLOCK, K.CHUNK_BLOCK = was
+    in_turn = (("HEADS_IN_TURN", "BWD_HEADS_IN_TURN") if args.channel
+               else ("HEADS_IN_TURN",))
+    was = [getattr(K, name) for name in in_turn]
     for turns in (args.turns.split(",") if args.turns else []):
-        kernels.HEADS_IN_TURN = int(turns)
-        time_kernels(args, dtype, f"{turns} heads in turn")
-    kernels.HEADS_IN_TURN = was
+        for name in in_turn:
+            setattr(K, name, int(turns))
+        try:
+            time_them(args, dtype, f"{turns} heads in turn")
+        except Exception as e:  # noqa: BLE001
+            print(f"  turns {turns} FAILED {type(e).__name__}: "
+                  f"{str(e)[-300:]!r}", flush=True)
+    for name, value in zip(in_turn, was):
+        setattr(K, name, value)
     if args.knockouts:
         knockouts(args, dtype)
     if not args.no_rule:

@@ -10,6 +10,9 @@ sizes, float32.
 * the chunked rule against the token-by-token rule: outputs, final state and
   all five gradients, at two chunk sizes, a ragged length, the decay's and
   the write strength's extremes;
+* guards that the rule's kernels engaged and are read right, the scalar
+  rule's (cell 6) and beside them the rule with a decay a channel's
+  (`channel_delta_rule`, ops/pallas/kda_rule.py, cell 12);
 * the flash kernel at width 256 and a group of 8 against the XLA path,
   forward and backward, at a multi-block length;
 * partial RoPE; the two norms;
@@ -36,10 +39,13 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_gdn_moe import (
     vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
     causal_attention_xla)
+from distributed_pytorch_from_scratch_tpu.obs import trace as obs_trace
 from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
-    delta_rule_recurrent, gated_delta_rule)
+    SUB, channel_delta_rule, delta_rule_recurrent, gated_delta_rule)
 from distributed_pytorch_from_scratch_tpu.ops.pallas import (
     delta_rule as rule_kernels)
+from distributed_pytorch_from_scratch_tpu.parallel.kda import (
+    KimiDeltaAttention)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
     flash_attention)
 from distributed_pytorch_from_scratch_tpu.ops.rope import (
@@ -362,6 +368,122 @@ def test_a_batch_past_one_calls_tables_is_walked_a_call_at_a_time(
     assert calls == calls1 == [("gdn_rule_fwd", 7)]     # there inside a loop
     for a, b in zip((o, S, *grads), (o1, S1, *grads1)):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+# ---- the rule with a decay a channel: that its kernels engaged ----
+
+def channel_inputs(t=128, widths=(128, 128)):
+    """`rule_inputs` with a decay a channel under the bounded gate."""
+    q, k, v, g, beta = rule_inputs(t, widths=widths)
+    gate = -5.0 * jax.nn.sigmoid(jax.random.normal(
+        jax.random.key(9), q.shape))
+    return q, k, v, gate, beta
+
+
+def channel_jaxpr(*args, grad=False):
+    rule = lambda *a: channel_delta_rule(*a)
+    if grad:
+        rule = jax.grad(lambda *a: jnp.sum(channel_delta_rule(*a)[0]),
+                        (0, 1, 2, 3, 4))
+    return jax.make_jaxpr(rule)(*args).jaxpr
+
+
+class _Said:
+    def __init__(self):
+        self.instants = []
+
+    def instant(self, name, **fields):
+        self.instants.append((name, fields))
+
+
+def test_off_the_tpu_or_at_other_widths_the_channel_rule_is_the_xla_text(
+        monkeypatch):
+    """The channel rule's kernels engage from what the call sees, as the
+    scalar rule's: a TPU, widths that are multiples of 128, chunks of 64 in
+    sub-blocks of 16. Anything else lowers with no Mosaic call, and the
+    instant on the program's tracer says which path a trace took."""
+    wide, narrow = channel_inputs(), channel_inputs(widths=(16, 8))
+    said = _Said()
+    monkeypatch.setattr(obs_trace, "_current", said)
+    assert jax.default_backend() != "tpu"
+    assert not pallas_calls(channel_jaxpr(*wide))
+    text = jax.jit(lambda *a: channel_delta_rule(*a)).lower(*wide).as_text()
+    assert "tpu_custom_call" not in text and "while" in text
+    assert [f["path"] for _, f in said.instants] == ["xla", "xla"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not pallas_calls(channel_jaxpr(*narrow))
+    assert not pallas_calls(jax.make_jaxpr(lambda *a: channel_delta_rule(
+        *a, chunk=32))(*wide).jaxpr)
+    assert len(pallas_calls(channel_jaxpr(*wide))) == 2
+    assert [f["path"] for _, f in said.instants[2:]] == ["xla", "xla",
+                                                         "kernel"]
+    name, fields = said.instants[-1]
+    assert name == "kda_rule" and fields == dict(
+        path="kernel", heads=6, tokens=128, d_k=128, d_v=128, chunk=64,
+        sub=SUB, dtype="float32", block=(6, 2))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        channel_delta_rule(*narrow, interpret=True)
+
+
+def test_the_channel_rules_kernels_are_not_read_as_flash_calls(monkeypatch):
+    """Every Mosaic call of the channel rule and of its gradient is named
+    `kda_rule_*` and has neither 3 nor 6 operands (benchmark/lib/kernels.py
+    would read it as a flash call)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = channel_inputs()
+    assert pallas_calls(channel_jaxpr(*args)) == [
+        ("kda_rule_pairs", 2), ("kda_rule_fwd", 5)]
+    calls = pallas_calls(channel_jaxpr(*args, grad=True))
+    assert sorted(calls) == [("kda_rule_bwd", 8), ("kda_rule_fwd", 5),
+                             ("kda_rule_pairs", 2)]
+    for name, operands in calls:
+        assert name.startswith("kda_rule_") and operands not in (3, 6)
+
+
+def test_on_the_channel_kernel_path_no_sub_blocks_factor_is_left_to_xla(
+        monkeypatch):
+    """The mechanism engaged: on the kernel path (forward and gradient) no
+    array outside a `pallas_call` has the decay factors' shape, a (B, C,
+    d_k) or (B, sub, d_k) tail (four times the keys, the 65 ms of cell 12's
+    step), and no product is at `HIGHEST` (the solve's were). The XLA text
+    has both: the guard reads what it should."""
+    C, dk = 64, 128
+    args = channel_inputs()
+    tails = {(C // SUB, C, dk), (C // SUB, SUB, dk)}
+    shapes = lambda eqn: {v.aval.shape[-3:] for v in eqn.invars + eqn.outvars
+                          if hasattr(v.aval, "shape")}
+    factors = lambda jaxpr: [eqn for eqn in eqns_outside_kernels(jaxpr)
+                             if shapes(eqn) & tails]
+    highest = lambda jaxpr: [
+        eqn for eqn in eqns_outside_kernels(jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and "HIGHEST" in str(eqn.params["precision"])]
+    for grad in (False, True):
+        text = channel_jaxpr(*args, grad=grad)
+        assert factors(text) and highest(text)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for grad in (False, True):
+        kernels = channel_jaxpr(*args, grad=grad)
+        assert pallas_calls(kernels)
+        assert not factors(kernels) and not highest(kernels)
+
+
+def test_the_delta_mixer_takes_the_kernel_path_inside_shard_map(monkeypatch):
+    """The call sits inside `shard_map`, per-shard heads: traced for two
+    shards at the published head widths, the mixer's rule is the kernels'
+    and their outputs carry the shards' varying axes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kda = KimiDeltaAttention(64, 4, 128, 128, tp_size=2)
+    params = jax.eval_shape(kda.init, jax.random.key(0))
+    x = jax.ShapeDtypeStruct((2, 128, 64), jnp.float32)
+    mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices()[:2])
+    fn = jax.shard_map(lambda p, x: kda.apply(p, x)[0], mesh=mesh,
+                       in_specs=(kda.specs(), jax.sharding.PartitionSpec()),
+                       out_specs=jax.sharding.PartitionSpec())
+    loss = lambda p, x: jnp.sum(fn(p, x))
+    calls = pallas_calls(jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr)
+    assert sorted(set(calls)) == [("kda_rule_bwd", 8), ("kda_rule_fwd", 5),
+                                  ("kda_rule_pairs", 2)]
 
 
 # ---- the flash kernel at width 256 and a group of 8 ----

@@ -11,7 +11,9 @@ expert, a multi-token-prediction module. CPU, tiny sizes.
 * the chunked rule with a decay a channel against the token-by-token rule:
   outputs, final state and all five gradients, at the gate's bound on every
   channel for whole chunks (what the sub-blocks rely on), near 0, a ragged
-  length; with every channel's decay equal it is the scalar rule;
+  length; with every channel's decay equal it is the scalar rule; as the XLA
+  text and as the Pallas kernels (ops/pallas/kda_rule.py) under the
+  interpreter, at the widths they hold;
 * the group-limited selection against a sort-based one, and today's at one
   group; `LatentAttention` with no q latent and a gate a head;
 * the shares of an expert layer add up to the uncut layer;
@@ -46,6 +48,7 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_kda_mla_moe import (
 from distributed_pytorch_from_scratch_tpu.obs import trace as obs_trace
 from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
     SUB, channel_delta_rule, delta_rule_recurrent, gated_delta_rule)
+from distributed_pytorch_from_scratch_tpu.ops.pallas import kda_rule
 from distributed_pytorch_from_scratch_tpu.ops.rope import rope_angles
 from distributed_pytorch_from_scratch_tpu.parallel.kda import (
     KimiDeltaAttention)
@@ -178,41 +181,90 @@ def rule_inputs(seed, t, g="drawn", b=2, h=2, dk=16, dv=8):
     return q, k, v, gate, beta
 
 
+# the rule as the Pallas kernels under the interpreter, at the widths they
+# hold: two heads a grid step for the four heads of two sequences (one call
+# takes both), their chains traced side by side, two chunks a grid step
+KERNELS = dict(dk=128, dv=128)
+
+
+def channel_rule(impl, monkeypatch, **kw):
+    if impl == "text":
+        return lambda *a: channel_delta_rule(*a, **kw)
+    monkeypatch.setattr(kda_rule, "HEAD_BLOCK", 2)
+    monkeypatch.setattr(kda_rule, "HEADS_IN_TURN", 2)
+    return lambda *a: channel_delta_rule(*a, interpret=True, **kw)
+
+
 @pytest.mark.parametrize("g", ["drawn", "bound", "near_zero"])
-@pytest.mark.parametrize("t,chunk", [(192, 64), (150, 64), (96, 32)])
-def test_the_chunked_channel_rule_equals_the_token_by_token_rule(g, t,
-                                                                 chunk):
-    """Outputs, the final state and all five gradients. At the bound a
-    sub-block's factor about its first row reaches exp(75): the products
-    stay finite and the entries above the diagonal are selected away."""
-    args = rule_inputs(7, t, g)
-    loss = lambda rule: lambda *a: jnp.sum(jnp.sin(rule(*a)[0]))
+@pytest.mark.parametrize("t,chunk,impl", [
+    (192, 64, "text"), (150, 64, "text"), (96, 32, "text"),
+    # three chunks (one a grid step), a ragged length, a chunk and a half
+    (192, 64, "kernels"), (150, 64, "kernels"), (96, 64, "kernels")])
+def test_the_chunked_channel_rule_equals_the_token_by_token_rule(
+        g, t, chunk, impl, monkeypatch):
+    """Outputs, the final state and all five gradients (`g`'s a channel).
+    At the bound a sub-block's factor about its first row reaches exp(75):
+    the products stay finite and the entries above the diagonal are
+    selected away."""
+    args = rule_inputs(7, t, g, **(KERNELS if impl == "kernels" else {}))
+    rule = channel_rule(impl, monkeypatch, chunk=chunk)
+    loss = lambda rule: lambda *a: (
+        lambda o, S: jnp.sum(jnp.sin(o)) + jnp.sum(S * S))(*rule(*a))
     with jax.default_matmul_precision("highest"):
-        o, S = channel_delta_rule(*args, chunk=chunk)
+        o, S = rule(*args)
         o_ref, S_ref = delta_rule_recurrent(*args)
-        grads = jax.grad(loss(lambda *a: channel_delta_rule(
-            *a, chunk=chunk)), argnums=range(5))(*args)
+        grads = jax.grad(loss(rule), argnums=range(5))(*args)
         grads_ref = jax.grad(loss(delta_rule_recurrent),
                              argnums=range(5))(*args)
     scale = float(jnp.max(jnp.abs(o_ref)))
     assert float(jnp.max(jnp.abs(o - o_ref))) <= 2e-6 * max(scale, 1.0)
     assert float(jnp.max(jnp.abs(S - S_ref))) <= 1e-5 * max(
         float(jnp.max(jnp.abs(S_ref))), 1.0)
+    assert grads[3].shape == args[3].shape      # a channel's
     for a, b in zip(grads, grads_ref):
         assert bool(jnp.all(jnp.isfinite(a)))
         assert float(jnp.max(jnp.abs(a - b))) <= 1e-4 * max(
             float(jnp.max(jnp.abs(b))), 1e-6)
 
 
-def test_with_every_channels_decay_equal_it_is_the_scalar_rule():
-    q, k, v, g, beta = rule_inputs(11, 160)
+@pytest.mark.parametrize("impl", ["text", "kernels"])
+def test_with_every_channels_decay_equal_it_is_the_scalar_rule(
+        impl, monkeypatch):
+    q, k, v, g, beta = rule_inputs(
+        11, 160, **(KERNELS if impl == "kernels" else {}))
     one = g[..., 0]
     with jax.default_matmul_precision("highest"):
-        o, S = channel_delta_rule(q, k, v, jnp.broadcast_to(
+        o, S = channel_rule(impl, monkeypatch)(q, k, v, jnp.broadcast_to(
             one[..., None], g.shape), beta)
         o_s, S_s = gated_delta_rule(q, k, v, one, beta)
     np.testing.assert_allclose(o, o_s, atol=2e-6)
     np.testing.assert_allclose(S, S_s, atol=2e-6)
+
+
+def test_the_kernels_take_a_batchs_heads_in_one_call_and_a_ragged_length(
+        monkeypatch):
+    """Two sequences of three heads, 100 tokens: one call a kernel for the
+    six heads, four a grid step (the last block hangs over), to the text's
+    value, state and gradients."""
+    args = rule_inputs(3, 100, h=3, **KERNELS)
+    monkeypatch.setattr(kda_rule, "HEAD_BLOCK", 4)
+    monkeypatch.setattr(kda_rule, "HEADS_IN_TURN", 2)
+    kernels = lambda *a: channel_delta_rule(*a, interpret=True)
+    loss = lambda rule: lambda *a: (
+        lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S))(*rule(*a))
+    with jax.default_matmul_precision("highest"):
+        got = (*kernels(*args), *jax.grad(loss(kernels), range(5))(*args))
+        want = (*channel_delta_rule(*args), *jax.grad(
+            loss(channel_delta_rule), range(5))(*args))
+    assert got[0].shape == (2, 3, 100, 128) and got[1].shape == (2, 3, 128,
+                                                                 128)
+    top = max(float(jnp.max(jnp.abs(b))) for b in want[2:])
+    for a, b in zip(got[:2], want[:2]):
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(
+            float(jnp.max(jnp.abs(b))), 1.0)
+    # the same sums in another order: by the largest gradient's scale
+    for a, b in zip(got[2:], want[2:]):
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(top, 1.0)
 
 
 def test_the_sub_blocks_rely_on_the_bound_and_the_mixer_says_so():

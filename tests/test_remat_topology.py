@@ -442,6 +442,43 @@ def test_the_delta_rules_kernels_compile_at_the_hybrid_cells_shape(
                                              ("gdn_rule_bwd", 10)]
 
 
+def test_the_channel_rules_kernels_compile_at_the_kda_cells_shape(
+        topo, described_tpu):
+    """Mosaic takes the three kernels of the rule with a decay a channel
+    (PR 60) at cell 12's one sequence (32 heads, 4096 tokens in chunks of
+    64, 128 / 128, bf16) at the module's blocks, under the names and
+    operand counts that keep them out of the benchmark's flash patterns."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.pallas import kda_rule
+    from distributed_pytorch_from_scratch_tpu.ops.pallas.delta_rule import (
+        ROWS)
+    h, n, C, dk, dv = 32, 64, 64, 128, 128
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    inputs = (arg(bf16, h, n, C, dk), arg(bf16, h, n, C, dk),
+              arg(bf16, h, n, C, dv), arg(f32, h, n, C + ROWS, dk),
+              arg(f32, h, n, C, C))
+    saved = (arg(f32, h, n // kda_rule.blocks(h, n)[1], dv, dk),
+             arg(bf16, h, n, C, dv), arg(f32, h, dv, dk))
+    calls = []
+    for fn, args in (
+            (lambda k, gb: kda_rule.rule_pairs(k, gb, sub=16),
+             (inputs[1], inputs[3])),
+            (lambda *a: kda_rule.rule_forward(*a, sub=16, residuals=True),
+             inputs),
+            (lambda *a: kda_rule.rule_backward(*a, sub=16),
+             inputs + saved)):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls += re.findall(
+            r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
+            r'custom_call_target="tpu_custom_call"', text)
+    assert [(name.split(".")[0], operands.count("%"))
+            for name, operands in calls] == [
+        ("kda_rule_pairs", 2), ("kda_rule_fwd", 5), ("kda_rule_bwd", 8)]
+
+
 def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
         topo, described_tpu):
     """The fifth cell's step (`lfm2-8b-a1b.train-ep4share-b2-t8192`: the
