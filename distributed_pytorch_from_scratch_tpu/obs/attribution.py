@@ -106,30 +106,6 @@ def calibrate_ici(chip: str, n: int,
     return bw, lat
 
 
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
-def resolve_flash_tiling(t: int, block_q: Optional[int] = None,
-                         block_k: Optional[int] = None,
-                         head_dim: int = 64,
-                         dtype: str = "bfloat16") -> Dict[str, int]:
-    """The (t_pad, bq, bk) the flash kernel would actually run — mirrors
-    `flash_attention`'s pow2 clamp. Blocks default to the autotuner table
-    (which needs no backend for a pure lookup when the key names one)."""
-    if block_q is None or block_k is None:
-        # lazy import: the kernel module imports jax, but a table lookup
-        # does not initialise a backend beyond jax.default_backend()
-        from ..ops.pallas.flash_attention import get_block_config
-        tuned = get_block_config(t, head_dim, dtype)
-        block_q = block_q or tuned.block_q
-        block_k = block_k or tuned.block_k
-    pow2 = max(128, 1 << (t - 1).bit_length())
-    bq, bk = min(block_q, pow2), min(block_k, pow2)
-    t_pad = _round_up(t, max(bq, bk))
-    return {"t_pad": t_pad, "block_q": bq, "block_k": bk}
-
-
 def flash_tile_stats(t: int, block_q: Optional[int] = None,
                      block_k: Optional[int] = None,
                      t_real: Optional[int] = None,
@@ -140,8 +116,10 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
     causal ideal — the quantified 't=1000 -> 1024 padding waste' suspect.
     With `mask` (an `ops/attention.AttnMask` that is not the triangle) the
     same under the declared mask: the ideal is the entries it leaves live,
-    the blocks the kernels' own clamp of the table's; `backward` reads the
-    backward's plan (merged rectangles, its own sub-tile).
+    the blocks the kernels' own (`flash_blocks`, asked with `block_q` /
+    `block_k` for the direction read); `backward` reads the backward's plan
+    (merged rectangles, its own sub-tile) at the backward's blocks. `dtype`
+    selects nothing and is kept for its callers.
 
     Reads the kernel's own static plan (`causal_plan_stats`, the function
     the kernels walk and `_fwd_call`'s cost_estimate prices): a tile is a
@@ -152,26 +130,17 @@ def flash_tile_stats(t: int, block_q: Optional[int] = None,
     whole square was 2.0). `t_real` < t prices the pad-aware bucketed path
     (attn_t_real).
     """
-    from ..ops.attention import live_entries
-    from ..ops.pallas.flash_attention import (CAUSAL, get_block_config,
-                                              mask_block, plan_stats,
-                                              window_block)
+    from ..ops.attention import CAUSAL, live_entries
+    from ..ops.pallas.flash_attention import flash_blocks, plan_stats
+    asked = ({"bwd_block_q": block_q, "bwd_block_k": block_k} if backward
+             else {"block_q": block_q, "block_k": block_k})
+    t_pad, bq, bk, bbq, bbk, mask = flash_blocks(
+        t, head_dim, mask or CAUSAL, t_real=t_real, **asked)
+    if backward:
+        bq, bk = bbq, bbk
     tr = t if t_real is None else t_real
-    if (mask is None or mask.kind == "causal"
-            or mask.kind == "sliding_window" and mask.window >= t):
-        mask = CAUSAL
-        tiling = resolve_flash_tiling(t, block_q, block_k, head_dim, dtype)
-        t_pad, bq, bk = tiling["t_pad"], tiling["block_q"], tiling["block_k"]
-        ideal = tr * (tr + 1) / 2
-    else:
-        tuned = get_block_config(t, head_dim, dtype)
-        asked = (min(block_q or tuned.bwd_block_q,
-                     block_k or tuned.bwd_block_k) if backward
-                 else min(block_q or tuned.block_q, block_k or tuned.block_k))
-        clamp = window_block if mask.kind == "sliding_window" else mask_block
-        t_pad, bq = t, clamp(mask, t, asked)
-        bk = bq
-        ideal = live_entries(mask, t)
+    ideal = (tr * (tr + 1) / 2 if mask.kind == "causal"
+             else live_entries(mask, t))
     plan = plan_stats(mask, t_pad, bq, bk, tr, head_dim, backward)
     live = plan["computed_unmasked"] + plan["computed_masked"]
     return {"t_pad": t_pad, "block_q": bq, "block_k": bk,
@@ -662,7 +631,7 @@ def attribution(cfg, batch: int, t: int, remat: str = "dots", spd: int = 8,
         "note": (f"t={t_real or t}->t_pad {stats['t_pad']} @ "
                  f"{stats['block_q']}x{stats['block_k']} blocks: "
                  f"{waste:.2f}x causal-ideal MXU work (fix: bucketing/"
-                 f"attn_t_real + tuned blocks)"),
+                 f"attn_t_real)"),
     }, {
         "name": "remat recompute",
         "est_ms": ms["remat_recompute"],

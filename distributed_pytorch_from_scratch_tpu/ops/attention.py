@@ -172,7 +172,6 @@ def causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         return causal_attention_xla(q, k, v, t_real=t_real)
     from .pallas.flash_attention import flash_attention
 
-    # block sizes come from the tuned-block table (get_block_config)
     return flash_attention(q, k, v, t_real=t_real,
                            interpret=impl == "flash_interpret")
 

@@ -1,17 +1,12 @@
-"""Shared JSON persistence for the Pallas block-autotuner tables.
+"""JSON persistence for the paged kernels' block-autotuner table.
 
-Both kernel families keep a small in-memory table of tuned block shapes
-— flash (`flash_attention.py`: (t_bucket, head_dim, dtype, backend) ->
-BlockConfig) and paged (`paged_attention.py`: (page_size, head_dim,
-kv_dtype, backend) -> PagedBlockConfig) — persisted as JSON (a tracked
-file beside this module, or wherever the table's env var points) so one
-on-chip sweep serves every later run. The env-var/merge/atomic-publish
-mechanics are identical and MUST NOT drift independently (a key-format
-drift between writer and reader silently un-tunes every dispatch), so
-they live here once: keys serialize as ':'-joined parts, values as the
-config's tuple, unreadable/garbled files are ignored (the table keeps
-its defaults), and writes publish atomically via os.replace (the
-training/checkpoint.py convention).
+`paged_attention.py` keeps a small in-memory table of tuned block shapes
+((page_size, head_dim, kv_dtype, backend) -> PagedBlockConfig), persisted
+as JSON (a tracked file beside this module, or wherever the table's env
+var points) so one on-chip sweep serves every later run. Keys serialize
+as ':'-joined parts, values as the config's tuple, unreadable/garbled
+files are ignored (the table keeps its defaults), and writes publish
+atomically via os.replace (the training/checkpoint.py convention).
 
 Cache format v2 (ISSUE 16): every entry carries PROVENANCE —
 `{source: sweep|online, capture, ts}` — because the control plane can

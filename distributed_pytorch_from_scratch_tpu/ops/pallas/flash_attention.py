@@ -35,65 +35,35 @@ saving survives training, not just decode. The dk/dv backward accumulates
 over the `group` query heads of each kv head through an extra sequential
 grid dimension.
 
-Round 6 (the 45M MFU-gap work): block shapes default to a cached
-autotuner table (`get_block_config` / `autotune_block_config` — the best
-combo flips between shapes, see DEFAULT_BLOCK_Q's sweep note), and the
-public `t_real` argument makes the kernels pad-aware for sequence
-bucketing: a t=1024 buffer holding 1000 real tokens does ~1000 tokens of
-work (dead tiles are skipped by the same grid guards as the internal
-padding), with exact zeros and exact zero gradients on the pad rows.
+Below a call's arguments everything is decided from its shapes, at trace
+time, in this module; each rule's readings stand beside its constant:
 
-PR 30: inside a grid tile the kernels walk a static causal sub-tile plan
-(`causal_subtile_plan`), so the one-tile-a-head shape the table picks at
-t = 1024 no longer computes the dead half of its square, and `t_real`
-skips inside a tile too.
+- the grid blocks and the padded length: `flash_blocks` (`DEFAULT_BLOCK`,
+  clamped to the sequence or to the mask's block), which
+  `obs/attribution.flash_tile_stats` asks too;
+- inside a grid tile, a static sub-tile plan (`causal_subtile_plan`;
+  `subtile_plan` under a mask; `_subtile_shape` takes the sub-tile from q/k's
+  width and the key blocks a head has), so one tile a head at t = 1024 does
+  not compute the dead half of its square, and `t_real` skips inside a tile;
+- the forward's walk (`_fwd_call`; instant `flash_fwd_walk`: `tile` / `row`
+  / `grid`): a head of several blocks keeps its K and V resident where they
+  fit `KV_ROW_VMEM_BYTES`, fetched once a head, the online softmax in
+  registers (a row over `KV_ROW_SCOPED_BYTES` asks Mosaic for its scoped
+  VMEM, `_vmem_limit`); longer rows walk the grid's key blocks, their K / V
+  index maps clamped to the diagonal;
+- the backward's walk (`_bwd_call`; instant `flash_bwd_walk`, with
+  `buffers`): ONE kernel (`_bwd_row_kernel`: s, p, dp and ds formed once a
+  rectangle, five products where the split kernels run seven) where the
+  head's whole rows fit `BWD_ROW_VMEM_BYTES` double-buffered, or
+  `BWD_ROW_ONCE_VMEM_BYTES` with every block kept ONCE (`pl.Buffered(1)`);
+  heads over both keep the split dq and dk / dv kernels.
 
-PR 34: a head whose sequence spans several blocks keeps its K and V
-resident where they fit `KV_ROW_VMEM_BYTES` (t = 4096 at q/k 192, v 128
-does): the forward's grid is then (b*h, query blocks), K and V are fetched
-once a head, a query block runs its diagonal tile's static plan and loops
-over the key tiles left of it, and the online softmax never leaves
-registers (`_fwd_kernel`; 16.8 -> 6.1 ms a call at that shape). The
-forward's sub-tile follows q/k's width and whether a head has several key
-blocks (`_subtile_shape`). Longer rows keep the gridded walk, its K / V
-index maps clamped to the diagonal.
-
-PR 40: the backward of such a head is ONE kernel where what it keeps of the
-head fits `BWD_ROW_VMEM_BYTES` (`_bwd_call` decides from the shapes and
-says which walk it took on the program's tracer, `flash_bwd_walk`): q, k,
-v, dO, lse and delta whole rows in VMEM, s, p, dp and ds formed once a
-rectangle and dq, dk and dv all fed from them (`_bwd_row_kernel`; five
-products where the split kernels run seven, 22.8 -> 12.5 ms a call at
-t = 4096, 192 / 128). Heads over the budget keep the split kernels.
-
-PR 52: the forward's row walk is admitted by what a v5e's VMEM holds, not
-by what Mosaic's default scoped limit does: `KV_ROW_VMEM_BYTES` went from 8
-to 32 MiB, and a row over `KV_ROW_SCOPED_BYTES` asks Mosaic for the scoped
-VMEM it needs (`_vmem_limit`, as the resident backward has since PR 40; a
-row inside it asks for nothing and its call is the one it was). t = 16,384
-at 128 / 128 and t = 8192 at 256 / 256, 16 MiB both, left the gridded walk
-(25.7 -> 13.6 ms a causal call and 13.5 -> 6.5 under `sliding_window(4096)`
-at the first shape, 9.7 -> 7.4 at the second: `KV_ROW_VMEM_BYTES`' note).
-`_fwd_call` says which walk it took on the program's tracer too
-(`flash_fwd_walk`: `tile` / `row` / `grid`); the kernel body and the calls'
-names did not change.
-
-PR 56: the backward's row walk is admitted twice. A head whose nine
-whole-row blocks fit `BWD_ROW_VMEM_BYTES` double-buffered keeps the call it
-had; one that does not, but fits `BWD_ROW_ONCE_VMEM_BYTES` with every block
-kept ONCE (`pipeline_mode=pl.Buffered(1)`: a whole-row block changes once a
-head, so the second buffer hides one head's DMA and nothing else), takes
-the same kernel body single-buffered. t = 16,384 at 128 / 128 with a group
-of 7 (112 MiB twice, 68 once) and t = 8192 at 256 / 256 with a group of 8
-(96, 60) left the split kernels (20.9 -> 14.2 ms a call under
-`sliding_window(4096)` and 46.7 -> 29.1 causal at the first shape, 24.5 ->
-16.7 at the second: `BWD_ROW_ONCE_VMEM_BYTES`' note). `flash_bwd_walk` says
-`buffers`.
+`t_real` makes the kernels pad-aware for sequence bucketing
+(`flash_attention`'s docstring).
 """
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import math
 from typing import Dict, NamedTuple, Optional, Tuple
@@ -116,10 +86,15 @@ MASK = -1e30  # hard mask; equivalent to the XLA path's -10000 (see module doc)
 # at these small head dims. Blocks clamp to the padded sequence length, so
 # shorter sequences are unaffected. The backward kernels are swept
 # separately (they keep larger per-block VMEM working sets).
-DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
-DEFAULT_BWD_BLOCK_Q = 1024
-DEFAULT_BWD_BLOCK_K = 1024
+# At t = 4096, q/k 192 over v 128 (PR 34, b*h 128; FWD_SUBTILE_WIDE's
+# table): the forward 6.90 / 6.09 / 5.72 ms at blocks 512 / 1024 / 2048, the
+# backward's one kernel 13.43 / 12.49 ms at 512 / 1024 (PR 40) and over the
+# scoped VMEM at 2048; the sweeps of PRs 52 - 56 at the longer cells' shapes
+# found 1024 again. So ONE value for all four blocks at every shape
+# (`flash_blocks`); a sweep (`scripts/tune_flash_blocks.py`, device time from
+# a capture) that finds another is adopted by editing this or that rule,
+# with its reading here.
+DEFAULT_BLOCK = 1024
 
 
 def _round_up(x: int, m: int) -> int:
@@ -138,10 +113,10 @@ def _out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
 
 # ------------------------------------------------------ causal sub-tile plan
 #
-# The block table's winner at t = 1024, head_dim 64 is ONE grid tile a head
-# (DEFAULT_BLOCK_Q's sweep note: grid-step overhead makes smaller grid
-# blocks slower), so the grid guards can skip nothing there. What the
-# kernels skip instead is decided inside the tile, at trace time: the tile's
+# `DEFAULT_BLOCK` at t = 1024, head_dim 64 is ONE grid tile a head (its
+# sweep note: grid-step overhead makes smaller grid blocks slower), so the
+# grid guards can skip nothing there. What the kernels skip instead is
+# decided inside the tile, at trace time: the tile's
 # (block_q x block_k) score square is cut into sub-tiles, and a plan that
 # depends on static values alone says which are never computed (wholly
 # above the diagonal, or wholly at or past t_real), which are computed with
@@ -716,7 +691,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
     A sub-row that sees its whole key row in one grid step finalises from
     values: (m, l, acc) never leave registers and no scratch exists. That
-    is the one-key-block grid (the table's winner at t = 1024) and, with
+    is the one-key-block grid (`DEFAULT_BLOCK` at t = 1024) and, with
     `row_walk`, a head whose sequence spans several blocks but whose K and V
     stay resident (`_fwd_call` decides): the key blocks are then the row of
     tiles the plans describe, walked inside the step. The tile on the
@@ -1655,205 +1630,48 @@ def _bwd_call(q, k, v, o, lse, do, *, t_real: int, block_q: int, block_k: int,
     return dq, dk, dv
 
 
-# ------------------------------------------- block-shape autotuner table
-#
-# The best (block_q, block_k, bwd_block_q, bwd_block_k) combo depends on
-# (padded seqlen, head_dim, dtype, backend) — at the reference shape the
-# grid-overhead-vs-causal-skip trade-off even inverts between block sizes
-# (see DEFAULT_BLOCK_Q's sweep note). Rather than bake one answer in, the
-# kernel consults a small cached table: built-in entries ship the swept
-# defaults, `autotune_block_config` measures and caches the best combo for
-# a new shape, and the cache persists as JSON (FLASH_BLOCKS_CACHE, else the
-# git-tracked ops/pallas/flash_blocks.json) so a sweep done once on
-# hardware (scripts/tune_flash_blocks.py --write_cache) serves every later
-# run.
+# ------------------------------------------- the blocks a call runs with
 
 
-@dataclasses.dataclass(frozen=True)
-class BlockConfig:
-    """One (fwd, bwd) block-shape choice for the flash kernels."""
-
-    block_q: int = DEFAULT_BLOCK_Q
-    block_k: int = DEFAULT_BLOCK_K
-    bwd_block_q: int = DEFAULT_BWD_BLOCK_Q
-    bwd_block_k: int = DEFAULT_BWD_BLOCK_K
-
-    def as_tuple(self) -> Tuple[int, int, int, int]:
-        return (self.block_q, self.block_k, self.bwd_block_q,
-                self.bwd_block_k)
-
-
-# (t_bucket, head_dim, dtype_name, backend) -> BlockConfig. t buckets by the
-# next power of two (the padded length the kernel actually runs), so t=1000
-# and t=1024 share one tuned entry. Built-in seed: the v5e sweep behind the
-# DEFAULT_* constants (b*h=256, t→1024, hd=64, bf16).
-_BLOCK_TABLE: Dict[Tuple[int, int, str, str], BlockConfig] = {
-    (1024, 64, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
-    # latent attention at its pre-training length, q/k 192 wide against v of
-    # 128 (the key's head_dim is q's): 4 x 4 tiles a head of which 10 are
-    # live. Swept in PR 34 (FWD_SUBTILE_WIDE's table, b*h 128): the forward
-    # 6.90 / 6.09 / 5.72 ms at blocks 512 / 1024 / 2048 with K and V
-    # resident, the backward's two kernels 29.1 / 22.8 ms at 512 / 1024 and
-    # over the scoped VMEM at 2048. Since PR 40 the backward here is one
-    # kernel with the head resident, 13.43 / 12.49 ms at 512 / 1024 (the note
-    # under FWD_SUBTILE_WIDE); the two kernels are what a head over
-    # `BWD_ROW_VMEM_BYTES` keeps.
-    (4096, 192, "bfloat16", "tpu"): BlockConfig(1024, 1024, 1024, 1024),
-}
-# key -> {source: sweep|online, capture, ts} provenance (ISSUE 16): an
-# online retune must never silently shadow a swept entry
-_BLOCK_META: Dict[Tuple[int, int, str, str], dict] = {}
-_cache_loaded = False
-
-
-def _parse_cache_key(parts):
-    return (int(parts[0]), int(parts[1]), parts[2], parts[3])
-
-
-def _parse_cache_cfg(blocks):
-    return BlockConfig(*(int(b) for b in blocks))
-
-
-def block_cache_path() -> str:
-    from .block_cache import default_cache_path
-    return default_cache_path("FLASH_BLOCKS_CACHE", "flash_blocks.json")
-
-
-def _table_key(t: int, head_dim: int, dtype) -> Tuple[int, int, str, str]:
-    t_bucket = max(128, 1 << (int(t) - 1).bit_length())
-    return (t_bucket, int(head_dim), jnp.dtype(dtype).name,
-            jax.default_backend())
-
-
-def load_block_cache(path: Optional[str] = None) -> int:
-    """Merge the JSON cache into the in-memory table; returns entries read.
-    Unreadable/garbled files are ignored (the table still has defaults)."""
-    from .block_cache import load_json_table
-    return load_json_table(
-        path or block_cache_path(), _BLOCK_TABLE,
-        _parse_cache_key, _parse_cache_cfg, meta=_BLOCK_META)
-
-
-def save_block_cache(path: Optional[str] = None) -> str:
-    from .block_cache import save_json_table
-    return save_json_table(path or block_cache_path(), _BLOCK_TABLE,
-                           meta=_BLOCK_META)
-
-
-def record_online_block_config(t: int, head_dim: int, dtype,
-                               config: BlockConfig,
-                               capture: Optional[str] = None,
-                               force: bool = False,
-                               path: Optional[str] = None) -> str:
-    """Adopt an ONLINE-retuned flash block shape: set it in-memory and
-    persist it with {source: online, capture, ts} provenance (ISSUE 16).
-    Refuses (ValueError) to shadow a swept cache entry without `force`."""
-    from .block_cache import write_online_entry
-    key = _table_key(t, head_dim, dtype)
-    out = write_online_entry(path or block_cache_path(), key, config,
-                             _parse_cache_key, _parse_cache_cfg,
-                             capture=capture, force=force)
-    _BLOCK_TABLE[key] = config
-    _BLOCK_META[key] = {"source": "online", "capture": capture, "ts": None}
-    return out
-
-
-def set_block_config(t: int, head_dim: int, dtype,
-                     config: BlockConfig) -> None:
-    _BLOCK_TABLE[_table_key(t, head_dim, dtype)] = config
-
-
-def get_block_config(t: int, head_dim: int, dtype) -> BlockConfig:
-    """Tuned blocks for this (t, head_dim, dtype) on the current backend,
-    falling back to the swept DEFAULT_* values. Loads the JSON cache once
-    per process."""
-    global _cache_loaded
-    if not _cache_loaded:
-        _cache_loaded = True
-        load_block_cache()
-    return _BLOCK_TABLE.get(_table_key(t, head_dim, dtype), BlockConfig())
-
-
-def autotune_block_config(t: int, head_dim: int, dtype=jnp.bfloat16,
-                          batch_heads: int = 8,
-                          sweep: Tuple[int, ...] = (128, 256, 512),
-                          iters: int = 5, warmup: int = 2,
-                          include_current: bool = True,
-                          write_cache: bool = False,
-                          interpret: bool = False) -> BlockConfig:
-    """Sweep block_q x block_k over `sweep` for this (t, head_dim, dtype),
-    time fwd and fwd+bwd on the CURRENT backend, record the best combo in
-    the table (and optionally the JSON cache). Returns the winner.
-
-    The fwd combo is chosen first; the bwd blocks are then swept with the
-    winning fwd blocks fixed (they run as separate kernels with separate
-    VMEM working sets, so the product factorises). Combos that clamp to an
-    identical effective shape (blocks > padded t) dedupe before timing.
-    `interpret` runs the sweep's machinery under the Pallas interpreter
-    (tests); its winners say nothing about a chip.
-    """
-    import time
-
-    _require_tpu("autotune_block_config", interpret)
-
-    key = jax.random.key(0)
-    shape = (1, batch_heads, t, head_dim)
-    q = jax.random.normal(jax.random.fold_in(key, 1), shape, dtype)
-    k = jax.random.normal(jax.random.fold_in(key, 2), shape, dtype)
-    v = jax.random.normal(jax.random.fold_in(key, 3), shape, dtype)
-
-    pow2 = max(128, 1 << (t - 1).bit_length())
-    candidates = sorted(set(
-        (min(bq, pow2), min(bk, pow2)) for bq in sweep for bk in sweep))
-    if include_current:
-        cur = get_block_config(t, head_dim, dtype)
-        candidates = sorted(set(
-            candidates + [(min(cur.block_q, pow2), min(cur.block_k, pow2))]))
-
-    def timed(fn) -> float:
-        for _ in range(warmup):
-            jax.block_until_ready(fn(q, k, v))
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = fn(q, k, v)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters
-
-    def sweep_over(pairs, build):
-        best = None
-        for pair in pairs:
-            try:
-                secs = timed(build(pair))
-            except Exception:  # noqa: BLE001 — an invalid combo just loses
-                continue
-            if best is None or secs < best[0]:
-                best = (secs, pair)
-        if best is None:
-            raise RuntimeError(
-                f"flash block autotune: every candidate failed at "
-                f"t={t} hd={head_dim} {jnp.dtype(dtype).name}")
-        return best[1]
-
-    fwd_bq, fwd_bk = sweep_over(candidates, lambda pair: jax.jit(
-        lambda q, k, v: flash_attention(q, k, v, block_q=pair[0],
-                                        block_k=pair[1],
-                                        interpret=interpret)))
-
-    def grad_fn(pair):
-        def loss(q, k, v):
-            return jnp.sum(flash_attention(
-                q, k, v, block_q=fwd_bq, block_k=fwd_bk,
-                bwd_block_q=pair[0], bwd_block_k=pair[1],
-                interpret=interpret).astype(jnp.float32) ** 2)
-        return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-
-    bwd_bq, bwd_bk = sweep_over(candidates, grad_fn)
-
-    best = BlockConfig(fwd_bq, fwd_bk, bwd_bq, bwd_bk)
-    set_block_config(t, head_dim, dtype, best)
-    if write_cache:
-        save_block_cache()
-    return best
+def flash_blocks(t: int, head_dim: int, mask: AttnMask = CAUSAL, *,
+                 t_real: Optional[int] = None, block_q: Optional[int] = None,
+                 block_k: Optional[int] = None,
+                 bwd_block_q: Optional[int] = None,
+                 bwd_block_k: Optional[int] = None):
+    """(t_pad, block_q, block_k, bwd_block_q, bwd_block_k, mask) as
+    `flash_attention` runs `t` rows at q/k width `head_dim`: a function of
+    its arguments alone and the one place that says it
+    (`obs/attribution.flash_tile_stats` asks here). A block not named is
+    `DEFAULT_BLOCK` at every width (its note: `head_dim` decides nothing
+    yet); `mask` comes back `CAUSAL` where a window covers the rows."""
+    asked = []
+    for name, blk in (("block_q", block_q), ("block_k", block_k),
+                      ("bwd_block_q", bwd_block_q),
+                      ("bwd_block_k", bwd_block_k)):
+        blk = blk or DEFAULT_BLOCK
+        if blk % 128 or blk & (blk - 1):
+            raise ValueError(
+                f"{name} must be a power-of-two multiple of 128, got {blk}")
+        asked.append(blk)
+    if mask.kind == "sliding_window" and mask.window >= t:
+        mask = CAUSAL       # every earlier row is inside the window
+    if mask.kind != "causal":
+        if t_real not in (None, t):
+            raise ValueError(f"a {mask.kind} mask takes no t_real, got "
+                             f"t={t}, t_real={t_real}")
+        clamp = window_block if mask.kind == "sliding_window" else mask_block
+        bq = bk = clamp(mask, t, min(asked[:2]))
+        bbq = bbk = clamp(mask, t, min(asked[2:]))
+    else:
+        # Clamp blocks to the next power of two >= t so that max(bq, bk) is
+        # a common multiple of both and t_pad divides evenly into full q AND
+        # k blocks (a non-power-of-two clamp once left q rows >= block_q
+        # unwritten). Padded blocks are skipped by the kernels' block_live
+        # guards, so over-padding costs only grid overhead. All four block
+        # sizes share one t_pad, so the bwd blocks participate in the clamp.
+        pow2 = max(128, 1 << (t - 1).bit_length())
+        bq, bk, bbq, bbk = (min(blk, pow2) for blk in asked)
+    return _round_up(t, max(bq, bk, bbq, bbk)), bq, bk, bbq, bbk, mask
 
 
 # ---------------------------------------------------------------- public
@@ -1887,13 +1705,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     grouped-query attention routed inside the kernels (no K/V repeat in HBM).
     v may be of ANOTHER width than q and k (latent attention: q/k of 192
     against v of 128); the output has v's width, the scores are scaled by
-    q's, and the block table is asked by q's.
+    q's, and `flash_blocks` is asked by q's.
 
     Drop-in replacement for `causal_attention_xla`
     (`/root/reference/models/model.py:73-77` semantics). Sequence length is
     padded to the block size internally; padded keys are masked, padded
-    query rows are sliced off. Block sizes default to the autotuner table
-    (`get_block_config`; explicit values override); `bwd_block_*` tune the
+    query rows are sliced off. `flash_blocks` says which blocks the call runs
+    with (explicit values override `DEFAULT_BLOCK`); `bwd_block_*` tune the
     dq/dkv kernels independently of the forward.
 
     `t_real` (pad-aware bucketing): when the caller's sequence buffer is
@@ -1918,40 +1736,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         t_real = t
     elif not 1 <= t_real <= t:
         raise ValueError(f"t_real {t_real} must be in [1, t={t}]")
-    if None in (block_q, block_k, bwd_block_q, bwd_block_k):
-        tuned = get_block_config(t, d, q.dtype)
-        block_q = block_q or tuned.block_q
-        block_k = block_k or tuned.block_k
-        bwd_block_q = bwd_block_q or tuned.bwd_block_q
-        bwd_block_k = bwd_block_k or tuned.bwd_block_k
-    for name, blk in (("block_q", block_q), ("block_k", block_k),
-                      ("bwd_block_q", bwd_block_q),
-                      ("bwd_block_k", bwd_block_k)):
-        if blk % 128 or blk & (blk - 1):
-            raise ValueError(
-                f"{name} must be a power-of-two multiple of 128, got {blk}")
-    # Clamp blocks to the next power of two >= t so that max(bq, bk) is a
-    # common multiple of both and t_pad divides evenly into full q AND k
-    # blocks (a non-power-of-two clamp once left q rows >= block_q
-    # unwritten). Padded blocks are skipped by the kernels' block_live
-    # guards, so over-padding costs only grid overhead. All four block
-    # sizes share one t_pad, so the bwd blocks participate in the clamp.
-    pow2 = max(128, 1 << (t - 1).bit_length())
-    if mask.kind == "sliding_window" and mask.window >= t:
-        mask = CAUSAL       # every earlier row is inside the window
-    if mask.kind != "causal":
-        if t_real != t:
-            raise ValueError(f"a {mask.kind} mask takes no t_real, got "
-                             f"t={t}, t_real={t_real}")
-        clamp = window_block if mask.kind == "sliding_window" else mask_block
-        bq = bk = clamp(mask, t, min(block_q, block_k))
-        bbq = bbk = clamp(mask, t, min(bwd_block_q, bwd_block_k))
-    else:
-        bq = min(block_q, pow2)
-        bk = min(block_k, pow2)
-        bbq = min(bwd_block_q, pow2)
-        bbk = min(bwd_block_k, pow2)
-    t_pad = _round_up(t, max(bq, bk, bbq, bbk))
+    t_pad, bq, bk, bbq, bbk, mask = flash_blocks(
+        t, d, mask, t_real=t_real, block_q=block_q, block_k=block_k,
+        bwd_block_q=bwd_block_q, bwd_block_k=bwd_block_k)
 
     def prep(x, nh):
         x = x.reshape(b * nh, t, x.shape[-1])

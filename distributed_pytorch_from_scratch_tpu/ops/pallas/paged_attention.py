@@ -46,9 +46,8 @@ table, and `axis_index('cp') * local_span`; nothing in the kernel assumes
 the pool is whole.
 
 Block shapes default to a cached autotuner table keyed on
-`(page_size, head_dim, kv_dtype, backend)` — the flash `BlockConfig`
-scheme extended to the paged family (`get_paged_block_config` /
-`autotune_paged_block_config`, JSON cache shared machinery,
+`(page_size, head_dim, kv_dtype, backend)` (`get_paged_block_config` /
+`autotune_paged_block_config`, JSON cache in `block_cache.py`,
 `scripts/tune_flash_blocks.py --paged` sweeps it on hardware). The one
 knob that matters is `pages_per_block`: how many (scattered) pages each
 grid step fetches and scores together — more pages per step amortize the
@@ -352,10 +351,9 @@ def check_paged_attn_impl(impl: str, interpret: bool = False) -> str:
 
 # ------------------------------------------- block autotuner (paged family)
 #
-# The flash BlockConfig scheme extended to the paged kernels: a small
-# cached table keyed on the shape facts the best block depends on, JSON
-# persistence so one hardware sweep (scripts/tune_flash_blocks.py --paged
-# --write_cache) serves every later run.
+# A small cached table keyed on the shape facts the best block depends on,
+# with JSON persistence so one hardware sweep (scripts/tune_flash_blocks.py
+# --paged --write_cache) serves every later run.
 
 
 @dataclasses.dataclass(frozen=True)

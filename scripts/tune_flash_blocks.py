@@ -11,7 +11,9 @@ This harness times the CURRENT kernels at the shapes that matter:
 
 For each shape: forward-only and forward+backward wall time per (block_q,
 block_k) x (bwd_block_q, bwd_block_k) grid, plus the XLA dense attention as
-the floor. Prints a table and the best combo per shape. Run on hardware:
+the floor. Prints a table and the best combo per shape; nothing is written
+(a winner is adopted by editing `flash_attention.DEFAULT_BLOCK` or
+`flash_blocks`, with the reading beside it). Run on hardware:
 
     python scripts/tune_flash_blocks.py [--quick]
 
@@ -196,7 +198,6 @@ def sweep_shape(name, b, h, hkv, t, d, blocks, iters):
     if bwd_results:
         w = bwd_results[0]
         print(f"  BEST f+b: bwd {w[1]}x{w[2]} @ {w[0]:.3f} ms")
-    return best_fwd, bwd_results[0] if bwd_results else None
 
 
 def sweep_subtiles(bhs, edges, t=1024, d=64, dv=None, blocks=None,
@@ -457,11 +458,11 @@ def parse_args(argv=None):
                     help="fewer block combos / iters")
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--write_cache", action="store_true",
-                    help="record each shape's winning combo in the "
-                         "autotuner cache (FLASH_BLOCKS_CACHE or "
-                         "the tracked ops/pallas/flash_blocks.json) so every "
-                         "later flash_attention call on this backend uses "
-                         "it automatically (get_block_config)")
+                    help="--paged: record each shape's winner in the paged "
+                         "kernels' table (below). A flash sweep writes "
+                         "nothing: its winner is adopted by editing "
+                         "flash_attention.DEFAULT_BLOCK or flash_blocks, "
+                         "with the reading beside it")
     ap.add_argument("--paged", action="store_true",
                     help="sweep the PAGED-attention kernel instead "
                          "(ops/pallas/paged_attention.py): pages_per_block "
@@ -535,25 +536,12 @@ def main():
     sizes = [256, 512, 1024] if args.quick else [128, 256, 512, 1024, 2048]
     blocks = list(itertools.product(sizes, sizes))
 
-    # NOTE cache keys are (t_pow2, head_dim, dtype, backend) — the gqa and
-    # reference shapes share one. The flagship (reference 45m) sweeps LAST
-    # so its entry is the one that persists.
     shapes = [("gqa 4x", 32, 8, 2, 1000, 64, args.iters),
               ("long context 8k", 2, 8, 8, 8192, 64,
                max(5, args.iters // 4)),
               ("reference 45m", 32, 8, 8, 1000, 64, args.iters)]
     for name, b, h, hkv, t, d, iters in shapes:
-        best_fwd, best_bwd = sweep_shape(name, b, h, hkv, t, d, blocks,
-                                         iters)
-        if args.write_cache and best_fwd:
-            from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (  # noqa: E501
-                BlockConfig, save_block_cache, set_block_config)
-            bb = best_bwd or (None, best_fwd[1], best_fwd[2])
-            set_block_config(t, d, jnp.bfloat16,
-                             BlockConfig(best_fwd[1], best_fwd[2],
-                                         bb[1], bb[2]))
-            path = save_block_cache()
-            print(f"  cached {name} -> {path}")
+        sweep_shape(name, b, h, hkv, t, d, blocks, iters)
 
 
 if __name__ == "__main__":

@@ -18,8 +18,10 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu import (MeshConfig, ModelConfig,
                                                   Transformer, make_mesh)
+from distributed_pytorch_from_scratch_tpu.obs.attribution import (
+    flash_tile_stats)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
-    causal_attention_xla)
+    CAUSAL, block_diffusion, causal_attention_xla, sliding_window)
 from distributed_pytorch_from_scratch_tpu.ops.pallas import (
     flash_attention as fa_mod)
 
@@ -307,78 +309,97 @@ def test_t_real_parity_reference_shape():
     assert jnp.abs(bucketed[:, :, tr:]).max() == 0.0
 
 
-# ---- block-shape autotuner table + cache ----
+# ---- the blocks a call runs with (flash_blocks) ----
+
+_B = 1024       # flash_attention.DEFAULT_BLOCK, spelt out: the cases pin it
 
 
-@pytest.fixture
-def block_table():
-    """Snapshot/restore the module-global tuned-block table around a test."""
-    from distributed_pytorch_from_scratch_tpu.ops.pallas import (
-        flash_attention as fa)
-
-    saved, saved_loaded = dict(fa._BLOCK_TABLE), fa._cache_loaded
-    fa._cache_loaded = True  # keep tests off the real user cache file
-    yield fa
-    fa._BLOCK_TABLE.clear()
-    fa._BLOCK_TABLE.update(saved)
-    fa._cache_loaded = saved_loaded
-
-
-def test_block_config_defaults_and_override(block_table):
-    fa = block_table
-    cfg = fa.get_block_config(333, 64, jnp.float32)
-    assert cfg == fa.BlockConfig()  # no entry -> the swept defaults
-    fa.set_block_config(333, 64, jnp.float32, fa.BlockConfig(128, 256,
-                                                             128, 128))
-    # t buckets by the padded pow2: 333 and 500 share the 512 entry
-    assert fa.get_block_config(500, 64, jnp.float32).block_k == 256
-    assert fa.get_block_config(600, 64, jnp.float32) == fa.BlockConfig()
-
-
-def test_block_cache_roundtrip(block_table, tmp_path):
-    fa = block_table
-    path = str(tmp_path / "blocks.json")
-    fa.set_block_config(256, 32, jnp.bfloat16, fa.BlockConfig(256, 128,
-                                                              128, 128))
-    fa.save_block_cache(path)
-    fa._BLOCK_TABLE.clear()
-    assert fa.get_block_config(256, 32, jnp.bfloat16) == fa.BlockConfig()
-    assert fa.load_block_cache(path) >= 1
-    assert fa.get_block_config(256, 32, jnp.bfloat16).block_q == 256
-    # a garbled cache is ignored, not fatal
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert fa.load_block_cache(str(bad)) == 0
-
-
-def test_tuned_blocks_drive_the_kernel(block_table):
-    """flash_attention with no explicit blocks must consult the table —
-    and stay correct with a deliberately odd tuned entry."""
-    fa = block_table
-    b, h, t, d = 1, 2, 300, 32
-    kq, kk, kv = jax.random.split(jax.random.key(3), 3)
-    q = jax.random.normal(kq, (b, h, t, d))
-    k = jax.random.normal(kk, (b, h, t, d))
-    v = jax.random.normal(kv, (b, h, t, d))
-    fa.set_block_config(t, d, q.dtype, fa.BlockConfig(128, 256, 128, 128))
-    out = flash_attention(q, k, v)  # blocks=None -> table entry
-    ref = causal_attention_xla(q, k, v)
-    assert jnp.abs(out - ref).max() < 1e-5
-
-
-def test_autotune_caches_winner(block_table, tmp_path, monkeypatch):
-    """autotune_block_config sweeps, records the winner in the table, and
-    persists it through the JSON cache when asked."""
-    fa = block_table
-    monkeypatch.setenv("FLASH_BLOCKS_CACHE", str(tmp_path / "fb.json"))
-    best = fa.autotune_block_config(128, 16, jnp.float32, batch_heads=2,
-                                    sweep=(128,), iters=1, warmup=0,
-                                    write_cache=True, interpret=True)
-    assert best == fa.BlockConfig(128, 128, 128, 128)
-    assert fa.get_block_config(128, 16, jnp.float32) == best
-    fa._BLOCK_TABLE.clear()
-    assert fa.load_block_cache() >= 1  # reads FLASH_BLOCKS_CACHE
-    assert fa.get_block_config(128, 16, jnp.float32) == best
+@pytest.mark.parametrize("t,d,mask,asked,want", [
+    # the eleven cells' attention shapes (benchmark/configs, benchmark/
+    # workloads), pinned to the blocks each has run with since its PR
+    pytest.param(1024, 64, CAUSAL, {}, (1024, _B, _B, _B, _B),
+                 id="gpt2-medium.train-b12-t1024"),
+    pytest.param(1024, 64, CAUSAL, {}, (1024, _B, _B, _B, _B),
+                 id="gpt2-large.train-dp2-tp2"),
+    pytest.param(1024, 64, CAUSAL, {}, (1024, _B, _B, _B, _B),
+                 id="gpt2-medium.train-ckpt-every40"),
+    # latent attention: q/k 192 wide over a v of 128; asked by q's width
+    pytest.param(4096, 192, CAUSAL, {}, (4096, _B, _B, _B, _B),
+                 id="joyai-llm-flash.train-ep16share-b4-t4096"),
+    pytest.param(4096, 192, CAUSAL, {}, (4096, _B, _B, _B, _B),
+                 id="xing4-29b-a4b.train-ep8share-b1-t4096"),
+    pytest.param(4096, 192, CAUSAL, {}, (4096, _B, _B, _B, _B),
+                 id="ling-3-flash.train-ep64share-b1-t4096"),
+    pytest.param(8192, 256, CAUSAL, {}, (8192, _B, _B, _B, _B),
+                 id="qwen3-next-80b-a3b.train-ep16share-b2-t8192"),
+    pytest.param(8192, 64, CAUSAL, {}, (8192, _B, _B, _B, _B),
+                 id="lfm2-8b-a1b.train-ep4share-b2-t8192"),
+    # 2 x 4096 rows [xt ; x0] under the block-diffusion mask, blocks of 4
+    pytest.param(8192, 128, block_diffusion(4, 4096), {},
+                 (8192, _B, _B, _B, _B),
+                 id="sdar-30b-a3b.train-ep8share-b2-t4096"),
+    pytest.param(8192, 128, sliding_window(2048), {}, (8192, _B, _B, _B, _B),
+                 id="trinity-mini.train-epshare-b2-t8192-window"),
+    pytest.param(8192, 128, CAUSAL, {}, (8192, _B, _B, _B, _B),
+                 id="trinity-mini.train-epshare-b2-t8192-global"),
+    pytest.param(16384, 128, sliding_window(4096), {},
+                 (16384, _B, _B, _B, _B),
+                 id="smallthinker-21b-a3b.train-ep4share-b1-t16384-window"),
+    pytest.param(16384, 128, CAUSAL, {}, (16384, _B, _B, _B, _B),
+                 id="smallthinker-21b-a3b.train-ep4share-b1-t16384-global"),
+    # the rule's edges
+    pytest.param(333, 64, CAUSAL, {}, (512, 512, 512, 512, 512),
+                 id="t-not-a-power-of-two-pads-to-the-clamped-block"),
+    pytest.param(1000, 64, CAUSAL, {"t_real": 900}, (1024, _B, _B, _B, _B),
+                 id="causal-takes-a-t_real"),
+    pytest.param(512, 64, sliding_window(512), {}, (512, 512, 512, 512, 512),
+                 id="a-window-that-covers-t-is-causal"),
+    pytest.param(700, 64, CAUSAL, {"block_q": 128, "block_k": 256},
+                 (1024, 128, 256, 1024, 1024),
+                 id="explicit-forward-blocks-share-the-backwards-t_pad"),
+    pytest.param(2048, 64, CAUSAL, {"bwd_block_q": 512, "bwd_block_k": 256},
+                 (2048, _B, _B, 512, 256), id="explicit-backward-blocks"),
+    pytest.param(8192, 128, sliding_window(2048),
+                 {"block_q": 512, "block_k": 2048}, (8192, 512, 512, _B, _B),
+                 id="a-masks-blocks-are-square-the-smaller-asked"),
+    pytest.param(1024, 128, block_diffusion(4, 512), {},
+                 (1024, 512, 512, 512, 512),
+                 id="block-diffusion-clamps-to-the-masks-half"),
+    pytest.param(1024, 64, CAUSAL, {"block_q": 192}, "block_q must be a power",
+                 id="a-block-not-a-multiple-of-128-raises"),
+    pytest.param(1024, 64, CAUSAL, {"bwd_block_k": 384},
+                 "bwd_block_k must be a power",
+                 id="a-block-not-a-power-of-two-raises"),
+    pytest.param(8192, 128, sliding_window(2048), {"t_real": 8000},
+                 "takes no t_real", id="a-mask-takes-no-t_real"),
+    pytest.param(384, 64, sliding_window(128), {}, "multiple of the grid",
+                 id="a-window-over-rows-its-block-does-not-divide-raises"),
+])
+def test_flash_blocks_is_the_one_rule(t, d, mask, asked, want):
+    """`flash_blocks` alone says the (t_pad, blocks) a call runs with: host
+    arithmetic on its arguments, no table, file or environment behind it.
+    `flash_tile_stats` reports the same for either direction (it asks; a
+    mirror of the clamp regrown there would part from the kernel's t_pad at
+    the explicit-forward case)."""
+    assert fa_mod.DEFAULT_BLOCK == _B
+    if isinstance(want, str):
+        with pytest.raises(ValueError, match=want):
+            fa_mod.flash_blocks(t, d, mask, **asked)
+        return
+    *run, folded = fa_mod.flash_blocks(t, d, mask, **asked)
+    assert tuple(run) == want
+    covered = mask.kind == "sliding_window" and mask.window >= t
+    assert folded == (CAUSAL if covered else mask)
+    for backward, names in ((False, ("block_q", "block_k")),
+                            (True, ("bwd_block_q", "bwd_block_k"))):
+        # the stats take one direction's blocks; the other keeps its default
+        own = {n: v for n, v in asked.items() if n in names + ("t_real",)}
+        run = fa_mod.flash_blocks(t, d, mask, **own)
+        stats = flash_tile_stats(
+            t, own.get(names[0]), own.get(names[1]), own.get("t_real"),
+            head_dim=d, mask=mask, backward=backward)
+        assert (stats["t_pad"], stats["block_q"], stats["block_k"]) == (
+            run[0], *(run[3:5] if backward else run[1:3]))
 
 
 # ---- model-level sequence bucketing (attn_t_real) ----
@@ -607,14 +628,12 @@ def test_subtile_plan_at_the_benchmark_shape():
 
 
 def test_subtile_plan_at_the_latent_benchmark_shape():
-    """t = 4096, q/k 192 against v 128, blocks 1024 (the table's entry), K
+    """t = 4096, q/k 192 against v 128, blocks 1024 (`DEFAULT_BLOCK`), K
     and V resident: a head walks 72 of 128 256 x 512 sub-tiles, 16 of them
     masked (it was 272 of 512 at 128 x 256). Each of the four query blocks runs the one-tile plan on its
     diagonal tile, 6 sub-tiles, and loops over the tiles left of it with a
     body of two unmasked sub-tiles a sub-row; `flash_tile_stats` reports the
     same walk."""
-    from distributed_pytorch_from_scratch_tpu.obs.attribution import (
-        flash_tile_stats)
     stats = fa_mod.causal_plan_stats(4096, 1024, 1024, 4096, 192)
     assert stats == {"computed_unmasked": 56, "computed_masked": 16,
                      "skipped": 56, "work_elems": 72 * 256 * 512,
@@ -919,7 +938,7 @@ def test_the_row_fits_by_bytes_alone(monkeypatch):
     assert _fwd_grid(q, k, v, **kw) == ((1, 4, 1), (1, 1024, 24))
     monkeypatch.setattr(fa_mod, "KV_ROW_VMEM_BYTES", need - 1)
     assert _fwd_grid(q, k, v, **kw) == ((1, 4, 4), (1, 256, 24))
-    # one key block a head is neither: the grid the table's winner has had
+    # one key block a head is neither: the grid `DEFAULT_BLOCK` has had
     assert _fwd_grid(q, k, v) == ((1, 1, 1), (1, 1024, 24))
     # the shipped budget is what a chip run has read beside the gridded walk
     # (PR 52), not what Mosaic's default scoped limit compiles: 32 MiB.
@@ -1005,7 +1024,7 @@ def _no_row_walk(monkeypatch, once=False):
 
 
 def _bwd_call_jaxpr(bh, bhkv, t, d, dv, interpret):
-    """`_bwd_call` traced at bf16 shapes alone, the table's blocks."""
+    """`_bwd_call` traced at bf16 shapes alone, `DEFAULT_BLOCK`'s blocks."""
     sds = jax.ShapeDtypeStruct
     args = (sds((bh, t, d), jnp.bfloat16), sds((bhkv, t, d), jnp.bfloat16),
             sds((bhkv, t, dv), jnp.bfloat16), sds((bh, t, dv), jnp.bfloat16),

@@ -444,7 +444,7 @@ def test_gate_fails_when_decode_bytes_grow():
 
 def test_paged_block_config_cache_roundtrip(tmp_path, monkeypatch):
     """The autotuner table persists and reloads through the JSON cache
-    (the flash BlockConfig convention, paged family): set -> save ->
+    (`ops/pallas/block_cache.py`): set -> save ->
     clear -> load -> same config; garbled files are ignored."""
     path = str(tmp_path / "paged_blocks.json")
     monkeypatch.setenv("PAGED_BLOCKS_CACHE", path)

@@ -202,15 +202,15 @@ def test_cell_2_backward_body_exchanges_the_dp_gradients_under_dots(
 def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
         topo, described_tpu, t, d, dv, t_real, asks):
     """Mosaic takes the multi-block forward that keeps a head's K and V in
-    VMEM (PR 34) at the table's blocks: one kernel an attention, grid (b*h,
-    query blocks, 1). Rows of up to 8 MiB compile inside its default scoped
-    VMEM and their call names no limit; the two cells whose row is 16 MiB
-    (PR 52) carry the limit `_fwd_call` asks for, 40 MiB, and a row of 32
-    MiB, the budget, 56."""
+    VMEM (PR 34) at `flash_blocks`' blocks: one kernel an attention, grid
+    (b*h, query blocks, 1). Rows of up to 8 MiB compile inside its default
+    scoped VMEM and their call names no limit; the two cells whose row is 16
+    MiB (PR 52) carry the limit `_fwd_call` asks for, 40 MiB, and a row of
+    32 MiB, the budget, 56."""
     from jax.sharding import SingleDeviceSharding
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         flash_attention as fa)
-    blocks = fa.get_block_config(t, d, jnp.bfloat16)
+    _, block_q, block_k, *_ = fa.flash_blocks(t, d, t_real=t_real)
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda w: jax.ShapeDtypeStruct((8, t, w), jnp.bfloat16,
                                          sharding=chip)
@@ -218,8 +218,8 @@ def test_flash_forward_with_a_resident_key_row_fits_mosaics_vmem(
     assert resident <= fa.KV_ROW_VMEM_BYTES
     assert asks == (resident > fa.KV_ROW_SCOPED_BYTES)
     text = jax.jit(lambda q, k, v: fa._fwd_call(
-        q, k, v, t_real=t_real, block_q=blocks.block_q,
-        block_k=blocks.block_k, hq=1, hkv=1, interpret=False)).lower(
+        q, k, v, t_real=t_real, block_q=block_q, block_k=block_k, hq=1,
+        hkv=1, interpret=False)).lower(
             arg(d), arg(d), arg(dv)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     call = next(line for line in text.splitlines()
@@ -267,8 +267,9 @@ def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
         topo, described_tpu, t, d, dv, group, t_real, window, buffers,
         names):
     """Mosaic takes the multi-block backward that keeps the whole head in
-    VMEM (PR 40) at the table's blocks with the scoped VMEM `_bwd_row_call`
-    asks for: double-buffered at the shapes `BWD_ROW_VMEM_BYTES` admits,
+    VMEM (PR 40) at `flash_blocks`' blocks with the scoped VMEM
+    `_bwd_row_call` asks for: double-buffered at the shapes
+    `BWD_ROW_VMEM_BYTES` admits,
     and with its nine whole-row blocks kept once (PR 56) at the shapes only
     `BWD_ROW_ONCE_VMEM_BYTES` does, whose call says so block by block and
     asks for the bytes as taken and the body's room; a head over both
@@ -278,7 +279,8 @@ def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
         CAUSAL, sliding_window)
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         flash_attention as fa)
-    blocks = fa.get_block_config(t, d, jnp.bfloat16)
+    mask = sliding_window(window) if window else CAUSAL
+    *_, block_q, block_k, mask = fa.flash_blocks(t, d, mask)
     chip = SingleDeviceSharding(topo.devices[0])
     arg = lambda rows, w, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         (rows, t, w), dtype, sharding=chip)
@@ -288,9 +290,8 @@ def test_flash_backward_with_a_resident_head_fits_the_vmem_it_asks_for(
     assert buffers == (2 if twice <= fa.BWD_ROW_VMEM_BYTES else
                        1 if once <= fa.BWD_ROW_ONCE_VMEM_BYTES else 0)
     lowered = jax.jit(lambda *a: fa._bwd_call(
-        *a, t_real=t_real, block_q=blocks.bwd_block_q,
-        block_k=blocks.bwd_block_k, hq=group, hkv=1, interpret=False,
-        mask=sliding_window(window) if window else CAUSAL)).lower(
+        *a, t_real=t_real, block_q=block_q, block_k=block_k, hq=group,
+        hkv=1, interpret=False, mask=mask)).lower(
             arg(2 * group, d), arg(2, d), arg(2, dv), arg(2 * group, dv),
             arg(2 * group, 1, jnp.float32), arg(2 * group, dv))
     text = lowered.compile().as_text()
