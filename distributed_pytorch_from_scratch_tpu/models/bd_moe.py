@@ -154,13 +154,22 @@ class BlockDiffusionMoETransformer(DecoderStack):
         activations `[gate | up]`, their product and both cotangents. At a
         held share of 1/8 the chunk is one mean share, an eighth of all
         pairs, 1 row a row (six shares, 6 rows a row, until PR 50, when
-        the chunk was what sized the step)."""
+        the chunk was what sized the step). The last term, 12.91 d a row,
+        is what the chip counts beyond those and is SET FROM ITS READINGS
+        (the flash backward's operands by head are most of it; not told
+        apart): cell 8 on a v5e counts 13.461 GiB at rung `true` and 13.547
+        at `flash`, the rung `auto` picks (the 0.76 GiB of kept outputs
+        cost it 0.09: the peak is not where the stacks are longest), for
+        steps this makes 13.40 and 14.16, so that both sit inside -1% /
+        +5% (ledger, PR 61; my chip run, PR 62; without the term `true`
+        made 12.59)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.bd_moe.moe_intermediate_size / self.tp_size
         attn = (5 * self.cfg.num_heads * self.head_dim + 6 * self.kv_dim
                 - 2 * self.d) / self.tp_size
-        return attn + chunk_rows * (6 * self.d + 5 * f)
+        return (attn + chunk_rows * (6 * self.d + 5 * f)
+                + 12.91 * self.d / self.tp_size)
 
     # ---- sub-module definitions ----
 

@@ -215,12 +215,18 @@ class ConvMoETransformer(DecoderStack):
         out, and the hidden activations `[gate | up]`, their product and
         both cotangents). At a held share of 1/4 the chunk is ALL pairs
         (under a sixth it is one mean share: `parallel/moe.CHUNK_SHARES`),
-        so this is what sizes the step. An attention layer holds less."""
+        so this is what sizes the step. An attention layer holds less.
+        The last term takes 18.96 d a token back off and is SET FROM THE
+        CHIP'S READING (the skeleton's 3.4 f a token is the one dense
+        layer's MLP, which no expert layer holds beside its chunk): cell 7
+        on a v5e counts 10.899 GiB at rung `true` and 11.609 at `dots`, the
+        rung `auto` picks, for steps this makes 11.04 and 11.64 (ledger, PR
+        61; my chip runs, PR 62; without the term `true` made 12.23)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.conv_moe.moe_intermediate_size / self.tp_size
         return 12.0 * self.d / self.tp_size + chunk_rows * (
-            2 * self.d + 5 * f)
+            2 * self.d + 5 * f) - 18.96 * self.d / self.tp_size
 
     # ---- sub-module definitions ----
 

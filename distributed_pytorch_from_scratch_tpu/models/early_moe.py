@@ -164,13 +164,19 @@ class EarlyRouterMoETransformer(DecoderStack):
         held share of a quarter, top_k rows a token): rows in and out with
         their cotangents, the outputs and the scatter's operand in float32
         (twice an element), and the hidden activations `[gate | up]`, their
-        product and both cotangents."""
+        product and both cotangents. The last term takes 16.47 d a token
+        back off and is SET FROM THE CHIP'S READING (a chunk of ALL pairs
+        is walked in pieces the count above holds whole): cell 10 on a v5e
+        counts 13.957 GiB at rung `true` and 14.459 at `flash`, the rung
+        `auto` picks, for steps this makes 14.14 and 14.58 (ledger, PR 61;
+        my chip run, PR 62; without the term `true` made 15.42)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.early_moe.moe_ffn_hidden_size / self.tp_size
         attn = (5 * self.cfg.num_heads * self.head_dim + 6 * self.kv_dim
                 - 2 * self.d) / self.tp_size
-        return attn + chunk_rows * (6 * self.d + 5 * f)
+        return (attn + chunk_rows * (6 * self.d + 5 * f)
+                - 16.47 * self.d / self.tp_size)
 
     # ---- sub-module definitions ----
 

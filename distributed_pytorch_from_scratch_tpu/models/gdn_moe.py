@@ -120,6 +120,17 @@ class GdnMoETransformer(DecoderStack):
     # ---- facts for training/memory.py ----
 
     @property
+    def head_dim(self) -> int:       # the full-attention layers' heads
+        return self.cfg.gdn_moe.head_dim
+
+    @property
+    def tagged_layers(self) -> Dict[str, float]:
+        """The gated attention's `q_proj` is `[q | gate]` of one projection
+        (parallel/gated_attention.py): twice the heads' width a layer."""
+        tagged = super().tagged_layers
+        return {**tagged, "q_proj": 2 * tagged["q_proj"]}
+
+    @property
     def layer_extra_elems_per_token(self) -> float:
         """What a Gated DeltaNet layer's backward holds at its fullest,
         beside the d-wide tensors the dense skeleton counts, in elements of
@@ -131,10 +142,12 @@ class GdnMoETransformer(DecoderStack):
         makes the rule's inputs again (`GatedDeltaNet.apply` keeps it apart
         from the rule's own backward, which runs a sequence at a time and
         holds 2 GB whatever the batch); the full-attention layer holds
-        less. Two readings: the benchmark's cell on a v5e counted 13.68
-        GiB for a step this made 13.42 (PERF.md section 5, PR 35) and, with
-        the chunk one mean share, counts 14.11 for a step this makes 12.88
-        (section 7, PR 50)."""
+        less. The last term, 22.63 d a token, is what the chip counts
+        beyond those and is SET FROM ITS READING (the rule's own backward,
+        a sequence at a time, is most of it; not told apart): cell 6 on a
+        v5e counts 14.110 GiB at rung `true` and 14.110 at `flash`, the
+        rung `auto` picks, for steps this makes 14.29 and 14.42 (ledger, PR
+        61; my chip runs, PR 62; without the term `true` made 12.88)."""
         gm, gdn, moe = self.cfg.gdn_moe, self._mods["gdn"], self._mods["moe"]
         hk = gm.linear_num_key_heads / self.tp_size
         hv = gm.linear_num_value_heads / self.tp_size
@@ -143,7 +156,8 @@ class GdnMoETransformer(DecoderStack):
                        + hv * (2 * dk + 2 * dv))
         chunk_rows = moe.chunk_share * moe.top_k
         return rule_inputs + chunk_rows * (
-            2 * self.d + 3 * gm.moe_intermediate_size / self.tp_size)
+            2 * self.d + 3 * gm.moe_intermediate_size / self.tp_size
+            ) + 22.63 * self.d / self.tp_size
 
     # ---- sub-module definitions ----
 

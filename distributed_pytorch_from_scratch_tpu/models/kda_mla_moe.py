@@ -185,7 +185,16 @@ class KdaMlaMoETransformer(MultiTokenPrediction, DecoderStack):
         the third family's rule holds one scalar) and its cotangent, and the
         output gate; and one chunk of the expert dispatch
         (`SharedRoutedFFN.chunk_share` of a token's pairs). The latent layer
-        holds less."""
+        holds less. The last term takes 41.93 d a token back off and is
+        SET FROM THE CHIP'S READING, which is LESS than the dense
+        skeleton's count alone at this shape (state, gradients and the
+        layers' weights in bfloat16 are 12.67 of the chip's 12.77 GiB:
+        what the estimate calls `cast` is not all held at once here, and
+        since PR 60 the rule's kernels make their operands in VMEM; not
+        told apart, PERF.md section 7): cell 12 on a v5e counts 12.774 GiB
+        at rung `true` and 12.840 at `flash`, the rung `auto` picks, for
+        steps this makes 12.94 and 13.07 (ledger, PR 61; my chip run, PR
+        62; without the term `true` made 13.76)."""
         km, moe = self.cfg.kda_mla_moe, self._mods["moe"]
         wide = self.num_local_heads * km.head_dim
         rule_inputs = (2 * 4 * wide          # projections and cotangents
@@ -195,7 +204,8 @@ class KdaMlaMoETransformer(MultiTokenPrediction, DecoderStack):
                        + wide)               # the output gate
         chunk_rows = moe.chunk_share * moe.top_k
         return rule_inputs + chunk_rows * (
-            2 * self.d + 3 * km.moe_intermediate_size / self.tp_size)
+            2 * self.d + 3 * km.moe_intermediate_size / self.tp_size
+            ) - 41.93 * self.d / self.tp_size
 
     # ---- sub-module definitions ----
 
@@ -229,6 +239,10 @@ class KdaMlaMoETransformer(MultiTokenPrediction, DecoderStack):
         }
 
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.cfg.kda_mla_moe.v_head_dim
 
     @property
     def rotary_dim(self) -> int:     # the latent layers read it

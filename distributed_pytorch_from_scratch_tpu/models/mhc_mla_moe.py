@@ -65,17 +65,22 @@ class HyperLatentMoETransformer(LatentMoETransformer):
         # (None without the facts: `_check_facts` then says what is missing)
         return _mixer(self.cfg) if self.cfg.latent_moe.hyper else None
 
+    held_beyond_d = 0.0         # the streams' term below stands in for it
+
     @property
     def layer_extra_elems_per_token(self) -> float:
-        """`mla_moe`'s tensors, and of the streams what one mixer's
-        backward holds at once beside the kept layer input: the streams
-        before and after the joint and their two cotangents (4 n d in the
-        compute dtype) and the float32 copy the maps' product reads (2 n d
-        of them). The kept layer inputs themselves are the stacks'
-        (`residual_streams`). Held to the chip by the benchmark's cell
-        (PERF.md section 5, PR 57)."""
+        """`mla_moe`'s attention and chunk, and of the streams what the
+        chip counts of one mixer's backward beside the kept layer input:
+        2.02 n d a token (the streams before and after a joint; the
+        kernels of ops/pallas/stream_mixer.py, PR 58, hold no float32 copy
+        and no second cotangent in HBM: 6 n d read 4.5% over). SET FROM THE
+        CHIP'S READING in place of `mla_moe`'s `held_beyond_d`: cell 11
+        counts 13.700 GiB at rung `true` and 14.011 at `flash`, the rung
+        `auto` picks, for steps this makes 13.88 and 14.18 (ledger, PR 61;
+        my chip runs, PR 62). The kept layer inputs themselves are the
+        stacks' (`residual_streams`)."""
         return (super().layer_extra_elems_per_token
-                + 6.0 * self.residual_streams * self.d)
+                + 2.02 * self.residual_streams * self.d)
 
     @staticmethod
     def param_counts(cfg: ModelConfig) -> Dict[str, int]:

@@ -233,6 +233,8 @@ class LatentMoETransformer(MultiTokenPrediction, DecoderStack):
         return (self.cfg.num_layers
                 + self.cfg.latent_moe.num_nextn_predict_layers)
 
+    held_beyond_d = 18.07       # set from cell 5's chip reading (below)
+
     @property
     def layer_extra_elems_per_token(self) -> float:
         """What a layer holds beside the d-wide tensors the dense skeleton
@@ -240,16 +242,20 @@ class LatentMoETransformer(MultiTokenPrediction, DecoderStack):
         the narrow one, materialised per head (q/k alone are three times d
         here), and one chunk of the expert dispatch (its rows in and out
         and the experts' hidden activations; `SharedRoutedFFN.chunk_share`
-        of a token's pairs). Two readings: the benchmark's cell on a v5e
-        counted 14.38 GiB for a step this made 13.99 (PERF.md section 5, PR
-        33) and, with the chunk one mean share, counts 14.23 for a step
-        this makes 13.28 (section 7, PR 50)."""
+        of a token's pairs); and `held_beyond_d` more d-wide tensors a
+        token, which is what the chip counts beyond those and is SET FROM
+        ITS READING (the shared expert's hidden activations, the latents'
+        up-projections and the module's layer are among it; not told
+        apart): cell 5 on a v5e counts 14.229 GiB at rung `true`, the rung
+        `auto` picks there, for a step this makes 14.41 (ledger, PR 61; my
+        chip run, PR 62; without the term it made 13.28)."""
         lm, moe = self.cfg.latent_moe, self._mods["moe"]
         attention = self.num_local_heads * 2.0 * (lm.qk_head_dim
                                                   + lm.v_head_dim)
         chunk_rows = moe.chunk_share * moe.top_k
         return attention + chunk_rows * (
-            2 * self.d + 3 * lm.moe_intermediate_size / self.tp_size)
+            2 * self.d + 3 * lm.moe_intermediate_size / self.tp_size
+            ) + self.held_beyond_d * self.d / self.tp_size
 
     # ---- sub-module definitions ----
 
@@ -285,6 +291,10 @@ class LatentMoETransformer(MultiTokenPrediction, DecoderStack):
         }
 
     # ---- what differs inside the forward (per-shard, inside shard_map) ----
+
+    @property
+    def v_head_dim(self) -> int:
+        return self.cfg.latent_moe.v_head_dim
 
     @property
     def rotary_dim(self) -> int:

@@ -255,6 +255,17 @@ REMAT_LADDER = (
     ("dots", ("q_proj", "k_proj", "v_proj")),
 )
 REMAT_RUNGS = tuple(name for name, _ in REMAT_LADDER)
+# Which of a layer's modules makes each name: a layer tags a name where its
+# parameters hold one of them (the stack's own attention is `wq` ... `wo`;
+# a family's whole-attention modules are `attn`, gated, and `mla`, latent,
+# whose q, k and v carry no name). `attn_proj` is tagged past any mixer's
+# reduce, so by every layer. `DecoderStack.tagged_layers` counts by it.
+LADDER_MADE_BY = {
+    "ffn_fc": ("fc",), "ffn_gate": ("gate_proj",), "ffn_up": ("up_proj",),
+    "flash_out": ("wo", "attn", "mla"), "flash_lse": ("wo", "attn", "mla"),
+    "q_proj": ("wq", "attn"), "k_proj": ("wk", "attn"),
+    "v_proj": ("wv", "attn"),
+}
 
 
 def remat_rung(remat) -> int:
@@ -274,7 +285,7 @@ def validate_remat(remat) -> None:
         remat_rung(remat)
 
 
-def remat_wrap(layer_fn, remat, static_argnums=()):
+def remat_wrap(layer_fn, remat, static_argnums=(), looped: bool = True):
     """Apply a per-layer remat policy; shared by every model family.
 
     `remat` is False (keep everything autodiff saves) or a rung of
@@ -282,7 +293,8 @@ def remat_wrap(layer_fn, remat, static_argnums=()):
     rung's names and recomputes the rest. Rung 0 passes no policy, so it is
     the program `remat=True` has always been. 'auto' is resolved by the
     caller (`resolve_remat`) before it gets here: the rung depends on the
-    shapes the layer is traced with.
+    shapes the layer is traced with. `looped`: does the layer run in a scan
+    of several layers.
     """
     if remat is False:
         return layer_fn
@@ -290,14 +302,18 @@ def remat_wrap(layer_fn, remat, static_argnums=()):
     if rung == 0:
         return jax.checkpoint(layer_fn, static_argnums=static_argnums)
     names = [n for _, ns in REMAT_LADDER[:rung + 1] for n in ns]
-    # prevent_cse=False: every layer_fn runs inside a lax.scan, whose
-    # forward and backward are separate loops, so there is nothing to CSE
-    # the recomputation with. The barrier that guards against it is a
-    # `reduce_precision` pass over each kept tensor (1.9 ms a step for
-    # `flash_out` alone on GPT-2 medium) and pins the kernel's padded
-    # layout on the stack.
+    # prevent_cse=False where the layer runs inside a lax.scan of several
+    # layers: its forward and backward are separate loops, so there is
+    # nothing to CSE the recomputation with. The barrier that guards
+    # against it is a `reduce_precision` pass over each kept tensor (1.9 ms
+    # a step for `flash_out` alone on GPT-2 medium) and pins the kernel's
+    # padded layout on the stack. A scan of ONE layer is no loop once XLA
+    # has simplified it: forward and backward then share a computation,
+    # the recompute is CSE'd with the forward and the layer keeps
+    # everything (cell 7 at `dots`: +1.75 GiB on the chip for 0.6 of
+    # named stacks, PERF.md section 6, PR 62), so there the barrier stays.
     return jax.checkpoint(
-        layer_fn, static_argnums=static_argnums, prevent_cse=False,
+        layer_fn, static_argnums=static_argnums, prevent_cse=not looped,
         policy=jax.checkpoint_policies.save_only_these_names(*names))
 
 
@@ -751,6 +767,24 @@ class DecoderStack:
     def stacked_layers(self) -> int:
         """Layers whose input the backward keeps, over all segments."""
         return self.cfg.num_layers
+
+    @property
+    def tagged_layers(self) -> Dict[str, int]:
+        """Name of REMAT_LADDER -> the stacked layers that tag it, over all
+        segments (`LADDER_MADE_BY`): what a rung's stack of that name is
+        long. A drawn family tags the MLP's names in its dense layers only
+        and the flash names in its attention layers only."""
+        held = [(layers, self._segment_mods(names))
+                for _, layers, names in self._segments]
+        return {name: sum(layers for layers, mods in held
+                          if any(m in mods for m in makers))
+                for name, makers in LADDER_MADE_BY.items()}
+
+    @property
+    def v_head_dim(self) -> int:
+        """The width of a head's attention OUTPUT (`flash_out`): the
+        heads', unless the family's v is of another width than q and k."""
+        return self.head_dim
 
     layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
     head_rows_share = 1.0       # the part of a batch's rows the head reads
@@ -1398,14 +1432,16 @@ class DecoderStack:
             # X_0: the embedding's row, n times
             x = jnp.broadcast_to(x, (self.residual_streams, *x.shape))
 
-        layer_fn = remat_wrap(
-            self._layer_body, resolve_remat(self, params, input_ids.shape),
-            static_argnums=(4, 6))
+        rung = resolve_remat(self, params, input_ids.shape)
 
         def stage_fn(z, layers, *mb, live=None, kind=None):
             # one scan over `layers`, layers of one `kind`; `mb` is
             # (*layer_pos, position_ids), whole or, under the pipeline, one
             # microbatch's rows
+            layer_fn = remat_wrap(
+                self._layer_body, rung, static_argnums=(4, 6),
+                looped=jax.tree.leaves(layers)[0].shape[0] > 1)
+
             def body(carry, lp):
                 return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind)
             z, auxs = lax.scan(body, z, layers)

@@ -181,13 +181,18 @@ class SlidingWindowMoETransformer(DecoderStack):
         up]`, their product and both cotangents, beside the shared
         expert's. At a held share of 1/8 the chunk is one mean share, an
         eighth of all pairs, 1 row a token (six shares, 6 rows a token,
-        until PR 50, when the chunk was what sized the step)."""
+        until PR 50, when the chunk was what sized the step). The last
+        term takes 1.69 d a token back off and is SET FROM THE CHIP'S
+        READING: cell 9 on a v5e counts 14.695 GiB at rung `true`, the
+        rung `auto` picks there, for a step this makes 14.89 (ledger, PR
+        61; my chip run, PR 62; without the term it made 14.99)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.swa_moe.moe_intermediate_size / self.tp_size
         attn = (7 * self.cfg.num_heads * self.head_dim + 6 * self.kv_dim
                 ) / self.tp_size
-        return attn + (chunk_rows + moe.n_shared) * (6 * self.d + 5 * f)
+        return (attn + (chunk_rows + moe.n_shared) * (6 * self.d + 5 * f)
+                - 1.69 * self.d / self.tp_size)
 
     # ---- sub-module definitions ----
 

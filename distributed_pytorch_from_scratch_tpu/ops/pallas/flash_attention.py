@@ -1770,15 +1770,19 @@ def _flash_with_t_fwd(q, k, v, t_real, block_q, block_k,
     # Name the kernel outputs so a remat policy can keep them: from the
     # 'flash' rung of models/transformer.REMAT_LADDER the backward finds
     # o/lse saved; below it, it re-runs the forward kernel to rebuild them.
+    # What is named, and so kept and stacked over the layers, is lse as
+    # (b h, t), t on the lanes: the kernels' (b h, t, 1) pads its one lane
+    # to 128 in HBM, 128 times the bytes (twice `flash_out` at a head of
+    # 128). The backward puts the lane back for its kernels.
     o = checkpoint_name(o, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return o, (q, k, v, o, lse)
 
 
 def _flash_with_t_bwd(t_real, block_q, block_k, bwd_block_q, bwd_block_k,
                       hq, hkv, interpret, mask, res, do):
     q, k, v, o, lse = res
-    return _bwd_call(q, k, v, o, lse, do, t_real=t_real,
+    return _bwd_call(q, k, v, o, lse[..., None], do, t_real=t_real,
                      block_q=bwd_block_q, block_k=bwd_block_k,
                      hq=hq, hkv=hkv, interpret=interpret, mask=mask)
 

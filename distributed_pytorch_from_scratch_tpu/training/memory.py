@@ -12,7 +12,14 @@ calls with the ZeRO stage only it knows.
 a v5e the plan (`memory_analysis().temp_size_in_bytes`) charges every
 stack that lives from the forward loop to the backward loop twice, the
 runtime reserves it once (PERF.md section 5 has both columns, per rung, for
-the benchmark's two cells; tests/test_attribution.py pins them).
+the two GPT-2 cells; tests/test_attribution.py pins them). What a model
+says of itself (`traced_step_bytes`) is held to the chip in all eleven
+cells of the benchmark, at the floor and at the rung `auto` picks: never
+under its count by more than 1%, never over by more than 5% (PERF.md
+section 5, PR 62; tests/test_remat_topology.py has a case a cell and rung).
+A drawn family's count of what a layer holds is SET from its cell's
+reading, at that cell's shape: a job of another shape reads it as far off
+as the families' docstrings say the untuned counts were (6 - 12%).
 """
 
 from __future__ import annotations
@@ -24,8 +31,10 @@ from typing import Dict, Optional
 GIB = 1024 ** 3
 
 # Of (limit - reserve), the share the chosen rung's estimate may take. The
-# estimate has read within 1% of the chip at every rung measured in the
-# benchmark's two cells (PERF.md section 5).
+# estimate has read within 1% of the chip at every rung measured in the two
+# GPT-2 cells (cells 1 and 2: PR 26, PR 28) and within -0.5% / +4.5% at the
+# floor and at the picked rung of the eight expert cells (PR 62; PERF.md
+# section 5).
 MARGIN = 0.93
 
 
@@ -83,7 +92,9 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                grad_bytes_per_param: float = 4.0,
                layer_extra_elems_per_token: float = 0.0,
                head_rows_share: float = 1.0,
-               residual_streams: int = 1) -> Dict[str, float]:
+               residual_streams: int = 1,
+               tagged_layers: Optional[Dict[str, float]] = None,
+               v_head_dim: Optional[int] = None) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -98,10 +109,15 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               hoists the casts out of the layer loops)
     stacks    the layer input every rung keeps (`residual_streams` x d wide:
               a family of hyper-connections carries several,
-              `DecoderStack.stream_mixer`), plus the rung's residuals,
-              L of each, at their logical sizes (the chip's count: the
-              stack of `flash_out` is not kept in the kernel's layout, which
-              pads a head of 64 to the 128 lanes)
+              `DecoderStack.stream_mixer`), L of it, plus the rung's
+              residuals at their logical sizes (the chip's count: the stack
+              of `flash_out` is not kept in the kernel's layout, which pads
+              a head of 64 to the 128 lanes, and `flash_lse` is named as
+              (b h, t) float32, t on the lanes), each over the layers that
+              tag it: `tagged_layers` (`DecoderStack.tagged_layers`: a drawn
+              family's MLP names are its dense layers', the flash names its
+              attention layers'; None: all L), `flash_out` at `v_head_dim`
+              a head where v is not of q's width
     head      logits in f32 and once more in the compute dtype, on
               `head_rows_share` of the rows (a family whose loss reads part
               of the rows the stack sees: `DecoderStack.head_rows_share`)
@@ -124,7 +140,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     f_w = tok * (f / tp) * dtype_bytes
     h_local = heads / tp
     names = {
-        "flash_out": tok * h_local * head_dim * dtype_bytes,
+        "flash_out": tok * h_local * (v_head_dim or head_dim) * dtype_bytes,
         "flash_lse": tok * h_local * 4,
         "q_proj": q_w, "k_proj": kv_w, "v_proj": kv_w,
         "attn_proj": wide if tp > 1 else 0.0,   # named only past a reduce
@@ -140,16 +156,19 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         per_layer = ((4 + residual_streams) * wide + 2 * q_w + 4 * kv_w
                      + names["flash_out"] + names["flash_lse"]
                      + (ffn_inputs + 1) * f_w)
+        stacks = layers * per_layer
     else:
-        per_layer = residual_streams * wide + sum(names[n] for _, ns in REMAT_LADDER[:rung + 1]
-                               for n in ns)
+        tagged = tagged_layers or {}
+        stacks = layers * residual_streams * wide + sum(
+            tagged.get(n, layers) * names[n]
+            for _, ns in REMAT_LADDER[:rung + 1] for n in ns)
     out = {
         "resident": param_count * (state_bytes_per_param
                                    - grad_bytes_per_param),
         "grads": param_count * grad_bytes_per_param,
         "cast": (layer_param_count * dtype_bytes if dtype_bytes < 4
                  else 0.0),
-        "stacks": layers * per_layer,
+        "stacks": stacks,
         "head": tok * head_rows_share * (vocab / tp) * (4 + dtype_bytes),
         "layer": tok * dtype_bytes * (6 * d * act + 3.4 * f / tp
                                       + layer_extra_elems_per_token),
@@ -225,13 +244,18 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
           allow_false: bool, verbose: bool, note: str = "") -> str:
     """The one selector: the highest rung (or 'false' above the ladder)
     whose `parts(key)["total"]` (a `step_bytes`) fits MARGIN x (budget -
-    reserve).
+    reserve); of rungs that keep the same, the lowest.
 
     `budget_gib` None reads the device; a backend with no `memory_stats`
     (the CPU) then gets rung 0, the program `remat=True` has always been.
-    `reserve_gib` None leaves room for one more copy of the resident state:
+    `reserve_gib` None leaves room for one more copy of the resident state,
     `AsyncCheckpointer`'s snapshot, which a model cannot know its caller
-    makes. Says what it chose on stderr and on the program's tracer."""
+    makes, wherever the floor rung fits beside it; where even the floor
+    does not, no rung could honour the reserve, no snapshot can be taken
+    and nothing is held back (`reserve_held` False: a chip's share of an
+    expert model, 7 - 9 GiB of state). A `reserve_gib` the caller names is
+    held as given. Says what it chose on stderr and on the program's
+    tracer."""
     from ..models.transformer import REMAT_RUNGS
     from ..obs.trace import current_tracer
     floor = REMAT_RUNGS[0]
@@ -240,18 +264,36 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
             budget_gib = hbm_budget_gib()
         except ValueError:
             return floor        # nothing was sized: nothing to say
-    reserve = (parts(floor)["resident"] / GIB if reserve_gib is None
-               else reserve_gib)
+    at_floor = parts(floor)
+    reserve, held = reserve_gib, True
+    if reserve is None:
+        reserve = at_floor["resident"] / GIB
+        held = at_floor["total"] / GIB <= (budget_gib - reserve) * MARGIN
+        if not held:
+            reserve = 0.0
     usable = (budget_gib - reserve) * MARGIN
-    sizes, picked = {}, floor
-    for key in (("false",) if allow_false else ()) + REMAT_RUNGS[::-1]:
-        sizes[key] = parts(key)["total"] / GIB
-        if sizes[key] <= usable or key == floor:
-            picked = key
+    sizes = {}      # in the order they were asked for: the line's order
+
+    def size(key):
+        if key not in sizes:
+            sizes[key] = (at_floor if key == floor
+                          else parts(key))["total"] / GIB
+        return sizes[key]
+
+    picked = next(key for key in (("false",) if allow_false else ())
+                  + REMAT_RUNGS[::-1] if size(key) <= usable or key == floor)
+    # a rung that keeps nothing here the rung below it does not (a name no
+    # layer of this model tags: `attn_proj` at tp 1, the MLP's names where
+    # every layer is routed) IS the rung below it
+    while picked in REMAT_RUNGS[1:]:
+        below = REMAT_RUNGS[REMAT_RUNGS.index(picked) - 1]
+        if size(below) < size(picked):
             break
+        picked = below
+    size(floor)     # always said
     fields = dict(rung=picked, estimate_gib=sizes[picked],
                   budget_gib=budget_gib, reserve_gib=reserve,
-                  usable_gib=usable,
+                  reserve_held=held, usable_gib=usable,
                   **{f"estimate_gib.{k}": v for k, v in sizes.items()})
     tracer = current_tracer()
     if tracer is not None:
@@ -260,7 +302,7 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
         est = ", ".join(f"{k}={v:.2f}GiB" for k, v in sizes.items())
         print(f"remat auto: picked '{picked}' (estimates {est}; budget "
               f"{budget_gib:.2f} GiB - reserve {reserve:.2f} GiB, x margin "
-              f"{MARGIN}{note})", file=sys.stderr)
+              f"{MARGIN}; reserve_held={held}{note})", file=sys.stderr)
     return picked
 
 
@@ -298,20 +340,33 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
     cannot see a ZeRO stage (stage 0's state is the largest) and never
     picks 'false': a rung is always a rematerialising model, which is what
     ZeRO-3's gather inside the layer body needs."""
+    return _pick(traced_step_bytes(model, param_count, layer_param_count, b,
+                                   t),
+                 model.remat_budget_gib, None, allow_false=False,
+                 verbose=True,
+                 note=f"; traced b{b} x t{t}, tp{model.tp_size}")
+
+
+def traced_step_bytes(model, param_count: int, layer_param_count: int,
+                      b: int, t: int):
+    """rung -> `step_bytes` of `model` at one device's parameter counts and
+    (b, t) token block, with what the model says of itself beside its
+    config's widths."""
     cfg = model.cfg
-    parts = functools.partial(
+    pp = model.pp_size
+    return functools.partial(
         step_bytes, param_count=param_count,
         layer_param_count=layer_param_count, b=b,
         t=t, d=cfg.attn_dim, kd=model.kv_dim,
         f=cfg.ffn_dim, heads=cfg.num_heads, head_dim=model.head_dim,
-        layers=model.stacked_layers // model.pp_size,
+        layers=model.stacked_layers // pp,
         vocab=cfg.padded_vocab_size(model.tp_size), tp=model.tp_size,
         dtype_bytes=2 if cfg.compute_dtype == "bfloat16" else 4,
         ffn_inputs=model.ffn_inputs,
         sequence_parallel=model.tp_layout(t)[0],
         layer_extra_elems_per_token=model.layer_extra_elems_per_token,
         head_rows_share=model.head_rows_share,
-        residual_streams=model.residual_streams)
-    return _pick(parts, model.remat_budget_gib, None, allow_false=False,
-                 verbose=True,
-                 note=f"; traced b{b} x t{t}, tp{model.tp_size}")
+        residual_streams=model.residual_streams,
+        tagged_layers={name: n // pp
+                       for name, n in model.tagged_layers.items()},
+        v_head_dim=model.v_head_dim)

@@ -53,6 +53,39 @@ def flash_bwd_calls():
     return calls
 
 
+@pytest.fixture(scope="session")
+def cell_step_bytes():
+    """`parts(cell)`: (the model of a benchmark cell, rung -> its
+    `training/memory.step_bytes`) at the shapes ONE device of the cell
+    traces, the model built by the benchmark's own `families/<family>.py`
+    from the cell's two files. Nothing is compiled."""
+    import functools
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.lib.cells import load_cell
+    from benchmark.lib.files import load_module
+    from distributed_pytorch_from_scratch_tpu.training import memory
+
+    @functools.lru_cache(maxsize=None)
+    def parts(cell):
+        workload, config = load_cell(cell)
+        mesh = workload.get("mesh", {})
+        tp, dp = mesh.get("tp", 1), mesh.get("dp", 1)
+        model = load_module("families", config["family"]).build(
+            config, mesh, workload["dtype"]).model
+        shapes = jax.eval_shape(model.init, jax.random.key(0))
+        count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))
+        # (a family whose head reads half the rows makes two of a token)
+        rows = int(workload["seqlen"] / model.head_rows_share)
+        return model, memory.traced_step_bytes(
+            model, count(shapes) // tp,
+            sum(count(shapes[k]) for k in model._layer_keys) // tp,
+            int(workload["batch"]) // dp, rows)
+    return parts
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _check_devices():
     assert jax.device_count() >= 8, (

@@ -295,6 +295,60 @@ def test_select_remat_picks_the_cells_rungs(capsys):
                         reserve_gib=0.0, verbose=False, **CELL1) == "dots"
 
 
+V5E_LIMIT_GIB = 15.748
+EXPERT_CELLS = [
+    "joyai-llm-flash.train-ep16share-b4-t4096",
+    "qwen3-next-80b-a3b.train-ep16share-b2-t8192",
+    "lfm2-8b-a1b.train-ep4share-b2-t8192",
+    "sdar-30b-a3b.train-ep8share-b2-t4096",
+    "trinity-mini.train-epshare-b2-t8192",
+    "smallthinker-21b-a3b.train-ep4share-b1-t16384",
+    "xing4-29b-a4b.train-ep8share-b1-t4096",
+    "ling-3-flash.train-ep64share-b1-t4096"]
+
+
+def picked(parts, capsys, reserve_gib=None):
+    from distributed_pytorch_from_scratch_tpu.training.memory import _pick
+    rung = _pick(parts, V5E_LIMIT_GIB, reserve_gib, allow_false=False,
+                 verbose=True)
+    return rung, capsys.readouterr().err
+
+
+@pytest.mark.parametrize("cell", ["gpt2-medium.train-b12-t1024",
+                                  "gpt2-medium.train-ckpt-every40"])
+def test_the_reserve_is_held_where_the_floor_can_take_a_snapshot(
+        cell, cell_step_bytes, capsys):
+    """Cells 1 and 3 hold 3.97 GiB of state: the floor rung fits beside one
+    more copy of it (cell 3 does take the snapshot), the copy is held back
+    and the rung is the one it has been."""
+    _, parts = cell_step_bytes(cell)
+    rung, said = picked(parts, capsys)
+    assert rung == "ffn"
+    assert "reserve 3.97 GiB" in said and "reserve_held=True" in said
+
+
+@pytest.mark.parametrize("cell", EXPERT_CELLS)
+def test_a_reserve_no_rung_can_honour_is_not_held(cell, cell_step_bytes,
+                                                  capsys):
+    """A chip's share of an expert model holds 5.7 - 8.6 GiB of state: the
+    floor rung plus one more copy is over the chip, no snapshot could be
+    taken, and `auto` sizes its rung by MARGIN x the budget; a caller that
+    names a reserve still gets it held."""
+    from distributed_pytorch_from_scratch_tpu.training.memory import (
+        GIB, MARGIN)
+    _, parts = cell_step_bytes(cell)
+    floor = parts("true")
+    assert (floor["total"] + floor["resident"]) / GIB > V5E_LIMIT_GIB
+    rung, said = picked(parts, capsys)
+    assert "reserve 0.00 GiB" in said and "reserve_held=False" in said
+    assert parts(rung)["total"] / GIB <= MARGIN * V5E_LIMIT_GIB \
+        or rung == "true"
+    named = floor["resident"] / GIB
+    rung, said = picked(parts, capsys, reserve_gib=named)
+    assert rung == "true"
+    assert f"reserve {named:.2f} GiB" in said and "reserve_held=True" in said
+
+
 def test_select_remat_steps_down_when_tight():
     """A small budget must force the ladder down — and a hopeless one
     still returns 'true' (the ladder's floor, never an exception)."""

@@ -560,8 +560,9 @@ def test_the_memory_facts_count_the_rows_the_chunk_and_half_the_logits():
     assert moe.chunk_share == 1 / 8
     assert moe.chunk_rows(131072) == 16384
     attn = 5 * 4 * 32 + 6 * 2 * 32 - 2 * 64
+    # (the last term: what the chip counts beside these, set from cell 8)
     assert eighth.layer_extra_elems_per_token == attn + 0.125 * 2 * (
-        6 * 64 + 5 * 32)
+        6 * 64 + 5 * 32) + 12.91 * 64
     assert (eighth.head_dim, eighth.kv_dim) == (32, 64)
     assert eighth.head_rows_share == 0.5 and eighth.draws_noise
     kw = dict(param_count=1e6, layer_param_count=5e5, b=2, t=8192, d=64,

@@ -491,7 +491,9 @@ def test_the_memory_facts_count_the_chunk_at_the_held_share():
     moe = quarter._mods["moe"]
     assert moe.chunk_share == 1.0           # a sixth or more: all the pairs
     attn = 5 * 6 * 32 + 6 * 2 * 32 - 2 * 64
-    assert quarter.layer_extra_elems_per_token == attn + 2 * (6 * 64 + 5 * 32)
+    # (the last term: what the chip counts beside these, set from cell 10)
+    assert quarter.layer_extra_elems_per_token == attn + 2 * (
+        6 * 64 + 5 * 32) - 16.47 * 64
     assert (quarter.head_dim, quarter.kv_dim) == (32, 64)
     eighth = build_model("early_moe", tiny(experts_held=1))
     assert eighth._mods["moe"].chunk_share == 1 / 8

@@ -546,26 +546,29 @@ def cell_config(name):
 FACTS_PINNED = {
     "tiny/llama": (4, 64, (1265958912, 791424, 0.0, 1.0, 2)),
     "tiny/gpt2": (4, 64, (911474688, 560640, 0.0, 1.0, 2)),
-    "tiny/mla_moe": (4, 64, (482021376.0, 383448, 768.0, 1.0, 4)),
-    "tiny/gdn_moe": (4, 64, (827056128.0, 749392, 1344.0, 1.0, 8)),
-    "tiny/conv_moe": (4, 64, (705060864.0, 794896, 1344.0, 1.0, 12)),
-    "tiny/bd_moe": (4, 64, (384958464.0, 280000, 1984.0, 0.5, 2)),
+    "tiny/mla_moe": (4, 64, (482021376.0, 383448, 1924.48, 1.0, 4)),
+    "tiny/gdn_moe": (4, 64, (827056128.0, 749392, 2792.3199999999997, 1.0, 8)),
+    "tiny/conv_moe": (4, 64, (705060864.0, 794896, 130.55999999999995, 1.0, 12)),
+    "tiny/bd_moe": (4, 64, (384958464.0, 280000, 2810.24, 0.5, 2)),
     # the same at tp 2: what a layer holds is divided over the tp ranks
-    "tiny-tp2/mla_moe": (4, 64, (482021376.0, 383448, 512.0, 1.0, 4)),
-    "tiny-tp2/gdn_moe": (4, 64, (827056128.0, 749392, 800.0, 1.0, 8)),
-    "tiny-tp2/conv_moe": (4, 64, (705060864.0, 794896, 800.0, 1.0, 12)),
-    "tiny-tp2/bd_moe": (4, 64, (384958464.0, 280000, 1376.0, 0.5, 2)),
+    "tiny-tp2/mla_moe": (4, 64, (482021376.0, 383448, 1090.24, 1.0, 4)),
+    "tiny-tp2/gdn_moe": (4, 64, (827056128.0, 749392, 1524.1599999999999, 1.0, 8)),
+    "tiny-tp2/conv_moe": (4, 64, (705060864.0, 794896, 193.27999999999997, 1.0, 12)),
+    "tiny-tp2/bd_moe": (4, 64, (384958464.0, 280000, 1789.12, 0.5, 2)),
     # the four drawn families at their cell's published widths and shape
     # (what a layer holds beside the skeleton fell in PR 50 where under a
     # sixth of the experts are held, the dispatch's chunk one mean share of
     # the pairs and not six: 39680.0, 78464.0 and 116224.0 until then; the
-    # fifth family's cell holds a quarter and keeps its one chunk of all)
+    # fifth family's cell holds a quarter and keeps its one chunk of all;
+    # since PR 62 each drawn family adds what the chip counts beyond its
+    # own count, a multiple of d set from its cell's reading: 23680.0,
+    # 60864.0, 76800.0 and 35584.0 until then, and the tiny rows with them)
     "joyai-llm-flash": (4, 4096,
-        (55680216072192.0, 680441088, 23680.0, 1.0, 6)),
+        (55680216072192.0, 680441088, 60687.36, 1.0, 6)),
     "qwen3-next-80b-a3b": (2, 8192,
-        (26242826895360.0, 625667136, 60864.0, 1.0, 4)),
-    "lfm2-8b-a1b": (2, 8192, (22914011234304.0, 507820288, 76800.0, 1.0, 5)),
-    "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 35584.0, 0.5, 6)),
+        (26242826895360.0, 625667136, 107210.23999999999, 1.0, 4)),
+    "lfm2-8b-a1b": (2, 8192, (22914011234304.0, 507820288, 37969.92, 1.0, 5)),
+    "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 62023.68, 0.5, 6)),
 }
 
 

@@ -188,3 +188,101 @@ def test_attn_proj_is_named_only_past_a_reduce():
         jax.eval_shape(FAMILIES["gpt2"](CFG, tp_size=tp).init,
                        jax.random.key(0)), *batch())).count("name=attn_proj")
     assert saved(1, "attn_proj") == 0 and saved(2, "attn_proj") > 0
+
+
+# ---- the flash kernel's kept outputs (PR 62) ----
+
+def _masks():
+    from distributed_pytorch_from_scratch_tpu.ops.attention import (
+        CAUSAL, block_diffusion, sliding_window)
+    return {"causal": CAUSAL, "window": sliding_window(128),
+            "block_diagonal": block_diffusion(4, 128)}
+
+
+@pytest.mark.parametrize("mask,d,dv", [
+    ("causal", 64, 64), ("causal", 128, 128), ("window", 128, 128),
+    ("block_diagonal", 128, 128),
+    ("causal", 192, 128)])          # latent attention's two widths
+def test_the_kept_lse_is_lane_dense(mask, d, dv):
+    """Under the `flash` rung's names the residual called `flash_lse` is
+    (b h, t) float32, t on the lanes: tok x h x 4 bytes, where the kernels'
+    (b h, t, 1) pads its one lane to 128 in HBM. The kernel's output is
+    kept beside it as it is."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention \
+        import flash_attention
+    b, h, hkv, t = 2, 4, 2, 256
+    attend = jax.checkpoint(
+        lambda q, k, v: flash_attention(
+            q, k, v, interpret=True, mask=_masks()[mask]).astype(
+                jnp.float32).sum(),
+        prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            "flash_out", "flash_lse"))
+    arg = lambda heads, w: jax.ShapeDtypeStruct((b, heads, t, w),
+                                                jnp.bfloat16)
+    saved = saved_residuals(attend, arg(h, d), arg(hkv, d), arg(hkv, dv))
+    lse, = (aval for aval, why in saved if "named 'flash_lse'" in why)
+    assert lse.shape == (b * h, t) and lse.dtype == jnp.float32
+    assert lse.size * lse.dtype.itemsize == b * t * h * 4
+    # what is kept beside it: q, k, v (the arguments) and the output
+    kept = sorted(aval.shape for aval, why in saved if "argument" not in why)
+    assert kept == [(b * h, t), (b * h, t, dv)], saved
+
+
+def test_the_flash_rung_runs_the_forward_kernel_once_a_layer():
+    """The gradient's jaxpr holds one layer body forward and one backward
+    (the layers are a scan): at rung 0 the backward's recompute calls the
+    flash forward again, two calls in all; from the `flash` rung up the
+    backward finds `flash_out` / `flash_lse` saved and one call is left,
+    with the same backward kernels either way."""
+    calls = {}
+    for rung in REMAT_RUNGS:
+        mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+        model = GPT2Transformer(CFG, remat=rung,
+                                attn_impl="flash_interpret")
+        text = str(jax.make_jaxpr(jax.grad(model.make_loss(mesh)))(
+            jax.eval_shape(model.init, jax.random.key(0)), *batch()))
+        calls[rung] = (len(re.findall(r"\bname=flash_fwd\b", text)),
+                       len(re.findall(r"\bname=flash_bwd\w*\b", text)))
+    keeps = REMAT_RUNGS.index("flash")
+    backward = calls["true"][1]     # (the interpreter's split pair here)
+    assert backward >= 1
+    assert calls == {r: (2 if i < keeps else 1, backward)
+                     for i, r in enumerate(REMAT_RUNGS)}, calls
+
+
+@functools.lru_cache(maxsize=None)
+def drawn_loss_and_grads(remat):
+    """A drawn family with a layer of each sort: conv_moe's dense
+    convolution layer (tags the MLP's names only), its attention layer
+    (the flash names and q, k, v) and an expert convolution layer (none),
+    the kernels under the interpreter."""
+    from distributed_pytorch_from_scratch_tpu.config import model_preset
+    from distributed_pytorch_from_scratch_tpu.models import build_model
+    cfg = model_preset("tiny-conv-moe")
+    cfg = dataclasses.replace(
+        cfg, num_layers=3, conv_moe=dataclasses.replace(
+            cfg.conv_moe, layer_types=("conv", "full_attention", "conv"),
+            num_dense_layers=1))
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    model = build_model("conv_moe", cfg, remat=remat,
+                        attn_impl="flash_interpret")
+    assert model.tagged_layers == {
+        "ffn_fc": 0, "ffn_gate": 1, "ffn_up": 1, "flash_out": 1,
+        "flash_lse": 1, "q_proj": 1, "k_proj": 1, "v_proj": 1}
+    ids = jax.random.randint(jax.random.key(3), (2, 129), 0, cfg.vocab_size)
+    pos = jnp.tile(jnp.arange(128, dtype=jnp.int32), (2, 1))
+    loss, grads = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+        model.init(jax.random.key(0)), ids[:, :-1], ids[:, 1:], pos)
+    return float(loss), jax.tree.map(np.asarray, grads)
+
+
+@pytest.mark.parametrize("rung", UPPER_RUNGS)
+def test_every_rung_gives_rung_zero_gradients_in_a_drawn_family(rung):
+    want_loss, want = drawn_loss_and_grads(True)
+    loss, grads = drawn_loss_and_grads(rung)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
