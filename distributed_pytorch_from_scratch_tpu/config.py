@@ -174,6 +174,53 @@ class KdaMlaMoEConfig:
 
 
 @dataclass(frozen=True)
+class SsmMoEConfig:
+    """What the `ssm_moe` family (models/ssm_moe.py) needs beyond
+    `ModelConfig`'s own fields: layers of ONE sublayer each, by a pattern of
+    one letter a layer (`M` a Mamba-2 state-space mixer, `*` grouped-query
+    attention with no positions, `E` a sigmoid-routed expert FFN whose
+    routed experts read and write a latent narrower than the model, of two
+    matrices each under a squared ReLU, with a shared expert of the same
+    form at the model's width), and a multi-token-prediction module that is
+    a pattern of its own. The keys are Nemotron-H's `config.json` names
+    (`nemotron_h`) where one exists. In `ModelConfig`, `attn_dim` is the
+    model width, `num_heads` / `num_kv_heads` the attention layers' heads
+    HELD, `num_layers` = `len(hybrid_override_pattern)`, `ffn_dim` the
+    shared expert's width (there is no dense MLP), `num_experts` the ROUTED
+    experts the router scores and `moe_top_k` the experts a token takes.
+    The mixers are built at the heads and groups the job HOLDS
+    (`mamba_num_heads`, `n_groups`, `num_heads`, `num_kv_heads`: one
+    tensor-parallel rank's), the first Mamba head held `mamba_head_offset`
+    among all of them."""
+
+    hybrid_override_pattern: str    # "M" | "*" | "E", a layer each
+    mamba_num_heads: int
+    mamba_head_dim: int
+    ssm_state_size: int
+    n_groups: int
+    head_dim: int                   # the attention heads' width
+    moe_intermediate_size: int
+    moe_latent_size: int
+    moe_shared_expert_intermediate_size: int
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    mamba_head_offset: int = 0
+    routed_scaling_factor: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    num_nextn_predict_layers: int = 0
+    mtp_hybrid_override_pattern: str = "*E"
+    mtp_loss_weight: float = 0.3    # not published; DeepSeek-V3's first
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+    norm_eps: float = 1e-5
+
+
+@dataclass(frozen=True)
 class ConvMoEConfig:
     """What the `conv_moe` family (models/conv_moe.py) needs beyond
     `ModelConfig`'s own fields: which layers mix by a gated short
@@ -338,6 +385,8 @@ class ModelConfig:
     early_moe: "EarlyMoEConfig | None" = None
     # The `kda_mla_moe` family's facts (None for every other family).
     kda_mla_moe: "KdaMlaMoEConfig | None" = None
+    # The `ssm_moe` family's facts (None for every other family).
+    ssm_moe: "SsmMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -406,7 +455,7 @@ class ModelConfig:
 
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
-                "early_moe", "kda_mla_moe")
+                "early_moe", "kda_mla_moe", "ssm_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -532,6 +581,22 @@ MODEL_PRESETS = {
             qk_rope_head_dim=8, v_head_dim=16, moe_intermediate_size=32,
             layer_group_size=3, first_k_dense_replace=1, n_group=4,
             topk_group=2, num_nextn_predict_layers=1, mtp_loss_weight=0.3)),
+    # the `ssm_moe` family at a CPU size: Nemotron-H's pattern in small,
+    # layers of one sublayer each, (expert, Mamba-2) three times and one
+    # attention layer; 4 Mamba heads 16 wide over 2 groups of B and C, a
+    # state 8 wide; 4 query heads over 2 key-value heads of 16, no
+    # positions; 16 routed two-matrix relu2 experts in a 32-wide latent
+    # (sigmoid top-3, scaling 2.5) and a shared expert 96 wide; one
+    # multi-token-prediction module (an attention and an expert layer)
+    "tiny-ssm-moe": ModelConfig(
+        attn_dim=64, ffn_dim=96, num_heads=4, num_kv_heads=2, num_layers=7,
+        vocab_size=1024, maxlen=256, num_experts=16, moe_top_k=3,
+        ssm_moe=SsmMoEConfig(
+            hybrid_override_pattern="EMEMEM*", mamba_num_heads=4,
+            mamba_head_dim=16, ssm_state_size=8, n_groups=2, head_dim=16,
+            moe_intermediate_size=48, moe_latent_size=32,
+            moe_shared_expert_intermediate_size=96, chunk_size=32,
+            routed_scaling_factor=2.5, num_nextn_predict_layers=1)),
 }
 
 

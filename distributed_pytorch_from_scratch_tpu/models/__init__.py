@@ -1,9 +1,9 @@
 """The model families, and the one place a family's name becomes a class:
-`llama` (the reference's block) and `gpt2`, and eight drawn from published
+`llama` (the reference's block) and `gpt2`, and nine drawn from published
 configurations, each holding one share of the experts its router scores:
 `mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
-`mhc_mla_moe` and `kda_mla_moe` (docs/DESIGN.md, "What a family file
-holds")."""
+`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe` (docs/DESIGN.md, "What a family
+file holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
@@ -13,6 +13,7 @@ from .gpt2 import GPT2Transformer
 from .kda_mla_moe import KdaMlaMoETransformer
 from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
+from .ssm_moe import SsmMoETransformer
 from .stack import DecoderStack
 from .swa_moe import SlidingWindowMoETransformer
 from .transformer import Transformer
@@ -21,7 +22,7 @@ FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
     ConvMoETransformer, BlockDiffusionMoETransformer,
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
-    HyperLatentMoETransformer, KdaMlaMoETransformer)}
+    HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

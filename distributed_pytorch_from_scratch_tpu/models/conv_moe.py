@@ -78,8 +78,9 @@ def layer_blocks(layer_types, num_dense_layers: int, kinds=None,
     """`layer_types` and `num_dense_layers` -> the blocks of the layer
     pattern, by run length: ((repeats, ((parameter key, mixer kind, dense?,
     layers), ...)), ...), `repeats` None for a segment scanned once.
-    `kinds` names the two kinds of layer a family has (this family's
-    `KINDS`; `models/swa_moe.py` cuts its own two the same way).
+    `kinds` names the kinds of layer a family has (this family's `KINDS`;
+    `models/swa_moe.py` cuts its own two the same way, `models/ssm_moe.py`
+    its three).
 
     The leading dense layers are one segment (one kind of mixer). What
     follows is read as runs of one kind; a period starts at a run and ends
@@ -107,7 +108,7 @@ def layer_blocks(layer_types, num_dense_layers: int, kinds=None,
             runs.append([kind, 1])
     at = 0
     while at < len(runs):
-        # two kinds of mixer: a period is one run, or two of unlike kinds
+        # a period is one run, or two of unlike kinds
         period = runs[at:at + 2]
         n = len(period)
         repeats = 1

@@ -64,7 +64,8 @@ from ..parallel.moe import SharedRoutedFFN
 from ..ops.rope import rope_angles
 from ..parallel.norm import RMSNorm
 from ..runtime.prng import fold
-from .stack import DecoderStack, Params, TPSublayers, idle_expert_params
+from .stack import (DecoderStack, Params, TPSublayers, _rows_in_order,
+                    idle_expert_params)
 
 ATTN = ("norm1", "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo",
         "norm2")
@@ -77,7 +78,11 @@ class MultiTokenPrediction:
     whose facts (`config_extra`) hold `num_nextn_predict_layers` (0 or 1)
     and `mtp_loss_weight` and whose `_segments` end in `mtp_layers`: its own
     leaves beside the stack's tree and `_extra_loss`. `models/kda_mla_moe.py`
-    takes it too."""
+    takes it too, and `models/ssm_moe.py`, whose module is two layers of one
+    sublayer each under two keys (`_mtp_keys`)."""
+
+    # the stacked keys the module's layers stand under, in the order they run
+    _mtp_keys = ("mtp_layers",)
 
     @property
     def _facts(self):
@@ -131,7 +136,11 @@ class MultiTokenPrediction:
             e = self.final_norm.apply(mp["enorm"], nxt.astype(trunk.dtype))
             h = jnp.concatenate([h, jnp.broadcast_to(e, h.shape)], axis=-1)
             h = self.eh_proj.apply(mp["eh_proj"], h, trunk.dtype)
-            h, mtp_aux = trunk.run(h, params["mtp_layers"])
+            auxs = []
+            for key in self._mtp_keys:
+                h, aux = trunk.run(h, params[key])
+                auxs.append(aux)
+            mtp_aux = auxs[0] if len(auxs) == 1 else _rows_in_order(auxs)
             logits = self._head(params, mp["norm"], h, trunk.dtype,
                                 scope=None, exit_params=mp.get("hc_exit"))
             after = jnp.concatenate(

@@ -22,8 +22,9 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   kinds of attention layer by `_pattern` key (`_kind`, `_attn_mask(t,
   kind)`, `unrotated_kinds`), the speed of its routers' selection bias
   (`router_bias_speed`), whether its routers read the layer's input
-  (`router_reads_layer_input`) and the mixer of its residual streams where
-  a layer's residual state is several (`stream_mixer`);
+  (`router_reads_layer_input`), the mixer of its residual streams where
+  a layer's residual state is several (`stream_mixer`) and whether a layer
+  is ONE norm and ONE sublayer (`one_sublayer`);
 * `_mods`, the per-layer modules (the attention projections are
   `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
   `models/decode.py` and `interop.py` read by name), and its mixer where
@@ -336,6 +337,7 @@ def resolve_remat(model, params: Params, ids_shape):
 # What a family may say it does not run with (`DecoderStack.refuses`): the
 # name the refusal prints -> does the model being built ask for it
 REFUSABLE = {
+    "tp_size > 1": lambda m: m.tp_size > 1,
     "pp_size > 1": lambda m: m.pp_size > 1,
     "cp_size > 1": lambda m: m.cp_size > 1,
     "ep_size > 1": lambda m: m.ep_size > 1,
@@ -426,6 +428,13 @@ def _rows_in_order(auxs):
     all count the same (every layer its mixers and an expert layer its
     router too; a linear-attention layer its decay), each counter's rows
     follow the layers that count it."""
+    counted = [aux for aux in auxs if aux is not None]
+    if len(counted) < len(auxs):
+        # some of the layers count nothing (a layer that is one sublayer,
+        # and that a mixer with no counter)
+        if len(counted) < 2:
+            return counted[0] if counted else None
+        auxs = counted
     keys = [set(aux) if isinstance(aux, dict) else None for aux in auxs]
     if None not in keys and any(k != keys[0] for k in keys):
         return {k: jnp.concatenate([a[k] for a in auxs if k in a])
@@ -598,6 +607,19 @@ class DecoderStack:
     # (`hc_exit`); the layers count `StreamMixer.counters`, one row a layer.
     # None: one stream, and the program is the one it has always been
     stream_mixer = None
+    # Is a layer ONE norm (`attn_norm_key`) and ONE sublayer, `x +
+    # sublayer(norm(x))`, where every other family's is a mixer and then a
+    # feed-forward part, each behind its norm (Nemotron-H: a layer is a
+    # state-space mixer, an attention or an expert FFN, and the pattern
+    # says which). What a layer's parameters hold says which sublayer it is,
+    # as ever: the routed experts (`moe`) make it the feed-forward part
+    # with no mixer before it, a `wo` the stack's attention and neither the
+    # family's own mixer (`_mix`), each with no feed-forward part behind
+    # it. `_segments` then lists one norm and one sublayer's modules a key,
+    # which is all `init`, `specs`, the counters' rows (a layer that counts
+    # nothing has no row: `_rows_in_order`) and `tagged_layers` read.
+    # False: the layer every other family has, the program it has always been
+    one_sublayer = False
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -668,6 +690,12 @@ class DecoderStack:
                 f"{self.residual_streams} residual streams: the pipeline's "
                 f"carries and a router that reads the layer's input take "
                 f"one")
+        if self.one_sublayer and (self.stream_mixer is not None
+                                  or self.router_reads_layer_input):
+            raise ValueError(
+                f"the {self.family} family's layers are one sublayer each: "
+                f"the stream mixers' two joints and a router that reads "
+                f"what entered the attention half take a layer of two")
         validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -1043,7 +1071,9 @@ class DecoderStack:
                     kind: "str | None" = None) -> jax.Array:
         """One decoder layer: x + attn(norm(x)), then x + mlp(norm(x)) or,
         with cfg.num_experts > 0, x + MoE(norm(x)) (parallel/moe.py); a
-        family that names post-norms adds N(attn(..)) and N(mlp(..)).
+        family that names post-norms adds N(attn(..)) and N(mlp(..)); a
+        family whose layers are `one_sublayer` runs the one of the two its
+        parameters hold.
         `layer_pos` is what the family's `_positions` hands every layer;
         `kind` is the kind of the layer's `_pattern` key (`_kind`; static):
         a family with two kinds of attention layer over one parameter tree
@@ -1125,6 +1155,8 @@ class DecoderStack:
             # (what entered the layer, for a router that reads it)
             router_x = x if self.router_reads_layer_input else None
             x = join(x, a, "hc_attn")
+            if self.one_sublayer:       # the mixer was the layer
+                return x, counted
 
             norm = self.ffn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm],
@@ -1145,6 +1177,12 @@ class DecoderStack:
         # the cp ring documents below. Bubble steps burn the layer FLOPs;
         # their outputs are structurally discarded (garbage flows only into
         # garbage — see _pipeline_layers).
+        if self.one_sublayer and "moe" in layer_params:
+            # the layer is its feed-forward part, with no mixer before it
+            norm = self.attn_norm_key
+            y = tp.gather(m[norm].apply(layer_params[norm], x))
+            ff, aux = self._ffn(layer_params, y, tp, dtype)
+            return x + ff, aux
         if "wo" not in layer_params:
             # no output projection of the stack's: the layer's mixer hands
             # back the sublayer's output itself (`_mix`)

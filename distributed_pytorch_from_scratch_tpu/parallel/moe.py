@@ -370,8 +370,10 @@ def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
                  wc: jax.Array, sizes: jax.Array, valid: jax.Array,
                  act=jax.nn.silu) -> jax.Array:
     """A chunk's (M, d) rows through the held experts' two grouped products
-    (`act(gate) * up` between them: the family's `activation`) and times
-    their weights. The grouped kernels write the rows of their
+    (`act(gate) * up` between them: the family's `activation`; `act(up)`
+    where the experts are of two matrices, which is said by the first
+    product's width: `gate_up` is then `up` alone, as wide as `down` is
+    long) and times their weights. The grouped kernels write the rows of their
     groups and NOTHING ELSE: a row no group holds comes back as whatever
     the buffer held, from the forward products and from their transposes
     alike (a 5,000-fold gradient norm on the chip, PR 33; the CPU lowering
@@ -385,7 +387,9 @@ def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
     f = down.shape[1]
     with jax.named_scope("moe_experts"):
         gu = lax.ragged_dot(rows, gate_up, sizes)
-        out = lax.ragged_dot(act(gu[:, :f]) * gu[:, f:], down, sizes)
+        hidden = (act(gu[:, :f]) * gu[:, f:] if gate_up.shape[-1] == 2 * f
+                  else act(gu))
+        out = lax.ragged_dot(hidden, down, sizes)
     with jax.named_scope("moe_route"):
         return jnp.where(valid, out, 0) * wc[:, None].astype(out.dtype)
 
@@ -538,9 +542,11 @@ sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
 # The gate activations a family may state for `SharedRoutedFFN`'s experts
-# (`activation`): SwiGLU's, and ReGLU's (its zeros are computed like any
-# other value: the grouped products are dense over a row's hidden width).
-ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+# (`activation`): SwiGLU's, ReGLU's (its zeros are computed like any other
+# value: the grouped products are dense over a row's hidden width), and the
+# squared ReLU of Nemotron-H's two-matrix experts (`gated` False).
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+               "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 # A chunk of `SharedRoutedFFN`'s sorted pairs: the unit of the layer's WORK
 # and of its MEMORY. Every mover and pass of a live chunk (the `x[tok]`
@@ -602,7 +608,18 @@ class SharedRoutedFFN:
     biased scores INSIDE them chosen, `select`, under the scope
     `moe_route/groups`; the layer then counts `groups_hit` (n_group,), the
     tokens of which a group got at least one choice), and `n_group` 1 is
-    the selection over all of them, the program it has always been. And
+    the selection over all of them, the program it has always been;
+    `gated` False makes every expert, held and shared, TWO matrices, `down
+    (act(up x))`, with no `gate` leaf (Nemotron-H's `relu2` experts);
+    `latent` is the width the ROUTED experts read and write where that is
+    not the model's: the tokens go down `latent["down"]` (d -> latent, no
+    bias, no norm) before the dispatch and the experts' weighted sum comes
+    back up `latent["up"]` (latent -> d) after it, under the scopes
+    `moe_latent/down` and `moe_latent/up`, so the rows the movers carry and
+    the grouped products' contraction are `latent` wide, while the router
+    and the shared expert read the d-wide token (Nemotron 3's latent
+    experts); `shared_width` is the shared expert's hidden width where it
+    is not `n_shared` routed experts'. And
     one the CALLER states, a call at a time: `apply`'s
     optional `router_x` is what the router reads where that is not what
     the experts read (a family whose router reads the layer's input,
@@ -694,6 +711,9 @@ class SharedRoutedFFN:
     activation: str = "silu"     # a key of ACTIVATIONS
     n_group: int = 1             # groups the selection is limited to
     topk_group: int = 1          # ... of which a token keeps this many
+    gated: bool = True           # False: experts of two matrices, no gate
+    latent: "int | None" = None  # the routed experts' width where not d
+    shared_width: "int | None" = None   # where not n_shared * f
 
     def __post_init__(self):
         held = self.num_held
@@ -729,18 +749,25 @@ class SharedRoutedFFN:
         if not (1 <= self.top_k <= self.num_experts):
             raise ValueError(f"top_k {self.top_k} out of range for "
                              f"{self.num_experts} experts")
-        if self.f % self.tp_size:
-            raise ValueError(f"expert width {self.f} not divisible by "
-                             f"tp_size {self.tp_size}")
+        if self.f % self.tp_size or self.shared_f % self.tp_size:
+            raise ValueError(f"expert widths {self.f}, {self.shared_f} not "
+                             f"divisible by tp_size {self.tp_size}")
 
     @property
     def num_held(self) -> int:
         return self.num_experts if self.held is None else self.held
 
+    @property
+    def shared_f(self) -> int:
+        """The shared expert's hidden width."""
+        return (self.n_shared * self.f if self.shared_width is None
+                else self.shared_width)
+
     # ---- init / specs ----
 
     def init(self, key: jax.Array) -> Params:
         d, f, H = self.d, self.f, self.num_held
+        l = self.latent or d        # what a routed expert reads and writes
 
         def w(k, shape, idim):
             bound = 1.0 / math.sqrt(idim)
@@ -753,20 +780,27 @@ class SharedRoutedFFN:
             # the selection bias: zeros, which only the rule of
             # training/optim.router_bias_step moves (never a gradient)
             "bias": jnp.zeros((self.num_experts,), jnp.float32),
-            "gate": w(fold(key, "gate"), (H, d, f), d),
-            "up": w(fold(key, "up"), (H, d, f), d),
-            "down": w(fold(key, "down"), (H, f, d), f),
+            "gate": w(fold(key, "gate"), (H, l, f), l),
+            "up": w(fold(key, "up"), (H, l, f), l),
+            "down": w(fold(key, "down"), (H, f, l), f),
         }
         if self.score == "softmax":
             del p["bias"]
+        if self.latent:
+            p["latent"] = {"down": w(fold(key, "latent_down"), (d, l), d),
+                           "up": w(fold(key, "latent_up"), (l, d), l)}
         if self.n_shared:
-            fs = self.n_shared * f
+            fs = self.shared_f
             p["shared"] = {"gate": w(fold(key, "shared_gate"), (d, fs), d),
                            "up": w(fold(key, "shared_up"), (d, fs), d),
                            "down": w(fold(key, "shared_down"), (fs, d), fs)}
             if self.shared_gate:
                 p["shared"]["gate_score"] = w(fold(key, "shared_gate_score"),
                                               (d, 1), d)
+        if not self.gated:
+            del p["gate"]
+            if self.n_shared:
+                del p["shared"]["gate"]
         return p
 
     def specs(self) -> Params:
@@ -781,6 +815,12 @@ class SharedRoutedFFN:
                 s["shared"]["gate_score"] = P(None, None)
         if self.score == "softmax":
             del s["bias"]
+        if self.latent:
+            s["latent"] = {"down": P(None, None), "up": P(None, None)}
+        if not self.gated:
+            del s["gate"]
+            if self.n_shared:
+                del s["shared"]["gate"]
         return s
 
     # ---- routing ----
@@ -896,6 +936,10 @@ class SharedRoutedFFN:
         S, k = b * t, self.top_k
         xf = x.reshape(S, d)
         xd = copy_to(xf.astype(compute_dtype), self.tp_axis)
+        xl = xd                 # what the routed experts read
+        if self.latent:
+            with jax.named_scope("moe_latent"), jax.named_scope("down"):
+                xl = xd @ params["latent"]["down"].astype(compute_dtype)
 
         M = self.chunk_rows(S * k)
         # rows move by gathers both ways or by the row scatter-add: the
@@ -922,8 +966,8 @@ class SharedRoutedFFN:
             token = jnp.pad(token, (0, chunks * M - S * k))
             w_sorted = jnp.pad(w_sorted, (0, chunks * M - S * k))
         # gate and up as one grouped product: one pass over the rows
-        gate_up = jnp.concatenate([params["gate"], params["up"]],
-                                  axis=-1).astype(compute_dtype)
+        gate_up = (jnp.concatenate([params["gate"], params["up"]], axis=-1)
+                   if self.gated else params["up"]).astype(compute_dtype)
         down = params["down"].astype(compute_dtype)
         if gathers:
             def chunk(y, c):
@@ -937,7 +981,7 @@ class SharedRoutedFFN:
                         n, at = rows_here - lo, pos - lo
                         idx = jnp.where(
                             (at >= 0) & (at < jnp.minimum(n, M)), at, M)
-                        rows = take_rows(xd, tok, idx, n)
+                        rows = take_rows(xl, tok, idx, n)
                     out = held_experts(rows, gate_up, down, wc, sizes, valid,
                                        act)
                     with jax.named_scope("moe_route"):
@@ -955,26 +999,33 @@ class SharedRoutedFFN:
                         (jnp.sum(sizes), jnp.where(lo < rows_here, M, 0)))
 
             y, (computed, walked) = lax.scan(
-                jax.checkpoint(chunk), _like(xd),
+                jax.checkpoint(chunk), _like(xl),
                 jnp.arange(chunks, dtype=jnp.int32))
             computed, walked = jnp.sum(computed), jnp.sum(walked)
         else:
             # one set of mesh axes for the walk's float operands: the
             # rows' (batch axes and tp)
-            vma = tuple(jax.typeof(xd).vma)
+            vma = tuple(jax.typeof(xl).vma)
             vary = lambda a: copy_to(a, vma) if vma else a
             y, computed, walked = walk_chunks(
-                M, act, xd, vary(gate_up), vary(down), vary(w_sorted), token,
+                M, act, xl, vary(gate_up), vary(down), vary(w_sorted), token,
                 ends, rows_here)
         counters["rows_computed"] = computed.astype(jnp.float32)
         counters["rows_walked"] = walked.astype(jnp.float32)
 
+        if self.latent:
+            with jax.named_scope("moe_latent"), jax.named_scope("up"):
+                y = y @ params["latent"]["up"].astype(compute_dtype)
         if self.n_shared:
             with jax.named_scope("moe_shared"):
                 sp = params["shared"]
-                g = xd @ sp["gate"].astype(compute_dtype)
-                u = xd @ sp["up"].astype(compute_dtype)
-                out = (act(g) * u) @ sp["down"].astype(compute_dtype)
+                if self.gated:
+                    g = xd @ sp["gate"].astype(compute_dtype)
+                    u = xd @ sp["up"].astype(compute_dtype)
+                    hidden = act(g) * u
+                else:
+                    hidden = act(xd @ sp["up"].astype(compute_dtype))
+                out = hidden @ sp["down"].astype(compute_dtype)
                 if self.shared_gate:
                     # the gate's product reads whole tokens on every tp
                     # rank and scales this rank's partial sum

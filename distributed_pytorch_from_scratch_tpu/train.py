@@ -222,8 +222,9 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "step, the batch), runs [noised ; clean] under the "
                         "block-diffusion attention mask and weights the "
                         "masked positions' CE by 1/p; tokens/s count data "
-                        "tokens; the later families, 'kda_mla_moe' among "
-                        "them, each with --model tiny-<family>: README) and "
+                        "tokens; the later families, 'kda_mla_moe' and "
+                        "'ssm_moe' among them, each with --model "
+                        "tiny-<family>: README) and "
                         "train under dp/tp/ZeRO 1 only: "
                         "pp/cp/ep > 1, SP, ZeRO 2/3, decode and serving "
                         "refuse them with a message")

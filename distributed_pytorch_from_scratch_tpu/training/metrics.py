@@ -176,7 +176,8 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     size of a bias entry's step (`router_bias_step`); the mixers'
     counters of a family with residual streams; a delta layer's decay
     (`kda_g_min`, `kda_g_spread`) and the groups' load (`groups_hit_max`)
-    of the `kda_mla_moe` family."""
+    of the `kda_mla_moe` family; a Mamba-2 layer's decay sums
+    (`ssm_decay_min`) of the `ssm_moe` family."""
     import numpy as np
 
     lo = cfg.expert_offset
@@ -202,6 +203,10 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
         # and the layers' mean spread of the decay over a head's channels
         out["kda_g_min"] = float(np.min(counters["kda_g_min"]))
         out["kda_g_spread"] = float(np.mean(counters["kda_g_spread"]))
+    if "ssm_decay_min" in counters:
+        # a family of Mamba-2 layers (parallel/mamba.py): the worst layer's
+        # most negative `dt A` summed over a chunk
+        out["ssm_decay_min"] = float(np.min(counters["ssm_decay_min"]))
     if "groups_hit" in counters:
         # a group-limited selection (parallel/moe.SharedRoutedFFN.select):
         # the tokens the busiest group got a choice of, over the groups'

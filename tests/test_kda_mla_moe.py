@@ -734,4 +734,4 @@ def test_the_new_familys_step_names_its_scopes():
                   "groups/", "moe_experts", "moe_shared", "dense_ffn",
                   "mtp/"):
         assert scope in text, scope
-    assert FAMILY in FAMILIES and len(FAMILIES) == 10
+    assert FAMILY in FAMILIES and len(FAMILIES) >= 10

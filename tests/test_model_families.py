@@ -18,6 +18,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          KdaMlaMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
+                                                         SsmMoEConfig,
                                                          SwaMoEConfig,
                                                          model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
@@ -77,9 +78,23 @@ KDA = dict(head_dim=16, kv_lora_rank=16, qk_nope_head_dim=16,
            topk_group=1)
 
 
+# the ssm_moe family: layers of one sublayer each, (expert, Mamba-2) twice
+# and an attention layer; 4 Mamba heads of 8 over 2 groups, a state 4 wide;
+# heads of 16; two-matrix experts in a 16-wide latent, a shared expert of 24
+SSM = dict(hybrid_override_pattern="EMEM*", mamba_num_heads=4,
+           mamba_head_dim=8, ssm_state_size=4, n_groups=2, head_dim=16,
+           moe_intermediate_size=16, moe_latent_size=16,
+           moe_shared_expert_intermediate_size=24, chunk_size=16,
+           num_nextn_predict_layers=1)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "ssm_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2,
+                           **{**TINY, "num_layers": 5, "ffn_dim": 24},
+                           ssm_moe=SsmMoEConfig(experts_held=held, **SSM))
     if extra == "kda_mla_moe":
         return ModelConfig(num_experts=8, **{**TINY, "num_layers": 3},
                            kda_mla_moe=KdaMlaMoEConfig(experts_held=held,
@@ -115,7 +130,7 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
                 "early_moe": "tiny-early-moe",
                 "mhc_mla_moe": "tiny-mhc-mla-moe",
-                "kda_mla_moe": "tiny-kda-mla-moe"}
+                "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -132,6 +147,9 @@ def _shapes(model):
          pp_microbatches=2)], ids=["tp2", "pp2-interleaved"])
 def test_init_and_specs_have_the_same_tree(family, config, kw):
     cfg = config_for(family, config)
+    if "tp_size > 1" in FAMILIES[family].refuses:
+        # (its mixers are built at one rank's heads: no `tp` axis)
+        kw = {k: v for k, v in kw.items() if k != "tp_size"}
     if kw.get("pp_size", 1) > 1 and cfg.family_facts is not None:
         # a family with a layer pattern says so where it is built
         with pytest.raises(ValueError, match="pp_size > 1"):
@@ -245,7 +263,8 @@ def test_a_family_with_facts_takes_its_tree_from_the_stack(family, name):
     assert name in vars(DecoderStack)
 
 
-REFUSED_BY = {"pp_size > 1": dict(pp_size=2), "cp_size > 1": dict(cp_size=2),
+REFUSED_BY = {"tp_size > 1": dict(tp_size=2),
+              "pp_size > 1": dict(pp_size=2), "cp_size > 1": dict(cp_size=2),
               "ep_size > 1": dict(ep_size=2),
               "sequence_parallel=True": dict(tp_size=2,
                                              sequence_parallel=True),
@@ -261,11 +280,21 @@ def test_the_stack_raises_a_familys_refusal_in_the_familys_words(family,
     assert set(REFUSED_BY) == set(REFUSABLE)
     cls = FAMILIES[family]
     assert set(cls.refuses) <= set(REFUSABLE)
+    # (a family whose mixers are built at one rank's heads refuses the
+    # `tp` axis itself, so what else it refuses is asked without one)
+    asked = {k: v for k, v in REFUSED_BY[what].items()
+             if what == "tp_size > 1" or k != "tp_size"
+             or "tp_size > 1" not in cls.refuses}
+    if what not in cls.refuses:     # every other family shards over `tp`
+        assert what == "tp_size > 1"
+        assert build_model(family, config_for(family, "moe8"),
+                           **asked).tp_size == 2
+        return
     why = cls.refuses[what]
     said = (f"the {family} family does not run with {what}"
             + (f" ({why})" if why else ""))
     with pytest.raises(ValueError, match=re.escape(said)):
-        build_model(family, config_for(family, "moe8"), **REFUSED_BY[what])
+        build_model(family, config_for(family, "moe8"), **asked)
 
 
 @drawn
