@@ -173,39 +173,55 @@ class KdaMlaMoETransformer(MultiTokenPrediction, DecoderStack):
                 + self.cfg.kda_mla_moe.num_nextn_predict_layers)
 
     @property
+    def head_dim(self) -> int:       # the delta layers' heads
+        return self.cfg.kda_mla_moe.head_dim
+
+    @property
+    def tagged_layers(self) -> Dict[str, int]:
+        """A delta layer tags its q, k and v projections with the ladder's
+        names before their convolutions (parallel/kda.py), each at the
+        heads' whole width; the latent layer's carry none."""
+        delta = sum(layers for _, layers, names in self._segments
+                    if "kda" in names)
+        return {**super().tagged_layers,
+                "q_proj": delta, "k_proj": delta, "v_proj": delta}
+
+    @property
     def layer_extra_elems_per_token(self) -> float:
         """What a delta layer's backward holds at its fullest, beside the
         d-wide tensors the dense skeleton counts, in elements of the compute
-        dtype a token: the pass that makes the rule's inputs again
-        (`KimiDeltaAttention.apply` keeps it apart from the rule's own
-        backward, which runs a sequence at a time): the four projections q,
-        k, v and the decay's, with their cotangents, the three convolutions'
-        float32 sums (two elements a channel), q, k and v by head, the
-        float32 decay (two elements a channel: d_k a head and token, where
-        the third family's rule holds one scalar) and its cotangent, and the
-        output gate; and one chunk of the expert dispatch
-        (`SharedRoutedFFN.chunk_share` of a token's pairs). The latent layer
-        holds less. The last term takes 41.93 d a token back off and is
-        SET FROM THE CHIP'S READING, which is LESS than the dense
-        skeleton's count alone at this shape (state, gradients and the
-        layers' weights in bfloat16 are 12.67 of the chip's 12.77 GiB:
-        what the estimate calls `cast` is not all held at once here, and
-        since PR 60 the rule's kernels make their operands in VMEM; not
-        told apart, PERF.md section 7): cell 12 on a v5e counts 12.774 GiB
-        at rung `true` and 12.840 at `flash`, the rung `auto` picks, for
-        steps this makes 12.94 and 13.07 (ledger, PR 61; my chip run, PR
-        62; without the term `true` made 13.76)."""
+        dtype a token. The mixer has no checkpoint of its own
+        (`KimiDeltaAttention.apply`), so what the layer's recompute made of
+        the rule's inputs stands until its transpose is reached, through the
+        rule's backward (which runs a sequence at a time): the four
+        projections q, k, v and the decay's, the three convolutions' float32
+        sums (two elements a channel), q, k and v by head, the float32 decay
+        (two elements a channel: d_k a head and token, where the third
+        family's rule holds one scalar), the cotangents of those four as the
+        rule's backward hands them over, and the output gate; and one chunk
+        of the expert dispatch (`SharedRoutedFFN.chunk_share` of a token's
+        pairs). The latent layer holds less. The last term takes 31.87 d a
+        token back off and is SET FROM THE CHIP'S READING, which is barely
+        over state, gradients and the layers' weights in bfloat16 (12.67
+        GiB: what the estimate calls `cast` is not all held at once here,
+        and the rule's kernels make their operands in VMEM; not told apart,
+        PERF.md section 7): cell 12 on a v5e counts 12.937 GiB at rung
+        `true`, 12.940 at `flash` and 13.400 at `dots`, the rung `auto`
+        picks, for steps this makes 13.11, 13.23 and 13.70 (my chip runs, PR
+        64: the floor's count + 1.3%, as PR 62 set it; without the term
+        `true` made 13.73)."""
         km, moe = self.cfg.kda_mla_moe, self._mods["moe"]
         wide = self.num_local_heads * km.head_dim
-        rule_inputs = (2 * 4 * wide          # projections and cotangents
+        rule_inputs = (4 * wide              # the four projections
                        + 2 * 3 * wide        # the convolutions' sums
                        + 3 * wide            # q, k, v by head
-                       + 2 * 2 * wide        # the float32 decay, its cotangent
+                       + 2 * wide            # the float32 decay
+                       + (3 + 2) * wide      # the rule's inputs' cotangents
                        + wide)               # the output gate
         chunk_rows = moe.chunk_share * moe.top_k
         return rule_inputs + chunk_rows * (
             2 * self.d + 3 * km.moe_intermediate_size / self.tp_size
-            ) - 41.93 * self.d / self.tp_size
+            ) - 31.87 * self.d / self.tp_size
 
     # ---- sub-module definitions ----
 

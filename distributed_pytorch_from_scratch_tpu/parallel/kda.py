@@ -57,6 +57,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.collectives import copy_to, reduce_from
@@ -67,10 +68,6 @@ from .linear import uniform_fan_in
 from .norm import GatedRMSNorm
 
 Params = Dict[str, Any]
-
-# the leaves the rule's inputs are made from (`_rule_inputs`)
-INPUT_LEAVES = ("w_q", "w_k", "w_v", "conv_q", "conv_k", "conv_v", "w_f",
-                "A_log", "dt_bias", "w_beta")
 
 
 def _inverse_softplus(dt: jax.Array) -> jax.Array:
@@ -158,16 +155,14 @@ class KimiDeltaAttention:
         """x (b, t, d), replicated over tp -> (the sublayer's output (b, t,
         d), reduced over tp; the decay's counters, scalars).
 
-        The rule's inputs are made under a `jax.checkpoint` of their own,
-        as `parallel/gdn.py`'s: the backward then holds `x` through the
-        rule's backward and makes the projections, the convolutions' sums
-        and the float32 decay (d_k wide a head and token) again after it."""
+        What of the rule's inputs a backward makes again is the layer's
+        remat rung's to say (`models/stack.remat_wrap`), and no checkpoint
+        of the mixer's own: the layer's recompute makes them once and the
+        backward transposes them where they stand."""
         b, t, d = x.shape
         with jax.named_scope("kda"):
             xd = copy_to(x.astype(compute_dtype), self.tp_axis)
-            q, k, v, g, beta = jax.checkpoint(
-                lambda p, xd: self._rule_inputs(p, xd, compute_dtype))(
-                    {n: params[n] for n in INPUT_LEAVES}, xd)
+            q, k, v, g, beta = self._rule_inputs(params, xd, compute_dtype)
             with jax.named_scope("gate"):
                 counters = self._counters(g)
         with jax.named_scope("kda_rule"):
@@ -200,9 +195,12 @@ class KimiDeltaAttention:
             "btd,dh...->bth...", xd, params[name].astype(compute_dtype))
         conv = lambda name, u: jax.nn.silu(causal_depthwise_conv(
             u, params[name])).astype(u.dtype)
-        q = conv("conv_q", project("w_q"))
-        k = conv("conv_k", project("w_k"))
-        v = conv("conv_v", project("w_v"))
+        # REMAT_LADDER's names, as the stack's own attention tags its
+        # projections: rung `dots` keeps them and its recompute starts at
+        # the convolutions
+        q = conv("conv_q", checkpoint_name(project("w_q"), "q_proj"))
+        k = conv("conv_k", checkpoint_name(project("w_k"), "k_proj"))
+        v = conv("conv_v", checkpoint_name(project("w_v"), "v_proj"))
         l2 = lambda u: u.astype(f32) * lax.rsqrt(jnp.sum(
             jnp.square(u.astype(f32)), axis=-1, keepdims=True) + self.eps)
         q = l2(q) * (1.0 / math.sqrt(dk))

@@ -14,6 +14,10 @@ expert, a multi-token-prediction module. CPU, tiny sizes.
   length; with every channel's decay equal it is the scalar rule; as the XLA
   text and as the Pallas kernels (ops/pallas/kda_rule.py) under the
   interpreter, at the widths they hold;
+* what a delta layer makes again is its remat rung's to say (PR 64: the
+  mixer keeps no checkpoint of its own): the forward products of `w_q`,
+  `w_k`, `w_v`, `w_f` counted in the train step's jaxpr at `true`, `flash`
+  and `dots`, and every rung's loss and gradients against `remat=False`'s;
 * the group-limited selection against a sort-based one, and today's at one
   group; `LatentAttention` with no q latent and a gate a head;
 * the shares of an expert layer add up to the uncut layer;
@@ -24,7 +28,9 @@ expert, a multi-token-prediction module. CPU, tiny sizes.
   remat pick (tests/test_mhc_mla_moe.py holds the other eight's).
 """
 
+import collections
 import dataclasses
+import functools
 import hashlib
 import json
 import re
@@ -33,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend import core as jex_core
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
@@ -147,6 +154,104 @@ def test_in_bfloat16_the_loss_is_the_references_to_bfloat16s_rounding():
     want = vanilla_loss(cfg, params, ids, tgt, pos)
     got = jax.jit(model.make_loss(mesh))(params, ids, tgt, pos)
     assert abs(float(got) - float(want)) <= 2e-2 * abs(float(want))
+
+
+# ---- what a delta layer makes again is the rung's to say ----
+
+# a weight stays the leaf it is through these on its way into a product
+_SAME_LEAF = {"convert_element_type", "squeeze", "reshape", "slice",
+              "dynamic_slice", "copy", "pvary"}
+
+
+def _count_products(jaxpr, leaf_of, counts):
+    """`counts[leaf, the axes of it a product contracts]` over `jaxpr` and
+    every jaxpr its equations hold (scan and checkpoint bodies, the
+    shard_map, custom derivatives), each body once: a scanned layer's
+    products count once a layer. `leaf_of`: variable -> the name of the
+    parameter leaf it is (a cast, a cut or one scanned layer of)."""
+    for eqn in jaxpr.eqns:
+        leaves = [None if isinstance(v, jex_core.Literal) else leaf_of.get(v)
+                  for v in eqn.invars]
+        if eqn.primitive.name == "dot_general":
+            contracted, _ = eqn.params["dimension_numbers"]
+            for leaf, axes in zip(leaves, contracted):
+                if leaf:
+                    counts[leaf, tuple(axes)] += 1
+        elif eqn.primitive.name in _SAME_LEAF and leaves[0]:
+            leaf_of[eqn.outvars[0]] = leaves[0]
+        for body in jax.core.jaxprs_in_params(eqn.params):
+            skipped = len(eqn.invars) - len(body.invars)  # a cond's index
+            if skipped >= 0:
+                _count_products(body, {
+                    v: leaf for v, leaf in zip(body.invars, leaves[skipped:])
+                    if leaf}, counts)
+
+
+@pytest.mark.parametrize("rung,forwards", [
+    (True, (2, 2, 2, 2)), ("flash", (2, 2, 2, 2)), ("dots", (1, 1, 1, 2))])
+def test_a_delta_layer_makes_its_rules_inputs_as_often_as_the_rung_says(
+        rung, forwards):
+    """The train step's jaxpr at the tiny preset, every delta segment: a
+    product that contracts a projection's first axis (d) is a FORWARD of it
+    (the transposes contract its heads, or do not hold it). Once in the
+    forward and once in the layer's recompute under `true` and `flash`;
+    under `dots` the recompute starts from the named q, k and v and only
+    the decay's projection, which carries no name, runs again. With a
+    checkpoint of the mixer's own (before PR 64) every count was 3, at
+    every rung: a name under an inner checkpoint is not the layer's policy's
+    to keep."""
+    mesh, model = on_mesh(tiny(), 1, remat=rung)
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    opt = jax.eval_shape(init_adam_state, params)
+    ids = jax.ShapeDtypeStruct((2, 128), np.int32)
+    step = build_train_step(model, mesh, OptimizerConfig())
+    args = (params, opt, ids, ids, ids)
+    closed = jax.make_jaxpr(step)(*args)
+    leaf_of = {}
+    for var, (path, _) in zip(closed.jaxpr.invars,
+                              jax.tree_util.tree_flatten_with_path(args)[0]):
+        if path[0].idx == 0:
+            leaf_of[var] = jax.tree_util.keystr(path[1:])
+    counts = collections.Counter()
+    _count_products(closed.jaxpr, leaf_of, counts)
+    for segment in ("dense_layers", "lead_kda_layers", "kda_layers"):
+        leaf = lambda name: f"['{segment}']['kda']['{name}']"
+        assert tuple(counts[leaf(n), (0,)] for n in (
+            "w_q", "w_k", "w_v", "w_f")) == forwards, (segment, rung)
+        # the output gate and `W_out` sit outside the rule's inputs: twice
+        # at every rung; and every projection is transposed once
+        assert counts[leaf("w_g"), (0,)] == counts[leaf("w_out"), (0,)] == 2
+        assert all(counts[leaf(n), (1, 2)] == 1 for n in (
+            "w_q", "w_k", "w_v", "w_f", "w_g"))
+
+
+@functools.lru_cache(maxsize=None)
+def _loss_and_grads(rung):
+    cfg = tiny(experts_held=8, expert_offset=4)
+    mesh, model = on_mesh(cfg, 1, remat=rung)
+    params = model.init(jax.random.key(3))
+    ids, tgt, pos = batch(cfg)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
+            params, ids, tgt, pos)
+
+
+@pytest.mark.parametrize("rung", [True, "flash", "dots"])
+def test_every_rung_is_the_unrematerialised_programs_numbers(rung):
+    """What a rung keeps changes when a tensor is made, never what it is:
+    the loss and every gradient leaf against `remat=False`'s, to the last
+    bit or to float32's rounding of a reordered sum (leaves to 1e-5 of
+    their largest entry; read: 2e-6 at `true`; the reference of
+    `test_loss_and_every_gradient_leaf_equal_the_reference` stands beside
+    it at the family's default)."""
+    want, want_g = _loss_and_grads(False)
+    got, got_g = _loss_and_grads(rung)
+    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
+                            jax.tree.leaves(got_g)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
+            jax.tree_util.keystr(path)
 
 
 def test_no_top_k_choice_sits_on_a_tie():
@@ -643,24 +748,35 @@ def test_remat_auto_sizes_the_benchmarks_cell(capsys):
     """`remat="auto"` at the cell's shapes on a v5e's 15.75 GiB walks the
     ladder with the delta layer's own count (`layer_extra_elems_per_token`:
     a float32 decay 4096 wide a token among it, less what the chip's
-    reading takes back off: PR 62) and, with no reserve held beside 8.57
-    GiB of state, keeps the one latent layer's flash outputs and the dense
-    layer's gate and up: 13.07 GiB where the chip counts 12.84."""
+    reading takes back off: PR 64) and, with no reserve held beside 8.57
+    GiB of state, keeps q, k and v of the five delta layers (fifteen stacks
+    of 33.5 MB, at the heads' whole width 32 x 128) on top of the one
+    latent layer's flash outputs and the dense layer's gate and up: 13.70
+    GiB where the chip counts 13.40 (12.937 at `true`, 12.940 at `flash`:
+    my chip runs, PR 64)."""
     cfg = dataclasses.replace(published(), compute_dtype="bfloat16")
     model = build_model(FAMILY, cfg, remat_budget_gib=15.748)
     assert model.layer_extra_elems_per_token == pytest.approx(
-        91040 - 41.93 * 2560)
-    assert model.tagged_layers["flash_out"] == 1 == \
-        model.tagged_layers["ffn_gate"] and not model.tagged_layers["q_proj"]
+        86944 - 31.87 * 2560)
+    tagged = model.tagged_layers
+    assert tagged["flash_out"] == 1 == tagged["ffn_gate"]
+    # (a latent layer's q, k, v carry no name: the five are the delta ones)
+    assert tagged["q_proj"] == tagged["k_proj"] == tagged["v_proj"] == 5
+    assert model.head_dim == 128 and model.kv_dim == 4096
     layer_params = cfg.num_params() - 2 * 19648 * 2560 - 2560
     memory.select_remat_traced.cache_clear()
     rung = memory.select_remat_traced(model, cfg.num_params(), layer_params,
                                       1, 4096)
     said = capsys.readouterr().err
-    # (`dots` keeps the same: q, k, v of a latent layer carry no name)
-    assert rung == "flash" and "reserve_held=False" in said, said
-    estimate = float(said.split(f"{rung}=")[1].split("GiB")[0])
-    assert 0.99 * 12.84 < estimate < 1.05 * 12.84, said
+    assert rung == "dots" and "reserve_held=False" in said, said
+    estimate = {name: float(said.split(f"{name}=")[1].split("GiB")[0])
+                for name in ("true", "flash", "dots")}
+    for name, chip in (("true", 12.937), ("flash", 12.940), ("dots", 13.400)):
+        assert 0.99 * chip < estimate[name] < 1.05 * chip, said
+    # the names are priced at their logical size, fifteen stacks of 4096 x
+    # 4096 in bfloat16 (0.469 GiB): the chip's `dots` is `flash` + 0.460
+    assert estimate["dots"] - estimate["flash"] == pytest.approx(
+        15 * 4096 * 4096 * 2 / memory.GIB, abs=0.011)
 
 
 # ---- what must not move ----

@@ -638,7 +638,10 @@ STANDING = {
     "swa_moe": ("tiny-swa-moe", "aa6b69d47db07d8b"),
     "early_moe": ("tiny-early-moe", "ed2c6e2f8e13659b"),
     "mhc_mla_moe": ("tiny-mhc-mla-moe", "c96270e5156e5692"),
-    "kda_mla_moe": ("tiny-kda-mla-moe", "29fbd62e921a3dd5"),
+    # PR 64 MEANT to move this one (29fbd62e921a3dd5 before it): the delta
+    # mixer's own checkpoint went and its q, k, v projections took the
+    # ladder's names (parallel/kda.py); the other eight are the parent's
+    "kda_mla_moe": ("tiny-kda-mla-moe", "5d125d7a82435e72"),
 }
 
 
