@@ -340,6 +340,24 @@ class EarlyMoEConfig:
 
 
 @dataclass(frozen=True)
+class LoopLlamaConfig:
+    """What the `loop_llama` family (models/loop_llama.py) needs beyond
+    `ModelConfig`'s own fields: the llama block (RoPE, RMSNorm, SwiGLU, an
+    untied head, no bias anywhere) with a norm behind each sublayer too,
+    whose stack of `num_layers` layers is RUN `loop_steps` TIMES A STEP
+    over the same weights, the final norm after every pass, an exit (the
+    one head) at the end of every pass and a learned exit gate whose
+    distribution over the passes weighs the exits' losses, less
+    `exit_entropy_coef` times that distribution's entropy (Ouro's
+    `total_ut_steps`, and the beta of its Stage-I objective). A dense
+    family: it holds no share of any expert."""
+
+    loop_steps: int = 4
+    exit_entropy_coef: float = 0.05
+    rms_norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -387,6 +405,8 @@ class ModelConfig:
     kda_mla_moe: "KdaMlaMoEConfig | None" = None
     # The `ssm_moe` family's facts (None for every other family).
     ssm_moe: "SsmMoEConfig | None" = None
+    # The `loop_llama` family's facts (None for every other family).
+    loop_llama: "LoopLlamaConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -417,7 +437,8 @@ class ModelConfig:
         """Routed experts this job holds: all of them, unless the family's
         facts name a share with `experts_held`."""
         share = self.family_facts and getattr(self, self.family_facts)
-        if not share or share.experts_held is None:
+        # (a dense family's facts name no share)
+        if not share or getattr(share, "experts_held", None) is None:
             return self.num_experts
         return share.experts_held
 
@@ -425,7 +446,7 @@ class ModelConfig:
     def expert_offset(self) -> int:
         """The first routed expert this job holds."""
         share = self.family_facts and getattr(self, self.family_facts)
-        return share.expert_offset if share else 0
+        return getattr(share, "expert_offset", 0) if share else 0
 
     def padded_vocab_size(self, tp_size: int) -> int:
         """Vocab size rounded up to a multiple of tp_size.
@@ -455,7 +476,7 @@ class ModelConfig:
 
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
-                "early_moe", "kda_mla_moe", "ssm_moe")
+                "early_moe", "kda_mla_moe", "ssm_moe", "loop_llama")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -597,6 +618,14 @@ MODEL_PRESETS = {
             moe_intermediate_size=48, moe_latent_size=32,
             moe_shared_expert_intermediate_size=96, chunk_size=32,
             routed_scaling_factor=2.5, num_nextn_predict_layers=1)),
+    # the `loop_llama` family at a CPU size: two llama layers with four
+    # norms each (4 heads of 16, SwiGLU 128 wide), passed three times a step
+    # (three: a pass count of 1 or 2 cannot agree by accident), an exit
+    # after every pass and the exit gate
+    "tiny-loop-llama": ModelConfig(
+        attn_dim=64, ffn_dim=128, num_heads=4, num_layers=2,
+        vocab_size=1024, maxlen=256, rope_theta=1e6,
+        loop_llama=LoopLlamaConfig(loop_steps=3)),
 }
 
 

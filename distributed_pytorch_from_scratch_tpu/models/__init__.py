@@ -1,8 +1,9 @@
 """The model families, and the one place a family's name becomes a class:
-`llama` (the reference's block) and `gpt2`, and nine drawn from published
-configurations, each holding one share of the experts its router scores:
-`mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
-`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe` (docs/DESIGN.md, "What a family
+`llama` (the reference's block) and `gpt2`, and ten drawn from published
+configurations: nine that each hold one share of the experts their router
+scores, `mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
+`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe`, and the dense `loop_llama`,
+whose stack is passed several times a step (docs/DESIGN.md, "What a family
 file holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
@@ -11,6 +12,7 @@ from .early_moe import EarlyRouterMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
 from .kda_mla_moe import KdaMlaMoETransformer
+from .loop_llama import LoopedTransformer
 from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
 from .ssm_moe import SsmMoETransformer
@@ -22,7 +24,8 @@ FAMILIES = {cls.family: cls for cls in (
     Transformer, GPT2Transformer, LatentMoETransformer, GdnMoETransformer,
     ConvMoETransformer, BlockDiffusionMoETransformer,
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
-    HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer)}
+    HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer,
+    LoopedTransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -23,8 +23,10 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   kind)`, `unrotated_kinds`), the speed of its routers' selection bias
   (`router_bias_speed`), whether its routers read the layer's input
   (`router_reads_layer_input`), the mixer of its residual streams where
-  a layer's residual state is several (`stream_mixer`) and whether a layer
-  is ONE norm and ONE sublayer (`one_sublayer`);
+  a layer's residual state is several (`stream_mixer`), whether a layer
+  is ONE norm and ONE sublayer (`one_sublayer`) and how often a step runs
+  the pattern over the same weights, with an exit after every pass
+  (`loop_steps`; the exits' gate and objective: `exit_entropy_coef`);
 * `_mods`, the per-layer modules (the attention projections are
   `wq`/`wk`/`wv`/`wo` wherever the stack's (q, k, v) dispatch runs, which
   `models/decode.py` and `interop.py` read by name), and its mixer where
@@ -358,6 +360,19 @@ def idle_expert_params(cfg: ModelConfig, expert_layers: int,
                             / cfg.num_experts) * (3 * cfg.attn_dim * width)
 
 
+def exit_distribution(z: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(`p`, `log p`), each (R, ...), of the exit distribution from the exit
+    gate's logits `z` (R, ...): `log p_r = log lam_r + sum_{j<r} log(1 -
+    lam_j)` with `lam = sigmoid(z)`, and the last pass takes the remainder,
+    `log p_R = sum_{j<R} log(1 - lam_j)` (its own gate's logit is not
+    read). Made of log-sigmoids, so one pass gives `p = 1` exactly."""
+    stay = jax.nn.log_sigmoid(-z[:-1])          # log(1 - lam_j), j < R
+    left = jnp.concatenate([jnp.zeros_like(z[:1]), jnp.cumsum(stay, axis=0)])
+    log_p = left + jnp.concatenate(
+        [jax.nn.log_sigmoid(z[:-1]), jnp.zeros_like(z[:1])])
+    return jnp.exp(log_p), log_p
+
+
 @dataclass(frozen=True)
 class TPSublayers:
     """How a layer's sublayers meet the 'tp' axis, from the resolved
@@ -620,6 +635,30 @@ class DecoderStack:
     # nothing has no row: `_rows_in_order`) and `tagged_layers` read.
     # False: the layer every other family has, the program it has always been
     one_sublayer = False
+    # How often a step RUNS THE PATTERN, over the same weights (a looped
+    # language model, Ouro's `total_ut_steps`): `_trunk` then scans R passes
+    # around the scan of layers, `final_norm` after every pass (the normed
+    # state is what the next pass reads) and hands back the R normed states
+    # (R, b, t, d); every state leaves through the one head (an exit a
+    # pass) and the loss weighs the exits' per-token CEs by the learned
+    # exit gate's distribution over the passes (`_loop_loss`; the tree then
+    # holds `exit_gate`, d + 1 float32 parameters, replicated), less
+    # `exit_entropy_coef` times that distribution's entropy. A weight's
+    # gradient is then a sum over R uses, accumulated in float32 by the
+    # outer scan's transpose. `training/memory.py` reads it (R x L kept
+    # layer inputs, the R states), `flops_per_step` is the family's. The
+    # family declares it ONCE, from its configuration alone (`passes(cfg)`,
+    # which `obs/attribution.py` asks with no model in hand); None: the
+    # pattern runs once, the program it has always been
+    @staticmethod
+    def passes(cfg: ModelConfig) -> "int | None":
+        return None
+
+    @property
+    def loop_steps(self) -> "int | None":
+        return self.passes(self.cfg)
+
+    exit_entropy_coef = 0.0
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -677,7 +716,8 @@ class DecoderStack:
                 raise ValueError(f"the {self.family} family needs "
                                  f"cfg.{self.config_extra} (its facts: "
                                  f"config.py)")
-            if not cfg.num_experts:
+            if not cfg.num_experts and hasattr(
+                    getattr(cfg, self.config_extra), "experts_held"):
                 raise ValueError(
                     f"the {self.family} family needs cfg.num_experts > 0 "
                     f"(the routed experts its router scores)")
@@ -696,6 +736,15 @@ class DecoderStack:
                 f"the {self.family} family's layers are one sublayer each: "
                 f"the stream mixers' two joints and a router that reads "
                 f"what entered the attention half take a layer of two")
+        if self.loop_steps is not None and (
+                self.loop_steps < 1 or self.is_moe or self.stream_mixer
+                or self.draws_noise):
+            raise ValueError(
+                f"the {self.family} family passes its stack "
+                f"{self.loop_steps} times a step: at least once, over dense "
+                f"layers of one residual stream and a loss that draws no "
+                f"noise (the layers' counters, the streams' exit and a "
+                f"weight a position have no R exits to join)")
         validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -917,7 +966,9 @@ class DecoderStack:
                 **{name: self._init_layers(key, name, count, names)
                    for name, count, names in self._segments},
                 "norm": self.final_norm.init(fold(key, "norm")),
-                **head, **leave, **self._init_more(key)}
+                **head, **leave, **self._exit_gate(
+                    lambda: self._init_exit_gate(fold(key, "exit_gate"))),
+                **self._init_more(key)}
 
     def specs(self) -> Params:
         """PartitionSpec pytree matching `init`'s structure."""
@@ -927,11 +978,28 @@ class DecoderStack:
                 **{name: self._layer_specs(names, name)
                    for name, _, names in self._segments},
                 "norm": self.final_norm.specs(),
-                **head, **leave, **self._more_specs()}
+                **head, **leave,
+                **self._exit_gate(lambda: {"weight": P(None), "bias": P()}),
+                **self._more_specs()}
 
     def _init_more(self, key: jax.Array) -> Params:
         """Top-level groups of the family's own, after the head."""
         return {}
+
+    def _exit_gate(self, make) -> Params:
+        """`{"exit_gate": make()}` where the stack is passed `loop_steps`
+        times, else nothing."""
+        return {} if self.loop_steps is None else {"exit_gate": make()}
+
+    def _init_exit_gate(self, key: jax.Array) -> Params:
+        """The exit gate, a `Linear(d, 1)` with bias on a pass's normed
+        state, float32 and replicated: a linear's uniform draw, so a fresh
+        gate's `lam` lies about 1/2."""
+        bound = self.d ** -0.5
+        kw, kb = jax.random.split(key)
+        draw = lambda k, shape: jax.random.uniform(
+            k, shape, jnp.float32, -bound, bound)
+        return {"weight": draw(kw, (self.d,)), "bias": draw(kb, ())}
 
     def _more_specs(self) -> Params:
         return {}
@@ -1446,6 +1514,10 @@ class DecoderStack:
         self = self._resolved(input_ids.shape[1])
         x, aux, trunk = self._trunk(params, input_ids, position_ids,
                                     head_layout)
+        if self.loop_steps is not None:
+            # the last pass's exit (its state is already normed)
+            with jax.named_scope("head_loss"):
+                return self._masked_logits(params, x[-1], trunk.dtype), aux
         return self._head(params, params["norm"], x, trunk.dtype), aux
 
     def _trunk(self, params: Params, input_ids: jax.Array,
@@ -1454,7 +1526,9 @@ class DecoderStack:
         last layer's output, aux, what a further segment over the same
         batch runs with: `SimpleNamespace(dtype, run)` where `run(z,
         layers)` scans `layers` from `z` under this trace's remat rung and
-        positions). `_head` turns the output into logits."""
+        positions). `_head` turns the output into logits. A family that
+        passes its stack `loop_steps` times gets the R normed states, (R,
+        b, t, d), in the output's place (`_loop_passes`)."""
         dtype = resolve_dtype(self.cfg.compute_dtype)
         sp = self.sequence_parallel
         if sp and input_ids.shape[1] % self.tp_size != 0:
@@ -1478,7 +1552,8 @@ class DecoderStack:
             # microbatch's rows
             layer_fn = remat_wrap(
                 self._layer_body, rung, static_argnums=(4, 6),
-                looped=jax.tree.leaves(layers)[0].shape[0] > 1)
+                looped=jax.tree.leaves(layers)[0].shape[0] > 1
+                or (self.loop_steps or 1) > 1)
 
             def body(carry, lp):
                 return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind)
@@ -1494,18 +1569,41 @@ class DecoderStack:
                                            (*layer_pos, position_ids),
                                            head_layout=head_layout)
         else:
-            auxs = []
-            for block in self._pattern:
-                x, aux = (run(x, params[block], block)
-                          if isinstance(block, str)
-                          else self._scan_periods(run, x, params, block))
-                auxs.append(aux)
-            # a block of dense layers has none; where several blocks count
-            # (a row a layer), the rows follow the layers
-            auxs = [aux for aux in auxs if aux is not None]
-            aux = (_rows_in_order(auxs) if len(auxs) > 1
-                   else auxs[0] if auxs else None)
+            def one_pass(x):
+                auxs = []
+                for block in self._pattern:
+                    x, aux = (run(x, params[block], block)
+                              if isinstance(block, str)
+                              else self._scan_periods(run, x, params, block))
+                    auxs.append(aux)
+                # a block of dense layers has none; where several blocks
+                # count (a row a layer), the rows follow the layers
+                auxs = [aux for aux in auxs if aux is not None]
+                return x, (_rows_in_order(auxs) if len(auxs) > 1
+                           else auxs[0] if auxs else None)
+
+            x, aux = (one_pass(x) if self.loop_steps is None
+                      else self._loop_passes(one_pass, params, x))
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
+
+    def _loop_passes(self, one_pass, params: Params, x: jax.Array):
+        """`loop_steps` passes of the pattern over the SAME parameters, the
+        final norm after every pass: `h_r = N_f(Layers(h_{r-1}))`. One scan
+        over passes around the scans of layers, so one traced copy of the
+        layer body; the layers' parameters are the scan's constants, whose
+        cotangent its transpose accumulates over the passes in the
+        parameters' own float32. Returns the R normed states, (R, b, t, d),
+        and no aux (the layers are dense). The norm runs under `head_loss`:
+        it is the exits' (the scope a device trace splits the step by)."""
+        def body(z, _):
+            with jax.named_scope("loop_pass"):
+                z, _ = one_pass(z)
+            with jax.named_scope("head_loss"):
+                z = self.final_norm.apply(params["norm"], z)
+            return z, z
+
+        _, states = lax.scan(body, x, None, length=self.loop_steps)
+        return states, None
 
     def _scan_periods(self, run, x: jax.Array, params: Params, period):
         """One scan over the periods of a `_pattern` block: the body runs
@@ -1542,15 +1640,19 @@ class DecoderStack:
         with (jax.named_scope(scope) if scope
               else contextlib.nullcontext()):
             x = self.final_norm.apply(norm_params, x)
-            logits = self._head_logits(params, x, dtype)
+            return self._masked_logits(params, x, dtype)
 
-            # Mask padded vocab entries so they carry no probability mass.
-            if self.vocab_padded != self.cfg.vocab_size:
-                local_v = self.vocab_padded // self.tp_size
-                start = lax.axis_index("tp") * local_v
-                col = start + jnp.arange(local_v)
-                logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
-                                   logits, jnp.asarray(NEG_INF, logits.dtype))
+    def _masked_logits(self, params: Params, x: jax.Array,
+                       dtype) -> jax.Array:
+        """The head on a normed state: LOCAL logits, the padded vocabulary
+        entries masked so they carry no probability mass."""
+        logits = self._head_logits(params, x, dtype)
+        if self.vocab_padded != self.cfg.vocab_size:
+            local_v = self.vocab_padded // self.tp_size
+            start = lax.axis_index("tp") * local_v
+            col = start + jnp.arange(local_v)
+            logits = jnp.where(col[None, None, :] < self.cfg.vocab_size,
+                               logits, jnp.asarray(NEG_INF, logits.dtype))
         return logits
 
     def _head_logits(self, params: Params, x: jax.Array, dtype) -> jax.Array:
@@ -1889,6 +1991,9 @@ class DecoderStack:
         x, aux, trunk = self._trunk(
             params, input_ids, position_ids,
             head_layout="pp_scatter" if pp_scatter else "replicated")
+        if self.loop_steps is not None:
+            return self._loop_loss(params, x, target_ids, trunk.dtype, mode,
+                                   batch_axes, with_counters)
         if weight is not None:
             x = x[:, :target_ids.shape[1]]
         logits = self._head(params, params["norm"], x, trunk.dtype)
@@ -1948,6 +2053,75 @@ class DecoderStack:
             return loss, jax.tree.map(lax.stop_gradient,
                                       {**counters, **more})
         return loss
+
+    def _loop_loss(self, params: Params, states: jax.Array,
+                   target_ids: jax.Array, dtype, mode: str, batch_axes,
+                   with_counters: bool):
+        """The loss of a stack passed R = `loop_steps` times, from its R
+        normed states (R, b, t, d): an exit a pass through the one head,
+        `l_r[i]` the per-token CE of exit r; the exit gate a token at a
+        time, `lam_r[i] = sigmoid(w_g . h_r[i] + b_g)`, `p_r = lam_r
+        prod_{j<r} (1 - lam_j)` and the last pass takes what is left; and
+
+            loss = mean_i [ sum_r p_r[i] l_r[i] - beta H(p[i]) ]
+
+        over the tokens that are not ignored (`exit_entropy_coef` = beta, H
+        the entropy: a uniform prior over the exit steps). The gate gets
+        gradient from both terms, the layers from all R exits through all
+        later passes.
+
+        An exit's logits are made again in the backward, not kept: the
+        exits are one scan whose body is a `jax.checkpoint`, so the peak
+        holds ONE exit's float32 logits beside their cotangent, not R. The
+        gate and `p` are float32 (a product over the width on the vector
+        unit, not a matmul at the default precision) and `log p` is made of
+        log-sigmoids: R = 1 gives p = 1 and H = 0 exactly. Under sequence
+        parallelism a shard holds its own rows of the states and the whole
+        rows of the CEs: it weighs its own rows and the sums go over 'tp'
+        too.
+
+        Counters (`with_counters`): `loss_main` (the whole objective),
+        `loss_exit` (R: each exit's mean CE), `exit_p_mean` (R: the mean of
+        `p_r` over the tokens) and `exit_entropy` (the mean H)."""
+        R = self.loop_steps
+
+        def exit_ce(h):
+            return self._token_ce(self._masked_logits(params, h, dtype),
+                                  target_ids, mode)[0]
+
+        with jax.named_scope("head_loss"):
+            # (a scan of one pass is no loop once XLA has simplified it:
+            # the barrier stays there, as `remat_wrap` says of a layer)
+            ces = lax.map(jax.checkpoint(exit_ce, prevent_cse=R == 1),
+                          states)                           # (R, b, t) f32
+            valid = target_ids != IGNORE_INDEX
+            if self.sequence_parallel:
+                tl = states.shape[2]
+                own = lambda a: lax.dynamic_slice_in_dim(
+                    a, lax.axis_index("tp") * tl, tl, axis=-1)
+                ces, valid = own(ces), own(valid)
+                batch_axes = tuple(batch_axes) + ("tp",)
+            with jax.named_scope("exit_gate"):
+                gate = params["exit_gate"]
+                z = (jnp.sum(states.astype(jnp.float32) * gate["weight"],
+                             axis=-1) + gate["bias"])       # (R, b, t)
+                p, log_p = exit_distribution(z)
+                entropy = -jnp.sum(p * log_p, axis=0)           # (b, t)
+            token = jnp.sum(p * ces, axis=0) - self.exit_entropy_coef * entropy
+            live = valid.astype(jnp.float32)
+            sums = lax.psum(
+                {"loss": jnp.sum(token * live), "count": jnp.sum(live),
+                 "exit": jnp.sum(ces * live, axis=(1, 2)),
+                 "p": jnp.sum(p * live, axis=(1, 2)),
+                 "entropy": jnp.sum(entropy * live)}, batch_axes)
+        count = jnp.maximum(sums["count"], 1.0)
+        loss = sums["loss"] / count
+        if not with_counters:
+            return loss
+        return loss, jax.tree.map(lax.stop_gradient, {
+            "loss_main": loss, "loss_exit": sums["exit"] / count,
+            "exit_p_mean": sums["p"] / count,
+            "exit_entropy": sums["entropy"] / count})
 
     def _extra_loss(self, params: Params, loss: jax.Array, x: jax.Array,
                     aux, trunk, input_ids, target_ids, position_ids,

@@ -171,8 +171,13 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
                     family: str = "llama") -> List[PhaseCost]:
     """Per-phase FLOPs + HBM bytes for ONE fwd+bwd+adam train step (global,
     all devices), itemised so shares can be compared against measured
-    fwd/bwd/adam times. remat is 'false' or a REMAT_LADDER rung's name."""
-    d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers
+    fwd/bwd/adam times. remat is 'false' or a REMAT_LADDER rung's name. A
+    family whose stack a step passes R times over the same weights
+    (`DecoderStack.passes`) runs every layer phase R x L times and the
+    head and the CE R times (an exit a pass); the embedding and Adam once."""
+    from ..models import family_class
+    R = family_class(family).passes(cfg) or 1
+    d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers * R
     h, hd, kd = cfg.num_heads, cfg.head_dim, cfg.kv_dim
     v = cfg.padded_vocab_size(1)
     N = batch * t            # tokens incl. any bucket padding
@@ -180,7 +185,6 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
     P = cfg.num_params()
     # the matrices that read the MLP's input plus the one that writes its
     # output: SwiGLU = gate/up/down, 3 matmuls; a fc/proj gelu MLP, 2
-    from ..models import family_class
     ffn_mats = family_class(family).ffn_inputs + 1
 
     stats = flash_tile_stats(t, block_q, block_k, t_real, hd,
@@ -205,8 +209,9 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
                        + ffn_mats * d * f * A)),
         PhaseCost("norms_rope", L * 16 * N * d, L * 6 * N * d * A,
                   "elementwise; bytes-bound"),
-        PhaseCost("lm_head", 2 * N * d * v, N * d * A + N * v * 4),
-        PhaseCost("ce_loss", 8 * N * v, 2 * N * v * 4,
+        PhaseCost("lm_head", R * 2 * N * d * v,
+                  R * (N * d * A + N * v * 4)),
+        PhaseCost("ce_loss", R * 8 * N * v, R * 2 * N * v * 4,
                   "f32 logits read+reduce"),
     ]
     # attention FLOPs scale by L too (itemised per layer above except attn)

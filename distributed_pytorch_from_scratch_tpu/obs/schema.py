@@ -114,6 +114,11 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     "moe_counters": ("load_max_over_mean", "rows_here_per_token",
                      "rows_computed_per_token", "rows_walked_per_token",
                      "sum_windows_per_block"),
+    # -- ISSUE 66: the counters of a stack passed R times a step at the log
+    # interval (training/metrics.loop_counters_summary): the objective, the
+    # exit distribution's mean entropy and the mean exit step; beside them
+    # `loss_exit_<r>` and `exit_p_<r>`, a pair a pass
+    "loop_counters": ("loss_main", "exit_entropy", "exit_step_mean"),
     # -- ISSUE 37: `train()`'s step function built again after its steady
     # program was in hand (a tail window, a new sequence bucket), at `step`;
     # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)

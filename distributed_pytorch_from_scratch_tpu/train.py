@@ -47,7 +47,8 @@ from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
 from .training.checkpoint import (AsyncCheckpointer, latest_step,
                                   load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
-                               bd_counters_summary, moe_counters_summary,
+                               bd_counters_summary, loop_counters_summary,
+                               moe_counters_summary,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
                                param_bytes_by_device, publish_hbm)
@@ -1299,7 +1300,15 @@ def train(args: argparse.Namespace) -> dict:
                                  "available": marks is not None})
                         if gnorm is not None:
                             writer.scalar("train/grad_norm", gnorm, n)
-                        if last_counters is not None:
+                        if (last_counters is not None
+                                and "loss_exit" in last_counters):
+                            # a stack passed R times: its exits and gate
+                            loop = loop_counters_summary(
+                                jax.device_get(last_counters))
+                            print("  " + ", ".join(
+                                f"{k} {v:.4g}" for k, v in loop.items()))
+                            writer.event("loop_counters", step=n, **loop)
+                        elif last_counters is not None:
                             moe = moe_counters_summary(
                                 jax.device_get(last_counters), cfg,
                                 window["input_ids"].size)

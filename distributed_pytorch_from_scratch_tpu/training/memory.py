@@ -94,7 +94,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                head_rows_share: float = 1.0,
                residual_streams: int = 1,
                tagged_layers: Optional[Dict[str, float]] = None,
-               v_head_dim: Optional[int] = None) -> Dict[str, float]:
+               v_head_dim: Optional[int] = None,
+               passes: Optional[int] = None) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -127,10 +128,26 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               (`DecoderStack.layer_extra_elems_per_token`)
     The peak is resident + cast + stacks + max(head, grads + layer): the
     head's backward is over before the layers' gradients exist.
+
+    `passes` (`DecoderStack.loop_steps`: a family whose stack a step passes
+    R times over the same weights; None: once): the kept layer input and
+    every rung's named stack are R x `layers` deep (a scan of passes around
+    the scan of layers keeps each pass's), `stacks` also holds what each
+    pass keeps around its final norm, R times the norm's input and its
+    output (the R normed states the exits read), and those states once more
+    in float32 (the exit gate's product over the width reads them so, and
+    its backward again), the head's transient is still ONE exit's (an
+    exit's logits are made again in the backward), and the layers' backward
+    holds the stacked layers' gradient twice: what a pass's backward scan
+    stacks, and the sum over the passes it is added to. Held to the chip at
+    the one cell that passes (cell 14 at rung `true`: 12.438 GiB counted,
+    12.415 made; PERF.md section 5, PR 66).
     """
     from ..models.transformer import REMAT_LADDER
     rung = _rung_index(remat)
     tok = b * t
+    R = passes or 1
+    depth = layers * R
     act = 1.0 / tp if sequence_parallel else 1.0
     wide = tok * d * dtype_bytes * act       # a (b, t, d) tensor
     # a column-linear's output (the heads' whole width: the model's, unless
@@ -156,16 +173,21 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         per_layer = ((4 + residual_streams) * wide + 2 * q_w + 4 * kv_w
                      + names["flash_out"] + names["flash_lse"]
                      + (ffn_inputs + 1) * f_w)
-        stacks = layers * per_layer
+        stacks = depth * per_layer
     else:
         tagged = tagged_layers or {}
-        stacks = layers * residual_streams * wide + sum(
-            tagged.get(n, layers) * names[n]
+        stacks = depth * residual_streams * wide + sum(
+            tagged.get(n, layers) * R * names[n]
             for _, ns in REMAT_LADDER[:rung + 1] for n in ns)
+    pass_grads = 0.0
+    if passes is not None:
+        stacks += (2 + 4 / dtype_bytes) * passes * wide
+        if passes > 1:
+            pass_grads = layer_param_count * grad_bytes_per_param
     out = {
         "resident": param_count * (state_bytes_per_param
                                    - grad_bytes_per_param),
-        "grads": param_count * grad_bytes_per_param,
+        "grads": param_count * grad_bytes_per_param + pass_grads,
         "cast": (layer_param_count * dtype_bytes if dtype_bytes < 4
                  else 0.0),
         "stacks": stacks,
@@ -369,4 +391,4 @@ def traced_step_bytes(model, param_count: int, layer_param_count: int,
         residual_streams=model.residual_streams,
         tagged_layers={name: n // pp
                        for name, n in model.tagged_layers.items()},
-        v_head_dim=model.v_head_dim)
+        v_head_dim=model.v_head_dim, passes=model.loop_steps)

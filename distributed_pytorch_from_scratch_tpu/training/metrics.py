@@ -161,6 +161,25 @@ def bd_counters_summary(counters: dict) -> dict:
             / max(float(counters["weight_sum"]), 1e-9)}
 
 
+def loop_counters_summary(counters: dict) -> dict:
+    """The counters of a stack passed R times a step (`DecoderStack.
+    _loop_loss`) as a log line's numbers: the whole objective, each exit's
+    mean CE (`loss_exit_<r>`, r from 1), the mean of the exit
+    distribution a pass (`exit_p_<r>`), its mean entropy, and the mean
+    exit step `sum_r r p_r` (1.875 of 4 where the gate says 1/2
+    everywhere)."""
+    import numpy as np
+
+    p = np.asarray(counters["exit_p_mean"], np.float64)
+    out = {"loss_main": float(counters["loss_main"]),
+           "exit_entropy": float(counters["exit_entropy"]),
+           "exit_step_mean": float(np.sum(p * np.arange(1, len(p) + 1)))}
+    for r, (ce, share) in enumerate(zip(counters["loss_exit"], p), 1):
+        out[f"loss_exit_{r}"] = float(ce)
+        out[f"exit_p_{r}"] = float(share)
+    return out
+
+
 def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     """The step's counters of an expert model (`DecoderStack.loss_shard`
     with `with_counters`, fetched to the host) as the few numbers a log

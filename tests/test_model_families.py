@@ -18,6 +18,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          KdaMlaMoEConfig,
                                                          LatentMoEConfig,
                                                          ModelConfig,
+                                                         LoopLlamaConfig,
                                                          SsmMoEConfig,
                                                          SwaMoEConfig,
                                                          model_preset)
@@ -91,6 +92,9 @@ SSM = dict(hybrid_override_pattern="EMEM*", mamba_num_heads=4,
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "loop_llama":
+        # a dense family with facts: three passes, and no expert to hold
+        return ModelConfig(**TINY, loop_llama=LoopLlamaConfig(loop_steps=3))
     if extra == "ssm_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2,
                            **{**TINY, "num_layers": 5, "ffn_dim": 24},
@@ -130,7 +134,8 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
                 "early_moe": "tiny-early-moe",
                 "mhc_mla_moe": "tiny-mhc-mla-moe",
-                "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe"}
+                "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe",
+                "loop_llama": "tiny-loop-llama"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -285,8 +290,17 @@ def test_the_stack_raises_a_familys_refusal_in_the_familys_words(family,
     asked = {k: v for k, v in REFUSED_BY[what].items()
              if what == "tp_size > 1" or k != "tp_size"
              or "tp_size > 1" not in cls.refuses}
+    dense = not config_for(family, "moe8").num_experts
+    if what == "ep_size > 1" and dense:
+        # a dense family has nothing to shard over 'ep': the stack's own
+        with pytest.raises(ValueError, match="ep_size > 1 requires "
+                                             "cfg.num_experts > 0"):
+            build_model(family, config_for(family, "moe8"), **asked)
+        return
     if what not in cls.refuses:     # every other family shards over `tp`
-        assert what == "tp_size > 1"
+        # (and a dense one takes llama's sequence parallelism with it)
+        assert what == "tp_size > 1" or (
+            dense and what == "sequence_parallel=True")
         assert build_model(family, config_for(family, "moe8"),
                            **asked).tp_size == 2
         return
@@ -304,6 +318,9 @@ def test_a_family_with_facts_needs_them_and_its_experts(family):
     with pytest.raises(ValueError, match=f"the {family} family needs "
                                          f"cfg.{extra} "):
         build_model(family, CONFIGS["moe8"])
+    if not hasattr(getattr(config_for(family, "dense"), extra),
+                   "experts_held"):
+        return      # a dense family's facts name no share of any expert
     with pytest.raises(ValueError, match=f"the {family} family needs "
                                          f"cfg.num_experts > 0"):
         build_model(family, dataclasses.replace(config_for(family, "dense"),

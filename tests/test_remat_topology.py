@@ -542,6 +542,55 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels, kernels
 
 
+def test_the_loop_cells_step_fits_a_v5e_at_the_rung_auto_picks(
+        topo, described_tpu):
+    """Cell 14's step (`ouro-2.6b.train-loop4-b1-t4096`: the loop_llama
+    family at the published widths, 8 layers passed 4 times, 1 x 4096
+    tokens, bf16) compiled for the described chip: a scan of passes around
+    the scan of layers and the exits as one scan under a checkpoint fit the
+    chip at the floor, which is what `remat="auto"` picks beside 6.84 GiB of
+    state; ONE flash forward and ONE backward kernel in the text (one traced
+    copy of the layer body, not four), and no float32 (4, 1, 4096, 49152)
+    tensor: one exit's logits at a time. The chip itself counts 12.438 GiB
+    (PERF.md section 5, PR 66)."""
+    from distributed_pytorch_from_scratch_tpu.config import LoopLlamaConfig
+    from distributed_pytorch_from_scratch_tpu.models import build_model
+    cfg = ModelConfig(
+        attn_dim=2048, ffn_dim=5632, num_heads=16, num_kv_heads=16,
+        num_layers=8, vocab_size=49152, maxlen=65536, rope_theta=1e6,
+        compute_dtype="bfloat16", loop_llama=LoopLlamaConfig(loop_steps=4))
+    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=topo.devices[:1])
+    model = build_model("loop_llama", cfg, remat_budget_gib=V5E_LIMIT_GIB)
+    params = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(model.init, jax.random.key(0)), model.shardings(mesh))
+    scalar = NamedSharding(mesh, P())
+    opt = AdamState(step=jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar),
+                    mu=params, nu=params)
+    ids = jax.ShapeDtypeStruct((1, 4096), jnp.int32, sharding=NamedSharding(
+        mesh, P(("dp", "ep"), "cp")))
+    step = build_train_step(model, mesh, OptimizerConfig(),
+                            with_grad_norm=True, with_counters=True)
+    said = io.StringIO()
+    with contextlib.redirect_stderr(said):
+        compiled = step.lower(params, opt, ids, ids, ids).compile()
+    assert "remat auto: picked 'true'" in said.getvalue()
+    assert "reserve_held=False" in said.getvalue()
+    estimate = float(re.search(r"true=([\d.]+)GiB", said.getvalue()).group(1))
+    plan = compiled.memory_analysis()
+    args = plan.argument_size_in_bytes / memory.GIB
+    planned = args + plan.temp_size_in_bytes / memory.GIB
+    assert args == pytest.approx(612_438_017 * 12 / memory.GIB, rel=1e-3)
+    assert planned < V5E_LIMIT_GIB, planned
+    assert estimate < planned * 1.01, (estimate, planned)
+    assert estimate <= memory.MARGIN * V5E_LIMIT_GIB, estimate
+    text = compiled.as_text()
+    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 2   # + recompute
+    assert len(re.findall(r"%flash_bwd[.\d]* = ", text)) == 1
+    assert "f32[4,1,4096,49152]" not in text
+    assert "f32[1,4096,49152]" in text
+
+
 def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(
         topo, described_tpu):
     """The sixth cell's step (`sdar-30b-a3b.train-ep8share-b2-t4096`: the
@@ -632,6 +681,10 @@ CHIP_GIB = {
     "ling-3-flash.train-ep64share-b1-t4096": {"true": 12.937,
                                               "flash": 12.940,
                                               "dots": 13.400},
+    # (PR 66's reading: the floor IS the rung `auto` picks where a stack is
+    # passed four times: 32 kept layer inputs and the layers' gradient
+    # twice beside 6.84 GiB of state; `ffn` would need 15.17)
+    "ouro-2.6b.train-loop4-b1-t4096": {"true": 12.438},
 }
 SNAPSHOTS = ("gpt2-medium.train-ckpt-every40",)
 
