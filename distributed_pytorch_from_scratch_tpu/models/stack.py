@@ -66,7 +66,7 @@ from ..parallel.embedding import VocabParallelEmbedding
 from ..parallel.gated_attention import gate_heads
 from ..parallel.linear import (OVERLAP_MODES, ColumnParallelLinear,
                                apply_column_ring_fused)
-from ..parallel.moe import aux_losses, aux_zeros
+from ..parallel.moe import aux_losses, aux_zeros, zeros_like_varying
 from ..runtime.prng import fold
 
 Params = Dict[str, Any]
@@ -318,6 +318,26 @@ def remat_wrap(layer_fn, remat, static_argnums=(), looped: bool = True):
     return jax.checkpoint(
         layer_fn, static_argnums=static_argnums, prevent_cse=not looped,
         policy=jax.checkpoint_policies.save_only_these_names(*names))
+
+
+def _pull_of(pull, params: Params):
+    """`pull` (a `jax.vjp`'s) as a function of `params`, a tree the function
+    was taken at: the residuals that ARE leaves of `params` (a
+    `jax.checkpoint` holds its inputs) are left out, and the call with the
+    same `params` puts them back. A scan then stacks what a step kept of
+    its other inputs, not a copy of the weights a step."""
+    leaves, tree = jax.tree.flatten(pull)
+    given = jax.tree.leaves(params)
+    at = [next((i for i, p in enumerate(given) if p is leaf), None)
+          for leaf in leaves]
+
+    def again(rest, params):
+        rest, given = iter(rest), jax.tree.leaves(params)
+        return jax.tree.unflatten(
+            tree, [next(rest) if i is None else given[i] for i in at])
+
+    return jax.tree_util.Partial(
+        again, [leaf for leaf, i in zip(leaves, at) if i is None])
 
 
 def resolve_remat(model, params: Params, ids_shape):
@@ -738,13 +758,14 @@ class DecoderStack:
                 f"what entered the attention half take a layer of two")
         if self.loop_steps is not None and (
                 self.loop_steps < 1 or self.is_moe or self.stream_mixer
-                or self.draws_noise):
+                or self.draws_noise or self._pattern != self._layer_keys[:1]):
             raise ValueError(
                 f"the {self.family} family passes its stack "
-                f"{self.loop_steps} times a step: at least once, over dense "
-                f"layers of one residual stream and a loss that draws no "
-                f"noise (the layers' counters, the streams' exit and a "
-                f"weight a position have no R exits to join)")
+                f"{self.loop_steps} times a step: at least once, over ONE "
+                f"segment of dense layers of one residual stream and a "
+                f"loss that draws no noise (the layers' counters, the "
+                f"streams' exit and a weight a position have no R exits to "
+                f"join; the walk of the passes indexes one stack)")
         validate_remat(self.remat)
         if cfg.num_heads % tp != 0:
             raise ValueError(f"num_heads {cfg.num_heads} not divisible by tp_size {tp}")
@@ -1546,10 +1567,10 @@ class DecoderStack:
 
         rung = resolve_remat(self, params, input_ids.shape)
 
-        def stage_fn(z, layers, *mb, live=None, kind=None):
-            # one scan over `layers`, layers of one `kind`; `mb` is
-            # (*layer_pos, position_ids), whole or, under the pipeline, one
-            # microbatch's rows
+        def layer_step(layers, *mb, live=None, kind=None):
+            # the step of a scan over `layers`, layers of one `kind`, under
+            # this trace's remat rung; `mb` is (*layer_pos, position_ids),
+            # whole or, under the pipeline, one microbatch's rows
             layer_fn = remat_wrap(
                 self._layer_body, rung, static_argnums=(4, 6),
                 looped=jax.tree.leaves(layers)[0].shape[0] > 1
@@ -1557,7 +1578,11 @@ class DecoderStack:
 
             def body(carry, lp):
                 return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind)
-            z, auxs = lax.scan(body, z, layers)
+            return body
+
+        def stage_fn(z, layers, *mb, live=None, kind=None):
+            z, auxs = lax.scan(layer_step(layers, *mb, live=live, kind=kind),
+                               z, layers)
             # auxs: None for dense; for MoE a dict of (L,...) stacked sums
             return z, self._fold_aux(auxs)
 
@@ -1582,28 +1607,120 @@ class DecoderStack:
                 return x, (_rows_in_order(auxs) if len(auxs) > 1
                            else auxs[0] if auxs else None)
 
-            x, aux = (one_pass(x) if self.loop_steps is None
-                      else self._loop_passes(one_pass, params, x))
+            if self.loop_steps is None:
+                x, aux = one_pass(x)
+            else:
+                # (what `_loop_passes` walks a layer at a time)
+                one_pass.step = lambda key, layers, *mb: layer_step(
+                    layers, *mb, kind=self._kind(key))
+                one_pass.mb = (*layer_pos, position_ids)
+                x, aux = self._loop_passes(one_pass, params, x)
         return x, aux, SimpleNamespace(dtype=dtype, run=run)
 
     def _loop_passes(self, one_pass, params: Params, x: jax.Array):
         """`loop_steps` passes of the pattern over the SAME parameters, the
-        final norm after every pass: `h_r = N_f(Layers(h_{r-1}))`. One scan
-        over passes around the scans of layers, so one traced copy of the
-        layer body; the layers' parameters are the scan's constants, whose
-        cotangent its transpose accumulates over the passes in the
-        parameters' own float32. Returns the R normed states, (R, b, t, d),
-        and no aux (the layers are dense). The norm runs under `head_loss`:
-        it is the exits' (the scope a device trace splits the step by)."""
-        def body(z, _):
-            with jax.named_scope("loop_pass"):
-                z, _ = one_pass(z)
-            with jax.named_scope("head_loss"):
-                z = self.final_norm.apply(params["norm"], z)
-            return z, z
+        final norm after every pass: `h_r = N_f(Layers(h_{r-1}))`. Returns
+        the R normed states, (R, b, t, d), and no aux (the layers are
+        dense). The norm runs under `head_loss`: it is the exits' (the
+        scope a device trace splits the step by); the rest of the walk
+        under `loop_pass`.
 
-        _, states = lax.scan(body, x, None, length=self.loop_steps)
-        return states, None
+        ONE walk of R x L layer applications over the stacked layers (layer
+        `i % L` at step i, the final norm where a pass ends: a `cond`), one
+        traced copy of the layer body, WITH ITS TRANSPOSE WRITTEN OUT (a
+        `jax.custom_vjp`, as `parallel/moe.walk_chunks` is). Autodiff's
+        transpose of a scan of passes around the scan of layers holds the
+        stacked layers' float32 gradient twice, what a pass's backward scan
+        stacks and the running sum it is then added to, and adds the two
+        whole stacks once a pass (1.53 GiB and 29 ms a step at cell 14's
+        shapes, which held `remat auto` on its floor: PERF.md section 6,
+        PR 67). Here the gradient is ONE stack in the parameters' own dtype
+        that the backward walk carries: a layer application adds its weight
+        gradient into its slice of it, in place, in the order the scans'
+        transpose summed (the last pass first).
+
+        A step of the forward is `jax.vjp` of the layer `stage_fn` scans
+        (`one_pass.step`: the one `_layer_body` under the trace's remat
+        rung), so what the walk keeps of a layer application is what the
+        rung names and the layer's input, R x L of each, stacked by the one
+        scan (a scan of passes around the scan of layers copies a pass's
+        stacks into the R x L deep ones and back out); the weights and
+        positions a checkpoint holds among its residuals are not stacked
+        but handed back by the backward walk (`_pull_of`). With
+        `remat=False` a layer keeps the casts of its weights, one a step.
+        Of a pass the walk keeps the final norm's input and output, R of
+        each; the norm's transpose is taken again from its input.
+
+        `one_pass(z)` is still a whole pass that autodiff can take: what
+        replaces this method builds on it (benchmark/tools/loop_control.
+        py)."""
+        (key,), R, norm = self._pattern, self.loop_steps, self.final_norm.apply
+        L = jax.tree.leaves(params[key])[0].shape[0]
+        take = lambda tree, i: jax.tree.map(
+            lambda a: lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
+
+        def forward(layers, norm_params, x, *mb):
+            # (a call of its own: its ops keep the names they were traced
+            # under, `dense_ffn`, `flash_fwd`, where the ops of a function
+            # `jax.vjp` runs inline are all named `jvp(...)`)
+            step = jax.jit(one_pass.step(key, layers, *mb))
+
+            def exit_norm(z):
+                with jax.named_scope("head_loss"):
+                    return norm(norm_params, z)
+
+            @jax.named_scope("loop_pass")
+            def layer(carry, i):
+                z, ends, states = carry
+                l, r = i % L, i // L
+                lp = take(layers, l)
+                (z, _), pull = jax.vjp(step, z, lp)
+                # (every layer of a pass writes the pass's slot, the last
+                # one last: no select, and no buffer through the `cond`)
+                ends = lax.dynamic_update_index_in_dim(ends, z, r, 0)
+                z = lax.cond(l == L - 1, exit_norm, lambda z: z, z)
+                states = lax.dynamic_update_index_in_dim(states, z, r, 0)
+                return (z, ends, states), _pull_of(pull, (lp, mb))
+
+            slots = zeros_like_varying(jnp.broadcast_to(x, (R, *x.shape)))
+            (_, ends, states), pulls = lax.scan(
+                layer, (x, slots, slots), jnp.arange(R * L))
+            return states, (layers, norm_params, mb, ends, pulls)
+
+        def backward(kept, d_states):
+            layers, norm_params, mb, ends, pulls = kept
+            zeros = lambda tree: jax.tree.map(zeros_like_varying, tree)
+
+            @jax.named_scope("loop_pass")
+            def layer(carry, at):
+                d_z, d_layers, d_norm = carry
+                i, pull = at
+                l, r = i % L, i // L
+
+                def exit_norm(d_z):
+                    with jax.named_scope("head_loss"):
+                        return jax.vjp(norm, norm_params, take(ends, r))[1](
+                            d_z + take(d_states, r))
+
+                d, d_z = lax.cond(l == L - 1, exit_norm,
+                                  lambda d_z: (zeros(norm_params), d_z), d_z)
+                d_norm = jax.tree.map(jnp.add, d_norm, d)
+                lp = take(layers, l)
+                d_z, d_lp = pull((lp, mb))((d_z, None))
+                return (d_z, jax.tree.map(
+                    lambda sums, d: lax.dynamic_update_index_in_dim(
+                        sums, lax.dynamic_index_in_dim(sums, l, 0) + d[None],
+                        l, 0), d_layers, d_lp), d_norm), None
+
+            (d_x, d_layers, d_norm), _ = lax.scan(
+                layer, (zeros(d_states[0]), zeros(layers),
+                        zeros(norm_params)),
+                (jnp.arange(R * L), pulls), reverse=True)
+            return d_layers, d_norm, d_x, *(None for _ in mb)
+
+        walk = jax.custom_vjp(lambda *args: forward(*args)[0])
+        walk.defvjp(forward, backward)
+        return walk(params[key], params["norm"], x, *one_pass.mb), None
 
     def _scan_periods(self, run, x: jax.Array, params: Params, period):
         """One scan over the periods of a `_pattern` block: the body runs

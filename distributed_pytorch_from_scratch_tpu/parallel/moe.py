@@ -475,7 +475,7 @@ def held_experts(rows: jax.Array, gate_up: jax.Array, down: jax.Array,
         return jnp.where(valid, out, 0) * wc[:, None].astype(out.dtype)
 
 
-def _like(a: jax.Array) -> jax.Array:
+def zeros_like_varying(a: jax.Array) -> jax.Array:
     """Zeros that vary over the mesh axes `a` varies over: a loop's carry."""
     vma = tuple(jax.typeof(a).vma)
     return copy_to(jnp.zeros_like(a), vma) if vma else jnp.zeros_like(a)
@@ -520,7 +520,8 @@ def walk_chunks(M: int, act, xd: jax.Array, gate_up: jax.Array,
 
     zero = rows_here * 0
     c, y, computed, windows = lax.while_loop(
-        lambda carry: carry[0] < n_live, body, (zero, _like(xd), zero, zero))
+        lambda carry: carry[0] < n_live, body,
+        (zero, zeros_like_varying(xd), zero, zero))
     return y, computed, c * M, windows
 
 
@@ -560,8 +561,8 @@ def _walk_chunks_bwd(M, act, res, g):
 
     _, d_x, d_gate_up, d_down, d_w = lax.while_loop(
         lambda carry: carry[0] < n_live, body,
-        (rows_here * 0, _like(xd), _like(gate_up), _like(down),
-         _like(w_sorted)))
+        (rows_here * 0, *map(zeros_like_varying,
+                             (xd, gate_up, down, w_sorted))))
     return d_x, d_gate_up, d_down, d_w, None, None, None
 
 
@@ -1095,7 +1096,7 @@ class SharedRoutedFFN:
                         (jnp.sum(sizes), jnp.where(lo < rows_here, M, 0)))
 
             y, (computed, walked) = lax.scan(
-                jax.checkpoint(chunk), _like(xl),
+                jax.checkpoint(chunk), zeros_like_varying(xl),
                 jnp.arange(chunks, dtype=jnp.int32))
             computed, walked = jnp.sum(computed), jnp.sum(walked)
             windows = blocks = rows_here * 0     # `sum_held`'s: none here

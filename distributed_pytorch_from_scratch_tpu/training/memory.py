@@ -131,17 +131,18 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
 
     `passes` (`DecoderStack.loop_steps`: a family whose stack a step passes
     R times over the same weights; None: once): the kept layer input and
-    every rung's named stack are R x `layers` deep (a scan of passes around
-    the scan of layers keeps each pass's), `stacks` also holds what each
-    pass keeps around its final norm, R times the norm's input and its
-    output (the R normed states the exits read), and those states once more
-    in float32 (the exit gate's product over the width reads them so, and
-    its backward again), the head's transient is still ONE exit's (an
-    exit's logits are made again in the backward), and the layers' backward
-    holds the stacked layers' gradient twice: what a pass's backward scan
-    stacks, and the sum over the passes it is added to. Held to the chip at
-    the one cell that passes (cell 14 at rung `true`: 12.438 GiB counted,
-    12.415 made; PERF.md section 5, PR 66).
+    every rung's named stack are R x `layers` deep (the walk of R x
+    `layers` layer applications keeps each one's, `DecoderStack.
+    _loop_passes`), `stacks` also holds what each pass keeps around its
+    final norm, R times the norm's input and its output (the R normed
+    states the exits read), and those states once more in float32 (the
+    exit gate's product over the width reads them so, and its backward
+    again), the head's transient is still ONE exit's (an exit's logits are
+    made again in the backward), and the layers' gradient is counted ONCE,
+    as every family's is: the backward walk adds a layer application's
+    weight gradient into its slice of the one stack (PR 67; the scans'
+    own transpose held a second stack, and PR 66 counted it). Held to the
+    chip at the one cell that passes (cell 14: PERF.md section 5, PR 67).
     """
     from ..models.transformer import REMAT_LADDER
     rung = _rung_index(remat)
@@ -179,15 +180,12 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         stacks = depth * residual_streams * wide + sum(
             tagged.get(n, layers) * R * names[n]
             for _, ns in REMAT_LADDER[:rung + 1] for n in ns)
-    pass_grads = 0.0
     if passes is not None:
         stacks += (2 + 4 / dtype_bytes) * passes * wide
-        if passes > 1:
-            pass_grads = layer_param_count * grad_bytes_per_param
     out = {
         "resident": param_count * (state_bytes_per_param
                                    - grad_bytes_per_param),
-        "grads": param_count * grad_bytes_per_param + pass_grads,
+        "grads": param_count * grad_bytes_per_param,
         "cast": (layer_param_count * dtype_bytes if dtype_bytes < 4
                  else 0.0),
         "stacks": stacks,
@@ -277,7 +275,8 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
     and nothing is held back (`reserve_held` False: a chip's share of an
     expert model, 7 - 9 GiB of state). A `reserve_gib` the caller names is
     held as given. Says what it chose on stderr and on the program's
-    tracer."""
+    tracer (the instant's `grads_gib`: the estimate's gradient tree, the
+    same at every rung)."""
     from ..models.transformer import REMAT_RUNGS
     from ..obs.trace import current_tracer
     floor = REMAT_RUNGS[0]
@@ -316,6 +315,7 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
     fields = dict(rung=picked, estimate_gib=sizes[picked],
                   budget_gib=budget_gib, reserve_gib=reserve,
                   reserve_held=held, usable_gib=usable,
+                  grads_gib=at_floor["grads"] / GIB,
                   **{f"estimate_gib.{k}": v for k, v in sizes.items()})
     tracer = current_tracer()
     if tracer is not None:

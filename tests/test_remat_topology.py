@@ -542,17 +542,30 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     assert not {"flash_bwd_dq", "flash_bwd_dkv"} & kernels, kernels
 
 
+@pytest.mark.parametrize("remat", ["auto", "true"])
 def test_the_loop_cells_step_fits_a_v5e_at_the_rung_auto_picks(
-        topo, described_tpu):
+        topo, described_tpu, remat):
     """Cell 14's step (`ouro-2.6b.train-loop4-b1-t4096`: the loop_llama
     family at the published widths, 8 layers passed 4 times, 1 x 4096
-    tokens, bf16) compiled for the described chip: a scan of passes around
-    the scan of layers and the exits as one scan under a checkpoint fit the
-    chip at the floor, which is what `remat="auto"` picks beside 6.84 GiB of
-    state; ONE flash forward and ONE backward kernel in the text (one traced
-    copy of the layer body, not four), and no float32 (4, 1, 4096, 49152)
-    tensor: one exit's logits at a time. The chip itself counts 12.438 GiB
-    (PERF.md section 5, PR 66)."""
+    tokens, bf16) compiled for the described chip: ONE walk of 32 layer
+    applications each way (one traced copy of the layer body: one flash
+    forward and ONE backward kernel in the text) and the exits as one scan
+    under a checkpoint (no float32 (4, 1, 4096, 49152) tensor: one exit's
+    logits at a time).
+
+    At the floor pinned the plan's temporaries are 5.03 GiB: the backward
+    walk carries the layers' float32 gradient as ONE stack and adds a layer
+    application's slice in place, where the transpose of a scan of passes
+    around the scan of layers held the stack twice (8.61 GiB on PR 66's
+    program, whose step this case refuses; PERF.md section 6, PR 67). So
+    `remat="auto"` climbs by itself beside 6.84 GiB of state, to `flash`:
+    the layer's recompute runs no flash forward. What the compiler refuses
+    a program by is the peak of what is live, `peak_memory_in_bytes` (it
+    refused PR 66's program at `flash`, 16.33 GiB, and takes this one at
+    `dots`, 15.25 of 15.75, at arguments + temporaries of 17.49), which on
+    PR 66's program read 12.394 where the chip counted 12.438: the rung
+    picked has to fit by it, and the estimate it was picked by may not be
+    under it."""
     from distributed_pytorch_from_scratch_tpu.config import LoopLlamaConfig
     from distributed_pytorch_from_scratch_tpu.models import build_model
     cfg = ModelConfig(
@@ -560,7 +573,9 @@ def test_the_loop_cells_step_fits_a_v5e_at_the_rung_auto_picks(
         num_layers=8, vocab_size=49152, maxlen=65536, rope_theta=1e6,
         compute_dtype="bfloat16", loop_llama=LoopLlamaConfig(loop_steps=4))
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=topo.devices[:1])
-    model = build_model("loop_llama", cfg, remat_budget_gib=V5E_LIMIT_GIB)
+    model = build_model("loop_llama", cfg, **(
+        dict(remat_budget_gib=V5E_LIMIT_GIB) if remat == "auto"
+        else dict(remat=remat)))
     params = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
         jax.eval_shape(model.init, jax.random.key(0)), model.shardings(mesh))
@@ -574,21 +589,27 @@ def test_the_loop_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     said = io.StringIO()
     with contextlib.redirect_stderr(said):
         compiled = step.lower(params, opt, ids, ids, ids).compile()
-    assert "remat auto: picked 'true'" in said.getvalue()
-    assert "reserve_held=False" in said.getvalue()
-    estimate = float(re.search(r"true=([\d.]+)GiB", said.getvalue()).group(1))
     plan = compiled.memory_analysis()
     args = plan.argument_size_in_bytes / memory.GIB
-    planned = args + plan.temp_size_in_bytes / memory.GIB
     assert args == pytest.approx(612_438_017 * 12 / memory.GIB, rel=1e-3)
-    assert planned < V5E_LIMIT_GIB, planned
-    assert estimate < planned * 1.01, (estimate, planned)
-    assert estimate <= memory.MARGIN * V5E_LIMIT_GIB, estimate
+    peak = plan.peak_memory_in_bytes / memory.GIB
+    assert peak < V5E_LIMIT_GIB, peak
     text = compiled.as_text()
-    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 2   # + recompute
     assert len(re.findall(r"%flash_bwd[.\d]* = ", text)) == 1
     assert "f32[4,1,4096,49152]" not in text
     assert "f32[1,4096,49152]" in text
+    forwards = len(re.findall(r"%flash_fwd[.\d]* = ", text))
+    if remat == "true":
+        assert plan.temp_size_in_bytes / memory.GIB <= 7.3
+        assert forwards == 2                                # + recompute
+        return
+    assert "remat auto: picked 'flash'" in said.getvalue()
+    assert "reserve_held=False" in said.getvalue()
+    estimate = float(re.search(r"flash=([\d.]+)GiB",
+                               said.getvalue()).group(1))
+    assert peak * 0.99 < estimate <= memory.MARGIN * V5E_LIMIT_GIB, (
+        estimate, peak)
+    assert forwards == 1
 
 
 def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(
@@ -681,10 +702,13 @@ CHIP_GIB = {
     "ling-3-flash.train-ep64share-b1-t4096": {"true": 12.937,
                                               "flash": 12.940,
                                               "dots": 13.400},
-    # (PR 66's reading: the floor IS the rung `auto` picks where a stack is
-    # passed four times: 32 kept layer inputs and the layers' gradient
-    # twice beside 6.84 GiB of state; `ffn` would need 15.17)
-    "ouro-2.6b.train-loop4-b1-t4096": {"true": 12.438},
+    # (PR 67's readings: the walk of the four passes carries the layers'
+    # gradient as ONE stack, so the floor fell from PR 66's 12.438 and
+    # `auto` picks `flash` beside 6.84 GiB of state; `dots` would need
+    # 15.64. At the floor PINNED the cell's `device.peak_hbm_gib` reads the
+    # float32 reference's phase, 11.934; the step's own count there, the
+    # buffers and the reserve at the window's end, is what stands here)
+    "ouro-2.6b.train-loop4-b1-t4096": {"true": 10.654, "flash": 13.850},
 }
 SNAPSHOTS = ("gpt2-medium.train-ckpt-every40",)
 
