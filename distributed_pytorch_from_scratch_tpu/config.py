@@ -358,6 +358,47 @@ class LoopLlamaConfig:
 
 
 @dataclass(frozen=True)
+class SsmDenseConfig:
+    """What the `ssm_dense` family (models/ssm_dense.py) needs beyond
+    `ModelConfig`'s own fields: a DENSE hybrid whose every layer is a mixer
+    and then a SwiGLU, the mixer by `layer_types` a Mamba-2 state-space
+    mixer (`mamba`) or grouped-query attention with no positions
+    (`attention`), under four published scalars: the embedding's rows times
+    `embedding_multiplier`, both sublayers' outputs times
+    `residual_multiplier` before the residual adds them, the softmax over
+    `q k^T * attention_multiplier` (NOT `1 / sqrt(head_dim)`) and the
+    logits of the tied head over `logits_scaling`. The keys are
+    `config.json`'s own (`granitemoehybrid` with `num_local_experts` 0). In
+    `ModelConfig`, `attn_dim` is the model width, `num_heads` /
+    `num_kv_heads` the attention layers' heads, `num_layers` =
+    `len(layer_types)`, `ffn_dim` the SwiGLU's width (the published
+    `shared_intermediate_size`), `num_experts` 0. A dense family: it holds
+    no share of any expert, and its mixers are whole."""
+
+    layer_types: "tuple[str, ...]"      # "mamba" | "attention", a layer each
+    mamba_n_heads: int
+    mamba_d_head: int
+    mamba_d_state: int
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_chunk_size: int = 256
+    mamba_expand: int = 2               # n_heads x d_head = expand x d
+    mamba_conv_bias: bool = True        # the only form the mixer computes
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: "float | None" = None    # None: 1 / sqrt(head_dim)
+    logits_scaling: float = 1.0
+    position_embedding_type: str = "nope"
+    rms_norm_eps: float = 1e-5
+    # the tied table's normal(0, .) at init
+    initializer_range: float = 0.1
+    # dt at init (`config.json` publishes none: Mamba-2's own defaults)
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -407,6 +448,8 @@ class ModelConfig:
     ssm_moe: "SsmMoEConfig | None" = None
     # The `loop_llama` family's facts (None for every other family).
     loop_llama: "LoopLlamaConfig | None" = None
+    # The `ssm_dense` family's facts (None for every other family).
+    ssm_dense: "SsmDenseConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -476,7 +519,8 @@ class ModelConfig:
 
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
-                "early_moe", "kda_mla_moe", "ssm_moe", "loop_llama")
+                "early_moe", "kda_mla_moe", "ssm_moe", "loop_llama",
+                "ssm_dense")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -626,6 +670,21 @@ MODEL_PRESETS = {
         attn_dim=64, ffn_dim=128, num_heads=4, num_layers=2,
         vocab_size=1024, maxlen=256, rope_theta=1e6,
         loop_llama=LoopLlamaConfig(loop_steps=3)),
+    # the `ssm_dense` family at a CPU size: Granite 4.0-H's layer in small,
+    # a mixer and a SwiGLU in every layer, (Mamba-2 x 2, attention) twice
+    # (a period that repeats) and one Mamba-2 layer more; 8 Mamba heads 16 wide over ONE group of B and C, a state 8
+    # wide, chunks of 32; 4 query heads over 2 key-value heads of 16, no
+    # positions; the four scalars at values no tolerance hides (none a
+    # power of two but the softmax's, which is not 1 / sqrt(16))
+    "tiny-ssm-dense": ModelConfig(
+        attn_dim=64, ffn_dim=96, num_heads=4, num_kv_heads=2, num_layers=7,
+        vocab_size=1024, maxlen=256,
+        ssm_dense=SsmDenseConfig(
+            layer_types=("mamba", "mamba", "attention") * 2 + ("mamba",),
+            mamba_n_heads=8, mamba_d_head=16, mamba_d_state=8,
+            mamba_chunk_size=32, embedding_multiplier=12.0,
+            residual_multiplier=0.22, attention_multiplier=0.125,
+            logits_scaling=8.0)),
 }
 
 

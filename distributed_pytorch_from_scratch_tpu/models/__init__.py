@@ -1,10 +1,11 @@
 """The model families, and the one place a family's name becomes a class:
-`llama` (the reference's block) and `gpt2`, and ten drawn from published
+`llama` (the reference's block) and `gpt2`, and eleven drawn from published
 configurations: nine that each hold one share of the experts their router
 scores, `mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
-`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe`, and the dense `loop_llama`,
-whose stack is passed several times a step (docs/DESIGN.md, "What a family
-file holds")."""
+`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe`, and two dense ones:
+`loop_llama`, whose stack is passed several times a step, and `ssm_dense`,
+whose every layer is a Mamba-2 mixer or an attention and then a SwiGLU
+(docs/DESIGN.md, "What a family file holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
@@ -15,6 +16,7 @@ from .kda_mla_moe import KdaMlaMoETransformer
 from .loop_llama import LoopedTransformer
 from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
+from .ssm_dense import SsmDenseTransformer
 from .ssm_moe import SsmMoETransformer
 from .stack import DecoderStack
 from .swa_moe import SlidingWindowMoETransformer
@@ -25,7 +27,7 @@ FAMILIES = {cls.family: cls for cls in (
     ConvMoETransformer, BlockDiffusionMoETransformer,
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
     HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer,
-    LoopedTransformer)}
+    LoopedTransformer, SsmDenseTransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

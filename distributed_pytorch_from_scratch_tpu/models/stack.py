@@ -19,6 +19,8 @@ differs and nothing else (docs/DESIGN.md, "What a family file holds"):
   `decodable`, `draws_noise`, `layer_extra_elems_per_token`; and, where it
   has them, the norms after a sublayer (`post_attn_norm_key`,
   `post_ffn_norm_key`), the embedding's multiplier (`embed_scale`), the
+  three other scalars a published configuration may state
+  (`residual_scale`, `softmax_scale`, `logit_scale`), the
   kinds of attention layer by `_pattern` key (`_kind`, `_attn_mask(t,
   kind)`, `unrotated_kinds`), the speed of its routers' selection bias
   (`router_bias_speed`), whether its routers read the layer's input
@@ -46,6 +48,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 from dataclasses import dataclass
 from types import SimpleNamespace
 from typing import Any, Dict, Tuple
@@ -615,6 +618,20 @@ class DecoderStack:
     post_ffn_norm_key = None
     # what the embedding's rows are multiplied by as they enter (None: 1)
     embed_scale = None
+    # Three scalars a published configuration may state, each applied in ONE
+    # place and each None where the program is the one it has always been:
+    # what BOTH sublayers' outputs are multiplied by, in the compute dtype,
+    # before the residual adds them, `x + s F(N(x))` (`_layer_body`'s
+    # `join`: the forward, the remat and the pipeline paths all add there);
+    residual_scale = None
+    # the softmax's scale where it is not `1 / sqrt(head_dim)`: the flash
+    # kernels and `ops/attention.py` scale by that themselves, so `_qkv`
+    # multiplies q by `softmax_scale x sqrt(head_dim)` before the dispatch
+    # (as `parallel/mla.LatentAttention.softmax_scale` is folded);
+    softmax_scale = None
+    # what the head's logits are multiplied by before the loss reads them
+    # (`_masked_logits`: every exit, the vocabulary-parallel CE after it)
+    logit_scale = None
     # the kinds of attention layer (`_kind`) that take NO positions at q
     # and k, of a family with two kinds of attention layer over one
     # parameter tree; the mask by kind is `_attn_mask`
@@ -750,6 +767,11 @@ class DecoderStack:
                 f"{self.residual_streams} residual streams: the pipeline's "
                 f"carries and a router that reads the layer's input take "
                 f"one")
+        if self.residual_scale is not None and self.stream_mixer is not None:
+            raise ValueError(
+                f"the {self.family} family scales its sublayers' outputs "
+                f"before the residual add: the stream mixers' `post` map "
+                f"joins them by its own weights")
         if self.one_sublayer and (self.stream_mixer is not None
                                   or self.router_reads_layer_input):
             raise ValueError(
@@ -1211,6 +1233,8 @@ class DecoderStack:
         def join(x, y, name):
             """The residual state past the sublayer whose output is `y`."""
             if mixer is None:
+                if self.residual_scale is not None:
+                    y = y * jnp.asarray(self.residual_scale, y.dtype)
                 return x + y
             return mixer.post(mixed[name], x, y)
 
@@ -1271,7 +1295,7 @@ class DecoderStack:
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm], x))
             ff, aux = self._ffn(layer_params, y, tp, dtype)
-            return x + ff, aux
+            return join(x, ff, None), aux
         if "wo" not in layer_params:
             # no output projection of the stack's: the layer's mixer hands
             # back the sublayer's output itself (`_mix`)
@@ -1334,8 +1358,12 @@ class DecoderStack:
             # positions, holds the two norms in its layers
             q = self._mods["q_norm"].apply(lp["q_norm"], q)
             k = self._mods["k_norm"].apply(lp["k_norm"], k)
+        q, k = self._position_qk(q, k, layer_pos)
+        if self.softmax_scale is not None:
+            # the kernels scale the scores by 1 / sqrt(h) themselves
+            q = q * jnp.asarray(self.softmax_scale * math.sqrt(h), q.dtype)
         # (the gate's logits are on no rung of REMAT_LADDER: recomputed)
-        return self._position_qk(q, k, layer_pos) + (v, *gate)
+        return (q, k, v, *gate)
 
     def _mix(self, lp: Params, y: jax.Array, layer_pos, dtype) -> jax.Array:
         """For a layer whose parameters hold no `wo` (a mixer that is not
@@ -1764,6 +1792,8 @@ class DecoderStack:
         """The head on a normed state: LOCAL logits, the padded vocabulary
         entries masked so they carry no probability mass."""
         logits = self._head_logits(params, x, dtype)
+        if self.logit_scale is not None:
+            logits = logits * jnp.asarray(self.logit_scale, logits.dtype)
         if self.vocab_padded != self.cfg.vocab_size:
             local_v = self.vocab_padded // self.tp_size
             start = lax.axis_index("tp") * local_v
@@ -2251,8 +2281,9 @@ class DecoderStack:
 
     def _counters(self, aux, batch_axes) -> Params:
         """The layers' counters summed over the batch axes; none where the
-        aux is the router sums of the auxiliary losses, or nothing."""
-        if not self.is_moe or self._router_aux_losses:
+        aux is the router sums of the auxiliary losses, or nothing (a dense
+        family whose mixers count says `_router_aux_losses` False too)."""
+        if aux is None or self._router_aux_losses:
             return {}
         over = self._counter_reduces
         if any(k in over for k in aux):

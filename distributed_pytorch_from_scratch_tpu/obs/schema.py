@@ -119,6 +119,9 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # exit distribution's mean entropy and the mean exit step; beside them
     # `loss_exit_<r>` and `exit_p_<r>`, a pair a pass
     "loop_counters": ("loss_main", "exit_entropy", "exit_step_mean"),
+    # -- ISSUE 68: the counters of a dense family whose mixers count, at the
+    # log interval (training/metrics.mixer_counters_summary)
+    "mixer_counters": ("loss_main", "ssm_decay_min", "resid_rms_last"),
     # -- ISSUE 37: `train()`'s step function built again after its steady
     # program was in hand (a tail window, a new sequence bucket), at `step`;
     # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)

@@ -122,6 +122,19 @@ class Mamba2Mixer:
 
     # ---- forward (per-shard, inside shard_map) ----
 
+    def _gate_norm(self, w: jax.Array, y: jax.Array,
+                   z: jax.Array) -> jax.Array:
+        """`w * RMSNorm over a group's channels (y * silu(z))` in float32:
+        the gate first, then the norm (y (b, t, H P) float32, z the gate's
+        logits). A method of its own so that a control can run the other
+        order (benchmark/tools/ssm_dense_control.py)."""
+        b, t, _ = y.shape
+        g = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(
+            b, t, self.groups, -1)
+        g = g * jax.lax.rsqrt(
+            jnp.mean(g * g, axis=-1, keepdims=True) + self.eps)
+        return w * g.reshape(b, t, self.inner)
+
     def apply(self, params: Params, u: jax.Array,
               compute_dtype: jnp.dtype = jnp.float32
               ) -> Tuple[jax.Array, Params]:
@@ -151,12 +164,7 @@ class Mamba2Mixer:
                 y = (y.astype(f32) + params["D"][:, None] * x.astype(f32)
                      ).reshape(b, t, self.inner)
             with jax.named_scope("gate_norm"):
-                # the gate first, then the norm over a group's channels
-                g = (y * jax.nn.silu(z.astype(f32))).reshape(
-                    b, t, self.groups, -1)
-                g = g * jax.lax.rsqrt(
-                    jnp.mean(g * g, axis=-1, keepdims=True) + self.eps)
-                y = (params["norm"] * g.reshape(b, t, self.inner)).astype(
+                y = self._gate_norm(params["norm"], y, z).astype(
                     compute_dtype)
             with jax.named_scope("out_proj"):
                 out = y @ params["w_out"].astype(compute_dtype)

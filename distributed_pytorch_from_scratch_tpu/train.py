@@ -48,7 +48,7 @@ from .training.checkpoint import (AsyncCheckpointer, latest_step,
                                   load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
                                bd_counters_summary, loop_counters_summary,
-                               moe_counters_summary,
+                               mixer_counters_summary, moe_counters_summary,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
                                param_bytes_by_device, publish_hbm)
@@ -223,8 +223,9 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "step, the batch), runs [noised ; clean] under the "
                         "block-diffusion attention mask and weights the "
                         "masked positions' CE by 1/p; tokens/s count data "
-                        "tokens; the later families, 'kda_mla_moe' and "
-                        "'ssm_moe' among them, each with --model "
+                        "tokens; the later families, 'kda_mla_moe', "
+                        "'ssm_moe' and the dense 'ssm_dense' among them, "
+                        "each with --model "
                         "tiny-<family>: README) and "
                         "train under dp/tp/ZeRO 1 only: "
                         "pp/cp/ep > 1, SP, ZeRO 2/3, decode and serving "
@@ -1308,6 +1309,14 @@ def train(args: argparse.Namespace) -> dict:
                             print("  " + ", ".join(
                                 f"{k} {v:.4g}" for k, v in loop.items()))
                             writer.event("loop_counters", step=n, **loop)
+                        elif (last_counters is not None
+                                and "routed" not in last_counters):
+                            # a dense family whose mixers count
+                            mixers = mixer_counters_summary(
+                                jax.device_get(last_counters))
+                            print("  " + ", ".join(
+                                f"{k} {v:.4g}" for k, v in mixers.items()))
+                            writer.event("mixer_counters", step=n, **mixers)
                         elif last_counters is not None:
                             moe = moe_counters_summary(
                                 jax.device_get(last_counters), cfg,

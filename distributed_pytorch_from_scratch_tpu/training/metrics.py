@@ -180,6 +180,20 @@ def loop_counters_summary(counters: dict) -> dict:
     return out
 
 
+def mixer_counters_summary(counters: dict) -> dict:
+    """The counters of a DENSE family whose mixers count (the `ssm_dense`
+    family: no router, so no row of `moe_counters_summary`'s) as a log
+    line's numbers: the loss, the worst Mamba-2 layer's most negative `dt
+    A` summed over a chunk (`ssm_decay_min`, parallel/mamba.py) and the RMS
+    of the residual stream that enters the final norm (`resid_rms_last`:
+    what the embedding's and the residual's multipliers hold steady)."""
+    import numpy as np
+
+    return {"loss_main": float(counters["loss_main"]),
+            "ssm_decay_min": float(np.min(counters["ssm_decay_min"])),
+            "resid_rms_last": float(counters["resid_rms_last"])}
+
+
 def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     """The step's counters of an expert model (`DecoderStack.loss_shard`
     with `with_counters`, fetched to the host) as the few numbers a log

@@ -19,6 +19,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          LatentMoEConfig,
                                                          ModelConfig,
                                                          LoopLlamaConfig,
+                                                         SsmDenseConfig,
                                                          SsmMoEConfig,
                                                          SwaMoEConfig,
                                                          model_preset)
@@ -89,9 +90,22 @@ SSM = dict(hybrid_override_pattern="EMEM*", mamba_num_heads=4,
            num_nextn_predict_layers=1)
 
 
+# the ssm_dense family: a mixer and a SwiGLU in every layer, (Mamba-2,
+# attention) twice; 4 Mamba heads of 16 (expand 2) over one group, a state
+# 4 wide; the four scalars off their neutral values
+SSM_DENSE = dict(layer_types=("mamba", "attention") * 2, mamba_n_heads=4,
+                 mamba_d_head=16, mamba_d_state=4, mamba_chunk_size=16,
+                 embedding_multiplier=12.0, residual_multiplier=0.22,
+                 attention_multiplier=0.125, logits_scaling=8.0)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "ssm_dense":
+        # a dense family with facts: no expert to hold
+        return ModelConfig(num_kv_heads=2, **{**TINY, "num_layers": 4},
+                           ssm_dense=SsmDenseConfig(**SSM_DENSE))
     if extra == "loop_llama":
         # a dense family with facts: three passes, and no expert to hold
         return ModelConfig(**TINY, loop_llama=LoopLlamaConfig(loop_steps=3))
@@ -135,7 +149,8 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "early_moe": "tiny-early-moe",
                 "mhc_mla_moe": "tiny-mhc-mla-moe",
                 "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe",
-                "loop_llama": "tiny-loop-llama"}
+                "loop_llama": "tiny-loop-llama",
+                "ssm_dense": "tiny-ssm-dense"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 

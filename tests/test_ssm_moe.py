@@ -679,4 +679,4 @@ def test_the_new_familys_step_names_its_scopes():
                   "moe_latent/down", "moe_latent/up", "moe_route/",
                   "moe_experts", "moe_shared", "mtp/", "head_loss"):
         assert scope in text, scope
-    assert FAMILY in FAMILIES and len(FAMILIES) == 12
+    assert FAMILY in FAMILIES and len(FAMILIES) >= 12
