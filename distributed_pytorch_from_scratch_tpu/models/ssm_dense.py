@@ -221,7 +221,10 @@ class SsmDenseTransformer(DecoderStack):
         13.85 at `dots` (+3.0%) and 12.56 at the floor (-3.2%: the floor is
         what is left where nothing fits and is picked by no estimate). The
         untuned count made 15.77 and 14.48; a job of another shape reads
-        the estimate as far off as that (11 - 17%)."""
+        the estimate as far off as that (11 - 17%). Since PR 69 the decays
+        and the mixed scores stay in VMEM on a TPU (`ops/pallas/ssd.py`) and
+        the chip counts less than the fit by what they held (PERF.md
+        section 5, PR 69); not re-fitted (ROADMAP D17)."""
         return LAYER_FIT_WIDTHS * self.d
 
     # ---- sub-module definitions ----

@@ -218,7 +218,11 @@ class SsmMoETransformer(MultiTokenPrediction, DecoderStack):
         compute dtype, the float32 output and the gated copy; each with its
         cotangent. NOT yet set from the chip's reading: cell 13 on a v5e
         counts 13.27 GiB at rung `dots`, the rung `auto` picks there, for a
-        step this makes 13.91 (my chip runs, PR 63)."""
+        step this makes 13.91 (my chip runs, PR 63). Since PR 69 the decays
+        and the mixed scores stay in VMEM on a TPU (`ops/pallas/ssd.py`;
+        between forward and backward a layer holds the chunks' entering
+        states, 2 N a head-channel and chunk) and this count, the text's,
+        stands above the step by them; not re-fitted (ROADMAP D17)."""
         mixer = self._mods["mamba"]
         proj = mixer.inner + mixer.conv_channels + mixer.heads
         scan = mixer.heads * mixer.chunk * 3 + 4 * mixer.inner

@@ -31,6 +31,11 @@ group is `P x heads / groups` channels either way. Nothing here reduces
 over a mesh axis: the sum over the ranks' `w_out` products is the
 deployment's (ROADMAP: a mixer split by heads over a `tp` axis).
 
+The recurrence is `ops/ssd.ssd`'s: two Pallas kernels on a TPU that read
+and write x and y as `(b, t, H P)`, the layout they have here (the `(b, t, H,
+P)` its signature takes is a reshape that cancels against its own), and XLA
+text everywhere else.
+
 Scopes for a device trace: `mamba/in_proj`, `mamba/conv`, `mamba/ssd`,
 `mamba/gate_norm`, `mamba/out_proj`. The layer counts `ssm_decay_min`, the
 smallest `dt A` summed over a chunk (how close a chunk's `exp` comes to
@@ -156,13 +161,15 @@ class Mamba2Mixer:
                 x, B, C = jnp.split(xBC, (self.inner, self.inner + GN), -1)
             with jax.named_scope("ssd"):
                 dt = jax.nn.softplus(dt.astype(f32) + params["dt_bias"])
-                x = x.reshape(b, t, H, Pd)
                 y, decay_min = ssd(
-                    x, dt, -jnp.exp(params["A_log"]),
+                    x.reshape(b, t, H, Pd), dt, -jnp.exp(params["A_log"]),
                     B.reshape(b, t, self.groups, self.state),
                     C.reshape(b, t, self.groups, self.state), self.chunk)
-                y = (y.astype(f32) + params["D"][:, None] * x.astype(f32)
-                     ).reshape(b, t, self.inner)
+                # `D x` on (b, t, H P) as it lies, D a head's over its
+                # channels: a (.., H, P) array of 64-wide heads is tiled
+                # otherwise, and each turn between the two is a copy
+                y = (y.reshape(b, t, self.inner).astype(f32)
+                     + jnp.repeat(params["D"], Pd) * x.astype(f32))
             with jax.named_scope("gate_norm"):
                 y = self._gate_norm(params["norm"], y, z).astype(
                     compute_dtype)

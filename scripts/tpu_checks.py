@@ -48,6 +48,7 @@ from distributed_pytorch_from_scratch_tpu.ops.pallas.paged_attention import (  #
     paged_attention)
 from distributed_pytorch_from_scratch_tpu.ops.ring_attention import (  # noqa: E402
     _block_attn_xla)
+from distributed_pytorch_from_scratch_tpu.ops import ssd as ssd_mod  # noqa: E402
 from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod  # noqa: E402
 from distributed_pytorch_from_scratch_tpu.runtime.compile_cache import (  # noqa: E402
     compile_cache_stats, enable_compile_cache)
@@ -338,6 +339,73 @@ def expert_layer_checks(interp: bool, dtype, tol: float):
            tol * float(jnp.max(jnp.abs(want["d_x"]))), secs, must_differ=True)
 
 
+# (heads, groups, chunk) of the two benchmark cells that run the state-space
+# recurrence (PERF.md's numbering), at heads of 64 and a state of 128
+CELL_SHAPES = {"15": (64, 1, 256), "13": (32, 2, 128)}
+
+
+def ssd_inputs(b, t, H, G, dtype, seed=3):
+    """x, dt, A, B, C about as the Mamba-2 mixer makes them at init: dt
+    log-uniform in [1e-3, 1e-1], A = -(1 .. H) (a chunk of 256 sums to
+    about -1,600 in the last head)."""
+    k = jax.random.split(jax.random.key(seed), 4)
+    normal = lambda key, *shape: jax.random.normal(
+        key, shape, jnp.float32).astype(dtype)
+    dt = jnp.exp(jax.random.uniform(k[1], (b, t, H), jnp.float32,
+                                    np.log(1e-3), np.log(1e-1)))
+    return (normal(k[0], b, t, H, 64), dt,
+            -(1.0 + jnp.arange(H, dtype=jnp.float32)),
+            normal(k[2], b, t, G, 128), normal(k[3], b, t, G, 128))
+
+
+def ssd_errors(args, chunk: int, interpret: bool) -> dict:
+    """The recurrence's kernels and its text, each against the text in
+    float32 at `Precision.HIGHEST` on the same numbers: {what: {"kernels",
+    "text"}}, the value and the five gradients relative to the
+    reference's largest entry, `decay_min` as a difference."""
+    w = jax.random.normal(jax.random.key(9), args[0].shape, jnp.float32)
+
+    def run(fn, args):
+        loss = lambda *a: jnp.sum(fn(*a)[0].astype(jnp.float32) * w)
+        (y, low), grads = jax.jit(lambda *a: (
+            fn(*a), jax.grad(loss, range(5))(*a)))(*args)
+        return [y, *grads], low
+
+    text = lambda *a: ssd_mod._ssd_text(*a, chunk, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, want_low = run(text, [a.astype(jnp.float32) for a in args])
+    # (on the chip `ssd` takes the kernels by itself; off it, asked for)
+    got, got_low = run(lambda *a: ssd_mod.ssd(*a, chunk,
+                                              interpret=interpret), args)
+    plain, _ = run(text, args)
+    rel = lambda a, b: max_err(a, b) / max(float(jnp.max(jnp.abs(b))), 1e-30)
+    out = {name: {"kernels": rel(g, r), "text": rel(p, r)}
+           for name, g, p, r in zip(("y", "dx", "ddt", "dA", "dB", "dC"),
+                                    got, plain, want)}
+    out["decay_min"] = {"kernels": abs(float(got_low) - float(want_low)),
+                        "text": 0.0, "value": float(want_low)}
+    return out
+
+
+def ssd_checks(interp: bool, dtype, tol: float):
+    """The state-space recurrence's kernels (ops/pallas/ssd.py) against its
+    XLA text at both cells' shapes (under the interpreter: their heads,
+    groups and chunks at 300 tokens, a length no chunk divides): the value,
+    every input's gradient and `decay_min`. A kernel may stand as far from
+    the float32 reference as twice the text in the same dtype does."""
+    for cell, (H, G, chunk) in CELL_SHAPES.items():
+        if interp:
+            H //= 4
+        args = ssd_inputs(1, 300 if interp else 4096, H, G, dtype)
+        t0 = time.time()
+        errors = ssd_errors(args, chunk, interp)
+        secs = time.time() - t0
+        for name, e in errors.items():
+            record(f"ssd kernels, cell {cell}'s shape: {name}", e["kernels"],
+                   max(2 * e["text"], 1e-3 if name == "decay_min" else tol),
+                   secs)
+
+
 def timer_check(interpret: bool) -> dict:
     """Is `block_until_ready` honest here? Time the same chain of donated
     jitted steps twice: once ending in `block_until_ready`, once ending in
@@ -505,6 +573,9 @@ def main():
     grouped_rows_check(interp, dtype, tol)
     sum_held_check(interp, dtype, tol)
     expert_layer_checks(interp, dtype, tol)
+
+    # --- the state-space recurrence's walk against its text
+    ssd_checks(interp, dtype, tol)
 
     timer = timer_check(interp)
 

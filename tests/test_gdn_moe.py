@@ -48,6 +48,7 @@ from distributed_pytorch_from_scratch_tpu.parallel.kda import (
     KimiDeltaAttention)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
     flash_attention)
+from distributed_pytorch_from_scratch_tpu.ops.ssd import ssd
 from distributed_pytorch_from_scratch_tpu.ops.rope import (
     apply_rotary_leading, rope_angles)
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
@@ -466,6 +467,98 @@ def test_on_the_channel_kernel_path_no_sub_blocks_factor_is_left_to_xla(
         kernels = channel_jaxpr(*args, grad=grad)
         assert pallas_calls(kernels)
         assert not factors(kernels) and not highest(kernels)
+
+
+# ---- the state-space recurrence's kernels (ops/pallas/ssd.py) ----
+
+def ssd_inputs(t=256, H=4, G=2, widths=(64, 128)):
+    """x (1, t, H, P), dt, A, B and C (1, t, G, N) of the Mamba-2
+    recurrence, P and N `widths`."""
+    k = jax.random.split(jax.random.key(3), 4)
+    return (jax.random.normal(k[0], (1, t, H, widths[0])),
+            jax.nn.softplus(jax.random.normal(k[1], (1, t, H)) - 2.0),
+            -(1.0 + jnp.arange(H, dtype=jnp.float32)),
+            jax.random.normal(k[2], (1, t, G, widths[1])),
+            jax.random.normal(k[3], (1, t, G, widths[1])))
+
+
+def ssd_jaxpr(*args, grad=False, **kw):
+    rule = lambda *a: ssd(*a, **kw)
+    if grad:
+        rule = jax.grad(lambda *a: jnp.sum(ssd(*a, **kw)[0]), (0, 1, 2, 3, 4))
+    return jax.make_jaxpr(rule)(*args).jaxpr
+
+
+def test_off_the_tpu_or_at_other_shapes_the_recurrence_is_the_xla_text(
+        monkeypatch):
+    """The recurrence's kernels engage from what the call sees, as the delta
+    rules': a TPU, heads of 64 in pairs of a group, a state and a chunk in
+    multiples of 128, float32 sums. Anything else lowers with no Mosaic
+    call, and the instant `ssd` on the program's tracer says which path a
+    trace took and at what shape."""
+    held, narrow = ssd_inputs(), ssd_inputs(widths=(8, 4))
+    said = _Said()
+    monkeypatch.setattr(obs_trace, "_current", said)
+    assert jax.default_backend() != "tpu"
+    assert not pallas_calls(ssd_jaxpr(*held, chunk=128))
+    text = jax.jit(lambda *a: ssd(*a, chunk=128)).lower(*held).as_text()
+    assert "tpu_custom_call" not in text and "while" in text
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for args, kw in ((narrow, dict(chunk=128)), (held, dict(chunk=64)),
+                     (ssd_inputs(H=3, G=3), dict(chunk=128)),
+                     (held, dict(chunk=128, state_dtype=jnp.bfloat16))):
+        assert not pallas_calls(ssd_jaxpr(*args, **kw))
+    assert [f["path"] for _, f in said.instants] == ["xla"] * 6
+    name, fields = said.instants[0]
+    assert name == "ssd" and fields == dict(
+        path="xla", heads=4, groups=2, tokens=256, chunk=128, head_dim=64,
+        state=128, dtype="float32", block=None)
+    assert len(pallas_calls(ssd_jaxpr(*held, chunk=128))) == 1
+    assert pallas_calls(ssd_jaxpr(*ssd_inputs(H=32, G=2), chunk=256))
+    assert [(name, f["path"], f["heads"], f["chunk"], f["block"])
+            for name, f in said.instants[6:]] == [
+        ("ssd", "kernel", 4, 128, 2), ("ssd", "kernel", 32, 256, 8)]
+
+
+def test_the_recurrences_kernels_are_not_read_as_flash_calls(monkeypatch):
+    """The Mosaic calls of the recurrence and of its gradient are named
+    `ssd_fwd` / `ssd_bwd` and have neither 3 nor 6 operands
+    (benchmark/lib/kernels.py would read either as a flash call)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = ssd_inputs()
+    assert pallas_calls(ssd_jaxpr(*args, chunk=128)) == [("ssd_fwd", 5)]
+    assert sorted(pallas_calls(ssd_jaxpr(*args, chunk=128, grad=True))) == [
+        ("ssd_bwd", 7), ("ssd_fwd", 5)]
+
+
+def test_on_the_recurrences_kernel_path_no_decay_of_a_chunk_is_left_to_xla(
+        monkeypatch):
+    """The mechanism engaged: on the kernel path (forward and gradient) no
+    array outside a `pallas_call` ends in two `chunk` dimensions (the
+    text's `gap`, `decay` and `mixed`, (b, c, G, R, Q, Q): 268 MB each in
+    float32 a layer of cell 15), and what the forward keeps for the
+    backward beside its inputs is the state each chunk ENTERED with, (b, c,
+    N, H P) float32. The XLA text has the decays: the guard reads what it
+    should."""
+    Q, t, H, G = 128, 512, 4, 2
+    args = ssd_inputs(t, H, G)
+    shapes = lambda eqn: [v.aval.shape for v in eqn.invars + eqn.outvars
+                          if hasattr(v.aval, "shape")]
+    decays = lambda jaxpr: [eqn for eqn in eqns_outside_kernels(jaxpr)
+                            if any(shape[-2:] == (Q, Q)
+                                   for shape in shapes(eqn))]
+    for grad in (False, True):
+        assert decays(ssd_jaxpr(*args, chunk=Q, grad=grad))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for grad in (False, True):
+        kernels = ssd_jaxpr(*args, chunk=Q, grad=grad)
+        assert pallas_calls(kernels) and not decays(kernels)
+    forward, = [eqn for eqn in eqns_outside_kernels(kernels)
+                if eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == "ssd_fwd"]
+    assert [(v.aval.shape, str(v.aval.dtype)) for v in forward.outvars] == [
+        ((1, t, H * 64), "float32"), ((1, t // Q, 128, H * 64), "float32")]
+
 
 
 def test_the_delta_mixer_takes_the_kernel_path_inside_shard_map(monkeypatch):

@@ -536,7 +536,9 @@ def test_the_memory_estimate_reads_the_passes():
 # This family's own step joins them (PR 68: its digest on PR 67's tree), so
 # that all twelve families standing before the stack's three scalars
 # (`residual_scale`, `softmax_scale`, `logit_scale`) are held to their text.
-STANDING = {"ssm_moe": ("tiny-ssm-moe", "6547cbbab2b5e5d2"),
+# (PR 69 meant to change the eleventh's text: its mixer writes `D x` on (b, t,
+# H P) as it lies, the same values; the digest is that tree's.)
+STANDING = {"ssm_moe": ("tiny-ssm-moe", "997c50bb28876b0f"),
             "llama": ("tiny", "14bb75356a403459"),
             "loop_llama": ("tiny-loop-llama", "93ef26ecbe667867")}
 

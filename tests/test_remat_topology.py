@@ -483,6 +483,36 @@ def test_the_channel_rules_kernels_compile_at_the_kda_cells_shape(
         ("kda_rule_pairs", 2), ("kda_rule_fwd", 5), ("kda_rule_bwd", 8)]
 
 
+@pytest.mark.parametrize("heads,groups,chunk", [(64, 1, 256), (32, 2, 128)])
+def test_the_recurrences_kernels_compile_at_the_ssm_cells_shapes(
+        topo, described_tpu, heads, groups, chunk):
+    """Mosaic takes the state-space recurrence's two kernels (PR 69) at cell
+    15's and cell 13's one sequence (64 heads in one group at chunks of
+    256; 32 heads in two groups at chunks of 128; 4096 tokens, heads of 64,
+    a state of 128, bf16), reached through `ops/ssd.ssd` as the mixer calls
+    it, under the names and operand counts that keep them out of the
+    benchmark's flash patterns."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.ssd import ssd
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (arg(bf16, 1, 4096, heads, 64), arg(f32, 1, 4096, heads),
+            arg(f32, heads), arg(bf16, 1, 4096, groups, 128),
+            arg(bf16, 1, 4096, groups, 128))
+    loss = lambda *a: jnp.sum(ssd(*a, chunk)[0].astype(f32))
+    text = jax.jit(jax.grad(loss, range(5))).lower(*args).compile().as_text()
+    calls = re.findall(
+        r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
+        r'custom_call_target="tpu_custom_call"', text)
+    # (taken alone, the gradient's calls are named by their transform too:
+    # `jvp_ssd_fwd_`; in a step they sit under its jitted scopes)
+    assert sorted((re.search(r"ssd_(fwd|bwd)", name).group(),
+                   operands.count("%")) for name, operands in calls) == [
+        ("ssd_bwd", 7), ("ssd_fwd", 5)]
+
+
 def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
         topo, described_tpu):
     """The fifth cell's step (`lfm2-8b-a1b.train-ep4share-b2-t8192`: the
@@ -620,9 +650,9 @@ def test_the_ssm_dense_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     tokens, bf16) compiled for the described chip: beside 8.63 GiB of
     weights and moments `remat="auto"` picks `dots` (every layer's
     `ffn_gate` / `ffn_up`, the attention layer's q, k, v and flash outputs),
-    so ONE flash forward in the text; the float32 decays of 256 x 256 a
-    head and chunk fit; no (1, 4096, 100352) logits: the slice's. The chip
-    itself counts 13.445 GiB (PERF.md section 5, PR 68)."""
+    so ONE flash forward in the text; no (1, 4096, 100352) logits: the
+    slice's. The chip itself counted 13.445 GiB with the recurrence as
+    XLA text (PERF.md section 5, PR 68)."""
     from distributed_pytorch_from_scratch_tpu.config import SsmDenseConfig
     from distributed_pytorch_from_scratch_tpu.models import build_model
     cfg = ModelConfig(
@@ -659,6 +689,24 @@ def test_the_ssm_dense_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 1
     assert len(re.findall(r"%flash_bwd[.\d]* = ", text)) == 1
     assert "f32[1,4096,12544]" in text and "100352" not in text
+    # since PR 69 the recurrence is two Mosaic calls (ops/pallas/ssd.py),
+    # forward, the layer's recompute (with the entering states) and the
+    # transpose, under the scope the benchmark reads as `mamba/ssd`, with
+    # operand counts its flash patterns pass over, and the text's float32
+    # decays of 256 x 256 a head and chunk are nowhere in the step
+    calls = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\(([^)]*)\), "
+        r'custom_call_target="tpu_custom_call"[^\n]*?op_name="([^"]*)"',
+        text)
+    ssd_calls = [(name.split(".")[0], operands.count("%"), scope)
+                 for name, _, operands, scope in calls
+                 if name.startswith("ssd_")]
+    assert sorted({(name, n) for name, n, _ in ssd_calls}) == [
+        ("ssd_bwd", 7), ("ssd_fwd", 5)]
+    assert all("mamba/ssd" in scope for _, _, scope in ssd_calls)
+    states = [out for name, out, _, _ in calls if name.startswith("ssd_fwd")]
+    assert any("f32[1,16,128,4096]" in out for out in states)
+    assert not re.search(r"\[[\d,]*256,256\]", text)
 
 
 def test_the_bd_moe_cells_step_compiles_for_a_v5e_at_the_rung_auto_picks(

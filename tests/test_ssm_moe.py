@@ -204,6 +204,76 @@ def test_the_chunked_recurrence_equals_the_token_by_token_one(t, chunk, H, G):
     assert y.shape == (2, t, H, 8) and float(decay_min) < 0.0
 
 
+def kernel_inputs(t, H, G, dtype=jnp.float32, dt_scale=1.0):
+    """`ssd_inputs` at the kernels' widths (heads of 64, a state of 128),
+    x, B and C in `dtype`."""
+    x, dt, A, B, C = ssd_inputs(t, H, G, Pd=64, N=128, dt_scale=dt_scale)
+    return x.astype(dtype), dt, A, B.astype(dtype), C.astype(dtype)
+
+
+@pytest.mark.parametrize("t,chunk,H,G,dtype,dt_scale,tol", [
+    # several groups, a length no chunk divides
+    (300, 128, 4, 2, jnp.float32, 1.0, 5e-5),
+    # one group of many heads (two blocks of 8), shorter than a chunk
+    (100, 256, 16, 1, jnp.float32, 1.0, 5e-5),
+    # groups of six heads (a block of three pairs), two chunks of 256
+    (300, 256, 12, 2, jnp.float32, 1.0, 5e-5),
+    # a large decay: a chunk's sums reach -60 and beyond
+    (256, 128, 4, 2, jnp.float32, 4.0, 1e-4),
+    (300, 128, 4, 1, jnp.bfloat16, 1.0, None),
+    (300, 256, 16, 2, jnp.bfloat16, 1.0, None)])
+def test_the_kernels_equal_the_text_and_the_token_by_token_recurrence(
+        t, chunk, H, G, dtype, dt_scale, tol):
+    """The Pallas kernels under the interpreter (`interpret=True`: the
+    path a TPU takes, asked for by name) against the XLA text and against
+    the token-by-token recurrence: the value, all five inputs' gradients
+    and `decay_min`. In float32 to `tol` of the largest entry (float32 sums
+    in another order; 1e-4 at the large decay, which is what float32
+    holds). With bfloat16 operands the products round, in the kernels and in
+    the text alike: each is held to the float32 recurrence on the same
+    numbers, and the kernels may stand twice as far from it as the text."""
+    args = kernel_inputs(t, H, G, dtype, dt_scale)
+    w = jax.random.normal(jax.random.key(9), (2, t, H, 64))
+    f32 = lambda a: a.astype(jnp.float32)
+
+    def run(fn, args):
+        loss = lambda *a: jnp.sum(f32(fn(*a)) * w)
+        return [fn(*args), *jax.grad(loss, range(5))(*args)]
+
+    with jax.default_matmul_precision("highest"):
+        want = run(token_by_token, [f32(a) for a in args])
+        text = run(lambda *a: ssd(*a, chunk=chunk)[0], args)
+        got = run(lambda *a: ssd(*a, chunk=chunk, interpret=True)[0], args)
+        low = [float(ssd(*args, chunk=chunk, interpret=i)[1])
+               for i in (False, True)]
+    assert low[0] == low[1] < 0.0
+    assert got[0].dtype == dtype and got[0].shape == (2, t, H, 64)
+    for name, a, b, c in zip(("y", "dx", "ddt", "dA", "dB", "dC"), got, text,
+                             want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        limit = tol if tol else max(2 * rel(b, c), 2e-3)
+        assert rel(a, c) <= limit, (name, rel(a, c), rel(b, c))
+        if tol:
+            assert rel(a, b) <= tol, (name, rel(a, b))
+
+
+def test_what_the_kernels_do_not_hold_is_the_texts_or_is_refused():
+    """A state in bfloat16 (the benchmark's control) and a shape outside
+    `ops/pallas/ssd.holds` take the text on every backend; asking for the
+    interpreter there raises, as the channel rule's does."""
+    args = kernel_inputs(128, 4, 2)
+    narrow = ssd_inputs(128, 4, 2)
+    for bad, kw in ((args, dict(chunk=128, state_dtype=jnp.bfloat16)),
+                    (args, dict(chunk=64)), (narrow, dict(chunk=128)),
+                    (kernel_inputs(128, 3, 3), dict(chunk=128))):
+        assert "pallas_call" not in str(jax.make_jaxpr(
+            lambda *a: ssd(*a, **kw))(*bad))
+        with pytest.raises(ValueError, match="heads of 64 in pairs"):
+            ssd(*bad, interpret=True, **kw)
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *a: ssd(*a, chunk=128, interpret=True))(*args))
+
+
 def test_a_head_reads_its_own_groups_b_and_c():
     """With every group's B and C group 0's, the chunked form computes
     another function: heads of the later groups differ, group 0's do not."""
