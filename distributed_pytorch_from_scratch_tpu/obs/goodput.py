@@ -6,9 +6,11 @@ I/O, eval — is badput with a named cause.
 
 The meter is driven by the same spans the tracer records (TrainObserver
 feeds both from one `with observer.span(bucket)`), so the timeline view and
-the aggregate view can never disagree. Time in no bucket (python loop
-overhead, logging, model init) lands in `other`, so the buckets always sum
-to wall time exactly.
+the aggregate view can never disagree. Time in no span (what the loop does
+between two spans: the token count, the loss sum, the heartbeat) lands in
+`other`, so the buckets always sum to wall time exactly. `train()`'s set-up
+(backend, data, model, weights, Adam state, step build) is the `setup.*`
+spans' bucket `setup`, and the host work of a log interval the `log` span's.
 """
 
 from __future__ import annotations
@@ -19,13 +21,17 @@ from typing import Callable, Dict
 # Every interval of wall time is attributed to exactly one of these.
 # "step" = dispatching the train step + blocked waiting on device results:
 # the tokens-on-device bucket that defines goodput. The rest is badput.
-BUCKETS = ("compile", "data_wait", "h2d", "step", "checkpoint", "eval")
+BUCKETS = ("setup", "compile", "data_wait", "h2d", "step", "log",
+           "checkpoint", "eval")
 
 
 class GoodputMeter:
-    def __init__(self, clock: Callable[[], float] = time.monotonic):
+    def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 started_ago: float = 0.0):
+        """`started_ago`: seconds the run was already under way when the
+        meter could be made; its wall starts that much earlier."""
         self._clock = clock
-        self._t0 = clock()
+        self._t0 = clock() - started_ago
         self._buckets: Dict[str, float] = {b: 0.0 for b in BUCKETS}
         self.tokens = 0
         self.steps = 0
@@ -34,6 +40,10 @@ class GoodputMeter:
         """Attribute `seconds` of wall time to `bucket`. Unknown buckets are
         created on the fly (they show up in the summary like any other)."""
         self._buckets[bucket] = self._buckets.get(bucket, 0.0) + seconds
+
+    def bucket(self, name: str) -> float:
+        """Seconds accounted to `name` so far."""
+        return self._buckets.get(name, 0.0)
 
     def add_progress(self, tokens: int, steps: int = 1) -> None:
         self.tokens += tokens

@@ -45,17 +45,48 @@ class LoopSpans:
         return self._observer.span(cat or name, name, **args)
 
 
+class SpanSequence:
+    """Spans of one bucket that follow one another on one thread, none
+    inside another: `enter(name)` ends the open one and starts the next as
+    `observer.span(bucket, name)`, `end()` ends the last (a second call
+    does nothing). For a long stretch of straight-line code, `train()`'s
+    set-up, whose phases a `with` each would indent by the hundred lines."""
+
+    def __init__(self, observer: "TrainObserver", bucket: str):
+        self._observer = observer
+        self._bucket = bucket
+        self._open = None
+
+    def enter(self, name: str, **args) -> None:
+        self.end()
+        self._open = self._observer.span(self._bucket, name, **args)
+        self._open.__enter__()
+
+    def end(self) -> None:
+        if self._open is not None:
+            span, self._open = self._open, None
+            span.__exit__(None, None, None)
+
+
 class TrainObserver:
     def __init__(self, log_dir: str, writer=None, trace: bool = True,
                  watchdog_secs: float = 0.0, sentinel: bool = True,
                  spike_factor: float = 3.0, halt_on_nonfinite: bool = True,
                  process_index: int = 0, flight_ring: int = 256,
-                 profile_on_anomaly: int = 0):
+                 profile_on_anomaly: int = 0,
+                 started: Optional[float] = None):
+        """`started`: a `time.perf_counter()` sample from before the
+        observer could exist (its directory needs the process index, which
+        needs the backend). The timeline's zero and the goodput meter's
+        wall start there, and `span_done` books what ran since."""
         self.writer = writer
         self.process_index = process_index
         self.tracer = SpanTracer(log_dir, enabled=trace, pid=process_index,
-                                 process_name=f"train-p{process_index}")
-        self.goodput = GoodputMeter()
+                                 process_name=f"train-p{process_index}",
+                                 t0=started)
+        self.goodput = GoodputMeter(
+            started_ago=0.0 if started is None
+            else time.perf_counter() - started)
         # anomaly-triggered device profiling (ISSUE 12): a flight dump
         # arms a bounded jax.profiler window that tick()s from heartbeat
         profiler = None
@@ -120,6 +151,24 @@ class TrainObserver:
                 # "recovered" line marks the moment it finished
                 self.watchdog.beat(phase=f"{name or bucket}:done")
 
+    def span_done(self, bucket: str, name: str, start: float, end: float,
+                  **args) -> None:
+        """A span of this thread that was over before the observer existed,
+        from two `time.perf_counter()` samples: the timeline's event, the
+        bucket's seconds, the flight ring's record and the watchdog's beat,
+        as `span` gives them. No profiler annotation: that cannot be
+        entered after the fact."""
+        self.tracer.complete_span(name, start, end, cat=bucket, **args)
+        self.goodput.account(bucket, end - start)
+        if self.flight is not None:
+            self.flight.record("span", bucket=bucket, name=name,
+                               dur_s=round(end - start, 6), **args)
+        if self.watchdog is not None:
+            self.watchdog.beat(phase=f"{name}:done")
+
+    def sequence(self, bucket: str) -> SpanSequence:
+        return SpanSequence(self, bucket)
+
     def instant(self, name: str, **args) -> None:
         self.tracer.instant(name, **args)
 
@@ -147,7 +196,7 @@ class TrainObserver:
                         expected_flops: Optional[float] = None,
                         step: int = 0) -> None:
         """Log the introspection record (obs.introspect.analyze_compiled)
-        to metrics + trace; the caller prints the human line.
+        as the `cost_analysis` event; the caller prints the human line.
         `expected_flops` = the hand-rolled estimate scaled to THIS program
         (x steps per dispatch, / world size for SPMD per-device HLO)."""
         if self.writer is not None:
@@ -161,7 +210,6 @@ class TrainObserver:
                 model_flops_per_step=model_flops,
                 steps_in_program=steps_in_program,
                 expected_program_flops=expected_flops)
-        self.tracer.instant("cost_analysis", flops=analysis.get("flops"))
 
     def close(self, print_summary: bool = True) -> Optional[dict]:
         """Stop the watchdog, write trace.json, log + return the goodput

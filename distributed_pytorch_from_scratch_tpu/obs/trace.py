@@ -72,11 +72,15 @@ class SpanTracer:
 
     def __init__(self, log_dir: Optional[str], enabled: bool = True,
                  pid: int = 0, process_name: Optional[str] = None,
-                 clock: Callable[[], float] = time.perf_counter):
+                 clock: Callable[[], float] = time.perf_counter,
+                 t0: Optional[float] = None):
         self.enabled = enabled and log_dir is not None
         self.pid = pid
         self._clock = clock
-        self._t0 = clock()
+        # the timeline's zero: now, or a sample of `clock` taken before the
+        # tracer could exist (a span that `complete_span` writes after the
+        # fact then starts at 0 and not before it)
+        self._t0 = clock() if t0 is None else t0
         self._lock = threading.Lock()
         self._jsonl = None
         self._unflushed = 0

@@ -26,6 +26,7 @@ import os
 import signal
 import threading
 import time
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -458,7 +459,15 @@ class _ShutdownFlag:
             signal.signal(sig, prev)
 
 
-def train(args: argparse.Namespace) -> dict:
+def train(args: argparse.Namespace,
+          stop: Optional[Callable[[int], bool]] = None) -> dict:
+    """`stop`: for a caller that runs `train()` in its own process and wants
+    it to end before `--max_steps`: polled with the step count once a window
+    of batches, where the shutdown signals are polled; true ends the run as
+    reaching `--max_steps` ends it (the record is returned)."""
+    # the timeline's zero and the start of `setup.backend`: the observer
+    # that books it needs the process index, and so the backend, first
+    t_train = time.perf_counter()
     if args.debug_nans:
         jax.config.update("jax_debug_nans", True)
     # Multi-host rendezvous before any backend use (no-op on single host;
@@ -493,12 +502,13 @@ def train(args: argparse.Namespace) -> dict:
                          f"{args.dp_size * args.ep_size} (the batch shards "
                          f"over both axes)")
     mesh = make_mesh(mesh_cfg)
+    t_mesh = time.perf_counter()
 
     # One metrics/trace dir per process in multi-host runs (the reference
     # keeps one TB dir per rank, `/root/reference/train.py:85`); TB event
     # files and profiler traces from two writers in one dir clobber.
-    # Created before model/data setup so the observer's timeline covers
-    # init and checkpoint restore too.
+    # Created before model/data setup: the observer's timeline covers the
+    # set-up phase by phase (`setup.*`, one after another up to the loop).
     proc_idx = process_info()[0]
     logs_dir = os.path.join(args.save_dir, "logs") if nproc == 1 else \
         os.path.join(args.save_dir, "logs", f"proc{proc_idx}")
@@ -521,11 +531,18 @@ def train(args: argparse.Namespace) -> dict:
         watchdog_secs=args.watchdog_secs, sentinel=not args.no_sentinel,
         spike_factor=args.sentinel_spike_factor,
         process_index=proc_idx, flight_ring=args.flight_ring,
-        profile_on_anomaly=args.profile_on_anomaly)
+        profile_on_anomaly=args.profile_on_anomaly, started=t_train)
+    # after the fact: the rendezvous and the first touch of the devices,
+    # then what it took to have the observer (the metrics writer's
+    # tensorboardX import is most of it, seconds where that imports torch)
+    observer.span_done("setup", "setup.backend", t_train, t_mesh)
+    observer.span_done("setup", "setup.logs", t_mesh, time.perf_counter())
+    setup = observer.sequence("setup")
     duty = None  # DutyCycleProfiler, built once the model shape is known
     advisor = None  # RetuneAdvisor (obs v5), rides the duty profiler
 
     try:
+        setup.enter("setup.data")
         dataloader = get_dataloader(args.data_path, args.batch_size,
                                     IGNORE_INDEX, split="train",
                                     maxlen=maxlen, shuffle=True,
@@ -536,6 +553,7 @@ def train(args: argparse.Namespace) -> dict:
             from .data.native import native_status
             print(f"data: {len(dataloader.dataset)} documents, collate = "
                   f"{native_status()}")
+        setup.enter("setup.model")
         # the preset with the flags laid over it: what no flag names (the
         # mla_moe family's `latent_moe`, rope_theta) stays the preset's
         cfg = dataclasses.replace(
@@ -704,6 +722,8 @@ def train(args: argparse.Namespace) -> dict:
                                lr_schedule=args.lr_schedule,
                                cosine_min_ratio=args.cosine_min_ratio)
 
+        # the weights: made here, or read back inside the `restore` spans
+        setup.enter("setup.init")
         params = model.init(jax.random.key(args.random_seed))
         # count from the actual pytree: exact for every family (cfg.num_params()
         # hardcodes the llama layout — untied head, SwiGLU, no position table)
@@ -736,7 +756,7 @@ def train(args: argparse.Namespace) -> dict:
               + (f", zero={zero_stage}" if zero_stage else "")
               + f" on {jax.device_count()} x {dev0.platform} "
                 f"[{dev0.device_kind}]")
-        opt_state = init_adam_state(params)
+        opt_state = None  # a resume below may bring the moments
         start_step = 0
         if args.resume:
             if nproc > 1:
@@ -779,7 +799,8 @@ def train(args: argparse.Namespace) -> dict:
                             f"{args.tp_size} --dp {args.dp_size} --zero "
                             f"{zero_stage} --model <preset>")
                     tmpl_p = model.to_canonical(params)
-                    tmpl_o = map_moments(opt_state, model.to_canonical)
+                    tmpl_o = map_moments(init_adam_state(params),
+                                         model.to_canonical)
                     if is_main:
                         with observer.span("checkpoint", "restore", step=last):
                             ck_p, ck_o, start_step = load_checkpoint(
@@ -813,9 +834,7 @@ def train(args: argparse.Namespace) -> dict:
                                 model.to_canonical(params),
                                 model.canonical_specs(), with_opt=True)
                         params = model.from_canonical(params)
-                        if opt_state is None:
-                            opt_state = init_adam_state(params)
-                        else:
+                        if opt_state is not None:
                             opt_state = map_moments(opt_state,
                                                     model.from_canonical)
                         print(f"resumed from iter {start_step} in "
@@ -854,8 +873,7 @@ def train(args: argparse.Namespace) -> dict:
                                 model.canonical_specs(), dst_lay, p_sh,
                                 moment_shardings=m_sh, with_opt=True,
                                 meter=meter)
-                        opt_state = (ck_o if ck_o is not None
-                                     else init_adam_state(params))
+                        opt_state = ck_o
                         writer.event(
                             "reshard_event", src_layout=info["src"],
                             dst_layout=info["dst"],
@@ -877,6 +895,9 @@ def train(args: argparse.Namespace) -> dict:
         else:
             shardings = model.shardings(mesh)
         params = jax.device_put(params, shardings)
+        setup.enter("setup.opt_state")
+        if opt_state is None:
+            opt_state = init_adam_state(params)
         moment_sh = (zero1_moment_shardings(model, mesh)
                      if zero_stage in (1, 2) else shardings)
         opt_state = jax.device_put(
@@ -884,6 +905,7 @@ def train(args: argparse.Namespace) -> dict:
                 step=jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec()),
                 mu=moment_sh, nu=moment_sh))
 
+        setup.enter("setup.build_step")
         spd = max(1, args.steps_per_dispatch)
         accum = max(1, args.grad_accum)
         if accum > 1 and spd > 1:
@@ -924,7 +946,8 @@ def train(args: argparse.Namespace) -> dict:
         feed = batch_feeder(mesh, tracer=observer.loop_spans)
         # profile a window shortly after start so compile+layout churn is over
         profiler = ProfilerTrace(logs_dir, start_step=start_step + 3,
-                                 num_steps=args.profile_steps)
+                                 num_steps=args.profile_steps,
+                                 tracer=observer.loop_spans)
         if args.profile_every:
             # duty-cycled measured attribution (ISSUE 15): the analytic
             # phase report this run is priced with rides along, so every
@@ -1058,7 +1081,7 @@ def train(args: argparse.Namespace) -> dict:
         accum_loss, n = jnp.zeros((), jnp.float32), start_step
         # (summed loss, steps) of the first and the newest dispatch: device
         # values, read once for the summary record
-        first_loss = last_loss = None
+        first_loss = last_loss = first_gnorm = None
         # the sentinel piggybacks on the logging-interval sync: last dispatch's
         # on-device grad norm + the per-interval mean loss, no extra D2H
         last_gnorm = None
@@ -1149,10 +1172,11 @@ def train(args: argparse.Namespace) -> dict:
                   f"restart with --resume to continue")
 
         multi = accum > 1 or spd > 1
-        host_wait, host_dispatches = 0.0, 0
+        host_dispatches = 0
         prefetcher = None  # closed in the finally on ANY exit (thread cleanup)
         recompiles_logged = 0
         compile_cache.subscribe(on_program)
+        setup.end()
         try:
             for epoch in range(start_epoch, max_epoch):
                 # One background thread assembles the NEXT dispatch's window
@@ -1172,7 +1196,6 @@ def train(args: argparse.Namespace) -> dict:
                     transform=stack_window if multi else (lambda bufs: bufs[0]),
                     tracer=observer.loop_spans)
                 while True:
-                    wait_before = prefetcher.wait_time
                     try:
                         # (the "data_wait" span is the prefetcher's own)
                         window = prefetcher.pull(step=n)
@@ -1186,6 +1209,11 @@ def train(args: argparse.Namespace) -> dict:
                     if shutdown_agreed(n):
                         prefetcher.close()
                         shutdown_save(n)
+                        done = True
+                        break
+                    if stop is not None and stop(n):
+                        # the caller's stop: the run ends here as it ends
+                        # at --max_steps (no shutdown checkpoint)
                         done = True
                         break
                     if accum > 1 and window["input_ids"].shape[0] < accum:
@@ -1235,10 +1263,6 @@ def train(args: argparse.Namespace) -> dict:
                         # the duty window's start/stop boundaries; `loss`
                         # is this dispatch's device value (stop barrier)
                         duty.tick(n, sync=loss)
-                    # only DISPATCHED pulls count toward the ms/dispatch wait
-                    # metric (dropped partial groups and the end-of-epoch
-                    # sentinel would deflate it)
-                    host_wait += prefetcher.wait_time - wait_before
                     host_dispatches += 1
                     if args.profile_steps:
                         profiler.maybe_stop(n, sync=loss)
@@ -1246,113 +1270,140 @@ def train(args: argparse.Namespace) -> dict:
                     last_loss = (loss, n - prev_n)
                     if first_loss is None:
                         first_loss = last_loss
+                        # the norm of the run's first gradient
+                        first_gnorm = (gnorms[0] if spd > 1 else last_gnorm)
                     if n // args.log_interval > prev_n // args.log_interval:
-                        lr, _ = schedule_lr(ocfg, jnp.asarray(n - 1))
                         # the one blocking D2H of the interval: cumulative loss
-                        # + last dispatch's grad norm ride the same sync
+                        # + last dispatch's grad norm ride the same sync.
+                        # The schedule's value is asked for first, inside the
+                        # span: its eager one-op programs queue behind the
+                        # interval's steps and the host's dispatch of them
+                        # waits for the device, so the wait begins at this
+                        # line (ahead of the span it read 1 ms and the loop
+                        # stood 2.6 s in no span; behind the sync the same
+                        # programs run on an idle device, 11 ms an interval:
+                        # PERF.md section 6, PR 70)
                         with observer.span("step", "device_sync", step=n):
+                            lr, _ = schedule_lr(ocfg, jnp.asarray(n - 1))
                             cum = float(accum_loss)
                             gnorm = (float(last_gnorm)
                                      if last_gnorm is not None else None)
-                        avg = cum / (n - start_step)
-                        interval_loss = (cum - last_cum) / max(n - last_log_n, 1)
-                        dt = time.time() - t_start
-                        tps = tokens_since / max(dt, 1e-9)
-                        useful = useful_since / max(tokens_since, 1)
-                        mfu = ((flops_step * steps_since) / max(dt, 1e-9)
-                               / peak_flops if peak_flops else None)
-                        mfu_s = (f"MFU {mfu*100:.1f}%" if mfu is not None
-                                 else "MFU not measured (no chip peak for "
-                                      "the cpu backend)")
-                        # None = the backend reports no memory_stats (CPU):
-                        # say so loudly; a 0.00 GiB watermark here misread
-                        # as "no HBM used" on every chip-less box (ISSUE 15)
-                        mem = device_memory_gib()
-                        mem_s = (f"{mem:.2f} GiB" if mem is not None
-                                 else "n/a (no memory stats)")
-                        rebuilt = recompiles[recompiles_logged:]
-                        recompiles_logged += len(rebuilt)
-                        print(f"step {n}/{args.max_steps} -> avg loss {avg:.4f}, "
-                              f"lr {float(lr):.8f}, {tps/1e3:.1f}k tok/s "
-                              f"({useful*100:.0f}% useful), "
-                              f"{mfu_s}, mem {mem_s}"
-                              + (f", {len(rebuilt)} recompile(s) at step "
-                                 f"{', '.join(str(r['step']) for r in rebuilt)}"
-                                 if rebuilt else ""))
-                        writer.scalar("train/ce_loss", avg, n)
-                        writer.scalar("train/lr", float(lr), n)
-                        writer.scalar("train/tokens_per_sec", tps, n)
-                        writer.scalar("train/useful_token_frac", useful, n)
-                        if mfu is not None:  # never export a fake 0
-                            writer.scalar("train/mfu", mfu, n)
-                        if mem is not None:  # never export a fake 0
-                            writer.scalar("device_memory_gib", mem, n)
-                        # live HBM watermarks (ISSUE 15): per-device
-                        # gauges + one hbm_watermark event per interval
-                        # ('unavailable' exported loudly on CPU)
-                        marks = publish_hbm(telemetry=telemetry,
-                                            writer=writer, step=n,
-                                            event=True)
-                        if advisor is not None:
-                            # proposals only — actuation stays at the
-                            # on_attribution safe point (or close())
-                            advisor.observe_hbm(
-                                {"devices": marks or [],
-                                 "available": marks is not None})
-                        if gnorm is not None:
-                            writer.scalar("train/grad_norm", gnorm, n)
-                        if (last_counters is not None
-                                and "loss_exit" in last_counters):
-                            # a stack passed R times: its exits and gate
-                            loop = loop_counters_summary(
-                                jax.device_get(last_counters))
-                            print("  " + ", ".join(
-                                f"{k} {v:.4g}" for k, v in loop.items()))
-                            writer.event("loop_counters", step=n, **loop)
-                        elif (last_counters is not None
-                                and "routed" not in last_counters):
-                            # a dense family whose mixers count
-                            mixers = mixer_counters_summary(
-                                jax.device_get(last_counters))
-                            print("  " + ", ".join(
-                                f"{k} {v:.4g}" for k, v in mixers.items()))
-                            writer.event("mixer_counters", step=n, **mixers)
-                        elif last_counters is not None:
-                            moe = moe_counters_summary(
-                                jax.device_get(last_counters), cfg,
-                                window["input_ids"].size)
-                            print("  " + ", ".join(
-                                f"{k} {v:.4g}" for k, v in moe.items()))
-                            writer.event("moe_counters", step=n, **moe)
-                            if "masked" in last_counters:
-                                bd = bd_counters_summary(
+                        # when the interval's last step was done: the
+                        # interval record's `ts`, timeline on or off
+                        synced = time.time()
+                        # the interval's host work, the device idle under it
+                        with observer.span("log", step=n) as found:
+                            built = compile_cache_stats()["programs"]
+                            avg = cum / (n - start_step)
+                            interval_loss = ((cum - last_cum)
+                                             / max(n - last_log_n, 1))
+                            dt = time.time() - t_start
+                            tps = tokens_since / max(dt, 1e-9)
+                            useful = useful_since / max(tokens_since, 1)
+                            mfu = ((flops_step * steps_since) / max(dt, 1e-9)
+                                   / peak_flops if peak_flops else None)
+                            mfu_s = (f"MFU {mfu*100:.1f}%" if mfu is not None
+                                     else "MFU not measured (no chip peak for "
+                                          "the cpu backend)")
+                            # None = the backend reports no memory_stats
+                            # (CPU): say so loudly; a 0.00 GiB watermark here
+                            # misread as "no HBM used" on every chip-less box
+                            # (ISSUE 15)
+                            mem = device_memory_gib()
+                            mem_s = (f"{mem:.2f} GiB" if mem is not None
+                                     else "n/a (no memory stats)")
+                            rebuilt = recompiles[recompiles_logged:]
+                            recompiles_logged += len(rebuilt)
+                            at = ", ".join(str(r["step"]) for r in rebuilt)
+                            print(f"step {n}/{args.max_steps} -> "
+                                  f"avg loss {avg:.4f}, "
+                                  f"lr {float(lr):.8f}, {tps/1e3:.1f}k tok/s "
+                                  f"({useful*100:.0f}% useful), "
+                                  f"{mfu_s}, mem {mem_s}"
+                                  + (f", {len(rebuilt)} recompile(s) at step "
+                                     f"{at}" if rebuilt else ""))
+                            writer.scalar("train/ce_loss", avg, n, ts=synced)
+                            writer.scalar("train/lr", float(lr), n)
+                            writer.scalar("train/tokens_per_sec", tps, n)
+                            writer.scalar("train/useful_token_frac", useful, n)
+                            if mfu is not None:  # never export a fake 0
+                                writer.scalar("train/mfu", mfu, n)
+                            if mem is not None:  # never export a fake 0
+                                writer.scalar("device_memory_gib", mem, n)
+                            # live HBM watermarks (ISSUE 15): per-device
+                            # gauges + one hbm_watermark event per interval
+                            # ('unavailable' exported loudly on CPU)
+                            marks = publish_hbm(telemetry=telemetry,
+                                                writer=writer, step=n,
+                                                event=True)
+                            if advisor is not None:
+                                # proposals only — actuation stays at the
+                                # on_attribution safe point (or close())
+                                advisor.observe_hbm(
+                                    {"devices": marks or [],
+                                     "available": marks is not None})
+                            if gnorm is not None:
+                                writer.scalar("train/grad_norm", gnorm, n)
+                            if (last_counters is not None
+                                    and "loss_exit" in last_counters):
+                                # a stack passed R times: its exits and gate
+                                loop = loop_counters_summary(
                                     jax.device_get(last_counters))
                                 print("  " + ", ".join(
-                                    f"{k} {v:.4g}" for k, v in bd.items()))
-                                writer.event("bd_counters", step=n, **bd)
-                        if telemetry is not None:
-                            # same numbers the log line prints — the live
-                            # endpoint view; the goodput buckets ride too
-                            # (a dict copy per log interval, not per step)
-                            telemetry.gauge("train/tokens_per_sec", tps)
-                            if mfu is not None:
-                                telemetry.gauge("train/mfu", mfu)
-                            telemetry.gauge("train/loss_avg", avg)
-                            telemetry.gauge(
-                                "train/step_time_ms",
-                                1e3 * dt / max(steps_since, 1))
-                            telemetry.counter("train/step", n)
-                            gsum = observer.goodput.summary()
-                            telemetry.gauge("train/goodput",
-                                            gsum["goodput"])
-                            for b, v in gsum["buckets_s"].items():
-                                telemetry.gauge(f"train/bucket_s/{b}", v)
-                        last_cum, last_log_n = cum, n
-                        t_start, tokens_since, steps_since = time.time(), 0, 0
-                        useful_since = 0
-                        # after the metrics land on disk: a non-finite interval
-                        # raises TrainingHealthError through the finally below
-                        observer.check_health(n, interval_loss, gnorm)
+                                    f"{k} {v:.4g}" for k, v in loop.items()))
+                                writer.event("loop_counters", step=n, **loop)
+                            elif (last_counters is not None
+                                    and "routed" not in last_counters):
+                                # a dense family whose mixers count
+                                mixers = mixer_counters_summary(
+                                    jax.device_get(last_counters))
+                                print("  " + ", ".join(
+                                    f"{k} {v:.4g}" for k, v in mixers.items()))
+                                writer.event("mixer_counters", step=n,
+                                             **mixers)
+                            elif last_counters is not None:
+                                moe = moe_counters_summary(
+                                    jax.device_get(last_counters), cfg,
+                                    window["input_ids"].size)
+                                print("  " + ", ".join(
+                                    f"{k} {v:.4g}" for k, v in moe.items()))
+                                writer.event("moe_counters", step=n, **moe)
+                                if "masked" in last_counters:
+                                    bd = bd_counters_summary(
+                                        jax.device_get(last_counters))
+                                    print("  " + ", ".join(
+                                        f"{k} {v:.4g}" for k, v in bd.items()))
+                                    writer.event("bd_counters", step=n, **bd)
+                            if telemetry is not None:
+                                # same numbers the log line prints — the live
+                                # endpoint view; the goodput buckets ride too
+                                # (a dict copy per log interval, not per step)
+                                telemetry.gauge("train/tokens_per_sec", tps)
+                                if mfu is not None:
+                                    telemetry.gauge("train/mfu", mfu)
+                                telemetry.gauge("train/loss_avg", avg)
+                                telemetry.gauge(
+                                    "train/step_time_ms",
+                                    1e3 * dt / max(steps_since, 1))
+                                telemetry.counter("train/step", n)
+                                gsum = observer.goodput.summary()
+                                telemetry.gauge("train/goodput",
+                                                gsum["goodput"])
+                                for b, v in gsum["buckets_s"].items():
+                                    telemetry.gauge(f"train/bucket_s/{b}", v)
+                            last_cum, last_log_n = cum, n
+                            t_start, tokens_since, steps_since = (
+                                time.time(), 0, 0)
+                            useful_since = 0
+                            # programs built inside the interval (the eager
+                            # one-op ones of a first interval; 0 in a steady
+                            # one)
+                            found["programs"] = (
+                                compile_cache_stats()["programs"] - built)
+                            # after the metrics land on disk: a non-finite
+                            # interval raises TrainingHealthError through the
+                            # finally below
+                            observer.check_health(n, interval_loss, gnorm)
                     if n // args.save_interval > prev_n // args.save_interval:
                         checkpointer.save(n, accum_loss, params, opt_state)
                     if n >= args.max_steps:
@@ -1415,6 +1466,8 @@ def train(args: argparse.Namespace) -> dict:
         final_avg = float(accum_loss) / max(n - start_step, 1)
         profiler.close(sync=accum_loss)
         if host_dispatches:
+            # the `data_wait` spans' bucket: one source for one number
+            host_wait = observer.goodput.bucket("data_wait")
             print(f"input pipeline: host waited "
                   f"{1e3 * host_wait / host_dispatches:.2f} ms/dispatch for "
                   f"data ({host_dispatches} dispatches; collate+stack ran on "
@@ -1427,6 +1480,8 @@ def train(args: argparse.Namespace) -> dict:
                               if first_loss else None),
                "last_loss": (float(last_loss[0]) / last_loss[1]
                              if last_loss else None),
+               "first_grad_norm": (float(first_gnorm)
+                                   if first_gnorm is not None else None),
                "platform": dev0.platform, "device_kind": dev0.device_kind,
                "device_count": jax.device_count(),
                "mesh": {"dp": args.dp_size, "pp": args.pp_size,
@@ -1454,6 +1509,7 @@ def train(args: argparse.Namespace) -> dict:
         # watchdog thread or the open trace/metrics handles when train()
         # is embedded (tests call it repeatedly). Both closes are
         # idempotent, so the happy path's finally running first is fine.
+        setup.end()  # the phase that raised is on the timeline as far as it got
         if duty is not None:
             duty.close()
         if advisor is not None:

@@ -17,6 +17,8 @@ from typing import List, Optional
 
 import jax
 
+from ..obs.trace import span_of
+
 
 class MetricsWriter:
     """Multihost-safe: process 0 writes `metrics.jsonl`, every other
@@ -79,9 +81,13 @@ class MetricsWriter:
         self.path = nxt
         self._jsonl = open(nxt, "a")
 
-    def scalar(self, tag: str, value: float, step: int) -> None:
+    def scalar(self, tag: str, value: float, step: int,
+               ts: Optional[float] = None) -> None:
+        """`ts`: a `time.time()` sample of the moment the value belongs to,
+        where that is earlier than the write (`train()`'s interval record:
+        when the interval's last step was done); else now."""
         self._write({"tag": tag, "value": float(value), "step": int(step),
-                     "ts": time.time()})
+                     "ts": time.time() if ts is None else ts})
         # post-close writes drop entirely: tensorboardX would resurrect a
         # fresh, never-flushed event file on a late add_scalar
         if self._tb is not None and not self._closed:
@@ -263,10 +269,16 @@ class ProfilerTrace:
     analogue of the reference's (absent) torch profiler; SURVEY §5.1. View
     the trace with TensorBoard's profile plugin or xprof."""
 
-    def __init__(self, log_dir: str, start_step: int, num_steps: int):
+    def __init__(self, log_dir: str, start_step: int, num_steps: int,
+                 tracer=None):
+        """`tracer`: anything with `SpanTracer.span` (`train()` hands its
+        `LoopSpans`): starting and stopping the capture hold the loop's
+        thread, the stop for as long as the capture takes to write, and are
+        the spans `profile.start` and `profile.stop` of bucket `profile`."""
         self.log_dir = os.path.join(log_dir, "profile")
         self.start_step = start_step
         self.stop_step = start_step + num_steps
+        self._tracer = tracer
         self._active = False
         self._done = False
 
@@ -277,8 +289,15 @@ class ProfilerTrace:
         # start_step and covers at least num_steps (`_done` stops it from
         # restarting every later step)
         if not self._active and not self._done and step >= self.start_step:
-            os.makedirs(self.log_dir, exist_ok=True)
-            jax.profiler.start_trace(self.log_dir)
+            with span_of(self._tracer, "profile.start", cat="profile",
+                         step=step):
+                os.makedirs(self.log_dir, exist_ok=True)
+                # no Python frames (JAX's default traces them): the capture
+                # must not slow the steps it reads
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(self.log_dir,
+                                         profiler_options=options)
             self._active = True
 
     def maybe_stop(self, step: int, sync=None) -> None:
@@ -286,9 +305,11 @@ class ProfilerTrace:
         dispatch is async, so without blocking on it stop_trace would fire
         while the profiled steps are still executing and truncate the trace."""
         if self._active and step >= self.stop_step:
-            if sync is not None:
-                jax.block_until_ready(sync)
-            jax.profiler.stop_trace()
+            with span_of(self._tracer, "profile.stop", cat="profile",
+                         step=step):
+                if sync is not None:
+                    jax.block_until_ready(sync)
+                jax.profiler.stop_trace()
             self._active = False
             self._done = True
             import sys

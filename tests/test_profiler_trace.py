@@ -17,8 +17,10 @@ from distributed_pytorch_from_scratch_tpu.training.metrics import (
 @pytest.fixture
 def profiler_calls(monkeypatch):
     calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, profiler_options=None: calls.append(
+            ("start", d, profiler_options)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop",)))
     return calls
@@ -65,7 +67,8 @@ def test_close_mid_window_stops_cleanly(tmp_path, profiler_calls):
     assert p._active
     p.close(sync=jnp.zeros(()))
     assert not p._active
-    assert profiler_calls == [("start", p.log_dir), ("stop",)]
+    assert [c[:2] for c in profiler_calls] == [("start", p.log_dir),
+                                               ("stop",)]
     p.close()  # idempotent: no second stop
     assert profiler_calls.count(("stop",)) == 1
 
@@ -75,3 +78,30 @@ def test_close_without_start_is_noop(tmp_path, profiler_calls):
     p.maybe_stop(1)
     p.close()
     assert profiler_calls == []
+
+
+def test_capture_traces_no_python_frames_and_spans_its_start_and_stop(
+        tmp_path, profiler_calls):
+    """JAX's default traces Python frames (`python_tracer_level` 1), which
+    slows the steps the capture reads: the program's capture turns it off,
+    as the benchmark's do. Starting and stopping hold the loop's thread and
+    are spans of the tracer the loop hands in."""
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+
+    assert jax.profiler.ProfileOptions().python_tracer_level != 0
+    tracer = SpanTracer(str(tmp_path / "timeline"))
+    p = ProfilerTrace(str(tmp_path), start_step=1, num_steps=2, tracer=tracer)
+    for step in range(5):
+        p.maybe_start(step)
+        p.maybe_stop(step + 1, sync=jnp.zeros(()))
+    (start,) = [c for c in profiler_calls if c[0] == "start"]
+    assert start[2].python_tracer_level == 0
+    tracer.close()
+    import json
+    events = json.load(open(tmp_path / "timeline" / "trace.json"))
+    spans = [(e["name"], e["cat"], e["args"]["step"])
+             for e in events["traceEvents"]
+             # (a worker whose compile listener is on writes `compile.*` too)
+             if e.get("ph") == "X" and e["name"].startswith("profile.")]
+    assert spans == [("profile.start", "profile", 1),
+                     ("profile.stop", "profile", 3)]
