@@ -214,15 +214,18 @@ class ConvMoETransformer(DecoderStack):
         bytes); and one chunk of the expert dispatch
         (`SharedRoutedFFN.chunk_share` of a token's pairs: rows in and
         out, and the hidden activations `[gate | up]`, their product and
-        both cotangents). At a held share of 1/4 the chunk is ALL pairs
-        (under a sixth it is one mean share: `parallel/moe.CHUNK_SHARES`),
-        so this is what sizes the step. An attention layer holds less.
+        both cotangents). A chunk is one mean share of the pairs
+        (`parallel/moe.CHUNK_SHARES`: at a held share of 1/4 one row a
+        token at top-4; until PR 71 ALL pairs there). An attention layer
+        holds less.
         The last term takes 18.96 d a token back off and is SET FROM THE
         CHIP'S READING (the skeleton's 3.4 f a token is the one dense
         layer's MLP, which no expert layer holds beside its chunk): cell 7
-        on a v5e counts 10.899 GiB at rung `true` and 11.609 at `dots`, the
-        rung `auto` picks, for steps this makes 11.04 and 11.64 (ledger, PR
-        61; my chip runs, PR 62; without the term `true` made 12.23)."""
+        on a v5e counts 9.632 GiB at rung `true` and 10.339 at `dots`, the
+        rung `auto` picks, for steps this makes 9.85 and 10.44 (my chip
+        runs, PR 71: the term PR 62 set at a chunk of all the pairs, 10.899
+        and 11.609 for 11.04 and 11.64, still reads inside the rule's
+        -0.5% / +4.5% at a chunk of a quarter of them, so it stands)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.conv_moe.moe_intermediate_size / self.tp_size

@@ -160,23 +160,26 @@ class EarlyRouterMoETransformer(DecoderStack):
         cotangents the flash backward reads and writes at heads x head_dim
         where the skeleton counts them at d, k and v with their rotated
         copies and cotangents; and one chunk of the expert dispatch
-        (`SharedRoutedFFN.chunk_share` of a token's pairs; ALL of them at a
-        held share of a quarter, top_k rows a token): rows in and out with
-        their cotangents, the outputs and the scatter's operand in float32
-        (twice an element), and the hidden activations `[gate | up]`, their
-        product and both cotangents. The last term takes 16.47 d a token
-        back off and is SET FROM THE CHIP'S READING (a chunk of ALL pairs
-        is walked in pieces the count above holds whole): cell 10 on a v5e
-        counts 13.957 GiB at rung `true` and 14.459 at `flash`, the rung
-        `auto` picks, for steps this makes 14.14 and 14.58 (ledger, PR 61;
-        my chip run, PR 62; without the term `true` made 15.42)."""
+        (`SharedRoutedFFN.chunk_share` of a token's pairs: a quarter of
+        them at a held share of a quarter, 1.5 rows a token at top-6): rows
+        in and out with their cotangents, the outputs and the sums in
+        float32 (twice an element), and the hidden activations `[gate |
+        up]`, their product and both cotangents. The last term adds 9.5 d a
+        token and is SET FROM THE CHIP'S READING (beside one chunk's
+        buffers the walk's backward carries the input's cotangent and the
+        sums whole, which the count above does not hold): cell 10 on a v5e
+        counts 13.366 GiB at rung `true`, 14.002 at `flash` and 14.538 at
+        `dots`, the rung `auto` picks, for steps this makes 13.53, 13.97
+        and 14.54 (my chip runs, PR 71; until then the one chunk was ALL
+        the pairs, the term took 16.47 d back off and the cell stood at
+        `flash` with 14.459)."""
         moe = self._mods["moe"]
         chunk_rows = moe.chunk_share * moe.top_k
         f = self.cfg.early_moe.moe_ffn_hidden_size / self.tp_size
         attn = (5 * self.cfg.num_heads * self.head_dim + 6 * self.kv_dim
                 - 2 * self.d) / self.tp_size
         return (attn + chunk_rows * (6 * self.d + 5 * f)
-                - 16.47 * self.d / self.tp_size)
+                + 9.5 * self.d / self.tp_size)
 
     # ---- sub-module definitions ----
 

@@ -232,88 +232,30 @@ class MoEFFN:
 
 # ---- the sorted dispatch's row movers (SharedRoutedFFN) ----
 #
-# A chunk of the sorted pairs is a partial permutation of the (token,
-# choice) pairs: sorted row r of the chunk holds token `tok[r]`, and pair
-# (s, j) sits in row `idx[s, j]` of it, or `idx[s, j] == M` (one past the
-# chunk: it reads as a zero row) where its expert is absent or its row lies
-# in another chunk. So rows can move by GATHERS in both directions, and
-# each mover is the other's transpose and says so, where autodiff would
-# make the gather's a row scatter-add. The chunk's first `n` rows are the
-# held pairs'; the rows past them are PADDING, which the movers own:
-# `take_rows` makes them zeros, `sum_rows` does not read them, and so no
+# A chunk of the sorted pairs is M rows of them: sorted row r of the chunk
+# holds token `tok[r]`. The chunk's first rows are the held pairs'; the
+# rows past them are PADDING, which the movers own: `take_held` makes them
+# zeros, `sum_held` selects them away before it multiplies, and so no
 # cotangent of a padding row is read either (the grouped products'
-# transposes write whatever they like there).
+# transposes write whatever they like there). Each mover is the other's
+# transpose, and `_walk_chunks_bwd` writes that out, where autodiff would
+# make the gather's a row scatter-add.
 #
 # What a 4 KB row costs on a v5e (bf16 x 2048; `scripts/
 # tune_moe_dispatch.py` alone on the chip, PERF.md section 6, PRs 42, 50
 # and 65): a gathered row 24 ns, an element-wise pass 12.5, and XLA:TPU's
 # row scatter-add, which walks its updates one at a time whatever their
 # indices, 75 - 82 ns a row at 49,152 rows and up, 110 at 16,384, 155 at
-# 8,192. Two constants of PR 42's table still choose between the gathers
-# through `pos` (`take_rows` / `sum_rows`: S k gathered rows a live chunk)
-# and movers that cost by the chunk's M rows (`take_held` / `sum_held`):
-# the gathers where the pairs are under 1.6 times the chunk's rows, which
-# since PR 50 is the one chunk of ALL the pairs and nothing else. The
-# second is no mover's price any more: no row scatter-add is left in the
-# layer (`sum_held` below; PR 65).
-ROW_GATHER_NS = 48
-ROW_SCATTER_NS = 78
-
-
-def _held(tok: jax.Array, n: jax.Array) -> jax.Array:
-    return (jnp.arange(tok.shape[0]) < n)[:, None]
-
-
-@jax.custom_vjp
-def take_rows(x: jax.Array, tok: jax.Array, idx: jax.Array, n: jax.Array
-              ) -> jax.Array:
-    """(S, d) tokens -> the chunk's (M, d) rows: `x[tok]` in the first n,
-    SELECTED zeros past them."""
-    with jax.named_scope("moe_route"), jax.named_scope("take_rows"):
-        return jnp.where(_held(tok, n), jnp.take(x, tok, axis=0), 0)
-
-
-@jax.custom_vjp
-def sum_rows(y: jax.Array, r: jax.Array, tok: jax.Array, idx: jax.Array,
-             n: jax.Array) -> jax.Array:
-    """(S, d) sums and the chunk's (M, d) rows -> `y[s] + sum_j r[idx[s,
-    j]]`: k row gathers a token, summed in float32 and cast once."""
-    with jax.named_scope("moe_route"), jax.named_scope("sum_rows"):
-        (S, k), M = idx.shape, r.shape[0]
-        # The columns of `idx` are walked a gather of at most M - 2 S rows
-        # at a time: the gathered rows and the sum, in and out, then take
-        # what the chunk's rows take, which is what the scatter-add's own
-        # sorted copy of its updates took (the compiler's plan of this
-        # layer alone at S k = 131,072 over M = 98,304: 3,899 MB against
-        # the scatter-add's 3,897, where six columns at once read 4,032
-        # and all eight 4,065). A gather's columns are summed in float32
-        # as adds of (S, d) slabs: ONE element-wise fusion reads the
-        # gathered rows once (as a `reduce` over a float32 copy of them it
-        # ran three passes in the step where it ran one alone, PERF.md
-        # section 6, PR 42). The barrier keeps a gather behind the sum
-        # before it, which it hands on in y's dtype.
-        at_once = max(1, M // S - 2)
-        for a in range(0, k, at_once):
-            cols = idx.T[a:a + at_once]
-            if a:
-                y, cols = lax.optimization_barrier((y, cols))
-            picked = jnp.take(r, cols.reshape(-1), axis=0,
-                              mode="clip").reshape(-1, S, r.shape[1])
-            acc = y.astype(jnp.float32)
-            for j in range(cols.shape[0]):
-                acc = acc + jnp.where((cols[j] < M)[:, None], picked[j], 0)
-            y = acc.astype(y.dtype)
-        return y
-
-
-def _take_rows_bwd(res, g):
-    tok, idx, n = res
-    zeros = jnp.zeros((idx.shape[0], g.shape[1]), g.dtype)
-    return sum_rows(zeros, g, tok, idx, n), None, None, None
+# 8,192. So no row scatter-add is left in the layer (`sum_held` below; PR
+# 65), and both movers cost by the chunk's M rows, whatever the share of
+# the experts a job holds (before PR 71 a job that held a sixth or more
+# moved the rows of ONE chunk of all its pairs by k gathers a token
+# through the sort's inverse permutation, and walked every pair for the
+# third of them it held).
 
 
 def take_held(x: jax.Array, tok: jax.Array, valid: jax.Array) -> jax.Array:
-    """`take_rows` for a chunk of a share, whose rows come back by
+    """(S, d) tokens -> the chunk's (M, d) rows, which come back by
     `sum_held`: plain `x[tok]` with the padding rows SELECTED to zeros."""
     return jnp.where(valid, jnp.take(x, tok, axis=0), 0)
 
@@ -419,14 +361,6 @@ def sum_held(y: jax.Array, r: jax.Array, tok: jax.Array, valid: jax.Array,
         return out.reshape(blocks * B, d)[:S], jnp.sum(windows)
 
 
-take_rows.defvjp(
-    lambda x, tok, idx, n: (take_rows(x, tok, idx, n), (tok, idx, n)),
-    _take_rows_bwd)
-sum_rows.defvjp(
-    lambda y, r, tok, idx, n: (sum_rows(y, r, tok, idx, n), (tok, idx, n)),
-    lambda res, g: (g, take_rows(g, *res), None, None, None))
-
-
 # ---- the sorted dispatch's walk over its chunks (SharedRoutedFFN) ----
 #
 # Chunk c of the sorted pairs is rows [c M, c M + M): its tokens and
@@ -485,7 +419,7 @@ def zeros_like_varying(a: jax.Array) -> jax.Array:
 def walk_chunks(M: int, act, xd: jax.Array, gate_up: jax.Array,
                 down: jax.Array, w_sorted: jax.Array, token: jax.Array,
                 ends: jax.Array, rows_here: jax.Array):
-    """The chunks of a share: rows in by `take_held`, through
+    """The layer's walk over its chunks: rows in by `take_held`, through
     `held_experts`, back by `sum_held`, chunk after chunk UP TO
     THE LAST HELD ROW: a loop of `ceil(rows_here / M)` steps, so a chunk
     past the held rows costs nothing, forward or backward. Returns the
@@ -636,10 +570,9 @@ ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
 # and of its MEMORY. Every mover and pass of a live chunk (the `x[tok]`
 # gather, the selects, the weights' multiply, `act(gate) * up` between the two
 # grouped products, `sum_held`'s sort, gather and sums, and the transposes
-# of them all)
-# walks the chunk's M rows whatever it holds, and the walk stops at the
-# last held row (`walk_chunks`); the grouped products alone follow the held
-# rows inside a chunk (the class docstring). So a layer pays for
+# of them all) walks the chunk's M rows whatever it holds, and the walk
+# stops at the last held row (`walk_chunks`); the grouped products alone
+# follow the held rows inside a chunk (the class docstring). So a layer pays for
 # `M * ceil(rows_here / M)` rows (its `rows_walked` counter), and the
 # chunk is `CHUNK_SHARES` times the job's mean share of the pairs. A finer
 # grain walks less padding (about M / 2 a layer) and makes the step's
@@ -658,15 +591,6 @@ ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
 # and 775.4 / 909.6 / 1129.5: there every chunk, live or skipped, cost 1.6
 # - 2.2 ms a layer, which is why the walk's transpose is written by hand.
 CHUNK_SHARES = 1
-# Where the job holds a sixth of the experts or more (this many mean shares
-# are all the pairs) the chunk is ALL the pairs and runs with no `cond`: the
-# boundary the policy had when a chunk was six shares, kept as a rule on the
-# static share. A `cond` around that one chunk read +25 ms a step and +1.0
-# GiB (PERF.md section 6, PR 39), and the held rows there are a third of
-# the pairs or more, so there is less padding to skip. Its rows move by the
-# gathers (`ROW_GATHER_NS` / `ROW_SCATTER_NS` above: one rule on static
-# shapes, which picks `take_held` / `sum_held` for every chunk of a share).
-WHOLE_FROM_SHARES = 6
 
 
 @dataclass(frozen=True)
@@ -684,8 +608,8 @@ class SharedRoutedFFN:
     `shared["gate_score"]`, d -> 1): Qwen3-Next's expert layer;
     `activation` is the experts' gate activation, held and shared alike
     (`ACTIVATIONS`: "silu", or "relu" for a ReGLU expert), which reaches
-    both movers' paths and `walk_chunks`' hand-written transpose through
-    `held_experts`; `n_group` > 1 limits the selection to groups
+    `walk_chunks` and its hand-written transpose through `held_experts`;
+    `n_group` > 1 limits the selection to groups
     (DeepSeek-V3's `noaux_tc` rule, which Ling-3.0 publishes too: the routed
     experts stand in `n_group` groups of equal size, one a node of the
     deployment; a group's score is the sum of its two largest biased
@@ -736,44 +660,36 @@ class SharedRoutedFFN:
     (token, choice) pairs are sorted by held expert (absent ones last) and
     the held experts' rows go through grouped matrix products
     (`lax.ragged_dot`: XLA:TPU makes it a grouped-matmul kernel whose grid
-    follows the group sizes). The sort is a permutation and the layer
-    keeps both directions of it: `order` (the pair of a sorted row) and
-    its inverse `pos` (the sorted row of a pair, from a prefix sum over a
-    one-hot of the keys; made only where the gathers want it). The sorted
-    pairs are walked in chunks (`chunk_rows`), and a chunk is the unit of
-    the layer's WORK and of its MEMORY (above `CHUNK_SHARES`): where under
-    a sixth of the experts are held it is the job's mean share of the
-    pairs and the walk is a loop that STOPS at the last held row
+    follows the group sizes). The sorted pairs are walked in chunks
+    (`chunk_rows`), and a chunk is the unit of the layer's WORK and of its
+    MEMORY (above `CHUNK_SHARES`): the job's mean share of the pairs
+    whatever share of the experts it holds (all the pairs where it holds
+    them all), and the walk is a loop that STOPS at the last held row
     (`walk_chunks`, forward and its hand-written transpose), so the
     movers, the selects, the weights' multiply, `act(gate) * up` and their
     transposes walk `M * ceil(rows_here / M)` rows (`rows_walked`) and a
-    chunk past the held rows costs nothing; where a sixth or more are held
-    (`WHOLE_FROM_SHARES`) the one chunk is ALL the pairs, a `scan` of one
-    step with no `cond`. Every pair that exists is computed whatever the
-    routing (tests force all tokens onto a few experts).
-    Rows go in by a gather and come back by one of two movers, picked by
-    one rule on static shapes (`ROW_GATHER_NS` / `ROW_SCATTER_NS`,
-    measured). In a chunk of a share by `take_held` (`x[tok]`, zeros
-    SELECTED into the padding rows) and `sum_held`: the chunk's rows put in
-    token order (one sort of its tokens, one row gather), then summed onto
-    the tokens a block of 256 at a time by one-hot products on the matrix
-    unit, in float32, cast once (on a TPU one Mosaic kernel; `sum_held`'s
-    docstring, and the layer's `sum_windows` / `sum_blocks` counters: the
-    windows of sorted rows the blocks took, one each near balance). Both
-    cost by the chunk's M rows, where gathers through `pos` cost by all
-    S k pairs a live chunk, and no XLA scatter is left in the layer: the
-    row scatter-add `y.at[tok].add` this mover replaced cost 110 - 155 ns a
-    row where a gathered row costs 24 (PR 65). In the one chunk of all the
-    pairs by `take_rows` and `sum_rows`: k row gathers a token through
-    `pos`, summed in float32, each mover the other's transpose by
-    `jax.custom_vjp`, so the backward moves rows the same way and reads no
-    padding row's cotangent. **The products follow the rows**: each held
-    expert's group ends at its own last row, the rows of a live chunk past
-    its last held pair belong to NO group, and XLA:TPU's grouped kernel
-    walks the groups it is given, so the products' time is the held rows'
-    (PERF.md section 6, PR 47; before PR 50 a chunk was six mean shares,
-    98,304 rows for some 20,000 held at a share of an eighth, and
-    everything but the products walked them all: PR 50). What that makes
+    chunk past the held rows costs nothing. Every pair that exists is
+    computed whatever the routing (tests force all tokens onto a few
+    experts).
+    Rows go in by `take_held` (`x[tok]`, zeros SELECTED into the padding
+    rows) and come back by `sum_held`: the chunk's rows put in token order
+    (one sort of its tokens, one row gather), then summed onto the tokens a
+    block of 256 at a time by one-hot products on the matrix unit, in
+    float32, cast once (on a TPU one Mosaic kernel; `sum_held`'s docstring,
+    and the layer's `sum_windows` / `sum_blocks` counters: the windows of
+    sorted rows the blocks took, one each near balance, k where the one
+    chunk is all the pairs of a job that holds every expert). Both cost by
+    the chunk's M rows, and no XLA scatter is left in the layer: the row
+    scatter-add `y.at[tok].add` this mover replaced cost 110 - 155 ns a
+    row where a gathered row costs 24 (PR 65). **The products follow the
+    rows**: each held expert's group ends at its own last row, the rows of
+    a live chunk past its last held pair belong to NO group, and XLA:TPU's
+    grouped kernel walks the groups it is given, so the products' time is
+    the held rows' (PERF.md section 6, PR 47; before PR 50 a chunk was six
+    mean shares, 98,304 rows for some 20,000 held at a share of an eighth,
+    and everything but the products walked them all: PR 50; a job that
+    held a sixth or more kept ONE chunk of all its pairs, moved by gathers
+    through the sort's inverse, until PR 71). What that makes
     load-bearing:
     a row no group holds comes back from a product AND from its transposes
     as whatever the buffer held, so every such row is selected, never
@@ -959,45 +875,30 @@ class SharedRoutedFFN:
         hit = jnp.any(group[..., None] == jnp.arange(self.n_group), axis=1)
         return jnp.sum(hit.astype(jnp.float32), axis=0)
 
-    def index(self, chosen: jax.Array, w: jax.Array, inverse: bool):
+    def index(self, chosen: jax.Array, w: jax.Array):
         """The sorted dispatch's index work over the (S, k) pairs, with no
         scalar gather or scatter (above `sort_pairs`): `order`, the pair of
         a sorted row, pairs sorted by held expert and absent experts last;
         `w_sorted`, the weights in that order; `ends` (held,), the sorted
-        row each held expert's pairs end at; `pos` (S, k), the sorted row
-        of a pair (`order`'s inverse; None unless `inverse`); `routed`
-        (num_experts,) int32, the pairs each routed expert was chosen
-        for."""
-        S, k = chosen.shape
+        row each held expert's pairs end at; `routed` (num_experts,) int32,
+        the pairs each routed expert was chosen for."""
         H = self.num_held
         local = chosen - self.offset
         here = (local >= 0) & (local < H)
         key = jnp.where(here, local, H).reshape(-1)             # (S*k,)
         order, w_sorted = sort_pairs(key, w.reshape(-1))
         with jax.named_scope("index"):
-            pos = None
-            if inverse:
-                # its expert's first row plus the earlier pairs of it: a
-                # prefix sum over the one-hot whose column sums `ends` are
-                hot = jax.nn.one_hot(key, H + 1, dtype=jnp.int32)
-                ends = jnp.cumsum(jnp.sum(hot, axis=0)[:H])
-                first = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends])
-                pos = jnp.sum((jnp.cumsum(hot, axis=0) - hot + first) * hot,
-                              axis=1).reshape(S, k)
-            else:
-                ends = jnp.cumsum(count_keys(key, H + 1)[:H])
+            ends = jnp.cumsum(count_keys(key, H + 1)[:H])
             routed = count_keys(chosen.reshape(-1), self.num_experts)
-        return order, w_sorted, ends, pos, routed
+        return order, w_sorted, ends, routed
 
     @property
     def chunk_share(self) -> float:
         """The part of the (token, choice) pairs one chunk holds:
-        `CHUNK_SHARES` times this job's mean share of them, and all of
-        them where `WHOLE_FROM_SHARES` shares are (the family sizes the
-        dispatch's buffers from it for `training/memory.py`)."""
-        if WHOLE_FROM_SHARES * self.num_held >= self.num_experts:
-            return 1.0
-        return CHUNK_SHARES * self.num_held / self.num_experts
+        `CHUNK_SHARES` times this job's mean share of them, all of them
+        where it holds every expert (the family sizes the dispatch's
+        buffers from it for `training/memory.py`)."""
+        return min(1.0, CHUNK_SHARES * self.num_held / self.num_experts)
 
     def chunk_rows(self, pairs: int) -> int:
         """Rows a chunk of `pairs` sorted pairs holds: `chunk_share` of
@@ -1021,13 +922,11 @@ class SharedRoutedFFN:
         the rows of the groups the grouped products were handed, over the
         chunks (the held rows: the counter says so of the program that
         ran), `rows_walked` the rows of the chunks whose body ran (what
-        the movers and the passes paid for: `M * ceil(rows_here / M)`,
-        and M where the one chunk is all the pairs), `sum_blocks` the
-        token blocks `sum_held` walked over the live chunks of a share and
-        `sum_windows` the windows of sorted rows they took (one a block
-        unless a block owns more rows than a window holds; both zero where
-        the one chunk is all the pairs), all float32 and local to this
-        shard."""
+        the movers and the passes paid for: `M * ceil(rows_here / M)`),
+        `sum_blocks` the token blocks `sum_held` walked over the live
+        chunks and `sum_windows` the windows of sorted rows they took (one
+        a block unless a block owns more rows than a window holds), all
+        float32 and local to this shard."""
         b, t, d = x.shape
         S, k = b * t, self.top_k
         xf = x.reshape(S, d)
@@ -1038,18 +937,13 @@ class SharedRoutedFFN:
                 xl = xd @ params["latent"]["down"].astype(compute_dtype)
 
         M = self.chunk_rows(S * k)
-        # rows move by gathers through `pos` both ways (S k a live chunk) or
-        # by `take_held` / `sum_held` (the chunk's M): two costs decide, on
-        # static shapes, a layer at a time
-        gathers = S * k * ROW_GATHER_NS <= M * ROW_SCATTER_NS
         act = ACTIVATIONS[self.activation]
         early = (contextlib.nullcontext() if router_x is None
                  else jax.named_scope("early"))
         with jax.named_scope("moe_route"), early:
             chosen, w = self.route(
                 params, xf if router_x is None else router_x.reshape(S, d))
-            order, w_sorted, ends, pos, routed = self.index(
-                chosen, w, inverse=gathers)
+            order, w_sorted, ends, routed = self.index(chosen, w)
             rows_here = ends[-1]
             token = order // k
             counters = {"routed": routed.astype(jnp.float32),
@@ -1066,49 +960,14 @@ class SharedRoutedFFN:
         gate_up = (jnp.concatenate([params["gate"], params["up"]], axis=-1)
                    if self.gated else params["up"]).astype(compute_dtype)
         down = params["down"].astype(compute_dtype)
-        if gathers:
-            def chunk(y, c):
-                lo, sizes, tok, wc, valid = chunk_of(c, M, token, w_sorted,
-                                                     ends, rows_here)
-
-                def live(y):
-                    with jax.named_scope("moe_route"):
-                        # a held pair whose row is in this chunk; every
-                        # other reads the zero row
-                        n, at = rows_here - lo, pos - lo
-                        idx = jnp.where(
-                            (at >= 0) & (at < jnp.minimum(n, M)), at, M)
-                        rows = take_rows(xl, tok, idx, n)
-                    out = held_experts(rows, gate_up, down, wc, sizes, valid,
-                                       act)
-                    with jax.named_scope("moe_route"):
-                        return sum_rows(y, out.astype(y.dtype), tok, idx, n)
-
-                if chunks == 1:
-                    # the one chunk is ALL the pairs (`WHOLE_FROM_SHARES`: a
-                    # held share of a sixth or more): none to skip to, and a
-                    # layer whose held experts got nothing this step runs
-                    # its products over zero groups (a `cond` around the one
-                    # chunk cost 25 ms a step and 1.0 GiB, PERF.md section
-                    # 6, PR 39)
-                    return live(y), (jnp.sum(sizes), jnp.int32(M))
-                return (lax.cond(lo < rows_here, live, lambda y: y, y),
-                        (jnp.sum(sizes), jnp.where(lo < rows_here, M, 0)))
-
-            y, (computed, walked) = lax.scan(
-                jax.checkpoint(chunk), zeros_like_varying(xl),
-                jnp.arange(chunks, dtype=jnp.int32))
-            computed, walked = jnp.sum(computed), jnp.sum(walked)
-            windows = blocks = rows_here * 0     # `sum_held`'s: none here
-        else:
-            # one set of mesh axes for the walk's float operands: the
-            # rows' (batch axes and tp)
-            vma = tuple(jax.typeof(xl).vma)
-            vary = lambda a: copy_to(a, vma) if vma else a
-            y, computed, walked, windows = walk_chunks(
-                M, act, xl, vary(gate_up), vary(down), vary(w_sorted), token,
-                ends, rows_here)
-            blocks = walked // M * -(-S // min(SUM_BLOCK, S))
+        # one set of mesh axes for the walk's float operands: the rows'
+        # (batch axes and tp)
+        vma = tuple(jax.typeof(xl).vma)
+        vary = lambda a: copy_to(a, vma) if vma else a
+        y, computed, walked, windows = walk_chunks(
+            M, act, xl, vary(gate_up), vary(down), vary(w_sorted), token,
+            ends, rows_here)
+        blocks = walked // M * -(-S // min(SUM_BLOCK, S))
         counters["rows_computed"] = computed.astype(jnp.float32)
         counters["rows_walked"] = walked.astype(jnp.float32)
         counters["sum_windows"] = windows.astype(jnp.float32)

@@ -210,11 +210,11 @@ def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:
     rows the grouped products' groups covered (the same) and the rows the
     dispatch's movers and passes walked (whole chunks: over `rows_here` it
     is the padding they still pay), the windows of token-sorted rows
-    `sum_held` took a block of tokens of a live chunk of a share
+    `sum_held` took a block of tokens of a live chunk
     (`sum_windows_per_block`: 1.0 says every block's rows fitted one
-    window, more is what a skewed router costs the mover, 0.0 that no
-    chunk of a share ran: the one chunk of all pairs moves by gathers),
-    and the held
+    window, more is what a skewed router costs the mover, or a job that
+    holds every expert, whose one chunk is top_k rows a token; 0.0 that
+    no chunk was live), and the held
     experts' load as max over mean, averaged over the expert layers (1.0 is
     balance); and, where the step ran the selection bias's rule, the mean
     size of a bias entry's step (`router_bias_step`); the mixers'

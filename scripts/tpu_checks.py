@@ -214,129 +214,147 @@ def grouped_rows_check(interp: bool, dtype, tol: float):
     """A grouped product and its two transposes read their groups' rows and
     no other: NaN in every row past the groups, of the left side and of
     the cotangent, reaches neither the groups' rows nor the weights'
-    gradient (what `SharedRoutedFFN` leaves between its two products)."""
-    M, d, f, H = (512, 32, 16, 4) if interp else (16384, 2048, 1536, 16)
-    sizes = jnp.full((H,), M // (5 * H), jnp.int32)
-    inside = (jnp.arange(M) < jnp.sum(sizes))[:, None]
-    keys = jax.random.split(jax.random.key(21), 3)
-    lhs = jax.random.normal(keys[0], (M, d), dtype)
-    rhs = jax.random.normal(keys[1], (H, d, f), dtype) / math.sqrt(d)
-    g = jax.random.normal(keys[2], (M, f), dtype)
+    gradient (what `SharedRoutedFFN` leaves between its two products): at
+    the chunk of an eighth held (cells 8 and 9's 16,384 rows), of a
+    quarter (cell 10's 24,576 rows of 2560) and at one chunk of all the
+    pairs of a job that holds every expert."""
+    shapes = [(512, 32, 16, 4), (768, 32, 16, 4)] if interp else [
+        (16384, 2048, 1536, 16), (24576, 2560, 1536, 16),
+        (32768, 2048, 1536, 8)]
+    for M, d, f, H in shapes:
+        sizes = jnp.full((H,), M // (5 * H), jnp.int32)
+        inside = (jnp.arange(M) < jnp.sum(sizes))[:, None]
+        keys = jax.random.split(jax.random.key(21), 3)
+        lhs = jax.random.normal(keys[0], (M, d), dtype)
+        rhs = jax.random.normal(keys[1], (H, d, f), dtype) / math.sqrt(d)
+        g = jax.random.normal(keys[2], (M, f), dtype)
 
-    @jax.jit
-    def run(lhs, g):
-        out, pull = jax.vjp(
-            lambda l, r: jax.lax.ragged_dot(l, r, sizes), lhs, rhs)
-        d_lhs, d_rhs = pull(g)
-        return jnp.where(inside, out, 0), jnp.where(inside, d_lhs, 0), d_rhs
+        @jax.jit
+        def run(lhs, g):
+            out, pull = jax.vjp(
+                lambda l, r: jax.lax.ragged_dot(l, r, sizes), lhs, rhs)
+            d_lhs, d_rhs = pull(g)
+            return (jnp.where(inside, out, 0), jnp.where(inside, d_lhs, 0),
+                    d_rhs)
 
-    want = run(jnp.where(inside, lhs, 0), jnp.where(inside, g, 0))
-    fill_free_memory_with_nan()
-    t0 = time.time()
-    got = jax.block_until_ready(run(jnp.where(inside, lhs, jnp.nan),
-                                    jnp.where(inside, g, jnp.nan)))
-    for name, a, b in zip(("rows", "d_lhs", "d_rhs"), want, got):
-        record(f"grouped product, NaN past its groups: {name}",
-               max_err(b, a), tol * float(jnp.max(jnp.abs(a))),
-               time.time() - t0)
+        want = run(jnp.where(inside, lhs, 0), jnp.where(inside, g, 0))
+        fill_free_memory_with_nan()
+        t0 = time.time()
+        got = jax.block_until_ready(run(jnp.where(inside, lhs, jnp.nan),
+                                        jnp.where(inside, g, jnp.nan)))
+        for name, a, b in zip(("rows", "d_lhs", "d_rhs"), want, got):
+            record(f"grouped product [{M} rows of {d}], NaN past its "
+                   f"groups: {name}", max_err(b, a),
+                   tol * float(jnp.max(jnp.abs(a))), time.time() - t0)
 
 
 def sum_held_check(interp: bool, dtype, tol: float):
     """`parallel/moe.sum_held` (on a TPU the Mosaic kernel `moe_sum_held`
     behind XLA's sort and row gather; here under the interpreter) at cells
-    8 and 9's chunk, 16,384 rows of 2048 onto 16,384 tokens, against the
+    8 and 9's chunk, 16,384 rows of 2048 onto 16,384 tokens, at cell 10's
+    (a quarter held: 24,576 rows of 2560) and at the one chunk of all the
+    pairs of a job that holds every expert (top-4: four rows a token, so
+    a block of tokens owns two windows and more), against the
     row scatter-add it replaced run in float32, with NaN in every row past
     the held ones (what a grouped product's transpose may leave there:
     the mover multiplies what it reads, so its select must come first):
-    a random routing, and every row on the first 700 tokens, whose three
+    a random routing, and every row on a twenty-third of the tokens, whose
     blocks own twenty times what a window holds."""
-    S, M, d = (512, 1024, 128) if interp else (16384, 16384, 2048)
-    keys = jax.random.split(jax.random.key(31), 3)
-    y = jax.random.normal(keys[0], (S, d), dtype)
-    valid = (jnp.arange(M) < M - M // 5)[:, None]
-    r = jnp.where(valid, jax.random.normal(keys[1], (M, d), dtype), jnp.nan)
-    for name, spread in (("random tokens", S), ("a few tokens", S // 23)):
-        tok = jax.random.randint(keys[2], (M,), 0, spread)
-        at = jnp.where(valid[:, 0], tok, S)
-        want = y.astype(jnp.float32).at[at].add(
-            jnp.where(valid, r, 0).astype(jnp.float32), mode="drop")
-        fill_free_memory_with_nan()
-        t0 = time.time()
-        got, took = jax.block_until_ready(jax.jit(
-            lambda y, r, tok: moe_mod.sum_held(
-                y, r, tok, valid, interpret=interp))(y, r, tok))
-        blocks = S // moe_mod.SUM_BLOCK
-        record(f"sum_held, NaN past the held rows, {name} "
-               f"({int(took)} windows over {blocks} blocks)",
-               max_err(got, want), tol * float(jnp.max(jnp.abs(want))),
-               time.time() - t0)
+    shapes = [(512, 1024, 128), (512, 2048, 128)] if interp else [
+        (16384, 16384, 2048), (16384, 24576, 2560), (8192, 32768, 2048)]
+    for S, M, d in shapes:
+        keys = jax.random.split(jax.random.key(31), 3)
+        y = jax.random.normal(keys[0], (S, d), dtype)
+        valid = (jnp.arange(M) < M - M // 5)[:, None]
+        r = jnp.where(valid, jax.random.normal(keys[1], (M, d), dtype),
+                      jnp.nan)
+        for name, spread in (("random tokens", S), ("a few tokens", S // 23)):
+            tok = jax.random.randint(keys[2], (M,), 0, spread)
+            at = jnp.where(valid[:, 0], tok, S)
+            want = y.astype(jnp.float32).at[at].add(
+                jnp.where(valid, r, 0).astype(jnp.float32), mode="drop")
+            fill_free_memory_with_nan()
+            t0 = time.time()
+            got, took = jax.block_until_ready(jax.jit(
+                lambda y, r, tok: moe_mod.sum_held(
+                    y, r, tok, valid, interpret=interp))(y, r, tok))
+            blocks = S // moe_mod.SUM_BLOCK
+            record(f"sum_held [{M} rows of {d} onto {S} tokens], NaN past "
+                   f"the held rows, {name} ({int(took)} windows over "
+                   f"{blocks} blocks)",
+                   max_err(got, want), tol * float(jnp.max(jnp.abs(want))),
+                   time.time() - t0)
 
 
 def expert_layer_checks(interp: bool, dtype, tol: float):
-    """The layer at cells 8 and 9's shape (16,384 tokens of 2048, top-8 of
-    128 experts, 16 held: eight chunks of 16,384 sorted rows, of which the
-    first one or two are live and the last live one ends in rows no
-    group holds) against `one_expert_at_a_time`: the output, and the
-    gradient of every leaf and of the input. Then with the movers' selects
-    taken out, which lets the rows no group holds into the input's
-    gradient: that one must DIFFER, or this check would not see what the
-    selects keep out."""
+    """The layer against `one_expert_at_a_time`, the output and the
+    gradient of every leaf and of the input, at three held shares: cells 8
+    and 9's shape (16,384 tokens of 2048, top-8 of 128 experts, 16 held:
+    eight chunks of 16,384 sorted rows, of which the first one or two are
+    live and the last live one ends in rows no group holds), cell 10's (a
+    quarter held: 16,384 tokens of 2560, top-6 of 64, 16 held, four chunks
+    of 24,576 rows) and a job that holds EVERY expert (top-2 of 8: one
+    chunk of all 16,384 pairs, two rows a token). Then, at the first, with
+    the movers' selects taken out, which lets the rows no group holds into
+    the input's gradient: that one must DIFFER, or this check would not
+    see what the selects keep out."""
     from jax.sharding import PartitionSpec as P
     from distributed_pytorch_from_scratch_tpu.config import MeshConfig
     from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 
-    b, t, d, f, E, H, k = (2, 256, 32, 16, 16, 2, 2) if interp else \
-        (2, 8192, 2048, 768, 128, 16, 8)
-    moe = moe_mod.SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
-    params = moe.init(jax.random.key(11))
-    x = jax.random.normal(jax.random.key(12), (b, t, d), jnp.float32)
+    shapes = [(2, 256, 32, 16, 16, 2, 2), (2, 256, 32, 16, 8, 2, 2),
+              (2, 256, 32, 16, 4, 4, 2)] if interp else [
+        (2, 8192, 2048, 768, 128, 16, 8), (1, 16384, 2560, 768, 64, 16, 6),
+        (1, 8192, 2048, 768, 8, 8, 2)]
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    held = int(jnp.sum(moe.route(params, x.reshape(-1, d))[0] < H))
-    shape = f"{held} held rows in chunks of {moe.chunk_rows(b * t * k)}"
+    for nth, (b, t, d, f, E, H, k) in enumerate(shapes):
+        moe = moe_mod.SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
+        params = moe.init(jax.random.key(11))
+        x = jax.random.normal(jax.random.key(12), (b, t, d), jnp.float32)
+        held = int(jnp.sum(moe.route(params, x.reshape(-1, d))[0] < H))
+        shape = (f"{H} of {E} held: {held} held rows in chunks of "
+                 f"{moe.chunk_rows(b * t * k)}")
 
-    def layer(params, x):
-        return jax.shard_map(
-            lambda p, x: moe.apply(p, x, dtype)[0], mesh=mesh,
-            in_specs=(moe.specs(), P()), out_specs=P())(params, x)
+        def layer(params, x):
+            return jax.shard_map(
+                lambda p, x: moe.apply(p, x, dtype)[0], mesh=mesh,
+                in_specs=(moe.specs(), P()), out_specs=P())(params, x)
 
-    def value_and_grads(fn, params, x):
-        sq = lambda p, x: jnp.sum(fn(p, x).astype(jnp.float32) ** 2)
-        d_params, d_x = jax.grad(sq, (0, 1))(params, x)
-        return {"y": fn(params, x), "d_x": d_x,
-                **{f"d_{name}": g for name, g in d_params.items()}}
+        def value_and_grads(fn, params, x):
+            sq = lambda p, x: jnp.sum(fn(p, x).astype(jnp.float32) ** 2)
+            d_params, d_x = jax.grad(sq, (0, 1))(params, x)
+            return {"y": fn(params, x), "d_x": d_x,
+                    **{f"d_{name}": g for name, g in d_params.items()}}
 
-    def run(fn):
-        fill_free_memory_with_nan()
-        t0 = time.time()
-        # traced anew a run: the last one runs `layer` over other movers
-        got = jax.block_until_ready(jax.jit(
-            lambda p, x: value_and_grads(fn, p, x))(params, x))
-        return got, time.time() - t0
+        def run(fn):
+            fill_free_memory_with_nan()
+            t0 = time.time()
+            # traced anew a run: the last one runs `layer` over other movers
+            got = jax.block_until_ready(jax.jit(
+                lambda p, x: value_and_grads(fn, p, x))(params, x))
+            return got, time.time() - t0
 
-    want, _ = run(lambda p, x: one_expert_at_a_time(moe, p, x, dtype))
-    got, secs = run(layer)
-    for name, a in want.items():
-        record(f"expert layer [{shape}] {name}", max_err(got[name], a),
-               tol * float(jnp.max(jnp.abs(a))), secs)
-    if interp:      # the CPU lowering zero-fills: nothing there to see
-        return
-    # whichever movers the layer's shape rule picks (chunks of a share:
-    # `take_held` / `sum_held`)
-    movers = ("take_rows", "sum_rows", "take_held", "sum_held")
-    selected = [getattr(moe_mod, name) for name in movers]
-    moe_mod.take_rows = lambda x, tok, idx, n: jnp.take(x, tok, axis=0)
-    moe_mod.sum_rows = lambda y, r, tok, idx, n: y.at[tok].add(r)
-    moe_mod.take_held = lambda x, tok, valid: jnp.take(x, tok, axis=0)
-    moe_mod.sum_held = lambda y, r, tok, valid: (y.at[tok].add(r),
-                                                 jnp.int32(0))
-    try:
+        want, _ = run(lambda p, x: one_expert_at_a_time(moe, p, x, dtype))
         got, secs = run(layer)
-    finally:
-        for name, mover in zip(movers, selected):
-            setattr(moe_mod, name, mover)
-    record("expert layer, the movers' selects out: d_x MUST differ",
-           max_err(got["d_x"], want["d_x"]),
-           tol * float(jnp.max(jnp.abs(want["d_x"]))), secs, must_differ=True)
+        for name, a in want.items():
+            record(f"expert layer [{shape}] {name}", max_err(got[name], a),
+                   tol * float(jnp.max(jnp.abs(a))), secs)
+        if interp or nth:    # the CPU lowering zero-fills: nothing to see
+            continue
+        movers = ("take_held", "sum_held")
+        selected = [getattr(moe_mod, name) for name in movers]
+        moe_mod.take_held = lambda x, tok, valid: jnp.take(x, tok, axis=0)
+        moe_mod.sum_held = lambda y, r, tok, valid: (y.at[tok].add(r),
+                                                     jnp.int32(0))
+        try:
+            got, secs = run(layer)
+        finally:
+            for name, mover in zip(movers, selected):
+                setattr(moe_mod, name, mover)
+        record("expert layer, the movers' selects out: d_x MUST differ",
+               max_err(got["d_x"], want["d_x"]),
+               tol * float(jnp.max(jnp.abs(want["d_x"]))), secs,
+               must_differ=True)
 
 
 # (heads, groups, chunk) of the two benchmark cells that run the state-space

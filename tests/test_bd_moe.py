@@ -575,9 +575,9 @@ def test_the_memory_facts_count_the_rows_the_chunk_and_half_the_logits():
 
 LOWERED_BEFORE = {"llama": ("tiny", "14bb75356a403459"),
                   "gpt2": ("tiny", "557e9d12313622a3"),
-                  "mla_moe": ("tiny-mla-moe", "f3e98f1b454c0b7d"),
-                  "gdn_moe": ("tiny-gdn-moe", "3b4f653c0360b911"),
-                  "conv_moe": ("tiny-conv-moe", "5dd8ca94f97f14ba")}
+                  "mla_moe": ("tiny-mla-moe", "83b0575bcf151845"),
+                  "gdn_moe": ("tiny-gdn-moe", "6d83ef8f30d65710"),
+                  "conv_moe": ("tiny-conv-moe", "64b65f649e0393d9")}
 
 
 @pytest.mark.parametrize("family", sorted(LOWERED_BEFORE))

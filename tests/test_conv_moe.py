@@ -492,20 +492,21 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
 
 
 def test_the_whole_chunk_is_what_the_memory_facts_count():
-    """At a held share of 1/4 the dispatch's one chunk is ALL pairs
-    (`chunk_share` 1): `layer_extra_elems_per_token` sizes its buffers by
-    top_k rows a token, at 1/16 by one mean share, 1/16, of them."""
+    """A chunk of the dispatch is one mean share of the pairs at every held
+    share (`chunk_share` 1/4 at a quarter held; 1 until PR 71):
+    `layer_extra_elems_per_token` sizes its buffers by that share of top_k
+    rows a token, at 1/16 by 1/16 of them."""
     quarter = build_model("conv_moe", tiny(experts_held=2))
     sixteenth = build_model("conv_moe", dataclasses.replace(
         tiny(experts_held=2), num_experts=32))
-    assert quarter._mods["moe"].chunk_share == 1.0
+    assert quarter._mods["moe"].chunk_share == 2 / 8
     assert sixteenth._mods["moe"].chunk_share == 2 / 32
-    assert quarter._mods["moe"].chunk_rows(4096) == 4096
+    assert quarter._mods["moe"].chunk_rows(4096) == 1024
     # (the second term: what the chip counts beside these, set from cell 7)
     conv = 12.0 * 64 - 18.96 * 64
     rows = lambda m: (m.layer_extra_elems_per_token - conv) / (
         2 * 64 + 5 * 32)
-    assert rows(quarter) == pytest.approx(2.0)
+    assert rows(quarter) == pytest.approx(2 * 2 / 8)
     assert rows(sixteenth) == pytest.approx(2 * 2 / 32)
     assert quarter.ffn_inputs == 2 and quarter.tied_head
     assert quarter.stacked_layers == 12
@@ -568,14 +569,15 @@ PARENTS_THREE_STEPS = [("0x1.8f1b4c0000000p+2", "0x1.c6cb860000000p+1"),
 
 def test_the_quarter_share_cells_steps_are_the_parents_whatever_the_grain(
         monkeypatch):
-    """Cell 7 holds a quarter of its experts: its one chunk is ALL the
-    pairs with no `cond`, the text the layer had before chunks were a
-    share or less, so three steps at its rehearsal shape (float32) read
-    the losses and gradient norms the parent's tree read (side by side on
-    one machine they were equal to the bit; held here to a float32's last
-    digits, since another CPU may order a product's sums otherwise), and
-    read the SAME BITS under the grain the policy had (six shares) and
-    under a quarter of a share: no grain reaches this shape."""
+    """Cell 7 holds a quarter of its experts. Until PR 71 its one chunk was
+    ALL the pairs, moved by gathers; since then it walks chunks of a
+    quarter of them by `take_held` / `sum_held`, the same sums in another
+    order. At its rehearsal shape (float32; 256 pairs a layer, under the
+    grouped kernel's tile, so ONE chunk under any grain) three steps still
+    read the losses and gradient norms PR 49's tree read, to a float32's
+    last digits, and read the SAME BITS under the grain the policy had
+    (six shares) and under a quarter of a share: no grain reaches this
+    shape."""
     from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 
     cfg = ModelConfig(
@@ -588,7 +590,7 @@ def test_the_quarter_share_cells_steps_are_the_parents_whatever_the_grain(
     def three_steps():
         mesh, model = on_mesh(cfg, 1)
         moe = model._mods["moe"]
-        assert moe.chunk_share == 1.0 and moe.chunk_rows(256) == 256
+        assert moe.chunk_rows(256) == 256       # under any grain
         params = model.init(jax.random.key(0))
         opt = init_adam_state(params)
         step = build_train_step(model, mesh, OptimizerConfig(),
@@ -613,31 +615,36 @@ def test_the_quarter_share_cells_steps_are_the_parents_whatever_the_grain(
 
 
 def test_a_chunk_of_all_the_pairs_is_computed_whatever_is_routed():
-    """Where the one chunk is ALL the pairs there is nothing to skip to:
-    the products run with no `cond` around them, also in a step that routes
-    nothing to the experts held (since PR 47 over ZERO groups: no row is
-    computed, the output zero, every gradient a finite zero); several
-    chunks are a loop that stops at the last held row (PR 50: until then a
-    `scan` over all of them with a `cond` that skipped those past it)."""
+    """Every held share walks chunks of one mean share under a loop that
+    stops at the last held row (PR 50 under a sixth held, PR 71 a quarter
+    and more: until then the one chunk of a quarter share was ALL the
+    pairs, computed with no `cond` around it whatever was routed); the one
+    chunk of a job that holds EVERY expert is still all the pairs, always
+    live. A step that routes nothing to the experts held walks NO chunk: no
+    row is computed, the output zero, every gradient a finite zero."""
     d, f, E, k = 32, 16, 32, 4
     x = jax.random.normal(jax.random.key(1), (2, 512, d))
     quarter = SharedRoutedFFN(d, f, E, k, held=8, n_shared=0)
     sixteenth = SharedRoutedFFN(d, f, E, k, held=2, n_shared=0)
-    assert quarter.chunk_rows(4096) == 4096
+    whole = SharedRoutedFFN(d, f, E, k, n_shared=0)
+    assert quarter.chunk_rows(4096) == 1024
     assert sixteenth.chunk_rows(4096) == 512
+    assert whole.chunk_rows(4096) == 4096
     count = lambda moe, op: str(jax.make_jaxpr(lambda p, x: apply_moe(
         moe, p, x))(moe.init(jax.random.key(0)), x)).count(f" {op}[")
-    assert count(quarter, "cond") == 0 == count(quarter, "while")
     # the walk over the live chunks, and inside it `sum_held`'s loop over a
     # token block's windows past its first (PR 65)
-    assert count(sixteenth, "cond") == 0 and count(sixteenth, "while") == 2
+    for moe in (quarter, sixteenth, whole):
+        assert count(moe, "cond") == 0 and count(moe, "while") == 2
+    _, c = apply_moe(whole, whole.init(jax.random.key(0)), x)
+    assert float(c["rows_here"]) == float(c["rows_walked"]) == 4096
     p = quarter.init(jax.random.key(0))
     # the selection bias sends every token to experts 8..11: none held
     p["bias"] = jnp.where((jnp.arange(E) >= 8) & (jnp.arange(E) < 12),
                           100.0, 0.0)
     out, c = apply_moe(quarter, p, x)
     assert float(c["rows_here"]) == float(c["rows_computed"]) == 0
-    assert float(c["rows_walked"]) == 4096      # no `cond`: the chunk ran
+    assert float(c["rows_walked"]) == 0         # the walk stopped at once
     assert not np.any(out)
     grads = jax.grad(lambda p: jnp.sum(apply_moe(quarter, p, x)[0] ** 2))(p)
     assert all(np.all(np.isfinite(g)) and not np.any(g)

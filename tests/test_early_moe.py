@@ -42,7 +42,6 @@ from distributed_pytorch_from_scratch_tpu.models.stack import REMAT_RUNGS
 from distributed_pytorch_from_scratch_tpu.models.vanilla_early_moe import (
     reference_loss_routed, sizes_of, vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import sliding_window
-from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
 from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
@@ -265,10 +264,11 @@ def test_another_routers_input_or_activation_is_another_model(variant,
 
 def test_the_activation_is_the_familys_fact_through_both_movers():
     """ReLU against SiLU in `SharedRoutedFFN` itself, the layer's output and
-    the gradient of every leaf, at a held share of a half (the one chunk of
-    all pairs, `take_rows` / `sum_rows`) and of an eighth (`walk_chunks`,
-    whose transpose is written by hand): each against the held experts
-    applied one by one with the activation in the open."""
+    the gradient of every leaf, at a held share of a half and of an eighth
+    (`walk_chunks` at both since PR 71, whose transpose is written by hand
+    and takes the activation as its second static argument; `take_held` in,
+    `sum_held` back): each against the held experts applied one by one with
+    the activation in the open."""
     d, f, E, k = 32, 16, 8, 2
     x = jax.random.normal(jax.random.key(1), (2, 64, d))
     for held, name, act in ((4, "relu", lambda z: jnp.maximum(z, 0)),
@@ -276,7 +276,7 @@ def test_the_activation_is_the_familys_fact_through_both_movers():
                             (1, "silu", jax.nn.silu)):
         layer = SharedRoutedFFN(d, f, E, k, held=held, offset=1, n_shared=0,
                                 score="softmax", activation=name)
-        assert (layer.chunk_share == 1.0) == (held == 4)
+        assert layer.chunk_share == held / 8
         p = layer.init(jax.random.key(0))
 
         def plain(p, x):
@@ -354,7 +354,7 @@ def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
         for share in range(4):
             lo = 2 * share
             held = SharedRoutedFFN(d, f, E, k, held=2, offset=lo, **kw)
-            assert held.chunk_share == 1.0
+            assert held.chunk_share == 0.25
             ps = {**p, **{n: p[n][lo:lo + 2] for n in ("gate", "up", "down")}}
             out, c = apply_moe(held, ps, x, router_x)
             np.testing.assert_array_equal(c["routed"], counted["routed"])
@@ -489,11 +489,12 @@ def test_train_cli_runs_the_family(tmp_path, capsys):
 def test_the_memory_facts_count_the_chunk_at_the_held_share():
     quarter = build_model("early_moe", tiny(experts_held=2))
     moe = quarter._mods["moe"]
-    assert moe.chunk_share == 1.0           # a sixth or more: all the pairs
+    assert moe.chunk_share == 0.25          # one mean share of the pairs
     attn = 5 * 6 * 32 + 6 * 2 * 32 - 2 * 64
-    # (the last term: what the chip counts beside these, set from cell 10)
-    assert quarter.layer_extra_elems_per_token == attn + 2 * (
-        6 * 64 + 5 * 32) - 16.47 * 64
+    # (the last term: what the chip counts beside these, set from cell 10
+    # at a chunk of a quarter of the pairs: PR 71)
+    assert quarter.layer_extra_elems_per_token == attn + 0.25 * 2 * (
+        6 * 64 + 5 * 32) + 9.5 * 64
     assert (quarter.head_dim, quarter.kv_dim) == (32, 64)
     eighth = build_model("early_moe", tiny(experts_held=1))
     assert eighth._mods["moe"].chunk_share == 1 / 8
@@ -530,9 +531,7 @@ def test_the_cut_at_the_published_widths_counts_656_529_920():
     shapes = jax.eval_shape(model.init, jax.random.key(0))
     assert sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes)) == \
         656_529_920
-    assert model._mods["moe"].chunk_share == 1.0
-    # the movers of the one chunk of all pairs: gathers both ways
+    assert model._mods["moe"].chunk_share == 0.25
+    # a chunk is one mean share of the 98,304 pairs a layer: four of them
     S, k = 16384, 6
-    M = model._mods["moe"].chunk_rows(S * k)
-    assert M == S * k
-    assert S * k * moe_mod.ROW_GATHER_NS <= M * moe_mod.ROW_SCATTER_NS
+    assert model._mods["moe"].chunk_rows(S * k) == S * k // 4 == 24576

@@ -787,7 +787,7 @@ def test_remat_auto_sizes_the_benchmarks_cell(capsys):
 # `parallel/moe.py` and `ops/delta_rule.py` were edited for this family (PR
 # 58's tree), and what `select_remat_traced` picks there since PR 62, which
 # meant to move it (tests/test_mhc_mla_moe.py's `STANDING` says how).
-NINTH = ("mhc_mla_moe", "tiny-mhc-mla-moe", "32925b44dbce4268", "ffn",
+NINTH = ("mhc_mla_moe", "tiny-mhc-mla-moe", "6060959548dcf1c3", "ffn",
          0.013556059449911118)
 
 

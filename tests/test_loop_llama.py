@@ -538,7 +538,7 @@ def test_the_memory_estimate_reads_the_passes():
 # (`residual_scale`, `softmax_scale`, `logit_scale`) are held to their text.
 # (PR 69 meant to change the eleventh's text: its mixer writes `D x` on (b, t,
 # H P) as it lies, the same values; the digest is that tree's.)
-STANDING = {"ssm_moe": ("tiny-ssm-moe", "997c50bb28876b0f"),
+STANDING = {"ssm_moe": ("tiny-ssm-moe", "17ff3dd5ace75c97"),
             "llama": ("tiny", "14bb75356a403459"),
             "loop_llama": ("tiny-loop-llama", "93ef26ecbe667867")}
 

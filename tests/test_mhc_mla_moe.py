@@ -430,18 +430,18 @@ def test_the_mixers_round_trip_through_canonical_and_a_checkpoint(tmp_path):
 STANDING = {
     "llama": ("tiny", "14bb75356a403459", "true", 0.018050289154052733),
     "gpt2": ("tiny", "557e9d12313622a3", "dots", 0.01830301284790039),
-    "mla_moe": ("tiny-mla-moe", "f3e98f1b454c0b7d", "flash",
+    "mla_moe": ("tiny-mla-moe", "83b0575bcf151845", "flash",
                 0.01279906988143921),
-    "gdn_moe": ("tiny-gdn-moe", "3b4f653c0360b911", "true",
+    "gdn_moe": ("tiny-gdn-moe", "6d83ef8f30d65710", "true",
                 0.019560834169387815),
-    "conv_moe": ("tiny-conv-moe", "5dd8ca94f97f14ba", "ffn",
+    "conv_moe": ("tiny-conv-moe", "64b65f649e0393d9", "ffn",
                  0.018542855978012085),
-    "bd_moe": ("tiny-bd-moe", "d219a45ecaf0c324", "dots",
+    "bd_moe": ("tiny-bd-moe", "35cad4194c7a5c5e", "dots",
                0.01311171531677246),
-    "swa_moe": ("tiny-swa-moe", "175f91eabf45a03b", "true",
+    "swa_moe": ("tiny-swa-moe", "9c832b8734e8942b", "true",
                 0.023941473960876467),
-    "early_moe": ("tiny-early-moe", "67042cb0fb6ef081", "true",
-                  0.01711925506591797),
+    "early_moe": ("tiny-early-moe", "fba41f5652566548", "true",
+                  0.02028942108154297),
 }
 
 

@@ -223,111 +223,11 @@ def test_a_router_forced_onto_the_same_experts_drops_nothing():
     np.testing.assert_allclose(got.reshape(-1, d), want, atol=2e-5)
 
 
-# ---- the dispatch's row movers: gathers both ways ----
-
-def held(tok, n):
-    """The chunk's first n rows (the held pairs'); the rest is padding."""
-    return (jnp.arange(tok.shape[0]) < n)[:, None]
-
-
-def plain_take(x, tok, idx, n):
-    """The gather as the layer wrote it before the movers, its select
-    with it (the transpose is autodiff's: a select and a row
-    scatter-add)."""
-    return jnp.where(held(tok, n), jnp.take(x, tok, axis=0), 0)
-
-
-def plain_sum(y, r, tok, idx, n):
-    """The combine as the layer wrote it before the movers: a row
-    scatter-add over the chunk's tokens, of rows selected to zeros past
-    the held pairs'."""
-    return y.at[tok].add(jnp.where(held(tok, n), r, 0))
-
-
-def a_chunk(S, k, E, H, M, c, seed=0):
-    """Chunk `c` of a random routing's sorted pairs, by `apply`'s own
-    formulae: `tok` (padded past the pairs), `idx`, `n = rows_here - lo`."""
-    chosen = jnp.argsort(jax.random.uniform(jax.random.key(seed), (S, E)),
-                         axis=-1)[:, :k]
-    key = jnp.where(chosen < H, chosen, H).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    rows_here = int(jnp.sum(key < H))
-    pos = jnp.argsort(order).reshape(S, k)
-    chunks = -(-S * k // M)
-    tok = jnp.pad(order // k, (0, chunks * M - S * k))[c * M:(c + 1) * M]
-    lo = c * M
-    idx = jnp.where((pos < rows_here) & (pos >= lo) & (pos < lo + M),
-                    pos - lo, M)
-    return tok, idx, rows_here - lo, rows_here
-
-
-@pytest.mark.parametrize("tp", [1, 2])
-@pytest.mark.parametrize("case,S,k,E,H,M,c", [
-    ("one chunk of all the pairs", 64, 4, 8, 2, 256, 0),
-    ("first of three, all its rows held", 64, 4, 8, 5, 96, 0),
-    ("the chunk the held rows end in", 64, 4, 8, 5, 96, 1),
-    ("a chunk that runs past the pairs", 60, 3, 8, 8, 64, 2),
-    ("a chunk no held row reaches", 64, 4, 8, 2, 96, 2),
-])
-def test_the_row_movers_are_the_plain_forms_and_each_other_s_transpose(
-        case, S, k, E, H, M, c, tp):
-    """`take_rows` / `sum_rows` against `jax.vjp` of the plain gather and
-    the plain row scatter-add, values and all three cotangents, on chunks whose tokens repeat (a token with several held
-    experts), with pairs not held, padding rows past `rows_here` and past
-    the pairs THAT HOLD NaN going in and on the cotangent side (what a
-    grouped product may leave there on the chip: selected away, never
-    multiplied); under `shard_map` with the rows varying over tp, as the
-    layer's are."""
-    from jax.sharding import PartitionSpec as P
-    from distributed_pytorch_from_scratch_tpu.ops.collectives import copy_to
-    from distributed_pytorch_from_scratch_tpu.parallel.moe import (
-        sum_rows, take_rows)
-
-    d = 16
-    tok, idx, n, rows_here = a_chunk(S, k, E, H, M, c)
-    held_here = int(jnp.sum(idx < M))
-    assert held_here == max(0, min(rows_here - c * M, M))
-    if case == "a chunk no held row reaches":
-        assert held_here == 0
-    elif case.startswith("first of three"):
-        assert held_here == M
-    else:                               # padding rows past the held pairs
-        assert 0 < held_here < M
-    if c == 0:      # a token of several held experts is in the chunk twice
-        assert int(jnp.max(jnp.sum(idx < M, axis=1))) >= 2
-    keys = jax.random.split(jax.random.key(1), 5)
-    x, y, gy = (jax.random.normal(kk, (S, d)) for kk in keys[:3])
-    # what the grouped products leave in padding rows on the chip: anything
-    r, gr = (jnp.where(held(tok, n), jax.random.normal(kk, (M, d)), jnp.nan)
-             for kk in keys[3:])
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-
-    def both(take, add):
-        def shard(x, y, r, gr, gy):
-            # rows that differ between the tp ranks, like a partial sum
-            rank = 1.0 + jax.lax.axis_index("tp")
-            vary = lambda a: copy_to(a, "tp") * rank
-            out, pull = jax.vjp(lambda x, y, r: (
-                take(x, tok, idx, n), add(y, r, tok, idx, n)),
-                vary(x), vary(y), vary(r))
-            return jax.tree.map(lambda a: a[None],
-                                (out, pull((vary(gr), vary(gy)))))
-        return jax.jit(jax.shard_map(shard, mesh=mesh, in_specs=P(),
-                                     out_specs=P("tp")))(x, y, r, gr, gy)
-
-    got, want = both(take_rows, sum_rows), both(plain_take, plain_sum)
-    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert a.shape == b.shape and a.shape[0] == tp
-        np.testing.assert_allclose(a, b, atol=1e-6)
-    if held_here == 0:
-        assert not np.any(np.asarray(got[1][0]))      # no cotangent to x
-        np.testing.assert_array_equal(                # nothing combined
-            got[0][1], y[None] * (1.0 + np.arange(tp))[:, None, None])
-
+# ---- the dispatch's row movers: `take_held` in, `sum_held` back ----
 
 def add_held(y, r, tok, valid):
-    """A chunk of a share's rows back onto the sums as the layer moved them
-    until PR 65, kept HERE as `sum_held`'s oracle: the row scatter-add
+    """A chunk's rows back onto the sums as the layer moved them until PR
+    65, kept HERE as `sum_held`'s oracle: the row scatter-add
     `y.at[tok].add(r)` over the chunk's held rows, a padding row aimed past
     `y`'s last row, where a scatter drops it."""
     at = jnp.where(valid[:, 0], tok, y.shape[0])
@@ -421,24 +321,24 @@ def test_sum_held_is_the_row_scatter_add_summed_in_float32(
 
 @pytest.mark.parametrize("tp", [1, 2])
 @pytest.mark.parametrize("dtype,tol", [("float32", 1e-6), ("bfloat16", 2e-2)])
-@pytest.mark.parametrize("case,E,H,k,forced,chunks,gathers", [
-    ("one chunk of all the pairs", 8, 2, 2, False, 1, True),
-    ("an eighth held, six live chunks", 24, 3, 3, True, 6, False),
-    ("a sixteenth held, six live chunks", 64, 4, 3, True, 6, False),
-    ("chunks the routing does not reach", 64, 4, 3, False, 6, False),
+@pytest.mark.parametrize("case,E,H,k,forced,chunks", [
+    ("a quarter held, four chunks of which the routing reaches some", 8, 2, 2,
+     False, 4),
+    ("an eighth held, six live chunks", 24, 3, 3, True, 6),
+    ("a sixteenth held, six live chunks", 64, 4, 3, True, 6),
+    ("chunks the routing does not reach", 64, 4, 3, False, 6),
 ])
 def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
-        monkeypatch, case, E, H, k, forced, chunks, gathers, dtype, tol, tp):
-    """`SharedRoutedFFN.apply` moving its rows by the movers, whatever its
-    shape rule would pick (under 1.6 pairs a row of the chunk: gathers,
-    which since chunks are a share or less is the one chunk of all the
-    pairs; the rule's own verdict is asserted, then set aside, so the
-    gathers still walk several chunks HERE), against itself
-    with the plain gather and row scatter-add in their place (the form
-    the layer had, kept HERE as the oracle): a scalar of the output and
-    the gradient of every leaf and of the input, float32 to 1e-6 of a
-    leaf's largest entry, bfloat16 (whose scatter-add sums in bf16 where
-    `sum_rows` sums in float32) within the family tests' tolerance."""
+        monkeypatch, case, E, H, k, forced, chunks, dtype, tol, tp):
+    """`SharedRoutedFFN.apply` walking its chunks (one arm whatever share
+    of the experts is held: the walk's text holds a `while` and no `cond`,
+    and the step says what it walked), against itself with the plain row
+    scatter-add in `sum_held`'s place, forward and in the walk's transpose
+    (the form the layer had, kept HERE as the oracle): a scalar of the
+    output and the gradient of every leaf and of the input, float32 to
+    1e-6 of a leaf's largest entry, bfloat16 (whose scatter-add sums in
+    bf16 where `sum_held` sums in float32) within the family tests'
+    tolerance."""
     from jax.sharding import PartitionSpec as P
     from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 
@@ -449,25 +349,32 @@ def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
         p["bias"] = jnp.where(jnp.arange(E) < k, 10.0, 0.0)
     x = jax.random.normal(jax.random.key(2), (4, 214, d))
     pairs = 4 * 214 * k
-    assert -(-pairs // moe.chunk_rows(pairs)) == chunks
-    assert (pairs * moe_mod.ROW_GATHER_NS
-            <= moe.chunk_rows(pairs) * moe_mod.ROW_SCATTER_NS) == gathers
-    monkeypatch.setattr(moe_mod, "ROW_SCATTER_NS", 10 ** 9)
+    M = moe.chunk_rows(pairs)
+    assert -(-pairs // M) == chunks
     mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
 
     def value_and_grads():
         def loss(p, x):
-            y, _ = jax.shard_map(
+            y, c = jax.shard_map(
                 lambda p, x: moe.apply(p, x, jnp.dtype(dtype)), mesh=mesh,
                 in_specs=(moe.specs(), P()), out_specs=(P(), P()))(p, x)
-            return jnp.sum(jnp.sin(y.astype(jnp.float32)))
+            return jnp.sum(jnp.sin(y.astype(jnp.float32))), c
         with jax.default_matmul_precision("highest"):
-            return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))(p, x)
+            step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                              has_aux=True))
+            text = str(jax.make_jaxpr(step)(p, x))
+            assert " while[" in text and " cond[" not in text
+            return step(p, x)
 
-    got, got_g = value_and_grads()
-    monkeypatch.setattr(moe_mod, "take_rows", plain_take)
-    monkeypatch.setattr(moe_mod, "sum_rows", plain_sum)
-    want, want_g = value_and_grads()
+    (got, c), got_g = value_and_grads()
+    held = int(c["rows_here"])
+    assert int(c["rows_walked"]) == M * -(-held // M) > 0
+    assert (held == pairs) == bool(forced)
+    assert float(c["sum_blocks"]) == -(-held // M) * -(-4 * 214
+                                                       // moe_mod.SUM_BLOCK)
+    monkeypatch.setattr(moe_mod, "sum_held", lambda y, r, tok, valid: (
+        add_held(y, r, tok, valid), jnp.int32(0)))
+    (want, _), want_g = value_and_grads()
     assert abs(float(got) - float(want)) <= tol * max(abs(float(want)), 1.0)
     flat = jax.tree_util.tree_leaves_with_path(want_g)
     assert len(flat) == len(jax.tree.leaves(got_g)) == 9
@@ -508,28 +415,28 @@ def garbage_past_the_groups(seen, garbage=jnp.nan):
 
 
 @pytest.mark.parametrize("garbage", [jnp.nan, jnp.inf], ids=["nan", "inf"])
-@pytest.mark.parametrize("case,E,H,k,S,forced,chunks,gathers,rows", [
-    ("gathers, a quarter held, one chunk", 8, 2, 2, 856, (), 1, True, None),
+@pytest.mark.parametrize("case,E,H,k,S,forced,chunks,rows", [
+    ("a quarter held, chunks the routing does not reach", 8, 2, 2, 600, (), 3,
+     None),
     ("the walk, an eighth held, every chunk live", 24, 3, 3, 856,
-     (0, 1, 2), 6, False, 2568),
+     (0, 1, 2), 6, 2568),
     ("the walk, a sixteenth held, every chunk live", 64, 4, 3, 856,
-     (0, 1, 2), 6, False, 2568),
+     (0, 1, 2), 6, 2568),
     ("the walk, chunks the routing does not reach", 64, 4, 3, 856, (), 6,
-     False, None),
-    ("no held row, one chunk", 8, 2, 2, 856, (4, 5), 1, True, 0),
-    ("no held row, every chunk skipped", 64, 4, 3, 856, (8, 9, 10), 6, False,
-     0),
-    ("the held rows end on the kernel's tile", 8, 2, 2, 512, (0, 4), 1, True,
-     512),
+     None),
+    ("no held row, a quarter held", 8, 2, 2, 600, (4, 5), 3, 0),
+    ("no held row, every chunk skipped", 64, 4, 3, 856, (8, 9, 10), 6, 0),
+    ("the held rows end on the first chunk's last row, a quarter held", 8, 2,
+     2, 512, (0, 4), 2, 512),
     ("the held rows end on a chunk's last row", 64, 4, 2, 1024, (0, 8), 4,
-     False, 1024),
-    ("every pair held", 4, 4, 2, 856, (), 1, True, 1712),
+     1024),
+    ("every pair held: one chunk of two rows a token", 4, 4, 2, 600, (), 1,
+     1200),
     ("a chunk of three rows a token, blocks of two windows", 192, 24, 24,
-     512, tuple(range(24)), 8, False, 12288),
+     512, tuple(range(24)), 8, 12288),
 ])
 def test_no_row_past_the_groups_is_read_anywhere(
-        monkeypatch, case, E, H, k, S, forced, chunks, gathers, rows,
-        garbage):
+        monkeypatch, case, E, H, k, S, forced, chunks, rows, garbage):
     """The groups the two products are handed cover the chunk's HELD rows
     and nothing more (their sizes are read where the products are called),
     and the layer reads no other row of what the products and their
@@ -551,8 +458,6 @@ def test_no_row_past_the_groups_is_read_anywhere(
     x = jax.random.normal(jax.random.key(2), (2, S // 2, d))
     M = moe.chunk_rows(S * k)
     assert -(-S * k // M) == chunks
-    assert (S * k * moe_mod.ROW_GATHER_NS
-            <= M * moe_mod.ROW_SCATTER_NS) == gathers
 
     def value_and_grads():
         def loss(p, x):
@@ -574,17 +479,16 @@ def test_no_row_past_the_groups_is_read_anywhere(
     held = float(c["rows_here"])
     assert held == float(c["rows_computed"])
     # the movers and the passes walked whole chunks up to the last held row
-    assert float(c["rows_walked"]) == (M if chunks == 1
-                                       else M * -(-int(held) // M))
+    live = -(-int(held) // M)
+    assert float(c["rows_walked"]) == M * live
     if rows is not None:
         assert held == rows
     else:
-        assert 0 < held < S * k
-    # two products a live chunk, none for a chunk the `cond` skips
-    live = chunks if chunks == 1 else -(-int(held) // M)
-    # `sum_held` walked every token block of every live chunk of a share,
-    # a window each unless a block owns more rows than one holds
-    blocks = 0 if gathers else live * -(-S // moe_mod.SUM_BLOCK)
+        assert 0 < held < S * k and live < chunks
+    # two products a live chunk, none for a chunk the walk stops before;
+    # `sum_held` walked every token block of every live chunk, a window
+    # each unless a block owns more rows than one holds
+    blocks = live * -(-S // moe_mod.SUM_BLOCK)
     assert float(c["sum_blocks"]) == blocks
     assert float(c["sum_windows"]) == (2 if "two windows" in case
                                        else 1) * blocks
@@ -603,27 +507,34 @@ def test_no_row_past_the_groups_is_read_anywhere(
             jax.tree_util.keystr(path)
 
 
-@pytest.mark.parametrize("cell,E,H,k,chunk,chunks,gathers", [
+@pytest.mark.parametrize("cell,E,H,k,chunk,chunks,parents", [
     ("joyai-llm-flash.train-ep16share-b4-t4096", 256, 16, 8, 8192, 16,
-     False),
+     "4b7872808d71b7c8"),
     ("qwen3-next-80b-a3b.train-ep16share-b2-t8192", 512, 32, 10, 10240, 16,
-     False),
-    ("lfm2-8b-a1b.train-ep4share-b2-t8192", 32, 8, 4, 65536, 1, True),
-    ("sdar-30b-a3b.train-ep8share-b2-t4096", 128, 16, 8, 16384, 8, False),
-    ("trinity-mini.train-epshare-b2-t8192", 128, 16, 8, 16384, 8, False),
+     "30a262a132862dc1"),
+    ("lfm2-8b-a1b.train-ep4share-b2-t8192", 32, 8, 4, 16384, 4, None),
+    ("sdar-30b-a3b.train-ep8share-b2-t4096", 128, 16, 8, 16384, 8,
+     "c47b1a7127cde798"),
+    ("trinity-mini.train-epshare-b2-t8192", 128, 16, 8, 16384, 8,
+     "c47b1a7127cde798"),
+    ("smallthinker-21b-a3b.train-ep4share-b1-t16384", 64, 16, 6, 24576, 4,
+     None),
 ])
-def test_the_gradient_s_text_scatters_no_row_whatever_the_rule_says(
-        cell, E, H, k, chunk, chunks, gathers):
+def test_the_gradient_s_text_scatters_no_row_at_any_cell_s_share(
+        cell, E, H, k, chunk, chunks, parents):
     """The chunk rule at each expert cell's routing (16,384 tokens of 2048
     in bf16, the cell's experts, held share and k): a chunk is ONE mean
-    share of the pairs where under a sixth of the experts are held, walked
-    by a loop up to the last held row, and ALL the pairs with no `cond`
-    anywhere where a sixth or more are (cell 7). The lowered gradient holds
-    NO scatter at all: where the shape rule picks the gathers (the one
-    chunk of all pairs) rows move by gathers both ways, and a chunk of a
-    share's rows come back by `sum_held`'s sort, gather and products (until
-    PR 65 by one row scatter-add of the chunk's M rows, forward and in the
-    walk's transpose); a scalar scatter is nowhere."""
+    share of the pairs whatever share of the experts is held (a quarter in
+    cells 7 and 10, whose one chunk was ALL the pairs until PR 71), walked
+    by a loop up to the last held row with no `cond` anywhere. The lowered
+    gradient holds NO scatter at all: a chunk's rows come back by
+    `sum_held`'s sort, gather and products (until PR 65 by one row
+    scatter-add of the chunk's M rows, forward and in the walk's
+    transpose); a scalar scatter is nowhere. Where under a sixth is held
+    the text is what PR 71's PARENT lowered here (its digest, locations
+    stripped: making the walk the one arm moved nothing in those cells);
+    cells 7 and 10's is the walk's since."""
+    import hashlib
     import re
     from jax.sharding import PartitionSpec as P
     from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
@@ -633,10 +544,7 @@ def test_the_gradient_s_text_scatters_no_row_whatever_the_rule_says(
     pairs = 16384 * k
     assert moe.chunk_rows(pairs) == chunk and chunk % 512 == 0
     assert -(-pairs // chunk) == chunks
-    assert chunk == (pairs if 6 * H >= E else
-                     moe_mod.CHUNK_SHARES * pairs * H // E)
-    assert (pairs * moe_mod.ROW_GATHER_NS
-            <= chunk * moe_mod.ROW_SCATTER_NS) == gathers
+    assert chunk == moe_mod.CHUNK_SHARES * pairs * H // E
     mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
     params = jax.eval_shape(moe.init, jax.random.key(0))
     x = jax.ShapeDtypeStruct((2, 8192, d), jnp.bfloat16)
@@ -651,10 +559,12 @@ def test_the_gradient_s_text_scatters_no_row_whatever_the_rule_says(
     updates = [sig.split(", ")[-1] for sig in re.findall(
         r"stablehlo\.scatter.*?\}\) : \((.*?)\) ->", text, re.S)]
     assert updates == [] and "stablehlo.scatter" not in text
-    # no `cond` in either: the one chunk of all pairs runs bare, the chunks
-    # of a share are a loop that STOPS at the last held row
+    # no `cond`: the chunks are a loop that STOPS at the last held row
     assert "stablehlo.case" not in text and "stablehlo.if" not in text
-    assert chunks == 1 or "stablehlo.while" in text
+    assert "stablehlo.while" in text
+    if parents:
+        bare = re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
+        assert hashlib.sha256(bare.encode()).hexdigest()[:16] == parents
 
 
 # ---- chunks of a share or less: the walk stops where the held rows do ----
@@ -674,33 +584,50 @@ def one_expert_at_a_time(moe, params, x):
     return y.reshape(x.shape)
 
 
-@pytest.mark.parametrize("case,forced,S,rows,live", [
-    ("every token on two held experts: every chunk live", (0, 1), 1024, 2048,
-     4),
-    ("no token on a held expert: no chunk live", (8, 9), 1024, 0, 0),
-    ("the held rows end exactly on a chunk's edge", (0, 8), 1024, 1024, 2),
-    ("one row past a chunk's edge", (0, 8), 1025, 1025, 3),
-    ("an untrained router: the chunks past the held rows skipped", (), 1024,
-     None, None),
+@pytest.mark.parametrize("case,E,H,forced,S,M,rows,live", [
+    ("every token on two held experts: every chunk live", 64, 4, (0, 1), 1024,
+     512, 2048, 4),
+    ("no token on a held expert: no chunk live", 64, 4, (8, 9), 1024, 512, 0,
+     0),
+    ("the held rows end exactly on a chunk's edge", 64, 4, (0, 8), 1024, 512,
+     1024, 2),
+    ("one row past a chunk's edge", 64, 4, (0, 8), 1025, 512, 1025, 3),
+    ("an untrained router: the chunks past the held rows skipped", 64, 4, (),
+     1024, 512, None, None),
+    ("a quarter held: the held rows end in the first chunk of a share", 8, 2,
+     (0, 4), 500, 512, 500, 1),
+    ("a quarter held: the held rows end in the second chunk", 8, 2, (0, 4),
+     1000, 512, 1000, 2),
+    ("a quarter held: the held rows end in the last chunk", 8, 2, (0, 1),
+     1024, 512, 2048, 4),
+    ("a quarter held: no held row", 8, 2, (4, 5), 1024, 512, 0, 0),
+    ("a quarter held, an untrained router", 8, 2, (), 1024, 512, None, None),
+    ("a half held: the held rows fill the first of two chunks", 8, 4, (0, 4),
+     1024, 1024, 1024, 1),
+    ("a half held: both chunks live", 8, 4, (0, 1), 1024, 1024, 2048, 2),
+    ("every expert held: one chunk of all the pairs", 4, 4, (), 1024, 2048,
+     2048, 1),
 ])
 def test_fine_chunks_equal_one_expert_at_a_time_in_value_and_every_gradient(
-        case, forced, S, rows, live):
-    """A sixteenth of the experts held, so chunks of 512 rows (the grain's
-    floor at this size) walked up to the last held row: the output and the
-    gradient
+        case, E, H, forced, S, M, rows, live):
+    """Chunks of one mean share of the pairs walked up to the last held row,
+    at every held share (a sixteenth, so chunks of 512 rows, the grain's
+    floor at this size; a quarter and a half, whose one chunk was all the
+    pairs until PR 71; every expert held, whose one chunk still is): the
+    output and the gradient
     of every leaf and of the input are the dense sum's over the held
     experts whichever chunks are live, every pair that exists is computed
     (`rows_computed == rows_here`), and the movers and passes walked whole
     chunks up to the last held row and no further (`rows_walked == M *
     ceil(rows_here / M)`)."""
-    d, f, E, H, k = 32, 16, 64, 4, 2
+    d, f, k = 32, 16, 2
     moe = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
     p = moe.init(jax.random.key(1))
     if forced:
         p["bias"] = jnp.zeros(E).at[jnp.array(forced)].set(10.0)
     x = jax.random.normal(jax.random.key(2), (1, S, d))
-    M = moe.chunk_rows(S * k)
-    assert M == 512 and -(-S * k // M) >= 4
+    assert M == moe.chunk_rows(S * k)
+    assert (M == S * k) == (H == E)
 
     def value_and_grads(layer):
         def loss(p, x):
@@ -720,6 +647,7 @@ def test_fine_chunks_equal_one_expert_at_a_time_in_value_and_every_gradient(
     else:
         assert held == rows
     assert int(c["rows_walked"]) == M * live == M * -(-held // M)
+    assert int(c["sum_blocks"]) == live * -(-S // 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
     flat = jax.tree_util.tree_leaves_with_path(want_g)
     assert len(flat) == len(jax.tree.leaves(got_g)) == 6
@@ -792,16 +720,21 @@ def test_blocks_of_several_windows_equal_one_expert_at_a_time(
             jax.tree_util.keystr(path)
 
 
-@pytest.mark.parametrize("dp,tp", [(2, 1), (1, 2), (2, 2)])
-def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp):
+@pytest.mark.parametrize("dp,tp,E,H", [
+    (2, 1, 64, 4), (1, 2, 64, 4), (2, 2, 64, 4),
+    (2, 1, 8, 2), (1, 2, 8, 2), (2, 2, 8, 4), (1, 2, 4, 4), (2, 1, 4, 4)],
+    ids=lambda v: str(v))
+def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp, E, H):
     """Under a mesh the loop's length is a data shard's own (`rows_here`
     differs between them, so no collective may run inside it: the float
     operands are cast to one set of mesh axes before the walk and the
     sums over them happen once, outside): batch rows over dp, the experts'
     width over tp, against one expert at a time on one device, in value
-    and every gradient; the shards' counters add up."""
+    and every gradient; the shards' counters add up. At a sixteenth, a
+    quarter and a half of the experts held, and with every expert held
+    (one chunk of all a shard's pairs, which every shard walks)."""
     from jax.sharding import PartitionSpec as P
-    d, f, E, H, k, S = 32, 16, 64, 4, 2, 1024
+    d, f, k, S = 32, 16, 2, 1024
     moe = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0, tp_size=tp)
     whole = SharedRoutedFFN(d, f, E, top_k=k, held=H, n_shared=0)
     p = whole.init(jax.random.key(1))
@@ -832,8 +765,10 @@ def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp):
         lambda p, x: (one_expert_at_a_time(whole, p, x), None))
     per_shard = [int(jnp.sum(whole.route(p, xs.reshape(-1, d))[0] < H))
                  for xs in x.reshape(dp, -1, S, d)]
-    assert len(set(per_shard)) == dp            # the shards' loops differ
+    if H < E:
+        assert len(set(per_shard)) == dp        # the shards' loops differ
     M = moe.chunk_rows(2 * S * k // dp)
+    assert M == max(512, 2 * S * k // dp * H // E)
     assert int(c["rows_here"]) == int(c["rows_computed"]) == sum(per_shard)
     assert int(c["rows_walked"]) == sum(M * -(-n // M) for n in per_shard)
     np.testing.assert_allclose(got, want, atol=2e-5)
@@ -847,24 +782,27 @@ def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp):
 @pytest.mark.parametrize("E,H,k,pairs,chunk", [
     (256, 16, 8, 131072, 8192), (512, 32, 10, 163840, 10240),
     (128, 16, 8, 131072, 16384), (64, 4, 3, 2568, 512), (24, 3, 3, 2568, 512),
-    (7, 1, 2, 8192, 1536), (6, 1, 2, 8192, 8192), (32, 8, 4, 65536, 65536),
-    (8, 8, 2, 4096, 4096), (4, 1, 2, 256, 256),
+    (7, 1, 2, 8192, 1536), (6, 1, 2, 8192, 1536), (32, 8, 4, 65536, 16384),
+    (64, 16, 6, 98304, 24576), (8, 4, 2, 4096, 2048), (8, 8, 2, 4096, 4096),
+    (4, 1, 2, 256, 256),
 ])
-def test_a_chunk_is_a_mean_share_or_all_the_pairs(E, H, k, pairs, chunk):
-    """The grain of the dispatch: under a sixth of the experts held, ONE
-    of the job's mean shares of the pairs up to the grouped kernel's 512-row
-    tile (the expert cells 5, 6, 8 and 9, and the tests' tiny shapes at the
-    tile's floor), walked by a loop that stops at the last held row; a
-    sixth or more, ALL the pairs in one chunk (cell 7, every all-held
-    shape). Neither text has a `cond`."""
+def test_a_chunk_is_a_mean_share_of_the_pairs_at_every_held_share(
+        E, H, k, pairs, chunk):
+    """The grain of the dispatch: ONE of the job's mean shares of the pairs
+    up to the grouped kernel's 512-row tile, whatever share of the experts
+    is held (the expert cells 5 to 13: a quarter held in cells 7 and 10, a
+    sixteenth in cell 5; a half; the tests' tiny shapes at the tile's
+    floor), walked by a loop that stops at the last held row; ALL the pairs
+    in one chunk where every expert is held or the pairs are under a tile.
+    The text has a `while` and no `cond` at every share."""
     moe = SharedRoutedFFN(32, 16, E, top_k=k, held=H, n_shared=0)
     assert moe.chunk_rows(pairs) == chunk
-    assert (moe.chunk_share == 1.0) == (6 * H >= E) == (chunk == pairs)
+    assert moe.chunk_share == H / E
+    assert (chunk == pairs) == (H == E or pairs <= 512)
     x = jax.ShapeDtypeStruct((1, pairs // k, 32), jnp.float32)
     text = str(jax.make_jaxpr(lambda p, x: apply_moe(moe, p, x))(
         jax.eval_shape(moe.init, jax.random.key(0)), x))
-    assert " cond[" not in text
-    assert (" while[" in text) == (chunk < pairs)
+    assert " cond[" not in text and " while[" in text
 
 
 # ---- the dispatch's index work: no scalar gather, no scalar scatter ----
@@ -894,9 +832,8 @@ def plain_index(moe, chosen, w):
     key = jnp.where((local >= 0) & (local < H), local, H).reshape(-1)
     order = jnp.argsort(key, stable=True)
     ends = jnp.cumsum(jnp.bincount(key, length=H + 1)[:H])
-    pos = jnp.argsort(order).reshape(chosen.shape)
     routed = jnp.bincount(chosen.reshape(-1), length=moe.num_experts)
-    return order, w.reshape(-1)[order], ends, pos, routed
+    return order, w.reshape(-1)[order], ends, routed
 
 
 INDEX_CASES = [
@@ -920,7 +857,7 @@ def test_the_index_work_equals_the_plain_gathers_and_counts(
         case, score, E, H, offset, k, forced):
     """`route` and `index` against `take_along_axis`, `bincount`,
     `argsort` and `w[order]`: every selection and every integer EXACTLY
-    (`chosen`, `w`, `order`, `w_sorted`, `ends`, `pos`, `routed`), and the
+    (`chosen`, `w`, `order`, `w_sorted`, `ends`, `routed`), and the
     cotangents of the router's weights and of the tokens through `w` and
     through `w_sorted`, and of the scores through the pick alone, to 1e-6
     of autodiff's of the plain forms, in float32."""
@@ -940,19 +877,18 @@ def test_the_index_work_equals_the_plain_gathers_and_counts(
     want_chosen, want_w = plain_route(moe, p, xf)
     np.testing.assert_array_equal(chosen, want_chosen)
     np.testing.assert_array_equal(w, want_w)
-    got = jax.jit(lambda c, w: moe.index(c, w, inverse=True))(chosen, w)
+    got = jax.jit(moe.index)(chosen, w)
     want = jax.jit(lambda c, w: plain_index(moe, c, w))(chosen, w)
-    for name, a, b in zip(("order", "w_sorted", "ends", "pos", "routed"),
-                          got, want):
+    assert len(got) == len(want) == 4
+    for name, a, b in zip(("order", "w_sorted", "ends", "routed"), got, want):
         assert a.dtype == b.dtype and a.shape == b.shape, name
         np.testing.assert_array_equal(a, b, err_msg=name)
-    assert moe.index(chosen, w, inverse=False)[3] is None
     held = int(got[2][-1])
     if forced is not None:
         assert sorted(np.unique(chosen).tolist()) == sorted(forced)
         assert held == (S if case.startswith("every token") else 0)
     else:
-        assert 0 < held < S * k and int(np.max(got[4])) < S
+        assert 0 < held < S * k and int(np.max(got[3])) < S
 
     # cotangents through w (unsorted) and through w_sorted
     cw, cs = (jax.random.normal(kk, (S * k,))
@@ -965,7 +901,7 @@ def test_the_index_work_equals_the_plain_gathers_and_counts(
             return jnp.sum(w.reshape(-1) * cw) + jnp.sum(jnp.sin(w_sorted) * cs)
         return jax.jit(jax.grad(f, argnums=(0, 1)))(p["router"], xf)
 
-    got_g = loss(moe.route, lambda c, w: moe.index(c, w, inverse=True))
+    got_g = loss(moe.route, moe.index)
     want_g = loss(lambda p, x: plain_route(moe, p, x),
                   lambda c, w: plain_index(moe, c, w))
     for a, b in zip(got_g, want_g):
@@ -980,26 +916,24 @@ def test_the_index_work_equals_the_plain_gathers_and_counts(
     np.testing.assert_array_equal(ds, want_ds)
 
 
-@pytest.mark.parametrize("regime,E,H,k", [("gathers", 8, 2, 2),
-                                          ("the walk", 64, 4, 3)])
-def test_no_scalar_gather_or_scatter_is_left_in_the_layer(regime, E, H, k):
+@pytest.mark.parametrize("share,E,H,k", [("a quarter held", 8, 2, 2),
+                                         ("a sixteenth held", 64, 4, 3)])
+def test_no_scalar_gather_or_scatter_is_left_in_the_layer(share, E, H, k):
     """The jaxpr of the value-and-gradient of `apply` under
-    `jax.checkpoint` (forward, recompute and backward), in both mover
-    regimes: no `scatter` / `scatter-add` whose update is a scalar, no
-    `gather` of one-element slices. What is left are the movers' row
-    gathers (`x[tok]` and its like, `sum_rows`' columns) and, in the
-    scatter regime, the forward's row scatter-add and the transposed
-    gather's UNTIL PR 65: since then `sum_held` brings a chunk of a
-    share's rows back by a sort, a row gather and products, and NO scatter
-    of any kind is left in either regime."""
+    `jax.checkpoint` (forward, recompute and backward), at a held share
+    that kept one chunk of all the pairs until PR 71 and at one that has
+    walked chunks of a share since PR 50: no `scatter` / `scatter-add`
+    whose update is a scalar, no `gather` of one-element slices. What is
+    left are the movers' row gathers (`x[tok]`, `sum_held`'s rows into
+    token order, the sums' cotangent at a chunk's tokens); since PR 65
+    `sum_held` brings a chunk's rows back by a sort, a row gather and
+    products, and NO scatter of any kind is left at either share."""
     from jax.sharding import PartitionSpec as P
-    from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 
     d = 32
     moe = SharedRoutedFFN(d, 16, E, top_k=k, held=H)
     pairs = 4 * 214 * k
-    assert (pairs * moe_mod.ROW_GATHER_NS <= moe.chunk_rows(pairs)
-            * moe_mod.ROW_SCATTER_NS) == (regime == "gathers")
+    assert -(-pairs // moe.chunk_rows(pairs)) >= 4
     p = moe.init(jax.random.key(1))
     x = jax.random.normal(jax.random.key(2), (4, 214, d))
 
@@ -1055,7 +989,9 @@ def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
     assert summary["rows_here_per_token"] == 2.0    # all experts held
     assert summary["rows_computed_per_token"] == 2.0
     assert summary["rows_walked_per_token"] == 2.0  # one chunk of all pairs
-    assert summary["sum_windows_per_block"] == 0.0  # ... moved by gathers
+    # ... of two rows a token: a block of 128 tokens owns 256 of them, in
+    # one window or two by where its first row falls among the lanes
+    assert 1.0 <= summary["sum_windows_per_block"] <= 2.0
     assert summary["load_max_over_mean"] >= 1.0
     # off by default: the step's output is what it has always been
     plain = build_train_step(model, mesh, ocfg, with_grad_norm=True)

@@ -620,7 +620,8 @@ FACTS_PINNED = {
     # (what a layer holds beside the skeleton fell in PR 50 where under a
     # sixth of the experts are held, the dispatch's chunk one mean share of
     # the pairs and not six: 39680.0, 78464.0 and 116224.0 until then; the
-    # fifth family's cell holds a quarter and keeps its one chunk of all;
+    # fifth family's cell holds a quarter and kept its one chunk of all
+    # until PR 71: 37969.92 until then, three rows of four less a token;
     # since PR 62 each drawn family adds what the chip counts beyond its
     # own count, a multiple of d set from its cell's reading: 23680.0,
     # 60864.0, 76800.0 and 35584.0 until then, and the tiny rows with them)
@@ -628,7 +629,8 @@ FACTS_PINNED = {
         (55680216072192.0, 680441088, 60687.36, 1.0, 6)),
     "qwen3-next-80b-a3b": (2, 8192,
         (26242826895360.0, 625667136, 107210.23999999999, 1.0, 4)),
-    "lfm2-8b-a1b": (2, 8192, (22914011234304.0, 507820288, 37969.92, 1.0, 5)),
+    "lfm2-8b-a1b": (2, 8192,
+        (22914011234304.0, 507820288, -1198.0800000000017, 1.0, 5)),
     "sdar-30b-a3b": (2, 4096, (25889945419776.0, 645623296, 62023.68, 0.5, 6)),
 }
 

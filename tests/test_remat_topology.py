@@ -521,12 +521,14 @@ def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
     picks there, `dots` since PR 62 (no reserve is held beside 5.68 GiB of
     state): the family's memory facts (`ffn_inputs`, `tagged_layers`: one
     dense and one attention layer of five, `layer_extra_elems_per_token`: at
-    a held share of 1/4 the dispatch's one chunk is all 65,536 pairs) are
+    a held share of 1/4 a chunk of the dispatch is 16,384 of the 65,536
+    pairs since PR 71) are
     held to the compiler's plan, and Mosaic takes the flash kernels at head
     64 under a group of 4 over several blocks a head (the forward and,
     since PR 40, ONE backward kernel with the head resident where there
-    were dq and dk/dv). The chip itself counts 11.61 GiB for this step
-    (PERF.md section 5, PR 62; 10.90 at the floor)."""
+    were dq and dk/dv). The chip itself counts 10.34 GiB for this step
+    (PERF.md section 5, PR 71; 9.63 at the floor; 11.61 and 10.90 with the
+    one chunk of all the pairs, PR 62)."""
     from distributed_pytorch_from_scratch_tpu.config import ConvMoEConfig
     from distributed_pytorch_from_scratch_tpu.models import build_model
     cfg = ModelConfig(
@@ -786,12 +788,16 @@ CHIP_GIB = {
     "joyai-llm-flash.train-ep16share-b4-t4096": {"true": 14.229},
     "qwen3-next-80b-a3b.train-ep16share-b2-t8192": {"true": 14.110,
                                                     "flash": 14.110},
-    "lfm2-8b-a1b.train-ep4share-b2-t8192": {"true": 10.899, "dots": 11.609},
+    # (PR 71's readings, here and in cell 10: a chunk of the dispatch is a
+    # quarter of the pairs where it was all of them, 10.899 / 11.609 and
+    # 13.957 / 14.459 at `flash` until then; cell 10's 0.5 GiB buy `dots`)
+    "lfm2-8b-a1b.train-ep4share-b2-t8192": {"true": 9.632, "dots": 10.339},
     "sdar-30b-a3b.train-ep8share-b2-t4096": {"true": 13.461,
                                              "flash": 13.547},
     "trinity-mini.train-epshare-b2-t8192": {"true": 14.695},
-    "smallthinker-21b-a3b.train-ep4share-b1-t16384": {"true": 13.957,
-                                                      "flash": 14.459},
+    "smallthinker-21b-a3b.train-ep4share-b1-t16384": {"true": 13.366,
+                                                      "flash": 14.002,
+                                                      "dots": 14.538},
     "xing4-29b-a4b.train-ep8share-b1-t4096": {"true": 13.700,
                                               "flash": 14.011},
     # (PR 64's readings: the delta mixer keeps no checkpoint of its own and

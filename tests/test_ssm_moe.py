@@ -701,17 +701,17 @@ def test_remat_auto_sizes_the_benchmarks_cell(capsys):
 # one-sublayer fact: every one of them at its default.
 STANDING = {
     "gpt2": ("tiny", "557e9d12313622a3"),
-    "mla_moe": ("tiny-mla-moe", "f3e98f1b454c0b7d"),
-    "gdn_moe": ("tiny-gdn-moe", "3b4f653c0360b911"),
-    "conv_moe": ("tiny-conv-moe", "5dd8ca94f97f14ba"),
-    "bd_moe": ("tiny-bd-moe", "d219a45ecaf0c324"),
-    "swa_moe": ("tiny-swa-moe", "175f91eabf45a03b"),
-    "early_moe": ("tiny-early-moe", "67042cb0fb6ef081"),
-    "mhc_mla_moe": ("tiny-mhc-mla-moe", "32925b44dbce4268"),
+    "mla_moe": ("tiny-mla-moe", "83b0575bcf151845"),
+    "gdn_moe": ("tiny-gdn-moe", "6d83ef8f30d65710"),
+    "conv_moe": ("tiny-conv-moe", "64b65f649e0393d9"),
+    "bd_moe": ("tiny-bd-moe", "35cad4194c7a5c5e"),
+    "swa_moe": ("tiny-swa-moe", "9c832b8734e8942b"),
+    "early_moe": ("tiny-early-moe", "fba41f5652566548"),
+    "mhc_mla_moe": ("tiny-mhc-mla-moe", "6060959548dcf1c3"),
     # PR 64 MEANT to move this one (29fbd62e921a3dd5 before it): the delta
     # mixer's own checkpoint went and its q, k, v projections took the
     # ladder's names (parallel/kda.py); the other eight are the parent's
-    "kda_mla_moe": ("tiny-kda-mla-moe", "cf87afeb9cf6964b"),
+    "kda_mla_moe": ("tiny-kda-mla-moe", "7e367f7a5ca3d3da"),
 }
 
 
