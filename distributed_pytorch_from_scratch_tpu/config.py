@@ -340,6 +340,34 @@ class EarlyMoEConfig:
 
 
 @dataclass(frozen=True)
+class DsaMoEConfig:
+    """What the `dsa_moe` family (models/dsa_moe.py) needs beyond
+    `ModelConfig`'s own fields: a grouped-query expert decoder whose every
+    attention layer CHOOSES its keys: `indexer_num_heads` index heads of
+    `indexer_head_dim` over one index key head score every earlier token, a
+    row keeps the `topk` keys of largest score and attends over them alone,
+    and the indexer trains on a loss of its own (parallel/dsa.py,
+    ops/index_select.py). Heads `head_dim` wide whatever the model's width,
+    q/k norms, a softmax top-k router normalised over the chosen with no
+    shared expert, over routed experts of which this job may hold a slice.
+    The keys are Keye-VL-2.0's `config.json` names (`KeyeVL2`, the
+    indexer's under its `sa_config`). In `ModelConfig`, `attn_dim` is the
+    model width, `num_heads` / `num_kv_heads` the heads, `num_experts` the
+    ROUTED experts the router scores and `moe_top_k` the experts a token
+    takes; `ffn_dim` is not read (every layer is an expert layer)."""
+
+    head_dim: int
+    moe_intermediate_size: int
+    indexer_num_heads: int
+    indexer_head_dim: int
+    topk: int
+    # the job's share of an expert-parallel deployment, as LatentMoEConfig's
+    experts_held: "int | None" = None
+    expert_offset: int = 0
+    rms_norm_eps: float = 1e-6
+
+
+@dataclass(frozen=True)
 class LoopLlamaConfig:
     """What the `loop_llama` family (models/loop_llama.py) needs beyond
     `ModelConfig`'s own fields: the llama block (RoPE, RMSNorm, SwiGLU, an
@@ -450,6 +478,8 @@ class ModelConfig:
     loop_llama: "LoopLlamaConfig | None" = None
     # The `ssm_dense` family's facts (None for every other family).
     ssm_dense: "SsmDenseConfig | None" = None
+    # The `dsa_moe` family's facts (None for every other family).
+    dsa_moe: "DsaMoEConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -520,7 +550,7 @@ class ModelConfig:
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
                 "early_moe", "kda_mla_moe", "ssm_moe", "loop_llama",
-                "ssm_dense")
+                "ssm_dense", "dsa_moe")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -632,6 +662,17 @@ MODEL_PRESETS = {
             sliding_window_layout=(0, 1, 1, 1) * 2,
             rope_layout=(0, 1, 1, 1) * 2, head_dim=32,
             moe_ffn_hidden_size=32, sliding_window_size=16)),
+    # the `dsa_moe` family at a CPU size: every layer scores its earlier
+    # tokens with 2 index heads of 16 and keeps a row's 16 best, fewer than
+    # any test's sequence; 4 query heads over 2 key-value heads of 32 (heads
+    # x width = 128, not the model's 64), q/k norms; 8 routed experts
+    # (softmax top-2, no shared expert)
+    "tiny-dsa-moe": ModelConfig(
+        attn_dim=64, ffn_dim=0, num_heads=4, num_kv_heads=2, num_layers=2,
+        vocab_size=1024, maxlen=256, rope_theta=1e7, num_experts=8,
+        moe_top_k=2, dsa_moe=DsaMoEConfig(
+            head_dim=32, moe_intermediate_size=32, indexer_num_heads=2,
+            indexer_head_dim=16, topk=16)),
     # the `kda_mla_moe` family at a CPU size: Ling-3.0's pattern in small,
     # groups of three layers (two Kimi Delta Attention layers, 4 heads 16
     # wide, then one head-gated latent-attention layer, q/k 24 wide over a

@@ -1,14 +1,16 @@
 """The model families, and the one place a family's name becomes a class:
-`llama` (the reference's block) and `gpt2`, and eleven drawn from published
-configurations: nine that each hold one share of the experts their router
+`llama` (the reference's block) and `gpt2`, and twelve drawn from published
+configurations: ten that each hold one share of the experts their router
 scores, `mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
-`mhc_mla_moe`, `kda_mla_moe` and `ssm_moe`, and two dense ones:
+`mhc_mla_moe`, `kda_mla_moe`, `ssm_moe` and `dsa_moe` (whose every layer
+chooses its keys), and two dense ones:
 `loop_llama`, whose stack is passed several times a step, and `ssm_dense`,
 whose every layer is a Mamba-2 mixer or an attention and then a SwiGLU
 (docs/DESIGN.md, "What a family file holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
 from .conv_moe import ConvMoETransformer
+from .dsa_moe import SelectedAttentionMoETransformer
 from .early_moe import EarlyRouterMoETransformer
 from .gdn_moe import GdnMoETransformer
 from .gpt2 import GPT2Transformer
@@ -27,7 +29,8 @@ FAMILIES = {cls.family: cls for cls in (
     ConvMoETransformer, BlockDiffusionMoETransformer,
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
     HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer,
-    LoopedTransformer, SsmDenseTransformer)}
+    LoopedTransformer, SsmDenseTransformer,
+    SelectedAttentionMoETransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -745,6 +745,15 @@ class DecoderStack:
     _counter_reduces = {"hc_sinkhorn_err": lax.pmax,
                         "hc_colsum_err": lax.pmax,
                         "hc_res_offdiag": lax.pmean}
+    # A LAYER'S OWN LOSS: the counters among a layer's aux that are terms
+    # of the step's loss, name of the term's sum over the rows -> name of
+    # the counter that counts those rows. `_extra_loss` adds every layer's
+    # `sum / rows`, both summed over the batch axes first, to the main CE
+    # with weight 1; the term carries the gradient the layer gave it (a
+    # layer that trains some of its parameters on a loss of its own splits
+    # them off with stop-gradients where it computes the term:
+    # parallel/dsa.py). {}: no layer has one
+    layer_losses = {}
 
     def __post_init__(self):
         cfg, tp = self.cfg, self.tp_size
@@ -2275,9 +2284,12 @@ class DecoderStack:
                     mode: str, batch_axes):
         """A family's further loss terms on top of the main CE (`x` is the
         last layer's output, `trunk` what `_trunk` returned): (loss,
-        counters of its own). No further term here; the layers' counters
-        of a family that carries them."""
-        return loss, self._counters(aux, batch_axes)
+        counters of its own). Here the layers' own losses (`layer_losses`)
+        and the layers' counters of a family that carries them."""
+        counters = self._counters(aux, batch_axes)
+        for term, rows in self.layer_losses.items():
+            loss = loss + jnp.sum(counters[term] / counters[rows])
+        return loss, counters
 
     def _counters(self, aux, batch_axes) -> Params:
         """The layers' counters summed over the batch axes; none where the

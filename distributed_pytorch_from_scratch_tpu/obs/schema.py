@@ -122,6 +122,9 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # -- ISSUE 68: the counters of a dense family whose mixers count, at the
     # log interval (training/metrics.mixer_counters_summary)
     "mixer_counters": ("loss_main", "ssm_decay_min", "resid_rms_last"),
+    # -- ISSUE 72: the counters of layers that choose their keys, at the log
+    # interval (training/metrics.dsa_counters_summary), beside `moe_counters`
+    "dsa_counters": ("kept_share", "index_kl", "index_entropy", "tau_ties"),
     # -- ISSUE 37: `train()`'s step function built again after its steady
     # program was in hand (a tail window, a new sequence bucket), at `step`;
     # beside these `backend_compile_s` (compiled) or `cache_load_s` (`hit`)

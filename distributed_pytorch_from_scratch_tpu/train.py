@@ -48,7 +48,8 @@ from .runtime.mesh import (batch_feeder, init_multihost, make_mesh,
 from .training.checkpoint import (AsyncCheckpointer, latest_step,
                                   load_checkpoint, map_moments)
 from .training.metrics import (MetricsWriter, ProfilerTrace,
-                               bd_counters_summary, loop_counters_summary,
+                               bd_counters_summary, dsa_counters_summary,
+                               loop_counters_summary,
                                mixer_counters_summary, moe_counters_summary,
                                chip_peak_flops, device_memory_gib,
                                hbm_watermarks, model_flops_per_step,
@@ -225,8 +226,9 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "block-diffusion attention mask and weights the "
                         "masked positions' CE by 1/p; tokens/s count data "
                         "tokens; the later families, 'kda_mla_moe', "
-                        "'ssm_moe' and the dense 'ssm_dense' among them, "
-                        "each with --model "
+                        "'ssm_moe', the dense 'ssm_dense' and 'dsa_moe' "
+                        "(every layer chooses its keys and carries a loss "
+                        "of its own; dp only) among them, each with --model "
                         "tiny-<family>: README) and "
                         "train under dp/tp/ZeRO 1 only: "
                         "pp/cp/ep > 1, SP, ZeRO 2/3, decode and serving "
@@ -1374,6 +1376,14 @@ def train(args: argparse.Namespace,
                                     print("  " + ", ".join(
                                         f"{k} {v:.4g}" for k, v in bd.items()))
                                     writer.event("bd_counters", step=n, **bd)
+                                if "dsa_kept" in last_counters:
+                                    dsa = dsa_counters_summary(
+                                        jax.device_get(last_counters))
+                                    print("  " + ", ".join(
+                                        f"{k} {v:.4g}"
+                                        for k, v in dsa.items()))
+                                    writer.event("dsa_counters", step=n,
+                                                 **dsa)
                             if telemetry is not None:
                                 # same numbers the log line prints — the live
                                 # endpoint view; the goodput buckets ride too

@@ -167,6 +167,27 @@ def bd_counters_summary(counters: dict) -> dict:
             / max(float(counters["weight_sum"]), 1e-9)}
 
 
+def dsa_counters_summary(counters: dict) -> dict:
+    """The counters of layers that CHOOSE their keys (`models/dsa_moe.py`,
+    `ops/index_select.SUMS`, a row a layer) as a log line's numbers: the
+    pairs the rows kept over the causal pairs (`sum_t min(t + 1, topk)` over
+    the triangle: the selection is live where this is under 1), the
+    indexer's loss a layer, summed (what the step adds to the CE), the mean
+    entropy of the index scores' softmax over a row's set (a fresh indexer's
+    is the log of the set's size) and the share of rows whose threshold is
+    shared by keys on both sides of the budget (the tie rule decided
+    them)."""
+    import numpy as np
+
+    rows = np.asarray(counters["dsa_rows"], np.float64)
+    of = lambda name: np.asarray(counters[name], np.float64)
+    return {"kept_share": float(np.sum(of("dsa_kept"))
+                                / np.sum(of("dsa_causal"))),
+            "index_kl": float(np.sum(of("dsa_index_kl") / rows)),
+            "index_entropy": float(np.mean(of("dsa_index_entropy") / rows)),
+            "tau_ties": float(np.mean(of("dsa_tau_ties") / rows))}
+
+
 def loop_counters_summary(counters: dict) -> dict:
     """The counters of a stack passed R times a step (`DecoderStack.
     _loop_loss`) as a log line's numbers: the whole objective, each exit's

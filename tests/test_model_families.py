@@ -12,6 +12,7 @@ import pytest
 from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          BdMoEConfig,
                                                          ConvMoEConfig,
+                                                         DsaMoEConfig,
                                                          EarlyMoEConfig,
                                                          GdnMoEConfig,
                                                          HyperConnectionConfig,
@@ -99,9 +100,18 @@ SSM_DENSE = dict(layer_types=("mamba", "attention") * 2, mamba_n_heads=4,
                  attention_multiplier=0.125, logits_scaling=8.0)
 
 
+# the dsa_moe family: every layer chooses 8 keys a row by 2 index heads of
+# 8; heads of 16
+DSA = dict(head_dim=16, moe_intermediate_size=16, indexer_num_heads=2,
+           indexer_head_dim=8, topk=8)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "dsa_moe":
+        return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
+                           dsa_moe=DsaMoEConfig(experts_held=held, **DSA))
     if extra == "ssm_dense":
         # a dense family with facts: no expert to hold
         return ModelConfig(num_kv_heads=2, **{**TINY, "num_layers": 4},
@@ -150,7 +160,7 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "mhc_mla_moe": "tiny-mhc-mla-moe",
                 "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe",
                 "loop_llama": "tiny-loop-llama",
-                "ssm_dense": "tiny-ssm-dense"}
+                "ssm_dense": "tiny-ssm-dense", "dsa_moe": "tiny-dsa-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
