@@ -122,6 +122,10 @@ class SelectedAttentionMoETransformer(DecoderStack):
     def head_dim(self) -> int:
         return self.cfg.dsa_moe.head_dim
 
+    # the rows' sets travel from the selection to the backward's walks as one
+    # bit a (row, key) pair, named with the lse (ops/index_select.py)
+    flash_lse_bytes_per_pair = 1 / 8
+
     @property
     def layer_extra_elems_per_token(self) -> float:
         """What a layer's backward holds at its fullest beside the d-wide
@@ -134,8 +138,9 @@ class SelectedAttentionMoETransformer(DecoderStack):
         kernels' per-row float32 columns (each head's lse and delta, the
         head weights and their gradient, two elements each). The T x T
         score and the selection's temporaries are in NO term: the kernels
-        re-make the score a tile in VMEM and a row's set is two numbers
-        (ops/pallas/dsa_attention.py). The last term is what the chip
+        make the score a tile in VMEM, and a row's set, one bit a pair,
+        is kept with the heads' lse (`flash_lse_bytes_per_pair`:
+        ops/pallas/dsa_attention.py). The last term is what the chip
         counts beyond those, SET FROM ITS READING (PERF.md section 5)."""
         dm = self.cfg.dsa_moe
         moe = self._mods["moe"]
@@ -245,5 +250,6 @@ class _Probing(SelectedAttentionMoETransformer):
 
 
 # model widths a token the chip counts beyond the terms of
-# `layer_extra_elems_per_token` (set from the benchmark's cell on a v5e)
-LAYER_FIT_WIDTHS = 8.0
+# `layer_extra_elems_per_token` (set from the benchmark's cell on a v5e,
+# whose window read 13.861 GiB at `flash` and 14.917 at `dots`: PERF.md §5)
+LAYER_FIT_WIDTHS = 3.0

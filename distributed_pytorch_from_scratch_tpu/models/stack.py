@@ -916,6 +916,9 @@ class DecoderStack:
         return self.head_dim
 
     layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
+    # bytes a (row, key) pair of a sequence that a layer keeps under the name
+    # `flash_lse` beside the heads' lse (a mask that is data: models/dsa_moe)
+    flash_lse_bytes_per_pair = 0.0
     head_rows_share = 1.0       # the part of a batch's rows the head reads
 
     @property

@@ -14,9 +14,9 @@ head weights `w` (b, t, J) float32:
     P[t, s] = (1 / H) sum_h (that softmax)      the indexer's target
     KL_t    = sum_{s in S_t} P[t, s] (log P[t, s] - log softmax_S(I)[t, s])
 
-A row's set travels as two numbers (`select`): `tau`, its `top_k`-th
-largest score, and `cut`, the last key index kept among the keys whose score
-EQUALS tau; `live` is the set as a boolean. **No gradient passes through the
+A row's set is two numbers (`select`): `tau`, its `top_k`-th largest
+score, and `cut`, the last key index kept among the keys whose score EQUALS
+tau; `live` is the set as a boolean. **No gradient passes through the
 choice, P is a constant of the KL, and the attention's gradient reaches no
 index tensor**: `selected_attention` returns `(o, sums)` where the sum of
 the rows' KL in `sums["dsa_index_kl"]` is differentiable in (qI, kI, w)
@@ -27,7 +27,9 @@ splits the parameters between the two losses exactly.
 `selected_attention` is the dispatch (`ops/attention.IMPLS`): `xla` is the
 text below, whole (t, t) matrices, the CPU default and the kernels' oracle;
 `flash` / `flash_interpret` are `ops/pallas/dsa_attention.py`'s five
-kernels, which re-make the score a tile and never hold it.
+kernels, which never hold the score: the selection makes it a tile at a
+time and writes the set as a bit a pair, the three attention walks read
+the bits, and the loss walk makes the tile once more for its numbers.
 """
 
 from __future__ import annotations
@@ -41,6 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ..obs.trace import current_tracer
 from .attention import repeat_kv, resolve_attention_impl
 
 # what `selected_attention` counts beside the output, each a SUM over the
@@ -141,24 +144,29 @@ def _selected_flash_fwd(q, k, v, q_idx, k_idx, w, top_k, bq, bk, interpret):
     blocks = dict(bq=bq, bk=bk, interpret=interpret)
     w4 = _rows_last(w.astype(jnp.float32))
     with jax.named_scope("dsa_select"):
-        tau, cut, tied = kernels.select_call(q_idx, k_idx, w4, top_k,
-                                             **blocks)
+        tau, cut, tied, bits, lse_i, kept = kernels.select_call(
+            q_idx, k_idx, w4, top_k, **blocks)
+    tracer = current_tracer()
+    if tracer is not None:
+        # what this call built: the kernels that make the index tile are
+        # the selection and the loss walk, and the set is `bits`
+        tracer.instant("dsa_walk", mask="bits", planes=bits.shape[1],
+                       bits_bytes=bits.size * bits.dtype.itemsize,
+                       index_tiles_a_layer=2, blocks=[bq, bk], t=t)
     # A rung that keeps the kernels' outputs keeps the choice they were
-    # made under: the backward never attends over a re-made selection. What
-    # is kept is kept lane-dense: a (.., t, 1) column is a tile of 128
-    # lanes a row on the chip, 128 times its size.
-    tau = checkpoint_name(tau[..., 0], "flash_lse")
-    cut = checkpoint_name(cut[..., 0], "flash_lse")
+    # made under: the backward never attends over a re-made selection. The
+    # choice is a bit a (row, key) pair, t / 8 bytes a row: the backward
+    # walks read nothing else of the indexer's.
+    bits = checkpoint_name(bits, "flash_lse")
     with jax.named_scope("dsa_attend"):
-        o, lse, lse_i, kept = kernels.fwd_call(
-            q, k, v, q_idx, k_idx, w4, tau[..., None], cut[..., None],
-            **blocks)
+        o, lse = kernels.fwd_call(q, k, v, bits, **blocks)
     o = checkpoint_name(o, "flash_out")
+    # (kept lane-dense: a (.., t, 1) column is a tile of 128 lanes a row
+    # on the chip, 128 times its size)
     lse = checkpoint_name(lse[..., 0], "flash_lse")
     with jax.named_scope("dsa_index_loss"):
         kl, entropy, d_qi, d_w, d_ki = kernels.loss_call(
-            q, k, lse[..., None], q_idx, k_idx, w4, tau[..., None],
-            cut[..., None], lse_i, **blocks)
+            q, k, lse[..., None], q_idx, k_idx, w4, tau, cut, lse_i, **blocks)
         # (with the flash outputs, so that a rung that keeps those does
         # not walk the triangle again for these)
         d_qi = checkpoint_name(d_qi, "flash_out")
@@ -166,8 +174,7 @@ def _selected_flash_fwd(q, k, v, q_idx, k_idx, w, top_k, bq, bk, interpret):
         d_w = checkpoint_name(
             jnp.swapaxes(d_w[..., 0], 1, 2).astype(w.dtype), "flash_out")
     sums = _sums(kl, entropy, kept, tied, b, t)
-    kept_back = (q, k, v, q_idx, k_idx, w, tau, cut, o, lse, d_qi, d_ki, d_w)
-    return (o, sums), kept_back
+    return (o, sums), (q, k, v, bits, o, lse, d_qi, d_ki, d_w)
 
 
 def _rows_last(w):
@@ -178,15 +185,14 @@ def _rows_last(w):
 
 def _selected_flash_bwd(top_k, bq, bk, interpret, kept_back, cts):
     from .pallas import dsa_attention as kernels
-    q, k, v, q_idx, k_idx, w, tau, cut, o, lse, d_qi, d_ki, d_w = kept_back
+    q, k, v, bits, o, lse, d_qi, d_ki, d_w = kept_back
     do, d_sums = cts
     with jax.named_scope("dsa_attend"):
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True)
         dq, dk, dv = kernels.bwd_calls(
-            q, k, v, q_idx, k_idx, _rows_last(w.astype(jnp.float32)),
-            tau[..., None], cut[..., None], do, lse[..., None], delta,
-            bq=bq, bk=bk, interpret=interpret)
+            q, k, v, bits, do, lse[..., None], delta, bq=bq, bk=bk,
+            interpret=interpret)
     with jax.named_scope("dsa_index_loss"):
         g = d_sums["dsa_index_kl"].astype(jnp.float32)
         scale = lambda a: (g * a.astype(jnp.float32)).astype(a.dtype)
@@ -230,9 +236,9 @@ def selection_probe(q_idx, k_idx, w, top_k: int, impl: str = "auto"):
         bq, bk = flash_blocks(t)
         blocks = dict(bq=bq, bk=bk, interpret=impl == "flash_interpret")
         w4 = _rows_last(w.astype(jnp.float32))
-        tau, cut, _ = kernels.select_call(q_idx, k_idx, w4, top_k, **blocks)
-        score, chosen = kernels.probe_call(q_idx, k_idx, w4, tau, cut,
-                                           **blocks)
+        _, _, _, bits, _, _ = kernels.select_call(q_idx, k_idx, w4, top_k,
+                                                  **blocks)
+        score, chosen = kernels.probe_call(q_idx, k_idx, w4, bits, **blocks)
     return score[:, -min(PROBE_ROWS, t):], chosen
 
 

@@ -5,30 +5,40 @@ are there).
 A row t of a sequence keeps the `top_k` keys s <= t of largest index score
 `I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])` (float32; ties to the
 earlier key). The score is T x T a sequence and is never written to HBM:
-every kernel RE-MAKES a tile of it from `qI` (b, J, t, c), `kI` (b, t, c)
-and `w` (b, J, t, 1) by the one function `_index_tile`, so all five see the
-same numbers, and the selection travels as two numbers a row: `tau`, the
-row's `top_k`-th largest score, and `cut`, the last key index that is kept
-among the keys whose score EQUALS tau. `(s <= t) and (I > tau or (I == tau
-and s <= cut))` (`_live`) is then the row's set, exactly.
+the two kernels that need its NUMBERS make a tile of it from `qI` (b, J, t,
+c), `kI` (b, t, c) and `w` (b, J, t, 1) by the one function `_index_tile`
+(the selection, and the indexer's loss), and the SET travels from the
+kernel that chooses it to the walks that attend under it as ONE BIT A
+(row, key) PAIR: `bits` (b, planes, t, bk) int32, the pair (t, s) in word
+`[s // (32 bk), t, s mod bk]` at bit `(s // bk) mod 32`, so that the key
+tile j of a walk reads its mask out of one (bq, bk) block of words with one
+AND (`_tile_bit`); `planes` is `bit_planes(t, bk)`, one at every length up
+to 32 key tiles (t^2 / 8 bytes a sequence: 32 MiB at 16,384).
 
 * `dsa_select`: a grid step holds one block of query rows' scores against
   every key up to the block's last row, as order-preserving int32 keys in
-  VMEM (`_sortable`), and finds tau by building its bits from the top (32
-  passes of compare-and-count over the scratch, no sort), then how many
-  keys are tied at tau and, only where a row's ties straddle the budget,
-  `cut` by bisection on the key index;
+  VMEM (`_sortable`), and finds `tau`, the row's `top_k`-th largest score,
+  by building its bits from the top (32 passes of compare-and-count over
+  the scratch, no sort), then how many keys are tied at tau and, only where
+  a row's ties straddle the budget, `cut`, the last key index kept among
+  the keys that score tau, by bisection on the key index. `(s <= t) and (I
+  > tau or (I == tau and s <= cut))` (`_live`) is then the row's set,
+  exactly, and one more pass over the scratch WRITES it (`bits`, the same
+  rule on the keys it compared) and takes what only the set's own scores
+  give: the logsumexp of I over the set and the set's size;
 * `dsa_flash_fwd`: the flash walk of the whole triangle, ALL the query
-  heads of a row block a grid step (the index tile and the set's mask are
-  made once a tile and shared by the heads, a key-value group at a time),
-  online softmax under the mask; beside o and each head's lse it returns
-  the logsumexp of I over a row's set and the number of keys it kept;
+  heads of a row block a grid step (a tile's mask is read once and shared
+  by the heads, a key-value group at a time), online softmax under the
+  mask; o and each head's lse;
 * `dsa_flash_bwd_dq`, `dsa_flash_bwd_dkv`: the standard two backward walks
-  under the same mask (the choice carries no gradient);
-* `dsa_index_loss`: the indexer's own loss in one more walk: a tile's
+  under the same bits (the choice carries no gradient);
+* `dsa_index_loss`: the indexer's own loss in one more walk, the second
+  and last to make the index tile (it needs z and I themselves): a tile's
   head-summed attention probabilities P (from q, k and the forward's lse),
   the KL of P from softmax_S(I), its gradient `softmax_S(I) - P` pushed back
-  through the ReLU into qI, kI and w, and the entropy of softmax_S(I).
+  through the ReLU into qI, kI and w, and the entropy of softmax_S(I); its
+  mask is `_live` of its own tile under the selection's (tau, cut), the
+  same bits (tests/test_dsa_moe.py holds them equal pair for pair).
 
 Nothing here plans tiles from a declaration: a tile above the diagonal is
 skipped, every other tile is computed whole and masked
@@ -103,6 +113,20 @@ def _live(score, tau, cut, rows, cols):
                              | ((score == tau) & (cols <= cut)))
 
 
+# key tiles a plane of the set's bits holds: a bit of an int32 word each
+WORD = 32
+
+
+def bit_planes(t: int, bk: int) -> int:
+    """Planes of (t, bk) int32 words that hold a sequence's set."""
+    return -(-(t // bk) // WORD)
+
+
+def _tile_bit(j):
+    """The word whose one bit is key tile `j`'s, in its plane."""
+    return jnp.int32(1) << (j % WORD)
+
+
 def _flip(bits):
     """A float32's bits <-> the int32 whose signed order is the floats':
     the bits below the sign turned where the sign is set (an involution)."""
@@ -113,10 +137,16 @@ def _sortable(x):
     return _flip(lax.bitcast_convert_type(x, jnp.int32))
 
 
+def _scores(keys):
+    """`_sortable`'s inverse: the float32 scores of int32 keys."""
+    return lax.bitcast_convert_type(_flip(keys), jnp.float32)
+
+
 # ---------------------------------------------------------------- selection
 
 def _select_kernel(ids, _, k_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref, ties_ref,
-                   keys_ref, *, bq: int, fill: int, chunk: int, t: int):
+                   bits_ref, lse_i_ref, kept_ref, keys_ref,
+                   *, bq: int, bk: int, chunk: int, t: int):
     i = ids[1]
     top_k = k_ref[0, 0]
     rows = i * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
@@ -125,15 +155,17 @@ def _select_kernel(ids, _, k_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref, ties_
     qi = qi_ref[0].reshape(J * bq, c)
     w = w_ref[0].reshape(J * bq, 1)
 
-    def write(n, _):
-        at = pl.multiple_of(n * fill, fill)
-        _, score = _index_tile(qi, ki_ref[0, pl.ds(at, fill), :], w, bq)
-        cols = at + lax.broadcasted_iota(jnp.int32, (1, fill), 1)
-        keys_ref[:, pl.ds(at, fill)] = jnp.where(
-            cols <= rows, _sortable(score), jnp.int32(INT_MIN))
-        return 0
+    def write(n, top):
+        at = pl.multiple_of(n * bk, bk)
+        _, score = _index_tile(qi, ki_ref[0, pl.ds(at, bk), :], w, bq)
+        cols = at + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        keys = jnp.where(cols <= rows, _sortable(score), jnp.int32(INT_MIN))
+        keys_ref[:, pl.ds(at, bk)] = keys
+        return jnp.maximum(top, jnp.max(keys, axis=1, keepdims=True))
 
-    lax.fori_loop(0, chunks * (chunk // fill), write, 0)
+    # (the row's largest key beside the writing: it is in the row's set)
+    top = lax.fori_loop(0, chunks * (chunk // bk), write,
+                        jnp.full((bq, 1), INT_MIN, jnp.int32))
 
     def count(pred):
         """Keys a row (bq, 1) for which `pred(keys, cols)` holds."""
@@ -179,24 +211,60 @@ def _select_kernel(ids, _, k_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref, ties_
              jnp.full((bq, 1), t - 1, jnp.int32)))
         cut_ref[0] = jnp.where(tied, lo, t)
 
-    tau = lax.bitcast_convert_type(_flip(tau_key), jnp.float32)
-    tau_ref[0] = jnp.where(short, -jnp.inf, tau)
+    tau_ref[0] = jnp.where(short, -jnp.inf, _scores(tau_key))
+
+    # The set itself, a key tile of the walks at a time: a bit a pair into
+    # the tile's place of the row block's words, and, over the set, the sum
+    # of exp(I - the row's largest) and the count.
+    # (a key after its row is INT_MIN in the scratch, under every score: a
+    # short row's threshold is INT_MIN itself, with no key kept AT it)
+    edge = jnp.where(short, jnp.int32(INT_MIN), tau_key)
+    cut = jnp.where(short, -1, cut_ref[0])
+    largest = _scores(top)
+    bits_ref[...] = jnp.zeros(bits_ref.shape, jnp.int32)
+
+    def pack(n, sums):
+        total, size = sums
+        at = pl.multiple_of(n * bk, bk)
+        keys = keys_ref[:, pl.ds(at, bk)]
+        cols = at + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        live = (keys > edge) | ((keys == edge) & (cols <= cut))
+        plane = n // WORD
+        bits_ref[0, plane] = bits_ref[0, plane] | jnp.where(
+            live, _tile_bit(n), jnp.int32(0))
+        total = total + jnp.sum(
+            jnp.where(live, jnp.exp(_scores(keys) - largest), 0.0), axis=1,
+            keepdims=True)
+        size = size + jnp.sum(live.astype(jnp.float32), axis=1,
+                              keepdims=True)
+        return total, size
+
+    zero = jnp.zeros((bq, 1), jnp.float32)
+    total, size = lax.fori_loop(0, (i * bq + bq - 1) // bk + 1, pack,
+                                (zero, zero))
+    lse_i_ref[0] = largest + jnp.log(total)
+    kept_ref[0] = size
 
 
 def select_call(q_idx, k_idx, w, top_k: int, *, bq: int, bk: int,
                 interpret: bool):
     """(tau (b, t, 1) float32, cut (b, t, 1) int32, tied (b, t, 1) float32:
-    is the row's threshold shared by keys on both sides of the budget)."""
+    is the row's threshold shared by keys on both sides of the budget; the
+    rows' sets `bits` (b, planes, t, bk) int32 (module docstring); the
+    index scores' logsumexp over a row's set (b, t, 1) and the keys the
+    row kept (b, t, 1), float32)."""
     b, J, t, c = q_idx.shape
     assert t % bq == 0 and t % bk == 0, (t, bq, bk)
-    # the score is made a tile of the walks' own shape (the same products,
-    # bit for bit); a counting pass reads a few tiles' keys at once
+    # the score is made a tile of the loss walk's own shape (the same
+    # products, bit for bit); a counting pass reads a few tiles' keys at once
     tiles = max(n for n in range(1, max(COUNT_CHUNK // bk, 1) + 1)
                 if (t // bk) % n == 0)
-    fill, chunk = bk, bk * tiles
-    kernel = _kernel(_select_kernel, 2, q_idx, interpret, bq=bq, fill=fill,
-                     chunk=chunk, t=t)
-    row = lambda bi, i: (bi, i, 0)
+    kernel = _kernel(_select_kernel, 2, q_idx, interpret, bq=bq, bk=bk,
+                     chunk=bk * tiles, t=t)
+    planes = bit_planes(t, bk)
+    row = pl.BlockSpec((1, bq, 1), lambda bi, i: (bi, i, 0))
+    column = lambda dtype: _out_struct((b, t, 1), dtype, q_idx)
+    f32 = jnp.float32
     return pl.pallas_call(
         kernel,
         grid=(b, t // bq),
@@ -206,10 +274,13 @@ def select_call(q_idx, k_idx, w, top_k: int, *, bq: int, bk: int,
             pl.BlockSpec((1, t, c), lambda bi, i: (bi, 0, 0)),
             pl.BlockSpec((1, J, bq, 1), lambda bi, i: (bi, 0, i, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, bq, 1), row)] * 3,
-        out_shape=[_out_struct((b, t, 1), jnp.float32, q_idx),
-                   _out_struct((b, t, 1), jnp.int32, q_idx),
-                   _out_struct((b, t, 1), jnp.float32, q_idx)],
+        out_specs=[row, row, row,
+                   pl.BlockSpec((1, planes, bq, bk),
+                                lambda bi, i: (bi, 0, i, 0)),
+                   row, row],
+        out_shape=[column(f32), column(jnp.int32), column(f32),
+                   _out_struct((b, planes, t, bk), jnp.int32, q_idx),
+                   column(f32), column(f32)],
         scratch_shapes=[pltpu.VMEM((bq, t), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
@@ -221,15 +292,10 @@ def select_call(q_idx, k_idx, w, top_k: int, *, bq: int, bk: int,
 
 # ------------------------------------------------------------ the flash walks
 
-def _tile_live(i, j, qi_ref, ki_ref, w_ref, tau_ref, cut_ref, bq: int,
-               bk: int):
-    """(z, I, live (bq, bk) bool) of the grid's tile (i, j)."""
-    J, c = qi_ref.shape[1], qi_ref.shape[3]
-    z, score = _index_tile(qi_ref[0].reshape(J * bq, c), ki_ref[0],
-                           w_ref[0].reshape(J * bq, 1), bq)
-    rows = i * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
-    cols = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-    return z, score, _live(score, tau_ref[0], cut_ref[0], rows, cols)
+def _tile_set(bits_ref, j):
+    """(bq, bk) bool: the pairs of key tile `j` that are in their rows'
+    sets, from the row block's words of that tile's plane."""
+    return (bits_ref[0, 0] & _tile_bit(j)) != 0
 
 
 def _crosses(i, j, bq: int, bk: int):
@@ -237,9 +303,8 @@ def _crosses(i, j, bq: int, bk: int):
     return j * bk <= i * bq + bq - 1
 
 
-def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref,
-                o_ref, lse_ref, lse_i_ref, kept_ref,
-                m_ref, l_ref, acc_ref, mi_ref, li_ref, n_ref,
+def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref,
                 *, scale: float, bq: int, bk: int, group: int):
     _, i, j = ids
 
@@ -248,22 +313,10 @@ def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, 
         m_ref[...] = jnp.full(m_ref.shape, MASK, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
-        mi_ref[...] = jnp.full(mi_ref.shape, MASK, jnp.float32)
-        li_ref[...] = jnp.zeros(li_ref.shape, jnp.float32)
-        n_ref[...] = jnp.zeros(n_ref.shape, jnp.float32)
 
     @pl.when(_crosses(i, j, bq, bk))
     def _():
-        _, score, live = _tile_live(i, j, qi_ref, ki_ref, w_ref, tau_ref,
-                                    cut_ref, bq, bk)
-        # the index scores' own logsumexp over the set, and the set's size
-        mi = jnp.maximum(mi_ref[...], jnp.max(
-            jnp.where(live, score, MASK), axis=1, keepdims=True))
-        li_ref[...] = (li_ref[...] * jnp.exp(mi_ref[...] - mi) + jnp.sum(
-            jnp.where(live, jnp.exp(score - mi), 0.0), axis=1, keepdims=True))
-        mi_ref[...] = mi
-        n_ref[...] += jnp.sum(live.astype(jnp.float32), axis=1,
-                              keepdims=True)
+        live = _tile_set(bits_ref, j)
         h = q_ref.shape[3]
         for g in range(k_ref.shape[1]):
             heads = slice(g * group, (g + 1) * group)
@@ -286,16 +339,16 @@ def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, 
     def _():
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])
-        lse_i_ref[0] = mi_ref[...] + jnp.log(li_ref[...])
-        kept_ref[0] = n_ref[...]
 
 
-def _specs(H: int, Hkv: int, J: int, h: int, c: int, bq: int, bk: int,
-           rows_outer: bool):
+def _specs(H: int, Hkv: int, h: int, bq: int, bk: int, rows_outer: bool,
+           index=None):
     """The block specs the walks share, for a grid (b, query block, key
-    block) (`rows_outer`) or (b, key block, query block). A tile that
-    crosses nothing names the block already there, so nothing is fetched
-    for it."""
+    block) (`rows_outer`) or (b, key block, query block); with `index` (J,
+    c), the index tensors' too. A tile that crosses nothing names the
+    blocks already there, so nothing is fetched for it; the set's words are
+    a row block's of the key tile's plane, which a walk with the rows
+    outside changes only from one plane to the next."""
     if rows_outer:
         qb = lambda bi, i, j: i
         kb = lambda bi, i, j: jnp.minimum(j, (i * bq + bq - 1) // bk)
@@ -306,50 +359,48 @@ def _specs(H: int, Hkv: int, J: int, h: int, c: int, bq: int, bk: int,
         (1, heads, bq, width), lambda *g: (g[0], 0, qb(*g), 0))
     of_k = lambda width: pl.BlockSpec(
         (1, Hkv, bk, width), lambda *g: (g[0], 0, kb(*g), 0))
-    return {
-        "q": of_q(h, H), "k": of_k(h), "v": of_k(h),
-        "qi": of_q(c, J), "w": of_q(1, J), "row_h": of_q(1, H),
-        "ki": pl.BlockSpec((1, bk, c), lambda *g: (g[0], kb(*g), 0)),
+    sp = {
+        "q": of_q(h, H), "k": of_k(h), "v": of_k(h), "row_h": of_q(1, H),
+        "bits": pl.BlockSpec((1, 1, bq, bk), lambda *g: (
+            g[0], kb(*g) // WORD, qb(*g), 0)),
         "row": pl.BlockSpec((1, bq, 1), lambda *g: (g[0], qb(*g), 0)),
     }
+    if index is not None:
+        J, c = index
+        sp.update(qi=of_q(c, J), w=of_q(1, J), ki=pl.BlockSpec(
+            (1, bk, c), lambda *g: (g[0], kb(*g), 0)))
+    return sp
 
 
-def _shapes(q, k, q_idx, bq: int, bk: int):
+def _shapes(q, k, bq: int, bk: int):
     b, H, t, h = q.shape
-    Hkv, (J, c) = k.shape[1], (q_idx.shape[1], q_idx.shape[3])
     assert t % bq == 0 and t % bk == 0, (t, bq, bk)
-    return b, H, Hkv, t, h, J, c
+    return b, H, k.shape[1], t, h
 
 
-def fwd_call(q, k, v, q_idx, k_idx, w, tau, cut, *, bq: int, bk: int,
-             interpret: bool):
-    """(o (b, H, t, h), lse (b, H, t, 1), the index scores' logsumexp over
-    a row's set (b, t, 1), the keys a row kept (b, t, 1)), the last three
-    float32."""
-    b, H, Hkv, t, h, J, c = _shapes(q, k, q_idx, bq, bk)
-    sp = _specs(H, Hkv, J, h, c, bq, bk, rows_outer=True)
+def fwd_call(q, k, v, bits, *, bq: int, bk: int, interpret: bool):
+    """(o (b, H, t, h), lse (b, H, t, 1) float32) of the attention over the
+    rows' sets `bits` (`select_call`'s)."""
+    b, H, Hkv, t, h = _shapes(q, k, bq, bk)
+    sp = _specs(H, Hkv, h, bq, bk, rows_outer=True)
     kernel = _kernel(_fwd_kernel, 3, q, interpret, scale=1.0 / math.sqrt(h),
                      bq=bq, bk=bk, group=H // Hkv)
     f32 = jnp.float32
     return pl.pallas_call(
         kernel,
         grid=(b, t // bq, t // bk),
-        in_specs=[sp["q"], sp["k"], sp["v"], sp["qi"], sp["ki"], sp["w"],
-                  sp["row"], sp["row"]],
-        out_specs=[sp["q"], sp["row_h"], sp["row"], sp["row"]],
+        in_specs=[sp["q"], sp["k"], sp["v"], sp["bits"]],
+        out_specs=[sp["q"], sp["row_h"]],
         out_shape=[_out_struct(q.shape, q.dtype, q),
-                   _out_struct((b, H, t, 1), f32, q),
-                   _out_struct((b, t, 1), f32, q),
-                   _out_struct((b, t, 1), f32, q)],
+                   _out_struct((b, H, t, 1), f32, q)],
         scratch_shapes=[pltpu.VMEM((H, bq, 1), f32), pltpu.VMEM((H, bq, 1), f32),
-                        pltpu.VMEM((H, bq, h), f32), pltpu.VMEM((bq, 1), f32),
-                        pltpu.VMEM((bq, 1), f32), pltpu.VMEM((bq, 1), f32)],
+                        pltpu.VMEM((H, bq, h), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="dsa_flash_fwd",
-    )(q, k, v, q_idx, k_idx, w, tau, cut)
+    )(q, k, v, bits)
 
 
 def _p_ds(g: int, group: int, live, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -368,8 +419,8 @@ def _p_ds(g: int, group: int, live, q_ref, k_ref, v_ref, do_ref, lse_ref,
     return q, do, flat(p), flat(ds)
 
 
-def _dq_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref,
-               do_ref, lse_ref, delta_ref, dq_ref, acc_ref,
+def _dq_kernel(ids, last, q_ref, k_ref, v_ref, bits_ref, do_ref, lse_ref,
+               delta_ref, dq_ref, acc_ref,
                *, scale: float, bq: int, bk: int, group: int):
     _, i, j = ids
 
@@ -379,8 +430,7 @@ def _dq_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, c
 
     @pl.when(_crosses(i, j, bq, bk))
     def _():
-        *_, live = _tile_live(i, j, qi_ref, ki_ref, w_ref, tau_ref, cut_ref,
-                              bq, bk)
+        live = _tile_set(bits_ref, j)
         for g in range(k_ref.shape[1]):
             *_, ds = _p_ds(g, group, live, q_ref, k_ref, v_ref, do_ref,
                            lse_ref, delta_ref, scale, bq, bk)
@@ -393,8 +443,8 @@ def _dq_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, c
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, cut_ref,
-                do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+def _dkv_kernel(ids, last, q_ref, k_ref, v_ref, bits_ref, do_ref, lse_ref,
+                delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
                 *, scale: float, bq: int, bk: int, group: int):
     _, j, i = ids
 
@@ -405,8 +455,7 @@ def _dkv_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, 
 
     @pl.when(_crosses(i, j, bq, bk))
     def _():
-        *_, live = _tile_live(i, j, qi_ref, ki_ref, w_ref, tau_ref, cut_ref,
-                              bq, bk)
+        live = _tile_set(bits_ref, j)
         for g in range(k_ref.shape[1]):
             q, do, p, ds = _p_ds(g, group, live, q_ref, k_ref, v_ref, do_ref,
                                  lse_ref, delta_ref, scale, bq, bk)
@@ -419,20 +468,20 @@ def _dkv_kernel(ids, last, q_ref, k_ref, v_ref, qi_ref, ki_ref, w_ref, tau_ref, 
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def bwd_calls(q, k, v, q_idx, k_idx, w, tau, cut, do, lse, delta, *,
-              bq: int, bk: int, interpret: bool):
-    """(dq, dk, dv) of the attention over the chosen keys; `delta` (b, H,
-    t, 1) float32 is `sum(do * o)` a row."""
-    b, H, Hkv, t, h, J, c = _shapes(q, k, q_idx, bq, bk)
+def bwd_calls(q, k, v, bits, do, lse, delta, *, bq: int, bk: int,
+              interpret: bool):
+    """(dq, dk, dv) of the attention over the rows' sets `bits`; `delta`
+    (b, H, t, 1) float32 is `sum(do * o)` a row."""
+    b, H, Hkv, t, h = _shapes(q, k, bq, bk)
     common = dict(scale=1.0 / math.sqrt(h), bq=bq, bk=bk, group=H // Hkv)
     f32 = jnp.float32
-    args = (q, k, v, q_idx, k_idx, w, tau, cut, do, lse, delta)
+    args = (q, k, v, bits, do, lse, delta)
 
     def ins(sp):
-        return [sp["q"], sp["k"], sp["v"], sp["qi"], sp["ki"], sp["w"],
-                sp["row"], sp["row"], sp["q"], sp["row_h"], sp["row_h"]]
+        return [sp["q"], sp["k"], sp["v"], sp["bits"], sp["q"], sp["row_h"],
+                sp["row_h"]]
 
-    sp = _specs(H, Hkv, J, h, c, bq, bk, rows_outer=True)
+    sp = _specs(H, Hkv, h, bq, bk, rows_outer=True)
     dq = pl.pallas_call(
         _kernel(_dq_kernel, 3, q, interpret, **common),
         grid=(b, t // bq, t // bk),
@@ -445,7 +494,7 @@ def bwd_calls(q, k, v, q_idx, k_idx, w, tau, cut, do, lse, delta, *,
         interpret=interpret,
         name="dsa_flash_bwd_dq",
     )(*args)
-    sp = _specs(H, Hkv, J, h, c, bq, bk, rows_outer=False)
+    sp = _specs(H, Hkv, h, bq, bk, rows_outer=False)
     dk, dv = pl.pallas_call(
         _kernel(_dkv_kernel, 3, q, interpret, **common),
         grid=(b, t // bk, t // bq),
@@ -486,8 +535,13 @@ def _loss_kernel(ids, last, q_ref, k_ref, lse_ref, qi_ref, ki_ref, w_ref, tau_re
 
     @pl.when(_crosses(i, j, bq, bk))
     def _():
-        z, score, live = _tile_live(i, j, qi_ref, ki_ref, w_ref, tau_ref,
-                                    cut_ref, bq, bk)
+        z, score = _index_tile(qi_ref[0].reshape(J * bq, c), ki_ref[0],
+                               w_ref[0].reshape(J * bq, 1), bq)
+        # (the selection's rule on the same numbers: the walks' bits, which
+        # read 0.5 ms a layer slower here than these compares, PR 73)
+        rows = i * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+        cols = j * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        live = _live(score, tau_ref[0], cut_ref[0], rows, cols)
         # the heads' probabilities, summed: the indexer's target
         target = jnp.zeros((bq, bk), jnp.float32)
         for g in range(k_ref.shape[1]):
@@ -527,9 +581,11 @@ def loss_call(q, k, lse, q_idx, k_idx, w, tau, cut, lse_i, *, bq: int,
               bk: int, interpret: bool):
     """(a row's KL (b, t, 1), the entropy of its softmax_S(I) (b, t, 1),
     and the gradients of the SUM of the rows' KL: d qI (b, J, t, c) in qI's
-    dtype, d w (b, J, t, 1) float32, d kI (b, t, c) float32)."""
-    b, H, Hkv, t, h, J, c = _shapes(q, k, q_idx, bq, bk)
-    sp = _specs(H, Hkv, J, h, c, bq, bk, rows_outer=True)
+    dtype, d w (b, J, t, 1) float32, d kI (b, t, c) float32), under the
+    selection's (tau, cut) and with its `lse_i`."""
+    b, H, Hkv, t, h = _shapes(q, k, bq, bk)
+    J, c = q_idx.shape[1], q_idx.shape[3]
+    sp = _specs(H, Hkv, h, bq, bk, rows_outer=True, index=(J, c))
     f32 = jnp.float32
     kernel = _kernel(_loss_kernel, 3, q, interpret, scale=1.0 / math.sqrt(h),
                      bq=bq, bk=bk, group=H // Hkv)
@@ -558,30 +614,30 @@ def loss_call(q, k, lse, q_idx, k_idx, w, tau, cut, lse_i, *, bq: int,
 
 # ------------------------------------------------------------------ a probe
 
-def _probe_kernel(ids, _, qi_ref, ki_ref, w_ref, tau_ref, cut_ref, score_ref,
-                  live_ref, *, bq: int, bk: int):
-    _, score, live = _tile_live(ids[1], ids[2], qi_ref,
-                                ki_ref, w_ref, tau_ref, cut_ref, bq, bk)
-    score_ref[0] = score
-    live_ref[0] = live.astype(jnp.int8)
+def _probe_kernel(ids, _, qi_ref, ki_ref, w_ref, bits_ref, score_ref,
+                  live_ref, *, bq: int):
+    J, c = qi_ref.shape[1], qi_ref.shape[3]
+    _, score_ref[0] = _index_tile(qi_ref[0].reshape(J * bq, c), ki_ref[0],
+                                  w_ref[0].reshape(J * bq, 1), bq)
+    live_ref[0] = _tile_set(bits_ref, ids[2]).astype(jnp.int8)
 
 
-def probe_call(q_idx, k_idx, w, tau, cut, *, bq: int, bk: int,
-               interpret: bool):
-    """What the walks see, written out (a check's probe, in no step): (I
-    (b, t, t) float32, every pair; is the pair in its row's set (b, t, t)
-    int8)."""
+def probe_call(q_idx, k_idx, w, bits, *, bq: int, bk: int, interpret: bool):
+    """What the kernels see, written out (a check's probe, in no step): (I
+    (b, t, t) float32, every pair, as `_index_tile` makes it; is the pair in
+    its row's set (b, t, t) int8, as the walks read it out of `bits`)."""
     b, J, t, c = q_idx.shape
     of_q = lambda width: pl.BlockSpec((1, J, bq, width),
                                       lambda bi, i, j: (bi, 0, i, 0))
-    row = pl.BlockSpec((1, bq, 1), lambda bi, i, j: (bi, i, 0))
     tile = pl.BlockSpec((1, bq, bk), lambda bi, i, j: (bi, i, j))
     return pl.pallas_call(
-        _kernel(_probe_kernel, 3, q_idx, interpret, bq=bq, bk=bk),
+        _kernel(_probe_kernel, 3, q_idx, interpret, bq=bq),
         grid=(b, t // bq, t // bk),
         in_specs=[of_q(c), pl.BlockSpec((1, bk, c),
                                         lambda bi, i, j: (bi, j, 0)),
-                  of_q(1), row, row],
+                  of_q(1),
+                  pl.BlockSpec((1, 1, bq, bk),
+                               lambda bi, i, j: (bi, j // WORD, i, 0))],
         out_specs=[tile, tile],
         out_shape=[_out_struct((b, t, t), jnp.float32, q_idx),
                    _out_struct((b, t, t), jnp.int8, q_idx)],
@@ -590,7 +646,7 @@ def probe_call(q_idx, k_idx, w, tau, cut, *, bq: int, bk: int,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="dsa_probe",
-    )(q_idx, k_idx, w, tau, cut)
+    )(q_idx, k_idx, w, bits)
 
 
 def require_tpu(interpret: bool) -> None:

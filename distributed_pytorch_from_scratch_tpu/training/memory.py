@@ -91,6 +91,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                state_bytes_per_param: float = 16.0,
                grad_bytes_per_param: float = 4.0,
                layer_extra_elems_per_token: float = 0.0,
+               flash_lse_bytes_per_pair: float = 0.0,
                head_rows_share: float = 1.0,
                residual_streams: int = 1,
                tagged_layers: Optional[Dict[str, float]] = None,
@@ -114,7 +115,9 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               residuals at their logical sizes (the chip's count: the stack
               of `flash_out` is not kept in the kernel's layout, which pads
               a head of 64 to the 128 lanes, and `flash_lse` is named as
-              (b h, t) float32, t on the lanes), each over the layers that
+              (b h, t) float32, t on the lanes; a family whose mask is data
+              keeps `flash_lse_bytes_per_pair` a (row, key) pair of a
+              sequence under that name too), each over the layers that
               tag it: `tagged_layers` (`DecoderStack.tagged_layers`: a drawn
               family's MLP names are its dense layers', the flash names its
               attention layers'; None: all L), `flash_out` at `v_head_dim`
@@ -159,7 +162,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     h_local = heads / tp
     names = {
         "flash_out": tok * h_local * (v_head_dim or head_dim) * dtype_bytes,
-        "flash_lse": tok * h_local * 4,
+        "flash_lse": tok * (h_local * 4 + t * flash_lse_bytes_per_pair),
         "q_proj": q_w, "k_proj": kv_w, "v_proj": kv_w,
         "attn_proj": wide if tp > 1 else 0.0,   # named only past a reduce
         "ffn_fc": f_w if ffn_inputs == 1 else 0.0,
@@ -387,6 +390,7 @@ def traced_step_bytes(model, param_count: int, layer_param_count: int,
         ffn_inputs=model.ffn_inputs,
         sequence_parallel=model.tp_layout(t)[0],
         layer_extra_elems_per_token=model.layer_extra_elems_per_token,
+        flash_lse_bytes_per_pair=model.flash_lse_bytes_per_pair,
         head_rows_share=model.head_rows_share,
         residual_streams=model.residual_streams,
         tagged_layers={name: n // pp

@@ -15,7 +15,10 @@ so the choice is live, some rows with fewer keys than the budget.
   ZERO gradient at q, k, v, the layer's input and every other leaf;
 * the kernels (the interpreter) against the XLA text at several shapes:
   several tiles each way, groups of 1 to 3, two sequences, ties at the
-  threshold (the ReLU's zeros) that the budget cuts by index;
+  threshold (the ReLU's zeros) that the budget cuts by index; the set the
+  selection WRITES, a bit a pair, is the text's for every pair, at one
+  plane of words, at two and at a second plane part full, and the walk's
+  instant says what was built at the cell's shape;
 * with top-k >= T the layer is cell 8's attention under `CAUSAL`;
 * the eight shares of the expert layer add up to the uncut reference's;
 * what the family does not run is refused with a message;
@@ -198,11 +201,24 @@ def test_each_loss_reaches_its_own_leaves_and_no_other_exactly(
 
 # ---- the kernels against the text ----
 
+def unpacked(bits, bk):
+    """(b, t, t) bool of `bits` (b, planes, t, bk) int32: the pair (row, s)
+    is bit `(s // bk) mod 32` of word `[s // (32 bk), row, s mod bk]`
+    (ops/pallas/dsa_attention.py's docstring, written out in numpy)."""
+    bits = np.asarray(bits)
+    t = bits.shape[2]
+    rows, cols = np.arange(t)[:, None], np.arange(t)[None, :]
+    words = bits[:, cols // (32 * bk), rows, cols % bk]
+    return ((words >> ((cols // bk) % 32)) & 1).astype(bool)
+
+
 @pytest.mark.parametrize("b,H,Hkv,t,J,top_k,bq,bk", [
     (2, 4, 2, 64, 2, 8, 16, 32),        # ties: two index heads' zeros
     (1, 6, 2, 96, 3, 40, 32, 32),       # a group of 3, square tiles
     (1, 2, 2, 64, 4, 64, 64, 16),       # top-k = T: nothing is dropped
     (1, 4, 1, 128, 1, 5, 8, 128),       # one index head, one key tile
+    (1, 2, 1, 512, 2, 24, 32, 8),       # 64 key tiles: two planes of bits
+    (1, 2, 2, 384, 1, 40, 64, 8),       # 48: the second plane part full
 ])
 def test_the_kernels_equal_the_text(b, H, Hkv, t, J, top_k, bq, bk,
                                     monkeypatch):
@@ -238,6 +254,28 @@ def test_the_kernels_equal_the_text(b, H, Hkv, t, J, top_k, bq, bk,
     for a, k in zip(g_x, g_k):
         np.testing.assert_allclose(k, a, atol=1e-5 * max(
             float(jnp.abs(a).max()), 1e-3))
+    # the set the selection writes, a bit a pair, IS the text's, every
+    # pair; it is what the loss walk's rule makes of the kernels' own score
+    # under the kernel's (tau, cut), and what the walks read out of it; and
+    # the two numbers the selection takes over the set are the text's
+    q_idx, k_idx, w = args[3:]
+    blocks = dict(bq=bq, bk=bk, interpret=True)
+    w4 = index_select._rows_last(w)
+    tau, cut, _, bits, lse_i, kept = dsa_attention.select_call(
+        q_idx, k_idx, w4, top_k, **blocks)
+    planes = dsa_attention.bit_planes(t, bk)
+    assert planes == -(-(t // bk) // 32) and bits.shape == (b, planes, t, bk)
+    score = index_select.index_scores(q_idx, k_idx, w)
+    keep = index_select.live(score, *index_select.select(score, top_k)[:2])
+    np.testing.assert_array_equal(unpacked(bits, bk), keep)
+    made, read = dsa_attention.probe_call(q_idx, k_idx, w4, bits, **blocks)
+    np.testing.assert_array_equal(read.astype(bool), keep)
+    np.testing.assert_array_equal(
+        index_select.live(made, tau[..., 0], cut[..., 0]), keep)
+    np.testing.assert_array_equal(kept[..., 0], keep.sum(-1))
+    np.testing.assert_allclose(
+        lse_i[..., 0], jax.nn.logsumexp(jnp.where(keep, score, -jnp.inf),
+                                        axis=-1), rtol=2e-6)
 
 
 def test_equal_scores_are_cut_by_index_and_counted():
@@ -254,6 +292,11 @@ def test_equal_scores_are_cut_by_index_and_counted():
     _, s_x = index_select.selected_attention_xla(q, q, q, *zeros, top_k)
     _, s_k = index_select._selected_flash(q, q, q, *zeros, top_k, 8, 16,
                                           True)
+    # the bits of a row whose ties straddle the budget: the earlier keys
+    _, _, _, bits, _, _ = dsa_attention.select_call(
+        zeros[0], zeros[1], index_select._rows_last(zeros[2]), top_k, bq=8,
+        bk=16, interpret=True)
+    np.testing.assert_array_equal(unpacked(bits, 16)[0], want.astype(bool))
     for s in (s_x, s_k):
         assert float(s["dsa_tau_ties"]) == t - top_k
         assert float(s["dsa_kept"]) == kept_pairs(t, top_k)
@@ -261,6 +304,36 @@ def test_equal_scores_are_cut_by_index_and_counted():
         sizes = np.minimum(np.arange(t) + 1, top_k)
         assert float(s["dsa_index_entropy"]) == pytest.approx(
             np.log(sizes).sum(), rel=1e-5)
+
+
+def test_the_walk_says_on_the_programs_tracer_what_it_built(tmp_path):
+    """At trace time `_selected_flash_fwd` leaves ONE instant `dsa_walk`
+    (as `flash_attention._bwd_call`'s `flash_bwd_walk`): the mask the
+    walks read, its planes and bytes a layer, and how many kernels of a
+    layer still make the index tile. At the cell's shape: one plane, 32
+    MiB, the selection and the loss walk."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    b, H, Hkv, t, h, J, c = 1, 32, 4, 16384, 128, 16, 64
+    arg = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype)
+    tracer = SpanTracer(str(tmp_path))
+    try:
+        o, sums = jax.eval_shape(
+            lambda *a: index_select.selected_attention(
+                *a, 2048, impl="flash_interpret"),
+            arg(b, H, t, h), arg(b, Hkv, t, h), arg(b, Hkv, t, h),
+            arg(b, J, t, c), arg(b, t, c), arg(b, t, J, dtype=jnp.float32))
+    finally:
+        tracer.close()
+    assert o.shape == (b, H, t, h) and set(sums) == set(index_select.SUMS)
+    events = [json.loads(line)["args"] for line in
+              open(tmp_path / "trace.jsonl")
+              if json.loads(line)["name"] == "dsa_walk"]
+    assert events == [{"mask": "bits", "planes": 1,
+                       "bits_bytes": 33_554_432, "index_tiles_a_layer": 2,
+                       "blocks": [128, 512], "t": 16384}]
 
 
 # ---- nothing dropped is the causal layer ----

@@ -780,12 +780,14 @@ def test_the_selection_kernels_compile_at_the_dsa_cells_shape(
     sixteenth cell's shape (`keye-vl-2.0-30b-a3b.train-ep8share-b1-t16384`:
     one sequence of 16,384 rows, 32 query heads over 4 key-value heads of
     128, 16 index heads of 64, bf16, blocks of 128 x 512): the selection
-    with a row block's 16,384 keys in VMEM (8 MiB of int32), the three
-    attention walks with every head of a row block a grid step, and the
-    indexer's loss walk with the index key's gradient (16,384 x 64 float32)
-    resident. The calls carry the names the benchmark's scopes read, and
-    none has the 3 or 6 operands by which the static-mask flash metrics
-    find their calls."""
+    with a row block's 16,384 keys in VMEM (8 MiB of int32) and the block's
+    set written as one plane of 128 x 512 words, the three attention walks
+    with every head of a row block a grid step and that block of words for
+    their mask (no index tensor: PR 73), and the indexer's loss walk, which
+    still makes its index tile, with the index key's gradient (16,384 x 64
+    float32) resident. The calls
+    carry the names the benchmark's scopes read, and none has the 3 or 6
+    operands by which the static-mask flash metrics find their calls."""
     from jax.sharding import SingleDeviceSharding
     from distributed_pytorch_from_scratch_tpu.ops.pallas import (
         dsa_attention as K)
@@ -800,23 +802,29 @@ def test_the_selection_kernels_compile_at_the_dsa_cells_shape(
     lse = arg(b, H, t, 1, dtype=f32)
     blocks = dict(bq=K.BLOCK_Q, bk=K.BLOCK_K, interpret=False)
     assert (K.BLOCK_Q, K.BLOCK_K) == (128, 512)
+    assert K.bit_planes(t, K.BLOCK_K) == 1
+    bits = arg(b, 1, t, K.BLOCK_K, dtype=i32)
+    select = jax.jit(lambda *a: K.select_call(*a, 2048, **blocks))
+    assert [(o.shape, o.dtype) for o in jax.eval_shape(select, qi, ki, w)][
+        3:] == [(bits.shape, bits.dtype), ((b, t, 1), f32), ((b, t, 1), f32)]
     text = ""
     for call, args in (
-            (lambda *a: K.select_call(*a, 2048, **blocks), (qi, ki, w)),
-            (lambda *a: K.fwd_call(*a, **blocks),
-             (q, k, k, qi, ki, w, tau, cut)),
+            (select, (qi, ki, w)),
+            (lambda *a: K.fwd_call(*a, **blocks), (q, k, k, bits)),
             (lambda *a: K.bwd_calls(*a, **blocks),
-             (q, k, k, qi, ki, w, tau, cut, q, lse, lse)),
+             (q, k, k, bits, q, lse, lse)),
             (lambda *a: K.loss_call(*a, **blocks),
              (q, k, lse, qi, ki, w, tau, cut, tau))):
         text += jax.jit(call).lower(*args).compile().as_text()
     calls = re.findall(
         r"%([\w.\-]+) = [^\n]*? custom-call\(([^)]*)\), "
         r'custom_call_target="tpu_custom_call"', text)
-    assert sorted((name.split(".")[0], operands.count("%"))
-                  for name, operands in calls) == [
-        ("dsa_flash_bwd_dkv", 11), ("dsa_flash_bwd_dq", 11),
-        ("dsa_flash_fwd", 8), ("dsa_index_loss", 9), ("dsa_select", 4)]
+    found = sorted((name.split(".")[0], operands.count("%"))
+                   for name, operands in calls)
+    assert found == [
+        ("dsa_flash_bwd_dkv", 7), ("dsa_flash_bwd_dq", 7),
+        ("dsa_flash_fwd", 4), ("dsa_index_loss", 9), ("dsa_select", 4)]
+    assert not {3, 6} & {operands for _, operands in found}
 
 
 # ---- the estimate against the chip's own counts, cell by cell (PR 62) ----
@@ -862,13 +870,15 @@ CHIP_GIB = {
     # layer's names kept. The floor counted 12.977 and the estimate reads
     # 3.2% under it there: models/ssm_dense.py says why it is not listed)
     "granite-4.0-h-micro.train-pp4stage-b1-t4096": {"dots": 13.445},
-    # (PR 72's readings, what the chip held when the window ended, buffers
-    # and the step's reservation: `dots` on the untuned estimate, 14.54 for
-    # 14.970 read, which is over the margin; `flash` with the family's last
-    # term set from that. The (t, t) index score is in no term: the
-    # kernels re-make it a tile and a row's set is two numbers)
-    "keye-vl-2.0-30b-a3b.train-ep8share-b1-t16384": {"dots": 14.970,
-                                                     "flash": 14.071},
+    # (PR 73's readings, what the chip held when the window ended, buffers
+    # and the step's reservation, `dots` with the rung named in a scratch
+    # wrapper: 14.970 and 14.071 in PR 72. The rows' sets are kept as one
+    # bit a pair with the lse, 32 MiB a layer, and the backward's walks hold
+    # no index tensor any more, which took more off the window than the
+    # bits put on; the family's last term is set from these two. The
+    # (t, t) index score is in no term: the kernels make it a tile)
+    "keye-vl-2.0-30b-a3b.train-ep8share-b1-t16384": {"dots": 14.917,
+                                                     "flash": 13.861},
 }
 SNAPSHOTS = ("gpt2-medium.train-ckpt-every40",)
 
