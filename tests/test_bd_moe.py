@@ -25,16 +25,16 @@ under a declared attention mask. CPU, tiny sizes, float32.
 
 import dataclasses
 import hashlib
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss,
+                           lowered_text, outputs_and_grads, token_file)
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    BdMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    BdMoEConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.bd_moe import (
     BlockDiffusionMoETransformer, block_diffusion_noise)
@@ -47,7 +47,6 @@ from distributed_pytorch_from_scratch_tpu.ops.attention import (
 from distributed_pytorch_from_scratch_tpu.ops.pallas import (
     flash_attention as fa_mod)
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
 from distributed_pytorch_from_scratch_tpu.training.optim import (
@@ -56,21 +55,18 @@ from distributed_pytorch_from_scratch_tpu.training.train_step import (
     build_train_step)
 
 
-def tiny(**facts):
-    cfg = model_preset("tiny-bd-moe")
-    return dataclasses.replace(
-        cfg, bd_moe=dataclasses.replace(cfg.bd_moe, **facts))
+R = Recipe("bd_moe", vanilla_loss)
+tiny, on_mesh = R.tiny, R.on_mesh
 
 
 def batch(cfg, b=2, L=32, seed=0):
+    """(its own: a batch is clean rows and positions, no targets: the family
+    makes its targets of the draw, so the recipe's reference and program,
+    which take (ids, targets, positions), do not serve and the comparison
+    below hands both sides the draw itself)"""
     rng = np.random.default_rng(seed)
     x0 = rng.integers(3, cfg.vocab_size, (b, L)).astype(np.int32)
     return x0, np.tile(np.arange(L, dtype=np.int32), (b, 1))
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model("bd_moe", cfg, tp_size=tp, **kw)
 
 
 def draw(cfg, x0, seed=7, step=3):
@@ -87,7 +83,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
     Leaves to 1e-5 of their largest entry."""
     cfg = tiny(experts_held=4, expert_offset=2)
     mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params = model.init(jax.random.key(3))
+    params = R.params(cfg)
     x0, pos = batch(cfg)
     xt, m, p = draw(cfg, x0)
     with jax.default_matmul_precision("highest"):
@@ -97,13 +93,8 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
         got, got_g = jax.jit(jax.value_and_grad(
             lambda pr: given(pr, x0, pos, xt, m, p)))(
                 jax.device_put(params, model.shardings(mesh)))
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 15
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    hold_loss(want, got)
+    assert len(hold_leaves(want_g, got_g, 1e-5)[0]) == 15
     # heads x width is not the model's width; no bias, no shared expert
     assert params["layers"]["wq"]["weight"].shape == (2, 64, 128)
     assert params["layers"]["wk"]["weight"].shape == (2, 64, 64)
@@ -282,13 +273,17 @@ def test_the_kernels_under_the_declared_mask_equal_the_dense_path(
     kernel = lambda q, k, v: fa_mod.flash_attention(
         q, k, v, block, block, block, block, interpret=True, mask=mask)
     dense = lambda q, k, v: masked_attention_xla(q, k, v, mask)
-    np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
+    # (a side's output and its three gradients are one compiled program)
+    weigh = lambda o: jnp.sum(o * w)
+    (o,), got = outputs_and_grads(lambda *a: (kernel(*a),), weigh, q, k, v,
+                                  precision=None)
+    (o_dense,), want = outputs_and_grads(lambda *a: (dense(*a),), weigh, q, k,
+                                         v, precision=None)
+    np.testing.assert_allclose(o, o_dense, atol=2e-5)
     assert flash_bwd_calls(kernel, q, k, v) == {
         "row": [("flash_bwd", [None] * 9)], "once": [("flash_bwd", [1] * 9)],
         "grid": [("flash_bwd_dq", [None] * 7), ("flash_bwd_dkv", [None] * 8)],
     }[walk if L > block else "row"]
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * w), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-4)
 
@@ -418,13 +413,6 @@ def test_the_loss_weights_are_one_over_the_level():
 
 # ---- the share test ----
 
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
-
 def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Eight jobs hold four experts each of one layer's 32. Their routed
     parts (there is no shared expert to count once) are the layer a job
@@ -524,10 +512,8 @@ def test_the_train_step_trains_and_counts_rows_and_masked_positions():
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
     import json
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "bd_moe", "--model", "tiny-bd-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
@@ -598,17 +584,7 @@ def test_the_mask_declaration_left_the_other_families_text_alone(family):
     pairs as before); `llama` and `gpt2`, which run no expert
     layer, stand as PR 40 left them."""
     preset, digest = LOWERED_BEFORE[family]
-    cfg = model_preset(preset)
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct((4, 256), np.int32)
-    kw = dict(with_counters=True) if cfg.family_facts else {}
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, **kw)
-    text = step.lower(params, opt, ids, ids, ids).as_text()
-    text = re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
+    text = lowered_text(family, model_preset(preset))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 

@@ -18,11 +18,15 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _smoke(*flags):
-    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     # one smoke at a time: every run rebuilds the same .chip_smoke/ work dir
     # (parallel test workers would pull it out from under each other)
     lock_path = os.path.join(tempfile.gettempdir(), "chip_smoke_test.lock")
-    with open(lock_path, "w") as lock:
+    # a compile cache of its own: the preflight failed once (PR 55's tree)
+    # on a CPU executable cached in the `.jax_cache/` six workers share
+    with open(lock_path, "w") as lock, \
+            tempfile.TemporaryDirectory() as cache:
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
+               "JAX_COMPILATION_CACHE_DIR": cache}
         fcntl.flock(lock, fcntl.LOCK_EX)
         return subprocess.run([sys.executable, "chip_smoke.py", *flags],
                               capture_output=True, text=True, timeout=900,

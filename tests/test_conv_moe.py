@@ -25,16 +25,17 @@ no shared expert over held experts, a tied head. CPU, tiny sizes, float32.
 
 import dataclasses
 import hashlib
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss,
+                           lowered_text, mesh_of, token_file)
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    ConvMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    ConvMoEConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.conv_moe import (
     ConvMoETransformer, layer_blocks, layers_in_order)
@@ -48,7 +49,6 @@ from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
     flash_attention)
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
 from distributed_pytorch_from_scratch_tpu.parallel.shortconv import ShortConv
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
 from distributed_pytorch_from_scratch_tpu.training.optim import (
@@ -60,34 +60,9 @@ PUBLISHED = (("conv",) * 2 + ("full_attention", "conv", "conv", "conv") * 4
              + ("full_attention", "conv", "conv") * 2)
 
 
-def tiny(**facts):
-    cfg = model_preset("tiny-conv-moe")
-    return dataclasses.replace(
-        cfg, conv_moe=dataclasses.replace(cfg.conv_moe, **facts))
-
-
-def batch(cfg, b=2, t=128, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model("conv_moe", cfg, tp_size=tp, **kw)
-
-
-def reference_and_program(cfg, tp=1, impl="xla", seed=3):
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params = model.init(jax.random.key(seed))
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    return model, params, want, got
+# the family's own: its reference, and sequences of 128 from id 0 up
+R = Recipe("conv_moe", vanilla_loss, t=128, low=0)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 # ---- the program against the plain reference ----
@@ -99,18 +74,15 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
     twelve layers LOOPED (the reference), on a job that holds experts 2..5
     of 8. Leaves to 1e-5 of their largest entry."""
     cfg = tiny(experts_held=4, expert_offset=2)
-    model, params, (want, want_g), (got, got_g) = reference_and_program(
-        cfg, tp, impl)
+    # (the parameters and the reference are one for the three layouts)
+    params, (want, want_g) = R.reference(cfg)
+    got, got_g = R.program(cfg, tp=tp, attn_impl=impl)
+    model = build_model("conv_moe", cfg, tp_size=tp)
     assert model._pattern == (
         "dense_layers", (("attn_layers_0", 1), ("conv_layers_0", 2)),
         (("attn_layers_1", 1), ("conv_layers_1", 1)))
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 56
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    hold_loss(want, got)
+    assert len(hold_leaves(want_g, got_g, 1e-5)[0]) == 56
     # the selection bias is a leaf no gradient reaches; there is no shared
     # expert and no head of its own
     bias = got_g["conv_layers_0"]["moe"]["bias"]
@@ -145,13 +117,13 @@ def test_the_published_depth_builds_and_runs_at_a_tiny_width():
         moe_top_k=2, conv_moe=ConvMoEConfig(
             layer_types=PUBLISHED, moe_intermediate_size=16,
             experts_held=2, expert_offset=4))
-    model, params, (want, want_g), (got, got_g) = reference_and_program(cfg)
+    params, (want, want_g) = R.reference(cfg)
+    got, got_g = R.program(cfg)
     assert params["attn_layers_0"]["wo"]["weight"].shape[:2] == (4, 1)
     assert params["conv_layers_0"]["conv"]["w_out"].shape[:2] == (4, 3)
     assert params["conv_layers_1"]["conv"]["w_out"].shape[:2] == (2, 2)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    for a, b in zip(jax.tree.leaves(want_g), jax.tree.leaves(got_g)):
-        assert np.max(np.abs(a - b)) <= 2e-5 * max(np.max(np.abs(a)), 1e-6)
+    hold_loss(want, got)
+    hold_leaves(want_g, got_g, 2e-5)
 
 
 # ---- the mixer against a loop over tokens ----
@@ -173,7 +145,7 @@ def mixer_by_tokens(p, x):
 
 
 def apply_on_one(module, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
     fn = jax.shard_map(lambda p, x: module.apply(p, x), mesh=mesh,
                        in_specs=(module.specs(), P()), out_specs=P())
     return fn(params, x)
@@ -288,13 +260,6 @@ def test_a_pattern_scanned_equals_the_same_layers_looped():
 
 
 # ---- the expert layer: shares, and no drop ----
-
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
 
 def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Four jobs hold eight experts each of one layer's 32. Their routed
@@ -433,17 +398,8 @@ def test_a_family_needs_its_own_facts_and_a_pattern_it_can_cut(cfg, message):
 
 def test_the_train_step_trains_and_counts_a_row_an_expert_layer():
     cfg = tiny(experts_held=4, expert_offset=2)
-    mesh, model = on_mesh(cfg, 1, attn_impl="xla")
-    params = model.init(jax.random.key(0))
-    opt = init_adam_state(params)
-    step = build_train_step(model, mesh, OptimizerConfig(lr=3e-3,
-                                                         warmup_steps=2),
-                            with_grad_norm=True, with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(8):
-        params, opt, (loss, norm, counters) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, _, counters), (_, model, *_) = R.train(
+        cfg, tp=1, steps=8, max_steps=20000, attn_impl="xla")
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
     assert counters["routed"].shape == (10, 8)       # ten expert layers
     assert counters["rows_here"].shape == (10,)
@@ -464,10 +420,8 @@ def test_the_train_step_trains_and_counts_a_row_an_expert_layer():
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
     import json
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "conv_moe", "--model", "tiny-conv-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
@@ -545,17 +499,9 @@ def test_the_pattern_declaration_left_the_other_families_text_alone(family):
     cfg = model_preset(preset)
     cfg = dataclasses.replace(cfg, **{facts: dataclasses.replace(
         getattr(cfg, facts), experts_held=1, expert_offset=3)})
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    moe = model._mods["moe"]
+    moe = build_model(family, cfg)._mods["moe"]
     assert moe.chunk_rows(4 * 256 * moe.top_k) < 4 * 256 * moe.top_k
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct((4, 256), np.int32)
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, with_counters=True)
-    text = step.lower(params, opt, ids, ids, ids).as_text()
-    text = re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
+    text = lowered_text(family, cfg)
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 

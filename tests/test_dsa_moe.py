@@ -32,10 +32,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, hold_leaves, hold_loss, mesh_of, token_file)
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    DsaMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    DsaMoEConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models import vanilla_dsa_moe
 from distributed_pytorch_from_scratch_tpu.models.dsa_moe import (
@@ -48,7 +49,6 @@ from distributed_pytorch_from_scratch_tpu.ops.attention import (
 from distributed_pytorch_from_scratch_tpu.ops.pallas import dsa_attention
 from distributed_pytorch_from_scratch_tpu.ops.rope import rope_angles
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     dsa_counters_summary, model_flops_per_step, moe_counters_summary)
 from distributed_pytorch_from_scratch_tpu.training.optim import (
@@ -59,22 +59,10 @@ from distributed_pytorch_from_scratch_tpu.training.train_step import (
 IMPLS = ("xla", "flash_interpret")
 
 
-def tiny(**facts):
-    cfg = model_preset("tiny-dsa-moe")
-    return dataclasses.replace(
-        cfg, dsa_moe=dataclasses.replace(cfg.dsa_moe, **facts))
-
-
-def batch(cfg, b=2, t=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(3, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    return (ids[:, :-1], ids[:, 1:],
-            np.tile(np.arange(t, dtype=np.int32), (b, 1)))
-
-
-def on_mesh(cfg, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    return mesh, build_model("dsa_moe", cfg, **kw)
+# the family's own: its reference's loss comes with its parts (the CE and a
+# layer's KL), and the program's side of them is its counters
+R = Recipe("dsa_moe", vanilla_parts)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 @pytest.fixture
@@ -92,17 +80,12 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(impl, small_blocks):
     """On a job that holds experts 2..5 of 8. Leaves to 1e-5 of their
     largest entry; the loss is the CE and both layers' KL."""
     cfg = tiny(experts_held=4, expert_offset=2)
-    mesh, model = on_mesh(cfg, attn_impl=impl)
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        (want, parts), want_g = jax.jit(jax.value_and_grad(
-            lambda pr: vanilla_parts(cfg, pr, ids, tgt, pos),
-            has_aux=True))(params)
-        loss = model.make_loss(mesh, with_counters=True)
-        (got, c), got_g = jax.jit(jax.value_and_grad(
-            lambda pr: loss(pr, ids, tgt, pos), has_aux=True))(params)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
+    # (the parameters and the reference are one for both; the program is
+    # built under this case's `small_blocks`, so it is not kept)
+    params, ((want, parts), want_g) = R.reference(cfg, has_aux=True)
+    (got, c), got_g = R.program(cfg, with_counters=True, cached=False,
+                                attn_impl=impl)
+    hold_loss(want, got)
     assert float(want) == pytest.approx(
         float(parts["ce"]) + float(parts["index_kl"].sum()), rel=1e-6)
     assert float(c["loss_main"]) == pytest.approx(float(parts["ce"]),
@@ -110,13 +93,8 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(impl, small_blocks):
     np.testing.assert_allclose(c["dsa_index_kl"] / c["dsa_rows"],
                                parts["index_kl"], rtol=1e-4)
     assert float(parts["index_kl"].min()) > 0.01        # the term is live
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 20
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a)) > 0, jax.tree_util.keystr(path)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    names, moved = hold_leaves(want_g, got_g, 1e-5)
+    assert len(names) == 20 and moved == names
     # a row keeps 16 of up to 64 keys; 15 rows a sequence see fewer
     np.testing.assert_array_equal(c["dsa_kept"], [2 * kept_pairs(64, 16)] * 2)
     assert kept_pairs(64, 16) == 136 + 48 * 16 < 64 * 65 // 2
@@ -363,7 +341,7 @@ def test_the_eight_shares_of_a_layer_add_up_to_the_uncut_references_layer():
     kw = dict(n_shared=0, score="softmax")
     p = SharedRoutedFFN(d, f, E, k, **kw).init(jax.random.key(0))
     x = jax.random.normal(jax.random.key(1), (2, 64, d))
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
     sizes = type("Sizes", (), {"top_k": k})         # (all that is read)
     with jax.default_matmul_precision("highest"):
         want, routed = vanilla_dsa_moe._expert_ffn(p, x, sizes, 0)
@@ -454,10 +432,8 @@ def test_the_train_step_trains_both_losses_and_counts_what_it_kept():
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
     import json
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "dsa_moe", "--model", "tiny-dsa-moe",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),

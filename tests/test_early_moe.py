@@ -25,16 +25,15 @@ CPU, tiny sizes.
 """
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from family_recipe import Recipe, apply_moe, token_file, worst_leaf
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    EarlyMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    EarlyMoEConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.early_moe import (
     EarlyRouterMoETransformer)
@@ -43,70 +42,23 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_early_moe import (
     reference_loss_routed, sizes_of, vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import sliding_window
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
 from distributed_pytorch_from_scratch_tpu.training.train_step import (
     build_train_step)
 
 PERIOD = (0, 1, 1, 1)
 
 
-def tiny(compute_dtype="float32", **facts):
-    cfg = model_preset("tiny-early-moe", compute_dtype=compute_dtype)
-    return dataclasses.replace(
-        cfg, early_moe=dataclasses.replace(cfg.early_moe, **facts))
+# the family's own: its reference (sequences of 64 from id 3 up: the recipe's)
+R = Recipe("early_moe", vanilla_loss)
+tiny, batch, on_mesh, reference = R.tiny, R.batch, R.on_mesh, R.reference
 
 
 def one_period(**facts):
     return dataclasses.replace(
         tiny(sliding_window_layout=PERIOD, rope_layout=PERIOD, **facts),
         num_layers=4)
-
-
-def batch(cfg, b=2, t=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(3, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    return (ids[:, :-1], ids[:, 1:],
-            np.tile(np.arange(t, dtype=np.int32), (b, 1)))
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model("early_moe", cfg, tp_size=tp, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def reference(cfg, t=64, seed=3, **variant):
-    """(parameters, the reference's loss and gradients) on `batch(cfg, t)`:
-    compiled once for every test that compares with it."""
-    params = build_model("early_moe", cfg).init(jax.random.key(seed))
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        return params, jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos, **variant)))(params)
-
-
-def program(cfg, params, tp=1, t=64, **kw):
-    mesh, model = on_mesh(cfg, tp, **kw)
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-
-
-def leaves_differ(want_g, got_g):
-    """The largest difference of a leaf over the leaf's largest entry, and
-    the leaf it is at."""
-    worst = (0.0, None)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g), strict=True):
-        a, b = np.asarray(a), np.asarray(b)
-        err = np.max(np.abs(a - b)) / max(np.max(np.abs(a)), 1e-6)
-        worst = max(worst, (float(err), jax.tree_util.keystr(path)))
-    return worst
 
 
 # ---- the program against the plain reference ----
@@ -121,13 +73,13 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, t):
     entry."""
     cfg = tiny(experts_held=4, expert_offset=2)
     params, (want, want_g) = reference(cfg, t)
-    got, got_g = program(cfg, params, tp, t, attn_impl=impl)
+    got, got_g = R.program(cfg, tp=tp, t=t, attn_impl=impl)
     model = build_model("early_moe", cfg)
     assert model._pattern == ((("full_layers_0", 1), ("window_layers_0", 3)),)
     assert [model._kind(k) for k in model._layer_keys] == ["full", "window"]
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
     assert len(jax.tree.leaves(got_g)) == 23
-    err, at = leaves_differ(want_g, got_g)
+    err, at = worst_leaf(want_g, got_g)
     assert err <= 1e-5, at
     # both kinds of layer hold the same parameters: two norms, four
     # projections with no bias, a router with no bias leaf, no shared expert
@@ -256,10 +208,10 @@ def test_another_routers_input_or_activation_is_another_model(variant,
     cfg = tiny(experts_held=4, expert_offset=2)
     params, (want, want_g) = reference(cfg)
     _, (other, other_g) = reference(cfg, **variant)
-    got, got_g = program(cfg, params, attn_impl="xla")
+    got, got_g = R.program(cfg, attn_impl="xla")
     assert abs(float(got) - float(want)) <= 1e-5 * float(want)
     assert abs(float(got) - float(other)) > loss_apart * float(want)
-    assert leaves_differ(other_g, got_g)[0] > 1e-2
+    assert worst_leaf(other_g, got_g)[0] > 1e-2
 
 
 def test_the_activation_is_the_familys_fact_through_both_movers():
@@ -299,7 +251,7 @@ def test_the_activation_is_the_familys_fact_through_both_movers():
                 lambda p, x: jnp.sum(apply_moe(layer, p, x)[0] * probe),
                 (0, 1))(p, x)
         assert abs(float(got) - float(want)) <= 1e-4 * abs(float(want))
-        assert leaves_differ(want_g, got_g)[0] <= 1e-4
+        assert worst_leaf(want_g, got_g)[0] <= 1e-4
     with pytest.raises(ValueError, match="activation must be one of"):
         SharedRoutedFFN(d, f, E, k, activation="gelu")
 
@@ -315,25 +267,13 @@ def test_every_remat_rung_gives_the_same_loss_and_gradients(remat):
     cfg = one_period(experts_held=4, expert_offset=2)
     params, (want, want_g) = reference(cfg)
     tp = 2 if remat in ("attn_proj", "dots") else 1
-    got, got_g = program(cfg, params, tp, attn_impl="xla", remat=remat)
+    got, got_g = R.program(cfg, tp, attn_impl="xla", remat=remat)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    err, at = leaves_differ(want_g, got_g)
+    err, at = worst_leaf(want_g, got_g)
     assert err <= 1e-5, at
 
 
 # ---- the shares ----
-
-def apply_moe(moe, params, x, router_x=None):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    if router_x is None:
-        fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                           in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-        return jax.jit(fn)(params, x)
-    fn = jax.shard_map(lambda p, x, r: moe.apply(p, x, router_x=r),
-                       mesh=mesh, in_specs=(moe.specs(), P(), P()),
-                       out_specs=(P(), P()))
-    return jax.jit(fn)(params, x, router_x)
-
 
 def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Four jobs hold two experts each of one layer's 8 (a quarter: the
@@ -435,17 +375,8 @@ def test_decode_and_the_hand_reduced_gradients_are_refused():
 
 def test_the_train_step_trains_and_counts_rows():
     cfg = tiny(experts_held=4, expert_offset=2)
-    mesh, model = on_mesh(cfg, 1, attn_impl="xla")
-    params = model.init(jax.random.key(0))
-    opt = init_adam_state(params)
-    step = build_train_step(model, mesh,
-                            OptimizerConfig(lr=3e-3, warmup_steps=2),
-                            with_grad_norm=True, with_counters=True)
-    ids, tgt, pos = batch(cfg, b=4, t=64)
-    losses = []
-    for _ in range(8):
-        params, opt, (loss, _, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, _, c), (_, model, *_) = R.train(
+        cfg, tp=1, steps=8, b=4, max_steps=20000, attn_impl="xla")
     assert np.isfinite(losses).all() and min(losses[-3:]) < losses[0]
     c = jax.device_get(c)
     assert c["routed"].shape == (8, 8)          # a row a layer, in order
@@ -467,10 +398,8 @@ def test_the_train_step_trains_and_counts_rows():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "early_moe", "--model", "tiny-early-moe",
         "--tp_size", "2", "--data_path", str(tokens),

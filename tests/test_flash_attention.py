@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import outputs_and_grads
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu import (MeshConfig, ModelConfig,
@@ -684,10 +685,8 @@ def test_subtile_gradients_match_oracle(t):
     """One tile a head, several sub-tiles: the fused backward's walk of key
     sub-columns, dk and dv formed transposed."""
     q, k, v, g = _qkv(t + 1, 1, 2, 2, t, 32, n=4)
-    gr = jax.grad(lambda *a: jnp.vdot(causal_attention_xla(*a), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a), g),
-                  (0, 1, 2))(q, k, v)
+    _, gr = _out_and_grads(lambda *a: causal_attention_xla(*a), g, q, k, v)
+    _, gf = _out_and_grads(lambda *a: flash_attention(*a), g, q, k, v)
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
 
@@ -703,14 +702,11 @@ def test_subtile_t_real_exact_zeros_with_tail_cotangent(t_real):
     them yields exact zero gradients — whether the edge cuts a sub-tile
     (masked) or falls between two (the dead ones are never computed)."""
     q, k, v, g = _qkv(t_real, 1, 2, 2, 512, 32, n=4)
-    out = flash_attention(q, k, v, t_real=t_real)
-    ref = _sliced_oracle(q, k, v, t_real)
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, t_real), g, q, k, v)
+    out, gf = _out_and_grads(
+        lambda *a: flash_attention(*a, t_real=t_real), g, q, k, v)
     assert jnp.abs(out - ref).max() < 1e-5
     assert jnp.abs(out[:, :, t_real:]).max() == 0.0
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, t_real=t_real), g),
-                  (0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
         assert jnp.abs(b[:, :, t_real:]).max() == 0.0
@@ -722,12 +718,10 @@ def test_subtile_gqa_groups(group):
     accumulates dk/dv of a kv head over its group's grid steps."""
     q, k, v, g = _qkv(group, 1, 4, 4 // group, 512, 32, n=4)
     tr = 500
-    out = flash_attention(q, k, v, t_real=tr)
-    assert jnp.abs(out - _sliced_oracle(q, k, v, tr)).max() < 1e-5
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, tr), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, t_real=tr), g),
-                  (0, 1, 2))(q, k, v)
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, tr), g, q, k, v)
+    out, gf = _out_and_grads(
+        lambda *a: flash_attention(*a, t_real=tr), g, q, k, v)
+    assert jnp.abs(out - ref).max() < 1e-5
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
 
@@ -743,12 +737,9 @@ def test_subtile_multiblock_diagonal_tiles(blocks, t_real):
     q, k, v, g = _qkv(t_real, 1, 2, 1, 1024, 32, n=4)
     kw = dict(block_q=blocks[0], block_k=blocks[1], bwd_block_q=blocks[0],
               bwd_block_k=blocks[1], t_real=t_real)
-    out = flash_attention(q, k, v, **kw)
-    assert jnp.abs(out - _sliced_oracle(q, k, v, t_real)).max() < 1e-5
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
-                  (0, 1, 2))(q, k, v)
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, t_real), g, q, k, v)
+    out, gf = _out_and_grads(lambda *a: flash_attention(*a, **kw), g, q, k, v)
+    assert jnp.abs(out - ref).max() < 1e-5
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
         assert not jnp.any(b[:, :, t_real:])
@@ -802,6 +793,18 @@ def _fwd_grid(q, k, v, **kw):
                                  gm.block_mappings[1].block_shape)
 
 
+def _out_and_grads(fn, g, *args):
+    """(`fn(*args)`, the gradients of its product with the cotangent `g` in
+    every argument) in one compiled program, at the backend's own products
+    (the output is the kernel's own call, the gradients go through its
+    forward rule: both are held)."""
+    (out,), grads = outputs_and_grads(
+        lambda *a: (fn(*a),),
+        lambda out: jnp.vdot(out, g).real.astype(jnp.float32), *args,
+        precision=None)
+    return out, grads
+
+
 def _rel(a, b):
     a, b = a.astype(jnp.float32), b.astype(jnp.float32)
     return float(jnp.abs(a - b).max() / jnp.abs(a).max())
@@ -819,15 +822,11 @@ def test_row_walk_matches_oracle(d, dv, blocks, dtype, tol):
     q, k, v, g = _qkv(blocks, 1, 1, 1, t, d, dtype, n=4, dv=dv)
     kw = dict(block_q=blk, block_k=blk, bwd_block_q=blk, bwd_block_k=blk)
     assert _fwd_grid(q, k, v, **kw) == ((1, blocks, 1), (1, t, d))
-    ref = causal_attention_xla(q, k, v)
-    out = flash_attention(q, k, v, **kw)
+    ref, gr = _out_and_grads(causal_attention_xla, g, q, k, v)
+    out, gf = _out_and_grads(lambda *a: flash_attention(*a, **kw), g, q, k, v)
     assert out.shape == (1, 1, t, dv) and out.dtype == dtype
     assert jnp.abs(ref.astype(jnp.float32)
                    - out.astype(jnp.float32)).max() < tol
-    gr = jax.grad(lambda *a: jnp.vdot(causal_attention_xla(*a), g).real
-                  .astype(jnp.float32), (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g).real
-                  .astype(jnp.float32), (0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         assert _rel(a, b) < 10 * tol
 
@@ -843,8 +842,8 @@ def test_row_walk_t_real_exact_zeros_with_tail_cotangent(t_real):
     q, k, v, g = _qkv(t_real, 1, 2, 2, 1024, 24, n=4, dv=16)
     kw = dict(block_q=256, block_k=256, bwd_block_q=256, bwd_block_k=256,
               t_real=t_real)
-    out = flash_attention(q, k, v, **kw)
-    ref = _sliced_oracle(q, k, v, t_real)
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, t_real), g, q, k, v)
+    out, gf = _out_and_grads(lambda *a: flash_attention(*a, **kw), g, q, k, v)
     assert jnp.abs(out - ref).max() < 1e-5
     assert jnp.abs(out[:, :, t_real:]).max() == 0.0
     flat = lambda x: x.reshape(2, 1024, x.shape[-1])
@@ -854,10 +853,6 @@ def test_row_walk_t_real_exact_zeros_with_tail_cotangent(t_real):
     assert (lse[:, t_real:] == fa_mod.MASK).all()
     assert (lse[:, :t_real] > fa_mod.MASK / 2).all()
     assert not o[:, t_real:].any()
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
-                  (0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
         assert jnp.abs(b[:, :, t_real:]).max() == 0.0
@@ -872,12 +867,9 @@ def test_row_walk_gqa_groups(group):
     kw = dict(block_q=256, block_k=256, bwd_block_q=256, bwd_block_k=256,
               t_real=tr)
     assert _fwd_grid(q, k, v, **kw) == ((8, 4, 1), (1, 1024, 24))
-    out = flash_attention(q, k, v, **kw)
-    assert jnp.abs(out - _sliced_oracle(q, k, v, tr)).max() < 1e-5
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, tr), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
-                  (0, 1, 2))(q, k, v)
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, tr), g, q, k, v)
+    out, gf = _out_and_grads(lambda *a: flash_attention(*a, **kw), g, q, k, v)
+    assert jnp.abs(out - ref).max() < 1e-5
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
 
@@ -916,13 +908,10 @@ def test_gridded_walk_where_the_row_does_not_fit(monkeypatch, blocks, t_real):
               bwd_block_k=blocks[1], t_real=t_real)
     assert _fwd_grid(q, k, v, **kw) == (
         (2, 1024 // blocks[0], 1024 // blocks[1]), (1, blocks[1], 24))
-    out = flash_attention(q, k, v, **kw)
-    assert jnp.abs(out - _sliced_oracle(q, k, v, t_real)).max() < 1e-5
+    ref, gr = _out_and_grads(lambda *a: _sliced_oracle(*a, t_real), g, q, k, v)
+    out, gf = _out_and_grads(lambda *a: flash_attention(*a, **kw), g, q, k, v)
+    assert jnp.abs(out - ref).max() < 1e-5
     assert not out[:, :, t_real:].any()
-    gr = jax.grad(lambda *a: jnp.vdot(_sliced_oracle(*a, t_real), g),
-                  (0, 1, 2))(q, k, v)
-    gf = jax.grad(lambda *a: jnp.vdot(flash_attention(*a, **kw), g),
-                  (0, 1, 2))(q, k, v)
     for a, b in zip(gr, gf):
         assert jnp.abs(a - b).max() < 1e-4
 
@@ -1040,8 +1029,9 @@ def _square(block, **kw):
 
 
 def _grads(fn, q, k, v, g):
-    return jax.grad(lambda *a: jnp.vdot(fn(*a), g).real.astype(jnp.float32),
-                    (0, 1, 2))(q, k, v)
+    return jax.jit(jax.grad(
+        lambda *a: jnp.vdot(fn(*a), g).real.astype(jnp.float32),
+        (0, 1, 2)))(q, k, v)
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),

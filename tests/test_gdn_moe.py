@@ -23,15 +23,18 @@ sizes, float32.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss,
+                           mesh_of, outputs_and_grads, token_file)
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    GdnMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    GdnMoEConfig, ModelConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.gdn_moe import (
     GdnMoETransformer)
@@ -54,31 +57,13 @@ from distributed_pytorch_from_scratch_tpu.ops.rope import (
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
 from distributed_pytorch_from_scratch_tpu.parallel.norm import (
     GatedRMSNorm, ZeroCenteredRMSNorm)
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
-from distributed_pytorch_from_scratch_tpu.training.train_step import (
-    build_train_step)
 
 
-def tiny(**facts):
-    cfg = model_preset("tiny-gdn-moe")
-    return dataclasses.replace(
-        cfg, gdn_moe=dataclasses.replace(cfg.gdn_moe, **facts))
-
-
-def batch(cfg, b=2, t=128, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model("gdn_moe", cfg, tp_size=tp, **kw)
+# the family's own: its reference, and sequences of 128 from id 0 up
+R = Recipe("gdn_moe", vanilla_loss, t=128, low=0)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 # ---- the program against the plain reference ----
@@ -92,22 +77,13 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
     the chunked rule sums a chunk's decays in another order than the
     recurrence does, in float32 (1.8e-5 at the worst leaf)."""
     cfg = tiny(experts_held=4, expert_offset=2)
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
+    _, model = on_mesh(cfg, tp, attn_impl=impl)
     assert model.periods == 2 and cfg.num_layers == 8
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 36
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 5e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    # (the parameters and the reference are one for the three layouts)
+    params, (want, want_g) = R.reference(cfg)
+    got, got_g = R.program(cfg, tp=tp, attn_impl=impl)
+    hold_loss(want, got)
+    assert len(hold_leaves(want_g, got_g, 5e-5)[0]) == 36
     # a softmax router has no selection bias
     assert "bias" not in params["gdn_layers"]["moe"]
     assert params["gdn_layers"]["gdn"]["w_qkvz"].shape[:2] == (2, 3)
@@ -153,6 +129,18 @@ def rule_inputs(t, decay=1.0, beta_shift=0.0, seed=0, widths=(16, 8),
 KERNELS = dict(widths=(128, 128), kernels=True)
 
 
+@functools.lru_cache(maxsize=None)
+def _rule(chunk, interpret):
+    """One function a (chunk, path): the sweep's cases that differ in their
+    data alone run one compiled program (`outputs_and_grads` keeps it; every
+    case steers the kernels' two block sizes alike)."""
+    return lambda *a: gated_delta_rule(*a, chunk=chunk, interpret=interpret)
+
+
+def _cosines(o, S):
+    return jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S)
+
+
 @pytest.mark.parametrize("t,chunk,decay,beta_shift,how", [
     (128, 16, 1.0, 0.0, {}), (128, 64, 1.0, 0.0, {}),
     (100, 64, 1.0, 0.0, {}),        # a length that is no multiple of chunk
@@ -178,17 +166,13 @@ def test_the_chunked_rule_equals_the_token_by_token_rule(
     monkeypatch.setattr(rule_kernels, "HEADS_IN_TURN", 2)
     args = rule_inputs(t, decay, beta_shift, **how)
     dk, dv = args[0].shape[-1], args[2].shape[-1]
-    scalar = lambda rule: lambda *a: (
-        lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S))(*rule(*a))
-    text = lambda *a: gated_delta_rule(*a, chunk=chunk)
-    chunked = (lambda *a: gated_delta_rule(*a, chunk=chunk, interpret=True)
-               ) if kernels else text
-    grads = lambda rule: jax.grad(scalar(rule), argnums=(0, 1, 2, 3, 4))(
-        *args)
-    with jax.default_matmul_precision("highest"):
-        o, S = chunked(*args)
-        o_want, S_want = delta_rule_recurrent(*args)
-        got, want = grads(chunked), grads(delta_rule_recurrent)
+    text, chunked = _rule(chunk, False), _rule(chunk, kernels)
+    # (a rule's outputs and its five gradients are one compiled program,
+    # and one for the cases of a shape and chunk: the rest is data)
+    (o, S), got = outputs_and_grads(chunked, _cosines, *args)
+    (o_want, S_want), want = outputs_and_grads(delta_rule_recurrent,
+                                               _cosines, *args)
+    all_five = got
     rel = lambda a, b: float(jnp.max(jnp.abs(a - b))
                              / jnp.maximum(jnp.max(jnp.abs(b)), 1e-9))
     assert o.shape == (2, 3, t, dv) and S.shape == (2, 3, dk, dv)
@@ -208,14 +192,13 @@ def test_the_chunked_rule_equals_the_token_by_token_rule(
     for a, b in zip(got, want):
         assert np.all(np.isfinite(a)) and rel(a, b) < tol
     if kernels:     # and the XLA text with its `lax.scan` that they replace
-        with jax.default_matmul_precision("highest"):
-            (o_text, S_text), g_text = text(*args), grads(text)
+        (o_text, S_text), g_text = outputs_and_grads(text, _cosines, *args)
         # the kernels multiply T rhs in bfloat16 pieces, six passes, as
         # `HIGHEST` does on the chip; the text's product here is float32's
         assert rel(o, o_text) < 2e-6 and rel(S, S_text) < 2e-6
         # the same sums in another order: by the largest gradient's scale
         top = max(float(jnp.max(jnp.abs(b))) for b in g_text)
-        for a, b in zip(grads(chunked), g_text):
+        for a, b in zip(all_five, g_text):
             assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * max(top, 1.0)
 
 
@@ -569,7 +552,7 @@ def test_the_delta_mixer_takes_the_kernel_path_inside_shard_map(monkeypatch):
     kda = KimiDeltaAttention(64, 4, 128, 128, tp_size=2)
     params = jax.eval_shape(kda.init, jax.random.key(0))
     x = jax.ShapeDtypeStruct((2, 128, 64), jnp.float32)
-    mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices()[:2])
+    mesh = mesh_of(2)
     fn = jax.shard_map(lambda p, x: kda.apply(p, x)[0], mesh=mesh,
                        in_specs=(kda.specs(), jax.sharding.PartitionSpec()),
                        out_specs=jax.sharding.PartitionSpec())
@@ -639,14 +622,6 @@ def test_the_zero_centred_and_the_gated_norm():
 
 # ---- the expert layer: shares, and no drop ----
 
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    from jax.sharding import PartitionSpec as P
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
-
 def gated_shared(p, x):
     xf, sh = x.reshape(-1, x.shape[-1]), p["shared"]
     out = ((jax.nn.silu(xf @ sh["gate"]) * (xf @ sh["up"])) @ sh["down"]
@@ -713,18 +688,7 @@ def test_a_softmax_router_forced_onto_the_same_experts_drops_nothing():
 
 def test_the_train_step_returns_a_row_of_counters_a_layer_and_the_loss_falls():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
-    opt = init_adam_state(params)
-    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
-    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
-                            with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(6):
-        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, gnorm, c), _ = R.train(cfg)
     assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
     # one row a layer, in the order the layers run (two periods of four)
     assert c["routed"].shape == (8, 8) and c["rows_here"].shape == (8,)
@@ -738,10 +702,8 @@ def test_the_train_step_returns_a_row_of_counters_a_layer_and_the_loss_falls():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "gdn_moe", "--model", "tiny-gdn-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),

@@ -29,6 +29,7 @@ from distributed_pytorch_from_scratch_tpu.analysis import (
     lint_file, lint_paths, validate_report)
 from distributed_pytorch_from_scratch_tpu.analysis.report import (
     write_report)
+from distributed_pytorch_from_scratch_tpu.analysis.rules import EXCLUDE_DIRS
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -94,9 +95,23 @@ def test_report_path_override_names_snippets():
 
 # -------------------------------------------------------- clean-repo gate --
 
+def _committed():
+    """What the sweep walks of the repo's root: its files and directories
+    but those `.gitignore` names as directories (a builder's `.scratch/`,
+    what a chip call brings back: a probe there is in no checkout the
+    driver makes) and those no sweep enters (`EXCLUDE_DIRS`)."""
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        ignored = {line.strip().rstrip("/") for line in f
+                   if line.strip().endswith("/")}
+    return [os.path.join(REPO, name) for name in sorted(os.listdir(REPO))
+            if name not in ignored | EXCLUDE_DIRS
+            and (name.endswith(".py")
+                 or os.path.isdir(os.path.join(REPO, name)))]
+
+
 @pytest.fixture(scope="module")
 def repo_sweep():
-    return lint_paths([REPO], root=REPO)
+    return lint_paths(_committed(), root=REPO)
 
 
 def test_repo_sweep_is_clean(repo_sweep):

@@ -33,26 +33,27 @@ import dataclasses
 import functools
 import hashlib
 import json
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss,
+                           lowered_text, mesh_of, on_one_device,
+                           outputs_and_grads, picked_rung, token_file)
 from jax.extend import core as jex_core
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
     load_checkpoint, save_checkpoint)
 from distributed_pytorch_from_scratch_tpu.config import (
-    KdaMlaMoEConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    KdaMlaMoEConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          build_model)
 from distributed_pytorch_from_scratch_tpu.models.kda_mla_moe import (
     KdaMlaMoETransformer, layer_counts)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_kda_mla_moe import (
     layers_in_order, vanilla_logits, vanilla_loss)
-from distributed_pytorch_from_scratch_tpu.obs import trace as obs_trace
 from distributed_pytorch_from_scratch_tpu.ops.delta_rule import (
     SUB, channel_delta_rule, delta_rule_recurrent, gated_delta_rule)
 from distributed_pytorch_from_scratch_tpu.ops.pallas import kda_rule
@@ -61,7 +62,6 @@ from distributed_pytorch_from_scratch_tpu.parallel.kda import (
     KimiDeltaAttention)
 from distributed_pytorch_from_scratch_tpu.parallel.mla import LatentAttention
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training import memory
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
@@ -71,24 +71,9 @@ from distributed_pytorch_from_scratch_tpu.training.train_step import (
     build_train_step)
 
 FAMILY = "kda_mla_moe"
-
-
-def tiny(dtype="float32", **facts):
-    cfg = model_preset("tiny-kda-mla-moe", compute_dtype=dtype)
-    return dataclasses.replace(
-        cfg, kda_mla_moe=dataclasses.replace(cfg.kda_mla_moe, **facts))
-
-
-def batch(cfg, b=2, t=96, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model(FAMILY, cfg, tp_size=tp, **kw)
+# the family's own: its reference, and sequences of 96 from id 0 up
+R = Recipe(FAMILY, vanilla_loss, vanilla_logits, t=96, low=0)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 # ---- the program against the plain reference ----
@@ -104,29 +89,16 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, mtp):
     5e-5 of their largest entry, as the third family's rule."""
     cfg = tiny(experts_held=8, expert_offset=4,
                num_nextn_predict_layers=mtp)
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params = model.init(jax.random.key(3))
+    # (the parameters and the reference are one per `mtp`)
+    params, (want, want_g) = R.reference(cfg)
     assert len(layers_in_order(params)) == cfg.num_layers == 6
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g))
-    moved = 0
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 5e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
-        moved += bool(np.any(a != 0))
+    got, got_g = R.program(cfg, tp=tp, attn_impl=impl)
+    hold_loss(want, got)
+    names, moved = hold_leaves(want_g, got_g, 5e-5)
     # every leaf but the selection biases has a gradient (A_log, dt_bias,
     # the convolutions and the head gate among them)
-    biases = sum("bias" in jax.tree_util.keystr(p) for p, _ in flat
-                 if "dt_bias" not in jax.tree_util.keystr(p))
-    assert moved == len(flat) - biases
+    biases = sum("bias" in name for name in names if "dt_bias" not in name)
+    assert len(moved) == len(names) - biases
     assert ("mtp" in params) == bool(mtp)
     assert params["kda_layers"]["kda"]["w_f"].shape[:2] == (1, 2)
     assert params["mla_layers"]["mla"]["w_gate"]["weight"].shape == (1, 1,
@@ -137,23 +109,23 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, mtp):
 def test_the_logits_equal_the_reference():
     cfg = tiny(num_nextn_predict_layers=0)
     mesh, model = on_mesh(cfg, 2)
-    params = model.init(jax.random.key(5))
     ids, _, pos = batch(cfg, t=80)
     with jax.default_matmul_precision("highest"):
-        want = vanilla_logits(cfg, params, ids, pos)
         got = jax.jit(model.make_forward(mesh))(
-            jax.device_put(params, model.shardings(mesh)), ids, pos)
-    np.testing.assert_allclose(got[..., :cfg.vocab_size], want, atol=3e-5)
+            jax.device_put(R.params(cfg, 5), model.shardings(mesh)), ids, pos)
+    np.testing.assert_allclose(got[..., :cfg.vocab_size],
+                               R.reference_logits(cfg, t=80, seed=5),
+                               atol=3e-5)
 
 
 def test_in_bfloat16_the_loss_is_the_references_to_bfloat16s_rounding():
     cfg = tiny("bfloat16", experts_held=8, expert_offset=4)
     mesh, model = on_mesh(cfg, 1)
-    params = model.init(jax.random.key(3))
+    params = R.params(cfg)
     ids, tgt, pos = batch(cfg)
-    want = vanilla_loss(cfg, params, ids, tgt, pos)
+    want = jax.jit(lambda p: vanilla_loss(cfg, p, ids, tgt, pos))(params)
     got = jax.jit(model.make_loss(mesh))(params, ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 2e-2 * abs(float(want))
+    hold_loss(want, got, 2e-2)
 
 
 # ---- what a delta layer makes again is the rung's to say ----
@@ -225,17 +197,6 @@ def test_a_delta_layer_makes_its_rules_inputs_as_often_as_the_rung_says(
             "w_q", "w_k", "w_v", "w_f", "w_g"))
 
 
-@functools.lru_cache(maxsize=None)
-def _loss_and_grads(rung):
-    cfg = tiny(experts_held=8, expert_offset=4)
-    mesh, model = on_mesh(cfg, 1, remat=rung)
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            params, ids, tgt, pos)
-
-
 @pytest.mark.parametrize("rung", [True, "flash", "dots"])
 def test_every_rung_is_the_unrematerialised_programs_numbers(rung):
     """What a rung keeps changes when a tensor is made, never what it is:
@@ -244,14 +205,11 @@ def test_every_rung_is_the_unrematerialised_programs_numbers(rung):
     their largest entry; read: 2e-6 at `true`; the reference of
     `test_loss_and_every_gradient_leaf_equal_the_reference` stands beside
     it at the family's default)."""
-    want, want_g = _loss_and_grads(False)
-    got, got_g = _loss_and_grads(rung)
-    assert abs(float(got) - float(want)) <= 1e-6 * abs(float(want))
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    cfg = tiny(experts_held=8, expert_offset=4)
+    want, want_g = R.program(cfg, remat=False)      # once for the three
+    got, got_g = R.program(cfg, remat=rung)
+    hold_loss(want, got, 1e-6)
+    hold_leaves(want_g, got_g, 1e-5)
 
 
 def test_no_top_k_choice_sits_on_a_tie():
@@ -292,12 +250,22 @@ def rule_inputs(seed, t, g="drawn", b=2, h=2, dk=16, dv=8):
 KERNELS = dict(dk=128, dv=128)
 
 
+@functools.lru_cache(maxsize=None)
+def _rule(interpret, **kw):
+    """One function a (path, chunk): the sweep's cases that differ in their
+    data alone run one compiled program (`outputs_and_grads` keeps it)."""
+    return lambda *a: channel_delta_rule(*a, interpret=interpret, **kw)
+
+
 def channel_rule(impl, monkeypatch, **kw):
-    if impl == "text":
-        return lambda *a: channel_delta_rule(*a, **kw)
-    monkeypatch.setattr(kda_rule, "HEAD_BLOCK", 2)
-    monkeypatch.setattr(kda_rule, "HEADS_IN_TURN", 2)
-    return lambda *a: channel_delta_rule(*a, interpret=True, **kw)
+    if impl == "kernels":       # (every caller steers the same two)
+        monkeypatch.setattr(kda_rule, "HEAD_BLOCK", 2)
+        monkeypatch.setattr(kda_rule, "HEADS_IN_TURN", 2)
+    return _rule(impl == "kernels", **kw)
+
+
+def _sines(o, S):
+    return jnp.sum(jnp.sin(o)) + jnp.sum(S * S)
 
 
 @pytest.mark.parametrize("g", ["drawn", "bound", "near_zero"])
@@ -313,14 +281,11 @@ def test_the_chunked_channel_rule_equals_the_token_by_token_rule(
     selected away."""
     args = rule_inputs(7, t, g, **(KERNELS if impl == "kernels" else {}))
     rule = channel_rule(impl, monkeypatch, chunk=chunk)
-    loss = lambda rule: lambda *a: (
-        lambda o, S: jnp.sum(jnp.sin(o)) + jnp.sum(S * S))(*rule(*a))
-    with jax.default_matmul_precision("highest"):
-        o, S = rule(*args)
-        o_ref, S_ref = delta_rule_recurrent(*args)
-        grads = jax.grad(loss(rule), argnums=range(5))(*args)
-        grads_ref = jax.grad(loss(delta_rule_recurrent),
-                             argnums=range(5))(*args)
+    # (a rule's outputs and its five gradients are one compiled program,
+    # and one for the cases of a (length, chunk, path): `g` is data)
+    (o, S), grads = outputs_and_grads(rule, _sines, *args)
+    (o_ref, S_ref), grads_ref = outputs_and_grads(delta_rule_recurrent,
+                                                  _sines, *args)
     scale = float(jnp.max(jnp.abs(o_ref)))
     assert float(jnp.max(jnp.abs(o - o_ref))) <= 2e-6 * max(scale, 1.0)
     assert float(jnp.max(jnp.abs(S - S_ref))) <= 1e-5 * max(
@@ -355,12 +320,10 @@ def test_the_kernels_take_a_batchs_heads_in_one_call_and_a_ragged_length(
     monkeypatch.setattr(kda_rule, "HEAD_BLOCK", 4)
     monkeypatch.setattr(kda_rule, "HEADS_IN_TURN", 2)
     kernels = lambda *a: channel_delta_rule(*a, interpret=True)
-    loss = lambda rule: lambda *a: (
-        lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S))(*rule(*a))
-    with jax.default_matmul_precision("highest"):
-        got = (*kernels(*args), *jax.grad(loss(kernels), range(5))(*args))
-        want = (*channel_delta_rule(*args), *jax.grad(
-            loss(channel_delta_rule), range(5))(*args))
+    loss = lambda o, S: jnp.sum(o * jnp.cos(o)) + jnp.sum(S * S)
+    flat = lambda out, grads: (*out, *grads)
+    got = flat(*outputs_and_grads(kernels, loss, *args))
+    want = flat(*outputs_and_grads(channel_delta_rule, loss, *args))
     assert got[0].shape == (2, 3, 100, 128) and got[1].shape == (2, 3, 128,
                                                                  128)
     top = max(float(jnp.max(jnp.abs(b))) for b in want[2:])
@@ -388,10 +351,8 @@ def test_the_mixers_gate_stays_over_its_bound_and_differs_by_channel():
     # a gate driven hard: the decay's projection a hundred times its size
     p = {**p, "w_f": 100.0 * p["w_f"]}
     x = jax.random.normal(jax.random.key(1), (2, 70, 64))
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: kda.apply(p, x), mesh=mesh,
-                       in_specs=(kda.specs(), P()), out_specs=(P(), P()))
-    y, c = jax.jit(fn)(p, x)
+    y, c = on_one_device(lambda p, x: kda.apply(p, x), (kda.specs(), P()),
+                         (P(), P()))(p, x)
     assert bool(jnp.all(jnp.isfinite(y)))
     assert -5.0 <= float(c["kda_g_min"]) < -4.9
     assert float(c["kda_g_spread"]) > 0.5
@@ -455,11 +416,11 @@ def test_latent_attention_with_no_q_latent_and_a_gate_a_head():
     y = jax.random.normal(jax.random.key(1), (2, 48, 64))
     pos = jnp.tile(jnp.arange(48), (2, 1))
     cos, sin = rope_angles(pos, 8, 10000.0)
-    mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices()[:2])
     fn = jax.shard_map(
         lambda p, y, cos, sin: attn.apply(p, y, cos, sin, jnp.float32,
                                           attn_impl="xla"),
-        mesh=mesh, in_specs=(attn.specs(), P(), P(), P()), out_specs=P())
+        mesh=mesh_of(tp=2), in_specs=(attn.specs(), P(), P(), P()),
+        out_specs=P())
     with jax.default_matmul_precision("highest"):
         got = jax.jit(fn)(p, y, cos, sin)
         # plainly
@@ -489,13 +450,6 @@ def test_latent_attention_with_no_q_latent_and_a_gate_a_head():
 
 
 # ---- the shares add up ----
-
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
 
 def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Four jobs hold four experts each of one layer's 16 in 2 groups (a
@@ -539,7 +493,7 @@ def test_the_shares_of_the_models_expert_layer_equal_the_uncut_references():
     cfg = tiny(num_nextn_predict_layers=0)
     km = cfg.kda_mla_moe
     model = build_model(FAMILY, cfg)
-    params = model.init(jax.random.key(2))
+    params = R.params(cfg, 2)
     lp = jax.tree.map(lambda a: a[0], params["lead_kda_layers"])
     y = jax.random.normal(jax.random.key(4), (2, 64, cfg.attn_dim))
     with jax.default_matmul_precision("highest"):
@@ -562,7 +516,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
         tmp_path):
     cfg = tiny()
     mesh, model = on_mesh(cfg, 2)
-    params = model.init(jax.random.key(1))
+    params = R.params(cfg, 1)
     n = layer_counts(cfg)
     assert n == {"dense_layers": 1, "lead_kda_layers": 1,
                  "lead_mla_layers": 1, "kda_layers": 2, "mla_layers": 1,
@@ -579,7 +533,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
     jax.tree.map(np.testing.assert_array_equal, back, params)
     save_checkpoint(str(tmp_path), 3, 1.0, canonical,
                     model.canonical_specs(), 1)
-    fresh = model.init(jax.random.key(9))
+    fresh = R.params(cfg, 9)
     restored, _, at = load_checkpoint(str(tmp_path), 3, fresh,
                                       model.canonical_specs())
     assert at == 3
@@ -590,18 +544,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
 
 def test_the_train_step_returns_the_decays_and_the_groups_rows_and_the_loss_falls():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
-    opt = init_adam_state(params)
-    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
-    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
-                            with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(6):
-        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, gnorm, c), _ = R.train(cfg)
     assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
     # a row a delta layer (4 of the 6), a row an expert layer (5 and the
     # module's), in the order the layers run
@@ -618,10 +561,8 @@ def test_the_train_step_returns_the_decays_and_the_groups_rows_and_the_loss_fall
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", FAMILY, "--model", "tiny-kda-mla-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
@@ -791,18 +732,6 @@ NINTH = ("mhc_mla_moe", "tiny-mhc-mla-moe", "6060959548dcf1c3", "ffn",
          0.013556059449911118)
 
 
-def lowered_text(family, cfg):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct((4, 256), np.int32)
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, with_counters=True)
-    text = step.lower(params, opt, ids, ids, ids).as_text()
-    return re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
-
-
 def test_the_ninth_standing_family_lowers_to_the_text_it_lowered_to():
     family, preset, digest, _, _ = NINTH
     text = lowered_text(family, model_preset(preset))
@@ -810,38 +739,18 @@ def test_the_ninth_standing_family_lowers_to_the_text_it_lowered_to():
     assert "kda" not in text and "groups_hit" not in text
 
 
-class _Seen:
-    def instant(self, name, **fields):
-        self.fields = fields
-
-
 def test_the_ninth_standing_family_is_picked_the_rung_it_was(monkeypatch):
     family, preset, _, rung, estimate = NINTH
-    model = build_model(family, model_preset(preset,
-                                             compute_dtype="bfloat16"),
-                        remat_budget_gib=0.02)
-    shapes = jax.eval_shape(model.init, jax.random.key(0))
-    count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))
-    seen = _Seen()
-    monkeypatch.setattr(obs_trace, "_current", seen)
-    memory.select_remat_traced.cache_clear()
-    got = memory.select_remat_traced(
-        model, count(shapes),
-        sum(count(shapes[k]) for k in model._layer_keys), 4, 256)
-    assert (got, seen.fields["estimate_gib"]) == (rung, estimate)
+    _, got, fields = picked_rung(
+        monkeypatch, family, model_preset(preset, compute_dtype="bfloat16"),
+        0.02)
+    assert (got, fields["estimate_gib"]) == (rung, estimate)
 
 
 def test_the_new_familys_step_names_its_scopes():
     """The named scopes a device trace splits the step by are the name
     stacks of the lowered text's debug info."""
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(FAMILY, tiny())
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct((2, 128), np.int32)
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_counters=True)
-    text = step.lower(params, opt, ids, ids, ids).as_text(debug_info=True)
+    text = lowered_text(FAMILY, tiny(), shape=(2, 128), debug_info=True)
     # (a name stack is cut where a function is called: the rule's inner
     # scopes stand under its `checkpoint`, the call under `kda_rule`; a
     # device trace's op_name has them joined)

@@ -33,9 +33,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, lowered_step, lowered_text, mesh_of,
+                           token_file, worst_leaf)
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    LoopLlamaConfig, MeshConfig, ModelConfig, OptimizerConfig, model_preset)
+    LoopLlamaConfig, ModelConfig, OptimizerConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.loop_llama import (
     LoopedTransformer)
@@ -45,68 +47,21 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_loop_llama import (
 from distributed_pytorch_from_scratch_tpu.obs import schema
 from distributed_pytorch_from_scratch_tpu.obs.attribution import (
     analytic_phases)
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training import memory
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     loop_counters_summary, model_flops_per_step)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
 from distributed_pytorch_from_scratch_tpu.training.train_step import (
     build_train_step)
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
 
-def tiny(compute_dtype="float32", **facts):
-    cfg = model_preset("tiny-loop-llama", compute_dtype=compute_dtype)
-    return dataclasses.replace(
-        cfg, loop_llama=dataclasses.replace(cfg.loop_llama, **facts))
-
-
-def batch(cfg, b=2, t=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(3, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    return (ids[:, :-1], ids[:, 1:],
-            np.tile(np.arange(t, dtype=np.int32), (b, 1)))
-
-
-def on_mesh(cfg, tp=1, dp=1, **kw):
-    mesh = make_mesh(MeshConfig(dp=dp, tp=tp),
-                     devices=jax.devices()[:dp * tp])
-    return mesh, build_model("loop_llama", cfg, tp_size=tp, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def reference(cfg, t=64, seed=3, **variant):
-    """(parameters, the reference's (loss, detail) and gradients) on
-    `batch(cfg, t)`: compiled once for every test that compares with it."""
-    params = build_model("loop_llama", cfg).init(jax.random.key(seed))
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        return params, jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos, detail=True,
-                                   **variant), has_aux=True))(params)
-
-
-def program(cfg, params, tp=1, dp=1, t=64, **kw):
-    mesh, model = on_mesh(cfg, tp, dp, **kw)
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(jax.value_and_grad(
-            model.make_loss(mesh, with_counters=True), has_aux=True))(
-                jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-
-
-def leaves_differ(want_g, got_g):
-    """The largest difference of a leaf over the leaf's largest entry, and
-    the leaf it is at."""
-    worst = (-1.0, None)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g), strict=True):
-        a, b = np.asarray(a), np.asarray(b)
-        err = np.max(np.abs(a - b)) / max(np.max(np.abs(a)), 1e-6)
-        worst = max(worst, (float(err), jax.tree_util.keystr(path)))
-    return worst
+# the family's own: its reference hands back the exits' detail beside the
+# loss, and the program's side of it is its counters
+R = Recipe("loop_llama", vanilla_loss)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
+reference = functools.partial(R.reference, has_aux=True, detail=True)
+program = functools.partial(R.program, with_counters=True)
 
 
 # ---- the program against the plain reference ----
@@ -124,10 +79,10 @@ def test_loss_exits_p_and_every_gradient_leaf_equal_the_reference(
     signs)."""
     cfg = tiny()
     params, ((want, detail), want_g) = reference(cfg, t)
-    (got, counters), got_g = program(cfg, params, tp, dp, t, attn_impl=impl)
+    (got, counters), got_g = program(cfg, tp, dp, t, attn_impl=impl)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
     assert len(jax.tree.leaves(got_g)) == 16
-    err, at = leaves_differ(want_g, got_g)
+    err, at = worst_leaf(want_g, got_g)
     assert err <= 1e-4, at
     counters = jax.device_get(counters)
     assert counters["loss_exit"].shape == (3,)
@@ -151,7 +106,7 @@ def test_the_shared_gradient_is_the_sum_over_unrolled_copies():
     number)."""
     cfg = tiny()
     params, _ = reference(cfg)
-    (_, _), got_g = program(cfg, params)
+    (_, _), got_g = program(cfg)
     ids, tgt, pos = batch(cfg)
     R, L = 3, cfg.num_layers
     unrolled = {**params, "layers": jax.tree.map(
@@ -162,14 +117,14 @@ def test_the_shared_gradient_is_the_sum_over_unrolled_copies():
     copies = jax.tree.map(lambda a: a.reshape(R, L, *a.shape[1:]),
                           grads["layers"])
     summed = jax.tree.map(lambda a: a.sum(0), copies)
-    err, at = leaves_differ(summed, got_g["layers"])
+    err, at = worst_leaf(summed, got_g["layers"])
     assert err <= 1e-4, at
     # one pass's share alone is far off: the sum is load-bearing
-    err, _ = leaves_differ(jax.tree.map(lambda a: a[-1], copies),
+    err, _ = worst_leaf(jax.tree.map(lambda a: a[-1], copies),
                            got_g["layers"])
     assert err > 0.2
     others = lambda g: {k: v for k, v in g.items() if k != "layers"}
-    err, at = leaves_differ(others(grads), others(got_g))
+    err, at = worst_leaf(others(grads), others(got_g))
     assert err <= 1e-4, at
 
 
@@ -185,7 +140,7 @@ def test_p_sums_to_one_and_the_last_step_takes_the_remainder():
     # the program's p (from log-sigmoids) is the same distribution
     cfg = tiny()
     params, ((_, detail), _) = reference(cfg)
-    (_, counters), _ = program(cfg, params)
+    (_, counters), _ = program(cfg)
     assert float(np.sum(counters["exit_p_mean"])) == pytest.approx(1.0,
                                                                    abs=1e-6)
     np.testing.assert_allclose(np.asarray(detail["p"]).sum(0), 1.0,
@@ -210,13 +165,13 @@ class PassedOnce(LoopedTransformer):
 def test_one_pass_is_the_llama_block_with_the_two_post_norms():
     cfg = tiny(loop_steps=1)
     params, ((want, _), want_g) = reference(cfg)
-    (got, counters), got_g = program(cfg, params)
+    (got, counters), got_g = program(cfg)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
     np.testing.assert_array_equal(counters["exit_p_mean"], [1.0])   # p = 1
     assert float(counters["exit_entropy"]) == 0.0                   # H = 0
     assert not np.any(np.asarray(got_g["exit_gate"]["weight"]))
     assert float(got_g["exit_gate"]["bias"]) == 0.0
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
     once = PassedOnce(cfg, attn_impl="xla")
     assert once.post_attn_norm_key and once.post_ffn_norm_key
     plain = {k: v for k, v in params.items() if k != "exit_gate"}
@@ -227,7 +182,7 @@ def test_one_pass_is_the_llama_block_with_the_two_post_norms():
         loss, grads = jax.jit(jax.value_and_grad(once.make_loss(mesh)))(
             plain, ids, tgt, pos)
     assert abs(float(got) - float(loss)) <= 1e-6 * abs(float(loss))
-    err, at = leaves_differ(grads, {k: got_g[k] for k in plain})
+    err, at = worst_leaf(grads, {k: got_g[k] for k in plain})
     assert err <= 1e-5, at
 
 
@@ -245,15 +200,15 @@ def test_fewer_passes_or_no_norm_between_them_is_another_model(variant):
                                    detail["loss_exit"][0], rtol=1e-6)
         assert abs(float(other_detail["loss_exit"][2])
                    - float(detail["loss_exit"][2])) > 1e-3
-    assert leaves_differ(want_g["layers"], other_g["layers"])[0] > 0.05
-    (got, _), got_g = program(cfg, params)
-    assert leaves_differ(want_g["layers"], got_g["layers"])[0] <= 1e-4
+    assert worst_leaf(want_g["layers"], other_g["layers"])[0] > 0.05
+    (got, _), got_g = program(cfg)
+    assert worst_leaf(want_g["layers"], got_g["layers"])[0] <= 1e-4
 
 
 def test_bfloat16_stays_in_its_band():
     cfg = tiny("bfloat16")
     params, ((want, _), _) = reference(tiny())
-    (got, counters), _ = program(cfg, params)
+    (got, counters), _ = program(cfg, params_of=tiny())
     assert abs(float(got) - float(want)) <= 2e-2 * abs(float(want))
     assert float(np.sum(counters["exit_p_mean"])) == pytest.approx(
         1.0, abs=1e-5)
@@ -266,9 +221,9 @@ def test_every_remat_rung_gives_the_same_loss_and_gradients(remat):
     cfg = tiny()
     params, ((want, _), want_g) = reference(cfg)
     tp = 2 if remat in ("attn_proj", "dots") else 1
-    (got, _), got_g = program(cfg, params, tp, attn_impl="xla", remat=remat)
+    (got, _), got_g = program(cfg, tp, attn_impl="xla", remat=remat)
     assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    err, at = leaves_differ(want_g, got_g)
+    err, at = worst_leaf(want_g, got_g)
     assert err <= 1e-4, at
 
 
@@ -387,17 +342,8 @@ def test_the_forward_hands_back_the_last_exits_logits():
 
 def test_the_train_step_trains_and_counts_its_exits():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, attn_impl="xla")
-    params = model.init(jax.random.key(0))
-    opt = init_adam_state(params)
-    step = build_train_step(model, mesh,
-                            OptimizerConfig(lr=3e-3, warmup_steps=2),
-                            with_grad_norm=True, with_counters=True)
-    ids, tgt, pos = batch(cfg, b=4, t=64)
-    losses = []
-    for _ in range(8):
-        params, opt, (loss, _, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, _, c), (mesh, model, *_) = R.train(
+        cfg, tp=1, steps=8, b=4, max_steps=20000, attn_impl="xla")
     assert np.isfinite(losses).all() and min(losses[-3:]) < losses[0]
     c = jax.device_get(c)
     assert set(c) == {"loss_main", "loss_exit", "exit_p_mean",
@@ -408,10 +354,8 @@ def test_the_train_step_trains_and_counts_its_exits():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "loop_llama", "--model", "tiny-loop-llama",
         "--tp_size", "2", "--data_path", str(tokens),
@@ -543,27 +487,6 @@ STANDING = {"ssm_moe": ("tiny-ssm-moe", "17ff3dd5ace75c97"),
             "loop_llama": ("tiny-loop-llama", "93ef26ecbe667867")}
 
 
-def lowered_step(family, cfg, shape=(4, 256)):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct(shape, np.int32)
-    kw = dict(with_counters=True) if cfg.family_facts else {}
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, **kw)
-    return step.lower(params, opt, ids, ids, ids)
-
-
-def lowered_text(family, cfg, shape=(4, 256), debug_info=False):
-    import re
-    lowered = lowered_step(family, cfg, shape)
-    if debug_info:
-        return lowered.as_text(debug_info=True)
-    return re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "",
-                  lowered.as_text())
-
-
 @pytest.mark.parametrize("family", sorted(STANDING))
 def test_a_standing_family_lowers_to_the_text_the_parent_lowered_it_to(
         family):
@@ -641,7 +564,7 @@ def test_the_benchmarks_family_file_is_pinned_to_the_vanilla_file():
                                rtol=1e-6)
     np.testing.assert_allclose(more["exit_p_mean"], detail["exit_p_mean"],
                                rtol=1e-5)
-    err, at = leaves_differ(their_grads, grads)
+    err, at = worst_leaf(their_grads, grads)
     assert err <= 1e-4, at
     published_file = json.loads(
         (ROOT / "benchmark" / "configs" / "ouro-2.6b.json").read_text())

@@ -35,49 +35,34 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, hold_leaves, hold_loss, jitted,
+                           lowered_text, on_one_device, picked_rung,
+                           token_file)
+from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    IGNORE_INDEX, HyperConnectionConfig, LatentMoEConfig, MeshConfig,
-    ModelConfig, OptimizerConfig, model_preset)
+    IGNORE_INDEX, HyperConnectionConfig, LatentMoEConfig, ModelConfig,
+    model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (build_model,
                                                          facts_family)
 from distributed_pytorch_from_scratch_tpu.models.mhc_mla_moe import (
     HyperLatentMoETransformer)
 from distributed_pytorch_from_scratch_tpu.models.vanilla_mhc_mla_moe import (
     mixer_maps, vanilla_logits, vanilla_loss, yarn_tables)
-from distributed_pytorch_from_scratch_tpu.obs import trace as obs_trace
 from distributed_pytorch_from_scratch_tpu.ops.rope import (YarnScaling,
                                                            rope_angles,
                                                            yarn_inv_freq)
 from distributed_pytorch_from_scratch_tpu.parallel.hyper import StreamMixer
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training import memory
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     moe_counters_summary)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
-from distributed_pytorch_from_scratch_tpu.training.train_step import (
-    build_train_step)
 
 FAMILY = "mhc_mla_moe"
-
-
-def tiny(compute_dtype="float32", **latent):
-    cfg = model_preset("tiny-mhc-mla-moe", compute_dtype=compute_dtype)
-    return dataclasses.replace(
-        cfg, latent_moe=dataclasses.replace(cfg.latent_moe, **latent))
-
-
-def batch(cfg, b=2, t=128, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model(FAMILY, cfg, tp_size=tp, **kw)
+# the family's own: its reference, sequences of 128 from id 0 up, and one
+# target that is no target (the loss's mask is in every comparison)
+R = Recipe(FAMILY, vanilla_loss, vanilla_logits, t=128, low=0,
+           ignore=((0, 5),))
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 class MixersInterpreted(HyperLatentMoETransformer):
@@ -104,64 +89,46 @@ def test_loss_logits_and_every_gradient_leaf_equal_the_reference(tp, impl,
     `mixers_interpret`: the mixers' Pallas kernels under the interpreter
     (ops/pallas/stream_mixer.py), at a width they hold."""
     cfg = tiny(experts_held=4, expert_offset=2, num_nextn_predict_layers=mtp)
+    how = dict(attn_impl=impl)
     if impl == "mixers_interpret":
         cfg = dataclasses.replace(cfg, attn_dim=128)
-        mesh, _ = on_mesh(cfg, tp)
-        model = MixersInterpreted(cfg, tp_size=tp, attn_impl="xla")
+        how = dict(attn_impl="xla", cls=MixersInterpreted)
+        model = MixersInterpreted(cfg)
         assert model.stream_mixer.interpret and model.exit_mixer.interpret
-    else:
-        mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    tgt = tgt.copy()
-    tgt[0, 5] = IGNORE_INDEX
-    sharded = jax.device_put(params, model.shardings(mesh))
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            sharded, ids, tgt, pos)
-        want_logits = jax.jit(
-            lambda p: vanilla_logits(cfg, p, ids, pos))(params)
-        got_logits = model.make_forward(mesh)(sharded, ids, pos)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    np.testing.assert_allclose(got_logits[..., :cfg.vocab_size], want_logits,
-                               atol=2e-4)
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) > 45
-    mixers = 0
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        name = jax.tree_util.keystr(path)
-        # (a mixer's alpha is ONE number, a sum over every token and stream
-        # that cancels to a hundredth of its terms or less. The text's
-        # transpose adds the reference's terms in the reference's order;
-        # the kernels' backward does not, and is held to 1e-5 of what a
-        # mixer's b and W read, 3e-4, where the sum itself is smaller: the
-        # text reads the same 1.6e-9 off the reference once x64 reorders
-        # it)
-        floor = 3e-4 if impl == "mixers_interpret" and "alpha" in name \
-            else 1e-6
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)),
-                                                   floor), name
-        if "hc_" in name:
-            # every mixer leaf is reached: W, alpha and b of each joint
-            assert np.any(a), name
-            mixers += 1
-    # two mixers a segment's layers (stacked: 3 leaves each) and the exit,
-    # of the model and of the module
-    assert mixers == (2 + mtp) * 6 + (1 + mtp) * 3
+    assert batch(cfg)[1][0, 5] == IGNORE_INDEX
+    # (the reference and the parameters are one per `mtp` and width: the
+    # four layouts of a `mtp` compare with the same numbers)
+    _, (want, want_g) = R.reference(cfg)
+    got, got_g, got_logits = R.program(cfg, tp=tp, logits=True, **how)
+    hold_loss(want, got)
+    np.testing.assert_allclose(got_logits[..., :cfg.vocab_size],
+                               R.reference_logits(cfg), atol=2e-4)
+    # (a mixer's alpha is ONE number, a sum over every token and stream that
+    # cancels to a hundredth of its terms or less. The text's transpose adds
+    # the reference's terms in the reference's order; the kernels' backward
+    # does not, and is held to 1e-5 of what a mixer's b and W read, 3e-4,
+    # where the sum itself is smaller: the text reads the same 1.6e-9 off
+    # the reference once x64 reorders it)
+    names, moved = hold_leaves(
+        want_g, got_g, 1e-5, lambda name: 3e-4 if (
+            impl == "mixers_interpret" and "alpha" in name) else 1e-6)
+    assert len(names) > 45
+    # every mixer leaf is reached: W, alpha and b of each joint; two mixers
+    # a segment's layers (stacked: 3 leaves each) and the exit, of the model
+    # and of the module
+    mixers = [name for name in names if "hc_" in name]
+    assert set(mixers) <= set(moved)
+    assert len(mixers) == (2 + mtp) * 6 + (1 + mtp) * 3
 
 
 def test_the_loss_in_bfloat16_is_near_the_float32_reference():
     cfg = tiny("bfloat16", experts_held=4, expert_offset=2)
     mesh, model = on_mesh(cfg, 1, attn_impl="xla")
-    params = model.init(jax.random.key(3))
+    params = R.params(cfg)
     ids, tgt, pos = batch(cfg)
     got = model.make_loss(mesh)(params, ids, tgt, pos)
-    with jax.default_matmul_precision("highest"):
-        want = vanilla_loss(cfg, params, ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 2e-2 * abs(float(want))
+    want = jitted(lambda p: vanilla_loss(cfg, p, ids, tgt, pos), params)
+    hold_loss(want, got, 2e-2)
     # the streams are carried in the compute dtype, the maps in float32
     mixer = model.stream_mixer
     X = jnp.ones((4, 1, 8, 64), jnp.bfloat16)
@@ -277,19 +244,15 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer():
     cfg = tiny(first_k_dense_replace=0, num_nextn_predict_layers=0)
     cfg = dataclasses.replace(cfg, num_layers=1)
     ids, _, pos = batch(cfg, t=64)
-    whole = build_model(FAMILY, cfg, attn_impl="xla")
-    params = whole.init(jax.random.key(5))
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    params = R.params(cfg, 5)
 
     def last_streams(model, p):
         """The streams behind the one layer, before the exit: the program's
         own trunk."""
         def shard(p, ids, pos):
             return model._resolved(ids.shape[1])._trunk(p, ids, pos)[0]
-        from jax.sharding import PartitionSpec as P
-        return jax.jit(jax.shard_map(
-            shard, mesh=mesh, in_specs=(model.specs(), P(), P()),
-            out_specs=P()))(p, ids, pos)
+        return on_one_device(shard, (model.specs(), P(), P()), P())(
+            p, ids, pos)
 
     def no_routed(p):
         """The same tree with the held experts' down projections at zero:
@@ -385,7 +348,7 @@ def test_the_mixers_round_trip_through_canonical_and_a_checkpoint(tmp_path):
     from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
         load_checkpoint, save_checkpoint)
     model = build_model(FAMILY, tiny())
-    params = model.init(jax.random.key(4))
+    params = R.params(tiny(), 4)
     specs = model.specs()
     is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)
     assert (jax.tree.structure(params)
@@ -407,7 +370,7 @@ def test_the_mixers_round_trip_through_canonical_and_a_checkpoint(tmp_path):
     jax.tree.map(np.testing.assert_array_equal, back, params)
     save_checkpoint(str(tmp_path), 3, 1.0, canonical,
                     model.canonical_specs(), 1)
-    fresh = model.init(jax.random.key(9))
+    fresh = R.params(tiny(), 9)
     restored, _, at = load_checkpoint(str(tmp_path), 3, fresh,
                                       model.canonical_specs())
     assert at == 3
@@ -446,16 +409,7 @@ STANDING = {
 
 
 def lowered_digest(family, cfg):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct((4, 256), np.int32)
-    kw = dict(with_counters=True) if cfg.family_facts else {}
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, **kw)
-    text = step.lower(params, opt, ids, ids, ids).as_text()
-    text = re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "", text)
+    text = lowered_text(family, cfg)
     return hashlib.sha256(text.encode()).hexdigest()[:16], text
 
 
@@ -465,24 +419,6 @@ def test_a_standing_family_lowers_to_the_text_it_lowered_to(family):
     got, text = lowered_digest(family, model_preset(preset))
     assert got == digest
     assert "mhc" not in text and "hc_" not in text
-
-
-class _Seen:
-    def instant(self, name, **fields):
-        self.fields = fields
-
-
-def picked_rung(monkeypatch, family, cfg, budget_gib):
-    model = build_model(family, cfg, remat_budget_gib=budget_gib)
-    shapes = jax.eval_shape(model.init, jax.random.key(0))
-    count = lambda tree: sum(x.size for x in jax.tree.leaves(tree))
-    seen = _Seen()
-    monkeypatch.setattr(obs_trace, "_current", seen)
-    memory.select_remat_traced.cache_clear()
-    rung = memory.select_remat_traced(
-        model, count(shapes),
-        sum(count(shapes[k]) for k in model._layer_keys), 4, 256)
-    return model, rung, seen.fields
 
 
 @pytest.mark.parametrize("family", sorted(STANDING))
@@ -525,18 +461,7 @@ def test_the_new_familys_rung_is_sized_with_a_kept_input_n_by_d_wide(
 
 def test_the_train_step_counts_the_mixers_a_row_a_layer_and_the_loss_falls():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
-    opt = init_adam_state(params)
-    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
-    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
-                            with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(6):
-        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, gnorm, c), _ = R.train(cfg)
     assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
     # 1 dense + 2 expert layers + the module's: the mixers count in all
     # four, the router in the three expert layers
@@ -552,7 +477,6 @@ def test_the_train_step_counts_the_mixers_a_row_a_layer_and_the_loss_falls():
 
 
 def test_the_step_names_the_mixers_scopes():
-    _, text = lowered_digest(FAMILY, tiny())
     mesh, model = on_mesh(tiny(), 1)
     params = jax.eval_shape(model.init, jax.random.key(0))
     ids = jax.ShapeDtypeStruct((2, 64), np.int32)
@@ -564,10 +488,8 @@ def test_the_step_names_the_mixers_scopes():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", FAMILY, "--model", "tiny-mhc-mla-moe",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),

@@ -18,16 +18,18 @@ multi-token-prediction module. CPU, tiny sizes, float32.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss,
+                           mesh_of, token_file)
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    IGNORE_INDEX, LatentMoEConfig, MeshConfig, ModelConfig, OptimizerConfig,
-    model_preset)
+    IGNORE_INDEX, LatentMoEConfig, ModelConfig, OptimizerConfig)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.mla_moe import (
     LatentMoETransformer)
@@ -40,32 +42,16 @@ from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (
 from distributed_pytorch_from_scratch_tpu.ops.rope import (
     apply_rotary_interleaved, rope_angles)
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
 from distributed_pytorch_from_scratch_tpu.training.train_step import (
     build_train_step)
 
 
-def tiny(**latent):
-    cfg = model_preset("tiny-mla-moe")
-    return dataclasses.replace(
-        cfg, latent_moe=dataclasses.replace(cfg.latent_moe, **latent))
-
-
-def batch(cfg, b=2, t=128, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    model = build_model("mla_moe", cfg, tp_size=tp, **kw)
-    return mesh, model
+# the family's own: its reference, sequences of 128 from id 0 up, and an
+# ignored target in the middle
+R = Recipe("mla_moe", vanilla_loss, t=128, low=0, ignore=((0, 5),))
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 # ---- the program against the plain reference ----
@@ -76,23 +62,12 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl):
     """A job that holds experts 2..5 of 8: what the absent ones would add
     is left out by program and reference alike."""
     cfg = tiny(experts_held=4, expert_offset=2)
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    tgt = tgt.copy()
-    tgt[0, 5] = IGNORE_INDEX            # an ignored target in the middle
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) > 40
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    assert batch(cfg)[1][0, 5] == IGNORE_INDEX
+    # (the parameters and the reference are one for the three layouts)
+    _, (want, want_g) = R.reference(cfg)
+    got, got_g = R.program(cfg, tp=tp, attn_impl=impl)
+    hold_loss(want, got)
+    assert len(hold_leaves(want_g, got_g, 1e-5)[0]) > 40
     # the selection bias is read by top-k alone: no gradient reaches it
     assert not np.any(np.asarray(got_g["layers"]["moe"]["bias"]))
 
@@ -157,14 +132,6 @@ def test_flash_refuses_q_and_k_of_different_widths():
 
 
 # ---- the expert layer: shares, and no drop ----
-
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    from jax.sharding import PartitionSpec as P
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
 
 def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Four jobs hold two experts each of one layer's eight. Their routed
@@ -290,7 +257,7 @@ def test_sum_held_is_the_row_scatter_add_summed_in_float32(
         valid, r, 0).astype(jnp.float32), tok, valid)
     with jax.default_matmul_precision("highest"):
         if form.endswith("tp2"):
-            mesh = make_mesh(MeshConfig(dp=1, tp=2), devices=jax.devices()[:2])
+            mesh = mesh_of(2)
 
             def shard(y, r):
                 rank = 1.0 + jax.lax.axis_index("tp")
@@ -351,7 +318,7 @@ def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
     pairs = 4 * 214 * k
     M = moe.chunk_rows(pairs)
     assert -(-pairs // M) == chunks
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
+    mesh = mesh_of(tp=tp)
 
     def value_and_grads():
         def loss(p, x):
@@ -376,12 +343,7 @@ def test_the_layer_equals_the_scatter_form_in_value_and_every_gradient(
         add_held(y, r, tok, valid), jnp.int32(0)))
     (want, _), want_g = value_and_grads()
     assert abs(float(got) - float(want)) <= tol * max(abs(float(want)), 1.0)
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 9
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    assert len(hold_leaves(want_g, got_g, tol)[0]) == 9
 
 
 def garbage_past_the_groups(seen, garbage=jnp.nan):
@@ -412,6 +374,27 @@ def garbage_past_the_groups(seen, garbage=jnp.nan):
         lambda lhs, rhs, sizes: (ragged_dot(lhs, rhs, sizes),
                                  (lhs, rhs, sizes)), bwd)
     return ragged_dot
+
+
+def _layer_value_and_grads(moe, p, x):
+    def loss(p, x):
+        y, c = apply_moe(moe, p, x)
+        return jnp.sum(jnp.sin(y)), (y, c)
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_run(E, H, k, S, forced):
+    """(the layer, its parameters, its input, `_layer_value_and_grads` of
+    them under jax's own `ragged_dot`)."""
+    moe = SharedRoutedFFN(32, 16, E, top_k=k, held=H)
+    p = moe.init(jax.random.key(1))
+    if forced:
+        p["bias"] = jnp.zeros(E).at[jnp.array(forced)].set(10.0)
+    x = jax.random.normal(jax.random.key(2), (2, S // 2, 32))
+    return moe, p, x, _layer_value_and_grads(moe, p, x)
 
 
 @pytest.mark.parametrize("garbage", [jnp.nan, jnp.inf], ids=["nan", "inf"])
@@ -450,23 +433,11 @@ def test_no_row_past_the_groups_is_read_anywhere(
     walk's transpose (`d_x`)."""
     from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod
 
-    d, f = 32, 16
-    moe = SharedRoutedFFN(d, f, E, top_k=k, held=H)
-    p = moe.init(jax.random.key(1))
-    if forced:
-        p["bias"] = jnp.zeros(E).at[jnp.array(forced)].set(10.0)
-    x = jax.random.normal(jax.random.key(2), (2, S // 2, d))
+    # (the plain run is one for a case's NaN and its inf)
+    moe, p, x, ((_, (want, _)), want_g) = _plain_run(E, H, k, S, forced)
     M = moe.chunk_rows(S * k)
     assert -(-S * k // M) == chunks
-
-    def value_and_grads():
-        def loss(p, x):
-            y, c = apply_moe(moe, p, x)
-            return jnp.sum(jnp.sin(y)), (y, c)
-        with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
-
-    (_, (want, _)), want_g = value_and_grads()
+    value_and_grads = lambda: _layer_value_and_grads(moe, p, x)
     seen = []
     monkeypatch.setattr(jax.lax, "ragged_dot",
                         garbage_past_the_groups(seen, garbage))
@@ -498,13 +469,8 @@ def test_no_row_past_the_groups_is_read_anywhere(
     np.testing.assert_array_equal(got, again)
     assert np.all(np.isfinite(got))
     np.testing.assert_allclose(got, want, atol=1e-6)
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 9
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.all(np.isfinite(b)), jax.tree_util.keystr(path)
-        assert np.max(np.abs(a - b)) <= 1e-6 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    # (a NaN or an inf in a leaf is an error past any tolerance)
+    assert len(hold_leaves(want_g, got_g, 1e-6)[0]) == 9
 
 
 @pytest.mark.parametrize("cell,E,H,k,chunk,chunks,parents", [
@@ -545,7 +511,7 @@ def test_the_gradient_s_text_scatters_no_row_at_any_cell_s_share(
     assert moe.chunk_rows(pairs) == chunk and chunk % 512 == 0
     assert -(-pairs // chunk) == chunks
     assert chunk == moe_mod.CHUNK_SHARES * pairs * H // E
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
     params = jax.eval_shape(moe.init, jax.random.key(0))
     x = jax.ShapeDtypeStruct((2, 8192, d), jnp.bfloat16)
 
@@ -634,7 +600,8 @@ def test_fine_chunks_equal_one_expert_at_a_time_in_value_and_every_gradient(
             y, c = layer(p, x)
             return jnp.sum(jnp.sin(y)), (y, c)
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+            return jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True))(p, x)
 
     (_, (got, c)), got_g = value_and_grads(lambda p, x: apply_moe(moe, p, x))
     (_, (want, _)), want_g = value_and_grads(
@@ -649,12 +616,7 @@ def test_fine_chunks_equal_one_expert_at_a_time_in_value_and_every_gradient(
     assert int(c["rows_walked"]) == M * live == M * -(-held // M)
     assert int(c["sum_blocks"]) == live * -(-S // 256)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 6
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 2e-5 * max(np.max(np.abs(a)), 1.0), \
-            jax.tree_util.keystr(path)
+    assert len(hold_leaves(want_g, got_g, 2e-5, floor=1.0)[0]) == 6
 
 
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
@@ -689,9 +651,10 @@ def test_blocks_of_several_windows_equal_one_expert_at_a_time(
             y, c = layer(p, x)
             return jnp.sum(jnp.sin(y.astype(jnp.float32))), (y, c)
         with jax.default_matmul_precision("highest"):
-            return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(p, x)
+            return jax.jit(jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True))(p, x)
 
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
     from jax.sharding import PartitionSpec as P
     mine = jax.jit(jax.shard_map(
         lambda p, x: moe.apply(p, x, jnp.dtype(dtype)), mesh=mesh,
@@ -712,12 +675,7 @@ def test_blocks_of_several_windows_equal_one_expert_at_a_time(
     scale = max(float(jnp.max(jnp.abs(want))), 1.0)
     assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - want))) \
         <= tol * scale
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 6
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(a)), 1.0), \
-            jax.tree_util.keystr(path)
+    assert len(hold_leaves(want_g, got_g, tol, floor=1.0)[0]) == 6
 
 
 @pytest.mark.parametrize("dp,tp,E,H", [
@@ -743,7 +701,7 @@ def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp, E, H):
     p["router"] = p["router"].at[:, 0].set(0.0)
     x = x.at[0, :, 0].set(3.0).at[1, :, 0].set(-3.0)
     p["router"] = p["router"].at[0, 0].set(4.0)
-    mesh = make_mesh(MeshConfig(dp=dp, tp=tp), devices=jax.devices()[:dp * tp])
+    mesh = mesh_of(tp, dp)
 
     def layer(p, x):
         def shard(p, x):
@@ -772,11 +730,7 @@ def test_the_walk_stops_at_each_data_shards_own_last_held_row(dp, tp, E, H):
     assert int(c["rows_here"]) == int(c["rows_computed"]) == sum(per_shard)
     assert int(c["rows_walked"]) == sum(M * -(-n // M) for n in per_shard)
     np.testing.assert_allclose(got, want, atol=2e-5)
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 2e-5 * max(np.max(np.abs(a)), 1.0), \
-            jax.tree_util.keystr(path)
+    hold_leaves(want_g, got_g, 2e-5, floor=1.0)
 
 
 @pytest.mark.parametrize("E,H,k,pairs,chunk", [
@@ -937,7 +891,7 @@ def test_no_scalar_gather_or_scatter_is_left_in_the_layer(share, E, H, k):
     p = moe.init(jax.random.key(1))
     x = jax.random.normal(jax.random.key(2), (4, 214, d))
 
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
+    mesh = mesh_of()
 
     def layer(p, x):
         y, _ = jax.shard_map(
@@ -967,18 +921,8 @@ def test_no_scalar_gather_or_scatter_is_left_in_the_layer(share, E, H, k):
 
 def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
-    opt = init_adam_state(params)
-    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
-    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
-                            with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(6):
-        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, gnorm, c), (mesh, model, params, opt, (ids, tgt, pos)) = (
+        R.train(cfg))
     assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
     assert c["routed"].shape == (3, 8) and c["rows_here"].shape == (3,)
     # every token takes top_k experts in each of the 2 + 1 expert layers
@@ -994,15 +938,14 @@ def test_the_train_step_returns_counters_when_asked_and_the_loss_falls():
     assert 1.0 <= summary["sum_windows_per_block"] <= 2.0
     assert summary["load_max_over_mean"] >= 1.0
     # off by default: the step's output is what it has always been
-    plain = build_train_step(model, mesh, ocfg, with_grad_norm=True)
+    plain = build_train_step(model, mesh, OptimizerConfig(),
+                             with_grad_norm=True)
     assert len(plain(params, opt, ids, tgt, pos)[2]) == 2
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "mla_moe", "--model", "tiny-mla-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),

@@ -8,6 +8,7 @@ import re
 
 import jax
 import pytest
+from family_recipe import TINY_PRESETS, mesh_of
 
 from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          BdMoEConfig,
@@ -153,14 +154,6 @@ def config_for(family, config):
         experts_held=held, hyper=hyper, **LATENT))
 
 
-TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
-                "gdn_moe": "tiny-gdn-moe", "conv_moe": "tiny-conv-moe",
-                "bd_moe": "tiny-bd-moe", "swa_moe": "tiny-swa-moe",
-                "early_moe": "tiny-early-moe",
-                "mhc_mla_moe": "tiny-mhc-mla-moe",
-                "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe",
-                "loop_llama": "tiny-loop-llama",
-                "ssm_dense": "tiny-ssm-dense", "dsa_moe": "tiny-dsa-moe"}
 families = pytest.mark.parametrize("family", sorted(FAMILIES))
 configs = pytest.mark.parametrize("config", sorted(CONFIGS))
 
@@ -353,10 +346,7 @@ def test_a_family_with_facts_needs_them_and_its_experts(family):
 
 
 def _tiny_on_one_device(family):
-    from distributed_pytorch_from_scratch_tpu.config import MeshConfig
-    from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    return mesh, build_model(family, model_preset(TINY_PRESETS[family]))
+    return mesh_of(), build_model(family, model_preset(TINY_PRESETS[family]))
 
 
 @drawn
@@ -380,7 +370,9 @@ def test_decode_and_serving_refuse_the_family(family):
     from distributed_pytorch_from_scratch_tpu.serving.engine import (
         ContinuousBatchingEngine, PagedEngine)
     mesh, model = _tiny_on_one_device(family)
-    params = model.init(jax.random.key(0))
+    # (the refusal comes before a parameter is read: a tree of the right
+    # shapes is enough, and `init` was six seconds a family)
+    params = _shapes(model)
     for build in (lambda: GreedyDecoder(model, mesh, 32),
                   lambda: make_generate(model, mesh, 32),
                   lambda: ContinuousBatchingEngine(model, mesh, params, 2,

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import outputs_and_grads
 
 from distributed_pytorch_from_scratch_tpu.obs.attribution import (
     flash_tile_stats)
@@ -122,9 +123,13 @@ def test_the_kernels_under_the_window_equal_the_dense_path(
         assert flash_bwd_calls(kernel, q, k, v) == [
             ("flash_bwd_window", [1] * 9)]
     dense = lambda q, k, v: masked_attention_xla(q, k, v, mask)
-    np.testing.assert_allclose(kernel(q, k, v), dense(q, k, v), atol=2e-5)
-    got = jax.grad(lambda *a: jnp.sum(kernel(*a) * wt), (0, 1, 2))(q, k, v)
-    want = jax.grad(lambda *a: jnp.sum(dense(*a) * wt), (0, 1, 2))(q, k, v)
+    # (a side's output and its three gradients are one compiled program)
+    weigh = lambda o: jnp.sum(o * wt)
+    (o,), got = outputs_and_grads(lambda *a: (kernel(*a),), weigh, q, k, v,
+                                  precision=None)
+    (o_dense,), want = outputs_and_grads(lambda *a: (dense(*a),), weigh, q, k,
+                                         v, precision=None)
+    np.testing.assert_allclose(o, o_dense, atol=2e-5)
     for a, b in zip(got, want):
         np.testing.assert_allclose(a, b, atol=1e-4)
 

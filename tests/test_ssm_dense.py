@@ -24,9 +24,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (
+    Recipe, hold_leaves, hold_loss, leaf_errors, mesh_of, token_file)
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    MeshConfig, ModelConfig, OptimizerConfig, SsmDenseConfig, model_preset)
+    ModelConfig, OptimizerConfig, SsmDenseConfig)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          build_model)
 from distributed_pytorch_from_scratch_tpu.models import (
@@ -34,7 +36,6 @@ from distributed_pytorch_from_scratch_tpu.models import (
 from distributed_pytorch_from_scratch_tpu.models.ssm_dense import (
     SsmDenseTransformer, layer_counts)
 from distributed_pytorch_from_scratch_tpu.parallel import mamba
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training import memory
 from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
     load_checkpoint, save_checkpoint)
@@ -56,12 +57,17 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 LOSS_RTOL, LEAF_RTOL = 1e-5, 5e-5
 
 
+# the family's own: its reference, and sequences of 80 from id 0 up
+R = Recipe(FAMILY, ref.vanilla_loss, t=80, low=0)
+batch = R.batch
+
+
 def tiny(dtype="float32", **facts):
-    cfg = model_preset("tiny-ssm-dense", compute_dtype=dtype)
+    """(its own: the pattern's length is the model's depth)"""
+    cfg = R.tiny(dtype, **facts)
     if "layer_types" in facts:
         cfg = dataclasses.replace(cfg, num_layers=len(facts["layer_types"]))
-    return dataclasses.replace(
-        cfg, ssm_dense=dataclasses.replace(cfg.ssm_dense, **facts))
+    return cfg
 
 
 def published(layers=40, vocab=100_352):
@@ -80,42 +86,23 @@ def published(layers=40, vocab=100_352):
             attention_multiplier=0.015625, logits_scaling=8.0))
 
 
-def batch(cfg, b=2, t=80, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
-
-
 def on_mesh(cfg, dp=1, **kw):
-    mesh = make_mesh(MeshConfig(dp=dp, tp=1), devices=jax.devices()[:dp])
-    return mesh, build_model(FAMILY, cfg, **kw)
+    """(its own: the family refuses `tp`, so a file's second axis is dp)"""
+    return R.on_mesh(cfg, dp=dp, **kw)
 
 
-def compare(cfg, want_cfg=None, dp=1, t=80, seed=3, **kw):
-    """The program built from `cfg` against the reference of `want_cfg`
-    (None: the same facts): (the loss's relative error, the worst leaf's
-    error over its largest entry, the leaves compared, those with a
-    gradient)."""
-    mesh, model = on_mesh(cfg, dp, **kw)
-    params = model.init(jax.random.key(seed))
-    ids, tgt, pos = batch(cfg, t=t)
-    want_cfg = cfg if want_cfg is None else want_cfg
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: ref.vanilla_loss(want_cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g))
-    worst, moved = 0.0, 0
-    for (_, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        worst = max(worst, float(np.max(np.abs(a - b))
-                                 / max(np.max(np.abs(a)), 1e-6)))
-        moved += bool(np.any(a != 0))
-    return (abs(float(got) - float(want)) / abs(float(want)), worst,
-            len(flat), moved)
+def compare(cfg, dp=1, cached=True, **kw):
+    """The program built from `cfg` against the reference of the published
+    facts, `tiny()` (the token-by-token reference reads no chunk size, so
+    one reference and one set of parameters serve every case of the file's
+    grids): (the loss's relative error, the worst leaf's error over its
+    largest entry, the leaves compared, those with a gradient).
+    `cached=False` where a test has patched either side."""
+    _, (want, want_g) = R.reference(tiny(), cached=cached)
+    got, got_g = R.program(cfg, dp=dp, params_of=tiny(), cached=cached, **kw)
+    errors = leaf_errors(want_g, got_g)
+    return (abs(float(got) - float(want)) / abs(float(want)),
+            max(errors)[0], len(errors), sum(m for _, _, m in errors))
 
 
 # ---- the program against the plain reference ----
@@ -144,7 +131,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(dp, impl, chunk):
 def test_the_forward_hands_back_the_references_logits():
     cfg = tiny()
     mesh, model = on_mesh(cfg)
-    params = model.init(jax.random.key(5))
+    params = R.params(cfg, 5)
     ids, _, pos = batch(cfg, t=48)
     with jax.default_matmul_precision("highest"):
         want = jax.jit(lambda p: ref.vanilla_logits(cfg, p, ids))(params)
@@ -165,7 +152,7 @@ def test_a_scalar_at_its_neutral_value_fails_the_comparison(neutral):
     four can be dropped inside a tolerance. (A fresh model's LOSS hardly
     sees them: 2e-6 for the softmax's, which is why the comparison holds
     every leaf.)"""
-    loss, leaf, _, _ = compare(tiny(**neutral), want_cfg=tiny())
+    loss, leaf, _, _ = compare(tiny(**neutral))
     assert leaf > 1000 * LEAF_RTOL, (loss, leaf)
 
 
@@ -195,7 +182,7 @@ def test_the_norm_before_the_gate_fails_the_comparison(monkeypatch):
         return (p["norm"] * y * jax.nn.silu(z)) @ p["w_out"]
 
     monkeypatch.setattr(ref, "_mamba", norm_first)
-    loss, leaf, _, _ = compare(tiny())
+    loss, leaf, _, _ = compare(tiny(), cached=False)
     assert leaf > 1000 * LEAF_RTOL, (loss, leaf)
 
 
@@ -205,7 +192,7 @@ def test_bfloat16_decays_fail_the_comparison(monkeypatch):
     sums reach -17 on these weights, where bfloat16's step is 1/16."""
     monkeypatch.setattr(mamba, "ssd", functools.partial(
         mamba.ssd, state_dtype=jnp.bfloat16))
-    loss, leaf, _, _ = compare(tiny())
+    loss, leaf, _, _ = compare(tiny(), cached=False)
     assert leaf > 100 * LEAF_RTOL, (loss, leaf)     # 0.034 read
 
 
@@ -215,19 +202,15 @@ def test_in_bfloat16_loss_and_gradients_are_the_references_to_its_rounding():
     relative L2 (no router: no choice flips)."""
     cfg = tiny("bfloat16")
     mesh, model = on_mesh(cfg)
-    params = model.init(jax.random.key(0))
+    params, (want, want_g) = R.reference(cfg, t=96, seed=0)
     ids, tgt, pos = batch(cfg, t=96)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: ref.vanilla_loss(cfg, p, ids, tgt, pos)))(params)
+    # (the program at the backend's own products: not `R.program`'s)
     got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
         params, ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 2e-3 * abs(float(want))
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g)):
-        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
-        assert np.linalg.norm(a - b) <= 0.06 * np.linalg.norm(a), \
-            jax.tree_util.keystr(path)
+    hold_loss(want, got, 2e-3)
+    l2 = lambda b, a: (np.linalg.norm(np.float64(a) - np.float64(b))
+                       / np.linalg.norm(np.float64(a)))
+    hold_leaves(want_g, got_g, 0.06, err=l2)
 
 
 # ---- the pattern, the parameters' other forms ----
@@ -280,7 +263,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
         tmp_path):
     cfg = tiny()
     mesh, model = on_mesh(cfg, 2)
-    params = model.init(jax.random.key(1))
+    params = R.params(cfg, 1)
     assert model._pattern == ((("mamba_layers_0", 2), ("attn_layers_0", 1)),
                               (("mamba_layers_1", 1),))
     assert params["mamba_layers_0"]["mamba"]["w_in"].shape == (
@@ -297,8 +280,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
     canonical = model.to_canonical(params)
     save_checkpoint(str(tmp_path), 3, 1.0, canonical,
                     model.canonical_specs(), 1)
-    restored, _, at = load_checkpoint(str(tmp_path), 3,
-                                      model.init(jax.random.key(9)),
+    restored, _, at = load_checkpoint(str(tmp_path), 3, R.params(cfg, 9),
                                       model.canonical_specs())
     assert at == 3
     jax.tree.map(np.testing.assert_array_equal, restored, params)
@@ -309,8 +291,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
 def test_the_train_step_counts_its_decays_and_the_residuals_rms():
     cfg = tiny()
     mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
+    params = jax.device_put(R.params(cfg, 0), model.shardings(mesh))
     opt = init_adam_state(params)
     ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
     step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
@@ -356,10 +337,8 @@ def test_the_multipliers_hold_the_residuals_rms():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", FAMILY, "--model", "tiny-ssm-dense",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
@@ -396,7 +375,7 @@ def test_decoding_and_the_hand_reduced_gradients_are_refused():
     assert not model.decodable and not model.hand_reduced_grads
     with pytest.raises(ValueError, match="cannot be decoded or served"):
         require_decodable(model)
-    mesh = make_mesh(MeshConfig(dp=2, tp=1), devices=jax.devices()[:2])
+    mesh = mesh_of(1, 2)
     with pytest.raises(ValueError, match="ZeRO stage 2 is not made to work"):
         build_train_step(model, mesh, OptimizerConfig(), zero=2)
 

@@ -21,16 +21,18 @@ Nemotron 3 publishes it) against its plain float32 reference
 import dataclasses
 import hashlib
 import json
-import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from family_recipe import (Recipe, apply_moe, hold_leaves, hold_loss, jitted,
+                           lowered_text, on_one_device, outputs_and_grads,
+                           token_file)
 from jax.sharding import PartitionSpec as P
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    MeshConfig, ModelConfig, OptimizerConfig, SsmMoEConfig, model_preset)
+    ModelConfig, SsmMoEConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import (FAMILIES,
                                                          build_model)
 from distributed_pytorch_from_scratch_tpu.models import vanilla_ssm_moe as ref
@@ -40,43 +42,37 @@ from distributed_pytorch_from_scratch_tpu.ops.ssd import (
     ssd, ssd_flops_per_token)
 from distributed_pytorch_from_scratch_tpu.parallel.moe import (
     ACTIVATIONS, SharedRoutedFFN)
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training import memory
 from distributed_pytorch_from_scratch_tpu.training.checkpoint import (
     load_checkpoint, save_checkpoint)
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
-from distributed_pytorch_from_scratch_tpu.training.optim import (
-    init_adam_state)
-from distributed_pytorch_from_scratch_tpu.training.train_step import (
-    build_train_step)
 
 FAMILY = "ssm_moe"
 
 
+# the family's own: its reference, and sequences of 80 from id 0 up
+R = Recipe(FAMILY, ref.vanilla_loss, t=80, low=0)
+batch = R.batch
+
+
 def tiny(dtype="float32", **facts):
-    cfg = model_preset("tiny-ssm-moe", compute_dtype=dtype)
+    """(its own: the pattern's length is the model's depth)"""
+    cfg = R.tiny(dtype, **facts)
     if "hybrid_override_pattern" in facts:
         cfg = dataclasses.replace(
             cfg, num_layers=len(facts["hybrid_override_pattern"]))
-    return dataclasses.replace(
-        cfg, ssm_moe=dataclasses.replace(cfg.ssm_moe, **facts))
-
-
-def batch(cfg, b=2, t=80, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(0, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    pos = np.tile(np.arange(t, dtype=np.int32), (b, 1))
-    return ids[:, :-1], ids[:, 1:], pos
+    return cfg
 
 
 def on_mesh(cfg, dp=1, **kw):
-    mesh = make_mesh(MeshConfig(dp=dp, tp=1), devices=jax.devices()[:dp])
-    return mesh, build_model(FAMILY, cfg, **kw)
+    """(its own: the family refuses `tp`, so a file's second axis is dp)"""
+    return R.on_mesh(cfg, dp=dp, **kw)
 
 
 def rel(a, b):
-    """The relative L2 error of a against b."""
+    """The relative L2 error of a against b (its own: what bfloat16 and the
+    kernels are held by; the recipe's measure is a leaf's largest entry)."""
     a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
     return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
 
@@ -95,27 +91,14 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(dp, impl, mtp):
     `dt_bias`, whose gradients exist only through the decays, among them)."""
     cfg = tiny(experts_held=8, expert_offset=4,
                num_nextn_predict_layers=mtp)
-    mesh, model = on_mesh(cfg, dp, attn_impl=impl)
-    params = model.init(jax.random.key(3))
-    ids, tgt, pos = batch(cfg)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: ref.vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-        got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g))
-    moved = 0
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 5e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
-        moved += bool(np.any(a != 0))
+    # (the parameters and the reference are one per `mtp`)
+    _, (want, want_g) = R.reference(cfg)
+    got, got_g = R.program(cfg, dp=dp, attn_impl=impl)
+    hold_loss(want, got)
+    names, moved = hold_leaves(want_g, got_g, 5e-5)
     # every leaf but the selection biases has a gradient
-    biases = sum(jax.tree_util.keystr(p).endswith("['moe']['bias']")
-                 for p, _ in flat)
-    assert biases == 1 + mtp and moved == len(flat) - biases
+    biases = sum(name.endswith("['moe']['bias']") for name in names)
+    assert biases == 1 + mtp and len(moved) == len(names) - biases
 
 
 def test_in_bfloat16_loss_and_gradients_are_the_references_to_its_rounding():
@@ -128,26 +111,20 @@ def test_in_bfloat16_loss_and_gradients_are_the_references_to_its_rounding():
     2: `moe_grad`'s sound readings)."""
     cfg = tiny("bfloat16")
     mesh, model = on_mesh(cfg)
-    params = model.init(jax.random.key(0))
+    params, (want, want_g) = R.reference(cfg, t=96, seed=0)
     ids, tgt, pos = batch(cfg, t=96)
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.jit(jax.value_and_grad(
-            lambda p: ref.vanilla_loss(cfg, p, ids, tgt, pos)))(params)
+    # (the program at the backend's own products: not `R.program`'s)
     got, got_g = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
         params, ids, tgt, pos)
-    assert abs(float(got) - float(want)) <= 2e-3 * abs(float(want))
-    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(want_g),
-                            jax.tree.leaves(got_g)):
-        name = jax.tree_util.keystr(path)
-        if not np.any(np.asarray(a)):
-            continue
-        assert rel(b, a) <= (0.4 if "moe" in name else 0.06), name
+    hold_loss(want, got, 2e-3)
+    hold_leaves(want_g, got_g, lambda name: 0.4 if "moe" in name else 0.06,
+                err=lambda b, a: rel(b, a) if np.any(a) else 0.0)
 
 
 def test_the_module_is_its_own_loss_term_and_counts_its_expert_layer():
     cfg = tiny()
     mesh, model = on_mesh(cfg)
-    params = model.init(jax.random.key(1))
+    params = R.params(cfg, 1)
     ids, tgt, pos = batch(cfg)
     loss, c = model.make_loss(mesh, with_counters=True)(params, ids, tgt, pos)
     np.testing.assert_allclose(
@@ -155,8 +132,8 @@ def test_the_module_is_its_own_loss_term_and_counts_its_expert_layer():
         rtol=1e-6)
     without = dataclasses.replace(cfg, ssm_moe=dataclasses.replace(
         cfg.ssm_moe, num_nextn_predict_layers=0))
-    with jax.default_matmul_precision("highest"):
-        main = ref.vanilla_loss(without, params, ids, tgt, pos)
+    main = jitted(lambda p: ref.vanilla_loss(without, p, ids, tgt, pos),
+                  params)
     np.testing.assert_allclose(float(c["loss_main"]), float(main), rtol=1e-5)
     # three expert layers and the module's; three Mamba layers, none of the
     # module's (its pattern is `*E`)
@@ -192,13 +169,13 @@ def test_the_chunked_recurrence_equals_the_token_by_token_one(t, chunk, H, G):
     another order."""
     args = ssd_inputs(t, H, G)
     w = jax.random.normal(jax.random.key(9), (2, t, H, 8))
-    with jax.default_matmul_precision("highest"):
-        want, want_g = jax.value_and_grad(
-            lambda *a: jnp.sum(token_by_token(*a) * w), range(5))(*args)
-        got, got_g = jax.value_and_grad(
-            lambda *a: jnp.sum(ssd(*a, chunk=chunk)[0] * w), range(5))(*args)
-        y, decay_min = ssd(*args, chunk=chunk)
-    np.testing.assert_allclose(got, want, rtol=2e-5)
+    weigh = lambda y, *_: jnp.sum(y * w)
+    # (a side's value and its five gradients are one compiled program)
+    (want_y,), want_g = outputs_and_grads(
+        lambda *a: (token_by_token(*a),), weigh, *args)
+    (y, decay_min), got_g = outputs_and_grads(
+        lambda *a: ssd(*a, chunk=chunk), weigh, *args)
+    np.testing.assert_allclose(weigh(y), weigh(want_y), rtol=2e-5)
     for a, b in zip(got_g, want_g):
         assert np.max(np.abs(a - b)) <= 2e-5 * np.max(np.abs(b))
     assert y.shape == (2, t, H, 8) and float(decay_min) < 0.0
@@ -237,16 +214,19 @@ def test_the_kernels_equal_the_text_and_the_token_by_token_recurrence(
     f32 = lambda a: a.astype(jnp.float32)
 
     def run(fn, args):
-        loss = lambda *a: jnp.sum(f32(fn(*a)) * w)
-        return [fn(*args), *jax.grad(loss, range(5))(*args)]
+        """[y, the five gradients, decay_min (None of the recurrence)] of
+        one compiled program (three eager runs of `fn` a side until PR
+        74)."""
+        (y, low), grads = outputs_and_grads(
+            lambda *a: (*fn(*a), None)[:2],
+            lambda y, _: jnp.sum(f32(y) * w), *args)
+        return [y, *grads, low]
 
-    with jax.default_matmul_precision("highest"):
-        want = run(token_by_token, [f32(a) for a in args])
-        text = run(lambda *a: ssd(*a, chunk=chunk)[0], args)
-        got = run(lambda *a: ssd(*a, chunk=chunk, interpret=True)[0], args)
-        low = [float(ssd(*args, chunk=chunk, interpret=i)[1])
-               for i in (False, True)]
-    assert low[0] == low[1] < 0.0
+    *want, _ = run(lambda *a: (token_by_token(*a),), [f32(a) for a in args])
+    *text, low_text = run(lambda *a: ssd(*a, chunk=chunk), args)
+    *got, low_got = run(lambda *a: ssd(*a, chunk=chunk, interpret=True),
+                        args)
+    assert float(low_text) == float(low_got) < 0.0
     assert got[0].dtype == dtype and got[0].shape == (2, t, H, 64)
     for name, a, b, c in zip(("y", "dx", "ddt", "dA", "dB", "dC"), got, text,
                              want):
@@ -309,13 +289,6 @@ def test_the_recurrences_flops_by_hand():
 
 # ---- the expert FFN's new facts, and its defaults ----
 
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
-
 def test_the_expert_ffns_defaults_are_the_leaves_it_always_made():
     moe = SharedRoutedFFN(32, 16, 8, top_k=2)
     p = moe.init(jax.random.key(0))
@@ -368,7 +341,6 @@ def test_two_matrix_experts_in_a_latent_equal_experts_applied_one_by_one(
 def sublayer(model, lp, x):
     """What the program's layer adds to the residual stream: `_layer_body`
     on one layer's parameters, less its input."""
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
     pos = jnp.zeros(x.shape[:2], jnp.int32)
     specs = jax.tree.map(lambda _: P(), lp)
 
@@ -377,8 +349,7 @@ def sublayer(model, lp, x):
             x, lp, (), pos, jnp.float32)
         return out - x
 
-    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs, P()),
-                                 out_specs=P()))(lp, x)
+    return on_one_device(body, (specs, P()), P())(lp, x)
 
 
 def one_layer(letter, **facts):
@@ -389,7 +360,7 @@ def one_layer(letter, **facts):
     cfg = dataclasses.replace(cfg, **{k: v for k, v in facts.items()
                                       if k in ("num_heads", "num_kv_heads")})
     model = build_model(FAMILY, cfg)
-    params = model.init(jax.random.key(5))
+    params = R.params(cfg, 5)
     key = model._layer_keys[0]
     return cfg, model, jax.tree.map(lambda a: a[0, 0], params[key])
 
@@ -487,7 +458,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
         tmp_path):
     cfg = tiny()
     mesh, model = on_mesh(cfg, 2)
-    params = model.init(jax.random.key(1))
+    params = R.params(cfg, 1)
     assert layer_counts(cfg) == {"mamba": 3, "attn": 1, "moe": 3,
                                  "mtp_mamba": 0, "mtp_attn": 1, "mtp_moe": 1}
     assert model._pattern == ((("moe_layers_0", 1), ("mamba_layers_0", 1)),
@@ -504,8 +475,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
                  model.from_canonical(canonical), params)
     save_checkpoint(str(tmp_path), 3, 1.0, canonical,
                     model.canonical_specs(), 1)
-    restored, _, at = load_checkpoint(str(tmp_path), 3,
-                                      model.init(jax.random.key(9)),
+    restored, _, at = load_checkpoint(str(tmp_path), 3, R.params(cfg, 9),
                                       model.canonical_specs())
     assert at == 3
     jax.tree.map(np.testing.assert_array_equal, restored, params)
@@ -515,18 +485,7 @@ def test_parameters_round_trip_through_the_canonical_form_and_a_checkpoint(
 
 def test_the_train_step_returns_the_decays_rows_and_the_loss_falls():
     cfg = tiny()
-    mesh, model = on_mesh(cfg, 2)
-    params = jax.device_put(model.init(jax.random.key(0)),
-                            model.shardings(mesh))
-    opt = init_adam_state(params)
-    ocfg = OptimizerConfig(lr=3e-3, warmup_steps=2, max_steps=20)
-    step = build_train_step(model, mesh, ocfg, with_grad_norm=True,
-                            with_counters=True)
-    ids, tgt, pos = batch(cfg, t=64)
-    losses = []
-    for _ in range(6):
-        params, opt, (loss, gnorm, c) = step(params, opt, ids, tgt, pos)
-        losses.append(float(loss))
+    losses, (_, gnorm, c), _ = R.train(cfg, tp=1, dp=2)
     assert losses[-1] < losses[0] and np.isfinite(float(gnorm))
     # a row a Mamba layer (3), a row an expert layer (3 and the module's)
     assert c["ssm_decay_min"].shape == (3,) and c["routed"].shape == (4, 16)
@@ -538,10 +497,8 @@ def test_the_train_step_returns_the_decays_rows_and_the_loss_falls():
 
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", FAMILY, "--model", "tiny-ssm-moe",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
@@ -713,22 +670,6 @@ STANDING = {
     # ladder's names (parallel/kda.py); the other eight are the parent's
     "kda_mla_moe": ("tiny-kda-mla-moe", "7e367f7a5ca3d3da"),
 }
-
-
-def lowered_text(family, cfg, shape=(4, 256), debug_info=False):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    model = build_model(family, cfg)
-    params = jax.eval_shape(model.init, jax.random.key(0))
-    opt = jax.eval_shape(init_adam_state, params)
-    ids = jax.ShapeDtypeStruct(shape, np.int32)
-    kw = dict(with_counters=True) if cfg.family_facts else {}
-    step = build_train_step(model, mesh, OptimizerConfig(),
-                            with_grad_norm=True, **kw)
-    lowered = step.lower(params, opt, ids, ids, ids)
-    if debug_info:
-        return lowered.as_text(debug_info=True)
-    return re.sub(r"loc\(.*?\)|#loc.*|metadata=\{[^}]*\}", "",
-                  lowered.as_text())
 
 
 @pytest.mark.parametrize("family", sorted(STANDING))

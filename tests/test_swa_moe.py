@@ -30,10 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from family_recipe import Recipe, apply_moe, hold_leaves, hold_loss, token_file
 
 from distributed_pytorch_from_scratch_tpu.config import (
-    MeshConfig, ModelConfig, OptimizerConfig, SwaMoEConfig, model_preset)
+    ModelConfig, OptimizerConfig, SwaMoEConfig, model_preset)
 from distributed_pytorch_from_scratch_tpu.models import build_model
 from distributed_pytorch_from_scratch_tpu.models.swa_moe import (
     SlidingWindowMoETransformer)
@@ -41,7 +41,6 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_swa_moe import (
     bias_rule, vanilla_loss)
 from distributed_pytorch_from_scratch_tpu.ops.attention import sliding_window
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
-from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 from distributed_pytorch_from_scratch_tpu.training.metrics import (
     model_flops_per_step, moe_counters_summary)
 from distributed_pytorch_from_scratch_tpu.training.optim import (
@@ -52,10 +51,9 @@ from distributed_pytorch_from_scratch_tpu.training.train_step import (
 PUBLISHED = (("sliding_attention",) * 3 + ("full_attention",)) * 8
 
 
-def tiny(compute_dtype="float32", **facts):
-    cfg = model_preset("tiny-swa-moe", compute_dtype=compute_dtype)
-    return dataclasses.replace(
-        cfg, swa_moe=dataclasses.replace(cfg.swa_moe, **facts))
+# the family's own: its reference (sequences of 64 from id 3 up: the recipe's)
+R = Recipe("swa_moe", vanilla_loss)
+tiny, batch, on_mesh = R.tiny, R.batch, R.on_mesh
 
 
 def small(**facts):
@@ -63,39 +61,6 @@ def small(**facts):
     return dataclasses.replace(
         tiny(layer_types=("sliding_attention",) * 2 + ("full_attention",),
              num_dense_layers=1, **facts), num_layers=3)
-
-
-def batch(cfg, b=2, t=64, seed=0):
-    rng = np.random.default_rng(seed)
-    ids = rng.integers(3, cfg.vocab_size, (b, t + 1)).astype(np.int32)
-    return (ids[:, :-1], ids[:, 1:],
-            np.tile(np.arange(t, dtype=np.int32), (b, 1)))
-
-
-def on_mesh(cfg, tp, **kw):
-    mesh = make_mesh(MeshConfig(dp=1, tp=tp), devices=jax.devices()[:tp])
-    return mesh, build_model("swa_moe", cfg, tp_size=tp, **kw)
-
-
-@functools.lru_cache(maxsize=None)
-def reference(cfg, t=64, seed=3):
-    """(parameters, the reference's loss and gradients) on `batch(cfg, t)`:
-    compiled once for every test that compares with it."""
-    params = build_model("swa_moe", cfg).init(jax.random.key(seed))
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        return params, jax.jit(jax.value_and_grad(
-            lambda p: vanilla_loss(cfg, p, ids, tgt, pos)))(params)
-
-
-def reference_and_program(cfg, tp=1, impl="xla", t=64):
-    mesh, model = on_mesh(cfg, tp, attn_impl=impl)
-    params, want = reference(cfg, t)
-    ids, tgt, pos = batch(cfg, t=t)
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.value_and_grad(model.make_loss(mesh)))(
-            jax.device_put(params, model.shardings(mesh)), ids, tgt, pos)
-    return model, params, want, got
 
 
 # ---- the program against the plain reference ----
@@ -109,19 +74,15 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, t):
     64 (or 128 under the kernels). Leaves to 1e-5 of their largest
     entry."""
     cfg = tiny(experts_held=4, expert_offset=2)
-    model, params, (want, want_g), (got, got_g) = reference_and_program(
-        cfg, tp, impl, t=t)
+    params, (want, want_g) = R.reference(cfg, t)
+    got, got_g = R.program(cfg, tp=tp, t=t, attn_impl=impl)
+    model = build_model("swa_moe", cfg, tp_size=tp)
     assert model._pattern == (
         "dense_layers", (("window_layers_0", 3), ("full_layers_0", 1)))
     assert [model._kind(k) for k in model._layer_keys] == [
         "window", "window", "full"]
-    assert abs(float(got) - float(want)) <= 1e-5 * abs(float(want))
-    flat = jax.tree_util.tree_leaves_with_path(want_g)
-    assert len(flat) == len(jax.tree.leaves(got_g)) == 55
-    for (path, a), b in zip(flat, jax.tree.leaves(got_g)):
-        a, b = np.asarray(a), np.asarray(b)
-        assert np.max(np.abs(a - b)) <= 1e-5 * max(np.max(np.abs(a)), 1e-6), \
-            jax.tree_util.keystr(path)
+    hold_loss(want, got)
+    assert len(hold_leaves(want_g, got_g, 1e-5)[0]) == 55
     # the selection bias is a leaf no gradient reaches; both kinds of layer
     # hold the same parameters, four norms and a gate of their own
     bias = got_g["window_layers_0"]["moe"]["bias"]
@@ -137,7 +98,7 @@ def test_loss_and_every_gradient_leaf_equal_the_reference(tp, impl, t):
 def test_logits_equal_the_reference_and_bfloat16_stays_near():
     cfg = tiny(experts_held=4, expert_offset=2)
     mesh, model = on_mesh(cfg, 1, attn_impl="xla")
-    params, (want, want_g) = reference(cfg)
+    params, (want, want_g) = R.reference(cfg)
     want = float(want)
     ids, tgt, pos = batch(cfg)
     with jax.default_matmul_precision("highest"):
@@ -241,13 +202,6 @@ def test_the_published_pattern_is_a_segment_and_two_period_blocks():
 
 
 # ---- the shares ----
-
-def apply_moe(moe, params, x):
-    mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
-    fn = jax.shard_map(lambda p, x: moe.apply(p, x), mesh=mesh,
-                       in_specs=(moe.specs(), P()), out_specs=(P(), P()))
-    return jax.jit(fn)(params, x)
-
 
 def test_the_four_shares_of_a_layer_add_up_to_the_uncut_layer():
     """Four jobs hold two experts each of one layer's 8, each beside the
@@ -471,10 +425,8 @@ def test_the_train_step_trains_and_counts_rows():
 
 def test_train_cli_runs_the_family(tmp_path, capsys):
     import json
-    from chip_smoke import write_tokens
     from distributed_pytorch_from_scratch_tpu import train as train_mod
-    tokens = tmp_path / "tokens.json"
-    write_tokens(str(tokens), 503, 16, 65)
+    tokens = token_file(tmp_path)
     train_mod.main([
         "--family", "swa_moe", "--model", "tiny-swa-moe", "--tp_size", "2",
         "--data_path", str(tokens), "--save_dir", str(tmp_path / "ckpt"),
