@@ -149,10 +149,12 @@ def _selected_flash_fwd(q, k, v, q_idx, k_idx, w, top_k, bq, bk, interpret):
     tracer = current_tracer()
     if tracer is not None:
         # what this call built: the kernels that make the index tile are
-        # the selection and the loss walk, and the set is `bits`
+        # the selection and the loss walk, the set is `bits`, and a head's
+        # score tile of the forward walk is (keys, rows)
         tracer.instant("dsa_walk", mask="bits", planes=bits.shape[1],
                        bits_bytes=bits.size * bits.dtype.itemsize,
-                       index_tiles_a_layer=2, blocks=[bq, bk], t=t)
+                       index_tiles_a_layer=2, blocks=[bq, bk], t=t,
+                       fwd_tile="keys_by_rows", fwd_tile_shape=[bk, bq])
     # A rung that keeps the kernels' outputs keeps the choice they were
     # made under: the backward never attends over a re-made selection. The
     # choice is a bit a (row, key) pair, t / 8 bytes a row: the backward
@@ -161,9 +163,9 @@ def _selected_flash_fwd(q, k, v, q_idx, k_idx, w, top_k, bq, bk, interpret):
     with jax.named_scope("dsa_attend"):
         o, lse = kernels.fwd_call(q, k, v, bits, **blocks)
     o = checkpoint_name(o, "flash_out")
-    # (kept lane-dense: a (.., t, 1) column is a tile of 128 lanes a row
-    # on the chip, 128 times its size)
-    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    # (the forward leaves it lane-dense, (b, H, t): a (.., t, 1) column is
+    # a tile of 128 lanes a row on the chip, 128 times its size)
+    lse = checkpoint_name(lse, "flash_lse")
     with jax.named_scope("dsa_index_loss"):
         kl, entropy, d_qi, d_w, d_ki = kernels.loss_call(
             q, k, lse[..., None], q_idx, k_idx, w4, tau, cut, lse_i, **blocks)
@@ -251,7 +253,7 @@ def selected_attention(q, k, v, q_idx, k_idx, w, top_k: int,
     if impl == "xla":
         return selected_attention_xla(q, k, v, q_idx, k_idx, w, top_k)
     from .pallas.dsa_attention import require_tpu
-    require_tpu(impl == "flash_interpret")
     bq, bk = flash_blocks(q.shape[2])
+    require_tpu(impl == "flash_interpret", bq)
     return _selected_flash(q, k, v, q_idx, k_idx, w, top_k, bq, bk,
                            impl == "flash_interpret")

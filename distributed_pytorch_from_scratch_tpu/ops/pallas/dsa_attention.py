@@ -27,9 +27,19 @@ to 32 key tiles (t^2 / 8 bytes a sequence: 32 MiB at 16,384).
   rule on the keys it compared) and takes what only the set's own scores
   give: the logsumexp of I over the set and the set's size;
 * `dsa_flash_fwd`: the flash walk of the whole triangle, ALL the query
-  heads of a row block a grid step (a tile's mask is read once and shared
-  by the heads, a key-value group at a time), online softmax under the
-  mask; o and each head's lse;
+  heads of a row block a grid step (a tile's mask is read once, turned
+  once and shared by the heads), online softmax under the mask with the
+  score tile TURNED: a head's scores are `k q^T`, (bk, bq), a key a
+  sublane and a query row a lane, so the row maximum and the row sum run
+  down the tile as element operations (a row a sublane made them 8192
+  cross-lane reductions a tile, and the exponents waited for them: 31.3 ms
+  a layer at the cell's shape, 15.5 turned); m, l (scratch (H, bq)), fade
+  and lse are lane-dense rows; `p v` is `v^T p` into an accumulator kept
+  turned (scratch (H, h, bq)) and turned back once a row block (with the
+  accumulator the usual way up and `fade` turned into a column a tile it
+  read 18.2 ms); a head's scores are asked for `AHEAD` heads before its
+  exponents, so the matrix unit has products to make meanwhile; o and
+  each head's lse, (b, H, t);
 * `dsa_flash_bwd_dq`, `dsa_flash_bwd_dkv`: the standard two backward walks
   under the same bits (the choice carries no gradient);
 * `dsa_index_loss`: the indexer's own loss in one more walk, the second
@@ -67,6 +77,8 @@ BLOCK_K = 512
 # keys a pass of the selection's compare-and-count reads at once
 COUNT_CHUNK = 2048
 VMEM_LIMIT = 96 * 2 ** 20
+# heads whose scores the forward walk asks for before a head's exponents
+AHEAD = 3
 
 _NT = (((1,), (1,)), ((), ()))   # a @ b^T
 _NN = (((1,), (0,)), ((), ()))   # a @ b
@@ -292,10 +304,16 @@ def select_call(q_idx, k_idx, w, top_k: int, *, bq: int, bk: int,
 
 # ------------------------------------------------------------ the flash walks
 
+def _tile_words(bits_ref, j):
+    """(bq, bk) int32, not 0 where the pair of key tile `j` is in its row's
+    set: the row block's words of that tile's plane under the tile's bit."""
+    return bits_ref[0, 0] & _tile_bit(j)
+
+
 def _tile_set(bits_ref, j):
     """(bq, bk) bool: the pairs of key tile `j` that are in their rows'
-    sets, from the row block's words of that tile's plane."""
-    return (bits_ref[0, 0] & _tile_bit(j)) != 0
+    sets."""
+    return _tile_words(bits_ref, j) != 0
 
 
 def _crosses(i, j, bq: int, bk: int):
@@ -306,7 +324,10 @@ def _crosses(i, j, bq: int, bk: int):
 def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref,
                 *, scale: float, bq: int, bk: int, group: int):
+    """m_ref, l_ref (H, bq) and acc_ref (H, h, bq): a tile is TURNED, a key
+    a sublane and a query row a lane (module docstring)."""
     _, i, j = ids
+    H = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _():
@@ -316,28 +337,37 @@ def _fwd_kernel(ids, last, q_ref, k_ref, v_ref, bits_ref, o_ref, lse_ref,
 
     @pl.when(_crosses(i, j, bq, bk))
     def _():
-        live = _tile_set(bits_ref, j)
-        h = q_ref.shape[3]
-        for g in range(k_ref.shape[1]):
-            heads = slice(g * group, (g + 1) * group)
-            q = q_ref[0, heads].reshape(group * bq, h)
-            s = (_dot(q, k_ref[0, g], _NT) * scale).reshape(group, bq, bk)
-            s = jnp.where(live[None], s, MASK)
-            m_old = m_ref[heads]
-            m = jnp.maximum(m_old, jnp.max(s, axis=2, keepdims=True))
-            # (a row with nothing live in this tile: exp(MASK - MASK) = 1)
-            p = jnp.where(live[None], jnp.exp(s - m), 0.0)
+        # the tile's set, turned once and shared by the heads
+        live = _tile_words(bits_ref, j).T != 0              # (bk, bq)
+
+        def scores(u):
+            return jnp.where(live, _dot(k_ref[0, u // group], q_ref[0, u],
+                                        _NT) * scale, MASK)
+
+        # (a head's scores are asked for `AHEAD` heads before its exponents
+        # are taken: Mosaic keeps the matrix unit's calls in this order, and
+        # the unit then has products to make while the vector unit works)
+        ahead = [scores(u) for u in range(min(AHEAD, H))]
+        for u in range(H):
+            s = ahead.pop(0)
+            if u + AHEAD < H:
+                ahead.append(scores(u + AHEAD))
+            row = slice(u, u + 1)
+            m_old = m_ref[row]
+            m = jnp.maximum(m_old, jnp.max(s, axis=0, keepdims=True))
+            # (a row with nothing live yet has m = MASK and exp(MASK - MASK)
+            # = 1: it takes its exponents from 0, and they are exp(MASK) = 0)
+            p = jnp.exp(s - jnp.where(m == MASK, 0.0, m))
             fade = jnp.exp(m_old - m)
-            l_ref[heads] = l_ref[heads] * fade + jnp.sum(p, axis=2,
-                                                         keepdims=True)
-            pv = _dot(p.reshape(group * bq, bk).astype(v_ref.dtype),
-                      v_ref[0, g], _NN)
-            acc_ref[heads] = acc_ref[heads] * fade + pv.reshape(group, bq, -1)
-            m_ref[heads] = m
+            l_ref[row] = l_ref[row] * fade + jnp.sum(p, axis=0, keepdims=True)
+            acc_ref[u] = acc_ref[u] * fade + _dot(
+                v_ref[0, u // group], p.astype(v_ref.dtype), _TN)
+            m_ref[row] = m
 
     @pl.when(j == last)
     def _():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        for u in range(H):
+            o_ref[0, u] = (acc_ref[u] / l_ref[u:u + 1]).T.astype(o_ref.dtype)
         lse_ref[0] = m_ref[...] + jnp.log(l_ref[...])
 
 
@@ -379,22 +409,23 @@ def _shapes(q, k, bq: int, bk: int):
 
 
 def fwd_call(q, k, v, bits, *, bq: int, bk: int, interpret: bool):
-    """(o (b, H, t, h), lse (b, H, t, 1) float32) of the attention over the
+    """(o (b, H, t, h), lse (b, H, t) float32) of the attention over the
     rows' sets `bits` (`select_call`'s)."""
     b, H, Hkv, t, h = _shapes(q, k, bq, bk)
     sp = _specs(H, Hkv, h, bq, bk, rows_outer=True)
     kernel = _kernel(_fwd_kernel, 3, q, interpret, scale=1.0 / math.sqrt(h),
                      bq=bq, bk=bk, group=H // Hkv)
     f32 = jnp.float32
+    row = pltpu.VMEM((H, bq), f32)
     return pl.pallas_call(
         kernel,
         grid=(b, t // bq, t // bk),
         in_specs=[sp["q"], sp["k"], sp["v"], sp["bits"]],
-        out_specs=[sp["q"], sp["row_h"]],
+        out_specs=[sp["q"], pl.BlockSpec((1, H, bq),
+                                         lambda bi, i, j: (bi, 0, i))],
         out_shape=[_out_struct(q.shape, q.dtype, q),
-                   _out_struct((b, H, t, 1), f32, q)],
-        scratch_shapes=[pltpu.VMEM((H, bq, 1), f32), pltpu.VMEM((H, bq, 1), f32),
-                        pltpu.VMEM((H, bq, h), f32)],
+                   _out_struct((b, H, t), f32, q)],
+        scratch_shapes=[row, row, pltpu.VMEM((H, h, bq), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT),
@@ -649,5 +680,13 @@ def probe_call(q_idx, k_idx, w, bits, *, bq: int, bk: int, interpret: bool):
     )(q_idx, k_idx, w, bits)
 
 
-def require_tpu(interpret: bool) -> None:
+def require_tpu(interpret: bool, bq: int = None) -> None:
+    """The kernels need a TPU or the interpreter; the forward walk's query
+    block `bq` rides the lanes, so Mosaic takes whole lane tiles of it."""
     _require_tpu("the selected-attention kernels", interpret)
+    if not interpret and bq is not None and bq % 128:
+        raise ValueError(
+            f"the selected-attention forward walk lays a block's query rows "
+            f"along the lanes: on a TPU the sequence must be a multiple of "
+            f"128 rows (its query block came out as {bq}); the Pallas "
+            f"interpreter and the XLA text take any length")

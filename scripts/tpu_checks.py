@@ -9,7 +9,9 @@ expert layer (`parallel/moe.SharedRoutedFFN`) at an expert cell's shape
 against one expert at a time, the device's free memory filled with NaN
 first: XLA:TPU's grouped products write their groups' rows only, and the
 layer's selects are what keeps the rest out (a second case takes the
-movers' selects out and MUST differ). Also asks the
+movers' selects out and MUST differ); the state-space recurrence's two
+kernels and the selected-attention family's five against their texts at
+their cells' widths. Also asks the
 timer question every later measurement rests on: does `block_until_ready`
 wait for the device?
 
@@ -39,7 +41,7 @@ import numpy as np
 from distributed_pytorch_from_scratch_tpu.models.decode import (  # noqa: E402
     _gather_page_view)
 from distributed_pytorch_from_scratch_tpu.ops.attention import (  # noqa: E402
-    CAUSAL, causal_attention_xla, sliding_window)
+    CAUSAL, causal_attention_xla, repeat_kv, sliding_window)
 from distributed_pytorch_from_scratch_tpu.ops.pallas import (  # noqa: E402
     flash_attention as fa_mod)
 from distributed_pytorch_from_scratch_tpu.ops.pallas.flash_attention import (  # noqa: E402
@@ -48,7 +50,10 @@ from distributed_pytorch_from_scratch_tpu.ops.pallas.paged_attention import (  #
     paged_attention)
 from distributed_pytorch_from_scratch_tpu.ops.ring_attention import (  # noqa: E402
     _block_attn_xla)
+from distributed_pytorch_from_scratch_tpu.ops import index_select  # noqa: E402
 from distributed_pytorch_from_scratch_tpu.ops import ssd as ssd_mod  # noqa: E402
+from distributed_pytorch_from_scratch_tpu.ops.pallas import (  # noqa: E402
+    dsa_attention)
 from distributed_pytorch_from_scratch_tpu.parallel import moe as moe_mod  # noqa: E402
 from distributed_pytorch_from_scratch_tpu.runtime.compile_cache import (  # noqa: E402
     compile_cache_stats, enable_compile_cache)
@@ -424,6 +429,66 @@ def ssd_checks(interp: bool, dtype, tol: float):
                    secs)
 
 
+def dsa_walk_checks(interp: bool, dtype, tol: float):
+    """The selected-attention family's five kernels
+    (ops/pallas/dsa_attention.py) against `selected_attention_xla` at the
+    published widths (32 query heads over 4 key-value heads of 128, 16
+    index heads of 64, top-k 2048, 4096 rows, blocks of 128 x 512; under
+    the interpreter a twentieth of it): o, the forward walk's lse (against
+    the text's logsumexp over each row's set), dq, dk, dv and the indexer's
+    three gradients, each relative to the text's largest entry. The two
+    sides choose their sets from scores summed in another order, so a pair
+    at a row's threshold may differ: a 2048th of a row's weight."""
+    b, H, Hkv, h, J, c, t, top_k = ((1, 4, 2, 16, 2, 8, 256, 24) if interp
+                                    else (1, 32, 4, 128, 16, 64, 4096, 2048))
+    ks = jax.random.split(jax.random.key(17), 7)
+    normal = lambda key, *shape: jax.random.normal(
+        key, shape, jnp.float32).astype(dtype)
+    args = (normal(ks[0], b, H, t, h), normal(ks[1], b, Hkv, t, h),
+            normal(ks[2], b, Hkv, t, h), normal(ks[3], b, J, t, c),
+            normal(ks[4], b, t, c),
+            jax.random.normal(ks[5], (b, t, J), jnp.float32) * 0.3)
+    lanes = jax.random.normal(ks[6], (b, H, t, h), jnp.float32)
+
+    def run(impl):
+        def value(*a):
+            o, sums = index_select.selected_attention(*a, top_k, impl=impl)
+            return (jnp.sum(o.astype(jnp.float32) * lanes)
+                    + sums["dsa_index_kl"]), o
+        (_, o), grads = jax.jit(jax.value_and_grad(
+            value, argnums=tuple(range(6)), has_aux=True))(*args)
+        return [o, *grads]
+
+    def lse_text(q, k, v, q_idx, k_idx, w):
+        score = index_select.index_scores(q_idx, k_idx, w)
+        keep = index_select.live(score,
+                                 *index_select.select(score, top_k)[:2])
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, repeat_kv(q, k, v)[0],
+                            preferred_element_type=jnp.float32)
+        return jax.nn.logsumexp(jnp.where(keep[:, None],
+                                          logits / math.sqrt(h), -jnp.inf),
+                                axis=-1)
+
+    def lse_kernels(q, k, v, q_idx, k_idx, w):
+        bq, bk = index_select.flash_blocks(t)
+        blocks = dict(bq=bq, bk=bk, interpret=interp)
+        bits = dsa_attention.select_call(
+            q_idx, k_idx, index_select._rows_last(w), top_k, **blocks)[3]
+        return dsa_attention.fwd_call(q, k, v, bits, **blocks)[1]
+
+    want = run("xla") + [jax.jit(lse_text)(*args)]
+    t0 = time.time()
+    got = run("flash_interpret" if interp else "flash")
+    got.append(jax.jit(lse_kernels)(*args))
+    jax.block_until_ready(got)
+    secs = time.time() - t0
+    for name, g, r in zip(("o", "dq", "dk", "dv", "d q_idx", "d k_idx",
+                           "d w", "lse"), got, want):
+        record(f"dsa walk, {H} / {Hkv} heads of {h}, {t} rows: {name}",
+               max_err(g, r) / max(float(jnp.max(jnp.abs(r))), 1e-30), tol,
+               secs)
+
+
 def timer_check(interpret: bool) -> dict:
     """Is `block_until_ready` honest here? Time the same chain of donated
     jitted steps twice: once ending in `block_until_ready`, once ending in
@@ -594,6 +659,9 @@ def main():
 
     # --- the state-space recurrence's walk against its text
     ssd_checks(interp, dtype, tol)
+
+    # --- the selected-attention walks against the dense text
+    dsa_walk_checks(interp, dtype, tol)
 
     timer = timer_check(interp)
 

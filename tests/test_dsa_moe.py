@@ -45,7 +45,7 @@ from distributed_pytorch_from_scratch_tpu.models.vanilla_dsa_moe import (
     sizes_of, vanilla_parts)
 from distributed_pytorch_from_scratch_tpu.ops import index_select
 from distributed_pytorch_from_scratch_tpu.ops.attention import (
-    causal_attention_xla)
+    causal_attention_xla, repeat_kv)
 from distributed_pytorch_from_scratch_tpu.ops.pallas import dsa_attention
 from distributed_pytorch_from_scratch_tpu.ops.rope import rope_angles
 from distributed_pytorch_from_scratch_tpu.parallel.moe import SharedRoutedFFN
@@ -197,6 +197,7 @@ def unpacked(bits, bk):
     (1, 4, 1, 128, 1, 5, 8, 128),       # one index head, one key tile
     (1, 2, 1, 512, 2, 24, 32, 8),       # 64 key tiles: two planes of bits
     (1, 2, 2, 384, 1, 40, 64, 8),       # 48: the second plane part full
+    (1, 8, 1, 256, 2, 8, 128, 64),      # the cell's group and query block
 ])
 def test_the_kernels_equal_the_text(b, H, Hkv, t, J, top_k, bq, bk,
                                     monkeypatch):
@@ -254,6 +255,19 @@ def test_the_kernels_equal_the_text(b, H, Hkv, t, J, top_k, bq, bk,
     np.testing.assert_allclose(
         lse_i[..., 0], jax.nn.logsumexp(jnp.where(keep, score, -jnp.inf),
                                         axis=-1), rtol=2e-6)
+    # the forward walk's lse is the text's logsumexp over each row's set,
+    # and leaves the kernel lane-dense, a row of the sequence a head
+    q, k, v = args[:3]
+    _, lse = dsa_attention.fwd_call(q, k, v, bits, **blocks)
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, repeat_kv(q, k, v)[0],
+                        precision="highest") / np.sqrt(h)
+    assert lse.shape == (b, H, t)
+    np.testing.assert_allclose(lse, jax.nn.logsumexp(
+        jnp.where(keep[:, None], logits, -jnp.inf), axis=-1), rtol=2e-6)
+    if bq == 128:
+        # some rows hold NO key of the first tile they cross: their running
+        # maximum stays at the mask's value there (the walk's exact zeros)
+        assert not keep[..., :bk].any(-1).all()
 
 
 def test_equal_scores_are_cut_by_index_and_counted():
@@ -288,8 +302,10 @@ def test_the_walk_says_on_the_programs_tracer_what_it_built(tmp_path):
     """At trace time `_selected_flash_fwd` leaves ONE instant `dsa_walk`
     (as `flash_attention._bwd_call`'s `flash_bwd_walk`): the mask the
     walks read, its planes and bytes a layer, and how many kernels of a
-    layer still make the index tile. At the cell's shape: one plane, 32
-    MiB, the selection and the loss walk."""
+    layer still make the index tile, and which way up the forward walk's
+    score tile is. At the cell's shape: one plane, 32 MiB, the selection and
+    the loss walk, a head's 512 keys down the sublanes by 128 rows along
+    the lanes."""
     import json
 
     from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
@@ -311,7 +327,9 @@ def test_the_walk_says_on_the_programs_tracer_what_it_built(tmp_path):
               if json.loads(line)["name"] == "dsa_walk"]
     assert events == [{"mask": "bits", "planes": 1,
                        "bits_bytes": 33_554_432, "index_tiles_a_layer": 2,
-                       "blocks": [128, 512], "t": 16384}]
+                       "blocks": [128, 512], "t": 16384,
+                       "fwd_tile": "keys_by_rows",
+                       "fwd_tile_shape": [512, 128]}]
 
 
 # ---- nothing dropped is the causal layer ----
