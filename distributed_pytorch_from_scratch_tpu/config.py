@@ -427,6 +427,46 @@ class SsmDenseConfig:
 
 
 @dataclass(frozen=True)
+class SambaYConfig:
+    """What the `sambay` family (models/sambay.py) needs beyond
+    `ModelConfig`'s own fields: a decoder-hybrid-decoder (SambaY,
+    arXiv:2507.06607, with differential attention, arXiv:2410.05258) whose
+    every layer is a mixer and then a SwiGLU between two LayerNorms. The
+    lower half alternates Mamba-1 mixers and window-`sliding_window`
+    differential attention; layer `N / 2` is a Mamba-1 mixer that also
+    LEAVES its scan's output (the memory), layer `N / 2 + 1` a full
+    differential attention that also leaves its keys and values; every
+    layer above reads one of the two (a gated memory unit, or
+    cross-attention with queries only). The keys are `config.json`'s own
+    (`phi4flash`); what it does not carry (the Mamba-1 sizes, the biases,
+    `initializer_range`) stands at the published code's defaults. In
+    `ModelConfig`, `attn_dim` is the model width, `num_heads` /
+    `num_kv_heads` the published 40 / 20 (a differential head is two query
+    heads; a key-value pair two key heads and one value twice as wide),
+    `ffn_dim` the SwiGLU's width, `num_layers` = `len(layers_here)`,
+    `num_experts` 0."""
+
+    num_hidden_layers: int              # the PUBLISHED depth, N % 4 == 0
+    # the published layers this job holds, in order (None: all of them)
+    layers_here: "tuple[int, ...] | None" = None
+    mb_per_layer: int = 2               # every mb_per_layer-th layer a scan
+    sliding_window: int = 512
+    layer_norm_eps: float = 1e-5
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: "int | None" = None  # None: ceil(d / 16)
+    attention_bias: bool = True         # on W_qkv / W_q and W_o
+    # the tied table's and every matrix's normal(0, .) at init
+    initializer_range: float = 0.02
+    lambda_std: float = 0.1             # the four lambda vectors' normal(0, .)
+    # dt at init (Mamba-1's own defaults)
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+
+
+@dataclass(frozen=True)
 class ModelConfig:
     """LLaMA-style decoder-only transformer shape.
 
@@ -480,6 +520,8 @@ class ModelConfig:
     ssm_dense: "SsmDenseConfig | None" = None
     # The `dsa_moe` family's facts (None for every other family).
     dsa_moe: "DsaMoEConfig | None" = None
+    # The `sambay` family's facts (None for every other family).
+    sambay: "SambaYConfig | None" = None
 
     @property
     def head_dim(self) -> int:
@@ -550,7 +592,7 @@ class ModelConfig:
 # the ModelConfig fields that carry one family's facts each
 FAMILY_FACTS = ("latent_moe", "gdn_moe", "conv_moe", "bd_moe", "swa_moe",
                 "early_moe", "kda_mla_moe", "ssm_moe", "loop_llama",
-                "ssm_dense", "dsa_moe")
+                "ssm_dense", "dsa_moe", "sambay")
 
 # CLI flag-string -> Transformer.remat value (shared by train.py/bench.py)
 REMAT_CHOICES = {"true": True, "dots": "dots", "false": False}
@@ -726,6 +768,18 @@ MODEL_PRESETS = {
             mamba_chunk_size=32, embedding_multiplier=12.0,
             residual_multiplier=0.22, attention_multiplier=0.125,
             logits_scaling=8.0)),
+    # the `sambay` family at a CPU size: all five kinds of layer in 8
+    # published layers, (Mamba-1, window) twice, the Mamba-1 layer that
+    # leaves the memory, the full layer that leaves its keys and values, one
+    # gated memory unit and one cross-attention; scans 128 channels wide
+    # over a state of 4 with a dt rank of 4; 4 query heads over 2 key heads
+    # of 16 (2 differential heads over ONE pair, its value 32 wide); a
+    # window of 16 rows, shorter than any test's sequence
+    "tiny-sambay": ModelConfig(
+        attn_dim=64, ffn_dim=96, num_heads=4, num_kv_heads=2, num_layers=8,
+        vocab_size=1024, maxlen=256,
+        sambay=SambaYConfig(num_hidden_layers=8, sliding_window=16,
+                            mamba_d_state=4)),
 }
 
 

@@ -1,11 +1,13 @@
 """The model families, and the one place a family's name becomes a class:
-`llama` (the reference's block) and `gpt2`, and twelve drawn from published
+`llama` (the reference's block) and `gpt2`, and thirteen drawn from published
 configurations: ten that each hold one share of the experts their router
 scores, `mla_moe`, `gdn_moe`, `conv_moe`, `bd_moe`, `swa_moe`, `early_moe`,
 `mhc_mla_moe`, `kda_mla_moe`, `ssm_moe` and `dsa_moe` (whose every layer
-chooses its keys), and two dense ones:
-`loop_llama`, whose stack is passed several times a step, and `ssm_dense`,
-whose every layer is a Mamba-2 mixer or an attention and then a SwiGLU
+chooses its keys), and three dense ones:
+`loop_llama`, whose stack is passed several times a step, `ssm_dense`,
+whose every layer is a Mamba-2 mixer or an attention and then a SwiGLU,
+and `sambay`, a decoder-hybrid-decoder whose upper layers read ONE lower
+layer's scan output and ONE lower layer's keys and values
 (docs/DESIGN.md, "What a family file holds")."""
 
 from .bd_moe import BlockDiffusionMoETransformer
@@ -18,6 +20,7 @@ from .kda_mla_moe import KdaMlaMoETransformer
 from .loop_llama import LoopedTransformer
 from .mhc_mla_moe import HyperLatentMoETransformer
 from .mla_moe import LatentMoETransformer
+from .sambay import SambaYTransformer
 from .ssm_dense import SsmDenseTransformer
 from .ssm_moe import SsmMoETransformer
 from .stack import DecoderStack
@@ -30,7 +33,7 @@ FAMILIES = {cls.family: cls for cls in (
     SlidingWindowMoETransformer, EarlyRouterMoETransformer,
     HyperLatentMoETransformer, KdaMlaMoETransformer, SsmMoETransformer,
     LoopedTransformer, SsmDenseTransformer,
-    SelectedAttentionMoETransformer)}
+    SelectedAttentionMoETransformer, SambaYTransformer)}
 
 
 def family_class(family: str) -> "type[DecoderStack]":

@@ -263,13 +263,15 @@ REMAT_LADDER = (
 REMAT_RUNGS = tuple(name for name, _ in REMAT_LADDER)
 # Which of a layer's modules makes each name: a layer tags a name where its
 # parameters hold one of them (the stack's own attention is `wq` ... `wo`;
-# a family's whole-attention modules are `attn`, gated, and `mla`, latent,
-# whose q, k and v carry no name). `attn_proj` is tagged past any mixer's
+# a family's whole-attention modules are `attn`, gated or differential, `mla`,
+# latent, whose q, k and v carry no name, and `cross`, whose keys and values
+# are another layer's). `attn_proj` is tagged past any mixer's
 # reduce, so by every layer. `DecoderStack.tagged_layers` counts by it.
 LADDER_MADE_BY = {
     "ffn_fc": ("fc",), "ffn_gate": ("gate_proj",), "ffn_up": ("up_proj",),
-    "flash_out": ("wo", "attn", "mla"), "flash_lse": ("wo", "attn", "mla"),
-    "q_proj": ("wq", "attn"), "k_proj": ("wk", "attn"),
+    "flash_out": ("wo", "attn", "mla", "cross"),
+    "flash_lse": ("wo", "attn", "mla", "cross"),
+    "q_proj": ("wq", "attn", "cross"), "k_proj": ("wk", "attn"),
     "v_proj": ("wv", "attn"),
 }
 
@@ -696,6 +698,22 @@ class DecoderStack:
         return self.passes(self.cfg)
 
     exit_entropy_coef = 0.0
+    # Do layers LEAVE VALUES FOR LATER LAYERS beside the residual stream (a
+    # decoder-hybrid-decoder: ONE layer's scan output and ONE layer's keys
+    # and values, read by every layer above). A layer whose mixer is the
+    # family's (`_mix_sharing`) is then handed `shared`, a dict of what the
+    # blocks before its own left, and may hand back more (`left`, a dict);
+    # what the LAST layer of a SEGMENT leaves joins `shared` for every
+    # later block (a layer of a period leaves nothing: a period's layers
+    # are alike). A value is made once: it is an OUTPUT of its maker's
+    # `jax.checkpoint` (kept whatever the rung, never remade) and an INPUT
+    # of each reader's (kept by reference), a constant of a later period's
+    # scan, whose transpose sums the readers' cotangents into it; the sum
+    # reaches the maker beside its own use's. A pipeline cut between a
+    # maker and a reader is not written (the family refuses `pp_size`).
+    # False: a layer hands on the stream alone, the program it has always
+    # been
+    shares_values = False
     # the jax.named_scope of `_qkv` and `_attn_project` in a device trace
     attn_scope = None
     # ---- what a family may say it cannot do (refused with a message where
@@ -916,6 +934,9 @@ class DecoderStack:
         return self.head_dim
 
     layer_extra_elems_per_token = 0.0   # see training/memory.step_bytes
+    # elements a token of the values layers leave for later layers
+    # (`shares_values`), in the compute dtype: kept whatever the rung
+    shared_elems_per_token = 0.0
     # bytes a (row, key) pair of a sequence that a layer keeps under the name
     # `flash_lse` beside the heads' lse (a mask that is data: models/dsa_moe)
     flash_lse_bytes_per_pair = 0.0
@@ -1191,7 +1212,8 @@ class DecoderStack:
 
     def _layer_body(self, x: jax.Array, layer_params: Params, layer_pos,
                     pos: jax.Array, dtype, live=None,
-                    kind: "str | None" = None) -> jax.Array:
+                    kind: "str | None" = None,
+                    shared: "Params | None" = None) -> jax.Array:
         """One decoder layer: x + attn(norm(x)), then x + mlp(norm(x)) or,
         with cfg.num_experts > 0, x + MoE(norm(x)) (parallel/moe.py); a
         family that names post-norms adds N(attn(..)) and N(mlp(..)); a
@@ -1201,6 +1223,9 @@ class DecoderStack:
         `kind` is the kind of the layer's `_pattern` key (`_kind`; static):
         a family with two kinds of attention layer over one parameter tree
         tells them apart by it (`_attn_mask`, `unrotated_kinds`).
+        `shared` (a family that `shares_values`): what earlier blocks left;
+        the layer then returns `(x, (aux, what it leaves or None))`. A
+        layer's `told` (`_told`) are not parameters and leave the tree here.
 
         `live` (optional scalar bool) is the
         pipeline-bubble gate used ONLY on pp meshes with ring CP: the dense
@@ -1213,6 +1238,10 @@ class DecoderStack:
         deadlock otherwise) with the per-block MXU work gated inside the
         ring (ops/ring_attention.py). Bubble steps therefore cost only the
         ring's wire traffic, not layer FLOPs (VERDICT r3 #3)."""
+        told = layer_params.get("told")
+        if told is not None:
+            layer_params = {k: v for k, v in layer_params.items()
+                            if k != "told"}
         if self.zero3_axis:
             # ZeRO-3: this layer's dp-sharded leaves gather here, inside
             # the remat boundary, so the gathered weights are transient in
@@ -1314,6 +1343,11 @@ class DecoderStack:
             norm = self.attn_norm_key
             y = tp.gather(m[norm].apply(layer_params[norm],
                                         read(x, "hc_attn")))
+            if self.shares_values:
+                a, counted, left = self._mix_sharing(
+                    layer_params, y, dtype, kind, told, shared)
+                x, aux = ffn_half(x, a, counted)
+                return x, (aux, left)
             return ffn_half(x, *self._mix_counted(layer_params, y, layer_pos,
                                                   dtype))
         if live is None or tp.ring_ov:
@@ -1389,6 +1423,23 @@ class DecoderStack:
         mixer counts something writes this one instead: the counters join
         the layer's aux, a row a layer (`_counter_reduces`)."""
         return self._mix(lp, y, layer_pos, dtype), None
+
+    def _mix_sharing(self, lp: Params, y: jax.Array, dtype,
+                     kind: "str | None", told: "Params | None",
+                     shared: Params):
+        """`_mix_counted` of a family that `shares_values`: (the mixer
+        sublayer's output, what it counted or None, what the layer LEAVES
+        for later blocks, a dict, or None). `kind` is the layer's, `told`
+        what `_told` gave it, `shared` what the blocks before left."""
+        raise NotImplementedError
+
+    def _told(self, key: str, layers: Params) -> Params:
+        """The stacked layers of `_pattern` key `key` with what a layer is
+        TOLD beside its parameters under `told` (arrays stacked like the
+        layers', made here from the configuration: a layer's published
+        index; no parameter, so no gradient and no optimizer state). Here
+        nothing: the tree as it is."""
+        return layers
 
     def _attn_project(self, lp: Params, o: jax.Array, tp: TPSublayers,
                       dtype, gate: "jax.Array | None" = None) -> jax.Array:
@@ -1607,28 +1658,35 @@ class DecoderStack:
 
         rung = resolve_remat(self, params, input_ids.shape)
 
-        def layer_step(layers, *mb, live=None, kind=None):
+        def layer_step(layers, *mb, live=None, kind=None, shared=None):
             # the step of a scan over `layers`, layers of one `kind`, under
             # this trace's remat rung; `mb` is (*layer_pos, position_ids),
-            # whole or, under the pipeline, one microbatch's rows
+            # whole or, under the pipeline, one microbatch's rows; `shared`
+            # (a family that `shares_values`) an input of every layer
             layer_fn = remat_wrap(
                 self._layer_body, rung, static_argnums=(4, 6),
                 looped=jax.tree.leaves(layers)[0].shape[0] > 1
                 or (self.loop_steps or 1) > 1)
 
             def body(carry, lp):
-                return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind)
+                return layer_fn(carry, lp, mb[:-1], mb[-1], dtype, live, kind,
+                                *(() if shared is None else (shared,)))
             return body
 
-        def stage_fn(z, layers, *mb, live=None, kind=None):
-            z, auxs = lax.scan(layer_step(layers, *mb, live=live, kind=kind),
-                               z, layers)
+        def stage_fn(z, layers, *mb, live=None, kind=None, shared=None):
+            z, auxs = lax.scan(layer_step(layers, *mb, live=live, kind=kind,
+                                          shared=shared), z, layers)
+            if shared is not None:
+                # (.., what the segment's LAST layer leaves)
+                auxs, left = auxs
+                return (z, self._fold_aux(auxs),
+                        jax.tree.map(lambda a: a[-1], left))
             # auxs: None for dense; for MoE a dict of (L,...) stacked sums
             return z, self._fold_aux(auxs)
 
-        run = lambda z, layers, key=None: stage_fn(
+        run = lambda z, layers, key=None, shared=None: stage_fn(
             z, layers, *layer_pos, position_ids,
-            kind=key and self._kind(key))
+            kind=key and self._kind(key), shared=shared)
         if self.pp_size > 1:
             x, aux = self._pipeline_layers(stage_fn, x, params["layers"],
                                            (*layer_pos, position_ids),
@@ -1636,10 +1694,19 @@ class DecoderStack:
         else:
             def one_pass(x):
                 auxs = []
+                shared = {} if self.shares_values else None
                 for block in self._pattern:
-                    x, aux = (run(x, params[block], block)
-                              if isinstance(block, str)
-                              else self._scan_periods(run, x, params, block))
+                    if not isinstance(block, str):
+                        x, aux = self._scan_periods(run, x, params, block,
+                                                    shared)
+                    elif shared is None:
+                        x, aux = run(x, self._told(block, params[block]),
+                                     block)
+                    else:
+                        x, aux, left = run(
+                            x, self._told(block, params[block]), block,
+                            shared)
+                        shared = {**shared, **(left or {})}
                     auxs.append(aux)
                 # a block of dense layers has none; where several blocks
                 # count (a row a layer), the rows follow the layers
@@ -1762,22 +1829,29 @@ class DecoderStack:
         walk.defvjp(forward, backward)
         return walk(params[key], params["norm"], x, *one_pass.mb), None
 
-    def _scan_periods(self, run, x: jax.Array, params: Params, period):
+    def _scan_periods(self, run, x: jax.Array, params: Params, period,
+                      shared: "Params | None" = None):
         """One scan over the periods of a `_pattern` block: the body runs
         each key's layers of the period through `run` (the one layer
         skeleton under the one remat policy; it is told the key, for the
         layers' kind), in the period's order. The
-        aux comes back one row a layer, in the order the layers ran."""
+        aux comes back one row a layer, in the order the layers ran.
+        `shared` (a family that `shares_values`): what earlier blocks left,
+        a constant of the scan; a period's layers read and leave nothing."""
         keys = [key for key, _ in period]
 
         def period(z, layers):
             auxs = []
             for key in keys:
-                z, aux = run(z, layers[key], key)
+                if shared is None:
+                    z, aux = run(z, layers[key], key)
+                else:
+                    z, aux, _ = run(z, layers[key], key, shared)
                 auxs.append(aux)
             return z, _rows_in_order(auxs)
 
-        x, aux = lax.scan(period, x, {key: params[key] for key in keys})
+        x, aux = lax.scan(period, x, {key: self._told(key, params[key])
+                                      for key in keys})
         # (periods, layers a period, ...) -> (layers, ...)
         return x, jax.tree.map(
             lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), aux)

@@ -120,8 +120,10 @@ EVENT_REQUIRED: Dict[str, Tuple[str, ...]] = {
     # `loss_exit_<r>` and `exit_p_<r>`, a pair a pass
     "loop_counters": ("loss_main", "exit_entropy", "exit_step_mean"),
     # -- ISSUE 68: the counters of a dense family whose mixers count, at the
-    # log interval (training/metrics.mixer_counters_summary)
-    "mixer_counters": ("loss_main", "ssm_decay_min", "resid_rms_last"),
+    # log interval (training/metrics.mixer_counters_summary); beside these
+    # what the family's mixers count (ISSUE 76: `sscan_decay_min`,
+    # `diff_lambda`, `memory_rms`, `shared_kv_readers`; `ssm_decay_min`)
+    "mixer_counters": ("loss_main", "resid_rms_last"),
     # -- ISSUE 72: the counters of layers that choose their keys, at the log
     # interval (training/metrics.dsa_counters_summary), beside `moe_counters`
     "dsa_counters": ("kept_share", "index_kl", "index_entropy", "tau_ties"),

@@ -226,7 +226,7 @@ def get_train_args(argv=None) -> argparse.Namespace:
                         "block-diffusion attention mask and weights the "
                         "masked positions' CE by 1/p; tokens/s count data "
                         "tokens; the later families, 'kda_mla_moe', "
-                        "'ssm_moe', the dense 'ssm_dense' and 'dsa_moe' "
+                        "'ssm_moe', the dense 'ssm_dense' and 'sambay', 'dsa_moe' "
                         "(every layer chooses its keys and carries a loss "
                         "of its own; dp only) among them, each with --model "
                         "tiny-<family>: README) and "

@@ -96,7 +96,8 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
                residual_streams: int = 1,
                tagged_layers: Optional[Dict[str, float]] = None,
                v_head_dim: Optional[int] = None,
-               passes: Optional[int] = None) -> Dict[str, float]:
+               passes: Optional[int] = None,
+               shared_elems_per_token: float = 0.0) -> Dict[str, float]:
     """Bytes one device holds at the peak of a fwd+bwd+Adam step, itemised.
 
     Everything is PER DEVICE: `param_count` / `layer_param_count` are this
@@ -121,7 +122,10 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               tag it: `tagged_layers` (`DecoderStack.tagged_layers`: a drawn
               family's MLP names are its dense layers', the flash names its
               attention layers'; None: all L), `flash_out` at `v_head_dim`
-              a head where v is not of q's width
+              a head where v is not of q's width; and the values layers
+              LEAVE for later layers (`DecoderStack.shares_values`:
+              `shared_elems_per_token` elements a token in the compute
+              dtype), kept whatever the rung
     head      logits in f32 and once more in the compute dtype, on
               `head_rows_share` of the rows (a family whose loss reads part
               of the rows the stack sees: `DecoderStack.head_rows_share`)
@@ -185,6 +189,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
             for _, ns in REMAT_LADDER[:rung + 1] for n in ns)
     if passes is not None:
         stacks += (2 + 4 / dtype_bytes) * passes * wide
+    stacks += tok * shared_elems_per_token * dtype_bytes
     out = {
         "resident": param_count * (state_bytes_per_param
                                    - grad_bytes_per_param),
@@ -395,4 +400,5 @@ def traced_step_bytes(model, param_count: int, layer_param_count: int,
         residual_streams=model.residual_streams,
         tagged_layers={name: n // pp
                        for name, n in model.tagged_layers.items()},
-        v_head_dim=model.v_head_dim, passes=model.loop_steps)
+        v_head_dim=model.v_head_dim, passes=model.loop_steps,
+        shared_elems_per_token=model.shared_elems_per_token)

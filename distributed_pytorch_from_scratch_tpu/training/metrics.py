@@ -209,16 +209,25 @@ def loop_counters_summary(counters: dict) -> dict:
 
 def mixer_counters_summary(counters: dict) -> dict:
     """The counters of a DENSE family whose mixers count (the `ssm_dense`
-    family: no router, so no row of `moe_counters_summary`'s) as a log
-    line's numbers: the loss, the worst Mamba-2 layer's most negative `dt
-    A` summed over a chunk (`ssm_decay_min`, parallel/mamba.py) and the RMS
-    of the residual stream that enters the final norm (`resid_rms_last`:
-    what the embedding's and the residual's multipliers hold steady)."""
+    and `sambay` families: no router, so no row of
+    `moe_counters_summary`'s) as a log line's numbers: the loss and the RMS
+    of the residual stream that enters the final norm (`resid_rms_last`),
+    and what the family's mixers count: the worst Mamba-2 layer's most
+    negative `dt A` summed over a chunk (`ssm_decay_min`,
+    parallel/mamba.py), or the worst Mamba-1 layer's of one step
+    (`sscan_decay_min`, parallel/mamba1.py), the differential attention
+    layers' mean `lambda` (`diff_lambda`), the RMS of the memory one layer
+    leaves the layers above it (`memory_rms`) and the layers that read the
+    one layer's keys and values (`shared_kv_readers`)."""
     import numpy as np
 
-    return {"loss_main": float(counters["loss_main"]),
-            "ssm_decay_min": float(np.min(counters["ssm_decay_min"])),
-            "resid_rms_last": float(counters["resid_rms_last"])}
+    out = {"loss_main": float(counters["loss_main"])}
+    for name, fold in (("ssm_decay_min", np.min), ("sscan_decay_min", np.min),
+                       ("diff_lambda", np.mean), ("memory_rms", np.mean),
+                       ("shared_kv_readers", np.mean)):
+        if name in counters:
+            out[name] = float(fold(counters[name]))
+    return {**out, "resid_rms_last": float(counters["resid_rms_last"])}
 
 
 def moe_counters_summary(counters: dict, cfg, tokens: int) -> dict:

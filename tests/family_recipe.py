@@ -47,7 +47,8 @@ TINY_PRESETS = {"llama": "tiny", "gpt2": "tiny", "mla_moe": "tiny-mla-moe",
                 "mhc_mla_moe": "tiny-mhc-mla-moe",
                 "kda_mla_moe": "tiny-kda-mla-moe", "ssm_moe": "tiny-ssm-moe",
                 "loop_llama": "tiny-loop-llama",
-                "ssm_dense": "tiny-ssm-dense", "dsa_moe": "tiny-dsa-moe"}
+                "ssm_dense": "tiny-ssm-dense", "dsa_moe": "tiny-dsa-moe",
+                "sambay": "tiny-sambay"}
 
 
 # ---- the configuration, the batch, the mesh, the model ----

@@ -21,6 +21,7 @@ from distributed_pytorch_from_scratch_tpu.config import (FAMILY_FACTS,
                                                          LatentMoEConfig,
                                                          ModelConfig,
                                                          LoopLlamaConfig,
+                                                         SambaYConfig,
                                                          SsmDenseConfig,
                                                          SsmMoEConfig,
                                                          SwaMoEConfig,
@@ -107,9 +108,18 @@ DSA = dict(head_dim=16, moe_intermediate_size=16, indexer_num_heads=2,
            indexer_head_dim=8, topk=8)
 
 
+# the sambay family: all five kinds of layer in 8 published layers, a
+# window of 8 rows, scans over a state 4 wide
+SAMBAY = dict(num_hidden_layers=8, sliding_window=8, mamba_d_state=4)
+
+
 def config_for(family, config):
     extra = FAMILIES[family].config_extra
     held = None if config == "dense" else 4
+    if extra == "sambay":
+        # a dense family with facts: no expert to hold
+        return ModelConfig(num_kv_heads=2, **{**TINY, "num_layers": 8},
+                           sambay=SambaYConfig(**SAMBAY))
     if extra == "dsa_moe":
         return ModelConfig(num_experts=8, num_kv_heads=2, **TINY,
                            dsa_moe=DsaMoEConfig(experts_held=held, **DSA))

@@ -513,6 +513,37 @@ def test_the_recurrences_kernels_compile_at_the_ssm_cells_shapes(
         ("ssd_bwd", 7), ("ssd_fwd", 5)]
 
 
+def test_the_selective_scans_kernels_compile_at_the_sambay_cells_shape(
+        topo, described_tpu):
+    """Mosaic takes the Mamba-1 scan's two kernels (PR 76) at cell 18's one
+    sequence (16,384 tokens of 5120 channels over a state of 16, u in
+    bf16), reached through `ops/selective_scan.selective_scan` as the mixer
+    calls it, under the names and operand counts that keep them out of the
+    benchmark's flash patterns; the states of every step, (16384, 5120, 16)
+    float32, are nowhere in the text, and the forward keeps the state a
+    chunk of 128 tokens entered with."""
+    from jax.sharding import SingleDeviceSharding
+    from distributed_pytorch_from_scratch_tpu.ops.selective_scan import (
+        selective_scan)
+    chip = SingleDeviceSharding(topo.devices[0])
+    arg = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=chip)
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    args = (arg(bf16, 1, 16384, 5120), arg(f32, 1, 16384, 5120),
+            arg(f32, 5120, 16), arg(f32, 1, 16384, 16),
+            arg(f32, 1, 16384, 16))
+    loss = lambda *a: jnp.sum(selective_scan(*a)[0])
+    text = jax.jit(jax.grad(loss, range(5))).lower(*args).compile().as_text()
+    calls = re.findall(
+        r"%([\w.\-]+) = ([^\n]*?) custom-call\(([^)]*)\), "
+        r'custom_call_target="tpu_custom_call"', text)
+    assert sorted((re.search(r"sscan_(fwd|bwd)", name).group(),
+                   operands.count("%")) for name, _, operands in calls) == [
+        ("sscan_bwd", 7), ("sscan_fwd", 5)]
+    assert any("f32[1,128,16,5120]" in out for _, out, _ in calls)
+    assert "16384,5120,16]" not in text and "16384,16,5120]" not in text
+
+
 def test_the_conv_moe_cells_step_fits_a_v5e_at_the_rung_auto_picks(
         topo, described_tpu):
     """The fifth cell's step (`lfm2-8b-a1b.train-ep4share-b2-t8192`: the
@@ -879,6 +910,12 @@ CHIP_GIB = {
     # (t, t) index score is in no term: the kernels make it a tile)
     "keye-vl-2.0-30b-a3b.train-ep8share-b1-t16384": {"dots": 14.917,
                                                      "flash": 13.861},
+    # (PR 76's reading at the floor, which `auto` picks beside 10.39 GiB of
+    # state: the next rung's `ffn_gate` / `ffn_up` stacks are 4.0 GiB at
+    # 16k. The memory and the one layer's keys and values, kept whatever
+    # the rung, are `shared_elems_per_token`; the family's last term is set
+    # from this reading)
+    "phi-4-mini-flash-reasoning.train-b1-t16384": {"true": 12.068},
 }
 SNAPSHOTS = ("gpt2-medium.train-ckpt-every40",)
 

@@ -315,7 +315,7 @@ def test_the_train_step_counts_its_decays_and_the_residuals_rms():
     summary = mixer_counters_summary(jax.device_get(c))
     assert summary["ssm_decay_min"] == float(jnp.min(c["ssm_decay_min"]))
     from distributed_pytorch_from_scratch_tpu.obs import schema
-    assert set(summary) == set(schema.EVENT_REQUIRED["mixer_counters"]) == {
+    assert set(schema.EVENT_REQUIRED["mixer_counters"]) <= set(summary) == {
         "loss_main", "ssm_decay_min", "resid_rms_last"}
 
 
