@@ -287,17 +287,21 @@ class SambaYTransformer(DecoderStack):
     @property
     def layer_extra_elems_per_token(self) -> float:
         """SET FROM THE CHIP'S READING, and negative: the benchmark's cell
-        on a v5e counts 12.068 GiB at rung `true`, which `auto` picks beside
-        10.39 GiB of state (my chip run, PR 76; PERF.md section 5), where
-        the untuned count made 13.80: what a layer's backward holds at its
+        on a v5e counts 12.068 GiB at rung `true`, the floor, beside 10.39
+        GiB of state (my chip run, PR 76; PERF.md section 5), where the
+        untuned count made 13.80: what a layer's backward holds at its
         fullest (by count a Mamba layer's `[u | z]`, the convolution's
         float32 sums, dt, the scan's float32 output and the gated copy, each
         with its cotangent: more than the dense skeleton's 6 d + 3.4 f a
         token) is live in the MIDDLE of the backward scan, when the later
         layers' gradients do not exist yet in the runtime's count, as in
         `models/ssm_dense.py`. `LAYER_FIT_WIDTHS` model widths a token come
-        back off, which makes 12.31 (+2.0%). The next rung, `ffn`, keeps 4.0
-        GiB of `ffn_gate` / `ffn_up` stacks at 16k and does not fit."""
+        back off, which makes 12.31 (+2.0%). The next group, `ffn`, keeps 4.0
+        GiB of `ffn_gate` / `ffn_up` stacks at 16k and does not fit: `auto`
+        passes over it and keeps the flash outputs and q, k, v of the three
+        attention layers behind it (`true+flash+dots`), where the count
+        makes 13.18 and the chip held 13.018 (+1.3%; my chip run, PR 77;
+        12.79 against 12.856 with the flash group alone, -0.5%)."""
         return LAYER_FIT_WIDTHS * self.d
 
     # ---- sub-module definitions ----

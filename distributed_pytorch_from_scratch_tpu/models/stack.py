@@ -251,7 +251,11 @@ def resolve_dp_reduce(*, dp_size: int, dense: bool, pp_size: int = 1) -> str:
 # the layer input's, and the chip measured that as a loss. Rung k keeps the
 # names of rungs 1..k; rung 0 keeps the layer input only (full remat); the
 # top rung keeps every matmul output a layer's backward reads, which is
-# what 'dots' has always meant here. Weights gathered by ZeRO-3 are never
+# what 'dots' has always meant here. That is what a rung's NAME means.
+# `remat="auto"` resolves to a SET of the groups below: one climb in this
+# order keeps each group that fits on top of those kept and passes over one
+# that does not (`training/memory._pick`), so a set need not be a prefix
+# (`remat_groups` has the spelling). Weights gathered by ZeRO-3 are never
 # on the ladder.
 REMAT_LADDER = (
     ("true", ()),
@@ -276,40 +280,68 @@ LADDER_MADE_BY = {
 }
 
 
-def remat_rung(remat) -> int:
-    """The ladder rung of a `remat` value that names one: True is rung 0,
-    a rung's name itself."""
+def remat_groups(remat) -> Tuple[str, ...]:
+    """The ladder's groups a `remat` value keeps, in the ladder's order:
+    none for True, a rung's name its prefix of the ladder, and the ONE
+    spelling of a set that is no prefix: the floor's name and the groups'
+    joined by '+' in the ladder's order ('true+flash+dots': the flash
+    kernel's outputs and q, k, v without the MLP's stacks)."""
     if remat is True:
-        return 0
-    if isinstance(remat, str) and remat in REMAT_RUNGS:
-        return REMAT_RUNGS.index(remat)
+        return ()
+    if isinstance(remat, str):
+        if remat in REMAT_RUNGS:
+            return REMAT_RUNGS[1:REMAT_RUNGS.index(remat) + 1]
+        floor, *groups = remat.split("+")
+        if floor == REMAT_RUNGS[0] and groups and groups == [
+                g for g in REMAT_RUNGS[1:] if g in groups]:
+            return tuple(groups)
     raise ValueError(
-        f"remat must be True, False, 'auto' or one of {REMAT_RUNGS}, "
-        f"got {remat!r}")
+        f"remat must be True, False, 'auto', one of {REMAT_RUNGS} or "
+        f"'{REMAT_RUNGS[0]}' and groups of the ladder joined by '+' in its "
+        f"order, got {remat!r}")
+
+
+def remat_names(remat) -> Tuple[str, ...]:
+    """The `checkpoint_name` tags a `remat` value keeps: its groups'."""
+    ladder = dict(REMAT_LADDER)
+    return tuple(n for group in remat_groups(remat) for n in ladder[group])
+
+
+def remat_spelling(groups, empty=()) -> str:
+    """The value that keeps `groups` (in the ladder's order): the name of
+    the lowest rung whose prefix holds them and beside them only groups of
+    `empty` (those that keep nothing in the model at hand: a name none of
+    its layers tags), else the joined spelling `remat_groups` reads."""
+    groups = tuple(groups)
+    for k, rung in enumerate(REMAT_RUNGS):
+        prefix = REMAT_RUNGS[1:k + 1]
+        if set(groups) <= set(prefix) <= set(groups) | set(empty):
+            return rung
+    return "+".join(REMAT_RUNGS[:1] + groups)
 
 
 def validate_remat(remat) -> None:
     if remat is not False and remat != "auto":
-        remat_rung(remat)
+        remat_groups(remat)
 
 
 def remat_wrap(layer_fn, remat, static_argnums=(), looped: bool = True):
     """Apply a per-layer remat policy; shared by every model family.
 
-    `remat` is False (keep everything autodiff saves) or a rung of
-    REMAT_LADDER: the layer is a `jax.checkpoint` whose policy saves the
-    rung's names and recomputes the rest. Rung 0 passes no policy, so it is
-    the program `remat=True` has always been. 'auto' is resolved by the
-    caller (`resolve_remat`) before it gets here: the rung depends on the
-    shapes the layer is traced with. `looped`: does the layer run in a scan
-    of several layers.
+    `remat` is False (keep everything autodiff saves) or names groups of
+    REMAT_LADDER (`remat_groups`: a rung's name, or a joined set): the
+    layer is a `jax.checkpoint` whose policy saves the groups' names and
+    recomputes the rest. Rung 0 passes no policy, so it is the program
+    `remat=True` has always been. 'auto' is resolved by the caller
+    (`resolve_remat`) before it gets here: what fits depends on the shapes
+    the layer is traced with. `looped`: does the layer run in a scan of
+    several layers.
     """
     if remat is False:
         return layer_fn
-    rung = remat_rung(remat)
-    if rung == 0:
+    names = remat_names(remat)
+    if not names:
         return jax.checkpoint(layer_fn, static_argnums=static_argnums)
-    names = [n for _, ns in REMAT_LADDER[:rung + 1] for n in ns]
     # prevent_cse=False where the layer runs inside a lax.scan of several
     # layers: its forward and backward are separate loops, so there is
     # nothing to CSE the recomputation with. The barrier that guards
@@ -346,10 +378,11 @@ def _pull_of(pull, params: Params):
 
 
 def resolve_remat(model, params: Params, ids_shape):
-    """`model.remat`, with 'auto' replaced by the rung `select_remat_traced`
-    picks for the shapes this trace holds: `params` and `ids_shape` are the
-    per-shard ones (this is called inside shard_map). Nothing is compiled
-    to find out; the answer is cached per (model, shapes)."""
+    """`model.remat`, with 'auto' replaced by what `select_remat_traced`
+    keeps at the shapes this trace holds (a rung's name, or a joined set):
+    `params` and `ids_shape` are the per-shard ones (this is called inside
+    shard_map). Nothing is compiled to find out; the answer is cached per
+    (model, shapes)."""
     if model.remat != "auto":
         return model.remat
     from ..training.memory import select_remat_traced
@@ -576,14 +609,17 @@ class DecoderStack:
     #            matmul output a layer's backward reads (needs flash
     #            attention or short t: the XLA attention path's softmax
     #            residual is O(t^2) and is recomputed, never kept)
+    #   groups of the ladder joined ('true+flash+dots') — keep just those
     #   False  — no remat (reference behaviour; OOMs the 45M b32xt1000 run
     #            on a 16G chip)
-    # 'auto' (the default) keeps what the chip has room to keep: a rung of
-    # REMAT_LADDER ('true' = True, 'attn_proj', 'ffn', 'flash', 'dots'),
-    # picked while the model is traced from the per-shard shapes and the
-    # device's memory_stats (training/memory.select_remat_traced). A backend
-    # with no memory_stats (the CPU) gets rung 0 unless `remat_budget_gib`
-    # names the HBM to size against.
+    # 'auto' (the default) keeps what the chip has room to keep: the groups
+    # of REMAT_LADDER ('attn_proj', 'ffn', 'flash', 'dots') that fit, asked
+    # in that order, one that does not fit passed over (a rung's name where
+    # they are its prefix), found while the model is traced from the
+    # per-shard shapes and the device's memory_stats
+    # (training/memory.select_remat_traced). A backend with no memory_stats
+    # (the CPU) gets rung 0 unless `remat_budget_gib` names the HBM to size
+    # against.
     remat: "bool | str" = "auto"
     remat_budget_gib: "float | None" = None
     # Pad-aware sequence bucketing: when the caller pads its (b, t) batch up

@@ -51,7 +51,7 @@ from ..parallel.moe import MoEFFN
 from ..parallel.norm import RMSNorm
 from ..runtime.prng import fold
 from .stack import (NEG_INF, REMAT_LADDER, REMAT_RUNGS,  # noqa: F401
-                    DecoderStack, Params, TPSublayers, remat_rung,
+                    DecoderStack, Params, TPSublayers, remat_groups,
                     remat_wrap, resolve_remat, resolve_tp_layout,
                     validate_cp, validate_pp, validate_remat,
                     validate_t_real, validate_tp_overlap)

@@ -171,10 +171,11 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
                     family: str = "llama") -> List[PhaseCost]:
     """Per-phase FLOPs + HBM bytes for ONE fwd+bwd+adam train step (global,
     all devices), itemised so shares can be compared against measured
-    fwd/bwd/adam times. remat is 'false' or a REMAT_LADDER rung's name. A
-    family whose stack a step passes R times over the same weights
-    (`DecoderStack.passes`) runs every layer phase R x L times and the
-    head and the CE R times (an exit a pass); the embedding and Adam once."""
+    fwd/bwd/adam times. remat is 'false' or names REMAT_LADDER's groups (a
+    rung, or a joined set). A family whose stack a step passes R times over
+    the same weights (`DecoderStack.passes`) runs every layer phase R x L
+    times and the head and the CE R times (an exit a pass); the embedding
+    and Adam once."""
     from ..models import family_class
     R = family_class(family).passes(cfg) or 1
     d, f, L = cfg.attn_dim, cfg.ffn_dim, cfg.num_layers * R
@@ -226,18 +227,17 @@ def analytic_phases(cfg, batch: int, t: int, remat: str = "dots",
     #   'false' — nothing replays
     layer_fwd_flops = sum(p.flops for p in fwd[1:6])
     layer_fwd_bytes = sum(p.bytes for p in fwd[1:6])
-    # the rungs of models/transformer.REMAT_LADDER in between keep the
-    # attention projection, then the FFN's input matmuls, then the flash
-    # outputs; the top rung keeps q/k/v too, which leaves the elementwise
-    # replay
+    # what each group of models/transformer.REMAT_LADDER takes out of the
+    # replay: the attention projection, the FFN's input matmuls, the flash
+    # kernel, and with q/k/v every other matmul (only the elementwise
+    # replay is left at the top rung); `remat` names the groups kept, as a
+    # rung's prefix or a joined set (`remat_groups`)
+    from ..models.stack import remat_groups
     ffn_in = fwd[4].flops * (ffn_mats - 1) / ffn_mats
-    kept = (fwd[3].flops, ffn_in, fwd[2].flops)
-    recompute = {"true": layer_fwd_flops,
-                 "attn_proj": layer_fwd_flops - sum(kept[:1]),
-                 "ffn": layer_fwd_flops - sum(kept[:2]),
-                 "flash": layer_fwd_flops - sum(kept),
-                 "dots": fwd[5].flops,
-                 "false": 0.0}[str(remat)]
+    kept = {"attn_proj": fwd[3].flops, "ffn": ffn_in, "flash": fwd[2].flops,
+            "dots": fwd[1].flops + fwd[4].flops - ffn_in}
+    recompute = (0.0 if str(remat) == "false" else layer_fwd_flops - sum(
+        kept[group] for group in remat_groups(str(remat))))
     recompute_bytes = (layer_fwd_bytes * recompute / layer_fwd_flops
                        if layer_fwd_flops else 0.0)
     bwd_flops = (2 * (fwd[1].flops + fwd[3].flops + fwd[4].flops

@@ -258,10 +258,11 @@ def get_train_args(argv=None) -> argparse.Namespace:
     g.add_argument("--remat", choices=sorted(REMAT_CHOICES) + ["auto"],
                    default="auto",
                    help="per-layer rematerialisation: 'auto' (default) = "
-                        "keep as many of the layer's named residuals "
-                        "(models/transformer.REMAT_LADDER) as the step's "
-                        "memory estimate says the chip has room for "
-                        "(training/memory.select_remat; rung 0 on a "
+                        "keep every group of the layer's named residuals "
+                        "(models/transformer.REMAT_LADDER, asked in its "
+                        "order) that the step's memory estimate says the "
+                        "chip has room for, passing over a group that does "
+                        "not fit (training/memory.select_remat; rung 0 on a "
                         "backend with no memory_stats); 'true' = rung 0, "
                         "recompute everything; 'dots' = the top rung, "
                         "every matmul output kept; 'false' = no remat")

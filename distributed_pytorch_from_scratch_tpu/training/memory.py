@@ -1,11 +1,15 @@
-"""Step-memory estimate + the selector of the remat rung.
+"""Step-memory estimate + the selector of what the backward keeps.
 
-`models/transformer.REMAT_LADDER` orders the per-layer residuals a backward
-may keep instead of recomputing. `select_remat` picks the highest rung whose
-estimated peak fits the device: it is what a model built with
-`remat="auto"` (the default) calls at trace time with the per-shard shapes
-it is traced with (`select_remat_traced`), and what `train.py --remat auto`
-calls with the ZeRO stage only it knows.
+`models/transformer.REMAT_LADDER` orders the groups of per-layer residuals
+a backward may keep instead of recomputing. `select_remat` climbs it once,
+in that order, and keeps every group whose estimated peak, on top of those
+kept so far, fits the device; a group that does not fit is passed over and
+the climb goes on (`_pick`). It is what a model built with `remat="auto"`
+(the default) calls at trace time with the per-shard shapes it is traced
+with (`select_remat_traced`), and what `train.py --remat auto` calls with
+the ZeRO stage only it knows. A rung's NAME still means its prefix of the
+ladder; what `auto` resolves to is a set of groups, spelt as a rung where
+it is one.
 
 `estimate_step_gib` is held to what the chip counts (`memory_stats()`:
 `peak_bytes_in_use + peak_bytes_reserved`), not to the compiler's plan: on
@@ -26,7 +30,7 @@ from __future__ import annotations
 
 import functools
 import sys
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 GIB = 1024 ** 3
 
@@ -75,12 +79,13 @@ def zero_state_bytes_per_param(zero_stage: int, dp: int,
     return 16.0 / dp + extra
 
 
-def _rung_index(remat) -> Optional[int]:
-    """Ladder index of a remat value or CLI key; None for no remat."""
-    from ..models.transformer import remat_rung
+def _kept_names(remat) -> Optional[Tuple[str, ...]]:
+    """The ladder's names a remat value or CLI key keeps; None for no
+    remat."""
+    from ..models.stack import remat_names
     if remat is False or str(remat).lower() == "false":
         return None
-    return remat_rung(remat if remat is True else str(remat).lower())
+    return remat_names(remat if remat is True else str(remat).lower())
 
 
 def step_bytes(remat, *, param_count: float, layer_param_count: float,
@@ -112,8 +117,10 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
               hoists the casts out of the layer loops)
     stacks    the layer input every rung keeps (`residual_streams` x d wide:
               a family of hyper-connections carries several,
-              `DecoderStack.stream_mixer`), L of it, plus the rung's
-              residuals at their logical sizes (the chip's count: the stack
+              `DecoderStack.stream_mixer`), L of it, plus the residuals of
+              the groups `remat` keeps (`models/stack.remat_groups`: a
+              rung's prefix of the ladder, or a joined set) at their
+              logical sizes (the chip's count: the stack
               of `flash_out` is not kept in the kernel's layout, which pads
               a head of 64 to the 128 lanes, and `flash_lse` is named as
               (b h, t) float32, t on the lanes; a family whose mask is data
@@ -151,8 +158,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     own transpose held a second stack, and PR 66 counted it). Held to the
     chip at the one cell that passes (cell 14: PERF.md section 5, PR 67).
     """
-    from ..models.transformer import REMAT_LADDER
-    rung = _rung_index(remat)
+    kept = _kept_names(remat)
     tok = b * t
     R = passes or 1
     depth = layers * R
@@ -173,7 +179,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
         "ffn_gate": f_w if ffn_inputs == 2 else 0.0,
         "ffn_up": f_w if ffn_inputs == 2 else 0.0,
     }
-    if rung is None:
+    if kept is None:
         # no remat: everything autodiff saves on the flash path — layer
         # input, 2 norm outputs, q/k/v and their head-split copies, flash
         # o/lse + the projection's input, both row-linear outputs, the
@@ -185,8 +191,7 @@ def step_bytes(remat, *, param_count: float, layer_param_count: float,
     else:
         tagged = tagged_layers or {}
         stacks = depth * residual_streams * wide + sum(
-            tagged.get(n, layers) * R * names[n]
-            for _, ns in REMAT_LADDER[:rung + 1] for n in ns)
+            tagged.get(n, layers) * R * names[n] for n in kept)
     if passes is not None:
         stacks += (2 + 4 / dtype_bytes) * passes * wide
     stacks += tok * shared_elems_per_token * dtype_bytes
@@ -243,7 +248,8 @@ def estimate_step_gib(cfg, batch: int, seqlen: int, remat,
                       sequence_parallel: bool = False) -> float:
     """Peak-HBM estimate (GiB, per device) for one fwd+bwd+Adam train step
     of `cfg` built as `family`, at the GLOBAL `batch` over `world` devices.
-    `remat` is a rung of the ladder ('true' ... 'dots') or 'false'."""
+    `remat` is a rung of the ladder ('true' ... 'dots'), a joined set of
+    its groups or 'false'."""
     return _cfg_step_bytes(cfg, batch, seqlen, remat, tp, world, dtype_bytes,
                            zero_stage, dp, family,
                            sequence_parallel)["total"] / GIB
@@ -270,22 +276,31 @@ def hbm_budget_gib() -> float:
 
 def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
           allow_false: bool, verbose: bool, note: str = "") -> str:
-    """The one selector: the highest rung (or 'false' above the ladder)
-    whose `parts(key)["total"]` (a `step_bytes`) fits MARGIN x (budget -
-    reserve); of rungs that keep the same, the lowest.
+    """The one selector: 'false' (above the ladder) where it fits, else ONE
+    climb of the ladder in its order from the floor (the layer input only).
+    Each group is sized on top of those kept so far, `parts(value)["total"]`
+    (a `step_bytes`), and kept if that fits MARGIN x (budget - reserve); a
+    group that does not fit is PASSED OVER and the climb goes on to the
+    next (dense layers at long context: the MLP's stacks are 4 GiB where
+    the flash outputs and q, k, v behind them are 0.7); a group that adds
+    nothing here (a name no layer of this model tags: `attn_proj` at tp 1,
+    the MLP's names where every layer is routed) is neither. What comes
+    back names the groups kept (`models/stack.remat_spelling`): the lowest
+    rung whose prefix keeps exactly that, else the joined spelling.
 
     `budget_gib` None reads the device; a backend with no `memory_stats`
     (the CPU) then gets rung 0, the program `remat=True` has always been.
     `reserve_gib` None leaves room for one more copy of the resident state,
     `AsyncCheckpointer`'s snapshot, which a model cannot know its caller
     makes, wherever the floor rung fits beside it; where even the floor
-    does not, no rung could honour the reserve, no snapshot can be taken
-    and nothing is held back (`reserve_held` False: a chip's share of an
-    expert model, 7 - 9 GiB of state). A `reserve_gib` the caller names is
-    held as given. Says what it chose on stderr and on the program's
-    tracer (the instant's `grads_gib`: the estimate's gradient tree, the
-    same at every rung)."""
-    from ..models.transformer import REMAT_RUNGS
+    does not, nothing kept could honour the reserve, no snapshot can be
+    taken and nothing is held back (`reserve_held` False: a chip's share of
+    an expert model, 7 - 9 GiB of state). A `reserve_gib` the caller names
+    is held as given. Says what it chose on stderr and on the program's
+    tracer: the instant's `kept` (the groups, in order), `passed_over`
+    (group -> the estimate that refused it) and `grads_gib` (the estimate's
+    gradient tree, the same whatever is kept)."""
+    from ..models.stack import REMAT_RUNGS, remat_spelling
     from ..obs.trace import current_tracer
     floor = REMAT_RUNGS[0]
     if budget_gib is None:
@@ -309,30 +324,35 @@ def _pick(parts, budget_gib: Optional[float], reserve_gib: Optional[float],
                           else parts(key))["total"] / GIB
         return sizes[key]
 
-    picked = next(key for key in (("false",) if allow_false else ())
-                  + REMAT_RUNGS[::-1] if size(key) <= usable or key == floor)
-    # a rung that keeps nothing here the rung below it does not (a name no
-    # layer of this model tags: `attn_proj` at tp 1, the MLP's names where
-    # every layer is routed) IS the rung below it
-    while picked in REMAT_RUNGS[1:]:
-        below = REMAT_RUNGS[REMAT_RUNGS.index(picked) - 1]
-        if size(below) < size(picked):
-            break
-        picked = below
+    kept, empty, passed_over = [], [], {}
+    picked = "false" if allow_false and size("false") <= usable else floor
     size(floor)     # always said
+    for group in REMAT_RUNGS[1:] if picked == floor else ():
+        asked = remat_spelling(kept + [group], empty)
+        if size(asked) <= size(picked):
+            empty.append(group)
+        elif size(asked) <= usable:
+            kept.append(group)
+            picked = asked
+        else:
+            passed_over[group] = size(asked)
     fields = dict(rung=picked, estimate_gib=sizes[picked],
                   budget_gib=budget_gib, reserve_gib=reserve,
                   reserve_held=held, usable_gib=usable,
-                  grads_gib=at_floor["grads"] / GIB,
+                  grads_gib=at_floor["grads"] / GIB, kept=kept,
+                  passed_over=passed_over,
                   **{f"estimate_gib.{k}": v for k, v in sizes.items()})
     tracer = current_tracer()
     if tracer is not None:
         tracer.instant("remat_auto", **fields)
     if verbose:
         est = ", ".join(f"{k}={v:.2f}GiB" for k, v in sizes.items())
-        print(f"remat auto: picked '{picked}' (estimates {est}; budget "
-              f"{budget_gib:.2f} GiB - reserve {reserve:.2f} GiB, x margin "
-              f"{MARGIN}; reserve_held={held}{note})", file=sys.stderr)
+        over = (" passing over " + ", ".join(passed_over)
+                if passed_over else "")
+        print(f"remat auto: picked '{picked}'{over} (estimates {est}; "
+              f"budget {budget_gib:.2f} GiB - reserve {reserve:.2f} GiB, x "
+              f"margin {MARGIN}; reserve_held={held}{note})",
+              file=sys.stderr)
     return picked
 
 
@@ -341,10 +361,12 @@ def select_remat(cfg, batch: int, seqlen: int, tp: int = 1, world: int = 1,
                  zero_stage: int = 0, dp: int = 1, family: str = "llama",
                  reserve_gib: Optional[float] = None,
                  sequence_parallel: bool = False) -> str:
-    """The fastest remat setting whose estimated peak fits the device:
-    'false', or a rung of `models/transformer.REMAT_LADDER` ('dots' ...
-    'true'); a value `Transformer(remat=...)` takes once 'true'/'false'
-    go through `config.REMAT_CHOICES`.
+    """What a step may keep of a layer with its estimated peak fitting the
+    device: 'false', or the groups of `models/transformer.REMAT_LADDER` the
+    climb keeps (`_pick`), under a rung's name ('true' ... 'dots') where
+    they are its prefix and joined ('true+flash+dots') where a group in
+    the middle was passed over; a value `Transformer(remat=...)` takes
+    once 'true'/'false' go through `config.REMAT_CHOICES`.
 
     `zero_stage`/`dp` size the train state per the ZeRO ladder. Stage 3
     never picks 'false': without remat, autodiff saves every layer's
@@ -368,8 +390,8 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
     holds — this device's parameter count and its (b, t) token block — and
     the device's `memory_stats()`. No second compile, no flag. The model
     cannot see a ZeRO stage (stage 0's state is the largest) and never
-    picks 'false': a rung is always a rematerialising model, which is what
-    ZeRO-3's gather inside the layer body needs."""
+    picks 'false': what the climb keeps is always a rematerialising model,
+    which is what ZeRO-3's gather inside the layer body needs."""
     return _pick(traced_step_bytes(model, param_count, layer_param_count, b,
                                    t),
                  model.remat_budget_gib, None, allow_false=False,
@@ -379,9 +401,9 @@ def select_remat_traced(model, param_count: int, layer_param_count: int,
 
 def traced_step_bytes(model, param_count: int, layer_param_count: int,
                       b: int, t: int):
-    """rung -> `step_bytes` of `model` at one device's parameter counts and
-    (b, t) token block, with what the model says of itself beside its
-    config's widths."""
+    """remat value -> `step_bytes` of `model` at one device's parameter
+    counts and (b, t) token block, with what the model says of itself
+    beside its config's widths."""
     cfg = model.cfg
     pp = model.pp_size
     return functools.partial(

@@ -154,10 +154,14 @@ def test_gpt2_family_prices_two_matmul_ffn(cfg45m):
 
 def test_attribution_remat_ordering(cfg45m):
     """remat=true must price strictly more recompute than dots, and dots
-    more than false."""
+    more than false; a joined set of the ladder's groups (what `auto`
+    resolves to where it passes over a group) is priced by what it keeps."""
     ms = {r: attribution(cfg45m, 32, 1000, remat=r)["analytic_step_ms"]
-          for r in ("false", "dots", "true")}
-    assert ms["false"] < ms["dots"] < ms["true"]
+          for r in ("false", "dots", "true+flash+dots", "flash",
+                    "true+flash", "true")}
+    assert ms["false"] < ms["dots"] < ms["true+flash+dots"] \
+        < ms["true+flash"] < ms["true"]
+    assert ms["flash"] < ms["true+flash"]
 
 
 def test_format_attribution_renders_table(cfg45m):
@@ -283,7 +287,10 @@ def test_select_remat_picks_the_cells_rungs(capsys):
         == "ffn"
     err = capsys.readouterr().err
     assert "remat auto: picked 'ffn'" in err and "reserve 3.97 GiB" in err
-    assert "dots=13.06GiB" in err and "ffn=10.79GiB" in err
+    # (the climb sizes each group on top of what it kept: `flash` over
+    # `ffn`, then q, k, v over `ffn` alone, and neither fits)
+    assert "ffn=10.79GiB" in err and "flash=11.37GiB" in err
+    assert "passing over flash, dots" in err and "true+ffn+dots=" in err
     assert select_remat(ModelConfig(**LARGE), budget_gib=limit,
                         verbose=False, **CELL2) == "flash"
     # ... and in the layout the model resolves to at tp 2 (PR 28), whose

@@ -373,11 +373,23 @@ def channel_jaxpr(*args, grad=False):
 
 
 class _Said:
+    """The program's tracer for a test: keeps the instants. Once a test of
+    the same worker has run `train()`, `runtime/compile_cache.py`'s
+    listener is registered for good and writes a compile span into
+    whatever tracer is current (which files share a worker changes from
+    run to run): its two calls are taken and dropped."""
+
     def __init__(self):
         self.instants = []
 
     def instant(self, name, **fields):
         self.instants.append((name, fields))
+
+    def now(self):
+        return 0.0
+
+    def complete_span(self, name, start, end, **fields):
+        pass
 
 
 def test_off_the_tpu_or_at_other_widths_the_channel_rule_is_the_xla_text(

@@ -391,7 +391,11 @@ def test_the_mixers_round_trip_through_canonical_and_a_checkpoint(tmp_path):
 # test_bd_moe.py and tests/test_conv_moe.py hold five of the digests too; a
 # PR that means to change a family's program changes them together.
 STANDING = {
-    "llama": ("tiny", "14bb75356a403459", "true", 0.018050289154052733),
+    # (PR 77's pick, which meant to move it: the climb passes over the
+    # MLP's stacks, 0.0220 of a usable 0.0186, and keeps the flash outputs
+    # behind them; `true` at 0.018050289154052733 until then)
+    "llama": ("tiny", "14bb75356a403459", "true+flash",
+              0.018569087982177733),
     "gpt2": ("tiny", "557e9d12313622a3", "dots", 0.01830301284790039),
     "mla_moe": ("tiny-mla-moe", "83b0575bcf151845", "flash",
                 0.01279906988143921),

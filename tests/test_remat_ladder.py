@@ -7,7 +7,10 @@ tensor parallelism, and with the flash kernel's tagged outputs in play. The
 text of the compiled program says what a rung buys under tp: the recomputed
 forward's all-reduce is gone from the rung that keeps the attention
 projection's output past its reduce. And `remat="auto"` with a budget that
-fits nothing is the program `remat=True` has always been.
+fits nothing is the program `remat=True` has always been. What `auto`
+resolves to is a SET of the ladder's groups (one climb that passes over a
+group that does not fit), spelt as a rung where it is one: the climb is
+held on hand-made sizes, and a set that is no prefix to rung 0's numbers.
 """
 
 import dataclasses
@@ -22,13 +25,16 @@ import pytest
 from distributed_pytorch_from_scratch_tpu.config import MeshConfig, ModelConfig
 from distributed_pytorch_from_scratch_tpu.models.gpt2 import GPT2Transformer
 from distributed_pytorch_from_scratch_tpu.models.transformer import (
-    REMAT_LADDER, REMAT_RUNGS, Transformer, remat_rung)
+    REMAT_LADDER, REMAT_RUNGS, Transformer, remat_groups)
 from distributed_pytorch_from_scratch_tpu.runtime.mesh import make_mesh
 
 CFG = ModelConfig(attn_dim=32, ffn_dim=64, num_heads=4, num_layers=2,
                   vocab_size=96, maxlen=32)
 FAMILIES = {"gpt2": GPT2Transformer, "llama": Transformer}
 UPPER_RUNGS = REMAT_RUNGS[1:]
+# a set of the ladder's groups that is no rung's prefix: what `auto` gets
+# where the MLP's stacks do not fit and what stands behind them does
+PASSED_OVER = "true+flash+dots"
 
 
 def batch(t=16, b=4):
@@ -54,14 +60,20 @@ def test_ladder_names_are_ordered_and_unique():
     names = [n for _, ns in REMAT_LADDER for n in ns]
     assert len(names) == len(set(names))
     assert REMAT_RUNGS[0] == "true" and REMAT_RUNGS[-1] == "dots"
-    assert remat_rung(True) == 0 and remat_rung("dots") == len(REMAT_RUNGS) - 1
-    for bad in (1, "sometimes", None):
+    assert remat_groups(True) == remat_groups("true") == ()
+    assert remat_groups("dots") == REMAT_RUNGS[1:]      # a rung: its prefix
+    assert remat_groups("flash") == ("attn_proj", "ffn", "flash")
+    assert remat_groups(PASSED_OVER) == ("flash", "dots")   # a set: itself
+    assert remat_groups("true+flash") == ("flash",)
+    for bad in (1, "sometimes", None, "flash+dots", "true+dots+flash",
+                "true+", "true+true", "true+flash+flash", "true+sometimes"):
         with pytest.raises(ValueError, match="remat must be"):
             Transformer(CFG, remat=bad)
+    assert Transformer(CFG, remat=PASSED_OVER).remat == PASSED_OVER
     assert Transformer(CFG).remat == "auto" == GPT2Transformer(CFG).remat
 
 
-@pytest.mark.parametrize("rung", UPPER_RUNGS)
+@pytest.mark.parametrize("rung", UPPER_RUNGS + (PASSED_OVER,))
 @pytest.mark.parametrize("tp", [1, 2])
 @pytest.mark.parametrize("family", sorted(FAMILIES))
 def test_every_rung_gives_rung_zero_gradients(family, tp, rung):
@@ -163,12 +175,138 @@ def test_auto_records_its_choice_on_the_tracer(tmp_path, capsys):
     assert len(chosen) == 1                      # once per (model, shapes)
     args = chosen[0]["args"]
     assert args["rung"] == "dots" and args["budget_gib"] == 1e3
+    # (nothing tags `attn_proj` at tp 1: not kept, and not passed over)
+    assert args["kept"] == ["ffn", "flash", "dots"]
+    assert args["passed_over"] == {}
     assert args["estimate_gib"] == args["estimate_gib.dots"] > 0
     assert args["reserve_gib"] > 0 and args["usable_gib"] < 1e3
     err = capsys.readouterr().err
     assert err.count("remat auto: picked 'dots'") == 1
     assert dataclasses.replace(GPT2Transformer(CFG), remat="flash").remat \
         == "flash"
+
+
+GIB = 1024 ** 3
+
+
+def hand_made_parts(floor, false=99.0, resident=1.0, **groups):
+    """A `parts` for `_pick` with no model behind it: the floor's GiB and
+    what each group of the ladder adds to it (0: no layer tags its names)."""
+    def parts(value):
+        total = false if value == "false" else floor + sum(
+            groups.get(group, 0.0) for group in remat_groups(value))
+        return {"total": total * GIB, "resident": resident * GIB,
+                "grads": 0.5 * GIB}
+    return parts
+
+
+# the case: (parts, the GiB usable, _pick's reserve and allow_false, (what
+# comes back, kept, passed over with the estimate that refused it,
+# reserve_held))
+CLIMBS = {
+    # a group too large in the middle is passed over, a later one is kept
+    "passes_over_the_middle": (
+        hand_made_parts(10.0, ffn=4.0, flash=0.5, dots=0.4), 13.0, 0.0,
+        False, (PASSED_OVER, ["flash", "dots"], {"ffn": 14.0}, True)),
+    "the_last_kept_alone": (
+        hand_made_parts(10.0, ffn=4.0, flash=0.5, dots=3.0), 13.0, 0.0,
+        False, ("true+flash", ["flash"], {"ffn": 14.0, "dots": 13.5}, True)),
+    # a kept set that is a rung's prefix comes back under the rung's name,
+    # the lowest that keeps the same (`dots` adds nothing here)
+    "a_prefix_is_its_rung": (
+        hand_made_parts(10.0, attn_proj=0.2, ffn=1.0, flash=0.5), 13.0, 0.0,
+        False, ("flash", ["attn_proj", "ffn", "flash"], {}, True)),
+    # a group that adds no bytes is neither kept nor passed over, and the
+    # rung's name stands for the set it keeps beside such groups
+    "no_bytes_is_neither": (
+        hand_made_parts(10.0, flash=0.5, dots=5.0), 13.0, 0.0, False,
+        ("flash", ["flash"], {"dots": 15.5}, True)),
+    "every_group_fits": (
+        hand_made_parts(10.0, ffn=1.0, flash=0.5, dots=0.4), 13.0, 0.0,
+        False, ("dots", ["ffn", "flash", "dots"], {}, True)),
+    # nothing fits: the floor, and no reserve is held where the floor does
+    # not fit beside it
+    "nothing_fits": (
+        hand_made_parts(20.0, ffn=1.0, flash=0.5), 13.0, None, False,
+        ("true", [], {"ffn": 21.0, "flash": 20.5}, False)),
+    # the reserve is held where the floor fits beside it, and what is kept
+    # fits beside it too
+    "beside_the_reserve": (
+        hand_made_parts(9.0, ffn=4.5, flash=0.5, dots=4.0), 13.0, None,
+        False, ("true+flash", ["flash"], {"ffn": 13.5, "dots": 13.5}, True)),
+    # 'false' stands above the ladder and is asked first
+    "false_is_asked_first": (
+        hand_made_parts(10.0, false=11.0, ffn=1.0), 13.0, 0.0, True,
+        ("false", [], {}, True)),
+    "false_does_not_fit": (
+        hand_made_parts(10.0, false=15.0, ffn=1.0), 13.0, 0.0, True,
+        ("ffn", ["ffn"], {}, True)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CLIMBS))
+def test_the_climb_keeps_what_fits_and_passes_over_what_does_not(
+        case, tmp_path, capsys):
+    """`training/memory._pick` on hand-made sizes: one walk of the ladder
+    in its order from the floor, each group sized on top of those kept."""
+    import json
+
+    from distributed_pytorch_from_scratch_tpu.obs.trace import SpanTracer
+    from distributed_pytorch_from_scratch_tpu.training import memory
+    parts, usable, reserve, allow_false, want = CLIMBS[case]
+    # (a reserve that is not named is the resident state's 1 GiB, where
+    # the floor fits beside it)
+    beside = reserve if reserve is not None else 1.0 * want[3]
+    tracer = SpanTracer(str(tmp_path))
+    try:
+        picked = memory._pick(parts, usable / memory.MARGIN + beside,
+                              reserve, allow_false=allow_false, verbose=True)
+    finally:
+        tracer.close()
+    said, = (json.loads(line)["args"] for line in open(
+        tmp_path / "trace.jsonl") if '"remat_auto"' in line)
+    assert (picked, said["kept"], said["passed_over"],
+            said["reserve_held"]) == want
+    assert said["rung"] == picked
+    assert said["estimate_gib"] == said[f"estimate_gib.{picked}"]
+    assert "estimate_gib.true" in said          # the floor is always said
+    err = capsys.readouterr().err
+    assert f"remat auto: picked '{picked}'" in err
+    assert ("passing over " + ", ".join(want[2]) in err) == bool(want[2])
+    if picked != "false":
+        # what comes back keeps exactly the groups that were kept, beside
+        # groups that add nothing
+        assert [g for g in remat_groups(picked)
+                if parts(f"true+{g}")["total"] > parts("true")["total"]
+                ] == said["kept"]
+
+
+def test_a_joined_sets_stacks_are_the_floors_and_its_groups_names():
+    """`step_bytes` of a set that is no prefix: the floor's stacks plus the
+    kept groups' names, each over the layers that tag it, and nothing of
+    the group between."""
+    from distributed_pytorch_from_scratch_tpu.training.memory import (
+        step_bytes)
+    b, t, heads, hd, vhd, kd, layers = 2, 512, 8, 16, 32, 64, 6
+    tagged = {"flash_out": 3, "flash_lse": 3, "q_proj": 3, "k_proj": 2,
+              "v_proj": 2, "ffn_gate": 6, "ffn_up": 6, "ffn_fc": 0}
+    sized = functools.partial(
+        step_bytes, param_count=1e6, layer_param_count=9e5, b=b, t=t, d=128,
+        kd=kd, f=512, heads=heads, head_dim=hd, layers=layers, vocab=1000,
+        tagged_layers=tagged, v_head_dim=vhd)
+    tok = b * t
+    flash = 3 * tok * heads * (vhd * 2 + 4)
+    dots = tok * 2 * (3 * heads * hd + 2 * kd + 2 * kd)
+    floor = sized("true")["stacks"]
+    assert sized("true+flash")["stacks"] == floor + flash
+    assert sized(PASSED_OVER)["stacks"] == floor + flash + dots
+    assert sized("true+dots")["stacks"] == floor + dots
+    ffn = 2 * 6 * tok * 512 * 2
+    assert sized("dots")["stacks"] == floor + ffn + flash + dots
+    # a prefix spelt as a set is the rung
+    assert sized("true+attn_proj+ffn") == sized("ffn")
+    for part in ("resident", "grads", "cast", "head", "layer"):
+        assert sized(PASSED_OVER)[part] == sized("true")[part]
 
 
 def test_attn_proj_is_named_only_past_a_reduce():
@@ -238,7 +376,7 @@ def test_the_flash_rung_runs_the_forward_kernel_once_a_layer():
     backward finds `flash_out` / `flash_lse` saved and one call is left,
     with the same backward kernels either way."""
     calls = {}
-    for rung in REMAT_RUNGS:
+    for rung in REMAT_RUNGS + ("true+flash",):
         mesh = make_mesh(MeshConfig(dp=1, tp=1), devices=jax.devices()[:1])
         model = GPT2Transformer(CFG, remat=rung,
                                 attn_impl="flash_interpret")
@@ -249,8 +387,10 @@ def test_the_flash_rung_runs_the_forward_kernel_once_a_layer():
     keeps = REMAT_RUNGS.index("flash")
     backward = calls["true"][1]     # (the interpreter's split pair here)
     assert backward >= 1
-    assert calls == {r: (2 if i < keeps else 1, backward)
-                     for i, r in enumerate(REMAT_RUNGS)}, calls
+    # (and with the `flash` group kept WITHOUT the MLP's `ffn_fc` below it)
+    assert calls == {"true+flash": (1, backward),
+                     **{r: (2 if i < keeps else 1, backward)
+                        for i, r in enumerate(REMAT_RUNGS)}}, calls
 
 
 @functools.lru_cache(maxsize=None)
@@ -279,7 +419,7 @@ def drawn_loss_and_grads(remat):
     return float(loss), jax.tree.map(np.asarray, grads)
 
 
-@pytest.mark.parametrize("rung", UPPER_RUNGS)
+@pytest.mark.parametrize("rung", UPPER_RUNGS + (PASSED_OVER,))
 def test_every_rung_gives_rung_zero_gradients_in_a_drawn_family(rung):
     want_loss, want = drawn_loss_and_grads(True)
     loss, grads = drawn_loss_and_grads(rung)

@@ -860,7 +860,8 @@ def test_the_selection_kernels_compile_at_the_dsa_cells_shape(
 
 # ---- the estimate against the chip's own counts, cell by cell (PR 62) ----
 
-# cell: {rung: `device.peak_hbm_gib`}, the rung `remat="auto"` picks last.
+# cell: {rung or joined set: `device.peak_hbm_gib`}, what `remat="auto"`
+# picks last.
 # The floor's count is the ledger's (PR 61, every cell then at its floor or
 # at the rung it still picks); the picked rung's is the builder's traced
 # run of PR 62 (PERF.md section 5 has the table). Cell 3 takes the snapshot
@@ -910,12 +911,18 @@ CHIP_GIB = {
     # (t, t) index score is in no term: the kernels make it a tile)
     "keye-vl-2.0-30b-a3b.train-ep8share-b1-t16384": {"dots": 14.917,
                                                      "flash": 13.861},
-    # (PR 76's reading at the floor, which `auto` picks beside 10.39 GiB of
-    # state: the next rung's `ffn_gate` / `ffn_up` stacks are 4.0 GiB at
-    # 16k. The memory and the one layer's keys and values, kept whatever
-    # the rung, are `shared_elems_per_token`; the family's last term is set
-    # from this reading)
-    "phi-4-mini-flash-reasoning.train-b1-t16384": {"true": 12.068},
+    # (PR 76's reading at the floor, from which the family's last term is
+    # set; the memory and the one layer's keys and values, kept whatever
+    # the rung, are `shared_elems_per_token`. Beside 10.39 GiB of state the
+    # `ffn_gate` / `ffn_up` stacks, 4.0 GiB at 16k, do not fit: since PR 77
+    # `auto` PASSES OVER that group and keeps the two behind it, the flash
+    # kernel's outputs in the three attention layers and their q, k, v.
+    # PR 77's readings, what the chip held when the window ended: the
+    # `flash` group alone named in a scratch wrapper, then what `auto`
+    # picks. The flash group reads 0.79 GiB on the chip for 0.48 of named
+    # stacks and q, k, v 0.16 for 0.39: PERF.md section 7)
+    "phi-4-mini-flash-reasoning.train-b1-t16384": {
+        "true": 12.068, "true+flash": 12.856, "true+flash+dots": 13.018},
 }
 SNAPSHOTS = ("gpt2-medium.train-ckpt-every40",)
 

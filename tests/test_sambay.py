@@ -140,6 +140,21 @@ def test_twelve_layers_carry_the_shared_values_through_a_scan_of_periods():
     hold_leaves(want_g, got_g, LEAF_RTOL, floor)
 
 
+def test_the_set_the_benchmarks_cell_keeps_is_the_floors_function():
+    """`remat="auto"` passes over the MLP's stacks in the benchmark's cell
+    and keeps the flash kernel's outputs and q, k, v (`true+flash+dots`,
+    tests/test_remat_topology.py): the same loss and gradients as the floor
+    (what `auto` is on this backend), through the interpreted kernels, in
+    the makers' segments of ONE layer (the policy under its CSE barrier),
+    the scanned periods and the cross layers whose keys are another's."""
+    cfg = dataclasses.replace(tiny(num_hidden_layers=12), num_layers=12)
+    want, want_g = R.program(cfg, t=128, attn_impl="flash_interpret")
+    got, got_g = R.program(cfg, t=128, attn_impl="flash_interpret",
+                           remat="true+flash+dots")
+    hold_loss(want, got, 1e-6)
+    hold_leaves(want_g, got_g, 1e-5, floor)
+
+
 # ---- the shared values' summed cotangents ----
 
 @dataclasses.dataclass(frozen=True)
